@@ -1546,7 +1546,7 @@ def main(argv: list[str] | None = None) -> None:
     parser.add_argument("--port", type=int, default=8100)
     parser.add_argument("--num-slots", type=int, default=8)
     # Default sized so a 4k-token prompt serves out of the box via chunked
-    # prefill (VERDICT r2 item 5). Memory math: scheduler.kv_cache_bytes —
+    # prefill. Memory math: scheduler.kv_cache_bytes —
     # 8 slots x 4096 is 4.3 GiB for llama-3-8b, 1.5 GiB for tinyllama-1.1b.
     # EngineCore clamps to the model's max_position_embeddings.
     parser.add_argument("--slot-capacity", type=int, default=4096)
@@ -1570,10 +1570,9 @@ def main(argv: list[str] | None = None) -> None:
     )
     parser.add_argument(
         "--init-timeout", type=float, default=None,
-        help="TPU backend-init guard: prove jax.devices() completes within "
-             "this many seconds in a probe child before serving; a hang "
-             "dumps the captured libtpu/PJRT log tail + faulthandler stacks "
-             "to stderr and exits instead of wedging silently (default 600; "
+        help="bound on the first device touch: if jax.devices() has not "
+             "returned after this many seconds, dump every thread's stack "
+             "to stderr and exit instead of hanging silently (default 600; "
              "0 disables; also via LLMLB_INIT_TIMEOUT)",
     )
     parser.add_argument(
@@ -1730,25 +1729,22 @@ def main(argv: list[str] | None = None) -> None:
     if args.min_prefix_len is not None:
         extra["min_prefix_len"] = max(1, args.min_prefix_len)
 
-    # Shared logging subsystem (VERDICT L1 gap closed gateway-side in
-    # logging_setup.py): level/format knobs + the worker-id field apply to
-    # engine processes too. No file sink here — engines run under their own
-    # supervisors that capture stderr.
+    # Shared logging subsystem (gateway/logging_setup.py): level/format
+    # knobs + the worker-id field apply to engine processes too. No file
+    # sink here — engines run under their own supervisors that capture
+    # stderr.
     from llmlb_tpu.gateway.logging_setup import init_logging
 
     init_logging(file_sink=False)
-    # TPU backend-init hang guard: BEFORE the first in-process jax backend
-    # touch (which construction below triggers), prove the backend comes up
-    # in a probe child or fail fast with the captured init-log evidence.
-    from llmlb_tpu.engine.tpu_probe import guard_backend_init
+    from llmlb_tpu.startup import configure_compile_cache, resolve_backend
 
-    guard_backend_init(args.init_timeout)
-    # Multi-host bring-up must precede the first jax backend use (engine
-    # construction enumerates devices). No-op unless LLMLB_COORDINATOR/
-    # LLMLB_NUM_HOSTS or LLMLB_DISTRIBUTED are set.
+    cache_dir = configure_compile_cache()
+    # Multi-host bring-up must precede the first jax backend use. No-op
+    # unless LLMLB_COORDINATOR/LLMLB_NUM_HOSTS or LLMLB_DISTRIBUTED are set.
     from llmlb_tpu.parallel.distributed import init_from_env
 
     init_from_env()
+    devices = resolve_backend(args.init_timeout)
     from llmlb_tpu.native import ensure_native_built
 
     ensure_native_built()  # build before serving; loader itself never builds
@@ -1767,6 +1763,14 @@ def main(argv: list[str] | None = None) -> None:
 
     import jax
 
+    from llmlb_tpu.ops.attention import attention_mode
+
+    log.info(
+        "serving %s on %s: %d x %s, mesh %s, attention %s, compile cache %s",
+        engine.model_id, devices[0].platform, len(devices),
+        devices[0].device_kind, dict(engine.core.mesh.shape),
+        attention_mode(), cache_dir,
+    )
     if jax.process_count() > 1 and jax.process_index() != 0:
         # Follower host of a multi-host engine: the step thread runs the
         # lockstep loop (engine/multihost.py) dispatching the same collective

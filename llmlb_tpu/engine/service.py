@@ -654,6 +654,7 @@ class Engine:
 
     def health(self) -> dict:
         from llmlb_tpu.engine.telemetry import device_telemetry
+        from llmlb_tpu.ops.attention import attention_mode, traced_routes
 
         stats = self.core.stats()
         return {
@@ -667,8 +668,13 @@ class Engine:
                 "total_tokens": stats.total_tokens,
                 "uptime_s": round(stats.uptime_s, 3),
                 "mesh": dict(self.core.mesh.shape),
+                "num_layers": self.core.cfg.num_layers,
             },
             "tpu": device_telemetry(),
+            # which attention path this process dispatches to, and the
+            # kernel each op resolved to when its program was traced
+            "attention": {"mode": attention_mode(),
+                          "traced": traced_routes()},
             "prefix_cache": self.core.prefix_cache_info(),
             "kv_cache": self.core.kv_cache_info(),
             # int8 quantization knobs + honest byte footprints

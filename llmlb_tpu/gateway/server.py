@@ -66,6 +66,11 @@ async def run_server(config: ServerConfig | None = None, *,
     from llmlb_tpu.native import ensure_native_built
 
     ensure_native_built()  # blocking make belongs here, not in a request path
+    # tiktoken fetches its vocabulary over the network on first use: resolve
+    # it once, on a thread, so no request ever waits on (or retries) it
+    from llmlb_tpu.gateway.token_accounting import load_encoder
+
+    encoder_load = asyncio.create_task(asyncio.to_thread(load_encoder))
 
     # In multi-worker mode the supervisor holds the instance lock for the
     # whole group; forked workers must not fight over it.
@@ -179,6 +184,7 @@ async def run_server(config: ServerConfig | None = None, *,
         await stop_event.wait()
     finally:
         log.info("shutting down")
+        encoder_load.cancel()
         if watch_task is not None:
             watch_task.cancel()
         if state.tray is not None:

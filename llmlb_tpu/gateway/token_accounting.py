@@ -11,24 +11,38 @@ the hot SSE line-splitter lives in native/ (used when built).
 from __future__ import annotations
 
 import json
-from functools import lru_cache
+import logging
+
+log = logging.getLogger("llmlb_tpu.gateway.token_accounting")
+
+# cl100k_base once load_encoder() has resolved it; until then, and for good
+# when tiktoken cannot supply it, estimates are chars/4.
+_encoding = None
 
 
-@lru_cache(maxsize=1)
-def _encoder():
-    import tiktoken
+def load_encoder() -> bool:
+    """Resolve tiktoken's cl100k_base once. BLOCKING — tiktoken downloads the
+    vocabulary on first use — so call it from process start-up, off the
+    event loop, never from a request path. A failure (no network, no cache)
+    is logged here once and remembered: estimate_tokens never retries it."""
+    global _encoding
+    try:
+        import tiktoken
 
-    return tiktoken.get_encoding("cl100k_base")
+        _encoding = tiktoken.get_encoding("cl100k_base")
+    except Exception as e:  # requests/OS/tiktoken errors: any means chars/4
+        log.warning("tiktoken cl100k_base unavailable (%s: %s); estimating "
+                    "tokens as chars/4", type(e).__name__, str(e)[:200])
+        return False
+    return True
 
 
 def estimate_tokens(text: str) -> int:
     if not text:
         return 0
-    try:
-        return len(_encoder().encode(text, disallowed_special=()))
-    except Exception:
-        # byte-pair estimate fallback: ~4 chars/token heuristic
-        return max(1, len(text) // 4)
+    if _encoding is not None:
+        return len(_encoding.encode(text, disallowed_special=()))
+    return max(1, len(text) // 4)  # ~4 chars/token
 
 
 def extract_usage_from_response(body: dict) -> tuple[int, int] | None:

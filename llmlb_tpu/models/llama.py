@@ -518,7 +518,9 @@ def _decode_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     slices it per layer and re-stacks the outputs into fresh buffers, and
     under the engine's k-step burst scan XLA materialized full-cache copies
     every layer — measured 40 ms/step on a v5e for a 2 GiB model whose
-    weight-streaming bound is ~3 ms (bench_runs/MEASUREMENTS.md). Unrolled,
+    weight-streaming bound is ~3 ms (bench_runs/r03_tpu_burst8.json, taken
+    BEFORE this change; the unrolled loop has no chip time on record).
+    Unrolled,
     each layer does one [B,1,K,D] scatter into the donated full cache at a
     static layer index and reads a static slice for attention, which XLA
     keeps in place. Decode programs are tiny, so L× code growth is cheap."""

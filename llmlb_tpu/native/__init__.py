@@ -1,9 +1,11 @@
 """ctypes bindings to the C++ native components (native/libllmlb_native.so).
 
-The library is built with `make -C native` (done automatically on first use
-when a toolchain is present). Every consumer has a pure-Python fallback, so
-the framework runs without the native build — but weight loading and SSE
-accounting use the native paths when available.
+The library is built with `make -C native` by `ensure_native_built()` at
+process start-up. Every consumer has a pure-Python fallback, so the framework
+runs without the native build — but weight loading and SSE accounting use the
+native paths when available. A build that FAILS means the Python paths for
+that process: a library left on disk by some earlier tree is never loaded in
+its place.
 """
 
 from __future__ import annotations
@@ -22,7 +24,7 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "libllmlb_native.so")
 
 _lib: ctypes.CDLL | None = None
 _lib_lock = threading.Lock()
-_build_attempted = False
+_build_ok: bool | None = None  # None until ensure_native_built() has run
 
 
 def _configure(lib: ctypes.CDLL) -> None:
@@ -54,64 +56,57 @@ def _configure(lib: ctypes.CDLL) -> None:
         c.c_char_p,
     ]
 
-    # Router core (scheduler hot path) — optional: older .so builds lack it,
-    # and LoadManager falls back to pure Python when these are absent.
-    if hasattr(lib, "rc_new"):
-        lib.rc_new.restype = c.c_void_p
-        lib.rc_new.argtypes = [c.c_double]
-        lib.rc_free.restype = None
-        lib.rc_free.argtypes = [c.c_void_p]
-        lib.rc_update_tps.restype = None
-        lib.rc_update_tps.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p,
-            c.c_int64, c.c_double, c.c_double,
-        ]
-        lib.rc_seed_tps.restype = None
-        lib.rc_seed_tps.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p,
-            c.c_double, c.c_int64, c.c_double,
-        ]
-        lib.rc_get_tps.restype = c.c_double
-        lib.rc_get_tps.argtypes = [c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p]
-        lib.rc_clear_endpoint.restype = None
-        lib.rc_clear_endpoint.argtypes = [c.c_void_p, c.c_char_p]
-        lib.rc_tracked_keys.restype = c.c_int64
-        lib.rc_tracked_keys.argtypes = [c.c_void_p]
-        lib.rc_begin.restype = None
-        lib.rc_begin.argtypes = [c.c_void_p, c.c_char_p]
-        lib.rc_release.restype = None
-        lib.rc_release.argtypes = [c.c_void_p, c.c_char_p]
-        lib.rc_active.restype = c.c_int64
-        lib.rc_active.argtypes = [c.c_void_p, c.c_char_p]
-        lib.rc_total_active.restype = c.c_int64
-        lib.rc_total_active.argtypes = [c.c_void_p]
-        lib.rc_total_requests.restype = c.c_int64
-        lib.rc_total_requests.argtypes = [c.c_void_p]
-        lib.rc_select.restype = c.c_int64
-        lib.rc_select.argtypes = [
-            c.c_void_p, c.c_char_p, c.POINTER(c.c_char_p),
-            c.POINTER(c.c_double), c.c_int64, c.c_int64, c.c_char_p, c.c_int,
-        ]
-        lib.rc_snapshot.restype = c.c_int64
-        lib.rc_snapshot.argtypes = [c.c_void_p, c.c_char_p, c.c_int64]
-    if hasattr(lib, "rc_tps_info"):
-        lib.rc_tps_info.restype = c.c_int32
-        lib.rc_tps_info.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p,
-            c.POINTER(c.c_double), c.POINTER(c.c_int64),
-            c.POINTER(c.c_double),
-        ]
-    # Consistent-hash owner + constant-time compare (proxy hot path) —
-    # optional like the router core: stale .so builds lack them and the
-    # Python twins stay behaviorally identical.
-    if hasattr(lib, "hrw_select"):
-        lib.hrw_select.restype = c.c_int64
-        lib.hrw_select.argtypes = [
-            c.c_char_p, c.POINTER(c.c_char_p), c.c_int64,
-        ]
-    if hasattr(lib, "ct_equal"):
-        lib.ct_equal.restype = c.c_int32
-        lib.ct_equal.argtypes = [c.c_char_p, c.c_int64, c.c_char_p, c.c_int64]
+    # Router core (scheduler hot path)
+    lib.rc_new.restype = c.c_void_p
+    lib.rc_new.argtypes = [c.c_double]
+    lib.rc_free.restype = None
+    lib.rc_free.argtypes = [c.c_void_p]
+    lib.rc_update_tps.restype = None
+    lib.rc_update_tps.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p,
+        c.c_int64, c.c_double, c.c_double,
+    ]
+    lib.rc_seed_tps.restype = None
+    lib.rc_seed_tps.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p,
+        c.c_double, c.c_int64, c.c_double,
+    ]
+    lib.rc_get_tps.restype = c.c_double
+    lib.rc_get_tps.argtypes = [c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p]
+    lib.rc_clear_endpoint.restype = None
+    lib.rc_clear_endpoint.argtypes = [c.c_void_p, c.c_char_p]
+    lib.rc_tracked_keys.restype = c.c_int64
+    lib.rc_tracked_keys.argtypes = [c.c_void_p]
+    lib.rc_begin.restype = None
+    lib.rc_begin.argtypes = [c.c_void_p, c.c_char_p]
+    lib.rc_release.restype = None
+    lib.rc_release.argtypes = [c.c_void_p, c.c_char_p]
+    lib.rc_active.restype = c.c_int64
+    lib.rc_active.argtypes = [c.c_void_p, c.c_char_p]
+    lib.rc_total_active.restype = c.c_int64
+    lib.rc_total_active.argtypes = [c.c_void_p]
+    lib.rc_total_requests.restype = c.c_int64
+    lib.rc_total_requests.argtypes = [c.c_void_p]
+    lib.rc_select.restype = c.c_int64
+    lib.rc_select.argtypes = [
+        c.c_void_p, c.c_char_p, c.POINTER(c.c_char_p),
+        c.POINTER(c.c_double), c.c_int64, c.c_int64, c.c_char_p, c.c_int,
+    ]
+    lib.rc_snapshot.restype = c.c_int64
+    lib.rc_snapshot.argtypes = [c.c_void_p, c.c_char_p, c.c_int64]
+    lib.rc_tps_info.restype = c.c_int32
+    lib.rc_tps_info.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_char_p, c.c_char_p,
+        c.POINTER(c.c_double), c.POINTER(c.c_int64),
+        c.POINTER(c.c_double),
+    ]
+    # Consistent-hash owner + constant-time compare (proxy hot path)
+    lib.hrw_select.restype = c.c_int64
+    lib.hrw_select.argtypes = [
+        c.c_char_p, c.POINTER(c.c_char_p), c.c_int64,
+    ]
+    lib.ct_equal.restype = c.c_int32
+    lib.ct_equal.argtypes = [c.c_char_p, c.c_int64, c.c_char_p, c.c_int64]
 
     lib.sse_new.restype = c.c_void_p
     lib.sse_feed.restype = None
@@ -127,25 +122,27 @@ def _configure(lib: ctypes.CDLL) -> None:
 
 
 def ensure_native_built() -> bool:
-    """Build the library if missing. BLOCKING (runs make): call this from
-    process startup (server mains, test setup), never from a request path."""
-    global _build_attempted
+    """Build the library from this tree's sources. BLOCKING (runs make):
+    call this from process startup (server mains, test setup), never from a
+    request path. False when the build failed — load_native() then returns
+    None for the life of the process, whatever library is on disk."""
+    global _build_ok
     with _lib_lock:
-        if _build_attempted:
-            return os.path.exists(_LIB_PATH)
-        _build_attempted = True
-        try:
-            # Always invoke make: its dependency tracking rebuilds the .so when
-            # the C++ sources changed (a stale library would otherwise be used
-            # silently) and is a near-no-op when fresh.
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
-                check=True, capture_output=True, timeout=120,
-            )
-        except Exception as e:
-            log.info("native build unavailable (%s); using Python fallbacks", e)
-            return os.path.exists(_LIB_PATH)
-    return os.path.exists(_LIB_PATH)
+        if _build_ok is None:
+            try:
+                # Always invoke make: its dependency tracking rebuilds the
+                # .so when the C++ sources changed and is a near-no-op when
+                # fresh.
+                subprocess.run(
+                    ["make", "-C", _NATIVE_DIR],
+                    check=True, capture_output=True, timeout=120,
+                )
+                _build_ok = os.path.exists(_LIB_PATH)
+            except (OSError, subprocess.SubprocessError) as e:
+                _build_ok = False
+                log.warning("native build failed (%s); using the Python "
+                            "paths", e)
+        return _build_ok
 
 
 def load_native() -> ctypes.CDLL | None:
@@ -155,13 +152,15 @@ def load_native() -> ctypes.CDLL | None:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
+        if _build_ok is False or not os.path.exists(_LIB_PATH):
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
             _configure(lib)
             _lib = lib
-        except OSError as e:
+        except (OSError, AttributeError) as e:
+            # AttributeError: a symbol this tree declares is missing, i.e.
+            # the file was built from other sources
             log.warning("failed to load native library: %s", e)
             return None
         return _lib
@@ -292,8 +291,7 @@ class NativeSseScanner:
 
 
 def native_hrw_available() -> bool:
-    lib = load_native()
-    return lib is not None and hasattr(lib, "hrw_select")
+    return load_native() is not None
 
 
 def native_hrw_select(key: str, endpoint_ids: list[str]) -> int:
@@ -302,7 +300,7 @@ def native_hrw_select(key: str, endpoint_ids: list[str]) -> int:
     balancer.hrw_owner — tested side by side."""
     lib = load_native()
     n = len(endpoint_ids)
-    if lib is None or not hasattr(lib, "hrw_select") or n == 0:
+    if lib is None or n == 0:
         return -1
     arr = (ctypes.c_char_p * n)(*[e.encode() for e in endpoint_ids])
     return lib.hrw_select(key.encode(), arr, n)
@@ -310,10 +308,9 @@ def native_hrw_select(key: str, endpoint_ids: list[str]) -> int:
 
 def native_ct_equal(a: bytes, b: bytes) -> bool | None:
     """Constant-time byte equality in compiled code; None when the native
-    library (or symbol) is unavailable — callers fall back to
-    hmac.compare_digest."""
+    library is unavailable — callers fall back to hmac.compare_digest."""
     lib = load_native()
-    if lib is None or not hasattr(lib, "ct_equal"):
+    if lib is None:
         return None
     return bool(lib.ct_equal(a, len(a), b, len(b)))
 
@@ -324,12 +321,12 @@ def native_ct_equal(a: bytes, b: bytes) -> bool | None:
 class NativeRouterCore:
     """C++ scheduler state: TPS-EMA map + active counts + round-robin
     selection (native/router_core.cpp). Raises RuntimeError when the library
-    (or this symbol, in a stale build) is unavailable — LoadManager keeps the
-    pure-Python implementation as the fallback."""
+    is unavailable — LoadManager keeps the pure-Python implementation as the
+    fallback."""
 
     def __init__(self, alpha: float):
         lib = load_native()
-        if lib is None or not hasattr(lib, "rc_new"):
+        if lib is None:
             raise RuntimeError("native router core unavailable")
         self._lib = lib
         self._handle = lib.rc_new(alpha)
@@ -358,8 +355,6 @@ class NativeRouterCore:
                  kind: str) -> tuple[float, int, float] | None:
         """(ema, samples, last_update) or None when unmeasured — feeds the
         cross-worker TPS gossip (publish + last-writer-wins compare)."""
-        if not hasattr(self._lib, "rc_tps_info"):
-            return None  # stale .so: gossip publish just skips this key
         ema = ctypes.c_double()
         samples = ctypes.c_int64()
         last = ctypes.c_double()

@@ -41,6 +41,23 @@ def _pallas_enabled() -> bool:
     return jax.default_backend() == "tpu" and jax.device_count() == 1
 
 
+# What each dispatcher below resolved to the last time it was traced, by op
+# ("paged_decode" -> "pallas:paged_flash_decode" or "xla"). The engine's
+# /api/health serves it, so "which kernel ran" is read off the dispatch
+# itself instead of a rule restated elsewhere.
+_traced: dict[str, str] = {}
+
+
+def attention_mode() -> str:
+    """"pallas" or "xla": the path the dispatchers take in this process."""
+    return "pallas" if _pallas_enabled() else "xla"
+
+
+def traced_routes() -> dict[str, str]:
+    """op -> kernel for every attention dispatcher traced so far."""
+    return dict(_traced)
+
+
 def _split_gqa(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
     """[B, T, H, D] -> [B, T, K, G, D] where H = K * G."""
     b, t, h, d = q.shape
@@ -57,7 +74,9 @@ def gqa_attention_prefill(
     if _pallas_enabled():
         from llmlb_tpu.ops.pallas_attention import flash_prefill
 
+        _traced["prefill"] = "pallas:flash_prefill"
         return flash_prefill(q, k, v, prompt_lens)
+    _traced["prefill"] = "xla"
     b, t, h, d = q.shape
     k_heads = k.shape[2]
     qg = _split_gqa(q, k_heads)
@@ -102,9 +121,11 @@ def gqa_attention_extend(
     if chunk_lens is not None and _pallas_enabled():
         from llmlb_tpu.ops.pallas_attention import flash_extend
 
+        _traced["extend"] = "pallas:flash_extend"
         return flash_extend(
             q, k_cache, v_cache, q_positions[:, 0], chunk_lens
         )
+    _traced["extend"] = "xla"
     b, t, h, d = q.shape
     k_heads = k_cache.shape[2]
     qg = _split_gqa(q, k_heads)  # [B, T, K, G, D]
@@ -175,15 +196,18 @@ def paged_attention_decode(
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_decode_quant
 
+            _traced["paged_decode"] = "pallas:paged_flash_decode_quant"
             return paged_flash_decode_quant(
                 q[:, 0], k_pages["q"], k_pages["s"], v_pages["q"],
                 v_pages["s"], block_tables, kv_lens, pages=pages,
             )[:, None]
         from llmlb_tpu.ops.pallas_attention import paged_flash_decode
 
+        _traced["paged_decode"] = "pallas:paged_flash_decode"
         return paged_flash_decode(
             q[:, 0], k_pages, v_pages, block_tables, kv_lens, pages=pages
         )[:, None]
+    _traced["paged_decode"] = "xla"
     tables = block_tables[:, :pages] if pages < ppn else block_tables
     k_cache = gather_kv_pages(k_pages, tables, dtype=q.dtype)
     v_cache = gather_kv_pages(v_pages, tables, dtype=q.dtype)
@@ -206,15 +230,18 @@ def paged_attention_extend(
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_extend_quant
 
+            _traced["paged_extend"] = "pallas:paged_flash_extend_quant"
             return paged_flash_extend_quant(
                 q, k_pages["q"], k_pages["s"], v_pages["q"], v_pages["s"],
                 block_tables, q_positions[:, 0], chunk_lens,
             )
         from llmlb_tpu.ops.pallas_attention import paged_flash_extend
 
+        _traced["paged_extend"] = "pallas:paged_flash_extend"
         return paged_flash_extend(
             q, k_pages, v_pages, block_tables, q_positions[:, 0], chunk_lens
         )
+    _traced["paged_extend"] = "xla"
     k_cache = gather_kv_pages(k_pages, block_tables, dtype=q.dtype)
     v_cache = gather_kv_pages(v_pages, block_tables, dtype=q.dtype)
     # chunk_lens=None pins gqa_attention_extend to the XLA einsum path (the
@@ -244,6 +271,7 @@ def gqa_attention_decode(
         if _pallas_enabled():
             from llmlb_tpu.ops.pallas_attention import flash_decode
 
+            _traced["decode"] = "pallas:flash_decode"
             # the kernel bounds its grid instead of slicing (no copy)
             return flash_decode(
                 q[:, 0], k_cache, v_cache, kv_lens, window=window
@@ -254,7 +282,9 @@ def gqa_attention_decode(
     elif _pallas_enabled():
         from llmlb_tpu.ops.pallas_attention import flash_decode
 
+        _traced["decode"] = "pallas:flash_decode"
         return flash_decode(q[:, 0], k_cache, v_cache, kv_lens)[:, None]
+    _traced["decode"] = "xla"
     b, t, h, d = q.shape
     k_heads = k_cache.shape[2]
     qg = _split_gqa(q, k_heads)  # [B, 1, K, G, D]

@@ -17,10 +17,11 @@ shape (PAPERS.md: S-LoRA lineage), built here in two flavors:
 - `lora_delta_pallas`: a Pallas TPU kernel. The adapter indices arrive via
   scalar prefetch (PrefetchScalarGridSpec), and the per-row A/B blocks are
   DMA'd straight from their pool rows by the block index_map — the gathered
-  [B, IN, R] copy the XLA path materializes never exists. Grid is (B,);
-  blocks take the full trailing dims, satisfying the Mosaic tiling rule the
-  attention kernels rely on (block dims equal to array dims are always
-  legal), so any (IN, R, OUT) works — ranks are far below one lane tile.
+  [B, IN, R] copy the XLA path materializes never exists. Grid is
+  (B, T blocks); blocks take the full trailing dims, satisfying the Mosaic
+  tiling rule the attention kernels rely on (block dims equal to array dims
+  are always legal), so any (IN, R, OUT) works — ranks are far below one
+  lane tile.
 
 Numerics: fp32 accumulation through both thin matmuls
 (`preferred_element_type`), delta returned in fp32; the caller adds it to the
@@ -69,8 +70,14 @@ def lora_delta_xla(
                       preferred_element_type=jnp.float32)
 
 
+# Rows of x per grid step. A whole [512, 5632] f32 output block (a prefill
+# chunk through TinyLlama's gate/up projection) is 11 MiB, 22 MiB double
+# buffered — past v5e's 16 MiB scoped VMEM; 128 rows keep it under 6 MiB.
+_BLOCK_T = 128
+
+
 def _bgmv_kernel(idx_ref, x_ref, a_ref, b_ref, o_ref):
-    """One batch row: shrink (x @ A) then expand (u @ B), fp32 accumulate.
+    """One block of one batch row: shrink (x @ A) then expand (u @ B), fp32.
     A/B blocks were already DMA'd from pool row idx_ref[bi] by the
     index_maps — the kernel body never touches the index itself."""
     u = jax.lax.dot_general(
@@ -101,18 +108,20 @@ def lora_delta_pallas(
     _, _, r = a.shape
     out_dim = b.shape[2]
 
+    blk_t = min(t, _BLOCK_T)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bsz,),
+        grid=(bsz, pl.cdiv(t, blk_t)),
         in_specs=[
-            pl.BlockSpec((1, t, in_dim), lambda bi, idx: (bi, 0, 0),
+            pl.BlockSpec((1, blk_t, in_dim), lambda bi, ti, idx: (bi, ti, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, in_dim, r), lambda bi, idx: (idx[bi], 0, 0),
+            pl.BlockSpec((1, in_dim, r), lambda bi, ti, idx: (idx[bi], 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, r, out_dim), lambda bi, idx: (idx[bi], 0, 0),
+            pl.BlockSpec((1, r, out_dim), lambda bi, ti, idx: (idx[bi], 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, t, out_dim), lambda bi, idx: (bi, 0, 0),
+        out_specs=pl.BlockSpec((1, blk_t, out_dim),
+                               lambda bi, ti, idx: (bi, ti, 0),
                                memory_space=pltpu.VMEM),
     )
     return pl.pallas_call(

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 _NEG_INF = -1e30  # finite: fully-masked rows must still produce softmax-able sums
 
@@ -125,11 +124,11 @@ def ring_prefill_attention(
         kv_head_axis = head_axis
     q_spec = P(batch_axis, seq_axis, head_axis, None)
     kv_spec = P(batch_axis, seq_axis, kv_head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=seq_axis, axis_size=sp),
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P(batch_axis)),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, prompt_lens)

@@ -9,8 +9,8 @@ same thing for this gateway: an in-process mock OpenAI upstream, the real app
     python scripts/bench_gateway.py [--seconds 10] [--concurrency 50]
 
 Prints one JSON line. Python/aiohttp will not reach a Rust router's ceiling;
-the number is tracked honestly in bench_runs/MEASUREMENTS.md and bounds how
-much gateway CPU one TPU engine's request rate can consume.
+the number bounds how much gateway CPU one TPU engine's request rate can
+consume.
 
 The gateway's own /metrics is scraped before and after the timed window and
 the TTFT/E2E/queue-wait percentile deltas are printed under "prometheus", so
@@ -87,15 +87,10 @@ import time
 
 
 def _pin_platform() -> None:
-    """On CPU-only hosts jax's TPU backend init hangs ~30 s per retry inside
-    make_c_api_client (BENCH_r05 tail); decide from host evidence BEFORE the
-    first device touch. Shares bench.py's detection so both harnesses agree."""
-    sys.path.insert(0, ".")
-    from bench import force_cpu_platform, tpu_possibly_present
-
-    if not tpu_possibly_present():
-        force_cpu_platform("no TPU evidence on this host; "
-                           "set LLMLB_BENCH_FORCE_TPU_PROBE=1 to override")
+    """Every engine this harness builds is `debug-tiny` on the CPU, and its
+    engine children are spawned with JAX_PLATFORMS=cpu: pin this process
+    the same way before its first device touch."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 _SAMPLE_RE = re.compile(
     r"^([A-Za-z_:][A-Za-z0-9_:]*)\{(.*)\}\s+(-?[0-9.eE+]+)$"

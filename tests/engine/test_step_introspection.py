@@ -326,65 +326,6 @@ async def test_profile_token_gates_every_route(monkeypatch):
         engine.shutdown()
 
 
-# ------------------------------------------------------------------ tpu probe
-
-
-def test_staged_probe_timeout_preserves_child_evidence():
-    """A hanging probe child is killed at the timeout and its stderr tail
-    survives as evidence — the diagnosis plumbing for init hangs."""
-    from llmlb_tpu.engine.tpu_probe import staged_probe
-
-    hang = ("import sys, time\n"
-            "print('[probe] stage1: hanging here', file=sys.stderr,"
-            " flush=True)\n"
-            "time.sleep(60)\n")
-    ok, diag, evidence = staged_probe((1,), code=hang, log_fn=lambda m: None)
-    assert ok is False
-    assert "timed out" in diag
-    rec = evidence["attempts"][0]
-    assert "timeout" in rec["outcome"]
-    assert any("hanging here" in ln for ln in rec["child_stderr_tail"])
-
-
-def test_staged_probe_reports_non_tpu_backend():
-    from llmlb_tpu.engine.tpu_probe import staged_probe
-
-    fake = "print('cpu 1 cpu')\n"
-    ok, diag, evidence = staged_probe((30,), code=fake, log_fn=lambda m: None)
-    assert ok is False
-    assert "not tpu" in diag
-    assert evidence["attempts"][0]["outcome"].startswith("ok:")
-
-
-def test_guard_backend_init_noop_without_tpu(monkeypatch):
-    from llmlb_tpu.engine import tpu_probe
-
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    # would raise SystemExit if it probed and failed; must return instantly
-    tpu_probe.guard_backend_init(1.0)
-    # disabled guard never probes even when a TPU is "expected"
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    tpu_probe.guard_backend_init(0)
-
-
-def test_guard_backend_init_fails_fast_on_hang(monkeypatch, capsys):
-    from llmlb_tpu.engine import tpu_probe
-
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    monkeypatch.setattr(
-        tpu_probe, "PROBE_CODE",
-        "import sys, time\n"
-        "print('libtpu: claiming device', file=sys.stderr, flush=True)\n"
-        "time.sleep(60)\n",
-    )
-    with pytest.raises(SystemExit) as exc:
-        tpu_probe.guard_backend_init(1.0)
-    assert "did not complete" in str(exc.value)
-    err = capsys.readouterr().err
-    assert "libtpu: claiming device" in err  # the captured child log tail
-    assert "LLMLB_INIT_TIMEOUT=0" in err
-
-
 def test_profile_wait_idle_wakes_on_stop_event_not_poll(tmp_path):
     """The /debug/profile wait path parks on the manager's idle event and
     wakes when the capture stops — the last 50 ms poll loop in a request
