@@ -36,6 +36,8 @@ import time
 
 import numpy as np
 
+from llmlb_tpu.engine import compilelog
+
 log = logging.getLogger("llmlb_tpu.disagg")
 
 
@@ -111,40 +113,57 @@ class SplitRuntime:
     def _prefill_loop(self) -> None:
         core = self.core
         core._tls.tag = "prefill"
+        compilelog.set_thread_class("loop")
+        # the loop's clock (engine/stepstats.py): waiting for the decode
+        # loop and for the lock is `control`, adoption and admission `admit`
+        clock = core._clock()
         while core._running:
             did = False
             try:
+                clock.switch("control")
                 self._yield_to_decode()
                 with self.lock:
+                    clock.switch("admit")
                     did |= self.pump_handoffs()
                     did |= core._try_insert()
+                    clock.switch("control")
                 self._yield_to_decode()
                 with self.lock:
+                    clock.switch("other")
                     did |= core._advance_prefill()
             except Exception:  # pragma: no cover - fail loud, keep serving
+                clock.abandon()
                 self._fail_reset()
             if not did:
+                clock.switch("idle")
                 time.sleep(0.001)
 
     def _decode_loop(self) -> None:
         core = self.core
         core._tls.tag = "decode"
+        compilelog.set_thread_class("loop")
+        clock = core._clock()
         while core._running:
             did = False
             try:
+                clock.switch("control")
                 self._decode_wants.set()
                 try:
                     with self.lock:
                         self._decode_wants.clear()
+                        clock.switch("other")
                         did |= core._decode_active()
                         # a finished/parked slot frees capacity: adopt the
                         # oldest staged request before the next decode step
+                        clock.switch("admit")
                         did |= self.pump_handoffs()
                 finally:
                     self._decode_wants.clear()
             except Exception:  # pragma: no cover - fail loud, keep serving
+                clock.abandon()
                 self._fail_reset()
             if not did:
+                clock.switch("idle")
                 time.sleep(0.001)
 
     # -------------------------------------------------------------- admission

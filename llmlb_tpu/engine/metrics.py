@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import threading
 
+from llmlb_tpu.engine import compilelog
+
 # Bucket edges in seconds, chosen around serving targets: TTFT p50 goals are
 # tens of ms (one-shot prefill) to seconds (chunked 4k prompts); ITL goals
 # are single-digit ms on TPU.
@@ -223,6 +225,13 @@ class EngineMetrics:
             p: Histogram(PHASE_BUCKETS) for p in PHASES
         }
         self.slow_steps_total = 0
+        # The step loops' clocks by loop tag (engine/stepstats.py LoopClock;
+        # the scheduler registers each as its loop makes it): where every
+        # second of a loop thread went, served as loop_seconds_total.
+        self.loop_clocks: dict = {}
+        # Programs built (engine/compilelog.py): the ledger is the
+        # process's, this engine serves what was built since it was made.
+        self._compile_base = compilelog.counters()
 
     # ------------------------------------------------------------ recorders
 
@@ -442,11 +451,29 @@ class EngineMetrics:
 
     # ----------------------------------------------------------- exposition
 
+    def loop_seconds(self) -> dict[str, dict[str, float]]:
+        """Cumulative seconds per loop tag and bucket (step, admit,
+        control, record, idle, other): they sum to the loop thread's life."""
+        return {tag: {b: round(v, 6) for b, v in clock.snapshot().items()}
+                for tag, clock in list(self.loop_clocks.items())}
+
+    def compile_info(self, builds: int = 0) -> dict:
+        """Programs built since this engine was made: totals by stage and
+        by thread class, and the newest `builds` builds by name."""
+        out = compilelog.summary(self._compile_base)
+        if builds:
+            out["builds"] = compilelog.recent(builds, self._compile_base)
+        return out
+
     def summary(self) -> dict:
         """Compact JSON figures for /api/health consumers (the gateway's
         scheduler and dashboard)."""
+        loop_seconds = self.loop_seconds()
+        compiled = self.compile_info()
         with self._lock:
             return {
+                "loop_seconds_total": loop_seconds,
+                "compile": compiled,
                 "requests_total": self.requests_total,
                 "tokens_total": self.tokens_total,
                 "errors_total": self.errors_total,
@@ -844,4 +871,22 @@ class EngineMetrics:
             lines.append(f"# TYPE {name} histogram")
             for phase, hist in self.step_phase.items():
                 _render_histogram(lines, name, hist, label=f'phase="{phase}"')
-            return "\n".join(lines) + "\n"
+        # where the step loops' time went, and the programs built: read
+        # outside the lock (each has its own)
+        lines.append("# TYPE llmlb_engine_loop_seconds_total counter")
+        for tag, buckets in self.loop_seconds().items():
+            for bucket, seconds in buckets.items():
+                lines.append(
+                    f'llmlb_engine_loop_seconds_total{{loop="{tag}",'
+                    f'bucket="{bucket}"}} {seconds}')
+        compiled = self.compile_info()
+        lines.append("# TYPE llmlb_engine_programs_built_total counter")
+        for cls, block in compiled["by_thread"].items():
+            lines.append(f'llmlb_engine_programs_built_total{{thread="{cls}"}} '
+                         f'{block["programs_total"]}')
+        lines.append("# TYPE llmlb_engine_compile_seconds_total counter")
+        for stage, seconds in compiled["seconds_total"].items():
+            lines.append(
+                f'llmlb_engine_compile_seconds_total{{stage="{stage}"}} '
+                f'{seconds}')
+        return "\n".join(lines) + "\n"

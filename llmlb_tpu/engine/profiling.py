@@ -52,9 +52,11 @@ class ProfileManager:
         self._timer: threading.Timer | None = None
         self._captures: list[dict] = []  # completed, newest last
         self._root_override = trace_root
-        # Set while no capture runs, cleared for the duration of one:
-        # wait_idle() parks on it instead of polling status() — set/clear
-        # only ever happen with _lock held, so waiters can't miss an edge.
+        # Set while no capture runs or is being written, cleared from a
+        # capture's start until its files are on disk and it is in the
+        # ledger: wait_idle() parks on it instead of polling status() —
+        # set/clear only ever happen with _lock held, so waiters can't miss
+        # an edge.
         self._idle = threading.Event()
         self._idle.set()
 
@@ -75,6 +77,11 @@ class ProfileManager:
         with self._lock:
             if self._active is not None:
                 raise ProfileError(409, "a profile capture is already running")
+            if not self._idle.is_set():
+                # stopped, but stop_trace is still writing the files: the
+                # tracer is global and not ours again until it returns
+                raise ProfileError(409, "the last profile capture is still "
+                                        "being written")
             # dir creation inside the lock, AFTER the busy check: a polling
             # client hammering start while a capture runs must not litter
             # the trace root with empty dirs the eviction never sees
@@ -138,7 +145,6 @@ class ProfileManager:
                 self._timer.cancel()
                 self._timer = None
             self._active = None
-            self._idle.set()
         try:
             jax.profiler.stop_trace()
         except Exception as e:
@@ -155,6 +161,9 @@ class ProfileManager:
             evicted = []
             while len(self._captures) > MAX_KEPT_CAPTURES:
                 evicted.append(self._captures.pop(0))
+            # only now is the capture over for a waiter: the trace files
+            # exist and status()/artifact() know the capture
+            self._idle.set()
         for stale in evicted:
             shutil.rmtree(stale["trace_dir"], ignore_errors=True)
             zip_path = stale["trace_dir"].rstrip("/") + ".zip"

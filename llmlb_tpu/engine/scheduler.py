@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from llmlb_tpu.engine import compilelog
 from llmlb_tpu.engine.kv_offload import KVOffloadTier
 from llmlb_tpu.engine.kv_transfer import (
     KV_WIRE_VERSION, KVPages, KVWireHeader, kv_compat_reason,
@@ -57,7 +58,7 @@ from llmlb_tpu.engine.metrics import EngineMetrics
 from llmlb_tpu.engine.paging import PagePool
 from llmlb_tpu.engine.prefix_cache import PrefixCache, PrefixEntry
 from llmlb_tpu.engine.flightrec import FlightRecorder, gateway_rid
-from llmlb_tpu.engine.stepstats import StepRecorder
+from llmlb_tpu.engine.stepstats import LoopClock, StepRecorder, StepSpan
 from llmlb_tpu.models import family_for
 from llmlb_tpu.models.llama import LlamaConfig, Params
 from llmlb_tpu.ops.grammar import (
@@ -964,10 +965,11 @@ class EngineCore:
         self.metrics = EngineMetrics()
         if self.lora is not None:
             self.lora.metrics = self.metrics
-        # Step introspection (engine/stepstats.py): per-step phase records,
+        # Step introspection (engine/stepstats.py): per-step span records,
         # slow-step anomalies, and the sliding decode window live MFU math
-        # reads. Always on — the recorder is a few clock reads per step
-        # (< 1% of step time, guarded by test_step_introspection).
+        # reads. Always on — the recorder is one clock read per span
+        # boundary (< 1% of step time, guarded by test_step_introspection).
+        # Each loop thread keeps its own LoopClock (self._clock()).
         self.step_stats = StepRecorder()
         # Per-request flight recorder (engine/flightrec.py): one event per
         # lifecycle edge, keyed by the gateway's X-Request-Id, served at
@@ -1019,9 +1021,6 @@ class EngineCore:
         if self.kv_offload is not None:
             log.info("KV offload tier: %.1f MiB host-RAM budget",
                      self.kv_offload.budget_bytes / 2**20)
-        # plan/insert time accrued since the last dispatched step; the next
-        # step record absorbs it (admission happens between dispatches)
-        self._pending_plan_s = 0.0
         # static per-token cost base for perf_info(): parameter count of the
         # served model (device arrays are cheap to .size). Scale leaves are
         # bookkeeping, not parameters — excluded from the FLOP count, as are
@@ -1081,6 +1080,16 @@ class EngineCore:
     def _loop_tag(self) -> str:
         return getattr(self._tls, "tag", "main")
 
+    def _clock(self) -> LoopClock:
+        """This thread's loop clock (engine/stepstats.py), made on first
+        use: the step loops, and a test that drives the steps by hand."""
+        clock = getattr(self._tls, "clock", None)
+        if clock is None:
+            clock = self._tls.clock = LoopClock(self.step_stats,
+                                                self._loop_tag())
+            self.metrics.loop_clocks[clock.tag] = clock
+        return clock
+
     def _note_prefill_dispatch(self) -> None:
         """Ledger every prefill dispatch by the loop that ran it. Split
         mode's acceptance invariant — the decode loop NEVER runs prefill —
@@ -1106,6 +1115,8 @@ class EngineCore:
             ).start()
 
     def _prewarm_windows(self) -> None:
+        compilelog.set_thread_class("prewarm")
+
         def sharded(x):
             # Shardings are part of jax's executable cache key: a prewarm
             # lowered without them compiles a different (unsharded) variant
@@ -1497,24 +1508,35 @@ class EngineCore:
                 self._park_slot(i, reason="migrate")
 
     def _loop(self) -> None:
+        compilelog.set_thread_class("loop")
+        # Every stretch of this thread's time goes to one bucket of its
+        # clock (engine/stepstats.py): the steps open and close themselves,
+        # the rest is switched here — three clock reads on an idle iteration.
+        clock = self._clock()
         while self._running:
             did_work = False
             try:
                 if self.coordinator is not None:
+                    clock.switch("control")
                     self._lockstep_tick()
                     if not self._running:
                         break
                 if self._drain_park_requested:
+                    clock.switch("control")
                     self._drain_park_requested = False
                     self._drain_park_all()
                 if self._park_rids:
+                    clock.switch("control")
                     rids = self._park_rids
                     self._park_rids = set()
                     self._park_requested(rids)
                 if self._drain_flush_requested:
+                    clock.switch("control")
                     self._drain_flush_requested = False
                     self._drain_flush_all()
+                clock.switch("admit")
                 did_work |= self._try_insert()
+                clock.switch("other")
                 # At most ONE prefill chunk per iteration: decode steps run
                 # between chunks, so active slots keep emitting tokens during
                 # a long prompt's prefill (prefill/decode interleaving).
@@ -1522,11 +1544,13 @@ class EngineCore:
                 did_work |= self._decode_active()
             except Exception:  # pragma: no cover - defensive: fail loud, keep serving
                 log.exception("engine step failed; resetting engine state")
+                clock.abandon()
                 self._fail_all("engine step error")
                 # prefill/decode donate the caches: after a failed dispatch the
                 # buffers may already be consumed — rebuild before serving again.
                 self._reset_caches()
             if not did_work:
+                clock.switch("idle")
                 time.sleep(0.001)
 
     def _reset_caches(self) -> None:
@@ -1557,23 +1581,26 @@ class EngineCore:
             self.prefix_cache.clear()
         self._prefix_pinned_pages = 0
 
-    def _record_step(self, kind: str, phases: dict[str, float], *,
+    def _record_step(self, kind: str, step: StepSpan, *,
                      active_slots: int = 0, tokens: int = 0,
                      slots: "list[int] | None" = None,
                      dispatches: int = 0, fused: bool = False) -> None:
-        """Finalize one step record: absorb plan/insert time accrued since
-        the previous dispatch, feed the ring buffer + anomaly detector, and
-        mirror the phase durations into the Prometheus histograms. `slots`
+        """Close one step (its last stamp) and finalize its record: the
+        admission time since the previous record becomes its plan phase,
+        the record feeds the ring buffer + anomaly detector, and the phase
+        durations are mirrored into the Prometheus histograms. `slots`
         names the slot ids this dispatch touched: their requests' gateway
         ids land on the StepRecord (so /api/steps?slow=1 names the victims)
         and a flagged step writes a slow_step event into each victim's
-        flight record. `dispatches` is the honest device-program count this
-        step issued (decode/verify kinds feed the per-loop dispatch ledger
-        and the fused-decode "exactly one" invariant); `fused` marks steps
-        served by the single-program path."""
-        if self._pending_plan_s > 0.0:
-            phases["plan"] = phases.get("plan", 0.0) + self._pending_plan_s
-            self._pending_plan_s = 0.0
+        flight record, with the span or bucket that held the time.
+        `dispatches` is the honest device-program count this step issued
+        (decode/verify kinds feed the per-loop dispatch ledger and the
+        fused-decode "exactly one" invariant); `fused` marks steps served
+        by the single-program path. What this costs after the step's last
+        stamp is the next record's since_prev.record_s."""
+        clock = self._clock()
+        clock.close(step, kind)
+        phases = step.phases()
         request_ids: dict[str, str] | None = None
         if slots:
             request_ids = {}
@@ -1588,14 +1615,15 @@ class EngineCore:
                                        active_slots=active_slots,
                                        tokens=tokens,
                                        request_ids=request_ids,
-                                       dispatches=dispatches)
+                                       dispatches=dispatches, span=step)
         self.metrics.record_step_phases(phases, slow=slow)
         if slow and request_ids and self.flightrec.enabled:
             total = round(sum(phases.values()), 6)
-            seq = self.step_stats.seq
             for rid in request_ids.values():
                 self.flightrec.emit(rid, "slow_step", kind=kind,
-                                    total_s=total, step_seq=seq)
+                                    total_s=total, step_seq=step.seq,
+                                    slow_in=step.slow_in)
+        clock.resume(step)
 
     # Same-bucket pending prompts prefill TOGETHER in one dispatch (padded to
     # a power-of-two group so the jit cache stays at log2 sizes). Bounded so
@@ -2361,7 +2389,6 @@ class EngineCore:
             # graceful drain: nothing new is admitted or re-activated —
             # parked work stays queued for the gateway's resume to collect
             return False
-        plan_start = time.perf_counter()
         self._prefill_spent_iter = 0  # first call of every loop iteration
         self._drain_pending()
         queued = (sum(len(q) for q in self._class_queues.values())
@@ -2570,16 +2597,11 @@ class EngineCore:
             batch_tokens += n
 
         if not batch:
-            if handled:
-                # admission work with no prefill dispatch of its own (cached
-                # inserts, long-prompt claims): the next step record absorbs
-                # it as its plan phase
-                self._pending_plan_s += time.perf_counter() - plan_start
+            # admission work with no prefill dispatch of its own (cached
+            # inserts, long-prompt claims): the loop's clock holds it under
+            # `admit`, and the next step record takes it as its plan phase
             return handled
 
-        # plan ends where dispatch begins; the prefill records below absorb
-        # the accrued time via _record_step
-        self._pending_plan_s += time.perf_counter() - plan_start
         self._prefill_spent_iter = batch_tokens
         # one prefill dispatch per length bucket present in the batch
         by_bucket: dict[int, list[tuple[int, Request, int]]] = {}
@@ -3109,7 +3131,7 @@ class EngineCore:
 
     def _verify_active(self, active: list[int], drafts: dict[int, list[int]],
                        lookahead: dict[int, list[int]],
-                       draft_s: float, fused: bool = False) -> bool:
+                       step: StepSpan, fused: bool = False) -> bool:
         """One speculative verify step: dispatch every active slot's last
         token + drafts as a K+1-token chunk through the extend path, sample
         every position, accept the longest prefix of drafts matching the
@@ -3117,14 +3139,15 @@ class EngineCore:
         rejected-suffix state (committed length + over-allocated pages).
         With `fused` the whole step is ONE device program (mask columns,
         last-token splice, accept counts, and the lens/last advance all
-        in-program); the host emit loop is unchanged either way."""
+        in-program); the host emit loop is unchanged either way. `step` is
+        the open step _decode_active drafted in."""
         k1 = self.spec.max_draft_tokens + 1
-        step_start = time.monotonic()
-        t_sync = time.perf_counter()
+        t_sync = step.mark("host_sync")
         if self.page_pool is not None:
             per_row = {i: len(drafts.get(i, ())) + 1 for i in active}
             active = self._ensure_decode_pages(active, 1, per_row)
             if not active:
+                self._clock().abandon()
                 self.metrics.set_batch_occupancy(0)
                 return True
             self._sync_block_tables()
@@ -3193,11 +3216,10 @@ class EngineCore:
             if masked:
                 mask = self._d_spec_mask.reshape(b * k1, -1)
                 self.metrics.record_masked_decode_step()
-        sync_s = time.perf_counter() - t_sync
 
         self._key, sk = jax.random.split(self._key)
         window = self._window_for(active, k1)
-        t_dispatch = time.perf_counter()
+        step.mark("dispatch")
         lora_idx = self._d_lora_idx if self.lora is not None else None
         if fused:
             fn = self._verify_fused_for(window, grammar)
@@ -3253,12 +3275,11 @@ class EngineCore:
                     self._d_seeds, mask, sk, lora_idx=lora_idx,
                 )
             dispatches += 2  # the ids splice + the verify program
-        t_compute = time.perf_counter()
+        step.mark("compute")
         jax.block_until_ready(toks_dev)
-        t_fetch = time.perf_counter()
+        step.mark("fetch")
         tokens = self._fetch_tokens(toks_dev)  # [B, K+2]: input col + samples
-        t_emit = time.perf_counter()
-        step_s = time.monotonic() - step_start
+        step_s = step.mark("emit") - t_sync
 
         drafted = sum(len(drafts.get(i, ())) for i in active)
         accepted_total = 0
@@ -3337,13 +3358,7 @@ class EngineCore:
                 self.flightrec.emit(rid_i, "spec_accept",
                                     drafted=n_drafted, accepted=n_accepted)
         self._record_step(
-            "verify",
-            {"draft": draft_s,
-             "host_sync": sync_s,
-             "dispatch": t_compute - t_dispatch,
-             "compute": t_fetch - t_compute,
-             "fetch": t_emit - t_fetch,
-             "emit": time.perf_counter() - t_emit},
+            "verify", step,
             active_slots=len(active), tokens=emitted_total,
             slots=active, dispatches=dispatches, fused=fused,
         )
@@ -3675,9 +3690,8 @@ class EngineCore:
             lidx[g:] = lidx[g - 1]
             lora_idx = jnp.asarray(lidx)
 
-        prefill_start = time.monotonic()
         self._note_prefill_dispatch()
-        t_dispatch = time.perf_counter()
+        step = self._clock().begin("dispatch")
         if self.page_pool is not None:
             # padding rows repeat the last real slot's table row, so their
             # duplicate scatters rewrite identical cells (same trick as ids)
@@ -3704,12 +3718,11 @@ class EngineCore:
                 self.mesh,
                 lora_idx=lora_idx,
             )
-        t_compute = time.perf_counter()
+        step.mark("compute")
         # jitted prefill returns futures (async dispatch); block before timing
         # or the histogram records dispatch overhead, not device execution.
         jax.block_until_ready(logits)
-        t_done = time.perf_counter()
-        self.metrics.record_prefill_step(time.monotonic() - prefill_start)
+        self.metrics.record_prefill_step(step.mark("emit") - step.t0)
         if self.flightrec.enabled:
             # emit before activation: split mode stages the group and vacates
             # the prefill slots, after which the requests are unreachable here
@@ -3718,9 +3731,7 @@ class EngineCore:
                                     tokens=n, cached_tokens=0)
         self._activate_group(group, slot_ids, lens, logits)
         self._record_step(
-            "prefill",
-            {"dispatch": t_compute - t_dispatch, "compute": t_done - t_compute,
-             "emit": time.perf_counter() - t_done},
+            "prefill", step,
             active_slots=len(group), tokens=sum(n for _, _, n in group),
             slots=[s for s, _, _ in group],
         )
@@ -3741,6 +3752,9 @@ class EngineCore:
             self.split.stage_group(group, logits)
             self.split.pump_handoffs()
             return
+        # inside a step (the prefill paths) this is its `activate` span;
+        # a handoff adoption between steps stays in the loop's bucket
+        self._clock().mark("activate")
         padded = len(padded_slot_ids)
         temps = np.ones((padded,), np.float32)
         top_ps = np.ones((padded,), np.float32)
@@ -3863,24 +3877,20 @@ class EngineCore:
         padded = self._cp_bucket_for(n)
         ids = np.zeros((1, padded), np.int32)
         ids[0, :n] = self._effective_prompt(request)
-        prefill_start = time.monotonic()
         self._note_prefill_dispatch()
-        t_dispatch = time.perf_counter()
+        step = self._clock().begin("dispatch")
         logits, k_all, v_all = self._cp_prefill_fn(
             self.params, jnp.asarray(ids), jnp.asarray([n], np.int32)
         )
-        t_compute = time.perf_counter()
+        step.mark("compute")
         jax.block_until_ready(logits)  # async dispatch; time real execution
-        t_done = time.perf_counter()
-        self.metrics.record_prefill_step(time.monotonic() - prefill_start)
+        # the legacy phases of this path end here (dispatch and compute);
+        # the record stays open so the KV scatter and the activation are
+        # spans of it and not a hole between records
+        step.freeze_phases()
+        self.metrics.record_prefill_step(step.mark("emit") - step.t0)
         self._fr_emit(request, "prefill_chunk", tokens=n, cached_tokens=0,
                       cp=True)
-        self._record_step(
-            "prefill",
-            {"dispatch": t_compute - t_dispatch,
-             "compute": t_done - t_compute},
-            active_slots=1, tokens=n,
-        )
         # KV beyond n is padding garbage; it lands in cells past the valid
         # length (masked by decode attention and overwritten as the sequence
         # grows into them) — same contract as the chunked path.
@@ -3898,6 +3908,7 @@ class EngineCore:
         slot.generated = 0
         self._attach_constraint(slot_id, request)
         self._activate_slot(slot_id, request, n, logits)
+        self._record_step("prefill", step, active_slots=1, tokens=n)
 
     def _advance_prefill(self) -> bool:
         """Feed ONE chunk of ONE prefilling slot's prompt into the KV cache.
@@ -3938,9 +3949,8 @@ class EngineCore:
         lora_idx = (jnp.asarray(self._lora_rows([request]))
                     if self.lora is not None else None)
 
-        prefill_start = time.monotonic()
         self._note_prefill_dispatch()
-        t_dispatch = time.perf_counter()
+        step = self._clock().begin("dispatch")
         if self.page_pool is not None:
             logits, self.cache_k, self.cache_v = self.family.prefill_extend_pages(
                 self.params,
@@ -3967,10 +3977,9 @@ class EngineCore:
                 self.mesh,
                 lora_idx=lora_idx,
             )
-        t_compute = time.perf_counter()
+        step.mark("compute")
         jax.block_until_ready(logits)  # async dispatch; time real execution
-        t_done = time.perf_counter()
-        self.metrics.record_prefill_step(time.monotonic() - prefill_start)
+        self.metrics.record_prefill_step(step.mark("emit") - step.t0)
 
         slot.prefill_pos = start + chunk_len
         self._fr_emit(request, "prefill_chunk", tokens=chunk_len,
@@ -3980,9 +3989,7 @@ class EngineCore:
             self._release_cache_entry(slot)  # suffix landed; donor evictable
             self._activate_slot(slot_id, request, n, logits)
         self._record_step(
-            "prefill",
-            {"dispatch": t_compute - t_dispatch, "compute": t_done - t_compute,
-             "emit": time.perf_counter() - t_done},
+            "prefill", step,
             active_slots=1, tokens=chunk_len,
             slots=[slot_id],
         )
@@ -4196,30 +4203,31 @@ class EngineCore:
         # instead of forcing the whole batch into single-step decode. With
         # no drafter attached anywhere this block is a no-op and the decode
         # path below is bit-identical to the pre-speculation engine.
-        draft_s = 0.0
+        clock = self._clock()
         if self._spec_available and any(
             self.slots[i].drafter is not None for i in active
         ):
-            t_draft = time.perf_counter()
+            step = clock.begin("draft")
             fused_spec = self._fused_step_ok(active)
             drafts, lookahead = self._collect_drafts(active, fused=fused_spec)
-            draft_s = time.perf_counter() - t_draft
             if any(drafts.values()):
-                return self._verify_active(active, drafts, lookahead, draft_s,
+                return self._verify_active(active, drafts, lookahead, step,
                                            fused=fused_spec)
             # no n-gram matched: fall through to plain decode; the draft
-            # time lands in this step's record below
-
-        t_sync = time.perf_counter()
+            # span stays on this step's record
+            t_sync = step.mark("host_sync")
+        else:
+            step = clock.begin("host_sync")
+            t_sync = step.t0
         if self.page_pool is not None:
             # alloc-on-extend: every page this dispatch writes must exist
             # before the tables ship to the device
             active = self._ensure_decode_pages(active, self.decode_burst)
             if not active:
+                clock.abandon()
                 self.metrics.set_batch_occupancy(0)
                 return True  # pool exhaustion finished requests: work done
             self._sync_block_tables()
-        sync_s = time.perf_counter() - t_sync
 
         self._key, sk = jax.random.split(self._key)
         k = self.decode_burst
@@ -4240,10 +4248,8 @@ class EngineCore:
             self.metrics.record_constrained_burst_fallback()
         lora_idx = self._d_lora_idx if self.lora is not None else None
         if k > 1 or fused_step:
-            burst_start = time.monotonic()
             window = self._window_for(active, k)
             grammar = fused_step and constrained_active
-            t_mask = time.perf_counter()
             gram_args = {}
             if grammar:
                 # Fresh int32 cursor vector from the host FSMs (source of
@@ -4260,10 +4266,9 @@ class EngineCore:
                     "gram_state": jnp.asarray(gs),
                 }
                 self.metrics.record_masked_decode_step()
-            sync_s += time.perf_counter() - t_mask
             fn = (self._decode_many_gram_for(window) if grammar
                   else self._decode_many_for(window))
-            t_dispatch = time.perf_counter()
+            step.mark("dispatch")
             if self.page_pool is not None:
                 (self._d_last_tokens, self._d_seq_lens, self.cache_k,
                  self.cache_v, toks_dev) = fn(
@@ -4280,36 +4285,28 @@ class EngineCore:
                     self._d_temps, self._d_top_ps, self._d_top_ks,
                     self._d_seeds, sk, lora_idx=lora_idx, **gram_args,
                 )
-            t_compute = time.perf_counter()
+            step.mark("compute")
             # split device execution from the D2H readback: the dispatch
             # returned futures, block_until_ready is the compute wait, the
             # fetch below is pure transfer
             jax.block_until_ready(toks_dev)
-            t_fetch = time.perf_counter()
+            step.mark("fetch")
             tokens = self._fetch_tokens(toks_dev)  # ONE D2H sync per k tokens
-            t_emit = time.perf_counter()
             # Tokens reach the host back-to-back, so wall-clock gaps between
             # _emit calls are ~0 and would poison the ITL histogram; record
             # the amortized per-token pacing of the burst instead.
-            step_s = (time.monotonic() - burst_start) / k
+            step_s = (step.mark("emit") - t_sync) / k
             self.metrics.record_decode_step(step_s, len(active))
             self._emit_fetched(tokens, active, itl=step_s)
             self._record_step(
-                "decode",
-                {"draft": draft_s,
-                 "host_sync": sync_s,
-                 "dispatch": t_compute - t_dispatch,
-                 "compute": t_fetch - t_compute,
-                 "fetch": t_emit - t_fetch,
-                 "emit": time.perf_counter() - t_emit},
+                "decode", step,
                 active_slots=len(active), tokens=k * len(active),
                 slots=active, dispatches=1, fused=fused_step,
             )
             return True
 
-        step_start = time.monotonic()
         first_in = self._d_last_tokens  # pre-step tokens: pending firsts
-        t_dispatch = time.perf_counter()
+        step.mark("dispatch")
         if self.page_pool is not None:
             logits, self.cache_k, self.cache_v = self.family.decode_step_paged(
                 self.params,
@@ -4335,40 +4332,31 @@ class EngineCore:
                 window=self._window_for(active, 1),
                 lora_idx=lora_idx,
             )
-        dispatch_s = time.perf_counter() - t_dispatch
-        t_mask = time.perf_counter()
-        mask = self._sync_mask() if constrained_active else None
-        sync_s += time.perf_counter() - t_mask
-        if mask is not None:
+        mask = None
+        if constrained_active:
+            step.mark("host_sync")
+            mask = self._sync_mask()
             self.metrics.record_masked_decode_step()
-        t_sample = time.perf_counter()
+            step.mark("dispatch")
         tokens_dev = sample_tokens(
             logits, sk, self._d_temps, self._d_top_ps, self._d_top_ks,
             mask, self._d_seeds, self._d_seq_lens,
         )
         self._d_last_tokens = tokens_dev
         self._d_seq_lens = self._d_seq_lens + 1
-        dispatch_s += time.perf_counter() - t_sample
-        t_compute = time.perf_counter()
+        step.mark("compute")
         jax.block_until_ready(tokens_dev)  # device execution, not transfer
-        t_fetch = time.perf_counter()
+        step.mark("fetch")
         # the one D2H sync per step; row 0 carries deferred first emissions.
         # itl = this step's duration: a deferred first and its decode token
         # land in the same fetch, so the wall gap between them is ~0 and
         # would skew the histogram exactly like an unamortized burst.
         tokens = self._fetch_tokens(jnp.stack([first_in, tokens_dev]))
-        t_emit = time.perf_counter()
-        step_s = time.monotonic() - step_start
+        step_s = step.mark("emit") - t_sync
         self.metrics.record_decode_step(step_s, len(active))
         self._emit_fetched(tokens, active, itl=step_s)
         self._record_step(
-            "decode",
-            {"draft": draft_s,
-             "host_sync": sync_s,
-             "dispatch": dispatch_s,
-             "compute": t_fetch - t_compute,
-             "fetch": t_emit - t_fetch,
-             "emit": time.perf_counter() - t_emit},
+            "decode", step,
             active_slots=len(active), tokens=len(active),
             slots=active,
             # legacy eager step: model forward, sample, lens advance are

@@ -661,8 +661,10 @@ class EngineAPI:
 
     async def steps(self, request: web.Request) -> web.Response:
         """GET /api/steps — the step-loop introspection surface: recent
-        per-step phase breakdowns (plan / host_sync / dispatch / compute /
-        fetch / emit), per-kind EMA baselines, and slow-step anomalies.
+        per-step records (spans on one clock, the account of the time since
+        the previous step, the legacy plan / host_sync / dispatch / compute /
+        fetch / emit phases), per-kind EMA baselines, slow-step anomalies,
+        and the programs built (docs/profiling.md).
         `?limit=N` bounds the record count (default 64, max ring size);
         `?slow=1` returns only anomalous steps."""
         core = self.engine.core
@@ -672,6 +674,9 @@ class EngineAPI:
             return _error(400, "'limit' must be an integer")
         slow_only = request.query.get("slow", "") in ("1", "true", "yes")
         body = core.step_stats.snapshot(limit=limit, slow_only=slow_only)
+        # programs built since the engine was made, the newest by name: a
+        # record's `builds` says which step paid for one
+        body["compile"] = core.metrics.compile_info(builds=64)
         body["perf"] = core.perf_info()
         body["flightrec"] = core.flightrec.counters()
         return web.json_response(body)

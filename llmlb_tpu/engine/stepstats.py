@@ -1,32 +1,64 @@
-"""Step-loop introspection: per-step phase records, ring buffer, anomalies.
+"""Step-loop introspection: one measured host timeline for the step loop.
 
-Answers "where does a step's time go?" for the engine's serving loop. Every
-scheduler step (prefill dispatch or decode dispatch) produces ONE StepRecord
-with a per-phase wall-clock breakdown:
+Answers "where does the loop's time go?" for the engine's serving loop, with
+one clock (``time.perf_counter()``) read once at each boundary:
 
-  plan      — request admission: queue pop, constraint prep, prefix match,
-              page reservation, slot claim (scheduler._try_insert)
-  host_sync — host→device state refresh before a dispatch: block-table rows
-              and grammar-mask rows changed since the last step
+* **A step is a span.** Every scheduler step (prefill dispatch, decode
+  dispatch, speculative verify) is opened by ``LoopClock.begin`` and cut
+  into named spans by ``StepSpan.mark``; each mark is one clock read, ends
+  the span before it and starts the next, so a step has no holes. Each span
+  is also a ``jax.profiler.TraceAnnotation`` (an inactive TraceMe unless a
+  capture runs), inside one ``llmlb.step`` annotation that carries the
+  record's ``seq``: a capture shows the steps on the host plane of the same
+  trace as the device ops, joined to ``/api/steps`` by ``seq``.
+* **The time between steps is accounted.** The loop switches a bucket
+  (``LoopClock.switch``) as it goes: ``admit`` (``_try_insert``),
+  ``control`` (lockstep tick, drain/park/flush, split-mode lock waits),
+  ``record`` (closing the previous record: histograms, flight-recorder
+  emits), ``idle`` (the 1 ms sleep when there is nothing to do) and
+  ``other`` (whatever none of these holds). Every record carries the split
+  of the time since the previous record of its loop (``since_prev``), and
+  the cumulative buckets, ``step`` included, are served as
+  ``loop_seconds_total``: over any interval they sum to the interval.
+* **Programs built inside a step** are named on its record (``builds``,
+  engine/compilelog.py).
+
+Span names (``SPANS``), in the order a decode step runs them:
+
+  draft     — speculative drafting: n-gram lookup, FSM lookahead
+  host_sync — host→device state refresh before a dispatch: page allocation,
+              block-table rows and grammar-mask rows changed since the last
+              step, the PRNG split, the choice of the context window
   dispatch  — the jitted step call returning its (async) futures: python +
               jax dispatch overhead, no device time
-  compute   — jax.block_until_ready delta: actual device execution
+  compute   — the host WAITING in jax.block_until_ready. Not device
+              execution time: the device may have started earlier (async
+              dispatch) and idles inside it whenever the host was late; the
+              device's own time comes from a profiler trace
   fetch     — device→host token readback (the per-step D2H sync)
   emit      — host-side token delivery: stop checks, grammar FSM advance,
               event-queue puts (detokenization itself runs on the service
               layer's consumer threads, off the step loop)
+  activate  — a prefilled request entering decode (_activate_group): the
+              first-token sample, the scatters of the sampling state, and
+              the first-token fetch where a grammar needs it
 
-Records land in a bounded ring buffer served at the engine's ``/api/steps``
-plus per-phase histograms in ``/metrics``. A slow-step anomaly detector
-keeps an EMA of step time per kind and flags steps that exceed a
-configurable multiple of it — the "one step took 40x the usual" events that
-histograms average away.
+The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
+since the previous record (``since_prev.admit_s``), ``emit`` still covers
+activation, and ``total_s`` is their sum. Records land in a bounded ring
+buffer served at the engine's ``/api/steps`` plus per-phase histograms in
+``/metrics``. A slow-step anomaly detector keeps an EMA per kind of the time
+a step took since the previous one ended (idle sleep left out), and flags
+steps that exceed a configurable multiple of it — so a stall outside every
+span (a lock, a queue drain, the collector) is flagged too, and the record
+names the span or bucket that held the time (``slow_in``).
 
-The recorder is deliberately dumb and allocation-light: a handful of
-``time.perf_counter()`` deltas per step and one dict append. The guarantee
+The recorder is deliberately dumb and allocation-light: one clock read and
+one inactive TraceMe per boundary, one dict append per step. The guarantee
 (tested in tests/engine/test_step_introspection.py) is < 1% of step time on
 the CPU debug engine, whose steps are orders of magnitude shorter than any
-real TPU step.
+real TPU step; on the v5e chip, where a decode burst takes 190 ms, parent
+and change read the same to within the spread of the runs (PERF.md §6, PR 24).
 """
 
 from __future__ import annotations
@@ -35,8 +67,22 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
+from llmlb_tpu.engine import compilelog
+
+# the one clock of every stamp below (a name of its own, so that a test can
+# put made-up stamps in its place without touching the process's clock)
+_now = time.perf_counter
+
 PHASES = ("plan", "draft", "host_sync", "dispatch", "compute", "fetch",
           "emit")
+# The closed set of span names a step is cut into (StepSpan.mark).
+SPANS = ("draft", "host_sync", "dispatch", "compute", "fetch", "emit",
+         "activate")
+# Where the loop's time goes between steps, and with "step" all of it.
+GAP_BUCKETS = ("admit", "control", "record", "idle", "other")
+LOOP_BUCKETS = ("step",) + GAP_BUCKETS
 
 # Step kinds the scheduler dispatches. Each kind keeps its OWN EMA baseline
 # in the slow-step detector: a K+1-token speculative verify step is
@@ -63,6 +109,158 @@ _SLOW_FLOOR_S = 0.020
 _WARMUP_STEPS = 16
 
 
+class StepSpan:
+    """One open step: its stamps and spans, made by LoopClock.begin and
+    handed to StepRecorder.observe once LoopClock.close has stamped its
+    end. All times are time.perf_counter() seconds."""
+
+    __slots__ = ("kind", "seq", "loop", "t0", "t1", "spans", "since_prev",
+                 "builds", "slow_in", "_name", "_start", "_ann", "_step_ann",
+                 "_resume", "_legacy_spans")
+
+    def __init__(self, loop: str, seq: int, t0: float, first_span: str,
+                 since_prev: dict[str, float], resume: str):
+        self.loop = loop
+        self.seq = seq
+        self.t0 = self._start = t0
+        self.spans: list[tuple[str, float, float]] = []
+        self.since_prev = since_prev
+        self.slow_in: str | None = None  # set by StepRecorder.observe
+        self._name = first_span
+        self._resume = resume
+        self._legacy_spans: int | None = None
+        self._step_ann = TraceAnnotation("llmlb.step", seq=seq)
+        self._step_ann.__enter__()
+        self._ann = TraceAnnotation(first_span)
+        self._ann.__enter__()
+
+    def mark(self, name: str) -> float:
+        """End the running span and start `name` at one clock read, which
+        is returned."""
+        now = _now()
+        self.spans.append((self._name, self._start, now - self._start))
+        self._ann.__exit__(None, None, None)
+        self._name = name
+        self._start = now
+        self._ann = TraceAnnotation(name)
+        self._ann.__enter__()
+        return now
+
+    def freeze_phases(self) -> None:
+        """Spans from the next mark on appear in `spans` and `wall_s` but
+        not in the legacy `phases_s` (the context-parallel prefill, whose
+        record used to close before its activation)."""
+        self._legacy_spans = len(self.spans) + 1
+
+    def phases(self) -> dict[str, float]:
+        """The legacy phase durations of a closed step: spans summed by
+        name, `activate` counted as `emit`, and the admission time since
+        the previous record as `plan`."""
+        out = {"plan": self.since_prev["admit"]}
+        spans = self.spans
+        if self._legacy_spans is not None:
+            spans = spans[:self._legacy_spans]
+        for name, _start, dur in spans:
+            if name == "activate":
+                name = "emit"
+            out[name] = out.get(name, 0.0) + dur
+        return out
+
+
+class LoopClock:
+    """The clock of ONE step-loop thread: at every instant the thread is
+    either inside a step or in one of the GAP_BUCKETS, and every switch is
+    one perf_counter read, so the buckets sum to the thread's lifetime.
+    `acc` is cumulative (served as loop_seconds_total); the gap since the
+    previous record's end goes onto the next record as `since_prev`."""
+
+    def __init__(self, recorder: "StepRecorder", tag: str):
+        self.recorder = recorder
+        self.tag = tag
+        self.acc = dict.fromkeys(LOOP_BUCKETS, 0.0)
+        self._gap = dict.fromkeys(GAP_BUCKETS, 0.0)
+        self._bucket = "other"
+        self._mark = _now()
+        self._step: StepSpan | None = None
+
+    def switch(self, bucket: str) -> None:
+        """The loop moves on to `bucket` (no-op while a step is open: the
+        step's own spans hold that time)."""
+        if self._step is not None:
+            return
+        now = _now()
+        dt = now - self._mark
+        self.acc[self._bucket] += dt
+        self._gap[self._bucket] += dt
+        self._bucket = bucket
+        self._mark = now
+
+    def begin(self, first_span: str) -> StepSpan:
+        """Open a step whose first span is `first_span`. Its kind is given
+        when it is closed (a decode step may turn into a verify)."""
+        resume = self._bucket
+        self.switch("step")
+        # steps are serialized (one loop, or split mode's lock), so the
+        # record this step will become is the recorder's next
+        step = StepSpan(self.tag, self.recorder.seq + 1, self._mark,
+                        first_span, self._gap, resume)
+        self._gap = dict.fromkeys(GAP_BUCKETS, 0.0)
+        compilelog.enter_step(step.seq)
+        self._step = step
+        return step
+
+    def mark(self, name: str) -> None:
+        """Start span `name` in the open step, if there is one (for code
+        that runs inside some steps and between others: activation)."""
+        if self._step is not None:
+            self._step.mark(name)
+
+    def _end(self, step: StepSpan, kind: str) -> float:
+        now = _now()
+        step.spans.append((step._name, step._start, now - step._start))
+        step._ann.__exit__(None, None, None)
+        step._step_ann.set_metadata(kind=kind)
+        step._step_ann.__exit__(None, None, None)
+        step.kind = kind
+        step.t1 = now
+        step.builds = compilelog.leave_step()
+        self._step = None
+        return now
+
+    def close(self, step: StepSpan, kind: str) -> None:
+        """Stamp the step's end. Until resume() the loop is in `record`;
+        after it, back in the bucket the step was opened from."""
+        now = self._end(step, kind)
+        self.acc["step"] += now - self._mark
+        self._bucket = "record"
+        self._mark = now
+
+    def resume(self, step: StepSpan) -> None:
+        self.switch(step._resume)
+
+    def abandon(self) -> None:
+        """Drop the open step, if any, without a record (a step that found
+        nothing to dispatch, or one that raised): its time is `other`."""
+        step = self._step
+        if step is None:
+            return
+        now = self._end(step, "abandoned")
+        self._gap = step.since_prev
+        dt = now - self._mark
+        self.acc["other"] += dt
+        self._gap["other"] += dt
+        self._bucket = "other"
+        self._mark = now
+
+    def snapshot(self) -> dict[str, float]:
+        """The cumulative buckets with the running stretch added to the
+        bucket it is in (read from another thread: one stretch may land
+        in the neighbouring bucket if the loop switches meanwhile)."""
+        out = dict(self.acc)
+        out[self._bucket] += max(0.0, _now() - self._mark)
+        return out
+
+
 class StepRecorder:
     """Bounded ring of per-step phase breakdowns + slow-step detection +
     a sliding window of (tokens, busy seconds) for live MFU math.
@@ -78,10 +276,12 @@ class StepRecorder:
         self.capacity = max(1, capacity)
         self.slow_ratio = slow_ratio
         self.slow_floor_s = slow_floor_s
+        # the one anchor that puts perf_counter stamps on the wall clock
+        self._wall_anchor = time.time() - _now()
         self._lock = threading.Lock()
         self._ring: deque[dict] = deque(maxlen=self.capacity)
         self._seq = 0
-        self._ema: dict[str, float] = {}  # kind -> EMA of total_s
+        self._ema: dict[str, float] = {}  # kind -> EMA of the judged time
         self._seen: dict[str, int] = {}
         self.slow_steps_total = 0
         # sliding window of decode steps for throughput-derived figures
@@ -92,7 +292,7 @@ class StepRecorder:
     def observe(self, kind: str, phases: dict[str, float], *,
                 active_slots: int = 0, tokens: int = 0,
                 request_ids: dict[str, str] | None = None,
-                dispatches: int = 0) -> bool:
+                dispatches: int = 0, span: StepSpan | None = None) -> bool:
         """Record one step; returns True when it was flagged anomalous.
         `phases` maps phase name -> seconds (missing phases count as 0);
         `tokens` is the number of tokens this step delivered to the host
@@ -101,44 +301,103 @@ class StepRecorder:
         flagged record NAMES its victims (/api/steps?slow=1); `dispatches`
         counts the device programs this step launched (the fused-decode
         invariant — scripts/check_fused_dispatch.py — asserts exactly 1 on
-        decode/verify records when LLMLB_FUSED_DECODE is on)."""
-        now = time.time()
+        decode/verify records when LLMLB_FUSED_DECODE is on). `span` is the
+        closed StepSpan the phases came from (a flagged one is told where its
+        time went: `span.slow_in`); without it (unit tests) the record's
+        stamps are rebuilt from the durations, ending now, and the detector
+        judges their sum."""
         total = sum(phases.values())
+        if span is not None:
+            t0, t1 = span.t0, span.t1
+            gap, spans = span.since_prev, span.spans
+            builds, loop = span.builds, span.loop
+            # everything since the previous record ended but the idle
+            # sleep: a stall between steps is judged like one inside
+            judged = (t1 - t0 + gap["admit"] + gap["control"]
+                      + gap["record"] + gap["other"])
+        else:
+            t1 = _now()
+            gap = dict.fromkeys(GAP_BUCKETS, 0.0)
+            gap["admit"] = phases.get("plan", 0.0)
+            at = t0 = t1 - total + gap["admit"]
+            spans = []
+            for p in PHASES[1:]:
+                if phases.get(p):
+                    spans.append((p, at, phases[p]))
+                    at += phases[p]
+            builds, loop = [], "main"
+            judged = total
+        record = {
+            "ts": self._wall_anchor + t1,
+            "kind": kind,
+            "total_s": total,
+            "phases_s": {p: phases.get(p, 0.0) for p in PHASES},
+            "active_slots": active_slots,
+            "tokens": tokens,
+            "dispatches": dispatches,
+            "request_ids": dict(request_ids) if request_ids else {},
+            "slow_in": None,
+            "loop": loop,
+            "t0_s": t0,
+            "t1_s": t1,
+            "spans": spans,
+            "since_prev": gap,
+            "builds": builds,
+        }
         with self._lock:
             seen = self._seen.get(kind, 0)
             ema = self._ema.get(kind)
             slow = False
             if seen >= _WARMUP_STEPS and ema is not None:
                 threshold = max(self.slow_ratio * ema, self.slow_floor_s)
-                slow = total > threshold
+                slow = judged > threshold
                 if slow:
                     self.slow_steps_total += 1
+                    record["slow_in"] = self._slow_in(record)
+                    if span is not None:
+                        span.slow_in = record["slow_in"]
             # anomalous steps do not feed the baseline: one 40x step must
             # not drag the EMA up and mask the next one
             if ema is None:
-                self._ema[kind] = total
+                self._ema[kind] = judged
             elif not slow:
-                self._ema[kind] = ema + _EMA_ALPHA * (total - ema)
+                self._ema[kind] = ema + _EMA_ALPHA * (judged - ema)
             self._seen[kind] = seen + 1
             self._seq += 1
-            self._ring.append({
-                "seq": self._seq,
-                "ts": now,
-                "kind": kind,
-                "total_s": total,
-                "phases_s": {p: phases.get(p, 0.0) for p in PHASES},
-                "active_slots": active_slots,
-                "tokens": tokens,
-                "dispatches": dispatches,
-                "request_ids": dict(request_ids) if request_ids else {},
-                "slow": slow,
-            })
+            record["seq"] = self._seq
+            record["slow"] = slow
+            self._ring.append(record)
             # decode AND verify steps feed the throughput window: both
             # deliver committed tokens, and live MFU must see speculative
             # throughput or it would collapse the moment speculation engages
             if kind in ("decode", "verify") and tokens > 0:
                 self._window.append((total, tokens))
         return slow
+
+    @staticmethod
+    def _parts(record: dict) -> dict[str, float]:
+        """A record's time by span name and by gap bucket (idle left out)."""
+        parts = {f"{b}_s": v for b, v in record["since_prev"].items()
+                 if b != "idle"}
+        for name, _start, dur in record["spans"]:
+            parts[name] = parts.get(name, 0.0) + dur
+        return parts
+
+    def _slow_in(self, record: dict) -> str:
+        """The span or bucket that holds a slow step's time: the part that
+        exceeds by most its mean over the last (up to 8) steps of the same
+        kind that were not slow. Lock held; runs for flagged steps only."""
+        usual: dict[str, float] = {}
+        n = 0
+        for r in reversed(self._ring):
+            if r["kind"] == record["kind"] and not r["slow"]:
+                for k, v in self._parts(r).items():
+                    usual[k] = usual.get(k, 0.0) + v
+                n += 1
+                if n == 8:
+                    break
+        parts = self._parts(record)
+        return max(parts, key=lambda k: parts[k] - usual.get(k, 0.0) / max(n, 1))
 
     # --------------------------------------------------------------- reading
 
@@ -177,7 +436,15 @@ class StepRecorder:
         records = [
             {**r,
              "total_s": round(r["total_s"], 6),
-             "phases_s": {k: round(v, 6) for k, v in r["phases_s"].items()}}
+             "phases_s": {k: round(v, 6) for k, v in r["phases_s"].items()},
+             "t0_s": round(r["t0_s"], 6), "t1_s": round(r["t1_s"], 6),
+             "wall_s": round(r["t1_s"] - r["t0_s"], 6),
+             "spans": [[name, round(start - r["t0_s"], 6), round(dur, 6)]
+                       for name, start, dur in r["spans"]],
+             "since_prev": {f"{b}_s": round(v, 6)
+                            for b, v in r["since_prev"].items()},
+             "builds": {"count": len(r["builds"]),
+                        "names": list(r["builds"])}}
             for r in records
         ]
         return {

@@ -8,13 +8,22 @@ instrumentation-overhead guarantee, and POST /api/profile producing a
 non-empty downloadable trace on CPU JAX.
 """
 
+import asyncio
 import io
 import time
 import zipfile
 
 import pytest
 
-from llmlb_tpu.engine.stepstats import PHASES, StepRecorder
+from llmlb_tpu.engine import compilelog, stepstats
+from llmlb_tpu.engine.stepstats import (
+    GAP_BUCKETS,
+    LOOP_BUCKETS,
+    PHASES,
+    SPANS,
+    LoopClock,
+    StepRecorder,
+)
 from llmlb_tpu.engine.telemetry import (
     chip_spec_for,
     model_bytes_per_token,
@@ -82,6 +91,217 @@ def test_step_recorder_snapshot_copies_records():
     a["phases_s"]["compute"] = 999.0
     b = rec.snapshot()["records"][0]
     assert b["phases_s"]["compute"] != 999.0
+
+
+# ------------------------------------------------ spans, loop clock (units)
+
+
+def _stamped(monkeypatch, stamps):
+    """A LoopClock whose perf_counter reads come from `stamps`, in order."""
+    it = iter(stamps)
+    monkeypatch.setattr(stepstats, "_now", lambda: next(it))
+    rec = StepRecorder()  # reads the clock once, for its wall anchor
+    return rec, LoopClock(rec, "main")
+
+
+DECODE_STAMPS = [
+    5.0,    # the recorder's wall anchor
+    10.0,   # the clock is made: the loop is in `other`
+    10.1,   # switch(admit)
+    10.3,   # begin(host_sync): 0.2 s of admission, t0
+    10.31,  # mark(dispatch)
+    10.32,  # mark(compute)
+    10.52,  # mark(fetch)
+    10.54,  # mark(emit)
+    10.55,  # close: t1
+    10.56,  # resume: closing the record cost 0.01 s
+    10.57,  # begin of the next step
+    10.60,  # close
+]
+
+
+def _one_decode_step(monkeypatch):
+    rec, clock = _stamped(monkeypatch, DECODE_STAMPS)
+    clock.switch("admit")
+    step = clock.begin("host_sync")
+    for name in ("dispatch", "compute", "fetch", "emit"):
+        step.mark(name)
+    clock.close(step, "decode")
+    rec.observe("decode", step.phases(), active_slots=2, tokens=16,
+                request_ids={"0": "req-a"}, dispatches=1, span=step)
+    clock.resume(step)
+    return rec, clock
+
+
+def test_a_step_record_is_a_measured_span(monkeypatch):
+    rec, clock = _one_decode_step(monkeypatch)
+    r = rec.snapshot()["records"][0]
+    assert (r["t0_s"], r["t1_s"]) == (10.3, 10.55)
+    assert r["wall_s"] == pytest.approx(0.25)
+    assert [s[0] for s in r["spans"]] == [
+        "host_sync", "dispatch", "compute", "fetch", "emit"]
+    assert all(name in SPANS for name, _at, _dur in r["spans"])
+    # spans lie end to end from t0: no hole, and they sum to the wall time
+    at = 0.0
+    for _name, start, dur in r["spans"]:
+        assert start == pytest.approx(at, abs=1e-6)
+        at += dur
+    assert at == pytest.approx(r["wall_s"], abs=1e-6)
+    assert r["since_prev"] == pytest.approx({
+        "admit_s": 0.2, "control_s": 0.0, "record_s": 0.0, "idle_s": 0.0,
+        "other_s": 0.1})
+    assert set(r["since_prev"]) == {f"{b}_s" for b in GAP_BUCKETS}
+    assert r["builds"] == {"count": 0, "names": []}
+    assert r["loop"] == "main" and r["slow_in"] is None
+    # ts is the last stamp through the recorder's one wall anchor
+    assert r["ts"] == pytest.approx(rec._wall_anchor + 10.55)
+    # the next record owns what closing this one cost, and no time is lost
+    step = clock.begin("compute")
+    clock.close(step, "decode")
+    rec.observe("decode", step.phases(), span=step)
+    nxt = rec.snapshot()["records"][0]
+    assert nxt["since_prev"]["record_s"] == pytest.approx(0.01)
+    assert r["t1_s"] + sum(nxt["since_prev"].values()) == pytest.approx(
+        nxt["t0_s"], abs=50e-6)
+    # the cumulative buckets hold every second since the clock was made
+    assert set(clock.acc) == set(LOOP_BUCKETS)
+    assert sum(clock.acc.values()) == pytest.approx(10.60 - 10.0)
+    assert clock.acc["step"] == pytest.approx(0.25 + 0.03)
+
+
+def test_legacy_fields_equal_what_observe_produced_for_the_same_stamps(
+        monkeypatch):
+    """The arithmetic the six step paths did by hand (t_sync, t_dispatch,
+    ... and the plan time lumped in) against the span helper, stamp for
+    stamp: every field a record had before PR 24 reads the same."""
+    rec, _clock = _one_decode_step(monkeypatch)
+    new = rec.snapshot()["records"][0]
+    old_rec = StepRecorder()
+    by_hand = {"plan": 10.3 - 10.1, "draft": 0.0, "host_sync": 10.31 - 10.3,
+               "dispatch": 10.32 - 10.31, "compute": 10.52 - 10.32,
+               "fetch": 10.54 - 10.52, "emit": 10.55 - 10.54}
+    old_rec.observe("decode", by_hand, active_slots=2, tokens=16,
+                    request_ids={"0": "req-a"}, dispatches=1)
+    old = old_rec.snapshot()["records"][0]
+    legacy = ("seq", "kind", "total_s", "phases_s", "active_slots", "tokens",
+              "dispatches", "request_ids", "slow")
+    for key in legacy:
+        assert new[key] == pytest.approx(old[key]), key
+    assert new["total_s"] == pytest.approx(sum(new["phases_s"].values()))
+    # a record made without a span (the unit tests' way) is whole too
+    assert old["wall_s"] == pytest.approx(0.25)
+    assert [s[0] for s in old["spans"]] == [
+        "host_sync", "dispatch", "compute", "fetch", "emit"]
+    assert old["since_prev"]["admit_s"] == pytest.approx(0.2)
+
+
+def test_activate_is_a_span_of_its_own_and_still_part_of_legacy_emit(
+        monkeypatch):
+    rec, clock = _stamped(monkeypatch, [
+        1.0, 2.0, 2.0, 2.01, 2.11, 2.115, 2.140, 2.141, 2.15, 2.2, 2.3, 2.31])
+    step = clock.begin("dispatch")       # 2.0
+    step.mark("compute")                 # 2.01
+    step.mark("emit")                    # 2.11
+    clock.mark("activate")               # 2.115, from inside _activate_group
+    step.mark("emit")                    # 2.140
+    clock.close(step, "prefill")         # 2.141
+    phases = step.phases()
+    assert phases["emit"] == pytest.approx(0.031)   # delivery + activation
+    rec.observe("prefill", phases, span=step)
+    r = rec.snapshot()["records"][0]
+    emit = sum(d for n, _a, d in r["spans"] if n == "emit")
+    activate = sum(d for n, _a, d in r["spans"] if n == "activate")
+    assert activate == pytest.approx(0.025)
+    assert emit == pytest.approx(r["phases_s"]["emit"] - activate)
+    # between steps the same call is no span: the loop's bucket holds it
+    clock.resume(step)                   # 2.15
+    clock.mark("activate")               # no clock read
+    # a frozen step (the context-parallel prefill) keeps later spans out of
+    # the legacy phases and in the record
+    step = clock.begin("dispatch")       # 2.2
+    step.freeze_phases()
+    step.mark("activate")                # 2.3
+    clock.close(step, "prefill")         # 2.31
+    assert step.phases() == pytest.approx({"plan": 0.0, "dispatch": 0.1})
+    assert [n for n, _a, _d in step.spans] == ["dispatch", "activate"]
+
+
+def test_an_abandoned_step_leaves_no_record_and_loses_no_time(monkeypatch):
+    rec, clock = _stamped(monkeypatch, [1.0, 2.0, 2.5, 2.7, 3.0, 3.1, 3.2])
+    clock.switch("admit")                # 2.5
+    clock.begin("host_sync")             # 2.7
+    clock.abandon()                      # 3.0: nothing to dispatch
+    assert rec.seq == 0
+    step = clock.begin("host_sync")      # 3.1
+    assert step.since_prev == pytest.approx({
+        "admit": 0.2, "control": 0.0, "record": 0.0, "idle": 0.0,
+        "other": 0.5 + 0.3 + 0.1})
+    clock.abandon()
+
+
+def test_a_stall_between_steps_is_flagged_and_named():
+    """The detector judges the time since the previous record ended, idle
+    sleep left out: a stall that no span covers is slow all the same."""
+    rec = StepRecorder(slow_floor_s=0.0)
+    clock = LoopClock(rec, "main")
+
+    def step_after(bucket, seconds, idle=0.0):
+        step = clock.begin("compute")
+        step.since_prev = dict.fromkeys(GAP_BUCKETS, 0.0)
+        step.since_prev[bucket] = seconds
+        step.since_prev["idle"] = idle
+        clock.close(step, "decode")
+        step.t1 = step.t0 + 0.001
+        step.spans = [("compute", step.t0, 0.001)]
+        slow = rec.observe("decode", step.phases(), span=step)
+        clock.resume(step)
+        return slow
+
+    for _ in range(30):
+        assert step_after("other", 0.0001) is False
+    # a long sleep with nothing to do is no stall
+    assert step_after("other", 0.0001, idle=5.0) is False
+    assert step_after("other", 0.060) is True
+    assert rec.snapshot(slow_only=True)["records"][0]["slow_in"] == "other_s"
+    assert step_after("admit", 0.060) is True
+    assert rec.snapshot(slow_only=True)["records"][0]["slow_in"] == "admit_s"
+
+
+# ------------------------------------------------------ the program ledger
+
+
+def test_ledger_counts_a_program_per_new_shape_and_names_its_step():
+    import jax
+    import numpy as np
+
+    @jax.jit
+    def ledger_probe(x):
+        return x * 2 + 1
+
+    base = compilelog.counters()
+    ledger_probe(np.ones((3, 5), np.float32)).block_until_ready()
+    first = compilelog.summary(base)
+    assert first["programs_total"] == 1
+    assert first["by_thread"]["other"]["programs_total"] == 1
+    assert all(first["seconds_total"][s] > 0 for s in compilelog.STAGES)
+    # the same shape again builds nothing
+    ledger_probe(np.ones((3, 5), np.float32)).block_until_ready()
+    assert compilelog.summary(base)["programs_total"] == 1
+    # a new shape inside a step is named on that step and carries its seq
+    compilelog.enter_step(77)
+    ledger_probe(np.ones((4, 5), np.float32)).block_until_ready()
+    assert compilelog.leave_step() == ["jit(ledger_probe)"]
+    after = compilelog.summary(base)
+    assert after["programs_total"] == 2
+    assert after["repeat_builds_total"] == 1  # the same name, built again
+    newest, older = compilelog.recent(2, base)
+    assert (newest["fun_name"], newest["step_seq"]) == (
+        "jit(ledger_probe)", 77)
+    assert older["step_seq"] is None and newest["thread"] == "other"
+    assert newest["trace_s"] > 0 and newest["backend_s"] > 0
+    # outside a step again
+    assert compilelog.leave_step() == []
+    assert compilelog.recent(0, base) == []
 
 
 # ---------------------------------------------------------- telemetry helpers
@@ -199,8 +419,277 @@ async def test_engine_steps_endpoint_and_phase_metrics(served_engine):
         await client.close()
 
 
+def _oldest_first(core, limit=512):
+    return core.step_stats.snapshot(limit=limit)["records"][::-1]
+
+
+def _span_sum(record, name):
+    return sum(dur for n, _at, dur in record["spans"] if n == name)
+
+
+async def test_served_records_are_spans_in_the_order_run(served_engine):
+    """A one-shot prefill, the chunks of a long prompt and the decode steps
+    of a served engine: span order and offsets, `activate` where a request
+    enters decode and nowhere else, and `emit` in the spans shorter than the
+    legacy emit phase by exactly the activation."""
+    from llmlb_tpu.engine.scheduler import SamplingParams
+
+    engine = served_engine
+    start = engine.core.step_stats.seq
+    await _run_requests(engine, n=1)
+    # 40 tokens against a largest bucket of 16: three chunks, the last
+    # one activates
+    await engine.complete(list(range(1, 41)),
+                          SamplingParams(temperature=0.0, max_tokens=4))
+    records = [r for r in _oldest_first(engine.core) if r["seq"] > start]
+    prefill = [r for r in records if r["kind"] == "prefill"]
+    decode = [r for r in records if r["kind"] == "decode"]
+    assert len(prefill) == 4 and decode
+    for r in records:
+        names = [n for n, _at, _dur in r["spans"]]
+        assert set(names) <= set(SPANS)
+        at = 0.0
+        for _name, offset, dur in r["spans"]:
+            assert offset == pytest.approx(at, abs=2e-6)
+            at += dur
+        # no hole inside a step: the spans cover its wall time
+        assert at >= 0.98 * r["wall_s"] - 2e-6
+        assert r["wall_s"] == pytest.approx(r["t1_s"] - r["t0_s"], abs=2e-6)
+        assert r["total_s"] == pytest.approx(
+            r["wall_s"] + r["since_prev"]["admit_s"], abs=1e-5)
+        assert r["phases_s"]["plan"] == pytest.approx(
+            r["since_prev"]["admit_s"], abs=2e-6)
+    for r in decode:
+        assert [n for n, _a, _d in r["spans"]] == [
+            "host_sync", "dispatch", "compute", "fetch", "emit"]
+        assert _span_sum(r, "emit") == pytest.approx(r["phases_s"]["emit"],
+                                                     abs=2e-6)
+    activating = [r for r in prefill if _span_sum(r, "activate") > 0]
+    assert [r["seq"] for r in activating] == [prefill[0]["seq"],
+                                              prefill[3]["seq"]]
+    for r in prefill:
+        names = [n for n, _a, _d in r["spans"]]
+        assert names[:3] == ["dispatch", "compute", "emit"]
+        assert (names[3:] == ["activate"]) == (r in activating)
+        assert _span_sum(r, "emit") == pytest.approx(
+            r["phases_s"]["emit"] - _span_sum(r, "activate"), abs=3e-6)
+        assert _span_sum(r, "emit") < r["phases_s"]["emit"] or \
+            r not in activating
+
+
+async def test_no_time_is_lost_between_consecutive_records(served_engine):
+    engine = served_engine
+    await _run_requests(engine, n=3, max_tokens=24)
+    records = _oldest_first(engine.core)
+    assert len(records) > 50
+    for prev, cur in zip(records[-51:], records[-50:]):
+        assert cur["seq"] == prev["seq"] + 1 and cur["loop"] == "main"
+        assert prev["t1_s"] + sum(cur["since_prev"].values()) == \
+            pytest.approx(cur["t0_s"], abs=50e-6)
+        # closing a record costs something, and the next one says what
+        assert cur["since_prev"]["record_s"] > 0
+
+
+async def test_loop_buckets_sum_to_the_interval(served_engine):
+    """Across an idle second and a busy one, every second of the loop
+    thread's life is in exactly one bucket of loop_seconds_total."""
+    engine = served_engine
+    metrics = engine.core.metrics
+
+    def reading():
+        return time.perf_counter(), metrics.summary()["loop_seconds_total"]
+
+    t_a, a = reading()
+    time.sleep(1.0)
+    t_b, b = reading()
+    await _run_requests(engine, n=6, max_tokens=50)
+    t_c, c = reading()
+    assert set(a) == {"main"} and set(a["main"]) == set(LOOP_BUCKETS)
+    for (t0, x), (t1, y) in (((t_a, a), (t_b, b)), ((t_b, b), (t_c, c))):
+        delta = {k: y["main"][k] - x["main"][k] for k in LOOP_BUCKETS}
+        assert all(v >= -1e-6 for v in delta.values()), delta
+        assert sum(delta.values()) == pytest.approx(t1 - t0, rel=0.01), delta
+    idle = {k: b["main"][k] - a["main"][k] for k in LOOP_BUCKETS}
+    busy = {k: c["main"][k] - b["main"][k] for k in LOOP_BUCKETS}
+    assert idle["idle"] > 0.8 and idle["step"] == 0.0
+    assert busy["step"] > busy["idle"]
+    # whatever no step and no named bucket holds stays small
+    assert busy["other"] < 0.1 * (sum(busy.values()) - busy["idle"])
+
+
+async def test_a_stall_in_admission_is_flagged_slow_and_named(served_engine):
+    """A stall that falls outside every span (here: _drain_pending asleep
+    for 60 ms between two decode steps) raises a slow record that names the
+    bucket, lands in /api/steps?slow=1 and in its victims' flight records."""
+    from llmlb_tpu.engine.scheduler import SamplingParams
+
+    engine = served_engine
+    core = engine.core
+    # arm the detector: its baseline starts at the first step, which built
+    # the decode program, and comes down by a twentieth a step
+    for _ in range(20):
+        await _run_requests(engine, n=1, max_tokens=40)
+        if core.step_stats.snapshot(limit=0)["ema_step_s"]["decode"] < 0.010:
+            break
+    before = core.step_stats.slow_steps_total
+    plain = core._drain_pending
+    stall = {"left": 0}
+
+    def drain_with_a_stall():
+        if stall["left"] and any(s.request is not None and not s.prefilling
+                                 for s in core.slots):
+            stall["left"] -= 1
+            time.sleep(0.060)
+        plain()
+
+    core._drain_pending = drain_with_a_stall
+    try:
+        task = asyncio.ensure_future(engine.complete(
+            [7, 2, 3, 4, 5], SamplingParams(temperature=0.0, max_tokens=40)))
+        await asyncio.sleep(0.02)
+        stall["left"] = 1
+        await task
+    finally:
+        core._drain_pending = plain
+    assert core.step_stats.slow_steps_total > before
+    slow = [r for r in core.step_stats.snapshot(
+        limit=512, slow_only=True)["records"]
+        if r["since_prev"]["admit_s"] >= 0.055]
+    assert slow, "the stalled step was not flagged"
+    record = slow[0]
+    assert record["kind"] == "decode" and record["slow_in"] == "admit_s"
+    assert record["wall_s"] < 0.055  # the step itself was not slow
+    assert record["request_ids"]
+    rid = next(iter(record["request_ids"].values()))
+    events = core.flightrec.timeline(rid)["events"]
+    flagged = [e for e in events if e["event"] == "slow_step"]
+    assert flagged and flagged[-1]["attrs"]["slow_in"] == "admit_s"
+    assert flagged[-1]["attrs"]["step_seq"] == record["seq"]
+
+
+async def test_verify_records_carry_a_draft_span():
+    from llmlb_tpu.engine.scheduler import SamplingParams
+    from llmlb_tpu.engine.service import Engine
+
+    engine = Engine.from_preset(
+        "debug-tiny", spec_decode=True, num_slots=2, slot_capacity=256,
+        prefill_buckets=(16, 32, 64))
+    try:
+        ids = engine.encode_chat([{"role": "user", "content":
+                                   "count: 1 2 3 4 5 6 7 8 9 then repeat: "
+                                   "1 2 3 4 5"}])
+        await engine.complete(
+            ids, SamplingParams(temperature=0.0, max_tokens=60))
+        records = _oldest_first(engine.core)
+    finally:
+        engine.shutdown()
+    verify = [r for r in records if r["kind"] == "verify"]
+    assert verify
+    for r in verify:
+        assert [n for n, _a, _d in r["spans"]] == [
+            "draft", "host_sync", "dispatch", "compute", "fetch", "emit"]
+        assert _span_sum(r, "draft") == pytest.approx(
+            r["phases_s"]["draft"], abs=2e-6)
+        assert sum(d for _n, _a, d in r["spans"]) >= 0.98 * r["wall_s"] - 2e-6
+    # a drafting step that found no draft is a decode record with the span
+    for r in records:
+        if r["kind"] == "decode" and r["phases_s"]["draft"] > 0:
+            assert r["spans"][0][0] == "draft"
+    for prev, cur in zip(records, records[1:]):
+        assert prev["t1_s"] + sum(cur["since_prev"].values()) == \
+            pytest.approx(cur["t0_s"], abs=50e-6)
+
+
+async def test_steps_health_and_metrics_serve_the_ledger_and_the_buckets(
+        served_engine):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from llmlb_tpu.engine.server import create_engine_app
+
+    engine = served_engine
+    await _run_requests(engine, n=1)
+    client = TestClient(TestServer(create_engine_app(engine,
+                                                     owns_engine=False)))
+    await client.start_server()
+    try:
+        body = await (await client.get("/api/steps?limit=512")).json()
+        ledger = body["compile"]
+        assert ledger["programs_total"] >= 1
+        assert set(ledger["seconds_total"]) == set(compilelog.STAGES)
+        assert set(ledger["by_thread"]) == set(compilelog.THREAD_CLASSES)
+        assert 1 <= len(ledger["builds"]) <= 64
+        # a build made inside a step carries that step's seq, and the
+        # step's record names the program
+        by_seq = {r["seq"]: r for r in body["records"]}
+        for b in ledger["builds"]:
+            assert set(b) >= {"ts", "fun_name", "thread", "trace_s",
+                              "lower_s", "backend_s", "cache_hit", "step_seq"}
+            # (the ledger is the process's: another test's engine may
+            # have built under the same seq, at another time)
+            r = by_seq.get(b["step_seq"])
+            if r and r["ts"] - r["wall_s"] <= b["ts"] <= r["ts"]:
+                assert b["thread"] == "loop"
+                assert b["fun_name"] in r["builds"]["names"]
+        health = await (await client.get("/api/health")).json()
+        served = health["metrics"]
+        assert served["compile"]["programs_total"] == ledger["programs_total"]
+        assert "builds" not in served["compile"]
+        assert set(served["loop_seconds_total"]["main"]) == set(LOOP_BUCKETS)
+        text = await (await client.get("/metrics")).text()
+        for series in (
+                'llmlb_engine_loop_seconds_total{loop="main",bucket="step"}',
+                'llmlb_engine_loop_seconds_total{loop="main",bucket="idle"}',
+                'llmlb_engine_programs_built_total{thread="loop"}',
+                'llmlb_engine_compile_seconds_total{stage="backend"}'):
+            assert series in text, series
+    finally:
+        await client.close()
+
+
+async def test_a_capture_shows_the_steps_joined_to_the_records_by_seq(
+        served_engine, tmp_path):
+    """Every step is a `llmlb.step` event on the host plane of a capture,
+    with its spans inside it, and carries the seq of its /api/steps
+    record: joined by that, and by no clock arithmetic."""
+    import glob
+    import os
+
+    import jax
+
+    from llmlb_tpu.engine.profiling import ProfileManager
+
+    engine = served_engine
+    mgr = ProfileManager(trace_root=str(tmp_path))
+    mgr.start(30)
+    first = engine.core.step_stats.seq + 1
+    await _run_requests(engine, n=1)
+    last = engine.core.step_stats.seq
+    done = mgr.stop()
+    found = glob.glob(os.path.join(done["trace_dir"], "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found
+    profile = jax.profiler.ProfileData.from_file(found[0])
+    steps, spans = {}, set()
+    for plane in profile.planes:
+        if not (plane.name or "").startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                if event.name == "llmlb.step":
+                    stats = dict(event.stats)
+                    steps[int(stats["seq"])] = stats["kind"]
+                elif event.name in SPANS:
+                    spans.add(event.name)
+    records = {r["seq"]: r["kind"] for r in _oldest_first(engine.core)
+               if first <= r["seq"] <= last}
+    assert records and {s: k for s, k in steps.items()
+                        if first <= s <= last} == records
+    assert {"dispatch", "compute", "emit", "activate"} <= spans
+
+
 async def test_instrumentation_overhead_under_one_percent(served_engine):
-    """Acceptance: the full per-step recording path (StepRecorder.observe +
+    """Acceptance: the full per-step recording path (the loop clock's
+    switches, the span helper, StepRecorder.observe +
     EngineMetrics.record_step_phases) must cost < 1% of a measured engine
     step. Measured against the CPU debug engine's mean decode step — real
     TPU steps are orders of magnitude longer, so this is the conservative
@@ -213,17 +702,29 @@ async def test_instrumentation_overhead_under_one_percent(served_engine):
     assert hist.n > 0
     mean_step_s = hist.total / hist.n
 
+    # the whole path a decode step takes: the loop's two bucket switches,
+    # the step's five spans (a clock read and an inactive TraceMe each), the
+    # record, the histograms, and back to the loop's bucket
     rec = StepRecorder()
+    clock = LoopClock(rec, "main")
     metrics = EngineMetrics()
-    phases = {"plan": 1e-5, "host_sync": 1e-6, "dispatch": 1e-3,
-              "compute": 1e-4, "fetch": 1e-4, "emit": 1e-4}
     n = 2000
     t0 = time.perf_counter()
     for _ in range(n):
-        slow = rec.observe("decode", phases, active_slots=2, tokens=2)
+        clock.switch("admit")
+        clock.switch("other")
+        step = clock.begin("host_sync")
+        for name in ("dispatch", "compute", "fetch", "emit"):
+            step.mark(name)
+        clock.close(step, "decode")
+        phases = step.phases()
+        slow = rec.observe("decode", phases, active_slots=2, tokens=2,
+                           request_ids={"0": "a", "1": "b"}, span=step)
         metrics.record_step_phases(phases, slow=slow)
+        clock.resume(step)
     per_step = (time.perf_counter() - t0) / n
-    # the timing side (10 perf_counter reads) is OS-cheap; bound the whole
+    assert len(rec.snapshot(limit=1)["records"][0]["spans"]) == 5
+    # the timing side (9 perf_counter reads) is OS-cheap; bound the whole
     # record path against the measured mean step
     assert per_step < 0.01 * mean_step_s, (
         f"instrumentation {per_step * 1e6:.1f}µs/step vs mean step "
@@ -331,6 +832,7 @@ def test_profile_wait_idle_wakes_on_stop_event_not_poll(tmp_path):
     wakes when the capture stops — the last 50 ms poll loop in a request
     path, now notify-based. Regression bound: wake latency well under one
     old poll tick."""
+    import os
     import threading
 
     from llmlb_tpu.engine.profiling import ProfileManager
@@ -340,24 +842,30 @@ def test_profile_wait_idle_wakes_on_stop_event_not_poll(tmp_path):
     mgr.start(30)
     assert mgr.wait_idle(0.01) is False  # recording: the wait parks
 
-    woke_after = {}
+    woke = {}
 
     def waiter():
-        t0 = time.perf_counter()
         assert mgr.wait_idle(10.0) is True
-        woke_after["s"] = time.perf_counter() - t0
+        woke["at"] = time.perf_counter()
+        # idle means written: the capture's files are on disk and it is in
+        # the ledger by the time a waiter wakes (the /debug/profile bug:
+        # idle used to be set before stop_trace had written anything)
+        woke["files"] = sum(len(f) for _r, _d, f in os.walk(str(tmp_path)))
+        woke["captures"] = len(mgr.status()["captures"])
 
     t = threading.Thread(target=waiter)
     t.start()
     time.sleep(0.05)
     t_stop = time.perf_counter()
     mgr.stop()
+    t_stopped = time.perf_counter()
     t.join(timeout=5)
-    stop_s = time.perf_counter() - t_stop
     assert not t.is_alive()
-    # the waiter wakes with the stop itself, not a later poll tick; the
-    # bound subtracts stop_trace's own serialization time
-    assert woke_after["s"] - stop_s < 0.045, (
-        f"wait_idle woke {woke_after['s'] * 1000:.1f}ms after a "
-        f"{stop_s * 1000:.1f}ms stop — still polling?"
+    assert woke["files"] > 0 and woke["captures"] == 1
+    # the waiter wakes with the stop itself — not before the trace is
+    # written, and not a later poll tick after it
+    assert woke["at"] >= t_stop
+    assert woke["at"] - t_stopped < 0.045, (
+        f"wait_idle woke {(woke['at'] - t_stopped) * 1000:.1f}ms after the "
+        f"stop returned — still polling?"
     )
