@@ -822,19 +822,28 @@ def kernel_leg() -> int:
             check("flash_decode", f"B={b},window={window}", got, want[:, 0])
 
         def paged_decode(pages, quant):
-            k_pages, v_pages, (qk, qv), tables = pool(b)
+            # the decode kernels take the pool stacked over layers and read
+            # it at (layer, page): layer 1 of 2 here, layer 0 all zeros (an
+            # int8 pool's scales go in as that layer's slice)
+            def stacked(x):
+                return jnp.stack([jnp.zeros_like(x), x])
+
+            k_pages, v_pages, quant_pools, tables = pool(b)
+            k_pages, v_pages = stacked(k_pages), stacked(v_pages)
+            qk, qv = ({m: stacked(x) for m, x in qp.items()}
+                      for qp in quant_pools)
             lens = jnp.asarray(rng.integers(1, pages * PS + 1, b), jnp.int32)
             if quant:
-                want = xla.paged_attention_decode(q, qk, qv, tables, lens,
+                want = xla.paged_attention_decode(q, qk, qv, 1, tables, lens,
                                                   window=pages * PS)
                 got = pa.paged_flash_decode_quant(
-                    q[:, 0], qk["q"], qk["s"], qv["q"], qv["s"], tables,
-                    lens, pages=pages, interpret=False)
+                    q[:, 0], qk["q"], qk["s"][1], qv["q"], qv["s"][1], 1,
+                    tables, lens, pages=pages, interpret=False)
             else:
-                want = xla.paged_attention_decode(q, k_pages, v_pages,
+                want = xla.paged_attention_decode(q, k_pages, v_pages, 1,
                                                   tables, lens,
                                                   window=pages * PS)
-                got = pa.paged_flash_decode(q[:, 0], k_pages, v_pages,
+                got = pa.paged_flash_decode(q[:, 0], k_pages, v_pages, 1,
                                             tables, lens, pages=pages,
                                             interpret=False)
             check("paged_flash_decode_quant" if quant else

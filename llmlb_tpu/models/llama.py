@@ -314,13 +314,6 @@ def _write_pool_layer(pool, layer_idx, page, off, kv):
     return pool.at[layer_idx, page, off].set(kv.astype(pool.dtype))
 
 
-def _pool_layer(pool, layer_idx):
-    """One layer's slice of the pool (both members when quantized)."""
-    if isinstance(pool, dict):
-        return {"q": pool["q"][layer_idx], "s": pool["s"][layer_idx]}
-    return pool[layer_idx]
-
-
 def make_write_kv_pages(block_tables: jnp.ndarray, page_size: int):
     """KV write that scatters token rows through the block table into the
     global page pool — the paged counterpart of make_write_kv_slots.
@@ -522,8 +515,15 @@ def _decode_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     BEFORE this change; the unrolled loop has no chip time on record).
     Unrolled,
     each layer does one [B,1,K,D] scatter into the donated full cache at a
-    static layer index and reads a static slice for attention, which XLA
-    keeps in place. Decode programs are tiny, so L× code growth is cheap."""
+    static layer index and reads a static slice for attention. XLA keeps
+    that slice in place only for an XLA reader, which it can fuse the slice
+    into; a Pallas kernel takes whole buffers as operands, so under the
+    Pallas dispatch `cache_k[layer_idx]` is expected to be copied on every
+    call, as the paged pool's slice was (PERF.md §6, PR 25: 105 MB a layer,
+    a third of the decode step, until _decode_paged_impl stopped slicing).
+    This dense-slot path has had no chip time since and no benchmark cell,
+    so that copy is unmeasured here. Decode programs are tiny, so L× code
+    growth is cheap."""
     b = input_ids.shape[0]
     capacity = cache_k.shape[2]
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
@@ -871,7 +871,14 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     one-token KV lands at page block_tables[b, pos//PS], offset pos%PS;
     freed/parked rows clamp into their own last cell or the trash page
     (their block-table rows are zeroed on free), so garbage writes can
-    never land in a page another row owns."""
+    never land in a page another row owns.
+
+    Attention gets the whole stacked pool [L, P, PS, K, D] and the layer
+    index, never `pool[layer_idx]`: the Pallas kernel addresses the pool at
+    (layer, page) and reads it in place, so the only pool-sized value in the
+    program is the donated pool itself, updated by each layer's [B, K, D]
+    scatter. No per-layer slice is materialized
+    (tests/test_decode_program_structure.py holds that)."""
     from llmlb_tpu.ops.attention import paged_attention_decode
 
     b = input_ids.shape[0]
@@ -897,8 +904,7 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
             cache_v = _write_pool_layer(cache_v, layer_idx, page, off,
                                         v[:, 0])
             return paged_attention_decode(
-                q, _pool_layer(cache_k, layer_idx),
-                _pool_layer(cache_v, layer_idx), block_tables,
+                q, cache_k, cache_v, layer_idx, block_tables,
                 write_pos + 1, window=window,
             )
 

@@ -179,17 +179,26 @@ def _pool_shape(pages):
 
 def paged_attention_decode(
     q: jnp.ndarray,  # [B, 1, H, D]
-    k_pages,  # [P, PS, K, D] pool, or quantized {"q","s"} pair
-    v_pages,  # [P, PS, K, D]
+    k_pages,  # [L, P, PS, K, D] stacked pool, or quantized {"q","s"} pair
+    v_pages,  # [L, P, PS, K, D]
+    layer,  # int32 scalar — the layer of the pool to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
     kv_lens: jnp.ndarray,  # [B] int32 — valid logical length per row
     window: int | None = None,  # static: read only the first `window` cells
 ) -> jnp.ndarray:
-    """One-token decode attention against the PAGED KV pool. Same contract
-    as gqa_attention_decode — `window` (STATIC) bounds the logical sweep,
-    rounded up to whole pages; rows with kv_lens beyond the swept pages
-    produce garbage the caller must discard (parked/freed slot rows)."""
-    ps = _pool_shape(k_pages)[1]
+    """One-token decode attention against one layer of the PAGED KV pool.
+    Same contract as gqa_attention_decode — `window` (STATIC) bounds the
+    logical sweep, rounded up to whole pages; rows with kv_lens beyond the
+    swept pages produce garbage the caller must discard (parked/freed slot
+    rows).
+
+    The pool arrives STACKED over layers, with the layer index beside it:
+    the Pallas kernels address it at (layer, page) and read it in place,
+    where a `pool[layer]` operand would be copied whole on every call (an
+    int8 pool's scales are the exception, and the smaller part: see
+    paged_flash_decode_quant). The XLA fallback (CPU tests, partitioned
+    meshes) slices the layer here."""
+    ps = _pool_shape(k_pages)[2]
     ppn = block_tables.shape[1]
     pages = ppn if window is None else max(1, min(ppn, -(-window // ps)))
     if _pallas_enabled():
@@ -198,19 +207,24 @@ def paged_attention_decode(
 
             _traced["paged_decode"] = "pallas:paged_flash_decode_quant"
             return paged_flash_decode_quant(
-                q[:, 0], k_pages["q"], k_pages["s"], v_pages["q"],
-                v_pages["s"], block_tables, kv_lens, pages=pages,
+                q[:, 0], k_pages["q"], k_pages["s"][layer], v_pages["q"],
+                v_pages["s"][layer], layer, block_tables, kv_lens,
+                pages=pages,
             )[:, None]
         from llmlb_tpu.ops.pallas_attention import paged_flash_decode
 
         _traced["paged_decode"] = "pallas:paged_flash_decode"
         return paged_flash_decode(
-            q[:, 0], k_pages, v_pages, block_tables, kv_lens, pages=pages
+            q[:, 0], k_pages, v_pages, layer, block_tables, kv_lens,
+            pages=pages,
         )[:, None]
     _traced["paged_decode"] = "xla"
     tables = block_tables[:, :pages] if pages < ppn else block_tables
-    k_cache = gather_kv_pages(k_pages, tables, dtype=q.dtype)
-    v_cache = gather_kv_pages(v_pages, tables, dtype=q.dtype)
+    # an XLA reader may slice: the slice fuses into the gather that reads it
+    k_layer, v_layer = jax.tree.map(lambda pool: pool[layer],
+                                    (k_pages, v_pages))
+    k_cache = gather_kv_pages(k_layer, tables, dtype=q.dtype)
+    v_cache = gather_kv_pages(v_layer, tables, dtype=q.dtype)
     return gqa_attention_decode(q, k_cache, v_cache, kv_lens)
 
 

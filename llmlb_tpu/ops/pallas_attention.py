@@ -195,23 +195,49 @@ def flash_decode(
 
 
 # ---------------------------------------------------------------------------
-# Paged decode: q [B, H, D] vs page pool [P, PS, K, D], block tables [B, PPN]
+# Paged decode: q [B, H, D] vs the stacked page pool [L, P, PS, K, D] at one
+# layer, block tables [B, PPN]
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(block_tables_ref, kv_lens_ref, *refs, **kw):
-    """Same online-softmax sweep as _decode_kernel; the block-table ref is
-    consumed by the BlockSpec index_map (it picks which POOL page each grid
-    step DMAs), so the body only needs the ragged lengths."""
-    del block_tables_ref
+def _paged_decode_kernel(layer_ref, block_tables_ref, kv_lens_ref, *refs,
+                         **kw):
+    """Same online-softmax sweep as _decode_kernel; the layer and block-table
+    refs are consumed by the BlockSpec index_map (they pick which POOL page
+    of which layer each grid step DMAs), so the body only needs the ragged
+    lengths."""
+    del layer_ref, block_tables_ref
     _decode_kernel(kv_lens_ref, *refs, **kw)
+
+
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as a [1] int32 scalar-prefetch operand: a run-time
+    value in SMEM, so every layer of a decode program lowers to the same
+    kernel."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _pool_page_map(bi, si, layer, tables, lens):
+    """KV values [L, P, PS, K, D]: page tables[b, i] of the layer."""
+    return (layer[0], tables[bi, si], 0, 0, 0)
+
+
+def _layer_scale_map(bi, si, layer, tables, lens):
+    """KV scales [P, PS, K] of one layer of an int8 pool: the same page."""
+    return (tables[bi, si], 0, 0)
+
+
+def _row_map(bi, si, layer, tables, lens):
+    """q and out [B, K, G, D]: row b, for every page of the sweep."""
+    return (bi, 0, 0, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
 def paged_flash_decode(
     q: jnp.ndarray,  # [B, H, D]
-    k_pages: jnp.ndarray,  # [P, PS, K, D] — global page pool
-    v_pages: jnp.ndarray,  # [P, PS, K, D]
+    k_pages: jnp.ndarray,  # [L, P, PS, K, D] — global page pool, all layers
+    v_pages: jnp.ndarray,  # [L, P, PS, K, D]
+    layer,  # int32 scalar — the layer of the pool to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32 — logical page i of row b
     kv_lens: jnp.ndarray,  # [B] int32 — valid logical length per row
     *,
@@ -221,48 +247,40 @@ def paged_flash_decode(
     """Ragged PAGED one-token GQA decode attention. Returns [B, H, D].
 
     The grid is (batch, logical_page) and the KV BlockSpec index_map gathers
-    each step's page THROUGH the prefetched block table
-    (`block_tables[b, i]` picks the pool row to DMA) — attention reads the
-    scattered pool directly, no contiguous per-row copy is ever
-    materialized. `pages` plays the role of flash_decode's `window`: the
-    sweep stops after that many logical pages and rows whose kv_lens extend
-    beyond produce garbage the caller must discard (parked/freed slot rows).
+    each step's page THROUGH the prefetched layer index and block table
+    (`(layer, block_tables[b, i])` picks the pool page to DMA) — attention
+    reads the scattered STACKED pool in place. Neither a contiguous per-row
+    copy nor a per-layer slice `pool[layer]` is ever materialized: a
+    pallas_call takes whole buffers as operands, so handing it a slice makes
+    XLA copy one layer of the pool (105 MB at 400 pages of Mistral-7B width)
+    per call. `layer` is an operand, not a Python constant, so the layers of
+    an unrolled decode program share one kernel. `pages` plays the role of
+    flash_decode's `window`: the sweep stops after that many logical pages
+    and rows whose kv_lens extend beyond produce garbage the caller must
+    discard (parked/freed slot rows).
     """
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
-    ps = k_pages.shape[1]
-    num_kv = k_pages.shape[2]
+    _, _, ps, num_kv, _ = k_pages.shape
     g = h // num_kv
     ppn = block_tables.shape[1]
     sweep = ppn if pages is None else max(1, min(pages, ppn))
     qg = q.reshape(b, num_kv, g, d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, sweep),
         in_specs=[
-            pl.BlockSpec(
-                (1, num_kv, g, d),
-                lambda bi, si, tables, lens: (bi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, ps, num_kv, d),
-                lambda bi, si, tables, lens: (tables[bi, si], 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, ps, num_kv, d),
-                lambda bi, si, tables, lens: (tables[bi, si], 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            pl.BlockSpec((1, num_kv, g, d), _row_map,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
+                         memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec(
-            (1, num_kv, g, d),
-            lambda bi, si, tables, lens: (bi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
+        out_specs=pl.BlockSpec((1, num_kv, g, d), _row_map,
+                               memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((num_kv, g, 1), jnp.float32),
             pltpu.VMEM((num_kv, g, 1), jnp.float32),
@@ -276,8 +294,8 @@ def paged_flash_decode(
         out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      qg, k_pages, v_pages)
+    )(_layer_operand(layer), block_tables.astype(jnp.int32),
+      kv_lens.astype(jnp.int32), qg, k_pages, v_pages)
     return out.reshape(b, h, d)
 
 
@@ -290,6 +308,7 @@ def paged_flash_decode(
 
 
 def _paged_decode_quant_kernel(
+    layer_ref,  # consumed by the index maps
     block_tables_ref,  # consumed by the index maps
     kv_lens_ref,  # [B] int32 (SMEM)
     q_ref,  # [1, K, G, D]
@@ -304,7 +323,7 @@ def _paged_decode_quant_kernel(
     num_kv: int,
     scale: float,
 ):
-    del block_tables_ref
+    del layer_ref, block_tables_ref
     b = pl.program_id(0)
     s = pl.program_id(1)
     num_blocks = pl.num_programs(1)
@@ -345,10 +364,11 @@ def _paged_decode_quant_kernel(
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
 def paged_flash_decode_quant(
     q: jnp.ndarray,  # [B, H, D]
-    k_pages: jnp.ndarray,  # [P, PS, K, D] int8
-    k_scales: jnp.ndarray,  # [P, PS, K] f32 — per written K vector
-    v_pages: jnp.ndarray,  # [P, PS, K, D] int8
+    k_pages: jnp.ndarray,  # [L, P, PS, K, D] int8 — all layers
+    k_scales: jnp.ndarray,  # [P, PS, K] f32 — THE LAYER'S, per written K vector
+    v_pages: jnp.ndarray,  # [L, P, PS, K, D] int8
     v_scales: jnp.ndarray,  # [P, PS, K] f32
+    layer,  # int32 scalar — the layer of the value pools to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
     kv_lens: jnp.ndarray,  # [B] int32
     *,
@@ -357,46 +377,43 @@ def paged_flash_decode_quant(
 ) -> jnp.ndarray:
     """Int8 variant of paged_flash_decode: dequant-on-read inside the
     kernel. Same grid/garbage contract; numerics match the XLA dequant
-    fallback (f32 dequant -> q.dtype operands -> f32 accumulation)."""
+    fallback (f32 dequant -> q.dtype operands -> f32 accumulation).
+
+    The VALUES follow paged_flash_decode's stacked-pool contract: read in
+    place at `(layer, page)`, never sliced. The SCALES arrive as the layer's
+    slice `scales[layer]`, and have to: Mosaic wants an operand row-major,
+    while XLA keeps an f32 [.., PS, K] array with K = 8 heads PS-minor (the
+    other way pads K to 128 lanes, 16x the bytes), so a scale operand is
+    re-laid-out on every call whatever its rank. On one layer that writes
+    26 MB at 400 pages of 128; on the stacked array it writes all L layers
+    every call (compiled for a v5e: 32 whole-array copies a decode step,
+    PERF.md §6, PR 25)."""
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
-    ps = k_pages.shape[1]
-    num_kv = k_pages.shape[2]
+    _, _, ps, num_kv, _ = k_pages.shape
     g = h // num_kv
     ppn = block_tables.shape[1]
     sweep = ppn if pages is None else max(1, min(pages, ppn))
     qg = q.reshape(b, num_kv, g, d)
 
-    def page_map(bi, si, tables, lens):
-        return (tables[bi, si], 0, 0, 0)
-
-    def scale_map(bi, si, tables, lens):
-        return (tables[bi, si], 0, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, sweep),
         in_specs=[
-            pl.BlockSpec(
-                (1, num_kv, g, d),
-                lambda bi, si, tables, lens: (bi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec((1, ps, num_kv, d), page_map,
+            pl.BlockSpec((1, num_kv, g, d), _row_map,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv), scale_map,
+            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv, d), page_map,
+            pl.BlockSpec((1, ps, num_kv), _layer_scale_map,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv), scale_map,
+            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, ps, num_kv), _layer_scale_map,
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec(
-            (1, num_kv, g, d),
-            lambda bi, si, tables, lens: (bi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
+        out_specs=pl.BlockSpec((1, num_kv, g, d), _row_map,
+                               memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((num_kv, g, 1), jnp.float32),
             pltpu.VMEM((num_kv, g, 1), jnp.float32),
@@ -411,8 +428,8 @@ def paged_flash_decode_quant(
         out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      qg, k_pages, k_scales, v_pages, v_scales)
+    )(_layer_operand(layer), block_tables.astype(jnp.int32),
+      kv_lens.astype(jnp.int32), qg, k_pages, k_scales, v_pages, v_scales)
     return out.reshape(b, h, d)
 
 

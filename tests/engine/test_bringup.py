@@ -123,9 +123,9 @@ def test_attention_dispatch_records_what_it_resolved():
     from llmlb_tpu.ops import attention
 
     q = jnp.zeros((1, 1, 4, 8), jnp.float32)
-    pages = jnp.zeros((3, 4, 2, 8), jnp.float32)
+    pages = jnp.zeros((2, 3, 4, 2, 8), jnp.float32)  # [L, P, PS, K, D]
     attention.paged_attention_decode(
-        q, pages, pages, jnp.zeros((1, 2), jnp.int32),
+        q, pages, pages, 1, jnp.zeros((1, 2), jnp.int32),
         jnp.ones((1,), jnp.int32))
     assert attention.attention_mode() == "xla"  # CPU backend
     assert attention.traced_routes()["paged_decode"] == "xla"

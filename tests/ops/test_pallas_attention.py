@@ -24,6 +24,7 @@ from llmlb_tpu.ops.pallas_attention import (
     paged_flash_decode,
     paged_flash_extend,
 )
+from tests.ops.pools import stacked_pool as _stacked
 
 
 @pytest.fixture(autouse=True)
@@ -139,6 +140,7 @@ def _paged_fixture(key, b, h, kv, d, page_size, pages_per_seq):
     return k_pages, v_pages, tables
 
 
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize(
     "b,h,kv,d,page_size,pages_per_seq",
     [
@@ -148,9 +150,10 @@ def _paged_fixture(key, b, h, kv, d, page_size, pages_per_seq):
     ],
 )
 def test_paged_flash_decode_matches_dense(b, h, kv, d, page_size,
-                                          pages_per_seq):
-    """The paged kernel gathering KV through the block table must equal the
-    dense kernel over the materialized (gathered) cache."""
+                                          pages_per_seq, layer):
+    """The paged kernel gathering KV through the layer index and the block
+    table must equal the dense kernel over the materialized (gathered)
+    cache of that layer."""
     keys = jax.random.split(jax.random.PRNGKey(10), 3)
     cap = page_size * pages_per_seq
     q = _rand(keys[0], (b, 1, h, d))
@@ -162,12 +165,14 @@ def test_paged_flash_decode_matches_dense(b, h, kv, d, page_size,
     v_cache = gather_kv_pages(v_pages, tables)
     expected = gqa_attention_decode(q, k_cache, v_cache, kv_lens)
     got = paged_flash_decode(
-        q[:, 0], k_pages, v_pages, tables, kv_lens, interpret=True
+        q[:, 0], _stacked(k_pages, layer), _stacked(v_pages, layer), layer,
+        tables, kv_lens, interpret=True
     )
     np.testing.assert_allclose(got, expected[:, 0], rtol=2e-5, atol=2e-5)
 
 
-def test_paged_flash_decode_page_window():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_flash_decode_page_window(layer):
     """`pages` bounds the sweep exactly like flash_decode's `window`: rows
     within the swept pages are exact."""
     b, h, kv, d, ps, ppn = 2, 4, 2, 16, 16, 4
@@ -179,13 +184,15 @@ def test_paged_flash_decode_page_window():
     k_cache = gather_kv_pages(k_pages, tables[:, :2])
     v_cache = gather_kv_pages(v_pages, tables[:, :2])
     expected = gqa_attention_decode(q, k_cache, v_cache, kv_lens)
+    k_pool, v_pool = _stacked(k_pages, layer), _stacked(v_pages, layer)
     got = paged_flash_decode(
-        q[:, 0], k_pages, v_pages, tables, kv_lens, pages=2, interpret=True
+        q[:, 0], k_pool, v_pool, layer, tables, kv_lens, pages=2,
+        interpret=True
     )
     np.testing.assert_allclose(got, expected[:, 0], rtol=2e-5, atol=2e-5)
     # the dispatcher derives the page count from a token window
     got2 = paged_attention_decode(
-        q, k_pages, v_pages, tables, kv_lens, window=2 * ps
+        q, k_pool, v_pool, layer, tables, kv_lens, window=2 * ps
     )
     np.testing.assert_allclose(got2, expected, rtol=2e-5, atol=2e-5)
 

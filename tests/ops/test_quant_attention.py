@@ -18,6 +18,7 @@ from llmlb_tpu.ops.pallas_attention import (
     paged_flash_extend_quant,
 )
 from llmlb_tpu.quant import quantize_kv
+from tests.ops.pools import stacked_pool as _stacked
 
 B, H, K, D, P, PS, PPN = 2, 8, 4, 16, 9, 8, 4
 TOL = 0.05
@@ -45,12 +46,17 @@ def test_gather_kv_pages_dequantizes():
                   - np.asarray(dense)).max() < TOL
 
 
-def test_paged_decode_xla_parity():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_decode_xla_parity(layer):
     k_pages, v_pages, qk, qv, tables, rng = _pools(1)
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
     kv_lens = jnp.asarray([PS * 3, PS * 2], jnp.int32)
-    base = paged_attention_decode(q, k_pages, v_pages, tables, kv_lens)
-    quant = paged_attention_decode(q, qk, qv, tables, kv_lens)
+    base = paged_attention_decode(q, _stacked(k_pages, layer),
+                                  _stacked(v_pages, layer), layer, tables,
+                                  kv_lens)
+    quant = paged_attention_decode(q, _stacked(qk, layer),
+                                   _stacked(qv, layer), layer, tables,
+                                   kv_lens)
     assert np.abs(np.asarray(base) - np.asarray(quant,
                                                 np.float32)).max() < TOL
 
@@ -69,41 +75,48 @@ def test_paged_extend_xla_parity():
                                                 np.float32)).max() < TOL
 
 
-def test_paged_flash_decode_quant_interpret_parity():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_flash_decode_quant_interpret_parity(layer):
     """Interpret-mode kernel vs both the bf16 kernel (tolerance) and the
     XLA dequant route (the two quantized paths read identical cells)."""
     k_pages, v_pages, qk, qv, tables, rng = _pools(3)
+    qk, qv = _stacked(qk, layer), _stacked(qv, layer)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     kv_lens = jnp.asarray([PS * 3 - 2, PS + 3], jnp.int32)
-    base = paged_flash_decode(q, k_pages, v_pages, tables, kv_lens,
-                              interpret=True)
+    base = paged_flash_decode(q, _stacked(k_pages, layer),
+                              _stacked(v_pages, layer), layer, tables,
+                              kv_lens, interpret=True)
     quant = paged_flash_decode_quant(
-        q, qk["q"], qk["s"], qv["q"], qv["s"], tables, kv_lens,
-        interpret=True,
+        q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer, tables,
+        kv_lens, interpret=True,
     )
     assert np.abs(np.asarray(base) - np.asarray(quant)).max() < TOL
 
     # both quantized routes dequant to q.dtype before the dots, so they
     # differ only by online- vs plain-softmax accumulation order
-    xla = paged_attention_decode(q[:, None], qk, qv, tables, kv_lens)[:, 0]
+    xla = paged_attention_decode(q[:, None], qk, qv, layer, tables,
+                                 kv_lens)[:, 0]
     assert np.abs(np.asarray(quant)
                   - np.asarray(xla, np.float32)).max() < 2e-3
 
 
-def test_paged_flash_decode_quant_respects_pages_window():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_flash_decode_quant_respects_pages_window(layer):
     """Rows within the swept pages stay exact when the sweep is bounded —
     the dequant variant must keep flash_decode's window contract."""
-    k_pages, v_pages, qk, qv, tables, rng = _pools(4)
+    _, _, qk, qv, tables, rng = _pools(4)
+    qk, qv = _stacked(qk, layer), _stacked(qv, layer)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     kv_lens = jnp.asarray([PS * 2, PS], jnp.int32)  # within 2 pages
     full = paged_flash_decode_quant(
-        q, qk["q"], qk["s"], qv["q"], qv["s"], tables, kv_lens,
-        interpret=True,
+        q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer, tables,
+        kv_lens, interpret=True,
     )
     windowed = paged_flash_decode_quant(
-        q, qk["q"], qk["s"], qv["q"], qv["s"], tables, kv_lens, pages=2,
-        interpret=True,
+        q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer, tables,
+        kv_lens, pages=2, interpret=True,
     )
+    assert np.abs(np.asarray(full)).max() < 10  # a mix of this layer's V
     np.testing.assert_allclose(np.asarray(full), np.asarray(windowed),
                                atol=1e-6)
 
@@ -138,8 +151,11 @@ def test_quantized_pool_means_quantized_kernel(route, monkeypatch):
     if route == "decode":
         q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
         kv_lens = jnp.asarray([PS, PS], jnp.int32)
-        out = attn.paged_attention_decode(q, qk, qv, tables, kv_lens)
-        ref = attn.paged_attention_decode(q, k_pages, v_pages, tables,
+        out = attn.paged_attention_decode(q, _stacked(qk, 1),
+                                          _stacked(qv, 1), 1, tables,
+                                          kv_lens)
+        ref = attn.paged_attention_decode(q, _stacked(k_pages, 1),
+                                          _stacked(v_pages, 1), 1, tables,
                                           kv_lens)
     else:
         q = jnp.asarray(rng.normal(size=(B, 3, H, D)), jnp.float32)

@@ -1,0 +1,179 @@
+"""The paged decode program reads the stacked KV pool in place.
+
+A `pallas_call` takes whole buffers as operands, so a decode program that
+hands the attention kernel `pool[layer]` makes XLA copy one layer of the pool
+per call: on the chip that was 105 MB per layer, K and V, a third of the
+decode step (PERF.md §6, PR 25). The program now passes the 5-D pool and a
+layer index, and these tests keep a later refactor from bringing the slice
+back: in the program as traced (the jaxpr), and in the program as the TPU's
+compiler leaves it for a described v5e. Its time is the benchmark's to show."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from llmlb_tpu.models import llama, mixtral
+from llmlb_tpu.ops import pallas_attention
+
+LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM = 3, 7, 8, 2, 16
+ROWS, PAGES_PER_ROW = 2, 3
+DIMS = dict(vocab_size=96, hidden_size=64, intermediate_size=80,
+            num_layers=LAYERS, num_heads=4, num_kv_heads=KV_HEADS,
+            dtype=jnp.float32)
+FAMILIES = {
+    "llama": (llama, llama.LlamaConfig(**DIMS)),
+    "mixtral": (mixtral, mixtral.MixtralConfig(
+        **DIMS, num_experts=4, experts_per_token=2)),
+}
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it (jit, scan,
+    cond …), except the bodies of Pallas kernels: their refs are blocks."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _decode_jaxpr(family, cfg, quantized, monkeypatch):
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")  # read while tracing
+    family.decode_step_paged._clear_cache()
+    params = jax.eval_shape(lambda key: family.init_params(cfg, key),
+                            jax.random.PRNGKey(0))
+    cache_k, cache_v = jax.eval_shape(
+        lambda: family.init_kv_pages(cfg, PAGES, PAGE_SIZE,
+                                     quantized=quantized))
+    rows = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((ROWS, PAGES_PER_ROW), jnp.int32)
+    try:
+        closed = jax.make_jaxpr(
+            lambda p, ids, lens, ck, cv, t: family.decode_step_paged(
+                p, cfg, ids, lens, ck, cv, t, window=2 * PAGE_SIZE)
+        )(params, rows, rows, cache_k, cache_v, tables)
+    finally:
+        family.decode_step_paged._clear_cache()  # traced with the env set
+    return closed.jaxpr
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
+                                                        monkeypatch):
+    family, cfg = FAMILIES[name]
+    values = (LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM)
+    layer_scales = values[1:-1]  # [P, PS, K]
+    # What each kernel must be given: K and V values whole. An int8 pool's
+    # scales go in as the layer's slice: an f32 operand with 8 heads minor
+    # is re-laid-out for Mosaic whatever its rank, which is cheapest on one
+    # layer (paged_flash_decode_quant's docstring). They are the only
+    # per-layer piece of the pool the program may hold.
+    kernel_operands = [values, values] + [layer_scales] * (2 * quantized)
+    never = {values[1:]} | (set() if quantized else {layer_scales})
+
+    eqns = list(_equations(_decode_jaxpr(family, cfg, quantized,
+                                         monkeypatch)))
+    sliced = [str(eqn) for eqn in eqns
+              if any(getattr(v.aval, "shape", None) in never
+                     for v in eqn.outvars)]
+    assert not sliced, f"a per-layer slice of the pool is back: {sliced}"
+
+    kernels = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"]
+    assert len(kernels) == LAYERS
+    q_rows = (ROWS, KV_HEADS, 2, HEAD_DIM)  # [B, K, G, D]
+    for eqn in kernels:
+        shapes = [v.aval.shape for v in eqn.invars]
+        assert sorted(s for s in shapes if len(s) >= 3) == sorted(
+            kernel_operands + [q_rows]), shapes
+
+
+# --- the same program as the chip's compiler leaves it ----------------------
+#
+# The TPU compiler is installed here and compiles for a chip that is described,
+# not attached. Only the test that needs it describes the topology (one process
+# at a time may load libtpu), so the call lives in a fixture of this file.
+
+CHIP_PAGES, CHIP_PAGE_SIZE, CHIP_ROWS, CHIP_TABLE = 400, 128, 32, 16
+CHIP_CFG = llama.LlamaConfig(  # Mistral-7B's widths, two layers deep
+    vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+    num_layers=2, num_heads=32, num_kv_heads=8, rope_theta=1e6,
+    tie_word_embeddings=False)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
+                                                          monkeypatch):
+    """Two decode steps under a scan, as the engine's burst program runs
+    them, compiled for a v5e at the benchmark cell's pool and widths: the
+    compiler materializes no per-layer piece of the value pool and copies no
+    value pool whole. (At the parent of PR 25 this program held a 105 MB
+    `bf16[400,128,8,128]` fusion per layer for K and for V.)"""
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    # the backend in this process is the CPU; the program under test is the
+    # chip's, so the kernels lower through Mosaic
+    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
+    jitted = (llama.decode_step_paged, pallas_attention.paged_flash_decode,
+              pallas_attention.paged_flash_decode_quant)
+    for fn in jitted:  # traced before with the interpreter or the XLA path
+        fn._clear_cache()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: llama.init_params(CHIP_CFG, key), jax.random.PRNGKey(0)))
+    cache_k, cache_v = on_chip(jax.eval_shape(
+        lambda: llama.init_kv_pages(CHIP_CFG, CHIP_PAGES, CHIP_PAGE_SIZE,
+                                    quantized=quantized)))
+    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
+    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
+
+    def burst(params, last, lens, cache_k, cache_v, tables):
+        def body(carry, _):
+            last, lens, ck, cv = carry
+            logits, ck, cv = llama.decode_step_paged(
+                params, CHIP_CFG, last, lens, ck, cv, tables, window=512)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    ck, cv), None
+
+        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
+                            length=2)[0]
+
+    try:
+        # conftest.py asks for float32 matmuls; the chip's program has bf16
+        # operands, and Mosaic refuses a float32 contraction over them
+        with jax.default_matmul_precision("default"):
+            hlo = jax.jit(burst, donate_argnums=(3, 4)).lower(
+                params, rows, rows, cache_k, cache_v, tables
+            ).compile().as_text()
+    finally:
+        for fn in jitted:
+            fn._clear_cache()
+
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2  # a layer
+    layer_values = r"(bf16|s8)\[400,128,8,128\]"
+    whole_pool = r"(bf16|s8)\[2,400,128,8,128\]"  # the values; an int8
+    # pool's scales are re-laid-out at the loop's ends at either commit
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    bad = [(shape, op) for shape, op in results
+           if re.match(layer_values, shape)
+           or (op == "copy" and re.match(whole_pool, shape))]
+    assert not bad, bad
