@@ -108,18 +108,22 @@ class StepCollector(threading.Thread):
 
 
 def build_cfg(config: dict):
+    """The program's configuration object for a published `config.json`,
+    asked of the program as a deployment asks it: `load_config` reads the
+    model directory's `config.json` and picks the class. The harness names
+    no architecture; a new one is the program's to know."""
+    import tempfile
+
     import jax.numpy as jnp
 
-    hf = config
+    from llmlb_tpu.engine.weights import load_config
+
     dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-        hf.get("torch_dtype", "bfloat16")]
-    if hf.get("model_type") == "mixtral":
-        from llmlb_tpu.models.mixtral import MixtralConfig
-
-        return MixtralConfig.from_hf_config(hf, dtype)
-    from llmlb_tpu.models.llama import LlamaConfig
-
-    return LlamaConfig.from_hf_config(hf, dtype)
+        config.get("torch_dtype", "bfloat16")]
+    with tempfile.TemporaryDirectory(prefix="bench-config-") as model_dir:
+        with open(os.path.join(model_dir, "config.json"), "w") as f:
+            json.dump(config, f)
+        return load_config(model_dir, dtype)
 
 
 def mesh_config_for(cfg, n_devices: int):
@@ -159,6 +163,8 @@ def make_params(family, cfg, seed: int, mesh):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
+    ap.add_argument("--base", default=ROOT,
+                    help="the directory of the manifest that lists --config")
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--port", type=int, required=True)
     ap.add_argument("--chips", type=int, default=1)
@@ -195,7 +201,7 @@ def main() -> int:
     from llmlb_tpu.engine.service import Engine
     from llmlb_tpu.models import family_for
 
-    from benchmark import correctness, trace as trace_mod
+    from benchmark import correctness, reference, trace as trace_mod
     from benchmark.tokenizer import WordTokenizer
 
     cfg = build_cfg(config)
@@ -213,7 +219,8 @@ def main() -> int:
     t = time.monotonic()
     correct = correctness.check(family, cfg, params, config,
                                 config["correctness"], args.seed,
-                                int(eng.get("kv_page_size", 128)))
+                                int(eng.get("kv_page_size", 128)),
+                                reference.module_for(config, args.base))
     split["correctness_s"] = time.monotonic() - t
     note(f"correctness: {json.dumps(correct)}")
 

@@ -3,8 +3,9 @@
 The harness lists no cell, configuration or metric in code. Everything is
 found from the manifest by name: a configuration's `file`, a cell's traffic
 mix at `benchmark/traffic/<traffic>.json`, an end-to-end metric's function at
-`benchmark/e2e_metrics/<name>.py` and a per-layer metric's reader at
-`benchmark/layer_metrics/<name>.py`.
+`benchmark/e2e_metrics/<name>.py`, a per-layer metric's reader at
+`benchmark/layer_metrics/<name>.py`, and an architecture's plain reference at
+`benchmark/reference/<name>.py`, named by the configuration's file.
 """
 
 from __future__ import annotations
@@ -85,11 +86,15 @@ def metrics_for(manifest: dict, section: str, cell_name: str) -> list[dict]:
             if "workloads" not in m or cell_name in m["workloads"]]
 
 
-def load_module(kind: str, name: str):
+def load_module(kind: str, name: str, base: str = ROOT):
     """The module `benchmark/<kind>/<name>.py`, found by the metric's (or
-    generator's, or kernel's) name; names may hold dots, so it is loaded
-    from its path and not imported."""
-    path = os.path.join(HERE, kind, name + ".py")
+    generator's, kernel's or reference's) name; names may hold dots, so it
+    is loaded from its path and not imported. Like a traffic mix, it is
+    taken from beside a rehearsal's manifest (`base`) if that has one of the
+    name, else from the benchmark's own."""
+    path = os.path.join(base, kind, name + ".py")
+    if base == ROOT or not os.path.isfile(path):
+        path = os.path.join(HERE, kind, name + ".py")
     if not os.path.isfile(path):
         raise ManifestError(f"{kind[:-1] if kind.endswith('s') else kind} "
                             f"{name!r} has no file {os.path.relpath(path, ROOT)}")

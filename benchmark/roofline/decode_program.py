@@ -20,13 +20,16 @@ def work(config: dict, engine: dict, *, live_tokens: float, rows: float) -> dict
         0 if hf.get("tie_word_embeddings") else embed_bytes)
     kv_bytes = live_tokens * layers * kv_heads * head_dim * 2 * itemsize
     # operations: 2 per parameter a token's forward pass multiplies by (for a
-    # mixture, the experts_per_token of num_local_experts it is routed to),
-    # plus attention over the live context
-    experts = hf.get("num_local_experts", 0)
+    # mixture, the experts per token of the experts it is routed among: their
+    # number under either key the published configs use, as the program's own
+    # `from_hf_config` reads it, their width `moe_intermediate_size` where a
+    # config states one), plus attention over the live context
+    experts = hf.get("num_local_experts", hf.get("num_experts", 0))
     n_params = engine["n_params"] - vocab * hidden * (
         0 if hf.get("tie_word_embeddings") else 1)
     if experts:
-        expert_params = layers * experts * 3 * hidden * hf["intermediate_size"]
+        expert_params = layers * experts * 3 * hidden * hf.get(
+            "moe_intermediate_size", hf["intermediate_size"])
         n_params -= expert_params * (1 - hf["num_experts_per_tok"] / experts)
     flops = 2 * n_params * rows + 4 * live_tokens * layers * heads * head_dim
     return {"flops": flops, "bytes": weight_bytes + kv_bytes}

@@ -74,7 +74,8 @@ class Ctx:
 
     async def send(self, messages: list[dict], max_tokens: int, *,
                    due_s: float, prompt_tokens: int, in_sample: bool,
-                   kind: str = "request", on_first=None) -> dict:
+                   kind: str = "request", on_first=None,
+                   on_words=None) -> dict:
         import aiohttp
 
         self._n += 1
@@ -127,6 +128,8 @@ class Ctx:
                                 on_first()
                         rec["last_s"] = t
                         rec["words"] += n
+                        if on_words is not None:
+                            on_words(rec["words"])
                         if 0 <= t < self.seconds:
                             self.window_tokens += n
                         parts.append(text)
@@ -359,6 +362,7 @@ async def run_cell(args, cell: dict, config: dict, config_path: str,
     gateway = f"http://127.0.0.1:{gateway_port}"
     launcher_argv = [sys.executable, os.path.join(HERE, "launcher.py"),
                      "--config", config_path, "--seed", str(args.seed),
+                     "--base", os.path.dirname(os.path.abspath(args.manifest)),
                      "--port", str(engine_port), "--chips", str(cell["chips"]),
                      "--platform", platform,
                      "--trace-dir", os.path.join(run_dir, "trace")]

@@ -125,16 +125,17 @@ def test_fixed_pairs_keep_both_multisets_and_use_no_seed():
 
 
 @pytest.mark.parametrize("a,b", SEEDS)
-def test_closed_loop_deals_out_the_same_lengths_and_starts(a, b):
+def test_closed_loop_deals_out_the_same_lengths(a, b):
     traffic = mf.load_traffic("decode-saturated")
     pa = closed_loop.client_plans(traffic, a, 32000)
     pb = closed_loop.client_plans(traffic, b, 32000)
     assert len(pa) == traffic["clients"] == 32
     flat = lambda ps: sorted(n for p in ps for n in p["prompt_tokens"])
     assert flat(pa) == flat(pb)
-    assert sorted(p["start_s"] for p in pa) == sorted(p["start_s"] for p in pb)
-    assert [p["start_s"] for p in pa] != [p["start_s"] for p in pb]
-    assert all(-traffic["ramp_s"] <= p["start_s"] < 0 for p in pa)
+    assert [p["prompt_tokens"] for p in pa] != [p["prompt_tokens"] for p in pb]
+    assert pa == closed_loop.client_plans(traffic, a, 32000)
+    # the stagger is the server's progress, not a clock's grid (PERF.md, PR 26)
+    assert traffic["start_after_tokens"] == 2
 
 
 @pytest.mark.parametrize("a,b", SEEDS)
@@ -167,6 +168,7 @@ class FakeCtx:
         self.t0 = time.monotonic() + float(traffic.get("ramp_s", 0))
         self.sent = []
         self.service_s = service_s
+        self.fail_first = False
 
     def now(self):
         return time.monotonic() - self.t0
@@ -175,13 +177,19 @@ class FakeCtx:
         await asyncio.sleep(max(0.0, t - self.now()))
 
     async def send(self, messages, max_tokens, *, due_s, prompt_tokens,
-                   in_sample, kind="request", on_first=None):
+                   in_sample, kind="request", on_first=None, on_words=None):
         rec = {"due_s": due_s, "send_s": self.now(), "in_sample": in_sample,
                "kind": kind, "prompt_tokens": prompt_tokens,
                "max_tokens": max_tokens, "first_s": self.now(),
                "text": " ".join(f"t{9 + i}" for i in range(max_tokens)) + " "}
         self.sent.append(rec)
-        await asyncio.sleep(self.service_s)
+        if self.fail_first and len(self.sent) == 1:
+            raise ConnectionError("the first answer never came")
+        # the answer arrives in four parts, as a burst's frames do
+        for part in range(1, 5):
+            await asyncio.sleep(self.service_s / 4)
+            if on_words is not None:
+                on_words(max_tokens * part // 4)
         rec["last_s"] = rec["end_s"] = self.now()
         return rec
 
@@ -222,7 +230,7 @@ def test_sessions_drive_samples_follow_up_turns_due_in_the_window():
 def test_closed_loop_drive_samples_what_finished_in_the_window():
     traffic = {"generator": "closed_loop", "clients": 3, "max_tokens": 4,
                "prompt": {"kind": "uniform", "lo": 8, "hi": 16}, "ramp_s": 0.3,
-               "requests_per_client": 2}
+               "requests_per_client": 2, "start_after_tokens": 2}
     ctx = FakeCtx(traffic, 1, 1, service_s=0.1)
     asyncio.run(closed_loop.drive(ctx))
     done = [r for r in ctx.sent if "end_s" in r]
@@ -230,3 +238,30 @@ def test_closed_loop_drive_samples_what_finished_in_the_window():
     for r in done:
         assert r["in_sample"] == (0 <= r["end_s"] < 1)
     assert sum(r["in_sample"] for r in done) >= 20
+
+
+@pytest.mark.parametrize("fail_first", [False, True])
+def test_closed_loop_starts_each_caller_behind_the_last_ones_progress(fail_first):
+    """`start_after_tokens`: the stagger counts in tokens the caller before
+    has received, not in seconds; a first answer that fails holds nobody
+    back."""
+    traffic = {"generator": "closed_loop", "clients": 4, "max_tokens": 8,
+               "prompt": {"kind": "uniform", "lo": 8, "hi": 16}, "ramp_s": 0.5,
+               "requests_per_client": 2, "start_after_tokens": 2}
+    ctx = FakeCtx(traffic, 5, 0.3, service_s=0.2)
+    ctx.fail_first = fail_first
+
+    async def run():
+        try:
+            await closed_loop.drive(ctx)
+        except ConnectionError:
+            pass
+
+    asyncio.run(run())
+    first = ctx.sent[:4]  # the four callers' first requests, in caller order
+    gaps = [b["send_s"] - a["send_s"] for a, b in zip(first, first[1:])]
+    assert first[0]["send_s"] == pytest.approx(-0.5, abs=0.03)
+    # a quarter of an answer (2 of 8 tokens) is a quarter of the service time
+    want = [0.0 if fail_first else 0.05, 0.05, 0.05]
+    assert gaps == pytest.approx(want, abs=0.03)
+    assert len(ctx.sent) > 8  # and every caller went on after its first

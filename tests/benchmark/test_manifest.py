@@ -139,19 +139,46 @@ def test_the_check_catches(mutate):
     assert mf.check(m), f"{mutate.__name__} went unnoticed"
 
 
+HARNESS = ("run.py", "launcher.py", "manifest.py", "warmup.py", "procs.py",
+           "samples.py", "correctness.py", "routing.py", "check_config.py")
+
+
+def _code(fn):
+    with open(os.path.join(mf.ROOT, "benchmark", fn)) as f:
+        code = "\n".join(ln for ln in f.read().splitlines()
+                         if not ln.lstrip().startswith("#"))
+    return code.split('"""', 2)[-1]  # the module docstring may name some
+
+
 def test_the_harness_lists_no_cell_metric_or_configuration_in_code():
-    here = os.path.join(mf.ROOT, "benchmark")
     names = ([w["name"] for w in MANIFEST["workloads"]]
              + [c["name"] for c in MANIFEST["configs"]]
              + [m["name"] for m in MANIFEST["per_layer"]])
-    for fn in ("run.py", "launcher.py", "manifest.py", "warmup.py", "procs.py",
-               "samples.py", "correctness.py"):
-        with open(os.path.join(here, fn)) as f:
-            code = "\n".join(ln for ln in f.read().splitlines()
-                             if not ln.lstrip().startswith("#"))
-        code = code.split('"""', 2)[-1]  # the module docstring may name some
+    for fn in HARNESS:
         for n in names:
-            assert f'"{n}"' not in code, f"{fn} names {n}"
+            assert f'"{n}"' not in _code(fn), f"{fn} names {n}"
+
+
+def test_the_harness_names_no_architecture_in_code():
+    """Which class a `model_type` gets is the program's to know (the
+    launcher asks its `load_config`), and which reference, the
+    configuration file's (`correctness.reference`; the table for files that
+    name none is in `benchmark/reference/__init__.py`). So no harness file
+    compares a `model_type` or holds an architecture's name."""
+    from benchmark.reference import REFERENCES
+
+    rehearsal = mf.load(os.path.join(os.path.dirname(__file__), "rehearsal",
+                                     "BENCHMARK.json"))
+    base = os.path.join(os.path.dirname(__file__), "rehearsal")
+    architectures = set(REFERENCES) | {"qwen", "olmoe", "deepseek", "granite"}
+    for manifest, root in ((MANIFEST, mf.ROOT), (rehearsal, base)):
+        for c in manifest["configs"]:
+            architectures.add(mf.load_config(manifest, c["name"], root)["model_type"])
+    for fn in HARNESS:
+        code = _code(fn).lower()
+        assert "model_type" not in code, f"{fn} looks at a model_type"
+        for name in architectures:
+            assert name not in code, f"{fn} names the architecture {name!r}"
 
 
 def test_the_manifest_fits_the_size_limit():

@@ -22,6 +22,44 @@ def req(i, due, send, first, last, words, *, prompt=100, sample=True, ok=True):
             "in_sample": sample, "kind": "request", "text": ""}
 
 
+LOOP_START = {"main": {"step": 10.0, "admit": 1.0, "control": 0.5,
+                       "record": 0.2, "idle": 30.0, "other": 0.3}}
+LOOP_END = {"main": {"step": 50.0, "admit": 2.0, "control": 0.5,
+                     "record": 0.7, "idle": 40.0, "other": 0.8}}
+DECODE_SPANS = [("host_sync", 0.01), ("dispatch", 0.01), ("compute", 0.20),
+                ("fetch", 0.02), ("emit", 0.01)]
+
+
+def ledger(programs: int) -> dict:
+    """An engine's `compile` block (llmlb_tpu/engine/compilelog.py)."""
+    def block(n, trace, lower, backend):
+        return {"programs_total": n, "cache_hits_total": n,
+                "repeat_builds_total": 0,
+                "seconds_total": {"trace": trace, "lower": lower,
+                                  "backend": backend}}
+    return {**block(programs, 20.0, 15.0, 12.5),
+            "by_thread": {"loop": block(programs - 8, 10.0, 7.0, 4.5),
+                          "prewarm": block(4, 10.0, 8.0, 8.0),
+                          "other": block(4, 0.0, 0.0, 0.0)}}
+
+
+def step(record: dict, t0: float, spans: list, since_prev: dict,
+         active_slots: int) -> dict:
+    """A step record as the engine serves it since PR 24: `record` plus its
+    stamps, its spans laid end to end from `t0`, and the account of the
+    time since the previous record."""
+    at, out = 0.0, []
+    for name, dur in spans:
+        out.append([name, at, dur])
+        at += dur
+    gaps = dict.fromkeys(("admit_s", "control_s", "record_s", "idle_s",
+                          "other_s"), 0.0)
+    return {**record, "t0_s": t0, "t1_s": t0 + at, "wall_s": at, "spans": out,
+            "since_prev": {**gaps, **since_prev},
+            "active_slots": active_slots,
+            "builds": {"count": 0, "names": []}}
+
+
 def collected():
     requests = [
         req(1, 0.0, 0.001, 0.301, 1.301, 11),   # ttft .301  tpot .1
@@ -38,17 +76,31 @@ def collected():
                       "r2": {"ttft_s": 0.480, "queue_wait_s": 0.030},
                       "r3": {"ttft_s": 0.190, "queue_wait_s": 0.020}},
         "steps": [
-            {"ts": 1001.0, "kind": "decode", "total_s": 0.25, "tokens": 8,
-             "phases_s": {"plan": 0.01, "dispatch": 0.01, "compute": 0.20,
-                          "fetch": 0.02, "emit": 0.01}},
-            {"ts": 1002.0, "kind": "prefill", "total_s": 0.15, "tokens": 300,
-             "phases_s": {"plan": 0.02, "dispatch": 0.01, "compute": 0.10,
-                          "emit": 0.02}},
-            {"ts": 1009.5, "kind": "prefill", "total_s": 0.10, "tokens": 200,
-             "phases_s": {"compute": 0.10}},
+            step({"ts": 1001.0, "kind": "decode", "total_s": 0.25, "tokens": 8,
+                  "phases_s": {"plan": 0.01, "dispatch": 0.01, "compute": 0.20,
+                               "fetch": 0.02, "emit": 0.01}},
+                 500.0, DECODE_SPANS, {"admit_s": 0.01}, 4),
+            step({"ts": 1002.0, "kind": "prefill", "total_s": 0.15, "tokens": 300,
+                  "phases_s": {"plan": 0.02, "dispatch": 0.01, "compute": 0.10,
+                               "emit": 0.02}},
+                 501.0, [("dispatch", 0.01), ("compute", 0.10),
+                         ("activate", 0.02)], {"admit_s": 0.02}, 1),
+            step({"ts": 1009.5, "kind": "prefill", "total_s": 0.10, "tokens": 200,
+                  "phases_s": {"compute": 0.10}},
+                 508.5, [("compute", 0.10)], {}, 1),
+            # a second decode step, so that a stretch between two exists; a
+            # copy of the first, which leaves sched.host_share where it was
+            step({"ts": 1009.9, "kind": "decode", "total_s": 0.25, "tokens": 8,
+                  "phases_s": {"plan": 0.01, "dispatch": 0.01, "compute": 0.20,
+                               "fetch": 0.02, "emit": 0.01}},
+                 509.0, DECODE_SPANS, {"admit_s": 0.01}, 4),
         ],
-        "health_start": {"metrics": {"prefix_cached_tokens_total": 1000}},
-        "health_end": {"metrics": {"prefix_cached_tokens_total": 1100}},
+        "health_start": {"metrics": {"prefix_cached_tokens_total": 1000,
+                                     "loop_seconds_total": LOOP_START,
+                                     "compile": ledger(240)}},
+        "health_end": {"metrics": {"prefix_cached_tokens_total": 1100,
+                                   "loop_seconds_total": LOOP_END,
+                                   "compile": ledger(240)}},
         "trace": {"busy_s": 6.0, "window_s": 8.0, "wall_start": 1005.0,
                   "wall_stop": 1009.9,
                   "modules": {"jit_many": {"count": 30, "time_s": 6.0, "median_s": 0.24},
@@ -135,6 +187,30 @@ def test_rooflines_are_shares_of_the_published_peaks():
     assert k["bytes"] == pytest.approx(live * 8 * 128 * 2 * 2 + rows * 32 * 128 * 2 * 2)
     want = 100 * (3840 * k["bytes"] / pk["hbm_bytes_per_s"]) / 0.5
     assert layer("kernel.paged_flash_decode_roofline") == pytest.approx(want)
+
+
+@pytest.mark.parametrize("keys", [
+    {"num_local_experts": 8},                                   # Mixtral
+    {"num_experts": 8},                                         # OLMoE
+    {"num_experts": 8, "moe_intermediate_size": 14336,
+     "intermediate_size": 999},                                 # Qwen-MoE
+], ids=lambda k: "+".join(k))
+def test_the_decode_roofline_counts_a_mixtures_routed_experts(keys):
+    """A token multiplies by 2 of 8 experts whichever key names the 8 and
+    whichever the width; a config with neither key is dense."""
+    hf = {**CONFIG, "num_hidden_layers": 4, "num_experts_per_tok": 2,
+          "intermediate_size": 14336, **keys}
+    expert_params = 4 * 8 * 3 * 4096 * 14336
+    n_params = expert_params + 500_000_000
+    engine = {"param_bytes": 2 * n_params, "n_params": n_params}
+    work = mf.load_module("roofline", "decode_program").work
+    sparse = work(hf, engine, live_tokens=0, rows=1)
+    routed = n_params - 32000 * 4096 - expert_params * 3 / 4
+    assert sparse["flops"] == pytest.approx(2 * routed)
+    assert sparse["bytes"] == 2 * n_params - 32000 * 4096 * 2  # every expert
+    dense = work({k: v for k, v in hf.items() if k not in keys}, engine,
+                 live_tokens=0, rows=1)
+    assert dense["flops"] == pytest.approx(2 * (n_params - 32000 * 4096))
 
 
 def test_a_reader_that_finds_nothing_returns_nothing():

@@ -31,7 +31,8 @@ def rehearse(workload, trace, seed=5, seconds=2):
 
 
 @pytest.mark.parametrize("workload,trace", [
-    ("tiny.open", 0), ("tiny.sessions", 1), ("tiny-moe.closed", 0)])
+    ("tiny.open", 0), ("tiny.sessions", 1), ("tiny-moe.closed", 0),
+    ("tiny-bias.closed", 1)])
 def test_rehearsal_runs_end_to_end_and_is_refused_as_a_measurement(workload, trace):
     proc = rehearse(workload, trace, seed=2147483655)
     assert proc.returncode == 4, proc.stderr[-3000:]
@@ -49,14 +50,43 @@ def test_rehearsal_runs_end_to_end_and_is_refused_as_a_measurement(workload, tra
         assert {"sched.host_share", "sched.queue_wait_p50_s",
                 "gateway.ttft_overhead_p50_s", "cache.prefix_hit_share",
                 "client.ttft_p50_s", "client.ttft_p90_s",
-                "client.send_lag_p99_s", "engine.compiles_in_window"} <= got <= wanted
-        assert line["metrics"]["cache.prefix_hit_share"]["value"] > 0
+                "client.send_lag_p99_s", "engine.compiles_in_window",
+                # the host timeline and the set-up ledger of PR 24
+                "sched.activate_share", "sched.loop_overhead_share",
+                "sched.loop_unattributed_share", "sched.longest_stall_s",
+                "setup.programs_built", "setup.trace_lower_s",
+                "setup.backend_compile_s", "setup.prewarm_build_s",
+                "engine.programs_built_in_window"} <= got <= wanted
+        if workload == "tiny.sessions":
+            assert line["metrics"]["cache.prefix_hit_share"]["value"] > 0
         assert "window_s" in line["device"]
     else:
         assert got == wanted
         assert all(v["value"] > 0 for v in line["metrics"].values())
     split = json.loads(proc.stdout.strip().splitlines()[-2])
     assert split["correctness"]["ok"] and split["setup_split"]["warmup_s"] > 0
+    # a mixture's `correct` has heard the routing; a dense model's has not
+    assert ("routing_agreement" in split["correctness"]) == (
+        workload == "tiny-moe.closed")
+
+
+def test_an_architecture_arrives_as_files():
+    """`tiny-bias.closed` above ran a `model_type` that neither table of the
+    harness held before PR 26, against a reference that lies beside the
+    rehearsal's manifest: no file of `benchmark/` knows either name."""
+    config = mf.load_config(mf.load(MANIFEST), "debug-bias-tiny", os.path.dirname(MANIFEST))
+    reference = config["correctness"]["reference"]
+    assert os.path.isfile(os.path.join(HERE, "rehearsal", "reference",
+                                       reference + ".py"))
+    for root, _dirs, files in os.walk(os.path.join(mf.ROOT, "benchmark")):
+        assert reference + ".py" not in files
+        for fn in files:
+            if fn.endswith((".py", ".json")):
+                with open(os.path.join(root, fn)) as f:
+                    text = f.read()
+                for name in (config["model_type"], reference,
+                             config["model_id"]):
+                    assert name not in text, f"{fn} knows {name!r}"
 
 
 def test_without_rehearse_a_run_that_finds_no_tpu_prints_no_result():

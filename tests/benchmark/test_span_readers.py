@@ -5,7 +5,8 @@ program that serves no spans, no loop buckets and no program ledger."""
 import pytest
 
 from benchmark import manifest as mf
-from tests.benchmark.conftest import LOOP_END, LOOP_START, ledger, span_fields
+from tests.benchmark.test_readers import (LOOP_END, LOOP_START, ledger,
+                                          step as span_fields)
 
 DECODE = [("host_sync", 0.01), ("dispatch", 0.01), ("compute", 0.20),
           ("fetch", 0.02), ("emit", 0.01)]  # 0.25 s
@@ -98,9 +99,14 @@ def test_a_stretch_is_counted_only_between_decode_steps_with_work():
 
 
 def test_every_new_reader_is_in_the_manifest_for_its_cells():
+    """Eight of the nine list no cells (PR 26), so a cell added later reads
+    the host timeline and the set-up ledger without an edit to an entry; the
+    stall detector stays with the open loop, which alone reports the metric
+    it moves."""
     manifest = mf.load()
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    cells = [w["name"] for w in manifest["workloads"]]
     for name, _ in BY_HAND:
-        want = cells[1:] if name == "sched.longest_stall_s" else cells
-        assert by_name[name]["workloads"] == want, name
+        if name == "sched.longest_stall_s":
+            assert by_name[name]["workloads"] == ["mistral-7b-l16.chat-paced"]
+        else:
+            assert "workloads" not in by_name[name], name
