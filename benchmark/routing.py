@@ -4,21 +4,30 @@
 `observed(family, name)` is the family's paged serving function `name`
 giving one more value at the end: per layer and token of the call, the
 experts chosen `[L, B, T, k]`, the router's float32 logits `[L, B, T, X]`,
-and which (token, choice) pairs the capacity dispatch kept `[L, B, T, k]`
-(all true on the exact path).
+and which (token, choice) pairs were kept `[L, B, T, k]` (all true for a
+program that drops nothing).
 
 The contract with the program is a static argument `routing=True` on those
 functions that makes them return exactly that; a family that has it is
-asked. `models/mixtral.py` does not have it yet, and a `benchmark` PR may
-not give it one, so until a PR that edits the program does (PERF.md §7),
-`_Tap` reads the same three arrays from the outside: it traces the
-function's own body again under a `jit` of its own, with
+asked, and what it reports is what the comparison counts. A family that has
+not is tapped: `_Tap` reads the same three arrays from the outside. It
+traces the function's own body again under a `jit` of its own, with
 `ops.moe.top_k_routing` wrapped so that each call sends its inputs and its
-choice to the host (`jax.debug.callback`, ordered, so layer by layer), and
-works out which pairs the capacity dispatch keeps from that choice by
-GShard's rule as `ops/moe.py` states it (choice-major, then by token, up to
-`capacity` per expert). The program's own jitted functions are never traced
-with the wrapper in place: the engine's programs are the ones they were.
+choice to the host (`jax.debug.callback`, ordered, so layer by layer). What
+the tap requires of the program is that alone: `top_k_routing(router_logits
+[S, X], k)` called once per layer of a traced call. Where `kept` comes
+from: while the program has a capacity dispatch (a `moe_dispatch_combine`
+in `ops/moe.py`, found with `getattr`, wrapped to note the `capacity` and
+`token_valid` it is called with), the tap works out which pairs it keeps
+from the choice by GShard's rule as `ops/moe.py` states it (choice-major,
+then by token, up to `capacity` per expert); a call that goes through no
+such dispatch, or a program that has none, keeps every pair. The program's
+own jitted functions are never traced with the wrapper in place: the
+engine's programs are the ones they were.
+
+`_Tap`, `_wrapped_routing` and `kept_by_capacity` are for the `benchmark`
+PR after the one that gives `models/mixtral.py` the `routing=` argument to
+delete (PERF.md section 7): from then on no family is tapped.
 """
 
 from __future__ import annotations
@@ -58,7 +67,9 @@ def kept_by_capacity(chosen, valid, capacity: int | None, experts: int):
 def _wrapped_routing(family, records: list):
     from llmlb_tpu.ops import moe
 
-    real_top_k, real_dispatch = moe.top_k_routing, moe.moe_dispatch_combine
+    real_top_k = moe.top_k_routing
+    # a program that drops nothing may have no capacity dispatch at all
+    real_dispatch = getattr(moe, "moe_dispatch_combine", None)
     dispatching: dict = {}  # capacity and token_valid of the call being traced
 
     def top_k_routing(router_logits, num_selected):
@@ -85,8 +96,8 @@ def _wrapped_routing(family, records: list):
         finally:
             dispatching.clear()
 
-    holders = [m for m in (moe, family)
-               if getattr(m, "moe_dispatch_combine", None) is real_dispatch]
+    holders = [m for m in (moe, family) if real_dispatch is not None
+               and getattr(m, "moe_dispatch_combine", None) is real_dispatch]
     moe.top_k_routing = top_k_routing
     for m in holders:
         m.moe_dispatch_combine = moe_dispatch_combine

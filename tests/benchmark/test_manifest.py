@@ -4,6 +4,7 @@ that each reporting cell reports, every cell, configuration and metric
 found by name with no list in code."""
 
 import copy
+import glob
 import inspect
 import json
 import os
@@ -157,6 +158,27 @@ def test_the_harness_lists_no_cell_metric_or_configuration_in_code():
     for fn in HARNESS:
         for n in names:
             assert f'"{n}"' not in _code(fn), f"{fn} names {n}"
+
+
+def test_no_test_of_the_harness_pins_the_programs_capacity_code():
+    """A test under `tests/benchmark/` holds the harness to its contract,
+    not the program to what it happens to have: the capacity dispatch, its
+    sizing function and the configuration's factor may be deleted by a PR
+    that edits the program, so a test names them only where it asks
+    whether they are there (`getattr`, `hasattr`). Spelt in pieces, so that
+    this file passes its own case."""
+    pins = ["_".join(words) for words in (
+        ("moe", "dispatch", "combine"), ("default", "capacity"),
+        ("capacity", "factor"))]
+    here = os.path.dirname(os.path.abspath(__file__))
+    found = []
+    for path in glob.glob(os.path.join(here, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            found += [f"{os.path.relpath(path, here)}:{i}: {line.strip()}"
+                      for i, line in enumerate(f, 1)
+                      if any(pin in line for pin in pins)
+                      and "getattr(" not in line and "hasattr(" not in line]
+    assert not found, "\n".join(found)
 
 
 def test_the_harness_names_no_architecture_in_code():

@@ -6,7 +6,17 @@ logits — the comparison the launcher makes at full width on the chip
 of the routing: a near tie decided the other way passes, and a wrong expert,
 a wrong router, a skipped renormalisation, a choice that is no top-k, a flip
 at a wide margin and a dropped assignment each fail by the field that names
-them."""
+them.
+
+What these tests may require of the program is the contract the harness
+documents and no more: the family's three paged serving functions with
+their signatures and three results, `init_params`, `init_kv_pages`, that
+`ops.moe.top_k_routing(router_logits [S, X], k)` is called once per layer
+of a traced call (the tap's way in), and, where a family has it, the static
+`routing=True` argument (benchmark/routing.py). Not that the program drops
+assignments, has a capacity dispatch, or lacks that argument: each case
+below holds on a program that drops nothing, has no capacity code and
+offers its routing, and on today's."""
 
 import inspect
 import json
@@ -50,16 +60,18 @@ def keeping(module, true_params, follow=True):
         __name__=module.__name__)
 
 
-def run(config, params=None, reference=None, **spec):
+def run(config, params=None, reference=None, family=None, **spec):
+    """The comparison of `family` (by default the program's own for the
+    configuration) with the configuration's reference."""
     cfg = build_cfg(config)
-    family = family_for(cfg)
+    program = family_for(cfg)
     if params is None:
-        params = family.init_params(cfg, jax.random.PRNGKey(3))
+        params = program.init_params(cfg, jax.random.PRNGKey(3))
     return correctness.check(
-        family, cfg, params, config,
+        family or program, cfg, params, config,
         {**config["correctness"], "tolerance": 1.0, **spec}, 7,
         config["engine"]["kv_page_size"],
-        reference or refs.module_for(config, REHEARSAL)), params, family
+        reference or refs.module_for(config, REHEARSAL)), params, program
 
 
 @pytest.mark.parametrize("name", ["debug-tiny", "debug-moe-tiny",
@@ -144,6 +156,29 @@ def _first_and_third(router_logits, num_selected):
     return jax.nn.softmax(vals[:, keep], axis=-1), idx[:, keep]
 
 
+@pytest.fixture
+def patch_top_k():
+    """`patch_top_k(wrong)` puts `wrong` in `ops.moe.top_k_routing`'s place
+    for the rest of the test. Whoever traces the program's body under the
+    patch — the tap's own jit, or a family that is asked and jits for
+    itself — traces it anew (nothing made before the patch is found) and
+    keeps nothing of it (nothing made under the patch is found after)."""
+    def forget():
+        routing.observed.cache_clear()
+        jax.clear_caches()
+
+    patched = []
+    with pytest.MonkeyPatch.context() as patch:
+        def put(wrong):
+            forget()
+            patch.setattr(moe_ops, "top_k_routing", wrong)
+            patched.append(wrong)
+
+        yield put
+    if patched:
+        forget()
+
+
 @pytest.mark.parametrize("wrong_params,wrong_top_k,grounds", [
     (_zeroed_expert, None, {"logits"}),
     (_permuted_router, None, {"router_rel_rms_err"}),
@@ -152,20 +187,14 @@ def _first_and_third(router_logits, num_selected):
 ], ids=["wrong_expert_weight", "wrong_router", "skipped_renormalisation",
         "choice_that_is_no_top_k"])
 def test_a_wrong_mixture_fails_by_the_field_that_names_it(
-        monkeypatch, wrong_params, wrong_top_k, grounds):
+        patch_top_k, wrong_params, wrong_top_k, grounds):
     config = load("debug-moe-tiny")
     cfg = build_cfg(config)
     true = mixtral.init_params(cfg, jax.random.PRNGKey(3))
     if wrong_top_k:
-        # only the tap's own trace of the program's body sees this: the
-        # program's jitted functions are not called for a mixture
-        monkeypatch.setattr(moe_ops, "top_k_routing", wrong_top_k)
-        routing.observed.cache_clear()
-    try:
-        out, _, _ = run(config, reference=keeping(moe, true), tolerance=0.01,
-                        params=wrong_params(true) if wrong_params else true)
-    finally:
-        routing.observed.cache_clear()
+        patch_top_k(wrong_top_k)
+    out, _, _ = run(config, reference=keeping(moe, true), tolerance=0.01,
+                    params=wrong_params(true) if wrong_params else true)
     assert not out["ok"] and grounds <= set(out["grounds"]), out
     if wrong_top_k is _no_renormalisation:
         # every choice is sound; the mixing weights are wrong (and with them
@@ -173,17 +202,70 @@ def test_a_wrong_mixture_fails_by_the_field_that_names_it(
         assert out["choice_is_own_topk"] and out["max_rel_rms_err"] > 0.05
 
 
+def reporting(not_kept=None):
+    """A family that offers its routing (the `routing=` argument of
+    benchmark/routing.py) and answers with what the program's own routing
+    is observed to be, but for `kept`: the pairs `not_kept[name]` lists, as
+    indices into [L, B, T, k], are reported as not kept. `reported` counts
+    the pairs reported so, the program's own among them."""
+    reported = []
+    observed = routing.observed  # `routing` is the argument's name below
+
+    def serving(name):
+        def fn(*args, routing=False, **kw):
+            assert routing, "the comparison asks a family that offers it"
+            *out, (chosen, logits, kept) = observed(mixtral, name)(*args, **kw)
+            kept = np.array(kept, bool)
+            for index in (not_kept or {}).get(name, ()):
+                kept[index] = False
+            reported.append(int((~kept).sum()))
+            return (*out, (chosen, logits, kept))
+
+        return staticmethod(fn)
+
+    names = ("prefill_into_pages", "prefill_extend_pages", "decode_step_paged")
+    family = type("reporting_family", (), {
+        "init_kv_pages": staticmethod(mixtral.init_kv_pages),
+        **{name: serving(name) for name in names}})
+    return family, reported
+
+
 def test_a_dropped_assignment_is_counted_and_named():
-    # 32 tokens > 4 x 4 experts: the program's capacity dispatch, 20 places
-    # an expert for 64 assignments
+    """Shown on what the comparison is given, not on what the program does:
+    a family whose reported routing says five pairs were not kept, three in
+    the prefill and two in the chunk, at sizes where the program itself
+    keeps every pair. Nothing else is wrong with it, and nothing else is
+    found."""
     config = load("debug-moe-tiny")
-    exact, _, _ = run(config, prefill_tokens=16)
-    assert exact["dropped_assignments"] == 0 and exact["ok"]
-    capacity, _, _ = run(config, prefill_tokens=32, extend_chunks=0,
-                         tolerance=0.001)
-    assert capacity["dropped_assignments"] > 0
-    assert "dropped_assignments" in capacity["grounds"] and not capacity["ok"]
-    assert capacity["prefill_rel_rms_err"] > 100 * exact["prefill_rel_rms_err"]
+    sound, reported = reporting()
+    out, _, _ = run(config, family=sound)
+    assert out["ok"] and out["dropped_assignments"] == sum(reported) == 0
+    family, reported = reporting({
+        "prefill_into_pages": [(0, 0, 3, 1), (1, 0, 3, 0), (1, 0, 15, 1)],
+        "prefill_extend_pages": [(0, 0, 0, 0), (1, 0, 9, 1)]})
+    out, _, _ = run(config, family=family)
+    assert reported[:2] == [3, 2] and not any(reported[2:])
+    assert out["dropped_assignments"] == 5
+    assert out["grounds"] == ["dropped_assignments"] and not out["ok"]
+    assert out["max_rel_rms_err"] < 1e-4  # the logits are the program's own
+
+
+@pytest.mark.parametrize("prefill_tokens", [16, 32])
+def test_what_the_program_reports_as_not_kept_is_what_is_counted(
+        prefill_tokens):
+    """A reading, and one rule: whatever `kept` the program's routing is
+    observed to hold, `dropped_assignments` counts its false entries, and
+    the ground is named exactly when there is one. 32 tokens are past the
+    4 x experts up to which today's program keeps to its exact path; a
+    program that drops nothing reads 0 at both sizes."""
+    family, reported = reporting()
+    out, _, _ = run(load("debug-moe-tiny"), family=family,
+                    prefill_tokens=prefill_tokens, extend_chunks=0)
+    print(f"prefill of {prefill_tokens}: {sum(reported)} of "
+          f"{2 * 2 * (prefill_tokens + 4)} assignments reported as not kept")
+    assert out["dropped_assignments"] == sum(reported)
+    assert ("dropped_assignments" in out["grounds"]) == (sum(reported) > 0)
+    assert out["ok"] == (not out["grounds"])
 
 
 def _verdict(**changes):
@@ -233,10 +315,29 @@ def test_a_flip_at_a_wide_margin_and_a_drop_are_counted():
     assert not _verdict(chosen=twice)["choice_is_own_topk"]
 
 
+def test_the_capacity_rule_by_hand():
+    """Three tokens, top 2, two places an expert: first choices of all
+    tokens before second choices, tokens in order; padding takes no room
+    and counts as kept; no capacity keeps all."""
+    chosen = np.asarray([[0, 1], [0, 1], [0, 1], [1, 0]])
+    valid = np.ones(4, bool)
+    assert routing.kept_by_capacity(chosen, valid, 2, 3).tolist() == [
+        [True, True], [True, False], [False, False], [True, False]]
+    # token 1 is padding: it takes no place, so token 2 finds one
+    padded = routing.kept_by_capacity(chosen, valid & (np.arange(4) != 1), 2, 3)
+    assert padded.tolist() == [
+        [True, True], [True, True], [True, False], [True, False]]
+    assert routing.kept_by_capacity(chosen, valid, None, 3).all()
+
+
 def test_the_tap_keeps_what_the_capacity_dispatch_keeps():
     """`routing.kept_by_capacity` is the harness's statement of the rule in
-    `ops/moe.py`: the program's capacity dispatch must equal the exact
-    mixture with exactly the pairs it says are dropped left out."""
+    `ops/moe.py`, and is held to the program's capacity dispatch for as
+    long as the program has one: the dispatch must equal the exact mixture
+    with exactly the pairs the statement says are dropped left out. A
+    program with no such dispatch has nothing to hold it to, and the tap
+    then keeps every pair of every call."""
+    dispatch = getattr(moe_ops, "moe_dispatch_combine", None)
     s, m, f, e, k, cap = 24, 16, 32, 4, 2, 8
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     x = jax.random.normal(keys[0], (s, m), jnp.float32)
@@ -245,21 +346,32 @@ def test_the_tap_keeps_what_the_capacity_dispatch_keeps():
               for kk in keys[2:4])
     wd = jax.random.normal(keys[4], (e, f, m), jnp.float32) * f ** -0.5
     valid = np.arange(s) < 20
-    with jax.default_matmul_precision("highest"):
-        got = moe_ops.moe_dispatch_combine(
-            x, logits, wg, wu, wd, num_selected=k, capacity=cap,
-            token_valid=jnp.asarray(valid))
-        weights, chosen = moe_ops.top_k_routing(logits, k)
-        kept = routing.kept_by_capacity(np.asarray(chosen), valid, cap, e)
-        assert 0 < (~kept).sum() < s * k
-        want = jnp.zeros_like(x)
-        for j in range(k):
-            for ex in range(e):
-                w = jnp.where((chosen[:, j] == ex) & kept[:, j] & valid,
-                              weights[:, j], 0.0)
-                want = want + w[:, None] * dense.swiglu(x, wg[ex], wu[ex], wd[ex])
-    assert np.abs(np.asarray(got - want))[valid].max() < 1e-5
-    assert routing.kept_by_capacity(np.asarray(chosen), valid, None, e).all()
+    weights, chosen = moe_ops.top_k_routing(logits, k)
+    kept = routing.kept_by_capacity(np.asarray(chosen), valid, cap, e)
+    assert 0 < (~kept).sum() < s * k
+    if dispatch is not None:
+        with jax.default_matmul_precision("highest"):
+            got = dispatch(x, logits, wg, wu, wd, num_selected=k, capacity=cap,
+                           token_valid=jnp.asarray(valid))
+            want = jnp.zeros_like(x)
+            for j in range(k):
+                for ex in range(e):
+                    w = jnp.where((chosen[:, j] == ex) & kept[:, j] & valid,
+                                  weights[:, j], 0.0)
+                    want = want + w[:, None] * dense.swiglu(
+                        x, wg[ex], wu[ex], wd[ex])
+        assert np.abs(np.asarray(got - want))[valid].max() < 1e-5
+        return
+    tapped = routing.observed(mixtral, "prefill_into_pages")
+    if isinstance(tapped, routing._Tap):
+        config = load("debug-moe-tiny")
+        cfg = build_cfg(config)
+        ck, cv = mixtral.init_kv_pages(cfg, 5, 16)
+        *_, (_, _, kept) = tapped(
+            mixtral.init_params(cfg, jax.random.PRNGKey(3)), cfg,
+            jnp.zeros((1, 64), jnp.int32), jnp.asarray([64], jnp.int32),
+            jnp.arange(1, 5, dtype=jnp.int32)[None, :], ck, cv, None)
+        assert kept.shape == (2, 1, 64, 2) and kept.all()
 
 
 def test_the_tap_is_off_the_serving_path():
@@ -267,13 +379,17 @@ def test_the_tap_is_off_the_serving_path():
     heard the routing, the program's own functions are the originals, their
     jaxprs hold no callback and no router-logit output, and the scheduler
     knows nothing of any of it."""
-    real = (moe_ops.top_k_routing, moe_ops.moe_dispatch_combine,
-            mixtral.moe_dispatch_combine)
+    def wrapped_while_tapped():
+        return [moe_ops.top_k_routing] + [
+            getattr(m, "moe_dispatch_combine", None) for m in (moe_ops, mixtral)]
+
+    real = wrapped_while_tapped()
     config = load("debug-moe-tiny")
+    # 32 tokens: past today's exact path, so every expert path the program
+    # has was traced under the tap
     out, params, family = run(config, prefill_tokens=32, extend_chunks=0)
-    assert out["dropped_assignments"] > 0  # both paths were tapped
-    assert real == (moe_ops.top_k_routing, moe_ops.moe_dispatch_combine,
-                    mixtral.moe_dispatch_combine)
+    assert out["positions_compared"] == 1 + 4
+    assert all(now is was for now, was in zip(wrapped_while_tapped(), real))
     cfg = build_cfg(config)
     ck, cv = family.init_kv_pages(cfg, 5, 16)
     table = jnp.arange(1, 5, dtype=jnp.int32)[None, :]
@@ -303,10 +419,13 @@ def test_a_family_that_offers_its_routing_is_asked_and_not_tapped():
 
     asked = routing.observed(family, "prefill_into_pages")
     assert asked(None, None, None)[3] == "its own routing"
-    assert isinstance(routing.observed(mixtral, "prefill_into_pages"),
-                      routing._Tap)
-    assert "routing" not in inspect.signature(
-        mixtral.prefill_into_pages).parameters  # PERF.md §7: still to come
+    # the program's own mixture is tapped exactly until it offers the same
+    for name in ("prefill_into_pages", "prefill_extend_pages",
+                 "decode_step_paged"):
+        offers = "routing" in inspect.signature(
+            getattr(mixtral, name)).parameters
+        assert isinstance(routing.observed(mixtral, name),
+                          routing._Tap) == (not offers)
 
 
 def test_the_reference_follows_and_keeps_its_own_weights():
