@@ -47,6 +47,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from llmlb_tpu.engine import compilelog
 from llmlb_tpu.engine.kv_offload import KVOffloadTier
@@ -235,6 +236,32 @@ def _sample_chunk(logits, key, temps, top_ps, top_ks, seeds, mask, start_pos):
     toks = sample_tokens(flat, key, rep(temps), rep(top_ps), rep(top_ks),
                          mask, rep(seeds), steps)
     return toks.reshape(b, t)
+
+
+@partial(jax.jit, donate_argnames=("state",))
+def _activate_rows(logits, key, temps, top_ps, top_ks, seeds, lens, slot_ids,
+                   bias, lora_rows, state):
+    """Activation of one prefilled group as ONE program: split the engine's
+    key, sample each row's first token from the prefill's `logits`
+    [padded, V], and scatter the group's rows into the per-slot arrays of
+    `state` (temps, top_ps, top_ks, seeds, seq_lens, last_tokens, lora_idx
+    — donated). jit keys it by what it is handed: the padded group size,
+    whether a grammar `bias` [padded, V] is present, whether the engine has
+    adapters (`lora_rows`; without them lora_idx passes through). Padding
+    rows repeat the last real row, so their duplicate scatters write
+    identical values. Returns (new key, firsts [padded], new state)."""
+    key, sk = jax.random.split(key)
+    # steps = lens - 1: decode dispatches sample with the PRE-increment
+    # seq_len, so the first decode token uses step = prompt_len — the
+    # activation sample must fold a DIFFERENT step or a seeded request's
+    # first two tokens would draw from the same per-row key.
+    firsts = sample_tokens(logits, sk, temps, top_ps, top_ks, bias, seeds,
+                           lens - 1)
+    rows = (temps, top_ps, top_ks, seeds, lens, firsts, lora_rows)
+    return key, firsts, tuple(
+        arr if row is None else arr.at[slot_ids].set(row)
+        for arr, row in zip(state, rows)
+    )
 
 
 @dataclasses.dataclass
@@ -746,8 +773,6 @@ class EngineCore:
         # the live flag; the discard on the emit paths still touches the set.
         self._cancelled_effective: set[str] = set()
         if jax.process_count() > 1:
-            from jax.sharding import NamedSharding, PartitionSpec
-
             from llmlb_tpu.engine.multihost import StepCoordinator
 
             self.coordinator = StepCoordinator()
@@ -769,22 +794,8 @@ class EngineCore:
         # only touched at insert time — the decode hot loop does zero H2D.
         self.slots = [_Slot() for _ in range(num_slots)]
         self._seq_lens = np.zeros((num_slots,), np.int32)
-        self._d_seq_lens = jnp.zeros((num_slots,), jnp.int32)
-        self._d_temps = jnp.ones((num_slots,), jnp.float32)
-        self._d_top_ps = jnp.ones((num_slots,), jnp.float32)
-        self._d_top_ks = jnp.zeros((num_slots,), jnp.int32)
-        self._d_last_tokens = jnp.zeros((num_slots,), jnp.int32)
-        # Per-slot sampling seeds (-1 = shared batch key); always passed to
-        # sample_tokens — unseeded rows are bit-identical to the pre-seed
-        # path, so goldens hold.
-        self._d_seeds = jnp.full((num_slots,), -1, jnp.int32)
-        # Per-slot LoRA adapter pool rows (0 = identity/no adapter),
-        # scattered at activation like the sampling params so the decode
-        # hot loop does zero per-step H2D. Only consulted when self.lora
-        # is set — LoRA-free engines pass lora_idx=None to every dispatch
-        # (the original compiled programs, bit for bit).
-        self._d_lora_idx = jnp.zeros((num_slots,), jnp.int32)
-        self._key = jax.random.PRNGKey(seed)
+        self._init_slot_state()
+        self._key = self._on_mesh(jax.random.PRNGKey(seed))
 
         # Grammar-constraint mask: one float32 [slots, V] additive bias
         # (0 allowed / -1e30 blocked), host-mutated as slot FSMs advance and
@@ -1121,10 +1132,10 @@ class EngineCore:
             # Shardings are part of jax's executable cache key: a prewarm
             # lowered without them compiles a different (unsharded) variant
             # and the real dispatch would still stall on a fresh compile.
-            # Only the explicitly device_put arrays (params, caches) carry
-            # one — the uncommitted scalar vectors must stay unspecified, or
-            # their incidental single-device placement conflicts with the
-            # mesh sharding at lowering time.
+            # Params and caches carry theirs. The per-slot vectors and the
+            # key are lowered unspecified below, while a dispatch hands them
+            # over placed on the mesh (_on_mesh): the two still land under
+            # different keys (ROADMAP Speed 5).
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
         def plain(x):
@@ -1553,6 +1564,34 @@ class EngineCore:
                 clock.switch("idle")
                 time.sleep(0.001)
 
+    def _on_mesh(self, x):
+        """Place the loop's small device state (the per-slot arrays, the
+        PRNG key) where every program that returns it leaves it: replicated
+        over the mesh. An array that changed placement after its first
+        program would build each program that takes it a second time."""
+        return jax.device_put(x, NamedSharding(self.mesh, PartitionSpec()))
+
+    def _init_slot_state(self) -> None:
+        """Sampling params, lengths and last tokens live ON DEVICE, one row
+        per slot, written at activation only — the decode hot loop does
+        zero H2D."""
+        n = self.num_slots
+        self._d_seq_lens = self._on_mesh(np.zeros((n,), np.int32))
+        self._d_temps = self._on_mesh(np.ones((n,), np.float32))
+        self._d_top_ps = self._on_mesh(np.ones((n,), np.float32))
+        self._d_top_ks = self._on_mesh(np.zeros((n,), np.int32))
+        self._d_last_tokens = self._on_mesh(np.zeros((n,), np.int32))
+        # Per-slot sampling seeds (-1 = shared batch key); always passed to
+        # sample_tokens — unseeded rows are bit-identical to the pre-seed
+        # path, so goldens hold.
+        self._d_seeds = self._on_mesh(np.full((n,), -1, np.int32))
+        # Per-slot LoRA adapter pool rows (0 = identity/no adapter),
+        # scattered at activation like the sampling params so the decode
+        # hot loop does zero per-step H2D. Only consulted when self.lora
+        # is set — LoRA-free engines pass lora_idx=None to every dispatch
+        # (the original compiled programs, bit for bit).
+        self._d_lora_idx = self._on_mesh(np.zeros((n,), np.int32))
+
     def _reset_caches(self) -> None:
         if self.page_pool is not None:
             ck, cv = self.family.init_kv_pages(self.cfg, self.kv_num_pages,
@@ -1574,8 +1613,8 @@ class EngineCore:
         self.cache_k = jax.device_put(ck, ck_sh)
         self.cache_v = jax.device_put(cv, cv_sh)
         self._seq_lens[:] = 0
-        self._d_seq_lens = jnp.zeros((self.num_slots,), jnp.int32)
-        self._d_last_tokens = jnp.zeros((self.num_slots,), jnp.int32)
+        # activation donates the per-slot arrays like the caches
+        self._init_slot_state()
         if self.prefix_cache is not None:
             # the rebuilt cache holds zeros; every pinned prefix is gone
             self.prefix_cache.clear()
@@ -3739,10 +3778,14 @@ class EngineCore:
     def _activate_group(self, group: list[tuple[int, Request, int]],
                         padded_slot_ids: np.ndarray, padded_lens: np.ndarray,
                         logits) -> None:
-        """Batched activation: ONE sample_tokens over the padded logits and
-        one vector scatter per device array — ~6 dispatches for the whole
-        group instead of ~6 per request. Padding rows repeat the last real
-        row, so their scatters rewrite identical values.
+        """Batched activation: ONE program (`_activate_rows`) samples every
+        row's first token from the padded logits and scatters the group's
+        sampling state, lengths, first tokens and adapter rows into the
+        per-slot device arrays — one dispatch for the whole group, built
+        once per padded group size (and once more where a grammar bias is
+        present). The host rows go in as the NumPy arrays filled here, so
+        the one call carries every transfer. Padding rows repeat the last
+        real row, so their scatters rewrite identical values.
 
         Split mode: a prefill-loop activation never lands in the prefill
         slot — the finished slot is STAGED (prompt KV pinned in its pages,
@@ -3755,6 +3798,7 @@ class EngineCore:
         # inside a step (the prefill paths) this is its `activate` span;
         # a handoff adoption between steps stays in the loop's bucket
         self._clock().mark("activate")
+        g = len(group)
         padded = len(padded_slot_ids)
         temps = np.ones((padded,), np.float32)
         top_ps = np.ones((padded,), np.float32)
@@ -3767,10 +3811,10 @@ class EngineCore:
             top_ks[row] = s.top_k
             if s.seed is not None:
                 seeds[row] = s.seed & 0x7FFFFFFF
-        temps[len(group):] = temps[len(group) - 1]
-        top_ps[len(group):] = top_ps[len(group) - 1]
-        top_ks[len(group):] = top_ks[len(group) - 1]
-        seeds[len(group):] = seeds[len(group) - 1]
+        temps[g:] = temps[g - 1]
+        top_ps[g:] = top_ps[g - 1]
+        top_ks[g:] = top_ks[g - 1]
+        seeds[g:] = seeds[g - 1]
 
         # Constrained rows mask their first-token sampling too: the bias is
         # each slot's FSM start-state row (padding repeats the last real row,
@@ -3780,43 +3824,30 @@ class EngineCore:
             for row, (slot_id, _r, _n) in enumerate(group)
             if self.slots[slot_id].constraint is not None
         ]
-        mask = None
+        bias = None
         if constrained:
             bias = np.zeros((padded, logits.shape[-1]), np.float32)
             for row, state in constrained:
                 bias[row] = state.bias_row()
-            bias[len(group):] = bias[len(group) - 1]
-            mask = jnp.asarray(bias)
+            bias[g:] = bias[g - 1]
 
-        self._key, sk = jax.random.split(self._key)
-        d_temps = jnp.asarray(temps)
-        d_top_ps = jnp.asarray(top_ps)
-        d_top_ks = jnp.asarray(top_ks)
-        d_seeds = jnp.asarray(seeds)
-        # steps = lens - 1: decode dispatches sample with the PRE-increment
-        # seq_len, so the first decode token uses step = prompt_len — the
-        # activation sample must fold a DIFFERENT step or a seeded request's
-        # first two tokens would draw from the same per-row key.
-        firsts = sample_tokens(logits, sk, d_temps, d_top_ps, d_top_ks,
-                               mask, d_seeds, jnp.asarray(padded_lens) - 1)
-        idx = jnp.asarray(padded_slot_ids)
-        self._d_temps = self._d_temps.at[idx].set(d_temps)
-        self._d_top_ps = self._d_top_ps.at[idx].set(d_top_ps)
-        self._d_top_ks = self._d_top_ks.at[idx].set(d_top_ks)
-        self._d_seeds = self._d_seeds.at[idx].set(d_seeds)
+        lora_rows = None
         if self.lora is not None:
             # adapter rows ride the same activation scatter as the sampling
             # params: the decode hot loop then needs zero per-step H2D
-            lidx = np.zeros((padded,), np.int32)
-            lidx[:len(group)] = self._lora_rows([r for _, r, _ in group])
-            lidx[len(group):] = lidx[len(group) - 1]
-            self._d_lora_idx = self._d_lora_idx.at[idx].set(
-                jnp.asarray(lidx)
-            )
-        self._d_seq_lens = self._d_seq_lens.at[idx].set(
-            jnp.asarray(padded_lens)
+            lora_rows = np.zeros((padded,), np.int32)
+            lora_rows[:g] = self._lora_rows([r for _, r, _ in group])
+            lora_rows[g:] = lora_rows[g - 1]
+
+        (self._key, firsts,
+         (self._d_temps, self._d_top_ps, self._d_top_ks, self._d_seeds,
+          self._d_seq_lens, self._d_last_tokens,
+          self._d_lora_idx)) = _activate_rows(
+            logits, self._key, temps, top_ps, top_ks, seeds,
+            padded_lens, padded_slot_ids, bias, lora_rows,
+            (self._d_temps, self._d_top_ps, self._d_top_ks, self._d_seeds,
+             self._d_seq_lens, self._d_last_tokens, self._d_lora_idx),
         )
-        self._d_last_tokens = self._d_last_tokens.at[idx].set(firsts)
 
         if constrained:
             # The NEXT decode dispatch needs each constrained slot's mask

@@ -39,9 +39,11 @@ Span names (``SPANS``), in the order a decode step runs them:
   emit      — host-side token delivery: stop checks, grammar FSM advance,
               event-queue puts (detokenization itself runs on the service
               layer's consumer threads, off the step loop)
-  activate  — a prefilled request entering decode (_activate_group): the
-              first-token sample, the scatters of the sampling state, and
-              the first-token fetch where a grammar needs it
+  activate  — a prefilled group entering decode (_activate_group): filling
+              the group's host rows, the dispatch of the one activation
+              program (first-token sample and the writes of the per-slot
+              state), the first-token fetch where a grammar needs it, and
+              the slot bookkeeping
 
 The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
 since the previous record (``since_prev.admit_s``), ``emit`` still covers
