@@ -795,19 +795,13 @@ class EngineMetrics:
                     "# TYPE llmlb_engine_prefix_cache_entries gauge",
                     "llmlb_engine_prefix_cache_entries "
                     f"{prefix_cache['entries']}",
-                    "# TYPE llmlb_engine_prefix_cache_pinned_slots gauge",
-                    "llmlb_engine_prefix_cache_pinned_slots "
-                    f"{prefix_cache['pinned_slots']}",
                     "# TYPE llmlb_engine_prefix_cache_pinned_hbm_bytes gauge",
                     "llmlb_engine_prefix_cache_pinned_hbm_bytes "
                     f"{prefix_cache['pinned_hbm_bytes']}",
+                    "# TYPE llmlb_engine_prefix_cache_pinned_pages gauge",
+                    "llmlb_engine_prefix_cache_pinned_pages "
+                    f"{prefix_cache['pinned_pages']}",
                 ]
-                if "pinned_pages" in prefix_cache:
-                    lines += [
-                        "# TYPE llmlb_engine_prefix_cache_pinned_pages gauge",
-                        "llmlb_engine_prefix_cache_pinned_pages "
-                        f"{prefix_cache['pinned_pages']}",
-                    ]
             if quant is not None:
                 # info-style gauge: one series per mode, active one = 1, so
                 # dashboards can legend the running quantization mode
@@ -822,8 +816,8 @@ class EngineMetrics:
                     f"llmlb_engine_param_bytes {quant.get('param_bytes', 0)}",
                 ]
             if kv_cache is not None:
-                # honest-dtype KV footprint: renders for BOTH layouts so
-                # capacity dashboards never fall back to implied-bf16 math
+                # honest-dtype KV footprint, so capacity dashboards never
+                # fall back to implied-bf16 math
                 lines += [
                     "# TYPE llmlb_engine_kv_hbm_bytes gauge",
                     f"llmlb_engine_kv_hbm_bytes {kv_cache.get('hbm_bytes', 0)}",

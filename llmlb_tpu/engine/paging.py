@@ -1,7 +1,6 @@
 """Refcounted page allocator for the paged KV cache.
 
-The paged layout replaces the engine's dense per-slot KV block
-[L, slots, slot_capacity, K, D] with one global page pool
+The engine's KV lives in one global page pool
 [L, num_pages, page_size, K, D] plus a per-slot block table mapping logical
 token positions to pool pages. This module owns the pure host-side
 bookkeeping: a free list and per-page refcounts. No jax, no locks — every
@@ -28,8 +27,7 @@ Refcount semantics:
 
 Page 0 is reserved as the *trash page* (refcount pinned forever): block-table
 entries default to it, so the batched decode step's garbage writes for
-empty/parked slot rows land in cells nothing ever reads — the paged
-counterpart of the dense layout's "garbage lands in the unused last cell".
+empty/parked slot rows land in cells nothing ever reads.
 """
 
 from __future__ import annotations

@@ -1,22 +1,23 @@
-"""Refcounted radix-tree prefix KV cache over the engine's slot cache.
+"""Refcounted radix-tree prefix KV cache over the engine's KV page pool.
 
 Production chat traffic is dominated by long shared prefixes (system prompts,
 few-shot templates, multi-turn history); re-running prefill for them is the
 single largest remaining prefill cost on the TPU path. This module is the
 host-side index for reusing that work: a path-compressed radix tree keyed on
-prompt token ids whose entries pin completed prefix KV rows in retained
-"donor" slots of the static-shape slot cache [L, NUM_SLOTS, CAP, K, D].
+prompt token ids whose entries pin the pool pages holding completed prefix
+KV (the donor's slot itself frees at donation time).
 
 Division of labor:
 - This module owns the pure bookkeeping — insert/match/refcount/evict over
-  token sequences and pinned slot ids. No jax, no device state, no locks
+  token sequences and pinned page ids. No jax, no device state, no locks
   (all calls happen on the scheduler's step-loop thread; in multihost
   lockstep every host runs the same deterministic sequence of calls, so the
   trees stay mirrored).
-- The scheduler (scheduler.py) owns the device side: copying matched rows
-  into a fresh slot with one jitted dynamic_update_slice and chunk-prefilling
-  only the uncached suffix, plus deciding WHEN to insert (request completion)
-  and evict (pinned budget / slot pressure).
+- The scheduler (scheduler.py) owns the device side and the page refcounts:
+  a hit copies the matched page ids into the reader's block table with a
+  refcount bump (never the KV bytes) and chunk-prefills only the uncached
+  suffix; it also decides WHEN to insert (request completion) and evict
+  (entry budget / page pressure).
 
 Correctness hinges on one property of causal attention: the KV rows for
 positions [0, m) depend only on tokens [0, m), so any stored prefix can
@@ -37,21 +38,16 @@ import dataclasses
 
 @dataclasses.dataclass
 class PrefixEntry:
-    """One cached prefix. Dense layout: `tokens` are resident as KV rows
-    [0, len(tokens)) of pinned slot `slot` in the engine's slot cache.
-    Paged layout: `pages` names the pool pages holding those rows in order
-    (`slot` is -1; the donor slot itself was freed at donation time — a hit
-    copies the page ids into the reader's block table with a refcount bump,
-    never the KV bytes)."""
+    """One cached prefix: `pages` names, in order, the pool pages holding
+    KV rows [0, len(tokens)) of `tokens`."""
 
     tokens: tuple[int, ...]
-    slot: int
+    pages: tuple[int, ...]
     refcount: int = 0
     last_used: int = 0
-    pages: tuple[int, ...] | None = None
     node: "_Node | None" = dataclasses.field(default=None, repr=False)
-    # key in the cache's entry dict (the slot for dense entries, a unique
-    # negative id for paged ones — freed slots recycle their ids, pages don't)
+    # key in the cache's entry dict (a running id: pages can be shared
+    # between entries, so they do not name one)
     key: int = dataclasses.field(default=0, repr=False)
     # namespace the entry was inserted under (LoRA adapter or None) — the
     # host-RAM offload tier re-keys spilled entries by (ns, tokens)
@@ -83,7 +79,7 @@ def _common_len(a, b) -> int:
 
 
 class PrefixCache:
-    """Radix-tree index of pinned prefix slots. Not threadsafe by design —
+    """Radix-tree index of pinned prefix pages. Not threadsafe by design —
     see module docstring (step-loop-thread only)."""
 
     def __init__(self, *, max_entries: int, min_len: int, align: int):
@@ -99,16 +95,14 @@ class PrefixCache:
         # adapters sharing a prompt must NEVER share cached KV — an
         # adapter-blind hit would be silent corruption (docs/lora.md). The
         # default ns=None tree is the historical adapter-free cache, bit
-        # for bit; budget and LRU stay GLOBAL across namespaces (one donor
-        # pool, shared fairly by eviction pressure, like the PR 5 mask
+        # for bit; budget and LRU stay GLOBAL across namespaces (one entry
+        # budget, shared fairly by eviction pressure, like the PR 5 mask
         # cache's single LRU over many schemas).
         self._roots: dict[object, _Node] = {None: _Node(())}
-        # keyed by entry.key: the donor slot id for dense entries, a unique
-        # negative id for paged (page-backed) entries
-        self._by_slot: dict[int, PrefixEntry] = {}
+        self._entries: dict[int, PrefixEntry] = {}  # by entry.key
         self._cached_tokens = 0
         self._clock = 0
-        self._next_paged_key = -2  # -1 is the scheduler's "no slot" marker
+        self._next_key = 0
 
     def _root_for(self, ns) -> "_Node":
         root = self._roots.get(ns)
@@ -120,24 +114,17 @@ class PrefixCache:
     #
     # __len__ and cached_tokens read single ints / dict size — safe to call
     # from scrape threads (/metrics, /api/health) while the step loop
-    # mutates. Everything else, including pinned_slots/entries (they iterate
-    # the dict), is step-loop-thread only.
+    # mutates. Everything else, including entries() (it iterates the dict),
+    # is step-loop-thread only.
 
     def __len__(self) -> int:
-        return len(self._by_slot)
-
-    def pinned_slots(self) -> frozenset[int]:
-        """Donor SLOTS held out of the serving pool — dense entries only
-        (page-backed donors pin pages, their slots were freed at donation)."""
-        return frozenset(
-            e.slot for e in self._by_slot.values() if e.pages is None
-        )
+        return len(self._entries)
 
     def cached_tokens(self) -> int:
         return self._cached_tokens
 
     def entries(self) -> list[PrefixEntry]:
-        return list(self._by_slot.values())
+        return list(self._entries.values())
 
     # ------------------------------------------------------------------ clock
 
@@ -180,12 +167,12 @@ class PrefixCache:
     def match(self, tokens, *, max_len: int,
               ns=None) -> tuple[PrefixEntry, int] | None:
         """Longest reusable cached prefix of `tokens`: returns (entry,
-        use_len) where entry's slot holds valid KV for rows [0, use_len) and
+        use_len) where entry's pages hold valid KV for rows [0, use_len) and
         use_len is capped at `max_len` (the caller must leave at least one
         suffix token to prefill, so it passes len(tokens) - 1) and aligned
         down to the prefill-bucket quantum. None when nothing aligned and
         >= min_len is cached. Bumps the winning entry's LRU clock."""
-        if max_len < self.min_len or not self._by_slot:
+        if max_len < self.min_len or not self._entries:
             return None
         matched, node = self._walk(tokens, ns)
         if not matched:
@@ -204,7 +191,7 @@ class PrefixCache:
 
     def covers(self, tokens, ns=None) -> bool:
         """True if some entry already holds ALL of `tokens` as its head —
-        inserting them again would pin a second slot for no new coverage."""
+        inserting them again would pin pages for no new coverage."""
         matched, node = self._walk(tokens, ns)
         return matched == len(tokens) and self._any_entry(node) is not None
 
@@ -228,18 +215,15 @@ class PrefixCache:
 
     # ----------------------------------------------------------------- insert
 
-    def insert(self, tokens, slot: int,
-               pages: tuple[int, ...] | None = None,
+    def insert(self, tokens, pages: tuple[int, ...],
                ns=None) -> PrefixEntry | None:
-        """Pin a donor for prefix `tokens` in namespace `ns`: slot `slot`
-        (dense) or the pool pages `pages` (paged; pass slot=-1). Returns
-        the new entry, or None when rejected (budget full, duplicate
-        coverage, or a slot already pinned). The caller aligns/filters
-        lengths, evicts to make room first, and owns the page refcounts."""
+        """Pin the pool pages `pages` as the donor for prefix `tokens` in
+        namespace `ns`. Returns the new entry, or None when rejected (budget
+        full or duplicate coverage). The caller aligns/filters lengths,
+        evicts to make room first, and owns the page refcounts."""
         tokens = tuple(tokens)
         if (not tokens
-                or (pages is None and slot in self._by_slot)
-                or len(self._by_slot) >= self.max_entries
+                or len(self._entries) >= self.max_entries
                 or self.covers(tokens, ns)):
             return None
         node = self._root_for(ns)
@@ -264,30 +248,22 @@ class PrefixCache:
             else:
                 node = child
             pos += lcp
-        if pages is None:
-            key = slot
-        else:
-            key = self._next_paged_key
-            self._next_paged_key -= 1
-        entry = PrefixEntry(tokens=tokens, slot=slot, pages=pages,
+        key = self._next_key
+        self._next_key += 1
+        entry = PrefixEntry(tokens=tokens, pages=tuple(pages),
                             last_used=self._tick(), node=node, key=key,
                             ns=ns)
         node.entry = entry
-        self._by_slot[key] = entry
+        self._entries[key] = entry
         self._cached_tokens += entry.length
         return entry
 
     # ------------------------------------------------------------------ evict
 
-    def evict_subsumed(self, tokens, ns=None) -> list[int]:
-        """Remove entries whose tokens are a STRICT prefix of `tokens`,
-        returning their freed slots (see evict_subsumed_entries)."""
-        return [e.slot for e in self.evict_subsumed_entries(tokens, ns)]
-
     def evict_subsumed_entries(self, tokens, ns=None) -> list["PrefixEntry"]:
         """Remove entries whose tokens are a STRICT prefix of `tokens` (and
         have no in-flight readers), returning them so the caller can release
-        their donor slots / page references. Called before inserting
+        their page references. Called before inserting
         `tokens`: any query matching a shorter ancestor also matches through
         the longer entry's subtree, so the ancestor is dead weight — without
         this, each turn of a growing conversation would pin a fresh donor
@@ -312,18 +288,12 @@ class PrefixCache:
             self._remove(entry)
         return victims
 
-    def evict_lru(self) -> int | None:
-        """Remove the least-recently-used entry with no in-flight readers.
-        Returns the freed slot id (the scheduler returns it to the free
-        pool), or None when every entry is acquired."""
-        entry = self.evict_lru_entry()
-        return None if entry is None else entry.slot
-
     def evict_lru_entry(self) -> PrefixEntry | None:
-        """evict_lru returning the whole entry — the paged scheduler needs
-        the page list to release its references."""
+        """Remove and return the least-recently-used entry with no
+        in-flight readers (the scheduler releases its page references), or
+        None when every entry is acquired."""
         victim: PrefixEntry | None = None
-        for entry in self._by_slot.values():
+        for entry in self._entries.values():
             if entry.refcount:
                 continue
             if victim is None or entry.last_used < victim.last_used:
@@ -334,7 +304,7 @@ class PrefixCache:
         return victim
 
     def _remove(self, entry: PrefixEntry) -> None:
-        del self._by_slot[entry.key]
+        del self._entries[entry.key]
         self._cached_tokens -= entry.length
         node = entry.node
         entry.node = None
@@ -358,7 +328,7 @@ class PrefixCache:
 
     def clear(self) -> None:
         """Drop everything — the device KV the entries pointed at is gone
-        (engine failure path rebuilds the slot cache)."""
+        (engine failure path rebuilds the page pool)."""
         self._roots = {None: _Node(())}
-        self._by_slot.clear()
+        self._entries.clear()
         self._cached_tokens = 0

@@ -1,24 +1,22 @@
-"""Continuous-batching scheduler: prefill/decode split over a slot cache.
+"""Continuous-batching scheduler: prefill/decode split over a paged KV pool.
 
 JetStream-style serving loop, TPU-first:
-- A fixed pool of NUM_SLOTS decode slots. KV lives in one of two layouts:
-  paged (default) — a global page pool [L, PAGES, PAGE, K, D] plus a
-  per-slot block table (engine/paging.py owns the refcounted allocator), so
-  HBM is held per page of tokens actually cached and short requests no
-  longer strand slot_capacity rows each; or dense — one static-shape cache
-  [L, NUM_SLOTS, CAP, K, D], the original layout, preserved bit for bit
-  behind --kv-layout dense. Either way one compiled `decode_step` serves
-  every mix of requests — raggedness is masks and tables, never shapes.
-- New requests prefill one at a time at bucketed prompt lengths (pow2 buckets ⇒
-  a handful of compiles) and scatter straight into a free slot row
-  (`prefill_into_slots`), while other slots keep decoding between prefills.
+- A fixed pool of NUM_SLOTS decode slots. KV lives in a global page pool
+  [L, PAGES, PAGE, K, D] plus a per-slot block table (engine/paging.py owns
+  the refcounted allocator), so HBM is held per page of tokens actually
+  cached and short requests do not strand slot_capacity rows each. One
+  compiled `decode_step_paged` serves every mix of requests — raggedness is
+  masks and tables, never shapes.
+- New requests prefill at bucketed prompt lengths (pow2 buckets ⇒ a handful
+  of compiles) and scatter straight into their pages
+  (`prefill_into_pages`), while other slots keep decoding between prefills.
 - Sampling params live in device arrays indexed by slot; updated on insert.
 - The step loop runs in a dedicated thread; completions stream to waiters
   through per-request queues (asyncio- and thread-friendly).
 - Prefix KV reuse (engine/prefix_cache.py): completed requests donate their
-  slot to a refcounted radix tree keyed on prompt token ids; a later request
-  sharing a prefix copies the cached rows with one device-side slice
-  (no recompute) and chunk-prefills only the uncached suffix.
+  full pages to a refcounted radix tree keyed on prompt token ids; a later
+  request sharing a prefix references those pages in its block table
+  (no copy, no recompute) and chunk-prefills only the uncached suffix.
 - Speculative decoding (llmlb_tpu/spec, docs/speculative.md): per-slot
   prompt-lookup drafters propose up to K tokens; one batched K+1-token
   verify dispatch through the extend path scores them all, the longest
@@ -95,22 +93,6 @@ PRIORITY_NAMES = {PRIORITY_HIGH: "high", PRIORITY_NORMAL: "normal",
                   PRIORITY_LOW: "low"}
 
 
-def kv_cache_bytes(cfg, num_slots: int, slot_capacity: int) -> int:
-    """HBM footprint of the DENSE contiguous slot cache [L, slots, cap, K, D]
-    ×2 (K and V). The serving memory budget is
-        weights ≈ 2·n_params bytes (bf16)
-        kv      = L · slots · cap · K · D · 2(kv) · itemsize
-    e.g. llama-3-8b (L=32, K=8, D=128) at 8×4096: 4.3 GiB — fits v5e-4 tp
-    alongside the 16 GiB of weights; tinyllama-1.1b (L=22, K=4, D=64) at
-    16×8192: 2.95 GiB on a single chip. The default capacity is sized so a
-    4k-token prompt serves out of the box. In paged mode
-    (the default) the footprint is kv_pool_bytes instead — every slot shares
-    one page pool, so short requests no longer strand `cap` rows each."""
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    return (cfg.num_layers * num_slots * slot_capacity
-            * cfg.num_kv_heads * cfg.head_dim_ * 2 * itemsize)
-
-
 def kv_page_bytes(cfg, page_size: int, quantized: bool = False) -> int:
     """HBM bytes ONE page holds across all layers, K and V included. The
     bf16 cell is D·2 bytes per (token, head); the int8 cell is D·1 plus one
@@ -123,37 +105,25 @@ def kv_page_bytes(cfg, page_size: int, quantized: bool = False) -> int:
 
 def kv_pool_bytes(cfg, num_pages: int, page_size: int,
                   quantized: bool = False) -> int:
-    """HBM footprint of the PAGED KV pool [L, pages, page_size, K, D] ×2
-    (K and V; int8 pools add their f32 scale arrays). At the default sizing
-    (num_pages = slots · cap/page_size + 1) this matches the dense footprint
-    within one trash page — the occupancy win comes from admitting MORE
-    slots against the same pool, not from a smaller pool. Quantized pools
+    """HBM footprint of the KV page pool [L, pages, page_size, K, D] ×2
+    (K and V; int8 pools add their f32 scale arrays). The serving memory
+    budget is weights ≈ 2·n_params bytes (bf16) plus this; the default
+    sizing (num_pages = slots · cap/page_size + 1) gives every slot its full
+    capacity, e.g. llama-3-8b (L=32, K=8, D=128) at 8×4096: 4.3 GiB — the
+    occupancy win comes from admitting MORE slots against the same pool,
+    not from a smaller pool. Quantized pools
     hold ~(D+4)/2D of the bf16 bytes per page, so the same HBM budget holds
     nearly twice the pages."""
     return num_pages * kv_page_bytes(cfg, page_size, quantized)
 
 
 @partial(jax.jit, donate_argnames=("cache_k", "cache_v"))
-def _scatter_kv_row(cache_k, cache_v, k_all, v_all, slot_id):
-    """Land a context-parallel prefill's KV [L, 1, T, K, D] in row `slot_id`
-    of the slot cache [L, SLOTS, CAP, K, D] (one in-place dynamic slice; the
-    caches are donated so no copy of the full cache is made)."""
-    zero = jnp.int32(0)
-    start = (zero, slot_id, zero, zero, zero)
-    return (
-        jax.lax.dynamic_update_slice(cache_k, k_all.astype(cache_k.dtype), start),
-        jax.lax.dynamic_update_slice(cache_v, v_all.astype(cache_v.dtype), start),
-    )
-
-
-@partial(jax.jit, donate_argnames=("cache_k", "cache_v"))
 def _scatter_kv_row_paged(cache_k, cache_v, k_all, v_all, table_row):
-    """Paged counterpart of _scatter_kv_row: land a context-parallel
-    prefill's KV [L, 1, T, K, D] in the pool pages named by `table_row`
-    [PPN] (positions past the allocated pages hit the trash page — padding
-    garbage, same contract as the dense scatter's cells past the valid
-    length). Quantized pools ({"q","s"} pairs) quantize per vector on the
-    way in, scales landing at the same cells."""
+    """Land a context-parallel prefill's KV [L, 1, T, K, D] in the pool
+    pages named by `table_row` [PPN] (positions past the allocated pages hit
+    the trash page — padding garbage past the valid length; the pools are
+    donated so no copy of them is made). Quantized pools ({"q","s"} pairs)
+    quantize per vector on the way in, scales landing at the same cells."""
     from llmlb_tpu.models.llama import kv_pool_values
     from llmlb_tpu.quant import quantize_kv
 
@@ -192,29 +162,6 @@ def _write_kv_pages(cache_k, cache_v, k_new, v_new, page_idx):
         return pool.at[:, page_idx].set(new.astype(pool.dtype))
 
     return scatter(cache_k, k_new), scatter(cache_v, v_new)
-
-
-@partial(jax.jit, donate_argnames=("cache_k", "cache_v"),
-         static_argnames=("rows",))
-def _copy_kv_prefix(cache_k, cache_v, src_slot, dst_slot, rows):
-    """Prefix-cache hit: copy the first `rows` KV rows of pinned donor row
-    `src_slot` into target row `dst_slot` — one device-side
-    dynamic_update_slice per cache, no recompute, no host round trip.
-    `rows` is static (the caller pads the matched length to the next power
-    of two, bounding the jit cache at log2(capacity) variants); rows copied
-    beyond the matched prefix are overwritten by the suffix prefill or sit
-    past the valid length where every attention masks them."""
-    zero = jnp.int32(0)
-    layers, _, _, kv_heads, head_dim = cache_k.shape
-    size = (layers, 1, rows, kv_heads, head_dim)
-    src = (zero, src_slot, zero, zero, zero)
-    dst = (zero, dst_slot, zero, zero, zero)
-    blk_k = jax.lax.dynamic_slice(cache_k, src, size)
-    blk_v = jax.lax.dynamic_slice(cache_v, src, size)
-    return (
-        jax.lax.dynamic_update_slice(cache_k, blk_k, dst),
-        jax.lax.dynamic_update_slice(cache_v, blk_v, dst),
-    )
 
 
 def _sample_chunk(logits, key, temps, top_ps, top_ks, seeds, mask, start_pos):
@@ -490,25 +437,10 @@ class EngineCore:
         )
         self.eos_id = eos_id
 
-        # KV layout: "paged" (default) backs every slot with a shared page
-        # pool + per-slot block table, so HBM is held per token actually
-        # cached instead of slot_capacity rows per request; "dense" keeps the
-        # contiguous [L, slots, cap, K, D] block and the original code paths
-        # bit for bit (every paged branch below gates on `self.page_pool`).
-        if kv_layout is None:
-            kv_layout = os.environ.get("LLMLB_KV_LAYOUT", "paged")
-        if kv_layout not in ("paged", "dense"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'dense', got {kv_layout!r}"
-            )
-        if kv_layout == "paged" and not hasattr(self.family,
-                                                "prefill_into_pages"):
-            log.warning(
-                "model family %s has no paged serving path; falling back to "
-                "the dense slot cache", self.family.__name__,
-            )
-            kv_layout = "dense"
-        self.kv_layout = kv_layout
+        # There is one KV layout. The keyword stays only until its last
+        # caller outside the package drops it (ROADMAP.md, Design debts).
+        if kv_layout not in (None, "paged"):
+            raise ValueError(f"kv_layout must be 'paged', got {kv_layout!r}")
 
         # Int8 quantization (llmlb_tpu/quant, docs/quantization.md): two
         # independent knobs — per-output-channel int8 projection weights
@@ -516,13 +448,6 @@ class EngineCore:
         # OFF by default; with both knobs off every path below is the
         # pre-quantization engine bit for bit (tier-1 guarded).
         self.quant = parse_quant_mode(quantize)
-        if self.quant.kv and self.kv_layout != "paged":
-            log.warning(
-                "int8 KV quantization requires the paged layout; the dense "
-                "slot cache stays bf16 (weights quantization, if requested, "
-                "still applies)"
-            )
-            self.quant = dataclasses.replace(self.quant, kv=False)
 
         # Page size: TPU-friendly default of 128 tokens (one flash block),
         # clamped into the slot capacity. docs/kv-cache.md discusses the
@@ -531,31 +456,27 @@ class EngineCore:
                                        self.slot_capacity))
         self.pages_per_slot = -(-self.slot_capacity // self.kv_page_size)
         # Pool size resolves after the mesh exists (the per-device default
-        # depends on the dp degree); 0 until the paged cache-init block runs.
+        # depends on the dp degree); 0 until the cache-init block runs.
         self._kv_pages_arg = kv_pages
         self.kv_num_pages = 0
-        # Dense-mode prefix hits dispatch a device-side row copy; paged hits
-        # must never (zero-copy page sharing). Exposed so tests/benches can
-        # assert the paged hit path stays copy-free.
-        self.kv_copy_dispatches = 0
 
-        # Prefix KV cache: completed requests may donate their slot to a
-        # radix tree keyed on prompt token ids; later requests sharing a
-        # prefix copy the cached rows device-side and prefill only the
-        # suffix. Disabled (None) the scheduler behaves exactly as before —
-        # every new branch below is gated on `self.prefix_cache is not None`.
+        # Prefix KV cache: completed requests may donate their full pages to
+        # a radix tree keyed on prompt token ids; later requests sharing a
+        # prefix reference those pages and prefill only the suffix. Disabled
+        # (None) the scheduler behaves exactly as before — every new branch
+        # below is gated on `self.prefix_cache is not None`.
         if prefix_cache is None:
             prefix_cache = os.environ.get(
                 "LLMLB_PREFIX_CACHE", "1"
             ).lower() not in ("0", "false", "off", "no")
         # Matched lengths are aligned DOWN to the smallest prefill bucket so
         # the uncached suffix always starts on a bucket boundary (chunked
-        # prefill then runs at its existing compiled sizes). Paged mode
-        # additionally aligns to whole pages: only FULL pages can be shared
-        # zero-copy (a partially-shared page would mix two requests' rows),
-        # so the quantum is lcm(bucket, page_size).
+        # prefill then runs at its existing compiled sizes), and to whole
+        # pages: only FULL pages can be shared zero-copy (a partially-shared
+        # page would mix two requests' rows), so the quantum is
+        # lcm(bucket, page_size).
         self.prefix_align = self.prefill_buckets[0] if self.prefill_buckets else 0
-        if self.kv_layout == "paged" and self.prefix_align:
+        if self.prefix_align:
             self.prefix_align = math.lcm(self.prefix_align, self.kv_page_size)
         self.min_prefix_len = (
             max(1, int(min_prefix_len)) if min_prefix_len is not None
@@ -563,7 +484,7 @@ class EngineCore:
         )
         if prefix_cache_slots is None:
             prefix_cache_slots = max(1, num_slots // 2)
-        # pinned donors must always leave at least one slot serving traffic
+        # the entry budget: max cached prefixes, capped below the slot count
         budget = max(0, min(int(prefix_cache_slots), num_slots - 1))
         self.prefix_cache: PrefixCache | None = (
             PrefixCache(max_entries=budget, min_len=self.min_prefix_len,
@@ -669,10 +590,9 @@ class EngineCore:
                     for v in self.params.values()) / 2**30,
             )
 
-        # Paged-mode host state: the page allocator, per-slot page lists, and
-        # the block tables (host numpy mirror + device array refreshed before
-        # the next dispatch whenever a table row changes).
-        self.page_pool: PagePool | None = None
+        # Host side of the page pool: per-slot page lists and the block
+        # tables (host numpy mirror + device array refreshed before the next
+        # dispatch whenever a table row changes); the allocator follows.
         self._slot_pages: list[list[int]] = [[] for _ in range(num_slots)]
         self._block_tables = np.zeros((num_slots, self.pages_per_slot),
                                       np.int32)
@@ -687,55 +607,34 @@ class EngineCore:
         # used() figure is the distinct-page truth.
         self._prefix_pinned_pages = 0
 
-        if self.kv_layout == "paged":
-            # Default pool: the dense PER-DEVICE footprint plus the reserved
-            # trash page. The dense cache shards its slot axis over dp while
-            # the page pool replicates (pages are shared by every slot, so
-            # they must be co-resident) — sizing from the full slot count on
-            # a dp>1 mesh would multiply per-device KV HBM by dp and OOM a
-            # deployment that fit the dense layout.
-            dp = self.mesh.shape.get("dp", 1)
-            default_pages = (
-                -(-num_slots // dp) * self.pages_per_slot + 1
-            )
-            self.kv_num_pages = max(int(self._kv_pages_arg or default_pages),
-                                    self.pages_per_slot + 1)
-            if dp > 1:
-                log.info(
-                    "paged KV pool replicates over dp=%d; defaulting to the "
-                    "per-device dense budget (%d pages) — raise --kv-pages "
-                    "to trade HBM for aggregate capacity", dp,
-                    self.kv_num_pages,
-                )
-            self.page_pool = PagePool(self.kv_num_pages)
-            ck, cv = self.family.init_kv_pages(cfg, self.kv_num_pages,
-                                               self.kv_page_size,
-                                               quantized=self.quant.kv)
-            ck_sh, cv_sh = self.family.kv_pages_shardings(
-                cfg, self.mesh, quantized=self.quant.kv
-            )
-            self.cache_k = jax.device_put(ck, ck_sh)
-            self.cache_v = jax.device_put(cv, cv_sh)
+        # Default pool: every slot's full capacity PER DEVICE plus the
+        # reserved trash page. The pool replicates over dp (pages are shared
+        # by every slot, so they must be co-resident) — sizing from the full
+        # slot count on a dp>1 mesh would multiply per-device KV HBM by dp.
+        dp = self.mesh.shape.get("dp", 1)
+        default_pages = (
+            -(-num_slots // dp) * self.pages_per_slot + 1
+        )
+        self.kv_num_pages = max(int(self._kv_pages_arg or default_pages),
+                                self.pages_per_slot + 1)
+        if dp > 1:
             log.info(
-                "KV cache: paged%s, %d pages x %d tokens (%d slots, %d "
-                "pages/slot) = %.2f GiB in HBM",
-                " int8" if self.quant.kv else "",
-                self.kv_num_pages, self.kv_page_size, num_slots,
-                self.pages_per_slot,
-                kv_pool_bytes(cfg, self.kv_num_pages, self.kv_page_size,
-                              quantized=self.quant.kv) / 2**30,
+                "paged KV pool replicates over dp=%d; defaulting to the "
+                "per-device slot budget (%d pages) — raise --kv-pages "
+                "to trade HBM for aggregate capacity", dp,
+                self.kv_num_pages,
             )
-        else:
-            ck, cv = self.family.init_kv_cache(cfg, num_slots,
-                                               self.slot_capacity)
-            ck_sh, cv_sh = self.family.kv_cache_shardings(cfg, self.mesh)
-            self.cache_k = jax.device_put(ck, ck_sh)
-            self.cache_v = jax.device_put(cv, cv_sh)
-            log.info(
-                "KV cache: dense, %d slots x %d capacity = %.2f GiB in HBM",
-                num_slots, self.slot_capacity,
-                kv_cache_bytes(cfg, num_slots, self.slot_capacity) / 2**30,
-            )
+        self.page_pool = PagePool(self.kv_num_pages)
+        self.cache_k, self.cache_v = self._fresh_kv_pool()
+        log.info(
+            "KV cache: paged%s, %d pages x %d tokens (%d slots, %d "
+            "pages/slot) = %.2f GiB in HBM",
+            " int8" if self.quant.kv else "",
+            self.kv_num_pages, self.kv_page_size, num_slots,
+            self.pages_per_slot,
+            kv_pool_bytes(cfg, self.kv_num_pages, self.kv_page_size,
+                          quantized=self.quant.kv) / 2**30,
+        )
 
         # Context-parallel prefill (ring attention over the mesh sp axis):
         # built lazily per padded length; fills a long prompt's KV in ONE
@@ -836,10 +735,7 @@ class EngineCore:
             max_ngram=max(1, int(spec_ngram)),
             min_ngram=1,
         )
-        self._spec_available = hasattr(
-            self.family,
-            "verify_step_paged" if self.kv_layout == "paged" else "verify_step",
-        )
+        self._spec_available = hasattr(self.family, "verify_step_paged")
         # jitted verify wrappers per context-window bucket (verify fn +
         # per-position sampling fused into one dispatch, like _decode_many)
         self._verify_fns: dict[int, Callable] = {}
@@ -884,9 +780,9 @@ class EngineCore:
         # ONE device program — the burst scan (even at k=1) with sampling
         # inside, grammar masking via the device-resident transition table
         # (ops/grammar.py), and verify steps with in-program mask columns,
-        # last-token splice, and accept counting. Default auto: on for the
-        # paged layout, off for dense; LLMLB_FUSED_DECODE=0 keeps every
-        # legacy path bit for bit (tier-1 pinned).
+        # last-token splice, and accept counting. On by default;
+        # LLMLB_FUSED_DECODE=0 keeps every legacy path bit for bit (tier-1
+        # pinned).
         if fused_decode is None:
             env = os.environ.get("LLMLB_FUSED_DECODE", "").strip().lower()
             if env in ("1", "true", "on", "yes"):
@@ -896,10 +792,10 @@ class EngineCore:
             elif env:
                 log.warning(
                     "LLMLB_FUSED_DECODE=%r is not a boolean; using the "
-                    "auto default", env,
+                    "default (on)", env,
                 )
         if fused_decode is None:
-            fused_decode = self.kv_layout == "paged"
+            fused_decode = True
         self.fused_decode = bool(fused_decode)
         # Device grammar tables: one concatenated [rows, V] int32 next-state
         # array shared by every resident schema (row 0 = the free row).
@@ -991,18 +887,16 @@ class EngineCore:
         # KV page shipping (engine/kv_transfer.py, docs/kv-cache.md): move
         # serialized pages instead of chunk-prefill replay on handoff and
         # resume. ON by default but inert until a peer actually offers or
-        # requests a payload; requires the paged layout (dense has no page
-        # identity to ship) and a single-host combined loop — split mode
-        # moves pages in-process by block-table exchange already, and a
-        # multihost restore would desync followers whose plan wire carries
-        # no page bytes. LLMLB_KV_SHIP=0 restores today's replay-only
+        # requests a payload; requires a single-host combined loop — split
+        # mode moves pages in-process by block-table exchange already, and
+        # a multihost restore would desync followers whose plan wire
+        # carries no page bytes. LLMLB_KV_SHIP=0 restores today's replay-only
         # behavior bit for bit (tier-1 pinned).
         if kv_ship is None:
             kv_ship = os.environ.get(
                 "LLMLB_KV_SHIP", "1"
             ).lower() not in ("0", "false", "off", "no")
-        self.kv_ship = (bool(kv_ship) and self.page_pool is not None
-                        and self.coordinator is None
+        self.kv_ship = (bool(kv_ship) and self.coordinator is None
                         and self.role != "split")
         # Serialized exports captured at drain-park time, keyed by gateway
         # request id, served via POST /v1/kv/export so the gateway can move
@@ -1025,8 +919,7 @@ class EngineCore:
         self.kv_offload: KVOffloadTier | None = (
             KVOffloadTier(kv_offload_bytes)
             if (kv_offload_bytes and kv_offload_bytes > 0
-                and self.page_pool is not None and self.coordinator is None
-                and self.role != "split")
+                and self.coordinator is None and self.role != "split")
             else None
         )
         if self.kv_offload is not None:
@@ -1074,11 +967,6 @@ class EngineCore:
         if self.role == "split":
             from llmlb_tpu.disagg.split import SplitRuntime
 
-            if self.page_pool is None:
-                raise ValueError(
-                    "--role split requires the paged KV layout: the handoff "
-                    "is a block-table page-id exchange"
-                )
             if self.coordinator is not None:
                 raise ValueError(
                     "--role split is single-host only (multihost lockstep "
@@ -1142,7 +1030,6 @@ class EngineCore:
             return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
         param_shapes = {k: sharded(v) for k, v in self.params.items()}
-        paged = self.page_pool is not None
         # the caches may be quantized {"q","s"} pytrees — map per leaf
         cache_k_shapes = jax.tree.map(sharded, self.cache_k)
         cache_v_shapes = jax.tree.map(sharded, self.cache_v)
@@ -1151,10 +1038,7 @@ class EngineCore:
             plain(self._d_last_tokens),
             plain(self._d_seq_lens),
             cache_k_shapes, cache_v_shapes,
-        ]
-        if paged:
-            args.append(plain(self._d_block_tables))
-        args += [
+            plain(self._d_block_tables),
             plain(self._d_temps), plain(self._d_top_ps),
             plain(self._d_top_ks), plain(self._d_seeds),
             plain(self._key),  # split keys keep this shape/dtype
@@ -1168,19 +1052,13 @@ class EngineCore:
                     # grammar/fused-verify variants compile on first use
                     # (their tables don't exist until a schema registers)
                     self._decode_many_for(w).lower(*args).compile()
-                elif paged:
+                else:
+                    # single-step mode compiles decode_step_paged per window
                     self.family.decode_step_paged.lower(
                         param_shapes, self.cfg, plain(self._d_last_tokens),
                         plain(self._d_seq_lens), cache_k_shapes,
                         cache_v_shapes, plain(self._d_block_tables),
                         self.mesh, window=w,
-                    ).compile()
-                else:
-                    # single-step mode compiles decode_step per window too
-                    self.family.decode_step.lower(
-                        param_shapes, self.cfg, plain(self._d_last_tokens),
-                        plain(self._d_seq_lens), cache_k_shapes,
-                        cache_v_shapes, self.mesh, window=w,
                     ).compile()
             except Exception:  # pragma: no cover - best-effort warmup
                 log.exception("window %d prewarm failed (will compile "
@@ -1214,7 +1092,7 @@ class EngineCore:
                 "every configured bucket)"
             )
         # Prompts beyond the largest one-shot bucket run through chunked
-        # prefill (prefill_extend_slots); the only hard cap is slot capacity.
+        # prefill (prefill_extend_pages); the only hard cap is slot capacity.
         if n + 1 >= self.slot_capacity:
             # a refused submit must not leak a pin the service layer's
             # prepare_lora already took for this request
@@ -1592,26 +1470,24 @@ class EngineCore:
         # (the original compiled programs, bit for bit).
         self._d_lora_idx = self._on_mesh(np.zeros((n,), np.int32))
 
+    def _fresh_kv_pool(self):
+        """A zeroed K and V page pool, placed on the mesh."""
+        ck, cv = self.family.init_kv_pages(self.cfg, self.kv_num_pages,
+                                           self.kv_page_size,
+                                           quantized=self.quant.kv)
+        ck_sh, cv_sh = self.family.kv_pages_shardings(
+            self.cfg, self.mesh, quantized=self.quant.kv
+        )
+        return jax.device_put(ck, ck_sh), jax.device_put(cv, cv_sh)
+
     def _reset_caches(self) -> None:
-        if self.page_pool is not None:
-            ck, cv = self.family.init_kv_pages(self.cfg, self.kv_num_pages,
-                                               self.kv_page_size,
-                                               quantized=self.quant.kv)
-            ck_sh, cv_sh = self.family.kv_pages_shardings(
-                self.cfg, self.mesh, quantized=self.quant.kv
-            )
-            # every page mapping is void with the rebuilt pool
-            self.page_pool.reset()
-            self._slot_pages = [[] for _ in range(self.num_slots)]
-            self._block_tables[:] = 0
-            self._d_block_tables = jnp.asarray(self._block_tables)
-            self._tables_dirty = False
-        else:
-            ck, cv = self.family.init_kv_cache(self.cfg, self.num_slots,
-                                               self.slot_capacity)
-            ck_sh, cv_sh = self.family.kv_cache_shardings(self.cfg, self.mesh)
-        self.cache_k = jax.device_put(ck, ck_sh)
-        self.cache_v = jax.device_put(cv, cv_sh)
+        # every page mapping is void with the rebuilt pool
+        self.page_pool.reset()
+        self._slot_pages = [[] for _ in range(self.num_slots)]
+        self._block_tables[:] = 0
+        self._d_block_tables = jnp.asarray(self._block_tables)
+        self._tables_dirty = False
+        self.cache_k, self.cache_v = self._fresh_kv_pool()
         self._seq_lens[:] = 0
         # activation donates the per-slot arrays like the caches
         self._init_slot_state()
@@ -1671,19 +1547,13 @@ class EngineCore:
     MAX_PREFILL_GROUP = 8
 
     def _free_slots(self) -> list[int]:
-        """Slots available for new requests: unoccupied and not pinned as
-        prefix-cache donors (dense mode only — paged donors pin pages, not
-        slots, so pinned_slots() is empty there and every idle slot serves).
-        Split mode admits only into the prefill pool (the decode pool fills
-        exclusively through handoff adoption)."""
+        """Slots available for new requests: every unoccupied one (prefix
+        donors pin pages, not slots). Split mode admits only into the
+        prefill pool (the decode pool fills exclusively through handoff
+        adoption)."""
         if self.split is not None:
             return self.split.free_prefill_slots()
-        pinned = (self.prefix_cache.pinned_slots()
-                  if self.prefix_cache is not None else ())
-        return [
-            i for i, s in enumerate(self.slots)
-            if s.request is None and i not in pinned
-        ]
+        return [i for i, s in enumerate(self.slots) if s.request is None]
 
     # ------------------------------------------ priority classes / preemption
 
@@ -1818,7 +1688,7 @@ class EngineCore:
 
     def _park_slot(self, slot_id: int, reason: str = "preempt") -> None:
         """Preempt one decoding slot: release its KV (pages back to the pool
-        — parking is cheap BECAUSE the layout is paged), capture resume
+        — parking is cheap BECAUSE KV is paged), capture resume
         state on the request, and requeue it at the front of its class. The
         grammar cursor and drafter park WITH the request; a resume must
         never re-walk the FSM from its start state. `reason` tags the flight
@@ -2000,8 +1870,6 @@ class EngineCore:
         its table row at the trash page so the batched decode step's ongoing
         garbage writes for the freed row can never land in a page a new
         owner holds."""
-        if self.page_pool is None:
-            return
         pages = self._slot_pages[slot_id]
         if pages:
             for p in pages:
@@ -2171,8 +2039,7 @@ class EngineCore:
         `fresh` via the donated scatter. The page-index vector (and the
         sections) pad to the next power of two by repeating the last page —
         a duplicate scatter of identical bytes — so the jit cache stays at
-        log2(pool) variants, the same discipline as _copy_kv_prefix's
-        static rows."""
+        log2(pool) variants."""
         n = len(fresh)
         pad = 1
         while pad < n:
@@ -2290,7 +2157,7 @@ class EngineCore:
         offload tier keeps the pages host-side so a local re-activation
         restores instead of re-prefilling. Skips first_pending parks: with
         zero committed tokens the faithful resume is the replay path."""
-        if self.page_pool is None or not self._slot_pages[slot_id]:
+        if not self._slot_pages[slot_id]:
             return
         slot = self.slots[slot_id]
         if slot.first_pending or not slot.out_tokens:
@@ -2390,7 +2257,7 @@ class EngineCore:
             for p in fresh:
                 self.page_pool.unref(p)
             return
-        if cache.insert(tokens, -1, pages=tuple(fresh), ns=ns) is None:
+        if cache.insert(tokens, tuple(fresh), ns=ns) is None:
             for p in fresh:
                 self.page_pool.unref(p)
             return
@@ -2433,15 +2300,6 @@ class EngineCore:
         queued = (sum(len(q) for q in self._class_queues.values())
                   + (1 if self._held_request is not None else 0))
         free = self._free_slots()
-        if (not free and self.page_pool is None
-                and self.prefix_cache is not None and len(self.prefix_cache)):
-            # Slot pressure (dense only): live traffic beats cached prefixes —
-            # evict the LRU donor so a queued request is never starved by the
-            # cache. Paged donors never pin slots, so evicting here could not
-            # free one and would just drain the warm cache for nothing; paged
-            # PAGE pressure has its own eviction path in _try_reserve_pages.
-            if queued > 0 and self._evict_one_prefix():
-                free = self._free_slots()
         if not free and queued > 0 and self.split is None:
             # Slot-pressure preemption: a queued request of a MORE important
             # class than some decoding slot parks the least important victim
@@ -2574,49 +2432,43 @@ class EngineCore:
                                               ns=request.sampling.lora)
                 if hit is not None and not self._prefer_cp_over(hit[1], n):
                     entry, use_len = hit
-                    fresh: list[int] | None = None
-                    if self.page_pool is not None:
-                        # zero-copy hit: the shared head rides the donor's
-                        # pages; only the suffix needs fresh ones. The donor
-                        # must be pinned ACROSS the reservation — its LRU
-                        # eviction inside _try_reserve_pages would free the
-                        # very pages we are about to share (and could hand
-                        # them back as the "fresh" suffix pages).
-                        self.prefix_cache.acquire(entry)
-                        shared = use_len // self.kv_page_size
-                        fresh = self._try_reserve_pages(
-                            self._pages_for_tokens(n) - shared
-                        )
-                        self.prefix_cache.release(entry)
-                        if fresh is None:
-                            self._hold_on_pool(request)
-                            break
-                        # no eviction point between the release above and
-                        # _insert_cached's re-acquire (same thread, no pool
-                        # ops in between), so the donor cannot vanish here
+                    # zero-copy hit: the shared head rides the donor's
+                    # pages; only the suffix needs fresh ones. The donor
+                    # must be pinned ACROSS the reservation — its LRU
+                    # eviction inside _try_reserve_pages would free the
+                    # very pages we are about to share (and could hand
+                    # them back as the "fresh" suffix pages).
+                    self.prefix_cache.acquire(entry)
+                    shared = use_len // self.kv_page_size
+                    fresh = self._try_reserve_pages(
+                        self._pages_for_tokens(n) - shared
+                    )
+                    self.prefix_cache.release(entry)
+                    if fresh is None:
+                        self._hold_on_pool(request)
+                        break
+                    # no eviction point between the release above and
+                    # _insert_cached's re-acquire (same thread, no pool
+                    # ops in between), so the donor cannot vanish here
                     self._insert_cached(free.pop(0), request, entry, use_len,
                                         fresh)
                     handled = True
                     inserted += 1
                     continue
                 self.metrics.record_prefix_miss()
-            pages: list[int] | None = None
-            if self.page_pool is not None:
-                need = self._pages_for_tokens(n)
+            need = self._pages_for_tokens(n)
+            pages = self._try_reserve_pages(need)
+            # Page-pressure preemption: a more important request may
+            # park less important decoders (their pages free) until the
+            # reservation covers — a refcount walk, no KV bytes move.
+            while pages is None and self._preempt_for_pages(
+                    self._priority_of(request)):
                 pages = self._try_reserve_pages(need)
-                # Page-pressure preemption: a more important request may
-                # park less important decoders (their pages free) until the
-                # reservation covers — the paged layout makes this a
-                # refcount walk, no KV bytes move.
-                while pages is None and self._preempt_for_pages(
-                        self._priority_of(request)):
-                    pages = self._try_reserve_pages(need)
-                if pages is None:
-                    self._hold_on_pool(request)
-                    break
+            if pages is None:
+                self._hold_on_pool(request)
+                break
             slot_id = free.pop(0)
-            if self.page_pool is not None:
-                self._assign_slot_pages(slot_id, (), pages)
+            self._assign_slot_pages(slot_id, (), pages)
             if n > long_cutoff:
                 heavy = self._insert_long(slot_id, request, n)
                 handled = True
@@ -2712,20 +2564,19 @@ class EngineCore:
 
     def _insert_cached(self, slot_id: int, request: Request,
                        entry: PrefixEntry, use_len: int,
-                       fresh_pages: list[int] | None = None) -> None:
+                       fresh_pages: list[int]) -> None:
         """Prefix-cache hit insert, then _advance_prefill chunk-prefills only
         the uncached suffix (prefill_pos starts at use_len).
 
-        Paged mode is ZERO-COPY: the donor's page ids for the matched head go
+        The hit is ZERO-COPY: the donor's page ids for the matched head go
         straight into this slot's block table with a refcount bump
         (`fresh_pages`, reserved by the caller, cover the suffix) — no device
-        dispatch at all. Dense mode copies `use_len` KV rows from the pinned
-        donor slot with one device-side dynamic_update_slice per cache.
+        dispatch at all.
 
         The entry stays acquired until activation/cancellation so the donor
-        cannot be evicted and reused mid-flight (paged hits hold their own
-        page references too, but the acquire keeps eviction accounting
-        identical across layouts)."""
+        cannot be evicted mid-flight (the hit holds its own page references
+        too; the acquire keeps the entry's LRU and eviction accounting
+        honest)."""
         # Claim the slot BEFORE any dispatch (same invariant as the batch
         # path): a failed dispatch then reaches this request through
         # _fail_all — which also releases cache_entry — instead of leaving
@@ -2744,19 +2595,8 @@ class EngineCore:
         self._d_seq_lens = self._d_seq_lens.at[slot_id].set(
             self.slot_capacity - 1
         )
-        if self.page_pool is not None:
-            shared = entry.pages[: use_len // self.kv_page_size]
-            self._assign_slot_pages(slot_id, shared, fresh_pages or [])
-        else:
-            rows = 1
-            while rows < use_len:
-                rows *= 2
-            rows = min(rows, self.slot_capacity)
-            self.cache_k, self.cache_v = _copy_kv_prefix(
-                self.cache_k, self.cache_v,
-                jnp.int32(entry.slot), jnp.int32(slot_id), rows,
-            )
-            self.kv_copy_dispatches += 1
+        shared = entry.pages[: use_len // self.kv_page_size]
+        self._assign_slot_pages(slot_id, shared, fresh_pages)
         self.metrics.record_prefix_hit(use_len)
         # the uncached suffix prefills via _advance_prefill (its own
         # prefill_chunk events); this event records the reused head
@@ -2994,8 +2834,6 @@ class EngineCore:
         row and committed length never rolls back below the prompt — so one
         unref per page is exactly right and the pool's double-free guard
         stays armed."""
-        if self.page_pool is None:
-            return
         keep = self._pages_for_tokens(keep_tokens)
         row = self._slot_pages[slot_id]
         if len(row) <= keep:
@@ -3015,36 +2853,20 @@ class EngineCore:
         first_in row), columns 1.. are the model's samples per position."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
 
-        if self.page_pool is not None:
-            def run(params, ids, chunk_lens, start_pos, tables,
-                    cache_k, cache_v, temps, top_ps, top_ks, seeds, mask,
-                    key, lora_idx=None):
-                logits, cache_k, cache_v = family.verify_step_paged(
-                    params, cfg, ids, chunk_lens, start_pos, tables,
-                    cache_k, cache_v, mesh, window=window,
-                    lora_idx=lora_idx,
-                )
-                toks = _sample_chunk(logits, key, temps, top_ps, top_ks,
-                                     seeds, mask, start_pos)
-                return (jnp.concatenate([ids[:, :1], toks], axis=1),
-                        cache_k, cache_v)
-
-            return jax.jit(run, donate_argnums=(5, 6))
-
-        def run(params, ids, chunk_lens, start_pos,
-                cache_k, cache_v, temps, top_ps, top_ks, seeds, mask, key,
-                lora_idx=None):
-            slot_ids = jnp.arange(ids.shape[0], dtype=jnp.int32)
-            logits, cache_k, cache_v = family.verify_step(
-                params, cfg, ids, chunk_lens, start_pos, slot_ids,
-                cache_k, cache_v, mesh, window=window, lora_idx=lora_idx,
+        def run(params, ids, chunk_lens, start_pos, tables,
+                cache_k, cache_v, temps, top_ps, top_ks, seeds, mask,
+                key, lora_idx=None):
+            logits, cache_k, cache_v = family.verify_step_paged(
+                params, cfg, ids, chunk_lens, start_pos, tables,
+                cache_k, cache_v, mesh, window=window,
+                lora_idx=lora_idx,
             )
             toks = _sample_chunk(logits, key, temps, top_ps, top_ks,
                                  seeds, mask, start_pos)
             return (jnp.concatenate([ids[:, :1], toks], axis=1),
                     cache_k, cache_v)
 
-        return jax.jit(run, donate_argnums=(4, 5))
+        return jax.jit(run, donate_argnums=(5, 6))
 
     def _verify_for(self, window: int) -> Callable:
         with self._decode_many_lock:
@@ -3111,40 +2933,17 @@ class EngineCore:
             )
             return out, new_last, new_lens
 
-        if self.page_pool is not None:
-            def run(params, ids, chunk_lens, start_pos, tables,
-                    cache_k, cache_v, temps, top_ps, top_ks, seeds, key,
-                    last_tokens, active_mask, lens,
-                    gram_table=None, gram_state=None, lora_idx=None):
-                ids = ids.at[:, 0].set(last_tokens)
-                mask = (gram_mask(gram_table, gram_state, ids)
-                        if grammar else None)
-                logits, cache_k, cache_v = family.verify_step_paged(
-                    params, cfg, ids, chunk_lens, start_pos, tables,
-                    cache_k, cache_v, mesh, window=window,
-                    lora_idx=lora_idx,
-                )
-                toks = _sample_chunk(logits, key, temps, top_ps, top_ks,
-                                     seeds, mask, start_pos)
-                out, new_last, new_lens = finish(
-                    ids, toks, chunk_lens, start_pos, lens, last_tokens,
-                    active_mask,
-                )
-                return out, new_last, new_lens, cache_k, cache_v
-
-            return jax.jit(run, donate_argnums=(5, 6))
-
-        def run(params, ids, chunk_lens, start_pos,
+        def run(params, ids, chunk_lens, start_pos, tables,
                 cache_k, cache_v, temps, top_ps, top_ks, seeds, key,
                 last_tokens, active_mask, lens,
                 gram_table=None, gram_state=None, lora_idx=None):
             ids = ids.at[:, 0].set(last_tokens)
             mask = (gram_mask(gram_table, gram_state, ids)
                     if grammar else None)
-            slot_ids = jnp.arange(ids.shape[0], dtype=jnp.int32)
-            logits, cache_k, cache_v = family.verify_step(
-                params, cfg, ids, chunk_lens, start_pos, slot_ids,
-                cache_k, cache_v, mesh, window=window, lora_idx=lora_idx,
+            logits, cache_k, cache_v = family.verify_step_paged(
+                params, cfg, ids, chunk_lens, start_pos, tables,
+                cache_k, cache_v, mesh, window=window,
+                lora_idx=lora_idx,
             )
             toks = _sample_chunk(logits, key, temps, top_ps, top_ks,
                                  seeds, mask, start_pos)
@@ -3154,7 +2953,7 @@ class EngineCore:
             )
             return out, new_last, new_lens, cache_k, cache_v
 
-        return jax.jit(run, donate_argnums=(4, 5))
+        return jax.jit(run, donate_argnums=(5, 6))
 
     def _verify_fused_for(self, window: int, grammar: bool) -> Callable:
         with self._decode_many_lock:
@@ -3182,14 +2981,13 @@ class EngineCore:
         the open step _decode_active drafted in."""
         k1 = self.spec.max_draft_tokens + 1
         t_sync = step.mark("host_sync")
-        if self.page_pool is not None:
-            per_row = {i: len(drafts.get(i, ())) + 1 for i in active}
-            active = self._ensure_decode_pages(active, 1, per_row)
-            if not active:
-                self._clock().abandon()
-                self.metrics.set_batch_occupancy(0)
-                return True
-            self._sync_block_tables()
+        per_row = {i: len(drafts.get(i, ())) + 1 for i in active}
+        active = self._ensure_decode_pages(active, 1, per_row)
+        if not active:
+            self._clock().abandon()
+            self.metrics.set_batch_occupancy(0)
+            return True
+        self._sync_block_tables()
 
         # Chunk arrays: active rows carry [last, d1..dm]; every other row
         # (prefilling/parked/free) degenerates to a 1-token chunk writing
@@ -3266,28 +3064,16 @@ class EngineCore:
                           "gram_state": jnp.asarray(gs)} if grammar else {})
             # jnp.asarray is an H2D transfer, not a device program; the
             # column-0 last-token splice happens in-program
-            if self.page_pool is not None:
-                (toks_dev, new_last, new_lens,
-                 self.cache_k, self.cache_v) = fn(
-                    self.params, jnp.asarray(ids), jnp.asarray(chunk_lens),
-                    jnp.asarray(start_pos), self._d_block_tables,
-                    self.cache_k, self.cache_v,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_seeds, sk, self._d_last_tokens,
-                    jnp.asarray(act), self._d_seq_lens,
-                    lora_idx=lora_idx, **gram_args,
-                )
-            else:
-                (toks_dev, new_last, new_lens,
-                 self.cache_k, self.cache_v) = fn(
-                    self.params, jnp.asarray(ids), jnp.asarray(chunk_lens),
-                    jnp.asarray(start_pos),
-                    self.cache_k, self.cache_v,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_seeds, sk, self._d_last_tokens,
-                    jnp.asarray(act), self._d_seq_lens,
-                    lora_idx=lora_idx, **gram_args,
-                )
+            (toks_dev, new_last, new_lens,
+             self.cache_k, self.cache_v) = fn(
+                self.params, jnp.asarray(ids), jnp.asarray(chunk_lens),
+                jnp.asarray(start_pos), self._d_block_tables,
+                self.cache_k, self.cache_v,
+                self._d_temps, self._d_top_ps, self._d_top_ks,
+                self._d_seeds, sk, self._d_last_tokens,
+                jnp.asarray(act), self._d_seq_lens,
+                lora_idx=lora_idx, **gram_args,
+            )
             self._d_last_tokens = new_last
             self._d_seq_lens = new_lens
             dispatches = 1
@@ -3297,22 +3083,13 @@ class EngineCore:
             # the host
             ids_dev = jnp.asarray(ids).at[:, 0].set(self._d_last_tokens)
             fn = self._verify_for(window)
-            if self.page_pool is not None:
-                toks_dev, self.cache_k, self.cache_v = fn(
-                    self.params, ids_dev, jnp.asarray(chunk_lens),
-                    jnp.asarray(start_pos), self._d_block_tables,
-                    self.cache_k, self.cache_v,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_seeds, mask, sk, lora_idx=lora_idx,
-                )
-            else:
-                toks_dev, self.cache_k, self.cache_v = fn(
-                    self.params, ids_dev, jnp.asarray(chunk_lens),
-                    jnp.asarray(start_pos),
-                    self.cache_k, self.cache_v,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_seeds, mask, sk, lora_idx=lora_idx,
-                )
+            toks_dev, self.cache_k, self.cache_v = fn(
+                self.params, ids_dev, jnp.asarray(chunk_lens),
+                jnp.asarray(start_pos), self._d_block_tables,
+                self.cache_k, self.cache_v,
+                self._d_temps, self._d_top_ps, self._d_top_ks,
+                self._d_seeds, mask, sk, lora_idx=lora_idx,
+            )
             dispatches += 2  # the ids splice + the verify program
         step.mark("compute")
         jax.block_until_ready(toks_dev)
@@ -3445,10 +3222,9 @@ class EngineCore:
 
     def _release_entry_pages(self, entry: PrefixEntry) -> None:
         """Drop the prefix cache's page references of a removed entry."""
-        if self.page_pool is not None and entry.pages:
-            for p in entry.pages:
-                self.page_pool.unref(p)
-            self._prefix_pinned_pages -= len(entry.pages)
+        for p in entry.pages:
+            self.page_pool.unref(p)
+        self._prefix_pinned_pages -= len(entry.pages)
 
     def _evict_one_prefix(self) -> bool:
         entry = self.prefix_cache.evict_lru_entry()
@@ -3463,10 +3239,8 @@ class EngineCore:
 
     def _maybe_cache_prefix(self, slot_id: int, request: Request) -> None:
         """On request completion: donate this request's prompt KV when the
-        aligned head is long enough and not already covered. Dense mode pins
-        the whole slot (it leaves the serving pool until eviction); paged
-        mode pins only the PAGES covering the head — the slot itself frees
-        immediately, which is the occupancy win of the paged layout."""
+        aligned head is long enough and not already covered. Only the PAGES
+        covering the head are pinned — the slot itself frees immediately."""
         cache = self.prefix_cache
         n = len(request.prompt_ids)
         length = (n // cache.align) * cache.align
@@ -3480,8 +3254,8 @@ class EngineCore:
             cache.touch(tokens, ns)  # a re-served prefix is a use: refresh LRU
             return
         # A longer prefix subsumes its ancestors (any match they could serve
-        # routes through this entry's subtree) — reclaim their donor slots
-        # first, or each turn of a growing conversation pins a fresh slot.
+        # routes through this entry's subtree) — reclaim their entries
+        # first, or each turn of a growing conversation spends a fresh one.
         # NOT counted as evictions: coverage is preserved, and on healthy
         # multi-turn traffic this fires once per turn — charging it to
         # evictions_total would make the donor-churn signal operators alert
@@ -3490,50 +3264,36 @@ class EngineCore:
             self._release_entry_pages(stale)
         if len(cache) >= cache.max_entries and not self._evict_one_prefix():
             return
-        if self.page_pool is not None:
-            pages = tuple(
-                self._slot_pages[slot_id][: length // self.kv_page_size]
-            )
-            if not pages:
-                return
-            if cache.insert(tokens, -1, pages=pages, ns=ns) is not None:
-                for p in pages:  # the cache is now a co-owner of the head
-                    self.page_pool.ref(p)
-                self._prefix_pinned_pages += len(pages)
-                self.metrics.record_prefix_insert(length)
+        pages = tuple(
+            self._slot_pages[slot_id][: length // self.kv_page_size]
+        )
+        if not pages:
             return
-        if cache.insert(tokens, slot_id, ns=ns) is not None:
+        if cache.insert(tokens, pages, ns=ns) is not None:
+            for p in pages:  # the cache is now a co-owner of the head
+                self.page_pool.ref(p)
+            self._prefix_pinned_pages += len(pages)
             self.metrics.record_prefix_insert(length)
 
     def prefix_cache_info(self) -> dict:
         """One JSON-safe block for /api/health, /api/system, and /metrics."""
         if self.prefix_cache is None:
             return {"enabled": False}
-        pinned = len(self.prefix_cache)
-        info = {
+        return {
             "enabled": True,
-            "entries": pinned,
+            "entries": len(self.prefix_cache),
             "budget_slots": self.prefix_cache.max_entries,
             "cached_tokens": self.prefix_cache.cached_tokens(),
             "min_prefix_len": self.min_prefix_len,
             "align": self.prefix_align,
-        }
-        if self.page_pool is not None:
             # zero-copy donors pin pages, never slots; HBM held is per page
-            info["pinned_slots"] = 0
-            info["pinned_pages"] = self._prefix_pinned_pages
-            info["pinned_hbm_bytes"] = (
+            "pinned_pages": self._prefix_pinned_pages,
+            "pinned_hbm_bytes": (
                 self._prefix_pinned_pages
                 * kv_page_bytes(self.cfg, self.kv_page_size,
                                 quantized=self.quant.kv)
-            )
-        else:
-            # a pinned donor holds its whole slot row out of the serving pool
-            info["pinned_slots"] = pinned
-            info["pinned_hbm_bytes"] = (
-                pinned * kv_cache_bytes(self.cfg, 1, self.slot_capacity)
-            )
-        return info
+            ),
+        }
 
     def structured_info(self) -> dict:
         """Structured-output block for /api/system, /api/health, /metrics:
@@ -3545,24 +3305,10 @@ class EngineCore:
         return info
 
     def kv_cache_info(self) -> dict:
-        """KV memory block for /api/system, /api/health, and /metrics: the
-        dense footprint, or live page-pool utilization when paged. Gauge
-        reads are approximate under concurrent step-loop mutation (same
-        stance as every other scrape-time figure)."""
-        if self.page_pool is None:
-            return {
-                "layout": "dense",
-                "kv_dtype": str(jnp.dtype(self.cfg.dtype)),
-                # what the cache ACTUALLY serves: --quantize kv on the dense
-                # layout downgrades to bf16 with only a boot-time log line,
-                # so dashboards must read the effective dtype, not the
-                # requested knob (the HBM math differs 2x)
-                "effective_kv_dtype": str(jnp.dtype(self.cfg.dtype)),
-                "num_slots": self.num_slots,
-                "slot_capacity": self.slot_capacity,
-                "hbm_bytes": kv_cache_bytes(self.cfg, self.num_slots,
-                                            self.slot_capacity),
-            }
+        """KV memory block for /api/system, /api/health, and /metrics: live
+        page-pool utilization. Gauge reads are approximate under concurrent
+        step-loop mutation (same stance as every other scrape-time
+        figure)."""
         pool = self.page_pool
         active = 0
         active_pages = 0
@@ -3613,10 +3359,8 @@ class EngineCore:
             "mode": self.quant.mode,
             "weights_int8": self.quant.weights,
             "kv_int8": self.quant.kv,
-            # the dtype the KV cache actually stores, post any silent
-            # layout downgrade (--kv-layout dense + --quantize kv serves
-            # bf16): self.quant.kv is already False in that case, so this
-            # reads the same source of truth as the pool allocation
+            # the dtype the KV pool actually stores: the same source of
+            # truth as the pool allocation
             "effective_kv_dtype": ("int8" if self.quant.kv
                                    else str(jnp.dtype(self.cfg.dtype))),
             "param_bytes": self.param_bytes,
@@ -3731,32 +3475,19 @@ class EngineCore:
 
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
-        if self.page_pool is not None:
-            # padding rows repeat the last real slot's table row, so their
-            # duplicate scatters rewrite identical cells (same trick as ids)
-            logits, self.cache_k, self.cache_v = self.family.prefill_into_pages(
-                self.params,
-                self.cfg,
-                jnp.asarray(ids),
-                jnp.asarray(lens),
-                jnp.asarray(self._block_tables[slot_ids]),
-                self.cache_k,
-                self.cache_v,
-                self.mesh,
-                lora_idx=lora_idx,
-            )
-        else:
-            logits, self.cache_k, self.cache_v = self.family.prefill_into_slots(
-                self.params,
-                self.cfg,
-                jnp.asarray(ids),
-                jnp.asarray(lens),
-                jnp.asarray(slot_ids),
-                self.cache_k,
-                self.cache_v,
-                self.mesh,
-                lora_idx=lora_idx,
-            )
+        # padding rows repeat the last real slot's table row, so their
+        # duplicate scatters rewrite identical cells (same trick as ids)
+        logits, self.cache_k, self.cache_v = self.family.prefill_into_pages(
+            self.params,
+            self.cfg,
+            jnp.asarray(ids),
+            jnp.asarray(lens),
+            jnp.asarray(self._block_tables[slot_ids]),
+            self.cache_k,
+            self.cache_v,
+            self.mesh,
+            lora_idx=lora_idx,
+        )
         step.mark("compute")
         # jitted prefill returns futures (async dispatch); block before timing
         # or the histogram records dispatch overhead, not device execution.
@@ -3899,7 +3630,7 @@ class EngineCore:
     def _cp_prefill_into_slot(self, slot_id: int, request: Request,
                               n: int) -> None:
         """One-shot ring-attention prefill of a long prompt, scattered into
-        the slot cache row (engine wiring for
+        the slot's pages (engine wiring for
         make_context_parallel_prefill)."""
         if self._cp_prefill_fn is None:
             self._cp_prefill_fn = self.family.make_context_parallel_prefill(
@@ -3925,15 +3656,10 @@ class EngineCore:
         # KV beyond n is padding garbage; it lands in cells past the valid
         # length (masked by decode attention and overwritten as the sequence
         # grows into them) — same contract as the chunked path.
-        if self.page_pool is not None:
-            self.cache_k, self.cache_v = _scatter_kv_row_paged(
-                self.cache_k, self.cache_v, k_all, v_all,
-                jnp.asarray(self._block_tables[slot_id]),
-            )
-        else:
-            self.cache_k, self.cache_v = _scatter_kv_row(
-                self.cache_k, self.cache_v, k_all, v_all, jnp.int32(slot_id)
-            )
+        self.cache_k, self.cache_v = _scatter_kv_row_paged(
+            self.cache_k, self.cache_v, k_all, v_all,
+            jnp.asarray(self._block_tables[slot_id]),
+        )
         slot = self.slots[slot_id]
         slot.request = request
         slot.generated = 0
@@ -3982,32 +3708,18 @@ class EngineCore:
 
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
-        if self.page_pool is not None:
-            logits, self.cache_k, self.cache_v = self.family.prefill_extend_pages(
-                self.params,
-                self.cfg,
-                jnp.asarray(ids),
-                jnp.asarray([chunk_len], np.int32),
-                jnp.asarray([start], np.int32),
-                jnp.asarray(self._block_tables[slot_id:slot_id + 1]),
-                self.cache_k,
-                self.cache_v,
-                self.mesh,
-                lora_idx=lora_idx,
-            )
-        else:
-            logits, self.cache_k, self.cache_v = self.family.prefill_extend_slots(
-                self.params,
-                self.cfg,
-                jnp.asarray(ids),
-                jnp.asarray([chunk_len], np.int32),
-                jnp.asarray([start], np.int32),
-                jnp.asarray([slot_id], np.int32),
-                self.cache_k,
-                self.cache_v,
-                self.mesh,
-                lora_idx=lora_idx,
-            )
+        logits, self.cache_k, self.cache_v = self.family.prefill_extend_pages(
+            self.params,
+            self.cfg,
+            jnp.asarray(ids),
+            jnp.asarray([chunk_len], np.int32),
+            jnp.asarray([start], np.int32),
+            jnp.asarray(self._block_tables[slot_id:slot_id + 1]),
+            self.cache_k,
+            self.cache_v,
+            self.mesh,
+            lora_idx=lora_idx,
+        )
         step.mark("compute")
         jax.block_until_ready(logits)  # async dispatch; time real execution
         self.metrics.record_prefill_step(step.mark("emit") - step.t0)
@@ -4053,56 +3765,29 @@ class EngineCore:
         """Jit a k-step decode: lax.scan feeds each step's sampled tokens
         back into the next ON DEVICE, so the host syncs once per k tokens
         instead of once per token. Sampling params are scan-invariant;
-        the caches are donated (the scan carries them in place). The paged
-        variant additionally threads the (scan-invariant) block tables —
-        _ensure_decode_pages pre-allocates every page the burst will write."""
+        the caches are donated (the scan carries them in place) and the
+        block tables are scan-invariant too — _ensure_decode_pages
+        pre-allocates every page the burst will write."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
 
-        if self.page_pool is not None:
-            def many(params, last, lens, cache_k, cache_v, tables,
-                     temps, top_ps, top_ks, seeds, key, lora_idx=None):
-                keys = jax.random.split(key, k)
-
-                def body(carry, step_key):
-                    last, lens, ck, cv = carry
-                    logits, ck, cv = family.decode_step_paged(
-                        params, cfg, last, lens, ck, cv, tables, mesh,
-                        window=window, lora_idx=lora_idx,
-                    )
-                    toks = sample_tokens(logits, step_key, temps, top_ps,
-                                         top_ks, None, seeds, lens)
-                    return (toks, lens + 1, ck, cv), toks
-
-                first_in = last  # pre-burst tokens: pending first emissions
-                (last, lens, cache_k, cache_v), toks = jax.lax.scan(
-                    body, (last, lens, cache_k, cache_v), keys
-                )
-                toks = jnp.concatenate([first_in[None, :], toks], axis=0)
-                return last, lens, cache_k, cache_v, toks
-
-            return jax.jit(many, donate_argnums=(3, 4))
-
-        def many(params, last, lens, cache_k, cache_v,
+        def many(params, last, lens, cache_k, cache_v, tables,
                  temps, top_ps, top_ks, seeds, key, lora_idx=None):
             keys = jax.random.split(key, k)
 
             def body(carry, step_key):
                 last, lens, ck, cv = carry
-                logits, ck, cv = family.decode_step(
-                    params, cfg, last, lens, ck, cv, mesh, window=window,
-                    lora_idx=lora_idx,
+                logits, ck, cv = family.decode_step_paged(
+                    params, cfg, last, lens, ck, cv, tables, mesh,
+                    window=window, lora_idx=lora_idx,
                 )
-                toks = sample_tokens(logits, step_key, temps, top_ps, top_ks,
-                                     None, seeds, lens)
+                toks = sample_tokens(logits, step_key, temps, top_ps,
+                                     top_ks, None, seeds, lens)
                 return (toks, lens + 1, ck, cv), toks
 
             first_in = last  # pre-burst tokens: pending first emissions
             (last, lens, cache_k, cache_v), toks = jax.lax.scan(
                 body, (last, lens, cache_k, cache_v), keys
             )
-            # One fetchable array [k+1, SLOTS]: row 0 carries the pre-burst
-            # last tokens so newly activated slots' first tokens ride the
-            # same host readback as the burst output.
             toks = jnp.concatenate([first_in[None, :], toks], axis=0)
             return last, lens, cache_k, cache_v, toks
 
@@ -4112,11 +3797,10 @@ class EngineCore:
         """Fetch/build a jit-wrapped step program through the process-wide
         _PROGRAM_CACHE so engines sharing a config reuse one executable
         set. The build key is everything the trace closes over (family,
-        cfg, mesh, layout, plus the caller's k/window/variant in `extra`);
+        cfg, mesh, plus the caller's k/window/variant in `extra`);
         array shapes (slots, pages, quantized-or-not pytrees) go through
         jit's own shape-keyed cache per call, not the build key."""
-        key = (kind, id(self.family), id(self.cfg), self.mesh,
-               self.page_pool is not None) + extra
+        key = (kind, id(self.family), id(self.cfg), self.mesh) + extra
         with _PROGRAM_CACHE_LOCK:
             hit = _PROGRAM_CACHE.get(key)
         if hit is None:
@@ -4148,43 +3832,16 @@ class EngineCore:
         the unconstrained sampling path."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
 
-        if self.page_pool is not None:
-            def many(params, last, lens, cache_k, cache_v, tables,
-                     temps, top_ps, top_ks, seeds, key, gram_table,
-                     gram_state, lora_idx=None):
-                keys = jax.random.split(key, k)
-
-                def body(carry, step_key):
-                    last, lens, gs, ck, cv = carry
-                    logits, ck, cv = family.decode_step_paged(
-                        params, cfg, last, lens, ck, cv, tables, mesh,
-                        window=window, lora_idx=lora_idx,
-                    )
-                    bias = grammar_bias(gram_table, gs)
-                    toks = sample_tokens(logits, step_key, temps, top_ps,
-                                         top_ks, bias, seeds, lens)
-                    gs = grammar_advance(gram_table, gs, toks)
-                    return (toks, lens + 1, gs, ck, cv), toks
-
-                first_in = last  # pre-burst tokens: pending first emissions
-                (last, lens, _, cache_k, cache_v), toks = jax.lax.scan(
-                    body, (last, lens, gram_state, cache_k, cache_v), keys
-                )
-                toks = jnp.concatenate([first_in[None, :], toks], axis=0)
-                return last, lens, cache_k, cache_v, toks
-
-            return jax.jit(many, donate_argnums=(3, 4))
-
-        def many(params, last, lens, cache_k, cache_v,
-                 temps, top_ps, top_ks, seeds, key, gram_table, gram_state,
-                 lora_idx=None):
+        def many(params, last, lens, cache_k, cache_v, tables,
+                 temps, top_ps, top_ks, seeds, key, gram_table,
+                 gram_state, lora_idx=None):
             keys = jax.random.split(key, k)
 
             def body(carry, step_key):
                 last, lens, gs, ck, cv = carry
-                logits, ck, cv = family.decode_step(
-                    params, cfg, last, lens, ck, cv, mesh, window=window,
-                    lora_idx=lora_idx,
+                logits, ck, cv = family.decode_step_paged(
+                    params, cfg, last, lens, ck, cv, tables, mesh,
+                    window=window, lora_idx=lora_idx,
                 )
                 bias = grammar_bias(gram_table, gs)
                 toks = sample_tokens(logits, step_key, temps, top_ps,
@@ -4250,15 +3907,14 @@ class EngineCore:
         else:
             step = clock.begin("host_sync")
             t_sync = step.t0
-        if self.page_pool is not None:
-            # alloc-on-extend: every page this dispatch writes must exist
-            # before the tables ship to the device
-            active = self._ensure_decode_pages(active, self.decode_burst)
-            if not active:
-                clock.abandon()
-                self.metrics.set_batch_occupancy(0)
-                return True  # pool exhaustion finished requests: work done
-            self._sync_block_tables()
+        # alloc-on-extend: every page this dispatch writes must exist
+        # before the tables ship to the device
+        active = self._ensure_decode_pages(active, self.decode_burst)
+        if not active:
+            clock.abandon()
+            self.metrics.set_batch_occupancy(0)
+            return True  # pool exhaustion finished requests: work done
+        self._sync_block_tables()
 
         self._key, sk = jax.random.split(self._key)
         k = self.decode_burst
@@ -4300,22 +3956,13 @@ class EngineCore:
             fn = (self._decode_many_gram_for(window) if grammar
                   else self._decode_many_for(window))
             step.mark("dispatch")
-            if self.page_pool is not None:
-                (self._d_last_tokens, self._d_seq_lens, self.cache_k,
-                 self.cache_v, toks_dev) = fn(
-                    self.params, self._d_last_tokens, self._d_seq_lens,
-                    self.cache_k, self.cache_v, self._d_block_tables,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_seeds, sk, lora_idx=lora_idx, **gram_args,
-                )
-            else:
-                (self._d_last_tokens, self._d_seq_lens, self.cache_k,
-                 self.cache_v, toks_dev) = fn(
-                    self.params, self._d_last_tokens, self._d_seq_lens,
-                    self.cache_k, self.cache_v,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_seeds, sk, lora_idx=lora_idx, **gram_args,
-                )
+            (self._d_last_tokens, self._d_seq_lens, self.cache_k,
+             self.cache_v, toks_dev) = fn(
+                self.params, self._d_last_tokens, self._d_seq_lens,
+                self.cache_k, self.cache_v, self._d_block_tables,
+                self._d_temps, self._d_top_ps, self._d_top_ks,
+                self._d_seeds, sk, lora_idx=lora_idx, **gram_args,
+            )
             step.mark("compute")
             # split device execution from the D2H readback: the dispatch
             # returned futures, block_until_ready is the compute wait, the
@@ -4338,31 +3985,18 @@ class EngineCore:
 
         first_in = self._d_last_tokens  # pre-step tokens: pending firsts
         step.mark("dispatch")
-        if self.page_pool is not None:
-            logits, self.cache_k, self.cache_v = self.family.decode_step_paged(
-                self.params,
-                self.cfg,
-                self._d_last_tokens,
-                self._d_seq_lens,
-                self.cache_k,
-                self.cache_v,
-                self._d_block_tables,
-                self.mesh,
-                window=self._window_for(active, 1),
-                lora_idx=lora_idx,
-            )
-        else:
-            logits, self.cache_k, self.cache_v = self.family.decode_step(
-                self.params,
-                self.cfg,
-                self._d_last_tokens,
-                self._d_seq_lens,
-                self.cache_k,
-                self.cache_v,
-                self.mesh,
-                window=self._window_for(active, 1),
-                lora_idx=lora_idx,
-            )
+        logits, self.cache_k, self.cache_v = self.family.decode_step_paged(
+            self.params,
+            self.cfg,
+            self._d_last_tokens,
+            self._d_seq_lens,
+            self.cache_k,
+            self.cache_v,
+            self._d_block_tables,
+            self.mesh,
+            window=self._window_for(active, 1),
+            lora_idx=lora_idx,
+        )
         mask = None
         if constrained_active:
             step.mark("host_sync")
@@ -4502,8 +4136,7 @@ class EngineCore:
 
         if finish is not None:
             request.finished_at = time.monotonic()
-            if (finish == "length" and request.export_kv and self.kv_ship
-                    and self.page_pool is not None):
+            if finish == "length" and request.export_kv and self.kv_ship:
                 # Handoff export: serialize this stream's KV pages D2H
                 # BEFORE the pool frees them below — the adopter lands
                 # them and continues with zero prefill dispatches. Only
@@ -4519,10 +4152,9 @@ class EngineCore:
             self._release_lora(request)
             if self.prefix_cache is not None:
                 # Donor retention: the freed slot's rows [0, prompt_len) hold
-                # exactly the prompt's KV — pin them for prefix reuse instead
-                # of discarding. Dense mode retains the whole slot (out of
-                # the free pool until evicted); paged mode pins only the
-                # head's pages and the slot frees immediately below.
+                # exactly the prompt's KV — pin the head's pages for prefix
+                # reuse instead of discarding; the slot frees immediately
+                # below.
                 self._maybe_cache_prefix(slot_id, request)
             self._free_slot_kv(slot_id)
             self._clear_constraint(slot_id)

@@ -634,8 +634,7 @@ class EngineAPI:
                 "tpu_engine": True,
                 "model": self.engine.model_id,
                 "prefix_cache": self.engine.core.prefix_cache_info(),
-                # paged mode reports live page-pool utilization; dense mode
-                # the static slot-cache footprint
+                # live page-pool utilization
                 "kv_cache": self.engine.core.kv_cache_info(),
                 # int8 quantization knobs + honest byte footprints
                 "quant": self.engine.core.quant_info(),
@@ -1551,7 +1550,7 @@ def main(argv: list[str] | None = None) -> None:
     parser.add_argument("--port", type=int, default=8100)
     parser.add_argument("--num-slots", type=int, default=8)
     # Default sized so a 4k-token prompt serves out of the box via chunked
-    # prefill. Memory math: scheduler.kv_cache_bytes —
+    # prefill. Memory math: scheduler.kv_pool_bytes —
     # 8 slots x 4096 is 4.3 GiB for llama-3-8b, 1.5 GiB for tinyllama-1.1b.
     # EngineCore clamps to the model's max_position_embeddings.
     parser.add_argument("--slot-capacity", type=int, default=4096)
@@ -1581,22 +1580,14 @@ def main(argv: list[str] | None = None) -> None:
              "0 disables; also via LLMLB_INIT_TIMEOUT)",
     )
     parser.add_argument(
-        "--kv-layout", choices=("paged", "dense"), default=None,
-        help="KV cache layout (default paged; also via LLMLB_KV_LAYOUT): "
-             "'paged' backs all slots with one shared page pool + block "
-             "tables so HBM is held per token cached; 'dense' reserves "
-             "slot-capacity rows per slot (the pre-paging layout, bit for "
-             "bit)",
-    )
-    parser.add_argument(
         "--kv-page-size", type=int, default=None,
-        help="tokens per KV page in paged mode (default 128; see "
+        help="tokens per KV page (default 128; see "
              "docs/kv-cache.md for the waste-vs-overhead tradeoff)",
     )
     parser.add_argument(
         "--kv-pages", type=int, default=None,
-        help="total pages in the paged pool (default: num_slots x "
-             "slot_capacity worth — the dense HBM budget; raise num_slots "
+        help="total pages in the KV pool (default: num_slots x "
+             "slot_capacity worth; raise num_slots "
              "against the same pool to serve more concurrent short "
              "requests)",
     )
@@ -1604,7 +1595,7 @@ def main(argv: list[str] | None = None) -> None:
         "--quantize", choices=("off", "weights", "kv", "all"), default=None,
         help="int8 quantization (default off; also via LLMLB_QUANTIZE): "
              "'weights' = per-output-channel int8 projection matrices, "
-             "'kv' = int8 KV pages + per-vector scales (paged layout only), "
+             "'kv' = int8 KV pages + per-vector scales, "
              "'all' = both — halves the HBM bytes each covers "
              "(docs/quantization.md); bf16 output is bit-identical when off",
     )
@@ -1669,8 +1660,8 @@ def main(argv: list[str] | None = None) -> None:
     )
     parser.add_argument(
         "--prefix-cache-slots", type=int, default=None,
-        help="max decode slots pinned as prefix donors "
-             "(default num_slots // 2, always leaving one serving slot)",
+        help="max cached prefixes (prefix-cache entries; donors pin pages, "
+             "not slots; default num_slots // 2, capped at num_slots - 1)",
     )
     parser.add_argument(
         "--min-prefix-len", type=int, default=None,
@@ -1703,8 +1694,6 @@ def main(argv: list[str] | None = None) -> None:
         extra["decode_burst"] = max(1, args.decode_burst)
     if args.prefill_chunk_budget is not None:
         extra["prefill_chunk_budget"] = max(0, args.prefill_chunk_budget)
-    if args.kv_layout is not None:
-        extra["kv_layout"] = args.kv_layout
     if args.kv_page_size is not None:
         extra["kv_page_size"] = max(1, args.kv_page_size)
     if args.kv_pages is not None:

@@ -462,8 +462,8 @@ class Engine:
         kv_restore = None
         if kv_pages is not None:
             if not core.kv_ship:
-                # this engine cannot land pages (knob off, dense layout,
-                # multihost, split prefill role): replay, with the reason
+                # this engine cannot land pages (knob off, multihost,
+                # split prefill role): replay, with the reason
                 core.metrics.record_kv_ship_fallback("disabled")
             elif not committed_ids:
                 # zero committed tokens: the faithful continuation is the

@@ -287,8 +287,8 @@ endpoints guide
     "serving": """\
 serving guide (tpu:// engine)
   - python -m llmlb_tpu.engine.server --preset llama-3-8b --checkpoint DIR
-  - continuous batching over slot cache; chunked prefill beyond the largest
-    bucket; --slot-capacity 4096 default (see scheduler.kv_cache_bytes)
+  - continuous batching over a paged KV pool; chunked prefill beyond the
+    largest bucket; --slot-capacity 4096 default (see scheduler.kv_pool_bytes)
   - multi-host: LLMLB_COORDINATOR/LLMLB_NUM_HOSTS/LLMLB_HOST_ID (leader
     serves HTTP, followers run the lockstep loop)
   - metrics: GET /metrics (Prometheus), GET /api/health (JSON).""",

@@ -1,21 +1,23 @@
 """Model families served by the tpu:// engine.
 
 `family_for(cfg)` resolves the function module (init_params / param_shardings /
-kv_cache_shardings / init_kv_cache / prefill / prefill_into_slots / decode_step
-— one shared serving contract) for a config, so the engine scheduler is
-family-agnostic: dense Llama-class (llama.py) and sparse-MoE Mixtral-class
-(mixtral.py) plug into the same continuous-batching loop.
+init_kv_pages / kv_pages_shardings / prefill_into_pages / prefill_extend_pages
+/ verify_step_paged / decode_step_paged — one shared serving contract over the
+paged KV pool) for a config, so the engine scheduler is family-agnostic: dense
+Llama-class (llama.py) and sparse-MoE Mixtral-class (mixtral.py) plug into the
+same continuous-batching loop.
 """
 
 from llmlb_tpu.models.llama import (
     LlamaConfig,
     init_params,
     param_shardings,
-    kv_cache_shardings,
-    init_kv_cache,
-    prefill,
-    prefill_into_slots,
-    decode_step,
+    kv_pages_shardings,
+    init_kv_pages,
+    prefill_into_pages,
+    prefill_extend_pages,
+    verify_step_paged,
+    decode_step_paged,
 )
 
 
@@ -35,9 +37,10 @@ __all__ = [
     "family_for",
     "init_params",
     "param_shardings",
-    "kv_cache_shardings",
-    "init_kv_cache",
-    "prefill",
-    "prefill_into_slots",
-    "decode_step",
+    "kv_pages_shardings",
+    "init_kv_pages",
+    "prefill_into_pages",
+    "prefill_extend_pages",
+    "verify_step_paged",
+    "decode_step_paged",
 ]

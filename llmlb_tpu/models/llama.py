@@ -5,15 +5,18 @@ TPU-first design decisions:
 - All layers are *stacked* along a leading axis. Prefill/extend iterate them
   with `lax.scan` (one layer compiles once — prefill compile time stays flat
   even for 80-layer configs); decode UNROLLS the loop so each layer updates
-  the donated KV cache in place at a static index — scanning the cache
-  materialized full-cache copies per layer under the engine's burst scan
-  (see _decode_impl).
-- Serving-shaped entry points: `prefill` (bucketed [B, T] prompts into fresh KV
-  slots) and `decode_step` ([B] one token per slot). Both have fully static
-  shapes; raggedness is carried by `prompt_lens` / `seq_lens` masks.
-- Sharding is expressed once in `param_shardings` / `kv_cache_shardings` using
+  the donated KV pool in place at a static index — scanning the pool
+  materialized full-pool copies per layer under the engine's burst scan
+  (see _decode_paged_impl).
+- Serving-shaped entry points over one KV layout, a global page pool
+  addressed through per-row block tables: `prefill_into_pages` (bucketed
+  [B, T] prompts), `prefill_extend_pages` (chunked append),
+  `verify_step_paged` (speculative verify) and `decode_step_paged` ([B] one
+  token per row). All have fully static shapes; raggedness is carried by
+  `prompt_lens` / `seq_lens` masks.
+- Sharding is expressed once in `param_shardings` / `kv_pages_shardings` using
   logical axes (parallel/sharding.py) — Megatron-style tp over heads/ffn/vocab,
-  dp over the batch/slot axis.
+  dp over the batch axis (the pool replicates over dp).
 
 The reference does no inference in-process (SURVEY.md L0: external runtimes over
 HTTP); this model family is the in-tree `tpu://` engine's compute core per the
@@ -31,11 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from llmlb_tpu.ops.attention import (
-    gqa_attention_decode,
-    gqa_attention_extend,
-    gqa_attention_prefill,
-)
+from llmlb_tpu.ops.attention import gqa_attention_prefill
 from llmlb_tpu.ops.norms import rms_norm
 from llmlb_tpu.ops.rope import RopeScaling, apply_rope, rope_frequencies
 from llmlb_tpu.parallel.mesh import validate_tp
@@ -220,26 +219,6 @@ def param_shardings(cfg: LlamaConfig, mesh: Mesh, rules: ShardingRules | None = 
 
 
 # ---------------------------------------------------------------------------
-# KV cache (slot-based: [L, B_slots, S_capacity, K, D])
-# ---------------------------------------------------------------------------
-
-def init_kv_cache(
-    cfg: LlamaConfig, num_slots: int, capacity: int, dtype=None
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    shape = (cfg.num_layers, num_slots, capacity, cfg.num_kv_heads, cfg.head_dim_)
-    dtype = dtype or cfg.dtype
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-
-
-def kv_cache_shardings(cfg: LlamaConfig, mesh: Mesh, rules: ShardingRules | None = None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    sharding = logical_to_sharding(
-        mesh, rules, "layers", "batch", "seq", "kv_heads", "head_dim"
-    )
-    return (sharding, sharding)
-
-
-# ---------------------------------------------------------------------------
 # Paged KV cache (global pool: [L, P_pages, page_size, K, D] + block tables)
 # ---------------------------------------------------------------------------
 
@@ -272,8 +251,8 @@ def kv_pages_shardings(cfg: LlamaConfig, mesh: Mesh,
                        rules: ShardingRules | None = None,
                        quantized: bool = False):
     """Pages are shared across slots, so the page axis cannot shard over dp
-    the way dense slots do (one sequence's pages must stay co-resident);
-    only the kv-head axis splits (tp), pages replicate over dp. Quantized
+    (one sequence's pages must stay co-resident); only the kv-head axis
+    splits (tp), pages replicate over dp. Quantized
     pools shard their scale arrays along the same axes minus head_dim."""
     rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
     sharding = logical_to_sharding(
@@ -316,9 +295,8 @@ def _write_pool_layer(pool, layer_idx, page, off, kv):
 
 def make_write_kv_pages(block_tables: jnp.ndarray, page_size: int):
     """KV write that scatters token rows through the block table into the
-    global page pool — the paged counterpart of make_write_kv_slots.
-    `positions` are logical per-row positions; page block_tables[b, p//PS],
-    offset p%PS is the physical cell."""
+    global page pool. `positions` are logical per-row positions; page
+    block_tables[b, p//PS], offset p%PS is the physical cell."""
 
     def write_kv(pool, kv, positions):
         page = jnp.take_along_axis(block_tables, positions // page_size,
@@ -443,35 +421,19 @@ def _default_mlp_fn(lp: Params, h: jnp.ndarray, token_valid,
     return _mlp(lp, h, lora_idx)
 
 
-def _write_kv_fresh(cache, kv, positions):
-    """KV write for prefill into fresh per-request slots (rows 0..B)."""
-    return lax.dynamic_update_slice(cache, kv.astype(cache.dtype),
-                                    (0, 0, 0, 0))
-
-
-def make_write_kv_slots(slot_ids: jnp.ndarray):
-    """KV write that scatters prompts into rows `slot_ids` of the engine's
-    live slot cache — the continuous-batching insert path."""
-
-    def write_kv(cache, kv, positions):
-        return cache.at[slot_ids[:, None], positions].set(
-            kv.astype(cache.dtype)
-        )
-
-    return write_kv
-
-
-def _prefill_impl(params, cfg, input_ids, prompt_lens, cache_k, cache_v, write_kv,
-                  *, stacked_names=None, mlp_fn=_default_mlp_fn,
-                  lora_idx=None):
+def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
+                  cache_k, cache_v, *, stacked_names=None,
+                  mlp_fn=_default_mlp_fn, lora_idx=None):
     """Shared prefill body for every model family.
 
-    `write_kv(cache, new_kv, positions)` places K/V; `mlp_fn(lp, h,
+    K/V scatter through `block_tables` into the page pool; `mlp_fn(lp, h,
     token_valid, lora_idx)` is the per-family feed-forward (dense SwiGLU
     here, routed experts for mixtral — token_valid marks non-padding tokens
     so MoE routing can ignore padding). `lora_idx` ([B] int32, optional)
     selects each row's adapter pool slot (docs/lora.md)."""
     b, t = input_ids.shape
+    write_kv = make_write_kv_pages(block_tables,
+                                   kv_pool_values(cache_k).shape[2])
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
     token_valid = positions < prompt_lens[:, None]  # [B, T]
@@ -501,196 +463,6 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, cache_k, cache_v, write_k
     return logits, cache_k, cache_v
 
 
-def _decode_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
-                 *, stacked_names=None, mlp_fn=_default_mlp_fn, window=None,
-                 lora_idx=None):
-    """Shared one-token decode body for every model family.
-
-    The layer loop is UNROLLED (static layer indices) rather than a
-    lax.scan with the caches as scan inputs/outputs. Scanning the cache
-    slices it per layer and re-stacks the outputs into fresh buffers, and
-    under the engine's k-step burst scan XLA materialized full-cache copies
-    every layer — measured 40 ms/step on a v5e for a 2 GiB model whose
-    weight-streaming bound is ~3 ms (bench_runs/r03_tpu_burst8.json, taken
-    BEFORE this change; the unrolled loop has no chip time on record).
-    Unrolled,
-    each layer does one [B,1,K,D] scatter into the donated full cache at a
-    static layer index and reads a static slice for attention. XLA keeps
-    that slice in place only for an XLA reader, which it can fuse the slice
-    into; a Pallas kernel takes whole buffers as operands, so under the
-    Pallas dispatch `cache_k[layer_idx]` is expected to be copied on every
-    call, as the paged pool's slice was (PERF.md §6, PR 25: 105 MB a layer,
-    a third of the decode step, until _decode_paged_impl stopped slicing).
-    This dense-slot path has had no chip time since and no benchmark cell,
-    so that copy is unmeasured here. Decode programs are tiny, so L× code
-    growth is cheap."""
-    b = input_ids.shape[0]
-    capacity = cache_k.shape[2]
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    # Freed slots keep counting on device; clamp so their garbage writes stay
-    # inside the (ignored) row instead of relying on scatter OOB semantics.
-    write_pos = jnp.minimum(seq_lens, capacity - 1)
-    positions = write_pos[:, None]  # [B, 1]
-    batch_idx = jnp.arange(b)
-
-    x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
-    names = _with_scales(params, stacked_names or _layer_stacked_names(cfg))
-
-    for layer_idx in range(cfg.num_layers):
-        lp = {n: params[n][layer_idx] for n in names}
-
-        def attn_fn(q, k, v, layer_idx=layer_idx):
-            nonlocal cache_k, cache_v  # write precedes attention over the cache
-            cache_k = cache_k.at[layer_idx, batch_idx, write_pos].set(
-                k[:, 0].astype(cache_k.dtype)
-            )
-            cache_v = cache_v.at[layer_idx, batch_idx, write_pos].set(
-                v[:, 0].astype(cache_v.dtype)
-            )
-            return gqa_attention_decode(
-                q, cache_k[layer_idx], cache_v[layer_idx], write_pos + 1,
-                window=window,
-            )
-
-        x, _, _ = _attn_block(cfg, lp, x, positions, inv_freq, attn_fn,
-                              lora_idx)
-        h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
-        x = x + mlp_fn(lp, h, None, lora_idx)
-
-    logits = _unembed(cfg, params, x[:, 0])
-    return logits, cache_k, cache_v
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"),
-         donate_argnames=("cache_k", "cache_v"))
-def prefill(
-    params: Params,
-    cfg: LlamaConfig,
-    input_ids: jnp.ndarray,  # [B, T] int32, right-padded
-    prompt_lens: jnp.ndarray,  # [B] int32
-    cache_k: jnp.ndarray,  # [L, B, S, K, D] — fresh slots, written at [0:T]
-    cache_v: jnp.ndarray,
-    mesh: Mesh | None = None,  # unused (GSPMD shards via param placement);
-    # accepted so all model families share one serving-call signature
-    lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
-):
-    """Prefill B prompts into their KV slots. Returns (last_logits [B, V] fp32,
-    cache_k, cache_v)."""
-    return _prefill_impl(
-        params, cfg, input_ids, prompt_lens, cache_k, cache_v, _write_kv_fresh,
-        lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"),
-         donate_argnames=("cache_k", "cache_v"))
-def prefill_into_slots(
-    params: Params,
-    cfg: LlamaConfig,
-    input_ids: jnp.ndarray,  # [B, T] int32, right-padded
-    prompt_lens: jnp.ndarray,  # [B] int32
-    slot_ids: jnp.ndarray,  # [B] int32 — target rows in the global slot cache
-    cache_k: jnp.ndarray,  # [L, NUM_SLOTS, CAP, K, D] — the engine's live cache
-    cache_v: jnp.ndarray,
-    mesh: Mesh | None = None,  # unused; shared family signature
-    lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
-):
-    """Prefill B prompts and scatter their KV into rows `slot_ids` of the live
-    slot cache — the continuous-batching insert path (new requests land in freed
-    slots while other slots keep decoding). Returns (last_logits [B, V] fp32,
-    cache_k, cache_v)."""
-    return _prefill_impl(
-        params, cfg, input_ids, prompt_lens, cache_k, cache_v,
-        make_write_kv_slots(slot_ids), lora_idx=lora_idx,
-    )
-
-
-def _prefill_extend_impl(params, cfg, input_ids, chunk_lens, start_pos, slot_ids,
-                         cache_k, cache_v, *, stacked_names=None,
-                         mlp_fn=_default_mlp_fn, all_logits=False, window=None,
-                         lora_idx=None):
-    """Shared chunked-prefill body: process a [B, T] chunk of prompt tokens
-    whose slots already hold `start_pos` tokens of KV. Queries attend over the
-    full slot row (earlier chunks + causal within this chunk). Backs long
-    prompts that exceed the one-shot prefill buckets, and — with
-    `all_logits=True` — the speculative verify step, which needs logits at
-    EVERY chunk position, not just the last. `window` (static) bounds how
-    much of the capacity axis attention reads, same contract as decode.
-
-    Padding tokens (i >= chunk_lens) write garbage K/V at positions beyond the
-    chunk; those cells sit past the valid range (masked by every later
-    attention) and are overwritten in place when the sequence grows into them.
-    """
-    _, t = input_ids.shape
-    capacity = cache_k.shape[2]
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    offs = jnp.arange(t, dtype=jnp.int32)[None, :]
-    positions = start_pos[:, None] + offs  # [B, T] global positions
-    write_pos = jnp.minimum(positions, capacity - 1)
-    token_valid = offs < chunk_lens[:, None]  # [B, T]
-
-    x = params["embed"][input_ids]  # [B, T, E]
-    stacked = {n: params[n] for n in _with_scales(
-        params, stacked_names or _layer_stacked_names(cfg))}
-
-    def layer(carry_x, layer_in):
-        lp, ck, cv = layer_in
-
-        def attn_fn(q, k, v):
-            nonlocal ck, cv  # cache write precedes attention over the cache
-            ck = ck.at[slot_ids[:, None], write_pos].set(k.astype(ck.dtype))
-            cv = cv.at[slot_ids[:, None], write_pos].set(v.astype(cv.dtype))
-            k_rows, v_rows = ck[slot_ids], cv[slot_ids]
-            if window is not None and window < capacity:
-                k_rows = lax.slice_in_dim(k_rows, 0, window, axis=1)
-                v_rows = lax.slice_in_dim(v_rows, 0, window, axis=1)
-            return gqa_attention_extend(
-                q, k_rows, v_rows, positions, chunk_lens
-            )
-
-        carry_x, _, _ = _attn_block(cfg, lp, carry_x, positions, inv_freq,
-                                    attn_fn, lora_idx)
-        h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
-        carry_x = carry_x + mlp_fn(lp, h, token_valid, lora_idx)
-        return carry_x, (ck, cv)
-
-    x, (cache_k, cache_v) = lax.scan(layer, x, (stacked, cache_k, cache_v))
-
-    if all_logits:
-        b = x.shape[0]
-        logits = _unembed(cfg, params, x.reshape(b * t, -1)).reshape(b, t, -1)
-        return logits, cache_k, cache_v
-    last = jnp.maximum(chunk_lens - 1, 0)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, E]
-    logits = _unembed(cfg, params, x_last)
-    return logits, cache_k, cache_v
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"),
-         donate_argnames=("cache_k", "cache_v"))
-def prefill_extend_slots(
-    params: Params,
-    cfg: LlamaConfig,
-    input_ids: jnp.ndarray,  # [B, T] int32, right-padded chunk
-    chunk_lens: jnp.ndarray,  # [B] int32 — valid tokens in this chunk
-    start_pos: jnp.ndarray,  # [B] int32 — tokens already in the slot's cache
-    slot_ids: jnp.ndarray,  # [B] int32 — target rows in the global slot cache
-    cache_k: jnp.ndarray,  # [L, NUM_SLOTS, CAP, K, D]
-    cache_v: jnp.ndarray,
-    mesh: Mesh | None = None,  # unused; shared family signature
-    lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
-):
-    """Chunked prefill: append a chunk of prompt tokens to slots that already
-    hold `start_pos` tokens, attending over everything so far. Lets the engine
-    serve prompts far beyond the one-shot prefill buckets while decode steps
-    interleave between chunks. Returns (chunk-last logits [B, V] fp32, caches).
-    """
-    return _prefill_extend_impl(
-        params, cfg, input_ids, chunk_lens, start_pos, slot_ids,
-        cache_k, cache_v, lora_idx=lora_idx,
-    )
-
-
 @partial(jax.jit, static_argnames=("cfg", "mesh"),
          donate_argnames=("cache_k", "cache_v"))
 def prefill_into_pages(
@@ -705,7 +477,8 @@ def prefill_into_pages(
     lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
 ):
     """Prefill B prompts and scatter their KV through the block tables into
-    the global page pool — the paged counterpart of prefill_into_slots.
+    the global page pool — the continuous-batching insert path (new
+    requests land in free pages while other rows keep decoding).
     Returns (last_logits [B, V] fp32, cache_k, cache_v).
 
     HANDOFF CONTRACT (docs/disaggregation.md): this entry point (and the
@@ -717,8 +490,7 @@ def prefill_into_pages(
     adopted continuation is token-identical. A family that fused
     prefill+sample, or wrote KV at relative positions, would break both."""
     return _prefill_impl(
-        params, cfg, input_ids, prompt_lens, cache_k, cache_v,
-        make_write_kv_pages(block_tables, kv_pool_values(cache_k).shape[2]),
+        params, cfg, input_ids, prompt_lens, block_tables, cache_k, cache_v,
         lora_idx=lora_idx,
     )
 
@@ -727,14 +499,19 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
                                block_tables, cache_k, cache_v, *,
                                stacked_names=None, mlp_fn=_default_mlp_fn,
                                all_logits=False, window=None, lora_idx=None):
-    """Paged counterpart of _prefill_extend_impl: the chunk's KV scatters
-    through the block table into the page pool and attention reads the pool
-    via ops.attention.paged_attention_extend. Padding tokens write garbage
-    past the chunk — into this row's own later pages or the trash page
-    (unallocated table entries), never another row's cells. `all_logits`
-    returns logits at every chunk position (the speculative verify step);
-    `window` (static) bounds the attention sweep to whole pages covering it,
-    same contract as paged decode."""
+    """Shared chunked-prefill body: process a [B, T] chunk of prompt tokens
+    whose rows already hold `start_pos` tokens of KV. The chunk's KV scatters
+    through the block table into the page pool and queries attend over the
+    full row (earlier chunks + causal within this chunk) via
+    ops.attention.paged_attention_extend. Backs long prompts that exceed the
+    one-shot prefill buckets, and — with `all_logits=True` — the speculative
+    verify step, which needs logits at EVERY chunk position, not just the
+    last. Padding tokens (i >= chunk_lens) write garbage past the chunk —
+    into this row's own later pages or the trash page (unallocated table
+    entries), never another row's cells; those cells sit past the valid
+    range (masked by every later attention) and are overwritten in place
+    when the sequence grows into them. `window` (static) bounds the
+    attention sweep to whole pages covering it, same contract as decode."""
     from llmlb_tpu.ops.attention import paged_attention_extend
 
     _, t = input_ids.shape
@@ -803,39 +580,14 @@ def prefill_extend_pages(
     mesh: Mesh | None = None,  # unused; shared family signature
     lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
 ):
-    """Paged chunked prefill: append a chunk of prompt tokens to rows that
-    already hold `start_pos` tokens, attending over everything so far
-    through the block tables. Same contract as prefill_extend_slots."""
+    """Chunked prefill: append a chunk of prompt tokens to rows that already
+    hold `start_pos` tokens, attending over everything so far through the
+    block tables. Lets the engine serve prompts far beyond the one-shot
+    prefill buckets while decode steps interleave between chunks. Returns
+    (chunk-last logits [B, V] fp32, caches)."""
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
         cache_k, cache_v, lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
-         donate_argnames=("cache_k", "cache_v"))
-def verify_step(
-    params: Params,
-    cfg: LlamaConfig,
-    input_ids: jnp.ndarray,  # [B, K+1] int32 — last committed token + drafts
-    chunk_lens: jnp.ndarray,  # [B] int32 — 1 + draft count per row
-    start_pos: jnp.ndarray,  # [B] int32 — committed tokens in the row's cache
-    slot_ids: jnp.ndarray,  # [B] int32 — target rows (engine passes arange)
-    cache_k: jnp.ndarray,  # [L, NUM_SLOTS, CAP, K, D]
-    cache_v: jnp.ndarray,
-    mesh: Mesh | None = None,  # unused; shared family signature
-    window: int | None = None,  # static context-window bucket
-    lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
-):
-    """Speculative verification over the dense slot cache: one extend-style
-    dispatch scores the last committed token plus up to K draft tokens,
-    returning logits at EVERY chunk position ([B, K+1, V] fp32) so the
-    scheduler can sample each position and accept the longest matching
-    draft prefix. KV for all chunk positions is written; rejected-suffix
-    cells become garbage past the rolled-back length (standard contract)."""
-    return _prefill_extend_impl(
-        params, cfg, input_ids, chunk_lens, start_pos, slot_ids,
-        cache_k, cache_v, all_logits=True, window=window, lora_idx=lora_idx,
     )
 
 
@@ -854,9 +606,12 @@ def verify_step_paged(
     window: int | None = None,  # static context-window bucket
     lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
 ):
-    """Paged speculative verification: same contract as verify_step with the
-    slot cache swapped for the page pool + block tables — the K+1-token
-    ragged extend the paged attention kernels were built for."""
+    """Speculative verification: one extend-style dispatch scores the last
+    committed token plus up to K draft tokens, returning logits at EVERY
+    chunk position ([B, K+1, V] fp32) so the scheduler can sample each
+    position and accept the longest matching draft prefix. KV for all chunk
+    positions is written; rejected-suffix cells become garbage past the
+    rolled-back length (standard contract)."""
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
         cache_k, cache_v, all_logits=True, window=window, lora_idx=lora_idx,
@@ -866,12 +621,17 @@ def verify_step_paged(
 def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                        block_tables, *, stacked_names=None,
                        mlp_fn=_default_mlp_fn, window=None, lora_idx=None):
-    """Paged counterpart of _decode_impl (same unrolled layer loop — see
-    that docstring for why decode never scans the cache). Each layer's
-    one-token KV lands at page block_tables[b, pos//PS], offset pos%PS;
-    freed/parked rows clamp into their own last cell or the trash page
-    (their block-table rows are zeroed on free), so garbage writes can
-    never land in a page another row owns.
+    """Shared one-token decode body for every model family.
+
+    The layer loop is UNROLLED (static layer indices) rather than a
+    lax.scan with the pools as scan inputs/outputs: scanning slices the
+    pool per layer and re-stacks the outputs into fresh buffers, and under
+    the engine's k-step burst scan XLA materialized full-pool copies every
+    layer. Decode programs are tiny, so L× code growth is cheap. Each
+    layer's one-token KV lands at page block_tables[b, pos//PS], offset
+    pos%PS; freed/parked rows keep counting on device and clamp into their
+    own last cell or the trash page (their block-table rows are zeroed on
+    free), so garbage writes can never land in a page another row owns.
 
     Attention gets the whole stacked pool [L, P, PS, K, D] and the layer
     index, never `pool[layer_idx]`: the Pallas kernel addresses the pool at
@@ -931,9 +691,8 @@ def decode_step_paged(
     window: int | None = None,  # static context-window bucket (≥ max seq+1)
     lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
 ):
-    """One paged decode step across all rows. Returns (logits [B, V] fp32,
-    caches). Same contract as decode_step with the dense slot cache swapped
-    for the page pool + block tables."""
+    """One decode step across all rows. Returns (logits [B, V] fp32,
+    caches)."""
     return _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k,
                               cache_v, block_tables, window=window,
                               lora_idx=lora_idx)
@@ -996,7 +755,7 @@ def make_context_parallel_prefill(cfg: LlamaConfig, mesh: Mesh):
 
     Returns a jitted `fn(params, input_ids [B,T], prompt_lens [B]) ->
     (last_logits [B,V] fp32, k_all [L,B,T,K,D], v_all)`. The caller scatters
-    k/v into its live slot cache (engine insert path) or keeps them
+    k/v into its page pool (engine insert path) or keeps them
     seq-sharded for context-parallel decode. New TPU-first design — the
     reference has no long-context subsystem (SURVEY.md §5).
     """
@@ -1040,21 +799,3 @@ def make_context_parallel_prefill(cfg: LlamaConfig, mesh: Mesh):
         return logits, k_all.astype(cfg.dtype), v_all.astype(cfg.dtype)
 
     return fn
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
-         donate_argnames=("cache_k", "cache_v"))
-def decode_step(
-    params: Params,
-    cfg: LlamaConfig,
-    input_ids: jnp.ndarray,  # [B] int32 — previous sampled token per slot
-    seq_lens: jnp.ndarray,  # [B] int32 — tokens already in cache (new token's position)
-    cache_k: jnp.ndarray,  # [L, B, S, K, D]
-    cache_v: jnp.ndarray,
-    mesh: Mesh | None = None,  # unused; shared family signature
-    window: int | None = None,  # static context-window bucket (≥ max seq+1)
-    lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
-):
-    """One decode step across all slots. Returns (logits [B, V] fp32, caches)."""
-    return _decode_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
-                        window=window, lora_idx=lora_idx)

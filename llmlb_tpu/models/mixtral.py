@@ -1,7 +1,7 @@
 """Mixtral-family sparse-MoE decoder (Mixtral-8x7B/8x22B, Qwen-MoE-class).
 
 Same serving-shaped skeleton as models/llama.py (stacked layers + lax.scan,
-static-shape prefill/decode over slot KV caches, GQA attention ops) with the
+static-shape prefill/decode over the paged KV pool, GQA attention ops) with the
 dense SwiGLU MLP swapped for top-k routed experts (ops/moe.py). Expert weights
 carry an `experts` logical axis mapped to the mesh `ep` axis, so a
 Mixtral-8x7B spans a multi-chip mesh as dp × ep × tp with GSPMD inserting the
@@ -23,15 +23,9 @@ from jax.sharding import Mesh
 
 from llmlb_tpu.models.llama import (
     LlamaConfig,
-    _decode_impl,
     _decode_paged_impl,
-    _prefill_extend_impl,
     _prefill_extend_paged_impl,
     _prefill_impl,
-    _write_kv_fresh,
-    kv_pool_values,
-    make_write_kv_pages,
-    make_write_kv_slots,
 )
 from llmlb_tpu.ops.moe import default_capacity, moe_dense_exact, moe_dispatch_combine
 from llmlb_tpu.parallel.sharding import logical_to_sharding
@@ -134,11 +128,9 @@ def param_shardings(cfg: MixtralConfig, mesh: Mesh, rules=None):
     }
 
 
-# KV cache layouts (dense slots + paged pool) identical to llama's — reuse.
+# The KV page pool is identical to llama's — reuse.
 from llmlb_tpu.models.llama import (  # noqa: E402,F401
-    init_kv_cache,
     init_kv_pages,
-    kv_cache_shardings,
     kv_pages_shardings,
 )
 
@@ -207,81 +199,19 @@ def _moe_mlp_fn(cfg: MixtralConfig, mesh: Mesh | None, exact: bool):
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"),
          donate_argnames=("cache_k", "cache_v"))
-def prefill(params, cfg: MixtralConfig, input_ids, prompt_lens, cache_k, cache_v,
-            mesh: Mesh | None = None, lora_idx=None):
-    """Prefill B prompts into fresh KV slots. Same contract as llama.prefill."""
-    b, t = input_ids.shape
-    return _prefill_impl(
-        params, cfg, input_ids, prompt_lens, cache_k, cache_v, _write_kv_fresh,
-        stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=b * t <= 4 * cfg.num_experts),
-        lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"),
-         donate_argnames=("cache_k", "cache_v"))
-def prefill_into_slots(params, cfg: MixtralConfig, input_ids, prompt_lens,
-                       slot_ids, cache_k, cache_v, mesh: Mesh | None = None,
-                       lora_idx=None):
-    """Continuous-batching insert path. Same contract as llama.prefill_into_slots."""
-    b, t = input_ids.shape
-    return _prefill_impl(
-        params, cfg, input_ids, prompt_lens, cache_k, cache_v,
-        make_write_kv_slots(slot_ids),
-        stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=b * t <= 4 * cfg.num_experts),
-        lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"),
-         donate_argnames=("cache_k", "cache_v"))
-def prefill_extend_slots(params, cfg: MixtralConfig, input_ids, chunk_lens,
-                         start_pos, slot_ids, cache_k, cache_v,
-                         mesh: Mesh | None = None, lora_idx=None):
-    """Chunked-prefill append path. Same contract as llama.prefill_extend_slots."""
-    b, t = input_ids.shape
-    return _prefill_extend_impl(
-        params, cfg, input_ids, chunk_lens, start_pos, slot_ids,
-        cache_k, cache_v,
-        stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=b * t <= 4 * cfg.num_experts),
-        lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
-         donate_argnames=("cache_k", "cache_v"))
-def decode_step(params, cfg: MixtralConfig, input_ids, seq_lens, cache_k, cache_v,
-                mesh: Mesh | None = None, window: int | None = None,
-                lora_idx=None):
-    """One decode step across all slots. Same contract as llama.decode_step.
-
-    Decode is ALWAYS exact MoE: capacity drops here would make a request's
-    tokens depend on which other slots share the batch."""
-    return _decode_impl(
-        params, cfg, input_ids, seq_lens, cache_k, cache_v,
-        stacked_names=_STACKED, mlp_fn=_moe_mlp_fn(cfg, mesh, exact=True),
-        window=window, lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"),
-         donate_argnames=("cache_k", "cache_v"))
 def prefill_into_pages(params, cfg: MixtralConfig, input_ids, prompt_lens,
                        block_tables, cache_k, cache_v,
                        mesh: Mesh | None = None, lora_idx=None):
-    """Paged insert path. Same contract as llama.prefill_into_pages —
-    including its HANDOFF CONTRACT (docs/disaggregation.md): final-row
-    logits aligned to batch rows and position-exact KV, so split-mode
+    """Continuous-batching insert path. Same contract as
+    llama.prefill_into_pages — including its HANDOFF CONTRACT
+    (docs/disaggregation.md): final-row logits aligned to batch rows and
+    position-exact KV, so split-mode
     staging and cross-process replay hold for MoE engines too (the router
     is position-independent; expert choice rides the token, not the
     slot, so a handed-off stream routes identically on the adopter)."""
     b, t = input_ids.shape
     return _prefill_impl(
-        params, cfg, input_ids, prompt_lens, cache_k, cache_v,
-        make_write_kv_pages(block_tables, kv_pool_values(cache_k).shape[2]),
+        params, cfg, input_ids, prompt_lens, block_tables, cache_k, cache_v,
         stacked_names=_STACKED,
         mlp_fn=_moe_mlp_fn(cfg, mesh, exact=b * t <= 4 * cfg.num_experts),
         lora_idx=lora_idx,
@@ -293,7 +223,8 @@ def prefill_into_pages(params, cfg: MixtralConfig, input_ids, prompt_lens,
 def prefill_extend_pages(params, cfg: MixtralConfig, input_ids, chunk_lens,
                          start_pos, block_tables, cache_k, cache_v,
                          mesh: Mesh | None = None, lora_idx=None):
-    """Paged chunked-prefill append. Same contract as llama.prefill_extend_pages."""
+    """Chunked-prefill append path. Same contract as
+    llama.prefill_extend_pages."""
     b, t = input_ids.shape
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
@@ -306,29 +237,13 @@ def prefill_extend_pages(params, cfg: MixtralConfig, input_ids, chunk_lens,
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
          donate_argnames=("cache_k", "cache_v"))
-def verify_step(params, cfg: MixtralConfig, input_ids, chunk_lens, start_pos,
-                slot_ids, cache_k, cache_v, mesh: Mesh | None = None,
-                window: int | None = None, lora_idx=None):
-    """Speculative verification over the dense slot cache. Same contract as
-    llama.verify_step; exact MoE like decode — capacity drops would make a
-    draft's acceptance depend on which other slots share the batch."""
-    return _prefill_extend_impl(
-        params, cfg, input_ids, chunk_lens, start_pos, slot_ids,
-        cache_k, cache_v, stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=True),
-        all_logits=True, window=window, lora_idx=lora_idx,
-    )
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
-         donate_argnames=("cache_k", "cache_v"))
 def verify_step_paged(params, cfg: MixtralConfig, input_ids, chunk_lens,
                       start_pos, block_tables, cache_k, cache_v,
                       mesh: Mesh | None = None, window: int | None = None,
                       lora_idx=None):
-    """Paged speculative verification. Same contract as
-    llama.verify_step_paged; exact MoE for the same batch-independence
-    reason as decode_step."""
+    """Speculative verification. Same contract as llama.verify_step_paged;
+    exact MoE like decode — capacity drops would make a draft's acceptance
+    depend on which other rows share the batch."""
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
         cache_k, cache_v, stacked_names=_STACKED,
@@ -343,8 +258,11 @@ def decode_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
                       cache_k, cache_v, block_tables,
                       mesh: Mesh | None = None, window: int | None = None,
                       lora_idx=None):
-    """One paged decode step. Same contract as llama.decode_step_paged;
-    exact MoE for the same batch-independence reason as decode_step."""
+    """One decode step across all rows. Same contract as
+    llama.decode_step_paged.
+
+    Decode is ALWAYS exact MoE: capacity drops here would make a request's
+    tokens depend on which other rows share the batch."""
     return _decode_paged_impl(
         params, cfg, input_ids, seq_lens, cache_k, cache_v, block_tables,
         stacked_names=_STACKED, mlp_fn=_moe_mlp_fn(cfg, mesh, exact=True),

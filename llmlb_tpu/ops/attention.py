@@ -2,17 +2,19 @@
 
 Design notes (TPU-first):
 - Static shapes everywhere: prefill runs at bucketed sequence lengths, decode at
-  T=1 over a fixed-capacity per-slot KV cache. Ragged reality is expressed with
-  masks, not dynamic shapes, so XLA tiles everything onto the MXU.
+  T=1 over a fixed-width block table into the KV page pool. Ragged reality is
+  expressed with masks, not dynamic shapes, so XLA tiles everything onto the
+  MXU.
 - Softmax in float32; QK^T and PV in bf16 inputs with fp32 accumulation
   (`preferred_element_type`) — the MXU accumulates in fp32 natively.
 - GQA is expressed by folding the group dimension into einsum so no materialized
   `repeat_kv` copy hits HBM.
 
 The reference gateway never touches attention (it proxies; SURVEY.md §5
-"long-context: absent") — this op family is new TPU-native design. A Pallas ragged
-paged attention kernel (PAPERS.md) replaces the dense decode path in a later phase;
-this XLA version is the correctness baseline it is checked against.
+"long-context: absent") — this op family is new TPU-native design. The Pallas
+paged kernels (ops/pallas_attention.py, PAPERS.md) serve decode and extend on an
+unpartitioned TPU; `gqa_attention_decode` / `gqa_attention_extend` are the plain
+einsums they are checked against and the paged XLA fall-back ends in.
 """
 
 from __future__ import annotations
@@ -103,29 +105,16 @@ def gqa_attention_prefill(
 
 def gqa_attention_extend(
     q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
-    k_cache: jnp.ndarray,  # [B, S, K, D] — slot cache incl. this chunk's keys
+    k_cache: jnp.ndarray,  # [B, S, K, D] — contiguous rows incl. the chunk's
     v_cache: jnp.ndarray,  # [B, S, K, D]
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
-    chunk_lens: jnp.ndarray | None = None,  # [B] int32 — enables Pallas route
 ) -> jnp.ndarray:
-    """Chunked-prefill attention: a chunk of T queries attends causally against
-    the full slot cache (earlier chunks + this chunk). Query i at global
-    position p may see cache positions <= p. Returns [B, T, H, D].
-
-    Generalizes decode (T=1); backs the engine's chunked long-prompt prefill
-    path (no reference counterpart — SURVEY.md §5 long-context is greenfield).
-    On a single TPU the Pallas flash kernel serves this; it assumes the
-    engine's contiguous chunk positions (q_positions[b] = start + iota), which
-    is what both callers construct.
-    """
-    if chunk_lens is not None and _pallas_enabled():
-        from llmlb_tpu.ops.pallas_attention import flash_extend
-
-        _traced["extend"] = "pallas:flash_extend"
-        return flash_extend(
-            q, k_cache, v_cache, q_positions[:, 0], chunk_lens
-        )
-    _traced["extend"] = "xla"
+    """Chunked-prefill attention, plain einsum: a chunk of T queries attends
+    causally against contiguous per-row KV (earlier chunks + this chunk).
+    Query i at global position p may see positions <= p. Returns
+    [B, T, H, D]. Generalizes decode (T=1). The reference the paged extend
+    kernels are checked against, and what the paged XLA fall-back
+    (paged_attention_extend) ends in after gathering its pages."""
     b, t, h, d = q.shape
     k_heads = k_cache.shape[2]
     qg = _split_gqa(q, k_heads)  # [B, T, K, G, D]
@@ -186,11 +175,12 @@ def paged_attention_decode(
     kv_lens: jnp.ndarray,  # [B] int32 — valid logical length per row
     window: int | None = None,  # static: read only the first `window` cells
 ) -> jnp.ndarray:
-    """One-token decode attention against one layer of the PAGED KV pool.
-    Same contract as gqa_attention_decode — `window` (STATIC) bounds the
-    logical sweep, rounded up to whole pages; rows with kv_lens beyond the
-    swept pages produce garbage the caller must discard (parked/freed slot
-    rows).
+    """One-token decode attention against one layer of the KV page pool.
+    `window` (STATIC) bounds the logical sweep, rounded up to whole pages;
+    rows with kv_lens beyond the swept pages produce garbage the caller must
+    discard (parked/freed slot rows). The scheduler picks the smallest
+    bucket covering every active sequence, so attention HBM traffic scales
+    with the context actually in use instead of the full row capacity.
 
     The pool arrives STACKED over layers, with the layer index beside it:
     the Pallas kernels address it at (layer, page) and read it in place,
@@ -236,10 +226,10 @@ def paged_attention_extend(
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
     chunk_lens: jnp.ndarray,  # [B] int32 — valid queries in the chunk
 ) -> jnp.ndarray:
-    """Chunked-prefill attention against the PAGED KV pool: the chunk's
+    """Chunked-prefill attention against the KV page pool: the chunk's
     queries attend causally over row b's pages (earlier chunks + this
-    chunk). Paged counterpart of gqa_attention_extend; assumes the engine's
-    contiguous chunk positions (q_positions[b] = start + iota)."""
+    chunk). Assumes the engine's contiguous chunk positions
+    (q_positions[b] = start + iota)."""
     if _pallas_enabled():
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_extend_quant
@@ -258,47 +248,20 @@ def paged_attention_extend(
     _traced["paged_extend"] = "xla"
     k_cache = gather_kv_pages(k_pages, block_tables, dtype=q.dtype)
     v_cache = gather_kv_pages(v_pages, block_tables, dtype=q.dtype)
-    # chunk_lens=None pins gqa_attention_extend to the XLA einsum path (the
-    # caches are already materialized dense here).
-    return gqa_attention_extend(q, k_cache, v_cache, q_positions, None)
+    return gqa_attention_extend(q, k_cache, v_cache, q_positions)
 
 
 def gqa_attention_decode(
     q: jnp.ndarray,  # [B, 1, H, D]
-    k_cache: jnp.ndarray,  # [B, S, K, D] — slot-capacity cache incl. current token
+    k_cache: jnp.ndarray,  # [B, S, K, D] — contiguous rows incl. current token
     v_cache: jnp.ndarray,  # [B, S, K, D]
-    kv_lens: jnp.ndarray,  # [B] int32 — valid cache length per slot (incl. current)
-    window: int | None = None,  # static: read only the first `window` cells
+    kv_lens: jnp.ndarray,  # [B] int32 — valid length per row (incl. current)
 ) -> jnp.ndarray:
-    """One-token decode attention against the slot cache. Returns [B, 1, H, D].
-
-    `window` (STATIC) bounds how much of the capacity axis is read: the
-    scheduler picks the smallest bucket covering every active sequence, so
-    attention HBM traffic scales with the context actually in use instead of
-    the full slot capacity (reading 2048 cells for 300-token contexts wasted
-    ~85% of decode's cache bandwidth). Rows with kv_lens <= window are
-    exact; rows with kv_lens > window (parked chunked-prefill / freed slots,
-    whose device counters sit at capacity) produce garbage the caller must
-    discard — the engine's emission loop skips exactly those rows."""
+    """One-token decode attention against contiguous per-row KV, plain
+    einsum. Returns [B, 1, H, D]. The reference the paged decode kernels are
+    checked against, and what the paged XLA fall-back
+    (paged_attention_decode) ends in after gathering its pages."""
     s = k_cache.shape[1]
-    if window is not None and window < s:
-        if _pallas_enabled():
-            from llmlb_tpu.ops.pallas_attention import flash_decode
-
-            _traced["decode"] = "pallas:flash_decode"
-            # the kernel bounds its grid instead of slicing (no copy)
-            return flash_decode(
-                q[:, 0], k_cache, v_cache, kv_lens, window=window
-            )[:, None]
-        k_cache = jax.lax.slice_in_dim(k_cache, 0, window, axis=1)
-        v_cache = jax.lax.slice_in_dim(v_cache, 0, window, axis=1)
-        s = window
-    elif _pallas_enabled():
-        from llmlb_tpu.ops.pallas_attention import flash_decode
-
-        _traced["decode"] = "pallas:flash_decode"
-        return flash_decode(q[:, 0], k_cache, v_cache, kv_lens)[:, None]
-    _traced["decode"] = "xla"
     b, t, h, d = q.shape
     k_heads = k_cache.shape[2]
     qg = _split_gqa(q, k_heads)  # [B, 1, K, G, D]

@@ -1,16 +1,20 @@
-"""Pallas TPU attention kernels: ragged flash-decode + causal flash-prefill.
+"""Pallas TPU attention kernels: ragged paged flash-decode/extend + causal
+flash-prefill.
 
 These are the hot ops of the serving engine (SURVEY.md §7 phase 4: "ragged paged
 attention Pallas kernel"). The XLA einsum paths in ops/attention.py are the
 correctness baselines; these kernels replace them on TPU:
 
-- `flash_decode`: one-token GQA attention against the slot KV cache. Grid is
-  (batch, kv_block) with the kv-block axis innermost, so Pallas's grid pipeline
-  double-buffers the next KV block's DMA behind the current block's compute.
-  Online softmax (m/l/acc) lives in VMEM scratch across the kv-block sweep.
-  Raggedness: per-slot `kv_lens` arrive via scalar prefetch (SMEM) and blocks
-  past the valid length skip their FLOPs entirely (`pl.when`) — decode cost
-  scales with the *actual* context, not the slot capacity.
+- `paged_flash_decode` (+ `_quant`): one-token GQA attention against the KV
+  page pool. Grid is (batch, logical_page) with the page axis innermost, so
+  Pallas's grid pipeline double-buffers the next page's DMA behind the
+  current page's compute. Online softmax (m/l/acc) lives in VMEM scratch
+  across the page sweep. Raggedness: per-row `kv_lens` arrive via scalar
+  prefetch (SMEM) and pages past the valid length skip their FLOPs entirely
+  (`pl.when`) — decode cost scales with the *actual* context, not the row
+  capacity.
+- `paged_flash_extend` (+ `_quant`): a chunk of queries against the pool
+  (chunked prefill, speculative verify), same page-table addressing.
 - `flash_prefill`: causal self-attention over bucketed prompts. Grid is
   (batch, q_block, kv_block); fully-future KV blocks (k_start > q_end) skip
   compute, giving the ~2x causal FLOP saving dense XLA attention leaves on the
@@ -65,7 +69,7 @@ def _online_update(m_ref, l_ref, acc_ref, idx, scores, v):
 
 
 # ---------------------------------------------------------------------------
-# Decode: q [B, H, D] vs slot cache [B, S, K, D], ragged kv_lens [B]
+# Decode body: q [1, K, G, D] vs one KV block [1, BLK, K, D], ragged kv_lens
 # ---------------------------------------------------------------------------
 
 
@@ -122,78 +126,6 @@ def _decode_kernel(
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_k", "interpret", "window")
-)
-def flash_decode(
-    q: jnp.ndarray,  # [B, H, D]
-    k_cache: jnp.ndarray,  # [B, S, K, D]
-    v_cache: jnp.ndarray,  # [B, S, K, D]
-    kv_lens: jnp.ndarray,  # [B] int32 — valid cache length per slot
-    *,
-    block_k: int = 128,
-    interpret: bool | None = None,
-    window: int | None = None,  # static: sweep only the first `window` cells
-) -> jnp.ndarray:
-    """Ragged one-token GQA decode attention. Returns [B, H, D] in q.dtype.
-
-    `window` bounds the kv-block sweep (grid), NOT the input shapes — the
-    kernel simply never DMAs cache blocks past it, so short contexts in a
-    large-capacity cache cost only the traffic they actually need and no
-    slice copy is materialized. Contract: rows with kv_lens <= window are
-    exact; rows with kv_lens > window produce GARBAGE (their mask believes
-    unswept cells are valid) and the caller must discard them — the engine
-    does this for parked/freed slot rows, whose device counters sit at
-    capacity while the scheduler picks the window from active rows only."""
-    if interpret is None:
-        interpret = _interpret_default()
-    b, h, d = q.shape
-    s = k_cache.shape[1]
-    num_kv = k_cache.shape[2]
-    g = h // num_kv
-    blk = min(block_k, s)
-    sweep = s if window is None else max(blk, min(window, s))
-    num_blocks = pl.cdiv(sweep, blk)
-    qg = q.reshape(b, num_kv, g, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, num_blocks),
-        in_specs=[
-            pl.BlockSpec(
-                (1, num_kv, g, d), lambda bi, si, lens: (bi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, blk, num_kv, d), lambda bi, si, lens: (bi, si, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, blk, num_kv, d), lambda bi, si, lens: (bi, si, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, num_kv, g, d), lambda bi, si, lens: (bi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _decode_kernel, block_k=blk, num_kv=num_kv, scale=d**-0.5
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(kv_lens.astype(jnp.int32), qg, k_cache, v_cache)
-    return out.reshape(b, h, d)
-
-
 # ---------------------------------------------------------------------------
 # Paged decode: q [B, H, D] vs the stacked page pool [L, P, PS, K, D] at one
 # layer, block tables [B, PPN]
@@ -202,7 +134,7 @@ def flash_decode(
 
 def _paged_decode_kernel(layer_ref, block_tables_ref, kv_lens_ref, *refs,
                          **kw):
-    """Same online-softmax sweep as _decode_kernel; the layer and block-table
+    """The online-softmax sweep of _decode_kernel; the layer and block-table
     refs are consumed by the BlockSpec index_map (they pick which POOL page
     of which layer each grid step DMAs), so the body only needs the ragged
     lengths."""
@@ -254,10 +186,14 @@ def paged_flash_decode(
     pallas_call takes whole buffers as operands, so handing it a slice makes
     XLA copy one layer of the pool (105 MB at 400 pages of Mistral-7B width)
     per call. `layer` is an operand, not a Python constant, so the layers of
-    an unrolled decode program share one kernel. `pages` plays the role of
-    flash_decode's `window`: the sweep stops after that many logical pages
-    and rows whose kv_lens extend beyond produce garbage the caller must
-    discard (parked/freed slot rows).
+    an unrolled decode program share one kernel. `pages` bounds the sweep
+    (grid), NOT the input shapes — the kernel simply never DMAs pages past
+    it, so short contexts in a large-capacity table cost only the traffic
+    they need. Contract: rows with kv_lens inside the swept pages are exact;
+    rows whose kv_lens extend beyond produce GARBAGE (their mask believes
+    unswept cells are valid) and the caller must discard them — the engine
+    does this for parked/freed slot rows, whose device counters sit at
+    capacity while the scheduler picks the window from active rows only.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -575,8 +511,9 @@ def flash_prefill(
 
 
 # ---------------------------------------------------------------------------
-# Extend (chunked prefill): q chunk [B, T, H, D] vs slot cache [B, S, K, D],
-# chunk starts at global position start_pos[b] (contiguous positions).
+# Extend body (chunked prefill): q block [1, BLK_Q, K, G, D] vs one KV block
+# [1, BLK_K, K, D]; the chunk starts at global position start_pos[b]
+# (contiguous positions).
 # ---------------------------------------------------------------------------
 
 
@@ -653,83 +590,6 @@ def _extend_kernel(
         o_ref[0] = out.reshape(num_kv, block_q, groups, -1).transpose(1, 0, 2, 3)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret")
-)
-def flash_extend(
-    q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
-    k_cache: jnp.ndarray,  # [B, S, K, D] — slot rows incl. this chunk's keys
-    v_cache: jnp.ndarray,  # [B, S, K, D]
-    start_pos: jnp.ndarray,  # [B] int32 — global position of the first query
-    chunk_lens: jnp.ndarray,  # [B] int32 — valid queries (rest are padding)
-    *,
-    block_q: int = 128,
-    block_k: int = 128,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Chunked-prefill attention: T contiguous queries starting at global
-    position start_pos[b] attend causally over the slot cache (earlier chunks
-    + this chunk). Pallas counterpart of ops.attention.gqa_attention_extend
-    for the engine's long-prompt path. Returns [B, T, H, D] in q.dtype."""
-    if interpret is None:
-        interpret = _interpret_default()
-    b, t, h, d = q.shape
-    s = k_cache.shape[1]
-    num_kv = k_cache.shape[2]
-    g = h // num_kv
-    blk_q = min(block_q, t)
-    blk_k = min(block_k, s)
-    grid = (b, pl.cdiv(t, blk_q), pl.cdiv(s, blk_k))
-    qg = q.reshape(b, t, num_kv, g, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, blk_q, num_kv, g, d),
-                lambda bi, qi, si, starts, lens: (bi, qi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, blk_k, num_kv, d),
-                lambda bi, qi, si, starts, lens: (bi, si, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, blk_k, num_kv, d),
-                lambda bi, qi, si, starts, lens: (bi, si, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, blk_q, num_kv, g, d),
-            lambda bi, qi, si, starts, lens: (bi, qi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _extend_kernel,
-            block_q=blk_q,
-            block_k=blk_k,
-            num_kv=num_kv,
-            groups=g,
-            scale=d**-0.5,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(start_pos.astype(jnp.int32), chunk_lens.astype(jnp.int32),
-      qg, k_cache, v_cache)
-    return out.reshape(b, t, h, d)
-
-
 # ---------------------------------------------------------------------------
 # Paged extend (chunked prefill): q chunk [B, T, H, D] vs page pool
 # [P, PS, K, D] through block tables [B, PPN]; chunk starts at start_pos[b].
@@ -738,7 +598,7 @@ def flash_extend(
 
 def _paged_extend_kernel(block_tables_ref, start_pos_ref, chunk_lens_ref,
                          *refs, **kw):
-    """Same masked sweep as _extend_kernel; logical KV position of grid step
+    """The masked sweep of _extend_kernel; logical KV position of grid step
     `ki` is ki * page_size because the index_map walks the block table in
     logical order — the body never needs the table itself."""
     del block_tables_ref

@@ -135,16 +135,21 @@ def _selftest_worker(process_id: int, num_hosts: int, port: int,
     params = {k: jax.device_put(v, sh[k]) for k, v in params.items()}
     dp_total = mesh.shape["dp"]
     batch = 2 * dp_total
-    ck, cv = mixtral.init_kv_cache(cfg, batch, 16)
-    ck_sh, cv_sh = mixtral.kv_cache_shardings(cfg, mesh)
+    # the serving programs over an identity block table: row b owns pages
+    # 1 + 2b and 2 + 2b of 8 tokens each (page 0 is the trash page)
+    ck, cv = mixtral.init_kv_pages(cfg, 2 * batch + 1, 8)
+    ck_sh, cv_sh = mixtral.kv_pages_shardings(cfg, mesh)
     ck, cv = jax.device_put(ck, ck_sh), jax.device_put(cv, cv_sh)
+    tables = jnp.arange(1, 2 * batch + 1, dtype=jnp.int32).reshape(batch, 2)
     ids = jax.random.randint(jax.random.PRNGKey(1), (batch, 8), 0,
                              cfg.vocab_size)
     lens = jnp.full((batch,), 8, jnp.int32)
 
-    logits, ck, cv = mixtral.prefill(params, cfg, ids, lens, ck, cv, mesh)
+    logits, ck, cv = mixtral.prefill_into_pages(
+        params, cfg, ids, lens, tables, ck, cv, mesh)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    logits, ck, cv = mixtral.decode_step(params, cfg, tok, lens, ck, cv, mesh)
+    logits, ck, cv = mixtral.decode_step_paged(
+        params, cfg, tok, lens, ck, cv, tables, mesh)
     # logits span non-addressable devices; reduce to a (replicated) scalar
     # before fetching — each process may only read its local shards
     finite = bool(jax.jit(lambda x: jnp.isfinite(x).all())(logits))

@@ -206,13 +206,16 @@ def _emit_stablehlo(cfg, out_path: str) -> int:
 
     family = family_for(cfg)
     params = family.init_params(cfg, jax.random.PRNGKey(0))
-    ck, cv = family.init_kv_cache(cfg, 1, 32)
+    # two 16-token pages behind page 0 (the trash page), one row owning both
+    ck, cv = family.init_kv_pages(cfg, 3, 16)
+    tables = jnp.asarray([[1, 2]], jnp.int32)
     ids = jnp.zeros((1, 16), jnp.int32)
     lens = jnp.full((1,), 16, jnp.int32)
 
     lowered = jax.jit(
-        lambda p, i, n, k, v: family.prefill(p, cfg, i, n, k, v)[0]
-    ).lower(params, ids, lens, ck, cv)
+        lambda p, i, n, t, k, v: family.prefill_into_pages(
+            p, cfg, i, n, t, k, v)[0]
+    ).lower(params, ids, lens, tables, ck, cv)
     text = lowered.as_text()
     with open(out_path, "w") as f:
         f.write(text)

@@ -392,107 +392,6 @@ async def run_prefix_bench(requests: int) -> dict:
         engine.shutdown()
 
 
-async def run_mixed_length_bench(requests_n: int) -> dict:
-    """Paged-vs-dense occupancy at EQUAL HBM budget: one pool worth of KV
-    serves a mixed short/long workload under both layouts. Dense reserves
-    slot_capacity rows per slot, capping concurrency at its slot count;
-    paged holds pages per token actually cached, so the same bytes admit
-    many more short requests at once. Reports peak concurrent sequences per
-    layout and confirms the page-pool gauges are visible in /metrics."""
-    import random
-
-    import aiohttp
-    from aiohttp.test_utils import TestServer
-
-    from llmlb_tpu.engine.scheduler import SamplingParams
-    from llmlb_tpu.engine.server import create_engine_app
-    from llmlb_tpu.engine.service import Engine
-
-    capacity, page = 256, 16
-    dense_slots = 4
-    results: dict = {}
-    for layout in ("dense", "paged"):
-        kwargs = dict(
-            num_slots=dense_slots, slot_capacity=capacity,
-            prefill_buckets=(16, 32, 64), kv_layout=layout,
-            kv_page_size=page,
-        )
-        if layout == "paged":
-            # same pool bytes as the dense cache (+1 trash page); the extra
-            # slots are bookkeeping only — HBM does not grow with them
-            kwargs["kv_pages"] = dense_slots * (capacity // page) + 1
-            kwargs["num_slots"] = dense_slots * 4
-        engine = Engine.from_preset("debug-tiny", **kwargs)
-        eng_server = TestServer(create_engine_app(engine, owns_engine=False))
-        await eng_server.start_server()
-        try:
-            r = random.Random(0)
-            prompts = []
-            for i in range(requests_n):
-                # 1-in-4 long prompts; the rest are short chats that would
-                # each strand a full slot row under the dense layout
-                n = 200 if i % 4 == 0 else 12
-                prompts.append([r.randrange(1, 500) for _ in range(n)])
-
-            peak = 0
-            done = False
-
-            async def sample() -> None:
-                nonlocal peak
-                while not done:
-                    peak = max(peak, engine.core.stats().active_slots)
-                    await asyncio.sleep(0.002)
-
-            sampler = asyncio.create_task(sample())
-            t0 = time.perf_counter()
-            outs = await asyncio.gather(*(
-                engine.complete(p, SamplingParams(temperature=0.0,
-                                                  max_tokens=8))
-                for p in prompts
-            ))
-            elapsed = time.perf_counter() - t0
-            done = True
-            await sampler
-
-            async with aiohttp.ClientSession() as s:
-                async with s.get(
-                    f"http://127.0.0.1:{eng_server.port}/metrics"
-                ) as resp:
-                    exposition = await resp.text()
-            info = engine.core.kv_cache_info()
-            results[layout] = {
-                "num_slots": engine.core.num_slots,
-                "kv_hbm_bytes": info["hbm_bytes"],
-                "peak_concurrent_sequences": peak,
-                "seconds": round(elapsed, 2),
-                "finished": sum(
-                    1 for o in outs if o.finish_reason in ("stop", "length")
-                ),
-                "page_gauges_in_metrics": (
-                    "llmlb_engine_kv_pages_total" in exposition
-                    if layout == "paged" else None
-                ),
-                "kv_cache": info,
-            }
-        finally:
-            await eng_server.close()
-            engine.shutdown()
-    dense_b = results["dense"]["kv_hbm_bytes"]
-    paged_b = results["paged"]["kv_hbm_bytes"]
-    return {
-        "metric": "paged_vs_dense_mixed_length_occupancy",
-        "requests": requests_n,
-        # paged may carry the one reserved trash page of extra HBM
-        "equal_hbm_budget": abs(paged_b - dense_b) <= dense_b // dense_slots,
-        "peak_concurrency_gain": round(
-            results["paged"]["peak_concurrent_sequences"]
-            / max(1, results["dense"]["peak_concurrent_sequences"]), 2
-        ),
-        "dense": results["dense"],
-        "paged": results["paged"],
-    }
-
-
 async def run_quantized_bench(requests_n: int) -> dict:
     """Int8-KV occupancy and throughput at EQUAL HBM budget
     (docs/quantization.md). Three engines, identical except the
@@ -500,8 +399,7 @@ async def run_quantized_bench(requests_n: int) -> dict:
     The quantized pools get as many pages as the bf16 pool's BYTES buy
     (bytes_per_page is ~(D+4)/2D of bf16, so ~1.9x the pages), and a
     saturating swarm of identical short chats measures peak concurrent
-    sequences per budget — the paged-attention analogue of the
-    mixed-length dense-vs-paged bench. Also reports decode tok/s and a
+    sequences per budget. Also reports decode tok/s and a
     greedy output-divergence sample (int8 vs bf16 token streams on the
     same prompts)."""
     import dataclasses as dc
@@ -1394,7 +1292,7 @@ async def run_slo_mix_bench(requests: int) -> dict:
         engine = Engine.from_preset(
             "debug-tiny", model_id="bench-slo", num_slots=8,
             slot_capacity=512, prefill_buckets=(16, 32, 64, 128, 256),
-            kv_layout="paged", kv_page_size=16, seed=0,
+            kv_page_size=16, seed=0,
             prefill_chunk_budget=budget, prefix_cache=False,
         )
         eng_server = TestServer(create_engine_app(engine, owns_engine=False))
@@ -1556,7 +1454,7 @@ async def run_slo_mix_bench(requests: int) -> dict:
         engine = Engine.from_preset(
             "debug-tiny", model_id="bench-slo",
             num_slots=1, slot_capacity=128, prefill_buckets=(16, 32),
-            kv_layout="paged", kv_page_size=16, prefix_cache=False, seed=0,
+            kv_page_size=16, prefix_cache=False, seed=0,
         )
         eng_server = TestServer(create_engine_app(engine, owns_engine=False))
         await eng_server.start_server()
@@ -1698,7 +1596,7 @@ async def run_disagg_bench(requests: int) -> dict:
         engine = Engine.from_preset(
             "debug-tiny", model_id="bench-disagg", num_slots=8,
             slot_capacity=512, prefill_buckets=(16, 32, 64, 128, 256),
-            kv_layout="paged", kv_page_size=16, seed=0,
+            kv_page_size=16, seed=0,
             prefill_chunk_budget=budget, prefix_cache=False, **extra,
         )
         eng_server = TestServer(create_engine_app(engine, owns_engine=False))
@@ -1840,7 +1738,7 @@ async def run_kv_ship_bench(requests: int) -> dict:
     LONG = 384
     CORE_KW = dict(num_slots=1, slot_capacity=512,
                    prefill_buckets=(16, 32, 64, 128, 256), seed=0,
-                   kv_layout="paged", kv_page_size=16)
+                   kv_page_size=16)
     iters = max(4, requests // 6)
 
     def _req(prompt, max_tokens=4, priority=1):
@@ -3394,7 +3292,7 @@ def main() -> None:
     parser.add_argument("--concurrency", type=int, default=50)
     parser.add_argument(
         "--workload",
-        choices=("proxy", "shared-prefix", "mixed-length", "chaos",
+        choices=("proxy", "shared-prefix", "chaos",
                  "structured", "spec-decode", "quantized", "throughput",
                  "slo-mix", "disagg", "lora", "kv-ship", "fused",
                  "rebalance"),
@@ -3402,7 +3300,7 @@ def main() -> None:
     )
     parser.add_argument("--requests", type=int, default=24,
                         help="request count for --workload shared-prefix / "
-                             "mixed-length / structured / spec-decode / "
+                             "structured / spec-decode / "
                              "quantized")
     parser.add_argument("--engine-kill", action="store_true",
                         help="--workload chaos variant: spawn REAL engine "
@@ -3452,8 +3350,6 @@ def main() -> None:
         result = asyncio.run(run_structured_bench(args.requests))
     elif args.workload == "spec-decode":
         result = asyncio.run(run_spec_bench(args.requests))
-    elif args.workload == "mixed-length":
-        result = asyncio.run(run_mixed_length_bench(args.requests))
     elif args.workload == "slo-mix":
         result = asyncio.run(run_slo_mix_bench(args.requests))
         print(json.dumps(result))

@@ -83,7 +83,7 @@ def _run_check_inner() -> list[str]:
         save_adapter(lora_dir, "acme", cfg, rank=4)
         core = EngineCore(
             cfg, num_slots=4, slot_capacity=128, prefill_buckets=(16, 32),
-            kv_layout="paged", kv_page_size=16, seed=0,
+            kv_page_size=16, seed=0,
             quantize="kv", lora_dir=lora_dir, spec_decode=True,
             eos_id=tok.eos_id,
         )

@@ -53,7 +53,7 @@ def engine_metric_names() -> set[str]:
     text = m.render(
         queue_depth=0, active_slots=0, num_slots=1,
         prefix_cache={
-            "enabled": True, "entries": 0, "pinned_slots": 0,
+            "enabled": True, "entries": 0,
             "pinned_pages": 0, "pinned_hbm_bytes": 0,
         },
         structured={

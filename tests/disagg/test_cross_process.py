@@ -24,7 +24,7 @@ from llmlb_tpu.engine.server import create_engine_app
 from llmlb_tpu.engine.service import Engine
 
 KW = dict(num_slots=2, slot_capacity=128, prefill_buckets=(16, 32),
-          seed=0, kv_layout="paged", kv_page_size=16)
+          seed=0, kv_page_size=16)
 
 SCHEMA = {
     "type": "object",

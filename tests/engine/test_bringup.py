@@ -139,12 +139,12 @@ def test_bench_fails_loudly_when_the_engine_raises():
     with value 0.0 and exit 0."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-        env=_env(JAX_PLATFORMS="cpu", LLMLB_KV_LAYOUT="no-such-layout"),
+        env=_env(JAX_PLATFORMS="cpu", LLMLB_ROLE="no-such-role"),
         capture_output=True, text=True, timeout=300,
     )
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
-    assert "Traceback" in proc.stderr and "kv_layout" in proc.stderr
+    assert "Traceback" in proc.stderr and "no-such-role" in proc.stderr
 
 
 def test_bench_refuses_a_cpu_nobody_asked_for():

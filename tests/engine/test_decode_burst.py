@@ -221,8 +221,6 @@ def test_prefill_dispatch_failure_reaches_batched_requests(cfg):
     core.family = type("F", (), {
         **{k: staticmethod(getattr(core.family, k))
            for k in dir(core.family) if not k.startswith("__")},
-        # both layouts' insert paths fail (paged is the default layout)
-        "prefill_into_slots": staticmethod(boom),
         "prefill_into_pages": staticmethod(boom),
     })()
     core.start()
@@ -271,10 +269,12 @@ def test_window_buckets_cross_boundary(cfg):
 
 
 def test_prewarm_compiles_both_modes(cfg):
-    """Prewarm must cover burst AND single-step modes (the k==1 path gained
-    per-window static recompiles of decode_step); a signature drift between
-    decode_step and the prewarm lowering would otherwise be swallowed by the
-    best-effort except and only surface as production compile stalls."""
+    """Prewarm must cover burst AND single-step modes (the legacy k==1 path
+    has per-window static recompiles of decode_step_paged); a signature
+    drift between decode_step_paged and the prewarm lowering would
+    otherwise be swallowed by the best-effort except and only surface as
+    production compile stalls. Fused engines dispatch the burst scan even
+    at k == 1, so the single-step arm needs fused decode off."""
     import dataclasses as _dc
 
     from unittest import mock
@@ -284,7 +284,8 @@ def test_prewarm_compiles_both_modes(cfg):
     cfg512 = _dc.replace(cfg, max_position_embeddings=1024)
     for burst in (4, 1):
         core = EngineCore(cfg512, num_slots=2, slot_capacity=512,
-                          prefill_buckets=(16,), seed=0, decode_burst=burst)
+                          prefill_buckets=(16,), seed=0, decode_burst=burst,
+                          fused_decode=burst > 1)
         assert core._window_buckets == (256, 512)
         core._running = True
         try:

@@ -24,7 +24,7 @@ from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.service import Engine
 
 KW = dict(num_slots=4, slot_capacity=256, prefill_buckets=(16, 32, 64),
-          seed=0, kv_layout="paged", kv_page_size=16, prefix_cache=False)
+          seed=0, kv_page_size=16, prefix_cache=False)
 
 
 @pytest.fixture(scope="module")
@@ -160,10 +160,6 @@ def test_role_normalization():
 
 
 def test_split_requires_paged_layout_and_two_slots():
-    with pytest.raises(ValueError, match="paged"):
-        EngineCore(get_preset("debug-tiny"), role="split",
-                   num_slots=2, slot_capacity=64, prefill_buckets=(16,),
-                   kv_layout="dense")
     with pytest.raises(ValueError, match="2 slots"):
         EngineCore(get_preset("debug-tiny"), role="split",
                    num_slots=1, slot_capacity=64, prefill_buckets=(16,))

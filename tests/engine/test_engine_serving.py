@@ -11,22 +11,22 @@ from llmlb_tpu.engine.server import create_engine_app
 from llmlb_tpu.engine.service import Engine
 
 
-# The whole serving contract runs over BOTH KV layouts: paged (default —
-# shared page pool + block tables) and dense (the original slot cache) —
-# plus the paged layout with the int8 quantization knob EXPLICITLY off,
-# proving the quantization plumbing is zero-cost when disabled
-# (docs/quantization.md; bit-identity itself is pinned by
+# The whole serving contract runs over two page-pool geometries: a page the
+# size of the smallest prefill bucket, and a page SMALLER than it (4 against
+# bucket 16: every prefill spans several pages and every decode burst
+# crosses one) — plus the int8 quantization knob EXPLICITLY off, proving the
+# quantization plumbing is zero-cost when disabled (docs/quantization.md;
+# bit-identity itself is pinned by
 # test_quantized_serving.test_quantize_off_bit_identical).
 @pytest.fixture(scope="module",
-                params=["paged", "dense", "paged-quantize-off"])
+                params=["paged", "paged-page4", "paged-quantize-off"])
 def engine(request):
-    layout = "dense" if request.param == "dense" else "paged"
+    page = 4 if request.param == "paged-page4" else 16
     extra = ({"quantize": "off"} if request.param == "paged-quantize-off"
              else {})
     eng = Engine.from_preset(
         "debug-tiny", num_slots=4, slot_capacity=64,
-        prefill_buckets=(16, 32), seed=0,
-        kv_layout=layout, kv_page_size=16, **extra,
+        prefill_buckets=(16, 32), seed=0, kv_page_size=page, **extra,
     )
     yield eng
     eng.shutdown()

@@ -1,0 +1,62 @@
+"""The serving contract a model family provides, held name by name.
+
+`models.family_for(cfg)` hands the scheduler a module of free functions and
+the scheduler calls them positionally (`engine/scheduler.py`); a missing
+name is a start-up `AttributeError` or a silent `hasattr` downgrade, and a
+drifted parameter is a `TypeError` at the first dispatch. This holds every
+family to llama's signatures up front, so the next family is added against
+a test and not against a traceback.
+"""
+
+import inspect
+
+import pytest
+
+from llmlb_tpu.engine.presets import get_preset
+from llmlb_tpu.models import family_for, llama
+
+# one config per module family_for can return
+FAMILIES = {
+    "llama": family_for(get_preset("debug-tiny")),
+    "mixtral": family_for(get_preset("debug-moe-tiny")),
+}
+
+CONTRACT = (
+    "init_params",
+    "param_shardings",
+    "init_kv_pages",
+    "kv_pages_shardings",
+    "prefill_into_pages",
+    "prefill_extend_pages",
+    "verify_step_paged",
+    "decode_step_paged",
+    "make_context_parallel_prefill",
+)
+# Found by `hasattr` and served without when absent: ring-attention prefill
+# runs llama's dense feed-forward, so a mixture of experts must not export it.
+OPTIONAL = {"make_context_parallel_prefill"}
+
+
+def _params(fn) -> list[tuple[str, inspect._ParameterKind]]:
+    """Names and kinds, in order: what a positional or a keyword call binds
+    to. Annotations and defaults may differ (a family names its own config
+    class); `inspect.signature` sees through `jax.jit`."""
+    return [(p.name, p.kind)
+            for p in inspect.signature(fn).parameters.values()]
+
+
+def test_every_family_module_is_covered():
+    assert FAMILIES["llama"] is llama
+    assert len({id(m) for m in FAMILIES.values()}) == len(FAMILIES)
+
+
+@pytest.mark.parametrize("name", CONTRACT)
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_family_provides_the_paged_contract(family, name):
+    module = FAMILIES[family]
+    if not hasattr(module, name):
+        assert name in OPTIONAL, f"{family} lacks {name}"
+        return
+    assert _params(getattr(module, name)) == _params(getattr(llama, name)), (
+        f"{family}.{name} takes other parameters than llama.{name}"
+    )

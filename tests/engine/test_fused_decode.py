@@ -10,8 +10,7 @@ The acceptance bars for the one-program decode step:
   exactly one device program, and constrained slots never force the batch
   into single-step decode (constrained_burst_fallback_total == 0).
 - PIN: LLMLB_FUSED_DECODE=0 resolves to the legacy path (and the grid
-  proves legacy output unchanged by this PR); default is on for paged
-  layout, off for dense.
+  proves legacy output unchanged by this PR); default is on.
 """
 
 import numpy as np
@@ -63,7 +62,7 @@ def _core(*, fused: bool, quant: str | None, lora_dir: str | None,
           spec: bool) -> EngineCore:
     core = EngineCore(
         CFG, num_slots=4, slot_capacity=128, prefill_buckets=(16, 32),
-        kv_layout="paged", kv_page_size=16, seed=0, quantize=quant,
+        kv_page_size=16, seed=0, quantize=quant,
         lora_dir=lora_dir, spec_decode=spec, fused_decode=fused,
         eos_id=TOK.eos_id,
     )
@@ -148,32 +147,27 @@ def _assert_fused_invariants(core: EngineCore, *, spec: bool) -> None:
 
 def test_env_pin_and_defaults(monkeypatch):
     """LLMLB_FUSED_DECODE resolves: 0 pins legacy, 1 pins fused, unset
-    defaults on for paged and off for dense (the conservative default for
-    the layout the fused path wasn't built around)."""
+    defaults on."""
     monkeypatch.setenv("LLMLB_FUSED_DECODE", "0")
     core = EngineCore(CFG, num_slots=2, slot_capacity=64,
-                      prefill_buckets=(16,), kv_layout="paged", seed=0)
+                      prefill_buckets=(16,), seed=0)
     assert core.fused_decode is False
     assert core._grammar_tables is None
 
     monkeypatch.setenv("LLMLB_FUSED_DECODE", "1")
     core = EngineCore(CFG, num_slots=2, slot_capacity=64,
-                      prefill_buckets=(16,), kv_layout="paged", seed=0)
+                      prefill_buckets=(16,), seed=0)
     assert core.fused_decode is True
     assert core._grammar_tables is not None
 
     monkeypatch.delenv("LLMLB_FUSED_DECODE")
     assert EngineCore(CFG, num_slots=2, slot_capacity=64,
-                      prefill_buckets=(16,), kv_layout="paged",
-                      seed=0).fused_decode is True
-    assert EngineCore(CFG, num_slots=2, slot_capacity=64,
-                      prefill_buckets=(16,), kv_layout="dense",
-                      seed=0).fused_decode is False
+                      prefill_buckets=(16,), seed=0).fused_decode is True
 
     # constructor kwarg beats the env var
     monkeypatch.setenv("LLMLB_FUSED_DECODE", "1")
     assert EngineCore(CFG, num_slots=2, slot_capacity=64,
-                      prefill_buckets=(16,), kv_layout="paged", seed=0,
+                      prefill_buckets=(16,), seed=0,
                       fused_decode=False).fused_decode is False
 
 

@@ -221,7 +221,7 @@ def _park_roundtrip(*, offload, temperature=0.0, seed=None, quantize=None,
     Returns (ref_tokens, victim_tokens, prefill_dispatches_for_victim+
     interloper, kv_transfer_info)."""
     kw = dict(num_slots=1, slot_capacity=64, prefill_buckets=(16,),
-              seed=0, kv_layout="paged", kv_page_size=16,
+              seed=0, kv_page_size=16,
               prefix_cache=False, quantize=quantize)
     if kv_ship is not None:
         kw["kv_ship"] = kv_ship
@@ -300,8 +300,8 @@ def test_prefix_evicted_to_tier_rehits_without_reprefill():
     A = list(rng.integers(1, cfg.vocab_size, size=(48,)))
     B = list(rng.integers(1, cfg.vocab_size, size=(48,)))
     core = EngineCore(cfg, num_slots=2, slot_capacity=64,
-                      prefill_buckets=(16,), seed=0, kv_layout="paged",
-                      kv_page_size=16, kv_pages=6,
+                      prefill_buckets=(16,), seed=0, kv_page_size=16,
+                      kv_pages=6,
                       kv_offload_bytes=1 << 28)
     core.start()
     try:

@@ -5,13 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llmlb_tpu.models import llama
 from llmlb_tpu.models.llama import (
     LlamaConfig,
-    decode_step,
-    init_kv_cache,
+    decode_step_paged,
     init_params,
-    prefill,
+    prefill_into_pages,
 )
+from tests.support import identity_kv_pages
 
 TINY = LlamaConfig(
     vocab_size=256,
@@ -36,19 +37,21 @@ def test_prefill_then_decode_matches_full_prefill():
     ids = jax.random.randint(jax.random.PRNGKey(1), (b, t_full), 0, cfg.vocab_size)
     lens_full = jnp.array([t_full, t_full], jnp.int32)
 
-    ck, cv = init_kv_cache(cfg, b, capacity)
-    full_logits, _, _ = prefill(params, cfg, ids, lens_full, ck, cv)
+    ck, cv, tables = identity_kv_pages(llama, cfg, b, capacity)
+    full_logits, _, _ = prefill_into_pages(params, cfg, ids, lens_full,
+                                           tables, ck, cv)
 
     # prefill only the first 5 tokens, then decode the remaining 3
     t0 = 5
-    ck, cv = init_kv_cache(cfg, b, capacity)
+    ck, cv, tables = identity_kv_pages(llama, cfg, b, capacity)
     padded = jnp.zeros((b, t0), jnp.int32).at[:, :t0].set(ids[:, :t0])
-    logits, ck, cv = prefill(
-        params, cfg, padded, jnp.array([t0, t0], jnp.int32), ck, cv
+    logits, ck, cv = prefill_into_pages(
+        params, cfg, padded, jnp.array([t0, t0], jnp.int32), tables, ck, cv
     )
     for step in range(t0, t_full):
-        logits, ck, cv = decode_step(
-            params, cfg, ids[:, step], jnp.full((b,), step, jnp.int32), ck, cv
+        logits, ck, cv = decode_step_paged(
+            params, cfg, ids[:, step], jnp.full((b,), step, jnp.int32),
+            ck, cv, tables,
         )
     np.testing.assert_allclose(
         np.asarray(logits), np.asarray(full_logits), rtol=2e-4, atol=2e-4
@@ -63,12 +66,14 @@ def test_ragged_prompt_lens_ignore_padding():
     ids = jax.random.randint(jax.random.PRNGKey(2), (b, t), 0, cfg.vocab_size)
     lens = jnp.array([5, 8], jnp.int32)
 
-    ck, cv = init_kv_cache(cfg, b, capacity)
-    logits_a, _, _ = prefill(params, cfg, ids, lens, ck, cv)
+    ck, cv, tables = identity_kv_pages(llama, cfg, b, capacity)
+    logits_a, _, _ = prefill_into_pages(params, cfg, ids, lens, tables,
+                                        ck, cv)
 
     garbage = ids.at[0, 5:].set(7)  # mutate only padding of sequence 0
-    ck, cv = init_kv_cache(cfg, b, capacity)
-    logits_b, _, _ = prefill(params, cfg, garbage, lens, ck, cv)
+    ck, cv, tables = identity_kv_pages(llama, cfg, b, capacity)
+    logits_b, _, _ = prefill_into_pages(params, cfg, garbage, lens, tables,
+                                        ck, cv)
     np.testing.assert_allclose(
         np.asarray(logits_a), np.asarray(logits_b), rtol=1e-5, atol=1e-5
     )
@@ -114,9 +119,9 @@ def test_matches_hf_transformers(attention_bias, tie):
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids_np)).logits[:, -1, :].numpy()
 
-    ck, cv = init_kv_cache(cfg, b, 16)
-    logits, _, _ = prefill(
+    ck, cv, tables = identity_kv_pages(llama, cfg, b, 16)
+    logits, _, _ = prefill_into_pages(
         params, cfg, jnp.asarray(ids_np, jnp.int32),
-        jnp.full((b,), t, jnp.int32), ck, cv,
+        jnp.full((b,), t, jnp.int32), tables, ck, cv,
     )
     np.testing.assert_allclose(np.asarray(logits), hf_logits, rtol=2e-3, atol=2e-3)

@@ -1,7 +1,7 @@
 """Long-context serving: chunked prefill + prefill/decode interleaving.
 
 VERDICT r1 items 4 and 7: prompts beyond the largest one-shot prefill bucket
-must stream through the engine (chunked prefill via prefill_extend_slots), and
+must stream through the engine (chunked prefill via prefill_extend_pages), and
 decode slots must keep emitting tokens while a long prompt prefills.
 """
 
@@ -16,6 +16,7 @@ from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.models import llama
+from tests.support import identity_kv_pages, kv_rows
 
 
 @pytest.fixture(scope="module")
@@ -37,38 +38,39 @@ def test_prefill_extend_matches_oneshot(tiny_cfg, tiny_params):
     prompt = rng.integers(0, cfg.vocab_size, size=(n,)).astype(np.int32)
 
     # one-shot reference: bucket 64
-    ck, cv = llama.init_kv_cache(cfg, 2, capacity)
+    ck, cv, tables = identity_kv_pages(llama, cfg, 2, capacity)
+    row1 = tables[1:2]
     ids = np.zeros((1, 64), np.int32)
     ids[0, :n] = prompt
-    ref_logits, ck_ref, cv_ref = llama.prefill_into_slots(
+    ref_logits, ck_ref, cv_ref = llama.prefill_into_pages(
         params, cfg, jnp.asarray(ids), jnp.asarray([n], np.int32),
-        jnp.asarray([1], np.int32), ck, cv,
+        row1, ck, cv,
     )
 
-    # chunked: 16-token chunks into slot 1
-    ck2, cv2 = llama.init_kv_cache(cfg, 2, capacity)
+    # chunked: 16-token chunks into row 1
+    ck2, cv2, _ = identity_kv_pages(llama, cfg, 2, capacity)
     logits = None
     for start in range(0, n, 16):
         chunk = prompt[start:start + 16]
         ids_c = np.zeros((1, 16), np.int32)
         ids_c[: , :len(chunk)] = chunk
-        logits, ck2, cv2 = llama.prefill_extend_slots(
+        logits, ck2, cv2 = llama.prefill_extend_pages(
             params, cfg, jnp.asarray(ids_c),
             jnp.asarray([len(chunk)], np.int32),
             jnp.asarray([start], np.int32),
-            jnp.asarray([1], np.int32), ck2, cv2,
+            row1, ck2, cv2,
         )
 
     np.testing.assert_allclose(
         np.asarray(logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4
     )
-    # caches agree over the valid region of slot 1
+    # caches agree over the valid region of row 1
     np.testing.assert_allclose(
-        np.asarray(ck_ref[:, 1, :n]), np.asarray(ck2[:, 1, :n]),
+        np.asarray(kv_rows(ck_ref, row1, n)), np.asarray(kv_rows(ck2, row1, n)),
         rtol=2e-4, atol=2e-4,
     )
     np.testing.assert_allclose(
-        np.asarray(cv_ref[:, 1, :n]), np.asarray(cv2[:, 1, :n]),
+        np.asarray(kv_rows(cv_ref, row1, n)), np.asarray(kv_rows(cv2, row1, n)),
         rtol=2e-4, atol=2e-4,
     )
 

@@ -52,19 +52,20 @@ def _run(core, lora=None, seed=None, temp=0.0, max_tokens=12,
     return _drain(r)[0]
 
 
-def _core(lora_dir=None, *, kv_layout="paged", num_slots=5, **kw):
+def _core(lora_dir=None, *, kv_page_size=16, num_slots=5, **kw):
     return EngineCore(CFG, num_slots=num_slots, slot_capacity=128,
-                      prefill_buckets=(8, 16), kv_layout=kv_layout,
-                      kv_page_size=16, seed=0, lora_dir=lora_dir, **kw)
+                      prefill_buckets=(8, 16), kv_page_size=kv_page_size,
+                      seed=0, lora_dir=lora_dir, **kw)
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "dense"])
-def test_mixed_adapter_batch_byte_identical_to_solo(lora_dir, kv_layout):
+@pytest.mark.parametrize("kv_page", [16, 4], ids=["paged", "paged-page4"])
+def test_mixed_adapter_batch_byte_identical_to_solo(lora_dir, kv_page):
     """≥3 adapters + 1 adapter-free row decode TOGETHER; every row matches
     its solo run exactly. Greedy and seeded-stochastic (one engine session
-    covers both — the jit compiles dominate tier-1 cost), paged and dense.
+    covers both — the jit compiles dominate tier-1 cost), at a page twice
+    the smallest prefill bucket and at one half of it.
     """
-    core = _core(lora_dir, kv_layout=kv_layout)
+    core = _core(lora_dir, kv_page_size=kv_page)
     core.start()
     try:
         for kw in ({}, dict(temp=0.8, seed=77)):

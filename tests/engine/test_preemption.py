@@ -7,7 +7,7 @@ resumed later via a chunk-prefill of its committed tokens — must emit
 exactly the token stream an uninterrupted run would have. Greedy is
 deterministic outright; seeded stochastic holds because sample keys fold
 PRNGKey(seed) by ABSOLUTE position, independent of batch composition.
-Covered over paged and dense KV layouts and with speculative decoding on.
+Covered over two page geometries and with speculative decoding on.
 """
 
 import asyncio
@@ -39,11 +39,13 @@ SCHEMA = {
 # the preemption point — the victim parks MID-GENERATION, resumes through
 # the prefill pool, and hands off a second time. Bit-identity must hold
 # across park + double handoff, grammar cursor and drafter riding along.
+# "paged-page4" is the same engine with a page a quarter of the smallest
+# prefill bucket: a park frees, and a resume re-lands, several pages a chunk.
 @pytest.fixture(scope="module",
-                params=["paged", "dense", "paged-spec", "split",
+                params=["paged", "paged-page4", "paged-spec", "split",
                         "split-spec"])
 def engine(request):
-    layout = "dense" if request.param == "dense" else "paged"
+    page = 4 if request.param == "paged-page4" else 16
     extra = {}
     if request.param.endswith("spec"):
         extra["spec_decode"] = True
@@ -54,7 +56,7 @@ def engine(request):
     eng = Engine.from_preset(
         "debug-tiny", num_slots=slots, slot_capacity=128,
         prefill_buckets=(16, 32), seed=0,
-        kv_layout=layout, kv_page_size=16, **extra,
+        kv_page_size=page, **extra,
     )
     yield eng
     eng.shutdown()
@@ -150,7 +152,7 @@ def test_midstream_page_exhaustion_parks_instead_of_finishing():
     token-identical to an uncontended run."""
     eng = Engine.from_preset(
         "debug-tiny", num_slots=2, slot_capacity=64,
-        prefill_buckets=(16,), seed=0, kv_layout="paged", kv_page_size=8,
+        prefill_buckets=(16,), seed=0, kv_page_size=8,
         kv_pages=9,  # trash page + 8: two growing decoders cannot both fit
         prefix_cache=False,
     )
@@ -181,7 +183,7 @@ def test_prefill_chunk_budget_interleaves_and_is_token_identical():
         return Engine.from_preset(
             "debug-tiny", num_slots=2, slot_capacity=256,
             prefill_buckets=(16, 32, 64, 128), seed=0,
-            kv_layout="paged", kv_page_size=16,
+            kv_page_size=16,
             prefill_chunk_budget=budget, prefix_cache=False,
         )
 
@@ -355,8 +357,7 @@ def lora_engine(tmp_path_factory):
     save_adapter(str(d), "acme", cfg, rank=4)
     eng = Engine.from_preset(
         "debug-tiny", num_slots=1, slot_capacity=128,
-        prefill_buckets=(16, 32), seed=0, kv_layout="paged",
-        kv_page_size=16, lora_dir=str(d),
+        prefill_buckets=(16, 32), seed=0, kv_page_size=16, lora_dir=str(d),
     )
     yield eng
     eng.shutdown()

@@ -4,7 +4,7 @@ Three layers, mirroring the implementation split:
 - PrefixCache unit tests (pure host-side: insert/match/refcount/evict,
   bucket alignment, LRU order, edge splitting).
 - EngineCore integration (CPU backend): cache hits serve the shared head
-  from copied KV rows, outputs stay greedy-identical to the cold path,
+  from the donor's pages, outputs stay greedy-identical to the cold path,
   cancellation mid-suffix-prefill releases the donor, disabled flag
   restores the old behavior.
 - A fast perf smoke asserting a cache-hit insert dispatches NO prefill
@@ -20,6 +20,7 @@ import pytest
 from llmlb_tpu.engine.prefix_cache import PrefixCache
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
+from tests.support import assert_hit_is_zero_copy
 
 # ----------------------------------------------------------------- radix tree
 
@@ -31,15 +32,21 @@ def make_cache(**kw):
     return PrefixCache(**kw)
 
 
+def _evict(c):
+    """Pages of the LRU entry evicted, or None when every entry is held."""
+    entry = c.evict_lru_entry()
+    return None if entry is None else entry.pages
+
+
 def test_insert_and_exact_match():
     c = make_cache()
-    assert c.insert((1, 2, 3, 4, 5, 6, 7, 8), slot=0) is not None
+    assert c.insert((1, 2, 3, 4, 5, 6, 7, 8), pages=(0,)) is not None
     got = c.match([1, 2, 3, 4, 5, 6, 7, 8, 9], max_len=8)
     assert got is not None
     entry, use_len = got
-    assert entry.slot == 0
+    assert entry.pages == (0,)
     assert use_len == 8
-    assert c.pinned_slots() == {0}
+    assert [e.pages for e in c.entries()] == [(0,)]
     assert c.cached_tokens() == 8
 
 
@@ -47,18 +54,18 @@ def test_match_uses_partial_head_of_longer_entry():
     """KV rows for [0, m) depend only on tokens [0, m): a stored prefix can
     donate any of its own prefixes, including partway into a radix edge."""
     c = make_cache()
-    c.insert(tuple(range(100, 112)), slot=1)  # 12 tokens
+    c.insert(tuple(range(100, 112)), pages=(1,))  # 12 tokens
     # query shares only the first 6 tokens, then diverges
     got = c.match(list(range(100, 106)) + [999, 998], max_len=7)
     assert got is not None
     entry, use_len = got
-    assert entry.slot == 1
+    assert entry.pages == (1,)
     assert use_len == 4  # 6 matched, aligned down to the 4-token quantum
 
 
 def test_match_respects_max_len_and_min_len():
     c = make_cache()
-    c.insert((1, 2, 3, 4, 5, 6, 7, 8), slot=0)
+    c.insert((1, 2, 3, 4, 5, 6, 7, 8), pages=(0,))
     # an identical prompt must leave >= 1 suffix token: max_len = n - 1
     entry, use_len = c.match([1, 2, 3, 4, 5, 6, 7, 8], max_len=7)
     assert use_len == 4  # 7 aligned down
@@ -68,82 +75,82 @@ def test_match_respects_max_len_and_min_len():
 
 def test_edge_split_on_divergent_insert():
     c = make_cache()
-    c.insert((1, 2, 3, 4, 5, 6, 7, 8), slot=0)
-    c.insert((1, 2, 3, 4, 9, 9, 9, 9), slot=1)  # splits the edge at depth 4
+    c.insert((1, 2, 3, 4, 5, 6, 7, 8), pages=(0,))
+    c.insert((1, 2, 3, 4, 9, 9, 9, 9), pages=(1,))  # splits the edge at depth 4
     e0, u0 = c.match([1, 2, 3, 4, 5, 6, 7, 8, 0], max_len=8)
     e1, u1 = c.match([1, 2, 3, 4, 9, 9, 9, 9, 0], max_len=8)
-    assert (e0.slot, u0) == (0, 8)
-    assert (e1.slot, u1) == (1, 8)
+    assert (e0.pages, u0) == ((0,), 8)
+    assert (e1.pages, u1) == ((1,), 8)
     assert len(c) == 2
 
 
 def test_covers_blocks_duplicate_coverage_but_allows_extension():
     c = make_cache()
-    c.insert((1, 2, 3, 4), slot=0)
+    c.insert((1, 2, 3, 4), pages=(0,))
     assert c.covers((1, 2, 3, 4))
-    assert c.insert((1, 2, 3, 4), slot=1) is None  # no new coverage
+    assert c.insert((1, 2, 3, 4), pages=(1,)) is None  # no new coverage
     # a LONGER prefix is new coverage
-    assert c.insert((1, 2, 3, 4, 5, 6, 7, 8), slot=1) is not None
+    assert c.insert((1, 2, 3, 4, 5, 6, 7, 8), pages=(1,)) is not None
     # ...and the short one is now covered by the long one too
     assert c.covers((1, 2, 3, 4))
 
 
 def test_refcount_blocks_eviction():
     c = make_cache()
-    e = c.insert((1, 2, 3, 4), slot=0)
+    e = c.insert((1, 2, 3, 4), pages=(0,))
     c.acquire(e)
-    assert c.evict_lru() is None  # in-flight reader pins it
+    assert _evict(c) is None  # in-flight reader pins it
     c.release(e)
-    assert c.evict_lru() == 0
+    assert _evict(c) == (0,)
     assert len(c) == 0
     assert c.match([1, 2, 3, 4, 5], max_len=4) is None
 
 
 def test_lru_eviction_order_and_match_refreshes():
     c = make_cache()
-    c.insert((1,) * 8, slot=0)
-    c.insert((2,) * 8, slot=1)
-    c.insert((3,) * 8, slot=2)
-    c.match([1] * 9, max_len=8)  # a match refreshes slot 0's clock
-    assert c.evict_lru() == 1    # slot 1 is now the oldest untouched
-    assert c.evict_lru() == 2
-    assert c.evict_lru() == 0
-    assert c.evict_lru() is None
+    c.insert((1,) * 8, pages=(0,))
+    c.insert((2,) * 8, pages=(1,))
+    c.insert((3,) * 8, pages=(2,))
+    c.match([1] * 9, max_len=8)  # a match refreshes the first entry's clock
+    assert _evict(c) == (1,)     # the second is now the oldest untouched
+    assert _evict(c) == (2,)
+    assert _evict(c) == (0,)
+    assert _evict(c) is None
 
 
 def test_evict_subsumed_reclaims_ancestor_donors():
     """A longer prefix covers every match its ancestors could serve; the
-    ancestors' donor slots are reclaimed instead of bleeding the budget one
-    slot per conversation turn."""
+    ancestors' entries are reclaimed instead of bleeding the budget one
+    entry per conversation turn."""
     c = make_cache()
-    e1 = c.insert((1, 2, 3, 4), slot=0)
+    e1 = c.insert((1, 2, 3, 4), pages=(0,))
     turn2 = (1, 2, 3, 4, 5, 6, 7, 8)
-    assert c.evict_subsumed(turn2) == [0]
-    c.insert(turn2, slot=1)
-    assert c.pinned_slots() == {1}
+    assert [e.pages for e in c.evict_subsumed_entries(turn2)] == [(0,)]
+    c.insert(turn2, pages=(1,))
+    assert [e.pages for e in c.entries()] == [(1,)]
     # coverage is preserved: the short head still matches via the long entry
     entry, use_len = c.match([1, 2, 3, 4, 9], max_len=4)
-    assert entry.slot == 1 and use_len == 4
+    assert entry.pages == (1,) and use_len == 4
     # an acquired ancestor is NOT reclaimed (in-flight reader)
-    e2 = c.insert((9, 9, 9, 9), slot=2)
+    e2 = c.insert((9, 9, 9, 9), pages=(2,))
     c.acquire(e2)
-    assert c.evict_subsumed((9, 9, 9, 9, 1, 1, 1, 1)) == []
+    assert c.evict_subsumed_entries((9, 9, 9, 9, 1, 1, 1, 1)) == []
     c.release(e2)
     assert e1.node is None  # removed entry is fully detached
 
 
 def test_budget_rejects_insert_when_full():
     c = make_cache(max_entries=1)
-    assert c.insert((1, 2, 3, 4), slot=0) is not None
-    assert c.insert((5, 6, 7, 8), slot=1) is None  # caller must evict first
-    assert c.evict_lru() == 0
-    assert c.insert((5, 6, 7, 8), slot=1) is not None
+    assert c.insert((1, 2, 3, 4), pages=(0,)) is not None
+    assert c.insert((5, 6, 7, 8), pages=(1,)) is None  # caller must evict first
+    assert _evict(c) == (0,)
+    assert c.insert((5, 6, 7, 8), pages=(1,)) is not None
 
 
 def test_clear_drops_everything():
     c = make_cache()
-    c.insert((1, 2, 3, 4), slot=0)
-    c.insert((1, 2, 3, 4, 5, 6, 7, 8), slot=1)
+    c.insert((1, 2, 3, 4), pages=(0,))
+    c.insert((1, 2, 3, 4, 5, 6, 7, 8), pages=(1,))
     c.clear()
     assert len(c) == 0
     assert c.match([1, 2, 3, 4, 5], max_len=4) is None
@@ -175,25 +182,30 @@ def prompt():
     return list(rng.integers(1, cfg.vocab_size, size=(48,)))
 
 
-# Every engine-core test runs over BOTH KV layouts: dense (pinned donor
-# slots + device-side row copies) and paged (zero-copy page sharing). The
-# 16-token page size matches the prefill bucket so aligned lengths — and
-# every counter assertion below — are identical across layouts.
-@pytest.fixture(params=["dense", "paged"])
-def kv_layout(request):
+# Every engine-core test runs over two page geometries: a page equal to the
+# prefill bucket (16), and a page that is a multiple of it (32), where the
+# sharing quantum prefix_align = lcm(bucket, page) is the page and no longer
+# the bucket — a 48-token prompt then donates 32 tokens, not 48.
+@pytest.fixture(params=[16, 32], ids=["paged", "paged-page32"])
+def kv_page(request):
     return request.param
 
 
-def make_core(kv_layout, **kw):
-    kw.setdefault("kv_page_size", 16)
-    return EngineCore(get_preset("debug-tiny"), kv_layout=kv_layout, **kw)
+def make_core(kv_page, **kw):
+    return EngineCore(get_preset("debug-tiny"), kv_page_size=kv_page, **kw)
 
 
-def test_cache_hit_reuses_prefix_and_matches_cold_output(prompt, kv_layout):
+def _head(n, kv_page):
+    """Tokens of an n-token prompt that can be donated: whole sharing
+    quanta, lcm(bucket 16, page)."""
+    return n // kv_page * kv_page
+
+
+def test_cache_hit_reuses_prefix_and_matches_cold_output(prompt, kv_page):
     """Warm identical prompt: hit counters move, cached tokens are the
-    aligned head, and greedy output equals the cold run's (the copied KV
-    rows are the same numbers the cold prefill computed)."""
-    core = make_core(kv_layout, num_slots=4, slot_capacity=64,
+    aligned head, and greedy output equals the cold run's (the shared
+    pages hold the same numbers the cold prefill computed)."""
+    core = make_core(kv_page, num_slots=4, slot_capacity=64,
                      prefill_buckets=(16,), seed=0)
     core.start()
     try:
@@ -203,7 +215,7 @@ def test_cache_hit_reuses_prefix_and_matches_cold_output(prompt, kv_layout):
         assert m.prefix_insertions_total == 1
         info = core.prefix_cache_info()
         assert info["enabled"] and info["entries"] == 1
-        assert info["cached_tokens"] == 48
+        assert info["cached_tokens"] == _head(48, kv_page)
 
         warm_toks, warm_fin = _run(core, prompt)
         assert m.prefix_hits_total == 1
@@ -214,8 +226,8 @@ def test_cache_hit_reuses_prefix_and_matches_cold_output(prompt, kv_layout):
         core.stop()
 
 
-def test_divergent_tail_still_hits_shared_head(prompt, kv_layout):
-    core = make_core(kv_layout, num_slots=4, slot_capacity=64,
+def test_divergent_tail_still_hits_shared_head(prompt, kv_page):
+    core = make_core(kv_page, num_slots=4, slot_capacity=64,
                      prefill_buckets=(16,), seed=0)
     core.start()
     try:
@@ -229,17 +241,16 @@ def test_divergent_tail_still_hits_shared_head(prompt, kv_layout):
         core.stop()
 
 
-def test_slot_pressure_evicts_donors_for_live_traffic(kv_layout):
-    """With every non-pinned slot busy and requests queued, pinned donors
-    are evicted LRU rather than starving the queue (dense); in paged mode
-    the same budget bound churns ENTRIES instead of slots."""
+def test_slot_pressure_evicts_donors_for_live_traffic(kv_page):
+    """Donors pin pages, not slots: under an entry budget of one, a run of
+    distinct prompts churns ENTRIES (LRU) and every slot stays free."""
     cfg = get_preset("debug-tiny")
     rng = np.random.default_rng(3)
-    core = make_core(kv_layout, num_slots=2, slot_capacity=64,
+    core = make_core(kv_page, num_slots=2, slot_capacity=64,
                      prefill_buckets=(16,), prefix_cache_slots=1, seed=0)
     core.start()
     try:
-        prompts = [list(rng.integers(1, cfg.vocab_size, size=(20,)))
+        prompts = [list(rng.integers(1, cfg.vocab_size, size=(40,)))
                    for _ in range(4)]
         for p in prompts:
             _run(core, p)  # each completion pins (budget 1 -> evictions)
@@ -268,12 +279,12 @@ def _drive_to_completion(core, request, limit=500):
     raise AssertionError("request did not finish")
 
 
-def test_cancel_mid_suffix_prefill_releases_entry(prompt, kv_layout):
+def test_cancel_mid_suffix_prefill_releases_entry(prompt, kv_page):
     """A cache-hit request cancelled during its suffix prefill must release
     the donor entry (refcount back to 0) so it stays evictable. Driven
     inline — the loop thread is never started — so the cancellation lands
-    exactly between the KV-row copy and the first suffix chunk."""
-    core = make_core(kv_layout, num_slots=4, slot_capacity=64,
+    exactly between the page-table hit and the first suffix chunk."""
+    core = make_core(kv_page, num_slots=4, slot_capacity=64,
                      prefill_buckets=(16,), seed=0)
     # warm the cache with one completed request
     kind, _ = _drive_to_completion(
@@ -285,23 +296,23 @@ def test_cancel_mid_suffix_prefill_releases_entry(prompt, kv_layout):
     r = Request(prompt_ids=list(prompt),
                 sampling=SamplingParams(temperature=0.0, max_tokens=8))
     core.pending.put(r)
-    core._try_insert()  # hit: copies rows, acquires the donor, prefilling
+    core._try_insert()  # hit: shares pages, acquires the donor, prefilling
     assert core.metrics.prefix_hits_total == 1
     assert entry.refcount == 1
-    assert core.prefix_cache.evict_lru() is None  # reader pins the donor
+    assert _evict(core.prefix_cache) is None  # reader pins the donor
 
     r.cancel()
     core._advance_prefill()  # observes the cancellation mid-suffix-prefill
     assert r.events.get_nowait() == ("done", "cancelled")
     assert entry.refcount == 0
-    assert core.prefix_cache.evict_lru() is not None  # evictable again
+    assert _evict(core.prefix_cache) is not None  # evictable again
 
 
-def test_multi_turn_conversation_reuses_one_donor_slot(prompt, kv_layout):
+def test_multi_turn_conversation_reuses_one_donor_slot(prompt, kv_page):
     """Growing-conversation shape: each turn extends the last prompt. The
     cache must hold ONE entry for the conversation (ancestors reclaimed),
-    not one pinned slot (or page set) per turn."""
-    core = make_core(kv_layout, num_slots=4, slot_capacity=64,
+    not one pinned page set per turn."""
+    core = make_core(kv_page, num_slots=4, slot_capacity=64,
                      prefill_buckets=(16,), prefix_cache_slots=3, seed=0)
     core.start()
     try:
@@ -312,7 +323,7 @@ def test_multi_turn_conversation_reuses_one_donor_slot(prompt, kv_layout):
         _run(core, turn)
         assert len(core.prefix_cache) == 1  # one donor covers all turns
         (entry,) = core.prefix_cache.entries()
-        assert entry.length == 48
+        assert entry.length == _head(48, kv_page)
     finally:
         core.stop()
 
@@ -331,8 +342,8 @@ def test_env_var_disables_prefix_cache(monkeypatch):
     assert core.prefix_cache is not None
 
 
-def test_disabled_flag_restores_plain_scheduler(prompt, kv_layout):
-    core = make_core(kv_layout, num_slots=2, slot_capacity=64,
+def test_disabled_flag_restores_plain_scheduler(prompt, kv_page):
+    core = make_core(kv_page, num_slots=2, slot_capacity=64,
                      prefill_buckets=(16,), prefix_cache=False, seed=0)
     core.start()
     try:
@@ -347,8 +358,8 @@ def test_disabled_flag_restores_plain_scheduler(prompt, kv_layout):
         core.stop()
 
 
-def test_prefix_metrics_in_prometheus_and_summary(prompt, kv_layout):
-    core = make_core(kv_layout, num_slots=4, slot_capacity=64,
+def test_prefix_metrics_in_prometheus_and_summary(prompt, kv_page):
+    core = make_core(kv_page, num_slots=4, slot_capacity=64,
                      prefill_buckets=(16,), seed=0)
     core.start()
     try:
@@ -363,12 +374,8 @@ def test_prefix_metrics_in_prometheus_and_summary(prompt, kv_layout):
         assert "llmlb_engine_prefix_cache_misses_total 1" in text
         assert "llmlb_engine_prefix_cache_cached_tokens_total 32" in text
         assert "llmlb_engine_prefix_cache_evictions_total 0" in text
-        if kv_layout == "paged":
-            # zero-copy donors pin pages, never slots
-            assert "llmlb_engine_prefix_cache_pinned_slots 0" in text
-            assert "llmlb_engine_prefix_cache_pinned_pages 3" in text
-        else:
-            assert "llmlb_engine_prefix_cache_pinned_slots 1" in text
+        pinned = _head(48, kv_page) // kv_page
+        assert f"llmlb_engine_prefix_cache_pinned_pages {pinned}" in text
         assert "llmlb_engine_prefix_cache_pinned_hbm_bytes" in text
         summary = core.metrics.summary()
         assert summary["prefix_hits_total"] == 1
@@ -380,13 +387,12 @@ def test_prefix_metrics_in_prometheus_and_summary(prompt, kv_layout):
 # ----------------------------------------------------------------- perf smoke
 
 
-def test_cache_hit_skips_prefill_for_cached_region(prompt, kv_layout):
+def test_cache_hit_skips_prefill_for_cached_region(prompt, kv_page):
     """Tier-1 regression guard: a hit must dispatch prefill steps ONLY for
     the uncached suffix. 48-token prompt over 16-token chunks: 3 dispatches
-    cold, exactly 1 warm (32 tokens ride the device-side row copy in dense
-    mode, the donor's shared pages in paged mode — which must additionally
-    dispatch ZERO cache copies)."""
-    core = make_core(kv_layout, num_slots=4, slot_capacity=64,
+    cold, exactly 1 warm (32 tokens ride the donor's shared pages — and the
+    hit builds no program of its own: there is no copy to dispatch)."""
+    core = make_core(kv_page, num_slots=4, slot_capacity=64,
                      prefill_buckets=(16,), seed=0)
     core.start()
     try:
@@ -394,15 +400,14 @@ def test_cache_hit_skips_prefill_for_cached_region(prompt, kv_layout):
         _run(core, prompt)
         cold_steps = m.prefill_step.n
         assert cold_steps == 3
-        _run(core, prompt)
+        with assert_hit_is_zero_copy(core, suffix_tokens=16):
+            _run(core, prompt)
         warm_steps = m.prefill_step.n - cold_steps
         assert m.prefix_hits_total == 1
         assert warm_steps == 1, (
             f"cache hit re-prefilled the cached region: {warm_steps} "
             f"dispatches for a 16-token suffix"
         )
-        if kv_layout == "paged":
-            assert core.kv_copy_dispatches == 0
     finally:
         core.stop()
 

@@ -20,6 +20,7 @@ import pytest
 
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
+from tests.support import assert_hit_is_zero_copy
 
 
 def _req(prompt, max_tokens=8, temperature=0.0, seed=None, spec=None):
@@ -61,14 +62,15 @@ def prompts():
 # ------------------------------------------------------- off == bit-identical
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "dense"])
-def test_quantize_off_bit_identical(prompts, kv_layout):
+@pytest.mark.parametrize("kv_page", [16, 4], ids=["paged", "paged-page4"])
+def test_quantize_off_bit_identical(prompts, kv_page):
     """The zero-cost-when-disabled acceptance bar: greedy and seeded
     stochastic streams from a quantize="off" engine match an engine built
-    without the knob token for token."""
+    without the knob token for token (at a page the size of the smallest
+    prefill bucket, and at one a quarter of it)."""
     streams = {}
     for label, quantize in (("default", None), ("off", "off")):
-        core = _core(kv_layout=kv_layout, quantize=quantize)
+        core = _core(kv_page_size=kv_page, quantize=quantize)
         core.start()
         try:
             reqs = [
@@ -118,14 +120,15 @@ def test_int8_kv_serves_and_reports_halved_bytes(prompts):
 
 def test_int8_kv_prefix_hit_stays_zero_copy(prompts):
     """Zero-copy sharing is page-id bookkeeping; the scale arrays ride the
-    same ids, so a hit must still dispatch no device copy."""
+    same ids, so a hit must still build and dispatch no program of its own
+    (40-token prompt, 32 shared: the suffix is 8 tokens)."""
     core = _core(quantize="kv")
     core.start()
     try:
         _collect(core.submit(_req(prompts[2])))
-        _collect(core.submit(_req(prompts[2])))
+        with assert_hit_is_zero_copy(core, suffix_tokens=8):
+            _collect(core.submit(_req(prompts[2])))
         assert core.metrics.prefix_hits_total == 1
-        assert core.kv_copy_dispatches == 0
     finally:
         core.stop()
 
@@ -156,7 +159,7 @@ def test_spec_decode_on_int8_pages_rolls_back_cleanly():
     Prompts with repeated n-grams guarantee the drafter proposes."""
     cfg = get_preset("debug-tiny")
     core = EngineCore(cfg, num_slots=4, slot_capacity=64,
-                      prefill_buckets=(16, 32), seed=0, kv_layout="paged",
+                      prefill_buckets=(16, 32), seed=0,
                       kv_page_size=4, quantize="kv", spec_decode=True,
                       spec_max_draft=3, prefix_cache=False)
     core.start()
@@ -237,15 +240,6 @@ def test_quantize_all_through_service_health():
         assert "llmlb_engine_param_bytes" in text
     finally:
         eng.shutdown()
-
-
-def test_dense_layout_rejects_kv_quant_gracefully():
-    core = _core(kv_layout="dense", quantize="all")
-    try:
-        assert core.quant.weights and not core.quant.kv
-        assert core.kv_cache_info()["kv_dtype"] != "int8"
-    finally:
-        core.stop()
 
 
 def test_streaming_loader_matches_core_quantization(tmp_path):

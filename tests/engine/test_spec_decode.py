@@ -57,10 +57,13 @@ def _ids(eng, text=PROMPT):
     return eng.encode_chat([{"role": "user", "content": text}])
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "dense"])
-def test_greedy_parity_token_exact(kv_layout):
+@pytest.mark.parametrize("kv_page", [128, 4], ids=["paged", "paged-page4"])
+def test_greedy_parity_token_exact(kv_page):
+    """Spec on == spec off, token for token — at the default page and at a
+    4-token page, where a K+1-token verify chunk crosses page boundaries and
+    a rejected suffix rolls pages back."""
     async def collect(spec):
-        eng = _engine(spec, kv_layout=kv_layout)
+        eng = _engine(spec, kv_page_size=kv_page)
         try:
             r = await eng.complete(
                 _ids(eng), SamplingParams(temperature=0.0, max_tokens=120)
@@ -241,7 +244,6 @@ def _paged_core(**kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("slot_capacity", 64)
     kw.setdefault("prefill_buckets", (16,))
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("kv_page_size", 4)
     kw.setdefault("prefix_cache", False)
     return EngineCore(cfg, **kw)
@@ -290,7 +292,7 @@ def test_spec_traffic_leaves_page_pool_clean():
     async def run():
         eng = Engine.from_preset(
             "debug-tiny", spec_decode=True, num_slots=4, slot_capacity=128,
-            prefill_buckets=(16, 32), kv_layout="paged", kv_page_size=4,
+            prefill_buckets=(16, 32), kv_page_size=4,
             prefix_cache=False,
         )
         try:

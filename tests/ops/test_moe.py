@@ -9,6 +9,7 @@ import pytest
 
 from llmlb_tpu.ops.moe import default_capacity, moe_dispatch_combine, top_k_routing
 from llmlb_tpu.parallel.mesh import MeshConfig, build_mesh
+from tests.support import identity_kv_pages
 
 
 def _dense_reference(x, logits, wg, wu, wd, k):
@@ -127,15 +128,17 @@ def test_mixtral_prefill_decode_consistency():
     ids = jax.random.randint(jax.random.PRNGKey(4), (b, t), 0, cfg.vocab_size)
     lens = jnp.full((b,), t, jnp.int32)
 
-    ck, cv = mixtral.init_kv_cache(cfg, b, cap)
-    logits_p, ck, cv = mixtral.prefill(params, cfg, ids, lens, ck, cv)
+    ck, cv, tables = identity_kv_pages(mixtral, cfg, b, cap)
+    logits_p, ck, cv = mixtral.prefill_into_pages(params, cfg, ids, lens,
+                                                  tables, ck, cv)
 
     # replay: prefill t-1 tokens then decode the t-th
-    ck2, cv2 = mixtral.init_kv_cache(cfg, b, cap)
+    ck2, cv2, _ = identity_kv_pages(mixtral, cfg, b, cap)
     lens2 = jnp.full((b,), t - 1, jnp.int32)
-    _, ck2, cv2 = mixtral.prefill(params, cfg, ids[:, : t - 1], lens2, ck2, cv2)
-    logits_d, _, _ = mixtral.decode_step(
-        params, cfg, ids[:, t - 1], lens2, ck2, cv2
+    _, ck2, cv2 = mixtral.prefill_into_pages(
+        params, cfg, ids[:, : t - 1], lens2, tables, ck2, cv2)
+    logits_d, _, _ = mixtral.decode_step_paged(
+        params, cfg, ids[:, t - 1], lens2, ck2, cv2, tables
     )
     np.testing.assert_allclose(
         np.asarray(logits_p), np.asarray(logits_d), rtol=5e-4, atol=5e-4
@@ -155,20 +158,23 @@ def test_mixtral_ep_tp_sharded_serving_step(cpu_mesh_devices):
     ids = jax.random.randint(jax.random.PRNGKey(6), (b, t), 0, cfg.vocab_size)
     lens = jnp.full((b,), t, jnp.int32)
 
-    ck, cv = mixtral.init_kv_cache(cfg, b, cap)
-    want, _, _ = mixtral.prefill(params, cfg, ids, lens, ck, cv)
+    ck, cv, tables = identity_kv_pages(mixtral, cfg, b, cap)
+    want, _, _ = mixtral.prefill_into_pages(params, cfg, ids, lens, tables,
+                                            ck, cv)
 
     shardings = mixtral.param_shardings(cfg, mesh)
     params_sh = {k: jax.device_put(v, shardings[k]) for k, v in params.items()}
-    ck, cv = mixtral.init_kv_cache(cfg, b, cap)
-    ck_sh, cv_sh = mixtral.kv_cache_shardings(cfg, mesh)
+    ck, cv, _ = identity_kv_pages(mixtral, cfg, b, cap)
+    ck_sh, cv_sh = mixtral.kv_pages_shardings(cfg, mesh)
     ck, cv = jax.device_put(ck, ck_sh), jax.device_put(cv, cv_sh)
-    got, ck, cv = mixtral.prefill(params_sh, cfg, ids, lens, ck, cv, mesh)
+    got, ck, cv = mixtral.prefill_into_pages(params_sh, cfg, ids, lens,
+                                             tables, ck, cv, mesh)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=5e-4, atol=5e-4
     )
 
     # and one decode step on the same sharded state
     tok = jnp.argmax(got, -1).astype(jnp.int32)
-    logits_d, _, _ = mixtral.decode_step(params_sh, cfg, tok, lens, ck, cv, mesh)
+    logits_d, _, _ = mixtral.decode_step_paged(params_sh, cfg, tok, lens,
+                                               ck, cv, tables, mesh)
     assert np.isfinite(np.asarray(logits_d)).all()

@@ -19,7 +19,6 @@ from llmlb_tpu.ops.attention import (
     gqa_attention_prefill,
 )
 from llmlb_tpu.ops.pallas_attention import (
-    flash_decode,
     flash_prefill,
     paged_flash_decode,
     paged_flash_extend,
@@ -37,45 +36,6 @@ def _pin_baseline_to_xla(monkeypatch):
 
 def _rand(key, shape):
     return jax.random.normal(key, shape, jnp.float32)
-
-
-@pytest.mark.parametrize(
-    "b,h,kv,d,s,block_k",
-    [
-        (2, 8, 8, 32, 64, 32),  # MHA, multiple blocks
-        (3, 8, 2, 16, 96, 32),  # GQA g=4, S divisible
-        (2, 4, 1, 32, 40, 32),  # MQA, ragged last block (40 = 32 + 8)
-        (1, 8, 4, 64, 128, 128),  # single block covers everything
-    ],
-)
-def test_flash_decode_matches_xla(b, h, kv, d, s, block_k):
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = _rand(keys[0], (b, 1, h, d))
-    k_cache = _rand(keys[1], (b, s, kv, d))
-    v_cache = _rand(keys[2], (b, s, kv, d))
-    kv_lens = jax.random.randint(keys[3], (b,), 1, s + 1, jnp.int32)
-
-    expected = gqa_attention_decode(q, k_cache, v_cache, kv_lens)
-    got = flash_decode(
-        q[:, 0], k_cache, v_cache, kv_lens, block_k=block_k, interpret=True
-    )
-    np.testing.assert_allclose(got, expected[:, 0], rtol=2e-5, atol=2e-5)
-
-
-def test_flash_decode_extreme_lens():
-    """kv_len=1 (only first token valid) and kv_len=S (fully dense)."""
-    b, h, kv, d, s = 2, 4, 2, 16, 48
-    keys = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = _rand(keys[0], (b, 1, h, d))
-    k_cache = _rand(keys[1], (b, s, kv, d))
-    v_cache = _rand(keys[2], (b, s, kv, d))
-    kv_lens = jnp.array([1, s], jnp.int32)
-
-    expected = gqa_attention_decode(q, k_cache, v_cache, kv_lens)
-    got = flash_decode(
-        q[:, 0], k_cache, v_cache, kv_lens, block_k=16, interpret=True
-    )
-    np.testing.assert_allclose(got, expected[:, 0], rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize(
@@ -152,8 +112,8 @@ def _paged_fixture(key, b, h, kv, d, page_size, pages_per_seq):
 def test_paged_flash_decode_matches_dense(b, h, kv, d, page_size,
                                           pages_per_seq, layer):
     """The paged kernel gathering KV through the layer index and the block
-    table must equal the dense kernel over the materialized (gathered)
-    cache of that layer."""
+    table must equal the einsum over the materialized (gathered) cache of
+    that layer."""
     keys = jax.random.split(jax.random.PRNGKey(10), 3)
     cap = page_size * pages_per_seq
     q = _rand(keys[0], (b, 1, h, d))
@@ -173,8 +133,7 @@ def test_paged_flash_decode_matches_dense(b, h, kv, d, page_size,
 
 @pytest.mark.parametrize("layer", [0, 2])
 def test_paged_flash_decode_page_window(layer):
-    """`pages` bounds the sweep exactly like flash_decode's `window`: rows
-    within the swept pages are exact."""
+    """`pages` bounds the sweep: rows within the swept pages are exact."""
     b, h, kv, d, ps, ppn = 2, 4, 2, 16, 16, 4
     keys = jax.random.split(jax.random.PRNGKey(11), 2)
     q = _rand(keys[0], (b, 1, h, d))
@@ -195,6 +154,60 @@ def test_paged_flash_decode_page_window(layer):
         q, k_pool, v_pool, layer, tables, kv_lens, window=2 * ps
     )
     np.testing.assert_allclose(got2, expected, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv_len", ["empty", "one", "full_table"])
+def test_paged_flash_decode_extreme_lens(kv_len):
+    """Row 0 at the edge of the ragged range beside an ordinary row 1: a
+    single valid cell (page 0 of the row, offset 0), every cell of a full
+    block table, and no cell at all — which must come out as finite zeros
+    (the kernel skips every page and divides by a guarded l), not NaN."""
+    b, h, kv, d, ps, ppn = 2, 4, 2, 16, 16, 3
+    layer = 1
+    keys = jax.random.split(jax.random.PRNGKey(13), 2)
+    q = _rand(keys[0], (b, 1, h, d))
+    k_pages, v_pages, tables = _paged_fixture(keys[1], b, h, kv, d, ps, ppn)
+    n0 = {"empty": 0, "one": 1, "full_table": ps * ppn}[kv_len]
+    kv_lens = jnp.array([n0, ps + 5], jnp.int32)
+
+    got = paged_flash_decode(
+        q[:, 0], _stacked(k_pages, layer), _stacked(v_pages, layer), layer,
+        tables, kv_lens, interpret=True
+    )
+    k_cache = gather_kv_pages(k_pages, tables)
+    v_cache = gather_kv_pages(v_pages, tables)
+    expected = gqa_attention_decode(q, k_cache, v_cache, kv_lens)[:, 0]
+    np.testing.assert_allclose(got[1], expected[1], rtol=2e-5, atol=2e-5)
+    if n0 == 0:
+        np.testing.assert_array_equal(np.asarray(got[0]), 0.0)
+    else:
+        np.testing.assert_allclose(got[0], expected[0], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_flash_decode_pages_below_longest_row(layer):
+    """A `pages` bound BELOW the longest row's page count (the engine's
+    parked/freed rows, whose device counters sit at capacity while the
+    window follows the active rows): rows inside the sweep stay exact, the
+    row beyond it is garbage by contract — but finite garbage, computed
+    from its own swept pages only."""
+    b, h, kv, d, ps, ppn = 3, 4, 2, 16, 16, 4
+    keys = jax.random.split(jax.random.PRNGKey(14), 2)
+    q = _rand(keys[0], (b, 1, h, d))
+    k_pages, v_pages, tables = _paged_fixture(keys[1], b, h, kv, d, ps, ppn)
+    kv_lens = jnp.array([ps + 1, ps * ppn, 2 * ps], jnp.int32)  # row 1 beyond
+
+    got = paged_flash_decode(
+        q[:, 0], _stacked(k_pages, layer), _stacked(v_pages, layer), layer,
+        tables, kv_lens, pages=2, interpret=True
+    )
+    k_cache = gather_kv_pages(k_pages, tables[:, :2])
+    v_cache = gather_kv_pages(v_pages, tables[:, :2])
+    # what the swept pages alone give: row 1 clipped to the two pages read
+    swept = gqa_attention_decode(
+        q, k_cache, v_cache, jnp.minimum(kv_lens, 2 * ps))[:, 0]
+    np.testing.assert_allclose(got, swept, rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
 
 
 @pytest.mark.parametrize(
@@ -218,7 +231,7 @@ def test_paged_flash_extend_matches_dense(b, t, h, kv, d, page_size,
 
     k_cache = gather_kv_pages(k_pages, tables)
     v_cache = gather_kv_pages(v_pages, tables)
-    expected = gqa_attention_extend(q, k_cache, v_cache, q_positions, None)
+    expected = gqa_attention_extend(q, k_cache, v_cache, q_positions)
     got = paged_flash_extend(
         q, k_pages, v_pages, tables, start_pos, chunk_lens,
         block_q=block_q, interpret=True,
@@ -245,13 +258,14 @@ def test_model_dispatch_pallas_matches_xla(monkeypatch):
     """
     import numpy as np
 
+    from llmlb_tpu.models import llama
     from llmlb_tpu.models.llama import (
         LlamaConfig,
-        decode_step,
-        init_kv_cache,
+        decode_step_paged,
         init_params,
-        prefill,
+        prefill_into_pages,
     )
+    from tests.support import identity_kv_pages
 
     cfg = LlamaConfig(
         vocab_size=128,
@@ -270,12 +284,14 @@ def test_model_dispatch_pallas_matches_xla(monkeypatch):
     results = {}
     for mode in ("xla", "pallas"):
         monkeypatch.setenv("LLMLB_TPU_ATTENTION", mode)
-        prefill._clear_cache()
-        decode_step._clear_cache()
-        ck, cv = init_kv_cache(cfg, batch, capacity)
-        logits, ck, cv = prefill(params, cfg, ids, lens, ck, cv)
+        prefill_into_pages._clear_cache()
+        decode_step_paged._clear_cache()
+        ck, cv, tables = identity_kv_pages(llama, cfg, batch, capacity)
+        logits, ck, cv = prefill_into_pages(params, cfg, ids, lens, tables,
+                                            ck, cv)
         toks = jnp.argmax(logits, -1).astype(jnp.int32)
-        logits2, ck, cv = decode_step(params, cfg, toks, lens, ck, cv)
+        logits2, ck, cv = decode_step_paged(params, cfg, toks, lens, ck, cv,
+                                            tables)
         results[mode] = (np.asarray(logits), np.asarray(logits2))
 
     np.testing.assert_allclose(
@@ -283,57 +299,4 @@ def test_model_dispatch_pallas_matches_xla(monkeypatch):
     )
     np.testing.assert_allclose(
         results["pallas"][1], results["xla"][1], rtol=1e-4, atol=1e-4
-    )
-
-
-def test_flash_extend_matches_xla_extend():
-    """Chunked-prefill kernel vs the XLA einsum baseline, ragged starts."""
-    import numpy as np
-
-    from llmlb_tpu.ops.attention import gqa_attention_extend
-    from llmlb_tpu.ops.pallas_attention import flash_extend
-
-    rng = np.random.default_rng(5)
-    b, t, h, k, d, s = 2, 16, 8, 4, 32, 64
-    q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-    kc = jnp.asarray(rng.standard_normal((b, s, k, d)), jnp.float32)
-    vc = jnp.asarray(rng.standard_normal((b, s, k, d)), jnp.float32)
-    starts = jnp.asarray([0, 23], jnp.int32)
-    chunk_lens = jnp.asarray([t, 9], jnp.int32)
-    positions = starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-
-    ref = gqa_attention_extend(q, kc, vc, positions)  # XLA path (no lens)
-    out = flash_extend(q, kc, vc, starts, chunk_lens, interpret=True,
-                       block_q=8, block_k=16)
-    # compare only valid queries; padded rows are ignored by the caller
-    for bi in range(b):
-        n = int(chunk_lens[bi])
-        np.testing.assert_allclose(
-            np.asarray(out)[bi, :n], np.asarray(ref)[bi, :n],
-            rtol=2e-5, atol=2e-5,
-        )
-
-
-def test_flash_decode_window_bounds_sweep():
-    """A static window >= max(kv_lens) must be a numeric no-op while sweeping
-    fewer kv blocks (the scheduler's context-window bucket optimization)."""
-    b, h, kv, d, s = 2, 8, 4, 32, 128
-    keys = jax.random.split(jax.random.PRNGKey(2), 4)
-    q = _rand(keys[0], (b, 1, h, d))
-    k_cache = _rand(keys[1], (b, s, kv, d))
-    v_cache = _rand(keys[2], (b, s, kv, d))
-    kv_lens = jnp.array([40, 64], jnp.int32)  # all within window=64
-
-    full = flash_decode(q[:, 0], k_cache, v_cache, kv_lens,
-                        block_k=32, interpret=True)
-    windowed = flash_decode(q[:, 0], k_cache, v_cache, kv_lens,
-                            block_k=32, interpret=True, window=64)
-    np.testing.assert_allclose(windowed, full, rtol=2e-5, atol=2e-5)
-
-    # the XLA dispatch path with a window must also match
-    xla_windowed = gqa_attention_decode(
-        q, k_cache, v_cache, kv_lens, window=64
-    )
-    np.testing.assert_allclose(
-        xla_windowed[:, 0], full, rtol=2e-5, atol=2e-5
     )

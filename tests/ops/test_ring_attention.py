@@ -76,9 +76,11 @@ def test_ring_with_dp_batch_sharding(cpu_mesh_devices):
 def test_context_parallel_prefill_matches_dense(cpu_mesh_devices):
     """Full-model sequence-parallel prefill == dense prefill (logits and KV)."""
     from llmlb_tpu.engine.presets import get_preset
+    from llmlb_tpu.models import llama
     from llmlb_tpu.models.llama import (
-        init_kv_cache, init_params, make_context_parallel_prefill, prefill,
+        init_params, make_context_parallel_prefill, prefill_into_pages,
     )
+    from tests.support import identity_kv_pages, kv_rows
 
     cfg = get_preset("debug-tiny")
     params = init_params(cfg, jax.random.PRNGKey(3))
@@ -88,10 +90,11 @@ def test_context_parallel_prefill_matches_dense(cpu_mesh_devices):
     ids = jax.random.randint(jax.random.PRNGKey(4), (b, t), 0, cfg.vocab_size)
     lens = jnp.array([32, 21], jnp.int32)
 
-    cache_k, cache_v = init_kv_cache(cfg, b, t)
-    dense_logits, dense_k, dense_v = prefill(
-        params, cfg, ids, lens, cache_k, cache_v
+    cache_k, cache_v, tables = identity_kv_pages(llama, cfg, b, t)
+    dense_logits, pool_k, pool_v = prefill_into_pages(
+        params, cfg, ids, lens, tables, cache_k, cache_v
     )
+    dense_k, dense_v = kv_rows(pool_k, tables, t), kv_rows(pool_v, tables, t)
 
     cp_prefill = make_context_parallel_prefill(cfg, mesh)
     cp_logits, k_all, v_all = cp_prefill(params, ids, lens)

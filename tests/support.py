@@ -9,6 +9,7 @@ health / failover / streaming entirely in-process, no TPUs required.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 
 from aiohttp import web
@@ -29,6 +30,61 @@ from llmlb_tpu.gateway.types import (
 
 TEST_JWT_SECRET = "test-jwt-secret"
 ADMIN_PASSWORD = "adminpass1"
+
+
+# ------------------------------------------------- family-level KV fixtures
+
+
+def identity_kv_pages(family, cfg, batch: int, capacity: int,
+                      page_size: int = 8, **kw):
+    """A fresh page pool and the identity block table over it, for tests
+    that call a family's paged entry points without an engine: row b owns
+    pages 1 + b*ppn .. (b+1)*ppn in order (page 0 is the trash page), so
+    logical position p of row b is cell [1 + b*ppn + p // page_size,
+    p % page_size]. Returns (cache_k, cache_v, tables [batch, ppn])."""
+    import jax.numpy as jnp
+
+    ppn = -(-capacity // page_size)
+    ck, cv = family.init_kv_pages(cfg, batch * ppn + 1, page_size, **kw)
+    tables = jnp.arange(1, batch * ppn + 1, dtype=jnp.int32)
+    return ck, cv, tables.reshape(batch, ppn)
+
+
+@contextlib.contextmanager
+def assert_hit_is_zero_copy(core, suffix_tokens: int):
+    """Around ONE request that hits the prefix cache of a started
+    EngineCore whose cold path already ran every program the hit's suffix
+    needs (same chunk and decode shapes): the hit builds NO program on the
+    loop threads — a copy of the shared head would be a new one — and
+    dispatches exactly one prefill, the `suffix_tokens`-token suffix chunk;
+    every other step it records is a decode."""
+    from llmlb_tpu.engine import compilelog
+
+    built = compilelog.counters()
+    prefills = sum(core.prefill_dispatch_by_loop.values())
+    seq = core.step_stats.snapshot(limit=1)["records"][0]["seq"]
+    yield
+    loop = compilelog.summary(built)["by_thread"]["loop"]
+    assert loop["programs_total"] == 0, (
+        "a prefix hit built a program: "
+        f"{[b['fun_name'] for b in compilelog.recent(since=built)]}"
+    )
+    assert sum(core.prefill_dispatch_by_loop.values()) - prefills == 1
+    steps = [r for r in core.step_stats.snapshot(limit=256)["records"]
+             if r["seq"] > seq]
+    assert [r["tokens"] for r in steps if r["kind"] == "prefill"] == [
+        suffix_tokens]
+    assert {r["kind"] for r in steps} == {"prefill", "decode"}
+
+
+def kv_rows(pool, tables, n: int):
+    """The first `n` logical KV rows of every table row, gathered out of a
+    bf16/f32 pool [L, P, PS, K, D] -> [L, B, n, K, D]."""
+    import jax
+
+    from llmlb_tpu.ops.attention import gather_kv_pages
+
+    return jax.vmap(lambda layer: gather_kv_pages(layer, tables))(pool)[:, :, :n]
 
 
 # --------------------------------------------------- SSE protocol invariants

@@ -23,7 +23,7 @@ def test_enumeration_is_not_vacuous():
     """The source scan must find the well-known knobs (no silent pass if
     the glob or regex breaks)."""
     knobs = check_env_docs.source_knobs()
-    for expected in ("LLMLB_QUANTIZE", "LLMLB_KV_LAYOUT",
+    for expected in ("LLMLB_QUANTIZE", "LLMLB_FUSED_DECODE",
                      "LLMLB_DECODE_BURST", "LLMLB_PREFIX_CACHE"):
         assert expected in knobs, expected
     # glob-style prose ("LLMLB_SPEC_{DECODE,...}") must not leak partials
