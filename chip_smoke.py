@@ -806,10 +806,11 @@ def kernel_leg() -> int:
     for b in (8, 32):
         q = rand(b, 1, H, D)
 
-        def paged_decode(pages, quant):
+        def paged_decode(pages, quant, dead=False):
             # the decode kernels take the pool stacked over layers and read
             # it at (layer, page): layer 1 of 2 here, layer 0 all zeros (an
-            # int8 pool's scales go in as that layer's slice)
+            # int8 pool's scales go in as that layer's slice). `dead`: two
+            # rows in three are not live (length 0) and must come out zero
             def stacked(x):
                 return jnp.stack([jnp.zeros_like(x), x])
 
@@ -818,6 +819,8 @@ def kernel_leg() -> int:
             qk, qv = ({m: stacked(x) for m, x in qp.items()}
                       for qp in quant_pools)
             lens = jnp.asarray(rng.integers(1, pages * PS + 1, b), jnp.int32)
+            if dead:
+                lens = jnp.where(jnp.arange(b) % 3 == 1, lens, 0)
             if quant:
                 want = xla.paged_attention_decode(q, qk, qv, 1, tables, lens,
                                                   window=pages * PS)
@@ -832,14 +835,15 @@ def kernel_leg() -> int:
                                             tables, lens, pages=pages,
                                             interpret=False)
             check("paged_flash_decode_quant" if quant else
-                  "paged_flash_decode", f"B={b},pages={pages}", got,
-                  want[:, 0])
+                  "paged_flash_decode", f"B={b},pages={pages},dead={dead}",
+                  got, jnp.where((lens > 0)[:, None, None], want[:, 0], 0.0))
 
-        for pages in (2, PPN):
+        for pages, dead in ((2, False), (PPN, False), (PPN, True)):
             for quant in (False, True):
                 attempt("paged_flash_decode_quant" if quant else
-                        "paged_flash_decode", f"B={b},pages={pages}",
-                        lambda: paged_decode(pages, quant))
+                        "paged_flash_decode",
+                        f"B={b},pages={pages},dead={dead}",
+                        lambda: paged_decode(pages, quant, dead))
 
     # prefill: causal self-attention over a bucketed prompt
     for b, t in ((8, 128), (2, 512)):

@@ -160,6 +160,11 @@ class EngineMetrics:
         # fused mode off).
         self.fused_decode_steps_total = 0
         self.decode_dispatches_total = 0
+        # Σ over decode steps of the pages their live rows hold, and of the
+        # pages of slots x window: live / window is the share of a
+        # window-wide sweep that was live context
+        self.decode_kv_pages_live_total = 0
+        self.decode_kv_pages_window_total = 0
         self.constrained_burst_fallback_total = 0
         # Overload protection (docs/scheduling.md): slots parked under
         # slot/page pressure, parked requests re-activated, and requests
@@ -338,6 +343,13 @@ class EngineMetrics:
             if fused:
                 self.fused_decode_steps_total += 1
 
+    def record_decode_kv_pages(self, kv_pages_live: int,
+                               kv_pages_window: int) -> None:
+        """One decode step's page counts (scheduler._kv_pages)."""
+        with self._lock:
+            self.decode_kv_pages_live_total += kv_pages_live
+            self.decode_kv_pages_window_total += kv_pages_window
+
     def record_constrained_burst_fallback(self) -> None:
         """A constrained slot forced the decode loop off the fused/burst
         path into single-step legacy decode this step."""
@@ -500,6 +512,9 @@ class EngineMetrics:
                 ),
                 "fused_decode_steps_total": self.fused_decode_steps_total,
                 "decode_dispatches_total": self.decode_dispatches_total,
+                "decode_kv_pages_live_total": self.decode_kv_pages_live_total,
+                "decode_kv_pages_window_total":
+                    self.decode_kv_pages_window_total,
                 "constrained_burst_fallback_total":
                     self.constrained_burst_fallback_total,
                 "preemptions_total": self.preemptions_total,
@@ -613,6 +628,12 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_decode_dispatches_total counter",
                 "llmlb_engine_decode_dispatches_total "
                 f"{self.decode_dispatches_total}",
+                "# TYPE llmlb_engine_decode_kv_pages_live_total counter",
+                "llmlb_engine_decode_kv_pages_live_total "
+                f"{self.decode_kv_pages_live_total}",
+                "# TYPE llmlb_engine_decode_kv_pages_window_total counter",
+                "llmlb_engine_decode_kv_pages_window_total "
+                f"{self.decode_kv_pages_window_total}",
                 "# TYPE llmlb_engine_constrained_burst_fallback_total "
                 "counter",
                 "llmlb_engine_constrained_burst_fallback_total "

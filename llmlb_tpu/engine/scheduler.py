@@ -327,7 +327,9 @@ class _Slot:
     # While prefilling is True the slot is excluded from decode emission and
     # its device seq_len is parked at capacity-1 so the batched decode step's
     # garbage writes land in the (unused) last cell, never inside the region
-    # the chunks are filling.
+    # the chunks are filling. The parked length is for the WRITE alone: the
+    # row is not among a dispatch's _live_rows, so attention reads none of
+    # its pages.
     prefilling: bool = False
     prefill_pos: int = 0
     # Prefix-cache entry this slot is reading (hit path): acquired for the
@@ -1033,6 +1035,7 @@ class EngineCore:
         # the caches may be quantized {"q","s"} pytrees — map per leaf
         cache_k_shapes = jax.tree.map(sharded, self.cache_k)
         cache_v_shapes = jax.tree.map(sharded, self.cache_v)
+        live = jax.ShapeDtypeStruct((self.num_slots,), np.bool_)  # _live_rows
         args = [
             param_shapes,
             plain(self._d_last_tokens),
@@ -1042,6 +1045,7 @@ class EngineCore:
             plain(self._d_temps), plain(self._d_top_ps),
             plain(self._d_top_ks), plain(self._d_seeds),
             plain(self._key),  # split keys keep this shape/dtype
+            live,
         ]
         for w in self._window_buckets:
             if not self._running:
@@ -1058,7 +1062,7 @@ class EngineCore:
                         param_shapes, self.cfg, plain(self._d_last_tokens),
                         plain(self._d_seq_lens), cache_k_shapes,
                         cache_v_shapes, plain(self._d_block_tables),
-                        self.mesh, window=w,
+                        self.mesh, window=w, live=live,
                     ).compile()
             except Exception:  # pragma: no cover - best-effort warmup
                 log.exception("window %d prewarm failed (will compile "
@@ -1499,7 +1503,8 @@ class EngineCore:
     def _record_step(self, kind: str, step: StepSpan, *,
                      active_slots: int = 0, tokens: int = 0,
                      slots: "list[int] | None" = None,
-                     dispatches: int = 0, fused: bool = False) -> None:
+                     dispatches: int = 0, fused: bool = False,
+                     kv_pages: "dict[str, int] | None" = None) -> None:
         """Close one step (its last stamp) and finalize its record: the
         admission time since the previous record becomes its plan phase,
         the record feeds the ring buffer + anomaly detector, and the phase
@@ -1511,8 +1516,11 @@ class EngineCore:
         `dispatches` is the honest device-program count this step issued
         (decode/verify kinds feed the per-loop dispatch ledger and the
         fused-decode "exactly one" invariant); `fused` marks steps served
-        by the single-program path. What this costs after the step's last
-        stamp is the next record's since_prev.record_s."""
+        by the single-program path. `kv_pages` (_kv_pages, decode records)
+        lands on the record and in the engine's two running totals: the
+        share of a (slots x window) sweep that live pages are. What this
+        costs after the step's last stamp is the next record's
+        since_prev.record_s."""
         clock = self._clock()
         clock.close(step, kind)
         phases = step.phases()
@@ -1530,7 +1538,10 @@ class EngineCore:
                                        active_slots=active_slots,
                                        tokens=tokens,
                                        request_ids=request_ids,
-                                       dispatches=dispatches, span=step)
+                                       dispatches=dispatches, span=step,
+                                       extra=kv_pages)
+        if kv_pages:
+            self.metrics.record_decode_kv_pages(**kv_pages)
         self.metrics.record_step_phases(phases, slow=slow)
         if slow and request_ids and self.flightrec.enabled:
             total = round(sum(phases.values()), 6)
@@ -3761,24 +3772,47 @@ class EngineCore:
                 return w
         return self.slot_capacity
 
+    def _live_rows(self, active: list[int]) -> np.ndarray:
+        """[slots] bool, true for the rows a decode dispatch emits for. It
+        rides the call as a host array (as the rows of _activate_rows do):
+        the device's length counters cannot say which rows decode — a freed
+        or never-used row keeps counting, a prefilling one is parked at
+        capacity - 1 — and the attention kernel walks the pages of live rows
+        only (models/llama._decode_paged_impl)."""
+        live = np.zeros((self.num_slots,), np.bool_)
+        live[active] = True
+        return live
+
+    def _kv_pages(self, active: list[int], k: int, window: int) -> dict:
+        """A decode record's two page counts: the pages the dispatch's live
+        rows hold once its k tokens are written — what the attention kernel
+        walks at the burst's last step — and the pages a rectangular
+        (slots x window) grid would sweep."""
+        ps = self.kv_page_size
+        live = sum(-(-(int(self._seq_lens[i]) + k) // ps) for i in active)
+        return {"kv_pages_live": live,
+                "kv_pages_window": self.num_slots * -(-window // ps)}
+
     def _build_decode_many(self, k: int, window: int) -> Callable:
         """Jit a k-step decode: lax.scan feeds each step's sampled tokens
         back into the next ON DEVICE, so the host syncs once per k tokens
         instead of once per token. Sampling params are scan-invariant;
         the caches are donated (the scan carries them in place) and the
         block tables are scan-invariant too — _ensure_decode_pages
-        pre-allocates every page the burst will write."""
+        pre-allocates every page the burst will write. `live` is the
+        dispatch's _live_rows: the rows it emits for, so the attention
+        kernel walks their pages and no other row's."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
 
         def many(params, last, lens, cache_k, cache_v, tables,
-                 temps, top_ps, top_ks, seeds, key, lora_idx=None):
+                 temps, top_ps, top_ks, seeds, key, live, lora_idx=None):
             keys = jax.random.split(key, k)
 
             def body(carry, step_key):
                 last, lens, ck, cv = carry
                 logits, ck, cv = family.decode_step_paged(
                     params, cfg, last, lens, ck, cv, tables, mesh,
-                    window=window, lora_idx=lora_idx,
+                    window=window, lora_idx=lora_idx, live=live,
                 )
                 toks = sample_tokens(logits, step_key, temps, top_ps,
                                      top_ks, None, seeds, lens)
@@ -3833,7 +3867,7 @@ class EngineCore:
         family, cfg, mesh = self.family, self.cfg, self.mesh
 
         def many(params, last, lens, cache_k, cache_v, tables,
-                 temps, top_ps, top_ks, seeds, key, gram_table,
+                 temps, top_ps, top_ks, seeds, key, live, gram_table,
                  gram_state, lora_idx=None):
             keys = jax.random.split(key, k)
 
@@ -3841,7 +3875,7 @@ class EngineCore:
                 last, lens, gs, ck, cv = carry
                 logits, ck, cv = family.decode_step_paged(
                     params, cfg, last, lens, ck, cv, tables, mesh,
-                    window=window, lora_idx=lora_idx,
+                    window=window, lora_idx=lora_idx, live=live,
                 )
                 bias = grammar_bias(gram_table, gs)
                 toks = sample_tokens(logits, step_key, temps, top_ps,
@@ -3936,6 +3970,7 @@ class EngineCore:
         lora_idx = self._d_lora_idx if self.lora is not None else None
         if k > 1 or fused_step:
             window = self._window_for(active, k)
+            kv_pages = self._kv_pages(active, k, window)
             grammar = fused_step and constrained_active
             gram_args = {}
             if grammar:
@@ -3961,7 +3996,8 @@ class EngineCore:
                 self.params, self._d_last_tokens, self._d_seq_lens,
                 self.cache_k, self.cache_v, self._d_block_tables,
                 self._d_temps, self._d_top_ps, self._d_top_ks,
-                self._d_seeds, sk, lora_idx=lora_idx, **gram_args,
+                self._d_seeds, sk, self._live_rows(active),
+                lora_idx=lora_idx, **gram_args,
             )
             step.mark("compute")
             # split device execution from the D2H readback: the dispatch
@@ -3980,10 +4016,13 @@ class EngineCore:
                 "decode", step,
                 active_slots=len(active), tokens=k * len(active),
                 slots=active, dispatches=1, fused=fused_step,
+                kv_pages=kv_pages,
             )
             return True
 
         first_in = self._d_last_tokens  # pre-step tokens: pending firsts
+        window = self._window_for(active, 1)
+        kv_pages = self._kv_pages(active, 1, window)
         step.mark("dispatch")
         logits, self.cache_k, self.cache_v = self.family.decode_step_paged(
             self.params,
@@ -3994,8 +4033,9 @@ class EngineCore:
             self.cache_v,
             self._d_block_tables,
             self.mesh,
-            window=self._window_for(active, 1),
+            window=window,
             lora_idx=lora_idx,
+            live=self._live_rows(active),
         )
         mask = None
         if constrained_active:
@@ -4027,6 +4067,7 @@ class EngineCore:
             # legacy eager step: model forward, sample, lens advance are
             # separate dispatches, plus the mask scatter when constrained
             dispatches=3 + (1 if mask is not None else 0), fused=False,
+            kv_pages=kv_pages,
         )
         return True
 

@@ -294,7 +294,8 @@ class StepRecorder:
     def observe(self, kind: str, phases: dict[str, float], *,
                 active_slots: int = 0, tokens: int = 0,
                 request_ids: dict[str, str] | None = None,
-                dispatches: int = 0, span: StepSpan | None = None) -> bool:
+                dispatches: int = 0, span: StepSpan | None = None,
+                extra: dict[str, int] | None = None) -> bool:
         """Record one step; returns True when it was flagged anomalous.
         `phases` maps phase name -> seconds (missing phases count as 0);
         `tokens` is the number of tokens this step delivered to the host
@@ -307,7 +308,9 @@ class StepRecorder:
         closed StepSpan the phases came from (a flagged one is told where its
         time went: `span.slow_in`); without it (unit tests) the record's
         stamps are rebuilt from the durations, ending now, and the detector
-        judges their sum."""
+        judges their sum. `extra` is a kind's own counts, put on the record
+        as they are (a decode step's `kv_pages_live` and `kv_pages_window`:
+        the pages its live rows hold, and the pages of slots x window)."""
         total = sum(phases.values())
         if span is not None:
             t0, t1 = span.t0, span.t1
@@ -345,6 +348,7 @@ class StepRecorder:
             "spans": spans,
             "since_prev": gap,
             "builds": builds,
+            **(extra or {}),
         }
         with self._lock:
             seen = self._seen.get(kind, 0)

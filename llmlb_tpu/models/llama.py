@@ -620,7 +620,8 @@ def verify_step_paged(
 
 def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                        block_tables, *, stacked_names=None,
-                       mlp_fn=_default_mlp_fn, window=None, lora_idx=None):
+                       mlp_fn=_default_mlp_fn, window=None, lora_idx=None,
+                       live=None):
     """Shared one-token decode body for every model family.
 
     The layer loop is UNROLLED (static layer indices) rather than a
@@ -629,17 +630,31 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     the engine's k-step burst scan XLA materialized full-pool copies every
     layer. Decode programs are tiny, so L× code growth is cheap. Each
     layer's one-token KV lands at page block_tables[b, pos//PS], offset
-    pos%PS; freed/parked rows keep counting on device and clamp into their
-    own last cell or the trash page (their block-table rows are zeroed on
-    free), so garbage writes can never land in a page another row owns.
+    pos%PS.
+
+    `live` ([B] bool; None = every row) says which rows are decoding. The
+    device's `seq_lens` is no guide to that: a freed or never-used row keeps
+    counting (every step adds one) until it clamps at capacity, and a
+    prefilling row is parked at capacity - 1 on purpose. Such rows still
+    WRITE — into their own last cell or the trash page (their block-table
+    rows are zeroed on free), never a page another row owns — but attend
+    over nothing: their length goes to attention as 0, which the Pallas
+    kernel writes as zeros without reading a page. Their logits are finite
+    and the caller discards them.
 
     Attention gets the whole stacked pool [L, P, PS, K, D] and the layer
     index, never `pool[layer_idx]`: the Pallas kernel addresses the pool at
     (layer, page) and reads it in place, so the only pool-sized value in the
     program is the donated pool itself, updated by each layer's [B, K, D]
     scatter. No per-layer slice is materialized
-    (tests/test_decode_program_structure.py holds that)."""
-    from llmlb_tpu.ops.attention import paged_attention_decode
+    (tests/test_decode_program_structure.py holds that). The kernel's grid,
+    the work-list of live (row, page) pairs, is built here once a step for
+    all the layers; under the engine's burst scan it follows `seq_lens`
+    across page boundaries."""
+    from llmlb_tpu.ops.attention import (
+        paged_attention_decode,
+        paged_decode_work,
+    )
 
     b = input_ids.shape[0]
     ps = kv_pool_values(cache_k).shape[2]
@@ -650,6 +665,10 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     batch_idx = jnp.arange(b)
     page = block_tables[batch_idx, write_pos // ps]  # [B]
     off = write_pos % ps
+    kv_lens = write_pos + 1  # the row's cells once this step's is written
+    if live is not None:
+        kv_lens = jnp.where(live, kv_lens, 0)
+    work = paged_decode_work(cache_k, block_tables, kv_lens, window)
 
     x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
     names = _with_scales(params, stacked_names or _layer_stacked_names(cfg))
@@ -664,8 +683,8 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
             cache_v = _write_pool_layer(cache_v, layer_idx, page, off,
                                         v[:, 0])
             return paged_attention_decode(
-                q, cache_k, cache_v, layer_idx, block_tables,
-                write_pos + 1, window=window,
+                q, cache_k, cache_v, layer_idx, block_tables, kv_lens,
+                window=window, work=work,
             )
 
         x, _, _ = _attn_block(cfg, lp, x, positions, inv_freq, attn_fn,
@@ -690,12 +709,14 @@ def decode_step_paged(
     mesh: Mesh | None = None,  # unused; shared family signature
     window: int | None = None,  # static context-window bucket (≥ max seq+1)
     lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
+    live: jnp.ndarray | None = None,  # [B] bool — rows decoding; None = all
 ):
     """One decode step across all rows. Returns (logits [B, V] fp32,
-    caches)."""
+    caches). Rows that are not `live` attend over nothing and their logits
+    are to be discarded (_decode_paged_impl)."""
     return _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k,
                               cache_v, block_tables, window=window,
-                              lora_idx=lora_idx)
+                              lora_idx=lora_idx, live=live)
 
 
 @partial(jax.jit, static_argnames=("cfg",))

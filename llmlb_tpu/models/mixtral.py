@@ -257,7 +257,7 @@ def verify_step_paged(params, cfg: MixtralConfig, input_ids, chunk_lens,
 def decode_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
                       cache_k, cache_v, block_tables,
                       mesh: Mesh | None = None, window: int | None = None,
-                      lora_idx=None):
+                      lora_idx=None, live=None):
     """One decode step across all rows. Same contract as
     llama.decode_step_paged.
 
@@ -266,5 +266,5 @@ def decode_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
     return _decode_paged_impl(
         params, cfg, input_ids, seq_lens, cache_k, cache_v, block_tables,
         stacked_names=_STACKED, mlp_fn=_moe_mlp_fn(cfg, mesh, exact=True),
-        window=window, lora_idx=lora_idx,
+        window=window, lora_idx=lora_idx, live=live,
     )

@@ -166,21 +166,52 @@ def _pool_shape(pages):
     return (pages["q"] if isinstance(pages, dict) else pages).shape
 
 
+def _window_pages(block_tables, page_size: int, window: int | None) -> int:
+    """Whole pages covering `window` cells, within the table's width."""
+    ppn = block_tables.shape[1]
+    return ppn if window is None else max(1, min(ppn, -(-window // page_size)))
+
+
+def paged_decode_work(
+    k_pages,  # the pool paged_attention_decode will be handed
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    kv_lens: jnp.ndarray,  # [B] int32 — valid length per row; 0 = not live
+    window: int | None = None,
+):
+    """What a decode step builds ONCE and hands every layer's
+    paged_attention_decode as `work`: on the Pallas route the kernels' grid,
+    the work-list of live (row, page) pairs (pallas_attention.
+    decode_work_list); on the XLA route nothing."""
+    if not _pallas_enabled():
+        return None
+    from llmlb_tpu.ops.pallas_attention import decode_work_list
+
+    ps = _pool_shape(k_pages)[2]
+    return decode_work_list(block_tables, kv_lens, page_size=ps,
+                            pages=_window_pages(block_tables, ps, window))
+
+
 def paged_attention_decode(
     q: jnp.ndarray,  # [B, 1, H, D]
     k_pages,  # [L, P, PS, K, D] stacked pool, or quantized {"q","s"} pair
     v_pages,  # [L, P, PS, K, D]
     layer,  # int32 scalar — the layer of the pool to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
-    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length per row
+    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
     window: int | None = None,  # static: read only the first `window` cells
+    work=None,  # paged_decode_work of the same tables, lengths and window
 ) -> jnp.ndarray:
     """One-token decode attention against one layer of the KV page pool.
-    `window` (STATIC) bounds the logical sweep, rounded up to whole pages;
-    rows with kv_lens beyond the swept pages produce garbage the caller must
-    discard (parked/freed slot rows). The scheduler picks the smallest
-    bucket covering every active sequence, so attention HBM traffic scales
-    with the context actually in use instead of the full row capacity.
+    `window` (STATIC) bounds what a row attends over, rounded up to whole
+    pages: a row attends over its first min(kv_lens, window) cells. A row
+    with kv_lens 0 is not live (the engine's freed, never-used and
+    prefilling slot rows): the Pallas kernels write it as zeros and read no
+    page for it, the XLA fall-back gives it the mean of its window's cells —
+    finite either way, and the caller discards it. The Pallas kernels' cost
+    follows the live pages (their grid is `work`, built once a step by the
+    caller, or here when it is left out); the XLA fall-back gathers the
+    window, so there the scheduler's smallest bucket covering every active
+    sequence still bounds the traffic.
 
     The pool arrives STACKED over layers, with the layer index beside it:
     the Pallas kernels address it at (layer, page) and read it in place,
@@ -190,7 +221,7 @@ def paged_attention_decode(
     meshes) slices the layer here."""
     ps = _pool_shape(k_pages)[2]
     ppn = block_tables.shape[1]
-    pages = ppn if window is None else max(1, min(ppn, -(-window // ps)))
+    pages = _window_pages(block_tables, ps, window)
     if _pallas_enabled():
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_decode_quant
@@ -199,14 +230,14 @@ def paged_attention_decode(
             return paged_flash_decode_quant(
                 q[:, 0], k_pages["q"], k_pages["s"][layer], v_pages["q"],
                 v_pages["s"][layer], layer, block_tables, kv_lens,
-                pages=pages,
+                pages=pages, work=work,
             )[:, None]
         from llmlb_tpu.ops.pallas_attention import paged_flash_decode
 
         _traced["paged_decode"] = "pallas:paged_flash_decode"
         return paged_flash_decode(
             q[:, 0], k_pages, v_pages, layer, block_tables, kv_lens,
-            pages=pages,
+            pages=pages, work=work,
         )[:, None]
     _traced["paged_decode"] = "xla"
     tables = block_tables[:, :pages] if pages < ppn else block_tables

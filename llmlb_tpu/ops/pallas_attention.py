@@ -6,13 +6,16 @@ attention Pallas kernel"). The XLA einsum paths in ops/attention.py are the
 correctness baselines; these kernels replace them on TPU:
 
 - `paged_flash_decode` (+ `_quant`): one-token GQA attention against the KV
-  page pool. Grid is (batch, logical_page) with the page axis innermost, so
-  Pallas's grid pipeline double-buffers the next page's DMA behind the
-  current page's compute. Online softmax (m/l/acc) lives in VMEM scratch
-  across the page sweep. Raggedness: per-row `kv_lens` arrive via scalar
-  prefetch (SMEM) and pages past the valid length skip their FLOPs entirely
-  (`pl.when`) — decode cost scales with the *actual* context, not the row
-  capacity.
+  page pool. The grid is a WORK-LIST of the live (row, page) pairs
+  (`decode_work_list`), a row's pages consecutive, so Pallas's grid pipeline
+  double-buffers the next page's DMA behind the current page's compute;
+  its length is a run-time value. Online softmax (m/l/acc) lives in VMEM
+  scratch across a row's pages. The item arrays and the per-row `kv_lens`
+  arrive via scalar prefetch (SMEM). A row of length 0 is not live (a
+  serving batch's freed, never-used and prefilling slots): it takes one
+  grid step that writes zeros and reads no page. Decode cost scales with
+  the pages the live rows hold — not with the row capacity, the table's
+  width or the batch's window bucket.
 - `paged_flash_extend` (+ `_quant`): a chunk of queries against the pool
   (chunked prefill, speculative verify), same page-table addressing.
 - `flash_prefill`: causal self-attention over bucketed prompts. Grid is
@@ -40,6 +43,7 @@ follows the public ragged-paged-attention pattern (PAPERS.md).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -69,77 +73,62 @@ def _online_update(m_ref, l_ref, acc_ref, idx, scores, v):
 
 
 # ---------------------------------------------------------------------------
-# Decode body: q [1, K, G, D] vs one KV block [1, BLK, K, D], ragged kv_lens
-# ---------------------------------------------------------------------------
-
-
-def _decode_kernel(
-    # scalar prefetch
-    kv_lens_ref,  # [B] int32 (SMEM)
-    # inputs
-    q_ref,  # [1, K, G, D]
-    k_ref,  # [1, BLK, K, D]
-    v_ref,  # [1, BLK, K, D]
-    # output
-    o_ref,  # [1, K, G, D]
-    # scratch
-    m_ref,  # [K, G, 1] f32
-    l_ref,  # [K, G, 1] f32
-    acc_ref,  # [K, G, D] f32
-    *,
-    block_k: int,
-    num_kv: int,
-    scale: float,
-):
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-    num_blocks = pl.num_programs(1)
-    kv_len = kv_lens_ref[b]
-
-    @pl.when(s == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(s * block_k < kv_len)
-    def _compute():
-        col = s * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), dimension=1
-        )
-        valid = col < kv_len  # [1, BLK]
-        for h in range(num_kv):  # static unroll over KV heads
-            q = q_ref[0, h]  # [G, D]
-            k = k_ref[0, :, h, :]  # [BLK, D]
-            v = v_ref[0, :, h, :]
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [G, BLK]
-            scores = jnp.where(valid, scores, _NEG_INF)
-            _online_update(m_ref, l_ref, acc_ref, h, scores, v)
-
-    @pl.when(s == num_blocks - 1)
-    def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-
-
-# ---------------------------------------------------------------------------
 # Paged decode: q [B, H, D] vs the stacked page pool [L, P, PS, K, D] at one
-# layer, block tables [B, PPN]
+# layer. The grid is a work-list of the live (row, page) pairs.
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(layer_ref, block_tables_ref, kv_lens_ref, *refs,
-                         **kw):
-    """The online-softmax sweep of _decode_kernel; the layer and block-table
-    refs are consumed by the BlockSpec index_map (they pick which POOL page
-    of which layer each grid step DMAs), so the body only needs the ragged
-    lengths."""
-    del layer_ref, block_tables_ref
-    _decode_kernel(kv_lens_ref, *refs, **kw)
+class DecodeWork(NamedTuple):
+    """The paged decode kernels' grid: one item per page a row attends over,
+    rows in order and a row's pages in order. Built by `decode_work_list`
+    once a decode step and shared by every layer's call."""
+
+    count: jnp.ndarray  # [] int32 — items in use: the grid's run-time length
+    row_of: jnp.ndarray  # [W] int32 — the row item i belongs to
+    page_of: jnp.ndarray  # [W] int32 — its logical page within that row
+    pool_page_of: jnp.ndarray  # [W] int32 — the pool page its step fetches
+
+
+def _swept_pages(block_tables, pages: int | None) -> int:
+    """Pages of a row the decode kernels may visit: `pages`, within the
+    table's width."""
+    ppn = block_tables.shape[1]
+    return ppn if pages is None else max(1, min(pages, ppn))
+
+
+def decode_work_list(
+    block_tables: jnp.ndarray,  # [B, PPN] int32 — logical page i of row b
+    kv_lens: jnp.ndarray,  # [B] int32 — valid length per row; 0 = not live
+    *,
+    page_size: int,
+    pages: int | None = None,  # static: at most the first `pages` pages a row
+) -> DecodeWork:
+    """The work-list of one decode step. A row of length n contributes its
+    ceil(n / page_size) pages — at most `pages`: what lies beyond is not
+    attended over, as the XLA route's sliced table has it. A row of length 0
+    (not live: freed, never used, prefilling) contributes ONE item, on which
+    the kernel writes that row's output as zeros and computes nothing; the
+    item names the pool page of the item before it, and a block whose index
+    repeats is not fetched again, so such a row costs one grid step and no
+    read of the pool. W = B x pages is static, `count` is a run-time value."""
+    b = block_tables.shape[0]
+    sweep = _swept_pages(block_tables, pages)
+    lens = kv_lens.astype(jnp.int32)
+    per_row = jnp.clip(-(-lens // page_size), 1, sweep)  # [B] items of a row
+    end = jnp.cumsum(per_row)
+    item = jnp.arange(b * sweep, dtype=jnp.int32)
+    # [W, B]: the rows that end at or before item i are the rows before its
+    # own, and their items are the items before its row's first
+    ended = item[:, None] >= end[None, :]
+    # items past `count` are never visited; they only have to index in range
+    row_of = jnp.minimum(jnp.sum(ended, axis=1, dtype=jnp.int32), b - 1)
+    page_of = jnp.clip(
+        item - jnp.sum(jnp.where(ended, per_row[None, :], 0), axis=1),
+        0, sweep - 1)
+    # the nearest item at or before i that reads a page (item 0 if none does)
+    reads = jax.lax.cummax(jnp.where(lens[row_of] > 0, item, 0))
+    pool_page_of = block_tables.astype(jnp.int32)[row_of, page_of][reads]
+    return DecodeWork(end[-1], row_of, page_of, pool_page_of)
 
 
 def _layer_operand(layer) -> jnp.ndarray:
@@ -149,121 +138,36 @@ def _layer_operand(layer) -> jnp.ndarray:
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
-def _pool_page_map(bi, si, layer, tables, lens):
-    """KV values [L, P, PS, K, D]: page tables[b, i] of the layer."""
-    return (layer[0], tables[bi, si], 0, 0, 0)
+# Index maps of the decode grid: item i, then the scalar-prefetch operands.
 
 
-def _layer_scale_map(bi, si, layer, tables, lens):
+def _pool_page_map(i, layer, row_of, page_of, pool_page_of, lens):
+    """KV values [L, P, PS, K, D]: the item's pool page, of the layer."""
+    return (layer[0], pool_page_of[i], 0, 0, 0)
+
+
+def _layer_scale_map(i, layer, row_of, page_of, pool_page_of, lens):
     """KV scales [P, PS, K] of one layer of an int8 pool: the same page."""
-    return (tables[bi, si], 0, 0)
+    return (pool_page_of[i], 0, 0)
 
 
-def _row_map(bi, si, layer, tables, lens):
-    """q and out [B, K, G, D]: row b, for every page of the sweep."""
-    return (bi, 0, 0, 0)
+def _row_map(i, layer, row_of, page_of, pool_page_of, lens):
+    """q and out [B, K, G, D]: the item's row, for every page of the row."""
+    return (row_of[i], 0, 0, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
-def paged_flash_decode(
-    q: jnp.ndarray,  # [B, H, D]
-    k_pages: jnp.ndarray,  # [L, P, PS, K, D] — global page pool, all layers
-    v_pages: jnp.ndarray,  # [L, P, PS, K, D]
-    layer,  # int32 scalar — the layer of the pool to attend over
-    block_tables: jnp.ndarray,  # [B, PPN] int32 — logical page i of row b
-    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length per row
-    *,
-    pages: int | None = None,  # static: sweep only the first `pages` pages
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Ragged PAGED one-token GQA decode attention. Returns [B, H, D].
-
-    The grid is (batch, logical_page) and the KV BlockSpec index_map gathers
-    each step's page THROUGH the prefetched layer index and block table
-    (`(layer, block_tables[b, i])` picks the pool page to DMA) — attention
-    reads the scattered STACKED pool in place. Neither a contiguous per-row
-    copy nor a per-layer slice `pool[layer]` is ever materialized: a
-    pallas_call takes whole buffers as operands, so handing it a slice makes
-    XLA copy one layer of the pool (105 MB at 400 pages of Mistral-7B width)
-    per call. `layer` is an operand, not a Python constant, so the layers of
-    an unrolled decode program share one kernel. `pages` bounds the sweep
-    (grid), NOT the input shapes — the kernel simply never DMAs pages past
-    it, so short contexts in a large-capacity table cost only the traffic
-    they need. Contract: rows with kv_lens inside the swept pages are exact;
-    rows whose kv_lens extend beyond produce GARBAGE (their mask believes
-    unswept cells are valid) and the caller must discard them — the engine
-    does this for parked/freed slot rows, whose device counters sit at
-    capacity while the scheduler picks the window from active rows only.
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    b, h, d = q.shape
-    _, _, ps, num_kv, _ = k_pages.shape
-    g = h // num_kv
-    ppn = block_tables.shape[1]
-    sweep = ppn if pages is None else max(1, min(pages, ppn))
-    qg = q.reshape(b, num_kv, g, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, sweep),
-        in_specs=[
-            pl.BlockSpec((1, num_kv, g, d), _row_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, num_kv, g, d), _row_map,
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel, block_k=ps, num_kv=num_kv, scale=d**-0.5
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(_layer_operand(layer), block_tables.astype(jnp.int32),
-      kv_lens.astype(jnp.int32), qg, k_pages, v_pages)
-    return out.reshape(b, h, d)
-
-
-# ---------------------------------------------------------------------------
-# Quantized paged decode: int8 page pool + per-vector f32 scales. The scale
-# arrays [P, PS, K] ride the SAME block-table prefetch as the values (their
-# BlockSpec index_map picks the identical pool page per grid step), and each
-# KV vector dequantizes in VMEM right before its dot — HBM moved int8 bytes.
-# ---------------------------------------------------------------------------
-
-
-def _paged_decode_quant_kernel(
-    layer_ref,  # consumed by the index maps
-    block_tables_ref,  # consumed by the index maps
-    kv_lens_ref,  # [B] int32 (SMEM)
-    q_ref,  # [1, K, G, D]
-    k_ref,  # [1, PS, K, D] int8
-    ks_ref,  # [1, PS, K] f32
-    v_ref,  # [1, PS, K, D] int8
-    vs_ref,  # [1, PS, K] f32
-    o_ref,  # [1, K, G, D]
-    m_ref, l_ref, acc_ref,
-    *,
-    block_k: int,
-    num_kv: int,
-    scale: float,
-):
-    del layer_ref, block_tables_ref
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-    num_blocks = pl.num_programs(1)
-    kv_len = kv_lens_ref[b]
+def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
+                 m_ref, l_ref, acc_ref, kv_head, *,
+                 block_k: int, sweep: int, num_kv: int, scale: float):
+    """One grid step of a paged decode kernel: item i of the work-list is
+    page `s` of row `row`. Online softmax (m/l/acc) lives in VMEM scratch
+    from a row's first item to its last; `kv_head(h)` loads head h's
+    [BLK, D] keys and values of the step's block. A row of length 0 has one
+    item, computes nothing and is written as zeros (l == 0)."""
+    i = pl.program_id(0)
+    s = page_of_ref[i]
+    kv_len = kv_lens_ref[row_of_ref[i]]
+    last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
 
     @pl.when(s == 0)
     def _init():
@@ -279,10 +183,7 @@ def _paged_decode_quant_kernel(
         valid = col < kv_len  # [1, BLK]
         for h in range(num_kv):  # static unroll over KV heads
             q = q_ref[0, h]  # [G, D]
-            k = (k_ref[0, :, h, :].astype(jnp.float32)
-                 * ks_ref[0, :, h][:, None]).astype(q.dtype)  # [BLK, D]
-            v = (v_ref[0, :, h, :].astype(jnp.float32)
-                 * vs_ref[0, :, h][:, None]).astype(q.dtype)
+            k, v = kv_head(h)  # [BLK, D] each
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -290,11 +191,148 @@ def _paged_decode_quant_kernel(
             scores = jnp.where(valid, scores, _NEG_INF)
             _online_update(m_ref, l_ref, acc_ref, h, scores, v)
 
-    @pl.when(s == num_blocks - 1)
+    @pl.when(s == last)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+
+
+def _paged_decode_kernel(
+    # scalar prefetch (SMEM); the layer and the pool pages are consumed by
+    # the BlockSpec index maps, which pick what each grid step DMAs
+    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
+    # inputs
+    q_ref,  # [1, K, G, D]
+    k_ref,  # [1, PS, K, D]
+    v_ref,  # [1, PS, K, D]
+    # output
+    o_ref,  # [1, K, G, D]
+    # scratch
+    m_ref,  # [K, G, 1] f32
+    l_ref,  # [K, G, 1] f32
+    acc_ref,  # [K, G, D] f32
+    **kw,
+):
+    del layer_ref, pool_page_of_ref
+
+    def kv_head(h):
+        return k_ref[0, :, h, :], v_ref[0, :, h, :]
+
+    _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
+                 m_ref, l_ref, acc_ref, kv_head, **kw)
+
+
+def _paged_decode_quant_kernel(
+    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
+    q_ref,  # [1, K, G, D]
+    k_ref,  # [1, PS, K, D] int8
+    ks_ref,  # [1, PS, K] f32
+    v_ref,  # [1, PS, K, D] int8
+    vs_ref,  # [1, PS, K] f32
+    o_ref,  # [1, K, G, D]
+    m_ref, l_ref, acc_ref,
+    **kw,
+):
+    """Int8 page pool + per-vector f32 scales: the scale arrays [P, PS, K]
+    ride the same work-list as the values (their index map picks the
+    identical pool page per grid step), and each KV vector dequantizes in
+    VMEM right before its dot — HBM moved int8 bytes."""
+    del layer_ref, pool_page_of_ref
+    dtype = q_ref.dtype
+
+    def kv_head(h):
+        k = (k_ref[0, :, h, :].astype(jnp.float32)
+             * ks_ref[0, :, h][:, None]).astype(dtype)  # [BLK, D]
+        v = (v_ref[0, :, h, :].astype(jnp.float32)
+             * vs_ref[0, :, h][:, None]).astype(dtype)
+        return k, v
+
+    _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
+                 m_ref, l_ref, acc_ref, kv_head, **kw)
+
+
+def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
+                       kv_lens, work, *, page_size, pages, interpret):
+    """The pallas_call both paged decode kernels share: `grid=(work.count,)`
+    — a run-time length — over the work-list's items; q and out blocks
+    follow the item's row, the KV blocks (`kv_specs`, one per operand of
+    `kv_operands`) its pool page."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, h, d = q.shape
+    num_kv = kv_operands[0].shape[3]
+    g = h // num_kv
+    if work is None:
+        work = decode_work_list(block_tables, kv_lens, page_size=page_size,
+                                pages=pages)
+    row_spec = pl.BlockSpec((1, num_kv, g, d), _row_map,
+                            memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(work.count,),
+        in_specs=[row_spec, *kv_specs],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((num_kv, g, 1), jnp.float32),
+            pltpu.VMEM((num_kv, g, 1), jnp.float32),
+            pltpu.VMEM((num_kv, g, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, block_k=page_size,
+                          sweep=_swept_pages(block_tables, pages),
+                          num_kv=num_kv, scale=d**-0.5),
+        out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
+      kv_lens.astype(jnp.int32), q.reshape(b, num_kv, g, d), *kv_operands)
+    return out.reshape(b, h, d)
+
+
+@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
+def paged_flash_decode(
+    q: jnp.ndarray,  # [B, H, D]
+    k_pages: jnp.ndarray,  # [L, P, PS, K, D] — global page pool, all layers
+    v_pages: jnp.ndarray,  # [L, P, PS, K, D]
+    layer,  # int32 scalar — the layer of the pool to attend over
+    block_tables: jnp.ndarray,  # [B, PPN] int32 — logical page i of row b
+    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
+    *,
+    pages: int | None = None,  # static: a row's first `pages` pages at most
+    work: DecodeWork | None = None,  # decode_work_list of the same operands
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Ragged PAGED one-token GQA decode attention. Returns [B, H, D].
+
+    The grid is the work-list of live (row, page) pairs (`decode_work_list`;
+    a decode program builds it once a step and hands it to every layer's
+    call as `work`, a direct caller may leave it out): its length is a
+    run-time value, so a call costs the pages the live rows hold and one
+    step per row that is not live, whatever the table's width and `pages`.
+    The KV BlockSpec index map picks each step's page through the
+    prefetched layer index and the item's pool page — attention reads the
+    scattered STACKED pool in place. Neither a contiguous per-row copy nor a
+    per-layer slice `pool[layer]` is ever materialized: a pallas_call takes
+    whole buffers as operands, so handing it a slice makes XLA copy one
+    layer of the pool (105 MB at 400 pages of Mistral-7B width) per call.
+    `layer` is an operand, not a Python constant, so the layers of an
+    unrolled decode program share one kernel.
+
+    Contract: a row attends over its first min(kv_lens, pages x PS) cells,
+    exactly; a row with kv_lens 0 is NOT LIVE — its output is zeros and no
+    page of the pool is read for it, whatever its table row holds (the
+    engine's freed, never-used and prefilling slot rows). `pages` bounds a
+    row's items and the static size of the work-list, not the input shapes.
+    """
+    ps = k_pages.shape[2]
+    kv_spec = pl.BlockSpec((None, 1, ps, k_pages.shape[3], k_pages.shape[4]),
+                           _pool_page_map, memory_space=pltpu.VMEM)
+    return _paged_decode_call(
+        _paged_decode_kernel, [kv_spec, kv_spec], (k_pages, v_pages), q,
+        layer, block_tables, kv_lens, work, page_size=ps, pages=pages,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
@@ -306,13 +344,14 @@ def paged_flash_decode_quant(
     v_scales: jnp.ndarray,  # [P, PS, K] f32
     layer,  # int32 scalar — the layer of the value pools to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
-    kv_lens: jnp.ndarray,  # [B] int32
+    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
     *,
     pages: int | None = None,
+    work: DecodeWork | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Int8 variant of paged_flash_decode: dequant-on-read inside the
-    kernel. Same grid/garbage contract; numerics match the XLA dequant
+    kernel. Same work-list grid and contract; numerics match the XLA dequant
     fallback (f32 dequant -> q.dtype operands -> f32 accumulation).
 
     The VALUES follow paged_flash_decode's stacked-pool contract: read in
@@ -324,49 +363,16 @@ def paged_flash_decode_quant(
     26 MB at 400 pages of 128; on the stacked array it writes all L layers
     every call (compiled for a v5e: 32 whole-array copies a decode step,
     PERF.md §6, PR 25)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    b, h, d = q.shape
-    _, _, ps, num_kv, _ = k_pages.shape
-    g = h // num_kv
-    ppn = block_tables.shape[1]
-    sweep = ppn if pages is None else max(1, min(pages, ppn))
-    qg = q.reshape(b, num_kv, g, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, sweep),
-        in_specs=[
-            pl.BlockSpec((1, num_kv, g, d), _row_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv), _layer_scale_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv), _layer_scale_map,
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, num_kv, g, d), _row_map,
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_quant_kernel, block_k=ps, num_kv=num_kv,
-            scale=d**-0.5,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(_layer_operand(layer), block_tables.astype(jnp.int32),
-      kv_lens.astype(jnp.int32), qg, k_pages, k_scales, v_pages, v_scales)
-    return out.reshape(b, h, d)
+    _, _, ps, num_kv, d = k_pages.shape
+    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
+                           memory_space=pltpu.VMEM)
+    scale_spec = pl.BlockSpec((1, ps, num_kv), _layer_scale_map,
+                              memory_space=pltpu.VMEM)
+    return _paged_decode_call(
+        _paged_decode_quant_kernel,
+        [kv_spec, scale_spec, kv_spec, scale_spec],
+        (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
+        kv_lens, work, page_size=ps, pages=pages, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

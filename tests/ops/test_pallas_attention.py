@@ -19,11 +19,18 @@ from llmlb_tpu.ops.attention import (
     gqa_attention_prefill,
 )
 from llmlb_tpu.ops.pallas_attention import (
+    decode_work_list,
     flash_prefill,
     paged_flash_decode,
     paged_flash_extend,
 )
-from tests.ops.pools import stacked_pool as _stacked
+from tests.ops.pools import (
+    DECODE_CASES,
+    DECODE_PPN,
+    DECODE_PS,
+    live_pages_case,
+    stacked_pool as _stacked,
+)
 
 
 @pytest.fixture(autouse=True)
@@ -186,11 +193,9 @@ def test_paged_flash_decode_extreme_lens(kv_len):
 
 @pytest.mark.parametrize("layer", [0, 2])
 def test_paged_flash_decode_pages_below_longest_row(layer):
-    """A `pages` bound BELOW the longest row's page count (the engine's
-    parked/freed rows, whose device counters sit at capacity while the
-    window follows the active rows): rows inside the sweep stay exact, the
-    row beyond it is garbage by contract — but finite garbage, computed
-    from its own swept pages only."""
+    """A `pages` bound BELOW the longest row's page count: rows inside the
+    sweep stay exact, and the row beyond it attends over its swept pages
+    only, as the XLA route's sliced table has it."""
     b, h, kv, d, ps, ppn = 3, 4, 2, 16, 16, 4
     keys = jax.random.split(jax.random.PRNGKey(14), 2)
     q = _rand(keys[0], (b, 1, h, d))
@@ -208,6 +213,109 @@ def test_paged_flash_decode_pages_below_longest_row(layer):
         q, k_cache, v_cache, jnp.minimum(kv_lens, 2 * ps))[:, 0]
     np.testing.assert_allclose(got, swept, rtol=2e-5, atol=2e-5)
     assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_paged_flash_decode_reads_live_pages_only(case):
+    """The kernel's contract (DECODE_CASES): live rows equal the einsum over
+    their own cells, rows that are not live are exactly zero, and the trash
+    page and every page no live row attends over hold NaN — so a step that
+    read what it should not would show in a live row."""
+    kv_lens, pages = DECODE_CASES[case]
+    h, kv, d, ps, layer = 4, 2, 16, DECODE_PS, 1
+    rng = np.random.default_rng(15)
+    tables, readable = live_pages_case(rng, kv_lens, pages)
+    shape = (len(readable), ps, kv, d)
+    k_pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(len(kv_lens), 1, h, d)), jnp.float32)
+    lens = jnp.asarray(kv_lens, jnp.int32)
+
+    def poisoned(pool):
+        return _stacked(
+            jnp.where(readable[:, None, None, None], pool, jnp.nan), layer)
+
+    got = np.asarray(paged_flash_decode(
+        q[:, 0], poisoned(k_pages), poisoned(v_pages), layer, tables, lens,
+        pages=pages, interpret=True))
+    sweep = DECODE_PPN if pages is None else pages
+    expected = np.asarray(gqa_attention_decode(
+        q, gather_kv_pages(k_pages, tables[:, :sweep]),
+        gather_kv_pages(v_pages, tables[:, :sweep]),
+        jnp.minimum(lens, sweep * ps)))[:, 0]
+    live = np.asarray(kv_lens) > 0
+    np.testing.assert_allclose(got[live], expected[live], rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
+def _expected_work_list(tables, kv_lens, ps, sweep):
+    """(row, page, pool page) per item, by the rule in words: a live row's
+    pages in order, one item for a row that is not live, rows in order; an
+    item that reads nothing names the pool page of the item before it."""
+    items = []
+    for row, n in enumerate(kv_lens):
+        for page in range(min(-(-n // ps), sweep)):
+            items.append((row, page, int(tables[row, page])))
+        if n == 0:
+            items.append((row, 0, items[-1][2] if items
+                          else int(tables[row, 0])))
+    return items
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_decode_work_list_holds_every_live_page_once_and_in_order(seed):
+    rng = np.random.default_rng(seed)
+    b, ps, ppn = int(rng.integers(1, 9)), 8, int(rng.integers(1, 6))
+    pages = [None, max(1, ppn - 1)][seed % 2]
+    sweep = ppn if pages is None else pages
+    live = rng.random(b) < 0.6
+    kv_lens = np.where(live, rng.integers(1, ps * ppn + 1, b), 0)
+    tables = rng.integers(0, 50, (b, ppn)).astype(np.int32)
+
+    work = decode_work_list(jnp.asarray(tables), jnp.asarray(kv_lens),
+                            page_size=ps, pages=pages)
+    count = int(work.count)
+    held = np.minimum(-(-kv_lens[live] // ps), sweep)
+    assert count == held.sum() + (~live).sum()
+    assert work.row_of.shape == (b * sweep,)  # static, whatever is live
+    got = list(zip(*(np.asarray(a)[:count].tolist()
+                     for a in (work.row_of, work.page_of,
+                               work.pool_page_of))))
+    assert got == _expected_work_list(tables, kv_lens.tolist(), ps, sweep)
+    # beyond the count nothing is visited, but every index stays in range
+    assert np.asarray(work.row_of).max() < b
+    assert np.asarray(work.page_of).max() < sweep
+
+
+def test_decode_work_list_follows_lens_inside_a_scan():
+    """Under the engine's burst scan the list is rebuilt from the lengths a
+    step has: a row crossing a page boundary gains an item at that step, a
+    row that is not live never does."""
+    ps, ppn = 8, 3
+    tables = jnp.asarray(np.arange(1, 13, dtype=np.int32).reshape(4, ppn))
+    live = jnp.asarray([True, False, True, True])
+    lens0 = jnp.asarray([6, 17, 8, 15], jnp.int32)  # cells before step 0
+
+    def body(lens, _):
+        kv_lens = jnp.where(live, lens + 1, 0)
+        work = decode_work_list(tables, kv_lens, page_size=ps)
+        return lens + 1, work
+
+    _, works = jax.jit(lambda lens: jax.lax.scan(body, lens, None, length=3)
+                       )(lens0)
+    for step in range(3):
+        kv_lens = np.where(np.asarray(live), np.asarray(lens0) + step + 1, 0)
+        count = int(works.count[step])
+        got = list(zip(*(np.asarray(a)[step, :count].tolist()
+                         for a in (works.row_of, works.page_of,
+                                   works.pool_page_of))))
+        assert got == _expected_work_list(np.asarray(tables),
+                                          kv_lens.tolist(), ps, ppn)
+    # 7, 8, 9 cells: one page, one page, two; 9, 10, 11: two; 16, 17, 18:
+    # two, three, three; and one item for the row that is not live
+    assert np.asarray(works.count).tolist() == [1 + 1 + 2 + 2, 1 + 1 + 2 + 3,
+                                                2 + 1 + 2 + 3]
 
 
 @pytest.mark.parametrize(

@@ -18,7 +18,12 @@ from llmlb_tpu.ops.pallas_attention import (
     paged_flash_extend_quant,
 )
 from llmlb_tpu.quant import quantize_kv
-from tests.ops.pools import stacked_pool as _stacked
+from tests.ops.pools import (
+    DECODE_CASES,
+    DECODE_PS,
+    live_pages_case,
+    stacked_pool as _stacked,
+)
 
 B, H, K, D, P, PS, PPN = 2, 8, 4, 16, 9, 8, 4
 TOL = 0.05
@@ -119,6 +124,44 @@ def test_paged_flash_decode_quant_respects_pages_window(layer):
     assert np.abs(np.asarray(full)).max() < 10  # a mix of this layer's V
     np.testing.assert_allclose(np.asarray(full), np.asarray(windowed),
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_paged_flash_decode_quant_reads_live_pages_only(case, monkeypatch):
+    """The int8 kernel under the bf16 kernel's contract (DECODE_CASES): live
+    rows equal the XLA dequant route on the sound pool, rows that are not
+    live are exactly zero, and the scales of the trash page and of every
+    page no live row attends over are NaN — a vector dequantized from one
+    of them would show in a live row."""
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "xla")  # the reference's route
+    kv_lens, pages = DECODE_CASES[case]
+    layer = 1
+    rng = np.random.default_rng(7)
+    tables, readable = live_pages_case(rng, kv_lens, pages)
+    shape = (len(readable), DECODE_PS, K, D)
+    kq, ks = quantize_kv(rng.normal(size=shape).astype(np.float32))
+    vq, vs = quantize_kv(rng.normal(size=shape).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(len(kv_lens), H, D)), jnp.float32)
+    lens = jnp.asarray(kv_lens, jnp.int32)
+
+    def poisoned(scales):
+        return jnp.where(readable[:, None, None], scales, jnp.nan)
+
+    got = np.asarray(paged_flash_decode_quant(
+        q, _stacked(jnp.asarray(kq), layer), poisoned(ks),
+        _stacked(jnp.asarray(vq), layer), poisoned(vs), layer, tables, lens,
+        pages=pages, interpret=True))
+    window = None if pages is None else pages * DECODE_PS
+    sound = [_stacked({"q": jnp.asarray(vals), "s": jnp.asarray(scales)},
+                      layer) for vals, scales in ((kq, ks), (vq, vs))]
+    expected = np.asarray(paged_attention_decode(
+        q[:, None], *sound, layer, tables,
+        lens if window is None else jnp.minimum(lens, window),
+        window=window), np.float32)[:, 0]
+    live = np.asarray(kv_lens) > 0
+    assert live.all() or not got[~live].any()
+    if live.any():
+        assert np.abs(got[live] - expected[live]).max() < 2e-3
 
 
 def test_paged_flash_extend_quant_interpret_parity():
