@@ -887,6 +887,61 @@ def kernel_leg() -> int:
 
     # LoRA bgmv: decode rows, a prefill chunk, the verify width; through
     # TinyLlama's projections (hidden 2048, kv 256, mlp 5632) at rank 16
+    # the latent-attention mixture's two kernels at kanana-2-30b-a3b's
+    # widths (models/deepseek_v3.py): absorbed decode over a latent pool
+    # (32 heads on one 512-wide latent and a 128-lane rope row), and the
+    # grouped expert matmul over stacked experts read at (layer, expert)
+    from llmlb_tpu.ops import pallas_moe
+
+    LAT, ROPE = 512, 128
+    for b in (8, 64):
+        def latent_decode(pages, dead=False):
+            p = b * PPN + 1
+            c_pages = jnp.stack([jnp.zeros((p, PS, LAT), bf16),
+                                 rand(p, PS, LAT)])
+            r_pages = jnp.stack([jnp.zeros((p, PS, ROPE), bf16), jnp.pad(
+                rand(p, PS, 64), ((0, 0), (0, 0), (0, ROPE - 64)))])
+            tables = jnp.asarray(
+                rng.permutation(np.arange(1, p)).reshape(b, PPN), jnp.int32)
+            q_abs, q_rope = rand(b, 1, H, LAT), rand(b, 1, H, 64)
+            lens = rng.integers(1, pages * PS + 1, b)
+            if dead:
+                lens[::3] = 0
+            lens = jnp.asarray(lens, jnp.int32)
+            kw = dict(scale=192 ** -0.5)
+            want = xla.paged_latent_decode(q_abs, q_rope, c_pages, r_pages, 1,
+                                           tables, lens, window=pages * PS,
+                                           **kw)[:, 0]
+            got = pa.paged_latent_decode(
+                q_abs[:, 0], xla._pad_last(q_rope[:, 0], ROPE), c_pages,
+                r_pages, 1, tables, lens, pages=pages, interpret=False, **kw)
+            live = np.asarray(lens) > 0
+            check("paged_latent_decode", f"B={b},pages={pages},dead={dead}",
+                  got[live][None], want[live][None])
+
+        for pages, dead in ((PPN, False), (4, False), (PPN, True)):
+            attempt("paged_latent_decode", f"B={b},pages={pages},dead={dead}",
+                    functools.partial(latent_decode, pages, dead))
+
+    for rows_, k_, o_, tile in ((384, 2048, 768, 32), (384, 768, 2048, 32),
+                                (6144, 2048, 768, 32)):
+        def gmm():
+            experts, layers = 16, 2
+            load = rng.multinomial(rows_ - 40, rng.dirichlet(np.ones(experts)))
+            load = jnp.asarray(load, jnp.int32)
+            a = rand(rows_, k_)
+            w = (rand(layers, experts, k_, o_) * k_ ** -0.5).astype(bf16)
+            work = pallas_moe.group_work_list(load, rows=rows_, tile=tile)
+            got = pallas_moe.grouped_expert_matmul(a, w, 1, work, tile=tile,
+                                                   interpret=False)
+            want = jax.lax.ragged_dot(a, w[1], load,
+                                      preferred_element_type=jnp.float32)
+            valid = int(load.sum())  # rows behind the experts': unspecified
+            check("grouped_expert_matmul", f"{rows_}x{k_}->{o_}",
+                  got[None, :valid], want[None, :valid])
+
+        attempt("grouped_expert_matmul", f"{rows_}x{k_}->{o_}", gmm)
+
     n_adapters, rank = 9, 16
     for b, t in ((8, 1), (32, 1), (2, 512), (8, 5)):
         for n_in, n_out in ((2048, 2048), (2048, 256), (2048, 5632),

@@ -164,6 +164,15 @@ class EngineMetrics:
         # pages of slots x window: live / window is the share of a
         # window-wide sweep that was live context
         self.decode_kv_pages_live_total = 0
+        # A mixture's expert load, from the family's step counters
+        # (scheduler._record_step): running totals, the fullest expert any
+        # step saw, and per expert layer how many experts took how many
+        # assignments in a step (models/deepseek_v3.LOAD_BUCKETS).
+        self.moe_counted_steps_total = 0
+        self.moe_experts_touched_total = 0
+        self.moe_expert_assignments_total = 0
+        self.moe_expert_load_max = 0
+        self.moe_expert_load_hist: list[list[int]] = []
         self.decode_kv_pages_window_total = 0
         self.constrained_burst_fallback_total = 0
         # Overload protection (docs/scheduling.md): slots parked under
@@ -350,6 +359,26 @@ class EngineMetrics:
             self.decode_kv_pages_live_total += kv_pages_live
             self.decode_kv_pages_window_total += kv_pages_window
 
+    def record_step_counters(self, counters: dict, max_names: tuple) -> None:
+        """One dispatch's step counters (a burst's are already reduced over
+        its steps): totals add, `max_names` keep the largest seen."""
+        with self._lock:
+            self.moe_counted_steps_total += 1
+            self.moe_experts_touched_total += counters.get(
+                "experts_touched", 0)
+            self.moe_expert_assignments_total += counters.get(
+                "expert_assignments", 0)
+            self.moe_expert_load_max = max(
+                self.moe_expert_load_max, counters.get("expert_load_max", 0))
+            hist = counters.get("expert_load_hist")
+            if hist:
+                if not self.moe_expert_load_hist:
+                    self.moe_expert_load_hist = [[0] * len(row)
+                                                 for row in hist]
+                for total, row in zip(self.moe_expert_load_hist, hist):
+                    for i, n in enumerate(row):
+                        total[i] += n
+
     def record_constrained_burst_fallback(self) -> None:
         """A constrained slot forced the decode loop off the fused/burst
         path into single-step legacy decode this step."""
@@ -517,6 +546,13 @@ class EngineMetrics:
                     self.decode_kv_pages_window_total,
                 "constrained_burst_fallback_total":
                     self.constrained_burst_fallback_total,
+                "moe_counted_steps_total": self.moe_counted_steps_total,
+                "moe_experts_touched_total": self.moe_experts_touched_total,
+                "moe_expert_assignments_total":
+                    self.moe_expert_assignments_total,
+                "moe_expert_load_max": self.moe_expert_load_max,
+                "moe_expert_load_hist": [list(row) for row in
+                                         self.moe_expert_load_hist],
                 "preemptions_total": self.preemptions_total,
                 "preempt_resumes_total": self.preempt_resumes_total,
                 "deadline_shed_total": self.deadline_shed_total,
@@ -634,6 +670,23 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_decode_kv_pages_window_total counter",
                 "llmlb_engine_decode_kv_pages_window_total "
                 f"{self.decode_kv_pages_window_total}",
+                "# TYPE llmlb_engine_moe_counted_steps_total counter",
+                "llmlb_engine_moe_counted_steps_total "
+                f"{self.moe_counted_steps_total}",
+                "# TYPE llmlb_engine_moe_experts_touched_total counter",
+                "llmlb_engine_moe_experts_touched_total "
+                f"{self.moe_experts_touched_total}",
+                "# TYPE llmlb_engine_moe_expert_assignments_total counter",
+                "llmlb_engine_moe_expert_assignments_total "
+                f"{self.moe_expert_assignments_total}",
+                "# TYPE llmlb_engine_moe_expert_load_max gauge",
+                "llmlb_engine_moe_expert_load_max "
+                f"{self.moe_expert_load_max}",
+                "# TYPE llmlb_engine_moe_expert_load_experts_total counter",
+                *(f'llmlb_engine_moe_expert_load_experts_total{{layer="{l}",'
+                  f'bucket="{b}"}} {n}'
+                  for l, row in enumerate(self.moe_expert_load_hist)
+                  for b, n in enumerate(row)),
                 "# TYPE llmlb_engine_constrained_burst_fallback_total "
                 "counter",
                 "llmlb_engine_constrained_burst_fallback_total "
@@ -848,6 +901,9 @@ class EngineMetrics:
                     "# TYPE llmlb_engine_kv_bytes_per_page gauge",
                     "llmlb_engine_kv_bytes_per_page "
                     f"{kv_cache.get('bytes_per_page', 0)}",
+                    "# TYPE llmlb_engine_kv_bytes_per_token gauge",
+                    "llmlb_engine_kv_bytes_per_token "
+                    f"{kv_cache.get('bytes_per_token', 0)}",
                     "# TYPE llmlb_engine_kv_pages_total gauge",
                     f"llmlb_engine_kv_pages_total {kv_cache['pages_total']}",
                     "# TYPE llmlb_engine_kv_pages_free gauge",

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.mixtral import MixtralConfig
 from llmlb_tpu.ops.rope import RopeScaling
@@ -29,6 +30,17 @@ PRESETS: dict[str, LlamaConfig] = {
         vocab_size=512, hidden_size=64, intermediate_size=128,
         num_layers=2, num_heads=4, num_kv_heads=2, dtype=jnp.float32,
         max_position_embeddings=128, num_experts=4, experts_per_token=2,
+    ),
+    # CI-sized latent-attention mixture (models/deepseek_v3.py): one leading
+    # dense layer, two expert layers, a shared expert
+    "debug-mla-tiny": DeepseekV3Config(
+        vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_layers=3, num_heads=4, num_kv_heads=4, head_dim=8,
+        rope_theta=10000.0, rms_eps=1e-6, dtype=jnp.float32,
+        max_position_embeddings=512, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, num_experts=8,
+        experts_per_token=2, moe_intermediate_size=32, num_shared_experts=1,
+        first_k_dense=1, routed_scaling_factor=2.448,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

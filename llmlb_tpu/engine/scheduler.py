@@ -93,14 +93,21 @@ PRIORITY_NAMES = {PRIORITY_HIGH: "high", PRIORITY_NORMAL: "normal",
                   PRIORITY_LOW: "low"}
 
 
+def kv_bytes_per_token_layer(cfg, quantized: bool = False) -> int:
+    """HBM bytes one token leaves in one layer of the page pool: the
+    family's to say (`kv_token_layer_bytes`) — K and V of every kv head for
+    a GQA family, the latent and the shared rope key for a latent one."""
+    return int(family_for(cfg).kv_token_layer_bytes(cfg, quantized))
+
+
 def kv_page_bytes(cfg, page_size: int, quantized: bool = False) -> int:
-    """HBM bytes ONE page holds across all layers, K and V included. The
-    bf16 cell is D·2 bytes per (token, head); the int8 cell is D·1 plus one
-    f32 scale (llmlb_tpu/quant.kv_cell_bytes) — the per-page figure the
-    kv gauges report so capacity math stays honest under quantization."""
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    cell = kv_cell_bytes(cfg.head_dim_, quantized, itemsize)
-    return int(cfg.num_layers * page_size * cfg.num_kv_heads * 2 * cell)
+    """HBM bytes ONE page holds across all layers: page_size tokens of the
+    family's per-token, per-layer cell (for a GQA family, K and V: the bf16
+    cell is D·2 bytes per (token, head); the int8 cell is D·1 plus one f32
+    scale, llmlb_tpu/quant.kv_cell_bytes) — the per-page figure the kv
+    gauges report so capacity math stays honest under quantization."""
+    return int(cfg.num_layers * page_size * kv_bytes_per_token_layer(
+        cfg, quantized))
 
 
 def kv_pool_bytes(cfg, num_pages: int, page_size: int,
@@ -162,6 +169,36 @@ def _write_kv_pages(cache_k, cache_v, k_new, v_new, page_idx):
         return pool.at[:, page_idx].set(new.astype(pool.dtype))
 
     return scatter(cache_k, k_new), scatter(cache_v, v_new)
+
+
+def _pack_step_counters(tokens, stats: dict, shapes: dict,
+                        max_names: tuple) -> jnp.ndarray:
+    """One int32 vector for the burst's ONE fetch: the token rows flattened,
+    then the family's step counters (`stats`: name -> [steps, *shape]) in
+    the order of their names, reduced over the burst's steps — summed, but
+    for `max_names`, of which the largest is kept."""
+    parts = [tokens.reshape(-1).astype(jnp.int32)]
+    for name in sorted(shapes):
+        per_step = stats[name].astype(jnp.int32)
+        reduced = (jnp.max(per_step, axis=0) if name in max_names
+                   else jnp.sum(per_step, axis=0))
+        parts.append(reduced.reshape(-1))
+    return jnp.concatenate(parts)
+
+
+def _unpack_step_counters(flat: np.ndarray, rows: int, cols: int,
+                          shapes: dict) -> tuple[np.ndarray, dict]:
+    """The host's half of _pack_step_counters: (tokens [rows, cols],
+    name -> int or nested list)."""
+    at = rows * cols
+    tokens = flat[:at].reshape(rows, cols)
+    counters = {}
+    for name in sorted(shapes):
+        n = int(np.prod(shapes[name], dtype=np.int64))
+        value = flat[at:at + n].reshape(shapes[name])
+        counters[name] = value.tolist() if shapes[name] else int(value)
+        at += n
+    return tokens, counters
 
 
 def _sample_chunk(logits, key, temps, top_ps, top_ks, seeds, mask, start_pos):
@@ -432,6 +469,13 @@ class EngineCore:
         # Family module (llama / mixtral) supplying the serving fns — one
         # shared contract, so dense and MoE models run the same loop.
         self.family = family_for(cfg)
+        # Step counters the family computes on the device (a mixture's
+        # expert load): name -> shape, {} for a family that has none. The
+        # burst carries them out in the fetch it already makes.
+        self._counter_shapes: dict[str, tuple] = getattr(
+            self.family, "step_counter_shapes", lambda _cfg: {})(cfg)
+        self._counter_max: tuple = getattr(self.family, "STEP_COUNTER_MAX",
+                                           ())
         self.num_slots = num_slots
         self.slot_capacity = min(slot_capacity, cfg.max_position_embeddings)
         self.prefill_buckets = tuple(
@@ -523,6 +567,12 @@ class EngineCore:
 
         if params is None:
             params = self.family.init_params(cfg, jax.random.PRNGKey(seed))
+        if self.quant.weights and not getattr(
+                self.family, "SUPPORTS_INT8_WEIGHTS", True):
+            raise NotImplementedError(
+                f"{self.family.__name__} does not serve int8 weights: some "
+                "of its projections would be quantized and others not; "
+                "start it without --quantize weights|all")
         if self.quant.weights:
             # Idempotent: checkpoints quantized at load time (streaming,
             # engine/weights.py) pass through; random-init / caller-supplied
@@ -562,6 +612,10 @@ class EngineCore:
                     "LLMLB_LORA_RANK_CAP", "16"))
             # MoE families serve attention-target adapters only (no pools
             # over the routed expert FFNs).
+            if not getattr(self.family, "SUPPORTS_LORA", True):
+                raise NotImplementedError(
+                    f"{self.family.__name__} carries no adapter pools: "
+                    "start it without --lora-dir")
             targets = (("wq", "wk", "wv", "wo")
                        if getattr(cfg, "num_experts", 0) > 1
                        else ("wq", "wk", "wv", "wo", "wg", "wu", "wd"))
@@ -1504,7 +1558,8 @@ class EngineCore:
                      active_slots: int = 0, tokens: int = 0,
                      slots: "list[int] | None" = None,
                      dispatches: int = 0, fused: bool = False,
-                     kv_pages: "dict[str, int] | None" = None) -> None:
+                     kv_pages: "dict[str, int] | None" = None,
+                     counters: "dict | None" = None) -> None:
         """Close one step (its last stamp) and finalize its record: the
         admission time since the previous record becomes its plan phase,
         the record feeds the ring buffer + anomaly detector, and the phase
@@ -1520,7 +1575,9 @@ class EngineCore:
         lands on the record and in the engine's two running totals: the
         share of a (slots x window) sweep that live pages are. What this
         costs after the step's last stamp is the next record's
-        since_prev.record_s."""
+        since_prev.record_s. `counters` are the family's step counters of
+        this dispatch (a mixture's expert load): the scalars land on the
+        record, everything in the engine's running totals."""
         clock = self._clock()
         clock.close(step, kind)
         phases = step.phases()
@@ -1534,14 +1591,19 @@ class EngineCore:
         if kind in ("decode", "verify") and dispatches > 0:
             self.decode_dispatch_by_loop[self._loop_tag()] += dispatches
             self.metrics.record_decode_dispatches(dispatches, fused=fused)
+        extra = dict(kv_pages or {})
+        extra.update((name, v) for name, v in (counters or {}).items()
+                     if isinstance(v, int))  # the scalars; not the histogram
         slow = self.step_stats.observe(kind, phases,
                                        active_slots=active_slots,
                                        tokens=tokens,
                                        request_ids=request_ids,
                                        dispatches=dispatches, span=step,
-                                       extra=kv_pages)
+                                       extra=extra or None)
         if kv_pages:
             self.metrics.record_decode_kv_pages(**kv_pages)
+        if counters:
+            self.metrics.record_step_counters(counters, self._counter_max)
         self.metrics.record_step_phases(phases, slow=slow)
         if slow and request_ids and self.flightrec.enabled:
             total = round(sum(phases.values()), 6)
@@ -1973,13 +2035,21 @@ class EngineCore:
     def _kv_dtype_name(self) -> str:
         return "int8" if self.quant.kv else str(jnp.dtype(self.cfg.dtype))
 
+    def _kv_wire_cell(self) -> tuple[int, int] | None:
+        """(kv heads, head dim) of the pages a KVSH payload carries, asked
+        of the family; None where its pool has no wire form (a latent
+        pool's two arrays are not K and V of one shape): such an engine
+        ships nothing and adopts nothing, and every move replays."""
+        return self.family.kv_wire_cell(self.cfg)
+
     def _kv_header(self, tokens: int, num_pages: int) -> KVWireHeader:
+        num_kv_heads, head_dim = self._kv_wire_cell() or (0, 0)
         return KVWireHeader(
             version=KV_WIRE_VERSION,
             layers=self.cfg.num_layers,
             page_size=self.kv_page_size,
-            num_kv_heads=self.cfg.num_kv_heads,
-            head_dim=self.cfg.head_dim_,
+            num_kv_heads=num_kv_heads,
+            head_dim=head_dim,
             kv_dtype=self._kv_dtype_name(),
             tokens=tokens,
             num_pages=num_pages,
@@ -1988,12 +2058,15 @@ class EngineCore:
     def kv_restore_reason(self, header: KVWireHeader) -> str | None:
         """None when an inbound payload can land in THIS pool verbatim,
         else the fallback-counter reason (dtype | page_size | geometry)."""
+        cell = self._kv_wire_cell()
+        if cell is None:
+            return "geometry"
         return kv_compat_reason(
             header,
             layers=self.cfg.num_layers,
             page_size=self.kv_page_size,
-            num_kv_heads=self.cfg.num_kv_heads,
-            head_dim=self.cfg.head_dim_,
+            num_kv_heads=cell[0],
+            head_dim=cell[1],
             kv_dtype=self._kv_dtype_name(),
         )
 
@@ -2024,7 +2097,8 @@ class EngineCore:
         wire payload (the /v1/handoff pages attachment). None when there is
         nothing shippable."""
         tokens = int(self._seq_lens[slot_id])
-        if tokens <= 0 or not self._slot_pages[slot_id]:
+        if (tokens <= 0 or not self._slot_pages[slot_id]
+                or self._kv_wire_cell() is None):
             return None
         pages = self._slot_pages[slot_id][: self._pages_for_tokens(tokens)]
         t0 = time.monotonic()
@@ -2182,7 +2256,8 @@ class EngineCore:
         # exports serve two callers: a draining engine spills EVERY park for
         # the gateway's resume fetch; a healthy engine spills only parks the
         # rebalancer explicitly requested (reason="migrate")
-        want_export = self.kv_ship and (self.draining or reason == "migrate")
+        want_export = (self.kv_ship and self._kv_wire_cell() is not None
+                       and (self.draining or reason == "migrate"))
         tier = self.kv_offload
         want_tier = tier is not None and tier.would_admit(nbytes)
         if not (want_export or want_tier):
@@ -2867,7 +2942,7 @@ class EngineCore:
         def run(params, ids, chunk_lens, start_pos, tables,
                 cache_k, cache_v, temps, top_ps, top_ks, seeds, mask,
                 key, lora_idx=None):
-            logits, cache_k, cache_v = family.verify_step_paged(
+            logits, cache_k, cache_v, *_ = family.verify_step_paged(
                 params, cfg, ids, chunk_lens, start_pos, tables,
                 cache_k, cache_v, mesh, window=window,
                 lora_idx=lora_idx,
@@ -2951,7 +3026,7 @@ class EngineCore:
             ids = ids.at[:, 0].set(last_tokens)
             mask = (gram_mask(gram_table, gram_state, ids)
                     if grammar else None)
-            logits, cache_k, cache_v = family.verify_step_paged(
+            logits, cache_k, cache_v, *_ = family.verify_step_paged(
                 params, cfg, ids, chunk_lens, start_pos, tables,
                 cache_k, cache_v, mesh, window=window,
                 lora_idx=lora_idx,
@@ -3357,6 +3432,9 @@ class EngineCore:
             "waste_tokens_mean": (round(waste / active, 1) if active else 0.0),
             "bytes_per_page": kv_page_bytes(self.cfg, self.kv_page_size,
                                             quantized=self.quant.kv),
+            # all layers of one token: the family's own cell
+            "bytes_per_token": self.cfg.num_layers * kv_bytes_per_token_layer(
+                self.cfg, self.quant.kv),
             "hbm_bytes": kv_pool_bytes(self.cfg, self.kv_num_pages,
                                        self.kv_page_size,
                                        quantized=self.quant.kv),
@@ -3409,7 +3487,6 @@ class EngineCore:
         ]
         mean_ctx = (sum(contexts) / len(contexts)) if contexts else 0.0
         batch = max(1, len(contexts))
-        itemsize = jnp.dtype(self.cfg.dtype).itemsize
         flops_tok = model_flops_per_token(self.cfg, self.n_params)
         # quantization-honest byte accounting: the measured param footprint
         # (int8 values + f32 scales when weights quantize) and the actual
@@ -3418,8 +3495,8 @@ class EngineCore:
         bytes_tok = model_bytes_per_token(
             self.cfg, self.n_params, mean_ctx, batch=batch,
             weight_bytes=self.param_bytes,
-            kv_cell_bytes=kv_cell_bytes(self.cfg.head_dim_, self.quant.kv,
-                                        itemsize),
+            kv_token_layer_bytes=kv_bytes_per_token_layer(self.cfg,
+                                                          self.quant.kv),
         )
         info = {
             "device_kind": str(kind),
@@ -3453,6 +3530,15 @@ class EngineCore:
                 bytes_tok * per_chip / spec.peak_hbm_bw, 6
             )
         return info
+
+    def _prefill_counters(self, stats: list) -> dict | None:
+        """A prefill dispatch's step counters on the host. The dispatch has
+        been waited for, and a prefill fetches nothing else: this is its
+        one small read (a burst's counters ride its token fetch instead)."""
+        if not stats:
+            return None
+        return {name: (np.asarray(v).tolist() if np.ndim(v)
+                       else int(v)) for name, v in stats[0].items()}
 
     def _prefill_group(self, bucket: int,
                        group: list[tuple[int, Request, int]]) -> None:
@@ -3488,7 +3574,8 @@ class EngineCore:
         step = self._clock().begin("dispatch")
         # padding rows repeat the last real slot's table row, so their
         # duplicate scatters rewrite identical cells (same trick as ids)
-        logits, self.cache_k, self.cache_v = self.family.prefill_into_pages(
+        (logits, self.cache_k, self.cache_v,
+         *stats) = self.family.prefill_into_pages(
             self.params,
             self.cfg,
             jnp.asarray(ids),
@@ -3515,6 +3602,7 @@ class EngineCore:
             "prefill", step,
             active_slots=len(group), tokens=sum(n for _, _, n in group),
             slots=[s for s, _, _ in group],
+            counters=self._prefill_counters(stats),
         )
 
     def _activate_group(self, group: list[tuple[int, Request, int]],
@@ -3719,7 +3807,8 @@ class EngineCore:
 
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
-        logits, self.cache_k, self.cache_v = self.family.prefill_extend_pages(
+        (logits, self.cache_k, self.cache_v,
+         *stats) = self.family.prefill_extend_pages(
             self.params,
             self.cfg,
             jnp.asarray(ids),
@@ -3746,6 +3835,7 @@ class EngineCore:
             "prefill", step,
             active_slots=1, tokens=chunk_len,
             slots=[slot_id],
+            counters=self._prefill_counters(stats),
         )
         return True
 
@@ -3801,8 +3891,11 @@ class EngineCore:
         block tables are scan-invariant too — _ensure_decode_pages
         pre-allocates every page the burst will write. `live` is the
         dispatch's _live_rows: the rows it emits for, so the attention
-        kernel walks their pages and no other row's."""
+        kernel walks their pages and no other row's. A family with step
+        counters (step_counter_shapes) returns them after its caches, and the
+        burst's sums ride behind the tokens in the one array fetched."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
+        shapes, max_names = self._counter_shapes, self._counter_max
 
         def many(params, last, lens, cache_k, cache_v, tables,
                  temps, top_ps, top_ks, seeds, key, live, lora_idx=None):
@@ -3810,19 +3903,21 @@ class EngineCore:
 
             def body(carry, step_key):
                 last, lens, ck, cv = carry
-                logits, ck, cv = family.decode_step_paged(
+                logits, ck, cv, *stats = family.decode_step_paged(
                     params, cfg, last, lens, ck, cv, tables, mesh,
                     window=window, lora_idx=lora_idx, live=live,
                 )
                 toks = sample_tokens(logits, step_key, temps, top_ps,
                                      top_ks, None, seeds, lens)
-                return (toks, lens + 1, ck, cv), toks
+                return (toks, lens + 1, ck, cv), (toks, stats)
 
             first_in = last  # pre-burst tokens: pending first emissions
-            (last, lens, cache_k, cache_v), toks = jax.lax.scan(
+            (last, lens, cache_k, cache_v), (toks, stats) = jax.lax.scan(
                 body, (last, lens, cache_k, cache_v), keys
             )
             toks = jnp.concatenate([first_in[None, :], toks], axis=0)
+            if shapes:
+                toks = _pack_step_counters(toks, stats[0], shapes, max_names)
             return last, lens, cache_k, cache_v, toks
 
         return jax.jit(many, donate_argnums=(3, 4))
@@ -3865,6 +3960,7 @@ class EngineCore:
         all-zero table row): their bias is + 0.0 everywhere, bit-preserving
         the unconstrained sampling path."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
+        shapes, max_names = self._counter_shapes, self._counter_max
 
         def many(params, last, lens, cache_k, cache_v, tables,
                  temps, top_ps, top_ks, seeds, key, live, gram_table,
@@ -3873,7 +3969,7 @@ class EngineCore:
 
             def body(carry, step_key):
                 last, lens, gs, ck, cv = carry
-                logits, ck, cv = family.decode_step_paged(
+                logits, ck, cv, *stats = family.decode_step_paged(
                     params, cfg, last, lens, ck, cv, tables, mesh,
                     window=window, lora_idx=lora_idx, live=live,
                 )
@@ -3881,13 +3977,15 @@ class EngineCore:
                 toks = sample_tokens(logits, step_key, temps, top_ps,
                                      top_ks, bias, seeds, lens)
                 gs = grammar_advance(gram_table, gs, toks)
-                return (toks, lens + 1, gs, ck, cv), toks
+                return (toks, lens + 1, gs, ck, cv), (toks, stats)
 
             first_in = last  # pre-burst tokens: pending first emissions
-            (last, lens, _, cache_k, cache_v), toks = jax.lax.scan(
+            (last, lens, _, cache_k, cache_v), (toks, stats) = jax.lax.scan(
                 body, (last, lens, gram_state, cache_k, cache_v), keys
             )
             toks = jnp.concatenate([first_in[None, :], toks], axis=0)
+            if shapes:
+                toks = _pack_step_counters(toks, stats[0], shapes, max_names)
             return last, lens, cache_k, cache_v, toks
 
         return jax.jit(many, donate_argnums=(3, 4))
@@ -4006,6 +4104,10 @@ class EngineCore:
             jax.block_until_ready(toks_dev)
             step.mark("fetch")
             tokens = self._fetch_tokens(toks_dev)  # ONE D2H sync per k tokens
+            counters = None
+            if self._counter_shapes:
+                tokens, counters = _unpack_step_counters(
+                    tokens, k + 1, self.num_slots, self._counter_shapes)
             # Tokens reach the host back-to-back, so wall-clock gaps between
             # _emit calls are ~0 and would poison the ITL histogram; record
             # the amortized per-token pacing of the burst instead.
@@ -4016,7 +4118,7 @@ class EngineCore:
                 "decode", step,
                 active_slots=len(active), tokens=k * len(active),
                 slots=active, dispatches=1, fused=fused_step,
-                kv_pages=kv_pages,
+                kv_pages=kv_pages, counters=counters,
             )
             return True
 
@@ -4024,7 +4126,8 @@ class EngineCore:
         window = self._window_for(active, 1)
         kv_pages = self._kv_pages(active, 1, window)
         step.mark("dispatch")
-        logits, self.cache_k, self.cache_v = self.family.decode_step_paged(
+        (logits, self.cache_k, self.cache_v,
+         *_) = self.family.decode_step_paged(
             self.params,
             self.cfg,
             self._d_last_tokens,

@@ -64,8 +64,11 @@ def model_flops_per_token(cfg, n_params: int) -> float:
         per_tok = getattr(cfg, "experts_per_token", 1) or 1
         # FFN weights are the expert-replicated part; attention/embed are
         # shared. Approximate: scale the FFN fraction by routed/total.
-        ffn = (3 * cfg.hidden_size * cfg.intermediate_size
-               * cfg.num_layers * experts)
+        # a family may give its experts a width and a layer count of their
+        # own (leading dense layers): models/deepseek_v3.py
+        ffn = (3 * cfg.hidden_size
+               * getattr(cfg, "moe_intermediate_size", cfg.intermediate_size)
+               * getattr(cfg, "num_moe_layers", cfg.num_layers) * experts)
         active = n_params - ffn + ffn * per_tok / experts
         return 2.0 * active
     return 2.0 * n_params
@@ -74,7 +77,8 @@ def model_flops_per_token(cfg, n_params: int) -> float:
 def model_bytes_per_token(cfg, n_params: int, mean_context: float,
                           batch: int = 1, *,
                           weight_bytes: float | None = None,
-                          kv_cell_bytes: float | None = None) -> float:
+                          kv_cell_bytes: float | None = None,
+                          kv_token_layer_bytes: float | None = None) -> float:
     """HBM bytes read per decoded token: every weight once per STEP (decode
     is memory-bound; weights dominate and are amortized across the `batch`
     sequences decoded together) plus the KV rows of the sequence's own
@@ -85,7 +89,9 @@ def model_bytes_per_token(cfg, n_params: int, mean_context: float,
     quantized — the engine passes its measured device-array bytes), and
     `kv_cell_bytes` the bytes per cached (token, head) cell (D·1 + 4-byte
     scale under int8 KV vs D·itemsize bf16). Defaults reproduce the
-    unquantized bf16 math exactly."""
+    unquantized bf16 math exactly. `kv_token_layer_bytes` is the family's
+    own figure for one token in one layer of the pool (the engine passes
+    it: a latent pool has no per-head cell) and overrides the head math."""
     import jax.numpy as jnp
 
     itemsize = jnp.dtype(cfg.dtype).itemsize
@@ -93,8 +99,9 @@ def model_bytes_per_token(cfg, n_params: int, mean_context: float,
         weight_bytes = n_params * itemsize
     if kv_cell_bytes is None:
         kv_cell_bytes = cfg.head_dim_ * itemsize
-    kv_bytes = (cfg.num_layers * mean_context * cfg.num_kv_heads
-                * kv_cell_bytes * 2)
+    if kv_token_layer_bytes is None:
+        kv_token_layer_bytes = cfg.num_kv_heads * kv_cell_bytes * 2
+    kv_bytes = cfg.num_layers * mean_context * kv_token_layer_bytes
     return weight_bytes / max(1, batch) + kv_bytes
 
 

@@ -54,6 +54,10 @@ def _param_builders(cfg: LlamaConfig) -> dict[str, LeafBuilder]:
 
         return build
 
+    from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
+
+    if isinstance(cfg, DeepseekV3Config):
+        return _deepseek_v3_param_builders(cfg, single)
     if getattr(cfg, "num_experts", 0) > 1:
         return _moe_param_builders(cfg, stack, single)
 
@@ -120,6 +124,92 @@ def _moe_param_builders(cfg, stack, single) -> dict[str, LeafBuilder]:
     return builders
 
 
+def _deepseek_v3_param_builders(cfg, single) -> dict[str, LeafBuilder]:
+    """`modeling_deepseek_v3` names onto the two-group pytree of
+    models/deepseek_v3.py: layers [0, first_k_dense) are the `dense_` group,
+    the rest the expert group. `kv_b_proj` [H*(Dn+Dv), C] is split per head
+    into `wk_b` [H, C, Dn] and `wv_b` [H, C, Dv] here, once."""
+    from llmlb_tpu.models.deepseek_v3 import DENSE
+
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    at = "model.layers.{i}.self_attn."
+
+    def stack(layers: range, fmt: str, shape=lambda w: w.T) -> LeafBuilder:
+        def build(get: TensorGetter) -> np.ndarray:
+            return np.stack([shape(get(fmt.format(i=i))) for i in layers])
+
+        return build
+
+    def kv_b(lo: int, hi: int):
+        def shape(w):  # [H*(Dn+Dv), C] -> [H, C, hi-lo]
+            per_head = w.reshape(cfg.num_heads, dn + dv, -1)
+            return per_head[:, lo:hi, :].transpose(0, 2, 1)
+
+        return shape
+
+    def experts(layers: range, proj: str) -> LeafBuilder:
+        def build(get: TensorGetter) -> np.ndarray:
+            return np.stack([np.stack([
+                get(f"model.layers.{i}.mlp.experts.{e}.{proj}.weight").T
+                for e in range(cfg.num_experts)]) for i in layers])
+
+        return build
+
+    def same(w):
+        return w
+
+    builders: dict[str, LeafBuilder] = {
+        "embed": single("model.embed_tokens.weight"),
+        "ln_final": single("model.norm.weight"),
+    }
+    dense = range(cfg.first_k_dense)
+    routed = range(cfg.first_k_dense, cfg.num_layers)
+    for prefix, layers in ((DENSE, dense), ("", routed)):
+        if not layers:
+            continue
+        builders.update({
+            prefix + "wq": stack(layers, at + "q_proj.weight"),
+            prefix + "wkv_a": stack(layers, at + "kv_a_proj_with_mqa.weight"),
+            prefix + "ln_kv": stack(layers, at + "kv_a_layernorm.weight", same),
+            prefix + "wk_b": stack(layers, at + "kv_b_proj.weight", kv_b(0, dn)),
+            prefix + "wv_b": stack(layers, at + "kv_b_proj.weight",
+                                   kv_b(dn, dn + dv)),
+            prefix + "wo": stack(layers, at + "o_proj.weight"),
+            prefix + "ln_attn": stack(
+                layers, "model.layers.{i}.input_layernorm.weight", same),
+            prefix + "ln_mlp": stack(
+                layers, "model.layers.{i}.post_attention_layernorm.weight",
+                same),
+        })
+    mlp = "model.layers.{i}.mlp."
+    if dense:
+        builders.update({
+            DENSE + "wg": stack(dense, mlp + "gate_proj.weight"),
+            DENSE + "wu": stack(dense, mlp + "up_proj.weight"),
+            DENSE + "wd": stack(dense, mlp + "down_proj.weight"),
+        })
+    if routed:
+        builders.update({
+            "router": stack(routed, mlp + "gate.weight"),
+            "router_bias": stack(
+                routed, mlp + "gate.e_score_correction_bias", same),
+            "we_gate": experts(routed, "gate_proj"),
+            "we_up": experts(routed, "up_proj"),
+            "we_down": experts(routed, "down_proj"),
+            "ws_gate": stack(routed, mlp + "shared_experts.gate_proj.weight"),
+            "ws_up": stack(routed, mlp + "shared_experts.up_proj.weight"),
+            "ws_down": stack(routed, mlp + "shared_experts.down_proj.weight"),
+        })
+    if not cfg.tie_word_embeddings:
+        builders["lm_head"] = single("lm_head.weight", True)
+    return builders
+
+
+# Leaves served in float32 whatever the serving dtype (the published
+# checkpoints keep them so): the router's choice bias.
+_FLOAT32_LEAVES = ("router_bias",)
+
+
 def convert_hf_tensors(cfg: LlamaConfig, get: TensorGetter) -> Params:
     """Map HF llama/qwen2/mistral/mixtral tensor names to our stacked pytree
     (all leaves materialized at once — tests and tooling; the serving load
@@ -176,14 +266,13 @@ def _safetensors_getter(model_dir: str) -> TensorGetter:
 
 
 def load_config(model_dir: str, dtype=None) -> LlamaConfig:
-    with open(os.path.join(model_dir, "config.json")) as f:
-        hf = json.load(f)
-    kwargs = {} if dtype is None else {"dtype": dtype}
-    if hf.get("model_type") == "mixtral" or hf.get("num_local_experts", 0) > 1:
-        from llmlb_tpu.models.mixtral import MixtralConfig
+    """The configuration object of a model directory's `config.json`
+    (models.config_from_hf: the class by `model_type`, a stated mechanism
+    the class would ignore refused by name)."""
+    from llmlb_tpu.models import config_from_hf
 
-        return MixtralConfig.from_hf_config(hf, **kwargs)
-    return LlamaConfig.from_hf_config(hf, **kwargs)
+    with open(os.path.join(model_dir, "config.json")) as f:
+        return config_from_hf(json.load(f), dtype)
 
 
 def load_checkpoint(model_dir: str, cfg: LlamaConfig, mesh=None,
@@ -214,6 +303,8 @@ def load_checkpoint(model_dir: str, cfg: LlamaConfig, mesh=None,
             q, scale = quantize_channelwise(np.asarray(host))
             params[name] = put(name, q)
             params[f"{name}_scale"] = put(f"{name}_scale", scale)
+        elif name in _FLOAT32_LEAVES:
+            params[name] = put(name, np.asarray(host, dtype=np.float32))
         else:
             params[name] = put(name, np.asarray(host, dtype=dtype))
         del host  # streaming contract: one host leaf live at a time

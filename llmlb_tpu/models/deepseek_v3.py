@@ -1,0 +1,539 @@
+"""DeepSeek-V3-class decoder (`model_type` `deepseek_v3`: DeepSeek-V3/R1,
+Kanana-2-30B-A3B): multi-head LATENT attention and a sigmoid-routed mixture
+of experts with shared experts behind leading dense layers.
+
+Same serving contract and the same three shared bodies as models/llama.py
+(docs/deepseek-v3.md); what differs is handed to them:
+
+- A stack of two kinds of layer (llama.LayerGroup): `first_k_dense` layers
+  with a dense SwiGLU, then expert layers. Each group owns its stacked
+  parameters — the dense group under a `dense_` prefix — so a prefill scans
+  each group over its own arrays and decode unrolls both.
+- Latent attention (llama.Attention). A token leaves in the page pool its
+  normalised latent `c` (kv_lora_rank numbers) and the rotated key all heads
+  share (qk_rope_head_dim numbers): two pools under the same page ids,
+  `cache_k` = c [L, P, PS, C] and `cache_v` = k_rope [L, P, PS, 128], no
+  head axis, no second copy (the rope pool's row is one whole 128-lane tile:
+  ops/pallas_attention.paged_latent_decode says why). PREFILL materialises
+  per-head keys and values from the chunk's own latent (T x T attention at
+  width 192/128 is cheaper than at 576/512); EXTEND, VERIFY and DECODE use
+  the ABSORBED form over the pool: q_abs = W^K_h q_nope attends the latent
+  itself and W^V_h carries the mix back out, so a cached prefix is never
+  up-projected. `wk_b` / `wv_b` are W_kv_b split per head once, at load.
+- The routed layer is ops/moe.py's, with DeepSeek-V3's rule
+  (sigmoid_bias_routing) and the shared experts added outside the routing.
+
+The paged serving functions return one value after (logits, cache_k,
+cache_v): the step's expert-load counters (step_counter_shapes), which the
+scheduler carries out with the fetch it already makes. One static switch,
+off in serving: under `routing=True` that value is instead (chosen
+[Lm, B, T, k], scores [Lm, B, T, X] f32 = sigmoid(h W_r) + b, kept
+[Lm, B, T, k] all true) over the Lm expert layers (benchmark/routing.py's
+contract).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from functools import partial
+from typing import Any, NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from llmlb_tpu.models.llama import (
+    Attention,
+    LayerGroup,
+    LlamaConfig,
+    _decode_paged_impl,
+    _default_mlp_fn,
+    _prefill_extend_paged_impl,
+    _prefill_impl,
+    _proj,
+    shard_rules_for,
+)
+from llmlb_tpu.ops import moe
+from llmlb_tpu.ops.attention import (
+    latent_attention_prefill,
+    paged_decode_work,
+    paged_latent_decode,
+    paged_latent_extend,
+)
+from llmlb_tpu.ops.norms import rms_norm
+from llmlb_tpu.ops.rope import apply_rope
+from llmlb_tpu.parallel.sharding import logical_to_sharding
+
+Params = dict[str, Any]
+
+# What the engine refuses for this family at start-up rather than serve
+# half done: int8 weights (the quant names cover some of its projections and
+# not wkv_a, wk_b, wv_b or the shared experts) and LoRA adapter pools.
+SUPPORTS_INT8_WEIGHTS = False
+SUPPORTS_LORA = False
+
+ROPE_CELL = 128  # lanes of the rope pool's row: the rope key, then zeros
+# Upper bounds of the expert-load histogram's buckets (assignments one
+# expert took in one step); the last bucket is open.
+LOAD_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64)
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV3Config(LlamaConfig):
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    num_experts: int = 128  # n_routed_experts
+    experts_per_token: int = 6
+    moe_intermediate_size: int = 768
+    num_shared_experts: int = 2
+    first_k_dense: int = 1  # first_k_dense_replace
+    routed_scaling_factor: float = 2.448
+    norm_topk_prob: bool = True
+    rope_interleave: bool = True
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @classmethod
+    def from_hf_config(cls, hf: dict, dtype=jnp.bfloat16) -> "DeepseekV3Config":
+        """Build from a published `config.json`. What this family does not
+        compute is refused by name: served wrong is worse than not served."""
+        unsupported = {
+            "q_lora_rank": hf.get("q_lora_rank") is not None,
+            "rope_scaling": hf.get("rope_scaling") is not None,
+            "attention_bias": bool(hf.get("attention_bias")),
+            "n_group": hf.get("n_group", 1) != 1,
+            "topk_group": hf.get("topk_group", 1) != 1,
+            "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+            "scoring_func": hf.get("scoring_func", "sigmoid") != "sigmoid",
+            "topk_method": hf.get("topk_method", "noaux_tc") != "noaux_tc",
+            "hidden_act": hf.get("hidden_act", "silu") != "silu",
+        }
+        bad = [k for k, v in unsupported.items() if v]
+        if bad:
+            raise NotImplementedError(
+                f"deepseek_v3 config key(s) {bad} = "
+                f"{[hf.get(k) for k in bad]} are not supported by "
+                "models/deepseek_v3.py; refusing to serve wrong logits")
+        rope = hf.get("qk_rope_head_dim", 64)
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_attention_heads"],
+            head_dim=rope,  # what RoPE turns: the bodies' rope_frequencies
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            dtype=dtype,
+            kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=hf["qk_nope_head_dim"],
+            qk_rope_head_dim=rope,
+            v_head_dim=hf["v_head_dim"],
+            num_experts=hf["n_routed_experts"],
+            experts_per_token=hf["num_experts_per_tok"],
+            moe_intermediate_size=hf["moe_intermediate_size"],
+            num_shared_experts=hf.get("n_shared_experts", 0),
+            first_k_dense=hf.get("first_k_dense_replace", 0),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            rope_interleave=bool(hf.get("rope_interleave", True)),
+        )
+
+
+# ---------------------------------------------------------------------------
+# Params: two groups, each with its own stacked arrays
+# ---------------------------------------------------------------------------
+
+DENSE = "dense_"
+_ATTN = ("wq", "wkv_a", "ln_kv", "wk_b", "wv_b", "wo", "ln_attn", "ln_mlp")
+_DENSE_MLP = ("wg", "wu", "wd")
+_MOE_MLP = ("router", "router_bias", "we_gate", "we_up", "we_down",
+            "ws_gate", "ws_up", "ws_down")
+
+
+def _layer_shapes(cfg: DeepseekV3Config) -> dict[str, tuple[tuple, int]]:
+    """name -> (shape of one layer's leaf, fan-in; 0 = ones)."""
+    e, h, c = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    f, x, fm = cfg.intermediate_size, cfg.num_experts, cfg.moe_intermediate_size
+    fs = fm * cfg.num_shared_experts
+    return {
+        "wq": ((e, h * (dn + dr)), e),
+        "wkv_a": ((e, c + dr), e),
+        "ln_kv": ((c,), 0),
+        "wk_b": ((h, c, dn), c),  # W_kv_b's key half, per head
+        "wv_b": ((h, c, dv), c),  # and its value half
+        "wo": ((h * dv, e), h * dv),
+        "ln_attn": ((e,), 0),
+        "ln_mlp": ((e,), 0),
+        "wg": ((e, f), e), "wu": ((e, f), e), "wd": ((f, e), f),
+        "router": ((e, x), e),
+        "we_gate": ((x, e, fm), e), "we_up": ((x, e, fm), e),
+        "we_down": ((x, fm, e), fm),
+        "ws_gate": ((e, fs), e), "ws_up": ((e, fs), e), "ws_down": ((fs, e), fs),
+    }
+
+
+def _group_leaves(cfg: DeepseekV3Config):
+    """(key in the pytree, name, layers) of every stacked leaf."""
+    out = [(DENSE + n, n, cfg.first_k_dense) for n in _ATTN + _DENSE_MLP]
+    out += [(n, n, cfg.num_moe_layers) for n in _ATTN + _MOE_MLP]
+    return [leaf for leaf in out if leaf[2] > 0]
+
+
+def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
+    """Random init (serving uses checkpoint weights; this backs tests and
+    the benchmark): fan-in scaled normal, norms ones, and the router's
+    choice bias a seeded draw that is NOT zero — a program that weighs by
+    score + bias, or chooses by score alone, then differs from one that
+    follows the rule. The draw is left as it is: such a router spreads a
+    decode step of 64 rows over 118 of 128 experts, where uniform routing
+    gives 122 and a bias balanced over seeded tokens by the architecture's
+    own rule gave 114 (PERF.md section 6, PR 31)."""
+    shapes = _layer_shapes(cfg)
+    leaves = _group_leaves(cfg)
+    keys = iter(jax.random.split(key, len(leaves) + 2))
+    e = cfg.hidden_size
+
+    def w(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * fan_in**-0.5).astype(cfg.dtype)
+
+    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
+                      "ln_final": jnp.ones((e,), cfg.dtype)}
+    for full, name, count in leaves:
+        k = next(keys)
+        if name == "router_bias":
+            params[full] = 0.02 * jax.random.normal(
+                k, (count, cfg.num_experts), jnp.float32)
+            continue
+        shape, fan_in = shapes[name]
+        params[full] = (w(k, (count, *shape), fan_in) if fan_in
+                        else jnp.ones((count, *shape), cfg.dtype))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(next(keys), (e, cfg.vocab_size), e)
+    return params
+
+
+def param_logical_axes(cfg: DeepseekV3Config) -> dict[str, tuple]:
+    layer = {
+        "wq": ("embed", "heads"), "wkv_a": ("embed", None), "ln_kv": (None,),
+        "wk_b": ("heads", None, None), "wv_b": ("heads", None, None),
+        "wo": ("heads", "embed"), "ln_attn": ("embed",), "ln_mlp": ("embed",),
+        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"), "wd": ("ffn", "embed"),
+        "router": ("embed", None), "router_bias": (None,),
+        "we_gate": ("experts", "embed", "ffn"),
+        "we_up": ("experts", "embed", "ffn"),
+        "we_down": ("experts", "ffn", "embed"),
+        "ws_gate": ("embed", "ffn"), "ws_up": ("embed", "ffn"),
+        "ws_down": ("ffn", "embed"),
+    }
+    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
+            "lm_head": ("embed", "vocab")}
+    for full, name, _count in _group_leaves(cfg):
+        axes[full] = ("layers", *layer[name])
+    return axes
+
+
+def param_shardings(cfg: DeepseekV3Config, mesh: Mesh, rules=None):
+    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
+    return {
+        name: logical_to_sharding(mesh, rules, *axes)
+        for name, axes in param_logical_axes(cfg).items()
+    }
+
+
+# ---------------------------------------------------------------------------
+# The latent page pool
+# ---------------------------------------------------------------------------
+
+def kv_token_layer_bytes(cfg: DeepseekV3Config, quantized: bool = False) -> int:
+    """HBM bytes one token leaves in one layer of the pool: the latent and
+    the rope pool's tile-wide row."""
+    _refuse_quantized(quantized)
+    return (cfg.kv_lora_rank + ROPE_CELL) * jnp.dtype(cfg.dtype).itemsize
+
+
+def kv_wire_cell(cfg: DeepseekV3Config) -> None:
+    """The latent pool has no KVSH wire form yet (engine/kv_transfer.py
+    ships K and V pages of one shape; the latent and the rope key are two
+    shapes): an engine of this family ships and adopts nothing, and a
+    handoff, resume or park replays its tokens instead."""
+    return None
+
+
+def _refuse_quantized(quantized: bool) -> None:
+    if quantized:
+        raise NotImplementedError(
+            "an int8 latent page pool is not implemented: serve "
+            "deepseek_v3 models without kv quantization (quantize modes "
+            "kv and all are refused for this family)")
+
+
+def init_kv_pages(cfg: DeepseekV3Config, num_pages: int, page_size: int,
+                  dtype=None, quantized: bool = False):
+    """The latent page pool, as the (cache_k, cache_v) pair of the serving
+    contract: c [L, P, PS, kv_lora_rank] and k_rope [L, P, PS, 128] (the
+    qk_rope_head_dim numbers, then zeros). Page 0 is the trash page."""
+    _refuse_quantized(quantized)
+    dtype = dtype or cfg.dtype
+    lead = (cfg.num_layers, num_pages, page_size)
+    return (jnp.zeros((*lead, cfg.kv_lora_rank), dtype),
+            jnp.zeros((*lead, ROPE_CELL), dtype))
+
+
+def kv_pages_shardings(cfg: DeepseekV3Config, mesh: Mesh, rules=None,
+                       quantized: bool = False):
+    """Every head reads the whole latent: the pool replicates (pages cannot
+    split over dp, and there is no head axis for tp)."""
+    _refuse_quantized(quantized)
+    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
+    sharding = logical_to_sharding(mesh, rules, "layers", None, "seq", None)
+    return (sharding, sharding)
+
+
+# ---------------------------------------------------------------------------
+# Attention
+# ---------------------------------------------------------------------------
+
+class LatentQuery(NamedTuple):
+    """What the block hands the three attention ops as `q`: the two parts
+    of the queries and the layer's per-head halves of W_kv_b."""
+
+    nope: jnp.ndarray  # [B, T, H, Dn]
+    rope: jnp.ndarray  # [B, T, H, Dr], rotated
+    wk_b: jnp.ndarray  # [H, C, Dn]
+    wv_b: jnp.ndarray  # [H, C, Dv]
+
+
+def _scale(cfg: DeepseekV3Config) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
+               attn_fn, lora_idx=None):
+    """Pre-norm latent attention sub-block. Returns (x_out, c, k_rope): the
+    two values the token leaves in the pool."""
+    b, t, _ = x.shape
+    heads, c_dim = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    h = rms_norm(x, lp["ln_attn"], cfg.rms_eps)
+    q = _proj(lp, "wq", h, lora_idx).reshape(b, t, heads, dn + dr)
+    kv = _proj(lp, "wkv_a", h, lora_idx)  # [B, T, C + Dr]
+    c = rms_norm(kv[..., :c_dim], lp["ln_kv"], cfg.rms_eps)
+    k_rope = apply_rope(kv[:, :, None, c_dim:], positions, inv_freq,
+                        cfg.rope_interleave)[:, :, 0]  # one head for all
+    k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, ROPE_CELL - dr)))
+    query = LatentQuery(
+        q[..., :dn],
+        apply_rope(q[..., dn:], positions, inv_freq, cfg.rope_interleave),
+        lp["wk_b"], lp["wv_b"])
+    out = attn_fn(query, c, k_rope)  # [B, T, H, Dv]
+    return x + _proj(lp, "wo", out.reshape(b, t, -1), lora_idx), c, k_rope
+
+
+def _attention(cfg: DeepseekV3Config) -> Attention:
+    dr = cfg.qk_rope_head_dim
+
+    def absorb(q: LatentQuery):
+        return jnp.einsum("bthd,hcd->bthc", q.nope, q.wk_b,
+                          preferred_element_type=jnp.float32
+                          ).astype(q.nope.dtype)
+
+    def carry_out(mix, q: LatentQuery):
+        return jnp.einsum("bthc,hcd->bthd", mix, q.wv_b,
+                          preferred_element_type=jnp.float32
+                          ).astype(mix.dtype)
+
+    def prefill(q: LatentQuery, c, k_rope, prompt_lens):
+        def up(w):  # per-head keys or values of the chunk, from its latent
+            return jnp.einsum("btc,hcd->bthd", c, w,
+                              preferred_element_type=jnp.float32
+                              ).astype(c.dtype)
+
+        shared = jnp.broadcast_to(k_rope[:, :, None, :dr],
+                                  (*q.rope.shape[:3], dr))
+        return latent_attention_prefill(
+            jnp.concatenate([q.nope, q.rope], axis=-1),
+            jnp.concatenate([up(q.wk_b), shared], axis=-1),
+            up(q.wv_b), prompt_lens)
+
+    def extend(q: LatentQuery, c_pool, r_pool, tables, positions, chunk_lens):
+        del chunk_lens  # padding queries attend like real ones; discarded
+        return carry_out(paged_latent_extend(
+            absorb(q), q.rope, c_pool, r_pool, tables, positions,
+            scale=_scale(cfg)), q)
+
+    def decode(q: LatentQuery, c_pool, r_pool, layer, tables, kv_lens, *,
+               window=None, work=None):
+        return carry_out(paged_latent_decode(
+            absorb(q), q.rope, c_pool, r_pool, layer, tables, kv_lens,
+            scale=_scale(cfg), window=window, work=work), q)
+
+    return Attention(_mla_block, prefill, extend, decode, paged_decode_work)
+
+
+# ---------------------------------------------------------------------------
+# Feed-forward and the stack
+# ---------------------------------------------------------------------------
+
+def _moe_mlp_fn(cfg: DeepseekV3Config, live=None):
+    """llama's `mlp_fn(lp, h, token_valid, lora_idx)` for an expert layer:
+    the routed experts by DeepSeek-V3's rule plus the shared experts, and
+    as aux the layer's ops/moe.Routing. `live` ([B] bool) stands in for
+    `token_valid` where the body has none (decode): rows that do not decode
+    are routed nowhere and counted in no expert's load."""
+
+    def fn(lp, h, token_valid, lora_idx=None):
+        b, t, m = h.shape
+        flat = h.reshape(b * t, m)
+        if token_valid is None and live is not None:
+            token_valid = jnp.broadcast_to(live[:, None], (b, t))
+        logits = jnp.einsum("sm,mx->sx", flat, lp["router"],
+                            preferred_element_type=jnp.float32)
+        routed, routing = moe.moe_routed(
+            flat, logits, lp["we_gate"], lp["we_up"], lp["we_down"],
+            layer=lp["layer"],  # the stacks whole: llama.LayerGroup.whole
+            route=lambda r: moe.sigmoid_bias_routing(
+                r, lp["router_bias"], cfg.experts_per_token,
+                scale=cfg.routed_scaling_factor,
+                normalize=cfg.norm_topk_prob),
+            token_valid=(None if token_valid is None
+                         else token_valid.reshape(b * t)),
+        )
+        shared = (jax.nn.silu(flat @ lp["ws_gate"]) * (flat @ lp["ws_up"])
+                  ) @ lp["ws_down"]
+        return (routed + shared).reshape(b, t, m), routing
+
+    return fn
+
+
+def _groups(cfg: DeepseekV3Config, live=None) -> list[LayerGroup]:
+    groups = [
+        LayerGroup(_ATTN + _DENSE_MLP, _default_mlp_fn, cfg.first_k_dense,
+                   DENSE),
+        LayerGroup(_ATTN + _MOE_MLP, _moe_mlp_fn(cfg, live),
+                   cfg.num_moe_layers, whole=("we_gate", "we_up", "we_down")),
+    ]
+    return [g for g in groups if g.count > 0]
+
+
+def step_counter_shapes(cfg: DeepseekV3Config) -> dict[str, tuple]:
+    """The counters a paged serving call returns, by name and shape (all
+    int32). Over the steps of a burst the scheduler sums them, but for
+    STEP_COUNTER_MAX, of which it keeps the largest."""
+    if cfg.num_moe_layers <= 0:
+        return {}
+    return {"experts_touched": (), "expert_assignments": (),
+            "expert_load_max": (),
+            "expert_load_hist": (cfg.num_moe_layers, len(LOAD_BUCKETS) + 1)}
+
+
+STEP_COUNTER_MAX = ("expert_load_max",)
+
+
+def _moe_aux(cfg: DeepseekV3Config, aux) -> moe.Routing | None:
+    """The expert group's Routing stacked over its layers [Lm, ...]."""
+    if cfg.num_moe_layers <= 0:
+        return None
+    stacked = aux[-1]
+    if isinstance(stacked, list):  # decode: one per unrolled layer
+        stacked = jax.tree.map(lambda *a: jnp.stack(a), *stacked)
+    return stacked
+
+
+def _extra(cfg: DeepseekV3Config, aux, shape, routing: bool):
+    """What follows (logits, cache_k, cache_v) in a call's result: the
+    step's counters, or under `routing` what the routers decided."""
+    r = _moe_aux(cfg, aux)
+    if r is None:
+        return ()
+    if routing:
+        lm = cfg.num_moe_layers
+        chosen = r.chosen.reshape(lm, *shape, -1)
+        return ((chosen, r.scores.reshape(lm, *shape, -1),
+                 jnp.ones(chosen.shape, bool)),)
+    load = r.load  # [Lm, X]
+    bounds = jnp.asarray(LOAD_BUCKETS, jnp.int32)
+    bucket = jnp.sum(load[..., None] > bounds, axis=-1)  # [Lm, X]
+    hist = jnp.sum(
+        bucket[..., None] == jnp.arange(len(LOAD_BUCKETS) + 1),
+        axis=1, dtype=jnp.int32)
+    return ({
+        "experts_touched": jnp.sum(load > 0, dtype=jnp.int32),
+        "expert_assignments": jnp.sum(load, dtype=jnp.int32),
+        "expert_load_max": jnp.max(load).astype(jnp.int32),
+        "expert_load_hist": hist,
+    },)
+
+
+_STATIC = ("cfg", "mesh", "routing")
+
+
+@partial(jax.jit, static_argnames=_STATIC,
+         donate_argnames=("cache_k", "cache_v"))
+def prefill_into_pages(params, cfg: DeepseekV3Config, input_ids, prompt_lens,
+                       block_tables, cache_k, cache_v,
+                       mesh: Mesh | None = None, lora_idx=None,
+                       routing: bool = False):
+    """Continuous-batching insert path. Same contract as
+    llama.prefill_into_pages, its HANDOFF CONTRACT included."""
+    logits, cache_k, cache_v, aux = _prefill_impl(
+        params, cfg, input_ids, prompt_lens, block_tables, cache_k, cache_v,
+        lora_idx=lora_idx, groups=_groups(cfg), attention=_attention(cfg))
+    return (logits, cache_k, cache_v,
+            *_extra(cfg, aux, input_ids.shape, routing))
+
+
+@partial(jax.jit, static_argnames=_STATIC,
+         donate_argnames=("cache_k", "cache_v"))
+def prefill_extend_pages(params, cfg: DeepseekV3Config, input_ids, chunk_lens,
+                         start_pos, block_tables, cache_k, cache_v,
+                         mesh: Mesh | None = None, lora_idx=None,
+                         routing: bool = False):
+    """Chunked-prefill append path. Same contract as
+    llama.prefill_extend_pages."""
+    logits, cache_k, cache_v, aux = _prefill_extend_paged_impl(
+        params, cfg, input_ids, chunk_lens, start_pos, block_tables,
+        cache_k, cache_v, lora_idx=lora_idx, groups=_groups(cfg),
+        attention=_attention(cfg))
+    return (logits, cache_k, cache_v,
+            *_extra(cfg, aux, input_ids.shape, routing))
+
+
+@partial(jax.jit, static_argnames=_STATIC + ("window",),
+         donate_argnames=("cache_k", "cache_v"))
+def verify_step_paged(params, cfg: DeepseekV3Config, input_ids, chunk_lens,
+                      start_pos, block_tables, cache_k, cache_v,
+                      mesh: Mesh | None = None, window: int | None = None,
+                      lora_idx=None, routing: bool = False):
+    """Speculative verification. Same contract as llama.verify_step_paged."""
+    logits, cache_k, cache_v, aux = _prefill_extend_paged_impl(
+        params, cfg, input_ids, chunk_lens, start_pos, block_tables,
+        cache_k, cache_v, all_logits=True, window=window, lora_idx=lora_idx,
+        groups=_groups(cfg), attention=_attention(cfg))
+    return (logits, cache_k, cache_v,
+            *_extra(cfg, aux, input_ids.shape, routing))
+
+
+@partial(jax.jit, static_argnames=_STATIC + ("window",),
+         donate_argnames=("cache_k", "cache_v"))
+def decode_step_paged(params, cfg: DeepseekV3Config, input_ids, seq_lens,
+                      cache_k, cache_v, block_tables,
+                      mesh: Mesh | None = None, window: int | None = None,
+                      lora_idx=None, live=None, routing: bool = False):
+    """One decode step across all rows. Same contract as
+    llama.decode_step_paged."""
+    logits, cache_k, cache_v, aux = _decode_paged_impl(
+        params, cfg, input_ids, seq_lens, cache_k, cache_v, block_tables,
+        window=window, lora_idx=lora_idx, live=live,
+        groups=_groups(cfg, live), attention=_attention(cfg))
+    return (logits, cache_k, cache_v,
+            *_extra(cfg, aux, (input_ids.shape[0], 1), routing))

@@ -27,14 +27,19 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from llmlb_tpu.ops.attention import gqa_attention_prefill
+from llmlb_tpu.ops.attention import (
+    gqa_attention_prefill,
+    paged_attention_decode,
+    paged_attention_extend,
+    paged_decode_work,
+)
 from llmlb_tpu.ops.norms import rms_norm
 from llmlb_tpu.ops.rope import RopeScaling, apply_rope, rope_frequencies
 from llmlb_tpu.parallel.mesh import validate_tp
@@ -267,6 +272,22 @@ def kv_pages_shardings(cfg: LlamaConfig, mesh: Mesh,
     return (sharding, sharding)
 
 
+def kv_token_layer_bytes(cfg: LlamaConfig, quantized: bool = False) -> int:
+    """HBM bytes one token leaves in one layer of the pool: K and V of
+    every kv head (the int8 cell is D·1 plus one f32 scale)."""
+    from llmlb_tpu.quant import kv_cell_bytes
+
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    return cfg.num_kv_heads * 2 * kv_cell_bytes(cfg.head_dim_, quantized,
+                                                itemsize)
+
+
+def kv_wire_cell(cfg: LlamaConfig) -> tuple[int, int]:
+    """(kv heads, head dim) of the K and V pages a KVSH payload carries
+    (engine/kv_transfer.py)."""
+    return cfg.num_kv_heads, cfg.head_dim_
+
+
 def kv_pool_values(pool):
     """The value array of a KV page pool (the int8 member of a quantized
     {"q","s"} pair, or the pool itself when bf16)."""
@@ -421,16 +442,139 @@ def _default_mlp_fn(lp: Params, h: jnp.ndarray, token_valid,
     return _mlp(lp, h, lora_idx)
 
 
+class Attention(NamedTuple):
+    """A family's attention, as the shared bodies below take it (they take
+    the feed-forward as `mlp_fn` the same way). The bodies never look inside
+    `q`, nor at what the two cached values are: `block` hands `attn_fn(q, k,
+    v)` whatever its own three ops expect, and returns the two values a
+    token leaves in the page pool (keys and values here; the latent and the
+    rotated shared key in models/deepseek_v3.py).
+
+    block(cfg, lp, x, positions, inv_freq, attn_fn, lora_idx)
+        -> (x + attention, k_cached, v_cached)
+    prefill(q, k, v, prompt_lens)                       fresh prompt, no pool
+    extend(q, pool_k, pool_v, tables, positions, chunk_lens)   one layer's pool
+    decode(q, pool_k, pool_v, layer, tables, kv_lens, window=, work=)
+    decode_work(pool_k, tables, kv_lens, window)        the decode kernel's grid
+    """
+
+    block: Callable
+    prefill: Callable
+    extend: Callable
+    decode: Callable
+    decode_work: Callable
+
+
+class LayerGroup(NamedTuple):
+    """`count` consecutive layers of one kind: their stacked parameters
+    (`names`, each [count, ...], under `prefix + name` in the pytree where
+    two groups have parameters of one name) and their feed-forward. A model
+    is a list
+    of groups in layer order (Llama and Mixtral: one; a model with leading
+    dense layers before its expert layers: two). Prefill and extend scan
+    each group, decode unrolls them all."""
+
+    names: tuple
+    mlp_fn: Callable
+    count: int
+    prefix: str = ""  # of the group's keys in the pytree; a layer sees none
+    # Of `names`, those a layer is handed WHOLE ([count, ...]) beside its
+    # own index in the group, `lp["layer"]`, instead of its slice: operands
+    # of a kernel, which takes whole buffers — a slice of the stack would be
+    # copied on every call, as a slice of the page pool was (PR 25).
+    whole: tuple = ()
+
+
+GQA_ATTENTION = Attention(_attn_block, gqa_attention_prefill,
+                          paged_attention_extend, paged_attention_decode,
+                          paged_decode_work)
+
+
+def _groups_for(cfg, stacked_names, mlp_fn, groups):
+    if groups is not None:
+        return groups
+    return [LayerGroup(tuple(stacked_names or _layer_stacked_names(cfg)),
+                       mlp_fn, cfg.num_layers)]
+
+
+def _group_params(params: Params, group: LayerGroup) -> tuple[Params, Params]:
+    """The group's stacked parameters with their companions (_with_scales),
+    under the names a layer reads them by: (those a layer gets its slice
+    of, those it gets whole). The first holds the layers' indices too
+    (`layer`) where the second is not empty."""
+    keys = _with_scales(params, [group.prefix + n for n in group.names])
+    named = {k[len(group.prefix):]: params[k] for k in keys}
+    whole = {n: w for n, w in named.items()
+             if any(n == base or n.startswith(base + "_")
+                    for base in group.whole)}
+    sliced = {n: w for n, w in named.items() if n not in whole}
+    if whole:
+        sliced["layer"] = jnp.arange(group.count, dtype=jnp.int32)
+    return sliced, whole
+
+
+def _mlp_out(res):
+    """A feed-forward gives its output, or (output, aux): what a routed
+    layer reports about its routing, stacked per group by the bodies."""
+    return res if isinstance(res, tuple) else (res, None)
+
+
+def _group_pools(pools, start: int, count: int):
+    """The `count` layers of the stacked pools from `start`: the pools
+    themselves where the group is the whole model."""
+    def part(pool):
+        if count == pool.shape[0]:
+            return pool
+        return lax.slice_in_dim(pool, start, start + count, axis=0)
+
+    return jax.tree.map(part, pools)
+
+
+def _join_pools(parts):
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *p: jnp.concatenate(p, axis=0), *parts)
+
+
+def _scan_groups(params, groups, x, cache_k, cache_v, layer_of):
+    """Prefill's and extend's walk over the stack: each group scanned over
+    its own stacked parameters and its own layers of the two pools.
+    `layer_of(group)` gives the scan body `(x, (lp, ck, cv)) -> (x, (ck, cv,
+    aux))`. Returns (x, cache_k, cache_v, aux per group)."""
+    done_k, done_v, aux, start = [], [], [], 0
+    for group in groups:
+        stacked, whole = _group_params(params, group)
+        body = layer_of(group)
+
+        def layer(carry_x, layer_in, body=body, whole=whole):
+            lp, ck, cv = layer_in
+            return body(carry_x, ({**lp, **whole}, ck, cv))
+
+        x, (ck, cv, group_aux) = lax.scan(
+            layer, x, (stacked, *_group_pools((cache_k, cache_v), start,
+                                              group.count)))
+        done_k.append(ck)
+        done_v.append(cv)
+        aux.append(group_aux)
+        start += group.count
+    return x, _join_pools(done_k), _join_pools(done_v), aux
+
+
 def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
                   cache_k, cache_v, *, stacked_names=None,
-                  mlp_fn=_default_mlp_fn, lora_idx=None):
+                  mlp_fn=_default_mlp_fn, lora_idx=None, groups=None,
+                  attention=None):
     """Shared prefill body for every model family.
 
     K/V scatter through `block_tables` into the page pool; `mlp_fn(lp, h,
     token_valid, lora_idx)` is the per-family feed-forward (dense SwiGLU
     here, routed experts for mixtral — token_valid marks non-padding tokens
     so MoE routing can ignore padding). `lora_idx` ([B] int32, optional)
-    selects each row's adapter pool slot (docs/lora.md)."""
+    selects each row's adapter pool slot (docs/lora.md). `groups` describes
+    a stack of more than one kind of layer and `attention` another
+    attention than GQA (LayerGroup, Attention). Returns (logits, cache_k,
+    cache_v, aux) with `aux` one entry per group: what its feed-forward
+    reported, stacked over the group's layers, or None."""
     b, t = input_ids.shape
     write_kv = make_write_kv_pages(block_tables,
                                    kv_pool_values(cache_k).shape[2])
@@ -439,28 +583,32 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
     token_valid = positions < prompt_lens[:, None]  # [B, T]
 
     x = params["embed"][input_ids]  # [B, T, E]
-    stacked = {n: params[n] for n in _with_scales(
-        params, stacked_names or _layer_stacked_names(cfg))}
+    attention = attention or GQA_ATTENTION
 
-    def layer(carry_x, layer_in):
-        lp, ck, cv = layer_in
-        carry_x, k, v = _attn_block(
-            cfg, lp, carry_x, positions, inv_freq,
-            lambda q, k, v: gqa_attention_prefill(q, k, v, prompt_lens),
-            lora_idx,
-        )
-        ck = write_kv(ck, k, positions)
-        cv = write_kv(cv, v, positions)
-        h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
-        carry_x = carry_x + mlp_fn(lp, h, token_valid, lora_idx)
-        return carry_x, (ck, cv)
+    def layer_of(group):
+        def layer(carry_x, layer_in):
+            lp, ck, cv = layer_in
+            carry_x, k, v = attention.block(
+                cfg, lp, carry_x, positions, inv_freq,
+                lambda q, k, v: attention.prefill(q, k, v, prompt_lens),
+                lora_idx,
+            )
+            ck = write_kv(ck, k, positions)
+            cv = write_kv(cv, v, positions)
+            h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
+            out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
+            return carry_x + out, (ck, cv, aux)
 
-    x, (cache_k, cache_v) = lax.scan(layer, x, (stacked, cache_k, cache_v))
+        return layer
+
+    x, cache_k, cache_v, aux = _scan_groups(
+        params, _groups_for(cfg, stacked_names, mlp_fn, groups), x,
+        cache_k, cache_v, layer_of)
 
     last = jnp.maximum(prompt_lens - 1, 0)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, E]
     logits = _unembed(cfg, params, x_last)
-    return logits, cache_k, cache_v
+    return logits, cache_k, cache_v, aux
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"),
@@ -492,13 +640,14 @@ def prefill_into_pages(
     return _prefill_impl(
         params, cfg, input_ids, prompt_lens, block_tables, cache_k, cache_v,
         lora_idx=lora_idx,
-    )
+    )[:3]
 
 
 def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
                                block_tables, cache_k, cache_v, *,
                                stacked_names=None, mlp_fn=_default_mlp_fn,
-                               all_logits=False, window=None, lora_idx=None):
+                               all_logits=False, window=None, lora_idx=None,
+                               groups=None, attention=None):
     """Shared chunked-prefill body: process a [B, T] chunk of prompt tokens
     whose rows already hold `start_pos` tokens of KV. The chunk's KV scatters
     through the block table into the page pool and queries attend over the
@@ -511,9 +660,8 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
     entries), never another row's cells; those cells sit past the valid
     range (masked by every later attention) and are overwritten in place
     when the sequence grows into them. `window` (static) bounds the
-    attention sweep to whole pages covering it, same contract as decode."""
-    from llmlb_tpu.ops.attention import paged_attention_extend
-
+    attention sweep to whole pages covering it, same contract as decode.
+    `groups`, `attention` and the fourth value returned: as _prefill_impl."""
     _, t = input_ids.shape
     ps = kv_pool_values(cache_k).shape[2]
     ppn = block_tables.shape[1]
@@ -534,36 +682,40 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
         )
 
     x = params["embed"][input_ids]  # [B, T, E]
-    stacked = {n: params[n] for n in _with_scales(
-        params, stacked_names or _layer_stacked_names(cfg))}
+    attention = attention or GQA_ATTENTION
 
-    def layer(carry_x, layer_in):
-        lp, ck, cv = layer_in
+    def layer_of(group):
+        def layer(carry_x, layer_in):
+            lp, ck, cv = layer_in
 
-        def attn_fn(q, k, v):
-            nonlocal ck, cv  # pool write precedes attention over the pool
-            ck = _write_pool(ck, page, off, k)
-            cv = _write_pool(cv, page, off, v)
-            return paged_attention_extend(
-                q, ck, cv, read_tables, positions, chunk_lens
-            )
+            def attn_fn(q, k, v):
+                nonlocal ck, cv  # pool write precedes attention over the pool
+                ck = _write_pool(ck, page, off, k)
+                cv = _write_pool(cv, page, off, v)
+                return attention.extend(
+                    q, ck, cv, read_tables, positions, chunk_lens
+                )
 
-        carry_x, _, _ = _attn_block(cfg, lp, carry_x, positions, inv_freq,
-                                    attn_fn, lora_idx)
-        h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
-        carry_x = carry_x + mlp_fn(lp, h, token_valid, lora_idx)
-        return carry_x, (ck, cv)
+            carry_x, _, _ = attention.block(
+                cfg, lp, carry_x, positions, inv_freq, attn_fn, lora_idx)
+            h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
+            out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
+            return carry_x + out, (ck, cv, aux)
 
-    x, (cache_k, cache_v) = lax.scan(layer, x, (stacked, cache_k, cache_v))
+        return layer
+
+    x, cache_k, cache_v, aux = _scan_groups(
+        params, _groups_for(cfg, stacked_names, mlp_fn, groups), x,
+        cache_k, cache_v, layer_of)
 
     if all_logits:
         b = x.shape[0]
         logits = _unembed(cfg, params, x.reshape(b * t, -1)).reshape(b, t, -1)
-        return logits, cache_k, cache_v
+        return logits, cache_k, cache_v, aux
     last = jnp.maximum(chunk_lens - 1, 0)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, E]
     logits = _unembed(cfg, params, x_last)
-    return logits, cache_k, cache_v
+    return logits, cache_k, cache_v, aux
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"),
@@ -588,7 +740,7 @@ def prefill_extend_pages(
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
         cache_k, cache_v, lora_idx=lora_idx,
-    )
+    )[:3]
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
@@ -615,13 +767,13 @@ def verify_step_paged(
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
         cache_k, cache_v, all_logits=True, window=window, lora_idx=lora_idx,
-    )
+    )[:3]
 
 
 def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                        block_tables, *, stacked_names=None,
                        mlp_fn=_default_mlp_fn, window=None, lora_idx=None,
-                       live=None):
+                       live=None, groups=None, attention=None):
     """Shared one-token decode body for every model family.
 
     The layer loop is UNROLLED (static layer indices) rather than a
@@ -650,12 +802,8 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     (tests/test_decode_program_structure.py holds that). The kernel's grid,
     the work-list of live (row, page) pairs, is built here once a step for
     all the layers; under the engine's burst scan it follows `seq_lens`
-    across page boundaries."""
-    from llmlb_tpu.ops.attention import (
-        paged_attention_decode,
-        paged_decode_work,
-    )
-
+    across page boundaries. `groups`, `attention` and the fourth value
+    returned: as _prefill_impl (a group's aux is a list over its layers)."""
     b = input_ids.shape[0]
     ps = kv_pool_values(cache_k).shape[2]
     capacity = block_tables.shape[1] * ps
@@ -668,32 +816,40 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     kv_lens = write_pos + 1  # the row's cells once this step's is written
     if live is not None:
         kv_lens = jnp.where(live, kv_lens, 0)
-    work = paged_decode_work(cache_k, block_tables, kv_lens, window)
+    attention = attention or GQA_ATTENTION
+    work = attention.decode_work(cache_k, block_tables, kv_lens, window)
 
     x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
-    names = _with_scales(params, stacked_names or _layer_stacked_names(cfg))
+    aux, layer_idx = [], 0
+    for group in _groups_for(cfg, stacked_names, mlp_fn, groups):
+        stacked, whole = _group_params(params, group)
+        group_aux = []
+        for in_group in range(group.count):
+            lp = {n: w[in_group] for n, w in stacked.items()}
+            lp.update(whole, layer=in_group)  # a static index, unrolled
 
-    for layer_idx in range(cfg.num_layers):
-        lp = {n: params[n][layer_idx] for n in names}
+            def attn_fn(q, k, v, layer_idx=layer_idx):
+                nonlocal cache_k, cache_v  # write precedes attention
+                cache_k = _write_pool_layer(cache_k, layer_idx, page, off,
+                                            k[:, 0])
+                cache_v = _write_pool_layer(cache_v, layer_idx, page, off,
+                                            v[:, 0])
+                return attention.decode(
+                    q, cache_k, cache_v, layer_idx, block_tables, kv_lens,
+                    window=window, work=work,
+                )
 
-        def attn_fn(q, k, v, layer_idx=layer_idx):
-            nonlocal cache_k, cache_v  # write precedes attention over the pool
-            cache_k = _write_pool_layer(cache_k, layer_idx, page, off,
-                                        k[:, 0])
-            cache_v = _write_pool_layer(cache_v, layer_idx, page, off,
-                                        v[:, 0])
-            return paged_attention_decode(
-                q, cache_k, cache_v, layer_idx, block_tables, kv_lens,
-                window=window, work=work,
-            )
-
-        x, _, _ = _attn_block(cfg, lp, x, positions, inv_freq, attn_fn,
-                              lora_idx)
-        h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
-        x = x + mlp_fn(lp, h, None, lora_idx)
+            x, _, _ = attention.block(cfg, lp, x, positions, inv_freq,
+                                      attn_fn, lora_idx)
+            h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
+            out, layer_aux = _mlp_out(group.mlp_fn(lp, h, None, lora_idx))
+            x = x + out
+            group_aux.append(layer_aux)
+            layer_idx += 1
+        aux.append(group_aux)
 
     logits = _unembed(cfg, params, x[:, 0])
-    return logits, cache_k, cache_v
+    return logits, cache_k, cache_v, aux
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
@@ -716,7 +872,7 @@ def decode_step_paged(
     are to be discarded (_decode_paged_impl)."""
     return _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k,
                               cache_v, block_tables, window=window,
-                              lora_idx=lora_idx, live=live)
+                              lora_idx=lora_idx, live=live)[:3]
 
 
 @partial(jax.jit, static_argnames=("cfg",))

@@ -2,7 +2,8 @@
 
 Same serving-shaped skeleton as models/llama.py (stacked layers + lax.scan,
 static-shape prefill/decode over the paged KV pool, GQA attention ops) with the
-dense SwiGLU MLP swapped for top-k routed experts (ops/moe.py). Expert weights
+dense SwiGLU MLP swapped for top-k routed experts (ops/moe.py: assignments
+sorted by expert, grouped products, nothing dropped). Expert weights
 carry an `experts` logical axis mapped to the mesh `ep` axis, so a
 Mixtral-8x7B spans a multi-chip mesh as dp × ep × tp with GSPMD inserting the
 dispatch/combine all-to-alls (BASELINE.json config #5 class).
@@ -22,12 +23,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from llmlb_tpu.models.llama import (
+    LayerGroup,
     LlamaConfig,
     _decode_paged_impl,
     _prefill_extend_paged_impl,
     _prefill_impl,
 )
-from llmlb_tpu.ops.moe import default_capacity, moe_dense_exact, moe_dispatch_combine
+from llmlb_tpu.ops import moe
 from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
@@ -37,7 +39,6 @@ Params = dict[str, Any]
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
-    capacity_factor: float = 1.25
 
     @classmethod
     def from_hf_config(cls, hf: dict, dtype=jnp.bfloat16) -> "MixtralConfig":
@@ -132,6 +133,8 @@ def param_shardings(cfg: MixtralConfig, mesh: Mesh, rules=None):
 from llmlb_tpu.models.llama import (  # noqa: E402,F401
     init_kv_pages,
     kv_pages_shardings,
+    kv_token_layer_bytes,
+    kv_wire_cell,
 )
 
 
@@ -139,62 +142,48 @@ _STACKED = ["wq", "wk", "wv", "wo", "router", "we_gate", "we_up", "we_down",
             "ln_attn", "ln_mlp"]
 
 
-def _moe_mlp(cfg: MixtralConfig, lp: Params, x: jnp.ndarray, mesh: Mesh | None,
-             *, exact: bool, token_valid: jnp.ndarray | None = None):
-    """x: [B, T, E] -> [B, T, E] through routed experts.
-
-    Two regimes, chosen statically by the caller:
-    - `exact=True` (decode, small prefills): exact dense-combine MoE — every
-      expert runs on every token. Decode is HBM-bound on expert weights either
-      way, and exactness keeps decode logits independent of which other
-      requests share the batch (no capacity drops, no cross-request
-      nondeterminism).
-    - `exact=False` (large prefills): GShard capacity dispatch — routed FLOPs
-      with capacity_factor headroom; over-capacity tokens are dropped
-      (standard MoE serving trade-off, tunable via cfg.capacity_factor).
-      `token_valid` keeps padding out of the capacity contest.
-    """
-    b, t, m = x.shape
-    s = b * t
-    flat = x.reshape(s, m)
-    logits = flat @ lp["router"]
-    # int8 expert weights carry per-output-channel scales (llmlb_tpu/quant);
-    # absent on bf16 pytrees, in which case the original einsums run.
-    scales = {
-        f"w_{k}_scale": lp.get(f"we_{k}_scale")
-        for k in ("gate", "up", "down")
-    }
-    if exact:
-        out = moe_dense_exact(
-            flat, logits, lp["we_gate"], lp["we_up"], lp["we_down"],
-            num_selected=cfg.experts_per_token, mesh=mesh, **scales,
-        )
-    else:
-        cap = default_capacity(
-            s, cfg.num_experts, cfg.experts_per_token, cfg.capacity_factor
-        )
-        out = moe_dispatch_combine(
-            flat, logits, lp["we_gate"], lp["we_up"], lp["we_down"],
-            num_selected=cfg.experts_per_token, capacity=cap, mesh=mesh,
-            token_valid=None if token_valid is None else token_valid.reshape(s),
-            **scales,
-        )
-    return out.reshape(b, t, m)
-
-
-def _moe_mlp_fn(cfg: MixtralConfig, mesh: Mesh | None, exact: bool):
+def _moe_mlp_fn(cfg: MixtralConfig):
     """Adapter matching llama's `mlp_fn(lp, h, token_valid, lora_idx)`
-    contract. `lora_idx` is accepted and ignored: MoE engines serve
-    attention-target adapters only (the expert FFNs carry no LoRA pools,
-    so there is nothing for the index to select)."""
+    contract: x [B, T, E] -> [B, T, E] through the routed layer of
+    ops/moe.py, at every size — every assignment is computed, nothing is
+    dropped, so a request's logits do not depend on which other rows share
+    the batch. `token_valid` keeps padding out of the grouped products.
+    `lora_idx` is accepted and ignored: MoE engines serve attention-target
+    adapters only (the expert FFNs carry no LoRA pools, so there is
+    nothing for the index to select)."""
+
+    def route(logits):
+        # looked up at trace time: benchmark/routing.py's tap wraps it
+        return moe.top_k_routing(logits, cfg.experts_per_token)
 
     def fn(lp, h, token_valid, lora_idx=None):
-        return _moe_mlp(
-            cfg, lp, h, mesh, exact=exact,
-            token_valid=None if exact else token_valid,
+        b, t, m = h.shape
+        flat = h.reshape(b * t, m)
+        # int8 expert weights carry per-output-channel scales
+        # (llmlb_tpu/quant); absent on bf16 pytrees
+        scales = {
+            f"w_{k}_scale": lp.get(f"we_{k}_scale")
+            for k in ("gate", "up", "down")
+        }
+        out, _ = moe.moe_routed(
+            flat, flat @ lp["router"], lp["we_gate"], lp["we_up"],
+            lp["we_down"], route=route,
+            layer=lp["layer"],  # the stacks whole: llama.LayerGroup.whole
+            token_valid=(None if token_valid is None
+                         else token_valid.reshape(b * t)),
+            **scales,
         )
+        return out.reshape(b, t, m)
 
     return fn
+
+
+def _groups(cfg: MixtralConfig) -> list[LayerGroup]:
+    """One group of expert layers, its experts handed whole beside the
+    layer's index: on the chip the grouped products read the stack in place
+    (ops/pallas_moe.py), as the latent family's do."""
+    return [LayerGroup(tuple(_STACKED), _moe_mlp_fn(cfg), cfg.num_layers,
+                       whole=("we_gate", "we_up", "we_down"))]
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"),
@@ -209,13 +198,10 @@ def prefill_into_pages(params, cfg: MixtralConfig, input_ids, prompt_lens,
     staging and cross-process replay hold for MoE engines too (the router
     is position-independent; expert choice rides the token, not the
     slot, so a handed-off stream routes identically on the adopter)."""
-    b, t = input_ids.shape
     return _prefill_impl(
         params, cfg, input_ids, prompt_lens, block_tables, cache_k, cache_v,
-        stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=b * t <= 4 * cfg.num_experts),
-        lora_idx=lora_idx,
-    )
+        groups=_groups(cfg), lora_idx=lora_idx,
+    )[:3]
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"),
@@ -225,14 +211,10 @@ def prefill_extend_pages(params, cfg: MixtralConfig, input_ids, chunk_lens,
                          mesh: Mesh | None = None, lora_idx=None):
     """Chunked-prefill append path. Same contract as
     llama.prefill_extend_pages."""
-    b, t = input_ids.shape
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
-        cache_k, cache_v,
-        stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=b * t <= 4 * cfg.num_experts),
-        lora_idx=lora_idx,
-    )
+        cache_k, cache_v, groups=_groups(cfg), lora_idx=lora_idx,
+    )[:3]
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
@@ -241,15 +223,12 @@ def verify_step_paged(params, cfg: MixtralConfig, input_ids, chunk_lens,
                       start_pos, block_tables, cache_k, cache_v,
                       mesh: Mesh | None = None, window: int | None = None,
                       lora_idx=None):
-    """Speculative verification. Same contract as llama.verify_step_paged;
-    exact MoE like decode — capacity drops would make a draft's acceptance
-    depend on which other rows share the batch."""
+    """Speculative verification. Same contract as llama.verify_step_paged."""
     return _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
-        cache_k, cache_v, stacked_names=_STACKED,
-        mlp_fn=_moe_mlp_fn(cfg, mesh, exact=True),
+        cache_k, cache_v, groups=_groups(cfg),
         all_logits=True, window=window, lora_idx=lora_idx,
-    )
+    )[:3]
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
@@ -259,12 +238,8 @@ def decode_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
                       mesh: Mesh | None = None, window: int | None = None,
                       lora_idx=None, live=None):
     """One decode step across all rows. Same contract as
-    llama.decode_step_paged.
-
-    Decode is ALWAYS exact MoE: capacity drops here would make a request's
-    tokens depend on which other rows share the batch."""
+    llama.decode_step_paged."""
     return _decode_paged_impl(
         params, cfg, input_ids, seq_lens, cache_k, cache_v, block_tables,
-        stacked_names=_STACKED, mlp_fn=_moe_mlp_fn(cfg, mesh, exact=True),
-        window=window, lora_idx=lora_idx, live=live,
-    )
+        groups=_groups(cfg), window=window, lora_idx=lora_idx, live=live,
+    )[:3]

@@ -79,6 +79,12 @@ def gqa_attention_prefill(
         _traced["prefill"] = "pallas:flash_prefill"
         return flash_prefill(q, k, v, prompt_lens)
     _traced["prefill"] = "xla"
+    return _prefill_einsum(q, k, v, prompt_lens)
+
+
+def _prefill_einsum(q, k, v, prompt_lens):
+    """gqa_attention_prefill as plain einsums; the values may be narrower
+    than the keys. Returns [B, T, H, Dv]."""
     b, t, h, d = q.shape
     k_heads = k.shape[2]
     qg = _split_gqa(q, k_heads)
@@ -100,7 +106,7 @@ def gqa_attention_prefill(
         "bkgts,bskd->btkgd", probs.astype(q.dtype), v,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(b, t, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, v.shape[-1]).astype(q.dtype)
 
 
 def gqa_attention_extend(
@@ -311,3 +317,124 @@ def gqa_attention_decode(
         preferred_element_type=jnp.float32,
     )
     return out.reshape(b, t, h, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, models/deepseek_v3.py): a token leaves in the pool
+# its normalised latent `c` [C] and the rotated key `k_rope` [R] that all
+# heads share — two pools [L, P, PS, C] and [L, P, PS, R] under the same
+# page ids, no head axis. Prefill materialises keys and values from the
+# chunk's own latent; extend, verify and decode attend in the ABSORBED form:
+# the query is carried into the latent space (q_abs = W^K_h q_nope, [H, C]),
+# scores are q_abs . c + q_rope . k_rope, and the output is the softmax's
+# mix of the latents themselves, which the caller carries back out through
+# W^V_h. The latent is key and value at once, read once.
+# ---------------------------------------------------------------------------
+
+
+def latent_attention_prefill(
+    q: jnp.ndarray,  # [B, T, H, Dq] — [q_nope | q_rope]
+    k: jnp.ndarray,  # [B, T, H, Dq] — [k_nope | k_rope], materialised
+    v: jnp.ndarray,  # [B, T, H, Dv], Dv <= Dq
+    prompt_lens: jnp.ndarray,  # [B] int32
+) -> jnp.ndarray:
+    """Causal self-attention over a fresh prompt with every head its own
+    keys (no grouping) and values narrower than keys: plain einsums on every
+    backend. `flash_prefill` is written for grouped heads of one width, and
+    Mosaic refuses it at 32 ungrouped heads of 192 ("unsupported shape
+    cast", my chip run, PR 31); a prefill kernel for this shape is ROADMAP
+    work. Scale Dq ** -0.5. Returns [B, T, H, Dv]."""
+    _traced["latent_prefill"] = "xla"
+    return _prefill_einsum(q, k, v, prompt_lens)
+
+
+def _pad_last(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """x with zeros appended on its last axis up to `width`: the rope pool
+    is a whole 128-lane tile wide (pallas_attention.paged_latent_decode),
+    the rope part of a query 64."""
+    pad = width - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _latent_attend(q_abs, q_rope, c, r, mask, scale):
+    """q_abs [B, T, H, C], q_rope [B, T, H, R'] against c [B, S, C] and
+    r [B, S, R] (R' <= R, the rest of r zeros); mask [B, T, S]. Returns the
+    mix of latents [B, T, H, C]."""
+    q_rope = _pad_last(q_rope, r.shape[-1])
+    scores = (
+        jnp.einsum("bthc,bsc->bhts", q_abs, c,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bthr,bsr->bhts", q_rope, r,
+                     preferred_element_type=jnp.float32)
+    ) * scale
+    scores = jnp.where(mask[:, None], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhts,bsc->bthc", probs.astype(c.dtype), c,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q_abs.dtype)
+
+
+def _gather_latent(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
+    """[P, PS, C] gathered by block tables [B, N] -> [B, N*PS, C]."""
+    b, n = tables.shape
+    return pages[tables].reshape(b, n * pages.shape[1], pages.shape[2])
+
+
+def paged_latent_extend(
+    q_abs: jnp.ndarray,  # [B, T, H, C]
+    q_rope: jnp.ndarray,  # [B, T, H, R]
+    c_pages: jnp.ndarray,  # [P, PS, C] — one layer's latent pool
+    r_pages: jnp.ndarray,  # [P, PS, R]
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
+    *,
+    scale: float,
+) -> jnp.ndarray:
+    """Absorbed attention of a chunk of queries over row b's pages (earlier
+    chunks, a cached prefix, this chunk), causal by position: plain einsums
+    over the gathered latent on every backend (a chunk's work is the
+    experts', not this; a paged kernel for it is ROADMAP work). Returns the
+    mix of latents [B, T, H, C]."""
+    _traced["latent_extend"] = "xla"
+    c = _gather_latent(c_pages, block_tables)
+    r = _gather_latent(r_pages, block_tables)
+    cell = jnp.arange(c.shape[1], dtype=jnp.int32)
+    mask = cell[None, None, :] <= q_positions[:, :, None]
+    return _latent_attend(q_abs, q_rope, c, r, mask, scale)
+
+
+def paged_latent_decode(
+    q_abs: jnp.ndarray,  # [B, 1, H, C]
+    q_rope: jnp.ndarray,  # [B, 1, H, R]
+    c_pages: jnp.ndarray,  # [L, P, PS, C] stacked latent pool
+    r_pages: jnp.ndarray,  # [L, P, PS, R]
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    kv_lens: jnp.ndarray,  # [B] int32 — valid length; 0 = not live
+    *,
+    scale: float,
+    window: int | None = None,
+    work=None,  # paged_decode_work of the same tables, lengths and window
+) -> jnp.ndarray:
+    """One-token absorbed attention against one layer of the latent pool:
+    same contract as paged_attention_decode (`window`, rows that are not
+    live, the stacked pool addressed at (layer, page), `work`). Returns the
+    mix of latents [B, 1, H, C]."""
+    ps = c_pages.shape[2]
+    ppn = block_tables.shape[1]
+    pages = _window_pages(block_tables, ps, window)
+    if _pallas_enabled():
+        from llmlb_tpu.ops.pallas_attention import paged_latent_decode as kernel
+
+        _traced["latent_decode"] = "pallas:paged_latent_decode"
+        return kernel(q_abs[:, 0], _pad_last(q_rope[:, 0], r_pages.shape[-1]),
+                      c_pages, r_pages, layer,
+                      block_tables, kv_lens, scale=scale, pages=pages,
+                      work=work)[:, None]
+    _traced["latent_decode"] = "xla"
+    tables = block_tables[:, :pages] if pages < ppn else block_tables
+    c = _gather_latent(c_pages[layer], tables)
+    r = _gather_latent(r_pages[layer], tables)
+    cell = jnp.arange(c.shape[1], dtype=jnp.int32)
+    mask = (cell[None, :] < kv_lens[:, None])[:, None, :]  # [B, 1, S]
+    return _latent_attend(q_abs, q_rope, c, r, mask, scale)

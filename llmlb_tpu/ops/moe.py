@@ -1,43 +1,55 @@
-"""Mixture-of-experts layer: top-k routing with capacity-bounded dispatch.
+"""Mixture-of-experts layer: a routing rule, then grouped expert products
+that drop nothing.
 
-TPU-first design — the GShard/Mesh-TensorFlow einsum formulation rather than
-gather/scatter token shuffling:
+One layer at every size (prefill, extend, verify, decode):
 
-- Static shapes: every tensor's shape depends only on (tokens, experts,
-  capacity), never on routing decisions. Raggedness is expressed by dropping
-  tokens over capacity (standard capacity-factor semantics), so the whole layer
-  jits once and tiles onto the MXU.
-- Expert parallelism rides GSPMD: expert-major tensors are sharding-constrained
-  to the mesh `ep` axis and XLA inserts the dispatch/combine all-to-alls. No
-  hand-written collectives — the idiomatic TPU way (scaling-book recipe).
-- dispatch/combine are one-hot einsums (bf16 matmuls on the MXU), which beats
-  dynamic scatter on TPU for the expert counts this framework targets (8-64).
+- The family passes the ROUTING RULE, `route(router_logits f32 [S, E]) ->
+  (weights [S, k] f32, chosen [S, k] int32, scores [S, E] f32)`; `scores` is
+  the quantity whose top-k decided. Two rules live here: `top_k_routing`
+  (Mixtral: the k largest logits, a softmax over those k) and
+  `sigmoid_bias_routing` (DeepSeek-V3 / Kanana `noaux_tc`: sigmoid scores,
+  the choice by score plus a per-expert bias, the weights the UNBIASED
+  scores of the chosen, normalised and scaled).
+- The S x k assignments are sorted by expert and the three SwiGLU products
+  run as grouped matmuls over the experts. On an unpartitioned TPU that is
+  ONE route for every family: the experts arrive stacked over the layers
+  and ops/pallas_moe.grouped_expert_matmul (`grouped_expert_matmul` in a
+  trace) reads them in place; its work-list is the (expert, row tile) pairs
+  that hold rows — an expert no token chose is not visited and its weights
+  are not read. `jax.lax.ragged_dot` on the layer's slice is the fall-back:
+  the CPU, a partitioned mesh, int8 experts. Every assignment is computed:
+  there is no capacity and nothing is dropped, so a token's output does not
+  depend on which other tokens share the batch.
+- Static shapes: S x k rows whatever the routing; the raggedness is the
+  run-time `group_sizes`. Padding tokens (`token_valid` false) are sorted
+  behind every expert's rows and belong to no group: they cost no product
+  and come out as zeros.
+- Expert parallelism rides GSPMD as before: the expert-major weights carry
+  the mesh `ep` axis and XLA places the collectives (it may gather the
+  weights of a grouped product; an expert layer that is TOLD which experts
+  a chip holds is ROADMAP work).
 
-The reference has no MoE anywhere (it is a gateway; SURVEY.md §2.4 "no EP");
-this op exists for the BASELINE.json config #5 class (Mixtral-8x7B across
-multi-slice v5e) as new TPU-native design.
+The reference has no MoE anywhere (it is a gateway; SURVEY.md §2.4 "no EP").
 """
 
 from __future__ import annotations
 
+from typing import Callable, NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from llmlb_tpu.ops.attention import _pallas_enabled
 
 
-def _expert_mm(eq: str, a: jnp.ndarray, w: jnp.ndarray,
-               scale: jnp.ndarray | None) -> jnp.ndarray:
-    """Per-expert einsum with optional int8 dequant (llmlb_tpu/quant): the
-    int8 -> compute-dtype convert fuses into the operand read, and the
-    per-output-channel scale [E, out] applies to the f32 OUTPUT — exact,
-    since the scale is constant along the contraction. Unquantized weights
-    run the original einsum untouched. Returns f32 (caller casts)."""
-    if scale is None:
-        return jnp.einsum(eq, a, w, preferred_element_type=jnp.float32)
-    y = jnp.einsum(eq, a, w.astype(a.dtype),
-                   preferred_element_type=jnp.float32)
-    return y * scale[:, None, :]
+class Routing(NamedTuple):
+    """What a routed layer decided, for the family's `routing=True` report
+    and its load counters."""
+
+    chosen: jnp.ndarray  # [S, k] int32 — the experts of each token
+    scores: jnp.ndarray  # [S, E] f32 — the quantity whose top-k decided
+    load: jnp.ndarray  # [E] int32 — assignments per expert, padding left out
 
 
 def top_k_routing(
@@ -51,125 +63,125 @@ def top_k_routing(
     return weights, gate_idx
 
 
-def moe_dispatch_combine(
+def sigmoid_bias_routing(
+    router_logits: jnp.ndarray,  # [S, E] fp32
+    bias: jnp.ndarray,  # [E] — e_score_correction_bias
+    num_selected: int,
+    *,
+    scale: float = 1.0,
+    normalize: bool = True,
+):
+    """DeepSeek-V3's `noaux_tc` gate with one group: scores are sigmoids,
+    the k experts are the top-k of score + bias, and the weights are the
+    UNBIASED scores of those k, normalised to sum 1 (`norm_topk_prob`) and
+    scaled (`routed_scaling_factor`). Returns (weights [S, k], indices
+    [S, k], biased scores [S, E])."""
+    scores = jax.nn.sigmoid(router_logits)
+    biased = scores + bias.astype(jnp.float32)
+    _, idx = lax.top_k(biased, num_selected)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return picked * scale, idx, biased
+
+
+def _grouped_mm(rows: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
+                scale: jnp.ndarray | None, row_expert: jnp.ndarray):
+    """rows [N, K] (sorted by expert) x w [E, K, O] -> [N, O] f32, row i
+    through its own expert's matrix. Int8 weights (llmlb_tpu/quant) convert
+    on the operand read and their per-output-channel scale [E, O] applies to
+    the f32 OUTPUT, row by row — exact, the scale being constant along the
+    contraction."""
+    if scale is not None:
+        w = w.astype(rows.dtype)
+    y = lax.ragged_dot(rows, w, group_sizes,
+                       preferred_element_type=jnp.float32)
+    if scale is not None:
+        y = y * scale[row_expert]
+    return y
+
+
+def moe_routed(
     x: jnp.ndarray,  # [S, M] tokens (S = B*T)
     router_logits: jnp.ndarray,  # [S, E]
     w_gate: jnp.ndarray,  # [E, M, F] per-expert gate proj (silu branch)
     w_up: jnp.ndarray,  # [E, M, F]
     w_down: jnp.ndarray,  # [E, F, M]
     *,
-    num_selected: int,
-    capacity: int,
-    mesh: Mesh | None = None,
-    ep_axis: str = "ep",
+    route: Callable,  # router_logits f32 -> (weights, chosen[, scores])
+    layer=None,  # int32 scalar: the w_* are stacked [L, E, ...], use layer's
     token_valid: jnp.ndarray | None = None,  # [S] bool — False = padding
     w_gate_scale: jnp.ndarray | None = None,  # [E, F] int8 dequant scales
     w_up_scale: jnp.ndarray | None = None,  # [E, F]
     w_down_scale: jnp.ndarray | None = None,  # [E, M]
-) -> jnp.ndarray:
-    """SwiGLU expert MLPs with top-k dispatch. Returns [S, M].
+) -> tuple[jnp.ndarray, Routing]:
+    """SwiGLU experts mixed by `route`, every assignment computed. Returns
+    ([S, M], Routing). Padding tokens come out as zeros and count in no
+    expert's load.
 
-    Tokens beyond an expert's `capacity` are dropped (contribute zero), per
-    standard capacity-factor semantics; callers size capacity as
-    ceil(S * k / E) * capacity_factor. Pass `token_valid` for padded batches:
-    padding tokens would otherwise route like real tokens and burn expert
-    capacity (a mostly-padded bucket could evict every real token).
-    """
+    With `layer`, the expert weights (and scales) arrive STACKED over the
+    layers, [L, E, ...]. On an unpartitioned TPU the products then run in
+    ops/pallas_moe.grouped_expert_matmul, which reads the stack in place at
+    (layer, expert) and visits only the experts that hold rows; a kernel
+    takes whole buffers, so handing one `w[layer]` copies the layer's
+    experts on every call (three matrices of 128 experts: 1.2 GB a layer,
+    more than half of a decode step on the chip; PERF.md section 6, PR 31).
+    Elsewhere (the CPU, a partitioned mesh, int8 experts) the layer is
+    sliced for `jax.lax.ragged_dot`."""
     s, m = x.shape
-    e = w_gate.shape[0]
-    weights, gate_idx = top_k_routing(router_logits.astype(jnp.float32), num_selected)
+    stacked = layer is not None
+    e = w_gate.shape[1] if stacked else w_gate.shape[0]
+    logits = router_logits.astype(jnp.float32)
+    weights, chosen, *rest = route(logits)
+    scores = rest[0] if rest else logits
+    k = chosen.shape[-1]
 
-    # Position of each (token, choice) in its expert's buffer: running count of
-    # prior assignments to the same expert, priority by (choice rank, token id).
-    # one_hot: [S, k, E]
-    one_hot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)
+    # assignment j of token t is flat row t*k + j; padding sorts behind
+    # every expert (key E) and lies outside every group
+    flat_e = chosen.reshape(s * k).astype(jnp.int32)
     if token_valid is not None:
-        one_hot = one_hot * token_valid.astype(jnp.int32)[:, None, None]
-    # flatten choices k-major so choice-0 assignments beat choice-1 on capacity
-    flat = one_hot.transpose(1, 0, 2).reshape(num_selected * s, e)  # [kS, E]
-    pos_flat = jnp.cumsum(flat, axis=0) - flat  # position within expert
-    pos = pos_flat.reshape(num_selected, s, e).transpose(1, 0, 2)  # [S, k, E]
-    in_cap = (pos < capacity) & (one_hot == 1)
+        flat_e = jnp.where(jnp.repeat(token_valid, k), flat_e, e)
+    order = jnp.argsort(flat_e, stable=True)
+    row_expert = jnp.minimum(flat_e[order], e - 1)
+    load = jnp.sum(flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :],
+                   axis=0, dtype=jnp.int32)  # [E]
+    rows = x[order // k]  # [S*k, M], sorted by expert
 
-    # dispatch mask [S, E, C]: token s -> slot pos in expert e (for kept pairs)
-    slot_oh = jax.nn.one_hot(
-        jnp.where(in_cap, pos, capacity), capacity, dtype=x.dtype
-    )  # [S, k, E, C] — overflow rows one_hot to nothing (index == C)
-    dispatch = slot_oh.sum(axis=1)  # [S, E, C]
-    combine = (slot_oh * weights[:, :, None, None].astype(x.dtype)).sum(axis=1)
+    quantized = w_gate_scale is not None
+    if stacked and not quantized and _pallas_enabled():
+        from llmlb_tpu.ops import pallas_moe
 
-    expert_in = jnp.einsum(
-        "sec,sm->ecm", dispatch, x, preferred_element_type=jnp.float32
-    ).astype(x.dtype)  # [E, C, M]
-    if mesh is not None and ep_axis in mesh.axis_names:
-        expert_in = lax.with_sharding_constraint(
-            expert_in, NamedSharding(mesh, P(ep_axis, None, None))
-        )
+        tile = pallas_moe.ROW_TILE
+        pad = -(s * k) % tile
+        if pad:
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        work = pallas_moe.group_work_list(load, rows=s * k + pad, tile=tile)
 
-    # Per-expert SwiGLU, batched over the (ep-sharded) expert dim.
-    h = jax.nn.silu(
-        _expert_mm("ecm,emf->ecf", expert_in, w_gate,
-                   w_gate_scale).astype(x.dtype)
-    ) * _expert_mm("ecm,emf->ecf", expert_in, w_up,
-                   w_up_scale).astype(x.dtype)
-    expert_out = _expert_mm(
-        "ecf,efm->ecm", h, w_down, w_down_scale
-    ).astype(x.dtype)
-    if mesh is not None and ep_axis in mesh.axis_names:
-        expert_out = lax.with_sharding_constraint(
-            expert_out, NamedSharding(mesh, P(ep_axis, None, None))
-        )
+        def product(a, w, out_dtype):
+            return pallas_moe.grouped_expert_matmul(
+                a, w, layer, work, tile=tile, out_dtype=out_dtype)
 
-    out = jnp.einsum(
-        "sec,ecm->sm", combine, expert_out, preferred_element_type=jnp.float32
-    )
-    return out.astype(x.dtype)
+        h = (jax.nn.silu(product(rows, w_gate, x.dtype))
+             * product(rows, w_up, x.dtype))
+        y = product(h, w_down, jnp.float32)[:s * k]
+    else:
+        if stacked:
+            w_gate, w_up, w_down, w_gate_scale, w_up_scale, w_down_scale = (
+                None if w is None else w[layer]
+                for w in (w_gate, w_up, w_down, w_gate_scale, w_up_scale,
+                          w_down_scale))
+        h = jax.nn.silu(
+            _grouped_mm(rows, w_gate, load, w_gate_scale, row_expert)
+            .astype(x.dtype)
+        ) * _grouped_mm(rows, w_up, load, w_up_scale,
+                        row_expert).astype(x.dtype)
+        y = _grouped_mm(h, w_down, load, w_down_scale, row_expert)  # [S*k, M]
 
-
-def moe_dense_exact(
-    x: jnp.ndarray,  # [S, M]
-    router_logits: jnp.ndarray,  # [S, E]
-    w_gate: jnp.ndarray,  # [E, M, F]
-    w_up: jnp.ndarray,  # [E, M, F]
-    w_down: jnp.ndarray,  # [E, F, M]
-    *,
-    num_selected: int,
-    mesh: Mesh | None = None,
-    ep_axis: str = "ep",
-    w_gate_scale: jnp.ndarray | None = None,  # [E, F] int8 dequant scales
-    w_up_scale: jnp.ndarray | None = None,  # [E, F]
-    w_down_scale: jnp.ndarray | None = None,  # [E, M]
-) -> jnp.ndarray:
-    """Exact top-k MoE: every expert runs on every token, combine masks the
-    rest. E/k × the routed FLOPs — the right trade for *decode*, where S is a
-    small decode batch and the step is bound by streaming expert weights from
-    HBM (which dense and routed both do), not by MXU FLOPs. No tokens are ever
-    dropped, so decode logits are exactly consistent with an unbounded-capacity
-    prefill. Expert dim still shards over `ep`.
-    """
-    weights, gate_idx = top_k_routing(router_logits.astype(jnp.float32), num_selected)
-    e = w_gate.shape[0]
-    # [S, E] combine weights (zero for unselected experts)
-    combine = (jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
-               * weights[..., None]).sum(axis=1)
-
-    h = jax.nn.silu(
-        _expert_mm("sm,emf->esf", x, w_gate, w_gate_scale).astype(x.dtype)
-    ) * _expert_mm("sm,emf->esf", x, w_up, w_up_scale).astype(x.dtype)
-    expert_out = _expert_mm(
-        "esf,efm->esm", h, w_down, w_down_scale
-    )  # [E, S, M] fp32
-    if mesh is not None and ep_axis in mesh.axis_names:
-        expert_out = lax.with_sharding_constraint(
-            expert_out, NamedSharding(mesh, P(ep_axis, None, None))
-        )
-    out = jnp.einsum("se,esm->sm", combine, expert_out)
-    return out.astype(x.dtype)
-
-
-def default_capacity(tokens: int, num_experts: int, num_selected: int,
-                     capacity_factor: float = 1.25) -> int:
-    """GShard-style capacity: factor × even-split load, floor 4, MXU-friendly
-    multiple of 4."""
-    cap = int(tokens * num_selected / num_experts * capacity_factor)
-    return max(4, (cap + 3) // 4 * 4)
+    # back to (token, choice) order: a gather by the inverse permutation
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(s * k, dtype=order.dtype))
+    y = y[inverse].reshape(s, k, m)
+    out = jnp.sum(y * weights[..., None], axis=1)
+    if token_valid is not None:
+        out = jnp.where(token_valid[:, None], out, 0.0)
+    return out.astype(x.dtype), Routing(chosen, scores, load)

@@ -376,6 +376,138 @@ def paged_flash_decode_quant(
 
 
 # ---------------------------------------------------------------------------
+# Latent (MLA) paged decode: 32 query heads on ONE shared latent per token.
+# The pool is two arrays under the same page ids, c [L, P, PS, C] and
+# k_rope [L, P, PS, R]; the latent is key and value at once. R is a whole
+# 128-lane tile (the 64 rope numbers, then zeros): Mosaic wants its operands
+# tiled (8, 128), and a [.., 64] pool was copied whole into that layout at
+# every call (201 MB at 768 pages: the compile's temp bytes said so).
+# ---------------------------------------------------------------------------
+
+
+def _latent_page_map(i, layer, row_of, page_of, pool_page_of, lens):
+    """Latent pools [L, P, PS, .]: the item's pool page, of the layer."""
+    return (layer[0], pool_page_of[i], 0, 0)
+
+
+def _latent_row_map(i, layer, row_of, page_of, pool_page_of, lens):
+    """Queries and output [B, H, .]: the item's row."""
+    return (row_of[i], 0, 0)
+
+
+def _paged_latent_decode_kernel(
+    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
+    qc_ref,  # [1, H, C] — queries carried into the latent space
+    qr_ref,  # [1, H, R] — rotated rope part of the queries
+    c_ref,  # [1, PS, C]
+    r_ref,  # [1, PS, R]
+    o_ref,  # [1, H, C]
+    m_ref,  # [H, 1] f32
+    l_ref,  # [H, 1] f32
+    acc_ref,  # [H, C] f32
+    *, block_k: int, sweep: int, scale: float,
+):
+    """One grid step: item i of the work-list is page `s` of row `row`
+    (_decode_item's contract: online softmax across a row's items, a row of
+    length 0 one item written as zeros). Every head attends over the same
+    [PS, C] latent tile: scores are two products (latent part, rope part),
+    the values are the tile itself."""
+    del layer_ref, pool_page_of_ref
+    i = pl.program_id(0)
+    s = page_of_ref[i]
+    kv_len = kv_lens_ref[row_of_ref[i]]
+    last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+
+    @pl.when(s == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(s * block_k < kv_len)
+    def _compute():
+        col = s * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), dimension=1)
+        c = c_ref[0]  # [PS, C]
+        nt = (((1,), (1,)), ((), ()))
+        scores = (
+            jax.lax.dot_general(qc_ref[0], c, nt,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(qr_ref[0], r_ref[0], nt,
+                                  preferred_element_type=jnp.float32)
+        ) * scale  # [H, PS]
+        scores = jnp.where(col < kv_len, scores, _NEG_INF)
+        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, c)
+
+    @pl.when(s == last)
+    def _finalize():
+        l = l_ref[:]
+        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+def paged_latent_decode(
+    q_abs: jnp.ndarray,  # [B, H, C]
+    q_rope: jnp.ndarray,  # [B, H, R]
+    c_pages: jnp.ndarray,  # [L, P, PS, C] — latent pool, all layers
+    r_pages: jnp.ndarray,  # [L, P, PS, R] — shared rotated keys
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
+    *,
+    scale: float,
+    pages: int | None = None,
+    work: DecodeWork | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Ragged PAGED one-token ABSORBED latent attention. Returns the mix of
+    latents [B, H, C]. Grid, `work`, `layer`, `pages` and the rows that are
+    not live: paged_flash_decode's contract word for word — the work-list of
+    live (row, page) pairs, the stacked pool read in place at (layer, page).
+    A page is fetched once for all H heads."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, h, c_dim = q_abs.shape
+    ps = c_pages.shape[2]
+    if work is None:
+        work = decode_work_list(block_tables, kv_lens, page_size=ps,
+                                pages=pages)
+
+    def row_spec(width):
+        return pl.BlockSpec((1, h, width), _latent_row_map,
+                            memory_space=pltpu.VMEM)
+
+    def pool_spec(width):
+        return pl.BlockSpec((None, 1, ps, width), _latent_page_map,
+                            memory_space=pltpu.VMEM)
+
+    r_dim = q_rope.shape[-1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(work.count,),
+        in_specs=[row_spec(c_dim), row_spec(r_dim), pool_spec(c_dim),
+                  pool_spec(r_dim)],
+        out_specs=row_spec(c_dim),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, c_dim), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_latent_decode_kernel, block_k=ps,
+                          sweep=_swept_pages(block_tables, pages),
+                          scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, h, c_dim), q_abs.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="paged_latent_decode",
+    )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
+      kv_lens.astype(jnp.int32), q_abs, q_rope, c_pages, r_pages)
+
+
+# ---------------------------------------------------------------------------
 # Prefill: causal q [B, T, H, D] vs fresh k/v [B, T, K, D], ragged prompt_lens
 # ---------------------------------------------------------------------------
 

@@ -51,12 +51,20 @@ def apply_rope(
     x: jnp.ndarray,  # [B, T, H, D]
     positions: jnp.ndarray,  # [B, T] int32
     inv_freq: jnp.ndarray,  # [D // 2]
+    interleaved: bool = False,
 ) -> jnp.ndarray:
-    """Rotate q or k by position. Split-half (rotate_half) layout, as HF Llama."""
+    """Rotate q or k by position. Split-half (rotate_half) layout, as HF
+    Llama: pair i is (x[i], x[i + D/2]). `interleaved` (DeepSeek-V3's
+    `rope_interleave`): pair i is (x[2i], x[2i+1]), rotated in place."""
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, T, D/2]
     cos = jnp.cos(angles)[:, :, None, :]  # [B, T, 1, D/2]
     sin = jnp.sin(angles)[:, :, None, :]
     xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        rotated = jnp.stack((x1 * cos - x2 * sin, x2 * cos + x1 * sin),
+                            axis=-1).reshape(x.shape)
+        return rotated.astype(x.dtype)
     x1, x2 = jnp.split(xf, 2, axis=-1)
     rotated = jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin), axis=-1)
     return rotated.astype(x.dtype)

@@ -64,6 +64,7 @@ def engine_metric_names() -> set[str]:
             "pages_free": 0, "pages_active": 0, "pages_pinned": 0,
             "utilization": 0.0, "fragmentation": 0.0,
             "waste_tokens_mean": 0.0, "bytes_per_page": 0, "hbm_bytes": 0,
+            "bytes_per_token": 0,
             "kv_dtype": "int8",
         },
         perf={

@@ -19,7 +19,12 @@ from llmlb_tpu.models import family_for, llama
 FAMILIES = {
     "llama": family_for(get_preset("debug-tiny")),
     "mixtral": family_for(get_preset("debug-moe-tiny")),
+    "deepseek_v3": family_for(get_preset("debug-mla-tiny")),
 }
+# Static switches a family may add BEHIND llama's parameters, keyword-only
+# in effect: the benchmark's check passes `routing` (models/deepseek_v3.py),
+# never positionally.
+EXTRA = {"deepseek_v3": ["routing"]}
 
 CONTRACT = (
     "init_params",
@@ -57,6 +62,25 @@ def test_family_provides_the_paged_contract(family, name):
     if not hasattr(module, name):
         assert name in OPTIONAL, f"{family} lacks {name}"
         return
-    assert _params(getattr(module, name)) == _params(getattr(llama, name)), (
+    want = _params(getattr(llama, name))
+    got = _params(getattr(module, name))
+    paged = name in ("prefill_into_pages", "prefill_extend_pages",
+                     "verify_step_paged", "decode_step_paged")
+    extra = EXTRA.get(family, []) if paged else []
+    assert got[:len(want)] == want and [n for n, _ in got[len(want):]] == extra, (
         f"{family}.{name} takes other parameters than llama.{name}"
     )
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_family_says_what_a_token_leaves_in_the_pool(family):
+    """The scheduler's page bytes, gauges and KVSH header ask the family."""
+    module = FAMILIES[family]
+    cfg = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
+           "deepseek_v3": "debug-mla-tiny"}[family]
+    cfg = get_preset(cfg)
+    ck, cv = module.init_kv_pages(cfg, 3, 8)
+    per_token = (ck[0, 0, 0].size + cv[0, 0, 0].size) * ck.dtype.itemsize
+    assert module.kv_token_layer_bytes(cfg) == per_token
+    cell = module.kv_wire_cell(cfg)
+    assert cell is None or cell == ck.shape[-2:] == cv.shape[-2:]

@@ -1,120 +1,148 @@
-"""MoE dispatch/combine + Mixtral model: correctness vs a dense per-token
-reference, capacity-drop semantics, and expert-parallel sharding equivalence
-on the virtual 8-device CPU mesh (SURVEY.md §4 strategy)."""
+"""The routed expert layer (ops/moe.py) + Mixtral model: correctness vs an
+every-expert-on-every-token sum under both routing rules, skewed routing,
+padding, expert-parallel sharding equivalence on the virtual 8-device CPU
+mesh (SURVEY.md §4 strategy)."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from llmlb_tpu.ops.moe import default_capacity, moe_dispatch_combine, top_k_routing
+from llmlb_tpu.ops.moe import moe_routed, sigmoid_bias_routing, top_k_routing
 from llmlb_tpu.parallel.mesh import MeshConfig, build_mesh
 from tests.support import identity_kv_pages
 
 
-def _dense_reference(x, logits, wg, wu, wd, k):
-    """Per-token loop: exact top-k MoE with no capacity limit."""
-    s, m = x.shape
-    weights, idx = top_k_routing(jnp.asarray(logits, jnp.float32), k)
+def _softmax_rule(k):
+    return lambda logits: top_k_routing(logits, k)
+
+
+def _sigmoid_rule(k, bias, scale=2.448):
+    return lambda logits: sigmoid_bias_routing(logits, bias, k, scale=scale)
+
+
+def _every_expert_reference(x, logits, wg, wu, wd, route):
+    """Every expert on every token, summed by the rule's weights: no
+    sorting, no groups, no capacity."""
+    weights, idx, *_ = route(jnp.asarray(logits, jnp.float32))
     weights, idx = np.asarray(weights), np.asarray(idx)
-    x, wg, wu, wd = map(np.asarray, (x, wg, wu, wd))
+    x, wg, wu, wd = (np.asarray(a, np.float64) for a in (x, wg, wu, wd))
     out = np.zeros_like(x)
-    for t in range(s):
-        for j in range(k):
-            e = idx[t, j]
-            h = x[t] @ wg[e]
-            h = (h / (1 + np.exp(-h))) * (x[t] @ wu[e])
-            out[t] += weights[t, j] * (h @ wd[e])
+    for e in range(wg.shape[0]):
+        h = x @ wg[e]
+        y = ((h / (1 + np.exp(-h))) * (x @ wu[e])) @ wd[e]  # [S, M]
+        out += np.where(idx == e, weights, 0.0).sum(-1)[:, None] * y
     return out
 
 
 def _rand_moe(key, s, m, f, e):
-    ks = jax.random.split(key, 5)
+    ks = jax.random.split(key, 6)
     x = jax.random.normal(ks[0], (s, m), jnp.float32)
     logits = jax.random.normal(ks[1], (s, e), jnp.float32)
     wg = jax.random.normal(ks[2], (e, m, f), jnp.float32) * m**-0.5
     wu = jax.random.normal(ks[3], (e, m, f), jnp.float32) * m**-0.5
     wd = jax.random.normal(ks[4], (e, f, m), jnp.float32) * f**-0.5
-    return x, logits, wg, wu, wd
+    bias = jax.random.normal(ks[5], (e,), jnp.float32) * 0.3
+    return x, logits, wg, wu, wd, bias
 
 
-@pytest.mark.parametrize("k", [1, 2])
-def test_moe_matches_dense_reference(k):
-    s, m, f, e = 32, 16, 24, 4
-    x, logits, wg, wu, wd = _rand_moe(jax.random.PRNGKey(0), s, m, f, e)
-    # capacity = s: no token can overflow even if routing is maximally skewed
-    got = moe_dispatch_combine(x, logits, wg, wu, wd, num_selected=k, capacity=s)
-    want = _dense_reference(x, logits, wg, wu, wd, k)
+RULES = {
+    "softmax_k1": lambda bias: _softmax_rule(1),
+    "softmax_k2": lambda bias: _softmax_rule(2),
+    "sigmoid_bias_k3": lambda bias: _sigmoid_rule(3, bias),
+}
+
+
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_routed_matches_every_expert_sum(rule):
+    s, m, f, e = 32, 16, 24, 8
+    x, logits, wg, wu, wd, bias = _rand_moe(jax.random.PRNGKey(0), s, m, f, e)
+    route = RULES[rule](bias)
+    got, routing = moe_routed(x, logits, wg, wu, wd, route=route)
+    want = _every_expert_reference(x, logits, wg, wu, wd, route)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    k = routing.chosen.shape[-1]
+    assert int(routing.load.sum()) == s * k  # every assignment, none dropped
 
 
-def test_moe_capacity_drops_tokens_not_crashes():
-    s, m, f, e = 64, 8, 12, 2
-    x, logits, wg, wu, wd = _rand_moe(jax.random.PRNGKey(1), s, m, f, e)
-    got = moe_dispatch_combine(x, logits, wg, wu, wd, num_selected=2, capacity=4)
-    assert np.isfinite(np.asarray(got)).all()
-    # with tiny capacity most tokens must be dropped → output mostly zeros
-    dropped = (np.abs(np.asarray(got)).sum(-1) == 0).sum()
-    assert dropped > 0
+@pytest.mark.parametrize("rule", ["softmax_k2", "sigmoid_bias_k3"])
+def test_routed_skewed_to_one_expert_drops_nothing(rule):
+    """All tokens' first choice is expert 2: a capacity dispatch would drop
+    most of them, the grouped products take them all."""
+    s, m, f, e = 48, 8, 12, 4
+    x, logits, wg, wu, wd, bias = _rand_moe(jax.random.PRNGKey(1), s, m, f, e)
+    logits = logits.at[:, 2].set(9.0)
+    route = RULES[rule](bias * 0)
+    got, routing = moe_routed(x, logits, wg, wu, wd, route=route)
+    want = _every_expert_reference(x, logits, wg, wu, wd, route)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    assert int(routing.load[2]) == s
+
+
+@pytest.mark.parametrize("rule", ["softmax_k2", "sigmoid_bias_k3"])
+def test_routed_padding_is_zero_and_outside_the_load(rule):
+    """Padding tokens belong to no expert's group: the real tokens' outputs
+    are what they are alone, padding rows come out as zeros and no expert's
+    load counts them."""
+    s_real, pad, m, f, e = 8, 56, 8, 12, 4
+    x, logits, wg, wu, wd, bias = _rand_moe(jax.random.PRNGKey(8), s_real, m,
+                                            f, e)
+    route = RULES[rule](bias)
+    alone, routing_alone = moe_routed(x, logits, wg, wu, wd, route=route)
+    x_pad = jnp.concatenate([x, jnp.ones((pad, m), jnp.float32)])
+    logits_pad = jnp.concatenate(
+        [logits, jnp.full((pad, e), 5.0, jnp.float32)])
+    valid = jnp.arange(s_real + pad) < s_real
+    padded, routing = moe_routed(x_pad, logits_pad, wg, wu, wd, route=route,
+                                 token_valid=valid)
+    np.testing.assert_allclose(
+        np.asarray(padded[:s_real]), np.asarray(alone), rtol=1e-5, atol=1e-5)
+    assert np.abs(np.asarray(padded[s_real:])).max() == 0.0
+    np.testing.assert_array_equal(np.asarray(routing.load),
+                                  np.asarray(routing_alone.load))
+
+
+def test_sigmoid_rule_chooses_by_bias_and_weighs_without_it():
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]], jnp.float32)
+    bias = jnp.asarray([-5.0, 0.0, 0.0, 5.0], jnp.float32)
+    w, idx, biased = sigmoid_bias_routing(logits, bias, 2, scale=2.0)
+    s = np.asarray(jax.nn.sigmoid(logits))[0]
+    assert sorted(np.asarray(idx)[0].tolist()) == [1, 3]  # 0 is biased out
+    picked = s[np.asarray(idx)[0]]
+    np.testing.assert_allclose(np.asarray(w)[0], picked / picked.sum() * 2.0,
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(biased)[0], s + np.asarray(bias),
+                               rtol=1e-6)
+
+
+def test_routed_int8_scales_apply_per_row_expert():
+    s, m, f, e = 16, 8, 12, 4
+    x, logits, wg, wu, wd, _ = _rand_moe(jax.random.PRNGKey(3), s, m, f, e)
+    route = _softmax_rule(2)
+    ones = {"w_gate_scale": jnp.full((e, f), 2.0), "w_up_scale":
+            jnp.full((e, f), 0.5), "w_down_scale": jnp.full((e, m), 3.0)}
+    plain, _ = moe_routed(x, logits, wg * 2.0, wu * 0.5, wd * 3.0, route=route)
+    scaled, _ = moe_routed(x, logits, wg, wu, wd, route=route, **ones)
+    np.testing.assert_allclose(np.asarray(scaled), np.asarray(plain),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_moe_ep_sharded_matches_unsharded(cpu_mesh_devices):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     mesh = build_mesh(MeshConfig(dp=1, sp=1, ep=4, tp=2), devices=cpu_mesh_devices)
     s, m, f, e = 32, 16, 24, 4
-    x, logits, wg, wu, wd = _rand_moe(jax.random.PRNGKey(2), s, m, f, e)
-    plain = moe_dispatch_combine(x, logits, wg, wu, wd, num_selected=2, capacity=s)
-    sharded = jax.jit(
-        lambda *a: moe_dispatch_combine(
-            *a, num_selected=2, capacity=s, mesh=mesh
-        )
-    )(x, logits, wg, wu, wd)
+    x, logits, wg, wu, wd, _ = _rand_moe(jax.random.PRNGKey(2), s, m, f, e)
+    fn = functools.partial(moe_routed, route=_softmax_rule(2))
+    plain, _ = fn(x, logits, wg, wu, wd)
+    ep = NamedSharding(mesh, P("ep", None, None))
+    sharded, _ = jax.jit(fn)(x, logits, *(jax.device_put(w, ep)
+                                          for w in (wg, wu, wd)))
     np.testing.assert_allclose(
         np.asarray(sharded), np.asarray(plain), rtol=1e-5, atol=1e-5
     )
-
-
-def test_token_valid_keeps_padding_out_of_capacity():
-    """Padding tokens must not consume expert capacity: real tokens' outputs
-    with a mostly-padded batch == the same tokens alone at the same capacity."""
-    s_real, pad, m, f, e = 8, 56, 8, 12, 2
-    x, logits, wg, wu, wd = _rand_moe(jax.random.PRNGKey(8), s_real, m, f, e)
-    cap = 8  # tight: 56 identical pad tokens would saturate both experts
-
-    alone = moe_dispatch_combine(
-        x, logits, wg, wu, wd, num_selected=2, capacity=cap
-    )
-
-    x_pad = jnp.concatenate([x, jnp.ones((pad, m), jnp.float32)])
-    logits_pad = jnp.concatenate(
-        [logits, jnp.full((pad, e), 5.0, jnp.float32)]
-    )
-    valid = jnp.arange(s_real + pad) < s_real
-    padded = moe_dispatch_combine(
-        x_pad, logits_pad, wg, wu, wd, num_selected=2, capacity=cap,
-        token_valid=valid,
-    )
-    np.testing.assert_allclose(
-        np.asarray(padded[:s_real]), np.asarray(alone), rtol=1e-5, atol=1e-5
-    )
-    # and the padding rows contribute nothing
-    assert np.abs(np.asarray(padded[s_real:])).max() == 0.0
-
-
-def test_dense_exact_matches_dispatch_at_full_capacity():
-    s, m, f, e = 48, 16, 24, 4
-    x, logits, wg, wu, wd = _rand_moe(jax.random.PRNGKey(7), s, m, f, e)
-    from llmlb_tpu.ops.moe import moe_dense_exact
-
-    dispatch = moe_dispatch_combine(x, logits, wg, wu, wd, num_selected=2, capacity=s)
-    dense = moe_dense_exact(x, logits, wg, wu, wd, num_selected=2)
-    np.testing.assert_allclose(
-        np.asarray(dense), np.asarray(dispatch), rtol=1e-4, atol=1e-4
-    )
-
-
-def test_default_capacity():
-    assert default_capacity(256, 8, 2) == 80  # 256*2/8*1.25
-    assert default_capacity(4, 8, 1) >= 4
 
 
 def test_mixtral_prefill_decode_consistency():

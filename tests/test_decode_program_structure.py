@@ -82,7 +82,16 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
                      for v in eqn.outvars)]
     assert not sliced, f"a per-layer slice of the pool is back: {sliced}"
 
-    kernels = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"]
+    calls = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"]
+    # a mixture's grouped expert products are kernels too, each handed the
+    # experts of every layer: [L, X, K, O], never one layer's slice
+    experts = [eqn for eqn in calls if "grouped_expert_matmul" in str(
+        eqn.params.get("name_and_src_info", eqn.params.get("name")))]
+    assert len(experts) == (3 * LAYERS if name == "mixtral" else 0)
+    for eqn in experts:
+        assert [v.aval.shape[0] for v in eqn.invars
+                if len(v.aval.shape) == 4] == [LAYERS]
+    kernels = [eqn for eqn in calls if not any(eqn is e for e in experts)]
     assert len(kernels) == LAYERS
     q_rows = (ROWS, KV_HEADS, 2, HEAD_DIM)  # [B, K, G, D]
     for eqn in kernels:
@@ -175,5 +184,83 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
     assert results
     bad = [(shape, op) for shape, op in results
            if re.match(layer_values, shape)
+           or (op == "copy" and re.match(whole_pool, shape))]
+    assert not bad, bad
+
+
+# --- the latent mixture's decode burst, as the chip's compiler leaves it -----
+
+LATENT_PAGES, LATENT_ROWS = 768, 64
+
+
+def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
+                                                                 monkeypatch):
+    """Two decode steps of a latent-attention mixture (kanana-2-30b-a3b's
+    widths, one dense and one expert layer) under a scan, compiled for a
+    v5e: per step and layer one latent attention kernel, per expert layer
+    three grouped matmuls; the compiler materializes no layer of either
+    pool and no layer's experts, and copies no pool whole. (With a rope pool
+    64 lanes wide it copied all of it, 201 MB, into a tiled layout at every
+    kernel call; with `w[layer]` handed to the grouped product it copied
+    three `bf16[128,2048,768]` a layer, more than half a decode step:
+    PERF.md section 6, PR 31.)"""
+    from llmlb_tpu.models import deepseek_v3
+    from llmlb_tpu.ops import pallas_moe
+
+    cfg = deepseek_v3.DeepseekV3Config(
+        vocab_size=128256, hidden_size=2048, intermediate_size=6144,
+        num_layers=2, num_heads=32, num_kv_heads=32, head_dim=64,
+        rope_theta=1e6, rms_eps=1e-6, max_position_embeddings=32768)
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
+    jitted = (deepseek_v3.decode_step_paged,
+              pallas_attention.paged_latent_decode,
+              pallas_moe.grouped_expert_matmul)
+    for fn in jitted:
+        fn._clear_cache()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: deepseek_v3.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache_k, cache_v = on_chip(jax.eval_shape(
+        lambda: deepseek_v3.init_kv_pages(cfg, LATENT_PAGES, CHIP_PAGE_SIZE)))
+    rows = on_chip(jax.ShapeDtypeStruct((LATENT_ROWS,), jnp.int32))
+    live = on_chip(jax.ShapeDtypeStruct((LATENT_ROWS,), jnp.bool_))
+    tables = on_chip(jax.ShapeDtypeStruct((LATENT_ROWS, CHIP_TABLE), jnp.int32))
+
+    def burst(params, last, lens, cache_k, cache_v, tables, live):
+        def body(carry, _):
+            last, lens, ck, cv = carry
+            logits, ck, cv, counters = deepseek_v3.decode_step_paged(
+                params, cfg, last, lens, ck, cv, tables, window=2048,
+                live=live)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    ck, cv), counters
+
+        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
+                            length=2)
+
+    try:
+        with jax.default_matmul_precision("default"):
+            hlo = jax.jit(burst, donate_argnums=(3, 4)).lower(
+                params, rows, rows, cache_k, cache_v, tables, live
+            ).compile().as_text()
+    finally:
+        for fn in jitted:
+            fn._clear_cache()
+
+    # two attention kernels (a layer each) and the expert layer's three products
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 + 3
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    a_layer = r"bf16\[768,128,(512|128)\]"  # of either pool
+    a_layers_experts = r"bf16\[128,(2048,768|768,2048)\]"
+    whole_pool = r"bf16\[2,768,128,(512|128)\]"
+    bad = [(shape, op) for shape, op in results
+           if re.match(a_layer, shape) or re.match(a_layers_experts, shape)
            or (op == "copy" and re.match(whole_pool, shape))]
     assert not bad, bad
