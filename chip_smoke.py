@@ -769,6 +769,14 @@ def kernel_leg() -> int:
                  {"q": jnp.asarray(vq), "s": jnp.asarray(vs)})
         return k_pages, v_pages, quant, tables
 
+    def stacked(x, layer):
+        """`x` as layer `layer` of a pool stacked over two layers, the other
+        all zeros: the paged kernels take the stacked pool and read it at
+        (layer, page); an int8 pool's scales go in as the layer's slice."""
+        layers = [jnp.zeros_like(x), jnp.zeros_like(x)]
+        layers[layer] = x
+        return jnp.stack(layers)
+
     results: dict[str, dict] = {}
 
     def outcome(kernel: str) -> dict:
@@ -807,16 +815,11 @@ def kernel_leg() -> int:
         q = rand(b, 1, H, D)
 
         def paged_decode(pages, quant, dead=False):
-            # the decode kernels take the pool stacked over layers and read
-            # it at (layer, page): layer 1 of 2 here, layer 0 all zeros (an
-            # int8 pool's scales go in as that layer's slice). `dead`: two
-            # rows in three are not live (length 0) and must come out zero
-            def stacked(x):
-                return jnp.stack([jnp.zeros_like(x), x])
-
+            # layer 1 of the stacked pool. `dead`: two rows in three are
+            # not live (length 0) and must come out zero
             k_pages, v_pages, quant_pools, tables = pool(b)
-            k_pages, v_pages = stacked(k_pages), stacked(v_pages)
-            qk, qv = ({m: stacked(x) for m, x in qp.items()}
+            k_pages, v_pages = stacked(k_pages, 1), stacked(v_pages, 1)
+            qk, qv = ({m: stacked(x, 1) for m, x in qp.items()}
                       for qp in quant_pools)
             lens = jnp.asarray(rng.integers(1, pages * PS + 1, b), jnp.int32)
             if dead:
@@ -863,27 +866,33 @@ def kernel_leg() -> int:
         positions = starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
         q = rand(b, t, H, D)
 
-        def paged_extend(quant):
-            k_pages, v_pages, (qk, qv), tables = pool(b)
+        def paged_extend(quant, layer):
+            k_pages, v_pages, quant_pools, tables = pool(b)
+            k_pages, v_pages = (stacked(k_pages, layer),
+                                stacked(v_pages, layer))
+            qk, qv = ({m: stacked(x, layer) for m, x in qp.items()}
+                      for qp in quant_pools)
             if quant:
-                want = xla.paged_attention_extend(q, qk, qv, tables,
+                want = xla.paged_attention_extend(q, qk, qv, layer, tables,
                                                   positions, chunk)
                 got = pa.paged_flash_extend_quant(
-                    q, qk["q"], qk["s"], qv["q"], qv["s"], tables, starts,
-                    chunk, interpret=False)
+                    q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer],
+                    layer, tables, starts, chunk, interpret=False)
             else:
-                want = xla.paged_attention_extend(q, k_pages, v_pages,
+                want = xla.paged_attention_extend(q, k_pages, v_pages, layer,
                                                   tables, positions, chunk)
-                got = pa.paged_flash_extend(q, k_pages, v_pages, tables,
-                                            starts, chunk, interpret=False)
+                got = pa.paged_flash_extend(q, k_pages, v_pages, layer,
+                                            tables, starts, chunk,
+                                            interpret=False)
             check("paged_flash_extend_quant" if quant else
-                  "paged_flash_extend", f"B={b},T={t}", got, want,
-                  valid=chunk)
+                  "paged_flash_extend", f"B={b},T={t},layer={layer}", got,
+                  want, valid=chunk)
 
         for quant in (False, True):
-            attempt("paged_flash_extend_quant" if quant else
-                    "paged_flash_extend", f"B={b},T={t}",
-                    lambda: paged_extend(quant))
+            for layer in (0, 1):
+                attempt("paged_flash_extend_quant" if quant else
+                        "paged_flash_extend", f"B={b},T={t},layer={layer}",
+                        lambda: paged_extend(quant, layer))
 
     # LoRA bgmv: decode rows, a prefill chunk, the verify width; through
     # TinyLlama's projections (hidden 2048, kv 256, mlp 5632) at rank 16

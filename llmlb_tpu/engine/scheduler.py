@@ -808,7 +808,7 @@ class EngineCore:
         # dispatch (lax.scan with on-device token feedback) per host readback.
         # The per-step host sync is pure latency — tokens/sec scales with k
         # while the host↔device round trip dominates the step. Auto: 8 on
-        # TPU (not re-derived for a local chip: ROADMAP Speed 4), 1 elsewhere
+        # TPU (not re-derived for a local chip: ROADMAP Speed 3), 1 elsewhere
         # (CPU tests keep single-step token-for-token goldens). Emission
         # becomes k-token bursts; EOS/max_tokens mid-burst are trimmed
         # host-side.
@@ -1079,7 +1079,7 @@ class EngineCore:
             # Params and caches carry theirs. The per-slot vectors and the
             # key are lowered unspecified below, while a dispatch hands them
             # over placed on the mesh (_on_mesh): the two still land under
-            # different keys (ROADMAP Speed 5).
+            # different keys (ROADMAP Speed 4).
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
         def plain(x):

@@ -365,10 +365,11 @@ def _attention(cfg: DeepseekV3Config) -> Attention:
             jnp.concatenate([up(q.wk_b), shared], axis=-1),
             up(q.wv_b), prompt_lens)
 
-    def extend(q: LatentQuery, c_pool, r_pool, tables, positions, chunk_lens):
+    def extend(q: LatentQuery, c_pool, r_pool, layer, tables, positions,
+               chunk_lens):
         del chunk_lens  # padding queries attend like real ones; discarded
         return carry_out(paged_latent_extend(
-            absorb(q), q.rope, c_pool, r_pool, tables, positions,
+            absorb(q), q.rope, c_pool, r_pool, layer, tables, positions,
             scale=_scale(cfg)), q)
 
     def decode(q: LatentQuery, c_pool, r_pool, layer, tables, kv_lens, *,

@@ -294,37 +294,17 @@ def kv_pool_values(pool):
     return pool["q"] if isinstance(pool, dict) else pool
 
 
-def _write_pool(pool, page, off, kv):
-    """Scatter K/V rows into pool cells [page, off] (leading layer axis
-    already sliced away). Quantized pools take the int8 values plus the
-    per-vector scales at the same indices — quantize-on-write."""
+def _write_pool(pool, layer, page, off, kv):
+    """Scatter K/V rows into cells [layer, page, off] of the stacked pool,
+    in place: `layer` is a static index (decode's unrolled loop) or a
+    run-time scalar (the layer scan of prefill and extend), `page` and
+    `off` arrays of the rows' shape. Quantized pools take the int8 values
+    plus the per-vector scales at the same indices — quantize-on-write."""
     if isinstance(pool, dict):
         q, s = quantize_kv(kv)
-        return {"q": pool["q"].at[page, off].set(q),
-                "s": pool["s"].at[page, off].set(s)}
-    return pool.at[page, off].set(kv.astype(pool.dtype))
-
-
-def _write_pool_layer(pool, layer_idx, page, off, kv):
-    """Decode-path scatter at a static layer index of the full pool."""
-    if isinstance(pool, dict):
-        q, s = quantize_kv(kv)
-        return {"q": pool["q"].at[layer_idx, page, off].set(q),
-                "s": pool["s"].at[layer_idx, page, off].set(s)}
-    return pool.at[layer_idx, page, off].set(kv.astype(pool.dtype))
-
-
-def make_write_kv_pages(block_tables: jnp.ndarray, page_size: int):
-    """KV write that scatters token rows through the block table into the
-    global page pool. `positions` are logical per-row positions; page
-    block_tables[b, p//PS], offset p%PS is the physical cell."""
-
-    def write_kv(pool, kv, positions):
-        page = jnp.take_along_axis(block_tables, positions // page_size,
-                                   axis=1)  # [B, T]
-        return _write_pool(pool, page, positions % page_size, kv)
-
-    return write_kv
+        return {"q": pool["q"].at[layer, page, off].set(q),
+                "s": pool["s"].at[layer, page, off].set(s)}
+    return pool.at[layer, page, off].set(kv.astype(pool.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -453,8 +433,9 @@ class Attention(NamedTuple):
     block(cfg, lp, x, positions, inv_freq, attn_fn, lora_idx)
         -> (x + attention, k_cached, v_cached)
     prefill(q, k, v, prompt_lens)                       fresh prompt, no pool
-    extend(q, pool_k, pool_v, tables, positions, chunk_lens)   one layer's pool
+    extend(q, pool_k, pool_v, layer, tables, positions, chunk_lens)
     decode(q, pool_k, pool_v, layer, tables, kv_lens, window=, work=)
+        both: the stacked pool and the layer to attend over, never a slice
     decode_work(pool_k, tables, kv_lens, window)        the decode kernel's grid
     """
 
@@ -472,7 +453,8 @@ class LayerGroup(NamedTuple):
     is a list
     of groups in layer order (Llama and Mixtral: one; a model with leading
     dense layers before its expert layers: two). Prefill and extend scan
-    each group, decode unrolls them all."""
+    each group's parameters with the page pool as the carry (_scan_groups),
+    decode unrolls them all."""
 
     names: tuple
     mlp_fn: Callable
@@ -519,45 +501,35 @@ def _mlp_out(res):
     return res if isinstance(res, tuple) else (res, None)
 
 
-def _group_pools(pools, start: int, count: int):
-    """The `count` layers of the stacked pools from `start`: the pools
-    themselves where the group is the whole model."""
-    def part(pool):
-        if count == pool.shape[0]:
-            return pool
-        return lax.slice_in_dim(pool, start, start + count, axis=0)
-
-    return jax.tree.map(part, pools)
-
-
-def _join_pools(parts):
-    if len(parts) == 1:
-        return parts[0]
-    return jax.tree.map(lambda *p: jnp.concatenate(p, axis=0), *parts)
-
-
 def _scan_groups(params, groups, x, cache_k, cache_v, layer_of):
-    """Prefill's and extend's walk over the stack: each group scanned over
-    its own stacked parameters and its own layers of the two pools.
-    `layer_of(group)` gives the scan body `(x, (lp, ck, cv)) -> (x, (ck, cv,
-    aux))`. Returns (x, cache_k, cache_v, aux per group)."""
-    done_k, done_v, aux, start = [], [], [], 0
+    """Prefill's and extend's walk over the stack: each group a `lax.scan`
+    over its own stacked parameters (one layer body traced and compiled a
+    group), with `(x, cache_k, cache_v)` as the CARRY. The pools are never
+    a scan's `xs` or `ys` and never sliced by layer: `ys` is a fresh stacked
+    buffer, so a pool that went through it was copied whole and each layer
+    of it sliced out and written back, on every call, whatever the prompt's
+    length (25 ms of a 45 ms prefill step: PERF.md section 6, PR 32). A
+    layer writes its cells at `pool.at[layer, page, off]` and an extend
+    kernel reads the pool at (layer, page), so the only pool-sized value in
+    the program is the donated pool itself, as in decode. A second group
+    goes on with the same carry. `layer_of(group)` gives the layer `(carry,
+    lp, layer) -> (carry, aux)`, `layer` the index in the whole stack.
+    Returns (x, cache_k, cache_v, aux per group)."""
+    carry, aux, start = (x, cache_k, cache_v), [], 0
     for group in groups:
         stacked, whole = _group_params(params, group)
         body = layer_of(group)
 
-        def layer(carry_x, layer_in, body=body, whole=whole):
-            lp, ck, cv = layer_in
-            return body(carry_x, ({**lp, **whole}, ck, cv))
+        def layer(carry, layer_in, body=body, whole=whole):
+            lp, idx = layer_in
+            return body(carry, {**lp, **whole}, idx)
 
-        x, (ck, cv, group_aux) = lax.scan(
-            layer, x, (stacked, *_group_pools((cache_k, cache_v), start,
-                                              group.count)))
-        done_k.append(ck)
-        done_v.append(cv)
+        carry, group_aux = lax.scan(
+            layer, carry,
+            (stacked, start + jnp.arange(group.count, dtype=jnp.int32)))
         aux.append(group_aux)
         start += group.count
-    return x, _join_pools(done_k), _join_pools(done_v), aux
+    return (*carry, aux)
 
 
 def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
@@ -576,28 +548,30 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
     cache_v, aux) with `aux` one entry per group: what its feed-forward
     reported, stacked over the group's layers, or None."""
     b, t = input_ids.shape
-    write_kv = make_write_kv_pages(block_tables,
-                                   kv_pool_values(cache_k).shape[2])
+    ps = kv_pool_values(cache_k).shape[2]
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
     token_valid = positions < prompt_lens[:, None]  # [B, T]
+    # position p of row b lands in cell (block_tables[b, p // PS], p % PS)
+    page = jnp.take_along_axis(block_tables, positions // ps, axis=1)
+    off = positions % ps
 
     x = params["embed"][input_ids]  # [B, T, E]
     attention = attention or GQA_ATTENTION
 
     def layer_of(group):
-        def layer(carry_x, layer_in):
-            lp, ck, cv = layer_in
+        def layer(carry, lp, layer_idx):
+            carry_x, ck, cv = carry
             carry_x, k, v = attention.block(
                 cfg, lp, carry_x, positions, inv_freq,
                 lambda q, k, v: attention.prefill(q, k, v, prompt_lens),
                 lora_idx,
             )
-            ck = write_kv(ck, k, positions)
-            cv = write_kv(cv, v, positions)
+            ck = _write_pool(ck, layer_idx, page, off, k)
+            cv = _write_pool(cv, layer_idx, page, off, v)
             h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
             out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
-            return carry_x + out, (ck, cv, aux)
+            return (carry_x + out, ck, cv), aux
 
         return layer
 
@@ -650,9 +624,10 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
                                groups=None, attention=None):
     """Shared chunked-prefill body: process a [B, T] chunk of prompt tokens
     whose rows already hold `start_pos` tokens of KV. The chunk's KV scatters
-    through the block table into the page pool and queries attend over the
-    full row (earlier chunks + causal within this chunk) via
-    ops.attention.paged_attention_extend. Backs long prompts that exceed the
+    through the block table into the page pool, in place (_scan_groups), and
+    queries attend over the full row (earlier chunks + causal within this
+    chunk) via ops.attention.paged_attention_extend at (layer, page). Backs
+    long prompts that exceed the
     one-shot prefill buckets, and — with `all_logits=True` — the speculative
     verify step, which needs logits at EVERY chunk position, not just the
     last. Padding tokens (i >= chunk_lens) write garbage past the chunk —
@@ -685,22 +660,22 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
     attention = attention or GQA_ATTENTION
 
     def layer_of(group):
-        def layer(carry_x, layer_in):
-            lp, ck, cv = layer_in
+        def layer(carry, lp, layer_idx):
+            carry_x, ck, cv = carry
 
             def attn_fn(q, k, v):
                 nonlocal ck, cv  # pool write precedes attention over the pool
-                ck = _write_pool(ck, page, off, k)
-                cv = _write_pool(cv, page, off, v)
+                ck = _write_pool(ck, layer_idx, page, off, k)
+                cv = _write_pool(cv, layer_idx, page, off, v)
                 return attention.extend(
-                    q, ck, cv, read_tables, positions, chunk_lens
+                    q, ck, cv, layer_idx, read_tables, positions, chunk_lens
                 )
 
             carry_x, _, _ = attention.block(
                 cfg, lp, carry_x, positions, inv_freq, attn_fn, lora_idx)
             h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
             out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
-            return carry_x + out, (ck, cv, aux)
+            return (carry_x + out, ck, cv), aux
 
         return layer
 
@@ -776,13 +751,11 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                        live=None, groups=None, attention=None):
     """Shared one-token decode body for every model family.
 
-    The layer loop is UNROLLED (static layer indices) rather than a
-    lax.scan with the pools as scan inputs/outputs: scanning slices the
-    pool per layer and re-stacks the outputs into fresh buffers, and under
-    the engine's k-step burst scan XLA materialized full-pool copies every
-    layer. Decode programs are tiny, so L× code growth is cheap. Each
-    layer's one-token KV lands at page block_tables[b, pos//PS], offset
-    pos%PS.
+    The layer loop is UNROLLED (static layer indices; decode programs are
+    tiny, so L× code growth is cheap) where prefill and extend scan with
+    the pools as the carry (_scan_groups); no body hands a scan the pools
+    as inputs and outputs, which copied them. Each layer's one-token KV
+    lands at page block_tables[b, pos//PS], offset pos%PS.
 
     `live` ([B] bool; None = every row) says which rows are decoding. The
     device's `seq_lens` is no guide to that: a freed or never-used row keeps
@@ -830,10 +803,8 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
 
             def attn_fn(q, k, v, layer_idx=layer_idx):
                 nonlocal cache_k, cache_v  # write precedes attention
-                cache_k = _write_pool_layer(cache_k, layer_idx, page, off,
-                                            k[:, 0])
-                cache_v = _write_pool_layer(cache_v, layer_idx, page, off,
-                                            v[:, 0])
+                cache_k = _write_pool(cache_k, layer_idx, page, off, k[:, 0])
+                cache_v = _write_pool(cache_v, layer_idx, page, off, v[:, 0])
                 return attention.decode(
                     q, cache_k, cache_v, layer_idx, block_tables, kv_lens,
                     window=window, work=work,

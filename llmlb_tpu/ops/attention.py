@@ -143,29 +143,34 @@ def gqa_attention_extend(
     return out.reshape(b, t, h, d).astype(q.dtype)
 
 
-def gather_kv_pages(pages, tables: jnp.ndarray,
-                    dtype=jnp.bfloat16) -> jnp.ndarray:
-    """Materialize contiguous per-row KV from the page pool: [P, PS, K, D]
-    gathered by block tables [B, N] -> [B, N*PS, K, D]. This is the XLA
-    fallback path (CPU tests / partitioned meshes) — on an unpartitioned TPU
-    the Pallas paged kernels index the pool through the block table instead
-    and never build this copy.
+def gather_kv_pages(pages, tables: jnp.ndarray, dtype=jnp.bfloat16,
+                    layer=None) -> jnp.ndarray:
+    """Materialize contiguous per-row KV from the page pool: one layer's
+    pages of the stacked pool [L, P, PS, K, D], gathered at (layer, block
+    tables [B, N]) -> [B, N*PS, K, D] in one gather, so no layer of the
+    pool is sliced out on the way; with `layer` left out, `pages` is one
+    layer [P, PS, K, D]. A latent pool [L, P, PS, C] gathers the same way
+    to [B, N*PS, C]. This is the XLA fallback path (CPU tests /
+    partitioned meshes) — on an unpartitioned TPU the Pallas paged kernels
+    index the pool through the block table instead and never build this
+    copy.
 
-    An int8 pool arrives as a {"q": int8 values, "s": f32 scales [P, PS, K]}
-    pair (llmlb_tpu/quant): both gather through the same table and the cells
-    dequantize to `dtype` here — the attention callers pass their compute
-    dtype so this route matches the Pallas quant kernels' numerics exactly
-    (f32 dequant -> q.dtype operands). HBM moved the int8 bytes + scales."""
-    if isinstance(pages, dict):
-        b, n = tables.shape
-        _, ps, k, d = pages["q"].shape
-        vals = pages["q"][tables].reshape(b, n * ps, k, d)
-        scales = pages["s"][tables].reshape(b, n * ps, k)
-        return (vals.astype(jnp.float32)
-                * scales[..., None]).astype(dtype)
+    An int8 pool arrives as a {"q": int8 values, "s": f32 scales [.., P, PS,
+    K]} pair (llmlb_tpu/quant): both gather through the same table and the
+    cells dequantize to `dtype` here — the attention callers pass their
+    compute dtype so this route matches the Pallas quant kernels' numerics
+    exactly (f32 dequant -> q.dtype operands). HBM moved the int8 bytes +
+    scales."""
+    at = tables if layer is None else (layer, tables)
     b, n = tables.shape
-    _, ps, k, d = pages.shape
-    return pages[tables].reshape(b, n * ps, k, d)
+
+    def rows(x):  # [B, N, PS, ...] -> [B, N*PS, ...]
+        return x.reshape(b, n * x.shape[2], *x.shape[3:])
+
+    if isinstance(pages, dict):
+        return (rows(pages["q"][at]).astype(jnp.float32)
+                * rows(pages["s"][at])[..., None]).astype(dtype)
+    return rows(pages[at])
 
 
 def _pool_shape(pages):
@@ -224,7 +229,7 @@ def paged_attention_decode(
     where a `pool[layer]` operand would be copied whole on every call (an
     int8 pool's scales are the exception, and the smaller part: see
     paged_flash_decode_quant). The XLA fallback (CPU tests, partitioned
-    meshes) slices the layer here."""
+    meshes) gathers the window's pages at (layer, table)."""
     ps = _pool_shape(k_pages)[2]
     ppn = block_tables.shape[1]
     pages = _window_pages(block_tables, ps, window)
@@ -247,44 +252,49 @@ def paged_attention_decode(
         )[:, None]
     _traced["paged_decode"] = "xla"
     tables = block_tables[:, :pages] if pages < ppn else block_tables
-    # an XLA reader may slice: the slice fuses into the gather that reads it
-    k_layer, v_layer = jax.tree.map(lambda pool: pool[layer],
-                                    (k_pages, v_pages))
-    k_cache = gather_kv_pages(k_layer, tables, dtype=q.dtype)
-    v_cache = gather_kv_pages(v_layer, tables, dtype=q.dtype)
+    k_cache = gather_kv_pages(k_pages, tables, dtype=q.dtype, layer=layer)
+    v_cache = gather_kv_pages(v_pages, tables, dtype=q.dtype, layer=layer)
     return gqa_attention_decode(q, k_cache, v_cache, kv_lens)
 
 
 def paged_attention_extend(
     q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
-    k_pages,  # [P, PS, K, D] pool, or quantized {"q","s"} pair
-    v_pages,  # [P, PS, K, D]
+    k_pages,  # [L, P, PS, K, D] stacked pool, or quantized {"q","s"} pair
+    v_pages,  # [L, P, PS, K, D]
+    layer,  # int32 scalar — the layer of the pool to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
     chunk_lens: jnp.ndarray,  # [B] int32 — valid queries in the chunk
 ) -> jnp.ndarray:
-    """Chunked-prefill attention against the KV page pool: the chunk's
-    queries attend causally over row b's pages (earlier chunks + this
-    chunk). Assumes the engine's contiguous chunk positions
-    (q_positions[b] = start + iota)."""
+    """Chunked-prefill attention against one layer of the KV page pool: the
+    chunk's queries attend causally over row b's pages (earlier chunks +
+    this chunk). Assumes the engine's contiguous chunk positions
+    (q_positions[b] = start + iota). The pool arrives stacked with the layer
+    index beside it, as paged_attention_decode's does and for its reason:
+    under the extend program's layer scan `layer` is a run-time value and
+    the pool the scan's carry."""
     if _pallas_enabled():
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_extend_quant
 
             _traced["paged_extend"] = "pallas:paged_flash_extend_quant"
             return paged_flash_extend_quant(
-                q, k_pages["q"], k_pages["s"], v_pages["q"], v_pages["s"],
-                block_tables, q_positions[:, 0], chunk_lens,
+                q, k_pages["q"], k_pages["s"][layer], v_pages["q"],
+                v_pages["s"][layer], layer, block_tables, q_positions[:, 0],
+                chunk_lens,
             )
         from llmlb_tpu.ops.pallas_attention import paged_flash_extend
 
         _traced["paged_extend"] = "pallas:paged_flash_extend"
         return paged_flash_extend(
-            q, k_pages, v_pages, block_tables, q_positions[:, 0], chunk_lens
+            q, k_pages, v_pages, layer, block_tables, q_positions[:, 0],
+            chunk_lens,
         )
     _traced["paged_extend"] = "xla"
-    k_cache = gather_kv_pages(k_pages, block_tables, dtype=q.dtype)
-    v_cache = gather_kv_pages(v_pages, block_tables, dtype=q.dtype)
+    k_cache = gather_kv_pages(k_pages, block_tables, dtype=q.dtype,
+                              layer=layer)
+    v_cache = gather_kv_pages(v_pages, block_tables, dtype=q.dtype,
+                              layer=layer)
     return gqa_attention_extend(q, k_cache, v_cache, q_positions)
 
 
@@ -374,30 +384,25 @@ def _latent_attend(q_abs, q_rope, c, r, mask, scale):
     return out.astype(q_abs.dtype)
 
 
-def _gather_latent(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
-    """[P, PS, C] gathered by block tables [B, N] -> [B, N*PS, C]."""
-    b, n = tables.shape
-    return pages[tables].reshape(b, n * pages.shape[1], pages.shape[2])
-
-
 def paged_latent_extend(
     q_abs: jnp.ndarray,  # [B, T, H, C]
     q_rope: jnp.ndarray,  # [B, T, H, R]
-    c_pages: jnp.ndarray,  # [P, PS, C] — one layer's latent pool
-    r_pages: jnp.ndarray,  # [P, PS, R]
+    c_pages: jnp.ndarray,  # [L, P, PS, C] stacked latent pool
+    r_pages: jnp.ndarray,  # [L, P, PS, R]
+    layer,  # int32 scalar
     block_tables: jnp.ndarray,  # [B, PPN] int32
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
     *,
     scale: float,
 ) -> jnp.ndarray:
     """Absorbed attention of a chunk of queries over row b's pages (earlier
-    chunks, a cached prefix, this chunk), causal by position: plain einsums
-    over the gathered latent on every backend (a chunk's work is the
-    experts', not this; a paged kernel for it is ROADMAP work). Returns the
-    mix of latents [B, T, H, C]."""
+    chunks, a cached prefix, this chunk) of one layer, causal by position:
+    plain einsums over the latent gathered at (layer, table) on every
+    backend (a chunk's work is the experts', not this; a paged kernel for it
+    is ROADMAP work). Returns the mix of latents [B, T, H, C]."""
     _traced["latent_extend"] = "xla"
-    c = _gather_latent(c_pages, block_tables)
-    r = _gather_latent(r_pages, block_tables)
+    c = gather_kv_pages(c_pages, block_tables, layer=layer)  # [B, S, C]
+    r = gather_kv_pages(r_pages, block_tables, layer=layer)
     cell = jnp.arange(c.shape[1], dtype=jnp.int32)
     mask = cell[None, None, :] <= q_positions[:, :, None]
     return _latent_attend(q_abs, q_rope, c, r, mask, scale)
@@ -433,8 +438,8 @@ def paged_latent_decode(
                       work=work)[:, None]
     _traced["latent_decode"] = "xla"
     tables = block_tables[:, :pages] if pages < ppn else block_tables
-    c = _gather_latent(c_pages[layer], tables)
-    r = _gather_latent(r_pages[layer], tables)
+    c = gather_kv_pages(c_pages, tables, layer=layer)  # [B, S, C]
+    r = gather_kv_pages(r_pages, tables, layer=layer)
     cell = jnp.arange(c.shape[1], dtype=jnp.int32)
     mask = (cell[None, :] < kv_lens[:, None])[:, None, :]  # [B, 1, S]
     return _latent_attend(q_abs, q_rope, c, r, mask, scale)

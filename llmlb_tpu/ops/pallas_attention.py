@@ -17,7 +17,8 @@ correctness baselines; these kernels replace them on TPU:
   the pages the live rows hold — not with the row capacity, the table's
   width or the batch's window bucket.
 - `paged_flash_extend` (+ `_quant`): a chunk of queries against the pool
-  (chunked prefill, speculative verify), same page-table addressing.
+  (chunked prefill, speculative verify), the stacked pool read in place at
+  (layer, page of the row's table) as in decode.
 - `flash_prefill`: causal self-attention over bucketed prompts. Grid is
   (batch, q_block, kv_block); fully-future KV blocks (k_start > q_end) skip
   compute, giving the ~2x causal FLOP saving dense XLA attention leaves on the
@@ -650,32 +651,18 @@ def flash_prefill(
 
 # ---------------------------------------------------------------------------
 # Extend body (chunked prefill): q block [1, BLK_Q, K, G, D] vs one KV block
-# [1, BLK_K, K, D]; the chunk starts at global position start_pos[b]
+# of BLK_K cells; the chunk starts at global position start_pos[b]
 # (contiguous positions).
 # ---------------------------------------------------------------------------
 
 
-def _extend_kernel(
-    # scalar prefetch
-    start_pos_ref,  # [B] int32 (SMEM) — global position of the chunk's 1st query
-    chunk_lens_ref,  # [B] int32 (SMEM) — valid queries in the chunk
-    # inputs
-    q_ref,  # [1, BLK_Q, K, G, D]
-    k_ref,  # [1, BLK_K, K, D]  (cache block)
-    v_ref,  # [1, BLK_K, K, D]
-    # output
-    o_ref,  # [1, BLK_Q, K, G, D]
-    # scratch
-    m_ref,  # [K, BLK_Q * G, 1] f32
-    l_ref,  # [K, BLK_Q * G, 1] f32
-    acc_ref,  # [K, BLK_Q * G, D] f32
-    *,
-    block_q: int,
-    block_k: int,
-    num_kv: int,
-    groups: int,
-    scale: float,
-):
+def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
+                 acc_ref, kv_head, *, block_q: int, block_k: int,
+                 num_kv: int, groups: int, scale: float):
+    """One grid step (row b, q block qi, KV block ki) of a paged extend
+    kernel: online softmax (m/l/acc) lives in VMEM scratch across a q
+    block's KV blocks; `kv_head(h)` loads head h's [BLK_K, D] keys and
+    values of the step's block."""
     b = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -711,8 +698,7 @@ def _extend_kernel(
         mask = col <= q_pos
         for h in range(num_kv):  # static unroll over KV heads
             q = q_ref[0, :, h].reshape(rows, -1)  # [BLK_Q*G, D]
-            k = k_ref[0, :, h, :]  # [BLK_K, D]
-            v = v_ref[0, :, h, :]
+            k, v = kv_head(h)  # [BLK_K, D] each
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -729,25 +715,126 @@ def _extend_kernel(
 
 
 # ---------------------------------------------------------------------------
-# Paged extend (chunked prefill): q chunk [B, T, H, D] vs page pool
-# [P, PS, K, D] through block tables [B, PPN]; chunk starts at start_pos[b].
+# Paged extend (chunked prefill): q chunk [B, T, H, D] vs the stacked page
+# pool [L, P, PS, K, D] at one layer, through block tables [B, PPN]; chunk
+# starts at start_pos[b].
 # ---------------------------------------------------------------------------
 
 
-def _paged_extend_kernel(block_tables_ref, start_pos_ref, chunk_lens_ref,
-                         *refs, **kw):
-    """The masked sweep of _extend_kernel; logical KV position of grid step
-    `ki` is ki * page_size because the index_map walks the block table in
-    logical order — the body never needs the table itself."""
-    del block_tables_ref
-    _extend_kernel(start_pos_ref, chunk_lens_ref, *refs, **kw)
+# Index maps of the extend grid (row, q block, logical page), then the
+# scalar-prefetch operands.
+
+
+def _extend_q_map(bi, qi, si, layer, tables, starts, lens):
+    """q and out [B, T, K, G, D]: the row's q block, for every page."""
+    return (bi, qi, 0, 0, 0)
+
+
+def _extend_page_map(bi, qi, si, layer, tables, starts, lens):
+    """KV values [L, P, PS, K, D]: logical page si of row bi, of the layer."""
+    return (layer[0], tables[bi, si], 0, 0, 0)
+
+
+def _extend_scale_map(bi, qi, si, layer, tables, starts, lens):
+    """KV scales [P, PS, K] of one layer of an int8 pool: the same page."""
+    return (tables[bi, si], 0, 0)
+
+
+def _paged_extend_kernel(
+    # scalar prefetch (SMEM); the layer and the table are consumed by the
+    # BlockSpec index maps, which walk the block table in logical order, so
+    # the logical KV position of grid step `ki` is ki * page_size
+    layer_ref, block_tables_ref, start_pos_ref, chunk_lens_ref,
+    # inputs
+    q_ref,  # [1, BLK_Q, K, G, D]
+    k_ref,  # [1, PS, K, D]
+    v_ref,  # [1, PS, K, D]
+    # output
+    o_ref,  # [1, BLK_Q, K, G, D]
+    # scratch
+    m_ref,  # [K, BLK_Q * G, 1] f32
+    l_ref,  # [K, BLK_Q * G, 1] f32
+    acc_ref,  # [K, BLK_Q * G, D] f32
+    **kw,
+):
+    del layer_ref, block_tables_ref
+
+    def kv_head(h):
+        return k_ref[0, :, h, :], v_ref[0, :, h, :]
+
+    _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
+                 acc_ref, kv_head, **kw)
+
+
+def _paged_extend_quant_kernel(
+    layer_ref, block_tables_ref, start_pos_ref, chunk_lens_ref,
+    q_ref,  # [1, BLK_Q, K, G, D]
+    k_ref,  # [1, PS, K, D] int8
+    ks_ref,  # [1, PS, K] f32
+    v_ref,  # [1, PS, K, D] int8
+    vs_ref,  # [1, PS, K] f32
+    o_ref,  # [1, BLK_Q, K, G, D]
+    m_ref, l_ref, acc_ref,
+    **kw,
+):
+    """Int8 pool + per-vector f32 scales, dequant-on-read — the verify and
+    chunked-prefill counterpart of _paged_decode_quant_kernel."""
+    del layer_ref, block_tables_ref
+    dtype = q_ref.dtype
+
+    def kv_head(h):
+        k = (k_ref[0, :, h, :].astype(jnp.float32)
+             * ks_ref[0, :, h][:, None]).astype(dtype)  # [BLK_K, D]
+        v = (v_ref[0, :, h, :].astype(jnp.float32)
+             * vs_ref[0, :, h][:, None]).astype(dtype)
+        return k, v
+
+    _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
+                 acc_ref, kv_head, **kw)
+
+
+def _paged_extend_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
+                       start_pos, chunk_lens, *, block_q, interpret):
+    """The pallas_call both paged extend kernels share: grid (row, q block,
+    logical page); q and out blocks follow (row, q block), the KV blocks
+    (`kv_specs`, one per operand of `kv_operands`) the row's page."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, t, h, d = q.shape
+    _, _, ps, num_kv, _ = kv_operands[0].shape
+    g = h // num_kv
+    blk_q = min(block_q, t)
+    q_spec = pl.BlockSpec((1, blk_q, num_kv, g, d), _extend_q_map,
+                          memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, pl.cdiv(t, blk_q), block_tables.shape[1]),
+        in_specs=[q_spec, *kv_specs],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
+            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
+            pltpu.VMEM((num_kv, blk_q * g, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, block_q=blk_q, block_k=ps, num_kv=num_kv,
+                          groups=g, scale=d**-0.5),
+        out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(_layer_operand(layer), block_tables.astype(jnp.int32),
+      start_pos.astype(jnp.int32), chunk_lens.astype(jnp.int32),
+      q.reshape(b, t, num_kv, g, d), *kv_operands)
+    return out.reshape(b, t, h, d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def paged_flash_extend(
     q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
-    k_pages: jnp.ndarray,  # [P, PS, K, D] — global page pool
-    v_pages: jnp.ndarray,  # [P, PS, K, D]
+    k_pages: jnp.ndarray,  # [L, P, PS, K, D] — global page pool, all layers
+    v_pages: jnp.ndarray,  # [L, P, PS, K, D]
+    layer,  # int32 scalar — the layer of the pool to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
     start_pos: jnp.ndarray,  # [B] int32 — global position of the first query
     chunk_lens: jnp.ndarray,  # [B] int32 — valid queries (rest are padding)
@@ -760,149 +847,29 @@ def paged_flash_extend(
     chunks + this chunk), gathered through the prefetched block table by the
     KV BlockSpec index_map. KV blocks entirely in the future of the chunk
     skip their FLOPs (`pl.when` in _extend_kernel), so cost scales with the
-    context actually filled, not pool capacity. Returns [B, T, H, D]."""
-    if interpret is None:
-        interpret = _interpret_default()
-    b, t, h, d = q.shape
-    ps = k_pages.shape[1]
-    num_kv = k_pages.shape[2]
-    g = h // num_kv
-    ppn = block_tables.shape[1]
-    blk_q = min(block_q, t)
-    grid = (b, pl.cdiv(t, blk_q), ppn)
-    qg = q.reshape(b, t, num_kv, g, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, blk_q, num_kv, g, d),
-                lambda bi, qi, si, tables, starts, lens: (bi, qi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, ps, num_kv, d),
-                lambda bi, qi, si, tables, starts, lens:
-                    (tables[bi, si], 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, ps, num_kv, d),
-                lambda bi, qi, si, tables, starts, lens:
-                    (tables[bi, si], 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, blk_q, num_kv, g, d),
-            lambda bi, qi, si, tables, starts, lens: (bi, qi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_extend_kernel,
-            block_q=blk_q,
-            block_k=ps,
-            num_kv=num_kv,
-            groups=g,
-            scale=d**-0.5,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), start_pos.astype(jnp.int32),
-      chunk_lens.astype(jnp.int32), qg, k_pages, v_pages)
-    return out.reshape(b, t, h, d)
-
-
-# ---------------------------------------------------------------------------
-# Quantized paged extend: int8 pool + per-vector scales, dequant-on-read —
-# the verify/chunked-prefill counterpart of paged_flash_decode_quant.
-# ---------------------------------------------------------------------------
-
-
-def _paged_extend_quant_kernel(
-    block_tables_ref,  # consumed by the index maps
-    start_pos_ref,  # [B] int32 (SMEM)
-    chunk_lens_ref,  # [B] int32 (SMEM)
-    q_ref,  # [1, BLK_Q, K, G, D]
-    k_ref,  # [1, PS, K, D] int8
-    ks_ref,  # [1, PS, K] f32
-    v_ref,  # [1, PS, K, D] int8
-    vs_ref,  # [1, PS, K] f32
-    o_ref,  # [1, BLK_Q, K, G, D]
-    m_ref, l_ref, acc_ref,
-    *,
-    block_q: int,
-    block_k: int,
-    num_kv: int,
-    groups: int,
-    scale: float,
-):
-    del block_tables_ref
-    b = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k_blocks = pl.num_programs(2)
-    start = start_pos_ref[b]
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-    rows = block_q * groups
-    useful = jnp.logical_and(
-        k_start <= start + q_start + block_q - 1,
-        q_start < chunk_lens_ref[b],
-    )
-
-    @pl.when(useful)
-    def _compute():
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), dimension=0)
-        col = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_k), dimension=1
-        )
-        q_pos = start + q_start + row // groups
-        mask = col <= q_pos
-        for h in range(num_kv):  # static unroll over KV heads
-            q = q_ref[0, :, h].reshape(rows, -1)  # [BLK_Q*G, D]
-            k = (k_ref[0, :, h, :].astype(jnp.float32)
-                 * ks_ref[0, :, h][:, None]).astype(q.dtype)  # [BLK_K, D]
-            v = (v_ref[0, :, h, :].astype(jnp.float32)
-                 * vs_ref[0, :, h][:, None]).astype(q.dtype)
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            scores = jnp.where(mask, scores, _NEG_INF)
-            _online_update(m_ref, l_ref, acc_ref, h, scores, v)
-
-    @pl.when(ki == num_k_blocks - 1)
-    def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        o_ref[0] = out.reshape(num_kv, block_q, groups, -1).transpose(1, 0, 2, 3)
+    context actually filled, not pool capacity. The pool arrives STACKED
+    with the layer index beside it, paged_flash_decode's contract: read in
+    place at (layer, page), never `pool[layer]` (a slice handed to a
+    pallas_call is copied whole), and `layer` is a run-time operand, so the
+    layers of a scanned extend program are one kernel. Returns
+    [B, T, H, D]."""
+    _, _, ps, num_kv, d = k_pages.shape
+    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _extend_page_map,
+                           memory_space=pltpu.VMEM)
+    return _paged_extend_call(
+        _paged_extend_kernel, [kv_spec, kv_spec], (k_pages, v_pages), q,
+        layer, block_tables, start_pos, chunk_lens, block_q=block_q,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def paged_flash_extend_quant(
     q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
-    k_pages: jnp.ndarray,  # [P, PS, K, D] int8
-    k_scales: jnp.ndarray,  # [P, PS, K] f32
-    v_pages: jnp.ndarray,  # [P, PS, K, D] int8
+    k_pages: jnp.ndarray,  # [L, P, PS, K, D] int8 — all layers
+    k_scales: jnp.ndarray,  # [P, PS, K] f32 — THE LAYER'S
+    v_pages: jnp.ndarray,  # [L, P, PS, K, D] int8
     v_scales: jnp.ndarray,  # [P, PS, K] f32
+    layer,  # int32 scalar — the layer of the value pools to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32
     start_pos: jnp.ndarray,  # [B] int32
     chunk_lens: jnp.ndarray,  # [B] int32
@@ -910,67 +877,18 @@ def paged_flash_extend_quant(
     block_q: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Int8 variant of paged_flash_extend: scales gather through the same
-    prefetched block table and each page's vectors dequantize in VMEM.
-    Same causal/ragged skip logic and garbage contract."""
-    if interpret is None:
-        interpret = _interpret_default()
-    b, t, h, d = q.shape
-    ps = k_pages.shape[1]
-    num_kv = k_pages.shape[2]
-    g = h // num_kv
-    ppn = block_tables.shape[1]
-    blk_q = min(block_q, t)
-    grid = (b, pl.cdiv(t, blk_q), ppn)
-    qg = q.reshape(b, t, num_kv, g, d)
-
-    def page_map(bi, qi, si, tables, starts, lens):
-        return (tables[bi, si], 0, 0, 0)
-
-    def scale_map(bi, qi, si, tables, starts, lens):
-        return (tables[bi, si], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, blk_q, num_kv, g, d),
-                lambda bi, qi, si, tables, starts, lens: (bi, qi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec((1, ps, num_kv, d), page_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv), scale_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv, d), page_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, num_kv), scale_map,
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, blk_q, num_kv, g, d),
-            lambda bi, qi, si, tables, starts, lens: (bi, qi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_extend_quant_kernel,
-            block_q=blk_q,
-            block_k=ps,
-            num_kv=num_kv,
-            groups=g,
-            scale=d**-0.5,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), start_pos.astype(jnp.int32),
-      chunk_lens.astype(jnp.int32), qg, k_pages, k_scales, v_pages, v_scales)
-    return out.reshape(b, t, h, d)
+    """Int8 variant of paged_flash_extend: each page's vectors dequantize
+    in VMEM. Same causal/ragged skip logic and garbage contract. The values
+    are read in place at (layer, page); the scales arrive as the layer's
+    slice and gather through the same prefetched block table
+    (paged_flash_decode_quant says why)."""
+    _, _, ps, num_kv, d = k_pages.shape
+    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _extend_page_map,
+                           memory_space=pltpu.VMEM)
+    scale_spec = pl.BlockSpec((1, ps, num_kv), _extend_scale_map,
+                              memory_space=pltpu.VMEM)
+    return _paged_extend_call(
+        _paged_extend_quant_kernel,
+        [kv_spec, scale_spec, kv_spec, scale_spec],
+        (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
+        start_pos, chunk_lens, block_q=block_q, interpret=interpret)

@@ -318,6 +318,7 @@ def test_decode_work_list_follows_lens_inside_a_scan():
                                                 2 + 1 + 2 + 3]
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize(
     "b,t,h,kv,d,page_size,pages_per_seq,block_q",
     [
@@ -327,7 +328,9 @@ def test_decode_work_list_follows_lens_inside_a_scan():
     ],
 )
 def test_paged_flash_extend_matches_dense(b, t, h, kv, d, page_size,
-                                          pages_per_seq, block_q):
+                                          pages_per_seq, block_q, layer):
+    """The kernel reads the stacked pool at (layer, page): every other layer
+    is NaN, so a page of the wrong layer shows in the output."""
     keys = jax.random.split(jax.random.PRNGKey(12), 4)
     cap = page_size * pages_per_seq
     q = _rand(keys[0], (b, t, h, d))
@@ -340,8 +343,9 @@ def test_paged_flash_extend_matches_dense(b, t, h, kv, d, page_size,
     k_cache = gather_kv_pages(k_pages, tables)
     v_cache = gather_kv_pages(v_pages, tables)
     expected = gqa_attention_extend(q, k_cache, v_cache, q_positions)
+    k_pool, v_pool = _stacked(k_pages, layer), _stacked(v_pages, layer)
     got = paged_flash_extend(
-        q, k_pages, v_pages, tables, start_pos, chunk_lens,
+        q, k_pool, v_pool, layer, tables, start_pos, chunk_lens,
         block_q=block_q, interpret=True,
     )
     # Padding rows (t >= chunk_len) are ignored downstream; compare valid rows.
@@ -351,11 +355,40 @@ def test_paged_flash_extend_matches_dense(b, t, h, kv, d, page_size,
             got[bi, : lens[bi]], expected[bi, : lens[bi]],
             rtol=2e-5, atol=2e-5,
         )
-    # the XLA dispatcher path must agree everywhere (it has no padding skip)
+    # the XLA dispatcher path must agree everywhere (it has no padding
+    # skip): one gather at (layer, table), the same cells
     got2 = paged_attention_extend(
-        q, k_pages, v_pages, tables, q_positions, chunk_lens
+        q, k_pool, v_pool, layer, tables, q_positions, chunk_lens
     )
     np.testing.assert_allclose(got2, expected, rtol=2e-5, atol=2e-5)
+
+
+def test_paged_flash_extend_under_a_scan_takes_the_layer_at_run_time():
+    """The extend programs call the kernel inside the layer scan: `layer`
+    is a traced scalar there, and each step must read its own layer."""
+    b, t, h, kv, d, ps, ppn, layers = 2, 8, 8, 2, 16, 8, 3, 3
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = _rand(keys[0], (b, t, h, d))
+    k_pool = _rand(keys[1], (layers, b * ppn + 1, ps, kv, d))
+    v_pool = _rand(keys[2], (layers, b * ppn + 1, ps, kv, d))
+    tables = jnp.arange(1, b * ppn + 1, dtype=jnp.int32).reshape(b, ppn)
+    start = jnp.asarray([9, 3], jnp.int32)
+    lens = jnp.asarray([t, t - 3], jnp.int32)
+    positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+
+    def body(carry, layer):
+        return carry, paged_flash_extend(q, k_pool, v_pool, layer, tables,
+                                         start, lens, interpret=True)
+
+    _, got = jax.jit(lambda: jax.lax.scan(
+        body, 0, jnp.arange(layers, dtype=jnp.int32)))()
+    for layer in range(layers):
+        expected = gqa_attention_extend(
+            q, gather_kv_pages(k_pool[layer], tables),
+            gather_kv_pages(v_pool[layer], tables), positions)
+        for bi, n in enumerate(np.asarray(lens)):
+            np.testing.assert_allclose(got[layer, bi, :n], expected[bi, :n],
+                                       rtol=2e-5, atol=2e-5)
 
 
 def test_model_dispatch_pallas_matches_xla(monkeypatch):

@@ -66,16 +66,20 @@ def test_paged_decode_xla_parity(layer):
                                                 np.float32)).max() < TOL
 
 
-def test_paged_extend_xla_parity():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_extend_xla_parity(layer):
     k_pages, v_pages, qk, qv, tables, rng = _pools(2)
     t = 4
     q = jnp.asarray(rng.normal(size=(B, t, H, D)), jnp.float32)
     start = jnp.asarray([8, 4], jnp.int32)
     positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     lens = jnp.asarray([t, t - 1], jnp.int32)
-    base = paged_attention_extend(q, k_pages, v_pages, tables, positions,
-                                  lens)
-    quant = paged_attention_extend(q, qk, qv, tables, positions, lens)
+    base = paged_attention_extend(q, _stacked(k_pages, layer),
+                                  _stacked(v_pages, layer), layer, tables,
+                                  positions, lens)
+    quant = paged_attention_extend(q, _stacked(qk, layer),
+                                   _stacked(qv, layer), layer, tables,
+                                   positions, lens)
     assert np.abs(np.asarray(base) - np.asarray(quant,
                                                 np.float32)).max() < TOL
 
@@ -164,22 +168,34 @@ def test_paged_flash_decode_quant_reads_live_pages_only(case, monkeypatch):
         assert np.abs(got[live] - expected[live]).max() < 2e-3
 
 
-def test_paged_flash_extend_quant_interpret_parity():
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_flash_extend_quant_interpret_parity(layer, monkeypatch):
+    """The int8 kernel reads the stacked values at (layer, page) and takes
+    the layer's scales; every other layer is poison (saturated values, 1e30
+    scales). Against the bf16 kernel (tolerance) and against the XLA dequant
+    route, which reads identical cells."""
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "xla")  # the reference's route
     k_pages, v_pages, qk, qv, tables, rng = _pools(5)
+    qk, qv = _stacked(qk, layer), _stacked(qv, layer)
     t = 6
     q = jnp.asarray(rng.normal(size=(B, t, H, D)), jnp.float32)
     start = jnp.asarray([10, 2], jnp.int32)
     lens = jnp.asarray([t, t - 2], jnp.int32)
-    base = paged_flash_extend(q, k_pages, v_pages, tables, start, lens,
-                              interpret=True)
+    base = paged_flash_extend(q, _stacked(k_pages, layer),
+                              _stacked(v_pages, layer), layer, tables, start,
+                              lens, interpret=True)
     quant = paged_flash_extend_quant(
-        q, qk["q"], qk["s"], qv["q"], qv["s"], tables, start, lens,
-        interpret=True,
+        q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer, tables,
+        start, lens, interpret=True,
     )
+    positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    xla = paged_attention_extend(q, qk, qv, layer, tables, positions, lens)
     # padding rows past chunk_lens are garbage in both — compare valid rows
     for b, n in enumerate([t, t - 2]):
         assert np.abs(np.asarray(base)[b, :n]
                       - np.asarray(quant)[b, :n]).max() < TOL
+        assert np.abs(np.asarray(quant)[b, :n]
+                      - np.asarray(xla, np.float32)[b, :n]).max() < 2e-3
 
 
 @pytest.mark.parametrize("route", ["decode", "extend"])
@@ -204,9 +220,11 @@ def test_quantized_pool_means_quantized_kernel(route, monkeypatch):
         q = jnp.asarray(rng.normal(size=(B, 3, H, D)), jnp.float32)
         positions = jnp.asarray([[8, 9, 10], [4, 5, 6]], jnp.int32)
         lens = jnp.asarray([3, 3], jnp.int32)
-        out = attn.paged_attention_extend(q, qk, qv, tables, positions,
-                                          lens)
-        ref = attn.paged_attention_extend(q, k_pages, v_pages, tables,
+        out = attn.paged_attention_extend(q, _stacked(qk, 1),
+                                          _stacked(qv, 1), 1, tables,
+                                          positions, lens)
+        ref = attn.paged_attention_extend(q, _stacked(k_pages, 1),
+                                          _stacked(v_pages, 1), 1, tables,
                                           positions, lens)
     assert np.abs(np.asarray(out, np.float32)
                   - np.asarray(ref, np.float32)).max() < TOL

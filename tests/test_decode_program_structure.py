@@ -1,20 +1,27 @@
-"""The paged decode program reads the stacked KV pool in place.
+"""The paged programs read and write the stacked KV pool in place.
 
 A `pallas_call` takes whole buffers as operands, so a decode program that
 hands the attention kernel `pool[layer]` makes XLA copy one layer of the pool
 per call: on the chip that was 105 MB per layer, K and V, a third of the
-decode step (PERF.md §6, PR 25). The program now passes the 5-D pool and a
-layer index, and these tests keep a later refactor from bringing the slice
-back: in the program as traced (the jaxpr), and in the program as the TPU's
-compiler leaves it for a described v5e. Its time is the benchmark's to show."""
+decode step (PERF.md §6, PR 25). And a `lax.scan` that takes the pool as
+`xs` and gives it back as `ys` copies the pool whole and slices every layer
+out of it and back, per call: 25 ms of a 45 ms prefill step (PERF.md §6,
+PR 32). Every program now passes the 5-D pool and a layer index, prefill and
+extend with the pool as the layer scan's carry, and these tests keep a later
+refactor from bringing a slice or a copy back: in the program as traced (the
+jaxpr), and in the program as the TPU's compiler leaves it for a described
+v5e. Its time is the benchmark's to show."""
 
+import dataclasses
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from llmlb_tpu.models import llama, mixtral
+from llmlb_tpu.engine.presets import get_preset
+from llmlb_tpu.models import deepseek_v3, llama, mixtral
 from llmlb_tpu.ops import pallas_attention
 
 LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM = 3, 7, 8, 2, 16
@@ -100,7 +107,123 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
             kernel_operands + [q_rows]), shapes
 
 
-# --- the same program as the chip's compiler leaves it ----------------------
+# --- prefill, extend and verify: the pool is the layer scan's carry ----------
+
+# one dense layer and two expert layers: two LayerGroups, two scans
+LATENT_TINY = get_preset("debug-mla-tiny")
+CHUNK = 2 * PAGE_SIZE
+ENTRY_POINTS = ("prefill_into_pages", "prefill_extend_pages",
+                "verify_step_paged")
+# (family, quantized, attention route): the families with an int8 pool have
+# a Pallas extend kernel too; the latent family extends over a gather
+SCANNED = [(name, quantized, route) for name in sorted(FAMILIES)
+           for quantized in (False, True) for route in ("pallas", "xla")]
+SCANNED.append(("deepseek_v3", False, "xla"))
+
+
+def _scanned_jaxpr(family, cfg, entry, quantized, route, monkeypatch):
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", route)  # read while tracing
+    fn = getattr(family, entry)
+    fn._clear_cache()
+    # a config of its own for each route: a static argument that no trace
+    # made under the other route has seen
+    cfg = dataclasses.replace(
+        cfg, max_position_embeddings=cfg.max_position_embeddings
+        + ("pallas", "xla").index(route) + 1)
+    params = jax.eval_shape(lambda key: family.init_params(cfg, key),
+                            jax.random.PRNGKey(0))
+    pools = jax.eval_shape(
+        lambda: family.init_kv_pages(cfg, PAGES, PAGE_SIZE,
+                                     quantized=quantized))
+    ids = jax.ShapeDtypeStruct((ROWS, CHUNK), jnp.int32)
+    rows = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((ROWS, PAGES_PER_ROW), jnp.int32)
+    if entry == "prefill_into_pages":
+        args, kw = (ids, rows, tables), {}
+    else:
+        args = (ids, rows, rows, tables)
+        kw = {"window": CHUNK} if entry == "verify_step_paged" else {}
+    try:
+        closed = jax.make_jaxpr(
+            lambda p, a, ck, cv: fn(p, cfg, *a, ck, cv, **kw)
+        )(params, args, *pools)
+    finally:
+        fn._clear_cache()  # traced with the env set
+    return closed.jaxpr, pools
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+@pytest.mark.parametrize(
+    "name,quantized,route", SCANNED,
+    ids=[f"{n}-{'int8' if q else 'bf16'}-{r}" for n, q, r in SCANNED])
+def test_prefill_and_extend_carry_the_pool_through_the_layer_scan(
+        name, quantized, route, entry, monkeypatch):
+    """No scan has a piece of the pool among its `xs` or `ys`, no equation
+    yields one layer of it, none but the scatters (and the loops and calls
+    they sit in) yields a pool, and an extend kernel is handed the pool
+    whole. (At the parent of PR 32 each pool was a scan's `xs` and `ys`, and
+    a stack of two groups was sliced per group and concatenated again.)"""
+    family, cfg = (FAMILIES[name] if name in FAMILIES
+                   else (deepseek_v3, LATENT_TINY))
+    jaxpr, pools = _scanned_jaxpr(family, cfg, entry, quantized, route,
+                                  monkeypatch)
+    stacked = {leaf.shape for leaf in jax.tree.leaves(pools)}
+    a_layer = {shape[1:] for shape in stacked}
+    values = {v.shape for v in jax.tree.leaves(
+        jax.tree.map(llama.kv_pool_values, pools,
+                     is_leaf=lambda p: isinstance(p, dict)))}
+    # the Pallas route hands an int8 pool's scales to the kernel as the
+    # layer's slice, as decode does; the values never
+    may_slice = ({s[1:] for s in stacked - values}
+                 if quantized and route == "pallas" else set())
+
+    def shapes(variables):
+        return [getattr(v.aval, "shape", None) for v in variables]
+
+    eqns = list(_equations(jaxpr))
+    scans = [eqn for eqn in eqns if eqn.primitive.name == "scan"]
+    groups = 2 if name == "deepseek_v3" else 1
+    assert len(scans) == groups
+    for eqn in scans:
+        consts, carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        through = (shapes(eqn.invars[consts + carry:])
+                   + shapes(eqn.outvars[carry:]))
+        assert not [s for s in through if s and s[1:] in a_layer], through
+        assert sorted(s for s in shapes(eqn.invars[consts:consts + carry])
+                      if s in stacked) == sorted(
+            leaf.shape for leaf in jax.tree.leaves(pools))
+
+    sliced = [str(eqn) for eqn in eqns
+              if any(s in a_layer - may_slice for s in shapes(eqn.outvars))]
+    assert not sliced, f"a per-layer slice of the pool: {sliced}"
+    makes_a_pool = {"scatter", "scan", "while", "pjit", "jit", "closed_call",
+                    "core_call", "custom_jvp_call", "custom_vjp_call"}
+    ranks = {shape[1:]: len(shape) for shape in stacked}
+    copied = [str(eqn) for eqn in eqns
+              if eqn.primitive.name not in makes_a_pool
+              and any(s and ranks.get(s[1:]) == len(s)
+                      and s[1:] not in may_slice
+                      for s in shapes(eqn.outvars))]
+    assert not copied, f"a pool or a group's part of one is built: {copied}"
+
+    kernels = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"
+               and "grouped_expert_matmul" not in str(
+                   eqn.params.get("name_and_src_info",
+                                  eqn.params.get("name")))]
+    # (a fresh prompt's flash_prefill reads no pool)
+    handed = [[s for s in shapes(eqn.invars) if s and len(s) >= 3]
+              for eqn in kernels]
+    assert not [s for ops in handed for s in ops
+                if s in a_layer - may_slice], handed
+    readers = [ops for ops in handed if values & set(ops)]
+    reads_the_pool = route == "pallas" and entry != "prefill_into_pages"
+    assert len(readers) == (1 if reads_the_pool else 0)  # the scan's body
+    for ops in readers:
+        assert sorted(s for s in ops if s in values) == sorted(
+            v.shape for v in jax.tree.leaves(pools) if v.shape in values)
+
+
+# --- the same programs as the chip's compiler leaves them --------------------
 #
 # The TPU compiler is installed here and compiles for a chip that is described,
 # not attached. Only the test that needs it describes the topology (one process
@@ -111,6 +234,12 @@ CHIP_CFG = llama.LlamaConfig(  # Mistral-7B's widths, two layers deep
     vocab_size=32000, hidden_size=4096, intermediate_size=14336,
     num_layers=2, num_heads=32, num_kv_heads=8, rope_theta=1e6,
     tie_word_embeddings=False)
+
+
+def _on_chip(sharding, tree):
+    """`tree`'s shapes as they lie on the described chip."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
 
 
 @pytest.fixture(scope="module")
@@ -142,9 +271,7 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
     for fn in jitted:  # traced before with the interpreter or the XLA path
         fn._clear_cache()
 
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_on_chip, one_chip)
 
     params = on_chip(jax.eval_shape(
         lambda key: llama.init_params(CHIP_CFG, key), jax.random.PRNGKey(0)))
@@ -188,10 +315,95 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
     assert not bad, bad
 
 
-# --- the latent mixture's decode burst, as the chip's compiler leaves it -----
+# --- prefill and extend, as the chip's compiler leaves them ------------------
 
 LATENT_PAGES, LATENT_ROWS = 768, 64
+LATENT_CFG = deepseek_v3.DeepseekV3Config(  # kanana-2-30b-a3b's widths: one
+    # dense and one expert layer, so two groups and two scans
+    vocab_size=128256, hidden_size=2048, intermediate_size=6144,
+    num_layers=2, num_heads=32, num_kv_heads=32, head_dim=64,
+    rope_theta=1e6, rms_eps=1e-6, max_position_embeddings=32768)
+COMPILED = {
+    "llama-bf16": (llama, CHIP_CFG, CHIP_PAGES, False),
+    "llama-int8": (llama, CHIP_CFG, CHIP_PAGES, True),
+    "deepseek_v3-bf16": (deepseek_v3, LATENT_CFG, LATENT_PAGES, False),
+}
 
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS[:2])
+@pytest.mark.parametrize("case", sorted(COMPILED))
+def test_compiled_prefill_and_extend_copy_no_part_of_the_pool(
+        case, entry, one_chip, monkeypatch):
+    """A 128-token prompt prefilled, or appended to a row, at the benchmark
+    cells' pools and widths, compiled for a v5e: the compiler copies no
+    value pool, slices no layer out of one and writes none back, and the
+    program's temporaries stay under one layer of the pool — the pool is
+    updated where it lies. (At the parent of PR 32 the Mistral programs held
+    two whole-pool copies and a `dynamic-slice` and `dynamic-update-slice`
+    of each layer of K and of V: 646 MB of temporaries for a prompt that
+    leaves 1 MB in two layers.)"""
+    from llmlb_tpu.ops import pallas_moe
+
+    family, cfg, pages, quantized = COMPILED[case]
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
+    fn = getattr(family, entry)
+    jitted = (fn, pallas_attention.flash_prefill,
+              pallas_attention.paged_flash_extend,
+              pallas_attention.paged_flash_extend_quant,
+              pallas_moe.grouped_expert_matmul)
+    for f in jitted:
+        f._clear_cache()
+
+    on_chip = functools.partial(_on_chip, one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: family.init_params(cfg, key), jax.random.PRNGKey(0)))
+    pools = on_chip(jax.eval_shape(
+        lambda: family.init_kv_pages(cfg, pages, CHIP_PAGE_SIZE,
+                                     quantized=quantized)))
+    ids = on_chip(jax.ShapeDtypeStruct((1, CHIP_PAGE_SIZE), jnp.int32))
+    row = on_chip(jax.ShapeDtypeStruct((1,), jnp.int32))
+    tables = on_chip(jax.ShapeDtypeStruct((1, CHIP_TABLE), jnp.int32))
+    args = ((ids, row, tables) if entry == "prefill_into_pages"
+            else (ids, row, row, tables))
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(params, cfg, *args, *pools).compile()
+    finally:
+        for f in jitted:
+            f._clear_cache()
+
+    hlo = compiled.as_text()
+    values = [llama.kv_pool_values(pool).shape for pool in pools]
+    rest = {",".join(map(str, shape[1:])) for shape in values}
+    a_layer = rf"(bf16|s8)\[(1,)?({'|'.join(rest)})\]"
+    whole_pool = rf"(bf16|s8)\[{cfg.num_layers},({'|'.join(rest)})\]"
+    moved = ("copy", "copy-start", "copy-done", "dynamic-slice",
+             "dynamic-update-slice", "concatenate")
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    bad = [(shape, op) for shape, op in results
+           if re.match(a_layer, shape)
+           or (op in moved and re.match(whole_pool, shape))]
+    assert not bad, bad
+
+    layer_bytes = min(
+        jnp.dtype(v.dtype).itemsize * v.size // cfg.num_layers
+        for v in map(llama.kv_pool_values, pools))
+    budget = layer_bytes
+    if quantized:
+        # An int8 pool's scales f32[.., PS, K] lie PS-minor in HBM; the
+        # scatter and the kernel want one layer of them K-minor, where 8
+        # heads pad to 128 lanes: 26 MB a buffer, K and V, there and back
+        # (paged_flash_decode_quant's docstring; the decode burst pays the
+        # same). The parent's int8 programs held 329 MB.
+        budget = 6 * pages * CHIP_PAGE_SIZE * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < budget
+
+
+# --- the latent mixture's decode burst, as the chip's compiler leaves it -----
 
 def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
                                                                  monkeypatch):
@@ -204,13 +416,9 @@ def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
     kernel call; with `w[layer]` handed to the grouped product it copied
     three `bf16[128,2048,768]` a layer, more than half a decode step:
     PERF.md section 6, PR 31.)"""
-    from llmlb_tpu.models import deepseek_v3
     from llmlb_tpu.ops import pallas_moe
 
-    cfg = deepseek_v3.DeepseekV3Config(
-        vocab_size=128256, hidden_size=2048, intermediate_size=6144,
-        num_layers=2, num_heads=32, num_kv_heads=32, head_dim=64,
-        rope_theta=1e6, rms_eps=1e-6, max_position_embeddings=32768)
+    cfg = LATENT_CFG
     monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
     monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
     monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
@@ -220,9 +428,7 @@ def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
     for fn in jitted:
         fn._clear_cache()
 
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_on_chip, one_chip)
 
     params = on_chip(jax.eval_shape(
         lambda key: deepseek_v3.init_params(cfg, key), jax.random.PRNGKey(0)))
