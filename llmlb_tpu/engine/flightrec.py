@@ -28,6 +28,9 @@ engine — every lifecycle edge the scheduler crosses:
                    (source: wire | offload; kind: stream | prefix)
   lora_acquire     adapter pinned for the request (+ load wait seconds)
   spec_accept      one speculative verify step's drafted/accepted counts
+  commit           a block family's row committed blocks in one burst of
+                   block passes (blocks, tokens emitted, position) — one
+                   event a burst and row, not one a token
   shed             dropped before prefill (deadline exceeded)
   finished         terminal success (reason: stop | length | cancelled)
   errored          terminal failure (message)
@@ -74,7 +77,7 @@ from collections import OrderedDict, deque
 EVENTS = (
     "admitted", "queued", "prefill_chunk", "staged", "handoff_emitted",
     "adopted", "parked", "resumed", "kv_shipped", "kv_spilled",
-    "kv_restored", "lora_acquire", "spec_accept",
+    "kv_restored", "lora_acquire", "spec_accept", "commit",
     "shed", "finished", "errored", "slow_step",
 )
 

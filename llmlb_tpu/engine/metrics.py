@@ -40,6 +40,13 @@ PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 # (dtype / page_size / geometry), adopter could not reserve pages
 # (capacity), malformed payload (error). Closed set: the fallback counter
 # renders one series per reason from the first scrape.
+# A block family's counts of one burst of block passes, as its `decode` step
+# records carry them and `/api/health .metrics` totals them (`<name>_total`):
+# passes of the burst; decoding rows summed over the passes; blocks and
+# positions committed; positions unmasked.
+BLOCK_COUNTS = ("block_passes", "row_passes", "blocks_committed",
+                "tokens_committed", "positions_unmasked")
+
 KV_FALLBACK_REASONS = ("disabled", "absent", "version", "dtype",
                        "page_size", "geometry", "capacity", "error")
 
@@ -174,6 +181,9 @@ class EngineMetrics:
         self.moe_expert_load_max = 0
         self.moe_expert_load_hist: list[list[int]] = []
         self.decode_kv_pages_window_total = 0
+        # Generation by diffusion over blocks (scheduler._emit_blocks):
+        # running totals of the bursts' counts, by the step records' names
+        self.block_totals = dict.fromkeys(BLOCK_COUNTS, 0)
         self.constrained_burst_fallback_total = 0
         # Overload protection (docs/scheduling.md): slots parked under
         # slot/page pressure, parked requests re-activated, and requests
@@ -358,6 +368,13 @@ class EngineMetrics:
         with self._lock:
             self.decode_kv_pages_live_total += kv_pages_live
             self.decode_kv_pages_window_total += kv_pages_window
+
+    def record_block_passes(self, counts: dict) -> None:
+        """One burst of block passes (a `decode` record of a block
+        family)."""
+        with self._lock:
+            for name in BLOCK_COUNTS:
+                self.block_totals[name] += counts.get(name, 0)
 
     def record_step_counters(self, counters: dict, max_names: tuple) -> None:
         """One dispatch's step counters (a burst's are already reduced over
@@ -546,6 +563,8 @@ class EngineMetrics:
                     self.decode_kv_pages_window_total,
                 "constrained_burst_fallback_total":
                     self.constrained_burst_fallback_total,
+                **{f"{name}_total": n
+                   for name, n in self.block_totals.items()},
                 "moe_counted_steps_total": self.moe_counted_steps_total,
                 "moe_experts_touched_total": self.moe_experts_touched_total,
                 "moe_expert_assignments_total":
@@ -670,6 +689,10 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_decode_kv_pages_window_total counter",
                 "llmlb_engine_decode_kv_pages_window_total "
                 f"{self.decode_kv_pages_window_total}",
+                *(line for name, n in self.block_totals.items()
+                  for line in (
+                      f"# TYPE llmlb_engine_{name}_total counter",
+                      f"llmlb_engine_{name}_total {n}")),
                 "# TYPE llmlb_engine_moe_counted_steps_total counter",
                 "llmlb_engine_moe_counted_steps_total "
                 f"{self.moe_counted_steps_total}",

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.mixtral import MixtralConfig
+from llmlb_tpu.models.sdar_moe import SdarMoeConfig
 from llmlb_tpu.ops.rope import RopeScaling
 
 PRESETS: dict[str, LlamaConfig] = {
@@ -41,6 +42,15 @@ PRESETS: dict[str, LlamaConfig] = {
         qk_rope_head_dim=8, v_head_dim=16, num_experts=8,
         experts_per_token=2, moe_intermediate_size=32, num_shared_experts=1,
         first_k_dense=1, routed_scaling_factor=2.448,
+    ),
+    # CI-sized mixture that generates by diffusion over blocks
+    # (models/sdar_moe.py, docs/block-diffusion.md): blocks of 4
+    "debug-sdar-tiny": SdarMoeConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=192,
+        num_layers=3, num_heads=8, num_kv_heads=2, head_dim=16,
+        rope_theta=1000000.0, rms_eps=1e-6, dtype=jnp.float32,
+        max_position_embeddings=512, num_experts=16, experts_per_token=4,
+        moe_intermediate_size=32, mask_token_id=500,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

@@ -65,7 +65,7 @@ from llmlb_tpu.ops.grammar import (
     grammar_advance,
     grammar_bias,
 )
-from llmlb_tpu.ops.sampling import sample_tokens
+from llmlb_tpu.ops.sampling import sample_tokens, token_probability
 from llmlb_tpu.parallel.mesh import MeshConfig, build_mesh, default_tp
 from llmlb_tpu.quant import kv_cell_bytes, parse_quant_mode, quantize_params
 from llmlb_tpu.spec import PromptLookupDrafter, SpecConfig
@@ -248,6 +248,36 @@ def _activate_rows(logits, key, temps, top_ps, top_ks, seeds, lens, slot_ids,
     )
 
 
+def _sample_block(logits, key, temps, top_ps, top_ks, seeds, lens, n_masked):
+    """Per-position sampling of a block pass: [S, B, V] logits as S*B rows,
+    each slot's params repeated per position; returns the sampled ids and
+    their probabilities (ops/sampling.token_probability), both [S, B]. A
+    seeded row folds (absolute position, masks left in its block): the same
+    key whatever rows share the batch and whether or not the block was
+    started over after a park, and another key for each pass of a block."""
+    s, b, v = logits.shape
+    flat = logits.reshape(s * b, v)
+
+    def rep(x):
+        return jnp.repeat(x, b)
+
+    steps = ((lens[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :])
+             * (b + 1) + n_masked[:, None]).reshape(-1)
+    ids = sample_tokens(flat, key, rep(temps), rep(top_ps), rep(top_ks),
+                        None, rep(seeds), steps)
+    conf = token_probability(flat, ids, rep(temps))
+    return ids.reshape(s, b), conf.reshape(s, b)
+
+
+@partial(jax.jit, donate_argnames=("state",))
+def _activate_block_rows(slot_ids, rows, state):
+    """Activation of one prefilled group of a block family as ONE program:
+    scatter the group's rows into the per-slot arrays of `state` (donated).
+    Nothing is sampled: a block family's first tokens come from its first
+    block's passes. Padding rows repeat the last real row."""
+    return tuple(arr.at[slot_ids].set(row) for arr, row in zip(state, rows))
+
+
 @dataclasses.dataclass
 class SamplingParams:
     temperature: float = 1.0
@@ -286,6 +316,15 @@ class SamplingParams:
     # park/resume re-prefills with the same adapter so resumed streams stay
     # token-identical.
     lora: str | None = None
+    # Generation by diffusion over blocks (docs/block-diffusion.md): the
+    # request's own procedure; None = the model configuration's. A family
+    # that decodes one token a step refuses them at submission, and
+    # `block_length` must equal the model's. JSON-safe: they ride the plan
+    # wire and /v1/resume replay as they are.
+    block_length: int | None = None
+    denoising_steps: int | None = None
+    remasking_strategy: str | None = None
+    confidence_threshold: float | None = None
 
 
 @dataclasses.dataclass
@@ -312,7 +351,8 @@ class Request:
     prompt_ids: list[int]
     sampling: SamplingParams
     request_id: str = dataclasses.field(default_factory=lambda: uuid.uuid4().hex)
-    # events: ("token", token_id) ... ("done", finish_reason) | ("error", msg)
+    # events: ("token", token_id) | ("tokens", [ids]: a block family's commit)
+    # ... ("done", finish_reason) | ("error", msg)
     events: queue.SimpleQueue = dataclasses.field(default_factory=queue.SimpleQueue)
     submitted_at: float = dataclasses.field(default_factory=time.monotonic)
     first_token_at: float | None = None
@@ -405,6 +445,12 @@ class _Slot:
     handoff_ready: bool = False
     handoff_logits: object | None = None
     handoff_ready_at: float = 0.0
+    # A block family's row (set at every activation): the prompt tokens its
+    # open FIRST block holds as given — committed with it, never emitted —
+    # and the tokens the request may emit in all (max_tokens, or what the
+    # slot's capacity leaves), which is what the device's `left` counts.
+    given: int = 0
+    token_limit: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -476,6 +522,12 @@ class EngineCore:
             self.family, "step_counter_shapes", lambda _cfg: {})(cfg)
         self._counter_max: tuple = getattr(self.family, "STEP_COUNTER_MAX",
                                            ())
+        # Generation by diffusion over blocks: a family that declares a
+        # block length B > 1 decodes by BLOCK PASSES (_decode_blocks) — a
+        # row commits 0 or B tokens a pass — and every other family by one
+        # token a step, on the programs it always built.
+        self.block = int(getattr(self.family, "block_length",
+                                 lambda _cfg: 1)(cfg))
         self.num_slots = num_slots
         self.slot_capacity = min(slot_capacity, cfg.max_position_embeddings)
         self.prefill_buckets = tuple(
@@ -791,7 +843,8 @@ class EngineCore:
             max_ngram=max(1, int(spec_ngram)),
             min_ngram=1,
         )
-        self._spec_available = hasattr(self.family, "verify_step_paged")
+        self._spec_available = (hasattr(self.family, "verify_step_paged")
+                                and self.block == 1)
         # jitted verify wrappers per context-window bucket (verify fn +
         # per-position sampling fused into one dispatch, like _decode_many)
         self._verify_fns: dict[int, Callable] = {}
@@ -1020,6 +1073,8 @@ class EngineCore:
         self.decode_dispatch_by_loop: dict[str, int] = {
             "main": 0, "prefill": 0, "decode": 0, "handoff": 0,
         }
+        if self.block > 1:
+            self._check_block_engine()
         if self.role == "split":
             from llmlb_tpu.disagg.split import SplitRuntime
 
@@ -1029,6 +1084,67 @@ class EngineCore:
                     "broadcasts one plan per combined step loop)"
                 )
             self.split = SplitRuntime(self, self._disagg_prefill_slots_arg)
+
+    def _check_block_engine(self) -> None:
+        """What an engine of a block family needs of its sizes, and what it
+        refuses to start with rather than serve wrong
+        (docs/block-diffusion.md)."""
+        b = self.block
+        sizes = {"slot_capacity": self.slot_capacity,
+                 "kv_page_size": self.kv_page_size,
+                 **{f"prefill bucket {n}": n for n in self.prefill_buckets}}
+        bad = [name for name, n in sizes.items() if n % b]
+        if bad:
+            raise ValueError(
+                f"a block length of {b} must divide {bad}: prefill chunks, "
+                "prefix-cache entries and pages end on block boundaries")
+        for on, what in ((self.quant.kv, "an int8 KV pool (--quantize kv)"),
+                         (self.spec.enabled, "speculative decoding"),
+                         (self.role == "split", "--role split"),
+                         (self.coordinator is not None, "multihost lockstep")):
+            if on:
+                raise NotImplementedError(
+                    f"{self.family.__name__} generates by diffusion over "
+                    f"blocks, which is not served with {what} yet")
+        # KV travels as bytes only between block boundaries, which a park
+        # keeps to, but the adopter's activation would sample a first token:
+        # a block family resumes by chunk-prefill replay alone
+        self.kv_ship = False
+        self.kv_offload = None
+
+    def _check_block_request(self, request: Request) -> None:
+        """Raise ValueError for what a request asks of generation by
+        diffusion over blocks that this engine cannot give it."""
+        s = request.sampling
+        asked = {k: getattr(s, k) for k in (
+            "block_length", "denoising_steps", "remasking_strategy",
+            "confidence_threshold") if getattr(s, k) is not None}
+        if self.block == 1:
+            if asked:
+                raise ValueError(
+                    f"{sorted(asked)} are parameters of generation by "
+                    "diffusion over blocks; this model decodes one token a "
+                    "step")
+            return
+        if s.constraint is not None:
+            raise ValueError(
+                "structured outputs (a grammar constraint) are not served "
+                "by a model that generates by diffusion over blocks: the "
+                "grammar's cursor advances token by token")
+        if (s.speculative or {}).get("enabled"):
+            raise ValueError(
+                "speculative decoding is not served by a model that "
+                "generates by diffusion over blocks")
+        if asked.get("block_length", self.block) != self.block:
+            raise ValueError(
+                f"'block_length' must be the model's, {self.block}; got "
+                f"{asked['block_length']}")
+        cfg = self.cfg
+        self.family.check_generation(
+            self.block,
+            asked.get("denoising_steps", cfg.denoising_steps),
+            asked.get("remasking_strategy", cfg.remasking_strategy),
+            asked.get("confidence_threshold", cfg.confidence_threshold))
 
     # ------------------------------------------------------------------ public
 
@@ -1101,11 +1217,19 @@ class EngineCore:
             plain(self._key),  # split keys keep this shape/dtype
             live,
         ]
+        if self.block > 1:
+            args = [param_shapes, *map(plain, self._block_state()),
+                    cache_k_shapes, cache_v_shapes,
+                    plain(self._d_block_tables),
+                    *map(plain, self._block_sampling()), plain(self._key),
+                    live]
         for w in self._window_buckets:
             if not self._running:
                 return
             try:
-                if self.decode_burst > 1 or self.fused_decode:
+                if self.block > 1:
+                    self._block_many_for(w).lower(*args).compile()
+                elif self.decode_burst > 1 or self.fused_decode:
                     # fused engines dispatch the burst scan even at k == 1;
                     # grammar/fused-verify variants compile on first use
                     # (their tables don't exist until a schema registers)
@@ -1151,7 +1275,10 @@ class EngineCore:
             )
         # Prompts beyond the largest one-shot bucket run through chunked
         # prefill (prefill_extend_pages); the only hard cap is slot capacity.
-        if n + 1 >= self.slot_capacity:
+        # a slot keeps `block` cells free past a sequence: the one that rows
+        # which are not decoding write their garbage to, and for a block
+        # family the rest of a block before it (block is 1 otherwise)
+        if n + self.block >= self.slot_capacity:
             # a refused submit must not leak a pin the service layer's
             # prepare_lora already took for this request
             self._release_lora(request)
@@ -1159,6 +1286,11 @@ class EngineCore:
                 f"prompt of {n} tokens does not fit the slot capacity "
                 f"({self.slot_capacity}) with room to generate"
             )
+        try:
+            self._check_block_request(request)
+        except ValueError:
+            self._release_lora(request)
+            raise
         # LoRA: pin (and hot-load) the adapter BEFORE the request can reach
         # a slot — the step loop must never block on disk I/O, and eviction
         # must see queued/parked requests as active. Idempotent: the service
@@ -1527,6 +1659,21 @@ class EngineCore:
         # is set — LoRA-free engines pass lora_idx=None to every dispatch
         # (the original compiled programs, bit for bit).
         self._d_lora_idx = self._on_mesh(np.zeros((n,), np.int32))
+        if self.block > 1:
+            # A block family's row (_block_state, _block_sampling): the open
+            # block's ids and which of them are still masked, the positions
+            # it may yet commit, the given tokens at the open block's head;
+            # and the request's procedure. `_d_seq_lens` is the COMMITTED
+            # length; `_d_last_tokens` is unused.
+            b = self.block
+            self._d_blk = self._on_mesh(
+                np.full((n, b), self.cfg.mask_token_id, np.int32))
+            self._d_masked = self._on_mesh(np.ones((n, b), np.bool_))
+            self._d_left = self._on_mesh(np.zeros((n,), np.int32))
+            self._d_skip = self._on_mesh(np.zeros((n,), np.int32))
+            self._d_per_pass = self._on_mesh(np.ones((n,), np.int32))
+            self._d_dynamic = self._on_mesh(np.zeros((n,), np.bool_))
+            self._d_threshold = self._on_mesh(np.ones((n,), np.float32))
 
     def _fresh_kv_pool(self):
         """A zeroed K and V page pool, placed on the mesh."""
@@ -1559,7 +1706,8 @@ class EngineCore:
                      slots: "list[int] | None" = None,
                      dispatches: int = 0, fused: bool = False,
                      kv_pages: "dict[str, int] | None" = None,
-                     counters: "dict | None" = None) -> None:
+                     counters: "dict | None" = None,
+                     block: "dict[str, int] | None" = None) -> None:
         """Close one step (its last stamp) and finalize its record: the
         admission time since the previous record becomes its plan phase,
         the record feeds the ring buffer + anomaly detector, and the phase
@@ -1577,7 +1725,9 @@ class EngineCore:
         costs after the step's last stamp is the next record's
         since_prev.record_s. `counters` are the family's step counters of
         this dispatch (a mixture's expert load): the scalars land on the
-        record, everything in the engine's running totals."""
+        record, everything in the engine's running totals. `block` are a
+        block family's counts of the burst (_emit_blocks): on the record
+        and in the running totals likewise."""
         clock = self._clock()
         clock.close(step, kind)
         phases = step.phases()
@@ -1591,7 +1741,7 @@ class EngineCore:
         if kind in ("decode", "verify") and dispatches > 0:
             self.decode_dispatch_by_loop[self._loop_tag()] += dispatches
             self.metrics.record_decode_dispatches(dispatches, fused=fused)
-        extra = dict(kv_pages or {})
+        extra = {**(kv_pages or {}), **(block or {})}
         extra.update((name, v) for name, v in (counters or {}).items()
                      if isinstance(v, int))  # the scalars; not the histogram
         slow = self.step_stats.observe(kind, phases,
@@ -1604,6 +1754,8 @@ class EngineCore:
             self.metrics.record_decode_kv_pages(**kv_pages)
         if counters:
             self.metrics.record_step_counters(counters, self._counter_max)
+        if block:
+            self.metrics.record_block_passes(block)
         self.metrics.record_step_phases(phases, slow=slow)
         if slow and request_ids and self.flightrec.enabled:
             total = round(sum(phases.values()), 6)
@@ -2437,7 +2589,11 @@ class EngineCore:
             prompt = self._effective_prompt(request)
             n = len(prompt)
             # Cap generation so the slot cache can hold prompt + output.
-            if self.slot_capacity - n - 1 <= 0:
+            room = self.slot_capacity - n - self.block  # as in submit
+            # a block family prefills the prompt's WHOLE blocks; the
+            # remainder opens its first block (_activate_block_group)
+            n -= n % self.block
+            if room <= 0:
                 if request.parked is not None:
                     # a request parked at the capacity edge has no room left
                     # to decode: finish it cleanly rather than erroring a
@@ -3553,7 +3709,8 @@ class EngineCore:
         lens = np.zeros((padded,), np.int32)
         slot_ids = np.zeros((padded,), np.int32)
         for row, (slot_id, request, n) in enumerate(group):
-            ids[row, :n] = self._effective_prompt(request)
+            # [:n]: a block family's n is the prompt's whole blocks
+            ids[row, :n] = self._effective_prompt(request)[:n]
             lens[row] = n
             slot_ids[row] = slot_id
         ids[g:] = ids[g - 1]
@@ -3624,6 +3781,9 @@ class EngineCore:
         if self.split is not None and self._loop_tag() == "prefill":
             self.split.stage_group(group, logits)
             self.split.pump_handoffs()
+            return
+        if self.block > 1:
+            self._activate_block_group(group, padded_slot_ids, padded_lens)
             return
         # inside a step (the prefill paths) this is its `activate` span;
         # a handoff adoption between steps stays in the loop's bucket
@@ -3718,6 +3878,99 @@ class EngineCore:
             slot.last_emit_at = 0.0
             slot.first_pending = True
 
+    # the per-slot device arrays an activation of a block family writes, in
+    # the order of _activate_block_group's rows
+    _BLOCK_ROW_STATE = ("_d_temps", "_d_top_ps", "_d_top_ks", "_d_seeds",
+                        "_d_seq_lens", "_d_blk", "_d_masked", "_d_left",
+                        "_d_skip", "_d_per_pass", "_d_dynamic",
+                        "_d_threshold")
+
+    def _block_state(self) -> tuple:
+        """A block family's per-row decode state, in the order the block
+        program takes and returns it."""
+        return (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
+                self._d_skip)
+
+    def _block_sampling(self) -> tuple:
+        """What the block program reads and never writes, after the tables:
+        each row's sampling params and its unmasking procedure."""
+        return (self._d_temps, self._d_top_ps, self._d_top_ks, self._d_seeds,
+                self._d_per_pass, self._d_dynamic, self._d_threshold)
+
+    def _activate_block_group(self, group: list[tuple[int, Request, int]],
+                              padded_slot_ids: np.ndarray,
+                              padded_lens: np.ndarray) -> None:
+        """_activate_group for a block family: nothing is sampled — the
+        prefill's logits are of the last prompt block's own tokens. Each
+        row's committed length is the `n` whole-block tokens just prefilled;
+        the prompt's remainder opens its first block as given tokens, the
+        rest of the block masked; `left` is the positions the row may yet
+        commit (given ones included), from which the program stops it."""
+        self._clock().mark("activate")
+        g, padded, b = len(group), len(padded_slot_ids), self.block
+        cfg = self.cfg
+        temps = np.ones((padded,), np.float32)
+        top_ps = np.ones((padded,), np.float32)
+        top_ks = np.zeros((padded,), np.int32)
+        seeds = np.full((padded,), -1, np.int32)
+        blk = np.full((padded, b), cfg.mask_token_id, np.int32)
+        masked = np.ones((padded, b), np.bool_)
+        left = np.zeros((padded,), np.int32)
+        skip = np.zeros((padded,), np.int32)
+        per_pass = np.ones((padded,), np.int32)
+        dynamic = np.zeros((padded,), np.bool_)
+        threshold = np.ones((padded,), np.float32)
+        for row, (slot_id, request, n) in enumerate(group):
+            s = request.sampling
+            temps[row], top_ps[row], top_ks[row] = (s.temperature, s.top_p,
+                                                    s.top_k)
+            if s.seed is not None:
+                seeds[row] = s.seed & 0x7FFFFFFF
+            rest = self._effective_prompt(request)[n:]
+            r = len(rest)
+            blk[row, :r] = rest
+            masked[row, :r] = False
+            skip[row] = r
+            done = request.parked.generated if request.parked else 0
+            limit = min(s.max_tokens,
+                        done + self.slot_capacity - b - n - r)
+            left[row] = r + limit - done
+            steps = (cfg.denoising_steps if s.denoising_steps is None
+                     else s.denoising_steps)
+            per_pass[row] = b // steps
+            dynamic[row] = ((s.remasking_strategy or cfg.remasking_strategy)
+                            == "low_confidence_dynamic")
+            threshold[row] = (cfg.confidence_threshold
+                              if s.confidence_threshold is None
+                              else s.confidence_threshold)
+            slot = self.slots[slot_id]
+            slot.given, slot.token_limit = r, limit
+        rows = (temps, top_ps, top_ks, seeds, padded_lens, blk, masked, left,
+                skip, per_pass, dynamic, threshold)
+        for arr in rows:
+            arr[g:] = arr[g - 1]
+        state = _activate_block_rows(
+            padded_slot_ids, rows,
+            tuple(getattr(self, name) for name in self._BLOCK_ROW_STATE))
+        for name, arr in zip(self._BLOCK_ROW_STATE, state):
+            setattr(self, name, arr)
+        for slot_id, request, n in group:
+            self._seq_lens[slot_id] = n
+            slot = self.slots[slot_id]
+            slot.request = request
+            slot.generated, slot.out_tokens = 0, []
+            if request.parked is not None:
+                # preemption resume: the committed tokens were prefilled as
+                # prompt; the open block starts over from masks, and the
+                # seeded keys of its passes are the ones it drew before
+                st = request.parked
+                slot.generated, slot.out_tokens = st.generated, list(st.tokens)
+                request.parked = None
+                self.metrics.record_resume()
+                self._fr_emit(request, "resumed", generated=st.generated)
+            slot.last_emit_at = 0.0
+            slot.first_pending = False
+
     def _cp_bucket_for(self, n: int) -> int:
         """Padded length for the context-parallel prefill jit cache: next
         power of two (≥ the largest one-shot bucket), capped at capacity."""
@@ -3784,7 +4037,7 @@ class EngineCore:
             return True
 
         prompt = self._effective_prompt(request)
-        n = len(prompt)
+        n = len(prompt) - len(prompt) % self.block
         start = slot.prefill_pos
         chunk_max = self.prefill_buckets[-1]
         prefill_budget = self._prefill_budget_now()
@@ -4024,6 +4277,8 @@ class EngineCore:
         # no drafter attached anywhere this block is a no-op and the decode
         # path below is bit-identical to the pre-speculation engine.
         clock = self._clock()
+        if self.block > 1:
+            return self._decode_blocks(active, clock)
         if self._spec_available and any(
             self.slots[i].drafter is not None for i in active
         ):
@@ -4174,6 +4429,187 @@ class EngineCore:
         )
         return True
 
+    def _block_reach(self) -> int:
+        """Positions past its committed length that a row may write in one
+        burst: a block commits at most every second pass (one to unmask,
+        one to commit), and the open block lies behind the last commit."""
+        return self.block * (-(-self.decode_burst // 2) + 1)
+
+    def _build_block_many(self, k: int, window: int) -> Callable:
+        """Jit a burst of k BLOCK PASSES (the decode program of a family
+        that generates by diffusion over blocks; traced as `many`, like
+        _build_decode_many's). Per row the scan carries the open block's ids
+        and mask flags, the committed length, the positions left and the
+        given tokens at the block's head. One pass: the family's block pass
+        (verify_step_paged: B ids behind the committed cache, logits at
+        every position, the block's K and V written past the committed
+        length) -> each position's sampled id and its probability -> a block
+        that ENTERED the pass with no mask is committed (length += B, a new
+        block of masks opens, its ids go out), else the masked positions
+        unmask by the row's strategy: the `per_pass` most probable, and
+        under `dynamic` every one above `threshold`. Rows are at different
+        passes of their blocks. A row whose positions are used up, or whose
+        committed block holds EOS past its given tokens, stops: it runs no
+        further pass and writes to the slot's last cell alone, as the rows
+        that are not decoding do. Returns the state, the caches, and ONE
+        int32 array for the burst's one fetch: per pass B + 2 rows of
+        [SLOTS] — the committed block's ids (-1: no commit), the positions
+        unmasked, whether the row ran — and the family's step counters
+        behind them (_pack_step_counters)."""
+        family, cfg, mesh = self.family, self.cfg, self.mesh
+        shapes, max_names = self._counter_shapes, self._counter_max
+        b, mask_id, eos = self.block, cfg.mask_token_id, self.eos_id
+        park = self.slot_capacity - 1
+
+        def many(params, blk, masked, lens, left, skip, cache_k, cache_v,
+                 tables, temps, top_ps, top_ks, seeds, per_pass, dynamic,
+                 threshold, key, live):
+            keys = jax.random.split(key, k)
+            offs = jnp.arange(b, dtype=jnp.int32)
+
+            def body(carry, step_key):
+                blk, masked, lens, left, skip, ck, cv = carry
+                run = live & (left > 0)
+                logits, ck, cv, *stats = family.verify_step_paged(
+                    params, cfg, blk, jnp.where(run, b, 0),
+                    jnp.where(run, lens, park), tables, ck, cv, mesh,
+                    window=window)
+                n_masked = jnp.sum(masked, axis=1, dtype=jnp.int32)
+                commit = run & (n_masked == 0)
+                ids, conf = _sample_block(logits, step_key, temps, top_ps,
+                                          top_ks, seeds, lens, n_masked)
+                conf = jnp.where(masked, conf, -1.0)
+                # rank among the block's masked positions, the more
+                # probable first and of equals the earlier: [S, i, j] is
+                # "j goes before i"
+                ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                    (conf[:, None, :] == conf[:, :, None])
+                    & (offs[None, None, :] < offs[None, :, None]))
+                rank = jnp.sum(ahead, axis=2, dtype=jnp.int32)
+                pick = ((rank < per_pass[:, None])
+                        | (dynamic[:, None] & (conf > threshold[:, None])))
+                pick = pick & masked & run[:, None]
+                stop = commit & jnp.any(
+                    (blk == eos) & (offs[None, :] >= skip[:, None]), axis=1)
+                out = jnp.concatenate([
+                    jnp.where(commit[:, None], blk, -1),
+                    jnp.sum(pick, axis=1, dtype=jnp.int32)[:, None],
+                    run[:, None].astype(jnp.int32)], axis=1)  # [S, B + 2]
+                blk = jnp.where(commit[:, None], mask_id,
+                                jnp.where(pick, ids, blk))
+                masked = jnp.where(commit[:, None], True, masked & ~pick)
+                lens = jnp.where(commit, lens + b, lens)
+                left = jnp.where(stop, 0, jnp.where(commit, left - b, left))
+                skip = jnp.where(commit, 0, skip)
+                return (blk, masked, lens, left, skip, ck, cv), (out.T, stats)
+
+            (blk, masked, lens, left, skip, cache_k, cache_v), (
+                out, stats) = jax.lax.scan(
+                body, (blk, masked, lens, left, skip, cache_k, cache_v), keys)
+            out = out.reshape(k * (b + 2), -1)
+            if shapes:
+                out = _pack_step_counters(out, stats[0], shapes, max_names)
+            return blk, masked, lens, left, skip, cache_k, cache_v, out
+
+        return jax.jit(many, donate_argnums=(6, 7))
+
+    def _block_many_for(self, window: int) -> Callable:
+        with self._decode_many_lock:
+            fn = self._decode_many.get(window)
+            if fn is None:
+                fn = self._shared_program(
+                    "block_many", (self.decode_burst, window, self.eos_id,
+                                   self.slot_capacity),
+                    lambda: self._build_block_many(self.decode_burst,
+                                                   window))
+                self._decode_many[window] = fn
+            return fn
+
+    def _decode_blocks(self, active: list[int], clock: LoopClock) -> bool:
+        """A decode step of a block family: ONE dispatch of `decode_burst`
+        block passes over the decoding rows, one fetch, and the blocks the
+        rows committed in it emitted in order (_emit_blocks)."""
+        step = clock.begin("host_sync")
+        t_sync = step.t0
+        k, b, reach = self.decode_burst, self.block, self._block_reach()
+        # alloc-on-extend: every cell a commit of this burst may write
+        active = self._ensure_decode_pages(active, reach - 1)
+        if not active:
+            clock.abandon()
+            self.metrics.set_batch_occupancy(0)
+            return True  # pool exhaustion finished requests: work done
+        self._sync_block_tables()
+        self._key, sk = jax.random.split(self._key)
+        window = self._window_for(active, reach - 1)
+        kv_pages = self._kv_pages(active, reach, window)
+        fn = self._block_many_for(window)
+        step.mark("dispatch")
+        (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
+         self._d_skip, self.cache_k, self.cache_v, out_dev) = fn(
+            self.params, *self._block_state(), self.cache_k, self.cache_v,
+            self._d_block_tables, *self._block_sampling(), sk,
+            self._live_rows(active))
+        step.mark("compute")
+        jax.block_until_ready(out_dev)
+        step.mark("fetch")
+        out = self._fetch_tokens(out_dev)  # ONE D2H sync per burst
+        counters = None
+        if self._counter_shapes:
+            out, counters = _unpack_step_counters(
+                out, k * (b + 2), self.num_slots, self._counter_shapes)
+        burst_s = step.mark("emit") - t_sync
+        self.metrics.record_decode_step(burst_s / k, len(active))
+        block = self._emit_blocks(out.reshape(k, b + 2, self.num_slots),
+                                  active, burst_s)
+        self._record_step(
+            "decode", step, active_slots=len(active),
+            tokens=block["tokens_committed"], slots=active, dispatches=1,
+            fused=True, kv_pages=kv_pages, counters=counters, block=block)
+        return True
+
+    def _emit_blocks(self, out: np.ndarray, active: list[int],
+                     burst_s: float) -> dict:
+        """Deliver one fetched burst of block passes, `out` [passes, B + 2,
+        SLOTS] as _build_block_many lays it out: each block a row committed,
+        in order, less the given tokens at the head of its first block, as
+        ONE event of several tokens; a request that ends inside a block
+        (max_tokens, EOS, cancel) takes the tokens before its end. Returns
+        the burst's counts for its step record."""
+        b = self.block
+        commits = {i: int((out[:, 0, i] >= 0).sum()) for i in active}
+        counts = {"block_passes": int(out.shape[0]),
+                  "row_passes": int(out[:, b + 1][:, active].sum()),
+                  "positions_unmasked": int(out[:, b][:, active].sum()),
+                  "blocks_committed": 0, "tokens_committed": 0}
+        committed: dict[int, tuple] = {}  # row -> (blocks, tokens, request)
+        for t in range(out.shape[0]):
+            for i in active:
+                slot = self.slots[i]
+                if out[t, 0, i] < 0 or slot.request is None:
+                    continue
+                request = slot.request
+                fresh = out[t, slot.given:b, i].tolist()
+                slot.given = 0
+                start = int(self._seq_lens[i])
+                self._seq_lens[i] = start + b
+                held: list[int] = []
+                # the burst's pacing, amortized over what the row committed
+                itl = burst_s / (commits[i] * b)
+                for token in fresh:
+                    self._emit(i, token, itl=itl, held=held)
+                    if slot.request is None:
+                        break  # finished (its tokens went out before `done`)
+                if held:
+                    request.events.put(("tokens", held))
+                counts["blocks_committed"] += 1
+                counts["tokens_committed"] += b
+                n_blocks, n_tokens, _ = committed.get(i, (0, 0, None))
+                committed[i] = (n_blocks + 1, n_tokens + len(fresh), request)
+        for n_blocks, n_tokens, request in committed.values():
+            self._fr_emit(request, "commit", blocks=n_blocks,
+                          tokens=n_tokens)
+        return counts
+
     def _emit_fetched(self, tokens, active: list[int],
                       itl: float | None) -> None:
         """Deliver one fetched token matrix [rows, SLOTS]: row 0 holds
@@ -4199,12 +4635,16 @@ class EngineCore:
                 self._emit(i, int(tokens[t, i]), itl=itl)
 
     def _emit(self, slot_id: int, token: int,
-              itl: float | None = None, first: bool = False) -> None:
+              itl: float | None = None, first: bool = False,
+              held: list[int] | None = None) -> None:
         """Deliver one generated token. `itl` overrides the wall-clock
         inter-token gap (burst decode delivers k tokens back-to-back; the
         caller passes the amortized pacing instead). `first` marks the
         deferred first emission, whose grammar advance already happened at
-        activation."""
+        activation. `held` (a committed block's tokens, _emit_blocks)
+        collects the token for ONE ("tokens", [ids]) event in place of a
+        ("token", id) event of its own; where the request ends here, what
+        was held goes out before its `done`."""
         slot = self.slots[slot_id]
         request = slot.request
         assert request is not None
@@ -4268,6 +4708,8 @@ class EngineCore:
             finish = "length"
         elif self._seq_lens[slot_id] + 1 >= self.slot_capacity:
             finish = "length"
+        elif self.block > 1 and slot.generated >= slot.token_limit:
+            finish = "length"  # what the slot's capacity left a block row
         if (finish is not None and finish != "stop" and state is not None
                 and not state.is_accepting):
             # cut short (max_tokens / capacity) before grammar acceptance
@@ -4275,10 +4717,15 @@ class EngineCore:
 
         if finish == "stop":
             pass  # EOS itself is not emitted as content
+        elif held is not None:
+            held.append(token)
         else:
             request.events.put(("token", token))
 
         if finish is not None:
+            if held:
+                request.events.put(("tokens", list(held)))
+                held.clear()
             request.finished_at = time.monotonic()
             if finish == "length" and request.export_kv and self.kv_ship:
                 # Handoff export: serialize this stream's KV pages D2H

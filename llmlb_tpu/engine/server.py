@@ -93,8 +93,36 @@ def _sampling_from(body: dict, default_max: int = 256) -> SamplingParams:
     return SamplingParams(
         temperature=temperature, top_p=top_p, top_k=top_k,
         max_tokens=max_tokens, speculative=_speculative_from(body),
-        priority=_priority_from(body),
+        priority=_priority_from(body), **_block_params_from(body),
     )
+
+
+def _block_params_from(body: dict) -> dict:
+    """Per-request parameters of generation by diffusion over blocks (an
+    OpenAI-dialect extension, docs/block-diffusion.md): `block_length`,
+    `denoising_steps`, `remasking_strategy`, `confidence_threshold`. Absent
+    -> the model configuration's. Types are checked here; whether the model
+    generates by blocks at all, and whether the values fit its block, is
+    the scheduler's to say at submission (a 400 either way)."""
+    out: dict = {}
+    for name in ("block_length", "denoising_steps"):
+        v = body.get(name)
+        if v is None:
+            continue
+        if not isinstance(v, int) or isinstance(v, bool) or v < 1:
+            raise ValueError(f"'{name}' must be a positive integer")
+        out[name] = v
+    v = body.get("remasking_strategy")
+    if v is not None:
+        if not isinstance(v, str):
+            raise ValueError("'remasking_strategy' must be a string")
+        out["remasking_strategy"] = v
+    v = body.get("confidence_threshold")
+    if v is not None:
+        if isinstance(v, bool) or not isinstance(v, (int, float)):
+            raise ValueError("'confidence_threshold' must be a number")
+        out["confidence_threshold"] = float(v)
+    return out
 
 
 _PRIORITY_NAMES = {"high": 0, "normal": 1, "low": 2}
@@ -813,6 +841,19 @@ class EngineAPI:
             "hint": "tensorboard --logdir <trace_dir> (profile plugin)",
         })
 
+    def _sampling(self, body: dict, default_max: int = 256) -> SamplingParams:
+        """_sampling_from, and what this engine's model cannot serve of the
+        body refused by name: per-token `logprobs` of a model that generates
+        by diffusion over blocks (a position's sampled probability is of one
+        pass among several; none is served wrong)."""
+        core = getattr(self.engine, "core", None)
+        if getattr(core, "block", 1) > 1 and (
+                body.get("logprobs") or body.get("top_logprobs")):
+            raise ValueError(
+                "'logprobs' are not served by a model that generates by "
+                "diffusion over blocks")
+        return _sampling_from(body, default_max)
+
     # ------------------------------------------------------ chat completions
 
     def _parse_chat(self, request: web.Request, body: dict):
@@ -837,7 +878,7 @@ class EngineAPI:
         # enforces token by token. Malformed or uncompilable requests 400
         # here with the offending feature named.
         structured = inspect_request(body)
-        sampling = _sampling_from(body)
+        sampling = self._sampling(body)
         sampling.seed = parse_seed(body)
         sampling.deadline_ms = _deadline_from(request)
         if structured is not None:
@@ -1245,7 +1286,7 @@ class EngineAPI:
             return _error(400, "'prompt' must be a non-empty string")
         model = body.get("model") or self.engine.model_id
         prompt_ids = self.engine.tokenizer.encode(prompt)
-        sampling = _sampling_from(body, default_max=16)
+        sampling = self._sampling(body, default_max=16)
         sampling.deadline_ms = _deadline_from(request)  # middleware 400s bad values
         adapter, base = self._parse_lora(body)  # middleware 400s bad values
         if adapter is not None:
@@ -1345,7 +1386,7 @@ class EngineAPI:
             messages = [{"role": "system", "content": body["instructions"]}] + messages
 
         prompt_ids = self.engine.encode_chat(messages)
-        sampling = _sampling_from(body)
+        sampling = self._sampling(body)
         sampling.deadline_ms = _deadline_from(request)
         response_id = f"resp_{uuid.uuid4().hex[:24]}"
         created = int(time.time())

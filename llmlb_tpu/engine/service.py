@@ -226,12 +226,13 @@ class Engine:
                 )
                 if kind == "error":
                     raise EngineError(str(value))
-                if kind == "token":
-                    completion_tokens += 1
-                    if completion_tokens == 1 and request.first_token_at:
+                if kind in _TOKEN_EVENTS:
+                    if not completion_tokens and request.first_token_at:
                         ttft = request.first_token_at - request.submitted_at
-                    pending_ids.append(int(value))
-                    acc += detok.push(int(value))
+                    for token in _tokens_of(kind, value):
+                        completion_tokens += 1
+                        pending_ids.append(token)
+                        acc += detok.push(token)
                 else:  # done
                     acc += detok.flush()
 
@@ -321,8 +322,8 @@ class Engine:
                 )
                 if kind == "error":
                     raise EngineError(str(value))
-                if kind == "token":
-                    committed.append(int(value))
+                if kind in _TOKEN_EVENTS:
+                    committed.extend(_tokens_of(kind, value))
                 else:  # done
                     finish = str(value)
                     break
@@ -523,13 +524,14 @@ class Engine:
                 )
                 if kind == "error":
                     raise EngineError(str(value))
-                if kind == "token":
-                    completion_tokens += 1
+                if kind in _TOKEN_EVENTS:
                     if ttft is None and request.first_token_at:
                         ttft = (request.first_token_at
                                 - request.submitted_at)
-                    pending_ids.append(int(value))
-                    acc += detok.push(int(value))
+                    for token in _tokens_of(kind, value):
+                        completion_tokens += 1
+                        pending_ids.append(token)
+                        acc += detok.push(token)
                 else:  # done
                     acc += detok.flush()
 
@@ -704,6 +706,16 @@ class Engine:
 
 class EngineError(RuntimeError):
     pass
+
+
+# A request's content events (scheduler.Request.events): one token, or the
+# several tokens a block family's row committed at once — one delta, and so
+# one frame on the wire, carries them all.
+_TOKEN_EVENTS = ("token", "tokens")
+
+
+def _tokens_of(kind: str, value) -> list[int]:
+    return [int(value)] if kind == "token" else [int(t) for t in value]
 
 
 def _find_stop(text: str, stops: list[str]) -> int | None:

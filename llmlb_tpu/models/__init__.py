@@ -7,7 +7,9 @@ paged KV pool) for a config, so the engine scheduler is family-agnostic: dense
 Llama-class (llama.py) and sparse-MoE Mixtral-class (mixtral.py) plug into the
 same continuous-batching loop; DeepSeek-V3-class (deepseek_v3.py: latent
 attention, sigmoid-routed experts behind leading dense layers) brings its own
-attention and layer stack to the same bodies.
+attention and layer stack to the same bodies, and SDAR-MoE-class
+(sdar_moe.py: QK-normed GQA under a block-causal mask, generation by
+diffusion over blocks) declares a block length the scheduler decodes by.
 
 `config_from_hf(hf, dtype)` picks the configuration class of a published
 `config.json` by its `model_type` and refuses a config that carries a key the
@@ -34,7 +36,11 @@ MODEL_TYPES = {
     "llama": "llama", "mistral": "llama", "qwen2": "llama",
     "mixtral": "mixtral",
     "deepseek_v3": "deepseek_v3",
+    "sdar_moe": "sdar_moe",
 }
+_CONFIG_CLASSES = {"llama": "LlamaConfig", "mixtral": "MixtralConfig",
+                   "deepseek_v3": "DeepseekV3Config",
+                   "sdar_moe": "SdarMoeConfig"}
 
 # Keys that change the function a model computes, and the classes that read
 # them. A config carrying one for a class that does not read it would be
@@ -47,7 +53,8 @@ _MECHANISM_KEYS = {
     "n_shared_experts": ("deepseek_v3",),
     "first_k_dense_replace": ("deepseek_v3",),
     "num_local_experts": ("mixtral",),
-    "num_experts": ("mixtral",),
+    "num_experts": ("mixtral", "sdar_moe"),
+    "moe_intermediate_size": ("deepseek_v3", "sdar_moe"),
     "sliding_window": (),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
@@ -71,6 +78,9 @@ def config_from_hf(hf: dict, dtype=None):
         value = hf.get(key)
         if family in readers or value in _ABSENT:
             continue
+        if (key == "moe_intermediate_size"
+                and value == hf.get("intermediate_size")):
+            continue  # the width the class reads is the experts' own
         if key == "sliding_window" and hf.get("use_sliding_window") is False:
             continue  # stated and switched off (Qwen2)
         raise ValueError(
@@ -79,18 +89,19 @@ def config_from_hf(hf: dict, dtype=None):
             "another model. Add the mechanism or the model_type "
             "(llmlb_tpu/models/__init__.py MODEL_TYPES)")
     module = importlib.import_module(f"llmlb_tpu.models.{family}")
-    cls = {"llama": "LlamaConfig", "mixtral": "MixtralConfig",
-           "deepseek_v3": "DeepseekV3Config"}[family]
+    cls = _CONFIG_CLASSES[family]
     kwargs = {} if dtype is None else {"dtype": dtype}
     return getattr(module, cls).from_hf_config(hf, **kwargs)
 
 
 def family_for(cfg):
     """Resolve the serving-function module for a model config."""
-    from llmlb_tpu.models import deepseek_v3, llama, mixtral
+    from llmlb_tpu.models import deepseek_v3, llama, mixtral, sdar_moe
 
     if isinstance(cfg, deepseek_v3.DeepseekV3Config):
         return deepseek_v3
+    if isinstance(cfg, sdar_moe.SdarMoeConfig):
+        return sdar_moe
     if isinstance(cfg, mixtral.MixtralConfig):
         return mixtral
     if isinstance(cfg, LlamaConfig):

@@ -71,18 +71,30 @@ def gqa_attention_prefill(
     k: jnp.ndarray,  # [B, T, K, D]
     v: jnp.ndarray,  # [B, T, K, D]
     prompt_lens: jnp.ndarray,  # [B] int32 — tokens beyond this are padding
+    block: int = 1,  # static: the mask's block length (_block_end)
 ) -> jnp.ndarray:
-    """Causal self-attention over a freshly-prefilled prompt. Returns [B, T, H, D]."""
+    """Causal self-attention over a freshly-prefilled prompt, block-causal
+    with `block` > 1. Returns [B, T, H, D]."""
     if _pallas_enabled():
         from llmlb_tpu.ops.pallas_attention import flash_prefill
 
         _traced["prefill"] = "pallas:flash_prefill"
-        return flash_prefill(q, k, v, prompt_lens)
+        return flash_prefill(q, k, v, prompt_lens, block=block)
     _traced["prefill"] = "xla"
-    return _prefill_einsum(q, k, v, prompt_lens)
+    return _prefill_einsum(q, k, v, prompt_lens, block)
 
 
-def _prefill_einsum(q, k, v, prompt_lens):
+def _block_end(pos, block: int):
+    """The last position a query at `pos` may see. `block` (STATIC) is the
+    length of the blocks generation by diffusion works in: position j is
+    visible to i iff j // block <= i // block — causal across blocks,
+    bidirectional inside one. At 1 that is the causal mask, and the value
+    is `pos` itself: the programs of every autoregressive family are the
+    ones they were."""
+    return pos if block == 1 else pos - pos % block + (block - 1)
+
+
+def _prefill_einsum(q, k, v, prompt_lens, block: int = 1):
     """gqa_attention_prefill as plain einsums; the values may be narrower
     than the keys. Returns [B, T, H, Dv]."""
     b, t, h, d = q.shape
@@ -96,7 +108,7 @@ def _prefill_einsum(q, k, v, prompt_lens):
     ) * scale
 
     pos = jnp.arange(t, dtype=jnp.int32)
-    causal = pos[:, None] >= pos[None, :]  # [Tq, Tk]
+    causal = _block_end(pos, block)[:, None] >= pos[None, :]  # [Tq, Tk]
     valid = pos[None, :] < prompt_lens[:, None, None]  # broadcast to [B, 1, Tk]
     mask = causal[None, :, :] & valid  # [B, Tq, Tk]
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
@@ -114,10 +126,12 @@ def gqa_attention_extend(
     k_cache: jnp.ndarray,  # [B, S, K, D] — contiguous rows incl. the chunk's
     v_cache: jnp.ndarray,  # [B, S, K, D]
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
+    block: int = 1,  # static: the mask's block length (_block_end)
 ) -> jnp.ndarray:
     """Chunked-prefill attention, plain einsum: a chunk of T queries attends
     causally against contiguous per-row KV (earlier chunks + this chunk).
-    Query i at global position p may see positions <= p. Returns
+    Query i at global position p may see positions <= p, or with `block`
+    > 1 up to the end of p's block. Returns
     [B, T, H, D]. Generalizes decode (T=1). The reference the paged extend
     kernels are checked against, and what the paged XLA fall-back
     (paged_attention_extend) ends in after gathering its pages."""
@@ -132,7 +146,8 @@ def gqa_attention_extend(
 
     s = k_cache.shape[1]
     cap_pos = jnp.arange(s, dtype=jnp.int32)
-    mask = cap_pos[None, None, :] <= q_positions[:, :, None]  # [B, T, S]
+    mask = (cap_pos[None, None, :]
+            <= _block_end(q_positions, block)[:, :, None])  # [B, T, S]
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
 
     probs = jax.nn.softmax(scores, axis=-1)
@@ -265,10 +280,13 @@ def paged_attention_extend(
     block_tables: jnp.ndarray,  # [B, PPN] int32
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
     chunk_lens: jnp.ndarray,  # [B] int32 — valid queries in the chunk
+    block: int = 1,  # static: the mask's block length (_block_end)
 ) -> jnp.ndarray:
     """Chunked-prefill attention against one layer of the KV page pool: the
-    chunk's queries attend causally over row b's pages (earlier chunks +
-    this chunk). Assumes the engine's contiguous chunk positions
+    chunk's queries attend causally (block-causally with `block` > 1, the
+    chunk then starting and ending on block boundaries) over row b's pages
+    (earlier chunks + this chunk). Assumes the engine's contiguous chunk
+    positions
     (q_positions[b] = start + iota). The pool arrives stacked with the layer
     index beside it, as paged_attention_decode's does and for its reason:
     under the extend program's layer scan `layer` is a run-time value and
@@ -281,21 +299,21 @@ def paged_attention_extend(
             return paged_flash_extend_quant(
                 q, k_pages["q"], k_pages["s"][layer], v_pages["q"],
                 v_pages["s"][layer], layer, block_tables, q_positions[:, 0],
-                chunk_lens,
+                chunk_lens, block=block,
             )
         from llmlb_tpu.ops.pallas_attention import paged_flash_extend
 
         _traced["paged_extend"] = "pallas:paged_flash_extend"
         return paged_flash_extend(
             q, k_pages, v_pages, layer, block_tables, q_positions[:, 0],
-            chunk_lens,
+            chunk_lens, block=block,
         )
     _traced["paged_extend"] = "xla"
     k_cache = gather_kv_pages(k_pages, block_tables, dtype=q.dtype,
                               layer=layer)
     v_cache = gather_kv_pages(v_pages, block_tables, dtype=q.dtype,
                               layer=layer)
-    return gqa_attention_extend(q, k_cache, v_cache, q_positions)
+    return gqa_attention_extend(q, k_cache, v_cache, q_positions, block)
 
 
 def gqa_attention_decode(

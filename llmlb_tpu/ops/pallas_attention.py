@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llmlb_tpu.ops.attention import _block_end
+
 _NEG_INF = -1e30  # finite: keeps fully-masked softmax rows NaN-free
 
 
@@ -532,6 +534,7 @@ def _prefill_kernel(
     num_kv: int,
     groups: int,
     scale: float,
+    block: int,
 ):
     b = pl.program_id(0)
     qi = pl.program_id(1)
@@ -549,7 +552,7 @@ def _prefill_kernel(
     k_start = ki * block_k
     rows = block_q * groups
     # causal skip: the whole KV block is in the future of the whole Q block
-    not_all_future = k_start <= q_start + block_q - 1
+    not_all_future = k_start <= _block_end(q_start + block_q - 1, block)
     # ragged skip: the whole KV block is beyond the prompt
     in_prompt = k_start < prompt_len
 
@@ -560,7 +563,7 @@ def _prefill_kernel(
             jnp.int32, (rows, block_k), dimension=1
         )
         q_pos = q_start + row // groups
-        mask = (col <= q_pos) & (col < prompt_len)
+        mask = (col <= _block_end(q_pos, block)) & (col < prompt_len)
         for h in range(num_kv):  # static unroll over KV heads
             q = q_ref[0, :, h].reshape(rows, -1)  # [BLK_Q*G, D]; t slow, g fast
             k = k_ref[0, :, h, :]  # [BLK_K, D]
@@ -581,7 +584,7 @@ def _prefill_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("block_q", "block_k", "interpret", "block")
 )
 def flash_prefill(
     q: jnp.ndarray,  # [B, T, H, D]
@@ -592,8 +595,10 @@ def flash_prefill(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    block: int = 1,
 ) -> jnp.ndarray:
-    """Causal ragged GQA prefill attention. Returns [B, T, H, D] in q.dtype."""
+    """Causal ragged GQA prefill attention, block-causal with `block` > 1
+    (ops/attention._block_end). Returns [B, T, H, D] in q.dtype."""
     if interpret is None:
         interpret = _interpret_default()
     b, t, h, d = q.shape
@@ -641,6 +646,7 @@ def flash_prefill(
             num_kv=num_kv,
             groups=g,
             scale=d**-0.5,
+            block=block,
         ),
         out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
         grid_spec=grid_spec,
@@ -658,7 +664,7 @@ def flash_prefill(
 
 def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
                  acc_ref, kv_head, *, block_q: int, block_k: int,
-                 num_kv: int, groups: int, scale: float):
+                 num_kv: int, groups: int, scale: float, block: int):
     """One grid step (row b, q block qi, KV block ki) of a paged extend
     kernel: online softmax (m/l/acc) lives in VMEM scratch across a q
     block's KV blocks; `kv_head(h)` loads head h's [BLK_K, D] keys and
@@ -684,7 +690,7 @@ def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
     # also skip Q blocks made entirely of padding rows (beyond chunk_lens) —
     # their zero-initialized output is ignored by the caller.
     useful = jnp.logical_and(
-        k_start <= start + q_start + block_q - 1,
+        k_start <= _block_end(start + q_start + block_q - 1, block),
         q_start < chunk_lens_ref[b],
     )
 
@@ -695,7 +701,7 @@ def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
             jnp.int32, (rows, block_k), dimension=1
         )
         q_pos = start + q_start + row // groups  # global position per query
-        mask = col <= q_pos
+        mask = col <= _block_end(q_pos, block)
         for h in range(num_kv):  # static unroll over KV heads
             q = q_ref[0, :, h].reshape(rows, -1)  # [BLK_Q*G, D]
             k, v = kv_head(h)  # [BLK_K, D] each
@@ -794,7 +800,7 @@ def _paged_extend_quant_kernel(
 
 
 def _paged_extend_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
-                       start_pos, chunk_lens, *, block_q, interpret):
+                       start_pos, chunk_lens, *, block_q, interpret, block):
     """The pallas_call both paged extend kernels share: grid (row, q block,
     logical page); q and out blocks follow (row, q block), the KV blocks
     (`kv_specs`, one per operand of `kv_operands`) the row's page."""
@@ -819,7 +825,7 @@ def _paged_extend_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
     )
     out = pl.pallas_call(
         functools.partial(kernel, block_q=blk_q, block_k=ps, num_kv=num_kv,
-                          groups=g, scale=d**-0.5),
+                          groups=g, scale=d**-0.5, block=block),
         out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -829,7 +835,7 @@ def _paged_extend_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
     return out.reshape(b, t, h, d)
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret", "block"))
 def paged_flash_extend(
     q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
     k_pages: jnp.ndarray,  # [L, P, PS, K, D] — global page pool, all layers
@@ -841,9 +847,11 @@ def paged_flash_extend(
     *,
     block_q: int = 128,
     interpret: bool | None = None,
+    block: int = 1,
 ) -> jnp.ndarray:
     """Paged chunked-prefill attention: T contiguous queries starting at
-    global position start_pos[b] attend causally over row b's pages (earlier
+    global position start_pos[b] attend causally (block-causally with
+    `block` > 1, ops/attention._block_end) over row b's pages (earlier
     chunks + this chunk), gathered through the prefetched block table by the
     KV BlockSpec index_map. KV blocks entirely in the future of the chunk
     skip their FLOPs (`pl.when` in _extend_kernel), so cost scales with the
@@ -859,10 +867,10 @@ def paged_flash_extend(
     return _paged_extend_call(
         _paged_extend_kernel, [kv_spec, kv_spec], (k_pages, v_pages), q,
         layer, block_tables, start_pos, chunk_lens, block_q=block_q,
-        interpret=interpret)
+        interpret=interpret, block=block)
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret", "block"))
 def paged_flash_extend_quant(
     q: jnp.ndarray,  # [B, T, H, D] — chunk of queries
     k_pages: jnp.ndarray,  # [L, P, PS, K, D] int8 — all layers
@@ -876,6 +884,7 @@ def paged_flash_extend_quant(
     *,
     block_q: int = 128,
     interpret: bool | None = None,
+    block: int = 1,
 ) -> jnp.ndarray:
     """Int8 variant of paged_flash_extend: each page's vectors dequantize
     in VMEM. Same causal/ragged skip logic and garbage contract. The values
@@ -891,4 +900,5 @@ def paged_flash_extend_quant(
         _paged_extend_quant_kernel,
         [kv_spec, scale_spec, kv_spec, scale_spec],
         (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
-        start_pos, chunk_lens, block_q=block_q, interpret=interpret)
+        start_pos, chunk_lens, block_q=block_q, interpret=interpret,
+        block=block)

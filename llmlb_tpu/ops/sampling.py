@@ -82,3 +82,18 @@ def sample_tokens(
     sampled_ids = jnp.take_along_axis(top_ids, sampled_idx[:, None], axis=-1)[:, 0]
 
     return jnp.where(temperature <= 0.0, greedy_ids, sampled_ids.astype(jnp.int32))
+
+
+def token_probability(
+    logits: jnp.ndarray,  # [B, V] float32
+    ids: jnp.ndarray,  # [B] int32
+    temperature: jnp.ndarray,  # [B] float32; 0 => greedy
+) -> jnp.ndarray:
+    """The probability [B] f32 of `ids` under the softmax of the FULL
+    logits at `temperature` (at 1 where it is 0: a greedy pick's confidence
+    is its plain probability). What generation by diffusion over blocks
+    unmasks by (engine/scheduler._build_block_many): one max and one sum
+    over the vocabulary beside the sampler's own top-k."""
+    scaled = logits / jnp.where(temperature > 0.0, temperature, 1.0)[:, None]
+    picked = jnp.take_along_axis(scaled, ids[:, None], axis=-1)[:, 0]
+    return jnp.exp(picked - jax.nn.logsumexp(scaled, axis=-1))

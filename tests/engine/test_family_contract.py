@@ -20,11 +20,12 @@ FAMILIES = {
     "llama": family_for(get_preset("debug-tiny")),
     "mixtral": family_for(get_preset("debug-moe-tiny")),
     "deepseek_v3": family_for(get_preset("debug-mla-tiny")),
+    "sdar_moe": family_for(get_preset("debug-sdar-tiny")),
 }
 # Static switches a family may add BEHIND llama's parameters, keyword-only
 # in effect: the benchmark's check passes `routing` (models/deepseek_v3.py),
 # never positionally.
-EXTRA = {"deepseek_v3": ["routing"]}
+EXTRA = {"deepseek_v3": ["routing"], "sdar_moe": ["routing"]}
 
 CONTRACT = (
     "init_params",
@@ -77,7 +78,8 @@ def test_family_says_what_a_token_leaves_in_the_pool(family):
     """The scheduler's page bytes, gauges and KVSH header ask the family."""
     module = FAMILIES[family]
     cfg = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
-           "deepseek_v3": "debug-mla-tiny"}[family]
+           "deepseek_v3": "debug-mla-tiny",
+           "sdar_moe": "debug-sdar-tiny"}[family]
     cfg = get_preset(cfg)
     ck, cv = module.init_kv_pages(cfg, 3, 8)
     per_token = (ck[0, 0, 0].size + cv[0, 0, 0].size) * ck.dtype.itemsize

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import pytest
 
 from llmlb_tpu.engine.presets import get_preset
-from llmlb_tpu.models import deepseek_v3, llama, mixtral
+from llmlb_tpu.models import deepseek_v3, llama, mixtral, sdar_moe
 from llmlb_tpu.ops import pallas_attention
 
 LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM = 3, 7, 8, 2, 16
@@ -323,7 +323,14 @@ LATENT_CFG = deepseek_v3.DeepseekV3Config(  # kanana-2-30b-a3b's widths: one
     vocab_size=128256, hidden_size=2048, intermediate_size=6144,
     num_layers=2, num_heads=32, num_kv_heads=32, head_dim=64,
     rope_theta=1e6, rms_eps=1e-6, max_position_embeddings=32768)
+BLOCK_PAGES = 544
+BLOCK_CFG = sdar_moe.SdarMoeConfig(  # sdar-30b-a3b's widths, two layers deep:
+    # the block mask in the prefill and extend kernels, experts of width 768
+    vocab_size=151936, hidden_size=2048, intermediate_size=6144,
+    num_layers=2, num_heads=32, num_kv_heads=4, head_dim=128,
+    rope_theta=1e6, rms_eps=1e-6, max_position_embeddings=32768)
 COMPILED = {
+    "sdar_moe-bf16": (sdar_moe, BLOCK_CFG, BLOCK_PAGES, False),
     "llama-bf16": (llama, CHIP_CFG, CHIP_PAGES, False),
     "llama-int8": (llama, CHIP_CFG, CHIP_PAGES, True),
     "deepseek_v3-bf16": (deepseek_v3, LATENT_CFG, LATENT_PAGES, False),
@@ -470,3 +477,56 @@ def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
            if re.match(a_layer, shape) or re.match(a_layers_experts, shape)
            or (op == "copy" and re.match(whole_pool, shape))]
     assert not bad, bad
+
+
+# --- a block pass, as the chip's compiler leaves it ---------------------------
+
+def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(one_chip,
+                                                                 monkeypatch):
+    """The block pass of a family that generates by diffusion over blocks
+    (models/sdar_moe.verify_step_paged: 32 rows of a block of 4 behind their
+    committed caches, logits at every position) at the benchmark cell's pool
+    and widths, compiled for a v5e: Mosaic takes the extend kernel under the
+    block mask at 4 queries a row and the grouped expert matmul at 128 rows,
+    no value pool is copied or sliced by the layer, and the temporaries are
+    the pass's logits, not a layer of the pool or of the experts."""
+    from llmlb_tpu.ops import pallas_moe
+
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
+    jitted = (sdar_moe.verify_step_paged, pallas_attention.paged_flash_extend,
+              pallas_moe.grouped_expert_matmul)
+    for f in jitted:
+        f._clear_cache()
+    on_chip = functools.partial(_on_chip, one_chip)
+    cfg, b = BLOCK_CFG, BLOCK_CFG.block_length
+    params = on_chip(jax.eval_shape(
+        lambda key: sdar_moe.init_params(cfg, key), jax.random.PRNGKey(0)))
+    pools = on_chip(jax.eval_shape(
+        lambda: sdar_moe.init_kv_pages(cfg, BLOCK_PAGES, CHIP_PAGE_SIZE)))
+    ids = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, b), jnp.int32))
+    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
+    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = sdar_moe.verify_step_paged.lower(
+                params, cfg, ids, rows, rows, tables, *pools, None,
+                window=1024).compile()
+    finally:
+        for f in jitted:
+            f._clear_cache()
+    hlo = compiled.as_text()
+    # under the layer scan: one extend kernel and three grouped products
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    pool = r"bf16\[(2,|1,)?544,128,4,128\]"
+    experts = r"bf16\[(2,|1,)?128,(2048,768|768,2048)\]"
+    moved = ("copy", "copy-start", "copy-done", "dynamic-slice",
+             "dynamic-update-slice", "concatenate")
+    bad = [(shape, op) for shape, op in results if op in moved
+           and (re.match(pool, shape) or re.match(experts, shape))]
+    assert not bad, bad
+    logits = CHIP_ROWS * b * cfg.vocab_size * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * logits
