@@ -171,6 +171,10 @@ class EngineMetrics:
         # pages of slots x window: live / window is the share of a
         # window-wide sweep that was live context
         self.decode_kv_pages_live_total = 0
+        # How the sampler takes its top 64 of this engine's vocabulary
+        # (ops/sampling.selection_plan): static per engine, set once where
+        # the engine is made, a reading and not a rate
+        self.sampling: dict[str, int] = {}
         # A mixture's expert load, from the family's step counters
         # (scheduler._record_step): running totals, the fullest expert any
         # step saw, and per expert layer how many experts took how many
@@ -563,6 +567,7 @@ class EngineMetrics:
                     self.decode_kv_pages_window_total,
                 "constrained_burst_fallback_total":
                     self.constrained_burst_fallback_total,
+                "sampling": dict(self.sampling),
                 **{f"{name}_total": n
                    for name, n in self.block_totals.items()},
                 "moe_counted_steps_total": self.moe_counted_steps_total,

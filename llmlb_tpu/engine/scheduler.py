@@ -65,7 +65,8 @@ from llmlb_tpu.ops.grammar import (
     grammar_advance,
     grammar_bias,
 )
-from llmlb_tpu.ops.sampling import sample_tokens, token_probability
+from llmlb_tpu.ops.sampling import (sample_tokens, selection_plan,
+                                    token_probability)
 from llmlb_tpu.parallel.mesh import MeshConfig, build_mesh, default_tp
 from llmlb_tpu.quant import kv_cell_bytes, parse_quant_mode, quantize_params
 from llmlb_tpu.spec import PromptLookupDrafter, SpecConfig
@@ -979,6 +980,7 @@ class EngineCore:
         # minimum-bucket rounding) instead of paying each path a full budget.
         self._prefill_spent_iter = 0
         self.metrics = EngineMetrics()
+        self.metrics.sampling = selection_plan(cfg.vocab_size)
         if self.lora is not None:
             self.lora.metrics = self.metrics
         # Step introspection (engine/stepstats.py): per-step span records,

@@ -2,9 +2,22 @@
 
 All sampling parameters are traced arrays (per-request, shape [B]) so one compiled
 decode step serves every request mix — no recompile when a user changes
-temperature. Top-p runs inside a static top-K=64 prefilter: a full 128k-vocab sort
-per step would thrash HBM bandwidth for no quality gain (p-mass beyond the top 64
-logits is negligible at serving temperatures).
+temperature. Top-p runs inside a static top-K=64 prefilter (p-mass beyond the top
+64 logits is negligible at serving temperatures), and the prefilter itself sorts
+no vocabulary: one `lax.top_k` over `[B, V]` costs a v5e 0.2-0.26 ns an element,
+a fifth of a block pass at 151,936 columns (PERF.md §6, PR 35). So
+`_top_k_by_groups` takes each contiguous group's maximum in one pass over the
+logits, picks the 64 groups with the largest maxima, and takes the top 64 of
+those groups' members: `G + 64·g` elements sorted a row instead of V
+(`selection_plan`), with `lax.top_k`'s own values and indices, bit for bit.
+
+Why it is exact. `lax.top_k` orders by (value descending, index ascending).
+Let x be one of the true top 64 in a group that was not picked: each of the 64
+picked groups then holds its maximum m >= x, and where m == x at a lower index
+(groups are contiguous, and the group stage breaks ties by lower index too), so
+64 elements come before x — a contradiction. The picked groups are gathered in
+ascending order, so candidate order is vocabulary order and the second
+`lax.top_k` breaks ties as the one-stage call does.
 
 Two per-request extensions ride the same traced-input discipline (no recompile
 per request mix):
@@ -27,6 +40,52 @@ import jax
 import jax.numpy as jnp
 
 TOPK_PREFILTER = 64
+# Columns in a group of the two-stage selection: one lane tile, so a group is
+# one 512 B row of the gather. Wider groups sort more candidates and narrower
+# ones leave half a tile empty (measured on the chip at 64-512, PERF.md §6).
+_GROUP = 128
+
+
+def selection_plan(vocab: int, k: int = TOPK_PREFILTER) -> dict[str, int]:
+    """How `sample_tokens` takes its top `k` of a row of `vocab` columns, from
+    the static shape alone: `group` columns a group (0: one `lax.top_k` over
+    the row) and `sorted_per_row`, the elements the sorts see a row (`vocab`
+    where one stage is kept). Two stages only where they sort at most half
+    the row: under that the pass for the maxima and the gather buy nothing,
+    and a row of fewer than `k` groups has no second stage at all. Served as
+    /api/health .metrics.sampling."""
+    k = min(k, vocab)
+    by_groups = -(-vocab // _GROUP) + k * _GROUP
+    if 2 * by_groups > vocab:
+        return {"vocab": vocab, "group": 0, "sorted_per_row": vocab}
+    return {"vocab": vocab, "group": _GROUP, "sorted_per_row": by_groups}
+
+
+def _top_k_by_groups(logits: jnp.ndarray, k: int
+                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`jax.lax.top_k(logits, k)` — the same values and indices, ties
+    included — without sorting the row (module docstring has the proof)."""
+    b, v = logits.shape
+    g = selection_plan(v, k)["group"]
+    if not g:
+        return jax.lax.top_k(logits, k)
+    groups = -(-v // g)
+    if groups * g != v:
+        # the padding holds the highest indices, so it loses every tie
+        logits = jnp.pad(logits, ((0, 0), (0, groups * g - v)),
+                         constant_values=-jnp.inf)
+    # rows of one lane tile: the maxima reduce over lanes where the head's
+    # matmul left the logits, and the gather below takes whole rows (a
+    # [B, G, g] view made XLA lay the logits out twice more, PERF.md §6)
+    flat = logits.reshape(b * groups, g)
+    _, picked = jax.lax.top_k(flat.max(axis=-1).reshape(b, groups), k)
+    picked = jnp.sort(picked, axis=-1)  # candidate order = vocabulary order
+    rows = jnp.arange(b, dtype=picked.dtype)[:, None] * groups + picked
+    candidates = flat.at[rows.reshape(-1)].get(
+        mode="promise_in_bounds", indices_are_sorted=True, unique_indices=True)
+    values, at = jax.lax.top_k(candidates.reshape(b, k * g), k)
+    ids = jnp.take_along_axis(picked, at // g, axis=1) * g + at % g
+    return values, ids
 
 
 def sample_tokens(
@@ -50,7 +109,7 @@ def sample_tokens(
     greedy_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     k = min(TOPK_PREFILTER, v)
-    top_logits, top_ids = jax.lax.top_k(logits, k)  # [B, k] sorted desc
+    top_logits, top_ids = _top_k_by_groups(logits, k)  # [B, k] sorted desc
 
     # top-k restriction (within the prefilter window)
     ranks = jnp.arange(k, dtype=jnp.int32)[None, :]

@@ -530,3 +530,42 @@ def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(one_chip,
     assert not bad, bad
     logits = CHIP_ROWS * b * cfg.vocab_size * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * logits
+
+
+def test_compiled_sampler_sorts_no_vocabulary(one_chip):
+    """`ops/sampling.sample_tokens` at the block pass's `f32[128, 151936]`,
+    compiled for a v5e: no `TopK` custom call and no `sort` sees a row of
+    more than the 64 picked groups' members (until PR 35 one `TopK` over
+    the vocabulary was a fifth of the pass, PERF.md §6), and the
+    temporaries stay under two copies of the logits. The guard against a
+    later edit that puts the vocabulary-wide sort back."""
+    from llmlb_tpu.ops import sampling
+
+    rows, vocab = 128, BLOCK_CFG.vocab_size
+    plan = sampling.selection_plan(vocab)
+    assert plan["group"] and plan["sorted_per_row"] < vocab // 8
+    on_chip = functools.partial(_on_chip, one_chip)
+    per_row = on_chip(jax.ShapeDtypeStruct((rows,), jnp.float32))
+    ints = on_chip(jax.ShapeDtypeStruct((rows,), jnp.int32))
+    logits = on_chip(jax.ShapeDtypeStruct((rows, vocab), jnp.float32))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    compiled = jax.jit(sampling.sample_tokens).lower(  # as _sample_block does
+        logits, key, per_row, per_row, ints, None, ints, ints).compile()
+    hlo = compiled.as_text()
+    shape_of = dict(re.findall(
+        r"^\s*(?:ROOT )?(%\S+) = \(?(\w+\[[\d,]*\])", hlo, re.M))
+    sorted_over = []  # the minor dimension of every sort's and TopK's operand
+    for line in hlo.splitlines():
+        m = re.search(r" (sort|custom-call)\((%[^,)\s]+)", line)
+        if not m or (m.group(1) == "custom-call"
+                     and 'custom_call_target="TopK"' not in line):
+            continue
+        dims = re.search(r"\[([\d,]*)\]", shape_of[m.group(2)]).group(1)
+        sorted_over.append(int(dims.split(",")[-1]))
+    # the group maxima, the 64 picked groups put in order, the candidates
+    k = sampling.TOPK_PREFILTER
+    assert len(sorted_over) >= 3, sorted_over
+    assert max(sorted_over) <= k * plan["group"], sorted_over
+    assert sum(sorted_over) <= plan["sorted_per_row"] + k, sorted_over
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 2 * rows * vocab * 4)
