@@ -13,6 +13,8 @@ from __future__ import annotations
 import threading
 
 from llmlb_tpu.engine import compilelog
+from llmlb_tpu.engine.streamstats import StreamStats
+from llmlb_tpu.hoststats import cpu_seconds, watch_gc
 
 # Bucket edges in seconds, chosen around serving targets: TTFT p50 goals are
 # tens of ms (one-shot prefill) to seconds (chunked 4k prompts); ITL goals
@@ -46,6 +48,18 @@ PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 # positions committed; positions unmasked.
 BLOCK_COUNTS = ("block_passes", "row_passes", "blocks_committed",
                 "tokens_committed", "positions_unmasked")
+
+# The engine's threads by class, for CPU seconds by class (hoststats.py):
+# the step loops (one, or split mode's two), the service layer's bridge
+# threads (one blocked in `events.get` for every stream in flight) and the
+# window prewarm. The HTTP event loop has no name: its handlers say so.
+THREAD_CLASSES = {
+    "step_loop": ("engine-step-loop", "engine-prefill-pool",
+                  "engine-decode-pool"),
+    "http_loop": (),
+    "event_bridge": ("engine-events",),
+    "prewarm": ("engine-prewarm",),
+}
 
 KV_FALLBACK_REASONS = ("disabled", "absent", "version", "dtype",
                        "page_size", "geometry", "capacity", "error")
@@ -260,6 +274,11 @@ class EngineMetrics:
         # Programs built (engine/compilelog.py): the ledger is the
         # process's, this engine serves what was built since it was made.
         self._compile_base = compilelog.counters()
+        # A token's way out, from the event queue to the socket
+        # (engine/streamstats.py; written by the HTTP event loop alone), and
+        # the process's collector clock (hoststats.py).
+        self.stream = StreamStats()
+        self.gc = watch_gc()
 
     # ------------------------------------------------------------ recorders
 
@@ -527,15 +546,26 @@ class EngineMetrics:
             out["builds"] = compilelog.recent(builds, self._compile_base)
         return out
 
-    def summary(self) -> dict:
+    def host_info(self, current: str | None = None) -> dict:
+        """What is read only when somebody asks: the stream path's
+        counters, the collector's, and CPU seconds by thread class
+        (`current` is the caller's class: the HTTP handlers say
+        "http_loop")."""
+        return {"stream": self.stream.snapshot(),
+                "gc": self.gc.snapshot(),
+                "cpu_seconds_total": cpu_seconds(THREAD_CLASSES, current)}
+
+    def summary(self, current: str | None = None) -> dict:
         """Compact JSON figures for /api/health consumers (the gateway's
         scheduler and dashboard)."""
         loop_seconds = self.loop_seconds()
         compiled = self.compile_info()
+        host = self.host_info(current)
         with self._lock:
             return {
                 "loop_seconds_total": loop_seconds,
                 "compile": compiled,
+                **host,
                 "requests_total": self.requests_total,
                 "tokens_total": self.tokens_total,
                 "errors_total": self.errors_total,
@@ -602,7 +632,8 @@ class EngineMetrics:
                sched: dict | None = None,
                lora: dict | None = None,
                flightrec: dict | None = None,
-               kv_offload: dict | None = None) -> str:
+               kv_offload: dict | None = None,
+               current: str | None = None) -> str:
         """Prometheus text exposition format. `prefix_cache` is the
         scheduler's prefix_cache_info() block (pinned-state gauges live
         there; the event counters live here); `kv_cache` is its
@@ -614,7 +645,8 @@ class EngineMetrics:
         block (active int8 mode + honest byte footprints); `flightrec` is
         the flight recorder's counters() block (docs/tracing.md) — the
         queue/service seconds pair feeds the Grafana queue-vs-compute
-        panel."""
+        panel; `current` is the calling thread's class for the CPU seconds
+        (host_info)."""
         with self._lock:
             lines = [
                 "# TYPE llmlb_engine_requests_total counter",
@@ -988,4 +1020,22 @@ class EngineMetrics:
             lines.append(
                 f'llmlb_engine_compile_seconds_total{{stage="{stage}"}} '
                 f'{seconds}')
+        # a token's way out, the collector and CPU by thread class
+        # (docs/tracing.md): read here, at scrape time, and nowhere else
+        host = self.host_info(current)
+        for key, value in host["stream"].items():
+            kind = "counter" if key.endswith("_total") else "gauge"
+            lines.append(f"# TYPE llmlb_engine_stream_{key} {kind}")
+            lines.append(f"llmlb_engine_stream_{key} {value}")
+        lines.append("# TYPE llmlb_engine_gc_collections_total counter")
+        for gen, n in host["gc"]["collections_total"].items():
+            lines.append(
+                f'llmlb_engine_gc_collections_total{{generation="{gen}"}} {n}')
+        lines.append("# TYPE llmlb_engine_gc_seconds_total counter")
+        lines.append(
+            f'llmlb_engine_gc_seconds_total {host["gc"]["seconds_total"]}')
+        lines.append("# TYPE llmlb_engine_cpu_seconds_total counter")
+        for cls, seconds in host["cpu_seconds_total"].items():
+            lines.append(
+                f'llmlb_engine_cpu_seconds_total{{class="{cls}"}} {seconds}')
         return "\n".join(lines) + "\n"

@@ -58,6 +58,7 @@ from llmlb_tpu.engine.paging import PagePool
 from llmlb_tpu.engine.prefix_cache import PrefixCache, PrefixEntry
 from llmlb_tpu.engine.flightrec import FlightRecorder, gateway_rid
 from llmlb_tpu.engine.stepstats import LoopClock, StepRecorder, StepSpan
+from llmlb_tpu.engine.streamstats import EventQueue
 from llmlb_tpu.models import family_for
 from llmlb_tpu.models.llama import LlamaConfig, Params
 from llmlb_tpu.ops.grammar import (
@@ -353,8 +354,9 @@ class Request:
     sampling: SamplingParams
     request_id: str = dataclasses.field(default_factory=lambda: uuid.uuid4().hex)
     # events: ("token", token_id) | ("tokens", [ids]: a block family's commit)
-    # ... ("done", finish_reason) | ("error", msg)
-    events: queue.SimpleQueue = dataclasses.field(default_factory=queue.SimpleQueue)
+    # ... ("done", finish_reason) | ("error", msg); each stamped at its put
+    # (engine/streamstats.py), and handed to the consumer as it was put
+    events: EventQueue = dataclasses.field(default_factory=EventQueue)
     submitted_at: float = dataclasses.field(default_factory=time.monotonic)
     first_token_at: float | None = None
     finished_at: float | None = None

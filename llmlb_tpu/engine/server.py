@@ -23,6 +23,7 @@ from aiohttp import web
 
 from llmlb_tpu import __version__
 from llmlb_tpu.disagg import HandoffError, handoff_payload, parse_handoff
+from llmlb_tpu.engine import stepstats
 from llmlb_tpu.engine.profiling import ProfileError, ProfileManager
 from llmlb_tpu.engine.scheduler import SamplingParams
 from llmlb_tpu.engine.service import Engine, EngineError
@@ -227,12 +228,28 @@ def _usage(prompt_tokens: int, completion_tokens: int) -> dict:
     }
 
 
+# Where a prepared response keeps (the engine's StreamStats, its
+# connection's protocol): create_engine_app's on_response_prepare puts them
+# there, so _sse_send can time its write without a word from the handlers.
+_WAY_OUT = "llmlb.way_out"
+
+
 async def _sse_send(resp: web.StreamResponse, payload: dict | str) -> None:
     if isinstance(payload, str):
         data = payload
     else:
         data = json.dumps(payload, separators=(",", ":"))
-    await resp.write(f"data: {data}\n\n".encode())
+    frame = f"data: {data}\n\n".encode()
+    way_out = resp.get(_WAY_OUT)
+    # aiohttp appends to the transport's buffer and awaits only while the
+    # transport holds writing paused (the reader downstream is behind by
+    # more than the socket buffers): such a write is timed, as a wait
+    if way_out is None or not way_out[1].writing_paused:
+        await resp.write(frame)
+        return
+    t0 = stepstats._now()
+    await resp.write(frame)
+    way_out[0].write_waited(t0)
 
 
 def _drain_grace_from_env() -> float:
@@ -567,7 +584,7 @@ class EngineAPI:
         )
 
     async def health(self, request: web.Request) -> web.Response:
-        body = self.engine.health()
+        body = self.engine.health(current="http_loop")
         if self.drain.draining:
             # the gateway's health checker re-parses this on EVERY probe and
             # flips the endpoint out of selection within one interval
@@ -649,6 +666,7 @@ class EngineAPI:
             sched=core.sched_info(), lora=core.lora_info(),
             flightrec=core.flightrec.counters(),
             kv_offload=core.kv_transfer_info()["offload"],
+            current="http_loop",
         )
         return web.Response(
             text=text, content_type="text/plain", charset="utf-8"
@@ -1543,6 +1561,12 @@ def create_engine_app(engine: Engine, *, owns_engine: bool = True,
 
     app = web.Application(client_max_size=KV_BODY_BYTES,
                           middlewares=[error_middleware, drain_middleware])
+    stream_stats = engine.core.metrics.stream
+
+    async def note_way_out(request: web.Request, response) -> None:
+        response[_WAY_OUT] = (stream_stats, request.protocol)
+
+    app.on_response_prepare.append(note_way_out)
     app.router.add_get("/v1/models", api.list_models)
     app.router.add_post("/v1/chat/completions", api.chat_completions)
     app.router.add_post("/v1/handoff", api.handoff_adopt)

@@ -18,6 +18,7 @@ from typing import AsyncIterator
 
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
+from llmlb_tpu.engine.streamstats import StreamStats, frame_annotation
 from llmlb_tpu.engine.tokenizer import (
     ByteTokenizer,
     HFTokenizer,
@@ -76,6 +77,11 @@ class Engine:
             tokenizer, core.cfg.vocab_size, metrics=core.metrics
         )
         core.constraint_compiler = self.constraint_compiler
+        # the way out's counters (engine/streamstats.py) are the core's, so
+        # that /api/health serves them; a core with no metrics (a scripted
+        # stand-in) gets a block nobody reads
+        self._stream_stats = (core.metrics.stream if core.metrics is not None
+                              else StreamStats())
 
     # ------------------------------------------------------------ construction
 
@@ -219,42 +225,62 @@ class Engine:
                 token_ids=pending_ids,
             )
 
+        # the way out, counted (engine/streamstats.py): every event's wait
+        # for this consumer (the queue and the hop to this loop), every
+        # frame from the event's arrival to the resumption after its yield,
+        # the whole stream at its last frame
+        stats = self._stream_stats
+        events = request.events
+        take = events.taker()
+        stats.open(events)
         try:
             while True:
-                kind, value = await loop.run_in_executor(
-                    self._executor, request.events.get
+                stamp, (kind, value) = await loop.run_in_executor(
+                    self._executor, take
                 )
-                if kind == "error":
-                    raise EngineError(str(value))
-                if kind in _TOKEN_EVENTS:
-                    if not completion_tokens and request.first_token_at:
-                        ttft = request.first_token_at - request.submitted_at
-                    for token in _tokens_of(kind, value):
-                        completion_tokens += 1
-                        pending_ids.append(token)
-                        acc += detok.push(token)
-                else:  # done
-                    acc += detok.flush()
+                with frame_annotation():
+                    tokens = (_tokens_of(kind, value)
+                              if kind in _TOKEN_EVENTS else ())
+                    t_got = stats.got(stamp, events, len(tokens))
+                    if kind == "error":
+                        raise EngineError(str(value))
+                    if tokens:
+                        if not completion_tokens and request.first_token_at:
+                            ttft = (request.first_token_at
+                                    - request.submitted_at)
+                        for token in tokens:
+                            completion_tokens += 1
+                            pending_ids.append(token)
+                            acc += detok.push(token)
+                    elif kind == "done":
+                        acc += detok.flush()
 
-                hit = _find_stop(acc, stop)
-                if hit is not None:
-                    finished = True
-                    request.cancel()
-                    yield final(acc[emitted:hit], "stop")
-                    return
-                if kind == "done":
-                    finished = True
-                    yield final(acc[emitted:], str(value))
-                    return
-                boundary = max(emitted, len(acc) - holdback)
-                if boundary > emitted:
-                    delta = StreamDelta(text=acc[emitted:boundary],
-                                        ttft_s=ttft, token_ids=pending_ids)
-                    pending_ids = []
-                    ttft = None  # report once
-                    emitted = boundary
-                    yield delta
+                    hit = _find_stop(acc, stop)
+                    if hit is not None:
+                        finished = True
+                        request.cancel()
+                        yield final(acc[emitted:hit], "stop")
+                        return
+                    if kind == "done":
+                        finished = True
+                        last = final(acc[emitted:], str(value))
+                        yield last
+                        if last.text:  # else the handler wrote no frame
+                            stats.frame(t_got)
+                        stats.finished(request)
+                        return
+                    boundary = max(emitted, len(acc) - holdback)
+                    if boundary > emitted:
+                        delta = StreamDelta(text=acc[emitted:boundary],
+                                            ttft_s=ttft,
+                                            token_ids=pending_ids)
+                        pending_ids = []
+                        ttft = None  # report once
+                        emitted = boundary
+                        yield delta
+                        stats.frame(t_got)
         finally:
+            stats.close(events)
             if not finished:
                 request.cancel()
 
@@ -517,42 +543,57 @@ class Engine:
         except BaseException:
             core._release_lora(request)  # see stream(): no pin leaks
             raise
+        stats = self._stream_stats  # as in stream(): the way out, counted
+        events = request.events
+        take = events.taker()
+        stats.open(events)
         try:
             while True:
-                kind, value = await loop.run_in_executor(
-                    self._executor, request.events.get
+                stamp, (kind, value) = await loop.run_in_executor(
+                    self._executor, take
                 )
-                if kind == "error":
-                    raise EngineError(str(value))
-                if kind in _TOKEN_EVENTS:
-                    if ttft is None and request.first_token_at:
-                        ttft = (request.first_token_at
-                                - request.submitted_at)
-                    for token in _tokens_of(kind, value):
-                        completion_tokens += 1
-                        pending_ids.append(token)
-                        acc += detok.push(token)
-                else:  # done
-                    acc += detok.flush()
+                with frame_annotation():
+                    tokens = (_tokens_of(kind, value)
+                              if kind in _TOKEN_EVENTS else ())
+                    t_got = stats.got(stamp, events, len(tokens))
+                    if kind == "error":
+                        raise EngineError(str(value))
+                    if tokens:
+                        if ttft is None and request.first_token_at:
+                            ttft = (request.first_token_at
+                                    - request.submitted_at)
+                        for token in tokens:
+                            completion_tokens += 1
+                            pending_ids.append(token)
+                            acc += detok.push(token)
+                    elif kind == "done":
+                        acc += detok.flush()
 
-                hit = _find_stop(acc, stop)
-                if hit is not None:
-                    finished = True
-                    request.cancel()
-                    yield final(acc[emitted:hit], "stop")
-                    return
-                if kind == "done":
-                    finished = True
-                    yield final(acc[emitted:], str(value))
-                    return
-                boundary = max(emitted, len(acc) - holdback)
-                if boundary > emitted:
-                    delta = StreamDelta(text=acc[emitted:boundary],
-                                        ttft_s=ttft, token_ids=pending_ids)
-                    pending_ids = []
-                    emitted = boundary
-                    yield delta
+                    hit = _find_stop(acc, stop)
+                    if hit is not None:
+                        finished = True
+                        request.cancel()
+                        yield final(acc[emitted:hit], "stop")
+                        return
+                    if kind == "done":
+                        finished = True
+                        last = final(acc[emitted:], str(value))
+                        yield last
+                        if last.text:  # else the handler wrote no frame
+                            stats.frame(t_got)
+                        stats.finished(request)
+                        return
+                    boundary = max(emitted, len(acc) - holdback)
+                    if boundary > emitted:
+                        delta = StreamDelta(text=acc[emitted:boundary],
+                                            ttft_s=ttft,
+                                            token_ids=pending_ids)
+                        pending_ids = []
+                        emitted = boundary
+                        yield delta
+                        stats.frame(t_got)
         finally:
+            stats.close(events)
             if not finished:
                 request.cancel()
 
@@ -654,7 +695,9 @@ class Engine:
             self._executor, self._embed_sync, batch_ids
         )
 
-    def health(self) -> dict:
+    def health(self, current: str | None = None) -> dict:
+        """`current` is the calling thread's class for the CPU seconds by
+        class (the HTTP handler says "http_loop")."""
         from llmlb_tpu.engine.telemetry import device_telemetry
         from llmlb_tpu.ops.attention import attention_mode, traced_routes
 
@@ -700,7 +743,7 @@ class Engine:
             # the gateway's telemetry-aware placement can read how close to
             # the hardware each engine is running
             "perf": self.core.perf_info(),
-            "metrics": self.core.metrics.summary(),
+            "metrics": self.core.metrics.summary(current),
         }
 
 

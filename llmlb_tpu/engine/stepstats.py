@@ -22,6 +22,15 @@ one clock (``time.perf_counter()``) read once at each boundary:
   ``loop_seconds_total``: over any interval they sum to the interval.
 * **Programs built inside a step** are named on its record (``builds``,
   engine/compilelog.py).
+* **Work or waiting.** The loop thread's CPU clock is read four times a step
+  (``LoopClock.begin``, the marks into and out of ``compute``,
+  ``LoopClock.close``): ``host_cpu_s`` is the CPU the thread spent in the
+  step outside ``compute`` and ``gap_cpu_s`` what it spent since the
+  previous record closed. A step whose host spans take 8 ms of wall time
+  and 3 ms of CPU waited 5 ms for the GIL or the kernel's scheduler.
+  ``gc_s`` is what the collector took (hoststats.py: it stalls every
+  thread) since the previous record closed: a 100 ms gap with ``gc_s`` 0.09
+  has its cause.
 
 Span names (``SPANS``), in the order a decode step runs them:
 
@@ -71,11 +80,15 @@ from collections import deque
 
 from jax.profiler import TraceAnnotation
 
+from llmlb_tpu import hoststats
 from llmlb_tpu.engine import compilelog
 
 # the one clock of every stamp below (a name of its own, so that a test can
 # put made-up stamps in its place without touching the process's clock)
 _now = time.perf_counter
+# the loop thread's own CPU clock: read where a step begins, where it starts
+# and stops waiting for the device, and where it ends, never per token
+_cpu = time.thread_time
 
 PHASES = ("plan", "draft", "host_sync", "dispatch", "compute", "fetch",
           "emit")
@@ -117,16 +130,22 @@ class StepSpan:
     end. All times are time.perf_counter() seconds."""
 
     __slots__ = ("kind", "seq", "loop", "t0", "t1", "spans", "since_prev",
-                 "builds", "slow_in", "_name", "_start", "_ann", "_step_ann",
-                 "_resume", "_legacy_spans")
+                 "builds", "slow_in", "host_cpu_s", "gap_cpu_s", "gc_s",
+                 "_name", "_start", "_ann", "_step_ann", "_resume",
+                 "_legacy_spans", "_cpu_mark")
 
     def __init__(self, loop: str, seq: int, t0: float, first_span: str,
-                 since_prev: dict[str, float], resume: str):
+                 since_prev: dict[str, float], resume: str,
+                 cpu0: float = 0.0, gap_cpu_s: float = 0.0):
         self.loop = loop
         self.seq = seq
         self.t0 = self._start = t0
         self.spans: list[tuple[str, float, float]] = []
         self.since_prev = since_prev
+        self.host_cpu_s = 0.0  # CPU of this thread outside `compute`
+        self.gap_cpu_s = gap_cpu_s  # ... since the previous record closed
+        self.gc_s = 0.0  # the collector, since the previous record closed
+        self._cpu_mark = cpu0
         self.slow_in: str | None = None  # set by StepRecorder.observe
         self._name = first_span
         self._resume = resume
@@ -140,6 +159,11 @@ class StepSpan:
         """End the running span and start `name` at one clock read, which
         is returned."""
         now = _now()
+        if name == "compute":
+            if self._name != "compute":
+                self.host_cpu_s += _cpu() - self._cpu_mark
+        elif self._name == "compute":
+            self._cpu_mark = _cpu()
         self.spans.append((self._name, self._start, now - self._start))
         self._ann.__exit__(None, None, None)
         self._name = name
@@ -184,6 +208,10 @@ class LoopClock:
         self._bucket = "other"
         self._mark = _now()
         self._step: StepSpan | None = None
+        # the thread's CPU clock and the collector's total where the last
+        # record closed (this thread makes the clock: _clock())
+        self._cpu_closed = _cpu()
+        self._gc_closed = hoststats.GC.seconds_total
 
     def switch(self, bucket: str) -> None:
         """The loop moves on to `bucket` (no-op while a step is open: the
@@ -204,8 +232,10 @@ class LoopClock:
         self.switch("step")
         # steps are serialized (one loop, or split mode's lock), so the
         # record this step will become is the recorder's next
+        cpu = _cpu()
         step = StepSpan(self.tag, self.recorder.seq + 1, self._mark,
-                        first_span, self._gap, resume)
+                        first_span, self._gap, resume, cpu,
+                        cpu - self._cpu_closed)
         self._gap = dict.fromkeys(GAP_BUCKETS, 0.0)
         compilelog.enter_step(step.seq)
         self._step = step
@@ -233,6 +263,12 @@ class LoopClock:
         """Stamp the step's end. Until resume() the loop is in `record`;
         after it, back in the bucket the step was opened from."""
         now = self._end(step, kind)
+        self._cpu_closed = cpu = _cpu()
+        if step._name != "compute":
+            step.host_cpu_s += cpu - step._cpu_mark
+        gc_total = hoststats.GC.seconds_total
+        step.gc_s = gc_total - self._gc_closed
+        self._gc_closed = gc_total
         self.acc["step"] += now - self._mark
         self._bucket = "record"
         self._mark = now
@@ -316,6 +352,8 @@ class StepRecorder:
             t0, t1 = span.t0, span.t1
             gap, spans = span.since_prev, span.spans
             builds, loop = span.builds, span.loop
+            cpu = {"host_cpu_s": span.host_cpu_s,
+                   "gap_cpu_s": span.gap_cpu_s, "gc_s": span.gc_s}
             # everything since the previous record ended but the idle
             # sleep: a stall between steps is judged like one inside
             judged = (t1 - t0 + gap["admit"] + gap["control"]
@@ -331,6 +369,7 @@ class StepRecorder:
                     spans.append((p, at, phases[p]))
                     at += phases[p]
             builds, loop = [], "main"
+            cpu = {}
             judged = total
         record = {
             "ts": self._wall_anchor + t1,
@@ -348,6 +387,7 @@ class StepRecorder:
             "spans": spans,
             "since_prev": gap,
             "builds": builds,
+            **cpu,
             **(extra or {}),
         }
         with self._lock:
@@ -445,6 +485,8 @@ class StepRecorder:
              "phases_s": {k: round(v, 6) for k, v in r["phases_s"].items()},
              "t0_s": round(r["t0_s"], 6), "t1_s": round(r["t1_s"], 6),
              "wall_s": round(r["t1_s"] - r["t0_s"], 6),
+             **{k: round(r[k], 6)
+                for k in ("host_cpu_s", "gap_cpu_s", "gc_s") if k in r},
              "spans": [[name, round(start - r["t0_s"], 6), round(dur, 6)]
                        for name, start, dur in r["spans"]],
              "since_prev": {f"{b}_s": round(v, 6)

@@ -64,6 +64,10 @@ log = logging.getLogger("llmlb_tpu.gateway.openai")
 
 CLOUD_PREFIXES = ("openai:", "google:", "anthropic:")
 
+# the stream relay's clock (a name of its own, so that a test can put made-up
+# stamps in its place)
+_now = time.perf_counter
+
 
 def error_response(status: int, message: str,
                    err_type: str = "invalid_request_error",
@@ -1377,12 +1381,26 @@ async def _forward_stream(
             # watchdog timer is per-stream, never per-chunk).
             write = guard.write if guard.active() else resp.write
             next_chunk = iterator.__anext__
+            # The relay, timed (metrics.RelayStats): one running mark cuts
+            # the pump's wall time into upstream_wait / feed / client_write,
+            # three clock reads a chunk, counted as they happen so that a
+            # scrape sees the streams in flight.
+            relay = state.metrics.relay
+            now = _now
+            mark = now()
             if replay is None:
-                feed(first_chunk)
-                await write(first_chunk)
-                if timeline is not None and b"data:" in first_chunk:
-                    timeline.mark()
+                chunk = first_chunk
                 while True:
+                    feed(chunk)
+                    t = now()
+                    relay.feed += t - mark
+                    await write(chunk)
+                    mark = now()
+                    relay.client_write += mark - t
+                    relay.chunks += 1
+                    relay.bytes += len(chunk)
+                    if timeline is not None and b"data:" in chunk:
+                        timeline.mark()
                     try:
                         chunk = await next_chunk()
                     except StopAsyncIteration:
@@ -1398,10 +1416,9 @@ async def _forward_stream(
                         # on the farewell frame either
                         await write(sse_error_frame(error))
                         break
-                    feed(chunk)
-                    await write(chunk)
-                    if timeline is not None and b"data:" in chunk:
-                        timeline.mark()
+                    t = now()
+                    relay.upstream_wait += t - mark
+                    mark = t
             else:
                 # Armed (resumable) pump: frames forward whole (a cut never
                 # leaks a partial event), gateway-internal llmlb.replay
@@ -1417,16 +1434,28 @@ async def _forward_stream(
                     handle = state.streams.register(
                         replay.rid, model, endpoint.id)
                 while True:
+                    t = now()
+                    relay.upstream_wait += t - mark
+                    mark = t
+                    relay.chunks += 1
                     for frame in splitter.push(chunk):
                         out = _replay_frame_out(replay, splicer, frame)
                         if out is None:
                             continue
                         feed(out)
+                        t = now()
+                        relay.feed += t - mark
                         await write(out)
+                        mark = now()
+                        relay.client_write += mark - t
+                        relay.bytes += len(out)
                         if is_done_frame(out):
                             terminal_sent = True
                         if timeline is not None and b"data:" in out:
                             timeline.mark()
+                    t = now()  # the splitter, and frames that go nowhere
+                    relay.feed += t - mark
+                    mark = t
                     # Frame boundary: a pending rebalance directive moves
                     # this stream NOW — park on the (healthy) origin, adopt
                     # on the planner's target, splice. Any failure leaves
@@ -1498,6 +1527,9 @@ async def _forward_stream(
                         splitter = FrameSplitter()
                         splicer = ChunkSplicer(replay)
                         replay.mark_ledger_stale()
+            # what is left is the wait that ended the pump (the upstream's
+            # end of stream)
+            relay.upstream_wait += now() - mark
     except asyncio.CancelledError:
         # the watchdog's cancel can land at any await once it fires (e.g.
         # the next upstream read, if the write completed in the race) —

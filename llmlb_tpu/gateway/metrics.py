@@ -42,6 +42,12 @@ overload-protection series (docs/scheduling.md):
   llmlb_gateway_ratelimit_rejections_total{reason}     counter (429s)
   llmlb_gateway_deadline_shed_total{model}             counter
   llmlb_gateway_stream_write_timeouts_total{model}     counter
+the stream relay and the process (docs/tracing.md "A token's way out"):
+  llmlb_gateway_relay_chunks_total             counter (upstream chunks)
+  llmlb_gateway_relay_bytes_total              counter (bytes written on)
+  llmlb_gateway_relay_seconds_total{phase}     counter (upstream_wait|feed|
+                                               client_write: a pump's wall time)
+  llmlb_gateway_cpu_seconds_total{class}       counter (process|loop|other)
 plus scrape-time gauges (active requests, admission queue depth, event-bus
 drops, trace-buffer size) injected by the /metrics handler.
 """
@@ -53,6 +59,7 @@ import threading
 from collections import defaultdict
 
 from llmlb_tpu.engine.metrics import Histogram
+from llmlb_tpu.hoststats import cpu_seconds
 
 # Sample lines of a Prometheus text exposition: `name value`,
 # `name{labels} value`, with optional trailing timestamp. The label block
@@ -101,6 +108,26 @@ QUEUE_WAIT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 # hosts, seconds when a delay fault or congested mesh is in play.
 GOSSIP_LAG_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                       1.0, 2.5, 5.0)
+
+
+class RelayStats:
+    """What the stream relay (api_openai._forward_stream, both pumps)
+    did, cumulative over every stream: upstream chunks taken, bytes written
+    on to the client, and a pump's wall time cut into three phases by one
+    running mark — `upstream_wait` (awaiting the engine's next chunk),
+    `feed` (token accounting; in the armed pump the frame splitter and the
+    replay ledger too) and `client_write` (awaiting the client's socket).
+    Written by the event loop alone, a chunk at a time, so a scrape in the
+    middle of a stream reads what the stream has done so far."""
+
+    __slots__ = ("chunks", "bytes", "upstream_wait", "feed", "client_write")
+
+    def __init__(self):
+        self.chunks = 0
+        self.bytes = 0
+        self.upstream_wait = 0.0
+        self.feed = 0.0
+        self.client_write = 0.0
 
 
 def _escape(value: str) -> str:
@@ -182,6 +209,8 @@ class GatewayMetrics:
         # committed tokens replayed onto the resuming engine (the work the
         # failover saved the client from losing)
         self._stream_resumed_tokens: dict[str, int] = defaultdict(int)
+        # the stream relay's chunks, bytes and seconds by phase
+        self.relay = RelayStats()
 
     # ------------------------------------------------------------ recorders
 
@@ -671,6 +700,23 @@ class GatewayMetrics:
                     )
                     lines.append(f"{name}_sum{{{labels}}} {hist.total}")
                     lines.append(f"{name}_count{{{labels}}} {hist.n}")
+            relay = self.relay
+            lines.append("# TYPE llmlb_gateway_relay_chunks_total counter")
+            lines.append(f"llmlb_gateway_relay_chunks_total {relay.chunks}")
+            lines.append("# TYPE llmlb_gateway_relay_bytes_total counter")
+            lines.append(f"llmlb_gateway_relay_bytes_total {relay.bytes}")
+            lines.append("# TYPE llmlb_gateway_relay_seconds_total counter")
+            for phase in ("upstream_wait", "feed", "client_write"):
+                lines.append(
+                    f'llmlb_gateway_relay_seconds_total{{phase="{phase}"}} '
+                    f'{round(getattr(relay, phase), 6)}')
+            # CPU seconds of this process and of the thread that serves
+            # this scrape, the event loop (hoststats.py): read here only
+            lines.append("# TYPE llmlb_gateway_cpu_seconds_total counter")
+            for cls, seconds in cpu_seconds({}, current="loop").items():
+                lines.append(
+                    f'llmlb_gateway_cpu_seconds_total{{class="{cls}"}} '
+                    f'{seconds}')
             for cname, value in sorted((counters or {}).items()):
                 lines.append(f"# TYPE {cname} counter")
                 lines.append(f"{cname} {value}")
