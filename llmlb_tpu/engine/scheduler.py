@@ -348,13 +348,22 @@ class ParkedState:
     spec_k: int = 0
 
 
+def event_tokens(kind: str, value) -> list[int]:
+    """The token ids an item of `Request.events` carries, in order: a
+    content event's, and none for `done` and `error`."""
+    return [int(t) for t in value] if kind == "tokens" else []
+
+
 @dataclasses.dataclass
 class Request:
     prompt_ids: list[int]
     sampling: SamplingParams
     request_id: str = dataclasses.field(default_factory=lambda: uuid.uuid4().hex)
-    # events: ("token", token_id) | ("tokens", [ids]: a block family's commit)
-    # ... ("done", finish_reason) | ("error", msg); each stamped at its put
+    # events: ("tokens", [ids]) — the content event: every token ONE fetch
+    # brought this request, in order (a burst's k, the deferred first token
+    # with them; a speculative step's accepted drafts + 1; a committed
+    # block; one where a step makes one) — then ("done", finish_reason) or
+    # ("error", msg). event_tokens reads one. Each is stamped at its put
     # (engine/streamstats.py), and handed to the consumer as it was put
     events: EventQueue = dataclasses.field(default_factory=EventQueue)
     submitted_at: float = dataclasses.field(default_factory=time.monotonic)
@@ -3355,10 +3364,13 @@ class EngineCore:
         spec_accepts: list[tuple[str, int, int]] = []
         for i in active:
             slot = self.slots[i]
-            if slot.first_pending and slot.request is not None:
+            request = slot.request
+            held: list[int] = []  # this row's tokens of this fetch: ONE event
+            if slot.first_pending and request is not None:
                 slot.first_pending = False
-                self._emit(i, int(tokens[i, 0]), first=True)
+                self._emit(i, int(tokens[i, 0]), first=True, held=held)
             if slot.request is None or slot.prefilling:
+                self._put_held(request, held)
                 continue
             rid_i = slot.request.request_id
             d = drafts.get(i, [])
@@ -3379,11 +3391,12 @@ class EngineCore:
                 self._seq_lens[i] += 1
                 emitted_i += 1
                 matched = j < len(d) and tok == d[j]
-                self._emit(i, tok, itl=itl)
+                self._emit(i, tok, itl=itl, held=held)
                 if matched:
                     j += 1
                 if slot.request is None or not matched:
                     break
+            self._put_held(request, held)
             accepted_total += j
             emitted_total += emitted_i
             if d:
@@ -4603,8 +4616,7 @@ class EngineCore:
                     self._emit(i, token, itl=itl, held=held)
                     if slot.request is None:
                         break  # finished (its tokens went out before `done`)
-                if held:
-                    request.events.put(("tokens", held))
+                self._put_held(request, held)
                 counts["blocks_committed"] += 1
                 counts["tokens_committed"] += b
                 n_blocks, n_tokens, _ = committed.get(i, (0, 0, None))
@@ -4616,43 +4628,58 @@ class EngineCore:
 
     def _emit_fetched(self, tokens, active: list[int],
                       itl: float | None) -> None:
-        """Deliver one fetched token matrix [rows, SLOTS]: row 0 holds
-        deferred first emissions for slots activated since the previous
-        fetch (no seq_len advance — the first token is prefill output, not
-        a decode step); rows 1.. are decode steps. Slots that finish
-        mid-matrix (EOS / max_tokens / capacity / cancel) have their
-        remaining tokens trimmed."""
+        """Deliver one fetched token matrix [rows, SLOTS], column by column:
+        everything the fetch brought a request goes out as ONE content
+        event. Row 0 holds deferred first emissions for slots activated
+        since the previous fetch (no seq_len advance — the first token is
+        prefill output, not a decode step); rows 1.. are decode steps. A
+        slot that finishes mid-matrix (EOS / max_tokens / capacity /
+        cancel) has the rest of its column trimmed. Slots share no state
+        here, so the order of the columns is free."""
         for i in active:
             slot = self.slots[i]
-            if slot.first_pending and slot.request is not None:
+            request = slot.request
+            if request is None:
+                continue
+            held: list[int] = []
+            if slot.first_pending:
                 slot.first_pending = False
                 # first=True: the grammar FSM already advanced on this token
                 # at activation (the synchronous fetch there) — advancing
                 # again would double-step the grammar.
-                self._emit(i, int(tokens[0, i]), first=True)
-        for t in range(1, tokens.shape[0]):
-            for i in active:
-                slot = self.slots[i]
-                if slot.request is None or slot.prefilling:
-                    continue
-                self._seq_lens[i] += 1
-                self._emit(i, int(tokens[t, i]), itl=itl)
+                self._emit(i, int(tokens[0, i]), first=True, held=held)
+            if not slot.prefilling:
+                for token in tokens[1:, i].tolist():
+                    if slot.request is None:
+                        break  # finished: its tokens went out before `done`
+                    self._seq_lens[i] += 1
+                    self._emit(i, token, itl=itl, held=held)
+            self._put_held(request, held)
 
-    def _emit(self, slot_id: int, token: int,
-              itl: float | None = None, first: bool = False,
-              held: list[int] | None = None) -> None:
+    @staticmethod
+    def _put_held(request: Request | None, held: list[int]) -> None:
+        """Put the content event of one row and fetch and leave `held`
+        empty; nothing where _emit already put it before the `done`."""
+        if held:
+            request.events.put(("tokens", held[:]))
+            held.clear()
+
+    def _emit(self, slot_id: int, token: int, *, held: list[int],
+              itl: float | None = None, first: bool = False) -> None:
         """Deliver one generated token. `itl` overrides the wall-clock
         inter-token gap (burst decode delivers k tokens back-to-back; the
         caller passes the amortized pacing instead). `first` marks the
         deferred first emission, whose grammar advance already happened at
-        activation. `held` (a committed block's tokens, _emit_blocks)
-        collects the token for ONE ("tokens", [ids]) event in place of a
-        ("token", id) event of its own; where the request ends here, what
-        was held goes out before its `done`."""
+        activation. `held` collects the tokens one fetch brought this row
+        (a burst's column, a speculative step's accepted span, a committed
+        block) for the ONE ("tokens", [ids]) event the caller puts after
+        the last of them; where the request ends here, what was held goes
+        out before its `done` and `held` is left empty."""
         slot = self.slots[slot_id]
         request = slot.request
         assert request is not None
         if self._is_cancelled(request):
+            self._put_held(request, held)
             request.finished_at = time.monotonic()
             request.events.put(("done", "cancelled"))
             self._fr_emit(request, "finished", reason="cancelled",
@@ -4719,17 +4746,11 @@ class EngineCore:
             # cut short (max_tokens / capacity) before grammar acceptance
             self.metrics.record_constraint_violation()
 
-        if finish == "stop":
-            pass  # EOS itself is not emitted as content
-        elif held is not None:
+        if finish != "stop":  # EOS itself is not emitted as content
             held.append(token)
-        else:
-            request.events.put(("token", token))
 
         if finish is not None:
-            if held:
-                request.events.put(("tokens", list(held)))
-                held.clear()
+            self._put_held(request, held)
             request.finished_at = time.monotonic()
             if finish == "length" and request.export_kv and self.kv_ship:
                 # Handoff export: serialize this stream's KV pages D2H

@@ -17,7 +17,12 @@ from typing import AsyncIterator
 
 
 from llmlb_tpu.engine.presets import get_preset
-from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
+from llmlb_tpu.engine.scheduler import (
+    EngineCore,
+    Request,
+    SamplingParams,
+    event_tokens,
+)
 from llmlb_tpu.engine.streamstats import StreamStats, frame_annotation
 from llmlb_tpu.engine.tokenizer import (
     ByteTokenizer,
@@ -239,8 +244,7 @@ class Engine:
                     self._executor, take
                 )
                 with frame_annotation():
-                    tokens = (_tokens_of(kind, value)
-                              if kind in _TOKEN_EVENTS else ())
+                    tokens = event_tokens(kind, value)
                     t_got = stats.got(stamp, events, len(tokens))
                     if kind == "error":
                         raise EngineError(str(value))
@@ -252,6 +256,8 @@ class Engine:
                             completion_tokens += 1
                             pending_ids.append(token)
                             acc += detok.push(token)
+                            if stop and _find_stop(acc, stop) is not None:
+                                break  # usage counts to the hit, no further
                     elif kind == "done":
                         acc += detok.flush()
 
@@ -348,11 +354,10 @@ class Engine:
                 )
                 if kind == "error":
                     raise EngineError(str(value))
-                if kind in _TOKEN_EVENTS:
-                    committed.extend(_tokens_of(kind, value))
-                else:  # done
+                if kind == "done":
                     finish = str(value)
                     break
+                committed.extend(event_tokens(kind, value))
         finally:
             if finish is None:
                 request.cancel()
@@ -553,8 +558,7 @@ class Engine:
                     self._executor, take
                 )
                 with frame_annotation():
-                    tokens = (_tokens_of(kind, value)
-                              if kind in _TOKEN_EVENTS else ())
+                    tokens = event_tokens(kind, value)
                     t_got = stats.got(stamp, events, len(tokens))
                     if kind == "error":
                         raise EngineError(str(value))
@@ -566,6 +570,8 @@ class Engine:
                             completion_tokens += 1
                             pending_ids.append(token)
                             acc += detok.push(token)
+                            if stop and _find_stop(acc, stop) is not None:
+                                break  # usage counts to the hit, no further
                     elif kind == "done":
                         acc += detok.flush()
 
@@ -749,16 +755,6 @@ class Engine:
 
 class EngineError(RuntimeError):
     pass
-
-
-# A request's content events (scheduler.Request.events): one token, or the
-# several tokens a block family's row committed at once — one delta, and so
-# one frame on the wire, carries them all.
-_TOKEN_EVENTS = ("token", "tokens")
-
-
-def _tokens_of(kind: str, value) -> list[int]:
-    return [int(value)] if kind == "token" else [int(t) for t in value]
 
 
 def _find_stop(text: str, stops: list[str]) -> int | None:

@@ -10,8 +10,11 @@ stages, and each has a counter here (docs/tracing.md "A token's way out"):
       reads how long ago the event was put: its wait in the queue and the
       hop back to the loop together, which is how long the token waited for
       the service's consumer. Every other consumer and every test of
-      `("token", id)` / `("tokens", [ids])` / `("done", reason)` calls
-      `get` and sees the events as they were put.
+      `("tokens", [ids])` / `("done", reason)` calls `get` and sees the
+      events as they were put. An event carries what one fetch brought
+      the request (a burst's k tokens of a row), so these are counts and
+      costs per event, and `tokens_total` beside them says how many
+      tokens shared one.
   the frame — from the consumer's resumption with an event to the
       generator's resumption after the delta's `yield`: detokenisation,
       the handler's JSON, `_sse_send` and its `await resp.write`.
@@ -23,7 +26,7 @@ stages, and each has a counter here (docs/tracing.md "A token's way out"):
 (`llmlb_engine_stream_*` in `/metrics`); window differences give every
 reading. All of it is written by ONE thread, the HTTP event loop, so it
 takes no lock; the queue's `n_put` is written by its one producer. Stamps
-are `stepstats._now`, three clock reads a token in all (the put, the
+are `stepstats._now`, three clock reads an event in all (the put, the
 resumption, the frame's end); a whole stream's two durations are on
 `Request.submitted_at`'s clock (`time.monotonic`), the same clock on Linux.
 """

@@ -180,17 +180,18 @@ def selftest_requests(cfg):
 
 
 def collect_tokens(reqs, timeout: float = 240.0) -> list[list[int]]:
+    from llmlb_tpu.engine.scheduler import event_tokens
+
     outs = []
     for r in reqs:
         toks = []
         while True:
             kind, val = r.events.get(timeout=timeout)
-            if kind == "token":
-                toks.append(int(val))
-            elif kind == "done":
+            if kind == "done":
                 break
-            else:
+            if kind == "error":
                 raise AssertionError(f"engine error: {val}")
+            toks.extend(event_tokens(kind, val))
         outs.append(toks)
     return outs
 

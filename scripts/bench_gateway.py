@@ -1732,7 +1732,12 @@ async def run_kv_ship_bench(requests: int) -> dict:
     import numpy as np
 
     from llmlb_tpu.engine.presets import get_preset
-    from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
+    from llmlb_tpu.engine.scheduler import (
+        EngineCore,
+        Request,
+        SamplingParams,
+        event_tokens,
+    )
 
     cfg = get_preset("debug-tiny")
     LONG = 384
@@ -1751,12 +1756,21 @@ async def run_kv_ship_bench(requests: int) -> dict:
         toks = []
         while True:
             kind, value = request.events.get(timeout=timeout)
-            if kind == "token":
-                toks.append(value)
-            elif kind == "error":
+            if kind == "error":
                 raise RuntimeError(f"engine error: {value}")
-            else:
+            if kind == "done":
                 return toks
+            toks.extend(event_tokens(kind, value))
+
+    def _take(request, n=1, timeout=300):
+        """Content events of a stream that is still generating, until
+        they carry n tokens or more."""
+        toks = []
+        while len(toks) < n:
+            kind, value = request.events.get(timeout=timeout)
+            assert kind == "tokens", (kind, value)
+            toks.extend(event_tokens(kind, value))
+        return toks
 
     def _stats(xs: list[float]) -> dict:
         xs = sorted(xs)
@@ -1780,11 +1794,7 @@ async def run_kv_ship_bench(requests: int) -> dict:
             _collect(core.submit(_req(prompt, max_tokens=2, priority=2)))
             _collect(core.submit(_req(inter, max_tokens=4, priority=0)))
             warm = core.submit(_req(prompt, max_tokens=24, priority=2))
-            seen = 0
-            while seen < 3:
-                kind, value = warm.events.get(timeout=300)
-                assert kind == "token", (kind, value)
-                seen += 1
+            _take(warm, 3)
             _collect(core.submit(_req(inter, max_tokens=4, priority=0)))
             _collect(warm)
             gaps, outs = [], []
@@ -1792,17 +1802,12 @@ async def run_kv_ship_bench(requests: int) -> dict:
             for _ in range(iters):
                 victim = core.submit(_req(prompt, max_tokens=24,
                                           priority=2))
-                toks = []
-                while len(toks) < 3:  # decoding: the park is mid-stream
-                    kind, value = victim.events.get(timeout=300)
-                    assert kind == "token", (kind, value)
-                    toks.append(value)
+                toks = _take(victim, 3)  # decoding: the park is mid-stream
                 _collect(core.submit(_req(inter, max_tokens=4, priority=0)))
                 t0 = time.perf_counter()
-                kind, value = victim.events.get(timeout=300)
+                toks += _take(victim)
                 gaps.append(time.perf_counter() - t0)
-                assert kind == "token", (kind, value)
-                outs.append(toks + [value] + _collect(victim))
+                outs.append(toks + _collect(victim))
             disp = sum(core.prefill_dispatch_by_loop.values()) - disp0
             info = core.kv_transfer_info()
             return {
@@ -1836,10 +1841,9 @@ async def run_kv_ship_bench(requests: int) -> dict:
             disp0 = sum(core.prefill_dispatch_by_loop.values())
             t0 = time.perf_counter()
             req = core.submit(_req(A, max_tokens=8))
-            kind, first = req.events.get(timeout=300)
+            first = _take(req)
             ttft = time.perf_counter() - t0
-            assert kind == "token", (kind, first)
-            out_a2 = [first] + _collect(req)
+            out_a2 = first + _collect(req)
             info = core.kv_transfer_info()
             return {
                 "mode": "ship" if ship else "replay",

@@ -40,15 +40,16 @@ PROMPT = [5, 6, 7, 8, 9] * 5
 
 
 def _drain(request):
+    from llmlb_tpu.engine.scheduler import event_tokens
+
     toks = []
     while True:
         kind, val = request.events.get(timeout=120)
-        if kind == "token":
-            toks.append(val)
-        elif kind == "done":
+        if kind == "done":
             return toks
-        else:
+        if kind == "error":
             raise RuntimeError(f"engine error: {val}")
+        toks.extend(event_tokens(kind, val))
 
 
 def run_check() -> list[str]:

@@ -20,6 +20,7 @@ from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.tokenizer import ByteTokenizer
 from llmlb_tpu.ops.sampling import sample_tokens
 from llmlb_tpu.structured import ConstraintCompiler
+from tests.support import collect
 
 STATE = ("_d_temps", "_d_top_ps", "_d_top_ks", "_d_seeds", "_d_seq_lens",
          "_d_last_tokens", "_d_lora_idx")
@@ -84,13 +85,10 @@ def _drain(core, requests, steps=200):
     for _ in range(steps):
         core._decode_active()
         for r in requests:
-            while not r.events.empty():
-                kind, val = r.events.get_nowait()
-                if kind == "token":
-                    out[id(r)].append(val)
-                else:
-                    assert kind == "done", val
-                    open_.discard(id(r))
+            tokens, finish = collect(r, timeout=None)  # what is queued
+            out[id(r)] += tokens
+            if finish is not None:
+                open_.discard(id(r))
         if not open_:
             return [out[id(r)] for r in requests]
     raise AssertionError("requests did not finish")

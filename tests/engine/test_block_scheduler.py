@@ -22,6 +22,7 @@ from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.engine.tokenizer import ByteTokenizer
 from llmlb_tpu.models import config_from_hf, sdar_moe
 from llmlb_tpu.ops.sampling import sample_tokens
+from tests.support import collect_events
 
 B, MASK = 4, 500
 HF = dict(
@@ -69,19 +70,7 @@ def _prompt(n, seed):
 
 def _collect(request, timeout=180):
     """(tokens, finish reason, tokens per content event)."""
-    tokens, frames = [], []
-    while True:
-        kind, value = request.events.get(timeout=timeout)
-        if kind == "token":
-            tokens.append(int(value))
-            frames.append(1)
-        elif kind == "tokens":
-            tokens.extend(int(t) for t in value)
-            frames.append(len(value))
-        elif kind == "done":
-            return tokens, value, frames
-        else:
-            raise AssertionError(value)
+    return collect_events(request, timeout)
 
 
 def _submit(core, prompt, max_tokens, **sampling):

@@ -5,24 +5,19 @@ lax.scan with on-device token feedback, syncing the host once per k tokens
 instead of per token. These tests pin the
 semantics the fusion must preserve: greedy outputs identical to the k=1 path,
 EOS/max_tokens finishing mid-burst trimmed, chunked prefill still interleaves.
+
+And the rule of the way out: whatever ONE fetch brought a row — a burst's k
+tokens, the activation's first token with them, a speculative step's accepted
+drafts + 1 — leaves the scheduler as ONE content event, so the service writes
+one frame a row and fetch.
 """
 
 import pytest
 
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
-
-
-def _collect(req: Request, timeout: float = 60.0) -> tuple[list[int], str]:
-    tokens: list[int] = []
-    while True:
-        kind, val = req.events.get(timeout=timeout)
-        if kind == "token":
-            tokens.append(val)
-        elif kind == "done":
-            return tokens, val
-        elif kind == "error":
-            raise RuntimeError(val)
+from tests.support import collect as _collect
+from tests.support import collect_events, take_tokens
 
 
 def _run_greedy(core: EngineCore, prompts: list[list[int]],
@@ -42,8 +37,10 @@ def cfg():
     return get_preset("debug-tiny")
 
 
-def test_burst_matches_single_step_greedy(cfg):
-    """Token-for-token equivalence: burst=4 vs burst=1 on the same prompts."""
+@pytest.mark.parametrize("burst", [4, 8])
+def test_burst_matches_single_step_greedy(cfg, burst):
+    """Token-for-token equivalence: burst=4 and 8 vs burst=1 on the same
+    prompts."""
     prompts = [[5, 9, 2], [7, 7, 7, 7], [3]]
     core1 = EngineCore(cfg, num_slots=4, slot_capacity=64,
                        prefill_buckets=(16, 32), seed=0, decode_burst=1)
@@ -54,7 +51,7 @@ def test_burst_matches_single_step_greedy(cfg):
         core1.stop()
 
     core4 = EngineCore(cfg, num_slots=4, slot_capacity=64,
-                       prefill_buckets=(16, 32), seed=0, decode_burst=4)
+                       prefill_buckets=(16, 32), seed=0, decode_burst=burst)
     core4.start()
     try:
         burst = _run_greedy(core4, prompts)
@@ -137,8 +134,7 @@ def test_burst_cancellation_mid_stream(cfg):
                       sampling=SamplingParams(temperature=0.0, max_tokens=100))
         core.submit(req)
         # wait for the first token, then cancel
-        kind, _ = req.events.get(timeout=60)
-        assert kind == "token"
+        assert take_tokens(req, 1, timeout=60)
         req.cancel()
         while True:
             kind, val = req.events.get(timeout=60)
@@ -153,6 +149,148 @@ def test_burst_cancellation_mid_stream(cfg):
         assert finish in ("stop", "length")
     finally:
         core.stop()
+
+
+# ------------------------------------------- one content event a row and fetch
+
+
+def _inline(cfg, **kw):
+    """A core whose loop the test drives by hand: a decode step's events
+    are then read before the next step runs."""
+    kw.setdefault("decode_burst", 8)
+    return EngineCore(cfg, num_slots=4, slot_capacity=128,
+                      prefill_buckets=(16, 32), seed=0, **kw)
+
+
+def _req(prompt, max_tokens):
+    return Request(prompt_ids=list(prompt), sampling=SamplingParams(
+        temperature=0.0, max_tokens=max_tokens))
+
+
+def _step(core, requests):
+    """One round of the loop; what it put each request: (tokens, finish
+    reason or None, tokens per content event)."""
+    core._try_insert()
+    core._advance_prefill()
+    core._decode_active()
+    return [collect_events(r, timeout=None) for r in requests]
+
+
+def _run_inline(core, requests, limit=200):
+    """Rounds until every request ended: per request its tokens, its finish
+    reason and the content events of every round that put it any."""
+    for r in requests:
+        core.submit(r)
+    tokens = [[] for _ in requests]
+    finish = [None] * len(requests)
+    rounds = [[] for _ in requests]
+    for _ in range(limit):
+        for i, (got, reason, sizes) in enumerate(_step(core, requests)):
+            tokens[i] += got
+            if sizes:
+                rounds[i].append(sizes)
+            finish[i] = finish[i] or reason
+        if all(finish):
+            return tokens, finish, rounds
+    raise AssertionError("requests did not finish")
+
+
+PROMPTS = ([5, 9, 2], [7, 7, 7, 7], [3])
+
+
+@pytest.fixture(scope="module")
+def single_step_tokens(cfg):
+    """The greedy tokens of PROMPTS, one token a step."""
+    core = _inline(cfg, decode_burst=1)
+    tokens, finish, _ = _run_inline(core, [_req(p, 40) for p in PROMPTS])
+    assert finish == ["length"] * 3
+    return tokens
+
+
+def test_a_burst_puts_one_event_a_live_row(cfg, single_step_tokens):
+    """Three rows that end at different places: every round puts a live row
+    ONE content event with that row's tokens in order — the activation's
+    first token in the same event as the burst it was fetched with — and a
+    row that ends inside a burst (max_tokens) gets the tokens before its
+    end."""
+    core = _inline(cfg)
+    limits = (25, 12, 40)
+    tokens, finish, rounds = _run_inline(
+        core, [_req(p, n) for p, n in zip(PROMPTS, limits)])
+    assert finish == ["length"] * 3
+    assert tokens == [t[:n] for t, n in zip(single_step_tokens, limits)]
+    assert rounds == [[[9], [8], [8]],
+                      [[9], [3]],
+                      [[9], [8], [8], [8], [7]]]
+    assert all(s.request is None for s in core.slots)
+
+
+def test_the_single_step_path_puts_an_event_a_fetch(cfg):
+    """The same rule where a fetch brings one token: a content event of
+    one, and of two where the activation's token rides it."""
+    _, _, rounds = _run_inline(_inline(cfg, decode_burst=1),
+                               [_req(PROMPTS[0], 5)])
+    assert rounds == [[[2], [1], [1], [1]]]
+
+
+@pytest.mark.parametrize("at", [10, 12, 17])
+def test_eos_inside_a_burst_takes_the_tokens_before_it(
+        cfg, single_step_tokens, at):
+    """The token at index `at` of the greedy stream made the EOS id: the
+    request ends with "stop" inside the first burst, inside the second, or
+    at the head of the third, with exactly the tokens before its first
+    occurrence, EOS itself no content, and the slot serves the next
+    request."""
+    want = single_step_tokens[0]
+    eos = want[at]
+    cut = want.index(eos)
+    core = _inline(cfg, eos_id=eos)
+    tokens, finish, rounds = _run_inline(core, [_req(PROMPTS[0], 40)])
+    assert (tokens, finish) == ([want[:cut]], ["stop"])
+    sizes = [s for (s,) in rounds[0]]
+    assert sizes == [n for n in (min(cut, 9), min(cut - 9, 8)) if n > 0]
+    again, _, _ = _run_inline(core, [_req(PROMPTS[0], 40)])
+    assert again == tokens
+
+
+@pytest.mark.parametrize("seen", [0, 3, 7])
+def test_a_cancel_inside_a_burst_takes_the_tokens_before_it(
+        cfg, single_step_tokens, monkeypatch, seen):
+    """A cancel that the emit loop sees after `seen` tokens of the second
+    burst: one event with those tokens (none for 0), then `done`, nothing
+    after it, and the slot is reusable."""
+    core = _inline(cfg)
+    request = core.submit(_req(PROMPTS[0], 40))
+    first, _, _ = _step(core, [request])[0]
+    assert first == single_step_tokens[0][:9]
+    asked = iter(range(100))
+    monkeypatch.setattr(
+        core, "_is_cancelled", lambda r: next(asked) >= seen)
+    (got, reason, sizes), = _step(core, [request])
+    assert got == single_step_tokens[0][9:9 + seen]
+    assert reason == "cancelled" and sizes == ([seen] if seen else [])
+    assert request.events.empty() and core.slots[0].request is None
+    monkeypatch.undo()
+    again, finish, _ = _run_inline(core, [_req(PROMPTS[0], 12)])
+    assert (again, finish) == ([single_step_tokens[0][:12]], ["length"])
+
+
+def test_the_speculative_step_puts_one_event_a_row(cfg):
+    """A verify step's accepted drafts + 1 of a row are one fetch: one
+    content event, of several tokens where drafts were accepted, and the
+    tokens are the non-speculating engine's."""
+    prompt = [5, 6, 7, 8, 9] * 5  # repetitive: prompt lookup drafts
+    want, _, _ = _run_inline(_inline(cfg, decode_burst=1),
+                             [_req(prompt, 48)])
+    core = _inline(cfg, decode_burst=1, spec_decode=True)
+    tokens, finish, rounds = _run_inline(core, [_req(prompt, 48)])
+    assert (tokens, finish) == (want, ["length"])
+    assert core.metrics.spec_verify_steps_total > 0
+    assert all(len(sizes) == 1 for sizes in rounds[0])  # one event a round
+    accepted = core.metrics.spec_accepted_tokens_total
+    assert accepted > 0 and max(s for (s,) in rounds[0]) > 1
+    # every accepted draft saved its row an event
+    assert len(rounds[0]) <= 48 - accepted
 
 
 def test_batched_prefill_matches_sequential(cfg):

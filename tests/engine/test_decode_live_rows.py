@@ -20,6 +20,7 @@ import pytest
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.ops.attention import traced_routes
+from tests.support import collect
 
 SLOTS, CAPACITY, PAGE, BURST = 6, 512, 16, 4
 WINDOW = 256  # the smallest bucket; every context here stays under it
@@ -37,14 +38,7 @@ def _request(name):
 
 
 def _tokens(request):
-    out = []
-    while not request.events.empty():
-        kind, val = request.events.get_nowait()
-        if kind == "token":
-            out.append(val)
-        else:
-            assert kind == "done", val
-    return out
+    return collect(request, timeout=None)[0]  # what is queued
 
 
 def _serve(monkeypatch, route):

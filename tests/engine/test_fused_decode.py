@@ -21,6 +21,7 @@ from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.tokenizer import ByteTokenizer
 from llmlb_tpu.lora import save_adapter
 from llmlb_tpu.structured import ConstraintCompiler
+from tests.support import collect as _drain
 
 CFG = get_preset("debug-tiny")
 TOK = ByteTokenizer(CFG.vocab_size)
@@ -44,18 +45,6 @@ def lora_dir(tmp_path_factory):
     d = tmp_path_factory.mktemp("fused_adapters")
     save_adapter(str(d), "acme", CFG, rank=4)
     return str(d)
-
-
-def _drain(request: Request) -> tuple[list[int], str]:
-    toks = []
-    while True:
-        kind, val = request.events.get(timeout=120)
-        if kind == "token":
-            toks.append(val)
-        elif kind == "done":
-            return toks, str(val)
-        else:
-            raise RuntimeError(val)
 
 
 def _core(*, fused: bool, quant: str | None, lora_dir: str | None,

@@ -32,6 +32,8 @@ from llmlb_tpu.engine.kv_transfer import (
 )
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
+from tests.support import collect as _collect
+from tests.support import take_tokens
 
 # ---------------------------------------------------------------- wire format
 
@@ -202,18 +204,6 @@ def _req(prompt, max_tokens=4, temperature=0.0, seed=None, priority=1):
                                            priority=priority))
 
 
-def _collect(request, timeout=120):
-    toks = []
-    while True:
-        kind, value = request.events.get(timeout=timeout)
-        if kind == "token":
-            toks.append(value)
-        elif kind == "error":
-            raise AssertionError(f"engine error: {value}")
-        else:
-            return toks, value
-
-
 def _park_roundtrip(*, offload, temperature=0.0, seed=None, quantize=None,
                     kv_ship=None):
     """Reference run, then the same request parked mid-decode by a
@@ -238,11 +228,8 @@ def _park_roundtrip(*, offload, temperature=0.0, seed=None, quantize=None,
         victim = core.submit(_req(prompt, max_tokens=24,
                                   temperature=temperature, seed=seed,
                                   priority=2))
-        toks = []
-        while len(toks) < 3:  # decoding: parked mid-generation, not queued
-            kind, value = victim.events.get(timeout=60)
-            assert kind == "token", (kind, value)
-            toks.append(value)
+        # decoding: parked mid-generation, not queued
+        toks = take_tokens(victim, 3, timeout=60)
         _collect(core.submit(_req([2] * 8, max_tokens=4, priority=0)))
         rest, _ = _collect(victim)
         toks += rest

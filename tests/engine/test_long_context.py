@@ -16,7 +16,7 @@ from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.models import llama
-from tests.support import identity_kv_pages, kv_rows
+from tests.support import collect, identity_kv_pages, kv_rows
 
 
 @pytest.fixture(scope="module")
@@ -90,14 +90,7 @@ def test_chunked_greedy_matches_oneshot_decode(tiny_cfg, tiny_params):
                       sampling=SamplingParams(temperature=0.0, max_tokens=8))
         core.submit(req)
         core.start()
-        toks = []
-        while True:
-            kind, val = req.events.get(timeout=60)
-            if kind == "token":
-                toks.append(val)
-            else:
-                assert kind == "done", (kind, val)
-                break
+        toks, _ = collect(req, timeout=60)
         core.stop()
         outs.append(toks)
     assert outs[0] == outs[1], outs
@@ -147,14 +140,7 @@ def test_decode_progresses_during_long_prefill(tiny_cfg, tiny_params):
 
     # run the loop to completion for the long request
     core.start()
-    toks = []
-    while True:
-        kind, val = long.events.get(timeout=60)
-        if kind == "token":
-            toks.append(val)
-        else:
-            assert kind == "done", (kind, val)
-            break
+    collect(long, timeout=60)
     core.stop()
 
 
@@ -205,16 +191,7 @@ def test_cp_prefill_engine_matches_chunked(tiny_cfg):
                 sampling=SamplingParams(temperature=0.0, max_tokens=8),
             )
             core.submit(req)
-            toks = []
-            while True:
-                kind, val = req.events.get(timeout=120)
-                if kind == "token":
-                    toks.append(val)
-                elif kind == "done":
-                    break
-                else:
-                    raise AssertionError(f"engine error: {val}")
-            return toks
+            return collect(req)[0]
         finally:
             core.stop()
 

@@ -17,6 +17,7 @@ from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.lora import save_adapter
+from tests.support import collect as _drain
 
 CFG = get_preset("debug-tiny")
 PROMPT = [3, 5, 7, 9, 11, 2, 4, 6]
@@ -29,18 +30,6 @@ def lora_dir(tmp_path_factory):
     for n in ADAPTERS:
         save_adapter(str(d), n, CFG, rank=4)
     return str(d)
-
-
-def _drain(request: Request) -> tuple[list[int], str]:
-    toks = []
-    while True:
-        kind, val = request.events.get(timeout=60)
-        if kind == "token":
-            toks.append(val)
-        elif kind == "done":
-            return toks, str(val)
-        else:
-            raise RuntimeError(val)
 
 
 def _run(core, lora=None, seed=None, temp=0.0, max_tokens=12,

@@ -21,6 +21,7 @@ from llmlb_tpu.engine.paging import PageError, PagePool
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from tests.support import assert_hit_is_zero_copy
+from tests.support import collect as _collect
 
 # ------------------------------------------------------------------ page pool
 
@@ -84,18 +85,6 @@ def _req(prompt, max_tokens=4, temperature=0.0):
     return Request(prompt_ids=list(prompt),
                    sampling=SamplingParams(temperature=temperature,
                                            max_tokens=max_tokens))
-
-
-def _collect(request, timeout=120):
-    toks = []
-    while True:
-        kind, value = request.events.get(timeout=timeout)
-        if kind == "token":
-            toks.append(value)
-        elif kind == "error":
-            raise AssertionError(f"engine error: {value}")
-        else:
-            return toks, value
 
 
 def _core(**kw):
@@ -275,7 +264,7 @@ def test_hit_under_pool_pressure_never_evicts_its_own_donor(prompt):
             kind, value = r.events.get_nowait()
             if kind == "done":
                 break
-            assert kind == "token"
+            assert kind == "tokens"
         except queue.Empty:
             pass
     else:

@@ -20,7 +20,7 @@ import pytest
 from llmlb_tpu.engine.prefix_cache import PrefixCache
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
-from tests.support import assert_hit_is_zero_copy
+from tests.support import assert_hit_is_zero_copy, collect
 
 # ----------------------------------------------------------------- radix tree
 
@@ -164,15 +164,7 @@ def _run(core, prompt_ids, *, max_tokens=4, temperature=0.0):
                 sampling=SamplingParams(temperature=temperature,
                                         max_tokens=max_tokens))
     core.submit(r)
-    toks = []
-    while True:
-        kind, value = r.events.get(timeout=120)
-        if kind == "token":
-            toks.append(value)
-        elif kind == "error":
-            raise AssertionError(f"engine error: {value}")
-        else:
-            return toks, value
+    return collect(r)
 
 
 @pytest.fixture(scope="module")

@@ -21,6 +21,7 @@ import pytest
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from tests.support import assert_hit_is_zero_copy
+from tests.support import collect as _collect
 
 
 def _req(prompt, max_tokens=8, temperature=0.0, seed=None, spec=None):
@@ -28,18 +29,6 @@ def _req(prompt, max_tokens=8, temperature=0.0, seed=None, spec=None):
                    sampling=SamplingParams(temperature=temperature,
                                            max_tokens=max_tokens,
                                            seed=seed, speculative=spec))
-
-
-def _collect(request, timeout=120):
-    toks = []
-    while True:
-        kind, value = request.events.get(timeout=timeout)
-        if kind == "token":
-            toks.append(value)
-        elif kind == "error":
-            raise AssertionError(f"engine error: {value}")
-        else:
-            return toks, value
 
 
 def _core(**kw):

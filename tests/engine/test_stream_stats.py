@@ -22,7 +22,7 @@ def _stamps(monkeypatch, stamps):
 # --------------------------------------------------------- the stamped queue
 
 
-@pytest.mark.parametrize("event", [("token", 7), ("tokens", [7, 8, 9, 10]),
+@pytest.mark.parametrize("event", [("tokens", [7]), ("tokens", [7, 8, 9, 10]),
                                    ("done", "length"), ("error", "boom")])
 def test_the_queue_hands_every_event_over_as_it_was_put(monkeypatch, event):
     _stamps(monkeypatch, [1.0, 2.0])
@@ -47,7 +47,7 @@ def test_residence_and_backlog_with_made_up_stamps(monkeypatch):
     _stamps(monkeypatch, [10.0, 10.1, 10.2, 10.5, 10.6, 10.7])
     stats, q = StreamStats(), EventQueue()
     stats.open(q)
-    q.put(("token", 1))
+    q.put(("tokens", [1]))
     q.put(("tokens", [2, 3, 4, 5]))
     q.put(("done", "stop"))
     take = q.taker()
@@ -141,8 +141,10 @@ async def test_conservation_over_whole_streams(engine):
     snap = stats.snapshot()
     d = {k: snap[k] - before[k] for k in snap
          if k.endswith("_total")}
-    # every stream: 12 token events and the done
-    assert d["events_total"] == d["events_put_total"] == 6 * 13
+    # every stream: a content event a fetch (this engine's step makes one
+    # token a row, and the first fetch brings the activation's token with
+    # it: 2, then 10 of 1) and the done — events are counted, not tokens
+    assert d["events_total"] == d["events_put_total"] == 6 * 12
     assert d["events_unread_total"] == 0 and _conserved(snap)
     assert snap["events_queued"] == 0 and snap["streams_live"] == 0
     # the tokens the consumers took are the tokens the scheduler made
@@ -152,6 +154,46 @@ async def test_conservation_over_whole_streams(engine):
     assert d["stream_seconds_total"] - d["made_seconds_total"] >= 0
     assert 0 < d["frames_total"] <= d["events_total"]
     assert d["frame_seconds_total"] > 0 and d["event_wait_seconds_total"] > 0
+
+
+@pytest.fixture(scope="module")
+def burst_engine():
+    """A dense engine whose fetch brings a row 8 tokens and whose every
+    token is text."""
+    from tests.support import word_engine
+
+    eng = word_engine(8)
+    yield eng
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("max_tokens, sizes", [
+    (33, [9, 8, 8, 8]),  # the activation's token rides the first burst
+    (20, [9, 8, 3]),     # ended inside a burst: what came before the end
+    (1, [1]),
+])
+async def test_a_dense_burst_is_one_event_and_one_frame_a_row(
+        burst_engine, max_tokens, sizes):
+    """tokens_total / frames_total of a dense stream reads the burst, not
+    1.0, and events_put_total counts events, not tokens."""
+    stats = burst_engine.core.metrics.stream
+    before = stats.snapshot()
+
+    async def one(i):
+        got = []
+        async for delta in burst_engine.stream([1 + i, 2, 3, 4],
+                                               _sampling(max_tokens)):
+            if delta.token_ids:
+                got.append(len(delta.token_ids))
+        return got
+
+    assert await asyncio.gather(*(one(i) for i in range(4))) == [sizes] * 4
+    snap = stats.snapshot()
+    d = {k: snap[k] - before[k] for k in snap if k.endswith("_total")}
+    assert d["tokens_total"] == 4 * max_tokens
+    assert d["frames_total"] == 4 * len(sizes)
+    assert d["events_put_total"] == d["events_total"] == 4 * (len(sizes) + 1)
+    assert _conserved(snap) and d["events_unread_total"] == 0
 
 
 async def test_a_consumer_that_quits_mid_stream(engine):
@@ -167,7 +209,8 @@ async def test_a_consumer_that_quits_mid_stream(engine):
     snap = stats.snapshot()
     assert snap["streams_live"] == 0 and snap["events_queued"] == 0
     assert _conserved(snap)
-    assert snap["events_total"] - before["events_total"] >= 3
+    # the three tokens came in two events (the first fetch brought two)
+    assert snap["events_total"] - before["events_total"] >= 2
     # a stream that did not run to its end counts under no duration
     assert snap["streams_finished_total"] == before["streams_finished_total"]
     # and the engine still serves, conserving
@@ -253,7 +296,7 @@ async def test_stream_stamps_stay_under_one_percent_of_a_step(engine):
     def path(q, stats, plain):
         take = q.get if plain else q.taker()
         for _ in range(rows):
-            q.put(("token", 1))
+            q.put(("tokens", [1]))
             if plain:
                 take()
                 continue

@@ -179,7 +179,7 @@ class _ScriptedCore:
 
     def submit(self, request):
         for t in self._tokens:
-            request.events.put(("token", t))
+            request.events.put(("tokens", [t]))
         request.events.put(("done", "stop"))
         return request
 

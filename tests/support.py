@@ -11,6 +11,7 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import json
+import queue
 
 from aiohttp import web
 from aiohttp.test_utils import TestClient, TestServer
@@ -30,6 +31,77 @@ from llmlb_tpu.gateway.types import (
 
 TEST_JWT_SECRET = "test-jwt-secret"
 ADMIN_PASSWORD = "adminpass1"
+
+
+# ------------------------------------------------- a request's events
+#
+# The one reader of `Request.events` for the engine's tests: a content event
+# is ("tokens", [ids]) and carries what one fetch brought the request
+# (scheduler.event_tokens), so a test that counts tokens counts ids, never
+# events.
+
+def collect_events(request, timeout: float | None = 120.0):
+    """Read a request's events to its `done`: (tokens, finish reason,
+    tokens per content event). `timeout=None` takes only what is queued,
+    and the finish reason is None where the request has not ended. An
+    `error` event raises."""
+    from llmlb_tpu.engine.scheduler import event_tokens
+
+    tokens: list[int] = []
+    sizes: list[int] = []
+    while True:
+        try:
+            kind, value = (request.events.get_nowait() if timeout is None
+                           else request.events.get(timeout=timeout))
+        except queue.Empty:
+            if timeout is None:
+                return tokens, None, sizes
+            raise
+        if kind == "done":
+            return tokens, value, sizes
+        assert kind == "tokens", f"engine {kind}: {value}"
+        ids = event_tokens(kind, value)
+        tokens.extend(ids)
+        sizes.append(len(ids))
+
+
+def collect(request, timeout: float | None = 120.0):
+    """(tokens, finish reason) of `collect_events`."""
+    tokens, finish, _ = collect_events(request, timeout)
+    return tokens, finish
+
+
+def take_tokens(request, n: int, timeout: float = 120.0) -> list[int]:
+    """The content events of a request that is still generating, until
+    they carry `n` tokens or more: all of their tokens."""
+    from llmlb_tpu.engine.scheduler import event_tokens
+
+    tokens: list[int] = []
+    while len(tokens) < n:
+        kind, value = request.events.get(timeout=timeout)
+        assert kind == "tokens", (kind, value)
+        tokens.extend(event_tokens(kind, value))
+    return tokens
+
+
+def word_engine(decode_burst: int, **core_kwargs):
+    """A started debug-tiny engine that fetches `decode_burst` tokens a row
+    at a time, behind the benchmark's tokenizer, whose every id is a word:
+    `Engine.stream` writes a frame only where the text grew, and
+    ByteTokenizer decodes most sampled ids to nothing."""
+    from benchmark.tokenizer import WordTokenizer
+    from llmlb_tpu.engine.presets import get_preset
+    from llmlb_tpu.engine.scheduler import EngineCore
+    from llmlb_tpu.engine.service import Engine
+
+    cfg = get_preset("debug-tiny")
+    tokenizer = WordTokenizer(cfg.vocab_size)
+    core_kwargs = {"num_slots": 4, "slot_capacity": 128,
+                   "prefill_buckets": (16, 32), "seed": 0, **core_kwargs}
+    core = EngineCore(cfg, decode_burst=decode_burst,
+                      eos_id=tokenizer.eos_id, **core_kwargs)
+    core.start()
+    return Engine("debug-tiny", core, tokenizer)
 
 
 # ------------------------------------------------- family-level KV fixtures

@@ -70,6 +70,6 @@ def test_checker_accepts_instrumented(tmp_path):
                               reason="preempt")
 
             def _tokens_only(self, request, tok):
-                request.events.put(("token", tok))  # not terminal: no emit
+                request.events.put(("tokens", [tok]))  # not terminal: no emit
     """))
     assert check_lifecycle_events.check_scheduler(ok) == []
