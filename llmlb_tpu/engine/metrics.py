@@ -198,6 +198,11 @@ class EngineMetrics:
         self.moe_expert_assignments_total = 0
         self.moe_expert_load_max = 0
         self.moe_expert_load_hist: list[list[int]] = []
+        # Of a chip that holds a share of the experts: assignments that went
+        # to the others; and rows whose recurrent state a dispatch advanced
+        # (models/nemotron_h.step_counter_shapes)
+        self.moe_assignments_elsewhere_total = 0
+        self.ssm_state_rows_total = 0
         self.decode_kv_pages_window_total = 0
         # Generation by diffusion over blocks (scheduler._emit_blocks):
         # running totals of the bursts' counts, by the step records' names
@@ -410,6 +415,9 @@ class EngineMetrics:
                 "expert_assignments", 0)
             self.moe_expert_load_max = max(
                 self.moe_expert_load_max, counters.get("expert_load_max", 0))
+            self.moe_assignments_elsewhere_total += counters.get(
+                "assignments_elsewhere", 0)
+            self.ssm_state_rows_total += counters.get("state_rows", 0)
             hist = counters.get("expert_load_hist")
             if hist:
                 if not self.moe_expert_load_hist:
@@ -605,6 +613,9 @@ class EngineMetrics:
                 "moe_expert_assignments_total":
                     self.moe_expert_assignments_total,
                 "moe_expert_load_max": self.moe_expert_load_max,
+                "moe_assignments_elsewhere_total":
+                    self.moe_assignments_elsewhere_total,
+                "ssm_state_rows_total": self.ssm_state_rows_total,
                 "moe_expert_load_hist": [list(row) for row in
                                          self.moe_expert_load_hist],
                 "preemptions_total": self.preemptions_total,
@@ -739,6 +750,11 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_moe_expert_assignments_total counter",
                 "llmlb_engine_moe_expert_assignments_total "
                 f"{self.moe_expert_assignments_total}",
+                "# TYPE llmlb_engine_moe_assignments_elsewhere_total counter",
+                "llmlb_engine_moe_assignments_elsewhere_total "
+                f"{self.moe_assignments_elsewhere_total}",
+                "# TYPE llmlb_engine_ssm_state_rows_total counter",
+                f"llmlb_engine_ssm_state_rows_total {self.ssm_state_rows_total}",
                 "# TYPE llmlb_engine_moe_expert_load_max gauge",
                 "llmlb_engine_moe_expert_load_max "
                 f"{self.moe_expert_load_max}",
@@ -948,6 +964,8 @@ class EngineMetrics:
                 lines += [
                     "# TYPE llmlb_engine_param_bytes gauge",
                     f"llmlb_engine_param_bytes {quant.get('param_bytes', 0)}",
+                    "# TYPE llmlb_engine_state_bytes gauge",
+                    f"llmlb_engine_state_bytes {quant.get('state_bytes', 0)}",
                 ]
             if kv_cache is not None:
                 # honest-dtype KV footprint, so capacity dashboards never

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.mixtral import MixtralConfig
+from llmlb_tpu.models.nemotron_h import NemotronHConfig
 from llmlb_tpu.models.sdar_moe import SdarMoeConfig
 from llmlb_tpu.ops.rope import RopeScaling
 
@@ -51,6 +52,19 @@ PRESETS: dict[str, LlamaConfig] = {
         rope_theta=1000000.0, rms_eps=1e-6, dtype=jnp.float32,
         max_position_embeddings=512, num_experts=16, experts_per_token=4,
         moe_intermediate_size=32, mask_token_id=500,
+    ),
+    # CI-sized hybrid of state-space, attention and expert layers
+    # (models/nemotron_h.py, docs/hybrid-state.md): every kind of layer, a
+    # scan chunk of 16, and the second half (4 of 8) of the experts held
+    "debug-nemotron-h-tiny": NemotronHConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=32,
+        num_layers=7, num_heads=4, num_kv_heads=2, head_dim=16,
+        rms_eps=1e-5, dtype=jnp.float32, max_position_embeddings=512,
+        pattern="MEM*EME", ssm_heads=8, ssm_head_dim=8, ssm_groups=2,
+        ssm_state=16, conv_kernel=4, chunk_size=16, router_experts=8,
+        num_experts=4, first_expert=4, experts_per_token=2,
+        moe_intermediate_size=32, shared_intermediate_size=48,
+        routed_scaling_factor=2.5,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

@@ -102,13 +102,20 @@ def kv_bytes_per_token_layer(cfg, quantized: bool = False) -> int:
     return int(family_for(cfg).kv_token_layer_bytes(cfg, quantized))
 
 
+def kv_pool_layers(cfg) -> int:
+    """Layers of the page pool: every layer, unless the family says that
+    only some of its layers attend (`kv_pool_layers`)."""
+    return int(getattr(family_for(cfg), "kv_pool_layers",
+                       lambda c: c.num_layers)(cfg))
+
+
 def kv_page_bytes(cfg, page_size: int, quantized: bool = False) -> int:
     """HBM bytes ONE page holds across all layers: page_size tokens of the
     family's per-token, per-layer cell (for a GQA family, K and V: the bf16
     cell is D·2 bytes per (token, head); the int8 cell is D·1 plus one f32
     scale, llmlb_tpu/quant.kv_cell_bytes) — the per-page figure the kv
     gauges report so capacity math stays honest under quantization."""
-    return int(cfg.num_layers * page_size * kv_bytes_per_token_layer(
+    return int(kv_pool_layers(cfg) * page_size * kv_bytes_per_token_layer(
         cfg, quantized))
 
 
@@ -540,6 +547,17 @@ class EngineCore:
         # token a step, on the programs it always built.
         self.block = int(getattr(self.family, "block_length",
                                  lambda _cfg: 1)(cfg))
+        # A family whose layers keep a recurrent state per SLOT beside the
+        # page pool (docs/hybrid-state.md) says so by `state_slot_bytes`:
+        # its pool is made for the slots, its prefill calls are told the
+        # rows' slots, and what would serve such a state wrong is off unless
+        # asked for, and refused when asked for (_check_slot_state_engine).
+        self._slot_state = hasattr(self.family, "state_slot_bytes")
+        if self._slot_state:
+            if prefix_cache is None and "LLMLB_PREFIX_CACHE" not in os.environ:
+                prefix_cache = False
+            if kv_ship is None and "LLMLB_KV_SHIP" not in os.environ:
+                kv_ship = False
         self.num_slots = num_slots
         self.slot_capacity = min(slot_capacity, cfg.max_position_embeddings)
         self.prefill_buckets = tuple(
@@ -596,6 +614,7 @@ class EngineCore:
             prefix_cache_slots = max(1, num_slots // 2)
         # the entry budget: max cached prefixes, capped below the slot count
         budget = max(0, min(int(prefix_cache_slots), num_slots - 1))
+        self._prefix_cache_asked = bool(prefix_cache)
         self.prefix_cache: PrefixCache | None = (
             PrefixCache(max_entries=budget, min_len=self.min_prefix_len,
                         align=self.prefix_align)
@@ -1062,6 +1081,9 @@ class EngineCore:
             int(v.size) * jnp.dtype(v.dtype).itemsize
             for v in self.params.values()
         )
+        # what the pool holds per slot beside its pages (a recurrent state)
+        self.state_bytes = (num_slots * int(self.family.state_slot_bytes(cfg))
+                            if self._slot_state else 0)
         self._running = False
         self._thread: threading.Thread | None = None
         self._started_at = time.monotonic()
@@ -1088,6 +1110,8 @@ class EngineCore:
         }
         if self.block > 1:
             self._check_block_engine()
+        if self._slot_state:
+            self._check_slot_state_engine()
         if self.role == "split":
             from llmlb_tpu.disagg.split import SplitRuntime
 
@@ -1124,6 +1148,29 @@ class EngineCore:
         # a block family resumes by chunk-prefill replay alone
         self.kv_ship = False
         self.kv_offload = None
+
+    def _check_slot_state_engine(self) -> None:
+        """What an engine of a family with a recurrent state per slot
+        refuses to start with rather than serve wrong
+        (docs/hybrid-state.md): each would read or move pages whose state
+        is not with them."""
+        name = self.family.__name__
+        for on, what, why in (
+            (self._prefix_cache_asked, "the prefix cache",
+             "a hit would extend behind pages that carry no state"),
+            (self.spec.enabled, "speculative decoding",
+             "a rejected draft would leave the state advanced"),
+            (self.kv_ship, "kv_ship",
+             "the state has no wire form: a handoff or resume replays"),
+            (self.kv_offload is not None, "the KV offload tier",
+             "parked pages would come back without their state"),
+            (self.role == "split", "--role split",
+             "the handoff moves pages by block table, not the state"),
+        ):
+            if on:
+                raise NotImplementedError(
+                    f"{name} keeps a recurrent state per slot beside the "
+                    f"page pool, which is not served with {what} yet ({why})")
 
     def _check_block_request(self, request: Request) -> None:
         """Raise ValueError for what a request asks of generation by
@@ -1690,9 +1737,10 @@ class EngineCore:
 
     def _fresh_kv_pool(self):
         """A zeroed K and V page pool, placed on the mesh."""
+        slots = {"num_slots": self.num_slots} if self._slot_state else {}
         ck, cv = self.family.init_kv_pages(self.cfg, self.kv_num_pages,
                                            self.kv_page_size,
-                                           quantized=self.quant.kv)
+                                           quantized=self.quant.kv, **slots)
         ck_sh, cv_sh = self.family.kv_pages_shardings(
             self.cfg, self.mesh, quantized=self.quant.kv
         )
@@ -3606,8 +3654,8 @@ class EngineCore:
             "bytes_per_page": kv_page_bytes(self.cfg, self.kv_page_size,
                                             quantized=self.quant.kv),
             # all layers of one token: the family's own cell
-            "bytes_per_token": self.cfg.num_layers * kv_bytes_per_token_layer(
-                self.cfg, self.quant.kv),
+            "bytes_per_token": kv_pool_layers(self.cfg)
+            * kv_bytes_per_token_layer(self.cfg, self.quant.kv),
             "hbm_bytes": kv_pool_bytes(self.cfg, self.kv_num_pages,
                                        self.kv_page_size,
                                        quantized=self.quant.kv),
@@ -3626,6 +3674,7 @@ class EngineCore:
             "effective_kv_dtype": ("int8" if self.quant.kv
                                    else str(jnp.dtype(self.cfg.dtype))),
             "param_bytes": self.param_bytes,
+            "state_bytes": self.state_bytes,
             "param_bytes_bf16": self.n_params * itemsize,
             "kv_cell_bytes": kv_cell_bytes(self.cfg.head_dim_,
                                            self.quant.kv, itemsize),
@@ -3704,6 +3753,13 @@ class EngineCore:
             )
         return info
 
+    def _state_slots(self, slot_ids) -> dict:
+        """The keyword that tells a prefill call its rows' slots, for a
+        family that keeps state per slot; nothing for any other."""
+        if not self._slot_state:
+            return {}
+        return {"slot_ids": jnp.asarray(slot_ids, jnp.int32)}
+
     def _prefill_counters(self, stats: list) -> dict | None:
         """A prefill dispatch's step counters on the host. The dispatch has
         been waited for, and a prefill fetches nothing else: this is its
@@ -3759,6 +3815,7 @@ class EngineCore:
             self.cache_v,
             self.mesh,
             lora_idx=lora_idx,
+            **self._state_slots(slot_ids),
         )
         step.mark("compute")
         # jitted prefill returns futures (async dispatch); block before timing
@@ -4089,6 +4146,7 @@ class EngineCore:
             self.cache_v,
             self.mesh,
             lora_idx=lora_idx,
+            **self._state_slots([slot_id]),
         )
         step.mark("compute")
         jax.block_until_ready(logits)  # async dispatch; time real execution

@@ -9,7 +9,10 @@ same continuous-batching loop; DeepSeek-V3-class (deepseek_v3.py: latent
 attention, sigmoid-routed experts behind leading dense layers) brings its own
 attention and layer stack to the same bodies, and SDAR-MoE-class
 (sdar_moe.py: QK-normed GQA under a block-causal mask, generation by
-diffusion over blocks) declares a block length the scheduler decodes by.
+diffusion over blocks) declares a block length the scheduler decodes by;
+Nemotron-H-class (nemotron_h.py: a stack of state-space, attention and
+expert layers, one mixer a layer) keeps a recurrent state per slot beside
+the page pool.
 
 `config_from_hf(hf, dtype)` picks the configuration class of a published
 `config.json` by its `model_type` and refuses a config that carries a key the
@@ -37,10 +40,12 @@ MODEL_TYPES = {
     "mixtral": "mixtral",
     "deepseek_v3": "deepseek_v3",
     "sdar_moe": "sdar_moe",
+    "nemotron_h": "nemotron_h",
 }
 _CONFIG_CLASSES = {"llama": "LlamaConfig", "mixtral": "MixtralConfig",
                    "deepseek_v3": "DeepseekV3Config",
-                   "sdar_moe": "SdarMoeConfig"}
+                   "sdar_moe": "SdarMoeConfig",
+                   "nemotron_h": "NemotronHConfig"}
 
 # Keys that change the function a model computes, and the classes that read
 # them. A config carrying one for a class that does not read it would be
@@ -49,12 +54,16 @@ _ABSENT = (None, False, 0, 1, [], {})
 _MECHANISM_KEYS = {
     "kv_lora_rank": ("deepseek_v3",),
     "q_lora_rank": (),
-    "n_routed_experts": ("deepseek_v3",),
-    "n_shared_experts": ("deepseek_v3",),
+    "n_routed_experts": ("deepseek_v3", "nemotron_h"),
+    "n_shared_experts": ("deepseek_v3", "nemotron_h"),
     "first_k_dense_replace": ("deepseek_v3",),
     "num_local_experts": ("mixtral",),
     "num_experts": ("mixtral", "sdar_moe"),
-    "moe_intermediate_size": ("deepseek_v3", "sdar_moe"),
+    "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h"),
+    "hybrid_override_pattern": ("nemotron_h",),
+    "mamba_num_heads": ("nemotron_h",),
+    "ssm_state_size": ("nemotron_h",),
+    "expert_parallel": ("nemotron_h",),
     "sliding_window": (),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
@@ -96,10 +105,18 @@ def config_from_hf(hf: dict, dtype=None):
 
 def family_for(cfg):
     """Resolve the serving-function module for a model config."""
-    from llmlb_tpu.models import deepseek_v3, llama, mixtral, sdar_moe
+    from llmlb_tpu.models import (
+        deepseek_v3,
+        llama,
+        mixtral,
+        nemotron_h,
+        sdar_moe,
+    )
 
     if isinstance(cfg, deepseek_v3.DeepseekV3Config):
         return deepseek_v3
+    if isinstance(cfg, nemotron_h.NemotronHConfig):
+        return nemotron_h
     if isinstance(cfg, sdar_moe.SdarMoeConfig):
         return sdar_moe
     if isinstance(cfg, mixtral.MixtralConfig):

@@ -288,9 +288,27 @@ def kv_wire_cell(cfg: LlamaConfig) -> tuple[int, int]:
     return cfg.num_kv_heads, cfg.head_dim_
 
 
+class StatePool(NamedTuple):
+    """One of the (cache_k, cache_v) pair of a family whose layers do not
+    all attend: the page pool of its attention layers, and beside it what
+    its other layers carry per SLOT, not per page (a state-space layer's
+    recurrent state: models/nemotron_h.py). It travels, is donated and is
+    returned as the pages alone do; the bodies below write and read the
+    pages through it (_pages, _write_pool) and hand the whole to a group's
+    `mixer`."""
+
+    pages: Any  # [L_attn, P, PS, K, D], or a quantized {"q", "s"} pair
+    state: jnp.ndarray  # [L_state, slots, ...]
+
+
+def _pages(pool):
+    return pool.pages if isinstance(pool, StatePool) else pool
+
+
 def kv_pool_values(pool):
     """The value array of a KV page pool (the int8 member of a quantized
     {"q","s"} pair, or the pool itself when bf16)."""
+    pool = _pages(pool)
     return pool["q"] if isinstance(pool, dict) else pool
 
 
@@ -300,6 +318,9 @@ def _write_pool(pool, layer, page, off, kv):
     run-time scalar (the layer scan of prefill and extend), `page` and
     `off` arrays of the rows' shape. Quantized pools take the int8 values
     plus the per-vector scales at the same indices — quantize-on-write."""
+    if isinstance(pool, StatePool):
+        return pool._replace(
+            pages=_write_pool(pool.pages, layer, page, off, kv))
     if isinstance(pool, dict):
         q, s = quantize_kv(kv)
         return {"q": pool["q"].at[layer, page, off].set(q),
@@ -448,16 +469,24 @@ class Attention(NamedTuple):
 
 class LayerGroup(NamedTuple):
     """`count` consecutive layers of one kind: their stacked parameters
-    (`names`, each [count, ...], under `prefix + name` in the pytree where
-    two groups have parameters of one name) and their feed-forward. A model
-    is a list
-    of groups in layer order (Llama and Mixtral: one; a model with leading
-    dense layers before its expert layers: two). Prefill and extend scan
-    each group's parameters with the page pool as the carry (_scan_groups),
-    decode unrolls them all."""
+    (`names`, under `prefix + name` in the pytree where two groups have
+    parameters of one name), what mixes their tokens and their
+    feed-forward. A model is a list of groups in layer order (Llama and
+    Mixtral: one; a model with leading dense layers before its expert
+    layers: two; a stack whose kinds alternate layer by layer: a group a
+    layer, all groups of a kind reading one stack at their `start`).
+    Prefill and extend scan each group's parameters with the page pool as
+    the carry (_scan_groups), decode unrolls them all.
+
+    A layer is `x + mix(norm(x))`, then `x + mlp_fn(norm(x))`, and either
+    half may be absent: `attends` says that the mix is the model's
+    `Attention` over the page pool; `mixer(lp, x, cache_k, cache_v, layer,
+    rows: StateRows) -> (x + mix, cache_k, cache_v)` is another mix, with a
+    state of its own in the pool (StatePool); `mlp_fn` None is a layer
+    without a feed-forward."""
 
     names: tuple
-    mlp_fn: Callable
+    mlp_fn: Callable | None
     count: int
     prefix: str = ""  # of the group's keys in the pytree; a layer sees none
     # Of `names`, those a layer is handed WHOLE ([count, ...]) beside its
@@ -465,6 +494,24 @@ class LayerGroup(NamedTuple):
     # of a kernel, which takes whole buffers — a slice of the stack would be
     # copied on every call, as a slice of the page pool was (PR 25).
     whole: tuple = ()
+    start: int = 0  # the group's first layer in its stacks [start + count, ...]
+    # The group's first layer in the POOL it writes (the page pool's layer
+    # axis, or its mixer's state); None: its place in the whole stack.
+    pool_layer: int | None = None
+    attends: bool = True
+    mixer: Callable | None = None
+
+
+class StateRows(NamedTuple):
+    """What a group's `mixer` is told about the rows of a call. Prefill:
+    `slots` and `lens` (a fresh sequence: whatever the slot held is void).
+    Extend: those and `start_pos` (0 is a fresh sequence too). Decode: one
+    token a row, `live` the rows to advance (None: all)."""
+
+    slots: jnp.ndarray | None  # [B] the rows' slots; None: row i is slot i
+    lens: jnp.ndarray | None = None  # [B] valid tokens of the chunk
+    start_pos: jnp.ndarray | None = None  # [B] tokens before the chunk
+    live: jnp.ndarray | None = None  # [B] bool
 
 
 GQA_ATTENTION = Attention(_attn_block, gqa_attention_prefill,
@@ -480,19 +527,26 @@ def _groups_for(cfg, stacked_names, mlp_fn, groups):
 
 
 def _group_params(params: Params, group: LayerGroup) -> tuple[Params, Params]:
-    """The group's stacked parameters with their companions (_with_scales),
+    """The stacks the group reads, with their companions (_with_scales),
     under the names a layer reads them by: (those a layer gets its slice
-    of, those it gets whole). The first holds the layers' indices too
-    (`layer`) where the second is not empty."""
+    of, those it gets whole beside its own index in them, `lp["layer"]`).
+    The group's layers are [start, start + count) of each."""
     keys = _with_scales(params, [group.prefix + n for n in group.names])
     named = {k[len(group.prefix):]: params[k] for k in keys}
     whole = {n: w for n, w in named.items()
              if any(n == base or n.startswith(base + "_")
                     for base in group.whole)}
-    sliced = {n: w for n, w in named.items() if n not in whole}
-    if whole:
-        sliced["layer"] = jnp.arange(group.count, dtype=jnp.int32)
-    return sliced, whole
+    return {n: w for n, w in named.items() if n not in whole}, whole
+
+
+def _feed_forward(cfg, group: LayerGroup, lp: Params, x, token_valid,
+                  lora_idx):
+    """x + the group's feed-forward of norm(x), and what it reported."""
+    if group.mlp_fn is None:
+        return x, None
+    h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
+    out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
+    return x + out, aux
 
 
 def _mlp_out(res):
@@ -513,29 +567,35 @@ def _scan_groups(params, groups, x, cache_k, cache_v, layer_of):
     kernel reads the pool at (layer, page), so the only pool-sized value in
     the program is the donated pool itself, as in decode. A second group
     goes on with the same carry. `layer_of(group)` gives the layer `(carry,
-    lp, layer) -> (carry, aux)`, `layer` the index in the whole stack.
-    Returns (x, cache_k, cache_v, aux per group)."""
-    carry, aux, start = (x, cache_k, cache_v), [], 0
+    lp, layer) -> (carry, aux)`, `layer` the layer's index in the pool it
+    writes (LayerGroup.pool_layer). Returns (x, cache_k, cache_v, aux per
+    group)."""
+    carry, aux, at = (x, cache_k, cache_v), [], 0
     for group in groups:
         stacked, whole = _group_params(params, group)
+        first, count = group.start, group.count
+        stacked = {n: w if (first, count) == (0, w.shape[0])
+                   else w[first:first + count] for n, w in stacked.items()}
+        own = jnp.arange(count, dtype=jnp.int32)
+        if whole:
+            stacked["layer"] = first + own if first else own
         body = layer_of(group)
 
         def layer(carry, layer_in, body=body, whole=whole):
             lp, idx = layer_in
             return body(carry, {**lp, **whole}, idx)
 
-        carry, group_aux = lax.scan(
-            layer, carry,
-            (stacked, start + jnp.arange(group.count, dtype=jnp.int32)))
+        pool_first = at if group.pool_layer is None else group.pool_layer
+        carry, group_aux = lax.scan(layer, carry, (stacked, pool_first + own))
         aux.append(group_aux)
-        start += group.count
+        at += count
     return (*carry, aux)
 
 
 def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
                   cache_k, cache_v, *, stacked_names=None,
                   mlp_fn=_default_mlp_fn, lora_idx=None, groups=None,
-                  attention=None):
+                  attention=None, slot_ids=None):
     """Shared prefill body for every model family.
 
     K/V scatter through `block_tables` into the page pool; `mlp_fn(lp, h,
@@ -544,9 +604,10 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
     so MoE routing can ignore padding). `lora_idx` ([B] int32, optional)
     selects each row's adapter pool slot (docs/lora.md). `groups` describes
     a stack of more than one kind of layer and `attention` another
-    attention than GQA (LayerGroup, Attention). Returns (logits, cache_k,
-    cache_v, aux) with `aux` one entry per group: what its feed-forward
-    reported, stacked over the group's layers, or None."""
+    attention than GQA (LayerGroup, Attention); `slot_ids` ([B]) are the
+    rows' slots, for a group whose mixer keeps a state per slot. Returns
+    (logits, cache_k, cache_v, aux) with `aux` one entry per group: what
+    its feed-forward reported, stacked over the group's layers, or None."""
     b, t = input_ids.shape
     ps = kv_pool_values(cache_k).shape[2]
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
@@ -558,20 +619,25 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
 
     x = params["embed"][input_ids]  # [B, T, E]
     attention = attention or GQA_ATTENTION
+    rows = StateRows(slot_ids, prompt_lens)
 
     def layer_of(group):
         def layer(carry, lp, layer_idx):
             carry_x, ck, cv = carry
-            carry_x, k, v = attention.block(
-                cfg, lp, carry_x, positions, inv_freq,
-                lambda q, k, v: attention.prefill(q, k, v, prompt_lens),
-                lora_idx,
-            )
-            ck = _write_pool(ck, layer_idx, page, off, k)
-            cv = _write_pool(cv, layer_idx, page, off, v)
-            h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
-            out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
-            return (carry_x + out, ck, cv), aux
+            if group.mixer is not None:
+                carry_x, ck, cv = group.mixer(lp, carry_x, ck, cv, layer_idx,
+                                              rows)
+            elif group.attends:
+                carry_x, k, v = attention.block(
+                    cfg, lp, carry_x, positions, inv_freq,
+                    lambda q, k, v: attention.prefill(q, k, v, prompt_lens),
+                    lora_idx,
+                )
+                ck = _write_pool(ck, layer_idx, page, off, k)
+                cv = _write_pool(cv, layer_idx, page, off, v)
+            carry_x, aux = _feed_forward(cfg, group, lp, carry_x,
+                                         token_valid, lora_idx)
+            return (carry_x, ck, cv), aux
 
         return layer
 
@@ -621,7 +687,7 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
                                block_tables, cache_k, cache_v, *,
                                stacked_names=None, mlp_fn=_default_mlp_fn,
                                all_logits=False, window=None, lora_idx=None,
-                               groups=None, attention=None):
+                               groups=None, attention=None, slot_ids=None):
     """Shared chunked-prefill body: process a [B, T] chunk of prompt tokens
     whose rows already hold `start_pos` tokens of KV. The chunk's KV scatters
     through the block table into the page pool, in place (_scan_groups), and
@@ -636,7 +702,8 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
     range (masked by every later attention) and are overwritten in place
     when the sequence grows into them. `window` (static) bounds the
     attention sweep to whole pages covering it, same contract as decode.
-    `groups`, `attention` and the fourth value returned: as _prefill_impl."""
+    `groups`, `attention`, `slot_ids` and the fourth value returned: as
+    _prefill_impl."""
     _, t = input_ids.shape
     ps = kv_pool_values(cache_k).shape[2]
     ppn = block_tables.shape[1]
@@ -658,6 +725,7 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
 
     x = params["embed"][input_ids]  # [B, T, E]
     attention = attention or GQA_ATTENTION
+    rows = StateRows(slot_ids, chunk_lens, start_pos)
 
     def layer_of(group):
         def layer(carry, lp, layer_idx):
@@ -668,14 +736,19 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
                 ck = _write_pool(ck, layer_idx, page, off, k)
                 cv = _write_pool(cv, layer_idx, page, off, v)
                 return attention.extend(
-                    q, ck, cv, layer_idx, read_tables, positions, chunk_lens
+                    q, _pages(ck), _pages(cv), layer_idx, read_tables,
+                    positions, chunk_lens
                 )
 
-            carry_x, _, _ = attention.block(
-                cfg, lp, carry_x, positions, inv_freq, attn_fn, lora_idx)
-            h = rms_norm(carry_x, lp["ln_mlp"], cfg.rms_eps)
-            out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
-            return (carry_x + out, ck, cv), aux
+            if group.mixer is not None:
+                carry_x, ck, cv = group.mixer(lp, carry_x, ck, cv, layer_idx,
+                                              rows)
+            elif group.attends:
+                carry_x, _, _ = attention.block(
+                    cfg, lp, carry_x, positions, inv_freq, attn_fn, lora_idx)
+            carry_x, aux = _feed_forward(cfg, group, lp, carry_x,
+                                         token_valid, lora_idx)
+            return (carry_x, ck, cv), aux
 
         return layer
 
@@ -748,7 +821,8 @@ def verify_step_paged(
 def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                        block_tables, *, stacked_names=None,
                        mlp_fn=_default_mlp_fn, window=None, lora_idx=None,
-                       live=None, groups=None, attention=None):
+                       live=None, groups=None, attention=None,
+                       slot_ids=None):
     """Shared one-token decode body for every model family.
 
     The layer loop is UNROLLED (static layer indices; decode programs are
@@ -776,7 +850,9 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     the work-list of live (row, page) pairs, is built here once a step for
     all the layers; under the engine's burst scan it follows `seq_lens`
     across page boundaries. `groups`, `attention` and the fourth value
-    returned: as _prefill_impl (a group's aux is a list over its layers)."""
+    returned: as _prefill_impl (a group's aux is a list over its layers);
+    `slot_ids` None says that row i is slot i, as the engine's burst has
+    it. A group's mixer advances the state of the `live` rows alone."""
     b = input_ids.shape[0]
     ps = kv_pool_values(cache_k).shape[2]
     capacity = block_tables.shape[1] * ps
@@ -790,34 +866,41 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     if live is not None:
         kv_lens = jnp.where(live, kv_lens, 0)
     attention = attention or GQA_ATTENTION
-    work = attention.decode_work(cache_k, block_tables, kv_lens, window)
+    work = attention.decode_work(_pages(cache_k), block_tables, kv_lens,
+                                 window)
+    rows = StateRows(slot_ids, live=live)
 
     x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
-    aux, layer_idx = [], 0
+    aux, at = [], 0
     for group in _groups_for(cfg, stacked_names, mlp_fn, groups):
         stacked, whole = _group_params(params, group)
+        pool_first = at if group.pool_layer is None else group.pool_layer
         group_aux = []
         for in_group in range(group.count):
-            lp = {n: w[in_group] for n, w in stacked.items()}
-            lp.update(whole, layer=in_group)  # a static index, unrolled
+            own = group.start + in_group  # static indices, unrolled
+            layer_idx = pool_first + in_group
+            lp = {n: w[own] for n, w in stacked.items()}
+            lp.update(whole, layer=own)
 
             def attn_fn(q, k, v, layer_idx=layer_idx):
                 nonlocal cache_k, cache_v  # write precedes attention
                 cache_k = _write_pool(cache_k, layer_idx, page, off, k[:, 0])
                 cache_v = _write_pool(cache_v, layer_idx, page, off, v[:, 0])
                 return attention.decode(
-                    q, cache_k, cache_v, layer_idx, block_tables, kv_lens,
-                    window=window, work=work,
+                    q, _pages(cache_k), _pages(cache_v), layer_idx,
+                    block_tables, kv_lens, window=window, work=work,
                 )
 
-            x, _, _ = attention.block(cfg, lp, x, positions, inv_freq,
-                                      attn_fn, lora_idx)
-            h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
-            out, layer_aux = _mlp_out(group.mlp_fn(lp, h, None, lora_idx))
-            x = x + out
+            if group.mixer is not None:
+                x, cache_k, cache_v = group.mixer(lp, x, cache_k, cache_v,
+                                                  layer_idx, rows)
+            elif group.attends:
+                x, _, _ = attention.block(cfg, lp, x, positions, inv_freq,
+                                          attn_fn, lora_idx)
+            x, layer_aux = _feed_forward(cfg, group, lp, x, None, lora_idx)
             group_aux.append(layer_aux)
-            layer_idx += 1
         aux.append(group_aux)
+        at += group.count
 
     logits = _unembed(cfg, params, x[:, 0])
     return logits, cache_k, cache_v, aux

@@ -26,8 +26,13 @@ One layer at every size (prefill, extend, verify, decode):
   and come out as zeros.
 - Expert parallelism rides GSPMD as before: the expert-major weights carry
   the mesh `ep` axis and XLA places the collectives (it may gather the
-  weights of a grouped product; an expert layer that is TOLD which experts
-  a chip holds is ROADMAP work).
+  weights of a grouped product). A layer that is TOLD which experts its
+  chip holds (`held=(first, count)`) routes over all of them and computes
+  the assignments of its own: the others are another chip's, sorted behind
+  every group as padding is, and add nothing here. The exchange between
+  the chips of such a deployment is ROADMAP work.
+- An expert is three matrices (SwiGLU: `w_gate`, `w_up`, `w_down`) or two
+  (`w_gate` None: `act(x W_up) W_down`), through the same products.
 
 The reference has no MoE anywhere (it is a gateway; SURVEY.md §2.4 "no EP").
 """
@@ -50,6 +55,9 @@ class Routing(NamedTuple):
     chosen: jnp.ndarray  # [S, k] int32 — the experts of each token
     scores: jnp.ndarray  # [S, E] f32 — the quantity whose top-k decided
     load: jnp.ndarray  # [E] int32 — assignments per expert, padding left out
+    # [] int32 — assignments of valid tokens to experts this chip does not
+    # hold (`held`); None where the layer holds them all
+    elsewhere: jnp.ndarray | None = None
 
 
 def top_k_routing(
@@ -104,7 +112,7 @@ def _grouped_mm(rows: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
 def moe_routed(
     x: jnp.ndarray,  # [S, M] tokens (S = B*T)
     router_logits: jnp.ndarray,  # [S, E]
-    w_gate: jnp.ndarray,  # [E, M, F] per-expert gate proj (silu branch)
+    w_gate: jnp.ndarray | None,  # [E, M, F] gate proj (silu branch), or None
     w_up: jnp.ndarray,  # [E, M, F]
     w_down: jnp.ndarray,  # [E, F, M]
     *,
@@ -114,10 +122,22 @@ def moe_routed(
     w_gate_scale: jnp.ndarray | None = None,  # [E, F] int8 dequant scales
     w_up_scale: jnp.ndarray | None = None,  # [E, F]
     w_down_scale: jnp.ndarray | None = None,  # [E, M]
+    held: tuple[int, int] | None = None,  # (first, count) of the w_*'s experts
+    act: Callable = jax.nn.silu,
+    up_transposed: bool = False,  # w_gate and w_up are [E, F, M]
 ) -> tuple[jnp.ndarray, Routing]:
-    """SwiGLU experts mixed by `route`, every assignment computed. Returns
-    ([S, M], Routing). Padding tokens come out as zeros and count in no
-    expert's load.
+    """SwiGLU experts (`w_gate` None: two-matrix experts, `act(x W_up)
+    W_down`) mixed by `route`, every assignment computed. Returns ([S, M],
+    Routing). Padding tokens come out as zeros and count in no expert's
+    load.
+
+    `held` says that the weights are experts [first, first + count) of the
+    router's E: the route is taken over all E and the weights are those of
+    all k chosen, an assignment of an expert outside the range adds nothing
+    (it is the chip's that holds it) and `Routing.load` counts the held.
+    `up_transposed`: `w_up` (and `w_gate`) arrive output-major, [E, F, M], as
+    a checkpoint stores a Linear (pallas_moe.grouped_expert_matmul says when
+    that is the layout to store).
 
     With `layer`, the expert weights (and scales) arrive STACKED over the
     layers, [L, E, ...]. On an unpartitioned TPU the products then run in
@@ -130,7 +150,7 @@ def moe_routed(
     sliced for `jax.lax.ragged_dot`."""
     s, m = x.shape
     stacked = layer is not None
-    e = w_gate.shape[1] if stacked else w_gate.shape[0]
+    e = w_up.shape[1] if stacked else w_up.shape[0]
     logits = router_logits.astype(jnp.float32)
     weights, chosen, *rest = route(logits)
     scores = rest[0] if rest else logits
@@ -139,6 +159,14 @@ def moe_routed(
     # assignment j of token t is flat row t*k + j; padding sorts behind
     # every expert (key E) and lies outside every group
     flat_e = chosen.reshape(s * k).astype(jnp.int32)
+    elsewhere = None
+    if held is not None:  # an absent expert's assignment sorts as padding
+        flat_e = flat_e - held[0]
+        absent = (flat_e < 0) | (flat_e >= e)
+        flat_e = jnp.where(absent, e, flat_e)
+        counted = absent if token_valid is None else (
+            absent & jnp.repeat(token_valid, k))
+        elsewhere = jnp.sum(counted, dtype=jnp.int32)
     if token_valid is not None:
         flat_e = jnp.where(jnp.repeat(token_valid, k), flat_e, e)
     order = jnp.argsort(flat_e, stable=True)
@@ -147,7 +175,7 @@ def moe_routed(
                    axis=0, dtype=jnp.int32)  # [E]
     rows = x[order // k]  # [S*k, M], sorted by expert
 
-    quantized = w_gate_scale is not None
+    quantized = w_up_scale is not None
     if stacked and not quantized and _pallas_enabled():
         from llmlb_tpu.ops import pallas_moe
 
@@ -157,12 +185,15 @@ def moe_routed(
             rows = jnp.pad(rows, ((0, pad), (0, 0)))
         work = pallas_moe.group_work_list(load, rows=s * k + pad, tile=tile)
 
-        def product(a, w, out_dtype):
+        def product(a, w, out_dtype, transposed=False):
             return pallas_moe.grouped_expert_matmul(
-                a, w, layer, work, tile=tile, out_dtype=out_dtype)
+                a, w, layer, work, tile=tile, out_dtype=out_dtype,
+                transposed=transposed)
 
-        h = (jax.nn.silu(product(rows, w_gate, x.dtype))
-             * product(rows, w_up, x.dtype))
+        gate = None if w_gate is None else act(
+            product(rows, w_gate, x.dtype, up_transposed))
+        h = product(rows, w_up, x.dtype, up_transposed)
+        h = act(h) if gate is None else gate * h
         y = product(h, w_down, jnp.float32)[:s * k]
     else:
         if stacked:
@@ -170,18 +201,24 @@ def moe_routed(
                 None if w is None else w[layer]
                 for w in (w_gate, w_up, w_down, w_gate_scale, w_up_scale,
                           w_down_scale))
-        h = jax.nn.silu(
+        if up_transposed:
+            w_gate, w_up = (None if w is None else jnp.swapaxes(w, -1, -2)
+                            for w in (w_gate, w_up))
+        gate = None if w_gate is None else act(
             _grouped_mm(rows, w_gate, load, w_gate_scale, row_expert)
-            .astype(x.dtype)
-        ) * _grouped_mm(rows, w_up, load, w_up_scale,
+            .astype(x.dtype))
+        h = _grouped_mm(rows, w_up, load, w_up_scale,
                         row_expert).astype(x.dtype)
+        h = act(h) if gate is None else gate * h
         y = _grouped_mm(h, w_down, load, w_down_scale, row_expert)  # [S*k, M]
 
     # back to (token, choice) order: a gather by the inverse permutation
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(s * k, dtype=order.dtype))
     y = y[inverse].reshape(s, k, m)
+    if held is not None:  # rows behind every group are unspecified
+        y = jnp.where(absent.reshape(s, k, 1), 0.0, y)
     out = jnp.sum(y * weights[..., None], axis=1)
     if token_valid is not None:
         out = jnp.where(token_valid[:, None], out, 0.0)
-    return out.astype(x.dtype), Routing(chosen, scores, load)
+    return out.astype(x.dtype), Routing(chosen, scores, load, elsewhere)

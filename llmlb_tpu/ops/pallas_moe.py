@@ -106,11 +106,12 @@ def _column_tile(k: int, n: int, itemsize: int, budget: int = 4 << 20) -> int:
 
 
 def _gmm_kernel(layer_ref, count_ref, expert_ref, tile_ref, lo_ref, hi_ref,
-                first_ref, x_ref, w_ref, o_ref, *, tile: int):
+                first_ref, x_ref, w_ref, o_ref, *, tile: int,
+                transposed: bool):
     del layer_ref, expert_ref
     item = pl.program_id(0) % count_ref[0]
     acc = jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        x_ref[...], w_ref[...], (((1,), (1 if transposed else 0,)), ((), ())),
         preferred_element_type=jnp.float32)  # [TM, TN]
     row = tile_ref[item] * tile + jax.lax.broadcasted_iota(
         jnp.int32, (tile, 1), dimension=0)
@@ -125,7 +126,8 @@ def _gmm_kernel(layer_ref, count_ref, expert_ref, tile_ref, lo_ref, hi_ref,
         o_ref[...] = jnp.where(mine, acc.astype(o_ref.dtype), o_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "out_dtype", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile", "out_dtype", "interpret",
+                                             "transposed"))
 def grouped_expert_matmul(
     rows: jnp.ndarray,  # [N, K] sorted by expert, N a multiple of `tile`
     w: jnp.ndarray,  # [L, X, K, O] — every layer's experts
@@ -135,13 +137,21 @@ def grouped_expert_matmul(
     tile: int,
     out_dtype=jnp.float32,
     interpret: bool | None = None,
+    transposed: bool = False,
 ) -> jnp.ndarray:
     """rows[i] @ w[layer, expert of row i] -> [N, O], row by row through its
-    own expert's matrix; rows beyond the experts' (padding) unspecified."""
+    own expert's matrix; rows beyond the experts' (padding) unspecified.
+
+    `transposed`: w is [L, X, O, K], as a checkpoint stores a Linear, and
+    the product contracts the last axis of both. For an O that is no
+    multiple of 128 lanes (1,856) it is the layout to store: the chip lays
+    a [K, O] matrix of such a width out with K minor, and handing that to a
+    kernel copied the whole stack, 3.8 GB, on every call (PERF.md section
+    6, PR 38)."""
     if interpret is None:
         interpret = _interpret_default()
     n, k = rows.shape
-    o = w.shape[-1]
+    o = w.shape[-2] if transposed else w.shape[-1]
     cols = _column_tile(k, o, w.dtype.itemsize)
     col_tiles = o // cols
 
@@ -151,7 +161,9 @@ def grouped_expert_matmul(
         return (tile_of[i % count[0]], 0)
 
     def w_map(i, layer, count, expert_of, tile_of, lo, hi, first):
-        return (layer[0], expert_of[i % count[0]], 0, i // count[0])
+        at = (0, i // count[0])
+        return (layer[0], expert_of[i % count[0]], *(at[::-1] if transposed
+                                                     else at))
 
     def o_map(i, layer, count, expert_of, tile_of, lo, hi, first):
         return (tile_of[i % count[0]], i // count[0])
@@ -161,13 +173,14 @@ def grouped_expert_matmul(
         grid=(work.count * col_tiles,),
         in_specs=[
             pl.BlockSpec((tile, k), x_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, None, k, cols), w_map,
+            pl.BlockSpec((None, None, cols, k) if transposed
+                         else (None, None, k, cols), w_map,
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((tile, cols), o_map, memory_space=pltpu.VMEM),
     )
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tile=tile),
+        functools.partial(_gmm_kernel, tile=tile, transposed=transposed),
         out_shape=jax.ShapeDtypeStruct((n, o), out_dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=48 << 20),

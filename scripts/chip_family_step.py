@@ -69,7 +69,10 @@ def main() -> int:
     ps, rows, k = args.page_size, args.rows, args.burst
     total = args.context + k * (args.bursts + 1)
     ppn = -(-total // ps)
-    cache_k, cache_v = family.init_kv_pages(cfg, rows * ppn + 1, ps)
+    # a family with state per slot (models/nemotron_h.py): row i is slot i
+    slotted = hasattr(family, "state_slot_bytes")
+    cache_k, cache_v = family.init_kv_pages(
+        cfg, rows * ppn + 1, ps, **({"num_slots": rows} if slotted else {}))
     tables = jnp.arange(1, rows * ppn + 1, dtype=jnp.int32).reshape(rows, ppn)
     rng = np.random.default_rng(args.seed)
     chunk = min(args.context, 512)
@@ -78,14 +81,17 @@ def main() -> int:
     for lo in range(0, rows, 8):  # prefill in groups of eight, chunk by chunk
         sl = slice(lo, lo + 8)
         n = ids[sl].shape[0]
+        slots = ({"slot_ids": jnp.arange(lo, lo + n, dtype=jnp.int32)}
+                 if slotted else {})
         _, cache_k, cache_v, *_ = family.prefill_into_pages(
             params, cfg, ids[sl, :chunk], jnp.full((n,), chunk, jnp.int32),
-            tables[sl], cache_k, cache_v)
+            tables[sl], cache_k, cache_v, **slots)
         for at in range(chunk, args.context, chunk):
             t = min(chunk, args.context - at)
             _, cache_k, cache_v, *_ = family.prefill_extend_pages(
                 params, cfg, ids[sl, at:at + t], jnp.full((n,), t, jnp.int32),
-                jnp.full((n,), at, jnp.int32), tables[sl], cache_k, cache_v)
+                jnp.full((n,), at, jnp.int32), tables[sl], cache_k, cache_v,
+                **slots)
     window = ppn * ps
 
     def burst(params, last, lens, cache_k, cache_v, tables):

@@ -21,11 +21,22 @@ FAMILIES = {
     "mixtral": family_for(get_preset("debug-moe-tiny")),
     "deepseek_v3": family_for(get_preset("debug-mla-tiny")),
     "sdar_moe": family_for(get_preset("debug-sdar-tiny")),
+    "nemotron_h": family_for(get_preset("debug-nemotron-h-tiny")),
 }
+PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
+           "deepseek_v3": "debug-mla-tiny", "sdar_moe": "debug-sdar-tiny",
+           "nemotron_h": "debug-nemotron-h-tiny"}
 # Static switches a family may add BEHIND llama's parameters, keyword-only
 # in effect: the benchmark's check passes `routing` (models/deepseek_v3.py),
-# never positionally.
-EXTRA = {"deepseek_v3": ["routing"], "sdar_moe": ["routing"]}
+# never positionally. `slot_ids`: the rows' slots, for a family that keeps
+# a state per slot beside the page pool (models/nemotron_h.py); the default
+# is row i in slot i, which serves the benchmark's check and its one row.
+EXTRA = {"deepseek_v3": ["routing"], "sdar_moe": ["routing"],
+         "nemotron_h": ["routing", "slot_ids"]}
+# What a family may add behind llama's parameters elsewhere: the slot count
+# of a pool with a state per slot (default 1); the scheduler knows such a
+# family by its `state_slot_bytes` (EngineCore._slot_state).
+EXTRA_OF = {("nemotron_h", "init_kv_pages"): ["num_slots"]}
 
 CONTRACT = (
     "init_params",
@@ -41,6 +52,13 @@ CONTRACT = (
 # Found by `hasattr` and served without when absent: ring-attention prefill
 # runs llama's dense feed-forward, so a mixture of experts must not export it.
 OPTIONAL = {"make_context_parallel_prefill"}
+# Absent BY DESIGN, family by family, and asked of every other: a family
+# with a recurrent state cannot verify a draft (a rejected token would leave
+# the state advanced, and there is no snapshot to roll back to), so it
+# exports no `verify_step_paged` and the scheduler's `hasattr`
+# (`_spec_available`) serves it without speculation; an engine asked for
+# speculation with it does not start (`_check_slot_state_engine`).
+ABSENT_BY_DESIGN = {"nemotron_h": {"verify_step_paged"}}
 
 
 def _params(fn) -> list[tuple[str, inspect._ParameterKind]]:
@@ -61,13 +79,17 @@ def test_every_family_module_is_covered():
 def test_family_provides_the_paged_contract(family, name):
     module = FAMILIES[family]
     if not hasattr(module, name):
-        assert name in OPTIONAL, f"{family} lacks {name}"
+        assert name in OPTIONAL | ABSENT_BY_DESIGN.get(family, set()), (
+            f"{family} lacks {name}")
         return
+    assert name not in ABSENT_BY_DESIGN.get(family, set()), (
+        f"{family} exports {name}, which it cannot serve")
     want = _params(getattr(llama, name))
     got = _params(getattr(module, name))
     paged = name in ("prefill_into_pages", "prefill_extend_pages",
                      "verify_step_paged", "decode_step_paged")
-    extra = EXTRA.get(family, []) if paged else []
+    extra = (EXTRA.get(family, []) if paged
+             else EXTRA_OF.get((family, name), []))
     assert got[:len(want)] == want and [n for n, _ in got[len(want):]] == extra, (
         f"{family}.{name} takes other parameters than llama.{name}"
     )
@@ -77,12 +99,23 @@ def test_family_provides_the_paged_contract(family, name):
 def test_family_says_what_a_token_leaves_in_the_pool(family):
     """The scheduler's page bytes, gauges and KVSH header ask the family."""
     module = FAMILIES[family]
-    cfg = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
-           "deepseek_v3": "debug-mla-tiny",
-           "sdar_moe": "debug-sdar-tiny"}[family]
-    cfg = get_preset(cfg)
-    ck, cv = module.init_kv_pages(cfg, 3, 8)
+    cfg = get_preset(PRESETS[family])
+    ck, cv = map(llama._pages, module.init_kv_pages(cfg, 3, 8))
     per_token = (ck[0, 0, 0].size + cv[0, 0, 0].size) * ck.dtype.itemsize
     assert module.kv_token_layer_bytes(cfg) == per_token
     cell = module.kv_wire_cell(cfg)
     assert cell is None or cell == ck.shape[-2:] == cv.shape[-2:]
+
+
+def test_an_added_parameter_has_a_default_that_serves_one_row():
+    """What a family adds behind llama's parameters is optional: the
+    benchmark's check (benchmark/correctness.py) calls every family with
+    llama's arguments alone."""
+    for family, names in EXTRA.items():
+        for fn in ("prefill_into_pages", "prefill_extend_pages",
+                   "decode_step_paged"):
+            params = inspect.signature(getattr(FAMILIES[family], fn)).parameters
+            assert all(params[n].default in (False, None) for n in names)
+    for (family, fn), names in EXTRA_OF.items():
+        params = inspect.signature(getattr(FAMILIES[family], fn)).parameters
+        assert [params[n].default for n in names] == [1]

@@ -1,0 +1,140 @@
+"""The state-space ops (ops/ssm.py) against the plain per-token recurrence:
+`ssd_chunked` at lengths that are not a multiple of the chunk, with and
+without an initial state, rows of unlike lengths in one call; `ssm_step`
+(the Pallas kernel in interpret mode and the `jax.numpy` route) continuing
+a scan; rows that are not live keeping their state; the convolution's
+carried rows."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llmlb_tpu.ops import ssm
+
+H, P, G, N = 8, 8, 2, 16
+
+
+def _inputs(seed, b, t):
+    r = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)  # noqa: E731
+    return dict(
+        x=f(b, t, H, P), dt=jax.nn.softplus(f(b, t, H) - 2.0),
+        a=-jnp.exp(jnp.asarray(r.uniform(0, 2.7, H), jnp.float32)),
+        b=f(b, t, G, N), c=f(b, t, G, N), d=f(H), s0=f(b, H, P, N))
+
+
+def _recurrence(x, dt, a, b, c, d, s0, lens):
+    """Token by token, row by row, in numpy float64."""
+    x, dt, a, b, c, d, s = (np.asarray(v, np.float64)
+                            for v in (x, dt, a, b, c, d, s0))
+    ys = np.zeros(x.shape)
+    for row in range(x.shape[0]):
+        for t in range(int(lens[row])):
+            bh = np.repeat(b[row, t], H // G, axis=0)
+            ch = np.repeat(c[row, t], H // G, axis=0)
+            s[row] = (np.exp(dt[row, t] * a)[:, None, None] * s[row]
+                      + (dt[row, t][:, None] * x[row, t])[:, :, None]
+                      * bh[:, None, :])
+            ys[row, t] = (s[row] * ch[:, None, :]).sum(-1) + d[:, None] * x[row, t]
+    return ys, s
+
+
+@pytest.mark.parametrize("t,lens,initial", [
+    (16, [16, 16], False),   # one whole chunk
+    (37, [37, 21], True),    # ends inside the third chunk; a shorter row
+    (37, [5, 37], False),    # shorter than one chunk
+    (48, [48, 33], True),    # whole chunks, a row that ends inside one
+])
+def test_chunked_scan_equals_the_per_token_recurrence(t, lens, initial):
+    v = _inputs(t, 2, t)
+    if not initial:
+        v["s0"] = jnp.zeros_like(v["s0"])
+    lens = jnp.asarray(lens, jnp.int32)
+    y, s = ssm.ssd_chunked(**v, lens=lens, chunk=16)
+    want_y, want_s = _recurrence(**v, lens=lens)
+    for row, n in enumerate(np.asarray(lens)):
+        np.testing.assert_allclose(np.asarray(y)[row, :n], want_y[row, :n],
+                                   rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(s), want_s, rtol=2e-4, atol=2e-4)
+
+
+def _step_case(seed=5, slots=4, layers=3):
+    v = _inputs(seed, slots, 1)
+    pool = jnp.asarray(np.random.default_rng(seed).normal(
+        size=(layers, slots, H, P, N)), jnp.float32)
+    args = (v["x"][:, 0], v["dt"][:, 0], v["a"], v["b"][:, 0], v["c"][:, 0],
+            v["d"])
+    return v, pool, args
+
+
+def test_the_step_continues_the_scan_and_touches_its_layer_alone():
+    v, pool, args = _step_case()
+    y, new = ssm.ssm_step(*args, pool + 0, 1)
+    want_y, want_s = _recurrence(**{**v, "s0": pool[1]}, lens=[1] * 4)
+    np.testing.assert_allclose(np.asarray(y), want_y[:, 0], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[1]), want_s, rtol=1e-5,
+                               atol=1e-5)
+    assert (np.asarray(new[0]) == np.asarray(pool[0])).all()
+    assert (np.asarray(new[2]) == np.asarray(pool[2])).all()
+
+
+def test_the_kernel_equals_the_plain_route_and_a_row_not_live_stays():
+    _v, pool, args = _step_case(seed=6)
+    live = jnp.asarray([True, False, True, False])
+    y, new = ssm.ssm_step(*args, pool + 0, 2, live=live)
+    x, dt, a, b, c, d = args
+    decay, dtx = ssm._step_inputs(x, dt, a, live)
+    k_pool, k_sc = ssm.ssm_decode_step(pool + 0, 2, decay, dtx, b, c,
+                                       interpret=True)
+    k_y = k_sc + x * d[:, None]
+    np.testing.assert_allclose(np.asarray(k_pool), np.asarray(new),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(k_y), np.asarray(y), rtol=1e-5,
+                               atol=1e-5)
+    for got in (new, k_pool):  # bit for bit
+        assert (np.asarray(got[2, 1]) == np.asarray(pool[2, 1])).all()
+        assert (np.asarray(got[2, 3]) == np.asarray(pool[2, 3])).all()
+        assert (np.asarray(got[2, 0]) != np.asarray(pool[2, 0])).any()
+
+
+def test_rows_at_named_slots_leave_the_other_slots_alone():
+    _v, pool, args = _step_case(seed=7)
+    two = tuple(v[:2] if v.ndim and v.shape[0] == 4 else v for v in args)
+    slots = jnp.asarray([3, 1])
+    _y, new = ssm.ssm_step(*two, pool + 0, 0, slots=slots)
+    assert (np.asarray(new[0, 0]) == np.asarray(pool[0, 0])).all()
+    assert (np.asarray(new[0, 2]) == np.asarray(pool[0, 2])).all()
+    _y, whole = ssm.ssm_step(*args, pool + 0, 0)
+    # row 0 of the two went to slot 3 with row 0's inputs
+    x, dt, a, b, c, d = two
+    want = _recurrence(x[:, None], dt[:, None], a, b[:, None], c[:, None], d,
+                       pool[0, slots], [1, 1])[1]
+    np.testing.assert_allclose(np.asarray(new[0, slots]), want, rtol=1e-5,
+                               atol=1e-5)
+    with pytest.raises(ValueError, match="say which slots"):
+        ssm.ssm_step(*two, pool, 0)
+    del whole
+
+
+def test_the_convolution_carries_the_rows_that_end_at_the_length():
+    r = np.random.default_rng(3)
+    x = jnp.asarray(r.normal(size=(2, 9, 6)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(6, 4)), jnp.float32)
+    b = jnp.asarray(r.normal(size=(6,)), jnp.float32)
+    lens = jnp.asarray([9, 5], jnp.int32)
+    out, carry = ssm.causal_conv(x, jnp.zeros((2, 3, 6)), w, b, lens)
+    padded = np.concatenate([np.zeros((2, 3, 6)), np.asarray(x)], axis=1)
+    want = sum(padded[:, j:j + 9] * np.asarray(w)[:, j] for j in range(4)) + np.asarray(b)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax.nn.silu(want)),
+                               rtol=1e-5, atol=1e-5)
+    assert (np.asarray(carry[0]) == np.asarray(x[0, 6:9])).all()
+    assert (np.asarray(carry[1]) == np.asarray(x[1, 2:5])).all()
+    # the next chunk, with the rows carried, equals the convolution unbroken
+    more = jnp.asarray(r.normal(size=(2, 4, 6)), jnp.float32)
+    nxt, _ = ssm.causal_conv(more[:1], carry[:1], w, b, jnp.asarray([4]))
+    whole, _ = ssm.causal_conv(jnp.concatenate([x[:1], more[:1]], axis=1),
+                               jnp.zeros((1, 3, 6)), w, b, jnp.asarray([13]))
+    np.testing.assert_allclose(np.asarray(nxt), np.asarray(whole[:, 9:]),
+                               rtol=1e-5, atol=1e-5)

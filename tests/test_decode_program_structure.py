@@ -532,6 +532,83 @@ def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * logits
 
 
+# --- a hybrid's decode burst: a state per slot beside the pages ---------------
+
+def test_compiled_hybrid_burst_updates_the_state_in_place_and_copies_no_experts(
+        one_chip, monkeypatch):
+    """Two decode steps of a hybrid of state-space, attention and expert
+    layers (nemotron-3-nano-30b-a3b's widths, two layers of each kind, 64 of
+    128 experts held, the benchmark cell's 544 pages and 32 slots) under a
+    scan, compiled for a v5e: per step one state kernel a state-space layer,
+    one attention kernel an attention layer and two grouped products an
+    expert layer; the compiler materializes no layer of the recurrent state
+    (67 MB at 32 slots), of the page pool or of the experts, and copies none
+    of the three whole. (With the up-projection stored `[K, 1856]` the chip
+    laid it out K-minor and copied the whole stack, 3.8 GB, into the kernel's
+    layout on every call: PERF.md section 6, PR 38.)"""
+    from llmlb_tpu.models import nemotron_h
+    from llmlb_tpu.ops import pallas_moe, ssm
+
+    cfg = nemotron_h.NemotronHConfig(
+        vocab_size=131072, hidden_size=2688, intermediate_size=1856,
+        num_layers=6, num_heads=32, num_kv_heads=2, head_dim=128,
+        rms_eps=1e-5, max_position_embeddings=4096, pattern="M*EM*E",
+        num_experts=64, router_experts=128, tie_word_embeddings=False)
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    for module in (pallas_attention, pallas_moe, ssm):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+    jitted = (nemotron_h.decode_step_paged, pallas_attention.paged_flash_decode,
+              pallas_moe.grouped_expert_matmul, ssm.ssm_decode_step)
+    for fn in jitted:
+        fn._clear_cache()
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda key: nemotron_h.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache_k, cache_v = on_chip(jax.eval_shape(
+        lambda: nemotron_h.init_kv_pages(cfg, 544, CHIP_PAGE_SIZE,
+                                         num_slots=CHIP_ROWS)))
+    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
+    live = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.bool_))
+    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
+
+    def burst(params, last, lens, cache_k, cache_v, tables, live):
+        def body(carry, _):
+            last, lens, ck, cv = carry
+            logits, ck, cv, counters = nemotron_h.decode_step_paged(
+                params, cfg, last, lens, ck, cv, tables, window=2048,
+                live=live)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    ck, cv), counters
+
+        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
+                            length=2)
+
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(burst, donate_argnums=(3, 4)).lower(
+                params, rows, rows, cache_k, cache_v, tables, live).compile()
+    finally:
+        for fn in jitted:
+            fn._clear_cache()
+    hlo = compiled.as_text()
+    # two layers of each kind: a state kernel, an attention kernel, two products
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * (1 + 1 + 2)
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    a_layer = (r"f32\[32,64,64,128\]", r"bf16\[544,128,2,128\]",
+               r"bf16\[64,(1856,2688|2688,1856)\]")
+    whole = (r"f32\[2,32,64,64,128\]", r"bf16\[2,544,128,2,128\]",
+             r"bf16\[2,64,(1856,2688|2688,1856)\]")
+    moved = ("copy", "copy-start", "copy-done", "transpose")
+    bad = [(shape, op) for shape, op in results
+           if any(re.match(p, shape) for p in a_layer)
+           or (op in moved and any(re.match(p, shape) for p in whole))]
+    assert not bad, bad
+    # the temporaries are the step's logits and activations, not the state
+    state = 2 * CHIP_ROWS * 64 * 64 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < state / 2
+
+
 def test_compiled_sampler_sorts_no_vocabulary(one_chip):
     """`ops/sampling.sample_tokens` at the block pass's `f32[128, 151936]`,
     compiled for a v5e: no `TopK` custom call and no `sort` sees a row of
