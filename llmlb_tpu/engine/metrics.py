@@ -49,6 +49,15 @@ PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 BLOCK_COUNTS = ("block_passes", "row_passes", "blocks_committed",
                 "tokens_committed", "positions_unmasked")
 
+# Why a dense decode burst did not leave before its predecessor was emitted
+# (scheduler._ahead_blocker; docs/scheduling.md), a closed set: a request
+# waits for admission; a slot is prefilling; a park / drain / flush / stop
+# request, a coordinator or split mode; a row's next mask comes from a host
+# FSM; a row has a drafter; the free list could not cover the pages; the
+# burst had no predecessor to leave ahead of.
+AHEAD_BLOCKERS = ("admission", "prefilling", "control", "constraint",
+                  "draft", "pages", "first")
+
 # The engine's threads by class, for CPU seconds by class (hoststats.py):
 # the step loops (one, or split mode's two), the service layer's bridge
 # threads (one blocked in `events.get` for every stream in flight) and the
@@ -181,6 +190,13 @@ class EngineMetrics:
         # fused mode off).
         self.fused_decode_steps_total = 0
         self.decode_dispatches_total = 0
+        # Dense decode bursts (scheduler._decode_bursts): how many were
+        # dispatched, how many of them left BEFORE their predecessor was
+        # emitted, and for the others what stood in the way
+        # (AHEAD_BLOCKERS). The two add up to the first.
+        self.decode_bursts_total = 0
+        self.decode_bursts_dispatched_ahead_total = 0
+        self.decode_bursts_not_ahead_total = dict.fromkeys(AHEAD_BLOCKERS, 0)
         # Σ over decode steps of the pages their live rows hold, and of the
         # pages of slots x window: live / window is the share of a
         # window-wide sweep that was live context
@@ -389,6 +405,16 @@ class EngineMetrics:
             self.decode_dispatches_total += max(0, int(n))
             if fused:
                 self.fused_decode_steps_total += 1
+
+    def record_decode_burst(self, blocked_by: str | None) -> None:
+        """One dense decode burst: dispatched ahead of its predecessor's
+        emit (`blocked_by` None), or not, and why not."""
+        with self._lock:
+            self.decode_bursts_total += 1
+            if blocked_by is None:
+                self.decode_bursts_dispatched_ahead_total += 1
+            else:
+                self.decode_bursts_not_ahead_total[blocked_by] += 1
 
     def record_decode_kv_pages(self, kv_pages_live: int,
                                kv_pages_window: int) -> None:
@@ -600,6 +626,11 @@ class EngineMetrics:
                 ),
                 "fused_decode_steps_total": self.fused_decode_steps_total,
                 "decode_dispatches_total": self.decode_dispatches_total,
+                "decode_bursts_total": self.decode_bursts_total,
+                "decode_bursts_dispatched_ahead_total":
+                    self.decode_bursts_dispatched_ahead_total,
+                "decode_bursts_not_ahead_total":
+                    dict(self.decode_bursts_not_ahead_total),
                 "decode_kv_pages_live_total": self.decode_kv_pages_live_total,
                 "decode_kv_pages_window_total":
                     self.decode_kv_pages_window_total,
@@ -731,6 +762,16 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_decode_dispatches_total counter",
                 "llmlb_engine_decode_dispatches_total "
                 f"{self.decode_dispatches_total}",
+                "# TYPE llmlb_engine_decode_bursts_total counter",
+                f"llmlb_engine_decode_bursts_total {self.decode_bursts_total}",
+                "# TYPE llmlb_engine_decode_bursts_dispatched_ahead_total "
+                "counter",
+                "llmlb_engine_decode_bursts_dispatched_ahead_total "
+                f"{self.decode_bursts_dispatched_ahead_total}",
+                "# TYPE llmlb_engine_decode_bursts_not_ahead_total counter",
+                *(f'llmlb_engine_decode_bursts_not_ahead_total'
+                  f'{{reason="{reason}"}} {n}' for reason, n
+                  in self.decode_bursts_not_ahead_total.items()),
                 "# TYPE llmlb_engine_decode_kv_pages_live_total counter",
                 "llmlb_engine_decode_kv_pages_live_total "
                 f"{self.decode_kv_pages_live_total}",

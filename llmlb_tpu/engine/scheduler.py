@@ -472,6 +472,30 @@ class _Slot:
     token_limit: int = 0
 
 
+@dataclasses.dataclass
+class _Burst:
+    """A dense decode burst from its dispatch to its emit
+    (EngineCore._decode_bursts)."""
+
+    step: StepSpan
+    # (slot, request, whether row 0 of its column is the request's first
+    # token) as they stood at the DISPATCH: the burst's tokens are theirs,
+    # whoever holds the slot when they are delivered
+    rows: list[tuple[int, Request, bool]]
+    kv_pages: dict[str, int]
+    # why it did not leave before its predecessor was emitted (one of
+    # metrics.AHEAD_BLOCKERS); None: it did
+    blocked_by: str | None
+    # what the one fetch brought: [k + 1, SLOTS] tokens, and the family's
+    # step counters behind them (_pack_step_counters)
+    fetched: np.ndarray | None = None
+    step_s: float = 0.0  # the cycle's wall time a token
+
+    @property
+    def slots(self) -> list[int]:
+        return [i for i, _, _ in self.rows]
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineStats:
     num_slots: int
@@ -804,6 +828,12 @@ class EngineCore:
         # iteration — the migration analogue of request_drain_park, scoped
         # to single streams instead of the whole engine.
         self._park_rids: set[str] = set()
+        # What kept the NEXT dense decode burst from leaving before its
+        # predecessor was emitted (_ahead_blocker), for that burst's record.
+        self._ahead_blocked_by = "first"
+        # The dense decode burst the device holds and the host has not
+        # fetched, at most ONE: set at its dispatch, cleared at its fetch.
+        self._in_flight: _Burst | None = None
         # Cancellations take effect ONLY via the plan in multihost mode: the
         # live .cancelled flag flips at arbitrary times on the leader (HTTP
         # thread), and acting on it directly would make hosts dispatch
@@ -1655,32 +1685,7 @@ class EngineCore:
         while self._running:
             did_work = False
             try:
-                if self.coordinator is not None:
-                    clock.switch("control")
-                    self._lockstep_tick()
-                    if not self._running:
-                        break
-                if self._drain_park_requested:
-                    clock.switch("control")
-                    self._drain_park_requested = False
-                    self._drain_park_all()
-                if self._park_rids:
-                    clock.switch("control")
-                    rids = self._park_rids
-                    self._park_rids = set()
-                    self._park_requested(rids)
-                if self._drain_flush_requested:
-                    clock.switch("control")
-                    self._drain_flush_requested = False
-                    self._drain_flush_all()
-                clock.switch("admit")
-                did_work |= self._try_insert()
-                clock.switch("other")
-                # At most ONE prefill chunk per iteration: decode steps run
-                # between chunks, so active slots keep emitting tokens during
-                # a long prompt's prefill (prefill/decode interleaving).
-                did_work |= self._advance_prefill()
-                did_work |= self._decode_active()
+                did_work = self._loop_once(clock)
             except Exception:  # pragma: no cover - defensive: fail loud, keep serving
                 log.exception("engine step failed; resetting engine state")
                 clock.abandon()
@@ -1691,6 +1696,38 @@ class EngineCore:
             if not did_work:
                 clock.switch("idle")
                 time.sleep(0.001)
+
+    def _loop_once(self, clock: LoopClock) -> bool:
+        """One iteration of the step loop: the control requests, admission,
+        one prefill chunk, the decode step (which may be several bursts,
+        _decode_bursts). True: it did work, or the plan said stop."""
+        if self.coordinator is not None:
+            clock.switch("control")
+            self._lockstep_tick()
+            if not self._running:
+                return True
+        if self._drain_park_requested:
+            clock.switch("control")
+            self._drain_park_requested = False
+            self._drain_park_all()
+        if self._park_rids:
+            clock.switch("control")
+            rids = self._park_rids
+            self._park_rids = set()
+            self._park_requested(rids)
+        if self._drain_flush_requested:
+            clock.switch("control")
+            self._drain_flush_requested = False
+            self._drain_flush_all()
+        clock.switch("admit")
+        did_work = self._try_insert()
+        clock.switch("other")
+        # At most ONE prefill chunk per iteration: decode steps run
+        # between chunks, so active slots keep emitting tokens during
+        # a long prompt's prefill (prefill/decode interleaving).
+        did_work |= self._advance_prefill()
+        did_work |= self._decode_active()
+        return did_work
 
     def _on_mesh(self, x):
         """Place the loop's small device state (the per-slot arrays, the
@@ -1753,6 +1790,7 @@ class EngineCore:
         self._block_tables[:] = 0
         self._d_block_tables = jnp.asarray(self._block_tables)
         self._tables_dirty = False
+        self._in_flight = None
         self.cache_k, self.cache_v = self._fresh_kv_pool()
         self._seq_lens[:] = 0
         # activation donates the per-slot arrays like the caches
@@ -1762,35 +1800,45 @@ class EngineCore:
             self.prefix_cache.clear()
         self._prefix_pinned_pages = 0
 
-    def _record_step(self, kind: str, step: StepSpan, *,
-                     active_slots: int = 0, tokens: int = 0,
-                     slots: "list[int] | None" = None,
-                     dispatches: int = 0, fused: bool = False,
-                     kv_pages: "dict[str, int] | None" = None,
-                     counters: "dict | None" = None,
-                     block: "dict[str, int] | None" = None) -> None:
-        """Close one step (its last stamp) and finalize its record: the
-        admission time since the previous record becomes its plan phase,
-        the record feeds the ring buffer + anomaly detector, and the phase
-        durations are mirrored into the Prometheus histograms. `slots`
-        names the slot ids this dispatch touched: their requests' gateway
-        ids land on the StepRecord (so /api/steps?slow=1 names the victims)
-        and a flagged step writes a slow_step event into each victim's
-        flight record, with the span or bucket that held the time.
-        `dispatches` is the honest device-program count this step issued
-        (decode/verify kinds feed the per-loop dispatch ledger and the
-        fused-decode "exactly one" invariant); `fused` marks steps served
-        by the single-program path. `kv_pages` (_kv_pages, decode records)
-        lands on the record and in the engine's two running totals: the
-        share of a (slots x window) sweep that live pages are. What this
-        costs after the step's last stamp is the next record's
-        since_prev.record_s. `counters` are the family's step counters of
-        this dispatch (a mixture's expert load): the scalars land on the
-        record, everything in the engine's running totals. `block` are a
-        block family's counts of the burst (_emit_blocks): on the record
-        and in the running totals likewise."""
+    def _record_step(self, kind: str, step: StepSpan, **counts) -> None:
+        """Close one step (its last stamp) and finalize its record
+        (_observe_step, whose arguments `counts` are). What that costs after
+        the step's last stamp is the next record's since_prev.record_s."""
         clock = self._clock()
         clock.close(step, kind)
+        self._observe_step(kind, step, **counts)
+        clock.resume(step)
+
+    def _observe_step(self, kind: str, step: StepSpan, *,
+                      active_slots: int = 0, tokens: int = 0,
+                      slots: "list[int] | None" = None,
+                      dispatches: int = 0, fused: bool = False,
+                      kv_pages: "dict[str, int] | None" = None,
+                      counters: "dict | None" = None,
+                      block: "dict[str, int] | None" = None,
+                      burst: "_Burst | None" = None) -> None:
+        """Finalize the record of a CLOSED step: the admission time since
+        the previous record becomes its plan phase, the record feeds the
+        ring buffer + anomaly detector, and the phase durations are mirrored
+        into the Prometheus histograms. `slots` names the slot ids this
+        dispatch touched: their requests' gateway ids land on the StepRecord
+        (so /api/steps?slow=1 names the victims) and a flagged step writes a
+        slow_step event into each victim's flight record, with the span or
+        bucket that held the time. `dispatches` is the honest device-program
+        count this step issued (decode/verify kinds feed the per-loop
+        dispatch ledger and the fused-decode "exactly one" invariant);
+        `fused` marks steps served by the single-program path. `kv_pages`
+        (_kv_pages, decode records) lands on the record and in the engine's
+        two running totals: the share of a (slots x window) sweep that live
+        pages are. `counters` are the family's step counters of this
+        dispatch (a mixture's expert load): the scalars land on the record,
+        everything in the engine's running totals. `block` are a block
+        family's counts of the burst (_emit_blocks): on the record and in
+        the running totals likewise. `burst` is a dense decode burst's:
+        whether it left ahead of its predecessor's emit, and why not, on the
+        record (`dispatched_ahead`, `ahead_blocked_by`) and in the running
+        totals. A step that LoopClock.handover closed is observed inside
+        its successor, under `emit_inflight`."""
         phases = step.phases()
         request_ids: dict[str, str] | None = None
         if slots:
@@ -1805,6 +1853,10 @@ class EngineCore:
         extra = {**(kv_pages or {}), **(block or {})}
         extra.update((name, v) for name, v in (counters or {}).items()
                      if isinstance(v, int))  # the scalars; not the histogram
+        if burst is not None:
+            extra["dispatched_ahead"] = burst.blocked_by is None
+            extra["ahead_blocked_by"] = burst.blocked_by
+            self.metrics.record_decode_burst(burst.blocked_by)
         slow = self.step_stats.observe(kind, phases,
                                        active_slots=active_slots,
                                        tokens=tokens,
@@ -1824,7 +1876,6 @@ class EngineCore:
                 self.flightrec.emit(rid, "slow_step", kind=kind,
                                     total_s=total, step_seq=step.seq,
                                     slow_in=step.slow_in)
-        clock.resume(step)
 
     # Same-bucket pending prompts prefill TOGETHER in one dispatch (padded to
     # a power-of-two group so the jit cache stays at log2 sizes). Bounded so
@@ -1983,6 +2034,10 @@ class EngineCore:
         slot = self.slots[slot_id]
         request = slot.request
         assert request is not None and not slot.prefilling
+        # a park reads the row's mirrors (out_tokens, _seq_lens) and may
+        # spill its pages: with a burst in flight the mirrors lag and the
+        # pages are still written (docs/kv-cache.md)
+        assert self._in_flight is None, "park with a burst in flight"
         request.parked = ParkedState(
             generated=slot.generated,
             tokens=list(slot.out_tokens),
@@ -2150,6 +2205,12 @@ class EngineCore:
         self._block_tables[slot_id, start:start + len(fresh)] = fresh
         self._tables_dirty = True
 
+    def _pages_short(self, slot_id: int, tokens: int) -> int:
+        """Pages the slot lacks to hold `tokens` positions (capped at its
+        capacity); zero or less: it has them."""
+        target = min(tokens, self.slot_capacity)
+        return self._pages_for_tokens(target) - len(self._slot_pages[slot_id])
+
     def _free_slot_kv(self, slot_id: int) -> None:
         """Return a slot's pages to the pool (shared prefix pages just drop
         this slot's reference; the donor entry keeps them alive) and point
@@ -2190,8 +2251,7 @@ class EngineCore:
                 # parked by a page-pressure preemption earlier in this walk
                 continue
             kk = per_row.get(i, k) if per_row is not None else k
-            target = min(int(self._seq_lens[i]) + kk + 1, self.slot_capacity)
-            need = self._pages_for_tokens(target) - len(self._slot_pages[i])
+            need = self._pages_short(i, int(self._seq_lens[i]) + kk + 1)
             if need > 0:
                 fresh = self._try_reserve_pages(need)
                 # a more important row may park less important decoders
@@ -2313,6 +2373,11 @@ class EngineCore:
         if (tokens <= 0 or not self._slot_pages[slot_id]
                 or self._kv_wire_cell() is None):
             return None
+        # a row that ends by its budget is not a row of the burst that left
+        # ahead (_prepare_burst counts it out): nothing in flight writes
+        # below `tokens`, and the gather below waits for what is in flight
+        assert (self._in_flight is None
+                or slot_id not in self._in_flight.slots)
         pages = self._slot_pages[slot_id][: self._pages_for_tokens(tokens)]
         t0 = time.monotonic()
         kvp = self._capture_kv(pages, tokens)
@@ -4341,6 +4406,7 @@ class EngineCore:
             # The occupancy gauge is otherwise only written on decode steps
             # and would freeze at the last batch size on an idle engine.
             self.metrics.set_batch_occupancy(0)
+            self._ahead_blocked_by = "first"  # the next burst follows none
             return False
 
         # Speculative decoding: when any active slot proposes drafts, ONE
@@ -4395,63 +4461,12 @@ class EngineCore:
         if k > 1 and constrained_active and not fused_step:
             k = 1
             self.metrics.record_constrained_burst_fallback()
-        lora_idx = self._d_lora_idx if self.lora is not None else None
         if k > 1 or fused_step:
-            window = self._window_for(active, k)
-            kv_pages = self._kv_pages(active, k, window)
-            grammar = fused_step and constrained_active
-            gram_args = {}
-            if grammar:
-                # Fresh int32 cursor vector from the host FSMs (source of
-                # truth, advanced in _emit): one [SLOTS] H2D per step instead
-                # of a [SLOTS, V] float32 mask scatter. Free/parked rows sit
-                # at cursor 0 — the all-zero free row.
-                gs = np.zeros((self.num_slots,), dtype=np.int32)
-                for i in active:
-                    slot = self.slots[i]
-                    if slot.constraint is not None and slot.gram_offset >= 0:
-                        gs[i] = slot.gram_offset + slot.constraint.state
-                gram_args = {
-                    "gram_table": self._grammar_tables.device(),
-                    "gram_state": jnp.asarray(gs),
-                }
-                self.metrics.record_masked_decode_step()
-            fn = (self._decode_many_gram_for(window) if grammar
-                  else self._decode_many_for(window))
-            step.mark("dispatch")
-            (self._d_last_tokens, self._d_seq_lens, self.cache_k,
-             self.cache_v, toks_dev) = fn(
-                self.params, self._d_last_tokens, self._d_seq_lens,
-                self.cache_k, self.cache_v, self._d_block_tables,
-                self._d_temps, self._d_top_ps, self._d_top_ks,
-                self._d_seeds, sk, self._live_rows(active),
-                lora_idx=lora_idx, **gram_args,
-            )
-            step.mark("compute")
-            # split device execution from the D2H readback: the dispatch
-            # returned futures, block_until_ready is the compute wait, the
-            # fetch below is pure transfer
-            jax.block_until_ready(toks_dev)
-            step.mark("fetch")
-            tokens = self._fetch_tokens(toks_dev)  # ONE D2H sync per k tokens
-            counters = None
-            if self._counter_shapes:
-                tokens, counters = _unpack_step_counters(
-                    tokens, k + 1, self.num_slots, self._counter_shapes)
-            # Tokens reach the host back-to-back, so wall-clock gaps between
-            # _emit calls are ~0 and would poison the ITL histogram; record
-            # the amortized per-token pacing of the burst instead.
-            step_s = (step.mark("emit") - t_sync) / k
-            self.metrics.record_decode_step(step_s, len(active))
-            self._emit_fetched(tokens, active, itl=step_s)
-            self._record_step(
-                "decode", step,
-                active_slots=len(active), tokens=k * len(active),
-                slots=active, dispatches=1, fused=fused_step,
-                kv_pages=kv_pages, counters=counters,
-            )
-            return True
+            return self._decode_bursts(
+                step, t_sync, active, k, sk, fused_step,
+                grammar=fused_step and constrained_active)
 
+        lora_idx = self._d_lora_idx if self.lora is not None else None
         first_in = self._d_last_tokens  # pre-step tokens: pending firsts
         window = self._window_for(active, 1)
         kv_pages = self._kv_pages(active, 1, window)
@@ -4492,7 +4507,7 @@ class EngineCore:
         tokens = self._fetch_tokens(jnp.stack([first_in, tokens_dev]))
         step_s = step.mark("emit") - t_sync
         self.metrics.record_decode_step(step_s, len(active))
-        self._emit_fetched(tokens, active, itl=step_s)
+        self._emit_fetched(tokens, self._burst_rows(active), itl=step_s)
         self._record_step(
             "decode", step,
             active_slots=len(active), tokens=len(active),
@@ -4503,6 +4518,194 @@ class EngineCore:
             kv_pages=kv_pages,
         )
         return True
+
+    def _burst_rows(self, active: list[int]) -> list[tuple[int, Request, bool]]:
+        """The rows of a decode dispatch as _emit_fetched delivers them:
+        (slot, its request, whether the request's first token is pending)
+        at the dispatch."""
+        return [(i, self.slots[i].request, self.slots[i].first_pending)
+                for i in active]
+
+    def _decode_bursts(self, step: StepSpan, t_cycle: float,
+                       active: list[int], k: int, sk, fused_step: bool,
+                       grammar: bool) -> bool:
+        """The dense decode burst (k steps in one program), and as many
+        more as can leave AHEAD: a burst whose rows need nothing from the
+        host is dispatched right after its predecessor's fetch, and the
+        predecessor's tokens are delivered and its record closed while it
+        computes (docs/scheduling.md "The two orders of a decode cycle").
+        Either way the host prepares the next burst (_prepare_burst) between
+        a dispatch and the wait for it. Whether the next burst leaves ahead
+        is decided after each fetch from what the loop can observe
+        (_ahead_blocker); where anything stands in the way, the cycle is
+        the parent's, step for step: emit, record, back through _loop, and
+        host_sync again (which finds its pages grown and its tables clean).
+        The device never holds more than ONE burst the host has not
+        fetched, and none is in flight when this returns."""
+        clock = self._clock()
+        lora_idx = self._d_lora_idx if self.lora is not None else None
+        window = self._window_for(active, k)
+        plan = (self._burst_rows(active), window,
+                self._kv_pages(active, k, window))
+        gram_args = {}
+        if grammar:
+            # Fresh int32 cursor vector from the host FSMs (source of
+            # truth, advanced in _emit): one [SLOTS] H2D per step instead
+            # of a [SLOTS, V] float32 mask scatter. Free/parked rows sit
+            # at cursor 0 — the all-zero free row.
+            gs = np.zeros((self.num_slots,), dtype=np.int32)
+            for i in active:
+                slot = self.slots[i]
+                if slot.constraint is not None and slot.gram_offset >= 0:
+                    gs[i] = slot.gram_offset + slot.constraint.state
+            gram_args = {
+                "gram_table": self._grammar_tables.device(),
+                "gram_state": jnp.asarray(gs),
+            }
+            self.metrics.record_masked_decode_step()
+        # what no wait for a burst changes (a grammar's cursors above are
+        # the host FSMs': such a burst is never followed ahead)
+        fixed = self._ahead_fixed_blocker(active, grammar)
+        blocked_by, self._ahead_blocked_by = self._ahead_blocked_by, "first"
+        prev: _Burst | None = None
+        while True:
+            rows, window, kv_pages = plan
+            fn = (self._decode_many_gram_for(window) if grammar
+                  else self._decode_many_for(window))
+            if prev is None:
+                step.mark("dispatch")
+            else:
+                # the key is split at the dispatch, never at the
+                # preparation: the sequence of keys is that of bursts and
+                # activations in the order they are dispatched
+                self._key, sk = jax.random.split(self._key)
+            burst = _Burst(step, rows, kv_pages, blocked_by)
+            (self._d_last_tokens, self._d_seq_lens, self.cache_k,
+             self.cache_v, toks_dev) = fn(
+                self.params, self._d_last_tokens, self._d_seq_lens,
+                self.cache_k, self.cache_v, self._d_block_tables,
+                self._d_temps, self._d_top_ps, self._d_top_ks,
+                self._d_seeds, sk, self._live_rows(burst.slots),
+                lora_idx=lora_idx, **gram_args,
+            )
+            self._in_flight = burst
+            if prev is not None:
+                step.mark("emit_inflight")
+                self._deliver_burst(prev, k, fused_step, closed=True)
+            plan = None
+            if fixed is None:
+                step.mark("host_sync_inflight")
+                plan = self._prepare_burst(rows, k)
+            step.mark("compute")
+            # split device execution from the D2H readback: the dispatch
+            # returned futures, block_until_ready is the compute wait, the
+            # fetch below is pure transfer
+            jax.block_until_ready(toks_dev)
+            step.mark("fetch")
+            burst.fetched = self._fetch_tokens(toks_dev)  # ONE D2H per k tokens
+            self._in_flight = None
+            blocked_by = fixed or self._ahead_blocker(plan)
+            # Tokens reach the host back-to-back, so wall-clock gaps between
+            # _emit calls are ~0 and would poison the ITL histogram; record
+            # the amortized per-token pacing of the burst's cycle instead.
+            if blocked_by is None:
+                step = clock.handover(step, "decode", "dispatch")
+                burst.step_s = (step.t0 - t_cycle) / k
+                prev, t_cycle = burst, step.t0
+                continue
+            self._ahead_blocked_by = blocked_by
+            burst.step_s = (step.mark("emit") - t_cycle) / k
+            self._deliver_burst(burst, k, fused_step, closed=False)
+            return True
+
+    def _deliver_burst(self, burst: _Burst, k: int, fused_step: bool, *,
+                       closed: bool) -> None:
+        """Emit a fetched burst's tokens and finalize its record; `closed`:
+        LoopClock.handover has closed its step already."""
+        rows = burst.rows
+        tokens, counters = burst.fetched, None
+        if self._counter_shapes:
+            tokens, counters = _unpack_step_counters(
+                tokens, k + 1, self.num_slots, self._counter_shapes)
+        self.metrics.record_decode_step(burst.step_s, len(rows))
+        self._emit_fetched(tokens, rows, itl=burst.step_s)
+        record = self._observe_step if closed else self._record_step
+        record("decode", burst.step,
+               active_slots=len(rows), tokens=k * len(rows),
+               slots=burst.slots, dispatches=1, fused=fused_step,
+               kv_pages=burst.kv_pages, counters=counters, burst=burst)
+
+    def _ahead_fixed_blocker(self, active: list[int],
+                             grammar: bool) -> str | None:
+        """What keeps every burst of these rows from leaving ahead, and no
+        wait for a burst changes: the loop is not alone (a coordinator's
+        tick, split mode's lock), or a row needs the host between two
+        bursts — its next mask comes from a host FSM advanced in _emit
+        (`grammar`: a constrained row reaches a dense burst on the fused
+        grammar alone), or a drafter reads what _emit appends."""
+        if self.coordinator is not None or self.split is not None:
+            return "control"
+        if grammar:
+            return "constraint"
+        if self._spec_available and any(
+                self.slots[i].drafter is not None for i in active):
+            return "draft"
+        return None
+
+    def _ahead_blocker(self, plan) -> str | None:
+        """After a burst's fetch: what today's loop would serve before the
+        next burst, as far as the loop can observe it — a stop, drain, park
+        or flush request; a slot in prefill; a request in the inbox, the
+        class queues or held on the pool — or why _prepare_burst has no
+        burst to offer. None: the prepared burst may leave now."""
+        if (not self._running or self._stop_requested or self.draining
+                or self._drain_park_requested or self._park_rids
+                or self._drain_flush_requested):
+            return "control"
+        if any(s.prefilling for s in self.slots):
+            return "prefilling"
+        if (self._held_request is not None or not self.pending.empty()
+                or any(self._class_queues.values())):
+            return "admission"
+        return plan if isinstance(plan, str) else None
+
+    def _prepare_burst(self, rows: list[tuple[int, Request, bool]], k: int):
+        """With the burst of `rows` in flight and every earlier one emitted:
+        what host_sync would do for the NEXT burst, from the lengths its
+        rows will have (the mirrors lag by the burst in flight: `_seq_lens`
+        + k). A row the host can count to its end inside the burst in
+        flight (max_tokens, the slot's capacity) is not a row of the next;
+        one that ends there by EOS, stop or cancel is, and _emit_fetched
+        drops its column. Pages come from the free list alone — this never
+        evicts, parks, preempts or finishes anything (_ensure_decode_pages
+        may do all four, and a park would read `out_tokens` that lag) — and
+        all of them or none. Returns (rows, window, kv_pages) of the next
+        burst, or why there is none: "pages", or "first" where no row
+        outlives the burst in flight."""
+        nxt: list[int] = []
+        need: dict[int, int] = {}
+        for i, request, first in rows:
+            slot = self.slots[i]
+            if slot.request is not request:
+                continue  # ended in the emit before this one
+            length = int(self._seq_lens[i]) + k
+            if (slot.generated + first + k >= request.sampling.max_tokens
+                    or length + 1 >= self.slot_capacity):
+                continue
+            nxt.append(i)
+            short = self._pages_short(i, length + k + 1)
+            if short > 0:
+                need[i] = short
+        if not nxt:
+            return "first"
+        if sum(need.values()) > self.page_pool.available():
+            return "pages"
+        for i, short in need.items():
+            self._extend_slot_pages(i, self.page_pool.alloc(short))
+        self._sync_block_tables()
+        window = self._window_for(nxt, 2 * k)
+        return ([(i, self.slots[i].request, False) for i in nxt], window,
+                self._kv_pages(nxt, 2 * k, window))
 
     def _block_reach(self) -> int:
         """Positions past its committed length that a row may write in one
@@ -4684,23 +4887,26 @@ class EngineCore:
                           tokens=n_tokens)
         return counts
 
-    def _emit_fetched(self, tokens, active: list[int],
+    def _emit_fetched(self, tokens, rows: list[tuple[int, Request, bool]],
                       itl: float | None) -> None:
-        """Deliver one fetched token matrix [rows, SLOTS], column by column:
-        everything the fetch brought a request goes out as ONE content
-        event. Row 0 holds deferred first emissions for slots activated
-        since the previous fetch (no seq_len advance — the first token is
-        prefill output, not a decode step); rows 1.. are decode steps. A
-        slot that finishes mid-matrix (EOS / max_tokens / capacity /
-        cancel) has the rest of its column trimmed. Slots share no state
+        """Deliver one fetched token matrix [rows, SLOTS], column by column,
+        to the requests that held the columns' slots when it was DISPATCHED
+        (`rows`, _burst_rows): everything the fetch brought a request goes
+        out as ONE content event. A slot whose request has ended since — in
+        the emit of the burst before, with this one already in flight — has
+        its column dropped, and so has one that holds another request by
+        now. Row 0 holds the deferred first emission of a request activated
+        since the fetch before the dispatch (no seq_len advance — the first
+        token is prefill output, not a decode step); rows 1.. are decode
+        steps. A slot that finishes mid-matrix (EOS / max_tokens / capacity
+        / cancel) has the rest of its column trimmed. Slots share no state
         here, so the order of the columns is free."""
-        for i in active:
+        for i, request, first in rows:
             slot = self.slots[i]
-            request = slot.request
-            if request is None:
+            if slot.request is not request:
                 continue
             held: list[int] = []
-            if slot.first_pending:
+            if first:
                 slot.first_pending = False
                 # first=True: the grammar FSM already advanced on this token
                 # at activation (the synchronous fetch there) — advancing

@@ -54,9 +54,30 @@ Span names (``SPANS``), in the order a decode step runs them:
               state), the first-token fetch where a grammar needs it, and
               the slot bookkeeping
 
+Host work done WITH A BURST IN FLIGHT, between a dispatch's return and the
+wait for it (scheduler._decode_bursts), has span names of its own
+(``INFLIGHT_SPANS``), so that the names above keep meaning "the device has
+nothing from this loop":
+
+  emit_inflight      — delivery of the burst BEFORE the one in flight and
+                       the closing of its record (what `emit` and the
+                       `record` bucket hold in the other order)
+  host_sync_inflight — what `host_sync` does, done for the NEXT burst from
+                       the lengths its rows will have: page growth from the
+                       free list, the block tables, the window, the live rows
+
+A decode burst runs in one of two orders. Today's: ``host_sync, dispatch,
+[host_sync_inflight,] compute, fetch, emit``. Dispatched ahead (it left
+right after its predecessor's fetch): ``dispatch, emit_inflight,
+[host_sync_inflight,] compute, fetch`` and, where the next burst does not
+leave ahead in its turn, ``emit``. Where one does, the record ends at the
+stamp the next begins at (``LoopClock.handover``): records never overlap.
+
 The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
 since the previous record (``since_prev.admit_s``), ``emit`` still covers
-activation, and ``total_s`` is their sum. Records land in a bounded ring
+activation, the in-flight spans count under ``compute`` — which is the
+interval from the dispatch's return to the device's completion, whatever
+the host did in it — and ``total_s`` is their sum. Records land in a bounded ring
 buffer served at the engine's ``/api/steps`` plus per-phase histograms in
 ``/metrics``. A slow-step anomaly detector keeps an EMA per kind of the time
 a step took since the previous one ended (idle sleep left out), and flags
@@ -94,7 +115,9 @@ PHASES = ("plan", "draft", "host_sync", "dispatch", "compute", "fetch",
           "emit")
 # The closed set of span names a step is cut into (StepSpan.mark).
 SPANS = ("draft", "host_sync", "dispatch", "compute", "fetch", "emit",
-         "activate")
+         "activate", "host_sync_inflight", "emit_inflight")
+# Host work with a burst in flight: `compute` in the legacy phases.
+INFLIGHT_SPANS = ("host_sync_inflight", "emit_inflight")
 # Where the loop's time goes between steps, and with "step" all of it.
 GAP_BUCKETS = ("admit", "control", "record", "idle", "other")
 LOOP_BUCKETS = ("step",) + GAP_BUCKETS
@@ -180,8 +203,9 @@ class StepSpan:
 
     def phases(self) -> dict[str, float]:
         """The legacy phase durations of a closed step: spans summed by
-        name, `activate` counted as `emit`, and the admission time since
-        the previous record as `plan`."""
+        name, `activate` counted as `emit`, the in-flight spans as
+        `compute`, and the admission time since the previous record as
+        `plan`."""
         out = {"plan": self.since_prev["admit"]}
         spans = self.spans
         if self._legacy_spans is not None:
@@ -189,6 +213,8 @@ class StepSpan:
         for name, _start, dur in spans:
             if name == "activate":
                 name = "emit"
+            elif name in INFLIGHT_SPANS:
+                name = "compute"
             out[name] = out.get(name, 0.0) + dur
         return out
 
@@ -225,15 +251,16 @@ class LoopClock:
         self._bucket = bucket
         self._mark = now
 
-    def begin(self, first_span: str) -> StepSpan:
+    def begin(self, first_span: str, seq: int | None = None) -> StepSpan:
         """Open a step whose first span is `first_span`. Its kind is given
         when it is closed (a decode step may turn into a verify)."""
         resume = self._bucket
         self.switch("step")
         # steps are serialized (one loop, or split mode's lock), so the
-        # record this step will become is the recorder's next
+        # record this step will become is the recorder's next (handover
+        # says otherwise: its closed step is not recorded yet)
         cpu = _cpu()
-        step = StepSpan(self.tag, self.recorder.seq + 1, self._mark,
+        step = StepSpan(self.tag, seq or self.recorder.seq + 1, self._mark,
                         first_span, self._gap, resume, cpu,
                         cpu - self._cpu_closed)
         self._gap = dict.fromkeys(GAP_BUCKETS, 0.0)
@@ -275,6 +302,16 @@ class LoopClock:
 
     def resume(self, step: StepSpan) -> None:
         self.switch(step._resume)
+
+    def handover(self, step: StepSpan, kind: str,
+                 first_span: str) -> StepSpan:
+        """Close `step` and open the next one where it ends: a burst that
+        leaves before its predecessor is recorded. Nothing runs in `record`
+        — the caller hands `step` to the recorder inside the new step, under
+        `emit_inflight` — and the new step resumes where `step` would have."""
+        self.close(step, kind)
+        self._bucket = step._resume
+        return self.begin(first_span, seq=step.seq + 1)
 
     def abandon(self) -> None:
         """Drop the open step, if any, without a record (a step that found

@@ -2889,7 +2889,11 @@ async def run_chaos_engine_kill(streams: int = 8,
                 raw = b""
                 async for chunk in r.content.iter_any():
                     raw += chunk
-                    if b'"content"' in raw and not out.get("started"):
+                    # a token, not the role chunk (`"content": ""`), which
+                    # the engine writes before it submits the request: a
+                    # kill there finds a stream the victim never recorded
+                    if (not out.get("started")
+                            and re.search(rb'"content": ?"[^"]', raw)):
                         out["started"] = True
                         counter[0] += 1
                         if counter[0] >= streams:

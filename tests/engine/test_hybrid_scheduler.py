@@ -20,7 +20,7 @@ from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.models import nemotron_h
 from tests.engine.test_hybrid_family import HF
-from tests.support import collect_events
+from tests.support import InlineLoop, collect_events
 
 CFG = get_preset("debug-nemotron-h-tiny")
 PARAMS = nemotron_h.init_params(CFG, jax.random.PRNGKey(0))
@@ -129,6 +129,46 @@ def test_park_and_resume_is_token_identical():
         _assert_greedy(victim_prompt, got_victim)
     finally:
         core.stop()
+
+
+def test_a_finished_rows_state_is_rewritten_by_the_next_activation():
+    """A row that meets its EOS inside burst n is still a row of burst n+1,
+    which left before n was emitted: n+1 advances the slot's state past the
+    end. The next request in that slot starts its prefill from zeros,
+    dispatched after n+1 (docs/kv-cache.md "A burst in flight and pages
+    already released"): its tokens, and those of the row that decoded
+    beside both, are the reference's."""
+    ending, beside, taking = _prompt(11, 90), _prompt(14, 91), _prompt(9, 92)
+
+    def serve(eos):
+        core = EngineCore(CFG, PARAMS, **{**ARGS, "eos_id": eos,
+                                          "num_slots": 2})
+        loop = InlineLoop(core)
+        requests = [Request(prompt_ids=p, sampling=SamplingParams(
+            temperature=0.0, max_tokens=n))
+            for p, n in ((ending, 30), (beside, 30), (taking, 10))]
+        core.pending.put(requests[0])
+        core.pending.put(requests[1])
+        # the EOS is in burst 2; burst 3 left ahead with the row in it; the
+        # arrival comes while 3 is in flight
+        loop.during[3] = [lambda: core.pending.put(requests[2])]
+        loop.run()
+        return [collect_events(r, None) for r in requests], loop
+
+    streams = [tokens for tokens, _f, _s in serve(-1)[0]]
+    probe, everything = streams[0], sum(streams, [])
+    # inside burst 2 (decode tokens 5 to 8), and no other row's token
+    at = next(i for i in (5, 6, 7) if everything.count(probe[i]) == 1)
+    ((first, reason, _s), (second, _, _), (third, _, _)), loop = serve(
+        probe[at])
+    assert reason == "stop" and first == probe[:at]
+    records = loop.decode_records()
+    assert records[2]["dispatched_ahead"]
+    assert records[2]["active_slots"] == 2  # the ended row among them
+    assert "0" in records[3]["request_ids"]  # its slot, taken at once
+    assert len(second) == 30 and len(third) == 10
+    _assert_greedy(beside, second)
+    _assert_greedy(taking, third)
 
 
 def test_the_engine_streams_the_family_end_to_end():

@@ -175,6 +175,72 @@ def test_midstream_page_exhaustion_parks_instead_of_finishing():
         eng.shutdown()
 
 
+@pytest.mark.parametrize("how", ["priority", "request_park"])
+def test_a_park_waits_for_the_burst_in_flight_and_resumes_identical(
+        how, monkeypatch):
+    """A park reads the row's mirrors (`out_tokens`, `_seq_lens`) and spills
+    its pages to the host: both lag or are still written while a burst of
+    that row is in flight. Whatever asks for a park while one is — a more
+    important arrival on full slots, `request_park` — keeps the NEXT burst
+    from leaving ahead (`admission`, `control`), so the park runs after the
+    burst's emit with nothing in flight (`_park_slot` asserts it), the
+    spilled pages restore without a prefill, and the stream is the
+    uninterrupted one's (docs/kv-cache.md "A burst in flight and pages
+    already released")."""
+    from tests.support import InlineLoop, collect
+
+    monkeypatch.setenv("LLMLB_KV_OFFLOAD_BYTES", str(1 << 26))
+
+    def serve(park: bool):
+        core = EngineCore(get_preset("debug-tiny"), num_slots=2,
+                          slot_capacity=128, prefill_buckets=(16, 32),
+                          kv_page_size=16, seed=0, decode_burst=4,
+                          prefix_cache=False)
+        loop = InlineLoop(core)
+        victim = Request(prompt_ids=[9, 8, 7, 6, 5], request_id="victim",
+                         sampling=SamplingParams(temperature=0.8, seed=21,
+                                                 max_tokens=40, priority=2))
+        beside = Request(prompt_ids=[1, 2, 3], sampling=SamplingParams(
+            temperature=0.0, max_tokens=40, priority=2))
+        urgent = Request(prompt_ids=[4, 4, 4, 4], sampling=SamplingParams(
+            temperature=0.0, max_tokens=6, priority=0))
+        core.pending.put(victim)
+        core.pending.put(beside)
+        parked_with = []
+        park_slot = core._park_slot
+
+        def park_and_tell(slot_id, reason="preempt"):
+            parked_with.append((core.slots[slot_id].request.request_id,
+                                core._in_flight, reason))
+            park_slot(slot_id, reason)
+
+        core._park_slot = park_and_tell
+        if park and how == "priority":
+            loop.during[3] = [lambda: core.pending.put(urgent)]
+        elif park:
+            loop.during[3] = [lambda: core.request_park("victim")]
+        loop.run()
+        return ([collect(r, None) for r in (victim, beside)], parked_with,
+                loop)
+
+    plain, nobody, _ = serve(park=False)
+    streams, parked_with, loop = serve(park=True)
+    assert nobody == [] and streams == plain
+    assert [len(t) for t, _f in streams] == [40, 40]
+    # one park, of the least important row with the fewest tokens, with no
+    # burst in flight, after bursts 2 and 3 had left ahead
+    assert len(parked_with) == 1 and parked_with[0][1] is None
+    records = loop.decode_records()
+    assert [r["dispatched_ahead"] for r in records[:4]] == [
+        False, True, True, False]
+    assert records[3]["ahead_blocked_by"] == (
+        "admission" if how == "priority" else "control")
+    # the spill was read with nothing in flight, and came back as bytes
+    m = loop.core.metrics
+    assert m.preemptions_total == 1 and m.preempt_resumes_total == 1
+    assert m.kv_restored_total == 1
+
+
 def test_prefill_chunk_budget_interleaves_and_is_token_identical():
     """With the budget on and a decoder active, a one-shot-sized prompt
     runs as multiple budget-sized chunks (decode steps between), and the

@@ -20,7 +20,7 @@ import pytest
 from llmlb_tpu.engine.prefix_cache import PrefixCache
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
-from tests.support import assert_hit_is_zero_copy, collect
+from tests.support import InlineLoop, assert_hit_is_zero_copy, collect
 
 # ----------------------------------------------------------------- radix tree
 
@@ -216,6 +216,50 @@ def test_cache_hit_reuses_prefix_and_matches_cold_output(prompt, kv_page):
         assert (warm_toks, warm_fin) == (cold_toks, cold_fin)
     finally:
         core.stop()
+
+
+@pytest.mark.parametrize("todays_order", [False, True],
+                         ids=["ahead", "todays-order"])
+def test_a_donors_pages_are_whole_with_a_burst_in_flight(prompt, todays_order):
+    """A request meets its EOS inside burst n while burst n+1, which left
+    before n was emitted, still writes its row: what it donates are whole
+    pages below its prompt's length, which n+1 never writes (it writes at
+    and past the final length). A later request with the same head is a
+    zero-copy hit and reads what a cold engine computes (docs/kv-cache.md
+    "A burst in flight and pages already released")."""
+    def serve(eos, *, cache):
+        core = make_core(16, num_slots=2, slot_capacity=96,
+                         prefill_buckets=(16, 32, 64), seed=0, decode_burst=4,
+                         eos_id=eos, prefix_cache=cache)
+        loop = InlineLoop(core, todays_order=todays_order)
+        donor, beside, reader = (Request(
+            prompt_ids=list(ids), sampling=SamplingParams(
+                temperature=0.0, max_tokens=n))
+            for ids, n in ((prompt, 30), (prompt[:20][::-1], 40),
+                           (prompt[:40] + [3, 1, 4], 12)))
+        core.pending.put(donor)
+        core.pending.put(beside)
+        # the donor ends in burst 2; the reader comes while 3 is in flight
+        loop.during[3] = [lambda: core.pending.put(reader)]
+        loop.run()
+        return [collect(r, None) for r in (donor, beside, reader)], loop
+
+    streams = [tokens for tokens, _ in serve(-1, cache=False)[0]]
+    probe, everything = streams[0], sum(streams, [])
+    # inside burst 2 (decode tokens 5 to 8), and no other row's token
+    at = next(i for i in (5, 6, 7) if everything.count(probe[i]) == 1)
+    eos = probe[at]
+    cold, _ = serve(eos, cache=False)
+    warm, loop = serve(eos, cache=True)
+    assert warm == cold
+    assert warm[0] == (probe[:at], "stop")
+    assert [len(t) for t, _ in warm[1:]] == [40, 12]
+    m = loop.core.metrics
+    assert m.prefix_insertions_total >= 1 and m.prefix_hits_total == 1
+    assert m.prefix_cached_tokens_total == 32  # the reader's 40, aligned
+    if not todays_order:
+        third = loop.decode_records()[2]
+        assert third["dispatched_ahead"] and third["active_slots"] == 2
 
 
 def test_divergent_tail_still_hits_shared_head(prompt, kv_page):

@@ -18,6 +18,7 @@ import pytest
 from llmlb_tpu.engine import compilelog, stepstats
 from llmlb_tpu.engine.stepstats import (
     GAP_BUCKETS,
+    INFLIGHT_SPANS,
     LOOP_BUCKETS,
     PHASES,
     SPANS,
@@ -224,6 +225,72 @@ def test_activate_is_a_span_of_its_own_and_still_part_of_legacy_emit(
     clock.close(step, "prefill")         # 2.31
     assert step.phases() == pytest.approx({"plan": 0.0, "dispatch": 0.1})
     assert [n for n, _a, _d in step.spans] == ["dispatch", "activate"]
+
+
+def test_a_handover_closes_a_step_where_the_next_begins(monkeypatch):
+    """A burst that leaves before its predecessor is recorded
+    (LoopClock.handover): the two records do not overlap, no time lies
+    between them, the second knows its seq before the first is observed, and
+    host work with a burst in flight is `compute` in the legacy phases, a
+    span of its own name in `spans` and CPU in `host_cpu_s`."""
+    cpu = iter([0.0, 0.0,            # the clock is made; begin
+                0.03, 0.03,          # into and out of the first `compute`
+                0.04, 0.04,          # handover: close, begin
+                0.08, 0.08,          # around the second `compute`
+                0.10])               # close
+    monkeypatch.setattr(stepstats, "_cpu", lambda: next(cpu))
+    rec, clock = _stamped(monkeypatch, [
+        1.0, 2.0,  # the recorder's anchor; the clock is made
+        2.0,    # begin(host_sync): t0
+        2.01,   # mark(dispatch)
+        2.02,   # mark(host_sync_inflight)
+        2.03,   # mark(compute)
+        2.13,   # mark(fetch)
+        2.14,   # handover: the first step's t1 ...
+        2.14,   # ... and the second's t0 (dispatch)
+        2.15,   # mark(emit_inflight)
+        2.17,   # mark(host_sync_inflight)
+        2.18,   # mark(compute)
+        2.28,   # mark(fetch)
+        2.29,   # mark(emit)
+        2.30,   # close
+        2.31,   # resume
+    ])
+    first = clock.begin("host_sync")
+    for name in ("dispatch", "host_sync_inflight", "compute", "fetch"):
+        first.mark(name)
+    second = clock.handover(first, "decode", "dispatch")
+    assert (first.t1, second.t0, second.seq) == (2.14, 2.14, first.seq + 1)
+    second.mark("emit_inflight")
+    # the first record is observed inside the second step
+    rec.observe("decode", first.phases(), span=first)
+    for name in ("host_sync_inflight", "compute", "fetch", "emit"):
+        second.mark(name)
+    clock.close(second, "decode")
+    rec.observe("decode", second.phases(), span=second)
+    clock.resume(second)
+    b, a = rec.snapshot()["records"]
+    assert (a["seq"], b["seq"]) == (first.seq, second.seq)
+    assert a["t1_s"] == b["t0_s"] == 2.14
+    assert b["since_prev"] == pytest.approx(dict.fromkeys(
+        (f"{g}_s" for g in GAP_BUCKETS), 0.0))
+    assert [n for n, _a, _d in b["spans"]] == [
+        "dispatch", "emit_inflight", "host_sync_inflight", "compute",
+        "fetch", "emit"]
+    assert all(n in SPANS for n, _a, _d in a["spans"] + b["spans"])
+    assert a["phases_s"] == pytest.approx({
+        "plan": 0.0, "draft": 0.0, "host_sync": 0.01, "dispatch": 0.01,
+        "compute": 0.01 + 0.10, "fetch": 0.01, "emit": 0.0})
+    assert b["phases_s"] == pytest.approx({
+        "plan": 0.0, "draft": 0.0, "host_sync": 0.0, "dispatch": 0.01,
+        "compute": 0.02 + 0.01 + 0.10, "fetch": 0.01, "emit": 0.01})
+    for r in (a, b):
+        assert r["total_s"] == pytest.approx(r["wall_s"])
+    # the thread's CPU outside `compute`, the in-flight spans included
+    assert a["host_cpu_s"] == pytest.approx(0.03 + 0.01)
+    assert b["host_cpu_s"] == pytest.approx(0.04 + 0.02)
+    assert clock.acc["step"] == pytest.approx(0.30)
+    assert clock.acc["record"] == pytest.approx(0.01)
 
 
 def test_an_abandoned_step_leaves_no_record_and_loses_no_time(monkeypatch):
@@ -459,11 +526,32 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
             r["wall_s"] + r["since_prev"]["admit_s"], abs=1e-5)
         assert r["phases_s"]["plan"] == pytest.approx(
             r["since_prev"]["admit_s"], abs=2e-6)
-    for r in decode:
-        assert [n for n, _a, _d in r["spans"]] == [
-            "host_sync", "dispatch", "compute", "fetch", "emit"]
+    for r, nxt in zip(decode, decode[1:] + [None]):
+        # the two orders of a decode cycle (docs/scheduling.md): today's,
+        # and the one of a burst that left before its predecessor was
+        # emitted; a burst whose successor left ahead has no `emit` of its
+        # own, the successor's `emit_inflight` holds it
+        names = [n for n, _a, _d in r["spans"]]
+        head = (["dispatch", "emit_inflight"] if r["dispatched_ahead"]
+                else ["host_sync", "dispatch"])
+        followed = nxt is not None and nxt["dispatched_ahead"]
+        assert names == head + ["host_sync_inflight", "compute", "fetch"] + (
+            [] if followed else ["emit"])
+        assert (r["ahead_blocked_by"] is None) == r["dispatched_ahead"]
+        if followed:
+            assert nxt["seq"] == r["seq"] + 1
+            assert nxt["since_prev"]["record_s"] == 0.0
+        # legacy phases: host work with a burst in flight is `compute`, the
+        # interval from the dispatch's return to the device's completion
         assert _span_sum(r, "emit") == pytest.approx(r["phases_s"]["emit"],
                                                      abs=2e-6)
+        assert r["phases_s"]["compute"] == pytest.approx(
+            sum(_span_sum(r, n) for n in ("compute",) + INFLIGHT_SPANS),
+            abs=3e-6)
+        assert r["phases_s"]["host_sync"] == pytest.approx(
+            _span_sum(r, "host_sync"), abs=2e-6)
+    assert any(r["dispatched_ahead"] for r in decode)
+    assert not decode[0]["dispatched_ahead"]
     activating = [r for r in prefill if _span_sum(r, "activate") > 0]
     assert [r["seq"] for r in activating] == [prefill[0]["seq"],
                                               prefill[3]["seq"]]
@@ -486,8 +574,14 @@ async def test_no_time_is_lost_between_consecutive_records(served_engine):
         assert cur["seq"] == prev["seq"] + 1 and cur["loop"] == "main"
         assert prev["t1_s"] + sum(cur["since_prev"].values()) == \
             pytest.approx(cur["t0_s"], abs=50e-6)
-        # closing a record costs something, and the next one says what
-        assert cur["since_prev"]["record_s"] > 0
+        # closing a record costs something, and the next one says what:
+        # in its gap, or, where it left ahead, in its `emit_inflight`
+        if cur.get("dispatched_ahead"):
+            assert cur["since_prev"]["record_s"] == 0.0
+            assert cur["t0_s"] - prev["t1_s"] < 50e-6
+            assert _span_sum(cur, "emit_inflight") > 0
+        else:
+            assert cur["since_prev"]["record_s"] > 0
 
 
 async def test_loop_buckets_sum_to_the_interval(served_engine):
@@ -544,11 +638,16 @@ async def test_a_stall_in_admission_is_flagged_slow_and_named(served_engine):
 
     core._drain_pending = drain_with_a_stall
     try:
-        task = asyncio.ensure_future(engine.complete(
-            [7, 2, 3, 4, 5], SamplingParams(temperature=0.0, max_tokens=40)))
+        # three requests on two slots: while one waits in the queue every
+        # decode cycle goes through admission (a steady batch with nothing
+        # waiting dispatches its bursts ahead and never gets there)
+        tasks = [asyncio.ensure_future(engine.complete(
+            [7 + i, 2, 3, 4, 5],
+            SamplingParams(temperature=0.0, max_tokens=40)))
+            for i in range(3)]
         await asyncio.sleep(0.02)
         stall["left"] = 1
-        await task
+        await asyncio.gather(*tasks)
     finally:
         core._drain_pending = plain
     assert core.step_stats.slow_steps_total > before

@@ -104,6 +104,50 @@ def word_engine(decode_burst: int, **core_kwargs):
     return Engine("debug-tiny", core, tokenizer)
 
 
+class InlineLoop:
+    """An engine core's step loop on the test's thread, so that the steps
+    and their order are the test's: `run` calls the loop's own iteration
+    (`EngineCore._loop_once`) until one does no work, with `_running` set so
+    that the loop's own state decides the order of a decode cycle, as in a
+    started engine (docs/scheduling.md "The two orders of a decode cycle").
+    `during[n]` is a list of calls made while the n-th dense burst is in
+    flight — `_prepare_burst`, which every dense burst calls between its
+    dispatch and the wait for it. `todays_order` patches the predicate to
+    "not now": the parent's cycle, step for step."""
+
+    def __init__(self, core, *, todays_order: bool = False):
+        self.core = core
+        self.bursts = 0
+        self.during: dict[int, list] = {}
+        core._running = True
+        prepare = core._prepare_burst
+
+        def prepare_and_tell(rows, k):
+            self.bursts += 1
+            assert core._in_flight is not None
+            for act in self.during.pop(self.bursts, ()):
+                act()
+            return prepare(rows, k)
+
+        core._prepare_burst = prepare_and_tell
+        if todays_order:
+            core._ahead_blocker = lambda plan: "control"
+
+    def run(self, iterations: int = 400) -> None:
+        core = self.core
+        clock = core._clock()
+        for _ in range(iterations):
+            did_work = core._loop_once(clock)
+            assert core._in_flight is None  # no burst outlives an iteration
+            if not did_work:
+                return
+        raise AssertionError("the requests did not finish")
+
+    def decode_records(self) -> list[dict]:
+        records = self.core.step_stats.snapshot(limit=512)["records"][::-1]
+        return [r for r in records if r["kind"] == "decode"]
+
+
 # ------------------------------------------------- family-level KV fixtures
 
 
