@@ -216,8 +216,11 @@ class EngineMetrics:
         self.moe_expert_load_hist: list[list[int]] = []
         # Of a chip that holds a share of the experts: assignments that went
         # to the others; and rows whose recurrent state a dispatch advanced
-        # (models/nemotron_h.step_counter_shapes)
+        # (models/nemotron_h.step_counter_shapes); of a router that also
+        # scores zero-compute experts, the assignments that went to those
+        # and cost no product (models/longcat_flash.step_counter_shapes)
         self.moe_assignments_elsewhere_total = 0
+        self.moe_zero_assignments_total = 0
         self.ssm_state_rows_total = 0
         self.decode_kv_pages_window_total = 0
         # Generation by diffusion over blocks (scheduler._emit_blocks):
@@ -443,6 +446,8 @@ class EngineMetrics:
                 self.moe_expert_load_max, counters.get("expert_load_max", 0))
             self.moe_assignments_elsewhere_total += counters.get(
                 "assignments_elsewhere", 0)
+            self.moe_zero_assignments_total += counters.get(
+                "zero_assignments", 0)
             self.ssm_state_rows_total += counters.get("state_rows", 0)
             hist = counters.get("expert_load_hist")
             if hist:
@@ -646,6 +651,7 @@ class EngineMetrics:
                 "moe_expert_load_max": self.moe_expert_load_max,
                 "moe_assignments_elsewhere_total":
                     self.moe_assignments_elsewhere_total,
+                "moe_zero_assignments_total": self.moe_zero_assignments_total,
                 "ssm_state_rows_total": self.ssm_state_rows_total,
                 "moe_expert_load_hist": [list(row) for row in
                                          self.moe_expert_load_hist],
@@ -794,6 +800,9 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_moe_assignments_elsewhere_total counter",
                 "llmlb_engine_moe_assignments_elsewhere_total "
                 f"{self.moe_assignments_elsewhere_total}",
+                "# TYPE llmlb_engine_moe_zero_assignments_total counter",
+                "llmlb_engine_moe_zero_assignments_total "
+                f"{self.moe_zero_assignments_total}",
                 "# TYPE llmlb_engine_ssm_state_rows_total counter",
                 f"llmlb_engine_ssm_state_rows_total {self.ssm_state_rows_total}",
                 "# TYPE llmlb_engine_moe_expert_load_max gauge",

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.llama import LlamaConfig
+from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
 from llmlb_tpu.models.mixtral import MixtralConfig
 from llmlb_tpu.models.nemotron_h import NemotronHConfig
 from llmlb_tpu.models.sdar_moe import SdarMoeConfig
@@ -65,6 +66,20 @@ PRESETS: dict[str, LlamaConfig] = {
         num_experts=4, first_expert=4, experts_per_token=2,
         moe_intermediate_size=32, shared_intermediate_size=48,
         routed_scaling_factor=2.5,
+    ),
+    # CI-sized shortcut-connected mixture (models/longcat_flash.py,
+    # docs/longcat-flash.md): two double layers, a low-rank query with both
+    # latents scaled, a router over 8 experts and 4 zero-compute ones that
+    # holds the second half (4 of 8) of the experts
+    "debug-longcat-tiny": LongcatFlashConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=2, num_heads=4, num_kv_heads=4, head_dim=8,
+        rope_theta=10000.0, rms_eps=1e-5, dtype=jnp.float32,
+        max_position_embeddings=512, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, q_lora_rank=16,
+        q_lora_scale=2.0, kv_lora_scale=2.0 ** 0.5, router_experts=8,
+        num_experts=4, first_expert=4, zero_experts=4, experts_per_token=3,
+        moe_intermediate_size=32, routed_scaling_factor=6.0,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

@@ -12,7 +12,9 @@ attention and layer stack to the same bodies, and SDAR-MoE-class
 diffusion over blocks) declares a block length the scheduler decodes by;
 Nemotron-H-class (nemotron_h.py: a stack of state-space, attention and
 expert layers, one mixer a layer) keeps a recurrent state per slot beside
-the page pool.
+the page pool; LongCat-Flash-class (longcat_flash.py: double layers whose
+mixture is a shortcut across two latent attentions, zero-compute experts)
+leaves a residual branch in one layer for a later one to add.
 
 `config_from_hf(hf, dtype)` picks the configuration class of a published
 `config.json` by its `model_type` and refuses a config that carries a key the
@@ -41,20 +43,23 @@ MODEL_TYPES = {
     "deepseek_v3": "deepseek_v3",
     "sdar_moe": "sdar_moe",
     "nemotron_h": "nemotron_h",
+    "longcat_flash": "longcat_flash",
 }
 _CONFIG_CLASSES = {"llama": "LlamaConfig", "mixtral": "MixtralConfig",
                    "deepseek_v3": "DeepseekV3Config",
                    "sdar_moe": "SdarMoeConfig",
-                   "nemotron_h": "NemotronHConfig"}
+                   "nemotron_h": "NemotronHConfig",
+                   "longcat_flash": "LongcatFlashConfig"}
 
 # Keys that change the function a model computes, and the classes that read
 # them. A config carrying one for a class that does not read it would be
 # served as another model without a word.
 _ABSENT = (None, False, 0, 1, [], {})
 _MECHANISM_KEYS = {
-    "kv_lora_rank": ("deepseek_v3",),
-    "q_lora_rank": (),
-    "n_routed_experts": ("deepseek_v3", "nemotron_h"),
+    "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
+    "q_lora_rank": ("longcat_flash",),
+    "zero_expert_num": ("longcat_flash",),
+    "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash"),
     "n_shared_experts": ("deepseek_v3", "nemotron_h"),
     "first_k_dense_replace": ("deepseek_v3",),
     "num_local_experts": ("mixtral",),
@@ -63,7 +68,7 @@ _MECHANISM_KEYS = {
     "hybrid_override_pattern": ("nemotron_h",),
     "mamba_num_heads": ("nemotron_h",),
     "ssm_state_size": ("nemotron_h",),
-    "expert_parallel": ("nemotron_h",),
+    "expert_parallel": ("nemotron_h", "longcat_flash"),
     "sliding_window": (),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
@@ -108,11 +113,14 @@ def family_for(cfg):
     from llmlb_tpu.models import (
         deepseek_v3,
         llama,
+        longcat_flash,
         mixtral,
         nemotron_h,
         sdar_moe,
     )
 
+    if isinstance(cfg, longcat_flash.LongcatFlashConfig):
+        return longcat_flash  # a DeepseekV3Config too: asked first
     if isinstance(cfg, deepseek_v3.DeepseekV3Config):
         return deepseek_v3
     if isinstance(cfg, nemotron_h.NemotronHConfig):

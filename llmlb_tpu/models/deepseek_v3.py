@@ -20,6 +20,10 @@ Same serving contract and the same three shared bodies as models/llama.py
   the ABSORBED form over the pool: q_abs = W^K_h q_nope attends the latent
   itself and W^V_h carries the mix back out, so a cached prefix is never
   up-projected. `wk_b` / `wv_b` are W_kv_b split per head once, at load.
+  The block also computes a low-rank query and scaled latents for a family
+  whose configuration has them (`q_lora_rank`, `*_lora_scale`); this
+  family's own `from_hf_config` still refuses `q_lora_rank`, because its
+  checkpoint loader (engine/weights.py) maps no `q_a_proj`.
 - The routed layer is ops/moe.py's, with DeepSeek-V3's rule
   (sigmoid_bias_routing) and the shared experts added outside the routing.
 
@@ -92,6 +96,13 @@ class DeepseekV3Config(LlamaConfig):
     routed_scaling_factor: float = 2.448
     norm_topk_prob: bool = True
     rope_interleave: bool = True
+    # The low-rank query (down, RMS norm, up) of a family that has one, and
+    # the factors by which its two latents are scaled behind their norms
+    # (`mla_scale_q_lora` / `mla_scale_kv_lora`: models/longcat_flash.py).
+    # None and 1: the block below is the program it always was.
+    q_lora_rank: int | None = None
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
 
     @property
     def num_moe_layers(self) -> int:
@@ -145,6 +156,23 @@ class DeepseekV3Config(LlamaConfig):
             norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
             rope_interleave=bool(hf.get("rope_interleave", True)),
         )
+
+
+def held_share(hf: dict) -> tuple[int, int, int]:
+    """(experts held here, experts the router scores, the first held) of a
+    config whose `expert_parallel` ({"chips", "chip", "experts"}) says that
+    this chip holds `n_routed_experts` of a deployment's `experts`, the
+    `chip`-th such share; the deployment's key, not a checkpoint's. Without
+    it every expert is held."""
+    held = hf["n_routed_experts"]
+    share = hf.get("expert_parallel") or {}
+    experts = int(share.get("experts", held))
+    chips, chip = int(share.get("chips", 1)), int(share.get("chip", 0))
+    if held * chips != experts or not 0 <= chip < chips:
+        raise ValueError(
+            f"expert_parallel {share} does not split {experts} experts "
+            f"into shares of n_routed_experts = {held}")
+    return held, experts, chip * held
 
 
 # ---------------------------------------------------------------------------
@@ -320,14 +348,34 @@ def _scale(cfg: DeepseekV3Config) -> float:
 def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
                attn_fn, lora_idx=None):
     """Pre-norm latent attention sub-block. Returns (x_out, c, k_rope): the
-    two values the token leaves in the pool."""
+    two values the token leaves in the pool.
+
+    With `cfg.q_lora_rank` the queries come through a latent of their own,
+    `W_qb RMSNorm(W_qa h)`. A latent's scale (`q_lora_scale`,
+    `kv_lora_scale`) multiplies its norm's weight, in float32 inside the
+    norm: what follows the norm is linear in it, so the scaled latent — and
+    for keys and values the latent the POOL keeps — is rounded once. The
+    rope key is not behind the norm and is not scaled."""
     b, t, _ = x.shape
     heads, c_dim = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+
+    def latent_norm(v, name, scale):
+        w = lp[name]
+        if scale != 1.0:
+            w = w.astype(jnp.float32) * scale
+        return rms_norm(v, w, cfg.rms_eps)
+
     h = rms_norm(x, lp["ln_attn"], cfg.rms_eps)
-    q = _proj(lp, "wq", h, lora_idx).reshape(b, t, heads, dn + dr)
+    if cfg.q_lora_rank:
+        q = _proj(lp, "wq_b", latent_norm(
+            _proj(lp, "wq_a", h, lora_idx), "ln_q", cfg.q_lora_scale),
+            lora_idx)
+    else:
+        q = _proj(lp, "wq", h, lora_idx)
+    q = q.reshape(b, t, heads, dn + dr)
     kv = _proj(lp, "wkv_a", h, lora_idx)  # [B, T, C + Dr]
-    c = rms_norm(kv[..., :c_dim], lp["ln_kv"], cfg.rms_eps)
+    c = latent_norm(kv[..., :c_dim], "ln_kv", cfg.kv_lora_scale)
     k_rope = apply_rope(kv[:, :, None, c_dim:], positions, inv_freq,
                         cfg.rope_interleave)[:, :, 0]  # one head for all
     k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, ROPE_CELL - dr)))

@@ -14,6 +14,12 @@ TPU-first design decisions:
   `verify_step_paged` (speculative verify) and `decode_step_paged` ([B] one
   token per row). All have fully static shapes; raggedness is carried by
   `prompt_lens` / `seq_lens` masks.
+- A model is a list of `LayerGroup`s handed to three shared bodies
+  (prefill, extend, decode). Two notions are defined there: a layer whose
+  halves may be absent or another mixer (a state per slot: `StatePool`),
+  and a DEFERRED BRANCH — a residual branch one layer computes and a later
+  layer adds, carried by the bodies between the two (`LayerGroup`'s
+  docstring; models/longcat_flash.py is its user).
 - Sharding is expressed once in `param_shardings` / `kv_pages_shardings` using
   logical axes (parallel/sharding.py) — Megatron-style tp over heads/ffn/vocab,
   dp over the batch axis (the pool replicates over dp).
@@ -25,6 +31,7 @@ BASELINE.json north star. HF-format checkpoints load via engine/weights.py.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Callable, NamedTuple
@@ -483,7 +490,20 @@ class LayerGroup(NamedTuple):
     `Attention` over the page pool; `mixer(lp, x, cache_k, cache_v, layer,
     rows: StateRows) -> (x + mix, cache_k, cache_v)` is another mix, with a
     state of its own in the pool (StatePool); `mlp_fn` None is a layer
-    without a feed-forward."""
+    without a feed-forward.
+
+    THE DEFERRED BRANCH. A residual branch may cross layers: `branch(lp, h,
+    token_valid, lora_idx) -> (value, aux)` is computed from the SAME normed
+    input `h` as the group's feed-forward and is NOT added there. The bodies
+    carry it — beside `x` and the pools in the scan's carry of prefill and
+    extend, in a variable of decode's unrolled loop — and the next layer
+    whose group says `joins` adds it to `x` after its own feed-forward, so
+    no mix or feed-forward in between has seen it (a shortcut-connected
+    mixture of experts: models/longcat_flash.py). The group's aux is then
+    the branch's. A model none of whose groups has a branch carries nothing
+    and compiles to the program it had. `scope`, where set, names the
+    group's layers in a device trace (`jax.named_scope`); the branch runs
+    under `deferred_branch` inside it."""
 
     names: tuple
     mlp_fn: Callable | None
@@ -500,6 +520,9 @@ class LayerGroup(NamedTuple):
     pool_layer: int | None = None
     attends: bool = True
     mixer: Callable | None = None
+    branch: Callable | None = None  # computed here, added by a later layer
+    joins: bool = False  # adds the branch an earlier layer left
+    scope: str = ""
 
 
 class StateRows(NamedTuple):
@@ -539,14 +562,27 @@ def _group_params(params: Params, group: LayerGroup) -> tuple[Params, Params]:
     return {n: w for n, w in named.items() if n not in whole}, whole
 
 
-def _feed_forward(cfg, group: LayerGroup, lp: Params, x, token_valid,
-                  lora_idx):
-    """x + the group's feed-forward of norm(x), and what it reported."""
+def _feed_forward(cfg, group: LayerGroup, lp: Params, x, deferred,
+                  token_valid, lora_idx):
+    """x + the group's feed-forward of norm(x), the deferred branch as the
+    layer leaves it (LayerGroup: computed here, or joined here, or passed
+    on), and what the layer reported."""
     if group.mlp_fn is None:
-        return x, None
+        return x, deferred, None
     h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
     out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
-    return x + out, aux
+    x = x + out
+    if group.branch is not None:
+        with jax.named_scope("deferred_branch"):
+            deferred, aux = group.branch(lp, h, token_valid, lora_idx)
+    elif group.joins:
+        x = x + deferred.astype(x.dtype)
+    return x, deferred, aux
+
+
+def _layer_scope(group: LayerGroup):
+    return (jax.named_scope(group.scope) if group.scope
+            else contextlib.nullcontext())
 
 
 def _mlp_out(res):
@@ -558,7 +594,9 @@ def _mlp_out(res):
 def _scan_groups(params, groups, x, cache_k, cache_v, layer_of):
     """Prefill's and extend's walk over the stack: each group a `lax.scan`
     over its own stacked parameters (one layer body traced and compiled a
-    group), with `(x, cache_k, cache_v)` as the CARRY. The pools are never
+    group), with `(x, cache_k, cache_v, deferred)` as the CARRY (`deferred`:
+    the branch a layer left for a later one, LayerGroup; nothing where no
+    group has a branch). The pools are never
     a scan's `xs` or `ys` and never sliced by layer: `ys` is a fresh stacked
     buffer, so a pool that went through it was copied whole and each layer
     of it sliced out and written back, on every call, whatever the prompt's
@@ -570,7 +608,8 @@ def _scan_groups(params, groups, x, cache_k, cache_v, layer_of):
     lp, layer) -> (carry, aux)`, `layer` the layer's index in the pool it
     writes (LayerGroup.pool_layer). Returns (x, cache_k, cache_v, aux per
     group)."""
-    carry, aux, at = (x, cache_k, cache_v), [], 0
+    deferred = (jnp.zeros_like(x) if any(g.branch for g in groups) else None)
+    carry, aux, at = (x, cache_k, cache_v, deferred), [], 0
     for group in groups:
         stacked, whole = _group_params(params, group)
         first, count = group.start, group.count
@@ -581,15 +620,16 @@ def _scan_groups(params, groups, x, cache_k, cache_v, layer_of):
             stacked["layer"] = first + own if first else own
         body = layer_of(group)
 
-        def layer(carry, layer_in, body=body, whole=whole):
+        def layer(carry, layer_in, body=body, whole=whole, group=group):
             lp, idx = layer_in
-            return body(carry, {**lp, **whole}, idx)
+            with _layer_scope(group):
+                return body(carry, {**lp, **whole}, idx)
 
         pool_first = at if group.pool_layer is None else group.pool_layer
         carry, group_aux = lax.scan(layer, carry, (stacked, pool_first + own))
         aux.append(group_aux)
         at += count
-    return (*carry, aux)
+    return (*carry[:3], aux)
 
 
 def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
@@ -623,7 +663,7 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
 
     def layer_of(group):
         def layer(carry, lp, layer_idx):
-            carry_x, ck, cv = carry
+            carry_x, ck, cv, deferred = carry
             if group.mixer is not None:
                 carry_x, ck, cv = group.mixer(lp, carry_x, ck, cv, layer_idx,
                                               rows)
@@ -635,9 +675,9 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
                 )
                 ck = _write_pool(ck, layer_idx, page, off, k)
                 cv = _write_pool(cv, layer_idx, page, off, v)
-            carry_x, aux = _feed_forward(cfg, group, lp, carry_x,
-                                         token_valid, lora_idx)
-            return (carry_x, ck, cv), aux
+            carry_x, deferred, aux = _feed_forward(
+                cfg, group, lp, carry_x, deferred, token_valid, lora_idx)
+            return (carry_x, ck, cv, deferred), aux
 
         return layer
 
@@ -729,7 +769,7 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
 
     def layer_of(group):
         def layer(carry, lp, layer_idx):
-            carry_x, ck, cv = carry
+            carry_x, ck, cv, deferred = carry
 
             def attn_fn(q, k, v):
                 nonlocal ck, cv  # pool write precedes attention over the pool
@@ -746,9 +786,9 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
             elif group.attends:
                 carry_x, _, _ = attention.block(
                     cfg, lp, carry_x, positions, inv_freq, attn_fn, lora_idx)
-            carry_x, aux = _feed_forward(cfg, group, lp, carry_x,
-                                         token_valid, lora_idx)
-            return (carry_x, ck, cv), aux
+            carry_x, deferred, aux = _feed_forward(
+                cfg, group, lp, carry_x, deferred, token_valid, lora_idx)
+            return (carry_x, ck, cv, deferred), aux
 
         return layer
 
@@ -871,7 +911,7 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     rows = StateRows(slot_ids, live=live)
 
     x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
-    aux, at = [], 0
+    aux, at, deferred = [], 0, None  # the branch a layer left (LayerGroup)
     for group in _groups_for(cfg, stacked_names, mlp_fn, groups):
         stacked, whole = _group_params(params, group)
         pool_first = at if group.pool_layer is None else group.pool_layer
@@ -891,13 +931,15 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                     block_tables, kv_lens, window=window, work=work,
                 )
 
-            if group.mixer is not None:
-                x, cache_k, cache_v = group.mixer(lp, x, cache_k, cache_v,
-                                                  layer_idx, rows)
-            elif group.attends:
-                x, _, _ = attention.block(cfg, lp, x, positions, inv_freq,
-                                          attn_fn, lora_idx)
-            x, layer_aux = _feed_forward(cfg, group, lp, x, None, lora_idx)
+            with _layer_scope(group):
+                if group.mixer is not None:
+                    x, cache_k, cache_v = group.mixer(
+                        lp, x, cache_k, cache_v, layer_idx, rows)
+                elif group.attends:
+                    x, _, _ = attention.block(cfg, lp, x, positions,
+                                              inv_freq, attn_fn, lora_idx)
+                x, deferred, layer_aux = _feed_forward(
+                    cfg, group, lp, x, deferred, None, lora_idx)
             group_aux.append(layer_aux)
         aux.append(group_aux)
         at += group.count

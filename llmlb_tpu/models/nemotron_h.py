@@ -59,6 +59,7 @@ from llmlb_tpu.models.deepseek_v3 import (  # noqa: F401 — family contract
     LOAD_BUCKETS,
     STEP_COUNTER_MAX,
     _extra as _routed_extra,
+    held_share,
 )
 from llmlb_tpu.models.llama import (
     GQA_ATTENTION,
@@ -162,14 +163,7 @@ class NemotronHConfig(LlamaConfig):
                 f"nemotron_h config key(s) {bad} = "
                 f"{[hf.get(k) for k in bad]} are not supported by "
                 "models/nemotron_h.py; refusing to serve wrong logits")
-        held = hf["n_routed_experts"]
-        share = hf.get("expert_parallel") or {}
-        experts = int(share.get("experts", held))
-        chips, chip = int(share.get("chips", 1)), int(share.get("chip", 0))
-        if held * chips != experts or not 0 <= chip < chips:
-            raise ValueError(
-                f"expert_parallel {share} does not split {experts} experts "
-                f"into shares of n_routed_experts = {held}")
+        held, experts, first = held_share(hf)
         return cls(
             vocab_size=hf["vocab_size"],
             hidden_size=hf["hidden_size"],
@@ -195,7 +189,7 @@ class NemotronHConfig(LlamaConfig):
             time_step_floor=float(hf.get("time_step_floor", 1e-4)),
             num_experts=held,
             router_experts=experts,
-            first_expert=chip * held,
+            first_expert=first,
             experts_per_token=hf["num_experts_per_tok"],
             moe_intermediate_size=hf["moe_intermediate_size"],
             shared_intermediate_size=hf.get(

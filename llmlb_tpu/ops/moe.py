@@ -9,7 +9,8 @@ One layer at every size (prefill, extend, verify, decode):
   (Mixtral: the k largest logits, a softmax over those k) and
   `sigmoid_bias_routing` (DeepSeek-V3 / Kanana `noaux_tc`: sigmoid scores,
   the choice by score plus a per-expert bias, the weights the UNBIASED
-  scores of the chosen, normalised and scaled).
+  scores of the chosen, normalised and scaled); `softmax_bias_routing` is
+  the same rule over a softmax of ALL the router's outputs (LongCat-Flash).
 - The S x k assignments are sorted by expert and the three SwiGLU products
   run as grouped matmuls over the experts. On an unpartitioned TPU that is
   ONE route for every family: the experts arrive stacked over the layers
@@ -33,6 +34,14 @@ One layer at every size (prefill, extend, verify, decode):
   the chips of such a deployment is ROADMAP work.
 - An expert is three matrices (SwiGLU: `w_gate`, `w_up`, `w_down`) or two
   (`w_gate` None: `act(x W_up) W_down`), through the same products.
+- An expert may be NO PRODUCT at all: a router that scores more outputs
+  than there are experts (`real=` of them are experts, the first ones) has
+  ZERO-COMPUTE experts behind them, and an assignment of one returns the
+  token itself times its weight. Such an assignment takes no row of a
+  group, belongs to no chip (it is computed where the token lives, so it
+  is never `elsewhere`) and costs one multiply-add of the token: it sorts
+  behind every group as padding does and `w x` is added outside the
+  products (`Routing.zero` counts them).
 
 The reference has no MoE anywhere (it is a gateway; SURVEY.md §2.4 "no EP").
 """
@@ -58,6 +67,9 @@ class Routing(NamedTuple):
     # [] int32 — assignments of valid tokens to experts this chip does not
     # hold (`held`); None where the layer holds them all
     elsewhere: jnp.ndarray | None = None
+    # [] int32 — assignments of valid tokens to zero-compute experts
+    # (`real`); None where every output of the router is an expert
+    zero: jnp.ndarray | None = None
 
 
 def top_k_routing(
@@ -69,6 +81,17 @@ def top_k_routing(
     # Mixtral normalizes softmax over the selected k (not over all experts).
     weights = jax.nn.softmax(gate_vals, axis=-1)
     return weights, gate_idx
+
+
+def _biased_choice(scores, bias, num_selected: int, scale: float,
+                   normalize: bool):
+    """The k largest of score + bias, weighed by their UNBIASED scores."""
+    biased = scores + bias.astype(jnp.float32)
+    _, idx = lax.top_k(biased, num_selected)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return picked * scale, idx, biased
 
 
 def sigmoid_bias_routing(
@@ -84,13 +107,25 @@ def sigmoid_bias_routing(
     UNBIASED scores of those k, normalised to sum 1 (`norm_topk_prob`) and
     scaled (`routed_scaling_factor`). Returns (weights [S, k], indices
     [S, k], biased scores [S, E])."""
-    scores = jax.nn.sigmoid(router_logits)
-    biased = scores + bias.astype(jnp.float32)
-    _, idx = lax.top_k(biased, num_selected)
-    picked = jnp.take_along_axis(scores, idx, axis=-1)
-    if normalize:
-        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
-    return picked * scale, idx, biased
+    return _biased_choice(jax.nn.sigmoid(router_logits), bias, num_selected,
+                          scale, normalize)
+
+
+def softmax_bias_routing(
+    router_logits: jnp.ndarray,  # [S, X] fp32 — every output of the router
+    bias: jnp.ndarray,  # [X] — e_score_correction_bias
+    num_selected: int,
+    *,
+    scale: float = 1.0,
+    normalize: bool = False,
+):
+    """LongCat-Flash's gate: scores are a softmax over ALL X outputs of the
+    router (its zero-compute experts among them), the k chosen are the
+    top-k of score + bias, the weights their UNBIASED scores, normalised
+    only if asked (`norm_topk_prob`, false as published) and scaled
+    (`routed_scaling_factor`). Returns as sigmoid_bias_routing."""
+    return _biased_choice(jax.nn.softmax(router_logits, axis=-1), bias,
+                          num_selected, scale, normalize)
 
 
 def _grouped_mm(rows: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
@@ -123,6 +158,7 @@ def moe_routed(
     w_up_scale: jnp.ndarray | None = None,  # [E, F]
     w_down_scale: jnp.ndarray | None = None,  # [E, M]
     held: tuple[int, int] | None = None,  # (first, count) of the w_*'s experts
+    real: int | None = None,  # of the router's E the first `real` are experts
     act: Callable = jax.nn.silu,
     up_transposed: bool = False,  # w_gate and w_up are [E, F, M]
 ) -> tuple[jnp.ndarray, Routing]:
@@ -135,6 +171,9 @@ def moe_routed(
     router's E: the route is taken over all E and the weights are those of
     all k chosen, an assignment of an expert outside the range adds nothing
     (it is the chip's that holds it) and `Routing.load` counts the held.
+    `real` says that only the router's first `real` outputs are experts
+    (those `held` divides): an assignment at or past it is a zero-compute
+    expert's and adds `weight x token`, here, whatever is held.
     `up_transposed`: `w_up` (and `w_gate`) arrive output-major, [E, F, M], as
     a checkpoint stores a Linear (pallas_moe.grouped_expert_matmul says when
     that is the layout to store).
@@ -159,16 +198,24 @@ def moe_routed(
     # assignment j of token t is flat row t*k + j; padding sorts behind
     # every expert (key E) and lies outside every group
     flat_e = chosen.reshape(s * k).astype(jnp.int32)
-    elsewhere = None
+    valid = None if token_valid is None else jnp.repeat(token_valid, k)
+
+    def count(which):
+        return jnp.sum(which if valid is None else which & valid,
+                       dtype=jnp.int32)
+
+    # a zero-compute expert's assignment: no row of any group
+    is_zero = None if real is None else flat_e >= real
+    zero = None if real is None else count(is_zero)
+    outside, elsewhere = is_zero, None
     if held is not None:  # an absent expert's assignment sorts as padding
         flat_e = flat_e - held[0]
-        absent = (flat_e < 0) | (flat_e >= e)
-        flat_e = jnp.where(absent, e, flat_e)
-        counted = absent if token_valid is None else (
-            absent & jnp.repeat(token_valid, k))
-        elsewhere = jnp.sum(counted, dtype=jnp.int32)
-    if token_valid is not None:
-        flat_e = jnp.where(jnp.repeat(token_valid, k), flat_e, e)
+        outside = (flat_e < 0) | (flat_e >= e)  # the zero-compute among them
+        elsewhere = count(outside if is_zero is None else outside & ~is_zero)
+    if outside is not None:
+        flat_e = jnp.where(outside, e, flat_e)
+    if valid is not None:
+        flat_e = jnp.where(valid, flat_e, e)
     order = jnp.argsort(flat_e, stable=True)
     row_expert = jnp.minimum(flat_e[order], e - 1)
     load = jnp.sum(flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :],
@@ -216,9 +263,12 @@ def moe_routed(
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(s * k, dtype=order.dtype))
     y = y[inverse].reshape(s, k, m)
-    if held is not None:  # rows behind every group are unspecified
-        y = jnp.where(absent.reshape(s, k, 1), 0.0, y)
+    if outside is not None:  # rows behind every group are unspecified
+        y = jnp.where(outside.reshape(s, k, 1), 0.0, y)
     out = jnp.sum(y * weights[..., None], axis=1)
+    if is_zero is not None:  # the identity experts: their weights x the token
+        out = out + x.astype(jnp.float32) * jnp.sum(
+            jnp.where(is_zero.reshape(s, k), weights, 0.0), axis=1)[:, None]
     if token_valid is not None:
         out = jnp.where(token_valid[:, None], out, 0.0)
-    return out.astype(x.dtype), Routing(chosen, scores, load, elsewhere)
+    return out.astype(x.dtype), Routing(chosen, scores, load, elsewhere, zero)

@@ -22,17 +22,20 @@ FAMILIES = {
     "deepseek_v3": family_for(get_preset("debug-mla-tiny")),
     "sdar_moe": family_for(get_preset("debug-sdar-tiny")),
     "nemotron_h": family_for(get_preset("debug-nemotron-h-tiny")),
+    "longcat_flash": family_for(get_preset("debug-longcat-tiny")),
 }
 PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "deepseek_v3": "debug-mla-tiny", "sdar_moe": "debug-sdar-tiny",
-           "nemotron_h": "debug-nemotron-h-tiny"}
+           "nemotron_h": "debug-nemotron-h-tiny",
+           "longcat_flash": "debug-longcat-tiny"}
 # Static switches a family may add BEHIND llama's parameters, keyword-only
 # in effect: the benchmark's check passes `routing` (models/deepseek_v3.py),
 # never positionally. `slot_ids`: the rows' slots, for a family that keeps
 # a state per slot beside the page pool (models/nemotron_h.py); the default
 # is row i in slot i, which serves the benchmark's check and its one row.
 EXTRA = {"deepseek_v3": ["routing"], "sdar_moe": ["routing"],
-         "nemotron_h": ["routing", "slot_ids"]}
+         "nemotron_h": ["routing", "slot_ids"],
+         "longcat_flash": ["routing"]}
 # What a family may add behind llama's parameters elsewhere: the slot count
 # of a pool with a state per slot (default 1); the scheduler knows such a
 # family by its `state_slot_bytes` (EngineCore._slot_state).

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import pytest
 
 from llmlb_tpu.engine.presets import get_preset
-from llmlb_tpu.models import deepseek_v3, llama, mixtral, sdar_moe
+from llmlb_tpu.models import deepseek_v3, llama, longcat_flash, mixtral, sdar_moe
 from llmlb_tpu.ops import pallas_attention
 
 LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM = 3, 7, 8, 2, 16
@@ -119,6 +119,15 @@ ENTRY_POINTS = ("prefill_into_pages", "prefill_extend_pages",
 SCANNED = [(name, quantized, route) for name in sorted(FAMILIES)
            for quantized in (False, True) for route in ("pallas", "xla")]
 SCANNED.append(("deepseek_v3", False, "xla"))
+# two double layers: a LayerGroup a sub-layer, four scans, and the branch a
+# sub-layer 0 leaves for its sub-layer 1 in their carry
+SCANNED.append(("longcat_flash", False, "xla"))
+# (family, configuration, scans, values a scan may carry beside the pools:
+# x, and in the scan of a sub-layer that LEAVES a branch the branch — the
+# sub-layer that joins it passes it on unchanged, a constant of its scan)
+LATENT = {"deepseek_v3": (deepseek_v3, LATENT_TINY, 2, {1}),
+          "longcat_flash": (longcat_flash, get_preset("debug-longcat-tiny"),
+                            4, {1, 2})}
 
 
 def _scanned_jaxpr(family, cfg, entry, quantized, route, monkeypatch):
@@ -163,8 +172,8 @@ def test_prefill_and_extend_carry_the_pool_through_the_layer_scan(
     they sit in) yields a pool, and an extend kernel is handed the pool
     whole. (At the parent of PR 32 each pool was a scan's `xs` and `ys`, and
     a stack of two groups was sliced per group and concatenated again.)"""
-    family, cfg = (FAMILIES[name] if name in FAMILIES
-                   else (deepseek_v3, LATENT_TINY))
+    family, cfg, groups, carried = (LATENT[name] if name in LATENT
+                                    else (*FAMILIES[name], 1, {1}))
     jaxpr, pools = _scanned_jaxpr(family, cfg, entry, quantized, route,
                                   monkeypatch)
     stacked = {leaf.shape for leaf in jax.tree.leaves(pools)}
@@ -182,8 +191,11 @@ def test_prefill_and_extend_carry_the_pool_through_the_layer_scan(
 
     eqns = list(_equations(jaxpr))
     scans = [eqn for eqn in eqns if eqn.primitive.name == "scan"]
-    groups = 2 if name == "deepseek_v3" else 1
     assert len(scans) == groups
+    # x and the pools, and only where a group has a deferred branch
+    # (llama.LayerGroup) the value it leaves for a later layer
+    assert {eqn.params["num_carry"] - len(jax.tree.leaves(pools))
+            for eqn in scans} == carried
     for eqn in scans:
         consts, carry = eqn.params["num_consts"], eqn.params["num_carry"]
         through = (shapes(eqn.invars[consts + carry:])
@@ -607,6 +619,84 @@ def test_compiled_hybrid_burst_updates_the_state_in_place_and_copies_no_experts(
     # the temporaries are the step's logits and activations, not the state
     state = 2 * CHIP_ROWS * 64 * 64 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < state / 2
+
+
+# --- a shortcut-connected mixture's decode burst -------------------------------
+
+def test_compiled_shortcut_burst_runs_its_kernels_and_copies_no_experts(
+        one_chip, monkeypatch):
+    """Two decode steps of double layers with a shortcut-connected mixture
+    (longcat-flash-omni-l4's widths, two layers = four attention sub-layers,
+    16 of 512 experts held behind a router of 768 outputs, the benchmark
+    cell's 544 pages and 32 rows) under a scan, compiled for a v5e: per step
+    one latent attention kernel a SUB-layer at 64 heads and three grouped
+    products a layer; the compiler materializes no layer of either pool and
+    no layer's experts, and copies no pool whole."""
+    from llmlb_tpu.ops import pallas_moe
+
+    cfg = longcat_flash.LongcatFlashConfig(
+        vocab_size=16384, hidden_size=6144, intermediate_size=12288,
+        num_layers=2, num_heads=64, num_kv_heads=64, head_dim=64,
+        rope_theta=1e7, rms_eps=1e-5, max_position_embeddings=131072,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, q_lora_rank=1536, q_lora_scale=2.0,
+        kv_lora_scale=12 ** 0.5, num_experts=16, router_experts=512,
+        zero_experts=256, experts_per_token=12, moe_intermediate_size=2048,
+        routed_scaling_factor=6.0)
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
+    jitted = (longcat_flash.decode_step_paged,
+              pallas_attention.paged_latent_decode,
+              pallas_moe.grouped_expert_matmul)
+    for fn in jitted:
+        fn._clear_cache()
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda key: longcat_flash.init_params(cfg, key),
+        jax.random.PRNGKey(0)))
+    cache_k, cache_v = on_chip(jax.eval_shape(
+        lambda: longcat_flash.init_kv_pages(cfg, 544, CHIP_PAGE_SIZE)))
+    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
+    live = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.bool_))
+    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
+
+    def burst(params, last, lens, cache_k, cache_v, tables, live):
+        def body(carry, _):
+            last, lens, ck, cv = carry
+            logits, ck, cv, counters = longcat_flash.decode_step_paged(
+                params, cfg, last, lens, ck, cv, tables, window=1024,
+                live=live)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    ck, cv), counters
+
+        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
+                            length=2)
+
+    try:
+        with jax.default_matmul_precision("default"):
+            hlo = jax.jit(burst, donate_argnums=(3, 4)).lower(
+                params, rows, rows, cache_k, cache_v, tables, live
+            ).compile().as_text()
+    finally:
+        for fn in jitted:
+            fn._clear_cache()
+    # four attention sub-layers' kernels and two mixtures' three products
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4 + 2 * 3
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    a_layer = r"bf16\[544,128,(512|128)\]"  # of either pool
+    a_layers_experts = r"bf16\[16,(6144,2048|2048,6144)\]"
+    whole = (r"bf16\[4,544,128,(512|128)\]",
+             r"bf16\[2,16,(6144,2048|2048,6144)\]")
+    moved = ("copy", "copy-start", "copy-done", "transpose")
+    bad = [(shape, op) for shape, op in results
+           if re.match(a_layer, shape) or re.match(a_layers_experts, shape)
+           or (op in moved and any(re.match(p, shape) for p in whole))]
+    assert not bad, bad
+    # the branch is named in the trace, and so is each sub-layer
+    for scope in ("sublayer0", "sublayer1", "deferred_branch"):
+        assert scope in hlo, scope
 
 
 def test_compiled_sampler_sorts_no_vocabulary(one_chip):
