@@ -45,9 +45,10 @@ PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 # A block family's counts of one burst of block passes, as its `decode` step
 # records carry them and `/api/health .metrics` totals them (`<name>_total`):
 # passes of the burst; decoding rows summed over the passes; blocks and
-# positions committed; positions unmasked.
+# positions committed; positions unmasked; commits whose pass also ran the
+# next block's first unmasking (the others are the last blocks of requests).
 BLOCK_COUNTS = ("block_passes", "row_passes", "blocks_committed",
-                "tokens_committed", "positions_unmasked")
+                "tokens_committed", "positions_unmasked", "blocks_fused")
 
 # Why a dense decode burst did not leave before its predecessor was emitted
 # (scheduler._ahead_blocker; docs/scheduling.md), a closed set: a request

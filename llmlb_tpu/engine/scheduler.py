@@ -4709,31 +4709,41 @@ class EngineCore:
 
     def _block_reach(self) -> int:
         """Positions past its committed length that a row may write in one
-        burst: a block commits at most every second pass (one to unmask,
-        one to commit), and the open block lies behind the last commit."""
-        return self.block * (-(-self.decode_burst // 2) + 1)
+        burst: a block may commit in every pass (its commit rides with the
+        next block's first unmasking, which at `denoising_steps` 1 completes
+        it), and a pass writes two blocks behind the committed length — the
+        complete one and the open one of a row that commits, the open one
+        and a block of padding of a row that does not."""
+        return self.block * (self.decode_burst + 1)
 
     def _build_block_many(self, k: int, window: int) -> Callable:
         """Jit a burst of k BLOCK PASSES (the decode program of a family
         that generates by diffusion over blocks; traced as `many`, like
         _build_decode_many's). Per row the scan carries the open block's ids
         and mask flags, the committed length, the positions left and the
-        given tokens at the block's head. One pass: the family's block pass
-        (verify_step_paged: B ids behind the committed cache, logits at
-        every position, the block's K and V written past the committed
-        length) -> each position's sampled id and its probability -> a block
-        that ENTERED the pass with no mask is committed (length += B, a new
-        block of masks opens, its ids go out), else the masked positions
-        unmask by the row's strategy: the `per_pass` most probable, and
-        under `dynamic` every one above `threshold`. Rows are at different
-        passes of their blocks. A row whose positions are used up, or whose
-        committed block holds EOS past its given tokens, stops: it runs no
-        further pass and writes to the slot's last cell alone, as the rows
-        that are not decoding do. Returns the state, the caches, and ONE
-        int32 array for the burst's one fetch: per pass B + 2 rows of
-        [SLOTS] — the committed block's ids (-1: no commit), the positions
-        unmasked, whether the row ran — and the family's step counters
-        behind them (_pack_step_counters)."""
+        given tokens at the block's head. One pass is ONE call of the
+        family's block pass (verify_step_paged) 2B positions wide. A row
+        whose open block ENTERED the pass with no mask commits it, and where
+        it goes on (no EOS past its given tokens in the block, positions
+        left behind it) the commit rides with the next block's first
+        unmasking: the row sends [its complete block | B masks], the layers
+        write the block's K and V to the pool before the masks attend to
+        them under the block mask, length += B, and the logits wanted are
+        the second half's. Every other row sends [its open block | padding]
+        (the padding goes to no expert and writes past the row's valid
+        range) and wants the first half's. Then each wanted position's
+        sampled id and its probability, and the masked positions of the
+        open block unmask by the row's strategy: the `per_pass` most
+        probable, and under `dynamic` every one above `threshold`. Rows are
+        at different passes of their blocks. A row whose positions are used
+        up, or whose committed block holds EOS past its given tokens, stops
+        with that commit: it runs no further pass and writes to the slot's
+        last cell alone, as the rows that are not decoding do. Returns the
+        state, the caches, and ONE int32 array for the burst's one fetch:
+        per pass B + 2 rows of [SLOTS] — the committed block's ids (-1: no
+        commit), the positions unmasked (of the block behind a commit, in a
+        pass that did both), whether the row ran — and the family's step
+        counters behind them (_pack_step_counters)."""
         family, cfg, mesh = self.family, self.cfg, self.mesh
         shapes, max_names = self._counter_shapes, self._counter_max
         b, mask_id, eos = self.block, cfg.mask_token_id, self.eos_id
@@ -4748,14 +4758,26 @@ class EngineCore:
             def body(carry, step_key):
                 blk, masked, lens, left, skip, ck, cv = carry
                 run = live & (left > 0)
+                commit = run & ~jnp.any(masked, axis=1)
+                stop = commit & ((left <= b) | jnp.any(
+                    (blk == eos) & (offs[None, :] >= skip[:, None]), axis=1))
+                fused = commit & ~stop  # the next block opens in this pass
+                out_blk = jnp.where(commit[:, None], blk, -1)
                 logits, ck, cv, *stats = family.verify_step_paged(
-                    params, cfg, blk, jnp.where(run, b, 0),
+                    params, cfg,
+                    jnp.concatenate([blk, jnp.full_like(blk, mask_id)], axis=1),
+                    jnp.where(run, jnp.where(fused, 2 * b, b), 0),
                     jnp.where(run, lens, park), tables, ck, cv, mesh,
-                    window=window)
-                n_masked = jnp.sum(masked, axis=1, dtype=jnp.int32)
-                commit = run & (n_masked == 0)
-                ids, conf = _sample_block(logits, step_key, temps, top_ps,
-                                          top_ks, seeds, lens, n_masked)
+                    window=window, logits_from=jnp.where(fused, b, 0),
+                    logits_len=b)
+                # from here on the open block is the one the logits are of
+                # (a row that stops opens none: nothing of it is masked)
+                blk = jnp.where(fused[:, None], mask_id, blk)
+                masked = masked | fused[:, None]
+                lens = jnp.where(commit, lens + b, lens)
+                ids, conf = _sample_block(
+                    logits, step_key, temps, top_ps, top_ks, seeds, lens,
+                    jnp.sum(masked, axis=1, dtype=jnp.int32))
                 conf = jnp.where(masked, conf, -1.0)
                 # rank among the block's masked positions, the more
                 # probable first and of equals the earlier: [S, i, j] is
@@ -4767,16 +4789,12 @@ class EngineCore:
                 pick = ((rank < per_pass[:, None])
                         | (dynamic[:, None] & (conf > threshold[:, None])))
                 pick = pick & masked & run[:, None]
-                stop = commit & jnp.any(
-                    (blk == eos) & (offs[None, :] >= skip[:, None]), axis=1)
                 out = jnp.concatenate([
-                    jnp.where(commit[:, None], blk, -1),
+                    out_blk,
                     jnp.sum(pick, axis=1, dtype=jnp.int32)[:, None],
                     run[:, None].astype(jnp.int32)], axis=1)  # [S, B + 2]
-                blk = jnp.where(commit[:, None], mask_id,
-                                jnp.where(pick, ids, blk))
-                masked = jnp.where(commit[:, None], True, masked & ~pick)
-                lens = jnp.where(commit, lens + b, lens)
+                blk = jnp.where(pick, ids, blk)
+                masked = masked & ~pick
                 left = jnp.where(stop, 0, jnp.where(commit, left - b, left))
                 skip = jnp.where(commit, 0, skip)
                 return (blk, masked, lens, left, skip, ck, cv), (out.T, stats)
@@ -4852,12 +4870,15 @@ class EngineCore:
         in order, less the given tokens at the head of its first block, as
         ONE event of several tokens; a request that ends inside a block
         (max_tokens, EOS, cancel) takes the tokens before its end. Returns
-        the burst's counts for its step record."""
+        the burst's counts for its step record; `blocks_fused` are the
+        commits whose pass unmasked positions too, the next block's."""
         b = self.block
         commits = {i: int((out[:, 0, i] >= 0).sum()) for i in active}
         counts = {"block_passes": int(out.shape[0]),
                   "row_passes": int(out[:, b + 1][:, active].sum()),
                   "positions_unmasked": int(out[:, b][:, active].sum()),
+                  "blocks_fused": int(((out[:, 0] >= 0) & (out[:, b] > 0))
+                                      [:, active].sum()),
                   "blocks_committed": 0, "tokens_committed": 0}
         committed: dict[int, tuple] = {}  # row -> (blocks, tokens, request)
         for t in range(out.shape[0]):

@@ -727,7 +727,8 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
                                block_tables, cache_k, cache_v, *,
                                stacked_names=None, mlp_fn=_default_mlp_fn,
                                all_logits=False, window=None, lora_idx=None,
-                               groups=None, attention=None, slot_ids=None):
+                               groups=None, attention=None, slot_ids=None,
+                               logits_from=None, logits_len=None):
     """Shared chunked-prefill body: process a [B, T] chunk of prompt tokens
     whose rows already hold `start_pos` tokens of KV. The chunk's KV scatters
     through the block table into the page pool, in place (_scan_groups), and
@@ -743,7 +744,11 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
     when the sequence grows into them. `window` (static) bounds the
     attention sweep to whole pages covering it, same contract as decode.
     `groups`, `attention`, `slot_ids` and the fourth value returned: as
-    _prefill_impl."""
+    _prefill_impl. `logits_from` ([B] int32, with `all_logits`) narrows the
+    logits to `logits_len` (static) positions a row from the row's own
+    offset into the chunk, [B, logits_len, V]: a block family's pass sends
+    two blocks and wants one's logits (scheduler._build_block_many). The
+    default, None, traces what it always did."""
     _, t = input_ids.shape
     ps = kv_pool_values(cache_k).shape[2]
     ppn = block_tables.shape[1]
@@ -798,6 +803,10 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
 
     if all_logits:
         b = x.shape[0]
+        if logits_from is not None:
+            t = logits_len
+            at = logits_from[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+            x = jnp.take_along_axis(x, at[:, :, None], axis=1)  # [B, t, E]
         logits = _unembed(cfg, params, x.reshape(b * t, -1)).reshape(b, t, -1)
         return logits, cache_k, cache_v, aux
     last = jnp.maximum(chunk_lens - 1, 0)

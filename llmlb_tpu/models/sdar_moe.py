@@ -295,21 +295,26 @@ def prefill_extend_pages(params, cfg: SdarMoeConfig, input_ids, chunk_lens,
             *_extra(cfg, aux, input_ids.shape, routing))
 
 
-@partial(jax.jit, static_argnames=_STATIC + ("window",),
+@partial(jax.jit, static_argnames=_STATIC + ("window", "logits_len"),
          donate_argnames=("cache_k", "cache_v"))
 def verify_step_paged(params, cfg: SdarMoeConfig, input_ids, chunk_lens,
                       start_pos, block_tables, cache_k, cache_v,
                       mesh: Mesh | None = None, window: int | None = None,
-                      lora_idx=None, routing: bool = False):
-    """The BLOCK PASS: T = block_length ids a row behind `start_pos`
-    committed tokens, logits at every position ([B, T, V] fp32) under the
-    block mask, the chunk's K and V written past the committed length. A
-    row with `chunk_lens` 0 is not decoding: its tokens go to no expert
-    and its logits are to be discarded."""
+                      lora_idx=None, routing: bool = False,
+                      logits_from=None, logits_len: int | None = None):
+    """The BLOCK PASS: T ids a row (whole blocks: one, or a complete block
+    and the block of masks behind it) after `start_pos` committed tokens,
+    logits at every position ([B, T, V] fp32) under the block mask, the
+    chunk's K and V written past the committed length. Positions at and
+    past a row's `chunk_lens` are padding: they go to no expert and their
+    logits are to be discarded; a row with `chunk_lens` 0 is not decoding.
+    `logits_from` [B]: logits at `logits_len` positions a row from that
+    offset alone, [B, logits_len, V] (llama._prefill_extend_paged_impl)."""
     logits, cache_k, cache_v, aux = _prefill_extend_paged_impl(
         params, cfg, input_ids, chunk_lens, start_pos, block_tables,
         cache_k, cache_v, all_logits=True, window=window, lora_idx=lora_idx,
-        groups=_groups(cfg), attention=_attention(cfg))
+        groups=_groups(cfg), attention=_attention(cfg),
+        logits_from=logits_from, logits_len=logits_len)
     return (logits, cache_k, cache_v,
             *_extra(cfg, aux, input_ids.shape, routing))
 

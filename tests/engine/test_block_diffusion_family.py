@@ -274,6 +274,72 @@ def test_a_block_pass_with_masks_agrees_at_every_position(model, masked):
         rtol=0, atol=1e-5)
 
 
+@pytest.mark.parametrize("route,idle", [("xla", 1), ("pallas", 2)])
+def test_a_pass_two_blocks_wide_agrees_with_two_passes_of_one(
+        model, monkeypatch, route, idle):
+    """The scheduler's pass (scheduler._build_block_many): T = 2B, and in
+    one batch a row that commits and goes on ([its complete block | B
+    masks], chunk length 2B, logits wanted from B), a row that unmasks
+    ([its open block | padding], chunk length B, logits wanted from 0) and
+    rows that are not decoding (chunk length 0). At every position wanted
+    it gives what two calls at T = B give — the commit, then the new
+    block's first pass — the committed block's K and V are the same in the
+    pool, and a padding position reaches no expert. (Another batch a route:
+    the route is read while tracing.)"""
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", route)
+    cfg, params = model
+    n = 2 + idle
+    ids, other = _ids(24, seed=3), _ids(24, seed=4)
+    masks = np.full(B, MASK, np.int32)
+    opened = other[20:24].copy()
+    opened[[0, 3]] = MASK
+    junk = _ids(B, seed=9)
+
+    def lens(*live):
+        return jnp.asarray([*live] + [0] * idle, np.int32)
+
+    def start(*live):
+        return jnp.asarray([*live] + [63] * idle, np.int32)
+
+    def prefilled():
+        ck, cv, tables = _pool(cfg, n, 4)
+        rows = np.stack([ids[:20], other[:20]] + [other[:20]] * idle)
+        _, ck, cv, *_ = sdar_moe.prefill_into_pages(
+            params, cfg, jnp.asarray(np.pad(rows, ((0, 0), (0, 12)))),
+            jnp.full((n,), 20, np.int32), tables, ck, cv, None)
+        return ck, cv, tables
+
+    def stack(*rows):
+        return jnp.asarray(np.stack([*rows] + [rows[-1]] * idle))
+
+    ck, cv, tables = prefilled()
+    first, ck, cv, *_ = sdar_moe.verify_step_paged(
+        params, cfg, stack(ids[20:24], opened), lens(B, B), start(20, 20),
+        tables, ck, cv, None)
+    second, ck, cv, *_ = sdar_moe.verify_step_paged(
+        params, cfg, stack(masks, junk), lens(B, 0), start(24, 63), tables,
+        ck, cv, None)
+    ck2, cv2, tables = prefilled()
+    wide, ck2, cv2, counters = sdar_moe.verify_step_paged(
+        params, cfg, stack(np.concatenate([ids[20:24], masks]),
+                           np.concatenate([opened, junk])),
+        lens(2 * B, B), start(20, 20), tables, ck2, cv2, None,
+        logits_from=jnp.asarray([B, 0] + [0] * idle, np.int32), logits_len=B)
+    assert wide.shape == (n, B, cfg.vocab_size)
+    np.testing.assert_allclose(wide[0], second[0], rtol=0, atol=1e-5)
+    np.testing.assert_allclose(wide[1], first[1], rtol=0, atol=1e-5)
+    want = np.asarray(ref.forward(
+        params, HF, np.concatenate([ids[:24], masks]))[0])[24:]
+    np.testing.assert_allclose(wide[0], want, rtol=0, atol=1e-5)
+    # the committing row's cells: the complete block and the open one
+    for a, b in ((ck, ck2), (cv, cv2)):
+        a, b = (np.asarray(pool)[:, np.asarray(tables[0])].reshape(
+            cfg.num_layers, -1, *pool.shape[3:])[:, 20:28] for pool in (a, b))
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
+    assert int(counters["expert_assignments"]) == (
+        cfg.num_layers * (2 * B + B) * cfg.experts_per_token)
+
+
 def test_the_routing_report_and_the_counters(model):
     cfg, params = model
     ids = _ids(16, seed=6)

@@ -220,16 +220,30 @@ def test_a_cancelled_request_frees_its_slot(core):
     assert len(again) == 5 and again[:len(got)] == got[:5]
 
 
-def test_the_step_records_the_totals_and_the_timeline_carry_the_counts(core):
-    time.sleep(0.1)  # the engine idle: every record from here on is this one's
-    before = core.metrics.summary()
-    seq = max(r["seq"] for r in core.step_stats.snapshot(limit=1)["records"])
-    prompt = _prompt(16, 100)  # whole blocks: no given tokens
-    request = _submit(core, prompt, 8)
-    _collect(request)
+def _decode_records(core, run):
+    """What `run()` returns, and the decode records of an idle engine's
+    steps while it ran."""
+    time.sleep(0.1)  # the engine idle: every record from here on is run's
+    seq = max((r["seq"] for r in
+               core.step_stats.snapshot(limit=1)["records"]), default=-1)
+    result = run()
     time.sleep(0.1)
-    recs = [r for r in core.step_stats.snapshot(limit=64)["records"]
-            if r["kind"] == "decode" and r["seq"] > seq]
+    return result, [r for r in core.step_stats.snapshot(limit=256)["records"]
+                    if r["kind"] == "decode" and r["seq"] > seq]
+
+
+def test_the_step_records_the_totals_and_the_timeline_carry_the_counts(core):
+    time.sleep(0.1)  # the engine idle: the totals stand still
+    before = core.metrics.summary()
+    prompt = _prompt(16, 100)  # whole blocks: no given tokens
+    sent = []
+
+    def run():
+        sent.append(_submit(core, prompt, 8))
+        return _collect(sent[0])
+
+    _, recs = _decode_records(core, run)
+    request = sent[0]
     assert recs
     for r in recs:
         assert r["block_passes"] == core.decode_burst == 3
@@ -239,18 +253,87 @@ def test_the_step_records_the_totals_and_the_timeline_carry_the_counts(core):
                 "kv_pages_live", "kv_pages_window"} <= set(r)
     assert sum(r["blocks_committed"] for r in recs) == 2
     assert sum(r["positions_unmasked"] for r in recs) == 8
+    # a commit rides with the next block's first unmasking: a row-pass for
+    # every pass of the reference that held a mask, and one for the
+    # request's last block, which commits alone
     passes = []
     ref.generate(PARAMS, HF, prompt, 8, passes=passes)
-    assert sum(r["row_passes"] for r in recs) == len(passes)
+    assert sum(r["row_passes"] for r in recs) == sum(
+        1 for _, before_, _ in passes if before_ > 0) + 1 == 9
+    assert sum(r["blocks_fused"] for r in recs) == 2 - 1
     after = core.metrics.summary()
     for name in ("block_passes", "row_passes", "blocks_committed",
-                 "tokens_committed", "positions_unmasked"):
+                 "tokens_committed", "positions_unmasked", "blocks_fused"):
         assert (after[f"{name}_total"] - before[f"{name}_total"]
                 == sum(r[name] for r in recs))
     events = core.flightrec.timeline(request.request_id)["events"]
     commits = [e["attrs"] for e in events if e["event"] == "commit"]
     assert sum(c["blocks"] for c in commits) == 2
     assert sum(c["tokens"] for c in commits) == 8
+
+
+@pytest.mark.parametrize("steps,row_passes", [(4, 33), (1, 9)])
+def test_a_block_costs_its_unmasking_passes_and_the_last_one_a_commit(
+        core, steps, row_passes):
+    """Eight blocks behind a whole-block prompt, `low_confidence_static`:
+    `denoising_steps` calls a block and one more for the request's last
+    commit — 33/32 and 9/32 calls a position where a commit of its own a
+    block made it 40/32 and 16/32."""
+    prompt = _prompt(16, 120 + steps)
+    kw = dict(denoising_steps=steps,
+              remasking_strategy="low_confidence_static")
+    (got, reason, _), recs = _decode_records(
+        core, lambda: _collect(_submit(core, prompt, 8 * B, **kw)))
+    assert reason == "length"
+    assert got == ref.generate(PARAMS, HF, prompt, 8 * B, **kw)
+    assert sum(r["tokens_committed"] for r in recs) == 8 * B
+    assert sum(r["row_passes"] for r in recs) == row_passes
+    assert sum(r["blocks_committed"] for r in recs) == 8
+    assert sum(r["blocks_fused"] for r in recs) == 7
+
+
+def test_rows_commit_in_the_pass_that_others_unmask_in_and_two_stop_there():
+    """Bursts of four passes over four rows at once: a row that completes a
+    block a pass (`denoising_steps` 1) commits in every pass — four blocks a
+    burst and the open one behind them, which from a committed length of 32
+    reaches the fourth page of 16 (_block_reach) — while its neighbours
+    unmask one and two positions a pass; one row's block holds EOS when it commits, one's
+    commit uses up its `max_tokens` (a multiple of the block length, and
+    not), and neither opens a block behind it."""
+    prompts = [_prompt(n, 130 + i) for i, n in enumerate((20, 18, 21, 12))]
+    cases = [(12 * B, dict(denoising_steps=1)), (6 * B - 2, {}),
+             (4 * B, dict(denoising_steps=2)), (6 * B, {})]
+    free = ref.generate(PARAMS, HF, prompts[3], 6 * B)
+    eos = free[2 * B + 1]  # inside the last row's third block
+    core = _core(eos_id=eos, decode_burst=4)
+    try:
+        def run():
+            sent = [_submit(core, prompt, n, **kw)
+                    for prompt, (n, kw) in zip(prompts, cases)]
+            return [_collect(request) for request in sent]
+
+        results, recs = _decode_records(core, run)
+        reasons = []
+        for prompt, (n, kw), (got, reason, _) in zip(prompts, cases, results):
+            assert got == ref.generate(PARAMS, HF, prompt, n, eos_id=eos,
+                                       **kw), (len(prompt), n, kw)
+            reasons.append(reason)
+        assert reasons[3] == "stop" and "length" in reasons
+        assert len(results[3][0]) == free.index(eos) < len(free)
+        # every commit but a request's last opened the next block
+        blocks = sum(r["blocks_committed"] for r in recs)
+        assert sum(r["blocks_fused"] for r in recs) == blocks - len(cases)
+        # a burst in which the first row committed in all four passes
+        assert max(r["blocks_committed"] for r in recs) >= 4
+        # no pass ran for a row behind its last commit
+        passes = [[] for _ in cases]
+        for prompt, (n, kw), held in zip(prompts, cases, passes):
+            ref.generate(PARAMS, HF, prompt, n, eos_id=eos, passes=held, **kw)
+        assert sum(r["row_passes"] for r in recs) == sum(
+            sum(1 for _, before, _ in held if before > 0) + 1
+            for held in passes)
+    finally:
+        core.stop()
 
 
 @pytest.mark.parametrize("sampling,message", [

@@ -38,8 +38,12 @@ EXTRA = {"deepseek_v3": ["routing"], "sdar_moe": ["routing"],
          "longcat_flash": ["routing"]}
 # What a family may add behind llama's parameters elsewhere: the slot count
 # of a pool with a state per slot (default 1); the scheduler knows such a
-# family by its `state_slot_bytes` (EngineCore._slot_state).
-EXTRA_OF = {("nemotron_h", "init_kv_pages"): ["num_slots"]}
+# family by its `state_slot_bytes` (EngineCore._slot_state). And behind the
+# switches above, on a block family's pass alone: one block's logits a row
+# from the row's own offset into a chunk of two (scheduler._build_block_many;
+# the default is every position's, which the benchmark's check takes).
+EXTRA_OF = {("nemotron_h", "init_kv_pages"): ["num_slots"],
+            ("sdar_moe", "verify_step_paged"): ["logits_from", "logits_len"]}
 
 CONTRACT = (
     "init_params",
@@ -91,8 +95,8 @@ def test_family_provides_the_paged_contract(family, name):
     got = _params(getattr(module, name))
     paged = name in ("prefill_into_pages", "prefill_extend_pages",
                      "verify_step_paged", "decode_step_paged")
-    extra = (EXTRA.get(family, []) if paged
-             else EXTRA_OF.get((family, name), []))
+    extra = ((EXTRA.get(family, []) if paged else [])
+             + EXTRA_OF.get((family, name), []))
     assert got[:len(want)] == want and [n for n, _ in got[len(want):]] == extra, (
         f"{family}.{name} takes other parameters than llama.{name}"
     )
@@ -121,4 +125,4 @@ def test_an_added_parameter_has_a_default_that_serves_one_row():
             assert all(params[n].default in (False, None) for n in names)
     for (family, fn), names in EXTRA_OF.items():
         params = inspect.signature(getattr(FAMILIES[family], fn)).parameters
-        assert [params[n].default for n in names] == [1]
+        assert all(params[n].default in (None, 1) for n in names)

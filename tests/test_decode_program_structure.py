@@ -235,6 +235,60 @@ def test_prefill_and_extend_carry_the_pool_through_the_layer_scan(
             v.shape for v in jax.tree.leaves(pools) if v.shape in values)
 
 
+# --- the logits' offset (a block family's pass) at its default ----------------
+
+OFFSET_FAMILIES = {**FAMILIES,
+                   **{name: case[:2] for name, case in LATENT.items()}}
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS[1:])
+@pytest.mark.parametrize("name", sorted(OFFSET_FAMILIES))
+def test_the_logits_offset_at_its_default_lowers_the_program_that_was(
+        name, entry, monkeypatch):
+    """llama._prefill_extend_paged_impl's `logits_from` / `logits_len` (a
+    block family's pass sends two blocks and wants one's logits) are named
+    by no other family, and named at their defaults they lower an extend
+    and a verify program to the text they lower to unnamed; set, they lower
+    another program."""
+    family, cfg = OFFSET_FAMILIES[name]
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "xla")  # read while tracing
+    body = llama._prefill_extend_paged_impl
+    fn = getattr(family, entry)
+    params = jax.eval_shape(lambda key: family.init_params(cfg, key),
+                            jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: family.init_kv_pages(cfg, PAGES, PAGE_SIZE))
+    ids = jax.ShapeDtypeStruct((ROWS, CHUNK), jnp.int32)
+    rows = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((ROWS, PAGES_PER_ROW), jnp.int32)
+    kw = {"window": CHUNK} if entry == "verify_step_paged" else {}
+
+    def lowered(**named):
+        seen = []
+
+        def spy(*args, **kwargs):
+            seen.append(kwargs)
+            return body(*args, **kwargs, **named)
+
+        monkeypatch.setattr(family, "_prefill_extend_paged_impl", spy)
+        # the entry's own function under a jit of this call's: no trace of
+        # an earlier one is found again
+        return jax.jit(
+            lambda p, i, r, t, ck, cv: fn.__wrapped__(
+                p, cfg, i, r, r, t, ck, cv, **kw)
+        ).lower(params, ids, rows, tables, *pools).as_text(), seen
+
+    plain, seen = lowered()
+    assert seen and not [k for kwargs in seen for k in kwargs
+                         if k.startswith("logits_")]
+    assert lowered(logits_from=None, logits_len=None)[0] == plain
+    if entry == "verify_step_paged":
+        half = lowered(logits_from=jnp.zeros((ROWS,), jnp.int32),
+                       logits_len=CHUNK // 2)[0]
+        assert half != plain
+        assert f"{ROWS}x{CHUNK // 2}x{cfg.vocab_size}xf32" in half
+        assert f"{ROWS}x{CHUNK // 2}x{cfg.vocab_size}xf32" not in plain
+
+
 # --- the same programs as the chip's compiler leaves them --------------------
 #
 # The TPU compiler is installed here and compiles for a chip that is described,
@@ -493,15 +547,19 @@ def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
 
 # --- a block pass, as the chip's compiler leaves it ---------------------------
 
-def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(one_chip,
-                                                                 monkeypatch):
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one-block", "two-blocks"])
+def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(
+        one_chip, monkeypatch, blocks):
     """The block pass of a family that generates by diffusion over blocks
     (models/sdar_moe.verify_step_paged: 32 rows of a block of 4 behind their
     committed caches, logits at every position) at the benchmark cell's pool
     and widths, compiled for a v5e: Mosaic takes the extend kernel under the
     block mask at 4 queries a row and the grouped expert matmul at 128 rows,
     no value pool is copied or sliced by the layer, and the temporaries are
-    the pass's logits, not a layer of the pool or of the experts."""
+    the pass's logits, not a layer of the pool or of the experts. And the
+    scheduler's pass, two blocks wide with one block's logits a row from
+    the row's own offset: 8 queries a row, 256 rows, the same kernels and
+    the logits of 4 positions a row, no more."""
     from llmlb_tpu.ops import pallas_moe
 
     monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
@@ -517,14 +575,15 @@ def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(one_chip,
         lambda key: sdar_moe.init_params(cfg, key), jax.random.PRNGKey(0)))
     pools = on_chip(jax.eval_shape(
         lambda: sdar_moe.init_kv_pages(cfg, BLOCK_PAGES, CHIP_PAGE_SIZE)))
-    ids = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, b), jnp.int32))
+    ids = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, blocks * b), jnp.int32))
     rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
     tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
+    wide = dict(logits_from=rows, logits_len=b) if blocks > 1 else {}
     try:
         with jax.default_matmul_precision("default"):
             compiled = sdar_moe.verify_step_paged.lower(
                 params, cfg, ids, rows, rows, tables, *pools, None,
-                window=1024).compile()
+                window=1024, **wide).compile()
     finally:
         for f in jitted:
             f._clear_cache()
