@@ -15,7 +15,9 @@ correctness baselines; these kernels replace them on TPU:
   serving batch's freed, never-used and prefilling slots): it takes one
   grid step that writes zeros and reads no page. Decode cost scales with
   the pages the live rows hold — not with the row capacity, the table's
-  width or the batch's window bucket.
+  width or the batch's window bucket. A grid step takes its page as it is
+  stored, [PS*K, D], and every query head against it in one masked product
+  (`_decode_item`): no per-head slice of the page.
 - `paged_flash_extend` (+ `_quant`): a chunk of queries against the pool
   (chunked prefill, speculative verify), the stacked pool read in place at
   (layer, page of the row's table) as in decode.
@@ -27,8 +29,9 @@ correctness baselines; these kernels replace them on TPU:
 
 Mosaic tiling: blocks always take the FULL trailing (heads, head_dim) dims —
 the lowering requires the last two block dims be (8,128)-aligned *or* equal to
-the array dims, and "equal" holds for any head count this way. KV heads are
-iterated with a static (unrolled) loop inside the kernel.
+the array dims, and "equal" holds for any head count this way. The prefill
+and extend kernels iterate KV heads with a static (unrolled) loop inside the
+kernel; the decode kernels take all of a page's heads at once.
 
 Numerics match the XLA baselines: fp32 scores/softmax/accumulation
 (`preferred_element_type`), finite -1e30 masking (fully-masked rows stay NaN-free).
@@ -149,24 +152,42 @@ def _pool_page_map(i, layer, row_of, page_of, pool_page_of, lens):
     return (layer[0], pool_page_of[i], 0, 0, 0)
 
 
+def _pool_rows_map(i, layer, row_of, page_of, pool_page_of, lens):
+    """KV values seen as [L, P, PS*K, D]: the same page, as its rows."""
+    return (layer[0], pool_page_of[i], 0, 0)
+
+
 def _layer_scale_map(i, layer, row_of, page_of, pool_page_of, lens):
     """KV scales [P, PS, K] of one layer of an int8 pool: the same page."""
     return (pool_page_of[i], 0, 0)
 
 
 def _row_map(i, layer, row_of, page_of, pool_page_of, lens):
-    """q and out [B, K, G, D]: the item's row, for every page of the row."""
-    return (row_of[i], 0, 0, 0)
+    """q and out [B, H, D]: the item's row, for every page of the row."""
+    return (row_of[i], 0, 0)
 
 
 def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, kv_head, *,
+                 m_ref, l_ref, acc_ref, page, *,
                  block_k: int, sweep: int, num_kv: int, scale: float):
     """One grid step of a paged decode kernel: item i of the work-list is
     page `s` of row `row`. Online softmax (m/l/acc) lives in VMEM scratch
-    from a row's first item to its last; `kv_head(h)` loads head h's
-    [BLK, D] keys and values of the step's block. A row of length 0 has one
-    item, computes nothing and is written as zeros (l == 0)."""
+    from a row's first item to its last. A row of length 0 has one item,
+    computes nothing and is written as zeros (l == 0).
+
+    The page is taken as it is stored: `page()` gives the step's keys and
+    values as [PS*K, D], row t*K + h the vector of cell t and KV head h, and
+    all H = K*G query heads (head-major: row r belongs to KV head r // G)
+    meet it in ONE product, one softmax update and one product. Entry (r, c)
+    of the scores counts where column c's KV head is row r's and its cell is
+    live; every other entry is -1e30, whose exp is exactly 0, so the result
+    is the attention of each head over its own keys. The other heads'
+    columns are work the MXU does for nothing: 4*H*D*PS*K FLOP a page
+    against its 4*PS*K*D bytes, H FLOP a byte (32 at Mistral-7B's heads, 64
+    at 64) where the chip's ridge is about 240 — the kernel stays bound by
+    its bytes. (Slicing a head out of the page instead takes one sublane of
+    every tile, 2*K times a page, for K products four rows tall: 1.6 us a
+    page of 512 KB whose bytes take 0.64, PERF.md §6, PR 43.)"""
     i = pl.program_id(0)
     s = page_of_ref[i]
     kv_len = kv_lens_ref[row_of_ref[i]]
@@ -180,19 +201,21 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
 
     @pl.when(s * block_k < kv_len)
     def _compute():
-        col = s * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), dimension=1
-        )
-        valid = col < kv_len  # [1, BLK]
-        for h in range(num_kv):  # static unroll over KV heads
-            q = q_ref[0, h]  # [G, D]
-            k, v = kv_head(h)  # [BLK, D] each
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [G, BLK]
-            scores = jnp.where(valid, scores, _NEG_INF)
-            _online_update(m_ref, l_ref, acc_ref, h, scores, v)
+        q = q_ref[0]  # [H, D]
+        k, v = page()  # [PS*K, D] each
+        heads = q.shape[0]
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, PS*K]
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k * num_kv), dimension=1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), dimension=0)
+        keep = jnp.logical_and(
+            col % num_kv == row // (heads // num_kv),
+            s * block_k + col // num_kv < kv_len)
+        scores = jnp.where(keep, scores, _NEG_INF)
+        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v)
 
     @pl.when(s == last)
     def _finalize():
@@ -206,57 +229,54 @@ def _paged_decode_kernel(
     # the BlockSpec index maps, which pick what each grid step DMAs
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
     # inputs
-    q_ref,  # [1, K, G, D]
-    k_ref,  # [1, PS, K, D]
-    v_ref,  # [1, PS, K, D]
+    q_ref,  # [1, H, D]
+    k_ref,  # [1, PS*K, D]
+    v_ref,  # [1, PS*K, D]
     # output
-    o_ref,  # [1, K, G, D]
+    o_ref,  # [1, H, D]
     # scratch
-    m_ref,  # [K, G, 1] f32
-    l_ref,  # [K, G, 1] f32
-    acc_ref,  # [K, G, D] f32
+    m_ref,  # [H, 1] f32
+    l_ref,  # [H, 1] f32
+    acc_ref,  # [H, D] f32
     **kw,
 ):
     del layer_ref, pool_page_of_ref
-
-    def kv_head(h):
-        return k_ref[0, :, h, :], v_ref[0, :, h, :]
-
     _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, kv_head, **kw)
+                 m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]), **kw)
 
 
 def _paged_decode_quant_kernel(
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
-    q_ref,  # [1, K, G, D]
+    q_ref,  # [1, H, D]
     k_ref,  # [1, PS, K, D] int8
     ks_ref,  # [1, PS, K] f32
     v_ref,  # [1, PS, K, D] int8
     vs_ref,  # [1, PS, K] f32
-    o_ref,  # [1, K, G, D]
+    o_ref,  # [1, H, D]
     m_ref, l_ref, acc_ref,
     **kw,
 ):
     """Int8 page pool + per-vector f32 scales: the scale arrays [P, PS, K]
     ride the same work-list as the values (their index map picks the
-    identical pool page per grid step), and each KV vector dequantizes in
-    VMEM right before its dot — HBM moved int8 bytes."""
+    identical pool page per grid step), and the whole page dequantizes in
+    VMEM right before the product — HBM moved int8 bytes. The blocks keep
+    the pool's [PS, K, D] (a scale [PS, K] meets its vector there) and take
+    the body's [PS*K, D] once they are in q's dtype."""
     del layer_ref, pool_page_of_ref
     dtype = q_ref.dtype
+    _, ps, num_kv, d = k_ref.shape
 
-    def kv_head(h):
-        k = (k_ref[0, :, h, :].astype(jnp.float32)
-             * ks_ref[0, :, h][:, None]).astype(dtype)  # [BLK, D]
-        v = (v_ref[0, :, h, :].astype(jnp.float32)
-             * vs_ref[0, :, h][:, None]).astype(dtype)
-        return k, v
+    def page():
+        k = (k_ref[0].astype(jnp.float32) * ks_ref[0][:, :, None]).astype(dtype)
+        v = (v_ref[0].astype(jnp.float32) * vs_ref[0][:, :, None]).astype(dtype)
+        return k.reshape(ps * num_kv, d), v.reshape(ps * num_kv, d)
 
     _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, kv_head, **kw)
+                 m_ref, l_ref, acc_ref, page, **kw)
 
 
 def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
-                       kv_lens, work, *, page_size, pages, interpret):
+                       kv_lens, work, *, page_size, num_kv, pages, interpret):
     """The pallas_call both paged decode kernels share: `grid=(work.count,)`
     — a run-time length — over the work-list's items; q and out blocks
     follow the item's row, the KV blocks (`kv_specs`, one per operand of
@@ -264,34 +284,30 @@ def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
-    num_kv = kv_operands[0].shape[3]
-    g = h // num_kv
     if work is None:
         work = decode_work_list(block_tables, kv_lens, page_size=page_size,
                                 pages=pages)
-    row_spec = pl.BlockSpec((1, num_kv, g, d), _row_map,
-                            memory_space=pltpu.VMEM)
+    row_spec = pl.BlockSpec((1, h, d), _row_map, memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(work.count,),
         in_specs=[row_spec, *kv_specs],
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, g, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(kernel, block_k=page_size,
                           sweep=_swept_pages(block_tables, pages),
                           num_kv=num_kv, scale=d**-0.5),
-        out_shape=jax.ShapeDtypeStruct((b, num_kv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
     )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
-      kv_lens.astype(jnp.int32), q.reshape(b, num_kv, g, d), *kv_operands)
-    return out.reshape(b, h, d)
+      kv_lens.astype(jnp.int32), q, *kv_operands)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
@@ -329,13 +345,19 @@ def paged_flash_decode(
     engine's freed, never-used and prefilling slot rows). `pages` bounds a
     row's items and the static size of the work-list, not the input shapes.
     """
-    ps = k_pages.shape[2]
-    kv_spec = pl.BlockSpec((None, 1, ps, k_pages.shape[3], k_pages.shape[4]),
-                           _pool_page_map, memory_space=pltpu.VMEM)
+    layers, pool_pages, ps, num_kv, d = k_pages.shape
+    # a page as its [PS*K, D] rows: in the chip's memory the same bytes
+    # (XLA compiles the reshape to a bitcast: the pool's two minor
+    # dimensions are tiled K rows deep or eight), and the DMA lands the
+    # block dense whatever K is
+    rows = (layers, pool_pages, ps * num_kv, d)
+    kv_spec = pl.BlockSpec((None, 1, ps * num_kv, d), _pool_rows_map,
+                           memory_space=pltpu.VMEM)
     return _paged_decode_call(
-        _paged_decode_kernel, [kv_spec, kv_spec], (k_pages, v_pages), q,
-        layer, block_tables, kv_lens, work, page_size=ps, pages=pages,
-        interpret=interpret)
+        _paged_decode_kernel, [kv_spec, kv_spec],
+        (k_pages.reshape(rows), v_pages.reshape(rows)), q, layer,
+        block_tables, kv_lens, work, page_size=ps, num_kv=num_kv,
+        pages=pages, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
@@ -375,7 +397,8 @@ def paged_flash_decode_quant(
         _paged_decode_quant_kernel,
         [kv_spec, scale_spec, kv_spec, scale_spec],
         (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
-        kv_lens, work, page_size=ps, pages=pages, interpret=interpret)
+        kv_lens, work, page_size=ps, num_kv=num_kv, pages=pages,
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,259 @@
+"""What a live page and a call cost the paged attention kernels, alone on the
+chip: no model, no engine, no scheduler.
+
+    chiprun -- python3 scripts/decode_page_cost.py
+
+`paged_flash_decode` at the heads of the benchmark's dense cells (32 rows,
+pages of 128 cells, head size 128; 8 KV heads x 4 as Mistral-7B has them, 2 x
+16 as Nemotron-3-Nano's attention layers): a sweep of 1, 2, 4 and 8 full pages
+a row, a straight line through it (µs a call = `call_us` + `page_us` x live
+pages), and one batch drawn as each cell draws its rows (`decode-saturated`:
+32 rows somewhere between a prompt of 64-128 and 512 tokens more;
+`chat-paced`: 5 rows of 32 live, lognormal prompts and outputs). Then
+`paged_flash_extend` at the block family's call (32 rows x 8 queries, 4 KV
+heads x 8, a table 8 pages wide, blocks of 4), the same sweep.
+
+A program is CALLS calls one after another, each on the last one's output, as
+a decode program's layers are; the work-list is built once outside them, as a
+decode step does. Two clocks: the device's own (the kernel's events on the
+profiler's "XLA Ops" line, `device_us`) and the host's around the whole
+program (`wall_us`, with whatever lies between two calls). The line is fitted
+to the device's where the trace has it. Prints one JSON object and writes it
+to `chiprun_out/decode_page_cost.json`. On the CPU (`JAX_PLATFORMS=cpu`) it
+runs the interpreter at a tenth of the size and says so: a rehearsal of the
+script, not a number.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+ROWS, PAGE, HEAD_DIM, CALLS = 32, 128, 128, 16
+SWEEP = (1, 2, 4, 8)
+DECODE_SHAPES = {  # name: (KV heads, queries a KV head, layers, pool pages)
+    "mistral-7b K8xG4": (8, 4, 16, 400),
+    "nemotron-3-nano K2xG16": (2, 16, 2, 400),  # the cell's 2 such layers
+}
+EXTEND_SHAPE = ("sdar-30b-a3b K4xG8, 8 queries", 4, 8, 7, 544, 8, 8, 4)
+
+
+def _draw_lens(rng, cell: str):
+    """Row lengths as a cell's traffic leaves them in a steady window."""
+    import numpy as np
+
+    if cell == "decode-saturated":
+        return rng.integers(64, 129, ROWS) + rng.integers(0, 513, ROWS)
+    lens = np.zeros(ROWS, np.int64)  # chat-paced
+    live = rng.choice(ROWS, 5, replace=False)
+    prompt = np.clip(np.exp(rng.normal(np.log(256), 0.9, 5)), 32, 1536)
+    out = np.clip(np.exp(rng.normal(np.log(96), 0.6, 5)), 16, 384)
+    lens[live] = (prompt + rng.random(5) * out).astype(np.int64)
+    return lens
+
+
+def _device_us(trace_dir: str, kernel: str):
+    """(mean µs, events) of the kernel's events on the device's "XLA Ops"
+    line of the newest trace under `trace_dir`; None where there is none."""
+    import jax
+
+    found = sorted(os.path.join(r, f) for r, _d, fs in os.walk(trace_dir)
+                   for f in fs if f.endswith(".xplane.pb"))
+    if not found:
+        return None
+    profile = jax.profiler.ProfileData.from_file(found[-1])
+    spans = [e.duration_ns for p in profile.planes
+             if (p.name or "").startswith("/device:TPU:")
+             for ln in p.lines if ln.name == "XLA Ops"
+             for e in ln.events if kernel in e.name]
+    return (sum(spans) / len(spans) / 1e3, len(spans)) if spans else None
+
+
+def _measure(program, args, kernel: str, reps: int) -> dict:
+    import jax
+
+    jax.block_until_ready(program(*args))  # compiled, and run once
+    trace_dir = tempfile.mkdtemp(prefix="page-cost-")
+    jax.profiler.start_trace(trace_dir)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = program(*args)
+    jax.block_until_ready(out)
+    wall = time.perf_counter() - t0
+    jax.profiler.stop_trace()
+    got = {"wall_us": wall / (reps * CALLS) * 1e6}
+    traced = _device_us(trace_dir, kernel)
+    if traced:
+        got["device_us"], got["events"] = traced
+    return got
+
+
+def _fit(points: list[tuple[float, float]]) -> dict:
+    """Least squares line through (live pages, µs a call)."""
+    n = len(points)
+    mx = sum(x for x, _ in points) / n
+    my = sum(y for _, y in points) / n
+    slope = (sum((x - mx) * (y - my) for x, y in points)
+             / sum((x - mx) ** 2 for x, _ in points))
+    return {"page_us": slope, "call_us": my - slope * mx}
+
+
+def _tables(rng, pages_a_row, width: int, pool_pages: int):
+    """Scattered distinct pool pages for the rows' live pages; the rest of a
+    table row names page 0, as an engine's unallocated entries do."""
+    import numpy as np
+
+    tables = np.zeros((ROWS, width), np.int32)
+    perm = rng.permutation(np.arange(1, pool_pages))
+    at = 0
+    for r, n in enumerate(pages_a_row):
+        tables[r, :n] = perm[at:at + n]
+        at += n
+    return tables
+
+
+def _operands(seed: int, q_shape: tuple, pool_shape: tuple):
+    """Seeded bf16 queries, key pool and value pool."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
+    return [jax.random.normal(key, shape, jnp.bfloat16)
+            for key, shape in zip(keys, (q_shape, pool_shape, pool_shape))]
+
+
+def _line(sweep: list[dict]) -> dict:
+    """The sweep's line, on the device's clock where every point has it."""
+    clock = "device_us" if all("device_us" in s for s in sweep) else "wall_us"
+    return {"clock": clock,
+            **_fit([(s["live_pages"], s[clock]) for s in sweep])}
+
+
+def decode_table(shape: str, reps: int, seed: int, small: bool) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmlb_tpu.ops import pallas_attention as pa
+
+    num_kv, groups, layers, pool_pages = DECODE_SHAPES[shape]
+    if small:
+        layers, pool_pages = 2, ROWS * 2 + 1
+    rng = np.random.default_rng(seed)
+    q, k_pages, v_pages = _operands(
+        seed, (ROWS, num_kv * groups, HEAD_DIM),
+        (layers, pool_pages, PAGE, num_kv, HEAD_DIM))
+    width = 2 if small else 16
+
+    @jax.jit
+    def program(q, k_pages, v_pages, tables, lens):
+        work = pa.decode_work_list(tables, lens, page_size=PAGE)
+        for i in range(CALLS):
+            q = pa.paged_flash_decode(q, k_pages, v_pages, i % layers, tables,
+                                      lens, work=work)
+        return q
+
+    def run(lens):
+        lens = np.minimum(np.asarray(lens), width * PAGE)
+        pages = -(-lens // PAGE)
+        tables = _tables(rng, pages, width, pool_pages)
+        got = _measure(program, (q, k_pages, v_pages, jnp.asarray(tables),
+                                 jnp.asarray(lens, jnp.int32)),
+                       "paged_flash_decode", reps)
+        return {"live_pages": int(pages.sum()),
+                "live_rows": int((lens > 0).sum()), **got}
+
+    sweep = [{"pages_a_row": p, **run(np.full(ROWS, p * PAGE))}
+             for p in (SWEEP[:2] if small else SWEEP)]
+    line = _line(sweep)
+    cells = {}
+    for cell in ("decode-saturated", "chat-paced"):
+        if shape.startswith("nemotron") and cell == "chat-paced":
+            continue  # no such cell
+        got = run(_draw_lens(rng, cell))
+        got["page_us_over_the_call"] = (
+            (got[line["clock"]] - line["call_us"])
+            / max(1, got["live_pages"]))
+        cells[cell] = got
+    return {"sweep": sweep, "line": line, "cells": cells}
+
+
+def extend_table(reps: int, seed: int, small: bool) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmlb_tpu.ops import pallas_attention as pa
+
+    name, num_kv, groups, layers, pool_pages, queries, width, block = \
+        EXTEND_SHAPE
+    if small:
+        layers, pool_pages, width = 2, ROWS * 2 + 1, 2
+    rng = np.random.default_rng(seed + 1)
+    q, k_pages, v_pages = _operands(
+        seed + 1, (ROWS, queries, num_kv * groups, HEAD_DIM),
+        (layers, pool_pages, PAGE, num_kv, HEAD_DIM))
+
+    @jax.jit
+    def program(q, k_pages, v_pages, tables, starts, chunk):
+        for i in range(CALLS):
+            q = pa.paged_flash_extend(q, k_pages, v_pages, i % layers, tables,
+                                      starts, chunk, block=block)
+        return q
+
+    sweep = []
+    for p in (SWEEP[:2] if small else SWEEP):
+        tables = _tables(rng, np.full(ROWS, p), width, pool_pages)
+        args = (q, k_pages, v_pages, jnp.asarray(tables),
+                jnp.full((ROWS,), p * PAGE - queries, jnp.int32),
+                jnp.full((ROWS,), queries, jnp.int32))
+        sweep.append({"pages_a_row": p, "live_pages": ROWS * p,
+                      "grid_steps": ROWS * width,
+                      **_measure(program, args, "paged_flash_extend", reps)})
+    return {"shape": name, "sweep": sweep, "line": _line(sweep)}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--reps", type=int, default=30,
+                    help="runs of a program of 16 calls, a measurement")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", choices=("decode", "extend"))
+    ap.add_argument("--out", default=os.path.join(
+        ROOT, "chiprun_out", "decode_page_cost.json"))
+    args = ap.parse_args()
+
+    import jax
+
+    device = jax.devices()[0]
+    small = device.platform != "tpu"
+    reps = 1 if small else args.reps
+    out = {"device": device.device_kind, "platform": device.platform,
+           "rows": ROWS, "page": PAGE, "head_dim": HEAD_DIM,
+           "calls_a_program": CALLS, "reps": reps}
+    if small:
+        out["note"] = ("not a chip: the interpreter at a tenth of the size, "
+                       "a rehearsal of the script and no number")
+    if args.only != "extend":
+        out["paged_flash_decode"] = {
+            shape: decode_table(shape, reps, args.seed, small)
+            for shape in DECODE_SHAPES}
+    if args.only != "decode":
+        out["paged_flash_extend"] = extend_table(reps, args.seed, small)
+    text = json.dumps(out)
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    with open(args.out, "w") as f:
+        f.write(text + "\n")
+    print(text)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

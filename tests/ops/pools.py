@@ -22,6 +22,12 @@ def stacked_pool(pool, layer, num_layers=3):
     return jnp.stack(layers)
 
 
+# (KV heads, queries a KV head) the decode kernels' one masked product is
+# held to: the benchmark's dense cells (Mistral-7B's, Nemotron-3-Nano's) and
+# the two ends, ungrouped and one KV head
+HEAD_SHAPES = {"K8xG4": (8, 4), "K2xG16": (2, 16), "MHA-8x1": (8, 1),
+               "MQA-1x4": (1, 4)}
+
 # Lengths of the paged decode kernels' contract cases, at page size PS = 16
 # and tables PPN = 4 pages wide: name -> (kv_lens, pages). A length of 0 is
 # a row that is not live.
@@ -31,6 +37,9 @@ DECODE_CASES = {
     "dead_rows_interleaved": ([0, 37, 0, 5, 0, 0, 64, 0], None),
     # on a page boundary, one cell under it and one over it
     "page_boundaries": ([16, 15, 17, 0, 32, 31, 33, 48], None),
+    # a row's last page holds ONE live cell: each head finds its one column
+    # there among the masked ones (its other heads', and the cells beyond)
+    "one_cell_in_last_page": ([49, 0, 17, 1], None),
     "every_row_dead": ([0, 0, 0], None),
     # one live row at capacity, the table's width swept …
     "capacity_at_table_width": ([0, 64, 0], None),

@@ -28,6 +28,7 @@ from tests.ops.pools import (
     DECODE_CASES,
     DECODE_PPN,
     DECODE_PS,
+    HEAD_SHAPES,
     live_pages_case,
     stacked_pool as _stacked,
 )
@@ -107,35 +108,87 @@ def _paged_fixture(key, b, h, kv, d, page_size, pages_per_seq):
     return k_pages, v_pages, tables
 
 
+# bf16 against the float32 reference over the same (rounded) numbers: the
+# kernel rounds the softmax's weights to the pool's dtype for the second
+# product, as the XLA route does
+_BF16_TOL = 2e-2
+
+
 @pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize(
-    "b,h,kv,d,page_size,pages_per_seq",
+    "b,h,kv,d,page_size,pages_per_seq,dtype",
     [
-        (2, 8, 8, 32, 16, 4),  # MHA
-        (3, 8, 2, 16, 32, 3),  # GQA g=4
-        (2, 4, 1, 32, 16, 2),  # MQA
+        (2, 8, 8, 32, 16, 4, jnp.float32),  # MHA
+        (3, 8, 2, 16, 32, 3, jnp.float32),  # GQA g=4
+        (2, 4, 1, 32, 16, 2, jnp.float32),  # MQA
+        # the benchmark cells' heads at the page they serve with, in the
+        # pool's dtype: Mistral-7B's, Nemotron-3-Nano's, and the two ends
+        (2, 32, 8, 128, 128, 2, jnp.bfloat16),  # K 8 x G 4
+        (2, 32, 2, 128, 128, 2, jnp.bfloat16),  # K 2 x G 16
+        (2, 8, 8, 128, 128, 2, jnp.bfloat16),  # MHA, 8 x 1
+        (2, 4, 1, 128, 128, 2, jnp.bfloat16),  # MQA, 1 x 4
     ],
 )
 def test_paged_flash_decode_matches_dense(b, h, kv, d, page_size,
-                                          pages_per_seq, layer):
+                                          pages_per_seq, dtype, layer):
     """The paged kernel gathering KV through the layer index and the block
     table must equal the einsum over the materialized (gathered) cache of
     that layer."""
     keys = jax.random.split(jax.random.PRNGKey(10), 3)
     cap = page_size * pages_per_seq
-    q = _rand(keys[0], (b, 1, h, d))
+    q = _rand(keys[0], (b, 1, h, d)).astype(dtype)
     k_pages, v_pages, tables = _paged_fixture(
         keys[1], b, h, kv, d, page_size, pages_per_seq)
+    k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
     kv_lens = jax.random.randint(keys[2], (b,), 1, cap + 1, jnp.int32)
 
-    k_cache = gather_kv_pages(k_pages, tables)
-    v_cache = gather_kv_pages(v_pages, tables)
-    expected = gqa_attention_decode(q, k_cache, v_cache, kv_lens)
+    k_cache = gather_kv_pages(k_pages.astype(jnp.float32), tables)
+    v_cache = gather_kv_pages(v_pages.astype(jnp.float32), tables)
+    expected = gqa_attention_decode(q.astype(jnp.float32), k_cache, v_cache,
+                                    kv_lens)
     got = paged_flash_decode(
         q[:, 0], _stacked(k_pages, layer), _stacked(v_pages, layer), layer,
         tables, kv_lens, interpret=True
     )
-    np.testing.assert_allclose(got, expected[:, 0], rtol=2e-5, atol=2e-5)
+    assert got.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else _BF16_TOL
+    np.testing.assert_allclose(got.astype(jnp.float32), expected[:, 0],
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", sorted(HEAD_SHAPES))
+def test_paged_flash_decode_lets_no_other_head_through(shape, dtype):
+    """A grid step takes every query head against every KV head's columns
+    of the page and masks the columns of the other heads. Nothing of them
+    may reach a head's output: with every OTHER KV head's keys and values
+    in the pool replaced by large finite numbers, the queries of one KV head
+    come out BIT-identical."""
+    kv, g = HEAD_SHAPES[shape]
+    b, d, ps, ppn, layer = 2, 32, 16, 3, 1
+    keys = jax.random.split(jax.random.PRNGKey(16), 2)
+    q = _rand(keys[0], (b, kv * g, d)).astype(dtype)
+    k_pages, v_pages, tables = _paged_fixture(keys[1], b, kv * g, kv, d, ps,
+                                              ppn)
+    kv_lens = jnp.array([ps * 2 + 1, ps * ppn], jnp.int32)
+
+    def run(k, v):
+        return np.asarray(paged_flash_decode(
+            q, _stacked(k.astype(dtype), layer),
+            _stacked(v.astype(dtype), layer), layer, tables, kv_lens,
+            interpret=True).astype(jnp.float32))
+
+    clean = run(k_pages, v_pages)
+    assert np.isfinite(clean).all()
+    for head in range(kv):
+        others = (jnp.arange(kv) != head)[None, None, :, None]
+        # alternating signs, so that a leak neither saturates nor cancels
+        loud = jnp.where(jnp.arange(d) % 2 == 0, 3e4, -3e4)
+        got = run(jnp.where(others, loud, k_pages),
+                  jnp.where(others, -loud, v_pages))
+        mine = slice(head * g, (head + 1) * g)
+        np.testing.assert_array_equal(got[:, mine], clean[:, mine])
 
 
 @pytest.mark.parametrize("layer", [0, 2])
@@ -163,18 +216,22 @@ def test_paged_flash_decode_page_window(layer):
     np.testing.assert_allclose(got2, expected, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("kv_len", ["empty", "one", "full_table"])
+@pytest.mark.parametrize("kv_len", ["empty", "one", "full_table",
+                                    "one_in_last_page"])
 def test_paged_flash_decode_extreme_lens(kv_len):
     """Row 0 at the edge of the ragged range beside an ordinary row 1: a
     single valid cell (page 0 of the row, offset 0), every cell of a full
-    block table, and no cell at all — which must come out as finite zeros
-    (the kernel skips every page and divides by a guarded l), not NaN."""
+    block table, one live cell in the row's last page (every head finds its
+    one column there among the masked ones), and no cell at all — which
+    must come out as finite zeros (the kernel skips every page and divides
+    by a guarded l), not NaN."""
     b, h, kv, d, ps, ppn = 2, 4, 2, 16, 16, 3
     layer = 1
     keys = jax.random.split(jax.random.PRNGKey(13), 2)
     q = _rand(keys[0], (b, 1, h, d))
     k_pages, v_pages, tables = _paged_fixture(keys[1], b, h, kv, d, ps, ppn)
-    n0 = {"empty": 0, "one": 1, "full_table": ps * ppn}[kv_len]
+    n0 = {"empty": 0, "one": 1, "full_table": ps * ppn,
+          "one_in_last_page": ps * (ppn - 1) + 1}[kv_len]
     kv_lens = jnp.array([n0, ps + 5], jnp.int32)
 
     got = paged_flash_decode(

@@ -21,6 +21,7 @@ from llmlb_tpu.quant import quantize_kv
 from tests.ops.pools import (
     DECODE_CASES,
     DECODE_PS,
+    HEAD_SHAPES,
     live_pages_case,
     stacked_pool as _stacked,
 )
@@ -29,10 +30,17 @@ B, H, K, D, P, PS, PPN = 2, 8, 4, 16, 9, 8, 4
 TOL = 0.05
 
 
-def _pools(seed=0):
+# (KV heads, queries a KV head, head size, page size): the module's small
+# shape in float32, then pools.HEAD_SHAPES at the page and head size the
+# benchmark's cells serve with, in bf16
+SHAPES = {"small": (K, H // K, D, PS),
+          **{name: (*heads, 128, 128) for name, heads in HEAD_SHAPES.items()}}
+
+
+def _pools(seed=0, k=K, d=D, ps=PS):
     rng = np.random.default_rng(seed)
-    k_pages = rng.normal(size=(P, PS, K, D)).astype(np.float32)
-    v_pages = rng.normal(size=(P, PS, K, D)).astype(np.float32)
+    k_pages = rng.normal(size=(P, ps, k, d)).astype(np.float32)
+    v_pages = rng.normal(size=(P, ps, k, d)).astype(np.float32)
     kq, ks = quantize_kv(k_pages)
     vq, vs = quantize_kv(v_pages)
     tables = np.array([[1, 2, 3, 0], [4, 5, 6, 0]], np.int32)
@@ -84,29 +92,67 @@ def test_paged_extend_xla_parity(layer):
                                                 np.float32)).max() < TOL
 
 
+@pytest.mark.parametrize("shape", sorted(SHAPES))
 @pytest.mark.parametrize("layer", [0, 2])
-def test_paged_flash_decode_quant_interpret_parity(layer):
+def test_paged_flash_decode_quant_interpret_parity(layer, shape):
     """Interpret-mode kernel vs both the bf16 kernel (tolerance) and the
     XLA dequant route (the two quantized paths read identical cells)."""
-    k_pages, v_pages, qk, qv, tables, rng = _pools(3)
+    k, g, d, ps = SHAPES[shape]
+    dtype = jnp.float32 if shape == "small" else jnp.bfloat16
+    k_pages, v_pages, qk, qv, tables, rng = _pools(3, k, d, ps)
     qk, qv = _stacked(qk, layer), _stacked(qv, layer)
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kv_lens = jnp.asarray([PS * 3 - 2, PS + 3], jnp.int32)
-    base = paged_flash_decode(q, _stacked(k_pages, layer),
-                              _stacked(v_pages, layer), layer, tables,
-                              kv_lens, interpret=True)
+    q = jnp.asarray(rng.normal(size=(B, k * g, d)), dtype)
+    # the second row's last page holds one live cell
+    kv_lens = jnp.asarray([ps * 3 - 2, ps + 1], jnp.int32)
+    base = paged_flash_decode(q, _stacked(k_pages, layer).astype(dtype),
+                              _stacked(v_pages, layer).astype(dtype), layer,
+                              tables, kv_lens, interpret=True)
     quant = paged_flash_decode_quant(
         q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer, tables,
         kv_lens, interpret=True,
     )
-    assert np.abs(np.asarray(base) - np.asarray(quant)).max() < TOL
+    assert quant.dtype == dtype
+    quant = np.asarray(quant, np.float32)
+    assert np.abs(np.asarray(base, np.float32) - quant).max() < TOL
 
     # both quantized routes dequant to q.dtype before the dots, so they
-    # differ only by online- vs plain-softmax accumulation order
+    # differ only by online- vs plain-softmax accumulation order (and, in
+    # bf16, by where the softmax's weights are rounded)
     xla = paged_attention_decode(q[:, None], qk, qv, layer, tables,
                                  kv_lens)[:, 0]
-    assert np.abs(np.asarray(quant)
-                  - np.asarray(xla, np.float32)).max() < 2e-3
+    assert np.abs(quant - np.asarray(xla, np.float32)).max() < (
+        2e-3 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_paged_flash_decode_quant_lets_no_other_head_through(shape):
+    """The int8 kernel under the bf16 kernel's mask
+    (test_paged_flash_decode_lets_no_other_head_through): every OTHER KV
+    head's values saturated and its scales large, and the queries of one KV
+    head come out BIT-identical."""
+    k, g, d, ps = SHAPES[shape]
+    d, ps, layer = min(d, 32), min(ps, 16), 1
+    _, _, qk, qv, tables, rng = _pools(5, k, d, ps)
+    q = jnp.asarray(rng.normal(size=(B, k * g, d)), jnp.float32)
+    kv_lens = jnp.asarray([ps * 2 + 1, ps * 3], jnp.int32)
+
+    def run(qk, qv):
+        qk, qv = _stacked(qk, layer), _stacked(qv, layer)
+        return np.asarray(paged_flash_decode_quant(
+            q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer,
+            tables, kv_lens, interpret=True))
+
+    clean = run(qk, qv)
+    assert np.isfinite(clean).all()
+    loud = jnp.where(jnp.arange(d) % 2 == 0, 127, -127).astype(jnp.int8)
+    for head in range(k):
+        others = jnp.arange(k) != head
+        got = run(*({"q": jnp.where(others[None, None, :, None], sign * loud,
+                                    pool["q"]),
+                     "s": jnp.where(others[None, None, :], 1e4, pool["s"])}
+                    for sign, pool in ((1, qk), (-1, qv))))
+        mine = slice(head * g, (head + 1) * g)
+        np.testing.assert_array_equal(got[:, mine], clean[:, mine])
 
 
 @pytest.mark.parametrize("layer", [0, 2])

@@ -14,6 +14,7 @@ v5e. Its time is the benchmark's to show."""
 
 import dataclasses
 import functools
+import inspect
 import re
 
 import jax
@@ -36,15 +37,21 @@ FAMILIES = {
 }
 
 
-def _equations(jaxpr):
+def _equations(jaxpr, kernel_bodies=False):
     """Every equation of a jaxpr and of the jaxprs nested in it (jit, scan,
-    cond …), except the bodies of Pallas kernels: their refs are blocks."""
+    cond …), except the bodies of Pallas kernels (their refs are blocks)
+    unless `kernel_bodies`."""
     for eqn in jaxpr.eqns:
         yield eqn
-        if eqn.primitive.name == "pallas_call":
+        if eqn.primitive.name == "pallas_call" and not kernel_bodies:
             continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub)
+            yield from _equations(sub, kernel_bodies)
+
+
+def _is_expert_kernel(eqn):
+    return "grouped_expert_matmul" in str(
+        eqn.params.get("name_and_src_info", eqn.params.get("name")))
 
 
 def _decode_jaxpr(family, cfg, quantized, monkeypatch):
@@ -79,8 +86,13 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
     # is re-laid-out for Mosaic whatever its rank, which is cheapest on one
     # layer (paged_flash_decode_quant's docstring). They are the only
     # per-layer piece of the pool the program may hold.
-    kernel_operands = [values, values] + [layer_scales] * (2 * quantized)
-    never = {values[1:]} | (set() if quantized else {layer_scales})
+    # The bf16 kernel takes the pool under the view [L, P, PS*K, D], a page
+    # as the rows it is stored as (a bitcast on the chip: the compiled
+    # burst below holds no copy under either shape).
+    rows = (LAYERS, PAGES, PAGE_SIZE * KV_HEADS, HEAD_DIM)
+    given = values if quantized else rows
+    kernel_operands = [given, given] + [layer_scales] * (2 * quantized)
+    never = {values[1:], rows[1:]} | (set() if quantized else {layer_scales})
 
     eqns = list(_equations(_decode_jaxpr(family, cfg, quantized,
                                          monkeypatch)))
@@ -92,19 +104,45 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
     calls = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"]
     # a mixture's grouped expert products are kernels too, each handed the
     # experts of every layer: [L, X, K, O], never one layer's slice
-    experts = [eqn for eqn in calls if "grouped_expert_matmul" in str(
-        eqn.params.get("name_and_src_info", eqn.params.get("name")))]
+    experts = [eqn for eqn in calls if _is_expert_kernel(eqn)]
     assert len(experts) == (3 * LAYERS if name == "mixtral" else 0)
     for eqn in experts:
         assert [v.aval.shape[0] for v in eqn.invars
                 if len(v.aval.shape) == 4] == [LAYERS]
     kernels = [eqn for eqn in calls if not any(eqn is e for e in experts)]
     assert len(kernels) == LAYERS
-    q_rows = (ROWS, KV_HEADS, 2, HEAD_DIM)  # [B, K, G, D]
+    q_rows = (ROWS, DIMS["num_heads"], HEAD_DIM)  # [B, H, D], head-major
     for eqn in kernels:
         shapes = [v.aval.shape for v in eqn.invars]
         assert sorted(s for s in shapes if len(s) >= 3) == sorted(
             kernel_operands + [q_rows]), shapes
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_a_decode_grid_step_is_one_pair_of_products(name, quantized,
+                                                    monkeypatch):
+    """The decode kernels take a page as it is stored: every query head
+    against all of its [PS*K, D] rows in ONE product, one softmax update and
+    ONE product (PERF.md §6, PR 43), whatever the number of KV heads — no
+    product a head, and no loop over the heads in the one body both kernels
+    share."""
+    family, cfg = FAMILIES[name]
+    kernels = [eqn for eqn in _equations(_decode_jaxpr(family, cfg, quantized,
+                                                       monkeypatch))
+               if eqn.primitive.name == "pallas_call"
+               and not _is_expert_kernel(eqn)]
+    assert len(kernels) == LAYERS
+    for eqn in kernels:
+        products = [e for e in _equations(eqn.params["jaxpr"], True)
+                    if e.primitive.name == "dot_general"]
+        rows = PAGE_SIZE * KV_HEADS  # a page's cells x KV heads
+        assert [tuple(v.aval.shape for v in e.invars) for e in products] == [
+            ((DIMS["num_heads"], HEAD_DIM), (rows, HEAD_DIM)),  # q, keys
+            ((DIMS["num_heads"], rows), (rows, HEAD_DIM)),  # weights, values
+        ]
+    body = inspect.getsource(pallas_attention._decode_item)
+    assert "for " not in body.split('"""')[2], "a loop is back in the body"
 
 
 # --- prefill, extend and verify: the pool is the layer scan's carry ----------
@@ -370,8 +408,9 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
             fn._clear_cache()
 
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2  # a layer
-    layer_values = r"(bf16|s8)\[400,128,8,128\]"
-    whole_pool = r"(bf16|s8)\[2,400,128,8,128\]"  # the values; an int8
+    # the pool as it is stored or as a page's [PS*K, D] rows
+    layer_values = r"(bf16|s8)\[400,(128,8|1024),128\]"
+    whole_pool = r"(bf16|s8)\[2,400,(128,8|1024),128\]"  # the values; an int8
     # pool's scales are re-laid-out at the loop's ends at either commit
     results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
     assert results
@@ -379,6 +418,46 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
            if re.match(layer_values, shape)
            or (op == "copy" and re.match(whole_pool, shape))]
     assert not bad, bad
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kv_heads,groups", [(8, 4), (2, 16), (32, 1)],
+                         ids=["mistral-K8xG4", "nemotron-K2xG16", "MHA-32"])
+def test_the_decode_kernels_lower_through_mosaic_at_the_cells_heads(
+        kv_heads, groups, quantized, one_chip):
+    """One masked product over a page's [PS*K, D] rows, compiled by the
+    chip's own compiler at the heads the benchmark's dense cells serve
+    (Mistral-7B's 8 x 4, Nemotron-3-Nano's 2 x 16 attention layers) and at
+    32 ungrouped heads, where a grid step's scores are 512 KB: the reshape of
+    the page block, the mask and the products are forms Mosaic takes, and
+    the scratch fits its VMEM. The bf16 pool reaches the kernel as it lies;
+    an int8 pool of two KV heads is re-laid-out before it at either commit
+    (XLA keeps `s8[.., 128, 2, 128]` cell-minor; PERF.md §7), so the int8
+    cases only have to compile."""
+    pool = jax.ShapeDtypeStruct(
+        (2, CHIP_PAGES, CHIP_PAGE_SIZE, kv_heads, 128),
+        jnp.int8 if quantized else jnp.bfloat16)
+    scales = jax.ShapeDtypeStruct((CHIP_PAGES, CHIP_PAGE_SIZE, kv_heads),
+                                  jnp.float32)
+    q = jax.ShapeDtypeStruct((CHIP_ROWS, kv_heads * groups, 128),
+                             jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32)
+    lens = jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    if quantized:
+        kernel = pallas_attention.paged_flash_decode_quant
+        operands = (q, pool, scales, pool, scales, layer, tables, lens)
+    else:
+        kernel = pallas_attention.paged_flash_decode
+        operands = (q, pool, pool, layer, tables, lens)
+    with jax.default_matmul_precision("default"):
+        hlo = jax.jit(functools.partial(kernel, interpret=False)).lower(
+            *_on_chip(one_chip, operands)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    copied = re.findall(
+        r"^\s*(?:ROOT )?%\S+ = (?:bf16|s8)\[2,400,\S+ (copy|fusion)\(", hlo,
+        re.M)
+    assert quantized or not copied, "the pool is re-laid-out for the kernel"
 
 
 # --- prefill and extend, as the chip's compiler leaves them ------------------
