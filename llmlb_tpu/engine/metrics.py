@@ -206,23 +206,21 @@ class EngineMetrics:
         # (ops/sampling.selection_plan): static per engine, set once where
         # the engine is made, a reading and not a rate
         self.sampling: dict[str, int] = {}
-        # A mixture's expert load, from the family's step counters
-        # (scheduler._record_step): running totals, the fullest expert any
-        # step saw, and per expert layer how many experts took how many
+        # The families' step counters (scheduler._record_step) under the
+        # names their records export them by: every scalar one some family
+        # computes (zero where this engine's computes none), a total or, of
+        # a "max" counter, the largest seen. Beside them the dispatches
+        # counted and, per expert layer, how many experts took how many
         # assignments in a step (models/deepseek_v3.LOAD_BUCKETS).
+        from llmlb_tpu.models import STEP_COUNTERS  # here: the gateway
+        # imports this module for Histogram and stays free of jax
+
+        self._step_counters = {name: c for name, c in STEP_COUNTERS.items()
+                               if name != "expert_load_hist"}
+        self.step_counter_totals = {
+            c.export: 0 for c in self._step_counters.values()}
         self.moe_counted_steps_total = 0
-        self.moe_experts_touched_total = 0
-        self.moe_expert_assignments_total = 0
-        self.moe_expert_load_max = 0
         self.moe_expert_load_hist: list[list[int]] = []
-        # Of a chip that holds a share of the experts: assignments that went
-        # to the others; and rows whose recurrent state a dispatch advanced
-        # (models/nemotron_h.step_counter_shapes); of a router that also
-        # scores zero-compute experts, the assignments that went to those
-        # and cost no product (models/longcat_flash.step_counter_shapes)
-        self.moe_assignments_elsewhere_total = 0
-        self.moe_zero_assignments_total = 0
-        self.ssm_state_rows_total = 0
         self.decode_kv_pages_window_total = 0
         # Generation by diffusion over blocks (scheduler._emit_blocks):
         # running totals of the bursts' counts, by the step records' names
@@ -314,10 +312,6 @@ class EngineMetrics:
     def record_itl(self, seconds: float) -> None:
         with self._lock:
             self.itl.observe(seconds)
-
-    def record_token(self, n: int = 1) -> None:
-        with self._lock:
-            self.tokens_total += n
 
     def record_emit(self, itl_seconds: float | None) -> None:
         """One locked update for the per-token hot path: a token plus its
@@ -434,22 +428,16 @@ class EngineMetrics:
             for name in BLOCK_COUNTS:
                 self.block_totals[name] += counts.get(name, 0)
 
-    def record_step_counters(self, counters: dict, max_names: tuple) -> None:
+    def record_step_counters(self, counters: dict) -> None:
         """One dispatch's step counters (a burst's are already reduced over
-        its steps): totals add, `max_names` keep the largest seen."""
+        its steps): totals add, a "max" counter keeps the largest seen."""
         with self._lock:
             self.moe_counted_steps_total += 1
-            self.moe_experts_touched_total += counters.get(
-                "experts_touched", 0)
-            self.moe_expert_assignments_total += counters.get(
-                "expert_assignments", 0)
-            self.moe_expert_load_max = max(
-                self.moe_expert_load_max, counters.get("expert_load_max", 0))
-            self.moe_assignments_elsewhere_total += counters.get(
-                "assignments_elsewhere", 0)
-            self.moe_zero_assignments_total += counters.get(
-                "zero_assignments", 0)
-            self.ssm_state_rows_total += counters.get("state_rows", 0)
+            totals = self.step_counter_totals
+            for name, (reduce, export) in self._step_counters.items():
+                n = counters.get(name, 0)
+                totals[export] = (max(totals[export], n) if reduce == "max"
+                                  else totals[export] + n)
             hist = counters.get("expert_load_hist")
             if hist:
                 if not self.moe_expert_load_hist:
@@ -646,14 +634,7 @@ class EngineMetrics:
                 **{f"{name}_total": n
                    for name, n in self.block_totals.items()},
                 "moe_counted_steps_total": self.moe_counted_steps_total,
-                "moe_experts_touched_total": self.moe_experts_touched_total,
-                "moe_expert_assignments_total":
-                    self.moe_expert_assignments_total,
-                "moe_expert_load_max": self.moe_expert_load_max,
-                "moe_assignments_elsewhere_total":
-                    self.moe_assignments_elsewhere_total,
-                "moe_zero_assignments_total": self.moe_zero_assignments_total,
-                "ssm_state_rows_total": self.ssm_state_rows_total,
+                **self.step_counter_totals,
                 "moe_expert_load_hist": [list(row) for row in
                                          self.moe_expert_load_hist],
                 "preemptions_total": self.preemptions_total,
@@ -792,23 +773,15 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_moe_counted_steps_total counter",
                 "llmlb_engine_moe_counted_steps_total "
                 f"{self.moe_counted_steps_total}",
-                "# TYPE llmlb_engine_moe_experts_touched_total counter",
-                "llmlb_engine_moe_experts_touched_total "
-                f"{self.moe_experts_touched_total}",
-                "# TYPE llmlb_engine_moe_expert_assignments_total counter",
-                "llmlb_engine_moe_expert_assignments_total "
-                f"{self.moe_expert_assignments_total}",
-                "# TYPE llmlb_engine_moe_assignments_elsewhere_total counter",
-                "llmlb_engine_moe_assignments_elsewhere_total "
-                f"{self.moe_assignments_elsewhere_total}",
-                "# TYPE llmlb_engine_moe_zero_assignments_total counter",
-                "llmlb_engine_moe_zero_assignments_total "
-                f"{self.moe_zero_assignments_total}",
-                "# TYPE llmlb_engine_ssm_state_rows_total counter",
-                f"llmlb_engine_ssm_state_rows_total {self.ssm_state_rows_total}",
-                "# TYPE llmlb_engine_moe_expert_load_max gauge",
-                "llmlb_engine_moe_expert_load_max "
-                f"{self.moe_expert_load_max}",
+                # the totals first, then the largest-seen gauges
+                *(line for kind, gauge in (("counter", False),
+                                           ("gauge", True))
+                  for reduce, export in self._step_counters.values()
+                  if (reduce == "max") == gauge
+                  for line in (
+                      f"# TYPE llmlb_engine_{export} {kind}",
+                      f"llmlb_engine_{export} "
+                      f"{self.step_counter_totals[export]}")),
                 "# TYPE llmlb_engine_moe_expert_load_experts_total counter",
                 *(f'llmlb_engine_moe_expert_load_experts_total{{layer="{l}",'
                   f'bucket="{b}"}} {n}'

@@ -5,11 +5,11 @@ JetStream-style serving loop, TPU-first:
   [L, PAGES, PAGE, K, D] plus a per-slot block table (engine/paging.py owns
   the refcounted allocator), so HBM is held per page of tokens actually
   cached and short requests do not strand slot_capacity rows each. One
-  compiled `decode_step_paged` serves every mix of requests — raggedness is
+  compiled decode program serves every mix of requests — raggedness is
   masks and tables, never shapes.
 - New requests prefill at bucketed prompt lengths (pow2 buckets ⇒ a handful
   of compiles) and scatter straight into their pages
-  (`prefill_into_pages`), while other slots keep decoding between prefills.
+  (programs.py `prefill`), while other slots keep decoding between prefills.
 - Sampling params live in device arrays indexed by slot; updated on insert.
 - The step loop runs in a dedicated thread; completions stream to waiters
   through per-request queues (asyncio- and thread-friendly).
@@ -40,7 +40,6 @@ import threading
 import time
 import uuid
 from functools import partial
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -56,36 +55,20 @@ from llmlb_tpu.engine.kv_transfer import (
 from llmlb_tpu.engine.metrics import EngineMetrics
 from llmlb_tpu.engine.paging import PagePool
 from llmlb_tpu.engine.prefix_cache import PrefixCache, PrefixEntry
+from llmlb_tpu.engine.programs import StepPrograms
 from llmlb_tpu.engine.flightrec import FlightRecorder, gateway_rid
 from llmlb_tpu.engine.stepstats import LoopClock, StepRecorder, StepSpan
 from llmlb_tpu.engine.streamstats import EventQueue
 from llmlb_tpu.models import family_for
 from llmlb_tpu.models.llama import LlamaConfig, Params
-from llmlb_tpu.ops.grammar import (
-    GrammarTables,
-    grammar_advance,
-    grammar_bias,
-)
-from llmlb_tpu.ops.sampling import (sample_tokens, selection_plan,
-                                    token_probability)
+from llmlb_tpu.ops.grammar import GrammarTables
+from llmlb_tpu.ops.sampling import sample_tokens, selection_plan
 from llmlb_tpu.parallel.mesh import MeshConfig, build_mesh, default_tp
 from llmlb_tpu.quant import kv_cell_bytes, parse_quant_mode, quantize_params
 from llmlb_tpu.spec import PromptLookupDrafter, SpecConfig
 from llmlb_tpu.structured.constraint import ConstraintState, TokenConstraint
 
 log = logging.getLogger("llmlb_tpu.engine")
-
-# Process-wide cache for the jit-wrapped fused/burst step builders
-# (_build_decode_many and friends). family.decode_step etc. are module-level
-# jits every engine shares, but the scan/verify wrappers are built per
-# EngineCore — without this cache each engine instance would recompile them
-# from scratch, which with fused decode on by default turns a test suite's
-# many short-lived CPU engines into a compile storm. Keyed by object
-# identity of the closed-over config/family (values keep strong refs so an
-# id() can never be recycled into an alias); grow-only for the process
-# lifetime, exactly like jit's own executable cache.
-_PROGRAM_CACHE: dict[tuple, tuple] = {}
-_PROGRAM_CACHE_LOCK = threading.Lock()
 
 # Priority classes (docs/scheduling.md): lower value = more important.
 # Dialect-facing names map high/normal/low onto 0/1/2 at the HTTP layer.
@@ -95,28 +78,15 @@ PRIORITY_NAMES = {PRIORITY_HIGH: "high", PRIORITY_NORMAL: "normal",
                   PRIORITY_LOW: "low"}
 
 
-def kv_bytes_per_token_layer(cfg, quantized: bool = False) -> int:
-    """HBM bytes one token leaves in one layer of the page pool: the
-    family's to say (`kv_token_layer_bytes`) — K and V of every kv head for
-    a GQA family, the latent and the shared rope key for a latent one."""
-    return int(family_for(cfg).kv_token_layer_bytes(cfg, quantized))
-
-
-def kv_pool_layers(cfg) -> int:
-    """Layers of the page pool: every layer, unless the family says that
-    only some of its layers attend (`kv_pool_layers`)."""
-    return int(getattr(family_for(cfg), "kv_pool_layers",
-                       lambda c: c.num_layers)(cfg))
-
-
 def kv_page_bytes(cfg, page_size: int, quantized: bool = False) -> int:
     """HBM bytes ONE page holds across all layers: page_size tokens of the
     family's per-token, per-layer cell (for a GQA family, K and V: the bf16
     cell is D·2 bytes per (token, head); the int8 cell is D·1 plus one f32
     scale, llmlb_tpu/quant.kv_cell_bytes) — the per-page figure the kv
     gauges report so capacity math stays honest under quantization."""
-    return int(kv_pool_layers(cfg) * page_size * kv_bytes_per_token_layer(
-        cfg, quantized))
+    family = family_for(cfg).FAMILY
+    return int(family.kv_pool_layers(cfg) * page_size
+               * family.kv_token_layer_bytes(cfg, quantized))
 
 
 def kv_pool_bytes(cfg, num_pages: int, page_size: int,
@@ -178,113 +148,6 @@ def _write_kv_pages(cache_k, cache_v, k_new, v_new, page_idx):
         return pool.at[:, page_idx].set(new.astype(pool.dtype))
 
     return scatter(cache_k, k_new), scatter(cache_v, v_new)
-
-
-def _pack_step_counters(tokens, stats: dict, shapes: dict,
-                        max_names: tuple) -> jnp.ndarray:
-    """One int32 vector for the burst's ONE fetch: the token rows flattened,
-    then the family's step counters (`stats`: name -> [steps, *shape]) in
-    the order of their names, reduced over the burst's steps — summed, but
-    for `max_names`, of which the largest is kept."""
-    parts = [tokens.reshape(-1).astype(jnp.int32)]
-    for name in sorted(shapes):
-        per_step = stats[name].astype(jnp.int32)
-        reduced = (jnp.max(per_step, axis=0) if name in max_names
-                   else jnp.sum(per_step, axis=0))
-        parts.append(reduced.reshape(-1))
-    return jnp.concatenate(parts)
-
-
-def _unpack_step_counters(flat: np.ndarray, rows: int, cols: int,
-                          shapes: dict) -> tuple[np.ndarray, dict]:
-    """The host's half of _pack_step_counters: (tokens [rows, cols],
-    name -> int or nested list)."""
-    at = rows * cols
-    tokens = flat[:at].reshape(rows, cols)
-    counters = {}
-    for name in sorted(shapes):
-        n = int(np.prod(shapes[name], dtype=np.int64))
-        value = flat[at:at + n].reshape(shapes[name])
-        counters[name] = value.tolist() if shapes[name] else int(value)
-        at += n
-    return tokens, counters
-
-
-def _sample_chunk(logits, key, temps, top_ps, top_ks, seeds, mask, start_pos):
-    """Per-position sampling for a verify chunk: [B, T, V] logits sampled as
-    B*T independent rows with each slot's params repeated per position and
-    the seed fold stepped by GLOBAL position (start + offset) — so a seeded
-    row draws the exact same key at sequence position p whether p was
-    reached by plain decode or inside a verify chunk (spec on/off produce
-    bit-identical seeded streams). `mask` is an optional [B*T, V] additive
-    grammar bias (per-position FSM lookahead rows)."""
-    b, t, v = logits.shape
-    flat = logits.reshape(b * t, v)
-
-    def rep(x):
-        return jnp.repeat(x, t)
-
-    steps = (start_pos[:, None]
-             + jnp.arange(t, dtype=jnp.int32)[None, :]).reshape(-1)
-    toks = sample_tokens(flat, key, rep(temps), rep(top_ps), rep(top_ks),
-                         mask, rep(seeds), steps)
-    return toks.reshape(b, t)
-
-
-@partial(jax.jit, donate_argnames=("state",))
-def _activate_rows(logits, key, temps, top_ps, top_ks, seeds, lens, slot_ids,
-                   bias, lora_rows, state):
-    """Activation of one prefilled group as ONE program: split the engine's
-    key, sample each row's first token from the prefill's `logits`
-    [padded, V], and scatter the group's rows into the per-slot arrays of
-    `state` (temps, top_ps, top_ks, seeds, seq_lens, last_tokens, lora_idx
-    — donated). jit keys it by what it is handed: the padded group size,
-    whether a grammar `bias` [padded, V] is present, whether the engine has
-    adapters (`lora_rows`; without them lora_idx passes through). Padding
-    rows repeat the last real row, so their duplicate scatters write
-    identical values. Returns (new key, firsts [padded], new state)."""
-    key, sk = jax.random.split(key)
-    # steps = lens - 1: decode dispatches sample with the PRE-increment
-    # seq_len, so the first decode token uses step = prompt_len — the
-    # activation sample must fold a DIFFERENT step or a seeded request's
-    # first two tokens would draw from the same per-row key.
-    firsts = sample_tokens(logits, sk, temps, top_ps, top_ks, bias, seeds,
-                           lens - 1)
-    rows = (temps, top_ps, top_ks, seeds, lens, firsts, lora_rows)
-    return key, firsts, tuple(
-        arr if row is None else arr.at[slot_ids].set(row)
-        for arr, row in zip(state, rows)
-    )
-
-
-def _sample_block(logits, key, temps, top_ps, top_ks, seeds, lens, n_masked):
-    """Per-position sampling of a block pass: [S, B, V] logits as S*B rows,
-    each slot's params repeated per position; returns the sampled ids and
-    their probabilities (ops/sampling.token_probability), both [S, B]. A
-    seeded row folds (absolute position, masks left in its block): the same
-    key whatever rows share the batch and whether or not the block was
-    started over after a park, and another key for each pass of a block."""
-    s, b, v = logits.shape
-    flat = logits.reshape(s * b, v)
-
-    def rep(x):
-        return jnp.repeat(x, b)
-
-    steps = ((lens[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :])
-             * (b + 1) + n_masked[:, None]).reshape(-1)
-    ids = sample_tokens(flat, key, rep(temps), rep(top_ps), rep(top_ks),
-                        None, rep(seeds), steps)
-    conf = token_probability(flat, ids, rep(temps))
-    return ids.reshape(s, b), conf.reshape(s, b)
-
-
-@partial(jax.jit, donate_argnames=("state",))
-def _activate_block_rows(slot_ids, rows, state):
-    """Activation of one prefilled group of a block family as ONE program:
-    scatter the group's rows into the per-slot arrays of `state` (donated).
-    Nothing is sampled: a block family's first tokens come from its first
-    block's passes. Padding rows repeat the last real row."""
-    return tuple(arr.at[slot_ids].set(row) for arr, row in zip(state, rows))
 
 
 @dataclasses.dataclass
@@ -555,28 +418,21 @@ class EngineCore:
         self.role = normalize_role(role)
         self._disagg_prefill_slots_arg = disagg_prefill_slots
         self.split = None  # SplitRuntime in split mode
-        # Family module (llama / mixtral) supplying the serving fns — one
-        # shared contract, so dense and MoE models run the same loop.
+        # The family's module (it is called through self.programs alone)
+        # and its record: what the family IS (models/family.py).
         self.family = family_for(cfg)
-        # Step counters the family computes on the device (a mixture's
-        # expert load): name -> shape, {} for a family that has none. The
-        # burst carries them out in the fetch it already makes.
-        self._counter_shapes: dict[str, tuple] = getattr(
-            self.family, "step_counter_shapes", lambda _cfg: {})(cfg)
-        self._counter_max: tuple = getattr(self.family, "STEP_COUNTER_MAX",
-                                           ())
+        record = self._record = self.family.FAMILY
         # Generation by diffusion over blocks: a family that declares a
         # block length B > 1 decodes by BLOCK PASSES (_decode_blocks) — a
         # row commits 0 or B tokens a pass — and every other family by one
         # token a step, on the programs it always built.
-        self.block = int(getattr(self.family, "block_length",
-                                 lambda _cfg: 1)(cfg))
+        self.block = int(record.block_length(cfg))
         # A family whose layers keep a recurrent state per SLOT beside the
         # page pool (docs/hybrid-state.md) says so by `state_slot_bytes`:
         # its pool is made for the slots, its prefill calls are told the
         # rows' slots, and what would serve such a state wrong is off unless
-        # asked for, and refused when asked for (_check_slot_state_engine).
-        self._slot_state = hasattr(self.family, "state_slot_bytes")
+        # asked for, and refused when asked for (_check_family_engine).
+        self._slot_state = record.state_slot_bytes is not None
         if self._slot_state:
             if prefix_cache is None and "LLMLB_PREFIX_CACHE" not in os.environ:
                 prefix_cache = False
@@ -646,6 +502,93 @@ class EngineCore:
             else None
         )
 
+        # Speculative decoding (llmlb_tpu/spec): prompt-lookup drafting +
+        # batched K+1-token verification. `spec_decode` sets the DEFAULT for
+        # requests that do not carry their own `speculative` knob (a request
+        # may opt in on an engine defaulting off, and vice versa); the
+        # engine-level max_draft_tokens bounds the verify chunk width, so
+        # there is exactly one verify compile per window bucket. OFF by
+        # default: with no drafter attached anywhere the decode path is
+        # bit-identical to the pre-speculation engine.
+        if spec_decode is None:
+            spec_decode = os.environ.get(
+                "LLMLB_SPEC_DECODE", "0"
+            ).lower() in ("1", "true", "on", "yes")
+        if spec_max_draft is None:
+            spec_max_draft = int(os.environ.get("LLMLB_SPEC_MAX_DRAFT", "4"))
+        if spec_ngram is None:
+            spec_ngram = int(os.environ.get("LLMLB_SPEC_NGRAM", "3"))
+        self.spec = SpecConfig(
+            enabled=bool(spec_decode),
+            max_draft_tokens=max(1, min(int(spec_max_draft), 16)),
+            max_ngram=max(1, int(spec_ngram)),
+            min_ngram=1,
+        )
+        self._spec_available = record.verifies_drafts and self.block == 1
+
+        # Decode burst: number of decode+sample steps fused into ONE device
+        # dispatch (lax.scan with on-device token feedback) per host readback.
+        # The per-step host sync is pure latency — tokens/sec scales with k
+        # while the host↔device round trip dominates the step. Auto: 8 on
+        # TPU (not re-derived for a local chip: ROADMAP Speed 3), 1 elsewhere
+        # (CPU tests keep single-step token-for-token goldens). Emission
+        # becomes k-token bursts; EOS/max_tokens mid-burst are trimmed
+        # host-side.
+        if decode_burst is None:
+            env = os.environ.get("LLMLB_DECODE_BURST")
+            if env:
+                try:
+                    decode_burst = max(1, int(env))
+                except ValueError:
+                    log.warning(
+                        "LLMLB_DECODE_BURST=%r is not an integer; using the "
+                        "auto default", env,
+                    )
+            if decode_burst is None:
+                decode_burst = 8 if jax.default_backend() == "tpu" else 1
+        self.decode_burst = max(1, int(decode_burst))
+
+        # a model sharded across processes runs in lockstep (below)
+        multihost = jax.process_count() > 1
+        # KV page shipping (engine/kv_transfer.py, docs/kv-cache.md): move
+        # serialized pages instead of chunk-prefill replay on handoff and
+        # resume. ON by default but inert until a peer actually offers or
+        # requests a payload; requires a single-host combined loop — split
+        # mode moves pages in-process by block-table exchange already, and
+        # a multihost restore would desync followers whose plan wire
+        # carries no page bytes. LLMLB_KV_SHIP=0 restores today's replay-only
+        # behavior bit for bit (tier-1 pinned).
+        if kv_ship is None:
+            kv_ship = os.environ.get(
+                "LLMLB_KV_SHIP", "1"
+            ).lower() not in ("0", "false", "off", "no")
+        self.kv_ship = (bool(kv_ship) and not multihost
+                        and self.role != "split")
+        # Tiered host-RAM offload (engine/kv_offload.py): cold prefix-cache
+        # evictions and parked-slot pages spill D2H into a bounded LRU tier
+        # and restore H2D on re-hit/resume. Default 0 = off — no spill, no
+        # restore, no behavior change (tier-1 pinned).
+        if kv_offload_bytes is None:
+            try:
+                kv_offload_bytes = int(os.environ.get(
+                    "LLMLB_KV_OFFLOAD_BYTES", "0") or 0)
+            except ValueError:
+                log.warning("LLMLB_KV_OFFLOAD_BYTES is not an integer; "
+                            "offload disabled")
+                kv_offload_bytes = 0
+        self.kv_offload: KVOffloadTier | None = (
+            KVOffloadTier(kv_offload_bytes)
+            if (kv_offload_bytes and kv_offload_bytes > 0
+                and not multihost and self.role != "split")
+            else None
+        )
+        if self.kv_offload is not None:
+            log.info("KV offload tier: %.1f MiB host-RAM budget",
+                     self.kv_offload.budget_bytes / 2**20)
+        if lora_dir is None:
+            lora_dir = os.environ.get("LLMLB_LORA_DIR") or None
+        self._check_family_engine(multihost, lora=bool(lora_dir))
+
         devices = jax.devices()
         if mesh_config is None:
             # Size the latency-critical axes (ep, tp) within ONE slice/host —
@@ -671,15 +614,16 @@ class EngineCore:
             )
         else:
             self.mesh = build_mesh(mesh_config, devices=devices)
+        # Every device program this engine runs, and every call of the
+        # family's entry points (engine/programs.py).
+        self.programs = StepPrograms(
+            self.family, cfg, self.mesh, decode_burst=self.decode_burst,
+            max_draft_tokens=self.spec.max_draft_tokens,
+            num_slots=num_slots, slot_capacity=self.slot_capacity,
+            eos_id=eos_id)
 
         if params is None:
             params = self.family.init_params(cfg, jax.random.PRNGKey(seed))
-        if self.quant.weights and not getattr(
-                self.family, "SUPPORTS_INT8_WEIGHTS", True):
-            raise NotImplementedError(
-                f"{self.family.__name__} does not serve int8 weights: some "
-                "of its projections would be quantized and others not; "
-                "start it without --quantize weights|all")
         if self.quant.weights:
             # Idempotent: checkpoints quantized at load time (streaming,
             # engine/weights.py) pass through; random-init / caller-supplied
@@ -696,8 +640,6 @@ class EngineCore:
         # Adapter deltas stay bf16 on top of (possibly int8) base weights:
         # the delta adds to the projection OUTPUT, so the dequant-on-read
         # path above is untouched.
-        if lora_dir is None:
-            lora_dir = os.environ.get("LLMLB_LORA_DIR") or None
         self.lora = None
         # one-time CP→chunked prefill fallback warning (satellite of the
         # fused-decode PR; the counter keeps counting after the first)
@@ -719,10 +661,6 @@ class EngineCore:
                     "LLMLB_LORA_RANK_CAP", "16"))
             # MoE families serve attention-target adapters only (no pools
             # over the routed expert FFNs).
-            if not getattr(self.family, "SUPPORTS_LORA", True):
-                raise NotImplementedError(
-                    f"{self.family.__name__} carries no adapter pools: "
-                    "start it without --lora-dir")
             targets = (("wq", "wk", "wv", "wo")
                        if getattr(cfg, "num_experts", 0) > 1
                        else ("wq", "wk", "wv", "wo", "wg", "wu", "wd"))
@@ -788,7 +726,8 @@ class EngineCore:
                 self.kv_num_pages,
             )
         self.page_pool = PagePool(self.kv_num_pages)
-        self.cache_k, self.cache_v = self._fresh_kv_pool()
+        self.cache_k, self.cache_v = self.programs.fresh_kv_pool(
+            self.kv_num_pages, self.kv_page_size, self.quant.kv)
         log.info(
             "KV cache: paged%s, %d pages x %d tokens (%d slots, %d "
             "pages/slot) = %.2f GiB in HBM",
@@ -800,10 +739,10 @@ class EngineCore:
         )
 
         # Context-parallel prefill (ring attention over the mesh sp axis):
-        # built lazily per padded length; fills a long prompt's KV in ONE
-        # distributed pass instead of many sequential chunks.
-        self._cp_prefill_fn = None
-        self._use_cp_prefill = self.mesh.shape.get("sp", 1) > 1
+        # fills a long prompt's KV in ONE distributed pass instead of many
+        # sequential chunks.
+        self._use_cp_prefill = (self.mesh.shape.get("sp", 1) > 1
+                                and record.context_parallel_prefill)
         self._prefill_rr = 0  # fair rotation among concurrently-prefilling slots
 
         # Multi-host lockstep (engine/multihost.py): with the model sharded
@@ -882,33 +821,6 @@ class EngineCore:
         self._mask_dirty_rows: set[int] = set()
         self._constrained_count = 0
 
-        # Speculative decoding (llmlb_tpu/spec): prompt-lookup drafting +
-        # batched K+1-token verification. `spec_decode` sets the DEFAULT for
-        # requests that do not carry their own `speculative` knob (a request
-        # may opt in on an engine defaulting off, and vice versa); the
-        # engine-level max_draft_tokens bounds the verify chunk width, so
-        # there is exactly one verify compile per window bucket. OFF by
-        # default: with no drafter attached anywhere the decode path is
-        # bit-identical to the pre-speculation engine.
-        if spec_decode is None:
-            spec_decode = os.environ.get(
-                "LLMLB_SPEC_DECODE", "0"
-            ).lower() in ("1", "true", "on", "yes")
-        if spec_max_draft is None:
-            spec_max_draft = int(os.environ.get("LLMLB_SPEC_MAX_DRAFT", "4"))
-        if spec_ngram is None:
-            spec_ngram = int(os.environ.get("LLMLB_SPEC_NGRAM", "3"))
-        self.spec = SpecConfig(
-            enabled=bool(spec_decode),
-            max_draft_tokens=max(1, min(int(spec_max_draft), 16)),
-            max_ngram=max(1, int(spec_ngram)),
-            min_ngram=1,
-        )
-        self._spec_available = (hasattr(self.family, "verify_step_paged")
-                                and self.block == 1)
-        # jitted verify wrappers per context-window bucket (verify fn +
-        # per-position sampling fused into one dispatch, like _decode_many)
-        self._verify_fns: dict[int, Callable] = {}
         # Per-position verify mask: a persistent [slots, K+1, V] device
         # buffer (lazily allocated — spec-free and constraint-free engines
         # never pay the HBM), refreshed per step ONLY for rows that are
@@ -917,34 +829,6 @@ class EngineCore:
         # verify-path analogue of the decode mask's dirty-row H2D contract.
         self._d_spec_mask: jnp.ndarray | None = None
         self._spec_masked_prev: set[int] = set()
-
-        # Decode burst: number of decode+sample steps fused into ONE device
-        # dispatch (lax.scan with on-device token feedback) per host readback.
-        # The per-step host sync is pure latency — tokens/sec scales with k
-        # while the host↔device round trip dominates the step. Auto: 8 on
-        # TPU (not re-derived for a local chip: ROADMAP Speed 3), 1 elsewhere
-        # (CPU tests keep single-step token-for-token goldens). Emission
-        # becomes k-token bursts; EOS/max_tokens mid-burst are trimmed
-        # host-side.
-        if decode_burst is None:
-            env = os.environ.get("LLMLB_DECODE_BURST")
-            if env:
-                try:
-                    decode_burst = max(1, int(env))
-                except ValueError:
-                    log.warning(
-                        "LLMLB_DECODE_BURST=%r is not an integer; using the "
-                        "auto default", env,
-                    )
-            if decode_burst is None:
-                decode_burst = 8 if jax.default_backend() == "tpu" else 1
-        self.decode_burst = max(1, int(decode_burst))
-        self._decode_many: dict[int, Callable] = {}  # per context window
-        # get-or-build under a lock: the prewarm thread and the step loop
-        # must share ONE jit wrapper per window (two wrappers for the same
-        # signature would compile twice; one wrapper lets jax's internal
-        # compile lock dedup concurrent callers)
-        self._decode_many_lock = threading.Lock()
 
         # Fused decode (docs/fused-decode.md): serve every decode step as
         # ONE device program — the burst scan (even at k=1) with sampling
@@ -979,11 +863,6 @@ class EngineCore:
         # mask rows are then maintained for every constrained slot so the
         # legacy fallback path masks mixed batches correctly.
         self._grammar_fallback = False
-        # Fused program caches: the grammar-masked burst scan per window
-        # (separate from _decode_many, whose int keys tier-1 pins), and the
-        # fused verify per (window, grammar?) pair.
-        self._decode_many_gram: dict[int, Callable] = {}
-        self._verify_fused: dict[tuple[int, bool], Callable] = {}
 
         # Context-window buckets (pow2, up to capacity): every decode reads
         # only the smallest bucket covering all active sequences, so
@@ -1055,47 +934,12 @@ class EngineCore:
         # gateway's /api/traces/{id}?view=timeline. LLMLB_FLIGHTREC=0
         # disables it (emit() returns before its first clock read).
         self.flightrec = FlightRecorder()
-        # KV page shipping (engine/kv_transfer.py, docs/kv-cache.md): move
-        # serialized pages instead of chunk-prefill replay on handoff and
-        # resume. ON by default but inert until a peer actually offers or
-        # requests a payload; requires a single-host combined loop — split
-        # mode moves pages in-process by block-table exchange already, and
-        # a multihost restore would desync followers whose plan wire
-        # carries no page bytes. LLMLB_KV_SHIP=0 restores today's replay-only
-        # behavior bit for bit (tier-1 pinned).
-        if kv_ship is None:
-            kv_ship = os.environ.get(
-                "LLMLB_KV_SHIP", "1"
-            ).lower() not in ("0", "false", "off", "no")
-        self.kv_ship = (bool(kv_ship) and self.coordinator is None
-                        and self.role != "split")
         # Serialized exports captured at drain-park time, keyed by gateway
         # request id, served via POST /v1/kv/export so the gateway can move
         # a mid-stream request's KV to the adopting engine instead of
         # replaying. Bounded by num_slots per drain; entries are consumed on
         # fetch and dropped wholesale on shutdown.
         self._kv_exports: dict[str, dict] = {}
-        # Tiered host-RAM offload (engine/kv_offload.py): cold prefix-cache
-        # evictions and parked-slot pages spill D2H into a bounded LRU tier
-        # and restore H2D on re-hit/resume. Default 0 = off — no spill, no
-        # restore, no behavior change (tier-1 pinned).
-        if kv_offload_bytes is None:
-            try:
-                kv_offload_bytes = int(os.environ.get(
-                    "LLMLB_KV_OFFLOAD_BYTES", "0") or 0)
-            except ValueError:
-                log.warning("LLMLB_KV_OFFLOAD_BYTES is not an integer; "
-                            "offload disabled")
-                kv_offload_bytes = 0
-        self.kv_offload: KVOffloadTier | None = (
-            KVOffloadTier(kv_offload_bytes)
-            if (kv_offload_bytes and kv_offload_bytes > 0
-                and self.coordinator is None and self.role != "split")
-            else None
-        )
-        if self.kv_offload is not None:
-            log.info("KV offload tier: %.1f MiB host-RAM budget",
-                     self.kv_offload.budget_bytes / 2**20)
         # static per-token cost base for perf_info(): parameter count of the
         # served model (device arrays are cheap to .size). Scale leaves are
         # bookkeeping, not parameters — excluded from the FLOP count, as are
@@ -1112,7 +956,7 @@ class EngineCore:
             for v in self.params.values()
         )
         # what the pool holds per slot beside its pages (a recurrent state)
-        self.state_bytes = (num_slots * int(self.family.state_slot_bytes(cfg))
+        self.state_bytes = (num_slots * int(record.state_slot_bytes(cfg))
                             if self._slot_state else 0)
         self._running = False
         self._thread: threading.Thread | None = None
@@ -1138,10 +982,6 @@ class EngineCore:
         self.decode_dispatch_by_loop: dict[str, int] = {
             "main": 0, "prefill": 0, "decode": 0, "handoff": 0,
         }
-        if self.block > 1:
-            self._check_block_engine()
-        if self._slot_state:
-            self._check_slot_state_engine()
         if self.role == "split":
             from llmlb_tpu.disagg.split import SplitRuntime
 
@@ -1152,55 +992,60 @@ class EngineCore:
                 )
             self.split = SplitRuntime(self, self._disagg_prefill_slots_arg)
 
-    def _check_block_engine(self) -> None:
-        """What an engine of a block family needs of its sizes, and what it
-        refuses to start with rather than serve wrong
-        (docs/block-diffusion.md)."""
-        b = self.block
-        sizes = {"slot_capacity": self.slot_capacity,
-                 "kv_page_size": self.kv_page_size,
-                 **{f"prefill bucket {n}": n for n in self.prefill_buckets}}
-        bad = [name for name, n in sizes.items() if n % b]
-        if bad:
-            raise ValueError(
-                f"a block length of {b} must divide {bad}: prefill chunks, "
-                "prefix-cache entries and pages end on block boundaries")
-        for on, what in ((self.quant.kv, "an int8 KV pool (--quantize kv)"),
-                         (self.spec.enabled, "speculative decoding"),
-                         (self.role == "split", "--role split"),
-                         (self.coordinator is not None, "multihost lockstep")):
-            if on:
-                raise NotImplementedError(
-                    f"{self.family.__name__} generates by diffusion over "
-                    f"blocks, which is not served with {what} yet")
-        # KV travels as bytes only between block boundaries, which a park
-        # keeps to, but the adopter's activation would sample a first token:
-        # a block family resumes by chunk-prefill replay alone
-        self.kv_ship = False
-        self.kv_offload = None
-
-    def _check_slot_state_engine(self) -> None:
-        """What an engine of a family with a recurrent state per slot
-        refuses to start with rather than serve wrong
-        (docs/hybrid-state.md): each would read or move pages whose state
-        is not with them."""
-        name = self.family.__name__
-        for on, what, why in (
-            (self._prefix_cache_asked, "the prefix cache",
-             "a hit would extend behind pages that carry no state"),
-            (self.spec.enabled, "speculative decoding",
-             "a rejected draft would leave the state advanced"),
-            (self.kv_ship, "kv_ship",
-             "the state has no wire form: a handoff or resume replays"),
-            (self.kv_offload is not None, "the KV offload tier",
-             "parked pages would come back without their state"),
-            (self.role == "split", "--role split",
-             "the handoff moves pages by block table, not the state"),
-        ):
-            if on:
-                raise NotImplementedError(
-                    f"{name} keeps a recurrent state per slot beside the "
-                    f"page pool, which is not served with {what} yet ({why})")
+    def _check_family_engine(self, multihost: bool, lora: bool) -> None:
+        """What an engine of this family refuses to start with rather than
+        serve wrong or half done, read off its record: what the family does
+        not serve; a block family's sizes and what it is not served with
+        yet (docs/block-diffusion.md); what would read or move pages whose
+        state per slot is not with them (docs/hybrid-state.md)."""
+        record, name = self._record, self.family.__name__
+        record.refuse(int8_weights=self.quant.weights, lora=lora,
+                      int8_kv=self.quant.kv)
+        if self.block > 1:
+            b = self.block
+            sizes = {"slot_capacity": self.slot_capacity,
+                     "kv_page_size": self.kv_page_size,
+                     **{f"prefill bucket {n}": n
+                        for n in self.prefill_buckets}}
+            bad = [size for size, n in sizes.items() if n % b]
+            if bad:
+                raise ValueError(
+                    f"a block length of {b} must divide {bad}: prefill "
+                    "chunks, prefix-cache entries and pages end on block "
+                    "boundaries")
+            for on, what in (
+                    (self.quant.kv, "an int8 KV pool (--quantize kv)"),
+                    (self.spec.enabled, "speculative decoding"),
+                    (self.role == "split", "--role split"),
+                    (multihost, "multihost lockstep")):
+                if on:
+                    raise NotImplementedError(
+                        f"{name} generates by diffusion over blocks, which "
+                        f"is not served with {what} yet")
+            # KV travels as bytes only between block boundaries, which a
+            # park keeps to, but the adopter's activation would sample a
+            # first token: a block family resumes by chunk-prefill replay
+            # alone
+            self.kv_ship = False
+            self.kv_offload = None
+        if self._slot_state:
+            for on, what, why in (
+                (self._prefix_cache_asked, "the prefix cache",
+                 "a hit would extend behind pages that carry no state"),
+                (self.spec.enabled, "speculative decoding",
+                 "a rejected draft would leave the state advanced"),
+                (self.kv_ship, "kv_ship",
+                 "the state has no wire form: a handoff or resume replays"),
+                (self.kv_offload is not None, "the KV offload tier",
+                 "parked pages would come back without their state"),
+                (self.role == "split", "--role split",
+                 "the handoff moves pages by block table, not the state"),
+            ):
+                if on:
+                    raise NotImplementedError(
+                        f"{name} keeps a recurrent state per slot beside "
+                        f"the page pool, which is not served with {what} "
+                        f"yet ({why})")
 
     def _check_block_request(self, request: Request) -> None:
         """Raise ValueError for what a request asks of generation by
@@ -1230,7 +1075,7 @@ class EngineCore:
                 f"'block_length' must be the model's, {self.block}; got "
                 f"{asked['block_length']}")
         cfg = self.cfg
-        self.family.check_generation(
+        self._record.check_generation(
             self.block,
             asked.get("denoising_steps", cfg.denoising_steps),
             asked.get("remasking_strategy", cfg.remasking_strategy),
@@ -1275,66 +1120,29 @@ class EngineCore:
                 daemon=True,
             ).start()
 
+    def _decode_operands(self, key) -> tuple:
+        """What a dispatch hands this engine's decode program before the
+        live rows: the dense burst's, or a block family's (the per-row state
+        its program takes and returns; behind the tables what it only reads:
+        each row's sampling params and its unmasking procedure)."""
+        if self.block > 1:
+            return (self.params, self._d_blk, self._d_masked,
+                    self._d_seq_lens, self._d_left, self._d_skip,
+                    self.cache_k, self.cache_v, self._d_block_tables,
+                    self._d_temps, self._d_top_ps, self._d_top_ks,
+                    self._d_seeds, self._d_per_pass, self._d_dynamic,
+                    self._d_threshold, key)
+        return (self.params, self._d_last_tokens, self._d_seq_lens,
+                self.cache_k, self.cache_v, self._d_block_tables,
+                self._d_temps, self._d_top_ps, self._d_top_ks,
+                self._d_seeds, key)
+
     def _prewarm_windows(self) -> None:
         compilelog.set_thread_class("prewarm")
-
-        def sharded(x):
-            # Shardings are part of jax's executable cache key: a prewarm
-            # lowered without them compiles a different (unsharded) variant
-            # and the real dispatch would still stall on a fresh compile.
-            # Params and caches carry theirs. The per-slot vectors and the
-            # key are lowered unspecified below, while a dispatch hands them
-            # over placed on the mesh (_on_mesh): the two still land under
-            # different keys (ROADMAP Speed 4).
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
-
-        def plain(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
-
-        param_shapes = {k: sharded(v) for k, v in self.params.items()}
-        # the caches may be quantized {"q","s"} pytrees — map per leaf
-        cache_k_shapes = jax.tree.map(sharded, self.cache_k)
-        cache_v_shapes = jax.tree.map(sharded, self.cache_v)
-        live = jax.ShapeDtypeStruct((self.num_slots,), np.bool_)  # _live_rows
-        args = [
-            param_shapes,
-            plain(self._d_last_tokens),
-            plain(self._d_seq_lens),
-            cache_k_shapes, cache_v_shapes,
-            plain(self._d_block_tables),
-            plain(self._d_temps), plain(self._d_top_ps),
-            plain(self._d_top_ks), plain(self._d_seeds),
-            plain(self._key),  # split keys keep this shape/dtype
-            live,
-        ]
-        if self.block > 1:
-            args = [param_shapes, *map(plain, self._block_state()),
-                    cache_k_shapes, cache_v_shapes,
-                    plain(self._d_block_tables),
-                    *map(plain, self._block_sampling()), plain(self._key),
-                    live]
-        for w in self._window_buckets:
-            if not self._running:
-                return
-            try:
-                if self.block > 1:
-                    self._block_many_for(w).lower(*args).compile()
-                elif self.decode_burst > 1 or self.fused_decode:
-                    # fused engines dispatch the burst scan even at k == 1;
-                    # grammar/fused-verify variants compile on first use
-                    # (their tables don't exist until a schema registers)
-                    self._decode_many_for(w).lower(*args).compile()
-                else:
-                    # single-step mode compiles decode_step_paged per window
-                    self.family.decode_step_paged.lower(
-                        param_shapes, self.cfg, plain(self._d_last_tokens),
-                        plain(self._d_seq_lens), cache_k_shapes,
-                        cache_v_shapes, plain(self._d_block_tables),
-                        self.mesh, window=w, live=live,
-                    ).compile()
-            except Exception:  # pragma: no cover - best-effort warmup
-                log.exception("window %d prewarm failed (will compile "
-                              "on first use)", w)
+        # split keys keep the engine key's shape and dtype
+        self.programs.prewarm(
+            self._window_buckets, self._decode_operands(self._key),
+            fused_decode=self.fused_decode, running=lambda: self._running)
 
     def stop(self) -> None:
         if self.coordinator is not None and self.coordinator.is_leader:
@@ -1364,7 +1172,7 @@ class EngineCore:
                 "every configured bucket)"
             )
         # Prompts beyond the largest one-shot bucket run through chunked
-        # prefill (prefill_extend_pages); the only hard cap is slot capacity.
+        # prefill (programs.extend); the only hard cap is slot capacity.
         # a slot keeps `block` cells free past a sequence: the one that rows
         # which are not decoding write their garbage to, and for a block
         # family the rest of a block before it (block is 1 otherwise)
@@ -1602,10 +1410,6 @@ class EngineCore:
             tokens_dev = self._replicate(tokens_dev)
         return np.asarray(tokens_dev)
 
-    def drain_active(self) -> bool:
-        """True while the engine refuses new admissions (graceful drain)."""
-        return self.draining
-
     def begin_drain(self) -> None:
         """Stop admitting new work; in-flight slots keep decoding. One-way —
         the draining process exits or is restarted by its supervisor."""
@@ -1757,7 +1561,7 @@ class EngineCore:
         # (the original compiled programs, bit for bit).
         self._d_lora_idx = self._on_mesh(np.zeros((n,), np.int32))
         if self.block > 1:
-            # A block family's row (_block_state, _block_sampling): the open
+            # A block family's row (_decode_operands): the open
             # block's ids and which of them are still masked, the positions
             # it may yet commit, the given tokens at the open block's head;
             # and the request's procedure. `_d_seq_lens` is the COMMITTED
@@ -1772,17 +1576,6 @@ class EngineCore:
             self._d_dynamic = self._on_mesh(np.zeros((n,), np.bool_))
             self._d_threshold = self._on_mesh(np.ones((n,), np.float32))
 
-    def _fresh_kv_pool(self):
-        """A zeroed K and V page pool, placed on the mesh."""
-        slots = {"num_slots": self.num_slots} if self._slot_state else {}
-        ck, cv = self.family.init_kv_pages(self.cfg, self.kv_num_pages,
-                                           self.kv_page_size,
-                                           quantized=self.quant.kv, **slots)
-        ck_sh, cv_sh = self.family.kv_pages_shardings(
-            self.cfg, self.mesh, quantized=self.quant.kv
-        )
-        return jax.device_put(ck, ck_sh), jax.device_put(cv, cv_sh)
-
     def _reset_caches(self) -> None:
         # every page mapping is void with the rebuilt pool
         self.page_pool.reset()
@@ -1791,7 +1584,8 @@ class EngineCore:
         self._d_block_tables = jnp.asarray(self._block_tables)
         self._tables_dirty = False
         self._in_flight = None
-        self.cache_k, self.cache_v = self._fresh_kv_pool()
+        self.cache_k, self.cache_v = self.programs.fresh_kv_pool(
+            self.kv_num_pages, self.kv_page_size, self.quant.kv)
         self._seq_lens[:] = 0
         # activation donates the per-slot arrays like the caches
         self._init_slot_state()
@@ -1866,7 +1660,7 @@ class EngineCore:
         if kv_pages:
             self.metrics.record_decode_kv_pages(**kv_pages)
         if counters:
-            self.metrics.record_step_counters(counters, self._counter_max)
+            self.metrics.record_step_counters(counters)
         if block:
             self.metrics.record_block_passes(block)
         self.metrics.record_step_phases(phases, slow=slow)
@@ -2313,7 +2107,7 @@ class EngineCore:
         of the family; None where its pool has no wire form (a latent
         pool's two arrays are not K and V of one shape): such an engine
         ships nothing and adopts nothing, and every move replays."""
-        return self.family.kv_wire_cell(self.cfg)
+        return self._record.kv_wire_cell(self.cfg)
 
     def _kv_header(self, tokens: int, num_pages: int) -> KVWireHeader:
         num_kv_heads, head_dim = self._kv_wire_cell() or (0, 0)
@@ -2874,9 +2668,7 @@ class EngineCore:
         """Claim a slot for a prompt beyond the largest one-shot bucket.
         Returns True when it ran a heavy synchronous prefill (CP path)."""
         slot = self.slots[slot_id]
-        cp_capable = self._use_cp_prefill and hasattr(
-            self.family, "make_context_parallel_prefill"
-        )
+        cp_capable = self._use_cp_prefill
         lora_request = self.lora is not None and request.sampling.lora
         if cp_capable and not lora_request:
             # Ring-attention prefill: one distributed pass over the mesh
@@ -2925,7 +2717,6 @@ class EngineCore:
         only take the hit when the cache covers at least half the prompt."""
         return (
             self._use_cp_prefill
-            and hasattr(self.family, "make_context_parallel_prefill")
             and n > (self.prefill_buckets[-1] if self.prefill_buckets else 0)
             and use_len < n // 2
         )
@@ -3212,129 +3003,6 @@ class EngineCore:
         self._block_tables[slot_id, keep:] = 0
         self._tables_dirty = True
 
-    def _build_verify(self, window: int) -> Callable:
-        """Jit one fused verify dispatch for a context-window bucket: the
-        K+1-token extend (family verify step) plus per-position sampling —
-        one device program, one host readback per verify step. Returns
-        [B, K+2] tokens: column 0 echoes the input last-token column (the
-        deferred-first-emission ride-along, same contract as decode's
-        first_in row), columns 1.. are the model's samples per position."""
-        family, cfg, mesh = self.family, self.cfg, self.mesh
-
-        def run(params, ids, chunk_lens, start_pos, tables,
-                cache_k, cache_v, temps, top_ps, top_ks, seeds, mask,
-                key, lora_idx=None):
-            logits, cache_k, cache_v, *_ = family.verify_step_paged(
-                params, cfg, ids, chunk_lens, start_pos, tables,
-                cache_k, cache_v, mesh, window=window,
-                lora_idx=lora_idx,
-            )
-            toks = _sample_chunk(logits, key, temps, top_ps, top_ks,
-                                 seeds, mask, start_pos)
-            return (jnp.concatenate([ids[:, :1], toks], axis=1),
-                    cache_k, cache_v)
-
-        return jax.jit(run, donate_argnums=(5, 6))
-
-    def _verify_for(self, window: int) -> Callable:
-        with self._decode_many_lock:
-            fn = self._verify_fns.get(window)
-            if fn is None:
-                fn = self._build_verify(window)
-                self._verify_fns[window] = fn
-            return fn
-
-    def _build_verify_fused(self, window: int, grammar: bool) -> Callable:
-        """Jit the FUSED verify step: everything the legacy verify path did
-        across several device programs — last-token splice into column 0,
-        per-position grammar masks (device transition-table walk instead of
-        the host FSM lookahead), the K+1-token extend, per-position
-        sampling, accept counting, and the seq-len/last-token advance —
-        compiled into ONE dispatch. Output tokens are [B, K+3]: column 0
-        echoes the input last token, columns 1..K+1 the samples, and the
-        final column the in-program accepted-prefix count per row."""
-        family, cfg, mesh = self.family, self.cfg, self.mesh
-        k1 = self.spec.max_draft_tokens + 1
-
-        def gram_mask(gram_table, gram_state, ids):
-            # Column j's mask is the grammar state after consuming drafts
-            # 1..j — the device analogue of the host pre-walk. A disallowed
-            # draft clamps (grammar_advance), replicating the last live
-            # state's row exactly like the legacy stripe padding; its
-            # sample can then never equal the draft, so acceptance stops
-            # at the same position the host truncation would have cut.
-            s = gram_state
-            biases = [grammar_bias(gram_table, s)]
-            for j in range(1, k1):
-                s = grammar_advance(gram_table, s, ids[:, j])
-                biases.append(grammar_bias(gram_table, s))
-            return jnp.stack(biases, axis=1).reshape(
-                ids.shape[0] * k1, -1
-            )
-
-        def finish(ids, toks, chunk_lens, start_pos, lens, last_tokens,
-                   active_mask):
-            # accepted = longest prefix of drafts matching the model's own
-            # samples — the same comparison the host emit loop walks
-            # (tokens[i, 1+j] == d[j]), vectorized as a cumprod
-            b = ids.shape[0]
-            cols = jnp.arange(1, k1, dtype=jnp.int32)[None, :]
-            matches = ((toks[:, :-1] == ids[:, 1:])
-                       & (cols < chunk_lens[:, None]))
-            accepted = jnp.sum(
-                jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1
-            ).astype(jnp.int32)
-            # Active rows advance by accepted + 1 (the correction/bonus
-            # sample); every other row — prefilling slots parked at
-            # capacity-1, free slots — must keep its lens/last untouched,
-            # which the host-side scatter got for free by only writing
-            # surviving rows.
-            new_lens = jnp.where(active_mask,
-                                 start_pos + accepted + 1, lens)
-            new_last = jnp.where(
-                active_mask,
-                toks[jnp.arange(b, dtype=jnp.int32), accepted],
-                last_tokens,
-            )
-            out = jnp.concatenate(
-                [ids[:, :1], toks, accepted[:, None]], axis=1
-            )
-            return out, new_last, new_lens
-
-        def run(params, ids, chunk_lens, start_pos, tables,
-                cache_k, cache_v, temps, top_ps, top_ks, seeds, key,
-                last_tokens, active_mask, lens,
-                gram_table=None, gram_state=None, lora_idx=None):
-            ids = ids.at[:, 0].set(last_tokens)
-            mask = (gram_mask(gram_table, gram_state, ids)
-                    if grammar else None)
-            logits, cache_k, cache_v, *_ = family.verify_step_paged(
-                params, cfg, ids, chunk_lens, start_pos, tables,
-                cache_k, cache_v, mesh, window=window,
-                lora_idx=lora_idx,
-            )
-            toks = _sample_chunk(logits, key, temps, top_ps, top_ks,
-                                 seeds, mask, start_pos)
-            out, new_last, new_lens = finish(
-                ids, toks, chunk_lens, start_pos, lens, last_tokens,
-                active_mask,
-            )
-            return out, new_last, new_lens, cache_k, cache_v
-
-        return jax.jit(run, donate_argnums=(5, 6))
-
-    def _verify_fused_for(self, window: int, grammar: bool) -> Callable:
-        with self._decode_many_lock:
-            key = (window, grammar)
-            fn = self._verify_fused.get(key)
-            if fn is None:
-                fn = self._shared_program(
-                    "verify_fused",
-                    (self.spec.max_draft_tokens, window, grammar),
-                    lambda: self._build_verify_fused(window, grammar))
-                self._verify_fused[key] = fn
-            return fn
-
     def _verify_active(self, active: list[int], drafts: dict[int, list[int]],
                        lookahead: dict[int, list[int]],
                        step: StepSpan, fused: bool = False) -> bool:
@@ -3427,7 +3095,7 @@ class EngineCore:
         step.mark("dispatch")
         lora_idx = self._d_lora_idx if self.lora is not None else None
         if fused:
-            fn = self._verify_fused_for(window, grammar)
+            fn = self.programs.verify(window, fused=True, grammar=grammar)
             gram_args = ({"gram_table": self._grammar_tables.device(),
                           "gram_state": jnp.asarray(gs)} if grammar else {})
             # jnp.asarray is an H2D transfer, not a device program; the
@@ -3450,7 +3118,7 @@ class EngineCore:
             # activated slots' first tokens never round-tripped through
             # the host
             ids_dev = jnp.asarray(ids).at[:, 0].set(self._d_last_tokens)
-            fn = self._verify_for(window)
+            fn = self.programs.verify(window, fused=False)
             toks_dev, self.cache_k, self.cache_v = fn(
                 self.params, ids_dev, jnp.asarray(chunk_lens),
                 jnp.asarray(start_pos), self._d_block_tables,
@@ -3719,8 +3387,7 @@ class EngineCore:
             "bytes_per_page": kv_page_bytes(self.cfg, self.kv_page_size,
                                             quantized=self.quant.kv),
             # all layers of one token: the family's own cell
-            "bytes_per_token": kv_pool_layers(self.cfg)
-            * kv_bytes_per_token_layer(self.cfg, self.quant.kv),
+            "bytes_per_token": kv_page_bytes(self.cfg, 1, self.quant.kv),
             "hbm_bytes": kv_pool_bytes(self.cfg, self.kv_num_pages,
                                        self.kv_page_size,
                                        quantized=self.quant.kv),
@@ -3782,8 +3449,8 @@ class EngineCore:
         bytes_tok = model_bytes_per_token(
             self.cfg, self.n_params, mean_ctx, batch=batch,
             weight_bytes=self.param_bytes,
-            kv_token_layer_bytes=kv_bytes_per_token_layer(self.cfg,
-                                                          self.quant.kv),
+            kv_token_layer_bytes=self._record.kv_token_layer_bytes(
+                self.cfg, self.quant.kv),
         )
         info = {
             "device_kind": str(kind),
@@ -3817,13 +3484,6 @@ class EngineCore:
                 bytes_tok * per_chip / spec.peak_hbm_bw, 6
             )
         return info
-
-    def _state_slots(self, slot_ids) -> dict:
-        """The keyword that tells a prefill call its rows' slots, for a
-        family that keeps state per slot; nothing for any other."""
-        if not self._slot_state:
-            return {}
-        return {"slot_ids": jnp.asarray(slot_ids, jnp.int32)}
 
     def _prefill_counters(self, stats: list) -> dict | None:
         """A prefill dispatch's step counters on the host. The dispatch has
@@ -3870,18 +3530,11 @@ class EngineCore:
         # padding rows repeat the last real slot's table row, so their
         # duplicate scatters rewrite identical cells (same trick as ids)
         (logits, self.cache_k, self.cache_v,
-         *stats) = self.family.prefill_into_pages(
-            self.params,
-            self.cfg,
-            jnp.asarray(ids),
-            jnp.asarray(lens),
+         *stats) = self.programs.prefill(
+            self.params, jnp.asarray(ids), jnp.asarray(lens),
             jnp.asarray(self._block_tables[slot_ids]),
-            self.cache_k,
-            self.cache_v,
-            self.mesh,
-            lora_idx=lora_idx,
-            **self._state_slots(slot_ids),
-        )
+            self.cache_k, self.cache_v,
+            slot_ids=slot_ids, lora_idx=lora_idx)
         step.mark("compute")
         # jitted prefill returns futures (async dispatch); block before timing
         # or the histogram records dispatch overhead, not device execution.
@@ -3971,7 +3624,7 @@ class EngineCore:
         (self._key, firsts,
          (self._d_temps, self._d_top_ps, self._d_top_ks, self._d_seeds,
           self._d_seq_lens, self._d_last_tokens,
-          self._d_lora_idx)) = _activate_rows(
+          self._d_lora_idx)) = self.programs.activate_rows(
             logits, self._key, temps, top_ps, top_ks, seeds,
             padded_lens, padded_slot_ids, bias, lora_rows,
             (self._d_temps, self._d_top_ps, self._d_top_ks, self._d_seeds,
@@ -4023,18 +3676,6 @@ class EngineCore:
                         "_d_seq_lens", "_d_blk", "_d_masked", "_d_left",
                         "_d_skip", "_d_per_pass", "_d_dynamic",
                         "_d_threshold")
-
-    def _block_state(self) -> tuple:
-        """A block family's per-row decode state, in the order the block
-        program takes and returns it."""
-        return (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
-                self._d_skip)
-
-    def _block_sampling(self) -> tuple:
-        """What the block program reads and never writes, after the tables:
-        each row's sampling params and its unmasking procedure."""
-        return (self._d_temps, self._d_top_ps, self._d_top_ks, self._d_seeds,
-                self._d_per_pass, self._d_dynamic, self._d_threshold)
 
     def _activate_block_group(self, group: list[tuple[int, Request, int]],
                               padded_slot_ids: np.ndarray,
@@ -4088,7 +3729,7 @@ class EngineCore:
                 skip, per_pass, dynamic, threshold)
         for arr in rows:
             arr[g:] = arr[g - 1]
-        state = _activate_block_rows(
+        state = self.programs.activate_block_rows(
             padded_slot_ids, rows,
             tuple(getattr(self, name) for name in self._BLOCK_ROW_STATE))
         for name, arr in zip(self._BLOCK_ROW_STATE, state):
@@ -4123,16 +3764,12 @@ class EngineCore:
         """One-shot ring-attention prefill of a long prompt, scattered into
         the slot's pages (engine wiring for
         make_context_parallel_prefill)."""
-        if self._cp_prefill_fn is None:
-            self._cp_prefill_fn = self.family.make_context_parallel_prefill(
-                self.cfg, self.mesh
-            )
         padded = self._cp_bucket_for(n)
         ids = np.zeros((1, padded), np.int32)
         ids[0, :n] = self._effective_prompt(request)
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
-        logits, k_all, v_all = self._cp_prefill_fn(
+        logits, k_all, v_all = self.programs.context_parallel_prefill(
             self.params, jnp.asarray(ids), jnp.asarray([n], np.int32)
         )
         step.mark("compute")
@@ -4200,19 +3837,13 @@ class EngineCore:
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
         (logits, self.cache_k, self.cache_v,
-         *stats) = self.family.prefill_extend_pages(
-            self.params,
-            self.cfg,
-            jnp.asarray(ids),
+         *stats) = self.programs.extend(
+            self.params, jnp.asarray(ids),
             jnp.asarray([chunk_len], np.int32),
             jnp.asarray([start], np.int32),
             jnp.asarray(self._block_tables[slot_id:slot_id + 1]),
-            self.cache_k,
-            self.cache_v,
-            self.mesh,
-            lora_idx=lora_idx,
-            **self._state_slots([slot_id]),
-        )
+            self.cache_k, self.cache_v,
+            slot_ids=[slot_id], lora_idx=lora_idx)
         step.mark("compute")
         jax.block_until_ready(logits)  # async dispatch; time real execution
         self.metrics.record_prefill_step(step.mark("emit") - step.t0)
@@ -4275,124 +3906,6 @@ class EngineCore:
         live = sum(-(-(int(self._seq_lens[i]) + k) // ps) for i in active)
         return {"kv_pages_live": live,
                 "kv_pages_window": self.num_slots * -(-window // ps)}
-
-    def _build_decode_many(self, k: int, window: int) -> Callable:
-        """Jit a k-step decode: lax.scan feeds each step's sampled tokens
-        back into the next ON DEVICE, so the host syncs once per k tokens
-        instead of once per token. Sampling params are scan-invariant;
-        the caches are donated (the scan carries them in place) and the
-        block tables are scan-invariant too — _ensure_decode_pages
-        pre-allocates every page the burst will write. `live` is the
-        dispatch's _live_rows: the rows it emits for, so the attention
-        kernel walks their pages and no other row's. A family with step
-        counters (step_counter_shapes) returns them after its caches, and the
-        burst's sums ride behind the tokens in the one array fetched."""
-        family, cfg, mesh = self.family, self.cfg, self.mesh
-        shapes, max_names = self._counter_shapes, self._counter_max
-
-        def many(params, last, lens, cache_k, cache_v, tables,
-                 temps, top_ps, top_ks, seeds, key, live, lora_idx=None):
-            keys = jax.random.split(key, k)
-
-            def body(carry, step_key):
-                last, lens, ck, cv = carry
-                logits, ck, cv, *stats = family.decode_step_paged(
-                    params, cfg, last, lens, ck, cv, tables, mesh,
-                    window=window, lora_idx=lora_idx, live=live,
-                )
-                toks = sample_tokens(logits, step_key, temps, top_ps,
-                                     top_ks, None, seeds, lens)
-                return (toks, lens + 1, ck, cv), (toks, stats)
-
-            first_in = last  # pre-burst tokens: pending first emissions
-            (last, lens, cache_k, cache_v), (toks, stats) = jax.lax.scan(
-                body, (last, lens, cache_k, cache_v), keys
-            )
-            toks = jnp.concatenate([first_in[None, :], toks], axis=0)
-            if shapes:
-                toks = _pack_step_counters(toks, stats[0], shapes, max_names)
-            return last, lens, cache_k, cache_v, toks
-
-        return jax.jit(many, donate_argnums=(3, 4))
-
-    def _shared_program(self, kind: str, extra: tuple, build) -> Callable:
-        """Fetch/build a jit-wrapped step program through the process-wide
-        _PROGRAM_CACHE so engines sharing a config reuse one executable
-        set. The build key is everything the trace closes over (family,
-        cfg, mesh, plus the caller's k/window/variant in `extra`);
-        array shapes (slots, pages, quantized-or-not pytrees) go through
-        jit's own shape-keyed cache per call, not the build key."""
-        key = (kind, id(self.family), id(self.cfg), self.mesh) + extra
-        with _PROGRAM_CACHE_LOCK:
-            hit = _PROGRAM_CACHE.get(key)
-        if hit is None:
-            fn = build()
-            with _PROGRAM_CACHE_LOCK:
-                # racing builders converge on whichever landed first
-                hit = _PROGRAM_CACHE.setdefault(
-                    key, (fn, self.family, self.cfg))
-        return hit[0]
-
-    def _decode_many_for(self, window: int) -> Callable:
-        with self._decode_many_lock:
-            fn = self._decode_many.get(window)
-            if fn is None:
-                fn = self._shared_program(
-                    "decode_many", (self.decode_burst, window),
-                    lambda: self._build_decode_many(self.decode_burst,
-                                                    window))
-                self._decode_many[window] = fn
-            return fn
-
-    def _build_decode_many_gram(self, k: int, window: int) -> Callable:
-        """Grammar-masked variant of the burst scan: same k-step decode
-        with, per step, the sampling bias gathered from the device grammar
-        table and the per-row cursor advanced in-program on the sampled
-        token — so constrained slots ride the burst instead of forcing the
-        batch into single-step decode. Free rows carry cursor 0 (the
-        all-zero table row): their bias is + 0.0 everywhere, bit-preserving
-        the unconstrained sampling path."""
-        family, cfg, mesh = self.family, self.cfg, self.mesh
-        shapes, max_names = self._counter_shapes, self._counter_max
-
-        def many(params, last, lens, cache_k, cache_v, tables,
-                 temps, top_ps, top_ks, seeds, key, live, gram_table,
-                 gram_state, lora_idx=None):
-            keys = jax.random.split(key, k)
-
-            def body(carry, step_key):
-                last, lens, gs, ck, cv = carry
-                logits, ck, cv, *stats = family.decode_step_paged(
-                    params, cfg, last, lens, ck, cv, tables, mesh,
-                    window=window, lora_idx=lora_idx, live=live,
-                )
-                bias = grammar_bias(gram_table, gs)
-                toks = sample_tokens(logits, step_key, temps, top_ps,
-                                     top_ks, bias, seeds, lens)
-                gs = grammar_advance(gram_table, gs, toks)
-                return (toks, lens + 1, gs, ck, cv), (toks, stats)
-
-            first_in = last  # pre-burst tokens: pending first emissions
-            (last, lens, _, cache_k, cache_v), (toks, stats) = jax.lax.scan(
-                body, (last, lens, gram_state, cache_k, cache_v), keys
-            )
-            toks = jnp.concatenate([first_in[None, :], toks], axis=0)
-            if shapes:
-                toks = _pack_step_counters(toks, stats[0], shapes, max_names)
-            return last, lens, cache_k, cache_v, toks
-
-        return jax.jit(many, donate_argnums=(3, 4))
-
-    def _decode_many_gram_for(self, window: int) -> Callable:
-        with self._decode_many_lock:
-            fn = self._decode_many_gram.get(window)
-            if fn is None:
-                fn = self._shared_program(
-                    "decode_many_gram", (self.decode_burst, window),
-                    lambda: self._build_decode_many_gram(self.decode_burst,
-                                                         window))
-                self._decode_many_gram[window] = fn
-            return fn
 
     def _decode_active(self) -> bool:
         decode_pool = (self.split.decode_pool if self.split is not None
@@ -4472,19 +3985,10 @@ class EngineCore:
         kv_pages = self._kv_pages(active, 1, window)
         step.mark("dispatch")
         (logits, self.cache_k, self.cache_v,
-         *_) = self.family.decode_step_paged(
-            self.params,
-            self.cfg,
-            self._d_last_tokens,
-            self._d_seq_lens,
-            self.cache_k,
-            self.cache_v,
-            self._d_block_tables,
-            self.mesh,
-            window=window,
-            lora_idx=lora_idx,
-            live=self._live_rows(active),
-        )
+         *_) = self.programs.decode_step(
+            self.params, self._d_last_tokens, self._d_seq_lens,
+            self.cache_k, self.cache_v, self._d_block_tables,
+            window=window, live=self._live_rows(active), lora_idx=lora_idx)
         mask = None
         if constrained_active:
             step.mark("host_sync")
@@ -4570,8 +4074,7 @@ class EngineCore:
         prev: _Burst | None = None
         while True:
             rows, window, kv_pages = plan
-            fn = (self._decode_many_gram_for(window) if grammar
-                  else self._decode_many_for(window))
+            fn = self.programs.decode_many(window, grammar)
             if prev is None:
                 step.mark("dispatch")
             else:
@@ -4582,10 +4085,7 @@ class EngineCore:
             burst = _Burst(step, rows, kv_pages, blocked_by)
             (self._d_last_tokens, self._d_seq_lens, self.cache_k,
              self.cache_v, toks_dev) = fn(
-                self.params, self._d_last_tokens, self._d_seq_lens,
-                self.cache_k, self.cache_v, self._d_block_tables,
-                self._d_temps, self._d_top_ps, self._d_top_ks,
-                self._d_seeds, sk, self._live_rows(burst.slots),
+                *self._decode_operands(sk), self._live_rows(burst.slots),
                 lora_idx=lora_idx, **gram_args,
             )
             self._in_flight = burst
@@ -4623,10 +4123,7 @@ class EngineCore:
         """Emit a fetched burst's tokens and finalize its record; `closed`:
         LoopClock.handover has closed its step already."""
         rows = burst.rows
-        tokens, counters = burst.fetched, None
-        if self._counter_shapes:
-            tokens, counters = _unpack_step_counters(
-                tokens, k + 1, self.num_slots, self._counter_shapes)
+        tokens, counters = self.programs.unpack(burst.fetched, k + 1)
         self.metrics.record_decode_step(burst.step_s, len(rows))
         self._emit_fetched(tokens, rows, itl=burst.step_s)
         record = self._observe_step if closed else self._record_step
@@ -4716,111 +4213,6 @@ class EngineCore:
         and a block of padding of a row that does not."""
         return self.block * (self.decode_burst + 1)
 
-    def _build_block_many(self, k: int, window: int) -> Callable:
-        """Jit a burst of k BLOCK PASSES (the decode program of a family
-        that generates by diffusion over blocks; traced as `many`, like
-        _build_decode_many's). Per row the scan carries the open block's ids
-        and mask flags, the committed length, the positions left and the
-        given tokens at the block's head. One pass is ONE call of the
-        family's block pass (verify_step_paged) 2B positions wide. A row
-        whose open block ENTERED the pass with no mask commits it, and where
-        it goes on (no EOS past its given tokens in the block, positions
-        left behind it) the commit rides with the next block's first
-        unmasking: the row sends [its complete block | B masks], the layers
-        write the block's K and V to the pool before the masks attend to
-        them under the block mask, length += B, and the logits wanted are
-        the second half's. Every other row sends [its open block | padding]
-        (the padding goes to no expert and writes past the row's valid
-        range) and wants the first half's. Then each wanted position's
-        sampled id and its probability, and the masked positions of the
-        open block unmask by the row's strategy: the `per_pass` most
-        probable, and under `dynamic` every one above `threshold`. Rows are
-        at different passes of their blocks. A row whose positions are used
-        up, or whose committed block holds EOS past its given tokens, stops
-        with that commit: it runs no further pass and writes to the slot's
-        last cell alone, as the rows that are not decoding do. Returns the
-        state, the caches, and ONE int32 array for the burst's one fetch:
-        per pass B + 2 rows of [SLOTS] — the committed block's ids (-1: no
-        commit), the positions unmasked (of the block behind a commit, in a
-        pass that did both), whether the row ran — and the family's step
-        counters behind them (_pack_step_counters)."""
-        family, cfg, mesh = self.family, self.cfg, self.mesh
-        shapes, max_names = self._counter_shapes, self._counter_max
-        b, mask_id, eos = self.block, cfg.mask_token_id, self.eos_id
-        park = self.slot_capacity - 1
-
-        def many(params, blk, masked, lens, left, skip, cache_k, cache_v,
-                 tables, temps, top_ps, top_ks, seeds, per_pass, dynamic,
-                 threshold, key, live):
-            keys = jax.random.split(key, k)
-            offs = jnp.arange(b, dtype=jnp.int32)
-
-            def body(carry, step_key):
-                blk, masked, lens, left, skip, ck, cv = carry
-                run = live & (left > 0)
-                commit = run & ~jnp.any(masked, axis=1)
-                stop = commit & ((left <= b) | jnp.any(
-                    (blk == eos) & (offs[None, :] >= skip[:, None]), axis=1))
-                fused = commit & ~stop  # the next block opens in this pass
-                out_blk = jnp.where(commit[:, None], blk, -1)
-                logits, ck, cv, *stats = family.verify_step_paged(
-                    params, cfg,
-                    jnp.concatenate([blk, jnp.full_like(blk, mask_id)], axis=1),
-                    jnp.where(run, jnp.where(fused, 2 * b, b), 0),
-                    jnp.where(run, lens, park), tables, ck, cv, mesh,
-                    window=window, logits_from=jnp.where(fused, b, 0),
-                    logits_len=b)
-                # from here on the open block is the one the logits are of
-                # (a row that stops opens none: nothing of it is masked)
-                blk = jnp.where(fused[:, None], mask_id, blk)
-                masked = masked | fused[:, None]
-                lens = jnp.where(commit, lens + b, lens)
-                ids, conf = _sample_block(
-                    logits, step_key, temps, top_ps, top_ks, seeds, lens,
-                    jnp.sum(masked, axis=1, dtype=jnp.int32))
-                conf = jnp.where(masked, conf, -1.0)
-                # rank among the block's masked positions, the more
-                # probable first and of equals the earlier: [S, i, j] is
-                # "j goes before i"
-                ahead = (conf[:, None, :] > conf[:, :, None]) | (
-                    (conf[:, None, :] == conf[:, :, None])
-                    & (offs[None, None, :] < offs[None, :, None]))
-                rank = jnp.sum(ahead, axis=2, dtype=jnp.int32)
-                pick = ((rank < per_pass[:, None])
-                        | (dynamic[:, None] & (conf > threshold[:, None])))
-                pick = pick & masked & run[:, None]
-                out = jnp.concatenate([
-                    out_blk,
-                    jnp.sum(pick, axis=1, dtype=jnp.int32)[:, None],
-                    run[:, None].astype(jnp.int32)], axis=1)  # [S, B + 2]
-                blk = jnp.where(pick, ids, blk)
-                masked = masked & ~pick
-                left = jnp.where(stop, 0, jnp.where(commit, left - b, left))
-                skip = jnp.where(commit, 0, skip)
-                return (blk, masked, lens, left, skip, ck, cv), (out.T, stats)
-
-            (blk, masked, lens, left, skip, cache_k, cache_v), (
-                out, stats) = jax.lax.scan(
-                body, (blk, masked, lens, left, skip, cache_k, cache_v), keys)
-            out = out.reshape(k * (b + 2), -1)
-            if shapes:
-                out = _pack_step_counters(out, stats[0], shapes, max_names)
-            return blk, masked, lens, left, skip, cache_k, cache_v, out
-
-        return jax.jit(many, donate_argnums=(6, 7))
-
-    def _block_many_for(self, window: int) -> Callable:
-        with self._decode_many_lock:
-            fn = self._decode_many.get(window)
-            if fn is None:
-                fn = self._shared_program(
-                    "block_many", (self.decode_burst, window, self.eos_id,
-                                   self.slot_capacity),
-                    lambda: self._build_block_many(self.decode_burst,
-                                                   window))
-                self._decode_many[window] = fn
-            return fn
-
     def _decode_blocks(self, active: list[int], clock: LoopClock) -> bool:
         """A decode step of a block family: ONE dispatch of `decode_burst`
         block passes over the decoding rows, one fetch, and the blocks the
@@ -4838,21 +4230,17 @@ class EngineCore:
         self._key, sk = jax.random.split(self._key)
         window = self._window_for(active, reach - 1)
         kv_pages = self._kv_pages(active, reach, window)
-        fn = self._block_many_for(window)
+        fn = self.programs.block_many(window)
         step.mark("dispatch")
         (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
          self._d_skip, self.cache_k, self.cache_v, out_dev) = fn(
-            self.params, *self._block_state(), self.cache_k, self.cache_v,
-            self._d_block_tables, *self._block_sampling(), sk,
-            self._live_rows(active))
+            *self._decode_operands(sk), self._live_rows(active))
         step.mark("compute")
         jax.block_until_ready(out_dev)
         step.mark("fetch")
-        out = self._fetch_tokens(out_dev)  # ONE D2H sync per burst
-        counters = None
-        if self._counter_shapes:
-            out, counters = _unpack_step_counters(
-                out, k * (b + 2), self.num_slots, self._counter_shapes)
+        # ONE D2H sync per burst
+        out, counters = self.programs.unpack(self._fetch_tokens(out_dev),
+                                             k * (b + 2))
         burst_s = step.mark("emit") - t_sync
         self.metrics.record_decode_step(burst_s / k, len(active))
         block = self._emit_blocks(out.reshape(k, b + 2, self.num_slots),
@@ -4866,7 +4254,7 @@ class EngineCore:
     def _emit_blocks(self, out: np.ndarray, active: list[int],
                      burst_s: float) -> dict:
         """Deliver one fetched burst of block passes, `out` [passes, B + 2,
-        SLOTS] as _build_block_many lays it out: each block a row committed,
+        SLOTS] as the block program lays it out: each block a row committed,
         in order, less the given tokens at the head of its first block, as
         ONE event of several tokens; a request that ends inside a block
         (max_tokens, EOS, cancel) takes the tokens before its end. Returns

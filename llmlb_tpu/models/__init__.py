@@ -1,96 +1,58 @@
-"""Model families served by the tpu:// engine.
+"""Model families served by the tpu:// engine, over one serving contract.
 
-`family_for(cfg)` resolves the function module (init_params / param_shardings /
-init_kv_pages / kv_pages_shardings / prefill_into_pages / prefill_extend_pages
-/ verify_step_paged / decode_step_paged — one shared serving contract over the
-paged KV pool) for a config, so the engine scheduler is family-agnostic: dense
-Llama-class (llama.py) and sparse-MoE Mixtral-class (mixtral.py) plug into the
-same continuous-batching loop; DeepSeek-V3-class (deepseek_v3.py: latent
-attention, sigmoid-routed experts behind leading dense layers) brings its own
-attention and layer stack to the same bodies, and SDAR-MoE-class
-(sdar_moe.py: QK-normed GQA under a block-causal mask, generation by
-diffusion over blocks) declares a block length the scheduler decodes by;
-Nemotron-H-class (nemotron_h.py: a stack of state-space, attention and
-expert layers, one mixer a layer) keeps a recurrent state per slot beside
-the page pool; LongCat-Flash-class (longcat_flash.py: double layers whose
-mixture is a shortcut across two latent attentions, zero-compute experts)
-leaves a residual branch in one layer for a later one to add.
+`family_for(cfg)` resolves a config's function module (init_params /
+param_shardings / init_kv_pages / kv_pages_shardings / prefill_into_pages /
+prefill_extend_pages / verify_step_paged / decode_step_paged over the paged
+KV pool), so the engine is family-agnostic. What a family IS — its
+configuration class and `model_type`s, its pool, how it decodes, what it
+refuses, its counters — is its module's `FAMILY` record (models/family.py);
+FAMILIES registers the modules and everything else here is derived.
 
 `config_from_hf(hf, dtype)` picks the configuration class of a published
 `config.json` by its `model_type` and refuses a config that carries a key the
 chosen class would ignore at the cost of wrong output.
 """
 
-from llmlb_tpu.models.llama import (
-    LlamaConfig,
-    init_params,
-    param_shardings,
-    kv_pages_shardings,
-    init_kv_pages,
-    prefill_into_pages,
-    prefill_extend_pages,
-    verify_step_paged,
-    decode_step_paged,
-)
+from llmlb_tpu.models import (deepseek_v3, llama, longcat_flash, mixtral,
+                              nemotron_h, sdar_moe)
 
+# Adding a family is its module and its line here. The order decides nothing
+# but the order /api/health and /metrics list the families' counters in.
+FAMILIES = (llama, mixtral, deepseek_v3, sdar_moe, longcat_flash, nemotron_h)
 
-# model_type -> the module whose configuration class reads it. A type that
-# is not here is read as a Llama-shaped dense decoder, as it always was —
-# under the guard below.
-MODEL_TYPES = {
-    "llama": "llama", "mistral": "llama", "qwen2": "llama",
-    "mixtral": "mixtral",
-    "deepseek_v3": "deepseek_v3",
-    "sdar_moe": "sdar_moe",
-    "nemotron_h": "nemotron_h",
-    "longcat_flash": "longcat_flash",
-}
-_CONFIG_CLASSES = {"llama": "LlamaConfig", "mixtral": "MixtralConfig",
-                   "deepseek_v3": "DeepseekV3Config",
-                   "sdar_moe": "SdarMoeConfig",
-                   "nemotron_h": "NemotronHConfig",
-                   "longcat_flash": "LongcatFlashConfig"}
+# Every counter some family computes: an engine of any family exports them
+# all, zero where its own computes none.
+STEP_COUNTERS = {name: counter for m in FAMILIES
+                 for name, counter in m.FAMILY.counters.items()}
+_BY_CONFIG_CLASS = {m.FAMILY.config_class: m for m in FAMILIES}
+_BY_MODEL_TYPE = {t: m for m in FAMILIES for t in m.FAMILY.model_types}
 
-# Keys that change the function a model computes, and the classes that read
-# them. A config carrying one for a class that does not read it would be
-# served as another model without a word.
+# Keys that change the function a model computes: what some family reads
+# (FAMILY.mechanism_keys), then the mechanisms nobody computes. A config
+# carrying one for a class that does not read it would be served as another
+# model without a word.
 _ABSENT = (None, False, 0, 1, [], {})
-_MECHANISM_KEYS = {
-    "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
-    "q_lora_rank": ("longcat_flash",),
-    "zero_expert_num": ("longcat_flash",),
-    "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash"),
-    "n_shared_experts": ("deepseek_v3", "nemotron_h"),
-    "first_k_dense_replace": ("deepseek_v3",),
-    "num_local_experts": ("mixtral",),
-    "num_experts": ("mixtral", "sdar_moe"),
-    "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h"),
-    "hybrid_override_pattern": ("nemotron_h",),
-    "mamba_num_heads": ("nemotron_h",),
-    "ssm_state_size": ("nemotron_h",),
-    "expert_parallel": ("nemotron_h", "longcat_flash"),
-    "sliding_window": (),
-    "attn_logit_softcapping": (),
-    "final_logit_softcapping": (),
-    "partial_rotary_factor": (),
-}
+_NOBODY_COMPUTES = ("sliding_window", "attn_logit_softcapping",
+                    "final_logit_softcapping", "partial_rotary_factor")
+_STATED_KEYS = tuple(dict.fromkeys(
+    [k for m in FAMILIES for k in m.FAMILY.mechanism_keys])) + _NOBODY_COMPUTES
 
 
 def config_from_hf(hf: dict, dtype=None):
     """The configuration object of a published `config.json`: the class by
-    `model_type` (MODEL_TYPES; a Mixtral-shaped config of another type by its
-    `num_local_experts`), refused where the class would ignore a mechanism
-    the config states."""
-    import importlib
-
+    `model_type` (a type no family names is read as a Llama-shaped dense
+    decoder, as it always was; a Mixtral-shaped config of such a type by
+    its `num_local_experts`), refused where the class would ignore a
+    mechanism the config states."""
     model_type = hf.get("model_type", "llama")
-    family = MODEL_TYPES.get(model_type, "llama")
-    if family == "llama" and max(hf.get("num_local_experts") or 0,
-                                 hf.get("num_experts") or 0) > 1:
-        family = "mixtral"
-    for key, readers in _MECHANISM_KEYS.items():
+    module = _BY_MODEL_TYPE.get(model_type, llama)
+    if module is llama and max(hf.get("num_local_experts") or 0,
+                               hf.get("num_experts") or 0) > 1:
+        module = mixtral
+    family = module.FAMILY
+    for key in _STATED_KEYS:
         value = hf.get(key)
-        if family in readers or value in _ABSENT:
+        if key in family.mechanism_keys or value in _ABSENT:
             continue
         if (key == "moe_intermediate_size"
                 and value == hf.get("intermediate_size")):
@@ -99,51 +61,20 @@ def config_from_hf(hf: dict, dtype=None):
             continue  # stated and switched off (Qwen2)
         raise ValueError(
             f"config.json ({model_type!r}) carries {key}={value!r}, which "
-            f"models/{family}.py does not compute: it would be served as "
-            "another model. Add the mechanism or the model_type "
-            "(llmlb_tpu/models/__init__.py MODEL_TYPES)")
-    module = importlib.import_module(f"llmlb_tpu.models.{family}")
-    cls = _CONFIG_CLASSES[family]
+            f"models/{family.name}.py does not compute: it would be served "
+            "as another model. Add the mechanism or the model_type (the "
+            "family's FAMILY record: llmlb_tpu/models/family.py)")
     kwargs = {} if dtype is None else {"dtype": dtype}
-    return getattr(module, cls).from_hf_config(hf, **kwargs)
+    return family.config_class.from_hf_config(hf, **kwargs)
 
 
 def family_for(cfg):
-    """Resolve the serving-function module for a model config."""
-    from llmlb_tpu.models import (
-        deepseek_v3,
-        llama,
-        longcat_flash,
-        mixtral,
-        nemotron_h,
-        sdar_moe,
-    )
-
-    if isinstance(cfg, longcat_flash.LongcatFlashConfig):
-        return longcat_flash  # a DeepseekV3Config too: asked first
-    if isinstance(cfg, deepseek_v3.DeepseekV3Config):
-        return deepseek_v3
-    if isinstance(cfg, nemotron_h.NemotronHConfig):
-        return nemotron_h
-    if isinstance(cfg, sdar_moe.SdarMoeConfig):
-        return sdar_moe
-    if isinstance(cfg, mixtral.MixtralConfig):
-        return mixtral
-    if isinstance(cfg, LlamaConfig):
-        return llama
+    """Resolve the serving-function module for a model config: the family
+    of the most derived registered class the config is an instance of."""
+    for cls in type(cfg).__mro__:
+        if cls in _BY_CONFIG_CLASS:
+            return _BY_CONFIG_CLASS[cls]
     raise TypeError(f"no model family for config type {type(cfg).__name__}")
 
 
-__all__ = [
-    "LlamaConfig",
-    "config_from_hf",
-    "family_for",
-    "init_params",
-    "param_shardings",
-    "kv_pages_shardings",
-    "init_kv_pages",
-    "prefill_into_pages",
-    "prefill_extend_pages",
-    "verify_step_paged",
-    "decode_step_paged",
-]
+__all__ = ["FAMILIES", "STEP_COUNTERS", "config_from_hf", "family_for"]

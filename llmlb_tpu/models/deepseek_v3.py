@@ -28,7 +28,7 @@ Same serving contract and the same three shared bodies as models/llama.py
   (sigmoid_bias_routing) and the shared experts added outside the routing.
 
 The paged serving functions return one value after (logits, cache_k,
-cache_v): the step's expert-load counters (step_counter_shapes), which the
+cache_v): the step's expert-load counters (step_counters), which the
 scheduler carries out with the fetch it already makes. One static switch,
 off in serving: under `routing=True` that value is instead (chosen
 [Lm, B, T, k], scores [Lm, B, T, X] f32 = sigmoid(h W_r) + b, kept
@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models.family import Family, StepCounter
 from llmlb_tpu.models.llama import (
     Attention,
     LayerGroup,
@@ -69,12 +70,6 @@ from llmlb_tpu.ops.rope import apply_rope
 from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
-
-# What the engine refuses for this family at start-up rather than serve
-# half done: int8 weights (the quant names cover some of its projections and
-# not wkv_a, wk_b, wv_b or the shared experts) and LoRA adapter pools.
-SUPPORTS_INT8_WEIGHTS = False
-SUPPORTS_LORA = False
 
 ROPE_CELL = 128  # lanes of the rope pool's row: the rope key, then zeros
 # Upper bounds of the expert-load histogram's buckets (assignments one
@@ -285,7 +280,7 @@ def param_shardings(cfg: DeepseekV3Config, mesh: Mesh, rules=None):
 def kv_token_layer_bytes(cfg: DeepseekV3Config, quantized: bool = False) -> int:
     """HBM bytes one token leaves in one layer of the pool: the latent and
     the rope pool's tile-wide row."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     return (cfg.kv_lora_rank + ROPE_CELL) * jnp.dtype(cfg.dtype).itemsize
 
 
@@ -297,20 +292,12 @@ def kv_wire_cell(cfg: DeepseekV3Config) -> None:
     return None
 
 
-def _refuse_quantized(quantized: bool) -> None:
-    if quantized:
-        raise NotImplementedError(
-            "an int8 latent page pool is not implemented: serve "
-            "deepseek_v3 models without kv quantization (quantize modes "
-            "kv and all are refused for this family)")
-
-
 def init_kv_pages(cfg: DeepseekV3Config, num_pages: int, page_size: int,
                   dtype=None, quantized: bool = False):
     """The latent page pool, as the (cache_k, cache_v) pair of the serving
     contract: c [L, P, PS, kv_lora_rank] and k_rope [L, P, PS, 128] (the
     qk_rope_head_dim numbers, then zeros). Page 0 is the trash page."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     dtype = dtype or cfg.dtype
     lead = (cfg.num_layers, num_pages, page_size)
     return (jnp.zeros((*lead, cfg.kv_lora_rank), dtype),
@@ -321,7 +308,7 @@ def kv_pages_shardings(cfg: DeepseekV3Config, mesh: Mesh, rules=None,
                        quantized: bool = False):
     """Every head reads the whole latent: the pool replicates (pages cannot
     split over dp, and there is no head axis for tp)."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
     sharding = logical_to_sharding(mesh, rules, "layers", None, "seq", None)
     return (sharding, sharding)
@@ -474,18 +461,22 @@ def _groups(cfg: DeepseekV3Config, live=None) -> list[LayerGroup]:
     return [g for g in groups if g.count > 0]
 
 
-def step_counter_shapes(cfg: DeepseekV3Config) -> dict[str, tuple]:
+EXPERT_LOAD_COUNTERS = {
+    "experts_touched": StepCounter("sum", "moe_experts_touched_total"),
+    "expert_assignments": StepCounter("sum", "moe_expert_assignments_total"),
+    "expert_load_max": StepCounter("max", "moe_expert_load_max"),
+    "expert_load_hist": StepCounter("sum", "moe_expert_load_hist"),
+}
+
+
+def step_counters(cfg: DeepseekV3Config) -> dict[str, tuple]:
     """The counters a paged serving call returns, by name and shape (all
-    int32). Over the steps of a burst the scheduler sums them, but for
-    STEP_COUNTER_MAX, of which it keeps the largest."""
+    int32; how each is reduced and exported: EXPERT_LOAD_COUNTERS)."""
     if cfg.num_moe_layers <= 0:
         return {}
     return {"experts_touched": (), "expert_assignments": (),
             "expert_load_max": (),
             "expert_load_hist": (cfg.num_moe_layers, len(LOAD_BUCKETS) + 1)}
-
-
-STEP_COUNTER_MAX = ("expert_load_max",)
 
 
 def _moe_aux(cfg: DeepseekV3Config, aux) -> moe.Routing | None:
@@ -586,3 +577,17 @@ def decode_step_paged(params, cfg: DeepseekV3Config, input_ids, seq_lens,
         groups=_groups(cfg, live), attention=_attention(cfg))
     return (logits, cache_k, cache_v,
             *_extra(cfg, aux, (input_ids.shape[0], 1), routing))
+
+
+# Refused: int8 weights (the quant names cover some of its projections and
+# not wkv_a, wk_b, wv_b or the shared experts), LoRA pools, an int8 pool.
+FAMILY = Family(
+    name="deepseek_v3", config_class=DeepseekV3Config,
+    model_types=("deepseek_v3",),
+    mechanism_keys=("kv_lora_rank", "n_routed_experts", "n_shared_experts",
+                    "first_k_dense_replace", "moe_intermediate_size"),
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
+    pool="latent page pool",
+    int8_weights=False, int8_kv=False, lora=False,
+    counters=EXPERT_LOAD_COUNTERS, step_counters=step_counters,
+    paged_keywords=("routing",))

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from llmlb_tpu.models.family import Family
 from llmlb_tpu.ops.attention import (
     gqa_attention_prefill,
     paged_attention_decode,
@@ -747,7 +748,7 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
     _prefill_impl. `logits_from` ([B] int32, with `all_logits`) narrows the
     logits to `logits_len` (static) positions a row from the row's own
     offset into the chunk, [B, logits_len, V]: a block family's pass sends
-    two blocks and wants one's logits (scheduler._build_block_many). The
+    two blocks and wants one's logits (programs._build_block_many). The
     default, None, traces what it always did."""
     _, t = input_ids.shape
     ps = kv_pool_values(cache_k).shape[2]
@@ -1081,3 +1082,10 @@ def make_context_parallel_prefill(cfg: LlamaConfig, mesh: Mesh):
         return logits, k_all.astype(cfg.dtype), v_all.astype(cfg.dtype)
 
     return fn
+
+
+FAMILY = Family(
+    name="llama", config_class=LlamaConfig,
+    model_types=("llama", "mistral", "qwen2"), mechanism_keys=(),
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
+    context_parallel_prefill=True)

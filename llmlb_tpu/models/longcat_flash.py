@@ -54,18 +54,18 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from llmlb_tpu.models.deepseek_v3 import (  # noqa: F401 — family contract
+    EXPERT_LOAD_COUNTERS,
     LOAD_BUCKETS,
     ROPE_CELL,
-    STEP_COUNTER_MAX,
     DeepseekV3Config,
     _attention,
     _extra as _routed_extra,
-    _refuse_quantized,
     held_share,
     kv_pages_shardings,
     kv_token_layer_bytes,
     kv_wire_cell,
 )
+from llmlb_tpu.models.family import Family, StepCounter
 from llmlb_tpu.models.llama import (
     LayerGroup,
     _decode_paged_impl,
@@ -79,9 +79,6 @@ from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
 F32 = jnp.float32
-
-SUPPORTS_INT8_WEIGHTS = False
-SUPPORTS_LORA = False
 
 SUB = ("s0_", "s1_")  # the prefixes of a layer's two sub-layers' leaves
 
@@ -298,7 +295,7 @@ def init_kv_pages(cfg: LongcatFlashConfig, num_pages: int, page_size: int,
     """deepseek_v3's latent pool with a layer an attention sub-layer: the
     scaled latent [2 L, P, PS, kv_lora_rank] and the rope key's tile-wide
     row [2 L, P, PS, 128]. Page 0 is the trash page."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     dtype = dtype or cfg.dtype
     lead = (kv_pool_layers(cfg), num_pages, page_size)
     return (jnp.zeros((*lead, cfg.kv_lora_rank), dtype),
@@ -354,7 +351,7 @@ def _groups(cfg: LongcatFlashConfig, live=None) -> list[LayerGroup]:
     return groups
 
 
-def step_counter_shapes(cfg: LongcatFlashConfig) -> dict[str, tuple]:
+def step_counters(cfg: LongcatFlashConfig) -> dict[str, tuple]:
     """The counters a paged serving call returns, by name and shape (all
     int32): deepseek_v3's expert load over the HELD experts, the
     assignments that went to experts another chip holds and those that
@@ -448,3 +445,19 @@ def decode_step_paged(params, cfg: LongcatFlashConfig, input_ids, seq_lens,
         groups=_groups(cfg, live), attention=_attention(cfg))
     return (logits, cache_k, cache_v,
             *_extra(cfg, aux, (input_ids.shape[0], 1), routing))
+
+
+FAMILY = Family(
+    name="longcat_flash", config_class=LongcatFlashConfig,
+    model_types=("longcat_flash",),
+    mechanism_keys=("kv_lora_rank", "q_lora_rank", "zero_expert_num",
+                    "n_routed_experts", "expert_parallel"),
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
+    kv_pool_layers=kv_pool_layers, pool="latent page pool",
+    int8_weights=False, int8_kv=False, lora=False,
+    counters={
+        **EXPERT_LOAD_COUNTERS,
+        "assignments_elsewhere": StepCounter(
+            "sum", "moe_assignments_elsewhere_total"),
+        "zero_assignments": StepCounter("sum", "moe_zero_assignments_total")},
+    step_counters=step_counters, paged_keywords=("routing",))

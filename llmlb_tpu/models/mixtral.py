@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models.family import Family
 from llmlb_tpu.models.llama import (
     LayerGroup,
     LlamaConfig,
@@ -243,3 +244,9 @@ def decode_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
         params, cfg, input_ids, seq_lens, cache_k, cache_v, block_tables,
         groups=_groups(cfg), window=window, lora_idx=lora_idx, live=live,
     )[:3]
+
+
+FAMILY = Family(
+    name="mixtral", config_class=MixtralConfig, model_types=("mixtral",),
+    mechanism_keys=("num_local_experts", "num_experts"),
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell)

@@ -55,12 +55,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from llmlb_tpu.models.deepseek_v3 import (  # noqa: F401 — family contract
+from llmlb_tpu.models.deepseek_v3 import (
+    EXPERT_LOAD_COUNTERS,
     LOAD_BUCKETS,
-    STEP_COUNTER_MAX,
     _extra as _routed_extra,
     held_share,
 )
+from llmlb_tpu.models.family import Family, StepCounter
 from llmlb_tpu.models.llama import (
     GQA_ATTENTION,
     LayerGroup,
@@ -80,9 +81,6 @@ from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
 F32 = jnp.float32
-
-SUPPORTS_INT8_WEIGHTS = False
-SUPPORTS_LORA = False  # no adapter pools over the state-space projections
 
 KINDS = "M*E"
 
@@ -319,14 +317,6 @@ def param_shardings(cfg: NemotronHConfig, mesh: Mesh, rules=None):
 # The pool: pages of the attention layers, state of the state-space layers
 # ---------------------------------------------------------------------------
 
-def _refuse_quantized(quantized: bool) -> None:
-    if quantized:
-        raise NotImplementedError(
-            "an int8 page pool beside a recurrent state is not implemented: "
-            "serve nemotron_h models without kv quantization (quantize "
-            "modes kv and all are refused for this family)")
-
-
 def init_kv_pages(cfg: NemotronHConfig, num_pages: int, page_size: int,
                   dtype=None, quantized: bool = False, num_slots: int = 1):
     """The (cache_k, cache_v) pair of the serving contract, each a
@@ -335,7 +325,7 @@ def init_kv_pages(cfg: NemotronHConfig, num_pages: int, page_size: int,
     of xBC the convolution looks back on [n_M, slots, kernel - 1, channels]).
     Page 0 is the trash page; the state has none (a row that does not
     advance is masked). `num_slots` 1 serves a caller with one row."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     dtype = dtype or cfg.dtype
     pages = (cfg.layers_of("*"), num_pages, page_size, cfg.num_kv_heads,
              cfg.head_dim_)
@@ -353,7 +343,7 @@ def kv_pages_shardings(cfg: NemotronHConfig, mesh: Mesh, rules=None,
                        quantized: bool = False):
     """Pages as llama's; the state replicates (a slot's rows are one
     sequence's, and its heads are not split: param_logical_axes)."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
     pages = logical_to_sharding(mesh, rules, "layers", None, "seq",
                                 "kv_heads", "head_dim")
@@ -370,7 +360,7 @@ def kv_pool_layers(cfg: NemotronHConfig) -> int:
 def kv_token_layer_bytes(cfg: NemotronHConfig, quantized: bool = False) -> int:
     """HBM bytes one token leaves in one layer of the PAGE pool (K and V of
     every kv head); the state-space layers leave nothing per token."""
-    _refuse_quantized(quantized)
+    FAMILY.refuse(int8_kv=quantized)
     return (2 * cfg.num_kv_heads * cfg.head_dim_
             * jnp.dtype(cfg.dtype).itemsize)
 
@@ -519,7 +509,7 @@ def _groups(cfg: NemotronHConfig, live=None) -> list[LayerGroup]:
     return groups
 
 
-def step_counter_shapes(cfg: NemotronHConfig) -> dict[str, tuple]:
+def step_counters(cfg: NemotronHConfig) -> dict[str, tuple]:
     """The counters a decode step returns, by name and shape (all int32):
     deepseek_v3's expert load over the HELD experts, the assignments that
     went to experts this chip does not hold, and the rows whose state the
@@ -614,3 +604,26 @@ def decode_step_paged(params, cfg: NemotronHConfig, input_ids, seq_lens,
                 else jnp.sum(live, dtype=jnp.int32))
     return (logits, cache_k, cache_v, *_extra(
         cfg, aux, (input_ids.shape[0], 1), routing, advanced))
+
+
+# It verifies no draft: a rejected token would leave the state advanced, and
+# there is no snapshot to roll back to. `slot_ids`: the rows' slots (default
+# row i in slot i); `num_slots`: the slot count of the pool's state.
+FAMILY = Family(
+    name="nemotron_h", config_class=NemotronHConfig,
+    model_types=("nemotron_h",),
+    mechanism_keys=("n_routed_experts", "n_shared_experts",
+                    "moe_intermediate_size", "hybrid_override_pattern",
+                    "mamba_num_heads", "ssm_state_size", "expert_parallel"),
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
+    kv_pool_layers=kv_pool_layers, state_slot_bytes=state_slot_bytes,
+    pool="page pool beside a recurrent state",
+    verifies_drafts=False,
+    int8_weights=False, int8_kv=False, lora=False,
+    counters={
+        **EXPERT_LOAD_COUNTERS,
+        "assignments_elsewhere": StepCounter(
+            "sum", "moe_assignments_elsewhere_total"),
+        "state_rows": StepCounter("sum", "ssm_state_rows_total")},
+    step_counters=step_counters, paged_keywords=("routing", "slot_ids"),
+    keywords_of={"init_kv_pages": ("num_slots",)})

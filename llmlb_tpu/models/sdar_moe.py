@@ -21,7 +21,7 @@ Same serving contract and the same shared bodies as models/llama.py
   (the open block's ids, `mask_token_id` where still masked) behind the
   row's committed cache, logits at every position — position i's logits
   predict position i's OWN token, no shift — and the chunk's K and V written
-  past the committed length. The scheduler (engine/scheduler.py
+  past the committed length. The block program (engine/programs.py
   _build_block_many) unmasks by confidence and commits a block whose pass
   it entered complete. `decode_step_paged` is kept for the family contract;
   the engine does not dispatch it for a family with BLOCK_LENGTH > 1.
@@ -43,11 +43,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from llmlb_tpu.models import mixtral
-from llmlb_tpu.models.deepseek_v3 import (  # noqa: F401 — family contract
-    STEP_COUNTER_MAX,
+from llmlb_tpu.models.deepseek_v3 import (
+    EXPERT_LOAD_COUNTERS,
     _extra,
-    step_counter_shapes,
+    step_counters,
 )
+from llmlb_tpu.models.family import Family
 from llmlb_tpu.models.llama import (
     Attention,
     LayerGroup,
@@ -77,13 +78,6 @@ from llmlb_tpu.ops.rope import apply_rope
 from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
-
-# What the engine refuses for this family at start-up rather than serve half
-# done: int8 weights (the quant names do not cover the head norms' place in
-# the projections' numerics, and the precision control of the benchmark is
-# that very substitution) and LoRA adapter pools.
-SUPPORTS_INT8_WEIGHTS = False
-SUPPORTS_LORA = False
 
 REMASKING = ("low_confidence_dynamic", "low_confidence_static")
 
@@ -333,3 +327,18 @@ def decode_step_paged(params, cfg: SdarMoeConfig, input_ids, seq_lens,
         groups=_groups(cfg, live), attention=_attention(cfg))
     return (logits, cache_k, cache_v,
             *_extra(cfg, aux, (input_ids.shape[0], 1), routing))
+
+
+# Refused: int8 weights (the quant names do not cover the head norms' place
+# in the projections' numerics, and the precision control of the benchmark
+# is that very substitution) and LoRA pools. `logits_from`: one block's
+# logits a row from the row's own offset into a chunk of two.
+FAMILY = Family(
+    name="sdar_moe", config_class=SdarMoeConfig, model_types=("sdar_moe",),
+    mechanism_keys=("num_experts", "moe_intermediate_size"),
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
+    block_length=block_length, check_generation=check_generation,
+    int8_weights=False, lora=False,
+    counters=EXPERT_LOAD_COUNTERS, step_counters=step_counters,
+    paged_keywords=("routing",),
+    keywords_of={"verify_step_paged": ("logits_from", "logits_len")})

@@ -151,7 +151,7 @@ def token_probability(
     """The probability [B] f32 of `ids` under the softmax of the FULL
     logits at `temperature` (at 1 where it is 0: a greedy pick's confidence
     is its plain probability). What generation by diffusion over blocks
-    unmasks by (engine/scheduler._build_block_many): one max and one sum
+    unmasks by (engine/programs._build_block_many): one max and one sum
     over the vocabulary beside the sampler's own top-k."""
     scaled = logits / jnp.where(temperature > 0.0, temperature, 1.0)[:, None]
     picked = jnp.take_along_axis(scaled, ids[:, None], axis=-1)[:, 0]

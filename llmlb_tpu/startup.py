@@ -1,9 +1,9 @@
 """What every entry point that touches JAX does first: place the compilation
 cache, then resolve the backend.
 
-Called from the engine server, bench.py, chip_smoke.py's kernel child and
-__graft_entry__.py — and from no constructor, so importing or testing the
-library configures nothing.
+Called from the engine server, benchmark/launcher.py, chip_smoke.py's
+kernel child and __graft_entry__.py — and from no constructor, so importing
+or testing the library configures nothing.
 """
 
 from __future__ import annotations

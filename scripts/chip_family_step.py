@@ -70,7 +70,7 @@ def main() -> int:
     total = args.context + k * (args.bursts + 1)
     ppn = -(-total // ps)
     # a family with state per slot (models/nemotron_h.py): row i is slot i
-    slotted = hasattr(family, "state_slot_bytes")
+    slotted = family.FAMILY.state_slot_bytes is not None
     cache_k, cache_v = family.init_kv_pages(
         cfg, rows * ppn + 1, ps, **({"num_slots": rows} if slotted else {}))
     tables = jnp.arange(1, rows * ppn + 1, dtype=jnp.int32).reshape(rows, ppn)

@@ -1,4 +1,4 @@
-"""Activation is one compiled program (`scheduler._activate_rows`).
+"""Activation is one compiled program (`programs._activate_rows`).
 
 A prefilled group enters decode through ONE dispatch that splits the engine's
 key, samples every row's first token and scatters the group's rows into the

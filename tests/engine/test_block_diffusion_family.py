@@ -277,7 +277,7 @@ def test_a_block_pass_with_masks_agrees_at_every_position(model, masked):
 @pytest.mark.parametrize("route,idle", [("xla", 1), ("pallas", 2)])
 def test_a_pass_two_blocks_wide_agrees_with_two_passes_of_one(
         model, monkeypatch, route, idle):
-    """The scheduler's pass (scheduler._build_block_many): T = 2B, and in
+    """The scheduler's pass (programs._build_block_many): T = 2B, and in
     one batch a row that commits and goes on ([its complete block | B
     masks], chunk length 2B, logits wanted from B), a row that unmasks
     ([its open block | padding], chunk length B, logits wanted from 0) and
@@ -358,7 +358,7 @@ def test_the_routing_report_and_the_counters(model):
         tables, ck, cv, None)[3]
     assert int(counters["expert_assignments"]) == 3 * 16 * 4
     assert 0 < int(counters["experts_touched"]) <= 3 * 16
-    assert set(sdar_moe.step_counter_shapes(cfg)) == set(counters)
+    assert set(sdar_moe.step_counters(cfg)) == set(counters)
 
 
 # --- one-term controls: each must fail the comparison above -----------------

@@ -131,33 +131,6 @@ def test_attention_dispatch_records_what_it_resolved():
     assert attention.traced_routes()["paged_decode"] == "xla"
 
 
-# --------------------------------------------------------------------- bench
-
-
-def test_bench_fails_loudly_when_the_engine_raises():
-    """A failed run is a traceback and a non-zero exit, never a JSON line
-    with value 0.0 and exit 0."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-        env=_env(JAX_PLATFORMS="cpu", LLMLB_ROLE="no-such-role"),
-        capture_output=True, text=True, timeout=300,
-    )
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""
-    assert "Traceback" in proc.stderr and "no-such-role" in proc.stderr
-
-
-def test_bench_refuses_a_cpu_nobody_asked_for():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-        env=_env(JAX_PLATFORMS=None), capture_output=True, text=True,
-        timeout=120,
-    )
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""
-    assert "no accelerator found" in proc.stderr
-
-
 # ---------------------------------------------------------------- chip smoke
 
 

@@ -356,11 +356,7 @@ def test_prefill_dispatch_failure_reaches_batched_requests(cfg):
     def boom(*args, **kwargs):
         raise RuntimeError("injected prefill failure")
 
-    core.family = type("F", (), {
-        **{k: staticmethod(getattr(core.family, k))
-           for k in dir(core.family) if not k.startswith("__")},
-        "prefill_into_pages": staticmethod(boom),
-    })()
+    core.programs.prefill = boom
     core.start()
     try:
         reqs = [
@@ -434,6 +430,8 @@ def test_prewarm_compiles_both_modes(cfg):
                 core._prewarm_windows()
             assert not logged.called
             if burst > 1:
-                assert sorted(core._decode_many) == [256, 512]
+                assert sorted(core.programs.cache) == [
+                    ("decode_many", 4, 256, False),
+                    ("decode_many", 4, 512, False)]
         finally:
             core._running = False

@@ -1,71 +1,50 @@
 """The serving contract a model family provides, held name by name.
 
-`models.family_for(cfg)` hands the scheduler a module of free functions and
-the scheduler calls them positionally (`engine/scheduler.py`); a missing
-name is a start-up `AttributeError` or a silent `hasattr` downgrade, and a
-drifted parameter is a `TypeError` at the first dispatch. This holds every
-family to llama's signatures up front, so the next family is added against
-a test and not against a traceback.
+`models.family_for(cfg)` hands the engine a module of free functions, which
+`engine/programs.py` calls positionally, and the module's `FAMILY` record
+(`models/family.py`) says what the family is. This holds every family to
+llama's signatures up front and every exception to them to the record, so
+the next family is added against a test and not against a traceback.
 """
 
+import dataclasses
 import inspect
 
 import pytest
 
+from llmlb_tpu import models
 from llmlb_tpu.engine.presets import get_preset
-from llmlb_tpu.models import family_for, llama
+from llmlb_tpu.models import (
+    FAMILIES,
+    config_from_hf,
+    deepseek_v3,
+    family_for,
+    llama,
+    longcat_flash,
+)
+from llmlb_tpu.models.family import Family
 
-# one config per module family_for can return
-FAMILIES = {
-    "llama": family_for(get_preset("debug-tiny")),
-    "mixtral": family_for(get_preset("debug-moe-tiny")),
-    "deepseek_v3": family_for(get_preset("debug-mla-tiny")),
-    "sdar_moe": family_for(get_preset("debug-sdar-tiny")),
-    "nemotron_h": family_for(get_preset("debug-nemotron-h-tiny")),
-    "longcat_flash": family_for(get_preset("debug-longcat-tiny")),
-}
+# one debug configuration per registered module
 PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "deepseek_v3": "debug-mla-tiny", "sdar_moe": "debug-sdar-tiny",
            "nemotron_h": "debug-nemotron-h-tiny",
            "longcat_flash": "debug-longcat-tiny"}
-# Static switches a family may add BEHIND llama's parameters, keyword-only
-# in effect: the benchmark's check passes `routing` (models/deepseek_v3.py),
-# never positionally. `slot_ids`: the rows' slots, for a family that keeps
-# a state per slot beside the page pool (models/nemotron_h.py); the default
-# is row i in slot i, which serves the benchmark's check and its one row.
-EXTRA = {"deepseek_v3": ["routing"], "sdar_moe": ["routing"],
-         "nemotron_h": ["routing", "slot_ids"],
-         "longcat_flash": ["routing"]}
-# What a family may add behind llama's parameters elsewhere: the slot count
-# of a pool with a state per slot (default 1); the scheduler knows such a
-# family by its `state_slot_bytes` (EngineCore._slot_state). And behind the
-# switches above, on a block family's pass alone: one block's logits a row
-# from the row's own offset into a chunk of two (scheduler._build_block_many;
-# the default is every position's, which the benchmark's check takes).
-EXTRA_OF = {("nemotron_h", "init_kv_pages"): ["num_slots"],
-            ("sdar_moe", "verify_step_paged"): ["logits_from", "logits_len"]}
+MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
+PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
+         "decode_step_paged")
 CONTRACT = (
     "init_params",
     "param_shardings",
     "init_kv_pages",
     "kv_pages_shardings",
-    "prefill_into_pages",
-    "prefill_extend_pages",
-    "verify_step_paged",
-    "decode_step_paged",
+    *PAGED,
     "make_context_parallel_prefill",
 )
-# Found by `hasattr` and served without when absent: ring-attention prefill
-# runs llama's dense feed-forward, so a mixture of experts must not export it.
-OPTIONAL = {"make_context_parallel_prefill"}
-# Absent BY DESIGN, family by family, and asked of every other: a family
-# with a recurrent state cannot verify a draft (a rejected token would leave
-# the state advanced, and there is no snapshot to roll back to), so it
-# exports no `verify_step_paged` and the scheduler's `hasattr`
-# (`_spec_available`) serves it without speculation; an engine asked for
-# speculation with it does not start (`_check_slot_state_engine`).
-ABSENT_BY_DESIGN = {"nemotron_h": {"verify_step_paged"}}
+# the record's field that says a family exports the name; every other name
+# of the contract is exported by all
+EXPORTED_IF = {"verify_step_paged": "verifies_drafts",
+               "make_context_parallel_prefill": "context_parallel_prefill"}
 
 
 def _params(fn) -> list[tuple[str, inspect._ParameterKind]]:
@@ -77,52 +56,181 @@ def _params(fn) -> list[tuple[str, inspect._ParameterKind]]:
 
 
 def test_every_family_module_is_covered():
-    assert FAMILIES["llama"] is llama
-    assert len({id(m) for m in FAMILIES.values()}) == len(FAMILIES)
+    assert sorted(MODULES) == sorted(PRESETS)
+    for name, module in MODULES.items():
+        assert module.__name__ == f"llmlb_tpu.models.{name}"
+        assert family_for(get_preset(PRESETS[name])) is module
 
 
 @pytest.mark.parametrize("name", CONTRACT)
-@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("family", sorted(PRESETS))
 def test_family_provides_the_paged_contract(family, name):
-    module = FAMILIES[family]
-    if not hasattr(module, name):
-        assert name in OPTIONAL | ABSENT_BY_DESIGN.get(family, set()), (
-            f"{family} lacks {name}")
+    module, record = MODULES[family], MODULES[family].FAMILY
+    exported = getattr(record, EXPORTED_IF.get(name, ""), True)
+    assert hasattr(module, name) == exported, (
+        f"{family} exports {name} and its record says it does not, or the "
+        "other way round")
+    if not exported:
         return
-    assert name not in ABSENT_BY_DESIGN.get(family, set()), (
-        f"{family} exports {name}, which it cannot serve")
     want = _params(getattr(llama, name))
     got = _params(getattr(module, name))
-    paged = name in ("prefill_into_pages", "prefill_extend_pages",
-                     "verify_step_paged", "decode_step_paged")
-    extra = ((EXTRA.get(family, []) if paged else [])
-             + EXTRA_OF.get((family, name), []))
+    extra = list((record.paged_keywords if name in PAGED else ())
+                 + record.keywords_of.get(name, ()))
     assert got[:len(want)] == want and [n for n, _ in got[len(want):]] == extra, (
-        f"{family}.{name} takes other parameters than llama.{name}"
-    )
+        f"{family}.{name} takes other parameters than llama.{name} and "
+        "the keywords its record states")
 
 
-@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("family", sorted(PRESETS))
 def test_family_says_what_a_token_leaves_in_the_pool(family):
-    """The scheduler's page bytes, gauges and KVSH header ask the family."""
-    module = FAMILIES[family]
+    """The scheduler's page bytes, gauges and KVSH header ask the record."""
+    module, record = MODULES[family], MODULES[family].FAMILY
     cfg = get_preset(PRESETS[family])
     ck, cv = map(llama._pages, module.init_kv_pages(cfg, 3, 8))
     per_token = (ck[0, 0, 0].size + cv[0, 0, 0].size) * ck.dtype.itemsize
-    assert module.kv_token_layer_bytes(cfg) == per_token
-    cell = module.kv_wire_cell(cfg)
+    assert record.kv_token_layer_bytes(cfg) == per_token
+    assert record.kv_pool_layers(cfg) == ck.shape[0]
+    cell = record.kv_wire_cell(cfg)
     assert cell is None or cell == ck.shape[-2:] == cv.shape[-2:]
 
 
-def test_an_added_parameter_has_a_default_that_serves_one_row():
+@pytest.mark.parametrize("family", sorted(PRESETS))
+def test_an_added_parameter_has_a_default_that_serves_one_row(family):
     """What a family adds behind llama's parameters is optional: the
     benchmark's check (benchmark/correctness.py) calls every family with
     llama's arguments alone."""
-    for family, names in EXTRA.items():
-        for fn in ("prefill_into_pages", "prefill_extend_pages",
-                   "decode_step_paged"):
-            params = inspect.signature(getattr(FAMILIES[family], fn)).parameters
-            assert all(params[n].default in (False, None) for n in names)
-    for (family, fn), names in EXTRA_OF.items():
-        params = inspect.signature(getattr(FAMILIES[family], fn)).parameters
+    module, record = MODULES[family], MODULES[family].FAMILY
+    for fn in PAGED:
+        if hasattr(module, fn):
+            params = inspect.signature(getattr(module, fn)).parameters
+            assert all(params[n].default in (False, None)
+                       for n in record.paged_keywords)
+    for fn, names in record.keywords_of.items():
+        params = inspect.signature(getattr(module, fn)).parameters
         assert all(params[n].default in (None, 1) for n in names)
+
+
+@pytest.mark.parametrize("family", sorted(PRESETS))
+def test_the_record_points_at_the_modules_own_functions(family):
+    """The functions a record names stay module-level functions under their
+    names (the benchmark's tests call some of them off the module), and a
+    counter a configuration returns is one the record declares."""
+    module, record = MODULES[family], MODULES[family].FAMILY
+    cfg = get_preset(PRESETS[family])
+    for field in ("kv_token_layer_bytes", "kv_wire_cell", "kv_pool_layers",
+                  "state_slot_bytes", "block_length", "check_generation",
+                  "step_counters"):
+        fn = getattr(record, field)
+        if fn is not None and hasattr(module, field):
+            assert fn is getattr(module, field)
+    assert set(record.step_counters(cfg)) <= set(record.counters)
+    assert all(c.reduce in ("sum", "max") for c in record.counters.values())
+    assert (record.block_length(cfg) > 1) == (
+        record.check_generation is not None)
+
+
+@pytest.mark.parametrize("kwargs,error", [
+    (dict(name="x", config_class=llama.LlamaConfig, model_types=(),
+          mechanism_keys=(), kv_token_layer_bytes=len, kv_wire_cell=len,
+          verifies_draft=False), "verifies_draft"),  # misspelt
+    (dict(name="x", config_class=llama.LlamaConfig, model_types=(),
+          mechanism_keys=(), kv_wire_cell=len), "kv_token_layer_bytes"),
+])
+def test_a_record_field_misspelt_or_missing_is_a_type_error(kwargs, error):
+    with pytest.raises(TypeError, match=error):
+        Family(**kwargs)
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        llama.FAMILY.lora = False
+
+
+def test_family_for_takes_the_most_derived_registered_class(monkeypatch):
+    """`LongcatFlashConfig` is a `DeepseekV3Config` too: each resolves to
+    its own module whatever the registry's order, and so does a config that
+    was `dataclasses.replace`d or subclassed by a test."""
+    long_cfg, deep_cfg = (get_preset("debug-longcat-tiny"),
+                          get_preset("debug-mla-tiny"))
+    assert issubclass(longcat_flash.LongcatFlashConfig,
+                      deepseek_v3.DeepseekV3Config)
+    for order in (FAMILIES, FAMILIES[::-1]):
+        monkeypatch.setattr(models, "_BY_CONFIG_CLASS", {
+            m.FAMILY.config_class: m for m in order})
+        assert family_for(long_cfg) is longcat_flash
+        assert family_for(deep_cfg) is deepseek_v3
+        assert family_for(dataclasses.replace(long_cfg, num_layers=1)) \
+            is longcat_flash
+        sub = type("Sub", (type(deep_cfg),), {})
+        assert family_for(sub(**dataclasses.asdict(deep_cfg))) is deepseek_v3
+    with pytest.raises(TypeError, match="no model family"):
+        family_for(object())
+
+
+# What `models/__init__.py` held by hand until the records said it: kept
+# here as the expectation the derived tables are held to.
+OLD_MODEL_TYPES = {
+    "llama": "llama", "mistral": "llama", "qwen2": "llama",
+    "mixtral": "mixtral", "deepseek_v3": "deepseek_v3",
+    "sdar_moe": "sdar_moe", "nemotron_h": "nemotron_h",
+    "longcat_flash": "longcat_flash",
+}
+OLD_MECHANISM_KEYS = {
+    "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
+    "q_lora_rank": ("longcat_flash",),
+    "zero_expert_num": ("longcat_flash",),
+    "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash"),
+    "n_shared_experts": ("deepseek_v3", "nemotron_h"),
+    "first_k_dense_replace": ("deepseek_v3",),
+    "num_local_experts": ("mixtral",),
+    "num_experts": ("mixtral", "sdar_moe"),
+    "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h"),
+    "hybrid_override_pattern": ("nemotron_h",),
+    "mamba_num_heads": ("nemotron_h",),
+    "ssm_state_size": ("nemotron_h",),
+    "expert_parallel": ("nemotron_h", "longcat_flash"),
+    "sliding_window": (),
+    "attn_logit_softcapping": (),
+    "final_logit_softcapping": (),
+    "partial_rotary_factor": (),
+}
+
+
+def test_the_records_state_the_old_tables():
+    assert {t: m.FAMILY.name for m in FAMILIES
+            for t in m.FAMILY.model_types} == OLD_MODEL_TYPES
+    assert set(models._STATED_KEYS) == set(OLD_MECHANISM_KEYS)
+    for key, readers in OLD_MECHANISM_KEYS.items():
+        assert {m.FAMILY.name for m in FAMILIES
+                if key in m.FAMILY.mechanism_keys} == set(readers), key
+
+
+class _Chosen(Exception):
+    pass
+
+
+@pytest.mark.parametrize("model_type", sorted(OLD_MODEL_TYPES))
+@pytest.mark.parametrize("key", sorted(OLD_MECHANISM_KEYS))
+def test_a_mechanism_key_is_accepted_or_refused_as_before(
+        monkeypatch, model_type, key):
+    """`config_from_hf` over every (key, model_type) pair of the old tables:
+    a key the type's class reads reaches the class, any other is refused by
+    name before it."""
+    family = OLD_MODEL_TYPES[model_type]
+    module = MODULES[family]
+
+    def chosen(hf, **kwargs):
+        raise _Chosen
+
+    monkeypatch.setattr(module.FAMILY.config_class, "from_hf_config",
+                        staticmethod(chosen))
+    # 7: present by every rule; num_experts > 1 would re-type a dense config
+    hf = {"model_type": model_type, "intermediate_size": 128, key: 7}
+    if family in OLD_MECHANISM_KEYS[key] or (
+            family == "llama" and key in ("num_local_experts", "num_experts")):
+        if family == "llama":  # re-typed as a mixture, which reads the key
+            monkeypatch.setattr(MODULES["mixtral"].FAMILY.config_class,
+                                "from_hf_config", staticmethod(chosen))
+        with pytest.raises(_Chosen):
+            config_from_hf(hf)
+    else:
+        with pytest.raises(ValueError, match=f"carries {key}=7, which "
+                           f"models/{family}.py does not compute"):
+            config_from_hf(hf)

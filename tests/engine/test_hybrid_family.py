@@ -252,7 +252,7 @@ def test_routing_and_counters_leave_logits_and_pool_bit_equal(params):
                              "expert_load_max", "expert_load_hist",
                              "assignments_elsewhere", "state_rows",
                              "scan_tokens", "scan_chunks"}
-    assert set(family.step_counter_shapes(CFG)) == set(plain[3]) - {
+    assert set(family.step_counters(CFG)) == set(plain[3]) - {
         "scan_tokens", "scan_chunks"}
 
 

@@ -227,7 +227,7 @@ def test_routing_and_counters_leave_logits_and_cache_bit_equal(params):
     touched = sum(len(np.unique(np.asarray(chosen[l]))) for l in range(lm))
     assert int(counters["experts_touched"]) == touched
     hist = np.asarray(counters["expert_load_hist"])
-    assert hist.shape == family.step_counter_shapes(CFG)["expert_load_hist"]
+    assert hist.shape == family.step_counters(CFG)["expert_load_hist"]
     assert hist.sum() == lm * x and hist[:, 0].sum() == lm * x - touched
 
 

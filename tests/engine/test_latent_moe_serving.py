@@ -12,11 +12,13 @@ import numpy as np
 import pytest
 
 from llmlb_tpu.engine.presets import get_preset
+from llmlb_tpu.engine.programs import (
+    _pack_step_counters,
+    _unpack_step_counters,
+)
 from llmlb_tpu.engine.scheduler import (
     EngineCore,
     SamplingParams,
-    _pack_step_counters,
-    _unpack_step_counters,
     kv_page_bytes,
 )
 from llmlb_tpu.engine.service import Engine

@@ -133,8 +133,8 @@ def test_another_family_refuses_the_mechanisms_by_name():
 
 
 def test_what_the_engine_must_refuse_is_said_by_the_family():
-    assert family.SUPPORTS_INT8_WEIGHTS is False
-    assert family.SUPPORTS_LORA is False
+    record = family.FAMILY
+    assert not (record.int8_weights or record.int8_kv or record.lora)
     assert family.kv_wire_cell(CFG) is None
     with pytest.raises(NotImplementedError, match="int8 latent page pool"):
         family.init_kv_pages(CFG, 3, PAGE, quantized=True)
@@ -368,7 +368,7 @@ def test_the_step_counters_have_the_shapes_the_family_states(params):
     *_, counters = family.prefill_into_pages(
         params, CFG, jnp.asarray(np.arange(8, 24)[None], jnp.int32),
         jnp.asarray([16]), tables, ck, cv)
-    shapes = family.step_counter_shapes(CFG)
+    shapes = family.step_counters(CFG)
     assert {k: v.shape for k, v in counters.items()} == shapes
     assert set(shapes) >= {"zero_assignments", "assignments_elsewhere",
                            "expert_assignments", "experts_touched"}

@@ -17,7 +17,7 @@ from safetensors.numpy import save_file
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.weights import load_checkpoint, load_config
 from llmlb_tpu.models import (
-    MODEL_TYPES,
+    FAMILIES,
     config_from_hf,
     deepseek_v3,
     llama,
@@ -124,7 +124,8 @@ def test_model_type_picks_the_class(model_type, cls):
     hf = {"mixtral": {**dense, "num_local_experts": 4, "num_experts_per_tok": 2},
           "deepseek_v3": HF_CONFIG}.get(model_type, dense)
     assert type(config_from_hf({**hf, "model_type": model_type})) is cls
-    assert model_type in MODEL_TYPES or cls is llama.LlamaConfig
+    assert (any(model_type in m.FAMILY.model_types for m in FAMILIES)
+            or cls is llama.LlamaConfig)
 
 
 @pytest.mark.parametrize("model_type,key,value", [
