@@ -1,0 +1,16 @@
+"""Kernels: the global layers' decode attention's roofline share in a
+decoder that also has window layers — the live keys and values the traced
+decode records counted (`global_kv_tokens`: a row's whole length in every
+global layer; keys 192 and values 128 wide on 4 KV heads) over the published
+peaks, as a share of the device time the trace gives `paged_flat_decode`.
+`kernel.window_decode_roofline`'s reading with the other counter, kernel
+and account; under a name of its own because the accepted
+`kernel.paged_flash_decode_roofline` lists other cells and another kernel."""
+
+from benchmark import manifest
+
+
+def read(collected: dict):
+    return manifest.load_module(
+        "layer_metrics", "kernel.window_decode_roofline").attention_share(
+            collected, "global_kv_tokens", "GLOBAL_DECODE_OPS", "global_decode")

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
+from llmlb_tpu.models.mimo_v2 import MimoV2Config
 from llmlb_tpu.models.mixtral import MixtralConfig
 from llmlb_tpu.models.nemotron_h import NemotronHConfig
 from llmlb_tpu.models.sdar_moe import SdarMoeConfig
@@ -80,6 +81,22 @@ PRESETS: dict[str, LlamaConfig] = {
         q_lora_scale=2.0, kv_lora_scale=2.0 ** 0.5, router_experts=8,
         num_experts=4, first_expert=4, zero_experts=4, experts_per_token=3,
         moe_intermediate_size=32, routed_scaling_factor=6.0,
+    ),
+    # CI-sized window-and-global decoder (models/mimo_v2.py,
+    # docs/window-attention.md): the published period, a leading global +
+    # dense layer and five window layers to one global behind it; a ring of
+    # 16 cells a slot, keys of 24 beside values of 16 (8 of 24 rotate), a
+    # sink a head, the second half (4 of 8) of the experts held
+    "debug-mimo-tiny": MimoV2Config(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=7, num_heads=8, num_kv_heads=2, head_dim=24,
+        rope_theta=10000000.0, rms_eps=1e-5, dtype=jnp.float32,
+        max_position_embeddings=1024, pattern=(0, 1, 1, 1, 1, 0, 1),
+        moe_pattern=(0, 1, 1, 1, 1, 1, 1), v_head_dim=16, window_kv_heads=4,
+        window_rope_theta=10000.0, sliding_window=16,
+        partial_rotary_factor=0.334, value_scale=0.707, router_experts=8,
+        num_experts=4, first_expert=4, experts_per_token=2,
+        moe_intermediate_size=32,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

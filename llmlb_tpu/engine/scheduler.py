@@ -1043,9 +1043,9 @@ class EngineCore:
             ):
                 if on:
                     raise NotImplementedError(
-                        f"{name} keeps a recurrent state per slot beside "
-                        f"the page pool, which is not served with {what} "
-                        f"yet ({why})")
+                        f"{name} keeps a state per slot beside the page "
+                        f"pool (a {record.pool}), which is not served with "
+                        f"{what} yet ({why})")
 
     def _check_block_request(self, request: Request) -> None:
         """Raise ValueError for what a request asks of generation by

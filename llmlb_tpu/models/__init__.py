@@ -13,12 +13,13 @@ FAMILIES registers the modules and everything else here is derived.
 chosen class would ignore at the cost of wrong output.
 """
 
-from llmlb_tpu.models import (deepseek_v3, llama, longcat_flash, mixtral,
-                              nemotron_h, sdar_moe)
+from llmlb_tpu.models import (deepseek_v3, llama, longcat_flash, mimo_v2,
+                              mixtral, nemotron_h, sdar_moe)
 
 # Adding a family is its module and its line here. The order decides nothing
 # but the order /api/health and /metrics list the families' counters in.
-FAMILIES = (llama, mixtral, deepseek_v3, sdar_moe, longcat_flash, nemotron_h)
+FAMILIES = (llama, mixtral, deepseek_v3, sdar_moe, longcat_flash, nemotron_h,
+            mimo_v2)
 
 # Every counter some family computes: an engine of any family exports them
 # all, zero where its own computes none.
@@ -32,8 +33,7 @@ _BY_MODEL_TYPE = {t: m for m in FAMILIES for t in m.FAMILY.model_types}
 # carrying one for a class that does not read it would be served as another
 # model without a word.
 _ABSENT = (None, False, 0, 1, [], {})
-_NOBODY_COMPUTES = ("sliding_window", "attn_logit_softcapping",
-                    "final_logit_softcapping", "partial_rotary_factor")
+_NOBODY_COMPUTES = ("attn_logit_softcapping", "final_logit_softcapping")
 _STATED_KEYS = tuple(dict.fromkeys(
     [k for m in FAMILIES for k in m.FAMILY.mechanism_keys])) + _NOBODY_COMPUTES
 

@@ -530,7 +530,8 @@ class StateRows(NamedTuple):
     """What a group's `mixer` is told about the rows of a call. Prefill:
     `slots` and `lens` (a fresh sequence: whatever the slot held is void).
     Extend: those and `start_pos` (0 is a fresh sequence too). Decode: one
-    token a row, `live` the rows to advance (None: all)."""
+    token a row (`lens` None), at position `start_pos`, `live` the rows to
+    advance (None: all)."""
 
     slots: jnp.ndarray | None  # [B] the rows' slots; None: row i is slot i
     lens: jnp.ndarray | None = None  # [B] valid tokens of the chunk
@@ -918,7 +919,7 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     attention = attention or GQA_ATTENTION
     work = attention.decode_work(_pages(cache_k), block_tables, kv_lens,
                                  window)
-    rows = StateRows(slot_ids, live=live)
+    rows = StateRows(slot_ids, start_pos=write_pos, live=live)
 
     x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
     aux, at, deferred = [], 0, None  # the branch a layer left (LayerGroup)

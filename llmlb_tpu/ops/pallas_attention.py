@@ -169,7 +169,8 @@ def _row_map(i, layer, row_of, page_of, pool_page_of, lens):
 
 def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
                  m_ref, l_ref, acc_ref, page, *,
-                 block_k: int, sweep: int, num_kv: int, scale: float):
+                 block_k: int, sweep: int, num_kv: int, scale: float,
+                 sink_ref=None):
     """One grid step of a paged decode kernel: item i of the work-list is
     page `s` of row `row`. Online softmax (m/l/acc) lives in VMEM scratch
     from a row's first item to its last. A row of length 0 has one item,
@@ -187,7 +188,12 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
     at 64) where the chip's ridge is about 240 — the kernel stays bound by
     its bytes. (Slicing a head out of the page instead takes one sublane of
     every tile, 2*K times a page, for K products four rows tall: 1.6 us a
-    page of 512 KB whose bytes take 0.64, PERF.md §6, PR 43.)"""
+    page of 512 KB whose bytes take 0.64, PERF.md §6, PR 43.)
+
+    The values may be narrower than the keys (o, acc [H, Dv]). `sink_ref`
+    ([H, 1] f32), where given, is a learnt logit a head that enters the
+    softmax's denominator and takes no value: a row starts from m = sink,
+    l = 1 (exp(sink - m)), acc = 0, and goes on as any other."""
     i = pl.program_id(0)
     s = page_of_ref[i]
     kv_len = kv_lens_ref[row_of_ref[i]]
@@ -195,14 +201,18 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
 
     @pl.when(s == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if sink_ref is None:
+            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+        else:
+            m_ref[:] = sink_ref[:]
+            l_ref[:] = jnp.ones_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     @pl.when(s * block_k < kv_len)
     def _compute():
         q = q_ref[0]  # [H, D]
-        k, v = page()  # [PS*K, D] each
+        k, v = page()  # [PS*K, D], [PS*K, Dv]
         heads = q.shape[0]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -245,6 +255,24 @@ def _paged_decode_kernel(
                  m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]), **kw)
 
 
+def _paged_decode_sink_kernel(
+    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
+    q_ref,  # [1, H, D]
+    sink_ref,  # [H, 1] f32
+    k_ref,  # [1, PS*K, D]
+    v_ref,  # [1, PS*K, Dv]
+    o_ref,  # [1, H, Dv]
+    m_ref, l_ref, acc_ref,
+    **kw,
+):
+    """_paged_decode_kernel with a sink a head in the softmax's denominator
+    (_decode_item)."""
+    del layer_ref, pool_page_of_ref
+    _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
+                 m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]),
+                 sink_ref=sink_ref, **kw)
+
+
 def _paged_decode_quant_kernel(
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
     q_ref,  # [1, H, D]
@@ -276,45 +304,51 @@ def _paged_decode_quant_kernel(
 
 
 def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
-                       kv_lens, work, *, page_size, num_kv, pages, interpret):
-    """The pallas_call both paged decode kernels share: `grid=(work.count,)`
+                       kv_lens, work, *, page_size, num_kv, pages, interpret,
+                       value_dim=None, name=None):
+    """The pallas_call the paged decode kernels share: `grid=(work.count,)`
     — a run-time length — over the work-list's items; q and out blocks
     follow the item's row, the KV blocks (`kv_specs`, one per operand of
-    `kv_operands`) its pool page."""
+    `kv_operands`) its pool page. `value_dim`: the values' width where it is
+    not the keys'; `name`: the call's name in a device trace where it is
+    not the calling function's."""
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
+    dv = d if value_dim is None else value_dim
     if work is None:
         work = decode_work_list(block_tables, kv_lens, page_size=page_size,
                                 pages=pages)
     row_spec = pl.BlockSpec((1, h, d), _row_map, memory_space=pltpu.VMEM)
+    out_spec = pl.BlockSpec((1, h, dv), _row_map, memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(work.count,),
         in_specs=[row_spec, *kv_specs],
-        out_specs=row_spec,
+        out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(kernel, block_k=page_size,
                           sweep=_swept_pages(block_tables, pages),
                           num_kv=num_kv, scale=d**-0.5),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
       kv_lens.astype(jnp.int32), q, *kv_operands)
 
 
-@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
+@functools.partial(jax.jit, static_argnames=("pages", "interpret", "name"))
 def paged_flash_decode(
     q: jnp.ndarray,  # [B, H, D]
     k_pages: jnp.ndarray,  # [L, P, PS, K, D] — global page pool, all layers
-    v_pages: jnp.ndarray,  # [L, P, PS, K, D]
+    v_pages: jnp.ndarray,  # [L, P, PS, K, Dv] — Dv = D, or narrower values
     layer,  # int32 scalar — the layer of the pool to attend over
     block_tables: jnp.ndarray,  # [B, PPN] int32 — logical page i of row b
     kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
@@ -322,8 +356,10 @@ def paged_flash_decode(
     pages: int | None = None,  # static: a row's first `pages` pages at most
     work: DecodeWork | None = None,  # decode_work_list of the same operands
     interpret: bool | None = None,
+    sink: jnp.ndarray | None = None,  # [H] — a logit a head, without a value
+    name: str | None = None,  # static: the call's name in a device trace
 ) -> jnp.ndarray:
-    """Ragged PAGED one-token GQA decode attention. Returns [B, H, D].
+    """Ragged PAGED one-token GQA decode attention. Returns [B, H, Dv].
 
     The grid is the work-list of live (row, page) pairs (`decode_work_list`;
     a decode program builds it once a step and hands it to every layer's
@@ -344,20 +380,36 @@ def paged_flash_decode(
     page of the pool is read for it, whatever its table row holds (the
     engine's freed, never-used and prefilling slot rows). `pages` bounds a
     row's items and the static size of the work-list, not the input shapes.
-    """
+
+    The values may be narrower than the keys (scores scale by the KEYS'
+    D ** -0.5). `sink` ([H], a learnt logit a head) enters each head's
+    softmax denominator and takes no value: p_j = exp(s_j - m) / (exp(sink -
+    m) + sum_j exp(s_j - m)). A pool of any other layout with the pages'
+    shape serves: a RING a slot [L, slots, cells, K, D] is a pool of one
+    page a row, its table [B, 1] the rows' slots (models/mimo_v2.py).
+    Without `sink`, and with values as wide as the keys, the call lowers to
+    what it always did."""
     layers, pool_pages, ps, num_kv, d = k_pages.shape
+    dv = v_pages.shape[-1]
     # a page as its [PS*K, D] rows: in the chip's memory the same bytes
     # (XLA compiles the reshape to a bitcast: the pool's two minor
     # dimensions are tiled K rows deep or eight), and the DMA lands the
     # block dense whatever K is
-    rows = (layers, pool_pages, ps * num_kv, d)
-    kv_spec = pl.BlockSpec((None, 1, ps * num_kv, d), _pool_rows_map,
-                           memory_space=pltpu.VMEM)
+    rows = (layers, pool_pages, ps * num_kv)
+    kernel = _paged_decode_kernel
+    specs = [pl.BlockSpec((None, 1, ps * num_kv, width), _pool_rows_map,
+                          memory_space=pltpu.VMEM) for width in (d, dv)]
+    operands = (k_pages.reshape(*rows, d), v_pages.reshape(*rows, dv))
+    if sink is not None:
+        h = q.shape[1]
+        kernel = _paged_decode_sink_kernel
+        specs = [pl.BlockSpec((h, 1), lambda i, *_: (0, 0),
+                              memory_space=pltpu.VMEM), *specs]
+        operands = (sink.astype(jnp.float32).reshape(h, 1), *operands)
     return _paged_decode_call(
-        _paged_decode_kernel, [kv_spec, kv_spec],
-        (k_pages.reshape(rows), v_pages.reshape(rows)), q, layer,
+        kernel, specs, operands, q, layer,
         block_tables, kv_lens, work, page_size=ps, num_kv=num_kv,
-        pages=pages, interpret=interpret)
+        pages=pages, interpret=interpret, value_dim=dv, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
@@ -531,6 +583,134 @@ def paged_latent_decode(
         name="paged_latent_decode",
     )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
       kv_lens.astype(jnp.int32), q_abs, q_rope, c_pages, r_pages)
+
+
+# ---------------------------------------------------------------------------
+# Flat paged decode: GQA against a pool WITHOUT a head axis, a cell one row
+# of all its KV heads side by side — keys [L, P, PS, K*D], values [L, P, PS,
+# K*Dv]. For heads whose width is no multiple of 128 lanes on fewer than 8
+# KV heads (4 x 192): a pool [.., PS, 4, 192] is tiled four rows deep and
+# two lane tiles wide, its view as a page's [PS*4, 192] rows is then no
+# bitcast, and XLA copied the whole pool, 441 MB, in front of every call of
+# paged_flash_decode (the compile for a described v5e said so, PR 45). A
+# row of 768 lanes is six whole tiles: nothing is padded and nothing copied.
+# ---------------------------------------------------------------------------
+
+
+def _paged_flat_decode_kernel(
+    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
+    q_ref,  # [1, H, K*D] — head r's query in its KV head's columns, else 0
+    k_ref,  # [1, PS, K*D]
+    v_ref,  # [1, PS, K*Dv]
+    o_ref,  # [1, H, Dv]
+    m_ref,  # [H, 1] f32
+    l_ref,  # [H, 1] f32
+    acc_ref,  # [H, K*Dv] f32
+    *, block_k: int, sweep: int, num_kv: int, scale: float,
+):
+    """One grid step: item i of the work-list is page `s` of row `row`
+    (_decode_item's contract). A query that is zero outside its own KV
+    head's columns meets the whole row of a cell in ONE product, [H, K*D] x
+    [K*D, PS]: the scores are [H, PS], no column of another head's to mask.
+    The mix p v is [H, K*Dv]; a head keeps its own KV head's Dv columns at
+    the end."""
+    del layer_ref, pool_page_of_ref
+    i = pl.program_id(0)
+    s = page_of_ref[i]
+    kv_len = kv_lens_ref[row_of_ref[i]]
+    last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+
+    @pl.when(s == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(s * block_k < kv_len)
+    def _compute():
+        col = s * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), dimension=1)
+        scores = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, PS]
+        scores = jnp.where(col < kv_len, scores, _NEG_INF)
+        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v_ref[0])
+
+    @pl.when(s == last)
+    def _finalize():
+        l = l_ref[:]
+        heads, dv = o_ref.shape[1], o_ref.shape[2]
+        row = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), dimension=0)
+        own = row // (heads // num_kv)
+        out = jnp.zeros((heads, dv), jnp.float32)
+        for kh in range(num_kv):  # static: a lane-aligned slice a KV head
+            out = out + jnp.where(own == kh,
+                                  acc_ref[:, kh * dv:(kh + 1) * dv], 0.0)
+        o_ref[0] = (out / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("num_kv", "pages", "interpret"))
+def paged_flat_decode(
+    q: jnp.ndarray,  # [B, H, D]
+    k_pages: jnp.ndarray,  # [L, P, PS, K*D] — a cell's KV heads side by side
+    v_pages: jnp.ndarray,  # [L, P, PS, K*Dv]
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
+    *,
+    num_kv: int,
+    pages: int | None = None,
+    work: DecodeWork | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Ragged PAGED one-token GQA decode attention over a pool without a
+    head axis. Returns [B, H, Dv]. Grid, `work`, `layer`, `pages` and the
+    rows that are not live: paged_flash_decode's contract word for word.
+    Scores scale by D ** -0.5; Dv must be a multiple of 128 lanes."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, h, d = q.shape
+    ps, kd = k_pages.shape[2:]
+    kdv = v_pages.shape[-1]
+    dv = kdv // num_kv
+    if work is None:
+        work = decode_work_list(block_tables, kv_lens, page_size=ps,
+                                pages=pages)
+    # head r's query in the columns of its KV head r // G, zeros elsewhere
+    own = (jnp.arange(h)[:, None] // (h // num_kv)
+           == jnp.arange(num_kv)[None, :])  # [H, K]
+    q_wide = jnp.where(own[None, :, :, None], q[:, :, None, :],
+                       jnp.zeros((), q.dtype)).reshape(b, h, kd)
+
+    def row_spec(width):
+        return pl.BlockSpec((1, h, width), _latent_row_map,
+                            memory_space=pltpu.VMEM)
+
+    def pool_spec(width):
+        return pl.BlockSpec((None, 1, ps, width), _latent_page_map,
+                            memory_space=pltpu.VMEM)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(work.count,),
+        in_specs=[row_spec(kd), pool_spec(kd), pool_spec(kdv)],
+        out_specs=row_spec(dv),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, kdv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_flat_decode_kernel, block_k=ps,
+                          sweep=_swept_pages(block_tables, pages),
+                          num_kv=num_kv, scale=d**-0.5),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="paged_flat_decode",
+    )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
+      kv_lens.astype(jnp.int32), q_wide, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -68,3 +68,25 @@ def apply_rope(
     x1, x2 = jnp.split(xf, 2, axis=-1)
     rotated = jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin), axis=-1)
     return rotated.astype(x.dtype)
+
+
+def rotary_dim(head_dim: int, partial_rotary_factor: float) -> int:
+    """Numbers of a head that RoPE turns under `partial_rotary_factor`: the
+    factor times the head, rounded down to an even count (0.334 x 192 =
+    64.1 -> 64)."""
+    return int(head_dim * partial_rotary_factor) // 2 * 2
+
+
+def apply_partial_rope(
+    x: jnp.ndarray,  # [B, T, H, D]
+    positions: jnp.ndarray,  # [B, T] int32
+    inv_freq: jnp.ndarray,  # [R // 2]: rope_frequencies(R, theta)
+) -> jnp.ndarray:
+    """RoPE on the FIRST R = 2 x len(inv_freq) numbers of each head, paired
+    split-half within those R (pair i is (x[i], x[i + R/2])); the other
+    D - R pass as they are (`partial_rotary_factor`)."""
+    r = 2 * inv_freq.shape[0]
+    if r == x.shape[-1]:
+        return apply_rope(x, positions, inv_freq)
+    return jnp.concatenate(
+        (apply_rope(x[..., :r], positions, inv_freq), x[..., r:]), axis=-1)

@@ -28,7 +28,8 @@ from llmlb_tpu.models.family import Family
 PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "deepseek_v3": "debug-mla-tiny", "sdar_moe": "debug-sdar-tiny",
            "nemotron_h": "debug-nemotron-h-tiny",
-           "longcat_flash": "debug-longcat-tiny"}
+           "longcat_flash": "debug-longcat-tiny",
+           "mimo_v2": "debug-mimo-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -170,26 +171,33 @@ OLD_MODEL_TYPES = {
     "llama": "llama", "mistral": "llama", "qwen2": "llama",
     "mixtral": "mixtral", "deepseek_v3": "deepseek_v3",
     "sdar_moe": "sdar_moe", "nemotron_h": "nemotron_h",
-    "longcat_flash": "longcat_flash",
+    "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
 }
 OLD_MECHANISM_KEYS = {
     "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
     "q_lora_rank": ("longcat_flash",),
     "zero_expert_num": ("longcat_flash",),
-    "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash"),
+    "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash",
+                         "mimo_v2"),
     "n_shared_experts": ("deepseek_v3", "nemotron_h"),
     "first_k_dense_replace": ("deepseek_v3",),
     "num_local_experts": ("mixtral",),
     "num_experts": ("mixtral", "sdar_moe"),
-    "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h"),
+    "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h",
+                              "mimo_v2"),
     "hybrid_override_pattern": ("nemotron_h",),
     "mamba_num_heads": ("nemotron_h",),
     "ssm_state_size": ("nemotron_h",),
-    "expert_parallel": ("nemotron_h", "longcat_flash"),
-    "sliding_window": (),
+    "expert_parallel": ("nemotron_h", "longcat_flash", "mimo_v2"),
+    # a window and a partial rotary embedding: computed by one family since
+    # PR 45, refused for every other as they were for all
+    "sliding_window": ("mimo_v2",),
+    "partial_rotary_factor": ("mimo_v2",),
+    "hybrid_layer_pattern": ("mimo_v2",),
+    "moe_layer_freq": ("mimo_v2",),
+    "swa_num_key_value_heads": ("mimo_v2",),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
-    "partial_rotary_factor": (),
 }
 
 
