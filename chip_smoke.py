@@ -859,10 +859,17 @@ def kernel_leg() -> int:
 
         attempt("flash_prefill", f"B={b},T={t}", prefill)
 
-    # extend: a 512-token prefill chunk, and the speculative verify width
-    for b, t in ((2, 512), (8, 5)):
-        starts = jnp.asarray(rng.integers(0, CAP - t, b), jnp.int32)
+    # extend: a 512-token prefill chunk (a grid step takes its page a KV
+    # head at a time), the speculative verify width and a block pass of
+    # generation by diffusion, 32 rows x 2 blocks of 4 under the block mask
+    # (the page as it is stored, in one masked product: pa.extend_body)
+    for b, t, block in ((2, 512, 1), (8, 5, 1), (32, 8, 4)):
+        body = pa.extend_body(min(pa.EXTEND_BLOCK_Q, t), H, K, PS)
+        case = f"B={b},T={t},block={block},body={body}"
+        starts = jnp.asarray(rng.integers(0, (CAP - t) // block, b) * block,
+                             jnp.int32)
         chunk = jnp.asarray(rng.integers(max(1, t // 2), t + 1, b), jnp.int32)
+        chunk = jnp.maximum(chunk // block * block, block)  # whole blocks
         positions = starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
         q = rand(b, t, H, D)
 
@@ -874,24 +881,26 @@ def kernel_leg() -> int:
                       for qp in quant_pools)
             if quant:
                 want = xla.paged_attention_extend(q, qk, qv, layer, tables,
-                                                  positions, chunk)
+                                                  positions, chunk, block)
                 got = pa.paged_flash_extend_quant(
                     q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer],
-                    layer, tables, starts, chunk, interpret=False)
+                    layer, tables, starts, chunk, interpret=False,
+                    block=block)
             else:
                 want = xla.paged_attention_extend(q, k_pages, v_pages, layer,
-                                                  tables, positions, chunk)
+                                                  tables, positions, chunk,
+                                                  block)
                 got = pa.paged_flash_extend(q, k_pages, v_pages, layer,
                                             tables, starts, chunk,
-                                            interpret=False)
+                                            interpret=False, block=block)
             check("paged_flash_extend_quant" if quant else
-                  "paged_flash_extend", f"B={b},T={t},layer={layer}", got,
-                  want, valid=chunk)
+                  "paged_flash_extend", f"{case},layer={layer}", got, want,
+                  valid=chunk)
 
         for quant in (False, True):
             for layer in (0, 1):
                 attempt("paged_flash_extend_quant" if quant else
-                        "paged_flash_extend", f"B={b},T={t},layer={layer}",
+                        "paged_flash_extend", f"{case},layer={layer}",
                         lambda: paged_extend(quant, layer))
 
     # LoRA bgmv: decode rows, a prefill chunk, the verify width; through

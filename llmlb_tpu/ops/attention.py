@@ -46,8 +46,12 @@ def _pallas_enabled() -> bool:
 # What each dispatcher below resolved to the last time it was traced, by op
 # ("paged_decode" -> "pallas:paged_flash_decode" or "xla"). The engine's
 # /api/health serves it, so "which kernel ran" is read off the dispatch
-# itself instead of a rule restated elsewhere.
-_traced: dict[str, str] = {}
+# itself instead of a rule restated elsewhere. One key maps further:
+# "paged_extend_body" -> {queries of a traced chunk: "page" | "heads"}, the
+# form of the Pallas extend kernels' grid step each extend program holds
+# (pallas_attention.extend_body: static a program, so "how often" is "in
+# which programs").
+_traced: dict[str, str | dict[str, str]] = {}
 
 
 def attention_mode() -> str:
@@ -55,9 +59,10 @@ def attention_mode() -> str:
     return "pallas" if _pallas_enabled() else "xla"
 
 
-def traced_routes() -> dict[str, str]:
+def traced_routes() -> dict[str, str | dict[str, str]]:
     """op -> kernel for every attention dispatcher traced so far."""
-    return dict(_traced)
+    return {op: dict(route) if isinstance(route, dict) else route
+            for op, route in _traced.items()}
 
 
 def _split_gqa(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
@@ -292,6 +297,12 @@ def paged_attention_extend(
     under the extend program's layer scan `layer` is a run-time value and
     the pool the scan's carry."""
     if _pallas_enabled():
+        from llmlb_tpu.ops.pallas_attention import EXTEND_BLOCK_Q, extend_body
+
+        _, t, heads, _ = q.shape
+        _, _, ps, num_kv, _ = _pool_shape(k_pages)
+        _traced.setdefault("paged_extend_body", {})[str(t)] = extend_body(
+            min(EXTEND_BLOCK_Q, t), heads, num_kv, ps)
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_extend_quant
 

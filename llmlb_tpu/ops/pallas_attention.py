@@ -19,8 +19,12 @@ correctness baselines; these kernels replace them on TPU:
   stored, [PS*K, D], and every query head against it in one masked product
   (`_decode_item`): no per-head slice of the page.
 - `paged_flash_extend` (+ `_quant`): a chunk of queries against the pool
-  (chunked prefill, speculative verify), the stacked pool read in place at
-  (layer, page of the row's table) as in decode.
+  (chunked prefill, speculative verify, a block pass of generation by
+  diffusion), the stacked pool read in place at (layer, page of the row's
+  table) as in decode. What a grid step does with its page follows the q
+  block's size (`extend_body`, from the shapes alone): a few queries take
+  the page as it is stored, in one masked product as decode does; a prefill
+  chunk's 128 take it a KV head at a time (`_extend_item`).
 - `flash_prefill`: causal self-attention over bucketed prompts. Grid is
   (batch, q_block, kv_block); fully-future KV blocks (k_start > q_end) skip
   compute, giving the ~2x causal FLOP saving dense XLA attention leaves on the
@@ -30,8 +34,9 @@ correctness baselines; these kernels replace them on TPU:
 Mosaic tiling: blocks always take the FULL trailing (heads, head_dim) dims —
 the lowering requires the last two block dims be (8,128)-aligned *or* equal to
 the array dims, and "equal" holds for any head count this way. The prefill
-and extend kernels iterate KV heads with a static (unrolled) loop inside the
-kernel; the decode kernels take all of a page's heads at once.
+kernel, and the extend kernels at a large q block, iterate KV heads with a
+static (unrolled) loop inside the kernel; the decode kernels, and the extend
+kernels at a small q block, take all of a page's heads at once.
 
 Numerics match the XLA baselines: fp32 scores/softmax/accumulation
 (`preferred_element_type`), finite -1e30 masking (fully-masked rows stay NaN-free).
@@ -273,6 +278,15 @@ def _paged_decode_sink_kernel(
                  sink_ref=sink_ref, **kw)
 
 
+def _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, dtype):
+    """An int8 page's keys and values [1, PS, K, D] times their scales
+    [1, PS, K], in `dtype` and as the page's [PS*K, D] rows."""
+    _, ps, num_kv, d = k_ref.shape
+    k = (k_ref[0].astype(jnp.float32) * ks_ref[0][:, :, None]).astype(dtype)
+    v = (v_ref[0].astype(jnp.float32) * vs_ref[0][:, :, None]).astype(dtype)
+    return k.reshape(ps * num_kv, d), v.reshape(ps * num_kv, d)
+
+
 def _paged_decode_quant_kernel(
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
     q_ref,  # [1, H, D]
@@ -291,13 +305,9 @@ def _paged_decode_quant_kernel(
     the pool's [PS, K, D] (a scale [PS, K] meets its vector there) and take
     the body's [PS*K, D] once they are in q's dtype."""
     del layer_ref, pool_page_of_ref
-    dtype = q_ref.dtype
-    _, ps, num_kv, d = k_ref.shape
 
     def page():
-        k = (k_ref[0].astype(jnp.float32) * ks_ref[0][:, :, None]).astype(dtype)
-        v = (v_ref[0].astype(jnp.float32) * vs_ref[0][:, :, None]).astype(dtype)
-        return k.reshape(ps * num_kv, d), v.reshape(ps * num_kv, d)
+        return _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, q_ref.dtype)
 
     _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
                  m_ref, l_ref, acc_ref, page, **kw)
@@ -859,19 +869,73 @@ def flash_prefill(
 
 
 # ---------------------------------------------------------------------------
-# Extend body (chunked prefill): q block [1, BLK_Q, K, G, D] vs one KV block
-# of BLK_K cells; the chunk starts at global position start_pos[b]
-# (contiguous positions).
+# Extend body (chunked prefill, verify, a block pass): a q block of BLK_Q
+# positions vs one KV block of BLK_K cells; the chunk starts at global
+# position start_pos[b] (contiguous positions). One algorithm in two inner
+# forms, picked from the shapes by `extend_body`.
 # ---------------------------------------------------------------------------
+
+EXTEND_BLOCK_Q = 128  # the paged extend kernels' q block, at most
+
+# The float32 scores ([BLK_Q*H, PS*K]) up to which a grid step takes its page
+# as it is stored: the measured crossover (`extend_body`).
+_PAGE_BODY_MAX_SCORE_BYTES = 2 << 20
+
+
+def extend_body(blk_q: int, heads: int, num_kv: int, page_size: int) -> str:
+    """Which inner form a grid step of the paged extend kernels takes, from
+    the shapes the wrapper sees at trace time: "page" (one masked product
+    over the page's [PS*K, D] rows, `_extend_item`) or "heads" (a product a
+    KV head on that head's slice of the page).
+
+    The arithmetic: the masked product computes every query row against
+    every KV head's columns, K times the FLOPs and K times the softmax
+    elements of the per-head form: 4 * R * D * PS*K FLOP a page against its
+    4 * PS*K*D bytes, R = blk_q * heads FLOP a byte whatever K and the page
+    size, where the chip's ridge is about 240 (`_decode_item`). That puts
+    the threshold at 256 rows: a block pass's 8 positions x 32 heads on the
+    one side, a prefill chunk's 128 x 32 = 4,096 on the other.
+
+    The measurement (scripts/decode_page_cost.py on a v5e, both forms at
+    every q block; PERF.md §6, PR 46) agrees on those two and puts the
+    crossover higher between them, so the measured one stands. The
+    per-head form pays its 2 * K sublane slices of the stored page whatever
+    the q block: 4.1-4.2 us a page of 128 cells at 4 and at 2 KV heads up to
+    16 positions, 1.7 at 8 KV heads, and from there it grows with the rows.
+    The masked form costs about 3.5 ns a ROW of the q block whatever K
+    (0.81 / 1.02 / 0.83 us a page at 256 rows and 4 / 8 / 2 KV heads, 3.6 at
+    1,024 at all three): the accumulators' rows, not the MXU, are its cost.
+    They meet where a step's scores are 2 MB: the masked form is the faster
+    at 4 KV heads through 32 positions x 32 heads (3.62 against 4.68 us), at
+    2 through 64 (5.20 against 9.14), at 8 through 16 (1.86 against 1.84, a
+    tie; 3.64 against 2.45 at 32). At 8 MB Mosaic refuses the scores the
+    VMEM (128 positions at 4 KV heads); 32 ungrouped KV heads are past 2 MB
+    at 8 positions."""
+    score_bytes = blk_q * heads * page_size * num_kv * 4
+    return "page" if score_bytes <= _PAGE_BODY_MAX_SCORE_BYTES else "heads"
 
 
 def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
-                 acc_ref, kv_head, *, block_q: int, block_k: int,
+                 acc_ref, load, *, body: str, block_q: int, block_k: int,
                  num_kv: int, groups: int, scale: float, block: int):
     """One grid step (row b, q block qi, KV block ki) of a paged extend
     kernel: online softmax (m/l/acc) lives in VMEM scratch across a q
-    block's KV blocks; `kv_head(h)` loads head h's [BLK_K, D] keys and
-    values of the step's block."""
+    block's KV blocks.
+
+    `body` "heads": q and out blocks [1, BLK_Q, K, G, D], scratch [K,
+    BLK_Q*G, .]; `load(h)` gives KV head h's [BLK_K, D] keys and values, a
+    slice of the stored page, and each head takes its own product, softmax
+    update and product.
+
+    `body` "page", the extend twin of `_decode_item`: q and out blocks as
+    the chunk lies, [1, BLK_Q*H, D] (row r the query of position r // H and
+    head r % H, of KV head (r % H) // G), scratch [BLK_Q*H, .]; `load()`
+    gives the step's page as it is stored, [PS*K, D], row t*K + h the
+    vector of cell t and KV head h, and every row meets it in ONE product,
+    one softmax update and one product. Entry (r, c) counts where column
+    c's KV head is row r's and its cell is visible to row r's query; every
+    other entry is -1e30, whose exp is exactly 0 (a row's first page always
+    holds a visible cell, so its maximum is a real score from there on)."""
     b = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -886,7 +950,6 @@ def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
 
     q_start = qi * block_q
     k_start = ki * block_k
-    rows = block_q * groups
     # Skip KV blocks entirely in the future of every query in this Q block
     # (query global positions are start + q_start .. start + q_start+BLK_Q-1),
     # so extend cost scales with the context actually filled, not capacity;
@@ -897,8 +960,8 @@ def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
         q_start < chunk_lens_ref[b],
     )
 
-    @pl.when(useful)
-    def _compute():
+    def _heads_step():
+        rows = block_q * groups
         row = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), dimension=0)
         col = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_k), dimension=1
@@ -907,7 +970,7 @@ def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
         mask = col <= _block_end(q_pos, block)
         for h in range(num_kv):  # static unroll over KV heads
             q = q_ref[0, :, h].reshape(rows, -1)  # [BLK_Q*G, D]
-            k, v = kv_head(h)  # [BLK_K, D] each
+            k, v = load(h)  # [BLK_K, D] each
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -915,12 +978,34 @@ def _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
             scores = jnp.where(mask, scores, _NEG_INF)
             _online_update(m_ref, l_ref, acc_ref, h, scores, v)
 
+    def _page_step():
+        heads = num_kv * groups
+        k, v = load()  # [PS*K, D] each
+        scores = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [BLK_Q*H, PS*K]
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k * num_kv), dimension=1)
+        row = jax.lax.broadcasted_iota(
+            jnp.int32, (block_q * heads, 1), dimension=0)
+        q_pos = start + q_start + row // heads  # global position per query
+        keep = jnp.logical_and(
+            col % num_kv == row % heads // groups,
+            k_start + col // num_kv <= _block_end(q_pos, block))
+        scores = jnp.where(keep, scores, _NEG_INF)
+        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v)
+
+    pl.when(useful)(_page_step if body == "page" else _heads_step)
+
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         out = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        o_ref[0] = out.reshape(num_kv, block_q, groups, -1).transpose(1, 0, 2, 3)
+        if body == "heads":  # [K, BLK_Q*G, D] -> the block's [BLK_Q, K, G, D]
+            out = out.reshape(num_kv, block_q, groups, -1).transpose(1, 0, 2, 3)
+        o_ref[0] = out
 
 
 # ---------------------------------------------------------------------------
@@ -939,9 +1024,19 @@ def _extend_q_map(bi, qi, si, layer, tables, starts, lens):
     return (bi, qi, 0, 0, 0)
 
 
+def _extend_q_rows_map(bi, qi, si, layer, tables, starts, lens):
+    """q and out seen as [B, T*H, D]: the same q block, as its rows."""
+    return (bi, qi, 0)
+
+
 def _extend_page_map(bi, qi, si, layer, tables, starts, lens):
     """KV values [L, P, PS, K, D]: logical page si of row bi, of the layer."""
     return (layer[0], tables[bi, si], 0, 0, 0)
+
+
+def _extend_page_rows_map(bi, qi, si, layer, tables, starts, lens):
+    """KV values seen as [L, P, PS*K, D]: the same page, as its rows."""
+    return (layer[0], tables[bi, si], 0, 0)
 
 
 def _extend_scale_map(bi, qi, si, layer, tables, starts, lens):
@@ -955,39 +1050,46 @@ def _paged_extend_kernel(
     # the logical KV position of grid step `ki` is ki * page_size
     layer_ref, block_tables_ref, start_pos_ref, chunk_lens_ref,
     # inputs
-    q_ref,  # [1, BLK_Q, K, G, D]
-    k_ref,  # [1, PS, K, D]
-    v_ref,  # [1, PS, K, D]
+    q_ref,  # [1, BLK_Q, K, G, D] | [1, BLK_Q*H, D]
+    k_ref,  # [1, PS, K, D] | [1, PS*K, D]
+    v_ref,  # [1, PS, K, D] | [1, PS*K, D]
     # output
-    o_ref,  # [1, BLK_Q, K, G, D]
+    o_ref,  # as q_ref
     # scratch
-    m_ref,  # [K, BLK_Q * G, 1] f32
-    l_ref,  # [K, BLK_Q * G, 1] f32
-    acc_ref,  # [K, BLK_Q * G, D] f32
-    **kw,
+    m_ref,  # [K, BLK_Q * G, 1] | [BLK_Q * H, 1] f32
+    l_ref,  # [K, BLK_Q * G, 1] | [BLK_Q * H, 1] f32
+    acc_ref,  # [K, BLK_Q * G, D] | [BLK_Q * H, D] f32
+    *, body: str, **kw,
 ):
+    """Shapes as `_extend_item`'s `body` has them: "heads" | "page"."""
     del layer_ref, block_tables_ref
 
     def kv_head(h):
         return k_ref[0, :, h, :], v_ref[0, :, h, :]
 
+    def page():
+        return k_ref[0], v_ref[0]
+
     _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
-                 acc_ref, kv_head, **kw)
+                 acc_ref, page if body == "page" else kv_head, body=body, **kw)
 
 
 def _paged_extend_quant_kernel(
     layer_ref, block_tables_ref, start_pos_ref, chunk_lens_ref,
-    q_ref,  # [1, BLK_Q, K, G, D]
+    q_ref,  # [1, BLK_Q, K, G, D] | [1, BLK_Q*H, D]
     k_ref,  # [1, PS, K, D] int8
     ks_ref,  # [1, PS, K] f32
     v_ref,  # [1, PS, K, D] int8
     vs_ref,  # [1, PS, K] f32
-    o_ref,  # [1, BLK_Q, K, G, D]
+    o_ref,  # as q_ref
     m_ref, l_ref, acc_ref,
-    **kw,
+    *, body: str, **kw,
 ):
     """Int8 pool + per-vector f32 scales, dequant-on-read — the verify and
-    chunked-prefill counterpart of _paged_decode_quant_kernel."""
+    chunked-prefill counterpart of _paged_decode_quant_kernel. The blocks
+    keep the pool's [PS, K, D] in either body (a scale [PS, K] meets its
+    vector there); the "page" body dequantizes the whole page and takes its
+    [PS*K, D] rows once they are in q's dtype."""
     del layer_ref, block_tables_ref
     dtype = q_ref.dtype
 
@@ -998,43 +1100,79 @@ def _paged_extend_quant_kernel(
              * vs_ref[0, :, h][:, None]).astype(dtype)
         return k, v
 
+    def page():
+        return _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, dtype)
+
     _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
-                 acc_ref, kv_head, **kw)
+                 acc_ref, page if body == "page" else kv_head, body=body, **kw)
 
 
-def _paged_extend_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
-                       start_pos, chunk_lens, *, block_q, interpret, block):
+def _paged_extend_call(q, k_pages, v_pages, scales, layer, block_tables,
+                       start_pos, chunk_lens, *, block_q, interpret, block,
+                       body=None):
     """The pallas_call both paged extend kernels share: grid (row, q block,
-    logical page); q and out blocks follow (row, q block), the KV blocks
-    (`kv_specs`, one per operand of `kv_operands`) the row's page."""
+    logical page); q and out blocks follow (row, q block), the KV blocks the
+    row's page. `scales`: an int8 pool's (k_scales, v_scales), the layer's;
+    None for a pool in q's dtype. `body`: `extend_body`'s answer for the
+    shapes; only a measurement of both forms (scripts/decode_page_cost.py,
+    the tests) names one."""
     if interpret is None:
         interpret = _interpret_default()
     b, t, h, d = q.shape
-    _, _, ps, num_kv, _ = kv_operands[0].shape
+    layers, pool_pages, ps, num_kv, _ = k_pages.shape
     g = h // num_kv
     blk_q = min(block_q, t)
-    q_spec = pl.BlockSpec((1, blk_q, num_kv, g, d), _extend_q_map,
-                          memory_space=pltpu.VMEM)
+    if body is None:
+        body = extend_body(blk_q, h, num_kv, ps)
+    if body == "page":  # the chunk as it lies: [T*H, D] rows
+        q_shape, rows = (b, t * h, d), (blk_q * h,)
+        q_spec = pl.BlockSpec((1, blk_q * h, d), _extend_q_rows_map,
+                              memory_space=pltpu.VMEM)
+    else:
+        q_shape, rows = (b, t, num_kv, g, d), (num_kv, blk_q * g)
+        q_spec = pl.BlockSpec((1, blk_q, num_kv, g, d), _extend_q_map,
+                              memory_space=pltpu.VMEM)
+    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _extend_page_map,
+                           memory_space=pltpu.VMEM)
+    if scales is not None:
+        scale_spec = pl.BlockSpec((1, ps, num_kv), _extend_scale_map,
+                                  memory_space=pltpu.VMEM)
+        kernel = _paged_extend_quant_kernel
+        kv_specs = [kv_spec, scale_spec, kv_spec, scale_spec]
+        kv_operands = (k_pages, scales[0], v_pages, scales[1])
+    else:
+        kernel = _paged_extend_kernel
+        kv_operands = (k_pages, v_pages)
+        if body == "page":
+            # a page as its [PS*K, D] rows: the same bytes on the chip, a
+            # bitcast (paged_flash_decode says why)
+            kv_spec = pl.BlockSpec((None, 1, ps * num_kv, d),
+                                   _extend_page_rows_map,
+                                   memory_space=pltpu.VMEM)
+            kv_operands = tuple(x.reshape(layers, pool_pages, ps * num_kv, d)
+                                for x in kv_operands)
+        kv_specs = [kv_spec, kv_spec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, pl.cdiv(t, blk_q), block_tables.shape[1]),
         in_specs=[q_spec, *kv_specs],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, 1), jnp.float32),
-            pltpu.VMEM((num_kv, blk_q * g, d), jnp.float32),
+            pltpu.VMEM((*rows, 1), jnp.float32),
+            pltpu.VMEM((*rows, 1), jnp.float32),
+            pltpu.VMEM((*rows, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(kernel, block_q=blk_q, block_k=ps, num_kv=num_kv,
-                          groups=g, scale=d**-0.5, block=block),
-        out_shape=jax.ShapeDtypeStruct((b, t, num_kv, g, d), q.dtype),
+        functools.partial(kernel, body=body, block_q=blk_q, block_k=ps,
+                          num_kv=num_kv, groups=g, scale=d**-0.5,
+                          block=block),
+        out_shape=jax.ShapeDtypeStruct(q_shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
     )(_layer_operand(layer), block_tables.astype(jnp.int32),
       start_pos.astype(jnp.int32), chunk_lens.astype(jnp.int32),
-      q.reshape(b, t, num_kv, g, d), *kv_operands)
+      q.reshape(q_shape), *kv_operands)
     return out.reshape(b, t, h, d)
 
 
@@ -1048,7 +1186,7 @@ def paged_flash_extend(
     start_pos: jnp.ndarray,  # [B] int32 — global position of the first query
     chunk_lens: jnp.ndarray,  # [B] int32 — valid queries (rest are padding)
     *,
-    block_q: int = 128,
+    block_q: int = EXTEND_BLOCK_Q,
     interpret: bool | None = None,
     block: int = 1,
 ) -> jnp.ndarray:
@@ -1057,20 +1195,19 @@ def paged_flash_extend(
     `block` > 1, ops/attention._block_end) over row b's pages (earlier
     chunks + this chunk), gathered through the prefetched block table by the
     KV BlockSpec index_map. KV blocks entirely in the future of the chunk
-    skip their FLOPs (`pl.when` in _extend_kernel), so cost scales with the
+    skip their FLOPs (`pl.when` in _extend_item), so cost scales with the
     context actually filled, not pool capacity. The pool arrives STACKED
     with the layer index beside it, paged_flash_decode's contract: read in
     place at (layer, page), never `pool[layer]` (a slice handed to a
     pallas_call is copied whole), and `layer` is a run-time operand, so the
-    layers of a scanned extend program are one kernel. Returns
-    [B, T, H, D]."""
-    _, _, ps, num_kv, d = k_pages.shape
-    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _extend_page_map,
-                           memory_space=pltpu.VMEM)
+    layers of a scanned extend program are one kernel. What a grid step
+    does with its page follows the q block's size (`extend_body`): a block
+    pass's or a verify chunk's few queries take the page as it is stored,
+    in one masked product; a prefill chunk's 128 take it a KV head at a
+    time. Returns [B, T, H, D]."""
     return _paged_extend_call(
-        _paged_extend_kernel, [kv_spec, kv_spec], (k_pages, v_pages), q,
-        layer, block_tables, start_pos, chunk_lens, block_q=block_q,
-        interpret=interpret, block=block)
+        q, k_pages, v_pages, None, layer, block_tables, start_pos,
+        chunk_lens, block_q=block_q, interpret=interpret, block=block)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret", "block"))
@@ -1085,23 +1222,16 @@ def paged_flash_extend_quant(
     start_pos: jnp.ndarray,  # [B] int32
     chunk_lens: jnp.ndarray,  # [B] int32
     *,
-    block_q: int = 128,
+    block_q: int = EXTEND_BLOCK_Q,
     interpret: bool | None = None,
     block: int = 1,
 ) -> jnp.ndarray:
     """Int8 variant of paged_flash_extend: each page's vectors dequantize
-    in VMEM. Same causal/ragged skip logic and garbage contract. The values
-    are read in place at (layer, page); the scales arrive as the layer's
-    slice and gather through the same prefetched block table
+    in VMEM. Same causal/ragged skip logic, garbage contract and choice of
+    body. The values are read in place at (layer, page); the scales arrive
+    as the layer's slice and gather through the same prefetched block table
     (paged_flash_decode_quant says why)."""
-    _, _, ps, num_kv, d = k_pages.shape
-    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _extend_page_map,
-                           memory_space=pltpu.VMEM)
-    scale_spec = pl.BlockSpec((1, ps, num_kv), _extend_scale_map,
-                              memory_space=pltpu.VMEM)
     return _paged_extend_call(
-        _paged_extend_quant_kernel,
-        [kv_spec, scale_spec, kv_spec, scale_spec],
-        (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
+        q, k_pages, v_pages, (k_scales, v_scales), layer, block_tables,
         start_pos, chunk_lens, block_q=block_q, interpret=interpret,
         block=block)

@@ -11,7 +11,16 @@ pages), and one batch drawn as each cell draws its rows (`decode-saturated`:
 32 rows somewhere between a prompt of 64-128 and 512 tokens more;
 `chat-paced`: 5 rows of 32 live, lognormal prompts and outputs). Then
 `paged_flash_extend` at the block family's call (32 rows x 8 queries, 4 KV
-heads x 8, a table 8 pages wide, blocks of 4), the same sweep.
+heads x 8, a table 8 pages wide, blocks of 4), the same sweep, at q blocks
+of 4, 8, 16, 32, 64 and 128 queries (`--q-blocks` names others), then a
+verify chunk's 8 queries and the q blocks about their crossover at the
+dense cells' heads, in BOTH forms of the kernel's grid step at each
+(`pallas_attention.extend_body`: "page", one masked product over the
+stored page; "heads", a product a KV head): µs a live page, µs a call
+before its first page, the form the kernel picks at that size, and the
+crossover — the largest q block at which the masked form is the faster. A
+form Mosaic refuses at a size (the masked one's scores at 128 queries) is
+reported as refused.
 
 A program is CALLS calls one after another, each on the last one's output, as
 a decode program's layers are; the work-list is built once outside them, as a
@@ -42,7 +51,14 @@ DECODE_SHAPES = {  # name: (KV heads, queries a KV head, layers, pool pages)
     "mistral-7b K8xG4": (8, 4, 16, 400),
     "nemotron-3-nano K2xG16": (2, 16, 2, 400),  # the cell's 2 such layers
 }
-EXTEND_SHAPE = ("sdar-30b-a3b K4xG8, 8 queries", 4, 8, 7, 544, 8, 8, 4)
+EXTEND_SHAPES = {  # name: (KV heads, queries a KV head, layers, pool pages,
+    # table width, block, q blocks): the block family's call at every q
+    # block; a verify chunk's 8 queries at the dense cells' heads, then the
+    # q blocks on both sides of `extend_body`'s threshold there
+    "sdar-30b-a3b K4xG8": (4, 8, 7, 544, 8, 4, (4, 8, 16, 32, 64, 128)),
+    "mistral-7b K8xG4": (8, 4, 16, 400, 8, 1, (8, 16, 32)),
+    "nemotron-3-nano K2xG16": (2, 16, 2, 400, 8, 1, (8, 32, 64)),
+}
 
 
 def _draw_lens(rng, cell: str):
@@ -185,15 +201,19 @@ def decode_table(shape: str, reps: int, seed: int, small: bool) -> dict:
     return {"sweep": sweep, "line": line, "cells": cells}
 
 
-def extend_table(reps: int, seed: int, small: bool) -> dict:
+def _extend_sweep(shape: str, body: str, queries: int, reps: int, seed: int,
+                  small: bool) -> dict:
+    """The sweep of one form of the extend kernels' grid step at one q
+    block: `pallas_attention._paged_extend_call` with the form named, under
+    the wrapper's name (the trace's row)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from llmlb_tpu.ops import pallas_attention as pa
 
-    name, num_kv, groups, layers, pool_pages, queries, width, block = \
-        EXTEND_SHAPE
+    num_kv, groups, layers, pool_pages, width, block, _ = \
+        EXTEND_SHAPES[shape]
     if small:
         layers, pool_pages, width = 2, ROWS * 2 + 1, 2
     rng = np.random.default_rng(seed + 1)
@@ -202,10 +222,16 @@ def extend_table(reps: int, seed: int, small: bool) -> dict:
         (layers, pool_pages, PAGE, num_kv, HEAD_DIM))
 
     @jax.jit
+    def paged_flash_extend(q, k_pages, v_pages, layer, tables, starts, chunk):
+        return pa._paged_extend_call(
+            q, k_pages, v_pages, None, layer, tables, starts, chunk,
+            block_q=pa.EXTEND_BLOCK_Q, interpret=None, block=block, body=body)
+
+    @jax.jit
     def program(q, k_pages, v_pages, tables, starts, chunk):
         for i in range(CALLS):
-            q = pa.paged_flash_extend(q, k_pages, v_pages, i % layers, tables,
-                                      starts, chunk, block=block)
+            q = paged_flash_extend(q, k_pages, v_pages, i % layers, tables,
+                                   starts, chunk)
         return q
 
     sweep = []
@@ -217,7 +243,35 @@ def extend_table(reps: int, seed: int, small: bool) -> dict:
         sweep.append({"pages_a_row": p, "live_pages": ROWS * p,
                       "grid_steps": ROWS * width,
                       **_measure(program, args, "paged_flash_extend", reps)})
-    return {"shape": name, "sweep": sweep, "line": _line(sweep)}
+    return {"sweep": sweep, "line": _line(sweep)}
+
+
+def extend_table(shape: str, q_blocks, reps: int, seed: int,
+                 small: bool) -> dict:
+    from llmlb_tpu.ops import pallas_attention as pa
+
+    num_kv, groups, *_ = EXTEND_SHAPES[shape]
+    table, faster = {}, {}
+    for queries in q_blocks:
+        row = {"chosen": pa.extend_body(min(pa.EXTEND_BLOCK_Q, queries),
+                                        num_kv * groups, num_kv, PAGE)}
+        for body in ("page", "heads"):
+            try:
+                row[body] = _extend_sweep(shape, body, queries, reps, seed,
+                                          small)
+            except Exception as e:  # Mosaic refuses the form at this size
+                row[body] = {"refused": f"{type(e).__name__}: {e}"[:300]}
+        if all("line" in row[body] for body in ("page", "heads")):
+            faster[queries] = min(
+                ("page", "heads"), key=lambda b: row[b]["line"]["page_us"])
+        table[str(queries)] = row
+    page_wins = [n for n, body in faster.items() if body == "page"]
+    return {"q_blocks": table,
+            "faster_by_page_us": {str(n): b for n, b in faster.items()},
+            "crossover": {"largest_q_block_page_wins":
+                          max(page_wins) if page_wins else None,
+                          "rows_there": max(page_wins) * num_kv * groups
+                          if page_wins else None}}
 
 
 def main() -> int:
@@ -226,6 +280,8 @@ def main() -> int:
                     help="runs of a program of 16 calls, a measurement")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--only", choices=("decode", "extend"))
+    ap.add_argument("--q-blocks", help="queries a row of the extend tables' "
+                    "calls, for every shape (default: each shape's own)")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "decode_page_cost.json"))
     args = ap.parse_args()
@@ -246,7 +302,11 @@ def main() -> int:
             shape: decode_table(shape, reps, args.seed, small)
             for shape in DECODE_SHAPES}
     if args.only != "decode":
-        out["paged_flash_extend"] = extend_table(reps, args.seed, small)
+        out["paged_flash_extend"] = {
+            shape: extend_table(
+                shape, [int(n) for n in args.q_blocks.split(",")]
+                if args.q_blocks else spec[-1], reps, args.seed, small)
+            for shape, spec in EXTEND_SHAPES.items()}
     text = json.dumps(out)
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:

@@ -19,7 +19,9 @@ from llmlb_tpu.ops.attention import (
     gqa_attention_prefill,
 )
 from llmlb_tpu.ops.pallas_attention import (
+    _paged_extend_call,
     decode_work_list,
+    extend_body,
     flash_prefill,
     paged_flash_decode,
     paged_flash_extend,
@@ -375,49 +377,199 @@ def test_decode_work_list_follows_lens_inside_a_scan():
                                                 2 + 1 + 2 + 3]
 
 
+# Where a chunk of 8 queries lies on pages of 128 cells, a row each: from the
+# middle of a page; across a page boundary inside the q block; its last query
+# the ONE live cell of the row's last page; a row of no queries (padding:
+# its output is not read); a chunk of one block in the row's first cells.
+# With blocks of 4 the chunks start on block boundaries, as the engine's do.
+_CHUNKS_OF_8 = {1: ([60, 124, 249, 17, 0], [8, 8, 8, 0, 4]),
+                4: ([60, 124, 252, 16, 0], [8, 8, 8, 0, 4])}
+
+
+def _chunks_of_8(b, block):
+    starts, lens = _CHUNKS_OF_8[block]
+    return ([starts[i % len(starts)] for i in range(b)],
+            [lens[i % len(lens)] for i in range(b)])
+
+
 @pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize(
-    "b,t,h,kv,d,page_size,pages_per_seq,block_q",
+    "b,t,h,kv,d,page_size,pages_per_seq,block_q,dtype,block",
     [
-        (2, 16, 8, 8, 32, 16, 4, 16),  # MHA
-        (2, 8, 8, 2, 16, 32, 2, 4),  # GQA g=4, small q blocks
-        (1, 12, 4, 1, 32, 16, 3, 8),  # MQA, ragged T
+        (2, 16, 8, 8, 32, 16, 4, 16, jnp.float32, 1),  # MHA
+        (2, 8, 8, 2, 16, 32, 2, 4, jnp.float32, 1),  # GQA g=4, small q blocks
+        (1, 12, 4, 1, 32, 16, 3, 8, jnp.float32, 1),  # MQA, ragged T
+        # the block family's call as its cell makes it (32 rows x 8 queries,
+        # 4 x 8 heads of 128, pages of 128, bf16, blocks of 4), then a
+        # chunk of 8 at the dense cells' heads: Mistral-7B's 8 x 4 and
+        # Nemotron-3-Nano's 2 x 16 — the page as it is stored (`extend_body`)
+        (32, 8, 32, 4, 128, 128, 3, 128, jnp.bfloat16, 4),
+        (5, 8, 32, 8, 128, 128, 3, 128, jnp.bfloat16, 1),
+        (5, 8, 32, 2, 128, 128, 3, 128, jnp.bfloat16, 1),
+        # the largest q blocks that do: 2 MB of scores a grid step
+        (2, 32, 32, 4, 128, 128, 2, 128, jnp.bfloat16, 4),
+        (2, 64, 32, 2, 128, 128, 2, 128, jnp.bfloat16, 1),
+        # the other side of the threshold at the same heads: a head at a time
+        (2, 64, 32, 4, 128, 128, 2, 128, jnp.bfloat16, 4),
+        (2, 64, 32, 8, 128, 128, 2, 128, jnp.bfloat16, 1),
+        (2, 128, 32, 2, 128, 128, 2, 128, jnp.bfloat16, 1),
+        # two q blocks a row in each form, float32
+        (5, 8, 32, 4, 32, 128, 3, 4, jnp.float32, 4),
+        (2, 64, 32, 8, 32, 128, 2, 32, jnp.float32, 1),
     ],
 )
 def test_paged_flash_extend_matches_dense(b, t, h, kv, d, page_size,
-                                          pages_per_seq, block_q, layer):
+                                          pages_per_seq, block_q, dtype,
+                                          block, layer):
     """The kernel reads the stacked pool at (layer, page): every other layer
-    is NaN, so a page of the wrong layer shows in the output."""
+    is NaN, so a page of the wrong layer shows in the output. Either form of
+    its grid step (the q block's size decides, `extend_body`) against the
+    float32 einsum over the gathered cache, under the causal mask and under
+    the block mask."""
     keys = jax.random.split(jax.random.PRNGKey(12), 4)
     cap = page_size * pages_per_seq
-    q = _rand(keys[0], (b, t, h, d))
+    q = _rand(keys[0], (b, t, h, d)).astype(dtype)
     k_pages, v_pages, tables = _paged_fixture(
         keys[1], b, h, kv, d, page_size, pages_per_seq)
-    start_pos = jax.random.randint(keys[2], (b,), 0, cap - t, jnp.int32)
-    chunk_lens = jax.random.randint(keys[3], (b,), 1, t + 1, jnp.int32)
+    k_pages, v_pages = k_pages.astype(dtype), v_pages.astype(dtype)
+    if (t, page_size) == (8, 128):
+        start_pos, chunk_lens = (jnp.asarray(x, jnp.int32)
+                                 for x in _chunks_of_8(b, block))
+    else:
+        start_pos = jax.random.randint(keys[2], (b,), 0, (cap - t) // block,
+                                       jnp.int32) * block
+        chunk_lens = jax.random.randint(keys[3], (b,), 1, t + 1, jnp.int32)
     q_positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
 
-    k_cache = gather_kv_pages(k_pages, tables)
-    v_cache = gather_kv_pages(v_pages, tables)
-    expected = gqa_attention_extend(q, k_cache, v_cache, q_positions)
+    k_cache = gather_kv_pages(k_pages.astype(jnp.float32), tables)
+    v_cache = gather_kv_pages(v_pages.astype(jnp.float32), tables)
+    expected = gqa_attention_extend(q.astype(jnp.float32), k_cache, v_cache,
+                                    q_positions, block)
     k_pool, v_pool = _stacked(k_pages, layer), _stacked(v_pages, layer)
     got = paged_flash_extend(
         q, k_pool, v_pool, layer, tables, start_pos, chunk_lens,
-        block_q=block_q, interpret=True,
+        block_q=block_q, interpret=True, block=block,
     )
+    assert got.dtype == dtype and got.shape == q.shape
+    tol = 2e-5 if dtype == jnp.float32 else _BF16_TOL
     # Padding rows (t >= chunk_len) are ignored downstream; compare valid rows.
     lens = np.asarray(chunk_lens)
     for bi in range(b):
         np.testing.assert_allclose(
-            got[bi, : lens[bi]], expected[bi, : lens[bi]],
-            rtol=2e-5, atol=2e-5,
+            got[bi, : lens[bi]].astype(jnp.float32),
+            expected[bi, : lens[bi]], rtol=tol, atol=tol,
         )
+    assert np.isfinite(np.asarray(got.astype(jnp.float32))).all()
     # the XLA dispatcher path must agree everywhere (it has no padding
     # skip): one gather at (layer, table), the same cells
     got2 = paged_attention_extend(
-        q, k_pool, v_pool, layer, tables, q_positions, chunk_lens
+        q, k_pool, v_pool, layer, tables, q_positions, chunk_lens, block
     )
-    np.testing.assert_allclose(got2, expected, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got2.astype(jnp.float32), expected,
+                               rtol=tol, atol=tol)
+
+
+def test_the_extend_cases_stand_on_both_sides_of_the_threshold():
+    """The cases above at 8 queries take the page as it is stored, those at
+    64 (at 2 KV heads: 128) a head at a time, at the heads of the cells that
+    run them."""
+    for kv, past in ((4, 64), (8, 64), (2, 128)):
+        assert extend_body(8, 32, kv, 128) == "page"
+        assert extend_body(past, 32, kv, 128) == "heads"
+        if kv != 8:
+            assert extend_body(past // 2, 32, kv, 128) == "page"
+    assert extend_body(4, 32, 4, 128) == "page"
+    assert extend_body(32, 32, 8, 128) == "heads"
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", sorted(HEAD_SHAPES))
+def test_paged_flash_extend_lets_no_other_head_through(shape, dtype):
+    """The extend twin of test_paged_flash_decode_lets_no_other_head_through:
+    at a small q block a grid step takes every query row against every KV
+    head's columns of the page and masks the other heads'. With every OTHER
+    KV head's keys and values replaced by large finite numbers, the queries
+    of one KV head come out BIT-identical."""
+    kv, g = HEAD_SHAPES[shape]
+    b, t, d, ps, ppn, layer = 2, 4, 32, 16, 3, 1
+    assert extend_body(t, kv * g, kv, ps) == "page"
+    keys = jax.random.split(jax.random.PRNGKey(17), 2)
+    q = _rand(keys[0], (b, t, kv * g, d)).astype(dtype)
+    k_pages, v_pages, tables = _paged_fixture(keys[1], b, kv * g, kv, d, ps,
+                                              ppn)
+    start = jnp.array([ps * 2 - 2, 5], jnp.int32)  # across a page boundary
+    lens = jnp.array([t, t - 1], jnp.int32)
+
+    def run(k, v):
+        return np.asarray(paged_flash_extend(
+            q, _stacked(k.astype(dtype), layer),
+            _stacked(v.astype(dtype), layer), layer, tables, start, lens,
+            interpret=True).astype(jnp.float32))
+
+    clean = run(k_pages, v_pages)
+    assert np.isfinite(clean).all()
+    for head in range(kv):
+        others = (jnp.arange(kv) != head)[None, None, :, None]
+        # alternating signs, so that a leak neither saturates nor cancels
+        loud = jnp.where(jnp.arange(d) % 2 == 0, 3e4, -3e4)
+        got = run(jnp.where(others, loud, k_pages),
+                  jnp.where(others, -loud, v_pages))
+        mine = slice(head * g, (head + 1) * g)
+        np.testing.assert_array_equal(got[:, :, mine], clean[:, :, mine])
+
+
+@pytest.mark.parametrize("block", [1, 4])
+def test_the_two_extend_bodies_agree(block):
+    """One algorithm in two inner forms: at a shape both take, the masked
+    product over the stored page and the product a KV head give the same
+    attention (float32: to rounding's order of summation), padding rows
+    and a row of no queries included."""
+    b, t, kv, g, d, ps, ppn, layer = 3, 8, 4, 2, 32, 16, 4, 2
+    keys = jax.random.split(jax.random.PRNGKey(18), 2)
+    q = _rand(keys[0], (b, t, kv * g, d))
+    k_pages, v_pages, tables = _paged_fixture(keys[1], b, kv * g, kv, d, ps,
+                                              ppn)
+    start = jnp.array([ps * 2 - 4, 8, 20], jnp.int32)
+    lens = jnp.array([t, 0, t - 4], jnp.int32)
+    got = {body: np.asarray(_paged_extend_call(
+        q, _stacked(k_pages, layer), _stacked(v_pages, layer), None, layer,
+        tables, start, lens, block_q=4, interpret=True, block=block,
+        body=body)) for body in ("page", "heads")}
+    np.testing.assert_allclose(got["page"], got["heads"], rtol=2e-6,
+                               atol=2e-6)
+    assert not got["page"][1].any() and not got["heads"][1].any()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_the_route_record_says_which_body_an_extend_program_holds(
+        quantized, monkeypatch):
+    """The choice is static a program, so "how often" is "in which
+    programs": the dispatcher's route record (the engine's health route
+    shows it under `traced`) maps each traced query count to the form of
+    the kernel's grid step, and `paged_extend` keeps its values."""
+    from llmlb_tpu.ops import attention
+
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    monkeypatch.setattr(attention, "_traced", {})
+    pool = jax.ShapeDtypeStruct((2, 5, 128, 8, 16),
+                                jnp.int8 if quantized else jnp.float32)
+    if quantized:
+        pool = {"q": pool, "s": jax.ShapeDtypeStruct((2, 5, 128, 8),
+                                                     jnp.float32)}
+    for t in (8, 64):
+        jax.eval_shape(
+            paged_attention_extend,
+            jax.ShapeDtypeStruct((1, t, 32, 16), jnp.float32), pool, pool, 0,
+            jax.ShapeDtypeStruct((1, 4), jnp.int32),
+            jax.ShapeDtypeStruct((1, t), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+    routes = attention.traced_routes()
+    assert routes["paged_extend_body"] == {"8": "page", "64": "heads"}
+    assert routes["paged_extend"] == (
+        "pallas:paged_flash_extend" + "_quant" * quantized)
+    routes["paged_extend_body"]["8"] = "?"  # a copy, not the record
+    assert attention.traced_routes()["paged_extend_body"]["8"] == "page"
 
 
 def test_paged_flash_extend_under_a_scan_takes_the_layer_at_run_time():

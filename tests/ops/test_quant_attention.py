@@ -12,6 +12,7 @@ from llmlb_tpu.ops.attention import (
     paged_attention_extend,
 )
 from llmlb_tpu.ops.pallas_attention import (
+    extend_body,
     paged_flash_decode,
     paged_flash_decode_quant,
     paged_flash_extend,
@@ -214,34 +215,93 @@ def test_paged_flash_decode_quant_reads_live_pages_only(case, monkeypatch):
         assert np.abs(got[live] - expected[live]).max() < 2e-3
 
 
+# (shape of SHAPES, queries, block_q, block): the module's small shape at a
+# chunk of 6 (its 48 rows take the page as it is stored) and split into q
+# blocks of 4; then a chunk of 8 at the heads of the cells that run one —
+# the block family's 4 x 8 under its block mask, Mistral-7B's and
+# Nemotron-3-Nano's — and 64 queries at the same heads, a head at a time
+EXTEND_SHAPES = {**SHAPES, "K4xG8": (4, 8, 128, 128)}
+EXTEND_CASES = {
+    "small-6": ("small", 6, 128, 1), "small-6-by-4": ("small", 6, 4, 1),
+    "K4xG8-8-blocks-of-4": ("K4xG8", 8, 128, 4),
+    "K8xG4-8": ("K8xG4", 8, 128, 1), "K2xG16-8": ("K2xG16", 8, 128, 1),
+    "K4xG8-64-blocks-of-4": ("K4xG8", 64, 128, 4),
+    "K8xG4-64": ("K8xG4", 64, 128, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXTEND_CASES))
 @pytest.mark.parametrize("layer", [0, 1, 2])
-def test_paged_flash_extend_quant_interpret_parity(layer, monkeypatch):
+def test_paged_flash_extend_quant_interpret_parity(layer, case, monkeypatch):
     """The int8 kernel reads the stacked values at (layer, page) and takes
     the layer's scales; every other layer is poison (saturated values, 1e30
     scales). Against the bf16 kernel (tolerance) and against the XLA dequant
-    route, which reads identical cells."""
+    route, which reads identical cells — in either form of the grid step
+    (`extend_body`): the first row's chunk crosses a page boundary from the
+    middle of a page, the second's is part padding."""
     monkeypatch.setenv("LLMLB_TPU_ATTENTION", "xla")  # the reference's route
-    k_pages, v_pages, qk, qv, tables, rng = _pools(5)
+    shape, t, block_q, block = EXTEND_CASES[case]
+    k, g, d, ps = EXTEND_SHAPES[shape]
+    dtype = jnp.float32 if shape == "small" else jnp.bfloat16
+    k_pages, v_pages, qk, qv, tables, rng = _pools(5, k, d, ps)
     qk, qv = _stacked(qk, layer), _stacked(qv, layer)
-    t = 6
-    q = jnp.asarray(rng.normal(size=(B, t, H, D)), jnp.float32)
-    start = jnp.asarray([10, 2], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, t, k * g, d)), dtype)
+    # three pages a row: the first chunk lies half under, half over the
+    # boundary of the second and the third
+    start = jnp.asarray([2 * ps - t // 2, 4], jnp.int32)
     lens = jnp.asarray([t, t - 2], jnp.int32)
-    base = paged_flash_extend(q, _stacked(k_pages, layer),
-                              _stacked(v_pages, layer), layer, tables, start,
-                              lens, interpret=True)
+    base = paged_flash_extend(q, _stacked(k_pages, layer).astype(dtype),
+                              _stacked(v_pages, layer).astype(dtype), layer,
+                              tables, start, lens, block_q=block_q,
+                              interpret=True, block=block)
     quant = paged_flash_extend_quant(
         q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer, tables,
-        start, lens, interpret=True,
+        start, lens, block_q=block_q, interpret=True, block=block,
     )
+    assert quant.dtype == dtype
     positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    xla = paged_attention_extend(q, qk, qv, layer, tables, positions, lens)
+    xla = paged_attention_extend(q, qk, qv, layer, tables, positions, lens,
+                                 block)
     # padding rows past chunk_lens are garbage in both — compare valid rows
     for b, n in enumerate([t, t - 2]):
-        assert np.abs(np.asarray(base)[b, :n]
-                      - np.asarray(quant)[b, :n]).max() < TOL
-        assert np.abs(np.asarray(quant)[b, :n]
-                      - np.asarray(xla, np.float32)[b, :n]).max() < 2e-3
+        assert np.abs(np.asarray(base, np.float32)[b, :n]
+                      - np.asarray(quant, np.float32)[b, :n]).max() < TOL
+        assert np.abs(np.asarray(quant, np.float32)[b, :n]
+                      - np.asarray(xla, np.float32)[b, :n]).max() < (
+            2e-3 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_paged_flash_extend_quant_lets_no_other_head_through(shape):
+    """The int8 extend kernel under the masked product's mask
+    (test_paged_flash_extend_lets_no_other_head_through): every OTHER KV
+    head's values saturated and its scales large, and the queries of one KV
+    head come out BIT-identical."""
+    k, g, d, ps = SHAPES[shape]
+    d, ps, layer, t = min(d, 32), min(ps, 16), 1, 4
+    assert extend_body(t, k * g, k, ps) == "page"
+    _, _, qk, qv, tables, rng = _pools(5, k, d, ps)
+    q = jnp.asarray(rng.normal(size=(B, t, k * g, d)), jnp.float32)
+    start = jnp.asarray([ps * 2 - 2, 5], jnp.int32)  # across a page boundary
+    lens = jnp.asarray([t, t - 1], jnp.int32)
+
+    def run(qk, qv):
+        qk, qv = _stacked(qk, layer), _stacked(qv, layer)
+        return np.asarray(paged_flash_extend_quant(
+            q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer,
+            tables, start, lens, interpret=True))
+
+    clean = run(qk, qv)
+    assert np.isfinite(clean).all()
+    loud = jnp.where(jnp.arange(d) % 2 == 0, 127, -127).astype(jnp.int8)
+    for head in range(k):
+        others = jnp.arange(k) != head
+        got = run(*({"q": jnp.where(others[None, None, :, None], sign * loud,
+                                    pool["q"]),
+                     "s": jnp.where(others[None, None, :], 1e4, pool["s"])}
+                    for sign, pool in ((1, qk), (-1, qv))))
+        mine = slice(head * g, (head + 1) * g)
+        np.testing.assert_array_equal(got[:, :, mine], clean[:, :, mine])
 
 
 @pytest.mark.parametrize("route", ["decode", "extend"])

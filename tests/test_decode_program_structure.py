@@ -145,6 +145,96 @@ def test_a_decode_grid_step_is_one_pair_of_products(name, quantized,
     assert "for " not in body.split('"""')[2], "a loop is back in the body"
 
 
+# --- the extend kernels: what a grid step does follows the q block -----------
+
+# the block family's heads, page and pool as its cell serves them
+EXTEND_KV, EXTEND_GROUPS, EXTEND_PAGE, EXTEND_PAGES = 4, 8, 128, 544
+
+
+def _extend_operands(queries, quantized, kv=EXTEND_KV, groups=EXTEND_GROUPS,
+                     rows=32, table=8, pages=EXTEND_PAGES):
+    """(kernel, operand shapes) of one paged extend call at head size 128."""
+    pool = jax.ShapeDtypeStruct((2, pages, EXTEND_PAGE, kv, 128),
+                                jnp.int8 if quantized else jnp.bfloat16)
+    scales = jax.ShapeDtypeStruct((pages, EXTEND_PAGE, kv), jnp.float32)
+    q = jax.ShapeDtypeStruct((rows, queries, kv * groups, 128), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((rows, table), jnp.int32)
+    lens = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    if quantized:
+        return pallas_attention.paged_flash_extend_quant, (
+            q, pool, scales, pool, scales, layer, tables, lens, lens)
+    return pallas_attention.paged_flash_extend, (
+        q, pool, pool, layer, tables, lens, lens)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("queries", [8, 128])
+def test_an_extend_grid_step_follows_the_q_block(queries, quantized):
+    """At a block pass's 8 queries a grid step of the extend kernels takes
+    the page as it is stored: every query row against all of its [PS*K, D]
+    rows in ONE product, one softmax update and ONE product (PERF.md §6,
+    PR 46). At a prefill chunk's 128 it keeps a pair of products a KV head,
+    128 x G rows tall: the MXU's fill a head at a time. One kernel, the
+    form read from the shapes alone."""
+    kernel, operands = _extend_operands(queries, quantized)
+    jaxpr = jax.make_jaxpr(functools.partial(kernel, interpret=True, block=4)
+                           )(*operands).jaxpr
+    (call,) = [e for e in _equations(jaxpr) if e.primitive.name == "pallas_call"]
+    products = [tuple(v.aval.shape for v in e.invars)
+                for e in _equations(call.params["jaxpr"], True)
+                if e.primitive.name == "dot_general"]
+    heads, cells = EXTEND_KV * EXTEND_GROUPS, EXTEND_PAGE * EXTEND_KV
+    if queries == 8:
+        assert products == [
+            ((queries * heads, 128), (cells, 128)),  # q, the page's keys
+            ((queries * heads, cells), (cells, 128)),  # weights, its values
+        ]
+    else:
+        rows = queries * EXTEND_GROUPS
+        assert products == [
+            ((rows, 128), (EXTEND_PAGE, 128)),  # a head's q, its keys
+            ((rows, EXTEND_PAGE), (EXTEND_PAGE, 128)),  # weights, values
+        ] * EXTEND_KV
+
+
+# (q block, query heads, KV heads, page) -> the form of a grid step: the
+# cells' calls, then the threshold's two sides at each cell's heads
+EXTEND_BODIES = {
+    (8, 32, 4, 128): "page",  # a block pass of sdar-30b-a3b: 2 blocks of 4
+    (4, 32, 4, 128): "page",  # … and one block
+    (8, 32, 8, 128): "page",  # a verify chunk of k + 1 <= 8 at Mistral-7B's
+    (8, 32, 2, 128): "page",  # … and at Nemotron-3-Nano's heads
+    (128, 32, 4, 128): "heads",  # a prefill chunk's q block
+    (128, 32, 8, 128): "heads",
+    (128, 32, 2, 128): "heads",
+    (128, 4, 1, 16): "page",  # a debug preset's chunk: 32 KB of scores
+    (32, 32, 4, 128): "page",  # the measured crossover at 4 KV heads …
+    (64, 32, 4, 128): "heads",
+    (16, 32, 8, 128): "page",  # … at 8 …
+    (32, 32, 8, 128): "heads",
+    (64, 32, 2, 128): "page",  # … and at 2: 2 MB of scores a step
+    (8, 32, 32, 128): "heads",  # 32 ungrouped heads: 4 MB of scores
+    (1, 4, 2, 8): "page",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(EXTEND_BODIES), ids=lambda s: "-".join(
+    map(str, s)))
+def test_the_extend_body_is_a_function_of_the_shapes(shape):
+    """`extend_body` answers from (q block, heads, KV heads, page size) and
+    nothing else: no flag, no environment, no model's name — and a wrapper
+    has no keyword that names a form."""
+    assert pallas_attention.extend_body(*shape) == EXTEND_BODIES[shape]
+    assert list(inspect.signature(pallas_attention.extend_body).parameters
+                ) == ["blk_q", "heads", "num_kv", "page_size"]
+    for wrapper in (pallas_attention.paged_flash_extend,
+                    pallas_attention.paged_flash_extend_quant):
+        assert "body" not in inspect.signature(wrapper).parameters
+    source = inspect.getsource(pallas_attention.extend_body)
+    assert "environ" not in source and "getenv" not in source
+
+
 # --- prefill, extend and verify: the pool is the layer scan's carry ----------
 
 # one dense layer and two expert layers: two LayerGroups, two scans
@@ -265,7 +355,13 @@ def test_prefill_and_extend_carry_the_pool_through_the_layer_scan(
               for eqn in kernels]
     assert not [s for ops in handed for s in ops
                 if s in a_layer - may_slice], handed
-    readers = [ops for ops in handed if values & set(ops)]
+    # a bf16 pool may reach the kernel under the view [L, P, PS*K, D], a
+    # page as the rows it is stored as (`extend_body` "page": this chunk's
+    # scores are small), which is the whole pool still
+    as_rows = {(*s[:2], s[2] * s[3], s[4]): s for s in values
+               if len(s) == 5}
+    whole = [[as_rows.get(s, s) for s in ops] for ops in handed]
+    readers = [ops for ops in whole if values & set(ops)]
     reads_the_pool = route == "pallas" and entry != "prefill_into_pages"
     assert len(readers) == (1 if reads_the_pool else 0)  # the scan's body
     for ops in readers:
@@ -458,6 +554,37 @@ def test_the_decode_kernels_lower_through_mosaic_at_the_cells_heads(
         r"^\s*(?:ROOT )?%\S+ = (?:bf16|s8)\[2,400,\S+ (copy|fusion)\(", hlo,
         re.M)
     assert quantized or not copied, "the pool is re-laid-out for the kernel"
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("queries", [4, 8, 32, 64, 128])
+@pytest.mark.parametrize("kv_heads,groups", [(4, 8), (8, 4), (2, 16)],
+                         ids=["sdar-K4xG8", "mistral-K8xG4",
+                              "nemotron-K2xG16"])
+def test_the_extend_kernels_lower_through_mosaic_at_the_cells_heads(
+        kv_heads, groups, queries, quantized, one_chip):
+    """Either form of the extend kernels' grid step, compiled by the chip's
+    own compiler at the heads of the cells that run it and at q blocks on
+    both sides of the threshold: the page's [PS*K, D] view, the mask over
+    rows of (position, head) and the products are forms Mosaic takes, the
+    scores fit its VMEM, and the bf16 pool reaches the kernel as it lies —
+    the view of a page as its rows is a bitcast, not a copy of the pool in
+    front of the call (PR 45's first fault was of that kind, and visible
+    here before any chip run). An int8 pool of few KV heads is re-laid-out
+    before either form at either commit (PERF.md §7), so the int8 cases
+    only have to compile."""
+    pages = 400 if kv_heads != EXTEND_KV else EXTEND_PAGES
+    kernel, operands = _extend_operands(queries, quantized, kv_heads, groups,
+                                        CHIP_ROWS, 8, pages)
+    with jax.default_matmul_precision("default"):
+        hlo = jax.jit(functools.partial(
+            kernel, interpret=False, block=4 if kv_heads == EXTEND_KV else 1)
+        ).lower(*_on_chip(one_chip, operands)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    moved = re.findall(
+        rf"^\s*(?:ROOT )?%\S+ = (?:bf16|s8)\[2,{pages},\S+ "
+        r"(copy|fusion|transpose)\(", hlo, re.M)
+    assert quantized or not moved, "the pool is re-laid-out for the kernel"
 
 
 # --- prefill and extend, as the chip's compiler leaves them ------------------
@@ -671,13 +798,23 @@ def test_compiled_block_pass_runs_its_kernels_and_copies_no_pool(
     assert hlo.count('custom_call_target="tpu_custom_call"') == 4
     results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
     assert results
-    pool = r"bf16\[(2,|1,)?544,128,4,128\]"
+    # the pool as it is stored or as a page's [PS*K, D] rows, the view the
+    # extend kernel takes at a block pass's q block
+    pool = r"bf16\[(2,|1,)?544,(128,4|512),128\]"
     experts = r"bf16\[(2,|1,)?128,(2048,768|768,2048)\]"
     moved = ("copy", "copy-start", "copy-done", "dynamic-slice",
-             "dynamic-update-slice", "concatenate")
+             "dynamic-update-slice", "concatenate", "transpose")
     bad = [(shape, op) for shape, op in results if op in moved
            and (re.match(pool, shape) or re.match(experts, shape))]
     assert not bad, bad
+    # the extend kernel takes the page as it is stored at 4 and at 8 queries:
+    # its call's result is the chunk as it lies, [rows, T*H, D]
+    assert pallas_attention.extend_body(blocks * b, cfg.num_heads,
+                                        cfg.num_kv_heads,
+                                        CHIP_PAGE_SIZE) == "page"
+    assert re.search(
+        rf"= bf16\[{CHIP_ROWS},{blocks * b * cfg.num_heads},128\]\S* "
+        r"custom-call\(", hlo), "the extend call's result"
     logits = CHIP_ROWS * b * cfg.vocab_size * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * logits
 
