@@ -56,6 +56,7 @@ from llmlb_tpu.models.llama import (
     _prefill_extend_paged_impl,
     _prefill_impl,
     _proj,
+    _proj_heads,
     shard_rules_for,
 )
 from llmlb_tpu.ops import moe
@@ -355,11 +356,11 @@ def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
 
     h = rms_norm(x, lp["ln_attn"], cfg.rms_eps)
     if cfg.q_lora_rank:
-        q = _proj(lp, "wq_b", latent_norm(
+        q = _proj_heads(lp, "wq_b", latent_norm(
             _proj(lp, "wq_a", h, lora_idx), "ln_q", cfg.q_lora_scale),
             lora_idx)
     else:
-        q = _proj(lp, "wq", h, lora_idx)
+        q = _proj_heads(lp, "wq", h, lora_idx)
     q = q.reshape(b, t, heads, dn + dr)
     kv = _proj(lp, "wkv_a", h, lora_idx)  # [B, T, C + Dr]
     c = latent_norm(kv[..., :c_dim], "ln_kv", cfg.kv_lora_scale)

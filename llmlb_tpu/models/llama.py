@@ -397,12 +397,32 @@ def _proj(lp: Params, name: str, x: jnp.ndarray,
     return y
 
 
+def _proj_heads(lp: Params, name: str, x: jnp.ndarray,
+                lora_idx: jnp.ndarray | None = None) -> jnp.ndarray:
+    """`_proj` for a projection whose caller splits the result into heads
+    (`wq`, `wk`, `wv`, a latent's `wq_b`): the same values, behind a barrier
+    that keeps the product a plain `[S, E] x [E, H*D]`.
+
+    Without it the chip's compiler folds the reshape to `[S, H, D]` into the
+    product, the product becomes a convolution over a weight `[H, D, E]`
+    whose minor dimension is the contracted one, and the layer's slice of
+    the `[L, E, H*D]` stack is read, transposed and written for it in front
+    of every product, every decode step: twice the weight's bytes moved, and
+    a third pass to read them (Mistral-7B's widths: 2.0 ms of a 13.2 ms
+    step for what takes 1.1; PERF.md section 6, PR 47). It happens at heads
+    of 128, 192 and 64 alike. Every other projection has no split behind it
+    and is one fusion of norm, slice in place and product; behind the
+    barrier these are too. `tests/test_decode_program_structure.py` holds
+    the compiled programs to it."""
+    return lax.optimization_barrier(_proj(lp, name, x, lora_idx))
+
+
 def _qkv(cfg: LlamaConfig, lp: Params, x: jnp.ndarray, lora_idx=None):
     b, t, _ = x.shape
     d = cfg.head_dim_
-    q = _proj(lp, "wq", x, lora_idx)
-    k = _proj(lp, "wk", x, lora_idx)
-    v = _proj(lp, "wv", x, lora_idx)
+    q = _proj_heads(lp, "wq", x, lora_idx)
+    k = _proj_heads(lp, "wk", x, lora_idx)
+    v = _proj_heads(lp, "wv", x, lora_idx)
     if cfg.attention_bias:
         q = q + lp["bq"]
         k = k + lp["bk"]

@@ -88,6 +88,7 @@ from llmlb_tpu.models.llama import (
     _default_mlp_fn,
     _prefill_extend_paged_impl,
     _prefill_impl,
+    _proj_heads,
     shard_rules_for,
 )
 from llmlb_tpu.ops import moe
@@ -523,18 +524,9 @@ def _qkv(cfg: MimoV2Config, lp: Params, x, positions, kind: int):
     kh = cfg.kv_heads_of(kind)
     h = rms_norm(x, lp["ln_attn"], cfg.rms_eps)
 
-    def proj(name):
-        # The barrier keeps the product a plain [S, E] x [E, H*D]. Without
-        # it the chip's compiler folds the split into heads of 192 (no
-        # multiple of 128 lanes) into the product and lays the WEIGHTS out
-        # for it: every layer's slice of the three stacks copied every
-        # step, 2 ms of a 10 ms step (my chip run, PR 45), or all three
-        # stacks transposed in front of every burst.
-        return lax.optimization_barrier(h @ lp[name])
-
-    q = proj("wq").reshape(b, t, cfg.num_heads, cfg.head_dim_)
-    k = proj("wk").reshape(b, t, kh, cfg.head_dim_)
-    v = proj("wv").reshape(b, t, kh, cfg.v_head_dim)
+    q = _proj_heads(lp, "wq", h).reshape(b, t, cfg.num_heads, cfg.head_dim_)
+    k = _proj_heads(lp, "wk", h).reshape(b, t, kh, cfg.head_dim_)
+    v = _proj_heads(lp, "wv", h).reshape(b, t, kh, cfg.v_head_dim)
     if cfg.value_scale != 1.0:
         v = (v.astype(F32) * cfg.value_scale).astype(v.dtype)
     inv_freq = rope_frequencies(

@@ -17,9 +17,33 @@ jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_default_matmul_precision", "highest")
 
 import asyncio  # noqa: E402
+import gc  # noqa: E402
 import inspect  # noqa: E402
 
 import pytest  # noqa: E402
+
+# Half of `vm.max_map_count`'s default (65,530): see `_room_for_compiled_programs`.
+MEMORY_MAPS_BUDGET = 32_000
+
+
+@pytest.fixture(autouse=True)
+def _room_for_compiled_programs():
+    """A worker keeps every program its tests compiled, and a loaded CPU
+    executable is a few memory maps. Near the kernel's limit
+    (`vm.max_map_count`) the next compile's `mmap` fails and the worker dies
+    of a segmentation fault inside XLA: at PR 47 an xdist worker of the
+    tier-1 run held 63,907 maps twenty seconds before
+    `tests/engine/test_window_family.py` fell in it, three runs of three,
+    and the file passes alone. Past half the limit the compiled programs are
+    dropped; a test that needs one compiles it again."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            maps = sum(1 for _ in f)
+    except OSError:  # no /proc, no such count to watch
+        maps = 0
+    if maps > MEMORY_MAPS_BUDGET:
+        jax.clear_caches()
+        gc.collect()
 
 
 def pytest_pyfunc_call(pyfuncitem):

@@ -22,8 +22,16 @@ import jax.numpy as jnp
 import pytest
 
 from llmlb_tpu.engine.presets import get_preset
-from llmlb_tpu.models import deepseek_v3, llama, longcat_flash, mixtral, sdar_moe
-from llmlb_tpu.ops import pallas_attention
+from llmlb_tpu.models import (
+    deepseek_v3,
+    llama,
+    longcat_flash,
+    mimo_v2,
+    mixtral,
+    nemotron_h,
+    sdar_moe,
+)
+from llmlb_tpu.ops import pallas_attention, pallas_moe, ssm
 
 LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM = 3, 7, 8, 2, 16
 ROWS, PAGES_PER_ROW = 2, 3
@@ -454,6 +462,55 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_burst(one_chip, monkeypatch, family, cfg, *, pages, rows,
+                    window, kernels, pool=None):
+    """Two decode steps of `family` under a scan, as the engine's burst
+    program runs them, compiled for the described v5e at `pages` of the
+    cell's page size, `rows` rows and a context `window`. `kernels`: the
+    jitted functions under the family's `decode_step_paged`, traced before
+    with the interpreter or the XLA path. `pool`: keywords of the family's
+    `init_kv_pages` (`quantized`; `num_slots` for a state a slot)."""
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    # the backend in this process is the CPU; the program under test is the
+    # chip's, so the kernels lower through Mosaic
+    for module in (pallas_attention, pallas_moe, ssm):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+    jitted = (family.decode_step_paged, *kernels)
+    for fn in jitted:
+        fn._clear_cache()
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda key: family.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache_k, cache_v = on_chip(jax.eval_shape(
+        lambda: family.init_kv_pages(cfg, pages, CHIP_PAGE_SIZE,
+                                     **(pool or {}))))
+    ints = on_chip(jax.ShapeDtypeStruct((rows,), jnp.int32))
+    live = on_chip(jax.ShapeDtypeStruct((rows,), jnp.bool_))
+    tables = on_chip(jax.ShapeDtypeStruct((rows, CHIP_TABLE), jnp.int32))
+
+    def burst(params, last, lens, cache_k, cache_v, tables, live):
+        def body(carry, _):
+            last, lens, ck, cv = carry
+            logits, ck, cv, *counters = family.decode_step_paged(
+                params, cfg, last, lens, ck, cv, tables, window=window,
+                live=live)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    ck, cv), counters
+
+        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
+                            length=2)
+
+    try:
+        # conftest.py asks for float32 matmuls; the chip's program has bf16
+        # operands, and Mosaic refuses a float32 contraction over them
+        with jax.default_matmul_precision("default"):
+            return jax.jit(burst, donate_argnums=(3, 4)).lower(
+                params, ints, ints, cache_k, cache_v, tables, live).compile()
+    finally:
+        for fn in jitted:
+            fn._clear_cache()
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
                                                           monkeypatch):
@@ -462,47 +519,11 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
     compiler materializes no per-layer piece of the value pool and copies no
     value pool whole. (At the parent of PR 25 this program held a 105 MB
     `bf16[400,128,8,128]` fusion per layer for K and for V.)"""
-    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
-    # the backend in this process is the CPU; the program under test is the
-    # chip's, so the kernels lower through Mosaic
-    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
-    jitted = (llama.decode_step_paged, pallas_attention.paged_flash_decode,
-              pallas_attention.paged_flash_decode_quant)
-    for fn in jitted:  # traced before with the interpreter or the XLA path
-        fn._clear_cache()
-
-    on_chip = functools.partial(_on_chip, one_chip)
-
-    params = on_chip(jax.eval_shape(
-        lambda key: llama.init_params(CHIP_CFG, key), jax.random.PRNGKey(0)))
-    cache_k, cache_v = on_chip(jax.eval_shape(
-        lambda: llama.init_kv_pages(CHIP_CFG, CHIP_PAGES, CHIP_PAGE_SIZE,
-                                    quantized=quantized)))
-    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
-    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
-
-    def burst(params, last, lens, cache_k, cache_v, tables):
-        def body(carry, _):
-            last, lens, ck, cv = carry
-            logits, ck, cv = llama.decode_step_paged(
-                params, CHIP_CFG, last, lens, ck, cv, tables, window=512)
-            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
-                    ck, cv), None
-
-        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
-                            length=2)[0]
-
-    try:
-        # conftest.py asks for float32 matmuls; the chip's program has bf16
-        # operands, and Mosaic refuses a float32 contraction over them
-        with jax.default_matmul_precision("default"):
-            hlo = jax.jit(burst, donate_argnums=(3, 4)).lower(
-                params, rows, rows, cache_k, cache_v, tables
-            ).compile().as_text()
-    finally:
-        for fn in jitted:
-            fn._clear_cache()
-
+    hlo = _compiled_burst(
+        one_chip, monkeypatch, llama, CHIP_CFG, pages=CHIP_PAGES,
+        rows=CHIP_ROWS, window=512, pool={"quantized": quantized},
+        kernels=(pallas_attention.paged_flash_decode,
+                 pallas_attention.paged_flash_decode_quant)).as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2  # a layer
     # the pool as it is stored or as a page's [PS*K, D] rows
     layer_values = r"(bf16|s8)\[400,(128,8|1024),128\]"
@@ -695,49 +716,11 @@ def test_compiled_latent_moe_burst_copies_no_pool_and_no_experts(one_chip,
     kernel call; with `w[layer]` handed to the grouped product it copied
     three `bf16[128,2048,768]` a layer, more than half a decode step:
     PERF.md section 6, PR 31.)"""
-    from llmlb_tpu.ops import pallas_moe
-
-    cfg = LATENT_CFG
-    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
-    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
-    monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
-    jitted = (deepseek_v3.decode_step_paged,
-              pallas_attention.paged_latent_decode,
-              pallas_moe.grouped_expert_matmul)
-    for fn in jitted:
-        fn._clear_cache()
-
-    on_chip = functools.partial(_on_chip, one_chip)
-
-    params = on_chip(jax.eval_shape(
-        lambda key: deepseek_v3.init_params(cfg, key), jax.random.PRNGKey(0)))
-    cache_k, cache_v = on_chip(jax.eval_shape(
-        lambda: deepseek_v3.init_kv_pages(cfg, LATENT_PAGES, CHIP_PAGE_SIZE)))
-    rows = on_chip(jax.ShapeDtypeStruct((LATENT_ROWS,), jnp.int32))
-    live = on_chip(jax.ShapeDtypeStruct((LATENT_ROWS,), jnp.bool_))
-    tables = on_chip(jax.ShapeDtypeStruct((LATENT_ROWS, CHIP_TABLE), jnp.int32))
-
-    def burst(params, last, lens, cache_k, cache_v, tables, live):
-        def body(carry, _):
-            last, lens, ck, cv = carry
-            logits, ck, cv, counters = deepseek_v3.decode_step_paged(
-                params, cfg, last, lens, ck, cv, tables, window=2048,
-                live=live)
-            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
-                    ck, cv), counters
-
-        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
-                            length=2)
-
-    try:
-        with jax.default_matmul_precision("default"):
-            hlo = jax.jit(burst, donate_argnums=(3, 4)).lower(
-                params, rows, rows, cache_k, cache_v, tables, live
-            ).compile().as_text()
-    finally:
-        for fn in jitted:
-            fn._clear_cache()
-
+    hlo = _compiled_burst(
+        one_chip, monkeypatch, deepseek_v3, LATENT_CFG, pages=LATENT_PAGES,
+        rows=LATENT_ROWS, window=2048,
+        kernels=(pallas_attention.paged_latent_decode,
+                 pallas_moe.grouped_expert_matmul)).as_text()
     # two attention kernels (a layer each) and the expert layer's three products
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2 + 3
     results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
@@ -833,50 +816,16 @@ def test_compiled_hybrid_burst_updates_the_state_in_place_and_copies_no_experts(
     of the three whole. (With the up-projection stored `[K, 1856]` the chip
     laid it out K-minor and copied the whole stack, 3.8 GB, into the kernel's
     layout on every call: PERF.md section 6, PR 38.)"""
-    from llmlb_tpu.models import nemotron_h
-    from llmlb_tpu.ops import pallas_moe, ssm
-
     cfg = nemotron_h.NemotronHConfig(
         vocab_size=131072, hidden_size=2688, intermediate_size=1856,
         num_layers=6, num_heads=32, num_kv_heads=2, head_dim=128,
         rms_eps=1e-5, max_position_embeddings=4096, pattern="M*EM*E",
         num_experts=64, router_experts=128, tie_word_embeddings=False)
-    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
-    for module in (pallas_attention, pallas_moe, ssm):
-        monkeypatch.setattr(module, "_interpret_default", lambda: False)
-    jitted = (nemotron_h.decode_step_paged, pallas_attention.paged_flash_decode,
-              pallas_moe.grouped_expert_matmul, ssm.ssm_decode_step)
-    for fn in jitted:
-        fn._clear_cache()
-    on_chip = functools.partial(_on_chip, one_chip)
-    params = on_chip(jax.eval_shape(
-        lambda key: nemotron_h.init_params(cfg, key), jax.random.PRNGKey(0)))
-    cache_k, cache_v = on_chip(jax.eval_shape(
-        lambda: nemotron_h.init_kv_pages(cfg, 544, CHIP_PAGE_SIZE,
-                                         num_slots=CHIP_ROWS)))
-    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
-    live = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.bool_))
-    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
-
-    def burst(params, last, lens, cache_k, cache_v, tables, live):
-        def body(carry, _):
-            last, lens, ck, cv = carry
-            logits, ck, cv, counters = nemotron_h.decode_step_paged(
-                params, cfg, last, lens, ck, cv, tables, window=2048,
-                live=live)
-            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
-                    ck, cv), counters
-
-        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
-                            length=2)
-
-    try:
-        with jax.default_matmul_precision("default"):
-            compiled = jax.jit(burst, donate_argnums=(3, 4)).lower(
-                params, rows, rows, cache_k, cache_v, tables, live).compile()
-    finally:
-        for fn in jitted:
-            fn._clear_cache()
+    compiled = _compiled_burst(
+        one_chip, monkeypatch, nemotron_h, cfg, pages=544, rows=CHIP_ROWS,
+        window=2048, pool={"num_slots": CHIP_ROWS},
+        kernels=(pallas_attention.paged_flash_decode,
+                 pallas_moe.grouped_expert_matmul, ssm.ssm_decode_step))
     hlo = compiled.as_text()
     # two layers of each kind: a state kernel, an attention kernel, two products
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * (1 + 1 + 2)
@@ -898,72 +847,45 @@ def test_compiled_hybrid_burst_updates_the_state_in_place_and_copies_no_experts(
 
 # --- a shortcut-connected mixture's decode burst -------------------------------
 
+SHORTCUT_CFG = longcat_flash.LongcatFlashConfig(  # longcat-flash-omni-l4's
+    # widths, two double layers deep: 16 of 512 experts held behind a router
+    # of 768 outputs, queries through a latent of 1,536
+    vocab_size=16384, hidden_size=6144, intermediate_size=12288,
+    num_layers=2, num_heads=64, num_kv_heads=64, head_dim=64,
+    rope_theta=1e7, rms_eps=1e-5, max_position_embeddings=131072,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, q_lora_rank=1536, q_lora_scale=2.0,
+    kv_lora_scale=12 ** 0.5, num_experts=16, router_experts=512,
+    zero_experts=256, experts_per_token=12, moe_intermediate_size=2048,
+    routed_scaling_factor=6.0)
+
+
 def test_compiled_shortcut_burst_runs_its_kernels_and_copies_no_experts(
         one_chip, monkeypatch):
     """Two decode steps of double layers with a shortcut-connected mixture
-    (longcat-flash-omni-l4's widths, two layers = four attention sub-layers,
-    16 of 512 experts held behind a router of 768 outputs, the benchmark
-    cell's 544 pages and 32 rows) under a scan, compiled for a v5e: per step
-    one latent attention kernel a SUB-layer at 64 heads and three grouped
-    products a layer; the compiler materializes no layer of either pool and
-    no layer's experts, and copies no pool whole."""
-    from llmlb_tpu.ops import pallas_moe
-
-    cfg = longcat_flash.LongcatFlashConfig(
-        vocab_size=16384, hidden_size=6144, intermediate_size=12288,
-        num_layers=2, num_heads=64, num_kv_heads=64, head_dim=64,
-        rope_theta=1e7, rms_eps=1e-5, max_position_embeddings=131072,
-        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, q_lora_rank=1536, q_lora_scale=2.0,
-        kv_lora_scale=12 ** 0.5, num_experts=16, router_experts=512,
-        zero_experts=256, experts_per_token=12, moe_intermediate_size=2048,
-        routed_scaling_factor=6.0)
-    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
-    monkeypatch.setattr(pallas_attention, "_interpret_default", lambda: False)
-    monkeypatch.setattr(pallas_moe, "_interpret_default", lambda: False)
-    jitted = (longcat_flash.decode_step_paged,
-              pallas_attention.paged_latent_decode,
-              pallas_moe.grouped_expert_matmul)
-    for fn in jitted:
-        fn._clear_cache()
-    on_chip = functools.partial(_on_chip, one_chip)
-    params = on_chip(jax.eval_shape(
-        lambda key: longcat_flash.init_params(cfg, key),
-        jax.random.PRNGKey(0)))
-    cache_k, cache_v = on_chip(jax.eval_shape(
-        lambda: longcat_flash.init_kv_pages(cfg, 544, CHIP_PAGE_SIZE)))
-    rows = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.int32))
-    live = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS,), jnp.bool_))
-    tables = on_chip(jax.ShapeDtypeStruct((CHIP_ROWS, CHIP_TABLE), jnp.int32))
-
-    def burst(params, last, lens, cache_k, cache_v, tables, live):
-        def body(carry, _):
-            last, lens, ck, cv = carry
-            logits, ck, cv, counters = longcat_flash.decode_step_paged(
-                params, cfg, last, lens, ck, cv, tables, window=1024,
-                live=live)
-            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
-                    ck, cv), counters
-
-        return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
-                            length=2)
-
-    try:
-        with jax.default_matmul_precision("default"):
-            hlo = jax.jit(burst, donate_argnums=(3, 4)).lower(
-                params, rows, rows, cache_k, cache_v, tables, live
-            ).compile().as_text()
-    finally:
-        for fn in jitted:
-            fn._clear_cache()
-    # four attention sub-layers' kernels and two mixtures' three products
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 4 + 2 * 3
+    (longcat-flash-omni-l4's widths and its four layers = eight attention
+    sub-layers, the benchmark cell's 544 pages and 32 rows) under a scan,
+    compiled for a v5e: per step one latent attention kernel a SUB-layer at
+    64 heads and three grouped products a layer; the compiler materializes
+    no layer of either pool and no layer's experts, and copies no pool
+    whole. At the cell's depth, because what the compiler stages through
+    its fast memory (`S(1)`) follows what fits there: since no slice of
+    `wq_b` is laid out again in it (PR 47) a rope pool of two layers, 71 MB,
+    is copied in and out around its first write every step; the cell's, 143
+    MB, stays where it is."""
+    cfg = dataclasses.replace(SHORTCUT_CFG, num_layers=4)
+    hlo = _compiled_burst(
+        one_chip, monkeypatch, longcat_flash, cfg, pages=544, rows=CHIP_ROWS,
+        window=1024, kernels=(pallas_attention.paged_latent_decode,
+                              pallas_moe.grouped_expert_matmul)).as_text()
+    # eight attention sub-layers' kernels and four mixtures' three products
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 8 + 4 * 3
     results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
     assert results
     a_layer = r"bf16\[544,128,(512|128)\]"  # of either pool
     a_layers_experts = r"bf16\[16,(6144,2048|2048,6144)\]"
-    whole = (r"bf16\[4,544,128,(512|128)\]",
-             r"bf16\[2,16,(6144,2048|2048,6144)\]")
+    whole = (r"bf16\[8,544,128,(512|128)\]",
+             r"bf16\[4,16,(6144,2048|2048,6144)\]")
     moved = ("copy", "copy-start", "copy-done", "transpose")
     bad = [(shape, op) for shape, op in results
            if re.match(a_layer, shape) or re.match(a_layers_experts, shape)
@@ -972,6 +894,103 @@ def test_compiled_shortcut_burst_runs_its_kernels_and_copies_no_experts(
     # the branch is named in the trace, and so is each sub-layer
     for scope in ("sublayer0", "sublayer1", "deferred_branch"):
         assert scope in hlo, scope
+
+
+# --- a projection that splits into heads, as the chip's compiler leaves it ----
+
+WINDOW_CFG = mimo_v2.MimoV2Config(  # mimo-v2-5-l7's widths: a global layer over
+    # a dense feed-forward, a window layer over a mixture of 16 held experts
+    vocab_size=19072, hidden_size=4096, intermediate_size=16384,
+    num_layers=2, num_heads=64, num_kv_heads=4, head_dim=192, rope_theta=1e7,
+    rms_eps=1e-5, max_position_embeddings=1048576,
+    partial_rotary_factor=0.334, value_scale=0.707, num_experts=16,
+    tie_word_embeddings=False)
+HEAD_SPLIT = {  # `_compiled_burst`'s keywords, a configuration's widths a case
+    "mistral-wq-wk-wv": dict(
+        family=llama, cfg=CHIP_CFG, pages=CHIP_PAGES, rows=CHIP_ROWS,
+        window=512, kernels=(pallas_attention.paged_flash_decode,)),
+    "kanana-wq": dict(
+        family=deepseek_v3, cfg=LATENT_CFG, pages=LATENT_PAGES,
+        rows=LATENT_ROWS, window=2048,
+        kernels=(pallas_attention.paged_latent_decode,
+                 pallas_moe.grouped_expert_matmul)),
+    "longcat-wq_b": dict(
+        family=longcat_flash, cfg=SHORTCUT_CFG, pages=544, rows=CHIP_ROWS,
+        window=1024, kernels=(pallas_attention.paged_latent_decode,
+                              pallas_moe.grouped_expert_matmul)),
+    "mimo-wq-wk-wv": dict(
+        family=mimo_v2, cfg=WINDOW_CFG, pages=544, rows=CHIP_ROWS,
+        window=2048, pool={"num_slots": CHIP_ROWS},
+        kernels=(pallas_attention.paged_flat_decode,
+                 pallas_attention.paged_flash_decode,  # a ring a page
+                 pallas_moe.grouped_expert_matmul)),
+}
+HEAD_SPLIT_WEIGHT = re.compile(r"(?:^|_)(wq|wk|wv|wq_b)$")
+
+
+def _relaid_head_split_weights(hlo, cfg, params):
+    """Every result of the compiled program `hlo` that is a layer (or more)
+    of a head-split weight of `params` laid out again: shaped `[l, E, H*D]`
+    like a piece of the stack but with another minor dimension than the
+    stack's own, or shaped `[H, D, E]`, the operand of the convolution the
+    split folds the product into."""
+    stacks = {name: w.shape for name, w in params.items()
+              if HEAD_SPLIT_WEIGHT.search(name) and len(w.shape) == 3}
+    assert stacks
+    heads = {cfg.num_heads, cfg.num_kv_heads,
+             getattr(cfg, "window_kv_heads", cfg.num_kv_heads)}
+    found = []
+    for shape, layout, op in re.findall(
+            r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\{([\d,]*)\S* ([\w\-]+)\(",
+            hlo, re.M):
+        dims = [int(d) for d in shape.split(",")]
+        for name, (layers, e, n) in stacks.items():
+            if ((len(dims) == 3 and dims[0] <= layers and dims[1:] == [e, n]
+                 and layout != "2,1,0")
+                    or (len(dims) in (3, 4) and dims[-1] == e
+                        and dims[-3] in heads and dims[-3] * dims[-2] == n
+                        and op != "bitcast")):
+                found.append((name, shape, layout, op))
+                break
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(HEAD_SPLIT))
+def test_compiled_decode_burst_transposes_no_head_split_weight(
+        case, one_chip, monkeypatch):
+    """Two decode steps under a scan, compiled for a v5e at the widths of
+    the benchmark's configurations, two layers deep: no layer's slice of
+    `wq`, `wk`, `wv` (Mistral-7B; MiMo-V2.5 at heads of 192, both kinds of
+    layer) or of a latent attention's `wq` (kanana-2-30b-a3b) or `wq_b`
+    behind a query latent (longcat-flash-omni, 64 heads) is transposed in
+    front of its product. Before `llama._proj_heads` the compiler folded the
+    split into heads into the product and every step held a
+    `bf16[1,4096,4096]{1,2,0}` fusion a layer, the stack's minor dimension
+    moved to the contracted one, and `bf16[8,128,4096]` for `wk` and `wv`:
+    2.0 ms of Mistral-7B's 13.2 ms step (PERF.md section 6, PR 47)."""
+    burst = HEAD_SPLIT[case]
+    hlo = _compiled_burst(one_chip, monkeypatch, **burst).as_text()
+    params = jax.eval_shape(
+        lambda key: burst["family"].init_params(burst["cfg"], key),
+        jax.random.PRNGKey(0))
+    assert not _relaid_head_split_weights(hlo, burst["cfg"], params)
+
+
+def test_a_head_split_projection_equals_the_plain_one_bit_for_bit():
+    """`llama._proj_heads` is `_proj` behind a barrier: the identity on
+    values, bf16 and float32, with and without an int8 weight's scale."""
+    key = jax.random.PRNGKey(7)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jax.random.normal(key, (2, 3, 64), dtype)
+        w = jax.random.normal(jax.random.fold_in(key, 1), (64, 4 * 16), dtype)
+        for lp in ({"wq": w},
+                   {"wq": (w * 8).astype(jnp.int8),
+                    "wq_scale": jnp.full((4 * 16,), 0.125, jnp.float32)}):
+            plain = jax.jit(lambda lp, x: llama._proj(lp, "wq", x))(lp, x)
+            split = jax.jit(lambda lp, x: llama._proj_heads(lp, "wq", x)
+                            .reshape(2, 3, 4, 16))(lp, x)
+            assert split.dtype == plain.dtype
+            assert (split.reshape(plain.shape) == plain).all()
 
 
 def test_compiled_sampler_sorts_no_vocabulary(one_chip):
