@@ -960,6 +960,42 @@ def kernel_leg() -> int:
 
         attempt("grouped_expert_matmul", f"{rows_}x{k_}->{o_}", gmm)
 
+    # the delta rule's step kernel at Olmo-Hybrid-7B's heads (30 heads, keys
+    # of 96, values of 192: a float32 state [96, 5760] a slot), layer 1 of a
+    # pool stacked over two, a row in three not live: the output, and the
+    # whole pool (the other layer and the rows not live must not move)
+    from llmlb_tpu.ops import delta_rule
+
+    for slots in (8, 32):
+        def rule_step():
+            def f32(*shape):
+                return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+            q, k = f32(slots, 30, 96) * 96 ** -0.5, f32(slots, 30, 96)
+            k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+            v = f32(slots, 30, 192)
+            alpha = jnp.asarray(rng.uniform(0.5, 1.0, (slots, 30)), jnp.float32)
+            beta = jnp.asarray(rng.uniform(0.0, 2.0, (slots, 30)), jnp.float32)
+            pool_ = f32(2, slots, 96, 30 * 192)
+            live = jnp.arange(slots) % 3 != 1
+            want_o, want = delta_rule.delta_rule_step(  # the jax.numpy route
+                pool_, 1, q, k, v, alpha, beta, live=live)
+            got, o = delta_rule.delta_rule_decode_step(
+                pool_ + 0, 1, q, jnp.where(live[:, None, None], k, 0.0), v,
+                jnp.where(live[:, None], alpha, 1.0),
+                jnp.where(live[:, None], beta, 0.0), interpret=False)
+            check("delta_rule_step", f"slots={slots},out", o[live][None],
+                  want_o[live][None])
+            check("delta_rule_step", f"slots={slots},pool",
+                  got.reshape(1, 2 * slots, -1), want.reshape(1, 2 * slots, -1))
+            unmoved = np.array_equal(np.asarray(got[0]), np.asarray(pool_[0])) \
+                and np.array_equal(np.asarray(got[1][~live]),
+                                   np.asarray(pool_[1][~live]))
+            check("delta_rule_step", f"slots={slots},rows not live unmoved",
+                  jnp.asarray([[float(unmoved)]]), jnp.asarray([[1.0]]))
+
+        attempt("delta_rule_step", f"slots={slots}", rule_step)
+
     n_adapters, rank = 9, 16
     for b, t in ((8, 1), (32, 1), (2, 512), (8, 5)):
         for n_in, n_out in ((2048, 2048), (2048, 256), (2048, 5632),

@@ -17,6 +17,7 @@ from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
 from llmlb_tpu.models.mimo_v2 import MimoV2Config
 from llmlb_tpu.models.mixtral import MixtralConfig
 from llmlb_tpu.models.nemotron_h import NemotronHConfig
+from llmlb_tpu.models.olmo_hybrid import OlmoHybridConfig
 from llmlb_tpu.models.sdar_moe import SdarMoeConfig
 from llmlb_tpu.ops.rope import RopeScaling
 
@@ -97,6 +98,20 @@ PRESETS: dict[str, LlamaConfig] = {
         partial_rotary_factor=0.334, value_scale=0.707, router_experts=8,
         num_experts=4, first_expert=4, experts_per_token=2,
         moe_intermediate_size=32,
+    ),
+    # CI-sized linear-attention hybrid (models/olmo_hybrid.py,
+    # docs/linear-attention.md): the published period of three delta-rule
+    # layers to a full-attention one and a linear layer behind it, values
+    # twice as wide as keys, chunks of 16, no grouping; three heads, so
+    # that the page pool holds a dead fourth
+    "debug-olmo-hybrid-tiny": OlmoHybridConfig(
+        vocab_size=512, hidden_size=48, intermediate_size=96,
+        num_layers=5, num_heads=3, num_kv_heads=3, head_dim=16,
+        rms_eps=1e-6, dtype=jnp.float32, max_position_embeddings=512,
+        layer_types=("linear_attention",) * 3 + ("full_attention",
+                                                 "linear_attention"),
+        lin_heads=3, lin_key_dim=8, lin_value_dim=16, conv_kernel=4,
+        allow_neg_eigval=True, chunk_size=16,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

@@ -14,12 +14,12 @@ chosen class would ignore at the cost of wrong output.
 """
 
 from llmlb_tpu.models import (deepseek_v3, llama, longcat_flash, mimo_v2,
-                              mixtral, nemotron_h, sdar_moe)
+                              mixtral, nemotron_h, olmo_hybrid, sdar_moe)
 
 # Adding a family is its module and its line here. The order decides nothing
 # but the order /api/health and /metrics list the families' counters in.
 FAMILIES = (llama, mixtral, deepseek_v3, sdar_moe, longcat_flash, nemotron_h,
-            mimo_v2)
+            mimo_v2, olmo_hybrid)
 
 # Every counter some family computes: an engine of any family exports them
 # all, zero where its own computes none.
@@ -50,10 +50,17 @@ def config_from_hf(hf: dict, dtype=None):
                                hf.get("num_experts") or 0) > 1:
         module = mixtral
     family = module.FAMILY
-    for key in _STATED_KEYS:
+    # every `linear_*` key says something of a linear-attention layer,
+    # whether or not a family reads that one yet
+    stated = _STATED_KEYS + tuple(k for k in hf if k.startswith("linear_")
+                                  and k not in _STATED_KEYS)
+    for key in stated:
         value = hf.get(key)
-        if key in family.mechanism_keys or value in _ABSENT:
-            continue
+        if key in family.mechanism_keys or (value in _ABSENT
+                                            and value is not True):
+            continue  # (True == 1, and a flag stated true is stated)
+        if key == "layer_types" and set(value) == {"full_attention"}:
+            continue  # stated, and every layer the kind the class computes
         if (key == "moe_intermediate_size"
                 and value == hf.get("intermediate_size")):
             continue  # the width the class reads is the experts' own

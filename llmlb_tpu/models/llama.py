@@ -506,8 +506,10 @@ class LayerGroup(NamedTuple):
     Prefill and extend scan each group's parameters with the page pool as
     the carry (_scan_groups), decode unrolls them all.
 
-    A layer is `x + mix(norm(x))`, then `x + mlp_fn(norm(x))`, and either
-    half may be absent: `attends` says that the mix is the model's
+    A layer is `x + mix(norm(x))`, then `x + mlp_fn(norm(x))` — under
+    `post_norm` `x + norm(mlp_fn(x))`, the block that norms what a sub-layer
+    gives and not what it takes (models/olmo_hybrid.py, whose mixes do the
+    same themselves) — and either half may be absent: `attends` says that the mix is the model's
     `Attention` over the page pool; `mixer(lp, x, cache_k, cache_v, layer,
     rows: StateRows) -> (x + mix, cache_k, cache_v)` is another mix, with a
     state of its own in the pool (StatePool); `mlp_fn` None is a layer
@@ -544,6 +546,7 @@ class LayerGroup(NamedTuple):
     branch: Callable | None = None  # computed here, added by a later layer
     joins: bool = False  # adds the branch an earlier layer left
     scope: str = ""
+    post_norm: bool = False  # `ln_mlp` norms the feed-forward's OUTPUT
 
 
 class StateRows(NamedTuple):
@@ -586,13 +589,16 @@ def _group_params(params: Params, group: LayerGroup) -> tuple[Params, Params]:
 
 def _feed_forward(cfg, group: LayerGroup, lp: Params, x, deferred,
                   token_valid, lora_idx):
-    """x + the group's feed-forward of norm(x), the deferred branch as the
-    layer leaves it (LayerGroup: computed here, or joined here, or passed
-    on), and what the layer reported."""
+    """x + the group's feed-forward of norm(x) (`post_norm`: x + the norm
+    of its feed-forward of x), the deferred branch as the layer leaves it
+    (LayerGroup: computed here, or joined here, or passed on), and what the
+    layer reported."""
     if group.mlp_fn is None:
         return x, deferred, None
-    h = rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
+    h = x if group.post_norm else rms_norm(x, lp["ln_mlp"], cfg.rms_eps)
     out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
+    if group.post_norm:
+        out = rms_norm(out, lp["ln_mlp"], cfg.rms_eps)
     x = x + out
     if group.branch is not None:
         with jax.named_scope("deferred_branch"):

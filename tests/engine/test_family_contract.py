@@ -9,6 +9,7 @@ the next family is added against a test and not against a traceback.
 
 import dataclasses
 import inspect
+import re
 
 import pytest
 
@@ -29,7 +30,8 @@ PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "deepseek_v3": "debug-mla-tiny", "sdar_moe": "debug-sdar-tiny",
            "nemotron_h": "debug-nemotron-h-tiny",
            "longcat_flash": "debug-longcat-tiny",
-           "mimo_v2": "debug-mimo-tiny"}
+           "mimo_v2": "debug-mimo-tiny",
+           "olmo_hybrid": "debug-olmo-hybrid-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -172,6 +174,7 @@ OLD_MODEL_TYPES = {
     "mixtral": "mixtral", "deepseek_v3": "deepseek_v3",
     "sdar_moe": "sdar_moe", "nemotron_h": "nemotron_h",
     "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
+    "olmo_hybrid": "olmo_hybrid",
 }
 OLD_MECHANISM_KEYS = {
     "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
@@ -196,6 +199,15 @@ OLD_MECHANISM_KEYS = {
     "hybrid_layer_pattern": ("mimo_v2",),
     "moe_layer_freq": ("mimo_v2",),
     "swa_num_key_value_heads": ("mimo_v2",),
+    # kinds of layer and the linear-attention layers' sizes: computed by one
+    # family since PR 48; before it no class read them and none refused them
+    "layer_types": ("olmo_hybrid",),
+    "linear_num_key_heads": ("olmo_hybrid",),
+    "linear_num_value_heads": ("olmo_hybrid",),
+    "linear_key_head_dim": ("olmo_hybrid",),
+    "linear_value_head_dim": ("olmo_hybrid",),
+    "linear_conv_kernel_dim": ("olmo_hybrid",),
+    "linear_allow_neg_eigval": ("olmo_hybrid",),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
 }
@@ -230,7 +242,9 @@ def test_a_mechanism_key_is_accepted_or_refused_as_before(
     monkeypatch.setattr(module.FAMILY.config_class, "from_hf_config",
                         staticmethod(chosen))
     # 7: present by every rule; num_experts > 1 would re-type a dense config
-    hf = {"model_type": model_type, "intermediate_size": 128, key: 7}
+    # (a `layer_types` is a list: one entry of a kind nobody attends by)
+    stated = ["sliding_attention"] if key == "layer_types" else 7
+    hf = {"model_type": model_type, "intermediate_size": 128, key: stated}
     if family in OLD_MECHANISM_KEYS[key] or (
             family == "llama" and key in ("num_local_experts", "num_experts")):
         if family == "llama":  # re-typed as a mixture, which reads the key
@@ -239,6 +253,51 @@ def test_a_mechanism_key_is_accepted_or_refused_as_before(
         with pytest.raises(_Chosen):
             config_from_hf(hf)
     else:
-        with pytest.raises(ValueError, match=f"carries {key}=7, which "
-                           f"models/{family}.py does not compute"):
+        with pytest.raises(ValueError, match=re.escape(
+                f"carries {key}={stated!r}, which models/{family}.py does "
+                "not compute")):
             config_from_hf(hf)
+
+
+LINEAR_STATED = [
+    {"layer_types": ["full_attention", "linear_attention"]},
+    {"layer_types": ["sliding_attention", "full_attention"]},
+    {"linear_num_key_heads": 30},
+    {"linear_conv_kernel_dim": 4},
+    {"linear_allow_neg_eigval": True},
+    {"linear_use_gate": True},  # a `linear_*` key no family reads yet
+]
+
+
+@pytest.mark.parametrize("stated", LINEAR_STATED, ids=lambda d: next(iter(d)))
+@pytest.mark.parametrize("model_type", sorted(
+    set(OLD_MODEL_TYPES) - {"olmo_hybrid"}) + ["a_type_nobody_registered"])
+def test_a_linear_attention_config_is_served_as_no_other_model(
+        model_type, stated):
+    """Before PR 48 an unregistered `model_type` with `layer_types` and
+    `linear_*` keys was read as a Llama-shaped dense decoder and would have
+    been SERVED as one. Every other family and an unregistered type refuse
+    a kind of layer other than `full_attention` and any `linear_*` key, by
+    name, before a configuration class sees the file."""
+    (key, value), = stated.items()
+    family = OLD_MODEL_TYPES.get(model_type, "llama")
+    hf = {"model_type": model_type, "intermediate_size": 128, **stated}
+    with pytest.raises(ValueError, match=re.escape(
+            f"carries {key}={value!r}, which models/{family}.py does not "
+            "compute")):
+        config_from_hf(hf)
+
+
+@pytest.mark.parametrize("model_type", ["llama", "qwen2",
+                                        "a_type_nobody_registered"])
+def test_a_list_of_full_attention_alone_stays_accepted(monkeypatch,
+                                                       model_type):
+    def chosen(hf, **kwargs):
+        raise _Chosen
+
+    monkeypatch.setattr(llama.FAMILY.config_class, "from_hf_config",
+                        staticmethod(chosen))
+    with pytest.raises(_Chosen):
+        config_from_hf({"model_type": model_type, "intermediate_size": 128,
+                        "layer_types": ["full_attention"] * 4,
+                        "linear_num_key_heads": None})
