@@ -198,6 +198,13 @@ class EngineMetrics:
         self.decode_bursts_total = 0
         self.decode_bursts_dispatched_ahead_total = 0
         self.decode_bursts_not_ahead_total = dict.fromkeys(AHEAD_BLOCKERS, 0)
+        # Prefill dispatches of every kind (one-shot groups, chunks, the
+        # context-parallel pass), and the one-shot groups among them that
+        # left BEFORE the burst fetched in front of them was emitted, with
+        # their activation and the next burst behind them
+        # (scheduler._admit_ahead).
+        self.prefill_dispatches_total = 0
+        self.prefills_dispatched_ahead_total = 0
         # Σ over decode steps of the pages their live rows hold, and of the
         # pages of slots x window: live / window is the share of a
         # window-wide sweep that was live context
@@ -414,6 +421,14 @@ class EngineMetrics:
             else:
                 self.decode_bursts_not_ahead_total[blocked_by] += 1
 
+    def record_prefill_dispatch(self, ahead: bool = False) -> None:
+        """One prefill dispatch; `ahead`: it left before the burst in front
+        of it was emitted."""
+        with self._lock:
+            self.prefill_dispatches_total += 1
+            if ahead:
+                self.prefills_dispatched_ahead_total += 1
+
     def record_decode_kv_pages(self, kv_pages_live: int,
                                kv_pages_window: int) -> None:
         """One decode step's page counts (scheduler._kv_pages)."""
@@ -625,6 +640,9 @@ class EngineMetrics:
                     self.decode_bursts_dispatched_ahead_total,
                 "decode_bursts_not_ahead_total":
                     dict(self.decode_bursts_not_ahead_total),
+                "prefill_dispatches_total": self.prefill_dispatches_total,
+                "prefills_dispatched_ahead_total":
+                    self.prefills_dispatched_ahead_total,
                 "decode_kv_pages_live_total": self.decode_kv_pages_live_total,
                 "decode_kv_pages_window_total":
                     self.decode_kv_pages_window_total,
@@ -760,6 +778,12 @@ class EngineMetrics:
                 *(f'llmlb_engine_decode_bursts_not_ahead_total'
                   f'{{reason="{reason}"}} {n}' for reason, n
                   in self.decode_bursts_not_ahead_total.items()),
+                "# TYPE llmlb_engine_prefill_dispatches_total counter",
+                "llmlb_engine_prefill_dispatches_total "
+                f"{self.prefill_dispatches_total}",
+                "# TYPE llmlb_engine_prefills_dispatched_ahead_total counter",
+                "llmlb_engine_prefills_dispatched_ahead_total "
+                f"{self.prefills_dispatched_ahead_total}",
                 "# TYPE llmlb_engine_decode_kv_pages_live_total counter",
                 "llmlb_engine_decode_kv_pages_live_total "
                 f"{self.decode_kv_pages_live_total}",

@@ -359,6 +359,18 @@ class _Burst:
         return [i for i, _, _ in self.rows]
 
 
+@dataclasses.dataclass
+class _AheadPrefill:
+    """A one-shot prefill group dispatched AHEAD (EngineCore._admit_ahead),
+    from its dispatch to its record, which is closed with the burst behind
+    it in flight (_record_ahead_prefill)."""
+
+    step: StepSpan
+    group: list[tuple[int, "Request", int]]  # (slot, request, tokens)
+    logits: object  # the dispatch's result: ready when the prefill is done
+    stats: list  # its step counters, still on the device
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineStats:
     num_slots: int
@@ -1096,11 +1108,13 @@ class EngineCore:
             self.metrics.loop_clocks[clock.tag] = clock
         return clock
 
-    def _note_prefill_dispatch(self) -> None:
+    def _note_prefill_dispatch(self, ahead: bool = False) -> None:
         """Ledger every prefill dispatch by the loop that ran it. Split
         mode's acceptance invariant — the decode loop NEVER runs prefill —
-        is asserted over this dict in tier-1."""
+        is asserted over this dict in tier-1. `ahead`: it leaves before the
+        burst fetched in front of it is emitted (_admit_ahead)."""
         self.prefill_dispatch_by_loop[self._loop_tag()] += 1
+        self.metrics.record_prefill_dispatch(ahead)
 
     def start(self) -> None:
         self._running = True
@@ -1610,7 +1624,8 @@ class EngineCore:
                       kv_pages: "dict[str, int] | None" = None,
                       counters: "dict | None" = None,
                       block: "dict[str, int] | None" = None,
-                      burst: "_Burst | None" = None) -> None:
+                      burst: "_Burst | None" = None,
+                      ahead: "bool | None" = None) -> None:
         """Finalize the record of a CLOSED step: the admission time since
         the previous record becomes its plan phase, the record feeds the
         ring buffer + anomaly detector, and the phase durations are mirrored
@@ -1631,8 +1646,10 @@ class EngineCore:
         the running totals likewise. `burst` is a dense decode burst's:
         whether it left ahead of its predecessor's emit, and why not, on the
         record (`dispatched_ahead`, `ahead_blocked_by`) and in the running
-        totals. A step that LoopClock.handover closed is observed inside
-        its successor, under `emit_inflight`."""
+        totals. `ahead` is a one-shot prefill group's: whether it left
+        before the burst in front of it was emitted (`dispatched_ahead` on
+        its record). A step that LoopClock.handover closed is observed
+        inside its successor, under `emit_inflight`."""
         phases = step.phases()
         request_ids: dict[str, str] | None = None
         if slots:
@@ -1651,6 +1668,8 @@ class EngineCore:
             extra["dispatched_ahead"] = burst.blocked_by is None
             extra["ahead_blocked_by"] = burst.blocked_by
             self.metrics.record_decode_burst(burst.blocked_by)
+        if ahead is not None:
+            extra["dispatched_ahead"] = ahead
         slow = self.step_stats.observe(kind, phases,
                                        active_slots=active_slots,
                                        tokens=tokens,
@@ -2893,11 +2912,10 @@ class EngineCore:
             slot.drafter = parked.drafter
             slot.spec_k = parked.spec_k
             return
+        if not self._speculates(request):
+            return
         knobs = request.sampling.speculative
         knobs = knobs if isinstance(knobs, dict) else {}
-        enabled = bool(knobs.get("enabled", self.spec.enabled))
-        if not enabled:
-            return
         try:
             k = int(knobs.get("max_draft_tokens")
                     or self.spec.max_draft_tokens)
@@ -2908,6 +2926,16 @@ class EngineCore:
             request.prompt_ids,
             max_ngram=self.spec.max_ngram, min_ngram=self.spec.min_ngram,
         )
+
+    def _speculates(self, request: Request) -> bool:
+        """Whether a fresh request gets a drafter at its slot claim: the
+        family verifies drafts, and the request's `speculative` knobs, else
+        the engine's default, say so."""
+        if not self._spec_available:
+            return False
+        knobs = request.sampling.speculative
+        knobs = knobs if isinstance(knobs, dict) else {}
+        return bool(knobs.get("enabled", self.spec.enabled))
 
     def _fused_step_ok(self, active: list[int]) -> bool:
         """True when this step can run as ONE fused device program: fused
@@ -3495,10 +3523,19 @@ class EngineCore:
                        else int(v)) for name, v in stats[0].items()}
 
     def _prefill_group(self, bucket: int,
-                       group: list[tuple[int, Request, int]]) -> None:
+                       group: list[tuple[int, Request, int]],
+                       after: StepSpan | None = None
+                       ) -> "_AheadPrefill | None":
         """Prefill G same-bucket prompts in one dispatch, padded to the next
         power of two by repeating the last row — duplicate scatters write
-        identical data to the same slot, so padding rows are free."""
+        identical data to the same slot, so padding rows are free.
+
+        `after` (_admit_ahead): the group leaves AHEAD, behind the closed,
+        unrecorded step of a burst that is fetched and not emitted. Nothing
+        is waited for: the activation is dispatched behind the prefill, the
+        step is returned open, in `activate_inflight`, for the caller to
+        hand over to the burst behind both, and its record is closed with
+        that burst in flight (_record_ahead_prefill)."""
         g = len(group)
         padded = 1
         while padded < g:
@@ -3525,8 +3562,9 @@ class EngineCore:
             lidx[g:] = lidx[g - 1]
             lora_idx = jnp.asarray(lidx)
 
-        self._note_prefill_dispatch()
-        step = self._clock().begin("dispatch")
+        ahead = after is not None
+        self._note_prefill_dispatch(ahead)
+        step = self._clock().begin("dispatch", after=after)
         # padding rows repeat the last real slot's table row, so their
         # duplicate scatters rewrite identical cells (same trick as ids)
         (logits, self.cache_k, self.cache_v,
@@ -3535,28 +3573,56 @@ class EngineCore:
             jnp.asarray(self._block_tables[slot_ids]),
             self.cache_k, self.cache_v,
             slot_ids=slot_ids, lora_idx=lora_idx)
+        if ahead:
+            self._activate_group(group, slot_ids, lens, logits, inflight=True)
+            return _AheadPrefill(step, group, logits, stats)
         step.mark("compute")
         # jitted prefill returns futures (async dispatch); block before timing
         # or the histogram records dispatch overhead, not device execution.
         jax.block_until_ready(logits)
         self.metrics.record_prefill_step(step.mark("emit") - step.t0)
+        # before activation: split mode stages the group and vacates the
+        # prefill slots, after which the requests are unreachable here
+        self._fr_prefilled(group)
+        self._activate_group(group, slot_ids, lens, logits)
+        self._record_step("prefill", step, ahead=False,
+                          **self._prefill_counts(group, stats))
+        return None
+
+    def _fr_prefilled(self, group: list[tuple[int, Request, int]]) -> None:
+        """A one-shot group's `prefill_chunk` events, stamped where the
+        host knows its prefill done."""
         if self.flightrec.enabled:
-            # emit before activation: split mode stages the group and vacates
-            # the prefill slots, after which the requests are unreachable here
             for _slot_id, request, n in group:
                 self.flightrec.emit(request.request_id, "prefill_chunk",
                                     tokens=n, cached_tokens=0)
-        self._activate_group(group, slot_ids, lens, logits)
-        self._record_step(
-            "prefill", step,
-            active_slots=len(group), tokens=sum(n for _, _, n in group),
-            slots=[s for s, _, _ in group],
-            counters=self._prefill_counters(stats),
-        )
+
+    def _prefill_counts(self, group: list[tuple[int, Request, int]],
+                        stats: list) -> dict:
+        """What a one-shot group's record counts (_observe_step)."""
+        return {"active_slots": len(group),
+                "tokens": sum(n for _, _, n in group),
+                "slots": [s for s, _, _ in group],
+                "counters": self._prefill_counters(stats)}
+
+    def _record_ahead_prefill(self, prefill: _AheadPrefill) -> None:
+        """With the burst behind it in flight and the burst in front of it
+        emitted: what _prefill_group does between its wait and its return,
+        for a group that left ahead. The wait is the first instant the host
+        knows the prefill done, so the prefill histogram still reads
+        dispatch -> done (not the dispatch alone), and the flight records'
+        `prefill_chunk` is stamped where it is in today's order."""
+        jax.block_until_ready(prefill.logits)
+        self.metrics.record_prefill_step(
+            time.perf_counter() - prefill.step.t0)
+        self._fr_prefilled(prefill.group)
+        self._observe_step(
+            "prefill", prefill.step,
+            **self._prefill_counts(prefill.group, prefill.stats), ahead=True)
 
     def _activate_group(self, group: list[tuple[int, Request, int]],
                         padded_slot_ids: np.ndarray, padded_lens: np.ndarray,
-                        logits) -> None:
+                        logits, *, inflight: bool = False) -> None:
         """Batched activation: ONE program (`_activate_rows`) samples every
         row's first token from the padded logits and scatters the group's
         sampling state, lengths, first tokens and adapter rows into the
@@ -3569,7 +3635,10 @@ class EngineCore:
         Split mode: a prefill-loop activation never lands in the prefill
         slot — the finished slot is STAGED (prompt KV pinned in its pages,
         final logits row held) and the handoff pump adopts it into a decode
-        slot, re-entering here under the "handoff" tag."""
+        slot, re-entering here under the "handoff" tag.
+
+        `inflight`: the group's prefill is still computing (it left ahead,
+        _prefill_group): the span is `activate_inflight`."""
         if self.split is not None and self._loop_tag() == "prefill":
             self.split.stage_group(group, logits)
             self.split.pump_handoffs()
@@ -3579,7 +3648,7 @@ class EngineCore:
             return
         # inside a step (the prefill paths) this is its `activate` span;
         # a handoff adoption between steps stays in the loop's bucket
-        self._clock().mark("activate")
+        self._clock().mark("activate_inflight" if inflight else "activate")
         g = len(group)
         padded = len(padded_slot_ids)
         temps = np.ones((padded,), np.float32)
@@ -4037,17 +4106,21 @@ class EngineCore:
         more as can leave AHEAD: a burst whose rows need nothing from the
         host is dispatched right after its predecessor's fetch, and the
         predecessor's tokens are delivered and its record closed while it
-        computes (docs/scheduling.md "The two orders of a decode cycle").
-        Either way the host prepares the next burst (_prepare_burst) between
-        a dispatch and the wait for it. Whether the next burst leaves ahead
-        is decided after each fetch from what the loop can observe
-        (_ahead_blocker); where anything stands in the way, the cycle is
-        the parent's, step for step: emit, record, back through _loop, and
-        host_sync again (which finds its pages grown and its tables clean).
-        The device never holds more than ONE burst the host has not
-        fetched, and none is in flight when this returns."""
+        computes (docs/scheduling.md "The three orders of a decode cycle").
+        Where the one thing in the way is an arrival that can be placed
+        without the predecessor's emit (_admit_ahead), its prefill and its
+        activation leave first and the burst, its rows among the burst's,
+        right behind them. Either way the host prepares the next burst
+        (_prepare_burst) between a dispatch and the wait for it. Which
+        order a cycle takes is decided after each fetch from what the loop
+        can observe (_ahead_blocker, _arrivals_ahead); where anything else
+        stands in the way, the cycle is the parent's, step for step: emit,
+        record, back through _loop, and host_sync again (which finds its
+        pages grown and its tables clean). The device never holds more than
+        ONE burst the host has not fetched, with the prefill and activation
+        dispatched in front of it, and nothing is in flight when this
+        returns."""
         clock = self._clock()
-        lora_idx = self._d_lora_idx if self.lora is not None else None
         window = self._window_for(active, k)
         plan = (self._burst_rows(active), window,
                 self._kv_pages(active, k, window))
@@ -4072,6 +4145,8 @@ class EngineCore:
         fixed = self._ahead_fixed_blocker(active, grammar)
         blocked_by, self._ahead_blocked_by = self._ahead_blocked_by, "first"
         prev: _Burst | None = None
+        # the prefill dispatched ahead, in front of the burst about to leave
+        placed: _AheadPrefill | None = None
         while True:
             rows, window, kv_pages = plan
             fn = self.programs.decode_many(window, grammar)
@@ -4083,6 +4158,9 @@ class EngineCore:
                 # activations in the order they are dispatched
                 self._key, sk = jax.random.split(self._key)
             burst = _Burst(step, rows, kv_pages, blocked_by)
+            # the adapter rows as the last activation left them (one ahead
+            # of this burst donates the array an earlier burst was handed)
+            lora_idx = self._d_lora_idx if self.lora is not None else None
             (self._d_last_tokens, self._d_seq_lens, self.cache_k,
              self.cache_v, toks_dev) = fn(
                 *self._decode_operands(sk), self._live_rows(burst.slots),
@@ -4092,6 +4170,9 @@ class EngineCore:
             if prev is not None:
                 step.mark("emit_inflight")
                 self._deliver_burst(prev, k, fused_step, closed=True)
+                if placed is not None:
+                    self._record_ahead_prefill(placed)
+                    placed = None
             plan = None
             if fixed is None:
                 step.mark("host_sync_inflight")
@@ -4105,9 +4186,21 @@ class EngineCore:
             burst.fetched = self._fetch_tokens(toks_dev)  # ONE D2H per k tokens
             self._in_flight = None
             blocked_by = fixed or self._ahead_blocker(plan)
+            arrivals = (self._arrivals_ahead(plan, k)
+                        if blocked_by == "admission" else None)
             # Tokens reach the host back-to-back, so wall-clock gaps between
             # _emit calls are ~0 and would poison the ITL histogram; record
             # the amortized per-token pacing of the burst's cycle instead.
+            if arrivals is not None:
+                # the burst's record ends here, the prefill's begins behind
+                # the placing, and the next burst's where the prefill's ends
+                clock.close(step, "decode")
+                burst.step_s = (step.t1 - t_cycle) / k
+                placed, plan = self._admit_ahead(step, arrivals, plan, k)
+                step = clock.handover(placed.step, "prefill",
+                                      "dispatch_inflight")
+                prev, t_cycle, blocked_by = burst, step.t0, None
+                continue
             if blocked_by is None:
                 step = clock.handover(step, "decode", "dispatch")
                 burst.step_s = (step.t0 - t_cycle) / k
@@ -4165,6 +4258,119 @@ class EngineCore:
                 or any(self._class_queues.values())):
             return "admission"
         return plan if isinstance(plan, str) else None
+
+    def _arrivals_ahead(self, plan, k: int
+                        ) -> "list[tuple[Request, int, int, bool]] | None":
+        """After a burst's fetch, with `admission` the one thing in the way
+        of the prepared burst `plan`: the queued requests in the order
+        _try_insert would take them — each with the tokens it prefills, the
+        pages it takes (its prompt's and what the burst behind the prefill
+        writes) and whether the prefix cache was asked about it — if EVERY
+        one of them
+        can be placed now and prefilled as ONE one-shot group, without the
+        host having emitted the fetched burst; else None, and the cycle
+        takes today's order, where _try_insert serves them all behind the
+        emit. That is: there is a burst to put them in (`plan` has rows and
+        pages); none is held on the pool; a slot is free NOW for each (the
+        slots the un-emitted burst will free do not count, so a queue deeper
+        than the free slots waits for that emit, as it does today); each is
+        fresh (not cancelled, expired, parked or carrying KV as bytes),
+        unconstrained (a constrained activation fetches its first token),
+        gets no drafter, fits a one-shot bucket and the iteration's prefill
+        budget, and misses the prefix cache as it stands (a hit extends
+        behind the donor's pages; with a host-RAM tier a miss may still be
+        a restore, so such an engine keeps today's order); all share one
+        bucket; and the free list alone covers every prompt plus what the
+        burst behind the prefill writes, all or none — nothing is evicted,
+        parked or preempted for an arrival placed ahead. Moves the inbox
+        into the class queues (as _try_insert would a few spans later) and
+        changes nothing else."""
+        if (not isinstance(plan, tuple) or self.kv_offload is not None
+                or self._held_request is not None):
+            return None
+        free = len(self._free_slots())
+        if not free:
+            return None  # a full house: nothing is touched, the inbox neither
+        self._drain_pending()
+        queued = self._queued_requests()
+        if not queued or len(queued) > min(free, self.MAX_PREFILL_GROUP):
+            return None
+        cutoff = self.prefill_buckets[-1] if self.prefill_buckets else 0
+        budget = self._prefill_budget_now()
+        if budget:
+            cutoff = min(cutoff, self._budget_chunk_len(budget))
+        if budget and sum(len(r.prompt_ids) for r in queued) > budget:
+            return None
+        arrivals: list[tuple[Request, int, int, bool]] = []
+        bucket = None  # the group's, set by its first request
+        for request in queued:
+            n = len(request.prompt_ids)
+            if (self._is_cancelled(request) or request.deadline_expired()
+                    or request.parked is not None
+                    or request.kv_restore is not None
+                    or request.sampling.constraint is not None
+                    or request.compiled_constraint is not None
+                    or self._speculates(request)
+                    or n > cutoff or n + 1 >= self.slot_capacity):
+                return None
+            bucket = bucket or self._bucket_for(n)
+            if self._bucket_for(n) != bucket:
+                return None
+            cacheable = (self.prefix_cache is not None
+                         and n - 1 >= self.min_prefix_len)
+            if cacheable and self.prefix_cache.match(
+                    request.prompt_ids, max_len=n - 1,
+                    ns=request.sampling.lora) is not None:
+                return None
+            pages = self._pages_for_tokens(min(n + k + 1, self.slot_capacity))
+            arrivals.append((request, n, pages, cacheable))
+        if sum(a[2] for a in arrivals) > self.page_pool.available():
+            return None
+        return arrivals
+
+    def _admit_ahead(self, step: StepSpan,
+                     arrivals: "list[tuple[Request, int, int, bool]]",
+                     plan: tuple, k: int) -> "tuple[_AheadPrefill, tuple]":
+        """Admission AHEAD: with the burst of the closed, unrecorded `step`
+        fetched and not emitted, place `arrivals` (_arrivals_ahead said they
+        can be) as _try_insert's one-shot path does, and dispatch their
+        prefill and their activation without waiting for either. Returns the
+        prefill, its step open, and the prepared burst `plan` with the new
+        rows in it (first token pending): the caller dispatches that burst
+        right behind, so the device runs prefill, activation and burst back
+        to back while the host emits the fetched burst. Each arrival's
+        pages, the prompt's and what the burst will write, come from the
+        free list in one piece. The placing is the loop's `admit`, as in
+        today's order."""
+        self._clock().switch("admit")
+        free = self._free_slots()
+        group: list[tuple[int, Request, int]] = []
+        for request, n, pages, cacheable in arrivals:
+            popped = self._pop_request()
+            assert popped is request, "the queue moved under _arrivals_ahead"
+            if cacheable:
+                self.metrics.record_prefix_miss()
+            slot_id = free.pop(0)
+            self._assign_slot_pages(slot_id, (), self.page_pool.alloc(pages))
+            # Claim the slot BEFORE any dispatch, as _try_insert does
+            self.slots[slot_id].request = request
+            self.slots[slot_id].generated = 0
+            self._attach_constraint(slot_id, request)
+            group.append((slot_id, request, n))
+        prefill = self._prefill_group(self._bucket_for(group[0][2]), group,
+                                      after=step)
+        self._sync_block_tables()
+        rows, window, kv_pages = plan
+        new = [slot_id for slot_id, _, _ in group]
+        window = max(window, self._window_for(new, k))
+        of_new = self._kv_pages(new, k, window)
+        kv_pages = {"kv_pages_live": (kv_pages["kv_pages_live"]
+                                      + of_new["kv_pages_live"]),
+                    "kv_pages_window": of_new["kv_pages_window"]}
+        rows = sorted(rows + [(slot_id, request, True)
+                              for slot_id, request, _ in group],
+                      key=lambda row: row[0])
+        return prefill, (rows, window, kv_pages)
 
     def _prepare_burst(self, rows: list[tuple[int, Request, bool]], k: int):
         """With the burst of `rows` in flight and every earlier one emitted:

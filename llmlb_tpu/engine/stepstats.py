@@ -54,24 +54,37 @@ Span names (``SPANS``), in the order a decode step runs them:
               state), the first-token fetch where a grammar needs it, and
               the slot bookkeeping
 
-Host work done WITH A BURST IN FLIGHT, between a dispatch's return and the
-wait for it (scheduler._decode_bursts), has span names of its own
-(``INFLIGHT_SPANS``), so that the names above keep meaning "the device has
-nothing from this loop":
+Host work done WITH A PROGRAM OF THIS LOOP ON THE DEVICE, between a
+dispatch's return and the wait for it (scheduler._decode_bursts), has span
+names of its own (``INFLIGHT_SPANS``), so that the names above keep meaning
+"the device has nothing from this loop":
 
   emit_inflight      — delivery of the burst BEFORE the one in flight and
                        the closing of its record (what `emit` and the
-                       `record` bucket hold in the other order)
+                       `record` bucket hold in the other order); behind a
+                       prefill dispatched ahead, the closing of the
+                       prefill's record too
   host_sync_inflight — what `host_sync` does, done for the NEXT burst from
                        the lengths its rows will have: page growth from the
                        free list, the block tables, the window, the live rows
+  activate_inflight  — what `activate` does, with the group's own prefill
+                       still computing: the activation of a prefill that
+                       was dispatched ahead (scheduler._admit_ahead)
+  dispatch_inflight  — the call of the burst behind such a prefill and its
+                       activation, which the device runs before it: the
+                       block tables with the new rows, the key split, the call
 
-A decode burst runs in one of two orders. Today's: ``host_sync, dispatch,
+A decode burst runs in one of three orders. Today's: ``host_sync, dispatch,
 [host_sync_inflight,] compute, fetch, emit``. Dispatched ahead (it left
 right after its predecessor's fetch): ``dispatch, emit_inflight,
 [host_sync_inflight,] compute, fetch`` and, where the next burst does not
 leave ahead in its turn, ``emit``. Where one does, the record ends at the
 stamp the next begins at (``LoopClock.handover``): records never overlap.
+Admission ahead (an arrival was placed right after the predecessor's fetch):
+the prefill's record is ``dispatch, activate_inflight`` behind a stretch of
+``admit``, and the burst's ``dispatch_inflight, emit_inflight,
+[host_sync_inflight,] compute, fetch[, emit]``; the three records — the
+predecessor's, the prefill's, the burst's — end and begin at one stamp each.
 
 The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
 since the previous record (``since_prev.admit_s``), ``emit`` still covers
@@ -115,9 +128,12 @@ PHASES = ("plan", "draft", "host_sync", "dispatch", "compute", "fetch",
           "emit")
 # The closed set of span names a step is cut into (StepSpan.mark).
 SPANS = ("draft", "host_sync", "dispatch", "compute", "fetch", "emit",
-         "activate", "host_sync_inflight", "emit_inflight")
-# Host work with a burst in flight: `compute` in the legacy phases.
-INFLIGHT_SPANS = ("host_sync_inflight", "emit_inflight")
+         "activate", "host_sync_inflight", "emit_inflight",
+         "activate_inflight", "dispatch_inflight")
+# Host work with a program of this loop on the device: `compute` in the
+# legacy phases.
+INFLIGHT_SPANS = ("host_sync_inflight", "emit_inflight", "activate_inflight",
+                  "dispatch_inflight")
 # Where the loop's time goes between steps, and with "step" all of it.
 GAP_BUCKETS = ("admit", "control", "record", "idle", "other")
 LOOP_BUCKETS = ("step",) + GAP_BUCKETS
@@ -239,28 +255,36 @@ class LoopClock:
         self._cpu_closed = _cpu()
         self._gc_closed = hoststats.GC.seconds_total
 
-    def switch(self, bucket: str) -> None:
+    def switch(self, bucket: str, now: float | None = None) -> None:
         """The loop moves on to `bucket` (no-op while a step is open: the
-        step's own spans hold that time)."""
+        step's own spans hold that time), at `now` if the caller has read
+        the clock for it."""
         if self._step is not None:
             return
-        now = _now()
+        if now is None:
+            now = _now()
         dt = now - self._mark
         self.acc[self._bucket] += dt
         self._gap[self._bucket] += dt
         self._bucket = bucket
         self._mark = now
 
-    def begin(self, first_span: str, seq: int | None = None) -> StepSpan:
+    def begin(self, first_span: str, *, after: StepSpan | None = None,
+              at: float | None = None) -> StepSpan:
         """Open a step whose first span is `first_span`. Its kind is given
-        when it is closed (a decode step may turn into a verify)."""
-        resume = self._bucket
-        self.switch("step")
+        when it is closed (a decode step may turn into a verify). `after`:
+        a step that is closed and not recorded yet (handover; or close and
+        a stretch of `admit`, where an arrival is placed ahead): this one
+        takes the seq behind it and resumes where it would have. `at`: the
+        clock read it begins at, where the caller has one."""
+        resume = self._bucket if after is None else after._resume
+        self.switch("step", at)
         # steps are serialized (one loop, or split mode's lock), so the
-        # record this step will become is the recorder's next (handover
-        # says otherwise: its closed step is not recorded yet)
+        # record this step will become is the recorder's next, or the one
+        # behind `after`'s
+        seq = self.recorder.seq + 1 if after is None else after.seq + 1
         cpu = _cpu()
-        step = StepSpan(self.tag, seq or self.recorder.seq + 1, self._mark,
+        step = StepSpan(self.tag, seq, self._mark,
                         first_span, self._gap, resume, cpu,
                         cpu - self._cpu_closed)
         self._gap = dict.fromkeys(GAP_BUCKETS, 0.0)
@@ -310,8 +334,8 @@ class LoopClock:
         — the caller hands `step` to the recorder inside the new step, under
         `emit_inflight` — and the new step resumes where `step` would have."""
         self.close(step, kind)
-        self._bucket = step._resume
-        return self.begin(first_span, seq=step.seq + 1)
+        # the two steps end and begin at ONE clock read: no stretch between
+        return self.begin(first_span, after=step, at=step.t1)
 
     def abandon(self) -> None:
         """Drop the open step, if any, without a record (a step that found

@@ -1,5 +1,6 @@
 """The host's stretch between two decode bursts, from a benchmark run's step
-records: which order each burst took and what the host did where.
+records: which order each burst and each prefill took and what the host did
+where.
 
     python3 scripts/burst_order.py .bench_run/<cell>/last_run.json [...]
 
@@ -9,9 +10,17 @@ records' count, the share dispatched ahead (`dispatched_ahead`,
 engine/scheduler.py `_decode_bursts`) and the reasons of the others, the mean
 milliseconds a decode record spends in each span — exposed (`host_sync`,
 `dispatch`, `fetch`, `emit`, and the gap before the record by bucket) against
-in flight (`host_sync_inflight`, `emit_inflight`) — and the same for the
-prefill records. A commit that has no such field (before PR 39) reads as
-"ahead" 0 with no reasons. No jax, no chip: it reads a file.
+in flight (`host_sync_inflight`, `emit_inflight`, `dispatch_inflight`) — and
+the same for the prefill records; then the share of the one-shot prefill
+records dispatched ahead (`_admit_ahead`: the prefill left before the burst
+in front of it was emitted) and, for those and for the others apart, the
+milliseconds a prefill record exposes (its spans other than `compute` and
+the in-flight ones, and the gap before it) against those it spends in
+flight (`activate_inflight`; `compute` is the host waiting). A commit that
+has no `dispatched_ahead` on its decode records (before PR 39) reads as
+"ahead" 0 with no reasons; one that has none on its prefill records (before
+PR 49; a chunk's record never has one) reads `prefill_ahead_share_pct` null.
+No jax, no chip: it reads a file.
 """
 
 from __future__ import annotations
@@ -19,6 +28,31 @@ from __future__ import annotations
 import json
 import sys
 from collections import Counter
+
+
+INFLIGHT = ("host_sync_inflight", "emit_inflight", "activate_inflight",
+            "dispatch_inflight")
+
+
+def _exposed_ms(records: list[dict]) -> dict[str, float] | None:
+    """Mean milliseconds a record keeps the device waiting for the host
+    (spans outside `compute` and INFLIGHT, and the gap before it less the
+    idle sleep) against those the host works with a program in flight."""
+    if not records:
+        return None
+    exposed = inflight = 0.0
+    for r in records:
+        for name, _at, dur in r.get("spans", ()):
+            if name in INFLIGHT:
+                inflight += dur
+            elif name != "compute":
+                exposed += dur
+        gap = r.get("since_prev") or {}
+        exposed += sum(dur for bucket, dur in gap.items()
+                       if bucket != "idle_s")
+    n = len(records)
+    return {"exposed": round(1e3 * exposed / n, 3),
+            "in_flight": round(1e3 * inflight / n, 3)}
 
 
 def _mean_ms(records: list[dict]) -> dict[str, float]:
@@ -41,6 +75,8 @@ def summarize(path: str) -> dict:
     prefill = [r for r in steps if r["kind"] == "prefill"]
     bursts = [r for r in decode if "dispatched_ahead" in r]
     ahead = [r for r in bursts if r["dispatched_ahead"]]
+    groups = [r for r in prefill if "dispatched_ahead" in r]
+    groups_ahead = [r for r in groups if r["dispatched_ahead"]]
     out = {
         "file": path,
         "workload": run["args"].get("workload"),
@@ -55,6 +91,13 @@ def summarize(path: str) -> dict:
                                 / max(1, len(decode)), 3),
         "decode_mean_ms": _mean_ms(decode),
         "prefill_mean_ms": _mean_ms(prefill),
+        "prefill_ahead": len(groups_ahead),
+        "prefill_ahead_share_pct": (
+            round(100.0 * len(groups_ahead) / len(groups), 1)
+            if groups else None),
+        "prefill_ahead_ms": _exposed_ms(groups_ahead),
+        "prefill_not_ahead_ms": _exposed_ms(
+            [r for r in prefill if not r.get("dispatched_ahead")]),
     }
     if ahead:
         out["ahead_mean_ms"] = _mean_ms(ahead)

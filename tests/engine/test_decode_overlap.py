@@ -1,20 +1,28 @@
-"""A dense decode burst leaves before its predecessor is emitted.
+"""A dense decode burst leaves before its predecessor is emitted, and an
+arrival's prefill before that.
 
-The step loop runs a decode cycle in one of two orders (docs/scheduling.md).
+The step loop runs a decode cycle in one of three orders (docs/scheduling.md).
 Today's: host_sync, dispatch, compute, fetch, emit, record, back through the
 loop. Ahead: right after a burst's fetch the next one is dispatched, and the
 fetched tokens are delivered, the record closed and the burst after that
-prepared while it computes. Which one a cycle takes is decided by what the
-loop observes in its own state (`EngineCore._ahead_blocker`), never by a
-setting. These tests hold what the reorder has to keep true:
+prepared while it computes. Admission ahead: where the one thing in the way
+is an arrival that can be placed without that emit, its prefill, its
+activation and the next burst (the new row among its rows) are dispatched
+back to back right after the fetch. Which one a cycle takes is decided by
+what the loop observes in its own state (`EngineCore._ahead_blocker`,
+`EngineCore._arrivals_ahead`), never by a setting. These tests hold what the
+reorder has to keep true:
 
-(a) the same requests give the same streams, finish reasons and usage in both
-    orders — rows ending by max_tokens, by EOS inside a burst with a new
-    request taking the slot at once, and a cancel among them;
+(a) the same requests give the same streams, finish reasons and usage in all
+    three orders — rows ending by max_tokens, by EOS inside a burst with a new
+    request taking the slot at once, and a cancel among them; an arrival, two
+    arrivals, an arrival with an EOS in the un-emitted burst, one cancelled
+    before its first token, one that ends inside the burst behind its prefill;
 (b) a request that arrives while a burst is in flight is prefilled before
     any further burst is dispatched;
 (c) a grammar, a drafter, a drain and a free list too short each keep the
-    cycle in today's order, and say so on the record;
+    cycle in today's order, and say so on the record; so does an arrival that
+    needs an eviction, a chunked prefill, a grammar or a slot nobody has yet;
 (d) the step records still tile the loop's time, and the counters add up.
 
 Most engines here are driven inline (`tests.support.InlineLoop`:
@@ -75,8 +83,13 @@ def _seeded(j: int, max_tokens: int, seed: int) -> Request:
 
 NEVER = CFG.vocab_size + 7  # an EOS id no row samples
 
+# InlineLoop's arguments for the three orders of a decode cycle
+ORDERS = {"today": {"todays_order": True},
+          "ahead": {"admission_ahead": False},  # PR 39's
+          "admission_ahead": {}}
 
-def _scenario(eos: int, ends_at: int, *, todays_order: bool):
+
+def _scenario(eos: int, ends_at: int, **order):
     """Four rows of one prefill group — greedy to max_tokens 30, seeded to 22
     (neither a multiple of the burst), greedy to EOS as its decode token
     `ends_at`, greedy and cancelled while burst 2 is in flight — and two that
@@ -84,7 +97,7 @@ def _scenario(eos: int, ends_at: int, *, todays_order: bool):
     takes the slot the EOS freed, and one three bursts on. Returns every
     request's events and the run."""
     core = _core(eos_id=eos)
-    run = Inline(core, todays_order=todays_order)
+    run = Inline(core, **order)
     reqs = {"long": _greedy(0, 30), "seeded": _seeded(1, 22, seed=1234),
             "eos": _greedy(2, 40), "cancelled": _greedy(3, 64),
             "takes_the_slot": _seeded(4, 11, seed=77),
@@ -120,20 +133,23 @@ def eos_inside_a_burst() -> tuple[int, int]:
 def test_both_orders_give_the_same_streams_reasons_and_usage(
         eos_inside_a_burst):
     eos, ends_at = eos_inside_a_burst
-    today, run_today = _scenario(eos, ends_at, todays_order=True)
-    ahead, run = _scenario(eos, ends_at, todays_order=False)
+    today, run_today = _scenario(eos, ends_at, **ORDERS["today"])
+    held, run_held = _scenario(eos, ends_at, **ORDERS["ahead"])
+    ahead, run = _scenario(eos, ends_at, **ORDERS["admission_ahead"])
     # tokens, finish reason and the size of every content event (usage is
     # the prompt's length and the number of tokens)
-    assert ahead == today
+    assert ahead == today and held == today
     assert {name: (len(t), finish)
             for name, (t, finish, _s) in ahead.items()} == {
         "long": (30, "length"), "seeded": (22, "length"),
         "eos": (ends_at, "stop"), "cancelled": (1 + BURST, "cancelled"),
         "takes_the_slot": (11, "length"), "late": (9, "length")}
-    # the order under test engaged ...
+    # the orders under test engaged ...
     records, records_today = run.decode_records(), run_today.decode_records()
+    records_held = run_held.decode_records()
     assert not any(r["dispatched_ahead"] for r in records_today)
-    assert sum(r["dispatched_ahead"] for r in records) >= 4
+    assert sum(r["dispatched_ahead"] for r in records_held) >= 4
+    assert sum(r["dispatched_ahead"] for r in records) >= 6
     # ... and the rows that ended unseen by the dispatch were in a burst that
     # had left already: the cancelled one in burst 3, the one that met its
     # EOS in the burst after. Their columns went to nobody: the request that
@@ -141,47 +157,162 @@ def test_both_orders_give_the_same_streams_reasons_and_usage(
     # has the stream of today's order, above.
     ends_in = -(-ends_at // BURST)
     for burst in (3, ends_in + 1):
-        assert records[burst - 1]["dispatched_ahead"]
-        assert records[burst - 1]["active_slots"] == \
-            records_today[burst - 1]["active_slots"] + 1
-    took = records[ends_in + 1]  # the first burst after the arrival
-    assert took["ahead_blocked_by"] == "admission"
-    assert "2" in took["request_ids"]
+        for recs in (records, records_held):
+            assert recs[burst - 1]["dispatched_ahead"]
+            assert recs[burst - 1]["active_slots"] == \
+                records_today[burst - 1]["active_slots"] + 1
+    # the first burst after the arrival: held for it where an arrival waits
+    # for the emit, behind its prefill where it does not
+    took_held, took = records_held[ends_in + 1], records[ends_in + 1]
+    assert took_held["ahead_blocked_by"] == "admission"
+    assert took["dispatched_ahead"]
+    assert "2" in took["request_ids"] and "2" in took_held["request_ids"]
+    # the last arrival came while the last burst of the rows before it was
+    # in flight: with no burst to put it in, it waited for the emit
+    prefills = run.records("prefill")
+    assert [r["dispatched_ahead"] for r in prefills] == [False, True, False]
+    assert records[-2]["ahead_blocked_by"] == "admission"
+    assert not any(r["dispatched_ahead"] for r in run_held.records("prefill"))
     totals = run.core.metrics.summary()
     assert totals["decode_bursts_dispatched_ahead_total"] == sum(
         r["dispatched_ahead"] for r in records)
+    assert totals["prefills_dispatched_ahead_total"] == 1
+    assert totals["prefill_dispatches_total"] == 3
+
+
+def _arrival_case(case: str, eos: int, **order):
+    """One run of a case of (a): two rows decoding, and what arrives while a
+    burst is in flight. Returns every request's events and the run."""
+    core = _core(eos_id=eos, num_slots=3 if case == "eos_unemitted" else 4)
+    run = Inline(core, **order)
+    reqs = {"first": _greedy(0, 40), "second": _seeded(1, 40, seed=21)}
+    for r in reqs.values():
+        core.pending.put(r)
+    if case == "two_arrivals":
+        reqs["late"], reqs["later"] = _seeded(2, 14, seed=8), _greedy(3, 11)
+    elif case == "max_tokens_in_the_burst_behind":
+        reqs["late"] = _greedy(2, 3)  # its first token and two of the burst
+    else:
+        reqs["late"] = _seeded(2, 14, seed=8)
+    arrive = [lambda r=r: core.pending.put(r)
+              for name, r in reqs.items() if name.startswith("late")]
+    # "eos_unemitted": `first` meets its EOS in burst 2, and the arrival comes
+    # while that burst is in flight
+    run.during[2 if case == "eos_unemitted" else 3] = arrive
+    if case == "cancelled_before_its_first_token":
+        # placed behind burst 3's fetch; its first token comes with burst 4's
+        run.during[4] = [reqs["late"].cancel]
+    run.run()
+    return {name: collect_events(r, timeout=None)
+            for name, r in reqs.items()}, run
+
+
+@pytest.fixture(scope="module")
+def eos_of_the_first_row() -> int:
+    """A token that the cases' greedy row emits as its decode token 5 or 6 —
+    inside its second burst — and no row of the case emits anywhere else."""
+    events, _ = _arrival_case("eos_unemitted", NEVER, todays_order=True)
+    tokens = events["first"][0]
+    everything = [t for toks, _f, _s in events.values() for t in toks]
+    for index in (5, 6):
+        if everything.count(tokens[index]) == 1:
+            return tokens[index]
+    raise AssertionError("no token of the row is its own: change a prompt")
+
+
+@pytest.mark.parametrize("case", [
+    "an_arrival", "two_arrivals", "eos_unemitted",
+    "cancelled_before_its_first_token", "max_tokens_in_the_burst_behind"])
+def test_an_arrival_gets_the_same_stream_in_the_three_orders(
+        case, eos_of_the_first_row):
+    eos = eos_of_the_first_row if case == "eos_unemitted" else NEVER
+    runs = {name: _arrival_case(case, eos, **order)
+            for name, order in ORDERS.items()}
+    today, run_today = runs["today"]
+    for name in ("ahead", "admission_ahead"):
+        assert runs[name][0] == today, name
+    late = today["late"]
+    assert (len(late[0]), late[1]) == {
+        "cancelled_before_its_first_token": (0, "cancelled"),
+        "max_tokens_in_the_burst_behind": (3, "length"),
+    }.get(case, (14, "length"))
+    # which order each run took, by its records
+    assert not any(r["dispatched_ahead"] for r in run_today.records()
+                   if "dispatched_ahead" in r)
+    _, run_held = runs["ahead"]
+    assert any(r["dispatched_ahead"] for r in run_held.decode_records())
+    assert not any(r["dispatched_ahead"] for r in run_held.records("prefill"))
+    assert "admission" in {r["ahead_blocked_by"]
+                           for r in run_held.decode_records()}
+    _, run = runs["admission_ahead"]
+    records = run.records()
+    at = [r["kind"] for r in records].index("prefill", 1)  # the arrival's
+    before, prefill, behind = records[at - 1:at + 2]
+    assert prefill["dispatched_ahead"] and behind["dispatched_ahead"]
+    assert behind["kind"] == before["kind"] == "decode"
+    new_rows = 2 if case == "two_arrivals" else 1
+    assert prefill["active_slots"] == new_rows
+    # the burst behind the prefill holds the new rows beside the old
+    assert behind["active_slots"] == before["active_slots"] + new_rows
+    assert "admission" not in {r["ahead_blocked_by"]
+                               for r in run.decode_records()}
+    if case == "eos_unemitted":
+        # the row that met its EOS in the un-emitted burst held slot 0 until
+        # that burst's emit: the arrival placed ahead of it took slot 2, the
+        # one placed behind it slot 0 — and the burst behind the prefill
+        # still carried the ended row's column, which went to nobody
+        assert today["first"][1] == "stop"
+        assert list(prefill["request_ids"]) == ["2"]
+        assert list(run_today.records("prefill")[1]["request_ids"]) == ["0"]
+        assert behind["active_slots"] == 3
+    assert run.core.metrics.summary()["prefills_dispatched_ahead_total"] == 1
 
 
 def test_a_started_engine_serves_the_same_usage_in_both_orders(
         eos_inside_a_burst):
     """The same through the service layer and the loop's own thread: six
     callers on four slots, so that rows end and slots change hands while
-    bursts are in flight."""
+    bursts are in flight, and two more that come one by one while there is
+    room."""
 
-    async def serve(todays_order: bool):
+    async def serve(order: str):
         core = _core(eos_id=eos_inside_a_burst[0])
-        if todays_order:
+        if order == "today":
             core._ahead_blocker = lambda plan: "control"
+        if order == "ahead":
+            core._arrivals_ahead = lambda plan, k: None
         core.start()
         engine = Engine("debug-tiny", core, TOK)
+
+        def caller(j: int):
+            return engine.complete(_prompt(j), SamplingParams(
+                temperature=0.0 if j % 2 == 0 else 0.8,
+                seed=None if j % 2 == 0 else 100 + j,
+                max_tokens=17 + 5 * j))
+
+        async def latecomer(j: int, after_s: float):
+            await asyncio.sleep(after_s)
+            return await caller(j)
+
         try:
-            finals = await asyncio.gather(*(
-                engine.complete(_prompt(j), SamplingParams(
-                    temperature=0.0 if j % 2 == 0 else 0.8,
-                    seed=None if j % 2 == 0 else 100 + j,
-                    max_tokens=17 + 5 * j))
-                for j in range(6)))
+            finals = await asyncio.gather(
+                *(caller(j) for j in range(6)),
+                latecomer(11, 0.3), latecomer(12, 0.6))
             return ([(f.text, f.finish_reason, f.prompt_tokens,
                       f.completion_tokens) for f in finals],
                     core.metrics.summary())
         finally:
             engine.shutdown()
 
-    today, totals_today = asyncio.run(serve(True))
-    ahead, totals = asyncio.run(serve(False))
-    assert ahead == today
+    today, totals_today = asyncio.run(serve("today"))
+    held, totals_held = asyncio.run(serve("ahead"))
+    ahead, totals = asyncio.run(serve("admission_ahead"))
+    assert ahead == today and held == today
     assert totals_today["decode_bursts_dispatched_ahead_total"] == 0
+    assert totals_held["decode_bursts_dispatched_ahead_total"] > 0
     assert totals["decode_bursts_dispatched_ahead_total"] > 0
+    assert totals_today["prefills_dispatched_ahead_total"] == 0
+    assert totals_held["prefills_dispatched_ahead_total"] == 0
 
 
 # ------------------------------------------------ (b) admission is not behind
@@ -203,12 +334,18 @@ def test_an_arrival_is_prefilled_before_any_further_burst():
     # while 3 was in flight: the next record is its prefill, then a decode
     assert kinds[:at + 2] == ["prefill", "decode", "decode", "decode",
                               "prefill", "decode"]
-    assert [r.get("dispatched_ahead") for r in records[1:at]] == [
-        False, True, True]
-    after = records[at + 1]
-    assert not after["dispatched_ahead"]
-    assert after["ahead_blocked_by"] == "admission"
+    assert [r.get("dispatched_ahead") for r in records[:at]] == [
+        False, False, True, True]
+    # no burst that was not in flight when the arrival was seen runs in
+    # front of its prefill: the prefill left right after burst 3's fetch,
+    # before that burst was emitted, and the burst behind it at once
+    prefill, after = records[at], records[at + 1]
+    assert prefill["dispatched_ahead"]
+    assert after["dispatched_ahead"] and after["ahead_blocked_by"] is None
     assert after["active_slots"] == 3  # the late row decodes at once
+    assert prefill["t0_s"] - records[at - 1]["t1_s"] == pytest.approx(
+        prefill["since_prev"]["admit_s"], abs=50e-6)
+    assert after["t0_s"] == pytest.approx(prefill["t1_s"], abs=50e-6)
     # and the order resumes behind it
     assert records[at + 2]["dispatched_ahead"]
     assert len(collect(late, timeout=None)[0]) == 12
@@ -296,6 +433,90 @@ def test_what_needs_the_host_between_two_bursts_keeps_todays_order(case):
         r["ahead_blocked_by"] == case for r in records)
 
 
+def _held_arrival_run(case: str, **order):
+    """One run of a case in which an arrival cannot be placed ahead: two
+    rows decoding, the arrival while burst 3 is in flight. Returns the
+    streams and the run."""
+    kwargs: dict = {}
+    first, second = _greedy(0, 14), _seeded(1, 14, seed=31)
+    late = _seeded(2, 6, seed=4)
+    before: list[Request] = []
+    if case == "eviction":
+        # 10 pages of 8 cells: a finished request's head pins 2, the two
+        # rows hold 3 each by burst 3's fetch, and the arrival's 20 tokens
+        # and its first burst take 4 where the free list has 2 — today's
+        # order evicts the pinned head for them
+        kwargs = {"prefix_cache": True, "kv_pages": 11, "num_slots": 3,
+                  "slot_capacity": 64}
+        before = [Request(prompt_ids=_prompt(14), sampling=SamplingParams(
+            temperature=0.0, max_tokens=2))]
+        late = Request(prompt_ids=_prompt(13, 7), sampling=SamplingParams(
+            temperature=0.9, seed=4, max_tokens=6))
+    elif case == "chunked":
+        # past the largest one-shot bucket: chunks between the bursts
+        late = Request(prompt_ids=_prompt(0, 40), sampling=SamplingParams(
+            temperature=0.9, seed=4, max_tokens=6))
+    elif case == "constrained":
+        late = Request(prompt_ids=_prompt(2), sampling=SamplingParams(
+            temperature=0.0, max_tokens=24,
+            constraint={"type": "json_schema", "schema": SCHEMA}))
+        kwargs = {"eos_id": TOK.eos_id}
+    elif case == "full_house":
+        kwargs = {"num_slots": 2}
+    core = _core(**kwargs)
+    if case == "constrained":
+        core.constraint_compiler = ConstraintCompiler(TOK, CFG.vocab_size)
+    for r in before:
+        core.pending.put(r)
+        Inline(core).run()  # alone, to its end: its head is pinned
+    run = Inline(core, **order)
+    run.first_seq = core.step_stats.seq + 1  # the two rows' group prefill
+    core.pending.put(first)
+    core.pending.put(second)
+    run.during[3] = [lambda: core.pending.put(late)]
+    run.run()
+    return [collect(r, timeout=None) for r in (first, second, late)], run
+
+
+@pytest.mark.parametrize("case", ["eviction", "chunked", "constrained",
+                                  "full_house"])
+def test_an_arrival_that_cannot_be_placed_ahead_keeps_todays_order(case):
+    today, run_today = _held_arrival_run(case, **ORDERS["today"])
+    streams, run = _held_arrival_run(case, **ORDERS["admission_ahead"])
+    assert streams == today
+    assert all(finish in ("length", "stop") for _t, finish in streams)
+    # no prefill left ahead, and the bursts say what held them: the arrival
+    totals = run.core.metrics.summary()
+    assert totals["prefills_dispatched_ahead_total"] == 0
+    assert not any(r.get("dispatched_ahead") for r in run.records("prefill"))
+    records = [r for r in run.records() if r["seq"] >= run.first_seq]
+    kinds = [r["kind"] for r in records]
+    assert kinds[:4] == ["prefill", "decode", "decode", "decode"]
+    fourth = next(r for r in records[4:] if r["kind"] == "decode")
+    assert fourth["ahead_blocked_by"] == "admission"
+    # ... and the cycle was the parent's: the same steps in the same order,
+    # with the same rows
+    recs_today = [r for r in run_today.records()
+                  if r["seq"] >= run_today.first_seq]
+    assert [(r["kind"], r["active_slots"], r["tokens"])
+            for r in records] == [(r["kind"], r["active_slots"], r["tokens"])
+                                  for r in recs_today]
+    blocked = [r["ahead_blocked_by"] for r in records if r["kind"] == "decode"]
+    if case == "eviction":
+        assert totals["prefix_evictions_total"] == 1
+        assert run_today.core.metrics.summary()["prefix_evictions_total"] == 1
+    elif case == "chunked":
+        # the arrival's prompt goes in by chunks between the bursts
+        assert "prefilling" in blocked
+        assert kinds.count("prefill") == 1 + 2  # 40 tokens by chunks of 32
+    elif case == "constrained":
+        assert "constraint" in blocked
+    elif case == "full_house":
+        # held from burst 4 until a row ends and its emit frees the slot
+        assert blocked.count("admission") >= 2
+        assert records[-1]["active_slots"] == 1
+
+
 def test_a_verify_step_is_not_a_burst_and_nothing_is_prepared_for_it():
     """With drafts that match, the step is a `verify`: no burst counters."""
     core = _core(spec_decode=True)
@@ -305,13 +526,59 @@ def test_a_verify_step_is_not_a_burst_and_nothing_is_prepared_for_it():
                                                      max_tokens=20)))
     run.run()
     records = core.step_stats.snapshot(limit=512)["records"]
+    assert {r["kind"] for r in records} == {"prefill", "decode", "verify"}
     for r in records:
+        # a dense burst's field, and a one-shot prefill group's
         assert ("dispatched_ahead" in r) == (
-            r["kind"] == "decode"), r["kind"]
+            r["kind"] != "verify"), r["kind"]
     assert not any(r.get("dispatched_ahead") for r in records)
 
 
 # ------------------------------------------------ (d) the records tile
+
+
+def _assert_records_tile(records: list[dict]) -> None:
+    for r in records:
+        # a record's spans lie end to end and sum to its wall time
+        at = 0.0
+        for _name, offset, dur in r["spans"]:
+            assert offset == pytest.approx(at, abs=3e-6)
+            at += dur
+        assert at == pytest.approx(r["wall_s"], abs=5e-6)
+        # legacy phases: their sum is the wall time and the admission, and
+        # host work with a program of the loop on the device is `compute`
+        assert r["total_s"] == pytest.approx(
+            r["wall_s"] + r["since_prev"]["admit_s"], abs=1e-5)
+        inflight = sum(d for n, _a, d in r["spans"] if n in INFLIGHT_SPANS)
+        waited = sum(d for n, _a, d in r["spans"] if n == "compute")
+        assert r["phases_s"]["compute"] == pytest.approx(
+            waited + inflight, abs=5e-6)
+        assert r["host_cpu_s"] <= r["wall_s"] - waited + 1e-3
+    for prev, cur in zip(records, records[1:]):
+        # consecutive records of the loop do not overlap, and what lies
+        # between them is the next one's gap
+        assert cur["seq"] == prev["seq"] + 1
+        assert cur["t0_s"] >= prev["t1_s"] - 2e-6
+        assert prev["t1_s"] + sum(cur["since_prev"].values()) == \
+            pytest.approx(cur["t0_s"], abs=50e-6)
+
+
+def _assert_totals_add_up(after: dict, records: list[dict]) -> None:
+    """The counters of the orders against the records of a whole run."""
+    decode = [r for r in records if r["kind"] == "decode"]
+    prefill = [r for r in records if r["kind"] == "prefill"]
+    assert set(after["decode_bursts_not_ahead_total"]) == set(AHEAD_BLOCKERS)
+    assert after["decode_bursts_total"] == len(decode)
+    assert after["decode_bursts_dispatched_ahead_total"] == sum(
+        r["dispatched_ahead"] for r in decode)
+    assert (after["decode_bursts_dispatched_ahead_total"]
+            + sum(after["decode_bursts_not_ahead_total"].values())
+            == after["decode_bursts_total"])
+    for reason, n in after["decode_bursts_not_ahead_total"].items():
+        assert n == sum(r["ahead_blocked_by"] == reason for r in decode)
+    assert after["prefill_dispatches_total"] == len(prefill)
+    assert after["prefills_dispatched_ahead_total"] == sum(
+        r["dispatched_ahead"] for r in prefill)
 
 
 def test_records_tile_the_loops_time_and_the_totals_add_up():
@@ -332,45 +599,67 @@ def test_records_tile_the_loops_time_and_the_totals_add_up():
     records = core.step_stats.snapshot(limit=512)["records"][::-1]
     decode = [r for r in records if r["kind"] == "decode"]
     assert any(r["dispatched_ahead"] for r in decode)
-    for r in records:
-        # a record's spans lie end to end and sum to its wall time
-        at = 0.0
-        for _name, offset, dur in r["spans"]:
-            assert offset == pytest.approx(at, abs=3e-6)
-            at += dur
-        assert at == pytest.approx(r["wall_s"], abs=5e-6)
-        # legacy phases: their sum is the wall time and the admission, and
-        # host work with a burst in flight is `compute`
-        assert r["total_s"] == pytest.approx(
-            r["wall_s"] + r["since_prev"]["admit_s"], abs=1e-5)
-        inflight = sum(d for n, _a, d in r["spans"] if n in INFLIGHT_SPANS)
-        waited = sum(d for n, _a, d in r["spans"] if n == "compute")
-        assert r["phases_s"]["compute"] == pytest.approx(
-            waited + inflight, abs=5e-6)
-        assert r["host_cpu_s"] <= r["wall_s"] - waited + 1e-3
-    for prev, cur in zip(records, records[1:]):
-        # consecutive records of the loop do not overlap, and what lies
-        # between them is the next one's gap
-        assert cur["seq"] == prev["seq"] + 1
-        assert cur["t0_s"] >= prev["t1_s"] - 2e-6
-        assert prev["t1_s"] + sum(cur["since_prev"].values()) == \
-            pytest.approx(cur["t0_s"], abs=50e-6)
+    _assert_records_tile(records)
     # every second of the loop thread is in one bucket
     delta = {b: after["loop_seconds_total"]["main"][b]
              - before["loop_seconds_total"]["main"][b] for b in LOOP_BUCKETS}
     assert sum(delta.values()) == pytest.approx(t1 - t0, rel=0.02)
     steps = sum(r["wall_s"] for r in records)
     assert delta["step"] == pytest.approx(steps, rel=0.02)
-    # the two totals and the reasons add up, to the records
-    assert set(after["decode_bursts_not_ahead_total"]) == set(AHEAD_BLOCKERS)
-    assert after["decode_bursts_total"] == len(decode)
-    assert after["decode_bursts_dispatched_ahead_total"] == sum(
-        r["dispatched_ahead"] for r in decode)
-    assert (after["decode_bursts_dispatched_ahead_total"]
-            + sum(after["decode_bursts_not_ahead_total"].values())
-            == after["decode_bursts_total"])
-    for reason, n in after["decode_bursts_not_ahead_total"].items():
-        assert n == sum(r["ahead_blocked_by"] == reason for r in decode)
+    # the totals and the reasons add up, to the records
+    _assert_totals_add_up(after, records)
     text = metrics.render(queue_depth=0, active_slots=0, num_slots=4)
     assert (f"llmlb_engine_decode_bursts_total {len(decode)}\n") in text
     assert 'llmlb_engine_decode_bursts_not_ahead_total{reason="first"}' in text
+    assert "llmlb_engine_prefills_dispatched_ahead_total " in text
+    assert "llmlb_engine_prefill_dispatches_total " in text
+
+
+def test_records_tile_with_a_prefill_between_two_bursts_that_left_ahead():
+    """Admission ahead: the predecessor's record, the prefill's and the
+    burst's end and begin at one stamp each, the placing between the first
+    two is the prefill's `admit`, and nothing of the three is counted twice."""
+    core = _core()
+    run = Inline(core)
+    clock = core._clock()  # the loop's clock: made before the first reading
+    core.pending.put(_greedy(0, 40))
+    core.pending.put(_seeded(1, 40, seed=2))
+    run.during[3] = [lambda: core.pending.put(_seeded(2, 13, seed=6))]
+    run.during[6] = [lambda: core.pending.put(_greedy(3, 9))]
+    t0, before = time.perf_counter(), dict(clock.snapshot())
+    run.run()
+    t1, after = time.perf_counter(), dict(clock.snapshot())
+    records = run.records()
+    _assert_records_tile(records)
+    kinds = [r["kind"] for r in records]
+    ahead = [i for i, r in enumerate(records)
+             if r["kind"] == "prefill" and r["dispatched_ahead"]]
+    assert len(ahead) == 2
+    for at in ahead:
+        before_it, prefill, behind = records[at - 1:at + 2]
+        assert kinds[at - 1] == kinds[at + 1] == "decode"
+        assert before_it["dispatched_ahead"] and behind["dispatched_ahead"]
+        names = [n for n, _a, _d in prefill["spans"]]
+        assert names == ["dispatch", "activate_inflight"]
+        # nothing between the three but the placing, which is `admit`
+        gap = prefill["since_prev"]
+        assert gap["admit_s"] > 0
+        assert sum(gap.values()) - gap["admit_s"] < 200e-6  # the close
+        assert sum(behind["since_prev"].values()) < 50e-6
+        assert [n for n, _a, _d in behind["spans"]][:3] == [
+            "dispatch_inflight", "emit_inflight", "host_sync_inflight"]
+        # the predecessor has no `emit` of its own: it is in `behind`
+        assert [n for n, _a, _d in before_it["spans"]][-1] == "fetch"
+        # legacy phases of the prefill: the placing is its plan, the
+        # activation behind the dispatch is compute, nothing is emit
+        assert prefill["phases_s"]["plan"] == pytest.approx(gap["admit_s"])
+        assert prefill["phases_s"]["emit"] == 0.0
+        assert prefill["phases_s"]["compute"] == pytest.approx(
+            prefill["wall_s"] - prefill["phases_s"]["dispatch"], abs=5e-6)
+    delta = {b: after[b] - before[b] for b in LOOP_BUCKETS}
+    assert sum(delta.values()) == pytest.approx(t1 - t0, rel=0.02)
+    assert delta["step"] == pytest.approx(
+        sum(r["wall_s"] for r in records), rel=0.02)
+    _assert_totals_add_up(core.metrics.summary(), records)
+    # the prefill histogram has every group, dispatched ahead or not
+    assert core.metrics.prefill_step.n == kinds.count("prefill")

@@ -25,6 +25,9 @@ def _burn(seconds: float) -> None:
 
 
 def test_cpu_seconds_by_thread_name_class():
+    # what threads of earlier tests in this process hold already (an engine
+    # some test left running keeps its bridge threads): read against it
+    base = hoststats.cpu_seconds(THREAD_CLASSES, current="http_loop")
     stop = threading.Event()
 
     def work():
@@ -42,9 +45,10 @@ def test_cpu_seconds_by_thread_name_class():
         out = hoststats.cpu_seconds(THREAD_CLASSES, current="http_loop")
         assert set(out) == {"process", "step_loop", "http_loop",
                             "event_bridge", "prewarm", "other"}
-        assert out["step_loop"] >= 0.045
-        assert out["event_bridge"] >= 0.09  # two threads of the pool
-        assert out["prewarm"] == 0.0  # no such thread lives
+        assert out["step_loop"] - base["step_loop"] >= 0.045
+        # two threads of the pool
+        assert out["event_bridge"] - base["event_bridge"] >= 0.09
+        assert out["prewarm"] == base["prewarm"]  # no such thread started
         # the calling thread is the class it says it is
         assert out["http_loop"] == pytest.approx(me0, abs=0.05)
         # `other` holds the thread no class names, and is never negative
@@ -59,7 +63,8 @@ def test_cpu_seconds_by_thread_name_class():
     # the threads have ended: their seconds are no class's any more, nothing
     # raises, and `other` keeps them (process time does not fall)
     after = hoststats.cpu_seconds(THREAD_CLASSES, current="http_loop")
-    assert after["step_loop"] == 0.0 and after["event_bridge"] == 0.0
+    assert after["step_loop"] <= base["step_loop"] + 0.02
+    assert after["event_bridge"] <= base["event_bridge"] + 0.02
     assert after["other"] >= out["other"] and after["process"] >= out["process"]
 
 

@@ -246,8 +246,7 @@ def test_a_handover_closes_a_step_where_the_next_begins(monkeypatch):
         2.02,   # mark(host_sync_inflight)
         2.03,   # mark(compute)
         2.13,   # mark(fetch)
-        2.14,   # handover: the first step's t1 ...
-        2.14,   # ... and the second's t0 (dispatch)
+        2.14,   # handover: the first step's t1 and the second's t0
         2.15,   # mark(emit_inflight)
         2.17,   # mark(host_sync_inflight)
         2.18,   # mark(compute)
@@ -291,6 +290,82 @@ def test_a_handover_closes_a_step_where_the_next_begins(monkeypatch):
     assert b["host_cpu_s"] == pytest.approx(0.04 + 0.02)
     assert clock.acc["step"] == pytest.approx(0.30)
     assert clock.acc["record"] == pytest.approx(0.01)
+
+
+def test_a_prefill_dispatched_ahead_lies_between_two_bursts(monkeypatch):
+    """Admission ahead (scheduler._admit_ahead): the fetched burst's step is
+    closed, the arrival is placed in `admit`, its prefill's step begins
+    behind the closed one (LoopClock.begin(after=)) and is handed over to
+    the burst behind it. The three records tile, their seqs follow each
+    other before any is observed, and the in-flight spans of the prefill
+    and of the burst are `compute` in the legacy phases."""
+    rec, clock = _stamped(monkeypatch, [
+        1.0, 2.0,  # the recorder's anchor; the clock is made
+        2.0,    # begin(dispatch): the burst that will be fetched, t0
+        2.01,   # mark(compute)
+        2.11,   # mark(fetch)
+        2.12,   # close: its t1 — an arrival can be placed
+        2.12,   # switch(admit)
+        2.125,  # begin(dispatch, after=): 5 ms of placing; the prefill's t0
+        2.130,  # mark(activate_inflight), from inside _activate_group
+        2.135,  # handover: the prefill's t1 and the next burst's t0
+        2.137,  # mark(emit_inflight)
+        2.140,  # mark(host_sync_inflight)
+        2.142,  # mark(compute)
+        2.242,  # mark(fetch)
+        2.243,  # mark(emit)
+        2.245,  # close
+        2.246,  # resume
+    ])
+    fetched = clock.begin("dispatch")
+    fetched.mark("compute")
+    fetched.mark("fetch")
+    clock.close(fetched, "decode")
+    clock.switch("admit")
+    prefill = clock.begin("dispatch", after=fetched)
+    clock.mark("activate_inflight")
+    behind = clock.handover(prefill, "prefill", "dispatch_inflight")
+    assert (prefill.seq, behind.seq) == (fetched.seq + 1, fetched.seq + 2)
+    behind.mark("emit_inflight")
+    # both closed records are observed inside the burst behind them
+    rec.observe("decode", fetched.phases(), span=fetched)
+    rec.observe("prefill", prefill.phases(), span=prefill)
+    for name in ("host_sync_inflight", "compute", "fetch", "emit"):
+        behind.mark(name)
+    clock.close(behind, "decode")
+    rec.observe("decode", behind.phases(), span=behind)
+    clock.resume(behind)
+    c, b, a = rec.snapshot()["records"]
+    assert [r["seq"] for r in (a, b, c)] == [fetched.seq, prefill.seq,
+                                             behind.seq]
+    assert (a["t1_s"], b["t0_s"], b["t1_s"], c["t0_s"]) == (
+        2.12, 2.125, 2.135, 2.135)
+    assert b["since_prev"] == pytest.approx({
+        "admit_s": 0.005, "control_s": 0.0, "record_s": 0.0, "idle_s": 0.0,
+        "other_s": 0.0})
+    assert sum(c["since_prev"].values()) == 0.0
+    assert [n for n, _a, _d in b["spans"]] == ["dispatch",
+                                               "activate_inflight"]
+    assert [n for n, _a, _d in c["spans"]] == [
+        "dispatch_inflight", "emit_inflight", "host_sync_inflight",
+        "compute", "fetch", "emit"]
+    assert all(n in SPANS for r in (a, b, c) for n, _a, _d in r["spans"])
+    assert {"activate_inflight", "dispatch_inflight"} <= set(INFLIGHT_SPANS)
+    # the legacy-phases rule: the placing is the prefill's plan, its
+    # activation behind the dispatch is compute (the prefill computes), and
+    # so is the call of the burst behind both
+    assert b["phases_s"] == pytest.approx({
+        "plan": 0.005, "draft": 0.0, "host_sync": 0.0, "dispatch": 0.005,
+        "compute": 0.005, "fetch": 0.0, "emit": 0.0})
+    assert c["phases_s"] == pytest.approx({
+        "plan": 0.0, "draft": 0.0, "host_sync": 0.0, "dispatch": 0.0,
+        "compute": 0.002 + 0.003 + 0.002 + 0.100, "fetch": 0.001,
+        "emit": 0.002})
+    assert b["total_s"] == pytest.approx(b["wall_s"] + 0.005)
+    # the loop resumes where the first burst's step was opened from
+    assert clock._bucket == "other"
+    assert clock.acc["admit"] == pytest.approx(0.005)
+    assert clock.acc["step"] == pytest.approx(0.12 + 0.01 + 0.11)
 
 
 def test_an_abandoned_step_leaves_no_record_and_loses_no_time(monkeypatch):
@@ -526,14 +601,21 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
             r["wall_s"] + r["since_prev"]["admit_s"], abs=1e-5)
         assert r["phases_s"]["plan"] == pytest.approx(
             r["since_prev"]["admit_s"], abs=2e-6)
+    by_seq = {r["seq"]: r for r in records}
     for r, nxt in zip(decode, decode[1:] + [None]):
-        # the two orders of a decode cycle (docs/scheduling.md): today's,
-        # and the one of a burst that left before its predecessor was
-        # emitted; a burst whose successor left ahead has no `emit` of its
-        # own, the successor's `emit_inflight` holds it
+        # the three orders of a decode cycle (docs/scheduling.md): today's,
+        # the one of a burst that left before its predecessor was emitted,
+        # and the one of a burst behind a prefill that did; a burst whose
+        # successor left ahead has no `emit` of its own, the successor's
+        # `emit_inflight` holds it
         names = [n for n, _a, _d in r["spans"]]
-        head = (["dispatch", "emit_inflight"] if r["dispatched_ahead"]
+        before = by_seq.get(r["seq"] - 1)
+        behind_a_prefill = bool(before and before["kind"] == "prefill"
+                                and before.get("dispatched_ahead"))
+        head = (["dispatch_inflight", "emit_inflight"] if behind_a_prefill
+                else ["dispatch", "emit_inflight"] if r["dispatched_ahead"]
                 else ["host_sync", "dispatch"])
+        assert not behind_a_prefill or r["dispatched_ahead"]
         followed = nxt is not None and nxt["dispatched_ahead"]
         assert names == head + ["host_sync_inflight", "compute", "fetch"] + (
             [] if followed else ["emit"])
@@ -563,6 +645,74 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
             r["phases_s"]["emit"] - _span_sum(r, "activate"), abs=3e-6)
         assert _span_sum(r, "emit") < r["phases_s"]["emit"] or \
             r not in activating
+        # a one-shot group says which order it took; a chunk has none
+        assert r.get("dispatched_ahead") == (
+            False if r is prefill[0] else None)
+
+
+async def test_a_served_arrival_is_prefilled_ahead_under_inflight_spans(
+        served_engine):
+    """A request that comes while another decodes and a slot is free: its
+    prefill leaves ahead, and the span names of the prefill and of the
+    burst behind it follow the legacy-phases rule — what the host does with
+    the prefill on the device is `compute`, not `emit` or `dispatch`."""
+    from llmlb_tpu.engine.scheduler import Request, SamplingParams
+    from tests.support import collect
+
+    engine = served_engine
+    core = engine.core
+    start = core.step_stats.seq
+    ahead_before = core.metrics.summary()["prefills_dispatched_ahead_total"]
+    # the second request is submitted from the loop's own thread while the
+    # first one's first burst is in flight (_prepare_burst runs there), so
+    # that the fetch behind it finds it whatever this machine's load
+    late_request = Request(prompt_ids=[8, 2, 3, 4, 5], sampling=SamplingParams(
+        temperature=0.0, max_tokens=8))
+    prepare, pending = core._prepare_burst, [late_request]
+
+    def prepare_and_submit(rows, k):
+        if pending:
+            core.submit(pending.pop())
+        return prepare(rows, k)
+
+    core._prepare_burst = prepare_and_submit
+    try:
+        await engine.complete([9, 2, 3, 4, 5], SamplingParams(
+            temperature=0.0, max_tokens=56))
+        assert len(collect(late_request)[0]) == 8
+    finally:
+        core._prepare_burst = prepare
+    records = [r for r in _oldest_first(core) if r["seq"] > start]
+    late = [r for r in records if r["kind"] == "prefill"][1]
+    assert late["dispatched_ahead"]
+    assert core.metrics.summary()["prefills_dispatched_ahead_total"] == \
+        ahead_before + 1
+    behind = next(r for r in records if r["seq"] == late["seq"] + 1)
+    fetched = next(r for r in records if r["seq"] == late["seq"] - 1)
+    assert behind["kind"] == fetched["kind"] == "decode"
+    assert behind["dispatched_ahead"] and behind["active_slots"] == 2
+    assert [n for n, _a, _d in late["spans"]] == ["dispatch",
+                                                  "activate_inflight"]
+    assert [n for n, _a, _d in behind["spans"]][:2] == [
+        "dispatch_inflight", "emit_inflight"]
+    assert [n for n, _a, _d in fetched["spans"]][-1] == "fetch"
+    assert set(n for r in records for n, _a, _d in r["spans"]) <= set(SPANS)
+    # legacy phases: no emit and no activate on the prefill's record, its
+    # in-flight activation under compute; no dispatch on the burst's
+    assert late["phases_s"]["emit"] == 0.0
+    assert late["phases_s"]["compute"] == pytest.approx(
+        _span_sum(late, "activate_inflight"), abs=2e-6)
+    assert late["phases_s"]["plan"] == pytest.approx(
+        late["since_prev"]["admit_s"], abs=2e-6)
+    assert late["since_prev"]["admit_s"] > 0
+    assert behind["phases_s"]["dispatch"] == 0.0
+    assert behind["phases_s"]["compute"] == pytest.approx(
+        sum(_span_sum(behind, n) for n in ("compute",) + INFLIGHT_SPANS),
+        abs=3e-6)
+    # the three records end and begin at one stamp each, less the placing
+    assert late["t0_s"] - fetched["t1_s"] == pytest.approx(
+        sum(late["since_prev"].values()), abs=5e-6)
+    assert behind["t0_s"] == late["t1_s"]
 
 
 async def test_no_time_is_lost_between_consecutive_records(served_engine):
@@ -575,12 +725,13 @@ async def test_no_time_is_lost_between_consecutive_records(served_engine):
         assert prev["t1_s"] + sum(cur["since_prev"].values()) == \
             pytest.approx(cur["t0_s"], abs=50e-6)
         # closing a record costs something, and the next one says what:
-        # in its gap, or, where it left ahead, in its `emit_inflight`
-        if cur.get("dispatched_ahead"):
+        # in its gap, or, where it left ahead, in its `emit_inflight` (a
+        # prefill that left ahead: in that of the burst behind it)
+        if cur.get("dispatched_ahead") and cur["kind"] == "decode":
             assert cur["since_prev"]["record_s"] == 0.0
-            assert cur["t0_s"] - prev["t1_s"] < 50e-6
+            assert cur["t0_s"] == prev["t1_s"]  # one clock read
             assert _span_sum(cur, "emit_inflight") > 0
-        else:
+        elif not cur.get("dispatched_ahead"):
             assert cur["since_prev"]["record_s"] > 0
 
 

@@ -109,13 +109,17 @@ class InlineLoop:
     and their order are the test's: `run` calls the loop's own iteration
     (`EngineCore._loop_once`) until one does no work, with `_running` set so
     that the loop's own state decides the order of a decode cycle, as in a
-    started engine (docs/scheduling.md "The two orders of a decode cycle").
+    started engine (docs/scheduling.md "The three orders of a decode cycle").
     `during[n]` is a list of calls made while the n-th dense burst is in
     flight — `_prepare_burst`, which every dense burst calls between its
     dispatch and the wait for it. `todays_order` patches the predicate to
-    "not now": the parent's cycle, step for step."""
+    "not now": the parent's cycle, step for step. `admission_ahead=False`
+    patches the other predicate to "no arrival can be placed ahead": a burst
+    may leave before its predecessor's emit (PR 39's order), an arrival is
+    served behind that emit."""
 
-    def __init__(self, core, *, todays_order: bool = False):
+    def __init__(self, core, *, todays_order: bool = False,
+                 admission_ahead: bool = True):
         self.core = core
         self.bursts = 0
         self.during: dict[int, list] = {}
@@ -132,6 +136,8 @@ class InlineLoop:
         core._prepare_burst = prepare_and_tell
         if todays_order:
             core._ahead_blocker = lambda plan: "control"
+        if not admission_ahead:
+            core._arrivals_ahead = lambda plan, k: None
 
     def run(self, iterations: int = 400) -> None:
         core = self.core
@@ -143,9 +149,13 @@ class InlineLoop:
                 return
         raise AssertionError("the requests did not finish")
 
-    def decode_records(self) -> list[dict]:
+    def records(self, kind: str | None = None) -> list[dict]:
+        """The step records, oldest first; of one kind if given."""
         records = self.core.step_stats.snapshot(limit=512)["records"][::-1]
-        return [r for r in records if r["kind"] == "decode"]
+        return [r for r in records if kind in (None, r["kind"])]
+
+    def decode_records(self) -> list[dict]:
+        return self.records("decode")
 
 
 # ------------------------------------------------- family-level KV fixtures
