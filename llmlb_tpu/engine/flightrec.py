@@ -9,6 +9,14 @@ engine — every lifecycle edge the scheduler crosses:
   queued           landed on a priority-class queue (class + depth)
   prefill_chunk    one prompt-KV fill dispatch (tokens, cached-prefix
                    tokens reused from the prefix cache)
+  first_token      the request's first token reached the host: its way in,
+                   cut into stages on the step loop's clock (accept, inbox,
+                   place, prefill, first_fetch — seconds each, a stage it
+                   never passed absent), with the prefill dispatches it took
+                   (chunks) and the seq of the step records of its first
+                   prefill (prefill_seq) and of the fetch that brought the
+                   token (fetch_seq). Once a request; the stages but accept
+                   sum to `finished`'s ttft_s (docs/tracing.md)
   staged           split-mode prefill complete, first token staged for a
                    decode-pool adoption (disagg, in-process)
   handoff_emitted  committed tokens wrapped into a cross-process handoff
@@ -75,7 +83,8 @@ from collections import OrderedDict, deque
 
 # The lifecycle taxonomy (docs/tracing.md documents each event's fields).
 EVENTS = (
-    "admitted", "queued", "prefill_chunk", "staged", "handoff_emitted",
+    "admitted", "queued", "prefill_chunk", "first_token", "staged",
+    "handoff_emitted",
     "adopted", "parked", "resumed", "kv_shipped", "kv_spilled",
     "kv_restored", "lora_acquire", "spec_accept", "commit",
     "shed", "finished", "errored", "slow_step",

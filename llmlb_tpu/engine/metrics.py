@@ -291,7 +291,7 @@ class EngineMetrics:
         # Step-phase time breakdown (engine/stepstats.py): one histogram per
         # phase of the step loop, fed once per dispatch, plus the slow-step
         # anomaly counter. Lazily keyed so only phases that occur render.
-        from llmlb_tpu.engine.stepstats import PHASES
+        from llmlb_tpu.engine.stepstats import PHASES, WAY_IN
 
         self.step_phase: dict[str, Histogram] = {
             p: Histogram(PHASE_BUCKETS) for p in PHASES
@@ -309,12 +309,25 @@ class EngineMetrics:
         # the process's collector clock (hoststats.py).
         self.stream = StreamStats()
         self.gc = watch_gc()
+        # A request's way in (docs/tracing.md): seconds by stage
+        # (stepstats.WAY_IN) summed over the requests whose first token
+        # reached the host, and their count; written by the step loop at
+        # each first token (record_first_token).
+        self.way_in_seconds_total = dict.fromkeys(WAY_IN, 0.0)
+        self.way_in_requests_total = 0
 
     # ------------------------------------------------------------ recorders
 
-    def record_ttft(self, seconds: float) -> None:
+    def record_first_token(self, ttft_s: float,
+                           stages: dict[str, float]) -> None:
+        """A request's first token reached the host: its time to first
+        token, and its way in by stage (a stage it never passed is not
+        among `stages`)."""
         with self._lock:
-            self.ttft.observe(seconds)
+            self.ttft.observe(ttft_s)
+            self.way_in_requests_total += 1
+            for stage, seconds in stages.items():
+                self.way_in_seconds_total[stage] += seconds
 
     def record_itl(self, seconds: float) -> None:
         with self._lock:
@@ -609,6 +622,11 @@ class EngineMetrics:
                 "loop_seconds_total": loop_seconds,
                 "compile": compiled,
                 **host,
+                "way_in": {
+                    "requests_total": self.way_in_requests_total,
+                    "seconds_total": {
+                        stage: round(v, 6)
+                        for stage, v in self.way_in_seconds_total.items()}},
                 "requests_total": self.requests_total,
                 "tokens_total": self.tokens_total,
                 "errors_total": self.errors_total,
@@ -1068,6 +1086,15 @@ class EngineMetrics:
             lines.append(f"# TYPE {name} histogram")
             for phase, hist in self.step_phase.items():
                 _render_histogram(lines, name, hist, label=f'phase="{phase}"')
+            # a request's way in, by stage (docs/tracing.md)
+            lines.append("# TYPE llmlb_engine_way_in_requests_total counter")
+            lines.append("llmlb_engine_way_in_requests_total "
+                         f"{self.way_in_requests_total}")
+            lines.append("# TYPE llmlb_engine_way_in_seconds_total counter")
+            for stage, seconds in self.way_in_seconds_total.items():
+                lines.append(
+                    f'llmlb_engine_way_in_seconds_total{{stage="{stage}"}} '
+                    f'{round(seconds, 6)}')
         # where the step loops' time went, and the programs built: read
         # outside the lock (each has its own)
         lines.append("# TYPE llmlb_engine_loop_seconds_total counter")

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from llmlb_tpu.engine import compilelog
+from llmlb_tpu.engine import compilelog, stepstats
 from llmlb_tpu.engine.kv_offload import KVOffloadTier
 from llmlb_tpu.engine.kv_transfer import (
     KV_WIRE_VERSION, KVPages, KVWireHeader, kv_compat_reason,
@@ -58,7 +58,7 @@ from llmlb_tpu.engine.prefix_cache import PrefixCache, PrefixEntry
 from llmlb_tpu.engine.programs import StepPrograms
 from llmlb_tpu.engine.flightrec import FlightRecorder, gateway_rid
 from llmlb_tpu.engine.stepstats import LoopClock, StepRecorder, StepSpan
-from llmlb_tpu.engine.streamstats import EventQueue
+from llmlb_tpu.engine.streamstats import EventQueue, first_token_annotation
 from llmlb_tpu.models import family_for
 from llmlb_tpu.models.llama import LlamaConfig, Params
 from llmlb_tpu.ops.grammar import GrammarTables
@@ -236,7 +236,34 @@ class Request:
     # ("error", msg). event_tokens reads one. Each is stamped at its put
     # (engine/streamstats.py), and handed to the consumer as it was put
     events: EventQueue = dataclasses.field(default_factory=EventQueue)
-    submitted_at: float = dataclasses.field(default_factory=time.monotonic)
+    # The request's way in (docs/tracing.md "A request's way in"): the
+    # instant each stage of its time to first token ENDS, all read from
+    # stepstats._now (the clock of every span), each once, where the work
+    # happens. stepstats.way_in_stages cuts them into stages; a stage this
+    # process never ran (a restored request has no prefill) has no stamp
+    # and is absent there. A parked, resumed or adopted request keeps the
+    # stamps it has.
+    #   received_at    the HTTP handler's entry (service.RECEIVED_AT; None
+    #                  for a request that came in no handler)
+    #   submitted_at   EngineCore.submit's `admitted` (the construction,
+    #                  until then)
+    #   taken_at       _drain_pending took it off the inbox (`queued`)
+    #   prefill_at     its first prefill dispatch began (that step's t0);
+    #                  prefill_seq is the step's seq, prefill_chunks the
+    #                  dispatches it took, cached_tokens what a prefix hit
+    #                  spared it
+    #   activated_at   the host knows its prompt filled and has issued its
+    #                  activation (_stamp_activated)
+    #   first_token_at the first token reached the host (_emit)
+    received_at: float | None = None
+    submitted_at: float = dataclasses.field(
+        default_factory=lambda: stepstats._now())
+    taken_at: float | None = None
+    prefill_at: float | None = None
+    prefill_seq: int | None = None
+    prefill_chunks: int = 0
+    cached_tokens: int = 0
+    activated_at: float | None = None
     first_token_at: float | None = None
     finished_at: float | None = None
     # Set by the consumer (stop hit / client gone); the step loop frees the slot
@@ -273,7 +300,7 @@ class Request:
         dl = self.sampling.deadline_ms
         if dl is None:
             return False
-        return ((now if now is not None else time.monotonic())
+        return ((now if now is not None else stepstats._now())
                 > self.submitted_at + float(dl) / 1000.0)
 
 
@@ -785,6 +812,11 @@ class EngineCore:
         # The dense decode burst the device holds and the host has not
         # fetched, at most ONE: set at its dispatch, cleared at its fetch.
         self._in_flight: _Burst | None = None
+        # the way in (_first_token): the seq of the step whose fetch is being
+        # delivered, and the entries of the requests whose FIRST token it
+        # brought, which its record takes as `first_tokens` (_observe_step)
+        self._fetch_seq = 0
+        self._first_tokens: list[dict] = []
         # Cancellations take effect ONLY via the plan in multihost mode: the
         # live .cancelled flag flips at arbitrary times on the leader (HTTP
         # thread), and acting on it directly would make hosts dispatch
@@ -1212,6 +1244,9 @@ class EngineCore:
         self.prepare_lora(request)
         with self._lock:
             self.total_requests += 1
+        # the way in: `accept` ends and `inbox` begins at this read, and
+        # time to first token is counted from it
+        request.submitted_at = stepstats._now()
         self._fr_emit(request, "admitted", prompt_tokens=n,
                       queue_depth=self.pending.qsize())
         if self.coordinator is not None:
@@ -1670,6 +1705,11 @@ class EngineCore:
             self.metrics.record_decode_burst(burst.blocked_by)
         if ahead is not None:
             extra["dispatched_ahead"] = ahead
+        if self._first_tokens:
+            # the requests whose first token this step's fetch brought
+            # (_first_token); absent on every other record
+            extra["first_tokens"] = self._first_tokens
+            self._first_tokens = []
         slow = self.step_stats.observe(kind, phases,
                                        active_slots=active_slots,
                                        tokens=tokens,
@@ -1732,6 +1772,7 @@ class EngineCore:
                 r = self.pending.get_nowait()
             except queue.Empty:
                 return
+            r.taken_at = stepstats._now()  # the way in: `inbox` ends
             cls = self._priority_of(r)
             self._class_queues[cls].append(r)
             self._fr_emit(r, "queued", cls=PRIORITY_NAMES[cls],
@@ -1813,7 +1854,7 @@ class EngineCore:
         slot = self.slots[slot_id]
         request = slot.request
         assert request is not None
-        request.finished_at = time.monotonic()
+        request.finished_at = stepstats._now()
         request.events.put(("done", reason))
         self.metrics.record_request_done(reason)
         self._fr_emit(request, "finished", reason=reason,
@@ -2090,7 +2131,7 @@ class EngineCore:
                         "%s at %d tokens", request.request_id,
                         int(self._seq_lens[i]),
                     )
-                    request.finished_at = time.monotonic()
+                    request.finished_at = stepstats._now()
                     request.events.put(("done", "length"))
                     self.metrics.record_request_done("length")
                     self._fr_emit(request, "finished", reason="length",
@@ -2309,6 +2350,8 @@ class EngineCore:
         # samples, which never happened here)
         slot.first_pending = False
         request.parked = None
+        # no prefill: `place` and `prefill` stay absent from its way in
+        self._stamp_activated((request,), stepstats._now())
         self.metrics.record_resume()
         self.metrics.record_kv_restore(kvp.nbytes)
         self._fr_emit(request, "kv_restored", source=kvp.source,
@@ -2537,7 +2580,7 @@ class EngineCore:
                     # a request parked at the capacity edge has no room left
                     # to decode: finish it cleanly rather than erroring a
                     # stream the client is already consuming
-                    request.finished_at = time.monotonic()
+                    request.finished_at = stepstats._now()
                     request.events.put(("done", "length"))
                     self.metrics.record_request_done("length")
                     self._fr_emit(request, "finished", reason="length",
@@ -2776,6 +2819,7 @@ class EngineCore:
         shared = entry.pages[: use_len // self.kv_page_size]
         self._assign_slot_pages(slot_id, shared, fresh_pages)
         self.metrics.record_prefix_hit(use_len)
+        request.cached_tokens = use_len
         # the uncached suffix prefills via _advance_prefill (its own
         # prefill_chunk events); this event records the reused head
         self._fr_emit(request, "prefill_chunk", tokens=0,
@@ -3171,6 +3215,7 @@ class EngineCore:
         # (request_id, drafted, accepted) per speculating slot — the slot's
         # request may finish inside the emit loop, so capture the id up front
         spec_accepts: list[tuple[str, int, int]] = []
+        self._begin_delivery(step)
         for i in active:
             slot = self.slots[i]
             request = slot.request
@@ -3565,6 +3610,7 @@ class EngineCore:
         ahead = after is not None
         self._note_prefill_dispatch(ahead)
         step = self._clock().begin("dispatch", after=after)
+        self._stamp_prefill(step, [r for _, r, _ in group])
         # padding rows repeat the last real slot's table row, so their
         # duplicate scatters rewrite identical cells (same trick as ids)
         (logits, self.cache_k, self.cache_v,
@@ -3613,8 +3659,11 @@ class EngineCore:
         dispatch -> done (not the dispatch alone), and the flight records'
         `prefill_chunk` is stamped where it is in today's order."""
         jax.block_until_ready(prefill.logits)
-        self.metrics.record_prefill_step(
-            time.perf_counter() - prefill.step.t0)
+        now = stepstats._now()
+        self.metrics.record_prefill_step(now - prefill.step.t0)
+        # the activation was issued behind the dispatch; `prefill` ends
+        # where the host knows the prompt filled, as in today's order
+        self._stamp_activated([r for _, r, _ in prefill.group], now)
         self._fr_prefilled(prefill.group)
         self._observe_step(
             "prefill", prefill.step,
@@ -3738,6 +3787,28 @@ class EngineCore:
             # it is emitted with the next decode fetch (first_pending).
             slot.last_emit_at = 0.0
             slot.first_pending = True
+        if not inflight:  # else _record_ahead_prefill, behind the wait
+            self._stamp_activated([r for _, r, _ in group], stepstats._now())
+
+    @staticmethod
+    def _stamp_prefill(step: StepSpan, requests: "list[Request]") -> None:
+        """The way in: `step` is a prefill dispatch of `requests`. For one
+        that has no first token yet, `place` ends where its FIRST dispatch
+        began (the step's own first stamp: no clock is read here)."""
+        for r in requests:
+            if r.first_token_at is None:
+                if r.prefill_at is None:
+                    r.prefill_at, r.prefill_seq = step.t0, step.seq
+                r.prefill_chunks += 1
+
+    @staticmethod
+    def _stamp_activated(requests: "list[Request]", now: float) -> None:
+        """The way in: `prefill` ends at `now`, ONE clock read for the
+        group, where the host knows the prompts filled and has issued the
+        activation; `first_fetch` runs from it."""
+        for r in requests:
+            if r.activated_at is None:
+                r.activated_at = now
 
     # the per-slot device arrays an activation of a block family writes, in
     # the order of _activate_block_group's rows
@@ -3819,6 +3890,7 @@ class EngineCore:
                 self._fr_emit(request, "resumed", generated=st.generated)
             slot.last_emit_at = 0.0
             slot.first_pending = False
+        self._stamp_activated([r for _, r, _ in group], stepstats._now())
 
     def _cp_bucket_for(self, n: int) -> int:
         """Padded length for the context-parallel prefill jit cache: next
@@ -3838,6 +3910,7 @@ class EngineCore:
         ids[0, :n] = self._effective_prompt(request)
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
+        self._stamp_prefill(step, [request])
         logits, k_all, v_all = self.programs.context_parallel_prefill(
             self.params, jnp.asarray(ids), jnp.asarray([n], np.int32)
         )
@@ -3905,6 +3978,7 @@ class EngineCore:
 
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
+        self._stamp_prefill(step, [request])
         (logits, self.cache_k, self.cache_v,
          *stats) = self.programs.extend(
             self.params, jnp.asarray(ids),
@@ -4080,6 +4154,7 @@ class EngineCore:
         tokens = self._fetch_tokens(jnp.stack([first_in, tokens_dev]))
         step_s = step.mark("emit") - t_sync
         self.metrics.record_decode_step(step_s, len(active))
+        self._begin_delivery(step)
         self._emit_fetched(tokens, self._burst_rows(active), itl=step_s)
         self._record_step(
             "decode", step,
@@ -4218,6 +4293,7 @@ class EngineCore:
         rows = burst.rows
         tokens, counters = self.programs.unpack(burst.fetched, k + 1)
         self.metrics.record_decode_step(burst.step_s, len(rows))
+        self._begin_delivery(burst.step)
         self._emit_fetched(tokens, rows, itl=burst.step_s)
         record = self._observe_step if closed else self._record_step
         record("decode", burst.step,
@@ -4449,6 +4525,7 @@ class EngineCore:
                                              k * (b + 2))
         burst_s = step.mark("emit") - t_sync
         self.metrics.record_decode_step(burst_s / k, len(active))
+        self._begin_delivery(step)
         block = self._emit_blocks(out.reshape(k, b + 2, self.num_slots),
                                   active, burst_s)
         self._record_step(
@@ -4559,7 +4636,7 @@ class EngineCore:
         assert request is not None
         if self._is_cancelled(request):
             self._put_held(request, held)
-            request.finished_at = time.monotonic()
+            request.finished_at = stepstats._now()
             request.events.put(("done", "cancelled"))
             self._fr_emit(request, "finished", reason="cancelled",
                           generated=slot.generated)
@@ -4586,10 +4663,9 @@ class EngineCore:
         # token is part of the sequence the next proposal continues).
         if slot.drafter is not None and token != self.eos_id:
             slot.drafter.append(token)
-        now = time.monotonic()
+        now = stepstats._now()
         if request.first_token_at is None:
-            request.first_token_at = now
-            self.metrics.record_ttft(now - request.submitted_at)
+            self._first_token(request, now)
         if not slot.last_emit_at:
             self.metrics.record_emit(None)  # first token: no inter-token gap
         else:
@@ -4630,7 +4706,7 @@ class EngineCore:
 
         if finish is not None:
             self._put_held(request, held)
-            request.finished_at = time.monotonic()
+            request.finished_at = stepstats._now()
             if finish == "length" and request.export_kv and self.kv_ship:
                 # Handoff export: serialize this stream's KV pages D2H
                 # BEFORE the pool frees them below — the adopter lands
@@ -4660,6 +4736,35 @@ class EngineCore:
             slot.drafter = None
             slot.spec_k = 0
             slot.out_tokens = []
+
+    def _begin_delivery(self, step: StepSpan) -> None:
+        """The tokens `step`'s fetch brought are about to be emitted: the
+        first tokens among them are its record's (_first_token)."""
+        self._fetch_seq = step.seq
+        self._first_tokens = []
+
+    def _first_token(self, request: Request, now: float) -> None:
+        """A request's first token reached the host at `now` (_emit's own
+        read): its way in is complete. The stages (stepstats.way_in_stages)
+        go, from the request's own stamps, to the three places that read
+        them: the `first_token` flight-recorder event, the record of the
+        step whose fetch brought the token (`first_tokens`, through
+        _observe_step) and the cumulative `.metrics.way_in`. Once a
+        request; inside a capture the instant is an annotation too."""
+        request.first_token_at = now
+        stages = stepstats.way_in_stages(request)
+        self.metrics.record_first_token(now - request.submitted_at, stages)
+        entry = {"id": gateway_rid(request.request_id), **stages,
+                 "chunks": request.prefill_chunks,
+                 "cached_tokens": request.cached_tokens,
+                 "prefill_seq": request.prefill_seq}
+        self._first_tokens.append(entry)
+        self._fr_emit(request, "first_token", **stages,
+                      chunks=request.prefill_chunks,
+                      prefill_seq=request.prefill_seq,
+                      fetch_seq=self._fetch_seq)
+        with first_token_annotation(entry["id"], self._fetch_seq):
+            pass
 
     def _fail_all(self, message: str) -> None:
         for slot_id, slot in enumerate(self.slots):

@@ -26,7 +26,7 @@ from llmlb_tpu.disagg import HandoffError, handoff_payload, parse_handoff
 from llmlb_tpu.engine import stepstats
 from llmlb_tpu.engine.profiling import ProfileError, ProfileManager
 from llmlb_tpu.engine.scheduler import SamplingParams
-from llmlb_tpu.engine.service import Engine, EngineError
+from llmlb_tpu.engine.service import RECEIVED_AT, Engine, EngineError
 from llmlb_tpu.structured import inspect_request, parse_seed
 
 log = logging.getLogger("llmlb_tpu.engine.server")
@@ -1510,7 +1510,10 @@ class EngineAPI:
 
 @web.middleware
 async def error_middleware(request: web.Request, handler):
-    """Normalize engine/validation failures to OpenAI-style JSON errors."""
+    """Normalize engine/validation failures to OpenAI-style JSON errors.
+    The outermost middleware, so also where a request's way in begins: the
+    handler's entry is stamped for `accept` (service.RECEIVED_AT)."""
+    RECEIVED_AT.set(stepstats._now())
     try:
         return await handler(request)
     except web.HTTPException:

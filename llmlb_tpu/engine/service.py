@@ -8,6 +8,7 @@ over the core's thread-side event queues.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import dataclasses
 import os
 import time
@@ -30,6 +31,15 @@ from llmlb_tpu.engine.tokenizer import (
     IncrementalDetokenizer,
     Tokenizer,
 )
+
+
+# The instant the HTTP handler of the request being served was entered
+# (stepstats._now; server.error_middleware sets it): where `accept`, the
+# first stage of a request's way in, begins (docs/tracing.md). A context
+# variable, so that every handler's every path to a Request carries it with
+# no argument; None for a request that came through no handler.
+RECEIVED_AT: contextvars.ContextVar[float | None] = contextvars.ContextVar(
+    "llmlb_received_at", default=None)
 
 
 def _quantize_weights(core_kwargs: dict) -> bool:
@@ -178,6 +188,7 @@ class Engine:
             )
         else:
             request = Request(prompt_ids=prompt_ids, sampling=sampling)
+        request.received_at = RECEIVED_AT.get()
         loop = asyncio.get_running_loop()
         if sampling.constraint is not None:
             # Compile (or LRU-fetch) the token-DFA BEFORE submit, off the
@@ -238,6 +249,7 @@ class Engine:
         events = request.events
         take = events.taker()
         stats.open(events)
+        first_frame = True  # the way in's last stage: the first delta out
         try:
             while True:
                 stamp, (kind, value) = await loop.run_in_executor(
@@ -272,7 +284,7 @@ class Engine:
                         last = final(acc[emitted:], str(value))
                         yield last
                         if last.text:  # else the handler wrote no frame
-                            stats.frame(t_got)
+                            stats.frame(t_got, stamp if first_frame else None)
                         stats.finished(request)
                         return
                     boundary = max(emitted, len(acc) - holdback)
@@ -284,7 +296,8 @@ class Engine:
                         ttft = None  # report once
                         emitted = boundary
                         yield delta
-                        stats.frame(t_got)
+                        stats.frame(t_got, stamp if first_frame else None)
+                        first_frame = False
         finally:
             stats.close(events)
             if not finished:
@@ -323,6 +336,7 @@ class Engine:
             prompt_ids=prompt_ids, sampling=bounded,
             request_id=(f"{request_id}.{uuid.uuid4().hex[:8]}"
                         if request_id else uuid.uuid4().hex),
+            received_at=RECEIVED_AT.get(),
         )
         # ask the scheduler to serialize this stream's KV pages at the
         # emit-budget finish, before the pool reclaims them — the payload
@@ -536,6 +550,7 @@ class Engine:
                 constraint=cursor, drafter=drafter, spec_k=spec_k,
             ),
             kv_restore=kv_restore,
+            received_at=RECEIVED_AT.get(),
         )
         if sampling.lora:
             # adoption replays prompt+committed WITH the adapter — the

@@ -138,6 +138,27 @@ INFLIGHT_SPANS = ("host_sync_inflight", "emit_inflight", "activate_inflight",
 GAP_BUCKETS = ("admit", "control", "record", "idle", "other")
 LOOP_BUCKETS = ("step",) + GAP_BUCKETS
 
+# A request's way in (docs/tracing.md): the stages its time to first token
+# is cut into, each named for the wait it holds, in the order a request
+# passes them. Stage i runs from stamp i to stamp i + 1 of WAY_IN_STAMPS
+# (fields of scheduler.Request, all read from `_now`): `accept` lies before
+# `submitted_at`, where time to first token starts, so the other four sum
+# to it.
+WAY_IN = ("accept", "inbox", "place", "prefill", "first_fetch")
+WAY_IN_STAMPS = ("received_at", "submitted_at", "taken_at", "prefill_at",
+                 "activated_at", "first_token_at")
+
+
+def way_in_stages(request) -> dict[str, float]:
+    """Seconds by stage of WAY_IN from a request's stamps. A stage this
+    process never ran (one of its two stamps is None: no handler, no
+    prefill of a restored request) is absent, not zero."""
+    stamps = [getattr(request, name) for name in WAY_IN_STAMPS]
+    return {stage: round(end - start, 6)
+            for stage, start, end in zip(WAY_IN, stamps, stamps[1:])
+            if start is not None and end is not None}
+
+
 # Step kinds the scheduler dispatches. Each kind keeps its OWN EMA baseline
 # in the slow-step detector: a K+1-token speculative verify step is
 # legitimately several times a single-token decode step, so folding them

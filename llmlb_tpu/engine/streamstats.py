@@ -27,8 +27,14 @@ stages, and each has a counter here (docs/tracing.md "A token's way out"):
 reading. All of it is written by ONE thread, the HTTP event loop, so it
 takes no lock; the queue's `n_put` is written by its one producer. Stamps
 are `stepstats._now`, three clock reads an event in all (the put, the
-resumption, the frame's end); a whole stream's two durations are on
-`Request.submitted_at`'s clock (`time.monotonic`), the same clock on Linux.
+resumption, the frame's end); a whole stream's two durations are on the
+same clock, as `Request.submitted_at` and `finished_at` are.
+
+The LAST stage of a request's way in is counted here too (docs/tracing.md
+"A request's way in"): `first_frames_total` and `first_frame_seconds_total`
+hold, for the first delta a stream yields, the time from its event's put to
+the generator's resumption after the yield — the frame's own end stamp, no
+further read.
 """
 
 from __future__ import annotations
@@ -36,7 +42,6 @@ from __future__ import annotations
 import contextlib
 import functools
 import queue
-import time
 
 from jax.profiler import TraceAnnotation
 
@@ -82,6 +87,8 @@ class StreamStats:
         self.event_backlog_max = 0
         self.frames_total = 0
         self.frame_seconds_total = 0.0
+        self.first_frames_total = 0       # streams whose first delta is out
+        self.first_frame_seconds_total = 0.0  # its event's put -> written
         self.write_waits_total = 0        # writes that found the reader behind
         self.write_wait_seconds_total = 0.0
         self.streams_finished_total = 0
@@ -113,18 +120,23 @@ class StreamStats:
             self.event_backlog_max = backlog
         return now
 
-    def frame(self, t0: float) -> None:
+    def frame(self, t0: float, first_put: float | None = None) -> None:
         """The generator resumed after the `yield` of the delta whose event
-        arrived at `t0`."""
+        arrived at `t0`. `first_put`: it is the stream's first delta, and
+        its event was put then."""
+        now = stepstats._now()
         self.frames_total += 1
-        self.frame_seconds_total += stepstats._now() - t0
+        self.frame_seconds_total += now - t0
+        if first_put is not None:
+            self.first_frames_total += 1
+            self.first_frame_seconds_total += now - first_put
 
     def finished(self, request) -> None:
         """The last frame of a stream the scheduler finished is written."""
         if request.finished_at is None:
             return
         self.streams_finished_total += 1
-        self.stream_seconds_total += time.monotonic() - request.submitted_at
+        self.stream_seconds_total += stepstats._now() - request.submitted_at
         self.made_seconds_total += request.finished_at - request.submitted_at
 
     # ---- the writer's side (server._sse_send, event loop)
@@ -157,3 +169,15 @@ def frame_annotation():
     capture. Outside one it is nothing at all: an event a token is no place
     for an object a time (one C call asks whether a capture runs)."""
     return TraceAnnotation("llmlb.stream.frame") if _capturing() else _NO_FRAME
+
+
+def first_token_annotation(request_id: str, fetch_seq: int):
+    """The instant a request's first token reached the host
+    (scheduler._first_token), on the host plane of a capture: it lies in
+    the `llmlb.step` that emits it and names the step whose fetch brought
+    it (`fetch_seq`: the same step in today's order, the one before where
+    the emit runs behind the next dispatch). Nothing outside a capture."""
+    if not _capturing():
+        return _NO_FRAME
+    return TraceAnnotation("llmlb.first_token", request_id=request_id,
+                           fetch_seq=fetch_seq)

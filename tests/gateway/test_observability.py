@@ -87,7 +87,7 @@ def assert_valid_exposition(text: str) -> dict:
 def test_engine_metrics_exposition_valid():
     m = EngineMetrics()
     for s in (0.004, 0.02, 0.3, 7.0, 45.0):
-        m.record_ttft(s)
+        m.record_first_token(s, {})
     for s in (0.002, 0.004, 0.08):
         m.record_itl(s)
     m.record_prefill_step(0.03)
