@@ -50,8 +50,9 @@ PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 BLOCK_COUNTS = ("block_passes", "row_passes", "blocks_committed",
                 "tokens_committed", "positions_unmasked", "blocks_fused")
 
-# Why a dense decode burst did not leave before its predecessor was emitted
-# (scheduler._ahead_blocker; docs/scheduling.md), a closed set: a request
+# Why a decode burst (dense, or a block family's) did not leave before its
+# predecessor was emitted (scheduler._ahead_blocker; docs/scheduling.md), a
+# closed set: a request
 # waits for admission; a slot is prefilling; a park / drain / flush / stop
 # request, a coordinator or split mode; a row's next mask comes from a host
 # FSM; a row has a drafter; the free list could not cover the pages; the
@@ -191,7 +192,7 @@ class EngineMetrics:
         # fused mode off).
         self.fused_decode_steps_total = 0
         self.decode_dispatches_total = 0
-        # Dense decode bursts (scheduler._decode_bursts): how many were
+        # Decode bursts (scheduler._decode_bursts): how many were
         # dispatched, how many of them left BEFORE their predecessor was
         # emitted, and for the others what stood in the way
         # (AHEAD_BLOCKERS). The two add up to the first.
@@ -425,7 +426,7 @@ class EngineMetrics:
                 self.fused_decode_steps_total += 1
 
     def record_decode_burst(self, blocked_by: str | None) -> None:
-        """One dense decode burst: dispatched ahead of its predecessor's
+        """One decode burst: dispatched ahead of its predecessor's
         emit (`blocked_by` None), or not, and why not."""
         with self._lock:
             self.decode_bursts_total += 1

@@ -38,6 +38,7 @@ import os
 import queue
 import threading
 import time
+import typing
 import uuid
 from functools import partial
 
@@ -362,10 +363,26 @@ class _Slot:
     token_limit: int = 0
 
 
+class _BurstBounds(typing.NamedTuple):
+    """What ONE decode burst may do to a row, in positions past the length
+    the row has at the burst's dispatch (EngineCore._burst_bounds): what the
+    host plans a burst from, also where it cannot count."""
+
+    # cells the row may write: its pages and the burst's window cover them
+    reach: int
+    # the most its length grows by: what the host's mirror (_seq_lens,
+    # `generated`) may lag by while the burst is in flight. A dense row
+    # grows by exactly that; a block row by the blocks it commits, which
+    # only the fetch tells
+    advance: int
+    # the positions its record's `kv_pages_live` counts
+    counted: int
+
+
 @dataclasses.dataclass
 class _Burst:
-    """A dense decode burst from its dispatch to its emit
-    (EngineCore._decode_bursts)."""
+    """A decode burst — dense steps, or a block family's passes — from its
+    dispatch to its emit (EngineCore._decode_bursts)."""
 
     step: StepSpan
     # (slot, request, whether row 0 of its column is the request's first
@@ -376,8 +393,9 @@ class _Burst:
     # why it did not leave before its predecessor was emitted (one of
     # metrics.AHEAD_BLOCKERS); None: it did
     blocked_by: str | None
-    # what the one fetch brought: [k + 1, SLOTS] tokens, and the family's
-    # step counters behind them (_pack_step_counters)
+    # what the one fetch brought: [k + 1, SLOTS] tokens (a block family:
+    # [k · (B + 2), SLOTS], _build_block_many), and the family's step
+    # counters behind them (_pack_step_counters)
     fetched: np.ndarray | None = None
     step_s: float = 0.0  # the cycle's wall time a token
 
@@ -462,7 +480,7 @@ class EngineCore:
         self.family = family_for(cfg)
         record = self._record = self.family.FAMILY
         # Generation by diffusion over blocks: a family that declares a
-        # block length B > 1 decodes by BLOCK PASSES (_decode_blocks) — a
+        # block length B > 1 decodes by BLOCK PASSES (_decode_bursts) — a
         # row commits 0 or B tokens a pass — and every other family by one
         # token a step, on the programs it always built.
         self.block = int(record.block_length(cfg))
@@ -806,10 +824,10 @@ class EngineCore:
         # iteration — the migration analogue of request_drain_park, scoped
         # to single streams instead of the whole engine.
         self._park_rids: set[str] = set()
-        # What kept the NEXT dense decode burst from leaving before its
+        # What kept the NEXT decode burst from leaving before its
         # predecessor was emitted (_ahead_blocker), for that burst's record.
         self._ahead_blocked_by = "first"
-        # The dense decode burst the device holds and the host has not
+        # The decode burst the device holds and the host has not
         # fetched, at most ONE: set at its dispatch, cleared at its fetch.
         self._in_flight: _Burst | None = None
         # the way in (_first_token): the seq of the step whose fetch is being
@@ -1678,7 +1696,7 @@ class EngineCore:
         dispatch (a mixture's expert load): the scalars land on the record,
         everything in the engine's running totals. `block` are a block
         family's counts of the burst (_emit_blocks): on the record and in
-        the running totals likewise. `burst` is a dense decode burst's:
+        the running totals likewise. `burst` is a decode burst's:
         whether it left ahead of its predecessor's emit, and why not, on the
         record (`dispatched_ahead`, `ahead_blocked_by`) and in the running
         totals. `ahead` is a one-shot prefill group's: whether it left
@@ -2081,9 +2099,12 @@ class EngineCore:
 
     def _sync_block_tables(self) -> None:
         """Refresh the device block tables before a dispatch that reads them
-        (one small H2D, only when a row changed since the last sync)."""
+        (one small H2D, only when a row changed since the last sync). A
+        COPY goes: a burst in flight keeps the tables it left with while the
+        host frees and grows rows under it (docs/kv-cache.md), and the CPU
+        backend would alias an aligned host array, not copy it."""
         if self._tables_dirty:
-            self._d_block_tables = jnp.asarray(self._block_tables)
+            self._d_block_tables = jnp.asarray(self._block_tables.copy())
             self._tables_dirty = False
 
     def _ensure_decode_pages(self, active: list[int], k: int,
@@ -3693,7 +3714,8 @@ class EngineCore:
             self.split.pump_handoffs()
             return
         if self.block > 1:
-            self._activate_block_group(group, padded_slot_ids, padded_lens)
+            self._activate_block_group(group, padded_slot_ids, padded_lens,
+                                       inflight=inflight)
             return
         # inside a step (the prefill paths) this is its `activate` span;
         # a handoff adoption between steps stays in the loop's bucket
@@ -3819,14 +3841,18 @@ class EngineCore:
 
     def _activate_block_group(self, group: list[tuple[int, Request, int]],
                               padded_slot_ids: np.ndarray,
-                              padded_lens: np.ndarray) -> None:
+                              padded_lens: np.ndarray, *,
+                              inflight: bool = False) -> None:
         """_activate_group for a block family: nothing is sampled — the
         prefill's logits are of the last prompt block's own tokens. Each
         row's committed length is the `n` whole-block tokens just prefilled;
         the prompt's remainder opens its first block as given tokens, the
         rest of the block masked; `left` is the positions the row may yet
-        commit (given ones included), from which the program stops it."""
-        self._clock().mark("activate")
+        commit (given ones included), from which the program stops it.
+        Nothing is fetched and no key is split, so a group whose prefill
+        left ahead (`inflight`, as in _activate_group) is activated as any
+        other."""
+        self._clock().mark("activate_inflight" if inflight else "activate")
         g, padded, b = len(group), len(padded_slot_ids), self.block
         cfg = self.cfg
         temps = np.ones((padded,), np.float32)
@@ -3890,7 +3916,8 @@ class EngineCore:
                 self._fr_emit(request, "resumed", generated=st.generated)
             slot.last_emit_at = 0.0
             slot.first_pending = False
-        self._stamp_activated([r for _, r, _ in group], stepstats._now())
+        if not inflight:  # else _record_ahead_prefill, behind the wait
+            self._stamp_activated([r for _, r, _ in group], stepstats._now())
 
     def _cp_bucket_for(self, n: int) -> int:
         """Padded length for the context-parallel prefill jit cache: next
@@ -4074,8 +4101,6 @@ class EngineCore:
         # no drafter attached anywhere this block is a no-op and the decode
         # path below is bit-identical to the pre-speculation engine.
         clock = self._clock()
-        if self.block > 1:
-            return self._decode_blocks(active, clock)
         if self._spec_available and any(
             self.slots[i].drafter is not None for i in active
         ):
@@ -4092,8 +4117,10 @@ class EngineCore:
             step = clock.begin("host_sync")
             t_sync = step.t0
         # alloc-on-extend: every page this dispatch writes must exist
-        # before the tables ship to the device
-        active = self._ensure_decode_pages(active, self.decode_burst)
+        # before the tables ship to the device (a block family: every cell a
+        # commit of this burst may write)
+        active = self._ensure_decode_pages(
+            active, self._burst_bounds(self.decode_burst).reach - 1)
         if not active:
             clock.abandon()
             self.metrics.set_batch_occupancy(0)
@@ -4113,7 +4140,8 @@ class EngineCore:
         constrained_active = self._constrained_count > 0 and any(
             self.slots[i].constraint is not None for i in active
         )
-        fused_step = self._fused_step_ok(active)
+        # a block family's burst is ONE program at any k (its passes)
+        fused_step = self.block > 1 or self._fused_step_ok(active)
         if k > 1 and constrained_active and not fused_step:
             k = 1
             self.metrics.record_constrained_burst_fallback()
@@ -4177,7 +4205,11 @@ class EngineCore:
     def _decode_bursts(self, step: StepSpan, t_cycle: float,
                        active: list[int], k: int, sk, fused_step: bool,
                        grammar: bool) -> bool:
-        """The dense decode burst (k steps in one program), and as many
+        """The decode burst — k steps in one program, or a block family's k
+        passes: ONE loop for both, and what a burst is (its bounds, its
+        program and the state it carries, the rows that go on, its
+        delivery) follows the family's block length in _burst_bounds,
+        _dispatch_burst, _prepare_burst and _deliver_burst — and as many
         more as can leave AHEAD: a burst whose rows need nothing from the
         host is dispatched right after its predecessor's fetch, and the
         predecessor's tokens are delivered and its record closed while it
@@ -4196,9 +4228,10 @@ class EngineCore:
         dispatched in front of it, and nothing is in flight when this
         returns."""
         clock = self._clock()
-        window = self._window_for(active, k)
+        bounds = self._burst_bounds(k)
+        window = self._window_for(active, bounds.reach - 1)
         plan = (self._burst_rows(active), window,
-                self._kv_pages(active, k, window))
+                self._kv_pages(active, bounds.counted, window))
         gram_args = {}
         if grammar:
             # Fresh int32 cursor vector from the host FSMs (source of
@@ -4224,7 +4257,6 @@ class EngineCore:
         placed: _AheadPrefill | None = None
         while True:
             rows, window, kv_pages = plan
-            fn = self.programs.decode_many(window, grammar)
             if prev is None:
                 step.mark("dispatch")
             else:
@@ -4233,14 +4265,8 @@ class EngineCore:
                 # activations in the order they are dispatched
                 self._key, sk = jax.random.split(self._key)
             burst = _Burst(step, rows, kv_pages, blocked_by)
-            # the adapter rows as the last activation left them (one ahead
-            # of this burst donates the array an earlier burst was handed)
-            lora_idx = self._d_lora_idx if self.lora is not None else None
-            (self._d_last_tokens, self._d_seq_lens, self.cache_k,
-             self.cache_v, toks_dev) = fn(
-                *self._decode_operands(sk), self._live_rows(burst.slots),
-                lora_idx=lora_idx, **gram_args,
-            )
+            toks_dev = self._dispatch_burst(window, sk, burst.slots,
+                                            grammar, gram_args)
             self._in_flight = burst
             if prev is not None:
                 step.mark("emit_inflight")
@@ -4286,20 +4312,61 @@ class EngineCore:
             self._deliver_burst(burst, k, fused_step, closed=False)
             return True
 
+    def _burst_bounds(self, k: int) -> _BurstBounds:
+        """What a burst of k may do to a row: a dense one writes k tokens
+        (and the host keeps a cell ahead of them); a block family's reaches
+        _block_reach() and commits at most a block a pass."""
+        if self.block > 1:
+            reach = self._block_reach()
+            return _BurstBounds(reach, self.block * k, reach)
+        return _BurstBounds(k + 1, k, k)
+
+    def _dispatch_burst(self, window: int, key, slots: list[int],
+                        grammar: bool, gram_args: dict):
+        """Call the burst's program for `window` over the rows `slots`: the
+        per-slot state and the pool it returns are the loop's from here on
+        (device futures: the next dispatch takes them unread). Returns the
+        array the burst's ONE fetch reads."""
+        live = self._live_rows(slots)
+        if self.block > 1:
+            (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
+             self._d_skip, self.cache_k, self.cache_v,
+             out_dev) = self.programs.block_many(window)(
+                *self._decode_operands(key), live)
+            return out_dev
+        # the adapter rows as the last activation left them (one ahead
+        # of this burst donates the array an earlier burst was handed)
+        lora_idx = self._d_lora_idx if self.lora is not None else None
+        (self._d_last_tokens, self._d_seq_lens, self.cache_k,
+         self.cache_v, toks_dev) = self.programs.decode_many(window, grammar)(
+            *self._decode_operands(key), live,
+            lora_idx=lora_idx, **gram_args,
+        )
+        return toks_dev
+
     def _deliver_burst(self, burst: _Burst, k: int, fused_step: bool, *,
                        closed: bool) -> None:
-        """Emit a fetched burst's tokens and finalize its record; `closed`:
-        LoopClock.handover has closed its step already."""
-        rows = burst.rows
-        tokens, counters = self.programs.unpack(burst.fetched, k + 1)
+        """Emit a fetched burst's tokens — a block family's: the blocks its
+        rows committed, in order (_emit_blocks) — and finalize its record;
+        `closed`: LoopClock.handover has closed its step already."""
+        rows, b = burst.rows, self.block
+        fetched, counters = self.programs.unpack(
+            burst.fetched, k * (b + 2) if b > 1 else k + 1)
         self.metrics.record_decode_step(burst.step_s, len(rows))
         self._begin_delivery(burst.step)
-        self._emit_fetched(tokens, rows, itl=burst.step_s)
+        block = None
+        if b > 1:
+            block = self._emit_blocks(
+                fetched.reshape(k, b + 2, self.num_slots), rows,
+                burst.step_s * k)
+        else:
+            self._emit_fetched(fetched, rows, itl=burst.step_s)
         record = self._observe_step if closed else self._record_step
-        record("decode", burst.step,
-               active_slots=len(rows), tokens=k * len(rows),
+        record("decode", burst.step, active_slots=len(rows),
+               tokens=block["tokens_committed"] if block else k * len(rows),
                slots=burst.slots, dispatches=1, fused=fused_step,
-               kv_pages=burst.kv_pages, counters=counters, burst=burst)
+               kv_pages=burst.kv_pages, counters=counters, block=block,
+               burst=burst)
 
     def _ahead_fixed_blocker(self, active: list[int],
                              grammar: bool) -> str | None:
@@ -4339,10 +4406,10 @@ class EngineCore:
                         ) -> "list[tuple[Request, int, int, bool]] | None":
         """After a burst's fetch, with `admission` the one thing in the way
         of the prepared burst `plan`: the queued requests in the order
-        _try_insert would take them — each with the tokens it prefills, the
-        pages it takes (its prompt's and what the burst behind the prefill
-        writes) and whether the prefix cache was asked about it — if EVERY
-        one of them
+        _try_insert would take them — each with the tokens it prefills (a
+        block family: the prompt's whole blocks), the pages it takes (those
+        tokens' and what the burst behind the prefill may write) and whether
+        the prefix cache was asked about it — if EVERY one of them
         can be placed now and prefilled as ONE one-shot group, without the
         host having emitted the fetched burst; else None, and the cycle
         takes today's order, where _try_insert serves them all behind the
@@ -4377,17 +4444,20 @@ class EngineCore:
             cutoff = min(cutoff, self._budget_chunk_len(budget))
         if budget and sum(len(r.prompt_ids) for r in queued) > budget:
             return None
+        reach = self._burst_bounds(k).reach
         arrivals: list[tuple[Request, int, int, bool]] = []
         bucket = None  # the group's, set by its first request
         for request in queued:
             n = len(request.prompt_ids)
+            room = self.slot_capacity - n - self.block  # as in _try_insert
+            n -= n % self.block  # what prefills: the prompt's whole blocks
             if (self._is_cancelled(request) or request.deadline_expired()
                     or request.parked is not None
                     or request.kv_restore is not None
                     or request.sampling.constraint is not None
                     or request.compiled_constraint is not None
                     or self._speculates(request)
-                    or n > cutoff or n + 1 >= self.slot_capacity):
+                    or n > cutoff or room <= 0):
                 return None
             bucket = bucket or self._bucket_for(n)
             if self._bucket_for(n) != bucket:
@@ -4398,7 +4468,7 @@ class EngineCore:
                     request.prompt_ids, max_len=n - 1,
                     ns=request.sampling.lora) is not None:
                 return None
-            pages = self._pages_for_tokens(min(n + k + 1, self.slot_capacity))
+            pages = self._pages_for_tokens(min(n + reach, self.slot_capacity))
             arrivals.append((request, n, pages, cacheable))
         if sum(a[2] for a in arrivals) > self.page_pool.available():
             return None
@@ -4412,7 +4482,8 @@ class EngineCore:
         can be) as _try_insert's one-shot path does, and dispatch their
         prefill and their activation without waiting for either. Returns the
         prefill, its step open, and the prepared burst `plan` with the new
-        rows in it (first token pending): the caller dispatches that burst
+        rows in it (first token pending; a block family's row has none,
+        its first block opens in the burst): the caller dispatches that burst
         right behind, so the device runs prefill, activation and burst back
         to back while the host emits the fetched burst. Each arrival's
         pages, the prompt's and what the burst will write, come from the
@@ -4438,53 +4509,73 @@ class EngineCore:
         self._sync_block_tables()
         rows, window, kv_pages = plan
         new = [slot_id for slot_id, _, _ in group]
-        window = max(window, self._window_for(new, k))
-        of_new = self._kv_pages(new, k, window)
+        bounds = self._burst_bounds(k)
+        window = max(window, self._window_for(new, bounds.reach - 1))
+        of_new = self._kv_pages(new, bounds.counted, window)
         kv_pages = {"kv_pages_live": (kv_pages["kv_pages_live"]
                                       + of_new["kv_pages_live"]),
                     "kv_pages_window": of_new["kv_pages_window"]}
-        rows = sorted(rows + [(slot_id, request, True)
-                              for slot_id, request, _ in group],
-                      key=lambda row: row[0])
+        # as the activation left them: a dense row's first token pending
+        rows = sorted(rows + self._burst_rows(new), key=lambda row: row[0])
         return prefill, (rows, window, kv_pages)
 
     def _prepare_burst(self, rows: list[tuple[int, Request, bool]], k: int):
         """With the burst of `rows` in flight and every earlier one emitted:
         what host_sync would do for the NEXT burst, from the lengths its
-        rows will have (the mirrors lag by the burst in flight: `_seq_lens`
-        + k). A row the host can count to its end inside the burst in
-        flight (max_tokens, the slot's capacity) is not a row of the next;
-        one that ends there by EOS, stop or cancel is, and _emit_fetched
-        drops its column. Pages come from the free list alone — this never
-        evicts, parks, preempts or finishes anything (_ensure_decode_pages
-        may do all four, and a park would read `out_tokens` that lag) — and
-        all of them or none. Returns (rows, window, kv_pages) of the next
-        burst, or why there is none: "pages", or "first" where no row
-        outlives the burst in flight."""
+        rows will have. The mirrors lag by the burst in flight: a dense
+        row's length is `_seq_lens` + k, which the host can count; a block
+        row's is at most `_seq_lens` + block · k (a commit a pass), which it
+        can only BOUND, so the next burst's cells are taken for the worst
+        case: up to `_seq_lens` + block · k + _block_reach().
+
+        Which rows go on. A dense row the host can count to its end inside
+        the burst in flight (max_tokens, the slot's capacity) is not a row
+        of the next; one that ends there by EOS, stop or cancel is, and
+        _emit_fetched drops its column. Of a block row the host only knows
+        whether it CAN end there by count (`generated` + block · k reaches
+        its `token_limit`): every row that holds its request goes on — the
+        program runs no pass for one that ended (`left` 0; one write to the
+        slot's last cell, as for a row that is not decoding) and
+        _emit_blocks drops its column — but where NO row is sure to outlive
+        the burst in flight there is no next burst to offer: ten passes
+        over nothing would stand in front of the next arrival.
+
+        Pages come from the free list alone — this never evicts, parks,
+        preempts or finishes anything (_ensure_decode_pages may do all
+        four, and a park would read `out_tokens` that lag) — and all of
+        them or none. Returns (rows, window, kv_pages) of the next burst,
+        or why there is none: "pages", or "first" where no row (is sure
+        to) outlive the burst in flight."""
+        bounds = self._burst_bounds(k)
         nxt: list[int] = []
         need: dict[int, int] = {}
+        outlives = False
         for i, request, first in rows:
             slot = self.slots[i]
             if slot.request is not request:
                 continue  # ended in the emit before this one
-            length = int(self._seq_lens[i]) + k
-            if (slot.generated + first + k >= request.sampling.max_tokens
-                    or length + 1 >= self.slot_capacity):
-                continue
+            length = int(self._seq_lens[i]) + bounds.advance
+            limit = (slot.token_limit if self.block > 1
+                     else request.sampling.max_tokens)
+            may_end = (slot.generated + first + bounds.advance >= limit
+                       or length + 1 >= self.slot_capacity)
+            if may_end and self.block == 1:
+                continue  # a dense row: counted to its end
+            outlives = outlives or not may_end
             nxt.append(i)
-            short = self._pages_short(i, length + k + 1)
+            short = self._pages_short(i, length + bounds.reach)
             if short > 0:
                 need[i] = short
-        if not nxt:
+        if not outlives:
             return "first"
         if sum(need.values()) > self.page_pool.available():
             return "pages"
         for i, short in need.items():
             self._extend_slot_pages(i, self.page_pool.alloc(short))
         self._sync_block_tables()
-        window = self._window_for(nxt, 2 * k)
+        window = self._window_for(nxt, bounds.advance + bounds.reach - 1)
         return ([(i, self.slots[i].request, False) for i in nxt], window,
-                self._kv_pages(nxt, 2 * k, window))
+                self._kv_pages(nxt, bounds.advance + bounds.counted, window))
 
     def _block_reach(self) -> int:
         """Positions past its committed length that a row may write in one
@@ -4495,55 +4586,25 @@ class EngineCore:
         and a block of padding of a row that does not."""
         return self.block * (self.decode_burst + 1)
 
-    def _decode_blocks(self, active: list[int], clock: LoopClock) -> bool:
-        """A decode step of a block family: ONE dispatch of `decode_burst`
-        block passes over the decoding rows, one fetch, and the blocks the
-        rows committed in it emitted in order (_emit_blocks)."""
-        step = clock.begin("host_sync")
-        t_sync = step.t0
-        k, b, reach = self.decode_burst, self.block, self._block_reach()
-        # alloc-on-extend: every cell a commit of this burst may write
-        active = self._ensure_decode_pages(active, reach - 1)
-        if not active:
-            clock.abandon()
-            self.metrics.set_batch_occupancy(0)
-            return True  # pool exhaustion finished requests: work done
-        self._sync_block_tables()
-        self._key, sk = jax.random.split(self._key)
-        window = self._window_for(active, reach - 1)
-        kv_pages = self._kv_pages(active, reach, window)
-        fn = self.programs.block_many(window)
-        step.mark("dispatch")
-        (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
-         self._d_skip, self.cache_k, self.cache_v, out_dev) = fn(
-            *self._decode_operands(sk), self._live_rows(active))
-        step.mark("compute")
-        jax.block_until_ready(out_dev)
-        step.mark("fetch")
-        # ONE D2H sync per burst
-        out, counters = self.programs.unpack(self._fetch_tokens(out_dev),
-                                             k * (b + 2))
-        burst_s = step.mark("emit") - t_sync
-        self.metrics.record_decode_step(burst_s / k, len(active))
-        self._begin_delivery(step)
-        block = self._emit_blocks(out.reshape(k, b + 2, self.num_slots),
-                                  active, burst_s)
-        self._record_step(
-            "decode", step, active_slots=len(active),
-            tokens=block["tokens_committed"], slots=active, dispatches=1,
-            fused=True, kv_pages=kv_pages, counters=counters, block=block)
-        return True
-
-    def _emit_blocks(self, out: np.ndarray, active: list[int],
+    def _emit_blocks(self, out: np.ndarray,
+                     rows: list[tuple[int, Request, bool]],
                      burst_s: float) -> dict:
         """Deliver one fetched burst of block passes, `out` [passes, B + 2,
-        SLOTS] as the block program lays it out: each block a row committed,
-        in order, less the given tokens at the head of its first block, as
-        ONE event of several tokens; a request that ends inside a block
-        (max_tokens, EOS, cancel) takes the tokens before its end. Returns
-        the burst's counts for its step record; `blocks_fused` are the
-        commits whose pass unmasked positions too, the next block's."""
+        SLOTS] as the block program lays it out, to the requests that held
+        the columns' slots when it was DISPATCHED (`rows`, _burst_rows; a
+        slot whose request has ended since, or that holds another by now,
+        has its column dropped, as in _emit_fetched): each block a row
+        committed, in order, less the given tokens at the head of its first
+        block, as ONE event of several tokens; a request that ends inside a
+        block (max_tokens, EOS, cancel) takes the tokens before its end.
+        `slot.given` and `_seq_lens` advance here and nowhere else. Returns
+        the burst's counts for its step record: what the DEVICE did for the
+        burst's rows (a dropped column's passes among them) and what was
+        delivered (`blocks_committed`, `tokens_committed`); `blocks_fused`
+        are the commits whose pass unmasked positions too, the next
+        block's."""
         b = self.block
+        active = [i for i, _, _ in rows]
         commits = {i: int((out[:, 0, i] >= 0).sum()) for i in active}
         counts = {"block_passes": int(out.shape[0]),
                   "row_passes": int(out[:, b + 1][:, active].sum()),
@@ -4553,11 +4614,10 @@ class EngineCore:
                   "blocks_committed": 0, "tokens_committed": 0}
         committed: dict[int, tuple] = {}  # row -> (blocks, tokens, request)
         for t in range(out.shape[0]):
-            for i in active:
+            for i, request, _ in rows:
                 slot = self.slots[i]
-                if out[t, 0, i] < 0 or slot.request is None:
+                if out[t, 0, i] < 0 or slot.request is not request:
                     continue
-                request = slot.request
                 fresh = out[t, slot.given:b, i].tolist()
                 slot.given = 0
                 start = int(self._seq_lens[i])

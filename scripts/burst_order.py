@@ -6,7 +6,8 @@ where.
 
 Reads the `steps` of `last_run.json` (benchmark/run.py writes the window's
 stepstats records there) and prints one JSON object a file: the decode
-records' count, the share dispatched ahead (`dispatched_ahead`,
+records' count (a dense burst's and, since PR 51, a block family's: both
+carry the field), the share dispatched ahead (`dispatched_ahead`,
 engine/scheduler.py `_decode_bursts`) and the reasons of the others, the mean
 milliseconds a decode record spends in each span — exposed (`host_sync`,
 `dispatch`, `fetch`, `emit`, and the gap before the record by bucket) against

@@ -110,8 +110,8 @@ class InlineLoop:
     (`EngineCore._loop_once`) until one does no work, with `_running` set so
     that the loop's own state decides the order of a decode cycle, as in a
     started engine (docs/scheduling.md "The three orders of a decode cycle").
-    `during[n]` is a list of calls made while the n-th dense burst is in
-    flight — `_prepare_burst`, which every dense burst calls between its
+    `during[n]` is a list of calls made while the n-th burst is in
+    flight — `_prepare_burst`, which every burst calls between its
     dispatch and the wait for it. `todays_order` patches the predicate to
     "not now": the parent's cycle, step for step. `admission_ahead=False`
     patches the other predicate to "no arrival can be placed ahead": a burst
