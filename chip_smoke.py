@@ -848,6 +848,40 @@ def kernel_leg() -> int:
                         f"B={b},pages={pages},dead={dead}",
                         lambda: paged_decode(pages, quant, dead))
 
+    # decode under a LOWER bound a row (models/afmoe.py: Trinity-Mini's
+    # window of 2,048 cells as a band of 17 pages of 128 a slot, 32 query
+    # heads of 128 on 4 KV heads): layer 1 of a band stacked over two, rows
+    # below, at and past the window and at page boundaries, a row in three
+    # not live; the work-list names the span's pages alone and the kernel
+    # masks at both ends, against the gathered band under the same mask
+    for b in (8, 32):
+        def band_decode():
+            w, r, d = 2048, 17, 128
+            band_k = stacked(rand((b + 1) * r, PS, K, d), 1)
+            band_v = stacked(rand((b + 1) * r, PS, K, d), 1)
+            at = (1, 127, 128, 129, 2047, 2048, 2049, 2175, 2176, 2177, 4351)
+            lens = jnp.asarray([at[i % len(at)] for i in range(b)], jnp.int32)
+            lens = jnp.where(jnp.arange(b) % 3 == 1, 0, lens)
+            low = jnp.maximum(lens - w, 0)
+            tables = (jnp.arange(b, dtype=jnp.int32)[:, None] * r
+                      + jnp.arange(r, dtype=jnp.int32)[None])
+            q = rand(b, 1, H, d)
+            want = xla.paged_band_decode(q, band_k, band_v, 1, tables, lens,
+                                         low)
+            got = pa.paged_flash_decode(
+                q[:, 0], band_k, band_v, 1, tables, lens, kv_from=low,
+                name=xla.BAND_DECODE, interpret=False)
+            check("paged_band_decode", f"B={b}", got,
+                  jnp.where((lens > 0)[:, None, None], want[:, 0], 0.0))
+            work = pa.decode_work_list(tables, lens, page_size=PS,
+                                       kv_from=low)
+            pages = jnp.where(lens > 0, (lens - 1) // PS - low // PS + 1, 1)
+            check("paged_band_decode", f"B={b},items",
+                  jnp.asarray([[float(work.count)]]),
+                  jnp.asarray([[float(jnp.sum(pages))]]))
+
+        attempt("paged_band_decode", f"B={b}", band_decode)
+
     # prefill: causal self-attention over a bucketed prompt
     for b, t in ((8, 128), (2, 512)):
         def prefill():

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from llmlb_tpu.models.afmoe import AfmoeConfig
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
@@ -112,6 +113,25 @@ PRESETS: dict[str, LlamaConfig] = {
                                                  "linear_attention"),
         lin_heads=3, lin_key_dim=8, lin_value_dim=16, conv_kernel=4,
         allow_neg_eigval=True, chunk_size=16,
+    ),
+    # CI-sized window-band decoder (models/afmoe.py, docs/afmoe.md): two
+    # leading dense layers and ONE published period behind them (window,
+    # window, window, global, window, window): a window of 16 cells held as
+    # a band of 3 pages of 8 a slot, so prompts, extends and decode all wrap
+    # it; gated, sandwich-normed attention, rotary in the window layers
+    # only, the second half (4 of 8) of the experts held, one shared expert
+    "debug-trinity-tiny": AfmoeConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=6, num_heads=8, num_kv_heads=2, head_dim=16,
+        rope_theta=10000.0, rms_eps=1e-5, dtype=jnp.float32,
+        max_position_embeddings=1024,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",)
+        + ("sliding_attention",) * 2,
+        sliding_window=16, band_page_size=8, num_dense_layers=2,
+        router_experts=8, num_experts=4, first_expert=4,
+        experts_per_token=2, moe_intermediate_size=32,
+        num_shared_experts=1, route_norm=True, route_scale=2.826,
+        mup_enabled=True,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

@@ -154,20 +154,22 @@ class DeepseekV3Config(LlamaConfig):
         )
 
 
-def held_share(hf: dict) -> tuple[int, int, int]:
+def held_share(hf: dict, key: str = "n_routed_experts"
+               ) -> tuple[int, int, int]:
     """(experts held here, experts the router scores, the first held) of a
     config whose `expert_parallel` ({"chips", "chip", "experts"}) says that
-    this chip holds `n_routed_experts` of a deployment's `experts`, the
-    `chip`-th such share; the deployment's key, not a checkpoint's. Without
-    it every expert is held."""
-    held = hf["n_routed_experts"]
+    this chip holds `n_routed_experts` (`key`: the family's own name for
+    the count) of a deployment's `experts`, the `chip`-th such share; the
+    deployment's key, not a checkpoint's. Without it every expert is
+    held."""
+    held = hf[key]
     share = hf.get("expert_parallel") or {}
     experts = int(share.get("experts", held))
     chips, chip = int(share.get("chips", 1)), int(share.get("chip", 0))
     if held * chips != experts or not 0 <= chip < chips:
         raise ValueError(
             f"expert_parallel {share} does not split {experts} experts "
-            f"into shares of n_routed_experts = {held}")
+            f"into shares of {key} = {held}")
     return held, experts, chip * held
 
 

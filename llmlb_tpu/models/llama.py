@@ -509,7 +509,8 @@ class LayerGroup(NamedTuple):
     A layer is `x + mix(norm(x))`, then `x + mlp_fn(norm(x))` — under
     `post_norm` `x + norm(mlp_fn(x))`, the block that norms what a sub-layer
     gives and not what it takes (models/olmo_hybrid.py, whose mixes do the
-    same themselves) — and either half may be absent: `attends` says that the mix is the model's
+    same themselves; `out_norm` names a second norm for a block that norms
+    both) — and either half may be absent: `attends` says that the mix is the model's
     `Attention` over the page pool; `mixer(lp, x, cache_k, cache_v, layer,
     rows: StateRows) -> (x + mix, cache_k, cache_v)` is another mix, with a
     state of its own in the pool (StatePool); `mlp_fn` None is a layer
@@ -547,6 +548,10 @@ class LayerGroup(NamedTuple):
     joins: bool = False  # adds the branch an earlier layer left
     scope: str = ""
     post_norm: bool = False  # `ln_mlp` norms the feed-forward's OUTPUT
+    # A sub-layer normed on BOTH sides (a sandwich: models/afmoe.py): the
+    # name of the group's parameter that norms what the feed-forward GIVES,
+    # `ln_mlp` still norming what it takes.
+    out_norm: str = ""
 
 
 class StateRows(NamedTuple):
@@ -590,7 +595,8 @@ def _group_params(params: Params, group: LayerGroup) -> tuple[Params, Params]:
 def _feed_forward(cfg, group: LayerGroup, lp: Params, x, deferred,
                   token_valid, lora_idx):
     """x + the group's feed-forward of norm(x) (`post_norm`: x + the norm
-    of its feed-forward of x), the deferred branch as the layer leaves it
+    of its feed-forward of x; `out_norm`: x + that norm of its feed-forward
+    of norm(x)), the deferred branch as the layer leaves it
     (LayerGroup: computed here, or joined here, or passed on), and what the
     layer reported."""
     if group.mlp_fn is None:
@@ -599,6 +605,8 @@ def _feed_forward(cfg, group: LayerGroup, lp: Params, x, deferred,
     out, aux = _mlp_out(group.mlp_fn(lp, h, token_valid, lora_idx))
     if group.post_norm:
         out = rms_norm(out, lp["ln_mlp"], cfg.rms_eps)
+    elif group.out_norm:
+        out = rms_norm(out, lp[group.out_norm], cfg.rms_eps)
     x = x + out
     if group.branch is not None:
         with jax.named_scope("deferred_branch"):
@@ -769,8 +777,10 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
     into this row's own later pages or the trash page (unallocated table
     entries), never another row's cells; those cells sit past the valid
     range (masked by every later attention) and are overwritten in place
-    when the sequence grows into them. `window` (static) bounds the
-    attention sweep to whole pages covering it, same contract as decode.
+    when the sequence grows into them. `window` (static, the engine's
+    context BUCKET: the first `window` cells; a model's sliding window is a
+    lower bound a row, ops/attention.paged_band_decode's `kv_from`) bounds
+    the attention sweep to whole pages covering it, same contract as decode.
     `groups`, `attention`, `slot_ids` and the fourth value returned: as
     _prefill_impl. `logits_from` ([B] int32, with `all_logits`) narrows the
     logits to `logits_len` (static) positions a row from the row's own
@@ -996,7 +1006,9 @@ def decode_step_paged(
     cache_v: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, PPN] int32
     mesh: Mesh | None = None,  # unused; shared family signature
-    window: int | None = None,  # static context-window bucket (≥ max seq+1)
+    # static context-window BUCKET (≥ max seq+1), not a sliding window
+    # (that is ops/attention.paged_band_decode's `kv_from`)
+    window: int | None = None,
     lora_idx: jnp.ndarray | None = None,  # [B] int32 adapter pool rows
     live: jnp.ndarray | None = None,  # [B] bool — rows decoding; None = all
 ):

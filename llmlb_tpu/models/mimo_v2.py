@@ -96,6 +96,7 @@ from llmlb_tpu.ops.attention import (
     _pallas_enabled,
     _traced,
     _window_pages,
+    band_positions,
     gather_kv_pages,
     paged_decode_work,
 )
@@ -503,15 +504,6 @@ def _page_blocks(k_pages, v_pages, layer, tables, kv_lens):
     return blocks, fetch
 
 
-def _ring_positions(n, window: int):
-    """The position each cell of a ring holds once a sequence is `n` [B]
-    long: the largest p < n with p mod W == cell, below 0 where there is
-    none yet. [B, W]."""
-    cell = jnp.arange(window, dtype=jnp.int32)[None, :]
-    last = n[:, None] - 1 - cell  # >= 0 where the cell has been written
-    return jnp.where(last >= 0, cell + last // window * window, -1)
-
-
 # ---------------------------------------------------------------------------
 # The two attentions
 # ---------------------------------------------------------------------------
@@ -646,13 +638,13 @@ def _window_mixer(cfg: MimoV2Config):
             at = (layer, slots)
             # the ring once the chunk is in: a cell's position is the
             # chunk's where that is at or past `start`, else what it held
-            held = _ring_positions(start + rows.lens, w)  # [B, W]
+            held = band_positions(start + rows.lens, w)  # [B, W]
             pick = jnp.clip(held - start[:, None], 0, t - 1)[:, :, None, None]
             new_k = jnp.take_along_axis(k, pick, axis=1)
             new_v = jnp.take_along_axis(v, pick, axis=1)
             if rows.start_pos is not None:  # the ring as it stood
                 old_k, old_v = ring_k[at], ring_v[at]  # [B, W, K, .]
-                before = _ring_positions(start, w)
+                before = band_positions(start, w)
                 sources.insert(0, (1, lambda j: (old_k, old_v, before)))
                 new = (held >= start[:, None])[:, :, None, None]
                 new_k = jnp.where(new, new_k, old_k)

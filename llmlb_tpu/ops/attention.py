@@ -198,7 +198,10 @@ def _pool_shape(pages):
 
 
 def _window_pages(block_tables, page_size: int, window: int | None) -> int:
-    """Whole pages covering `window` cells, within the table's width."""
+    """Whole pages covering `window` cells, within the table's width.
+    `window` here and in every `window=` below is the engine's CONTEXT
+    BUCKET (a row's FIRST `window` cells), not a model's sliding window:
+    that is a lower bound a row, `kv_from` (paged_band_decode)."""
     ppn = block_tables.shape[1]
     return ppn if window is None else max(1, min(ppn, -(-window // page_size)))
 
@@ -233,7 +236,8 @@ def paged_attention_decode(
     work=None,  # paged_decode_work of the same tables, lengths and window
 ) -> jnp.ndarray:
     """One-token decode attention against one layer of the KV page pool.
-    `window` (STATIC) bounds what a row attends over, rounded up to whole
+    `window` (STATIC, the context bucket; a sliding window is `kv_from` of
+    paged_band_decode) bounds what a row attends over, rounded up to whole
     pages: a row attends over its first min(kv_lens, window) cells. A row
     with kv_lens 0 is not live (the engine's freed, never-used and
     prefilling slot rows): the Pallas kernels write it as zeros and read no
@@ -275,6 +279,68 @@ def paged_attention_decode(
     k_cache = gather_kv_pages(k_pages, tables, dtype=q.dtype, layer=layer)
     v_cache = gather_kv_pages(v_pages, tables, dtype=q.dtype, layer=layer)
     return gqa_attention_decode(q, k_cache, v_cache, kv_lens)
+
+
+def band_positions(kv_lens: jnp.ndarray, cells: int) -> jnp.ndarray:
+    """The position each cell of a band of `cells` cells a row holds once
+    the row is `kv_lens` [B] long — position p lives in cell p mod `cells`,
+    so cell c holds the largest p < len with p mod cells == c — and below 0
+    where there is none yet. [B, cells]."""
+    cell = jnp.arange(cells, dtype=jnp.int32)[None, :]
+    last = kv_lens[:, None] - 1 - cell  # >= 0 where the cell has been written
+    return jnp.where(last >= 0, cell + last // cells * cells, -1)
+
+
+def paged_band_work(k_pages, band_tables: jnp.ndarray, kv_lens: jnp.ndarray,
+                    kv_from: jnp.ndarray):
+    """paged_decode_work for paged_band_decode: built once a step for all
+    the layers that share the band's tables; nothing on the XLA route."""
+    if not _pallas_enabled():
+        return None
+    from llmlb_tpu.ops.pallas_attention import decode_work_list
+
+    return decode_work_list(band_tables, kv_lens,
+                            page_size=_pool_shape(k_pages)[2],
+                            kv_from=kv_from)
+
+
+BAND_DECODE = "paged_band_decode"  # the call's name in a device trace
+
+
+def paged_band_decode(
+    q: jnp.ndarray,  # [B, 1, H, D]
+    k_pages: jnp.ndarray,  # [L, P, PS, K, D] — the bands' pages, stacked
+    v_pages: jnp.ndarray,  # [L, P, PS, K, D]
+    layer,  # int32 scalar
+    band_tables: jnp.ndarray,  # [B, R] int32 — the row's own R pages
+    kv_lens: jnp.ndarray,  # [B] int32 — the row's length; 0 = not live
+    kv_from: jnp.ndarray,  # [B] int32 — the first position the row reads
+    work=None,  # paged_band_work of the same operands
+) -> jnp.ndarray:
+    """One-token decode attention under a SLIDING WINDOW held as a band of
+    R pages a row: position p lives in column (p // PS) mod R of the row's
+    table, cell p mod PS, so the band always holds the last (R - 1) x PS
+    positions whole. A row attends over positions `kv_from <= p < kv_lens`
+    (the caller keeps that span within the band: kv_lens - kv_from <= (R -
+    1) x PS). `kv_from` is the model's bound and a run-time value a row;
+    the static `window=` of paged_attention_decode is the context bucket
+    and another thing. On the Pallas route this is ONE call of
+    paged_flash_decode whose work-list holds only the pages of the span,
+    masked at both ends; the XLA fall-back gathers the R pages and masks by
+    the position each cell holds."""
+    if _pallas_enabled():
+        from llmlb_tpu.ops.pallas_attention import paged_flash_decode
+
+        _traced["band_decode"] = "pallas:" + BAND_DECODE
+        return paged_flash_decode(
+            q[:, 0], k_pages, v_pages, layer, band_tables, kv_lens,
+            work=work, kv_from=kv_from, name=BAND_DECODE)[:, None]
+    _traced["band_decode"] = "xla"
+    k_cache = gather_kv_pages(k_pages, band_tables, dtype=q.dtype, layer=layer)
+    v_cache = gather_kv_pages(v_pages, band_tables, dtype=q.dtype, layer=layer)
+    held = band_positions(kv_lens, k_cache.shape[1])
+    return gqa_attention_decode(q, k_cache, v_cache, kv_lens,
+                                valid=held >= kv_from[:, None])
 
 
 def paged_attention_extend(
@@ -332,11 +398,14 @@ def gqa_attention_decode(
     k_cache: jnp.ndarray,  # [B, S, K, D] — contiguous rows incl. current token
     v_cache: jnp.ndarray,  # [B, S, K, D]
     kv_lens: jnp.ndarray,  # [B] int32 — valid length per row (incl. current)
+    valid: jnp.ndarray | None = None,  # [B, S] bool — the cells seen instead
 ) -> jnp.ndarray:
     """One-token decode attention against contiguous per-row KV, plain
     einsum. Returns [B, 1, H, D]. The reference the paged decode kernels are
     checked against, and what the paged XLA fall-back
-    (paged_attention_decode) ends in after gathering its pages."""
+    (paged_attention_decode) ends in after gathering its pages. `valid`,
+    where given, says cell by cell what a row sees (a band's cells are not
+    in the positions' order: paged_band_decode)."""
     s = k_cache.shape[1]
     b, t, h, d = q.shape
     k_heads = k_cache.shape[2]
@@ -347,7 +416,8 @@ def gqa_attention_decode(
         "btkgd,bskd->bkgts", qg, k_cache, preferred_element_type=jnp.float32
     ) * scale  # [B, K, G, 1, S]
 
-    valid = jnp.arange(s, dtype=jnp.int32)[None, :] < kv_lens[:, None]  # [B, S]
+    if valid is None:
+        valid = jnp.arange(s, dtype=jnp.int32)[None, :] < kv_lens[:, None]
     scores = jnp.where(valid[:, None, None, None, :], scores, _NEG_INF)
 
     probs = jax.nn.softmax(scores, axis=-1)

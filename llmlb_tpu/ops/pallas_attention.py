@@ -113,6 +113,7 @@ def decode_work_list(
     *,
     page_size: int,
     pages: int | None = None,  # static: at most the first `pages` pages a row
+    kv_from: jnp.ndarray | None = None,  # [B] int32 — a row's first cell read
 ) -> DecodeWork:
     """The work-list of one decode step. A row of length n contributes its
     ceil(n / page_size) pages — at most `pages`: what lies beyond is not
@@ -121,11 +122,25 @@ def decode_work_list(
     the kernel writes that row's output as zeros and computes nothing; the
     item names the pool page of the item before it, and a block whose index
     repeats is not fetched again, so such a row costs one grid step and no
-    read of the pool. W = B x pages is static, `count` is a run-time value."""
-    b = block_tables.shape[0]
+    read of the pool. W = B x pages is static, `count` is a run-time value.
+
+    `kv_from` is a LOWER bound a row (a model's sliding window; not the
+    `pages` bucket, which bounds from above): a row's items are only the
+    logical pages that hold cells `kv_from <= c < n`, `page_of` their
+    logical index, and logical page p is column p mod PPN of the table — a
+    table as wide as the context is read as it always was, a BAND of R
+    pages a row (models/afmoe.py) wraps. The caller keeps a row's span
+    within `pages` pages."""
+    b, ppn = block_tables.shape
     sweep = _swept_pages(block_tables, pages)
     lens = kv_lens.astype(jnp.int32)
-    per_row = jnp.clip(-(-lens // page_size), 1, sweep)  # [B] items of a row
+    ends = -(-lens // page_size)  # [B] a row's pages up to its length
+    first = None
+    if kv_from is not None:
+        first = jnp.clip(kv_from.astype(jnp.int32), 0,
+                         jnp.maximum(lens - 1, 0)) // page_size
+        ends = ends - first
+    per_row = jnp.clip(ends, 1, sweep)  # [B] items of a row
     end = jnp.cumsum(per_row)
     item = jnp.arange(b * sweep, dtype=jnp.int32)
     # [W, B]: the rows that end at or before item i are the rows before its
@@ -136,9 +151,13 @@ def decode_work_list(
     page_of = jnp.clip(
         item - jnp.sum(jnp.where(ended, per_row[None, :], 0), axis=1),
         0, sweep - 1)
+    column = page_of
+    if first is not None:
+        page_of = first[row_of] + page_of
+        column = page_of % ppn
     # the nearest item at or before i that reads a page (item 0 if none does)
     reads = jax.lax.cummax(jnp.where(lens[row_of] > 0, item, 0))
-    pool_page_of = block_tables.astype(jnp.int32)[row_of, page_of][reads]
+    pool_page_of = block_tables.astype(jnp.int32)[row_of, column][reads]
     return DecodeWork(end[-1], row_of, page_of, pool_page_of)
 
 
@@ -152,22 +171,22 @@ def _layer_operand(layer) -> jnp.ndarray:
 # Index maps of the decode grid: item i, then the scalar-prefetch operands.
 
 
-def _pool_page_map(i, layer, row_of, page_of, pool_page_of, lens):
+def _pool_page_map(i, layer, row_of, page_of, pool_page_of, *lens):
     """KV values [L, P, PS, K, D]: the item's pool page, of the layer."""
     return (layer[0], pool_page_of[i], 0, 0, 0)
 
 
-def _pool_rows_map(i, layer, row_of, page_of, pool_page_of, lens):
+def _pool_rows_map(i, layer, row_of, page_of, pool_page_of, *lens):
     """KV values seen as [L, P, PS*K, D]: the same page, as its rows."""
     return (layer[0], pool_page_of[i], 0, 0)
 
 
-def _layer_scale_map(i, layer, row_of, page_of, pool_page_of, lens):
+def _layer_scale_map(i, layer, row_of, page_of, pool_page_of, *lens):
     """KV scales [P, PS, K] of one layer of an int8 pool: the same page."""
     return (pool_page_of[i], 0, 0)
 
 
-def _row_map(i, layer, row_of, page_of, pool_page_of, lens):
+def _row_map(i, layer, row_of, page_of, pool_page_of, *lens):
     """q and out [B, H, D]: the item's row, for every page of the row."""
     return (row_of[i], 0, 0)
 
@@ -175,7 +194,7 @@ def _row_map(i, layer, row_of, page_of, pool_page_of, lens):
 def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
                  m_ref, l_ref, acc_ref, page, *,
                  block_k: int, sweep: int, num_kv: int, scale: float,
-                 sink_ref=None):
+                 sink_ref=None, kv_from_ref=None):
     """One grid step of a paged decode kernel: item i of the work-list is
     page `s` of row `row`. Online softmax (m/l/acc) lives in VMEM scratch
     from a row's first item to its last. A row of length 0 has one item,
@@ -198,13 +217,26 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
     The values may be narrower than the keys (o, acc [H, Dv]). `sink_ref`
     ([H, 1] f32), where given, is a learnt logit a head that enters the
     softmax's denominator and takes no value: a row starts from m = sink,
-    l = 1 (exp(sink - m)), acc = 0, and goes on as any other."""
+    l = 1 (exp(sink - m)), acc = 0, and goes on as any other.
+
+    `kv_from_ref` ([B] in SMEM), where given, is each row's LOWER bound
+    (decode_work_list's `kv_from`): the row's items start at the page that
+    holds that cell and `s` is the item's LOGICAL page, so the mask holds at
+    both ends of the span — the oldest page's cells below the bound, the
+    newest page's at or past the length."""
     i = pl.program_id(0)
     s = page_of_ref[i]
     kv_len = kv_lens_ref[row_of_ref[i]]
-    last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+    if kv_from_ref is None:
+        first, kv_from = 0, None
+        last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+    else:
+        kv_from = jnp.clip(kv_from_ref[row_of_ref[i]], 0,
+                           jnp.maximum(kv_len - 1, 0))
+        first = kv_from // block_k
+        last = jnp.maximum(pl.cdiv(kv_len, block_k), 1) - 1
 
-    @pl.when(s == 0)
+    @pl.when(s == first)
     def _init():
         if sink_ref is None:
             m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -226,9 +258,11 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
         col = jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k * num_kv), dimension=1)
         row = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), dimension=0)
-        keep = jnp.logical_and(
-            col % num_kv == row // (heads // num_kv),
-            s * block_k + col // num_kv < kv_len)
+        own_head = col % num_kv == row // (heads // num_kv)
+        cell = s * block_k + col // num_kv
+        keep = jnp.logical_and(own_head, cell < kv_len)
+        if kv_from is not None:
+            keep = jnp.logical_and(keep, cell >= kv_from)
         scores = jnp.where(keep, scores, _NEG_INF)
         _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v)
 
@@ -278,6 +312,19 @@ def _paged_decode_sink_kernel(
                  sink_ref=sink_ref, **kw)
 
 
+def _paged_decode_from_kernel(
+    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
+    kv_from_ref,  # [B] — a row's first cell read
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+    **kw,
+):
+    """_paged_decode_kernel with a lower bound a row (_decode_item)."""
+    del layer_ref, pool_page_of_ref
+    _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
+                 m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]),
+                 kv_from_ref=kv_from_ref, **kw)
+
+
 def _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, dtype):
     """An int8 page's keys and values [1, PS, K, D] times their scales
     [1, PS, K], in `dtype` and as the page's [PS*K, D] rows."""
@@ -315,24 +362,26 @@ def _paged_decode_quant_kernel(
 
 def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
                        kv_lens, work, *, page_size, num_kv, pages, interpret,
-                       value_dim=None, name=None):
+                       value_dim=None, name=None, kv_from=None):
     """The pallas_call the paged decode kernels share: `grid=(work.count,)`
     — a run-time length — over the work-list's items; q and out blocks
     follow the item's row, the KV blocks (`kv_specs`, one per operand of
     `kv_operands`) its pool page. `value_dim`: the values' width where it is
     not the keys'; `name`: the call's name in a device trace where it is
-    not the calling function's."""
+    not the calling function's; `kv_from` ([B]): a sixth scalar-prefetch
+    operand, the rows' lower bounds, for a kernel that takes one."""
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
     dv = d if value_dim is None else value_dim
     if work is None:
         work = decode_work_list(block_tables, kv_lens, page_size=page_size,
-                                pages=pages)
+                                pages=pages, kv_from=kv_from)
+    bounds = () if kv_from is None else (kv_from.astype(jnp.int32),)
     row_spec = pl.BlockSpec((1, h, d), _row_map, memory_space=pltpu.VMEM)
     out_spec = pl.BlockSpec((1, h, dv), _row_map, memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + len(bounds),
         grid=(work.count,),
         in_specs=[row_spec, *kv_specs],
         out_specs=out_spec,
@@ -351,7 +400,7 @@ def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
         interpret=interpret,
         **({} if name is None else {"name": name}),
     )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
-      kv_lens.astype(jnp.int32), q, *kv_operands)
+      kv_lens.astype(jnp.int32), *bounds, q, *kv_operands)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret", "name"))
@@ -368,6 +417,7 @@ def paged_flash_decode(
     interpret: bool | None = None,
     sink: jnp.ndarray | None = None,  # [H] — a logit a head, without a value
     name: str | None = None,  # static: the call's name in a device trace
+    kv_from: jnp.ndarray | None = None,  # [B] int32 — a row's first cell read
 ) -> jnp.ndarray:
     """Ragged PAGED one-token GQA decode attention. Returns [B, H, Dv].
 
@@ -398,7 +448,17 @@ def paged_flash_decode(
     shape serves: a RING a slot [L, slots, cells, K, D] is a pool of one
     page a row, its table [B, 1] the rows' slots (models/mimo_v2.py).
     Without `sink`, and with values as wide as the keys, the call lowers to
-    what it always did."""
+    what it always did.
+
+    `kv_from` ([B], a LOWER bound a row: a model's sliding window — `pages`
+    is the context bucket, the first cells a row may read) makes a row
+    attend over cells `kv_from <= c < kv_lens` alone: its items are the
+    pages that hold them (decode_work_list), logical page p column p mod
+    PPN of `block_tables`, masked at both ends. The table may then be a
+    BAND of R pages a row that the positions wrap around
+    (models/afmoe.py)."""
+    if sink is not None and kv_from is not None:
+        raise NotImplementedError("a sink beside a lower bound: no kernel")
     layers, pool_pages, ps, num_kv, d = k_pages.shape
     dv = v_pages.shape[-1]
     # a page as its [PS*K, D] rows: in the chip's memory the same bytes
@@ -416,10 +476,13 @@ def paged_flash_decode(
         specs = [pl.BlockSpec((h, 1), lambda i, *_: (0, 0),
                               memory_space=pltpu.VMEM), *specs]
         operands = (sink.astype(jnp.float32).reshape(h, 1), *operands)
+    if kv_from is not None:
+        kernel = _paged_decode_from_kernel
     return _paged_decode_call(
         kernel, specs, operands, q, layer,
         block_tables, kv_lens, work, page_size=ps, num_kv=num_kv,
-        pages=pages, interpret=interpret, value_dim=dv, name=name)
+        pages=pages, interpret=interpret, value_dim=dv, name=name,
+        kv_from=kv_from)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))

@@ -11,7 +11,8 @@ this says nothing of times. It builds the configuration's family with the
 shapes of its weights and of a pool, compiles `--steps` decode steps under a
 scan (a block family: one block pass two blocks wide) with the kernels
 lowered through Mosaic, and prints one line a large result, largest first,
-then the compiler's own count of temporary bytes. A weight- or pool-shaped
+then the compiler's own count of temporary bytes (`--chunk N [--extend]`:
+the prefill, or the extend, of rows x N tokens instead). A weight- or pool-shaped
 line in a layout other than the stored one (`{2,1,0}` for a `[L, E, N]`
 stack) is a copy the program makes every step: PR 31's experts, PR 45's
 whole-pool copy and the transposed slices of `wq`, `wk`, `wv` that PR 47
@@ -80,8 +81,11 @@ def large_results(hlo: str, min_bytes: int) -> list[dict]:
 
 
 def compile_decode(config: dict, *, rows: int, pages: int, page_size: int,
-                   table: int, steps: int):
-    """The configuration's decode program, compiled for one described v5e."""
+                   table: int, steps: int, chunk: int = 0,
+                   extend: bool = False):
+    """The configuration's decode program, compiled for one described v5e;
+    with `chunk` its prefill of `rows` x `chunk` tokens instead, or with
+    `extend` too its extend of such a chunk."""
     # before jax is imported: the backend here is the CPU, and the compiler
     # is told which chip it describes (else it warns, and logs under /tmp)
     for name, value in (("JAX_PLATFORMS", "cpu"), ("TPU_LOG_DIR", "disabled"),
@@ -126,6 +130,16 @@ def compile_decode(config: dict, *, rows: int, pages: int, page_size: int,
     window = table * page_size
     block = family.FAMILY.block_length(cfg)
     with jax.default_matmul_precision("default"):
+        if chunk:
+            ids = on_chip(jax.ShapeDtypeStruct((rows, chunk), jnp.int32))
+            slots = {"slot_ids": ints} if slotted else {}
+            if extend:
+                return cfg, family.prefill_extend_pages.lower(
+                    params, cfg, ids, ints, ints, tables, *pools, None,
+                    **slots).compile()
+            return cfg, family.prefill_into_pages.lower(
+                params, cfg, ids, ints, tables, *pools, None,
+                **slots).compile()
         if block > 1:  # the scheduler's pass: two blocks wide, one's logits
             ids = on_chip(jax.ShapeDtypeStruct((rows, 2 * block), jnp.int32))
             return cfg, family.verify_step_paged.lower(
@@ -162,6 +176,10 @@ def main() -> int:
     ap.add_argument("--table", type=int, default=16,
                     help="pages a row's block table holds")
     ap.add_argument("--steps", type=int, default=2)
+    ap.add_argument("--chunk", type=int, default=0,
+                    help="compile the prefill of rows x CHUNK tokens instead")
+    ap.add_argument("--extend", action="store_true",
+                    help="with --chunk: the extend of such a chunk")
     ap.add_argument("--min-mb", type=float, default=1.0)
     ap.add_argument("--hlo", help="also write the compiled module's text here")
     args = ap.parse_args()
@@ -175,7 +193,8 @@ def main() -> int:
     rows = args.rows or engine.get("num_slots", 32)
     cfg, compiled = compile_decode(
         config, rows=rows, pages=args.pages or engine.get("kv_pages", 544),
-        page_size=args.page_size, table=args.table, steps=args.steps)
+        page_size=args.page_size, table=args.table, steps=args.steps,
+        chunk=args.chunk, extend=args.extend)
     hlo = compiled.as_text()
     if args.hlo:
         with open(args.hlo, "w") as f:

@@ -31,7 +31,8 @@ PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "nemotron_h": "debug-nemotron-h-tiny",
            "longcat_flash": "debug-longcat-tiny",
            "mimo_v2": "debug-mimo-tiny",
-           "olmo_hybrid": "debug-olmo-hybrid-tiny"}
+           "olmo_hybrid": "debug-olmo-hybrid-tiny",
+           "afmoe": "debug-trinity-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -174,7 +175,7 @@ OLD_MODEL_TYPES = {
     "mixtral": "mixtral", "deepseek_v3": "deepseek_v3",
     "sdar_moe": "sdar_moe", "nemotron_h": "nemotron_h",
     "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
-    "olmo_hybrid": "olmo_hybrid",
+    "olmo_hybrid": "olmo_hybrid", "afmoe": "afmoe",
 }
 OLD_MECHANISM_KEYS = {
     "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
@@ -185,29 +186,40 @@ OLD_MECHANISM_KEYS = {
     "n_shared_experts": ("deepseek_v3", "nemotron_h"),
     "first_k_dense_replace": ("deepseek_v3",),
     "num_local_experts": ("mixtral",),
-    "num_experts": ("mixtral", "sdar_moe"),
+    "num_experts": ("mixtral", "sdar_moe", "afmoe"),
     "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h",
-                              "mimo_v2"),
+                              "mimo_v2", "afmoe"),
     "hybrid_override_pattern": ("nemotron_h",),
     "mamba_num_heads": ("nemotron_h",),
     "ssm_state_size": ("nemotron_h",),
-    "expert_parallel": ("nemotron_h", "longcat_flash", "mimo_v2"),
+    "expert_parallel": ("nemotron_h", "longcat_flash", "mimo_v2", "afmoe"),
     # a window and a partial rotary embedding: computed by one family since
     # PR 45, refused for every other as they were for all
-    "sliding_window": ("mimo_v2",),
+    "sliding_window": ("mimo_v2", "afmoe"),
     "partial_rotary_factor": ("mimo_v2",),
     "hybrid_layer_pattern": ("mimo_v2",),
     "moe_layer_freq": ("mimo_v2",),
     "swa_num_key_value_heads": ("mimo_v2",),
     # kinds of layer and the linear-attention layers' sizes: computed by one
     # family since PR 48; before it no class read them and none refused them
-    "layer_types": ("olmo_hybrid",),
+    "layer_types": ("olmo_hybrid", "afmoe"),
     "linear_num_key_heads": ("olmo_hybrid",),
     "linear_num_value_heads": ("olmo_hybrid",),
     "linear_key_head_dim": ("olmo_hybrid",),
     "linear_value_head_dim": ("olmo_hybrid",),
     "linear_conv_kernel_dim": ("olmo_hybrid",),
     "linear_allow_neg_eigval": ("olmo_hybrid",),
+    # a window of pages, a norm on both sides, a scaled embedding and a
+    # sigmoid-routed mixture with a shared expert: one family since PR 52
+    # (`num_experts_per_tok`, which every mixture's config carries, is read
+    # by it and listed by none: a listed key is refused of all the others)
+    "num_dense_layers": ("afmoe",),
+    "num_shared_experts": ("afmoe",),
+    "route_norm": ("afmoe",),
+    "route_scale": ("afmoe",),
+    "score_func": ("afmoe",),
+    "mup_enabled": ("afmoe",),
+    "global_attn_every_n_layers": ("afmoe",),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
 }
@@ -270,8 +282,11 @@ LINEAR_STATED = [
 
 
 @pytest.mark.parametrize("stated", LINEAR_STATED, ids=lambda d: next(iter(d)))
+# (afmoe reads `layer_types` too and refuses a kind it does not attend by in
+# its own class, by name: tests/engine/test_band_family.py)
 @pytest.mark.parametrize("model_type", sorted(
-    set(OLD_MODEL_TYPES) - {"olmo_hybrid"}) + ["a_type_nobody_registered"])
+    set(OLD_MODEL_TYPES) - {"olmo_hybrid", "afmoe"})
+    + ["a_type_nobody_registered"])
 def test_a_linear_attention_config_is_served_as_no_other_model(
         model_type, stated):
     """Before PR 48 an unregistered `model_type` with `layer_types` and
@@ -286,6 +301,17 @@ def test_a_linear_attention_config_is_served_as_no_other_model(
             f"carries {key}={value!r}, which models/{family}.py does not "
             "compute")):
         config_from_hf(hf)
+
+
+@pytest.mark.parametrize("stated", LINEAR_STATED[2:],
+                         ids=lambda d: next(iter(d)))
+def test_a_linear_key_is_refused_of_the_window_band_family_too(stated):
+    (key, value), = stated.items()
+    with pytest.raises(ValueError, match=re.escape(
+            f"carries {key}={value!r}, which models/afmoe.py does not "
+            "compute")):
+        config_from_hf({"model_type": "afmoe", "intermediate_size": 128,
+                        **stated})
 
 
 @pytest.mark.parametrize("model_type", ["llama", "qwen2",

@@ -1,0 +1,146 @@
+"""The paged decode kernel under a LOWER bound a row (a sliding window held
+as a band of pages a slot: ops/attention.paged_band_decode, models/afmoe.py)
+against a dense masked softmax over the whole sequence: the bounded
+work-list names only the pages of the span, the mask holds at both ends, a
+band of R pages wraps, and the XLA fall-back gives the same. CPU, the Pallas
+interpreter."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llmlb_tpu.ops.attention import band_positions, paged_band_decode
+from llmlb_tpu.ops.pallas_attention import decode_work_list, paged_flash_decode
+
+PS, W, H, KV, D = 8, 16, 4, 2, 16
+R = W // PS + 1  # pages of a band
+LAYERS, SLOTS = 2, 3
+# below the window, at it, past it; at and around page boundaries; not live
+LENS = [1, 7, 8, 9, 15, 16, 17, 23, 24, 25, 40, 41, 47, 48, 100, 0]
+
+
+def _sequence(key, n):
+    """Keys and values [n, KV, D] of every position of one sequence."""
+    k1, k2 = jax.random.split(key)
+    return (jax.random.normal(k1, (n, KV, D), jnp.float32),
+            jax.random.normal(k2, (n, KV, D), jnp.float32))
+
+
+def _dense(q, k, v, lo, n):
+    """q [H, D] over positions lo <= p < n of k, v [N, KV, D]."""
+    if n == 0:
+        return np.zeros((H, D), np.float32)
+    k = np.repeat(np.asarray(k[lo:n]), H // KV, axis=1)  # [S, H, D]
+    v = np.repeat(np.asarray(v[lo:n]), H // KV, axis=1)
+    s = np.einsum("hd,shd->hs", np.asarray(q), k) / np.sqrt(D)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("hs,shd->hd", p / p.sum(-1, keepdims=True), v)
+
+
+def _band(seqs, lens, layer):
+    """The bands of `seqs` once each row is `lens` long: [LAYERS, (SLOTS +
+    1) x R, PS, KV, D], row i in slot i, position p in page (p // PS) mod R
+    of its slot and cell p mod PS; the other layer holds noise."""
+    shape = (LAYERS, (SLOTS + 1) * R, PS, KV, D)
+    pool_k = np.array(jax.random.normal(jax.random.PRNGKey(99), shape))
+    pool_v = np.array(pool_k[::-1])
+    for slot, ((k, v), n) in enumerate(zip(seqs, lens)):
+        for p in range(n):  # later positions overwrite: the band wraps
+            page = slot * R + p // PS % R
+            pool_k[layer, page, p % PS] = np.asarray(k[p])
+            pool_v[layer, page, p % PS] = np.asarray(v[p])
+    return jnp.asarray(pool_k), jnp.asarray(pool_v)
+
+
+@pytest.mark.parametrize("route", ["pallas", "xla"])
+@pytest.mark.parametrize("at", range(0, len(LENS), SLOTS))
+def test_a_band_decode_is_the_dense_softmax_over_the_window(at, route,
+                                                            monkeypatch):
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", route)
+    lens = (LENS[at:at + SLOTS] + [0] * SLOTS)[:SLOTS]
+    keys = jax.random.split(jax.random.PRNGKey(at), SLOTS + 1)
+    seqs = [_sequence(k, max(n, 1)) for k, n in zip(keys, lens)]
+    q = jax.random.normal(keys[-1], (SLOTS, 1, H, D), jnp.float32)
+    layer = at % LAYERS
+    pool_k, pool_v = _band(seqs, lens, layer)
+    tables = jnp.arange(SLOTS * R, dtype=jnp.int32).reshape(SLOTS, R)
+    kv_lens = jnp.asarray(lens, jnp.int32)
+    kv_from = jnp.maximum(kv_lens - W, 0)
+    got = paged_band_decode(q, pool_k, pool_v, layer, tables, kv_lens, kv_from)
+    for row, n in enumerate(lens):
+        if n == 0 and route == "xla":
+            continue  # finite and discarded (paged_attention_decode)
+        want = _dense(q[row, 0], *seqs[row], max(n - W, 0), n)
+        np.testing.assert_allclose(np.asarray(got[row, 0]), want, atol=2e-6)
+
+
+@pytest.mark.parametrize("n", [n for n in LENS if n])
+def test_the_bounded_work_list_names_the_spans_pages_alone(n):
+    """A row's items are the logical pages that hold `max(n - W, 0) <= p <
+    n`: ceil(n / PS) of them while n <= W, never more than R, each fetched
+    from column (page mod R) of the band's table; a row that is not live
+    beside it takes one item and reads nothing."""
+    tables = jnp.asarray([[10, 11, 12], [20, 21, 22]], jnp.int32)
+    lens = jnp.asarray([n, 0], jnp.int32)
+    work = decode_work_list(tables, lens, page_size=PS,
+                            kv_from=jnp.maximum(lens - W, 0))
+    first, last = max(n - W, 0) // PS, (n - 1) // PS
+    pages = list(range(first, last + 1))
+    count = int(work.count)
+    assert count == len(pages) + 1 and len(pages) <= R
+    if n <= W:
+        assert len(pages) == -(-n // PS)
+    assert (last - first + 1) * PS <= W + PS or len(pages) == R
+    assert np.asarray(work.row_of)[:count].tolist() == [0] * len(pages) + [1]
+    assert np.asarray(work.page_of)[:len(pages)].tolist() == pages
+    assert np.asarray(work.pool_page_of)[:count].tolist() == (
+        [10 + p % R for p in pages] + [10 + last % R])
+
+
+def test_without_a_bound_the_work_list_is_the_one_it_was():
+    tables = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    lens = jnp.asarray([9, 0, 30], jnp.int32)
+    plain = decode_work_list(tables, lens, page_size=PS)
+    zero = decode_work_list(tables, lens, page_size=PS,
+                            kv_from=jnp.zeros((3,), jnp.int32))
+    for a, b in zip(plain, zero):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_bound_over_a_table_as_wide_as_the_context_reads_the_last_pages():
+    """The same bound over an ordinary block table (no wrap: PPN covers the
+    context): only the pages of the span are items."""
+    n, ppn = 45, 8
+    k, v = _sequence(jax.random.PRNGKey(5), n)
+    pool = np.zeros((1, ppn + 1, PS, KV, D), np.float32)
+    pool_k, pool_v = pool.copy(), pool.copy()
+    for p in range(n):
+        pool_k[0, 1 + p // PS, p % PS] = np.asarray(k[p])
+        pool_v[0, 1 + p // PS, p % PS] = np.asarray(v[p])
+    tables = jnp.arange(1, ppn + 1, dtype=jnp.int32)[None]
+    q = jax.random.normal(jax.random.PRNGKey(6), (1, H, D), jnp.float32)
+    lens, lo = jnp.asarray([n], jnp.int32), jnp.asarray([n - W], jnp.int32)
+    work = decode_work_list(tables, lens, page_size=PS, kv_from=lo)
+    assert int(work.count) == 3  # positions 29..44: pages 3, 4, 5
+    got = paged_flash_decode(q, jnp.asarray(pool_k), jnp.asarray(pool_v), 0,
+                             tables, lens, work=work, kv_from=lo)
+    np.testing.assert_allclose(np.asarray(got[0]),
+                               _dense(q[0], k, v, n - W, n), atol=2e-6)
+
+
+def test_band_positions_are_the_last_positions_a_ring_holds():
+    held = np.asarray(band_positions(jnp.asarray([0, 5, 24, 30]), 24))
+    assert (held[0] == -1).all()
+    assert held[1].tolist() == list(range(5)) + [-1] * 19
+    assert held[2].tolist() == list(range(24))
+    assert held[3].tolist() == [24, 25, 26, 27, 28, 29] + list(range(6, 24))
+
+
+def test_a_sink_beside_a_bound_is_refused():
+    z = jnp.zeros((1, 2, PS, KV, D))
+    with pytest.raises(NotImplementedError, match="sink"):
+        paged_flash_decode(jnp.zeros((1, H, D)), z, z, 0,
+                           jnp.zeros((1, 2), jnp.int32),
+                           jnp.ones((1,), jnp.int32), sink=jnp.zeros((H,)),
+                           kv_from=jnp.zeros((1,), jnp.int32))
