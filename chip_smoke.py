@@ -882,6 +882,69 @@ def kernel_leg() -> int:
 
         attempt("paged_band_decode", f"B={b}", band_decode)
 
+    # a GROUP of a row's pages a grid step (PR 54) at the narrowest page
+    # the cells serve, Nemotron-3-Nano's 2 KV heads x 16 of 128 (four
+    # pages an item by pa.decode_group): rows whose pages are no multiple
+    # of four and a row in three not live, bf16 and int8; then the same
+    # heads under a lower bound over a band of 17 pages, spans that wrap the
+    # band inside a group and start mid-page
+    def grouped_decode(quant):
+        b, kv, d = 32, 2, 128
+        p = b * PPN + 1
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, p)).reshape(b, PPN), jnp.int32)
+        lens = jnp.asarray(rng.integers(1, PPN * PS + 1, b), jnp.int32)
+        lens = jnp.where(jnp.arange(b) % 3 == 1, 0, lens)
+        q = rand(b, 1, H, d)
+        pools = [rand(p, PS, kv, d) for _ in "kv"]
+        name = "paged_flash_decode_quant" if quant else "paged_flash_decode"
+        if quant:
+            qk, qv = ({m: stacked(jnp.asarray(x), 1) for m, x in zip(
+                "qs", quantize_kv(np.asarray(pool, np.float32)))}
+                for pool in pools)
+            want = xla.paged_attention_decode(q, qk, qv, 1, tables, lens)
+            got = pa.paged_flash_decode_quant(
+                q[:, 0], qk["q"], qk["s"][1], qv["q"], qv["s"][1], 1, tables,
+                lens, interpret=False)
+        else:
+            k_pages, v_pages = (stacked(pool, 1) for pool in pools)
+            want = xla.paged_attention_decode(q, k_pages, v_pages, 1, tables,
+                                              lens)
+            got = pa.paged_flash_decode(q[:, 0], k_pages, v_pages, 1, tables,
+                                        lens, interpret=False)
+        check(name, "K=2,group=4", got,
+              jnp.where((lens > 0)[:, None, None], want[:, 0], 0.0))
+        group = pa.decode_group(PS, kv, d, d, PPN)
+        work = pa.decode_work_list(tables, lens, page_size=PS, group=group)
+        items = jnp.where(lens > 0, -(-(-(-lens // PS)) // group), 1)
+        check(name, "K=2,group=4,items",
+              jnp.asarray([[float(group), float(work.count)]]),
+              jnp.asarray([[4.0, float(jnp.sum(items))]]))
+
+    for quant in (False, True):
+        attempt("paged_flash_decode_quant" if quant else "paged_flash_decode",
+                "K=2,group=4", functools.partial(grouped_decode, quant))
+
+    def grouped_band():
+        b, kv, d, w, r = 32, 2, 128, 2048, 17
+        band_k = stacked(rand((b + 1) * r, PS, kv, d), 1)
+        band_v = stacked(rand((b + 1) * r, PS, kv, d), 1)
+        at = (2049, 2175, 2177, 3000, 4351, 5000, 6001, 127, 1)
+        lens = jnp.asarray([at[i % len(at)] for i in range(b)], jnp.int32)
+        lens = jnp.where(jnp.arange(b) % 3 == 1, 0, lens)
+        low = jnp.maximum(lens - w, 0)
+        tables = (jnp.arange(b, dtype=jnp.int32)[:, None] * r
+                  + jnp.arange(r, dtype=jnp.int32)[None])
+        q = rand(b, 1, H, d)
+        want = xla.paged_band_decode(q, band_k, band_v, 1, tables, lens, low)
+        got = pa.paged_flash_decode(
+            q[:, 0], band_k, band_v, 1, tables, lens, kv_from=low,
+            name=xla.BAND_DECODE, interpret=False)
+        check("paged_band_decode", "K=2,group=4", got,
+              jnp.where((lens > 0)[:, None, None], want[:, 0], 0.0))
+
+    attempt("paged_band_decode", "K=2,group=4", grouped_band)
+
     # prefill: causal self-attention over a bucketed prompt
     for b, t in ((8, 128), (2, 512)):
         def prefill():

@@ -98,6 +98,7 @@ from llmlb_tpu.ops.attention import (
     _window_pages,
     band_positions,
     gather_kv_pages,
+    note_decode_group,
     paged_decode_work,
 )
 from llmlb_tpu.ops.norms import rms_norm
@@ -675,6 +676,7 @@ def _ring_decode(q, ring_k, ring_v, layer, slots, kv_lens, sink, shared):
         if "work" not in shared:
             shared["work"] = decode_work_list(
                 table, kv_lens, page_size=ring_k.shape[2], pages=1)
+        note_decode_group(WINDOW_DECODE, shared["work"])
         return paged_flash_decode(
             q[:, 0], ring_k, ring_v, layer, table, kv_lens, pages=1,
             work=shared["work"], sink=sink, name=WINDOW_DECODE)[:, None]

@@ -46,12 +46,21 @@ def _pallas_enabled() -> bool:
 # What each dispatcher below resolved to the last time it was traced, by op
 # ("paged_decode" -> "pallas:paged_flash_decode" or "xla"). The engine's
 # /api/health serves it, so "which kernel ran" is read off the dispatch
-# itself instead of a rule restated elsewhere. One key maps further:
+# itself instead of a rule restated elsewhere. Two keys map further:
 # "paged_extend_body" -> {queries of a traced chunk: "page" | "heads"}, the
 # form of the Pallas extend kernels' grid step each extend program holds
 # (pallas_attention.extend_body: static a program, so "how often" is "in
-# which programs").
-_traced: dict[str, str | dict[str, str]] = {}
+# which programs"); "paged_decode_group" -> {a paged decode call's name in a
+# device trace: the pages its grid step takes} (pallas_attention.
+# decode_group: static by shape, so with the step records' live pages it
+# says how many grid steps a step ran).
+_traced: dict[str, str | dict[str, str | int]] = {}
+
+
+def note_decode_group(name: str, work) -> None:
+    """Record under "paged_decode_group" the pages a grid step of the paged
+    decode call `name` takes: its work-list's own group."""
+    _traced.setdefault("paged_decode_group", {})[name] = work.group
 
 
 def attention_mode() -> str:
@@ -59,7 +68,7 @@ def attention_mode() -> str:
     return "pallas" if _pallas_enabled() else "xla"
 
 
-def traced_routes() -> dict[str, str | dict[str, str]]:
+def traced_routes() -> dict[str, str | dict[str, str | int]]:
     """op -> kernel for every attention dispatcher traced so far."""
     return {op: dict(route) if isinstance(route, dict) else route
             for op, route in _traced.items()}
@@ -214,15 +223,22 @@ def paged_decode_work(
 ):
     """What a decode step builds ONCE and hands every layer's
     paged_attention_decode as `work`: on the Pallas route the kernels' grid,
-    the work-list of live (row, page) pairs (pallas_attention.
-    decode_work_list); on the XLA route nothing."""
+    the work-list of the live rows' pages, a group of a row's pages an item
+    (pallas_attention.decode_work_list, decode_group); on the XLA route
+    nothing."""
     if not _pallas_enabled():
         return None
-    from llmlb_tpu.ops.pallas_attention import decode_work_list
+    from llmlb_tpu.ops.pallas_attention import decode_group, decode_work_list
 
-    ps = _pool_shape(k_pages)[2]
-    return decode_work_list(block_tables, kv_lens, page_size=ps,
-                            pages=_window_pages(block_tables, ps, window))
+    shape = _pool_shape(k_pages)
+    ps = shape[2]
+    pages = _window_pages(block_tables, ps, window)
+    group = 1  # the latent and the flat kernel's pools have no head axis
+    if len(shape) == 5:
+        num_kv, d = shape[3:]
+        group = decode_group(ps, num_kv, d, d, pages)
+    return decode_work_list(block_tables, kv_lens, page_size=ps, pages=pages,
+                            group=group)
 
 
 def paged_attention_decode(
@@ -258,10 +274,13 @@ def paged_attention_decode(
     ppn = block_tables.shape[1]
     pages = _window_pages(block_tables, ps, window)
     if _pallas_enabled():
+        if work is None:
+            work = paged_decode_work(k_pages, block_tables, kv_lens, window)
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_decode_quant
 
             _traced["paged_decode"] = "pallas:paged_flash_decode_quant"
+            note_decode_group("paged_flash_decode_quant", work)
             return paged_flash_decode_quant(
                 q[:, 0], k_pages["q"], k_pages["s"][layer], v_pages["q"],
                 v_pages["s"][layer], layer, block_tables, kv_lens,
@@ -270,6 +289,7 @@ def paged_attention_decode(
         from llmlb_tpu.ops.pallas_attention import paged_flash_decode
 
         _traced["paged_decode"] = "pallas:paged_flash_decode"
+        note_decode_group("paged_flash_decode", work)
         return paged_flash_decode(
             q[:, 0], k_pages, v_pages, layer, block_tables, kv_lens,
             pages=pages, work=work,
@@ -297,11 +317,12 @@ def paged_band_work(k_pages, band_tables: jnp.ndarray, kv_lens: jnp.ndarray,
     the layers that share the band's tables; nothing on the XLA route."""
     if not _pallas_enabled():
         return None
-    from llmlb_tpu.ops.pallas_attention import decode_work_list
+    from llmlb_tpu.ops.pallas_attention import decode_group, decode_work_list
 
-    return decode_work_list(band_tables, kv_lens,
-                            page_size=_pool_shape(k_pages)[2],
-                            kv_from=kv_from)
+    _, _, ps, num_kv, d = _pool_shape(k_pages)
+    return decode_work_list(
+        band_tables, kv_lens, page_size=ps, kv_from=kv_from,
+        group=decode_group(ps, num_kv, d, d, band_tables.shape[1]))
 
 
 BAND_DECODE = "paged_band_decode"  # the call's name in a device trace
@@ -331,7 +352,10 @@ def paged_band_decode(
     if _pallas_enabled():
         from llmlb_tpu.ops.pallas_attention import paged_flash_decode
 
+        if work is None:
+            work = paged_band_work(k_pages, band_tables, kv_lens, kv_from)
         _traced["band_decode"] = "pallas:" + BAND_DECODE
+        note_decode_group(BAND_DECODE, work)
         return paged_flash_decode(
             q[:, 0], k_pages, v_pages, layer, band_tables, kv_lens,
             work=work, kv_from=kv_from, name=BAND_DECODE)[:, None]

@@ -6,18 +6,20 @@ attention Pallas kernel"). The XLA einsum paths in ops/attention.py are the
 correctness baselines; these kernels replace them on TPU:
 
 - `paged_flash_decode` (+ `_quant`): one-token GQA attention against the KV
-  page pool. The grid is a WORK-LIST of the live (row, page) pairs
-  (`decode_work_list`), a row's pages consecutive, so Pallas's grid pipeline
-  double-buffers the next page's DMA behind the current page's compute;
-  its length is a run-time value. Online softmax (m/l/acc) lives in VMEM
-  scratch across a row's pages. The item arrays and the per-row `kv_lens`
-  arrive via scalar prefetch (SMEM). A row of length 0 is not live (a
-  serving batch's freed, never-used and prefilling slots): it takes one
-  grid step that writes zeros and reads no page. Decode cost scales with
-  the pages the live rows hold — not with the row capacity, the table's
-  width or the batch's window bucket. A grid step takes its page as it is
-  stored, [PS*K, D], and every query head against it in one masked product
-  (`_decode_item`): no per-head slice of the page.
+  page pool. The grid is a WORK-LIST of the live rows' pages, a GROUP of a
+  row's consecutive pages an item (`decode_work_list`; `decode_group`
+  sizes the group from the shapes: about a megabyte a grid step), a row's
+  items consecutive, so Pallas's grid pipeline double-buffers the next
+  group's DMAs behind the current group's compute; its length is a
+  run-time value. Online softmax (m/l/acc) lives in VMEM scratch across a
+  row's items. The item arrays and the per-row `kv_lens` arrive via scalar
+  prefetch (SMEM). A row of length 0 is not live (a serving batch's freed,
+  never-used and prefilling slots): it takes one grid step that writes
+  zeros and reads no page. Decode cost scales with the pages the live rows
+  hold — not with the row capacity, the table's width or the batch's
+  window bucket. A grid step takes its pages as they are stored, one under
+  another [G*PS*K, D], and every query head against them in ONE masked
+  product (`_decode_item`): no per-head slice of a page, no product a page.
 - `paged_flash_extend` (+ `_quant`): a chunk of queries against the pool
   (chunked prefill, speculative verify, a block pass of generation by
   diffusion), the stacked pool read in place at (layer, page of the row's
@@ -85,19 +87,26 @@ def _online_update(m_ref, l_ref, acc_ref, idx, scores, v):
 
 # ---------------------------------------------------------------------------
 # Paged decode: q [B, H, D] vs the stacked page pool [L, P, PS, K, D] at one
-# layer. The grid is a work-list of the live (row, page) pairs.
+# layer. The grid is a work-list of the live rows' pages, a group an item.
 # ---------------------------------------------------------------------------
 
 
 class DecodeWork(NamedTuple):
-    """The paged decode kernels' grid: one item per page a row attends over,
-    rows in order and a row's pages in order. Built by `decode_work_list`
-    once a decode step and shared by every layer's call."""
+    """The paged decode kernels' grid: one item per GROUP of consecutive
+    logical pages a row attends over, rows in order and a row's groups in
+    order. Built by `decode_work_list` once a decode step and shared by
+    every layer's call. The group G is the arrays' own: `pool_page_of` holds
+    G entries an item."""
 
     count: jnp.ndarray  # [] int32 — items in use: the grid's run-time length
     row_of: jnp.ndarray  # [W] int32 — the row item i belongs to
-    page_of: jnp.ndarray  # [W] int32 — its logical page within that row
-    pool_page_of: jnp.ndarray  # [W] int32 — the pool page its step fetches
+    page_of: jnp.ndarray  # [W] int32 — its first logical page within that row
+    pool_page_of: jnp.ndarray  # [W * G] int32 — the pool pages its step fetches
+
+    @property
+    def group(self) -> int:
+        """Pages an item holds (static: the arrays' shapes)."""
+        return self.pool_page_of.shape[0] // self.row_of.shape[0]
 
 
 def _swept_pages(block_tables, pages: int | None) -> int:
@@ -107,6 +116,31 @@ def _swept_pages(block_tables, pages: int | None) -> int:
     return ppn if pages is None else max(1, min(pages, ppn))
 
 
+# Keys and values a grid step of paged_flash_decode takes at most, in
+# elements: a megabyte of bf16. A grid step costs about 0.3 us over its
+# bytes whatever they are (scripts/decode_page_cost.py, PERF.md §6 PR 54),
+# and a page of 2 KV heads is 131 KB, 0.16 us of the memory's time.
+_GROUP_ELEMENTS = (1 << 20) // 2
+# ... in pages: a page of the group is a block operand of the call, whose
+# index map every program that holds the kernel traces and lowers at every
+# start (4.7 ms a block; at 8 pages a cell's set-up read a fifth more on its
+# prewarm thread), and a step's product covers the whole group whatever of
+# it is live (2 pages a row at a group of 8 cost what 8 do)
+_GROUP_MAX = 4
+
+
+def decode_group(page_size: int, num_kv: int, head_dim: int, value_dim: int,
+                 sweep: int) -> int:
+    """How many of a row's pages one grid step of paged_flash_decode takes:
+    a function of the shapes alone, the same where the work-list is built
+    and where the kernel is called. As many pages [PS, K, D + Dv] as make
+    about a megabyte of bf16 — 2 at 8 KV heads of 128, 4 at 4 and at 2 (half
+    a megabyte there), 1 at 32 — no more than the `sweep` a row has, and no
+    more than 4."""
+    page = page_size * num_kv * (head_dim + value_dim)
+    return max(1, min(_GROUP_ELEMENTS // page, sweep, _GROUP_MAX))
+
+
 def decode_work_list(
     block_tables: jnp.ndarray,  # [B, PPN] int32 — logical page i of row b
     kv_lens: jnp.ndarray,  # [B] int32 — valid length per row; 0 = not live
@@ -114,25 +148,38 @@ def decode_work_list(
     page_size: int,
     pages: int | None = None,  # static: at most the first `pages` pages a row
     kv_from: jnp.ndarray | None = None,  # [B] int32 — a row's first cell read
+    group: int = 1,  # static: logical pages an item holds
 ) -> DecodeWork:
-    """The work-list of one decode step. A row of length n contributes its
+    """The work-list of one decode step. A row of length n attends over its
     ceil(n / page_size) pages — at most `pages`: what lies beyond is not
-    attended over, as the XLA route's sliced table has it. A row of length 0
-    (not live: freed, never used, prefilling) contributes ONE item, on which
-    the kernel writes that row's output as zeros and computes nothing; the
-    item names the pool page of the item before it, and a block whose index
-    repeats is not fetched again, so such a row costs one grid step and no
-    read of the pool. W = B x pages is static, `count` is a run-time value.
+    attended over, as the XLA route's sliced table has it — and contributes
+    one item per `group` of them, the last group short where the pages are
+    no multiple. A row of length 0 (not live: freed, never used, prefilling)
+    contributes ONE item, on which the kernel writes that row's output as
+    zeros and computes nothing. W = B x ceil(pages / group) is static,
+    `count` is a run-time value.
+
+    An item names `group` pool pages, entry g the page its step's g-th KV
+    block fetches. An entry that stands for no page (a short last group's,
+    a row's that is not live) repeats what the g-th block fetched last —
+    before it has fetched any, ONE page of the step, the first live item's
+    first — and a block whose index repeats is not fetched again: a step
+    reads each live page once and, for each of the G blocks that no row is
+    long enough to fill, that one page once. The kernel masks such an
+    entry's cells by the row's length, and what it repeats is a page a live
+    row attends over (a masked cell's weight is an exact 0, and 0 x NaN is
+    not); the product still covers the whole group.
 
     `kv_from` is a LOWER bound a row (a model's sliding window; not the
-    `pages` bucket, which bounds from above): a row's items are only the
-    logical pages that hold cells `kv_from <= c < n`, `page_of` their
-    logical index, and logical page p is column p mod PPN of the table — a
-    table as wide as the context is read as it always was, a BAND of R
-    pages a row (models/afmoe.py) wraps. The caller keeps a row's span
-    within `pages` pages."""
+    `pages` bucket, which bounds from above): a row's items cover only the
+    logical pages that hold cells `kv_from <= c < n`, `page_of` the logical
+    index of an item's first, and logical page p is column p mod PPN of the
+    table — a table as wide as the context is read as it always was, a BAND
+    of R pages a row (models/afmoe.py) wraps, a page at a time inside a
+    group too. The caller keeps a row's span within `pages` pages."""
     b, ppn = block_tables.shape
     sweep = _swept_pages(block_tables, pages)
+    groups = -(-sweep // group)  # items of a row at most
     lens = kv_lens.astype(jnp.int32)
     ends = -(-lens // page_size)  # [B] a row's pages up to its length
     first = None
@@ -140,25 +187,43 @@ def decode_work_list(
         first = jnp.clip(kv_from.astype(jnp.int32), 0,
                          jnp.maximum(lens - 1, 0)) // page_size
         ends = ends - first
-    per_row = jnp.clip(ends, 1, sweep)  # [B] items of a row
+    row_pages = jnp.clip(ends, 1, sweep)  # [B] pages of a row in the list
+    per_row = -(-row_pages // group)  # [B] items of a row
     end = jnp.cumsum(per_row)
-    item = jnp.arange(b * sweep, dtype=jnp.int32)
+    item = jnp.arange(b * groups, dtype=jnp.int32)
     # [W, B]: the rows that end at or before item i are the rows before its
     # own, and their items are the items before its row's first
     ended = item[:, None] >= end[None, :]
     # items past `count` are never visited; they only have to index in range
     row_of = jnp.minimum(jnp.sum(ended, axis=1, dtype=jnp.int32), b - 1)
-    page_of = jnp.clip(
+    page_of = group * jnp.clip(
         item - jnp.sum(jnp.where(ended, per_row[None, :], 0), axis=1),
-        0, sweep - 1)
-    column = page_of
+        0, groups - 1)
+    # [W, G]: the pages of an item, and which of them are pages of its row
+    # (an item's first always is, where the row is live)
+    slot = jnp.arange(group, dtype=jnp.int32)[None, :]
+    within = page_of[:, None] + slot
+    real = jnp.logical_and(
+        lens[row_of][:, None] > 0,
+        jnp.logical_or(slot == 0, within < row_pages[row_of][:, None]))
+    column = jnp.minimum(within, sweep - 1)
     if first is not None:
         page_of = first[row_of] + page_of
-        column = page_of % ppn
-    # the nearest item at or before i that reads a page (item 0 if none does)
-    reads = jax.lax.cummax(jnp.where(lens[row_of] > 0, item, 0))
-    pool_page_of = block_tables.astype(jnp.int32)[row_of, column][reads]
-    return DecodeWork(end[-1], row_of, page_of, pool_page_of)
+        column = (first[row_of][:, None] + within) % ppn
+    # the nearest item at or before i whose g-th entry reads a page (item
+    # 0's, for an entry 0 before any does); for a later entry before any
+    # does, the first live item's first page, the same for every such item:
+    # one fetch, and a block in VMEM beside a live row's page is always a
+    # page some live row attends over
+    nearest = jax.lax.cummax(jnp.where(real, item[:, None], -1), axis=0)
+    fetched = block_tables.astype(jnp.int32)[row_of[:, None], column]
+    pool_page_of = jnp.take_along_axis(fetched, jnp.maximum(nearest, 0),
+                                       axis=0)
+    if group > 1:
+        pool_page_of = jnp.where(
+            jnp.logical_or(nearest >= 0, slot == 0), pool_page_of,
+            fetched[jnp.argmax(real[:, 0]), 0])
+    return DecodeWork(end[-1], row_of, page_of, pool_page_of.reshape(-1))
 
 
 def _layer_operand(layer) -> jnp.ndarray:
@@ -169,50 +234,67 @@ def _layer_operand(layer) -> jnp.ndarray:
 
 
 # Index maps of the decode grid: item i, then the scalar-prefetch operands.
+# A KV block's map takes the entry `g` of the item's `group` it fetches.
 
 
-def _pool_page_map(i, layer, row_of, page_of, pool_page_of, *lens):
-    """KV values [L, P, PS, K, D]: the item's pool page, of the layer."""
-    return (layer[0], pool_page_of[i], 0, 0, 0)
+def _group_entry(i, g: int, group: int):
+    return i if group == 1 else i * group + g
 
 
-def _pool_rows_map(i, layer, row_of, page_of, pool_page_of, *lens):
+def _pool_page_map(g, group, i, layer, row_of, page_of, pool_page_of, *lens):
+    """KV values [L, P, PS, K, D]: the item's g-th pool page, of the layer."""
+    return (layer[0], pool_page_of[_group_entry(i, g, group)], 0, 0, 0)
+
+
+def _pool_rows_map(g, group, i, layer, row_of, page_of, pool_page_of, *lens):
     """KV values seen as [L, P, PS*K, D]: the same page, as its rows."""
-    return (layer[0], pool_page_of[i], 0, 0)
+    return (layer[0], pool_page_of[_group_entry(i, g, group)], 0, 0)
 
 
-def _layer_scale_map(i, layer, row_of, page_of, pool_page_of, *lens):
+def _layer_scale_map(g, group, i, layer, row_of, page_of, pool_page_of,
+                     *lens):
     """KV scales [P, PS, K] of one layer of an int8 pool: the same page."""
-    return (pool_page_of[i], 0, 0)
+    return (pool_page_of[_group_entry(i, g, group)], 0, 0)
 
 
 def _row_map(i, layer, row_of, page_of, pool_page_of, *lens):
-    """q and out [B, H, D]: the item's row, for every page of the row."""
+    """q and out [B, H, D]: the item's row, for every item of the row."""
     return (row_of[i], 0, 0)
 
 
-def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, page, *,
-                 block_k: int, sweep: int, num_kv: int, scale: float,
-                 sink_ref=None, kv_from_ref=None):
-    """One grid step of a paged decode kernel: item i of the work-list is
-    page `s` of row `row`. Online softmax (m/l/acc) lives in VMEM scratch
-    from a row's first item to its last. A row of length 0 has one item,
-    computes nothing and is written as zeros (l == 0).
+def _stacked(refs):
+    """The group's blocks [1, N, ...] one under another: [G*N, ...]."""
+    blocks = [ref[0] for ref in refs]
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=0)
 
-    The page is taken as it is stored: `page()` gives the step's keys and
-    values as [PS*K, D], row t*K + h the vector of cell t and KV head h, and
-    all H = K*G query heads (head-major: row r belongs to KV head r // G)
-    meet it in ONE product, one softmax update and one product. Entry (r, c)
-    of the scores counts where column c's KV head is row r's and its cell is
-    live; every other entry is -1e30, whose exp is exactly 0, so the result
-    is the attention of each head over its own keys. The other heads'
-    columns are work the MXU does for nothing: 4*H*D*PS*K FLOP a page
-    against its 4*PS*K*D bytes, H FLOP a byte (32 at Mistral-7B's heads, 64
-    at 64) where the chip's ridge is about 240 — the kernel stays bound by
-    its bytes. (Slicing a head out of the page instead takes one sublane of
-    every tile, 2*K times a page, for K products four rows tall: 1.6 us a
-    page of 512 KB whose bytes take 0.64, PERF.md §6, PR 43.)
+
+def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
+                 m_ref, l_ref, acc_ref, pages, *,
+                 block_k: int, sweep: int, num_kv: int, scale: float,
+                 group: int = 1, sink_ref=None, kv_from_ref=None):
+    """One grid step of a paged decode kernel: item i of the work-list is
+    the `group` logical pages from `s` on of row `row`. Online softmax
+    (m/l/acc) lives in VMEM scratch from a row's first item to its last. A
+    row of length 0 has one item, computes nothing and is written as zeros
+    (l == 0).
+
+    The pages are taken as they are stored: `pages()` gives the step's keys
+    and values as [G*PS*K, D], row (g*PS + t)*K + h the vector of cell t of
+    the group's g-th page and KV head h, and all H = K*G' query heads
+    (head-major: row r belongs to KV head r // G') meet the whole group in
+    ONE product, one softmax update and one product: the body is the same
+    size whatever the group. Entry (r, c) of the scores counts where column
+    c's KV head is row r's and its cell is live; every other entry is
+    -1e30, whose exp is exactly 0, so the result is the attention of each
+    head over its own keys. A column's cell is s*PS + c // K whichever page
+    of the group holds it, so the cells of a short last group's missing
+    pages lie at or past the row's length and are masked with the rest. The
+    other heads' columns are work the MXU does for nothing: 4*H*D*PS*K FLOP
+    a page against its 4*PS*K*D bytes, H FLOP a byte (32 at Mistral-7B's
+    heads, 64 at 64) where the chip's ridge is about 240 — the kernel stays
+    bound by its bytes. (Slicing a head out of the page instead takes one
+    sublane of every tile, 2*K times a page, for K products four rows tall:
+    1.6 us a page of 512 KB whose bytes take 0.64, PERF.md §6, PR 43.)
 
     The values may be narrower than the keys (o, acc [H, Dv]). `sink_ref`
     ([H, 1] f32), where given, is a learnt logit a head that enters the
@@ -221,15 +303,17 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
 
     `kv_from_ref` ([B] in SMEM), where given, is each row's LOWER bound
     (decode_work_list's `kv_from`): the row's items start at the page that
-    holds that cell and `s` is the item's LOGICAL page, so the mask holds at
-    both ends of the span — the oldest page's cells below the bound, the
-    newest page's at or past the length."""
+    holds that cell and `s` is the item's first LOGICAL page, so the mask
+    holds at both ends of the span — the oldest page's cells below the
+    bound, the newest page's at or past the length."""
     i = pl.program_id(0)
     s = page_of_ref[i]
     kv_len = kv_lens_ref[row_of_ref[i]]
     if kv_from_ref is None:
         first, kv_from = 0, None
         last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+        if group > 1:  # a group may reach past the `pages` a row is read to
+            kv_len = jnp.minimum(kv_len, sweep * block_k)
     else:
         kv_from = jnp.clip(kv_from_ref[row_of_ref[i]], 0,
                            jnp.maximum(kv_len - 1, 0))
@@ -249,14 +333,14 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
     @pl.when(s * block_k < kv_len)
     def _compute():
         q = q_ref[0]  # [H, D]
-        k, v = page()  # [PS*K, D], [PS*K, Dv]
+        k, v = pages()  # [G*PS*K, D], [G*PS*K, Dv]
         heads = q.shape[0]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [H, PS*K]
+        ) * scale  # [H, G*PS*K]
         col = jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k * num_kv), dimension=1)
+            jnp.int32, (1, group * block_k * num_kv), dimension=1)
         row = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), dimension=0)
         own_head = col % num_kv == row // (heads // num_kv)
         cell = s * block_k + col // num_kv
@@ -266,7 +350,7 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
         scores = jnp.where(keep, scores, _NEG_INF)
         _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v)
 
-    @pl.when(s == last)
+    @pl.when(s == last if group == 1 else s + group > last)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -277,113 +361,111 @@ def _paged_decode_kernel(
     # scalar prefetch (SMEM); the layer and the pool pages are consumed by
     # the BlockSpec index maps, which pick what each grid step DMAs
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
-    # inputs
-    q_ref,  # [1, H, D]
-    k_ref,  # [1, PS*K, D]
-    v_ref,  # [1, PS*K, D]
-    # output
-    o_ref,  # [1, H, D]
-    # scratch
-    m_ref,  # [H, 1] f32
-    l_ref,  # [H, 1] f32
-    acc_ref,  # [H, D] f32
-    **kw,
+    *refs,
+    group: int, sink: bool = False, bound: bool = False, **kw,
 ):
+    """`refs`, in the call's order: the rows' lower bounds [B] (SMEM, the
+    sixth scalar-prefetch operand) where `bound`; q [1, H, D]; the sinks
+    [H, 1] f32 where `sink`; the group's key blocks, G of [1, PS*K, D], and
+    its value blocks, G of [1, PS*K, Dv]; the output [1, H, Dv]; the
+    scratch m, l [H, 1] and acc [H, Dv], f32."""
     del layer_ref, pool_page_of_ref
+    refs = list(refs)
+    kv_from_ref = refs.pop(0) if bound else None
+    q_ref = refs.pop(0)
+    sink_ref = refs.pop(0) if sink else None
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
     _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]), **kw)
+                 m_ref, l_ref, acc_ref,
+                 lambda: (_stacked(k_refs), _stacked(v_refs)),
+                 group=group, sink_ref=sink_ref, kv_from_ref=kv_from_ref,
+                 **kw)
 
 
-def _paged_decode_sink_kernel(
-    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
-    q_ref,  # [1, H, D]
-    sink_ref,  # [H, 1] f32
-    k_ref,  # [1, PS*K, D]
-    v_ref,  # [1, PS*K, Dv]
-    o_ref,  # [1, H, Dv]
-    m_ref, l_ref, acc_ref,
-    **kw,
-):
-    """_paged_decode_kernel with a sink a head in the softmax's denominator
-    (_decode_item)."""
-    del layer_ref, pool_page_of_ref
-    _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]),
-                 sink_ref=sink_ref, **kw)
+def _dequantized_pages(k_refs, ks_refs, v_refs, vs_refs, dtype):
+    """A group of int8 pages' keys and values, G of [1, PS, K, D], times
+    their scales, G of [1, PS, K], in `dtype` and as the group's [G*PS*K,
+    D] rows."""
+    def dequantized(refs, scale_refs):
+        return (_stacked(refs).astype(jnp.float32)
+                * _stacked(scale_refs)[:, :, None]).astype(dtype)
 
-
-def _paged_decode_from_kernel(
-    layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
-    kv_from_ref,  # [B] — a row's first cell read
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    **kw,
-):
-    """_paged_decode_kernel with a lower bound a row (_decode_item)."""
-    del layer_ref, pool_page_of_ref
-    _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, lambda: (k_ref[0], v_ref[0]),
-                 kv_from_ref=kv_from_ref, **kw)
-
-
-def _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, dtype):
-    """An int8 page's keys and values [1, PS, K, D] times their scales
-    [1, PS, K], in `dtype` and as the page's [PS*K, D] rows."""
-    _, ps, num_kv, d = k_ref.shape
-    k = (k_ref[0].astype(jnp.float32) * ks_ref[0][:, :, None]).astype(dtype)
-    v = (v_ref[0].astype(jnp.float32) * vs_ref[0][:, :, None]).astype(dtype)
-    return k.reshape(ps * num_kv, d), v.reshape(ps * num_kv, d)
+    k, v = dequantized(k_refs, ks_refs), dequantized(v_refs, vs_refs)
+    cells, num_kv, d = k.shape
+    return k.reshape(cells * num_kv, d), v.reshape(cells * num_kv, d)
 
 
 def _paged_decode_quant_kernel(
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
     q_ref,  # [1, H, D]
-    k_ref,  # [1, PS, K, D] int8
-    ks_ref,  # [1, PS, K] f32
-    v_ref,  # [1, PS, K, D] int8
-    vs_ref,  # [1, PS, K] f32
-    o_ref,  # [1, H, D]
-    m_ref, l_ref, acc_ref,
-    **kw,
+    *refs,
+    group: int, **kw,
 ):
     """Int8 page pool + per-vector f32 scales: the scale arrays [P, PS, K]
-    ride the same work-list as the values (their index map picks the
-    identical pool page per grid step), and the whole page dequantizes in
+    ride the same work-list as the values (their index maps pick the
+    identical pool pages per grid step), and the whole group dequantizes in
     VMEM right before the product — HBM moved int8 bytes. The blocks keep
     the pool's [PS, K, D] (a scale [PS, K] meets its vector there) and take
-    the body's [PS*K, D] once they are in q's dtype."""
+    the body's [G*PS*K, D] once they are in q's dtype. `refs`: the group's
+    key blocks, G of [1, PS, K, D] int8, their scales, G of [1, PS, K] f32,
+    the values and their scales likewise; the output [1, H, D]; the
+    scratch."""
     del layer_ref, pool_page_of_ref
+    k_refs, ks_refs, v_refs, vs_refs = (
+        refs[n * group:(n + 1) * group] for n in range(4))
+    o_ref, m_ref, l_ref, acc_ref = refs[4 * group:]
 
-    def page():
-        return _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, q_ref.dtype)
+    def pages():
+        return _dequantized_pages(k_refs, ks_refs, v_refs, vs_refs,
+                                  q_ref.dtype)
 
     _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
-                 m_ref, l_ref, acc_ref, page, **kw)
+                 m_ref, l_ref, acc_ref, pages, group=group, **kw)
 
 
-def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
+def _paged_decode_call(kernel, kv_blocks, kv_operands, q, layer, block_tables,
                        kv_lens, work, *, page_size, num_kv, pages, interpret,
-                       value_dim=None, name=None, kv_from=None):
+                       value_dim=None, name=None, kv_from=None, sink=None):
     """The pallas_call the paged decode kernels share: `grid=(work.count,)`
     — a run-time length — over the work-list's items; q and out blocks
-    follow the item's row, the KV blocks (`kv_specs`, one per operand of
-    `kv_operands`) its pool page. `value_dim`: the values' width where it is
-    not the keys'; `name`: the call's name in a device trace where it is
-    not the calling function's; `kv_from` ([B]): a sixth scalar-prefetch
-    operand, the rows' lower bounds, for a kernel that takes one."""
+    follow the item's row, the KV blocks its pool pages: `kv_blocks` gives
+    each operand of `kv_operands` its (block shape, index map), and the call
+    hands the operand in once a page of the group, the g-th block spec
+    fetching the item's g-th pool page. The group is the work-list's own:
+    `decode_group`'s where `work` is built here. `value_dim`: the values'
+    width where it is not the keys';
+    `name`: the call's name in a device trace where it is not the calling
+    function's; `kv_from` ([B]): a sixth scalar-prefetch operand, the rows'
+    lower bounds; `sink` ([H, 1] f32): an operand behind q, whole every
+    step."""
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
     dv = d if value_dim is None else value_dim
+    sweep = _swept_pages(block_tables, pages)
     if work is None:
-        work = decode_work_list(block_tables, kv_lens, page_size=page_size,
-                                pages=pages, kv_from=kv_from)
+        work = decode_work_list(
+            block_tables, kv_lens, page_size=page_size, pages=pages,
+            kv_from=kv_from,
+            group=decode_group(page_size, num_kv, d, dv, sweep))
+    group = work.group
     bounds = () if kv_from is None else (kv_from.astype(jnp.int32),)
     row_spec = pl.BlockSpec((1, h, d), _row_map, memory_space=pltpu.VMEM)
     out_spec = pl.BlockSpec((1, h, dv), _row_map, memory_space=pltpu.VMEM)
+    sinks, sink_specs = (), []
+    if sink is not None:
+        sinks = (sink,)
+        sink_specs = [pl.BlockSpec(sink.shape, lambda i, *_: (0, 0),
+                                   memory_space=pltpu.VMEM)]
+    kv_specs = [
+        pl.BlockSpec(block, functools.partial(index_map, g, group),
+                     memory_space=pltpu.VMEM)
+        for block, index_map in kv_blocks for g in range(group)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 + len(bounds),
         grid=(work.count,),
-        in_specs=[row_spec, *kv_specs],
+        in_specs=[row_spec, *sink_specs, *kv_specs],
         out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
@@ -392,15 +474,15 @@ def _paged_decode_call(kernel, kv_specs, kv_operands, q, layer, block_tables,
         ],
     )
     return pl.pallas_call(
-        functools.partial(kernel, block_k=page_size,
-                          sweep=_swept_pages(block_tables, pages),
-                          num_kv=num_kv, scale=d**-0.5),
+        functools.partial(kernel, block_k=page_size, sweep=sweep,
+                          num_kv=num_kv, scale=d**-0.5, group=group),
         out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         **({} if name is None else {"name": name}),
     )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
-      kv_lens.astype(jnp.int32), *bounds, q, *kv_operands)
+      kv_lens.astype(jnp.int32), *bounds, q, *sinks,
+      *(operand for operand in kv_operands for _ in range(group)))
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret", "name"))
@@ -421,19 +503,23 @@ def paged_flash_decode(
 ) -> jnp.ndarray:
     """Ragged PAGED one-token GQA decode attention. Returns [B, H, Dv].
 
-    The grid is the work-list of live (row, page) pairs (`decode_work_list`;
-    a decode program builds it once a step and hands it to every layer's
-    call as `work`, a direct caller may leave it out): its length is a
-    run-time value, so a call costs the pages the live rows hold and one
-    step per row that is not live, whatever the table's width and `pages`.
-    The KV BlockSpec index map picks each step's page through the
-    prefetched layer index and the item's pool page — attention reads the
-    scattered STACKED pool in place. Neither a contiguous per-row copy nor a
-    per-layer slice `pool[layer]` is ever materialized: a pallas_call takes
-    whole buffers as operands, so handing it a slice makes XLA copy one
-    layer of the pool (105 MB at 400 pages of Mistral-7B width) per call.
-    `layer` is an operand, not a Python constant, so the layers of an
-    unrolled decode program share one kernel.
+    The grid is the work-list of the live rows' pages, a GROUP of a row's
+    consecutive pages an item (`decode_work_list`; a decode program builds
+    it once a step and hands it to every layer's call as `work`, a direct
+    caller may leave it out): its length is a run-time value, so a call
+    costs the pages the live rows hold and one step per row that is not
+    live, whatever the table's width and `pages`. A grid step costs about
+    0.3 us over its bytes, so it takes as many pages as make a megabyte
+    (`decode_group`, from the shapes alone; a `work` that is given brings
+    its own group): one KV block spec a page of the group over the same
+    pool operand, whose index map picks the item's g-th page through the
+    prefetched layer index and the item's pool pages — attention reads the
+    scattered STACKED pool in place. Neither a contiguous per-row copy nor a per-layer slice
+    `pool[layer]` is ever materialized: a pallas_call takes whole buffers
+    as operands, so handing it a slice makes XLA copy one layer of the pool
+    (105 MB at 400 pages of Mistral-7B width) per call. `layer` is an
+    operand, not a Python constant, so the layers of an unrolled decode
+    program share one kernel.
 
     Contract: a row attends over its first min(kv_lens, pages x PS) cells,
     exactly; a row with kv_lens 0 is NOT LIVE — its output is zeros and no
@@ -446,9 +532,9 @@ def paged_flash_decode(
     softmax denominator and takes no value: p_j = exp(s_j - m) / (exp(sink -
     m) + sum_j exp(s_j - m)). A pool of any other layout with the pages'
     shape serves: a RING a slot [L, slots, cells, K, D] is a pool of one
-    page a row, its table [B, 1] the rows' slots (models/mimo_v2.py).
-    Without `sink`, and with values as wide as the keys, the call lowers to
-    what it always did.
+    page a row, its table [B, 1] the rows' slots (models/mimo_v2.py), and
+    its group is 1. Without `sink`, with values as wide as the keys and at
+    a group of 1, the call lowers to what it always did.
 
     `kv_from` ([B], a LOWER bound a row: a model's sliding window — `pages`
     is the context bucket, the first cells a row may read) makes a row
@@ -466,23 +552,16 @@ def paged_flash_decode(
     # dimensions are tiled K rows deep or eight), and the DMA lands the
     # block dense whatever K is
     rows = (layers, pool_pages, ps * num_kv)
-    kernel = _paged_decode_kernel
-    specs = [pl.BlockSpec((None, 1, ps * num_kv, width), _pool_rows_map,
-                          memory_space=pltpu.VMEM) for width in (d, dv)]
-    operands = (k_pages.reshape(*rows, d), v_pages.reshape(*rows, dv))
-    if sink is not None:
-        h = q.shape[1]
-        kernel = _paged_decode_sink_kernel
-        specs = [pl.BlockSpec((h, 1), lambda i, *_: (0, 0),
-                              memory_space=pltpu.VMEM), *specs]
-        operands = (sink.astype(jnp.float32).reshape(h, 1), *operands)
-    if kv_from is not None:
-        kernel = _paged_decode_from_kernel
     return _paged_decode_call(
-        kernel, specs, operands, q, layer,
+        functools.partial(_paged_decode_kernel, sink=sink is not None,
+                          bound=kv_from is not None),
+        [((None, 1, ps * num_kv, width), _pool_rows_map) for width in (d, dv)],
+        (k_pages.reshape(*rows, d), v_pages.reshape(*rows, dv)), q, layer,
         block_tables, kv_lens, work, page_size=ps, num_kv=num_kv,
         pages=pages, interpret=interpret, value_dim=dv, name=name,
-        kv_from=kv_from)
+        kv_from=kv_from,
+        sink=(None if sink is None
+              else sink.astype(jnp.float32).reshape(q.shape[1], 1)))
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
@@ -501,8 +580,8 @@ def paged_flash_decode_quant(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Int8 variant of paged_flash_decode: dequant-on-read inside the
-    kernel. Same work-list grid and contract; numerics match the XLA dequant
-    fallback (f32 dequant -> q.dtype operands -> f32 accumulation).
+    kernel. Same work-list grid, group and contract; numerics match the XLA
+    dequant fallback (f32 dequant -> q.dtype operands -> f32 accumulation).
 
     The VALUES follow paged_flash_decode's stacked-pool contract: read in
     place at `(layer, page)`, never sliced. The SCALES arrive as the layer's
@@ -514,13 +593,11 @@ def paged_flash_decode_quant(
     every call (compiled for a v5e: 32 whole-array copies a decode step,
     PERF.md §6, PR 25)."""
     _, _, ps, num_kv, d = k_pages.shape
-    kv_spec = pl.BlockSpec((None, 1, ps, num_kv, d), _pool_page_map,
-                           memory_space=pltpu.VMEM)
-    scale_spec = pl.BlockSpec((1, ps, num_kv), _layer_scale_map,
-                              memory_space=pltpu.VMEM)
+    kv_block = ((None, 1, ps, num_kv, d), _pool_page_map)
+    scale_block = ((1, ps, num_kv), _layer_scale_map)
     return _paged_decode_call(
         _paged_decode_quant_kernel,
-        [kv_spec, scale_spec, kv_spec, scale_spec],
+        [kv_block, scale_block, kv_block, scale_block],
         (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
         kv_lens, work, page_size=ps, num_kv=num_kv, pages=pages,
         interpret=interpret)
@@ -1164,7 +1241,7 @@ def _paged_extend_quant_kernel(
         return k, v
 
     def page():
-        return _dequantized_page(k_ref, ks_ref, v_ref, vs_ref, dtype)
+        return _dequantized_pages([k_ref], [ks_ref], [v_ref], [vs_ref], dtype)
 
     _extend_item(start_pos_ref, chunk_lens_ref, q_ref, o_ref, m_ref, l_ref,
                  acc_ref, page if body == "page" else kv_head, body=body, **kw)

@@ -3,19 +3,26 @@ chip: no model, no engine, no scheduler.
 
     chiprun -- python3 scripts/decode_page_cost.py
 
-`paged_flash_decode` at the heads of the benchmark's dense cells (32 rows,
-pages of 128 cells, head size 128; 8 KV heads x 4 as Mistral-7B has them, 2 x
-16 as Nemotron-3-Nano's attention layers): a sweep of 1, 2, 4 and 8 full pages
-a row, a straight line through it (µs a call = `call_us` + `page_us` x live
-pages), and one batch drawn as each cell draws its rows (`decode-saturated`:
-32 rows somewhere between a prompt of 64-128 and 512 tokens more;
-`chat-paced`: 5 rows of 32 live, lognormal prompts and outputs). Then
+`paged_flash_decode` at the heads of the cells that run it (32 rows, pages of
+128 cells, head size 128; 8 KV heads x 4 as Mistral-7B has them, 2 x 16 as
+Nemotron-3-Nano's attention layers, 4 x 8 under a lower bound a row over a
+band of 17 pages as Trinity-Mini's window layers call it), at every GROUP of
+pages a grid step (1, 2, 4, 8; `decode_group`'s own pick is named beside
+them): a sweep of 2, 4, 8 and 16 full pages a row, a straight line through it
+(µs a call = `call_us` + `page_us` x live pages), the seconds ONE call takes
+to trace and to lower at that group (`engine/compilelog`'s stages around a
+fresh program of the work-list and one call: what a kernel change adds to
+every program's build at every start, PERF.md §6 PR 53), and at the rule's
+group one batch drawn as each cell draws its rows (`decode-saturated`: 32
+rows somewhere between a prompt of 64-128 and 512 tokens more; `chat-paced`:
+5 rows of 32 live, lognormal prompts and outputs; `reason-long-out`: 32 rows
+between a prompt and 4,096 tokens more, the last 2,048 read). Then
 `paged_flash_extend` at the block family's call (32 rows x 8 queries, 4 KV
-heads x 8, a table 8 pages wide, blocks of 4), the same sweep, at q blocks
-of 4, 8, 16, 32, 64 and 128 queries (`--q-blocks` names others), then a
-verify chunk's 8 queries and the q blocks about their crossover at the
-dense cells' heads, in BOTH forms of the kernel's grid step at each
-(`pallas_attention.extend_body`: "page", one masked product over the
+heads x 8, a table 8 pages wide, blocks of 4), a sweep of 1, 2, 4 and 8
+pages, at q blocks of 4, 8, 16, 32, 64 and 128 queries (`--q-blocks` names
+others), then a verify chunk's 8 queries and the q blocks about their
+crossover at the dense cells' heads, in BOTH forms of the kernel's grid step
+at each (`pallas_attention.extend_body`: "page", one masked product over the
 stored page; "heads", a product a KV head): µs a live page, µs a call
 before its first page, the form the kernel picks at that size, and the
 crossover — the largest q block at which the masked form is the faster. A
@@ -30,7 +37,8 @@ program (`wall_us`, with whatever lies between two calls). The line is fitted
 to the device's where the trace has it. Prints one JSON object and writes it
 to `chiprun_out/decode_page_cost.json`. On the CPU (`JAX_PLATFORMS=cpu`) it
 runs the interpreter at a tenth of the size and says so: a rehearsal of the
-script, not a number.
+script, not a number (the seconds to trace and lower are the host's either
+way, through the interpreter's lowering there).
 """
 
 from __future__ import annotations
@@ -46,10 +54,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 ROWS, PAGE, HEAD_DIM, CALLS = 32, 128, 128, 16
-SWEEP = (1, 2, 4, 8)
-DECODE_SHAPES = {  # name: (KV heads, queries a KV head, layers, pool pages)
-    "mistral-7b K8xG4": (8, 4, 16, 400),
-    "nemotron-3-nano K2xG16": (2, 16, 2, 400),  # the cell's 2 such layers
+SWEEP = (1, 2, 4, 8)  # pages a row, the extend tables
+DECODE_SWEEP = (2, 4, 8, 16)  # pages a row, the decode tables
+GROUPS = (1, 2, 4, 8)  # pages a grid step
+BAND_WINDOW = 2048  # cells a row of the band's shape reads at most
+DECODE_SHAPES = {  # name: (KV heads, queries a KV head, layers, table
+    # width, band): a band's table is a row's own pages, which the
+    # positions wrap around, read from a lower bound a row
+    "mistral-7b K8xG4": (8, 4, 16, 16, False),
+    "nemotron-3-nano K2xG16": (2, 16, 2, 16, False),  # the cell's 2 layers
+    "trinity-mini band K4xG8": (4, 8, 12, BAND_WINDOW // PAGE + 1, True),
 }
 EXTEND_SHAPES = {  # name: (KV heads, queries a KV head, layers, pool pages,
     # table width, block, q blocks): the block family's call at every q
@@ -67,6 +81,8 @@ def _draw_lens(rng, cell: str):
 
     if cell == "decode-saturated":
         return rng.integers(64, 129, ROWS) + rng.integers(0, 513, ROWS)
+    if cell == "reason-long-out":
+        return rng.integers(64, 129, ROWS) + rng.integers(0, 4097, ROWS)
     lens = np.zeros(ROWS, np.int64)  # chat-paced
     live = rng.choice(ROWS, 5, replace=False)
     prompt = np.clip(np.exp(rng.normal(np.log(256), 0.9, 5)), 32, 1536)
@@ -152,53 +168,107 @@ def _line(sweep: list[dict]) -> dict:
             **_fit([(s["live_pages"], s[clock]) for s in sweep])}
 
 
+def _build_seconds(fn, args, builds: int = 3) -> dict:
+    """Median seconds `fn` takes to trace and to lower, fresh each time, by
+    engine/compilelog's stages (sums of JAX's own events: a nested trace
+    counts in its caller's too, as in the engine's `setup.trace_lower_s`)."""
+    import statistics
+
+    import jax
+
+    from llmlb_tpu.engine import compilelog
+
+    seen = {"trace": [], "lower": []}
+    for _ in range(builds):
+        jax.clear_caches()
+        before = compilelog.counters()
+        jax.jit(fn).lower(*args)
+        took = compilelog.summary(before)["seconds_total"]
+        for stage in seen:
+            seen[stage].append(took[stage])
+    return {f"{stage}_s": statistics.median(v) for stage, v in seen.items()}
+
+
 def decode_table(shape: str, reps: int, seed: int, small: bool) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from llmlb_tpu.ops import pallas_attention as pa
+    from llmlb_tpu.ops.attention import BAND_DECODE
 
-    num_kv, groups, layers, pool_pages = DECODE_SHAPES[shape]
+    num_kv, groups, layers, width, band = DECODE_SHAPES[shape]
+    kernel = BAND_DECODE if band else "paged_flash_decode"
+    pool_pages = ROWS * width + 1
+    sweep_pages = DECODE_SWEEP
     if small:
-        layers, pool_pages = 2, ROWS * 2 + 1
+        layers, width, sweep_pages = 2, (3 if band else 2), DECODE_SWEEP[:1]
+        pool_pages = ROWS * width + 1
+    window = (width - 1) * PAGE if band else None
     rng = np.random.default_rng(seed)
     q, k_pages, v_pages = _operands(
         seed, (ROWS, num_kv * groups, HEAD_DIM),
         (layers, pool_pages, PAGE, num_kv, HEAD_DIM))
-    width = 2 if small else 16
+    rule = pa.decode_group(PAGE, num_kv, HEAD_DIM, HEAD_DIM, width)
 
-    @jax.jit
-    def program(q, k_pages, v_pages, tables, lens):
-        work = pa.decode_work_list(tables, lens, page_size=PAGE)
-        for i in range(CALLS):
-            q = pa.paged_flash_decode(q, k_pages, v_pages, i % layers, tables,
-                                      lens, work=work)
-        return q
+    def call(group, calls):
+        def program(q, k_pages, v_pages, tables, lens):
+            kv_from = jnp.maximum(lens - window, 0) if band else None
+            work = pa.decode_work_list(tables, lens, page_size=PAGE,
+                                       kv_from=kv_from, group=group)
+            for i in range(calls):
+                q = pa.paged_flash_decode(
+                    q, k_pages, v_pages, i % layers, tables, lens, work=work,
+                    kv_from=kv_from, name=BAND_DECODE if band else None)
+            return q
 
-    def run(lens):
-        lens = np.minimum(np.asarray(lens), width * PAGE)
-        pages = -(-lens // PAGE)
-        tables = _tables(rng, pages, width, pool_pages)
-        got = _measure(program, (q, k_pages, v_pages, jnp.asarray(tables),
-                                 jnp.asarray(lens, jnp.int32)),
-                       "paged_flash_decode", reps)
+        return program
+
+    def operands(lens):
+        lens = np.asarray(lens)
+        if band:  # every row holds its whole band
+            tables = 1 + rng.permutation(ROWS * width).reshape(ROWS, width)
+            pages = -(-lens // PAGE) - np.maximum(lens - window, 0) // PAGE
+        else:
+            lens = np.minimum(lens, width * PAGE)
+            pages = -(-lens // PAGE)
+            tables = _tables(rng, pages, width, pool_pages)
+        return pages, (q, k_pages, v_pages, jnp.asarray(tables, jnp.int32),
+                       jnp.asarray(lens, jnp.int32))
+
+    def run(program, lens, group):
+        pages, args = operands(lens)
         return {"live_pages": int(pages.sum()),
-                "live_rows": int((lens > 0).sum()), **got}
+                "grid_steps": int((-(-pages // group)).sum()
+                                  + (pages == 0).sum()),
+                "live_rows": int((np.asarray(lens) > 0).sum()),
+                **_measure(program, args, kernel, reps)}
 
-    sweep = [{"pages_a_row": p, **run(np.full(ROWS, p * PAGE))}
-             for p in (SWEEP[:2] if small else SWEEP)]
-    line = _line(sweep)
+    by_group = {}
+    for group in GROUPS:
+        program = jax.jit(call(group, CALLS))
+        sweep = [{"pages_a_row": p, **run(program, np.full(ROWS, p * PAGE),
+                                          group)}
+                 for p in sweep_pages if p < width or not band]
+        by_group[str(group)] = {
+            "sweep": sweep, **({} if small else {"line": _line(sweep)}),
+            "one_call": _build_seconds(
+                call(group, 1), operands(np.full(ROWS, PAGE))[1])}
     cells = {}
-    for cell in ("decode-saturated", "chat-paced"):
+    program = jax.jit(call(rule, CALLS))
+    line = by_group.get(str(rule), {}).get("line")
+    for cell in (("reason-long-out",) if band
+                 else ("decode-saturated", "chat-paced")):
         if shape.startswith("nemotron") and cell == "chat-paced":
             continue  # no such cell
-        got = run(_draw_lens(rng, cell))
-        got["page_us_over_the_call"] = (
-            (got[line["clock"]] - line["call_us"])
-            / max(1, got["live_pages"]))
+        got = run(program, _draw_lens(rng, cell), rule)
+        if line:
+            got["page_us_over_the_call"] = (
+                (got[line["clock"]] - line["call_us"])
+                / max(1, got["live_pages"]))
         cells[cell] = got
-    return {"sweep": sweep, "line": line, "cells": cells}
+    return {"page_bytes": PAGE * num_kv * 2 * HEAD_DIM * 2,
+            "rule_group": rule, "groups": by_group, "cells": cells}
 
 
 def _extend_sweep(shape: str, body: str, queries: int, reps: int, seed: int,

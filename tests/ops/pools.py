@@ -3,6 +3,18 @@
 import jax.numpy as jnp
 import numpy as np
 
+from llmlb_tpu.ops.pallas_attention import decode_work_list
+
+
+def grouped_work(group, tables, lens, page_size, pages=None, kv_from=None):
+    """The `work=` that makes a paged decode kernel take `group` pages a
+    grid step, whatever its shapes' own group is; None (the kernel builds
+    its list by `decode_group`) where `group` is None."""
+    if group is None:
+        return None
+    return decode_work_list(tables, lens, page_size=page_size, pages=pages,
+                            kv_from=kv_from, group=group)
+
 
 def stacked_pool(pool, layer, num_layers=3):
     """The paged decode paths' operand: `pool` ([P, PS, K, D] values, [P, PS,

@@ -2,7 +2,9 @@
 as a band of pages a slot: ops/attention.paged_band_decode, models/afmoe.py)
 against a dense masked softmax over the whole sequence: the bounded
 work-list names only the pages of the span, the mask holds at both ends, a
-band of R pages wraps, and the XLA fall-back gives the same. CPU, the Pallas
+band of R pages wraps, and the XLA fall-back gives the same; and the same
+with a GROUP of the span's pages a grid step (PR 54), the span wrapping
+inside a group and the bound falling mid-page. CPU, the Pallas
 interpreter."""
 
 import jax
@@ -12,6 +14,7 @@ import pytest
 
 from llmlb_tpu.ops.attention import band_positions, paged_band_decode
 from llmlb_tpu.ops.pallas_attention import decode_work_list, paged_flash_decode
+from tests.ops.pools import grouped_work
 
 PS, W, H, KV, D = 8, 16, 4, 2, 16
 R = W // PS + 1  # pages of a band
@@ -144,3 +147,77 @@ def test_a_sink_beside_a_bound_is_refused():
                            jnp.zeros((1, 2), jnp.int32),
                            jnp.ones((1,), jnp.int32), sink=jnp.zeros((H,)),
                            kv_from=jnp.zeros((1,), jnp.int32))
+
+
+# --- a group of the span's pages a grid step (PR 54) -------------------------
+
+WIDE_W = 32  # a window of four pages: a band of five
+WIDE_R = WIDE_W // PS + 1
+
+
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
+@pytest.mark.parametrize("kv", [2, 4, 8, 32])
+def test_a_grouped_band_decode_wraps_inside_a_group(kv, group):
+    """Rows whose span starts mid-page and wraps the band INSIDE a group
+    (43 cells: pages 1..5 of a band of 5, the bound at cell 11; 77: pages
+    5..9, columns 0..4 from the middle of the table), a short one, a span
+    of one page and a row that is not live: each is the dense softmax over
+    `kv_from <= p < len`, at the cells' KV heads and every group."""
+    lens = [43, 77, 5, 40, 0]
+    h = kv * (1 if kv == 32 else 2)
+    keys = jax.random.split(jax.random.PRNGKey(kv + group), 3)
+    seq_k = jax.random.normal(keys[0], (len(lens), 80, kv, D), jnp.float32)
+    seq_v = jax.random.normal(keys[1], (len(lens), 80, kv, D), jnp.float32)
+    q = jax.random.normal(keys[2], (len(lens), h, D), jnp.float32)
+    pool_k = np.array(jax.random.normal(
+        jax.random.PRNGKey(9), (LAYERS, len(lens) * WIDE_R + 1, PS, kv, D)))
+    pool_v = pool_k[::-1].copy()
+    tables = 1 + np.arange(len(lens) * WIDE_R, dtype=np.int32).reshape(
+        len(lens), WIDE_R)[:, ::-1]  # a row's pages in no pool order
+    for row, n in enumerate(lens):
+        for p in range(n):  # later positions overwrite: the band wraps
+            page = tables[row, p // PS % WIDE_R]
+            pool_k[1, page, p % PS] = np.asarray(seq_k[row, p])
+            pool_v[1, page, p % PS] = np.asarray(seq_v[row, p])
+    kv_lens = jnp.asarray(lens, jnp.int32)
+    kv_from = jnp.maximum(kv_lens - WIDE_W, 0)  # 11, 45: mid-page
+    got = np.asarray(paged_flash_decode(
+        q, jnp.asarray(pool_k), jnp.asarray(pool_v), 1, jnp.asarray(tables),
+        kv_lens, kv_from=kv_from, interpret=True,
+        work=grouped_work(group, jnp.asarray(tables), kv_lens, PS,
+                          kv_from=kv_from)))
+    for row, n in enumerate(lens):
+        if n == 0:
+            assert not got[row].any()
+            continue
+        k = np.repeat(np.asarray(seq_k[row, max(n - WIDE_W, 0):n]),
+                      h // kv, axis=1)
+        v = np.repeat(np.asarray(seq_v[row, max(n - WIDE_W, 0):n]),
+                      h // kv, axis=1)
+        s = np.einsum("hd,shd->hs", np.asarray(q[row]), k) / np.sqrt(D)
+        w = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("hs,shd->hd", w / w.sum(-1, keepdims=True), v)
+        np.testing.assert_allclose(got[row], want, atol=3e-6)
+
+
+@pytest.mark.parametrize("group", [2, 4])
+def test_a_grouped_bounded_work_list_wraps_a_page_at_a_time(group):
+    """A span of pages 5..9 over a band of 5: the items start at the page
+    the bound falls in, G pages each, and every page is column p mod R of
+    the table — the wrap falls inside the first group."""
+    tables = jnp.asarray([[10, 11, 12, 13, 14], [20, 21, 22, 23, 24]],
+                         jnp.int32)
+    lens = jnp.asarray([77, 0], jnp.int32)
+    work = decode_work_list(tables, lens, page_size=PS,
+                            kv_from=jnp.maximum(lens - WIDE_W, 0),
+                            group=group)
+    items = -(-5 // group)
+    assert int(work.count) == items + 1
+    assert np.asarray(work.page_of)[:items].tolist() == list(
+        range(5, 10, group))
+    pool = np.asarray(work.pool_page_of).reshape(-1, group)[:items + 1]
+    want = [10 + p % 5 for p in range(5, 10)]  # 10, 11, 12, 13, 14
+    assert pool[:items].reshape(-1)[:5].tolist() == want
+    # the short last group and the row that is not live repeat a page
+    assert set(pool.reshape(-1)[5:].tolist()) <= set(want)
+    np.testing.assert_array_equal(pool[items], pool[items - 1])

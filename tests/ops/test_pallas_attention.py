@@ -31,6 +31,7 @@ from tests.ops.pools import (
     DECODE_PPN,
     DECODE_PS,
     HEAD_SHAPES,
+    grouped_work,
     live_pages_case,
     stacked_pool as _stacked,
 )
@@ -274,12 +275,16 @@ def test_paged_flash_decode_pages_below_longest_row(layer):
     assert np.isfinite(np.asarray(got)).all()
 
 
+@pytest.mark.parametrize("group", [None, 1, 2, 3, 8])
 @pytest.mark.parametrize("case", sorted(DECODE_CASES))
-def test_paged_flash_decode_reads_live_pages_only(case):
+def test_paged_flash_decode_reads_live_pages_only(case, group):
     """The kernel's contract (DECODE_CASES): live rows equal the einsum over
     their own cells, rows that are not live are exactly zero, and the trash
     page and every page no live row attends over hold NaN — so a step that
-    read what it should not would show in a live row."""
+    read what it should not would show in a live row. At every group of
+    pages a grid step (None: the shapes' own, 4 here): a short last
+    group's missing pages are blocks in VMEM beside live ones, and hold a
+    page some live row attends over."""
     kv_lens, pages = DECODE_CASES[case]
     h, kv, d, ps, layer = 4, 2, 16, DECODE_PS, 1
     rng = np.random.default_rng(15)
@@ -296,7 +301,8 @@ def test_paged_flash_decode_reads_live_pages_only(case):
 
     got = np.asarray(paged_flash_decode(
         q[:, 0], poisoned(k_pages), poisoned(v_pages), layer, tables, lens,
-        pages=pages, interpret=True))
+        pages=pages, interpret=True,
+        work=grouped_work(group, tables, lens, ps, pages)))
     sweep = DECODE_PPN if pages is None else pages
     expected = np.asarray(gqa_attention_decode(
         q, gather_kv_pages(k_pages, tables[:, :sweep]),
@@ -570,6 +576,45 @@ def test_the_route_record_says_which_body_an_extend_program_holds(
         "pallas:paged_flash_extend" + "_quant" * quantized)
     routes["paged_extend_body"]["8"] = "?"  # a copy, not the record
     assert attention.traced_routes()["paged_extend_body"]["8"] == "page"
+
+
+def test_the_route_record_says_which_group_a_decode_call_took(monkeypatch):
+    """`attention.traced.paged_decode_group`: the pages a grid step of each
+    traced paged decode call takes, by the call's name in a device trace —
+    the shapes' own (2 at 8 KV heads of 128, 4 at 4 under a band's bound, 1
+    over a ring of one page a row), static a program."""
+    from llmlb_tpu.ops import attention
+
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    monkeypatch.setattr(attention, "_traced", {})
+
+    def shapes(kv, table, d=128):
+        pool = jax.ShapeDtypeStruct((2, 40, 128, kv, d), jnp.bfloat16)
+        return (jax.ShapeDtypeStruct((3, 1, 32, d), jnp.bfloat16), pool, pool,
+                0, jax.ShapeDtypeStruct((3, table), jnp.int32),
+                jax.ShapeDtypeStruct((3,), jnp.int32))
+
+    def decode(*operands):  # the step's own order: the list, then the call
+        work = attention.paged_decode_work(operands[1], *operands[4:])
+        return attention.paged_attention_decode(*operands, work=work)
+
+    def band(*operands):
+        work = attention.paged_band_work(operands[1], *operands[4:])
+        return attention.paged_band_decode(*operands, work=work)
+
+    jax.eval_shape(decode, *shapes(8, 16))
+    jax.eval_shape(band, *shapes(4, 17),
+                   jax.ShapeDtypeStruct((3,), jnp.int32))
+    def ring(q, ring_k, ring_v, layer, table, lens):  # mimo's window layers
+        from llmlb_tpu.models import mimo_v2
+
+        return mimo_v2._ring_decode(q, ring_k, ring_v, layer, table[:, 0],
+                                    lens, jnp.zeros((32,), jnp.float32), {})
+
+    jax.eval_shape(ring, *shapes(4, 1))
+    assert attention.traced_routes()["paged_decode_group"] == {
+        "paged_flash_decode": 2, "paged_band_decode": 4,
+        "paged_window_decode": 1}
 
 
 def test_paged_flash_extend_under_a_scan_takes_the_layer_at_run_time():

@@ -23,6 +23,7 @@ from tests.ops.pools import (
     DECODE_CASES,
     DECODE_PS,
     HEAD_SHAPES,
+    grouped_work,
     live_pages_case,
     stacked_pool as _stacked,
 )
@@ -125,6 +126,42 @@ def test_paged_flash_decode_quant_interpret_parity(layer, shape):
         2e-3 if dtype == jnp.float32 else 2e-2)
 
 
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
+@pytest.mark.parametrize("kv", [2, 4, 8, 32])
+def test_paged_flash_decode_quant_takes_a_group_of_pages_a_grid_step(
+        kv, group, monkeypatch):
+    """The int8 twin rides the bf16 kernel's grid step (PR 54): a group of
+    a row's pages and of their scales dequantizes as one and meets the
+    queries in ONE product. Against the XLA dequant route, at the cells'
+    KV heads and every group: ragged rows, one not live, one alone on a
+    bucket of fewer pages than a group."""
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "xla")  # the reference's route
+    rng = np.random.default_rng(kv + group)
+    rows, ppn, layer = 4, 5, 1
+    shape = (1 + rows * ppn, PS, kv, D)
+    pools = [dict(zip("qs", map(jnp.asarray, quantize_kv(
+        rng.normal(size=shape).astype(np.float32))))) for _ in "kv"]
+    qk, qv = (_stacked(pool, layer) for pool in pools)
+    tables = jnp.asarray(1 + rng.permutation(rows * ppn).reshape(rows, ppn),
+                         jnp.int32)
+    q = jnp.asarray(rng.normal(size=(rows, kv * 2, D)), jnp.float32)
+    for lens, pages in (([PS * 5, 0, PS * 2 + 3, 1], None),
+                        ([0, 0, PS * 4 - 1, 0], 3)):
+        lens = jnp.asarray(lens, jnp.int32)
+        got = np.asarray(paged_flash_decode_quant(
+            q, qk["q"], qk["s"][layer], qv["q"], qv["s"][layer], layer,
+            tables, lens, pages=pages, interpret=True,
+            work=grouped_work(group, tables, lens, PS, pages)))
+        window = None if pages is None else pages * PS
+        want = np.asarray(paged_attention_decode(
+            q[:, None], qk, qv, layer, tables,
+            lens if window is None else jnp.minimum(lens, window),
+            window=window))[:, 0]
+        live = np.asarray(lens) > 0
+        assert np.abs(got[live] - want[live]).max() < 2e-3
+        assert not got[~live].any()
+
+
 @pytest.mark.parametrize("shape", sorted(SHAPES))
 def test_paged_flash_decode_quant_lets_no_other_head_through(shape):
     """The int8 kernel under the bf16 kernel's mask
@@ -177,13 +214,16 @@ def test_paged_flash_decode_quant_respects_pages_window(layer):
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("group", [None, 1, 2, 3, 8])
 @pytest.mark.parametrize("case", sorted(DECODE_CASES))
-def test_paged_flash_decode_quant_reads_live_pages_only(case, monkeypatch):
+def test_paged_flash_decode_quant_reads_live_pages_only(case, group,
+                                                        monkeypatch):
     """The int8 kernel under the bf16 kernel's contract (DECODE_CASES): live
     rows equal the XLA dequant route on the sound pool, rows that are not
     live are exactly zero, and the scales of the trash page and of every
     page no live row attends over are NaN — a vector dequantized from one
-    of them would show in a live row."""
+    of them would show in a live row. At every group of pages a grid step
+    (None: the shapes' own)."""
     monkeypatch.setenv("LLMLB_TPU_ATTENTION", "xla")  # the reference's route
     kv_lens, pages = DECODE_CASES[case]
     layer = 1
@@ -201,7 +241,8 @@ def test_paged_flash_decode_quant_reads_live_pages_only(case, monkeypatch):
     got = np.asarray(paged_flash_decode_quant(
         q, _stacked(jnp.asarray(kq), layer), poisoned(ks),
         _stacked(jnp.asarray(vq), layer), poisoned(vs), layer, tables, lens,
-        pages=pages, interpret=True))
+        pages=pages, interpret=True,
+        work=grouped_work(group, tables, lens, DECODE_PS, pages)))
     window = None if pages is None else pages * DECODE_PS
     sound = [_stacked({"q": jnp.asarray(vals), "s": jnp.asarray(scales)},
                       layer) for vals, scales in ((kq, ks), (vq, vs))]

@@ -12,9 +12,11 @@ refactor from bringing a slice or a copy back: in the program as traced (the
 jaxpr), and in the program as the TPU's compiler leaves it for a described
 v5e. Its time is the benchmark's to show."""
 
+import collections
 import dataclasses
 import functools
 import inspect
+import pathlib
 import re
 
 import jax
@@ -43,6 +45,12 @@ FAMILIES = {
     "mixtral": (mixtral, mixtral.MixtralConfig(
         **DIMS, num_experts=4, experts_per_token=2)),
 }
+
+
+# the pages a grid step of the decode kernels takes at these shapes (the
+# window of `_decode_jaxpr` is two pages)
+DECODE_GROUP = pallas_attention.decode_group(PAGE_SIZE, KV_HEADS, HEAD_DIM,
+                                             HEAD_DIM, 2)
 
 
 def _equations(jaxpr, kernel_bodies=False):
@@ -97,9 +105,12 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
     # The bf16 kernel takes the pool under the view [L, P, PS*K, D], a page
     # as the rows it is stored as (a bitcast on the chip: the compiled
     # burst below holds no copy under either shape).
+    # Each operand goes in once a page of the group a grid step takes (two
+    # at this window of two pages): the same buffer under another block.
     rows = (LAYERS, PAGES, PAGE_SIZE * KV_HEADS, HEAD_DIM)
     given = values if quantized else rows
-    kernel_operands = [given, given] + [layer_scales] * (2 * quantized)
+    kernel_operands = DECODE_GROUP * (
+        [given, given] + [layer_scales] * (2 * quantized))
     never = {values[1:], rows[1:]} | (set() if quantized else {layer_scales})
 
     eqns = list(_equations(_decode_jaxpr(family, cfg, quantized,
@@ -130,11 +141,11 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
 @pytest.mark.parametrize("name", sorted(FAMILIES))
 def test_a_decode_grid_step_is_one_pair_of_products(name, quantized,
                                                     monkeypatch):
-    """The decode kernels take a page as it is stored: every query head
-    against all of its [PS*K, D] rows in ONE product, one softmax update and
-    ONE product (PERF.md §6, PR 43), whatever the number of KV heads — no
-    product a head, and no loop over the heads in the one body both kernels
-    share."""
+    """The decode kernels take a group of pages as they are stored: every
+    query head against all of its [G*PS*K, D] rows in ONE product, one
+    softmax update and ONE product (PERF.md §6, PR 43, PR 54), whatever the
+    number of KV heads and the group — no product a head or a page, and no
+    loop over the heads or the group in the one body both kernels share."""
     family, cfg = FAMILIES[name]
     kernels = [eqn for eqn in _equations(_decode_jaxpr(family, cfg, quantized,
                                                        monkeypatch))
@@ -144,13 +155,113 @@ def test_a_decode_grid_step_is_one_pair_of_products(name, quantized,
     for eqn in kernels:
         products = [e for e in _equations(eqn.params["jaxpr"], True)
                     if e.primitive.name == "dot_general"]
-        rows = PAGE_SIZE * KV_HEADS  # a page's cells x KV heads
+        rows = DECODE_GROUP * PAGE_SIZE * KV_HEADS  # a group's cells x KV heads
         assert [tuple(v.aval.shape for v in e.invars) for e in products] == [
             ((DIMS["num_heads"], HEAD_DIM), (rows, HEAD_DIM)),  # q, keys
             ((DIMS["num_heads"], rows), (rows, HEAD_DIM)),  # weights, values
         ]
     body = inspect.getsource(pallas_attention._decode_item)
     assert "for " not in body.split('"""')[2], "a loop is back in the body"
+
+
+# --- a group of pages a grid step: the body is written once ------------------
+
+DECODE_VARIANTS = ["plain", "sink", "bound", "quant"]
+# the kernel and its index maps as PR 52 (the parent of the group) traced them
+# at OLMo-Hybrid's 32 stored heads, where the rule gives a group of 1
+PARENT_FORMS = pathlib.Path(__file__).parent / "data" / "paged_decode_kernel_pr52"
+
+
+def _decode_kernel_call(variant, kv_heads, groups, group=None, table=34):
+    """The pallas_call equation of one paged decode call at a cell's page
+    (128 cells, heads of 128), traced for the interpreter."""
+    rows = 4
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    pool = jax.ShapeDtypeStruct((2, 40, 128, kv_heads, 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((rows, kv_heads * groups, 128), jnp.bfloat16)
+    tail = (ints(), ints(rows, table), ints(rows))
+    name = {"sink": "sink", "bound": "kv_from"}.get(variant)
+    extra = {"sink": jax.ShapeDtypeStruct((kv_heads * groups,), jnp.float32),
+             "bound": ints(rows)}.get(variant)
+
+    def fn(*operands):
+        """The call as a decode program makes it: a `group` that is given
+        comes as the work-list's (None: the kernel's own, by shape)."""
+        named = {} if name is None else {name: operands[-1]}
+        operands = operands[:len(operands) - len(named)]
+        if group is not None:
+            named["work"] = pallas_attention.decode_work_list(
+                *operands[-2:], page_size=128, group=group,
+                kv_from=named.get("kv_from"))
+        kernel = (pallas_attention.paged_flash_decode_quant
+                  if variant == "quant" else pallas_attention.paged_flash_decode)
+        return kernel(*operands, interpret=True, **named)
+
+    if variant == "quant":
+        pool8 = jax.ShapeDtypeStruct(pool.shape, jnp.int8)
+        scales = jax.ShapeDtypeStruct(pool.shape[1:4], jnp.float32)
+        operands = (q, pool8, scales, pool8, scales, *tail)
+    else:
+        operands = (q, pool, pool, *tail) + (() if extra is None else (extra,))
+    (call,) = [e for e in _equations(jax.make_jaxpr(fn)(*operands).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    return call
+
+
+def _kernel_form(call) -> str:
+    """A kernel as text: its body, then each block's index map."""
+    maps = [str(block.index_map_jaxpr)
+            for block in call.params["grid_mapping"].block_mappings]
+    return "\n".join([str(call.params["jaxpr"]), *maps]) + "\n"
+
+
+@pytest.mark.parametrize("variant", DECODE_VARIANTS)
+def test_at_a_group_of_one_the_decode_kernel_is_the_parents(variant):
+    """Where the rule gives a group of 1 (32 stored heads: a page is two
+    megabytes) the call traces to the kernel and the index maps it traced to
+    before there were groups, text for text — and a ring, one page a row,
+    has a group of 1 whatever its heads."""
+    assert pallas_attention.decode_group(128, 32, 128, 128, 34) == 1
+    assert pallas_attention.decode_group(128, 4, 192, 128, 1) == 1
+    got = _kernel_form(_decode_kernel_call(variant, 32, 1))
+    assert got == (PARENT_FORMS / f"{variant}.txt").read_text()
+
+
+@pytest.mark.parametrize("variant", DECODE_VARIANTS)
+@pytest.mark.parametrize("kv_heads,groups", [(2, 16), (4, 8)],
+                         ids=["nemotron-K2xG16", "trinity-K4xG8"])
+def test_the_decode_body_does_not_grow_with_the_group(kv_heads, groups,
+                                                       variant):
+    """What refused PR 53: a body written out once a page of the group is
+    traced and lowered that many times, at every start. The kernel's jaxpr
+    holds the same equations at a group of 8 as at a group of 2 — one
+    product pair, one mask, one update — but for the reads of the group's
+    block refs, one a block."""
+    def census(group):
+        call = _decode_kernel_call(variant, kv_heads, groups, group=group)
+        return collections.Counter(
+            e.primitive.name
+            for e in _equations(call.params["jaxpr"], True))
+
+    small, large = census(2), census(8)
+    operands = 4 if variant == "quant" else 2  # values, and an int8 pool's scales
+    assert large - small == collections.Counter({"get": operands * (8 - 2)})
+    assert not small - large
+    assert large["dot_general"] == 2 and large["concatenate"] == operands
+    assert large["exp"] == small["exp"] == census(1)["exp"]
+
+
+def test_the_group_is_a_function_of_the_shapes():
+    """As many pages as make a megabyte of bf16 keys and values, no more
+    than the sweep and no more than 4 (a page of the group is a block
+    operand every program traces: PERF.md §6, PR 54): the cells' widths."""
+    group = functools.partial(pallas_attention.decode_group, 128)
+    assert [group(kv, 128, 128, 34) for kv in (2, 4, 8, 32)] == [4, 4, 2, 1]
+    assert [group(2, 128, 128, sweep) for sweep in (1, 2, 3, 17)] == [1, 2, 3, 4]
+    assert group(4, 192, 128, 34) == 3  # narrower values count as they are
+    assert pallas_attention.decode_group(8, 2, 16, 16, 64) == 4  # a tiny page
+    source = inspect.getsource(pallas_attention.decode_group)
+    assert "environ" not in source and "name" not in source
 
 
 # --- the extend kernels: what a grid step does follows the q block -----------
