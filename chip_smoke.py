@@ -1093,6 +1093,88 @@ def kernel_leg() -> int:
 
         attempt("delta_rule_step", f"slots={slots}", rule_step)
 
+    # the state-space step kernel at ONE group of B and C for all 64 heads
+    # (Granite-4.0-H: a [128, 4096] tile of coefficients transposed at once)
+    # and at Nemotron-3-Nano's 8 groups, layer 1 of a pool stacked over two,
+    # a row in three not live: the output, and the whole pool
+    from llmlb_tpu.ops import ssm
+
+    for groups in (1, 8):
+        def state_step(slots=32, heads=64, p=64, n=128):
+            def f32(*shape):
+                return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+            x, bm, cm = (f32(slots, heads, p), f32(slots, groups, n),
+                         f32(slots, groups, n))
+            dt = jnp.asarray(rng.uniform(0.001, 0.1, (slots, heads)),
+                             jnp.float32)
+            a = -jnp.asarray(rng.uniform(1.0, 16.0, heads), jnp.float32)
+            pool_ = f32(2, slots, heads, p, n)
+            live = jnp.arange(slots) % 3 != 1
+            decay, dtx = ssm._step_inputs(x, dt, a, live)
+            # the jax.numpy route: slots named, so no kernel
+            want_y, want = ssm.ssm_step(
+                x, dt, a, bm, cm, jnp.zeros(heads), pool_, 1,
+                slots=jnp.arange(slots), live=live)
+            got, y = ssm.ssm_decode_step(pool_ + 0, 1, decay, dtx, bm, cm,
+                                         interpret=False)
+            case = f"groups={groups}"
+            check("ssm_decode_step", case + ",out", y.reshape(1, slots, -1),
+                  want_y.reshape(1, slots, -1))
+            check("ssm_decode_step", case + ",pool",
+                  got.reshape(1, 2 * slots, -1), want.reshape(1, 2 * slots, -1))
+            unmoved = np.array_equal(np.asarray(got[0]), np.asarray(pool_[0])) \
+                and np.array_equal(np.asarray(got[1][~live]),
+                                   np.asarray(pool_[1][~live]))
+            check("ssm_decode_step", case + ",rows not live unmoved",
+                  jnp.asarray([[float(unmoved)]]), jnp.asarray([[1.0]]))
+
+        attempt("ssm_decode_step", f"groups={groups}", state_step)
+
+    # 8 KV heads of 64 (Granite-4.0-H): a fresh prompt through flash_prefill
+    # as it is; decode and extend over a pool whose rows hold two heads side
+    # by side ([.., 4, 128], ops/attention.lane_pack: no lane is padding),
+    # the queries in their own head's lanes, against the XLA arm over the
+    # same numbers as [.., 8, 64]
+    def narrow_heads(b=8, k8=8, d64=64, pages=4):
+        f = xla.lane_pack(k8, d64)
+        p = b * pages + 1
+        kp, vp = rand(2, p, PS, k8, d64), rand(2, p, PS, k8, d64)
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, p)).reshape(b, pages), jnp.int32)
+        lens = jnp.asarray(rng.integers(1, pages * PS + 1, b), jnp.int32)
+        q = rand(b, 1, H, d64)
+        want = xla.gqa_attention_decode(
+            q, xla.gather_kv_pages(kp, tables, layer=1),
+            xla.gather_kv_pages(vp, tables, layer=1), lens)
+        got = xla.unpack_heads(pa.paged_flash_decode(
+            xla.pack_queries(q, k8, f)[:, 0], xla.pack_kv(kp, f),
+            xla.pack_kv(vp, f), 1, tables, lens, pages=pages,
+            interpret=False)[:, None], k8, f)
+        check("paged_flash_decode", f"8x64 packed by {f}", got, want)
+        t = 64
+        starts = jnp.asarray(rng.integers(0, (pages - 1) * PS, b), jnp.int32)
+        chunk = jnp.asarray(rng.integers(1, t + 1, b), jnp.int32)
+        q = rand(b, t, H, d64)
+        pos = starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+        want = xla.gqa_attention_extend(
+            q, xla.gather_kv_pages(kp, tables, layer=1),
+            xla.gather_kv_pages(vp, tables, layer=1), pos)
+        got = xla.unpack_heads(pa.paged_flash_extend(
+            xla.pack_queries(q, k8, f), xla.pack_kv(kp, f),
+            xla.pack_kv(vp, f), 1, tables, starts, chunk, interpret=False),
+            k8, f)
+        check("paged_flash_extend", f"8x64 packed by {f}", got, want,
+              valid=chunk)
+        k, v = rand(b, 256, k8, d64), rand(b, 256, k8, d64)
+        q = rand(b, 256, H, d64)
+        lens = jnp.asarray(rng.integers(1, 257, b), jnp.int32)
+        check("flash_prefill", "8x64",
+              pa.flash_prefill(q, k, v, lens, interpret=False),
+              xla._prefill_einsum(q, k, v, lens), valid=lens)
+
+    attempt("paged_flash_decode", "8x64 packed", narrow_heads)
+
     n_adapters, rank = 9, 16
     for b, t in ((8, 1), (32, 1), (2, 512), (8, 5)):
         for n_in, n_out in ((2048, 2048), (2048, 256), (2048, 5632),

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from llmlb_tpu.models.afmoe import AfmoeConfig
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
+from llmlb_tpu.models.granite_hybrid import GraniteHybridConfig
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
 from llmlb_tpu.models.mimo_v2 import MimoV2Config
@@ -132,6 +133,22 @@ PRESETS: dict[str, LlamaConfig] = {
         experts_per_token=2, moe_intermediate_size=32,
         num_shared_experts=1, route_norm=True, route_scale=2.826,
         mup_enabled=True,
+    ),
+    # CI-sized dense hybrid (models/granite_hybrid.py,
+    # docs/granite-hybrid.md): runs of 2, 3 and 1 state-space layers at ONE
+    # group between two attention layers, a feed-forward in every layer, the
+    # four multipliers, the head tied to the embedding table
+    "debug-granite-hybrid-tiny": GraniteHybridConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=8, num_heads=4, num_kv_heads=2, rms_eps=1e-5,
+        dtype=jnp.float32, max_position_embeddings=512,
+        tie_word_embeddings=True, embedding_multiplier=12.0,
+        logits_scaling=8.0,
+        layer_types=("mamba", "mamba", "attention", "mamba", "mamba",
+                     "mamba", "attention", "mamba"),
+        ssm_heads=8, ssm_head_dim=16, ssm_groups=1, ssm_state=16,
+        conv_kernel=4, chunk_size=16, attention_multiplier=0.0625,
+        residual_multiplier=0.22,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

@@ -90,6 +90,11 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     max_position_embeddings: int = 8192
     dtype: Any = jnp.bfloat16
+    # Granite's scalars on the way in and out (models/granite_hybrid.py):
+    # x0 = embed[ids] * embedding_multiplier, logits / logits_scaling. At 1
+    # the bodies below trace what they always did.
+    embedding_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def head_dim_(self) -> int:
@@ -458,12 +463,20 @@ def _attn_block(cfg: LlamaConfig, lp: Params, x: jnp.ndarray, positions,
     return x + _proj(lp, "wo", attn.reshape(b, t, -1), lora_idx), k, v
 
 
+def _embed(cfg: LlamaConfig, params: Params, input_ids) -> jnp.ndarray:
+    x = params["embed"][input_ids]
+    scale = cfg.embedding_multiplier
+    return x if scale == 1.0 else x * scale
+
+
 def _unembed(cfg: LlamaConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     x = rms_norm(x, params["ln_final"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    return jnp.einsum(
+    logits = jnp.einsum(
         "be,ev->bv", x, head, preferred_element_type=jnp.float32
     )
+    scale = cfg.logits_scaling
+    return logits if scale == 1.0 else logits / scale
 
 
 def _default_mlp_fn(lp: Params, h: jnp.ndarray, token_valid,
@@ -693,7 +706,7 @@ def _prefill_impl(params, cfg, input_ids, prompt_lens, block_tables,
     page = jnp.take_along_axis(block_tables, positions // ps, axis=1)
     off = positions % ps
 
-    x = params["embed"][input_ids]  # [B, T, E]
+    x = _embed(cfg, params, input_ids)  # [B, T, E]
     attention = attention or GQA_ATTENTION
     rows = StateRows(slot_ids, prompt_lens)
 
@@ -806,7 +819,7 @@ def _prefill_extend_paged_impl(params, cfg, input_ids, chunk_lens, start_pos,
             block_tables, 0, max(1, -(-window // ps)), axis=1
         )
 
-    x = params["embed"][input_ids]  # [B, T, E]
+    x = _embed(cfg, params, input_ids)  # [B, T, E]
     attention = attention or GQA_ATTENTION
     rows = StateRows(slot_ids, chunk_lens, start_pos)
 
@@ -957,7 +970,7 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                                  window)
     rows = StateRows(slot_ids, start_pos=write_pos, live=live)
 
-    x = params["embed"][input_ids][:, None, :]  # [B, 1, E]
+    x = _embed(cfg, params, input_ids)[:, None, :]  # [B, 1, E]
     aux, at, deferred = [], 0, None  # the branch a layer left (LayerGroup)
     for group in _groups_for(cfg, stacked_names, mlp_fn, groups):
         stacked, whole = _group_params(params, group)

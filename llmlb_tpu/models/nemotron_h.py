@@ -239,6 +239,29 @@ def _leaves(cfg: NemotronHConfig):
             for n in _NAMES[kind] if cfg.layers_of(kind)]
 
 
+def seeded_vector(cfg, name: str, k, shape):
+    """A seeded leaf that is no matrix, by the published initialisation of
+    the state-space layers (init_params says which; models/granite_hybrid.py
+    seeds its own by the same rule): `cfg` names `conv_kernel` and the three
+    `time_step_*`."""
+    if name in ("ssm_conv_w", "ssm_conv_b"):
+        bound = cfg.conv_kernel**-0.5
+        return jax.random.uniform(k, shape, F32, -bound, bound
+                                  ).astype(cfg.dtype)
+    if name == "ssm_a_log":
+        return jnp.log(jax.random.uniform(k, shape, F32, 1.0, 16.0))
+    if name == "ssm_dt_bias":
+        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(k, shape, F32, lo, hi)),
+                         cfg.time_step_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+    if name == "router_bias":
+        return 0.02 * jax.random.normal(k, shape, F32)
+    if name == "ssm_d":
+        return jnp.ones(shape, F32)
+    return jnp.ones(shape, cfg.dtype)  # the norms
+
+
 def init_params(cfg: NemotronHConfig, key: jax.Array) -> Params:
     """Random init (serving uses checkpoint weights; this backs tests and
     the benchmark) by the family's published initialisation, from the
@@ -258,24 +281,6 @@ def init_params(cfg: NemotronHConfig, key: jax.Array) -> Params:
         return (jax.random.normal(k, shape, F32) * fan_in**-0.5
                 ).astype(cfg.dtype)
 
-    def own_rule(name, k, shape):
-        if name in ("ssm_conv_w", "ssm_conv_b"):
-            bound = cfg.conv_kernel**-0.5
-            return jax.random.uniform(k, shape, F32, -bound, bound
-                                      ).astype(cfg.dtype)
-        if name == "ssm_a_log":
-            return jnp.log(jax.random.uniform(k, shape, F32, 1.0, 16.0))
-        if name == "ssm_dt_bias":
-            lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-            dt = jnp.maximum(jnp.exp(jax.random.uniform(k, shape, F32, lo, hi)),
-                             cfg.time_step_floor)
-            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-        if name == "router_bias":
-            return 0.02 * jax.random.normal(k, shape, F32)
-        if name == "ssm_d":
-            return jnp.ones(shape, F32)
-        return jnp.ones(shape, cfg.dtype)  # the norms
-
     params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
                       "ln_final": jnp.ones((e,), cfg.dtype),
                       "lm_head": w(next(keys), (e, cfg.vocab_size), e)}
@@ -283,7 +288,7 @@ def init_params(cfg: NemotronHConfig, key: jax.Array) -> Params:
         shape, fan_in = shapes[name]
         k = next(keys)
         params[name] = (w(k, (count, *shape), fan_in) if fan_in
-                        else own_rule(name, k, (count, *shape)))
+                        else seeded_vector(cfg, name, k, (count, *shape)))
     return params
 
 
@@ -400,8 +405,14 @@ def _nope_block(cfg: NemotronHConfig, lp: Params, x, positions, inv_freq,
 _ATTENTION = GQA_ATTENTION._replace(block=_nope_block)
 
 
-def _ssm_mixer(cfg: NemotronHConfig):
-    """llama.LayerGroup's `mixer` for a state-space layer."""
+def ssm_mixer(cfg, residual: float = 1.0):
+    """llama.LayerGroup's `mixer` for a state-space (Mamba-2) layer, of any
+    family whose configuration names the mixer's sizes as NemotronHConfig
+    does (`ssm_heads`, `ssm_head_dim`, `ssm_groups`, `ssm_state`, `d_inner`,
+    `conv_dim`, `chunk_size`) and its parameters by `_SSM`. `residual`
+    scales what the mixer GIVES before it joins x (Granite's
+    `residual_multiplier`, models/granite_hybrid.py); at 1 nothing is
+    traced for it."""
     heads, p, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
                       cfg.ssm_state)
     di, cd = cfg.d_inner, cfg.conv_dim
@@ -450,7 +461,10 @@ def _ssm_mixer(cfg: NemotronHConfig):
         y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
                               + cfg.rms_eps)
         y = (y.reshape(b, t, di) * lp["ln_gate"].astype(F32)).astype(x.dtype)
-        return (x + y @ lp["ssm_out"], cache_k._replace(state=state),
+        out = y @ lp["ssm_out"]
+        if residual != 1.0:
+            out = out * residual
+        return (x + out, cache_k._replace(state=state),
                 cache_v._replace(state=conv))
 
     return mixer
@@ -494,7 +508,7 @@ def _groups(cfg: NemotronHConfig, live=None) -> list[LayerGroup]:
     """A group a layer, in the pattern's order; a layer's parameters and
     its place in its pool are its kind's next row."""
     kinds = {
-        "M": dict(mlp_fn=None, attends=False, mixer=_ssm_mixer(cfg)),
+        "M": dict(mlp_fn=None, attends=False, mixer=ssm_mixer(cfg)),
         "*": dict(mlp_fn=None),
         "E": dict(mlp_fn=_moe_mlp_fn(cfg, live), attends=False,
                   whole=("we_up", "we_down")),

@@ -80,6 +80,61 @@ def _split_gqa(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
     return q.reshape(b, t, num_kv_heads, h // num_kv_heads, d)
 
 
+# --- heads narrower than the chip's 128 lanes ------------------------------
+# A page pool [L, P, PS, K, D] with D = 64 is stored 128 lanes wide, half of
+# every tile padding: twice the bytes held and twice the bytes a paged kernel
+# reads, and at a whole model's size enough for the compiler to re-lay the
+# pool out inside every decode step (PERF.md section 6, PR 55). The same
+# bytes as [L, P, PS, K / f, f * D] have no padding: `f` KV heads side by
+# side in a row. The paged ops take such a pool as it is — K / f KV heads of
+# f * D — given queries that are zero outside their own KV head's lanes.
+
+LANES = 128
+
+
+def lane_pack(num_kv: int, head_dim: int) -> int:
+    """How many KV heads share a row of a lane-packed pool: as many as fill
+    the 128 lanes, a power of two that divides the KV heads; 1 where the
+    head is 128 wide or wider."""
+    f = 1
+    while 2 * f * head_dim <= LANES and num_kv % (2 * f) == 0:
+        f *= 2
+    return f
+
+
+def pack_kv(x: jnp.ndarray, f: int) -> jnp.ndarray:
+    """Keys or values [..., K, D] as a packed pool holds them,
+    [..., K / f, f * D]: the same numbers in the same order."""
+    *lead, k, d = x.shape
+    return x.reshape(*lead, k // f, f * d)
+
+
+def _own_lanes(heads: int, num_kv: int, f: int) -> jnp.ndarray:
+    """[H, f] bool: the place of query head j's KV head in its packed row."""
+    place = (jnp.arange(heads) // (heads // num_kv)) % f
+    return place[:, None] == jnp.arange(f)[None, :]
+
+
+def pack_queries(q: jnp.ndarray, num_kv: int, f: int) -> jnp.ndarray:
+    """Queries [B, T, H, D] against a pool packed by `f`: [B, T, H, f * D],
+    a head's vector in its own KV head's lanes and zero in the others', so
+    that its product with a packed row is its product with its own key;
+    times sqrt(f), because the ops scale by (f * D)^-0.5."""
+    b, t, h, d = q.shape
+    own = _own_lanes(h, num_kv, f)[:, :, None]
+    packed = jnp.where(own, (q * f**0.5)[:, :, :, None, :], 0)
+    return packed.reshape(b, t, h, f * d).astype(q.dtype)
+
+
+def unpack_heads(out: jnp.ndarray, num_kv: int, f: int) -> jnp.ndarray:
+    """What attention over packed values gives [B, T, H, f * D], cut to each
+    head's own KV head's lanes: [B, T, H, D]."""
+    b, t, h, fd = out.shape
+    own = _own_lanes(h, num_kv, f)[:, :, None]
+    return jnp.sum(jnp.where(own, out.reshape(b, t, h, f, fd // f), 0),
+                   axis=3).astype(out.dtype)
+
+
 def gqa_attention_prefill(
     q: jnp.ndarray,  # [B, T, H, D]
     k: jnp.ndarray,  # [B, T, K, D]

@@ -32,7 +32,8 @@ PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "longcat_flash": "debug-longcat-tiny",
            "mimo_v2": "debug-mimo-tiny",
            "olmo_hybrid": "debug-olmo-hybrid-tiny",
-           "afmoe": "debug-trinity-tiny"}
+           "afmoe": "debug-trinity-tiny",
+           "granite_hybrid": "debug-granite-hybrid-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -176,6 +177,7 @@ OLD_MODEL_TYPES = {
     "sdar_moe": "sdar_moe", "nemotron_h": "nemotron_h",
     "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
     "olmo_hybrid": "olmo_hybrid", "afmoe": "afmoe",
+    "granitemoehybrid": "granite_hybrid",
 }
 OLD_MECHANISM_KEYS = {
     "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
@@ -185,7 +187,7 @@ OLD_MECHANISM_KEYS = {
                          "mimo_v2"),
     "n_shared_experts": ("deepseek_v3", "nemotron_h"),
     "first_k_dense_replace": ("deepseek_v3",),
-    "num_local_experts": ("mixtral",),
+    "num_local_experts": ("mixtral", "granite_hybrid"),
     "num_experts": ("mixtral", "sdar_moe", "afmoe"),
     "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h",
                               "mimo_v2", "afmoe"),
@@ -202,7 +204,7 @@ OLD_MECHANISM_KEYS = {
     "swa_num_key_value_heads": ("mimo_v2",),
     # kinds of layer and the linear-attention layers' sizes: computed by one
     # family since PR 48; before it no class read them and none refused them
-    "layer_types": ("olmo_hybrid", "afmoe"),
+    "layer_types": ("olmo_hybrid", "afmoe", "granite_hybrid"),
     "linear_num_key_heads": ("olmo_hybrid",),
     "linear_num_value_heads": ("olmo_hybrid",),
     "linear_key_head_dim": ("olmo_hybrid",),
@@ -220,6 +222,16 @@ OLD_MECHANISM_KEYS = {
     "score_func": ("afmoe",),
     "mup_enabled": ("afmoe",),
     "global_attn_every_n_layers": ("afmoe",),
+    # state-space layers at one group beside a feed-forward in every layer,
+    # Granite's four multipliers and its positions: one family since PR 55
+    # (it reads `num_local_experts` to refuse its mixture siblings by name)
+    **dict.fromkeys((
+        "mamba_n_heads", "mamba_d_head", "mamba_n_groups", "mamba_d_state",
+        "mamba_d_conv", "mamba_expand", "mamba_chunk_size",
+        "mamba_conv_bias", "embedding_multiplier",
+        "attention_multiplier", "residual_multiplier", "logits_scaling",
+        "position_embedding_type", "shared_intermediate_size"),
+        ("granite_hybrid",)),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
 }
@@ -282,10 +294,11 @@ LINEAR_STATED = [
 
 
 @pytest.mark.parametrize("stated", LINEAR_STATED, ids=lambda d: next(iter(d)))
-# (afmoe reads `layer_types` too and refuses a kind it does not attend by in
-# its own class, by name: tests/engine/test_band_family.py)
+# (afmoe and granite_hybrid read `layer_types` too and refuse a kind they do
+# not compute in their own class, by name: tests/engine/test_band_family.py,
+# test_granite_family.py)
 @pytest.mark.parametrize("model_type", sorted(
-    set(OLD_MODEL_TYPES) - {"olmo_hybrid", "afmoe"})
+    set(OLD_MODEL_TYPES) - {"olmo_hybrid", "afmoe", "granitemoehybrid"})
     + ["a_type_nobody_registered"])
 def test_a_linear_attention_config_is_served_as_no_other_model(
         model_type, stated):
@@ -305,12 +318,15 @@ def test_a_linear_attention_config_is_served_as_no_other_model(
 
 @pytest.mark.parametrize("stated", LINEAR_STATED[2:],
                          ids=lambda d: next(iter(d)))
-def test_a_linear_key_is_refused_of_the_window_band_family_too(stated):
+@pytest.mark.parametrize("model_type", ["afmoe", "granitemoehybrid"])
+def test_a_linear_key_is_refused_of_the_window_band_family_too(model_type,
+                                                               stated):
     (key, value), = stated.items()
+    family = OLD_MODEL_TYPES[model_type]
     with pytest.raises(ValueError, match=re.escape(
-            f"carries {key}={value!r}, which models/afmoe.py does not "
+            f"carries {key}={value!r}, which models/{family}.py does not "
             "compute")):
-        config_from_hf({"model_type": "afmoe", "intermediate_size": 128,
+        config_from_hf({"model_type": model_type, "intermediate_size": 128,
                         **stated})
 
 

@@ -138,3 +138,80 @@ def test_the_convolution_carries_the_rows_that_end_at_the_length():
                                jnp.zeros((1, 3, 6)), w, b, jnp.asarray([13]))
     np.testing.assert_allclose(np.asarray(nxt), np.asarray(whole[:, 9:]),
                                rtol=1e-5, atol=1e-5)
+
+
+# --- ONE group of B and C for all the heads (Granite-4.0-H, PR 55) -----------
+
+def _one_group(seed, b, t, heads=64, p=64, n=16):
+    r = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)  # noqa: E731
+    return dict(
+        x=f(b, t, heads, p), dt=jax.nn.softplus(f(b, t, heads) - 2.0),
+        a=-jnp.exp(jnp.asarray(r.uniform(0, 2.7, heads), jnp.float32)),
+        b=f(b, t, 1, n), c=f(b, t, 1, n), d=f(heads), s0=f(b, heads, p, n))
+
+
+def _recurrence_one_group(x, dt, a, b, c, d, s0, lens):
+    """`_recurrence` for any head count and one group, vectorised over the
+    heads, in numpy float64."""
+    x, dt, a, b, c, d, s = (np.asarray(v, np.float64)
+                            for v in (x, dt, a, b, c, d, s0))
+    ys = np.zeros(x.shape)
+    for row in range(x.shape[0]):
+        for t in range(int(lens[row])):
+            s[row] = (np.exp(dt[row, t] * a)[:, None, None] * s[row]
+                      + (dt[row, t][:, None] * x[row, t])[:, :, None]
+                      * b[row, t, 0][None, None, :])
+            ys[row, t] = s[row] @ c[row, t, 0] + d[:, None] * x[row, t]
+    return ys, s
+
+
+@pytest.mark.parametrize("live", [None, [True, False, True]],
+                         ids=["all_live", "a_row_not_live"])
+def test_the_kernel_at_one_group_of_4096_channels_equals_the_plain_route(live):
+    """`ssm_decode_step` (interpret) at H P = 4096 channels in ONE group —
+    the whole [128, 4096] tile of coefficients transposed in one pass —
+    against `ssm_step`'s jax.numpy arm and the per-token recurrence."""
+    v = _one_group(11, 3, 1)
+    pool = jnp.asarray(np.random.default_rng(12).normal(
+        size=(2, 3, 64, 64, 16)), jnp.float32)
+    x, dt, b, c = v["x"][:, 0], v["dt"][:, 0], v["b"][:, 0], v["c"][:, 0]
+    live = None if live is None else jnp.asarray(live)
+    y, new = ssm.ssm_step(x, dt, v["a"], b, c, v["d"], pool + 0, 1,
+                          slots=jnp.arange(3), live=live)  # no kernel
+    decay, dtx = ssm._step_inputs(x, dt, v["a"], live)
+    k_pool, k_sc = ssm.ssm_decode_step(pool + 0, 1, decay, dtx, b, c,
+                                       interpret=True)
+    np.testing.assert_allclose(np.asarray(k_pool), np.asarray(new),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(k_sc + x * v["d"][:, None]),
+                               np.asarray(y), rtol=1e-5, atol=1e-5)
+    assert (np.asarray(k_pool[0]) == np.asarray(pool[0])).all()
+    rows = [0, 2] if live is not None else [0, 1, 2]
+    want_y, want_s = _recurrence_one_group(**{**v, "s0": pool[1]},
+                                           lens=[1, 1, 1])
+    np.testing.assert_allclose(np.asarray(y)[rows], want_y[rows, 0],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[1])[rows], want_s[rows],
+                               rtol=1e-5, atol=1e-5)
+    if live is not None:  # bit for bit
+        assert (np.asarray(k_pool[1, 1]) == np.asarray(pool[1, 1])).all()
+        assert (np.asarray(new[1, 1]) == np.asarray(pool[1, 1])).all()
+
+
+@pytest.mark.parametrize("t,lens,initial", [
+    (256, [256, 200], False),  # one whole chunk of the published 256
+    (300, [300, 41], True),    # ends inside the second; shorter than one
+])
+def test_the_scan_at_chunks_of_256_and_one_group_equals_the_recurrence(
+        t, lens, initial):
+    v = _one_group(t, 2, t, heads=8, p=8)
+    if not initial:
+        v["s0"] = jnp.zeros_like(v["s0"])
+    lens = jnp.asarray(lens, jnp.int32)
+    y, s = ssm.ssd_chunked(**v, lens=lens, chunk=256)
+    want_y, want_s = _recurrence_one_group(**v, lens=lens)
+    for row, n in enumerate(np.asarray(lens)):
+        np.testing.assert_allclose(np.asarray(y)[row, :n], want_y[row, :n],
+                                   rtol=5e-4, atol=5e-4)
+    np.testing.assert_allclose(np.asarray(s), want_s, rtol=5e-4, atol=5e-4)

@@ -648,6 +648,42 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
     assert not bad, bad
 
 
+def test_a_pool_at_heads_of_64_is_held_two_heads_to_a_row_and_copied_nowhere(
+        one_chip, monkeypatch):
+    """Granite-4.0-H's widths (8 KV heads of 64, 64 state-space heads of 64
+    at ONE group) at a cut of three layers, compiled for a v5e: the page
+    pool is `[A, P, 128, 4, 128]`, two heads to a 128-lane row, so no tile of
+    it is half padding, and the compiler neither re-lays it out nor copies
+    it (as `[.., 8, 64]` at the whole model's size it did both inside every
+    decode step, with 1.2 GB of temporaries: PERF.md section 6, PR 55); the
+    state step at one group of 4,096 channels lowers through Mosaic."""
+    from llmlb_tpu.models import granite_hybrid
+
+    cfg = granite_hybrid.GraniteHybridConfig(
+        vocab_size=100352, hidden_size=2048, intermediate_size=8192,
+        num_layers=3, num_heads=32, num_kv_heads=8, tie_word_embeddings=True,
+        embedding_multiplier=12.0, logits_scaling=8.0,
+        layer_types=("mamba", "attention", "mamba"))
+    assert cfg.pool_pack == 2
+    compiled = _compiled_burst(
+        one_chip, monkeypatch, granite_hybrid, cfg, pages=CHIP_PAGES,
+        rows=CHIP_ROWS, window=512, pool={"num_slots": CHIP_ROWS},
+        kernels=(pallas_attention.paged_flash_decode, ssm.ssm_decode_step))
+    hlo = compiled.as_text()
+    # two state steps and one attention a decode step
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    pool = rf"bf16\[1,{CHIP_PAGES},(128,4|512),128\]"
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    bad = [(shape, op) for shape, op in results
+           if re.match(pool, shape) and op in ("copy", "transpose")]
+    assert not bad, bad
+    assert "remat_compressed" not in hlo
+    # the head is the embedding table as it lies: no transposed copy of it
+    assert not [shape for shape, op in results
+                if shape.startswith("bf16[2048,100352]")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kv_heads,groups", [(8, 4), (2, 16), (32, 1)],
                          ids=["mistral-K8xG4", "nemotron-K2xG16", "MHA-32"])
