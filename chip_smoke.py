@@ -1094,9 +1094,9 @@ def kernel_leg() -> int:
         attempt("delta_rule_step", f"slots={slots}", rule_step)
 
     # the state-space step kernel at ONE group of B and C for all 64 heads
-    # (Granite-4.0-H: a [128, 4096] tile of coefficients transposed at once)
-    # and at Nemotron-3-Nano's 8 groups, layer 1 of a pool stacked over two,
-    # a row in three not live: the output, and the whole pool
+    # (Granite-4.0-H) and at Nemotron-3-Nano's 8 groups — one body, a group's
+    # rows chosen a turn of its loop — layer 1 of a pool stacked over two, a
+    # row in three not live: the output, and the whole pool
     from llmlb_tpu.ops import ssm
 
     for groups in (1, 8):

@@ -30,6 +30,7 @@ it, the last `width - 1` rows of the convolution's input.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -130,26 +131,57 @@ def _step_inputs(x, dt, a, live):
     return decay, dtx
 
 
-def _ssm_decode_kernel(layer_ref, slot_ref, coef_ref, b_ref, c_ref, s_ref,
-                       y_ref, o_ref, *, groups: int):
-    """One slot: S <- decay S + (dt x) (x) B, y = S C. The per-(head,
-    channel) numbers arrive on lanes (coef [8, H*P]: row 0 dt x, row 1 the
-    decay) and are wanted down the state's sublanes, so a group's slice is
-    padded to a whole tile and transposed once ([128, R*P] -> [R*P, 128])
-    and its columns broadcast along the N lanes; y goes back the same way."""
-    del layer_ref, slot_ref
+def _tile_rows(h: int, p: int, groups: int) -> int:
+    """The (head, channel) rows a turn of the kernel's loop takes: 512 (four
+    [128, 128] tiles at once through the transpose unit: a turn waits for
+    each of its two crossings, and at one tile a turn that wait paces the
+    kernel) where whole heads fill them and a group's heads fill or divide
+    them, else all of them at once."""
+    rows, r = math.gcd(h * p, 512), h // groups
+    hpt = rows // p
+    whole = rows % p == 0 and (hpt % r == 0 or r % hpt == 0)
+    return rows if whole else h * p
+
+
+def _ssm_decode_kernel(layer_ref, slot_ref, decay_ref, dtx_ref, b_ref, c_ref,
+                       s_ref, y_ref, o_ref):
+    """One slot: S <- decay S + (dt x) (x) B, y = S C, `_tile_rows` (head,
+    channel) rows a turn of the loop, the same turn whatever the heads and
+    the groups. The decay is a scalar a head (SMEM) and multiplies its
+    [P, N] rows as a splat; a group's B and C are sublane broadcasts over
+    its heads' rows. Only dt x has to cross from lanes to sublanes (its
+    strip of the row, broadcast and transposed), and the sum over N crosses
+    back the same way: the product transposed so that N lies on sublanes,
+    its vregs added, one sublane reduce a tile, and y arrives on the lanes
+    it is written in."""
+    del layer_ref
+    slot = slot_ref[pl.program_id(0)]
     h, p, n = s_ref.shape
-    r = h // groups
-    gp = r * p  # (head, channel) pairs of one group
-    for g in range(groups):
-        lanes = pl.ds(g * gp, gp)
-        coef = jnp.concatenate(
-            [coef_ref[:, lanes], jnp.zeros((120, gp), F32)], axis=0).T
-        s = s_ref[pl.ds(g * r, r)].reshape(gp, n)
-        s = s * coef[:, 1:2] + coef[:, 0:1] * b_ref[pl.ds(g, 1), :]
-        o_ref[pl.ds(g * r, r)] = s.reshape(r, p, n)
-        y = jnp.sum(s * c_ref[pl.ds(g, 1), :], axis=1, keepdims=True)
-        y_ref[:, lanes] = jnp.broadcast_to(y, (gp, 128)).T[:8]
+    groups = b_ref.shape[0]
+    rows = _tile_rows(h, p, groups)
+    hpt = rows // p  # heads a turn
+    r = h // groups  # heads a group
+    gpt = max(1, hpt // r)  # groups a turn
+
+    def turn(t, carry):
+        head = t * hpt
+        lanes = pl.ds(pl.multiple_of(t * rows, rows), rows)
+
+        def group_rows(ref):  # [rows, N]: each (head, channel) row's group's
+            own = ref[pl.ds(head // r, gpt), :]
+            return jnp.broadcast_to(own[:, None, :], (gpt, rows // gpt, n)
+                                    ).reshape(rows, n)
+
+        s = jnp.concatenate([s_ref[head + j] * decay_ref[slot, head + j]
+                             for j in range(hpt)], axis=0)
+        dtx = jnp.broadcast_to(dtx_ref[pl.ds(slot, 1), lanes], (n, rows)).T
+        s = s + dtx * group_rows(b_ref)
+        o_ref[pl.ds(head, hpt)] = s.reshape(hpt, p, n)
+        y_ref[pl.ds(slot, 1), lanes] = jnp.sum((s * group_rows(c_ref)).T,
+                                               axis=0, keepdims=True)
+        return carry
+
+    lax.fori_loop(0, h * p // rows, turn, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",),
@@ -158,50 +190,53 @@ def ssm_decode_step(pool, layer, decay, dtx, b, c, *, interpret=None):
     """The decode step's state update as one kernel over the STACKED pool
     [L, slots, H, P, N] f32, read and written in place at (layer, slot)
     (`input_output_aliases`): a slice of the stack handed to a kernel is
-    copied, as a slice of the page pool was (PR 25). decay [B, H], dtx
-    [B, H, P] f32, b and c [B, G, N]; row i is slot i. Returns (pool,
-    S C [B, H, P] f32)."""
+    copied, as a slice of the page pool was (PR 25). decay [B, H] (scalars,
+    in SMEM), dtx [B, H, P] f32 (with y, all the rows' [B, H P] in one
+    block for the whole call: a 3-D view of it made the compiler re-lay out
+    the page pool, PERF.md section 6, PR 56), b and c [B, G, N]; row i is slot
+    i. Returns (pool, S C [B, H, P] f32)."""
     if interpret is None:
         interpret = _interpret_default()
     _, slots, h, p, n = pool.shape
     groups = b.shape[1]
-    coef = jnp.zeros((slots, 8, h * p), F32)
-    coef = coef.at[:, 0].set(dtx.reshape(slots, h * p))
-    coef = coef.at[:, 1].set(jnp.repeat(decay, p, axis=1))
 
-    def row(i, layer, slot):
+    def rows(i, *_):
+        return (0, 0)
+
+    def row(i, layer, slot, decay):
         return (slot[i], 0, 0)
 
-    def state(i, layer, slot):
+    def state(i, layer, slot, decay):
         return (layer[0], slot[i], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(slots,),
         in_specs=[
-            pl.BlockSpec((None, 8, h * p), row),
+            pl.BlockSpec((slots, h * p), rows),
             pl.BlockSpec((None, groups, n), row),
             pl.BlockSpec((None, groups, n), row),
             pl.BlockSpec((None, None, h, p, n), state),
         ],
         out_specs=[
-            pl.BlockSpec((None, 8, h * p), row),
+            pl.BlockSpec((slots, h * p), rows),
             pl.BlockSpec((None, None, h, p, n), state),
         ],
     )
     y, pool = pl.pallas_call(
-        functools.partial(_ssm_decode_kernel, groups=groups),
-        out_shape=[jax.ShapeDtypeStruct((slots, 8, h * p), F32),
+        _ssm_decode_kernel,
+        out_shape=[jax.ShapeDtypeStruct((slots, h * p), F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         grid_spec=grid_spec,
-        input_output_aliases={5: 1},  # the pool, behind the two scalars
+        input_output_aliases={6: 1},  # the pool, behind the three scalars
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=48 << 20),
         interpret=interpret,
         name="ssm_decode_step",
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.arange(slots, dtype=jnp.int32), coef, b.astype(F32),
-      c.astype(F32), pool)
-    return pool, y[:, 0].reshape(slots, h, p)
+      jnp.arange(slots, dtype=jnp.int32), decay.astype(F32),
+      dtx.astype(F32).reshape(slots, h * p), b.astype(F32), c.astype(F32),
+      pool)
+    return pool, y.reshape(slots, h, p)
 
 
 def ssm_step(x, dt, a, b, c, d, pool, layer, *, slots=None, live=None):

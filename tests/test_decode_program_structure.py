@@ -264,6 +264,39 @@ def test_the_group_is_a_function_of_the_shapes():
     assert "environ" not in source and "name" not in source
 
 
+# --- the state-space step kernel: one turn of one loop, whatever the shape -----
+
+def _state_step_census(heads, groups, channels=64, state=128, slots=4):
+    """The primitives of `ssm_decode_step`'s traced kernel body."""
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    closed = jax.make_jaxpr(
+        lambda *a: ssm.ssm_decode_step(*a, interpret=False))(
+        f32((2, slots, heads, channels, state)),
+        jax.ShapeDtypeStruct((), jnp.int32), f32((slots, heads)),
+        f32((slots, heads, channels)), f32((slots, groups, state)),
+        f32((slots, groups, state)))
+    (call,) = [e for e in _equations(closed.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    return collections.Counter(
+        e.primitive.name for e in _equations(call.params["jaxpr"], True))
+
+
+@pytest.mark.parametrize("heads,groups", [(8, 1), (64, 2), (64, 8), (128, 8)],
+                         ids=["H8xG1", "H64xG2", "H64xG8", "H128xG8"])
+def test_the_state_step_body_does_not_grow_with_the_heads_or_the_groups(
+        heads, groups):
+    """The guard PR 54 gave the paged decode kernel, for the kernel a
+    Granite decode program holds 36 call sites of: its traced body at 8
+    heads, at 128, at two groups and at eight is the body at Granite's 64
+    heads and one group, equation for equation (a body written out a head
+    or a group is traced and lowered that many times at every start: what
+    refused PR 53) — one loop, and in a turn of it one crossing each way."""
+    want = _state_step_census(64, 1)
+    assert _state_step_census(heads, groups) == want
+    assert want["transpose"] == 2 and want["reduce_sum"] == 1
+    assert want["scan"] + want["while"] == 1  # lax.fori_loop, either form
+
+
 # --- the extend kernels: what a grid step does follows the q block -----------
 
 # the block family's heads, page and pool as its cell serves them
@@ -622,6 +655,23 @@ def _compiled_burst(one_chip, monkeypatch, family, cfg, *, pages, rows,
             fn._clear_cache()
 
 
+def _coefficient_rows(hlo, results):
+    """What PR 56 took out of a compiled burst with state-space layers at 64
+    heads of 64: the `[slots, 8, H P]` buffer of coefficient rows (zeros and
+    two rows of numbers, written in front of every state step and read back
+    by it) under any op, and any operand of a state step that a
+    `dynamic-update-slice` (or a `pad`) made."""
+    found = [(shape, op) for shape, op in results
+             if shape.startswith(f"f32[{CHIP_ROWS},8,4096]")]
+    made_by = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = \S+ ([\w\-]+)\(", hlo,
+                              re.M))
+    steps = re.findall(r"custom-call\(([^)]*)\)[^\n]*ssm_decode_step", hlo)
+    operands = [name for step in steps
+                for name in re.findall(r"%[\w.\-]+", step)]
+    return found + [(name, made_by[name]) for name in operands
+                    if made_by.get(name) in ("dynamic-update-slice", "pad")]
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
                                                           monkeypatch):
@@ -678,6 +728,7 @@ def test_a_pool_at_heads_of_64_is_held_two_heads_to_a_row_and_copied_nowhere(
            if re.match(pool, shape) and op in ("copy", "transpose")]
     assert not bad, bad
     assert "remat_compressed" not in hlo
+    assert not _coefficient_rows(hlo, results)
     # the head is the embedding table as it lies: no transposed copy of it
     assert not [shape for shape, op in results
                 if shape.startswith("bf16[2048,100352]")]
@@ -987,6 +1038,7 @@ def test_compiled_hybrid_burst_updates_the_state_in_place_and_copies_no_experts(
            if any(re.match(p, shape) for p in a_layer)
            or (op in moved and any(re.match(p, shape) for p in whole))]
     assert not bad, bad
+    assert not _coefficient_rows(hlo, results)
     # the temporaries are the step's logits and activations, not the state
     state = 2 * CHIP_ROWS * 64 * 64 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < state / 2
