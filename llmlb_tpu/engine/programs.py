@@ -528,7 +528,7 @@ class StepPrograms:
             # Params and caches carry theirs. The per-slot vectors and the
             # key are lowered unspecified, while a dispatch hands them over
             # placed on the mesh: the two still land under different keys
-            # (ROADMAP Speed 4).
+            # (ROADMAP, Speed, "Set-up", cure (a)).
             return jax.ShapeDtypeStruct(
                 x.shape, x.dtype, sharding=x.sharding if placed else None)
 

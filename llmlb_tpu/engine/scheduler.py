@@ -587,10 +587,10 @@ class EngineCore:
         # dispatch (lax.scan with on-device token feedback) per host readback.
         # The per-step host sync is pure latency — tokens/sec scales with k
         # while the host↔device round trip dominates the step. Auto: 8 on
-        # TPU (not re-derived for a local chip: ROADMAP Speed 3), 1 elsewhere
-        # (CPU tests keep single-step token-for-token goldens). Emission
-        # becomes k-token bursts; EOS/max_tokens mid-burst are trimmed
-        # host-side.
+        # TPU (not re-derived for a local chip: ROADMAP, Speed, "Time to
+        # first token", cure (b)), 1 elsewhere (CPU tests keep single-step
+        # token-for-token goldens). Emission becomes k-token bursts;
+        # EOS/max_tokens mid-burst are trimmed host-side.
         if decode_burst is None:
             env = os.environ.get("LLMLB_DECODE_BURST")
             if env:

@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.deepseek_v3 import (
     EXPERT_LOAD_COUNTERS,
     LOAD_BUCKETS,
@@ -247,12 +248,13 @@ def _layer_shapes(cfg: AfmoeConfig) -> dict[str, tuple[tuple, int]]:
     }
 
 
-def _stacks(cfg: AfmoeConfig):
-    """(prefix, names, layers) of every stack the config calls for."""
+def _leaves(cfg: AfmoeConfig) -> list[stacks.Leaf]:
+    """Every stacked leaf the config calls for: a stack a kind of attention
+    and a kind of feed-forward."""
     dense = cfg.num_layers - cfg.num_moe_layers
-    out = [(G, _ATTN, cfg.layers_of(GLOBAL)), (W, _ATTN, cfg.layers_of(WINDOW)),
-           (DENSE, _DENSE_MLP, dense), ("", _MOE_MLP, cfg.num_moe_layers)]
-    return [s for s in out if s[2] > 0]
+    return stacks.stack_leaves(_layer_shapes(cfg), [
+        (G, _ATTN, cfg.layers_of(GLOBAL)), (W, _ATTN, cfg.layers_of(WINDOW)),
+        (DENSE, _DENSE_MLP, dense), ("", _MOE_MLP, cfg.num_moe_layers)])
 
 
 def init_params(cfg: AfmoeConfig, key: jax.Array) -> Params:
@@ -260,57 +262,17 @@ def init_params(cfg: AfmoeConfig, key: jax.Array) -> Params:
     the benchmark): matrices normal x fan_in^-0.5, norms ones, the router's
     choice bias a seeded normal of sd 0.02 in float32 (deepseek_v3.
     init_params says why it is not zero)."""
-    leaves = [(p, n, count) for p, names, count in _stacks(cfg) for n in names]
-    keys = iter(jax.random.split(key, len(leaves) + 2))
-    e = cfg.hidden_size
-    shapes = _layer_shapes(cfg)
-
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, F32) * fan_in**-0.5
-                ).astype(cfg.dtype)
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype),
-                      "lm_head": w(next(keys), (e, cfg.vocab_size), e)}
-    for prefix, name, count in leaves:
-        shape, fan_in = shapes[name]
-        k = next(keys)
-        if fan_in:
-            leaf = w(k, (count, *shape), fan_in)
-        elif name == "router_bias":
-            leaf = 0.02 * jax.random.normal(k, (count, *shape), F32)
-        else:
-            leaf = jnp.ones((count, *shape), cfg.dtype)  # the norms
-        params[prefix + name] = leaf
-    return params
+    return stacks.init_params(cfg, key, _leaves(cfg), stacks.seeded_bias(0.02))
 
 
 def param_logical_axes(cfg: AfmoeConfig) -> dict[str, tuple]:
-    layer = {
-        "wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
-        "wv": ("embed", "kv_heads"), "wgate": ("embed", "heads"),
-        "wo": ("heads", "embed"),
-        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"), "wd": ("ffn", "embed"),
-        "ws_gate": ("embed", "ffn"), "ws_up": ("embed", "ffn"),
-        "ws_down": ("ffn", "embed"),
-        "we_gate": ("experts", "embed", "ffn"),
-        "we_up": ("experts", "embed", "ffn"),
-        "we_down": ("experts", "ffn", "embed"),
-    }
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
-            "lm_head": ("embed", "vocab")}
-    shapes = _layer_shapes(cfg)
-    for prefix, names, _count in _stacks(cfg):
-        for name in names:
-            axes[prefix + name] = ("layers", *layer.get(
-                name, (None,) * len(shapes[name][0])))
-    return axes
+    layer = {**stacks.GQA_AXES, **stacks.MLP_AXES, **stacks.EXPERT_AXES,
+             "wgate": ("embed", "heads")}
+    return stacks.param_logical_axes(cfg, _leaves(cfg), layer)
 
 
 def param_shardings(cfg: AfmoeConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {name: logical_to_sharding(mesh, rules, *axes)
-            for name, axes in param_logical_axes(cfg).items()}
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

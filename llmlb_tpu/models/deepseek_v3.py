@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.family import Family, StepCounter
 from llmlb_tpu.models.llama import (
     Attention,
@@ -185,7 +186,7 @@ _MOE_MLP = ("router", "router_bias", "we_gate", "we_up", "we_down",
 
 
 def _layer_shapes(cfg: DeepseekV3Config) -> dict[str, tuple[tuple, int]]:
-    """name -> (shape of one layer's leaf, fan-in; 0 = ones)."""
+    """name -> (shape of one layer's leaf, fan-in; 0 = its own rule)."""
     e, h, c = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     f, x, fm = cfg.intermediate_size, cfg.num_experts, cfg.moe_intermediate_size
@@ -200,18 +201,19 @@ def _layer_shapes(cfg: DeepseekV3Config) -> dict[str, tuple[tuple, int]]:
         "ln_attn": ((e,), 0),
         "ln_mlp": ((e,), 0),
         "wg": ((e, f), e), "wu": ((e, f), e), "wd": ((f, e), f),
-        "router": ((e, x), e),
+        "router": ((e, x), e), "router_bias": ((x,), 0),
         "we_gate": ((x, e, fm), e), "we_up": ((x, e, fm), e),
         "we_down": ((x, fm, e), fm),
         "ws_gate": ((e, fs), e), "ws_up": ((e, fs), e), "ws_down": ((fs, e), fs),
     }
 
 
-def _group_leaves(cfg: DeepseekV3Config):
-    """(key in the pytree, name, layers) of every stacked leaf."""
-    out = [(DENSE + n, n, cfg.first_k_dense) for n in _ATTN + _DENSE_MLP]
-    out += [(n, n, cfg.num_moe_layers) for n in _ATTN + _MOE_MLP]
-    return [leaf for leaf in out if leaf[2] > 0]
+def _group_leaves(cfg: DeepseekV3Config) -> list[stacks.Leaf]:
+    """Every stacked leaf: the dense group's under `DENSE`, the mixture
+    group's under their names."""
+    return stacks.stack_leaves(_layer_shapes(cfg), [
+        (DENSE, _ATTN + _DENSE_MLP, cfg.first_k_dense),
+        ("", _ATTN + _MOE_MLP, cfg.num_moe_layers)])
 
 
 def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
@@ -223,57 +225,23 @@ def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
     decode step of 64 rows over 118 of 128 experts, where uniform routing
     gives 122 and a bias balanced over seeded tokens by the architecture's
     own rule gave 114 (PERF.md section 6, PR 31)."""
-    shapes = _layer_shapes(cfg)
-    leaves = _group_leaves(cfg)
-    keys = iter(jax.random.split(key, len(leaves) + 2))
-    e = cfg.hidden_size
-
-    def w(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
-                * fan_in**-0.5).astype(cfg.dtype)
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype)}
-    for full, name, count in leaves:
-        k = next(keys)
-        if name == "router_bias":
-            params[full] = 0.02 * jax.random.normal(
-                k, (count, cfg.num_experts), jnp.float32)
-            continue
-        shape, fan_in = shapes[name]
-        params[full] = (w(k, (count, *shape), fan_in) if fan_in
-                        else jnp.ones((count, *shape), cfg.dtype))
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = w(next(keys), (e, cfg.vocab_size), e)
-    return params
+    return stacks.init_params(cfg, key, _group_leaves(cfg),
+                              stacks.seeded_bias(0.02), head_last=True)
 
 
 def param_logical_axes(cfg: DeepseekV3Config) -> dict[str, tuple]:
     layer = {
-        "wq": ("embed", "heads"), "wkv_a": ("embed", None), "ln_kv": (None,),
+        **stacks.MLP_AXES, **stacks.EXPERT_AXES,
+        "wq": ("embed", "heads"), "wkv_a": ("embed", None),
         "wk_b": ("heads", None, None), "wv_b": ("heads", None, None),
         "wo": ("heads", "embed"), "ln_attn": ("embed",), "ln_mlp": ("embed",),
-        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"), "wd": ("ffn", "embed"),
-        "router": ("embed", None), "router_bias": (None,),
-        "we_gate": ("experts", "embed", "ffn"),
-        "we_up": ("experts", "embed", "ffn"),
-        "we_down": ("experts", "ffn", "embed"),
-        "ws_gate": ("embed", "ffn"), "ws_up": ("embed", "ffn"),
-        "ws_down": ("ffn", "embed"),
+        "router": ("embed", None),
     }
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
-            "lm_head": ("embed", "vocab")}
-    for full, name, _count in _group_leaves(cfg):
-        axes[full] = ("layers", *layer[name])
-    return axes
+    return stacks.param_logical_axes(cfg, _group_leaves(cfg), layer)
 
 
 def param_shardings(cfg: DeepseekV3Config, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {
-        name: logical_to_sharding(mesh, rules, *axes)
-        for name, axes in param_logical_axes(cfg).items()
-    }
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.family import Family, StepCounter
 from llmlb_tpu.models.llama import (
     GQA_ATTENTION,
@@ -73,7 +74,13 @@ from llmlb_tpu.models.llama import (
     _qkv,
     shard_rules_for,
 )
-from llmlb_tpu.models.nemotron_h import seeded_vector, ssm_mixer
+from llmlb_tpu.models.nemotron_h import (
+    ATTN,
+    SSM,
+    mixer_shapes,
+    seeded_vector,
+    ssm_mixer,
+)
 from llmlb_tpu.ops.attention import (
     lane_pack,
     pack_kv,
@@ -202,11 +209,8 @@ class GraniteHybridConfig(LlamaConfig):
 # Params: one stack a RUN of like layers, the feed-forward with its layer
 # ---------------------------------------------------------------------------
 
-_SSM = ("ln_ssm", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
-        "ssm_a_log", "ssm_d", "ln_gate", "ssm_out")
-_ATTN = ("ln_attn", "wq", "wk", "wv", "wo")
 _MLP = ("ln_mlp", "wg", "wu", "wd")
-_NAMES = {MAMBA: _SSM + _MLP, ATTENTION: _ATTN + _MLP}
+_NAMES = {MAMBA: SSM + _MLP, ATTENTION: ATTN + _MLP}
 
 
 def runs(layer_types) -> list[tuple[str, str, int]]:
@@ -222,28 +226,16 @@ def runs(layer_types) -> list[tuple[str, str, int]]:
 
 def _layer_shapes(cfg: GraniteHybridConfig) -> dict[str, tuple[tuple, int]]:
     """name -> (shape of one layer's leaf, fan-in; 0 = its own rule)."""
-    e, d, f = cfg.hidden_size, cfg.head_dim_, cfg.intermediate_size
-    di, cd, hs = cfg.d_inner, cfg.conv_dim, cfg.ssm_heads
-    return {
-        "ln_ssm": ((e,), 0), "ssm_in": ((e, di + cd + hs), e),
-        "ssm_conv_w": ((cd, cfg.conv_kernel), 0), "ssm_conv_b": ((cd,), 0),
-        "ssm_dt_bias": ((hs,), 0), "ssm_a_log": ((hs,), 0),
-        "ssm_d": ((hs,), 0), "ln_gate": ((di,), 0), "ssm_out": ((di, e), di),
-        "ln_attn": ((e,), 0), "wq": ((e, cfg.num_heads * d), e),
-        "wk": ((e, cfg.num_kv_heads * d), e),
-        "wv": ((e, cfg.num_kv_heads * d), e),
-        "wo": ((cfg.num_heads * d, e), cfg.num_heads * d),
-        "ln_mlp": ((e,), 0), "wg": ((e, f), e), "wu": ((e, f), e),
-        "wd": ((f, e), f),
-    }
+    e, f = cfg.hidden_size, cfg.intermediate_size
+    return {**mixer_shapes(cfg), "ln_mlp": ((e,), 0),
+            "wg": ((e, f), e), "wu": ((e, f), e), "wd": ((f, e), f)}
 
 
-def _leaves(cfg: GraniteHybridConfig):
-    """(key in the pytree, a layer's name for it, layers) of every stacked
-    leaf `layer_types` calls for."""
-    return [(prefix + n, n, count)
-            for prefix, kind, count in runs(cfg.layer_types)
-            for n in _NAMES[kind]]
+def _leaves(cfg: GraniteHybridConfig) -> list[stacks.Leaf]:
+    """Every stacked leaf `layer_types` calls for: a stack a run."""
+    return stacks.stack_leaves(_layer_shapes(cfg), [
+        (prefix, _NAMES[kind], count)
+        for prefix, kind, count in runs(cfg.layer_types)])
 
 
 def init_params(cfg: GraniteHybridConfig, key: jax.Array) -> Params:
@@ -255,51 +247,18 @@ def init_params(cfg: GraniteHybridConfig, key: jax.Array) -> Params:
     time_step_floor; `D` and the norms ones; the convolution uniform within
     +-kernel^-0.5. The head is the embedding table when the config ties
     them."""
-    shapes = _layer_shapes(cfg)
-    leaves = _leaves(cfg)
-    keys = iter(jax.random.split(key, len(leaves) + 2))  # + table, head
-    e = cfg.hidden_size
-
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, F32) * fan_in**-0.5
-                ).astype(cfg.dtype)
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype)}
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = w(next(keys), (e, cfg.vocab_size), e)
-    for key_name, name, count in leaves:
-        shape, fan_in = shapes[name]
-        k = next(keys)
-        params[key_name] = (w(k, (count, *shape), fan_in) if fan_in
-                            else seeded_vector(cfg, name, k,
-                                               (count, *shape)))
-    return params
+    return stacks.init_params(cfg, key, _leaves(cfg), seeded_vector)
 
 
 def param_logical_axes(cfg: GraniteHybridConfig) -> dict[str, tuple]:
     """Attention and the feed-forward shard as llama's; the state-space
     projections replicate (nemotron_h.param_logical_axes says why)."""
-    layer = {
-        "wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
-        "wv": ("embed", "kv_heads"), "wo": ("heads", "embed"),
-        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"),
-        "wd": ("ffn", "embed"),
-    }
-    shapes = _layer_shapes(cfg)
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",)}
-    if not cfg.tie_word_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    for key_name, name, _count in _leaves(cfg):
-        axes[key_name] = ("layers", *layer.get(
-            name, (None,) * len(shapes[name][0])))
-    return axes
+    layer = {**stacks.GQA_AXES, **stacks.MLP_AXES}
+    return stacks.param_logical_axes(cfg, _leaves(cfg), layer)
 
 
 def param_shardings(cfg: GraniteHybridConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {name: logical_to_sharding(mesh, rules, *axes)
-            for name, axes in param_logical_axes(cfg).items()}
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

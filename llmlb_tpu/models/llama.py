@@ -41,7 +41,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.family import Family
+from llmlb_tpu.models.stacks import shard_rules_for
 from llmlb_tpu.ops.attention import (
     gqa_attention_prefill,
     paged_attention_decode,
@@ -50,7 +52,6 @@ from llmlb_tpu.ops.attention import (
 )
 from llmlb_tpu.ops.norms import rms_norm
 from llmlb_tpu.ops.rope import RopeScaling, apply_rope, rope_frequencies
-from llmlb_tpu.parallel.mesh import validate_tp
 from llmlb_tpu.parallel.sharding import ShardingRules, logical_to_sharding
 from llmlb_tpu.quant import quantize_kv
 
@@ -217,23 +218,8 @@ def param_logical_axes(cfg: LlamaConfig) -> dict[str, tuple]:
     return axes
 
 
-def shard_rules_for(cfg: LlamaConfig, tp: int) -> ShardingRules:
-    """Default rules; kv heads replicate when tp exceeds the kv head count."""
-    validate_tp(cfg.num_heads, cfg.num_kv_heads, tp)
-    if cfg.intermediate_size % tp != 0:
-        raise ValueError(
-            f"intermediate_size={cfg.intermediate_size} not divisible by tp={tp}"
-        )
-    kv_shardable = cfg.num_kv_heads % tp == 0
-    return ShardingRules(kv_heads="tp" if kv_shardable else None)
-
-
 def param_shardings(cfg: LlamaConfig, mesh: Mesh, rules: ShardingRules | None = None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {
-        name: logical_to_sharding(mesh, rules, *axes)
-        for name, axes in param_logical_axes(cfg).items()
-    }
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

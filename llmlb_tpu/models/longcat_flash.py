@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.deepseek_v3 import (  # noqa: F401 — family contract
     EXPERT_LOAD_COUNTERS,
     LOAD_BUCKETS,
@@ -72,10 +73,8 @@ from llmlb_tpu.models.llama import (
     _default_mlp_fn,
     _prefill_extend_paged_impl,
     _prefill_impl,
-    shard_rules_for,
 )
 from llmlb_tpu.ops import moe
-from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
 F32 = jnp.float32
@@ -214,10 +213,10 @@ def _layer_shapes(cfg: LongcatFlashConfig) -> dict[str, tuple[tuple, int]]:
     }
 
 
-def _leaves(cfg: LongcatFlashConfig):
-    """(key in the pytree, name) of every stacked leaf [num_layers, ...]."""
-    return [(prefix + n, n) for prefix, names in zip(SUB, _NAMES)
-            for n in names]
+def _leaves(cfg: LongcatFlashConfig) -> list[stacks.Leaf]:
+    """Every stacked leaf [num_layers, ...]: a stack a sub-layer."""
+    return stacks.stack_leaves(_layer_shapes(cfg), [
+        (prefix, names, cfg.num_layers) for prefix, names in zip(SUB, _NAMES)])
 
 
 def router_bias_sd(cfg: LongcatFlashConfig) -> float:
@@ -233,52 +232,21 @@ def init_params(cfg: LongcatFlashConfig, key: jax.Array) -> Params:
     fan_in^-0.5 (_layer_shapes says what a scaled latent's fan-in is), norms
     ones, the router's choice bias a seeded normal that
     is NOT zero (deepseek_v3.init_params says why), of router_bias_sd."""
-    shapes = _layer_shapes(cfg)
-    leaves = _leaves(cfg)
-    keys = iter(jax.random.split(key, len(leaves) + 2))
-    e, count = cfg.hidden_size, cfg.num_layers
-
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, F32) * fan_in**-0.5
-                ).astype(cfg.dtype)
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype),
-                      "lm_head": w(next(keys), (e, cfg.vocab_size), e)}
-    for full, name in leaves:
-        k = next(keys)
-        shape, fan_in = shapes[name]
-        if name == "router_bias":
-            params[full] = router_bias_sd(cfg) * jax.random.normal(
-                k, (count, *shape), F32)
-        else:
-            params[full] = (w(k, (count, *shape), fan_in) if fan_in
-                            else jnp.ones((count, *shape), cfg.dtype))
-    return params
+    return stacks.init_params(cfg, key, _leaves(cfg),
+                              stacks.seeded_bias(router_bias_sd(cfg)))
 
 
 def param_logical_axes(cfg: LongcatFlashConfig) -> dict[str, tuple]:
     layer = {
+        **stacks.MLP_AXES, **stacks.EXPERT_AXES,
         "wq_b": (None, "heads"), "wk_b": ("heads", None, None),
         "wv_b": ("heads", None, None), "wo": ("heads", "embed"),
-        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"), "wd": ("ffn", "embed"),
-        "we_gate": ("experts", "embed", "ffn"),
-        "we_up": ("experts", "embed", "ffn"),
-        "we_down": ("experts", "ffn", "embed"),
     }
-    shapes = _layer_shapes(cfg)
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
-            "lm_head": ("embed", "vocab")}
-    for full, name in _leaves(cfg):
-        axes[full] = ("layers", *layer.get(
-            name, (None,) * len(shapes[name][0])))
-    return axes
+    return stacks.param_logical_axes(cfg, _leaves(cfg), layer)
 
 
 def param_shardings(cfg: LongcatFlashConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {name: logical_to_sharding(mesh, rules, *axes)
-            for name, axes in param_logical_axes(cfg).items()}
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.deepseek_v3 import (
     EXPERT_LOAD_COUNTERS,
     LOAD_BUCKETS,
@@ -276,16 +277,25 @@ def _layer_shapes(cfg: MimoV2Config, kv_heads: int
     }
 
 
-def _stacks(cfg: MimoV2Config):
-    """(prefix, names, layers, KV heads) of every stack the patterns call
-    for."""
+def _leaves(cfg: MimoV2Config) -> list[stacks.Leaf]:
+    """Every stacked leaf the patterns call for: a stack a kind of
+    attention, at its own KV heads, and a kind of feed-forward."""
     dense = len(cfg.moe_pattern) - cfg.num_moe_layers
-    out = [(G, _ATTN, cfg.layers_of(GLOBAL), cfg.num_kv_heads),
-           (W, _window_names(cfg), cfg.layers_of(WINDOW),
-            cfg.window_kv_heads),
-           (DENSE, _DENSE_MLP, dense, 0),
-           ("", _MOE_MLP, cfg.num_moe_layers, 0)]
-    return [s for s in out if s[2] > 0]
+    stacks_ = [(G, _ATTN, cfg.layers_of(GLOBAL), cfg.num_kv_heads),
+               (W, _window_names(cfg), cfg.layers_of(WINDOW),
+                cfg.window_kv_heads),
+               (DENSE, _DENSE_MLP, dense, 0),
+               ("", _MOE_MLP, cfg.num_moe_layers, 0)]
+    return [leaf for *stack, kv_heads in stacks_ for leaf in
+            stacks.stack_leaves(_layer_shapes(cfg, kv_heads), [stack])]
+
+
+def _own_rule(cfg, name: str, k, shape):
+    """The window layers' SINKS a seeded normal of sd 1 in float32, the rest
+    as the other mixtures' (init_params says why neither is zero)."""
+    if name == "sink":
+        return jax.random.normal(k, shape, F32)
+    return stacks.seeded_bias(0.02)(cfg, name, k, shape)
 
 
 def init_params(cfg: MimoV2Config, key: jax.Array) -> Params:
@@ -295,56 +305,16 @@ def init_params(cfg: MimoV2Config, key: jax.Array) -> Params:
     it is not zero), and the window layers' SINKS a seeded normal of sd 1 in
     float32, not zero: a program that leaves the sink out, or gives every
     head the same, then differs from one that follows the rule."""
-    leaves = [(p, n, count, kv) for p, names, count, kv in _stacks(cfg)
-              for n in names]
-    keys = iter(jax.random.split(key, len(leaves) + 2))
-    e = cfg.hidden_size
-
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, F32) * fan_in**-0.5
-                ).astype(cfg.dtype)
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype),
-                      "lm_head": w(next(keys), (e, cfg.vocab_size), e)}
-    for prefix, name, count, kv in leaves:
-        shape, fan_in = _layer_shapes(cfg, kv)[name]
-        k = next(keys)
-        if fan_in:
-            leaf = w(k, (count, *shape), fan_in)
-        elif name == "router_bias":
-            leaf = 0.02 * jax.random.normal(k, (count, *shape), F32)
-        elif name == "sink":
-            leaf = jax.random.normal(k, (count, *shape), F32)
-        else:
-            leaf = jnp.ones((count, *shape), cfg.dtype)  # the norms
-        params[prefix + name] = leaf
-    return params
+    return stacks.init_params(cfg, key, _leaves(cfg), _own_rule)
 
 
 def param_logical_axes(cfg: MimoV2Config) -> dict[str, tuple]:
-    layer = {
-        "wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
-        "wv": ("embed", "kv_heads"), "wo": ("heads", "embed"),
-        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"), "wd": ("ffn", "embed"),
-        "we_gate": ("experts", "embed", "ffn"),
-        "we_up": ("experts", "embed", "ffn"),
-        "we_down": ("experts", "ffn", "embed"),
-    }
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
-            "lm_head": ("embed", "vocab")}
-    for prefix, names, _count, kv in _stacks(cfg):
-        shapes = _layer_shapes(cfg, kv)
-        for name in names:
-            axes[prefix + name] = ("layers", *layer.get(
-                name, (None,) * len(shapes[name][0])))
-    return axes
+    layer = {**stacks.GQA_AXES, **stacks.MLP_AXES, **stacks.EXPERT_AXES}
+    return stacks.param_logical_axes(cfg, _leaves(cfg), layer)
 
 
 def param_shardings(cfg: MimoV2Config, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {name: logical_to_sharding(mesh, rules, *axes)
-            for name, axes in param_logical_axes(cfg).items()}
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.family import Family
 from llmlb_tpu.models.llama import (
     LayerGroup,
@@ -31,7 +32,6 @@ from llmlb_tpu.models.llama import (
     _prefill_impl,
 )
 from llmlb_tpu.ops import moe
-from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
 
@@ -118,16 +118,9 @@ def param_logical_axes(cfg: MixtralConfig) -> dict[str, tuple]:
     return axes
 
 
-# Same rules as the dense family (ShardingRules already maps experts -> "ep").
-from llmlb_tpu.models.llama import shard_rules_for  # noqa: E402,F401
-
-
 def param_shardings(cfg: MixtralConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {
-        name: logical_to_sharding(mesh, rules, *axes)
-        for name, axes in param_logical_axes(cfg).items()
-    }
+    # the dense family's rules (ShardingRules already maps experts -> "ep")
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # The KV page pool is identical to llama's — reuse.

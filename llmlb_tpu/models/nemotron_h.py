@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.deepseek_v3 import (
     EXPERT_LOAD_COUNTERS,
     LOAD_BUCKETS,
@@ -202,20 +203,20 @@ class NemotronHConfig(LlamaConfig):
 # Params: one stack a kind
 # ---------------------------------------------------------------------------
 
-_SSM = ("ln_ssm", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
+SSM = ("ln_ssm", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
         "ssm_a_log", "ssm_d", "ln_gate", "ssm_out")
-_ATTN = ("ln_attn", "wq", "wk", "wv", "wo")
+ATTN = ("ln_attn", "wq", "wk", "wv", "wo")
 _MOE = ("ln_mlp", "router", "router_bias", "we_up", "we_down", "ws_up",
         "ws_down")
-_NAMES = {"M": _SSM, "*": _ATTN, "E": _MOE}
+_NAMES = {"M": SSM, "*": ATTN, "E": _MOE}
 
 
-def _layer_shapes(cfg: NemotronHConfig) -> dict[str, tuple[tuple, int]]:
-    """name -> (shape of one layer's leaf, fan-in; 0 = its own rule)."""
+def mixer_shapes(cfg) -> dict[str, tuple[tuple, int]]:
+    """`_layer_shapes` of the state-space and the attention layer's leaves
+    (`SSM`, `ATTN`), which models/granite_hybrid.py holds under the same
+    names: seeded_vector reads them."""
     e, d = cfg.hidden_size, cfg.head_dim_
     di, cd, hs = cfg.d_inner, cfg.conv_dim, cfg.ssm_heads
-    x, fm, fs = cfg.num_experts, cfg.moe_intermediate_size, (
-        cfg.shared_intermediate_size)
     return {
         "ln_ssm": ((e,), 0), "ssm_in": ((e, di + cd + hs), e),
         "ssm_conv_w": ((cd, cfg.conv_kernel), 0), "ssm_conv_b": ((cd,), 0),
@@ -225,6 +226,15 @@ def _layer_shapes(cfg: NemotronHConfig) -> dict[str, tuple[tuple, int]]:
         "wk": ((e, cfg.num_kv_heads * d), e),
         "wv": ((e, cfg.num_kv_heads * d), e),
         "wo": ((cfg.num_heads * d, e), cfg.num_heads * d),
+    }
+
+
+def _layer_shapes(cfg: NemotronHConfig) -> dict[str, tuple[tuple, int]]:
+    """name -> (shape of one layer's leaf, fan-in; 0 = its own rule)."""
+    e, x, fm, fs = (cfg.hidden_size, cfg.num_experts,
+                    cfg.moe_intermediate_size, cfg.shared_intermediate_size)
+    return {
+        **mixer_shapes(cfg),
         "ln_mlp": ((e,), 0), "router": ((e, cfg.router_experts), e),
         "router_bias": ((cfg.router_experts,), 0),
         # output-major, as the checkpoint has it: ops/pallas_moe says why
@@ -233,10 +243,10 @@ def _layer_shapes(cfg: NemotronHConfig) -> dict[str, tuple[tuple, int]]:
     }
 
 
-def _leaves(cfg: NemotronHConfig):
-    """(name, layers) of every stacked leaf the pattern calls for."""
-    return [(n, cfg.layers_of(kind)) for kind in KINDS
-            for n in _NAMES[kind] if cfg.layers_of(kind)]
+def _leaves(cfg: NemotronHConfig) -> list[stacks.Leaf]:
+    """Every stacked leaf the pattern calls for: one stack a kind."""
+    return stacks.stack_leaves(_layer_shapes(cfg), [
+        ("", _NAMES[kind], cfg.layers_of(kind)) for kind in KINDS])
 
 
 def seeded_vector(cfg, name: str, k, shape):
@@ -272,24 +282,7 @@ def init_params(cfg: NemotronHConfig, key: jax.Array) -> Params:
     norms ones; the convolution uniform within +-kernel^-0.5; the router's
     choice bias a seeded normal of sd 0.02 (deepseek_v3.init_params says
     why it is not zero)."""
-    shapes = _layer_shapes(cfg)
-    leaves = _leaves(cfg)
-    keys = iter(jax.random.split(key, len(leaves) + 2))
-    e = cfg.hidden_size
-
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, F32) * fan_in**-0.5
-                ).astype(cfg.dtype)
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype),
-                      "lm_head": w(next(keys), (e, cfg.vocab_size), e)}
-    for name, count in leaves:
-        shape, fan_in = shapes[name]
-        k = next(keys)
-        params[name] = (w(k, (count, *shape), fan_in) if fan_in
-                        else seeded_vector(cfg, name, k, (count, *shape)))
-    return params
+    return stacks.init_params(cfg, key, _leaves(cfg), seeded_vector)
 
 
 def param_logical_axes(cfg: NemotronHConfig) -> dict[str, tuple]:
@@ -297,25 +290,14 @@ def param_logical_axes(cfg: NemotronHConfig) -> dict[str, tuple]:
     state-space projections replicate (their output is split into parts of
     unlike widths, which a tensor-parallel split would cut across)."""
     layer = {
-        "wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
-        "wv": ("embed", "kv_heads"), "wo": ("heads", "embed"),
-        "we_up": ("experts", "ffn", "embed"),
-        "we_down": ("experts", "ffn", "embed"),
-        "ws_up": ("embed", "ffn"), "ws_down": ("ffn", "embed"),
+        **stacks.GQA_AXES, **stacks.EXPERT_AXES,
+        "we_up": ("experts", "ffn", "embed"),  # output-major: _layer_shapes
     }
-    shapes = _layer_shapes(cfg)
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
-            "lm_head": ("embed", "vocab")}
-    for name, _count in _leaves(cfg):
-        axes[name] = ("layers", *layer.get(
-            name, (None,) * len(shapes[name][0])))
-    return axes
+    return stacks.param_logical_axes(cfg, _leaves(cfg), layer)
 
 
 def param_shardings(cfg: NemotronHConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {name: logical_to_sharding(mesh, rules, *axes)
-            for name, axes in param_logical_axes(cfg).items()}
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -409,7 +391,7 @@ def ssm_mixer(cfg, residual: float = 1.0):
     """llama.LayerGroup's `mixer` for a state-space (Mamba-2) layer, of any
     family whose configuration names the mixer's sizes as NemotronHConfig
     does (`ssm_heads`, `ssm_head_dim`, `ssm_groups`, `ssm_state`, `d_inner`,
-    `conv_dim`, `chunk_size`) and its parameters by `_SSM`. `residual`
+    `conv_dim`, `chunk_size`) and its parameters by `SSM`. `residual`
     scales what the mixer GIVES before it joins x (Granite's
     `residual_multiplier`, models/granite_hybrid.py); at 1 nothing is
     traced for it."""

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from llmlb_tpu.models import stacks
 from llmlb_tpu.models.family import Family, StepCounter
 from llmlb_tpu.models.llama import (
     GQA_ATTENTION,
@@ -200,12 +201,27 @@ def _layer_shapes(cfg: OlmoHybridConfig) -> dict[str, tuple[tuple, int]]:
     }
 
 
-def _leaves(cfg: OlmoHybridConfig):
-    """(name in the pytree, layers) of every stacked leaf."""
-    return ([(_LIN_PREFIX + n, cfg.layers_of(LINEAR)) for n in _LIN
-             if cfg.layers_of(LINEAR)]
-            + [(n, cfg.layers_of(FULL)) for n in _ATTN if cfg.layers_of(FULL)]
-            + [(n, cfg.num_layers) for n in _MLP])
+def _leaves(cfg: OlmoHybridConfig) -> list[stacks.Leaf]:
+    """Every stacked leaf, under its name in the pytree."""
+    return stacks.stack_leaves(_layer_shapes(cfg), [
+        ("", [_LIN_PREFIX + n for n in _LIN], cfg.layers_of(LINEAR)),
+        ("", _ATTN, cfg.layers_of(FULL)), ("", _MLP, cfg.num_layers)])
+
+
+def _own_rule(cfg, name: str, k, shape):
+    """A seeded leaf that is no matrix, by the gated-delta-net layer's own
+    rule (init_params says which and why)."""
+    if name == "lin_conv_w":
+        bound = cfg.conv_kernel**-0.5
+        return jax.random.uniform(k, shape, F32, -bound, bound
+                                  ).astype(cfg.dtype)
+    if name == "lin_a_log":
+        return jnp.log(jax.random.uniform(k, shape, F32, 1.0, 16.0))
+    if name == "lin_dt_bias":
+        dt = jnp.exp(jax.random.uniform(k, shape, F32, jnp.log(0.001),
+                                        jnp.log(0.1)))
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+    return jnp.ones(shape, cfg.dtype)  # the norms
 
 
 def init_params(cfg: OlmoHybridConfig, key: jax.Array) -> Params:
@@ -217,61 +233,19 @@ def init_params(cfg: OlmoHybridConfig, key: jax.Array) -> Params:
     log-uniform draw in [0.001, 0.1] — so that the decay runs where a
     trained layer's does (0.2 to 1, most of it near 1): a state that decays
     to nothing would hide the rule."""
-    shapes = _layer_shapes(cfg)
-    leaves = _leaves(cfg)
-    keys = iter(jax.random.split(key, len(leaves) + 2))
-    e = cfg.hidden_size
-
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, F32) * fan_in**-0.5
-                ).astype(cfg.dtype)
-
-    def own_rule(name, k, shape):
-        if name == "lin_conv_w":
-            bound = cfg.conv_kernel**-0.5
-            return jax.random.uniform(k, shape, F32, -bound, bound
-                                      ).astype(cfg.dtype)
-        if name == "lin_a_log":
-            return jnp.log(jax.random.uniform(k, shape, F32, 1.0, 16.0))
-        if name == "lin_dt_bias":
-            dt = jnp.exp(jax.random.uniform(k, shape, F32, jnp.log(0.001),
-                                            jnp.log(0.1)))
-            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-        return jnp.ones(shape, cfg.dtype)  # the norms
-
-    params: Params = {"embed": w(next(keys), (cfg.vocab_size, e), e),
-                      "ln_final": jnp.ones((e,), cfg.dtype),
-                      "lm_head": w(next(keys), (e, cfg.vocab_size), e)}
-    for name, count in leaves:
-        shape, fan_in = shapes[name]
-        k = next(keys)
-        params[name] = (w(k, (count, *shape), fan_in) if fan_in
-                        else own_rule(name, k, (count, *shape)))
-    return params
+    return stacks.init_params(cfg, key, _leaves(cfg), _own_rule)
 
 
 def param_logical_axes(cfg: OlmoHybridConfig) -> dict[str, tuple]:
     """Attention and the feed-forward shard as llama's; the linear layers'
     projections replicate (their heads are not split: the state pool is one
     slot's whole)."""
-    layer = {
-        "wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
-        "wv": ("embed", "kv_heads"), "wo": ("heads", "embed"),
-        "wg": ("embed", "ffn"), "wu": ("embed", "ffn"), "wd": ("ffn", "embed"),
-    }
-    shapes = _layer_shapes(cfg)
-    axes = {"embed": ("vocab", "embed"), "ln_final": ("embed",),
-            "lm_head": ("embed", "vocab")}
-    for name, _count in _leaves(cfg):
-        axes[name] = ("layers", *layer.get(
-            name, (None,) * len(shapes[name][0])))
-    return axes
+    layer = {**stacks.GQA_AXES, **stacks.MLP_AXES}
+    return stacks.param_logical_axes(cfg, _leaves(cfg), layer)
 
 
 def param_shardings(cfg: OlmoHybridConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
-    return {name: logical_to_sharding(mesh, rules, *axes)
-            for name, axes in param_logical_axes(cfg).items()}
+    return stacks.param_shardings(cfg, mesh, rules, param_logical_axes(cfg))
 
 
 # ---------------------------------------------------------------------------

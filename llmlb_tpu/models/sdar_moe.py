@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from llmlb_tpu.models import mixtral
+from llmlb_tpu.models import mixtral, stacks
 from llmlb_tpu.models.deepseek_v3 import (
     EXPERT_LOAD_COUNTERS,
     _extra,
@@ -58,7 +58,6 @@ from llmlb_tpu.models.llama import (
     _prefill_impl,
     _proj,
     _qkv,
-    shard_rules_for,
 )
 from llmlb_tpu.models.llama import (  # noqa: F401 — the GQA page pool, reused
     init_kv_pages,
@@ -75,7 +74,6 @@ from llmlb_tpu.ops.attention import (
 )
 from llmlb_tpu.ops.norms import rms_norm
 from llmlb_tpu.ops.rope import apply_rope
-from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
 
@@ -190,11 +188,9 @@ def init_params(cfg: SdarMoeConfig, key: jax.Array) -> Params:
 
 
 def param_shardings(cfg: SdarMoeConfig, mesh: Mesh, rules=None):
-    rules = rules or shard_rules_for(cfg, mesh.shape["tp"])
     axes = mixtral.param_logical_axes(cfg)
     axes["q_norm"] = axes["k_norm"] = ("layers", "head_dim")
-    return {name: logical_to_sharding(mesh, rules, *a)
-            for name, a in axes.items()}
+    return stacks.param_shardings(cfg, mesh, rules, axes)
 
 
 _STACKED = (*mixtral._STACKED, "q_norm", "k_norm")

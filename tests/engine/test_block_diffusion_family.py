@@ -4,7 +4,8 @@ position: prefill, extends from a page boundary and mid-page, block passes
 with masks in them. Float32 on the CPU, small size. One-term controls — the
 reference with one term of the published layer changed — each part from the
 program by far more than rounding: the comparison would catch that term
-computed wrong. Docs: docs/block-diffusion.md.
+computed wrong. What the family does not compute refused by name is the
+suite's case (tests/engine/family_suite.py). Docs: docs/block-diffusion.md.
 """
 
 import dataclasses
@@ -19,6 +20,10 @@ from benchmark.reference import sdar_moe as ref
 from llmlb_tpu.models import config_from_hf, family_for, sdar_moe
 from llmlb_tpu.ops import attention as ops
 from llmlb_tpu.parallel.mesh import MeshConfig, build_mesh
+from tests.engine.family_suite import (  # noqa: F401 — the case it has
+    Case,
+    test_what_the_family_does_not_compute_is_refused_by_name,
+)
 from llmlb_tpu.ops.pallas_attention import (
     flash_prefill,
     paged_flash_extend,
@@ -473,15 +478,14 @@ def test_the_configuration_reads_every_key_and_the_experts_own_width(model):
     assert set(sdar_moe.param_shardings(cfg, mesh)) >= set(params)
 
 
-@pytest.mark.parametrize("key,value", [
-    ("mlp_only_layers", [1]), ("decoder_sparse_step", 2),
-    ("use_sliding_window", True), ("tie_word_embeddings", True),
-    ("rope_scaling", {"rope_type": "yarn", "factor": 4.0}),
-    ("attention_bias", True), ("norm_topk_prob", False),
-    ("hidden_act", "gelu")])
-def test_what_the_family_does_not_compute_is_refused_by_name(key, value):
-    with pytest.raises(NotImplementedError, match=key):
-        config_from_hf({**HF, key: value}, jnp.float32)
+CASE = Case(
+    family=sdar_moe, preset="debug-sdar-tiny", hf=HF,
+    refused=tuple(({key: value}, key) for key, value in (
+        ("mlp_only_layers", [1]), ("decoder_sparse_step", 2),
+        ("use_sliding_window", True), ("tie_word_embeddings", True),
+        ("rope_scaling", {"rope_type": "yarn", "factor": 4.0}),
+        ("attention_bias", True), ("norm_topk_prob", False),
+        ("hidden_act", "gelu"))))
 
 
 @pytest.mark.parametrize("assumed,message", [

@@ -1,14 +1,15 @@
 """models/longcat_flash.py on the CPU at a small size, seeded weights
-(docs/longcat-flash.md): the family's prefill -> two extend chunks -> decode
-steps through the pages against the plain reference's one forward pass
-(benchmark/reference/longcat_flash.py), by logits, routing followed; a burst
-of decode steps under a scan; the 32-chip question at a small size — the
-shares' held parts, with the identity part and everything outside the
-mixture counted once, add up to the uncut layer; the shortcut: a program
-that adds the mixture one sub-layer early is told apart; the zero-compute
-assignments in ops/moe.py; the configuration read from its published keys
-(the catalog row itself where the catalog is installed) and what the family
-does not compute refused by name."""
+(docs/longcat-flash.md). The family's record for the suite
+(tests/engine/family_suite.py): prefill -> two extend chunks -> decode steps
+through the pages against the plain reference's one forward pass
+(benchmark/reference/longcat_flash.py), by logits, routing followed; the
+32-chip question at a small size — the shares' held parts, with the identity
+part and everything outside the mixture counted once, add up to the uncut
+layer; the shortcut: a program that adds the mixture one sub-layer early is
+told apart; the configuration read from its published keys and what it does
+not compute refused by name. Its own: a burst of decode steps under a scan;
+the zero-compute assignments in ops/moe.py; the catalog row itself where
+the catalog is installed."""
 
 import dataclasses
 import json
@@ -21,12 +22,19 @@ import pytest
 
 from benchmark import check_shortcut, correctness
 from benchmark.reference import longcat_flash as reference
-from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.models import config_from_hf, deepseek_v3, family_for
 from llmlb_tpu.models import longcat_flash as family
 from llmlb_tpu.ops import moe
+from tests.engine.family_suite import (  # noqa: F401 — the cases it has
+    Case,
+    Shares,
+    test_a_program_with_one_term_wrong_fails_the_comparison,
+    test_prefill_extend_decode_match_the_reference_at_every_position,
+    test_the_preset_is_the_published_config_read,
+    test_the_shares_add_up_to_the_uncut_layer,
+    test_what_the_family_does_not_compute_is_refused_by_name,
+)
 
-CFG = get_preset("debug-longcat-tiny")
 HF = {
     "model_type": "longcat_flash", "attention_bias": False, "vocab_size": 512,
     "hidden_size": 64, "ffn_hidden_size": 96, "expert_ffn_hidden_size": 32,
@@ -39,27 +47,88 @@ HF = {
     "zero_expert_num": 4, "zero_expert_type": "identity", "moe_topk": 3,
     "expert_parallel": {"chips": 2, "chip": 1, "experts": 8},
 }
-SPEC = {"prefill_tokens": 24, "extend_chunks": 2, "extend_tokens": 12,
-        "decode_steps": 5, "tolerance": 1e-3, "router_tolerance": 1e-4,
-        "flip_margin_multiple": 6.0}
 PAGE = 16
 
 
-@pytest.fixture(scope="module")
-def params():
-    return family.init_params(CFG, jax.random.PRNGKey(7))
-
-
-def test_the_preset_is_the_published_config_read():
-    cfg = config_from_hf(HF, jnp.float32)
-    assert cfg == CFG and family_for(cfg) is family
-    assert isinstance(cfg, deepseek_v3.DeepseekV3Config)  # and asked first
-    assert cfg.held_experts == (4, 4) and cfg.router_experts == 8
-    assert cfg.router_width == 12 and cfg.zero_experts == 4
-    assert (cfg.q_lora_scale, cfg.kv_lora_scale) == (2.0, 2.0 ** 0.5)
-    assert family.kv_pool_layers(cfg) == 4  # two attention sub-layers a layer
+def _reads(cfg):
     ck, cv = family.init_kv_pages(cfg, 3, PAGE)
-    assert ck.shape == (4, 3, PAGE, 32) and cv.shape == (4, 3, PAGE, 128)
+    return [
+        (isinstance(cfg, deepseek_v3.DeepseekV3Config), True),  # asked first
+        ((cfg.held_experts, cfg.router_experts), ((4, 4), 8)),
+        ((cfg.router_width, cfg.zero_experts), (12, 4)),
+        ((cfg.q_lora_scale, cfg.kv_lora_scale), (2.0, 2.0 ** 0.5)),
+        (family.kv_pool_layers(cfg), 4),  # two attention sub-layers a layer
+        ((ck.shape, cv.shape), ((4, 3, PAGE, 32), (4, 3, PAGE, 128)))]
+
+
+def _shares():
+    """One layer with all 8 experts, and its cut into the shares of chip 0
+    and chip 1 (the 32-chip deployment at a small size). Each chip's layer
+    is y_rest + its held experts' part + the identity part: a chip's part
+    is what it HOLDS, and the identity part and everything outside the
+    mixture are counted once."""
+    whole_hf = {**HF, "n_routed_experts": 8, "expert_parallel": None}
+    whole = config_from_hf(whole_hf, jnp.float32)
+    assert whole.held_experts == (0, 8)
+    p = family.init_params(whole, jax.random.PRNGKey(11))
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(19, 64)),
+                    jnp.float32)
+    d, layer = reference.dims(whole_hf), 1
+    uncut, scores = reference.double_layer(p, layer, x, d,
+                                           reference.rule(whole_hf))
+    a = reference.attention_sublayer(
+        x, layer, *(p["s0_" + n] for n in reference._ATTN), **d)
+    _, h = reference.feed_forward(
+        a, layer, *(p["s0_" + n] for n in reference._MLP), eps=d["eps"])
+    experts = ("s0_we_gate", "s0_we_up", "s0_we_down")
+    parts, counted, rest = [], [], None
+    for chip in (0, 1):
+        hf = {**HF, "expert_parallel": {"chips": 2, "chip": chip,
+                                        "experts": 8}}
+        cfg = config_from_hf(hf, jnp.float32)
+        share = {**p, **{n: p[n][:, 4 * chip:4 * chip + 4] for n in experts}}
+        y, chip_scores = reference.double_layer(share, layer, x, d,
+                                                reference.rule(hf))
+        np.testing.assert_array_equal(chip_scores, scores)  # one router
+        held, identity, _ = reference.mixture_parts(
+            h, layer, p["s0_router"][layer], p["s0_router_bias"][layer],
+            *(share[n] for n in experts), None, **reference.rule(hf))
+        rest = y - held if rest is None else rest  # what both compute alike
+        # the program's mixture of this share
+        lp = {n[3:]: share[n][layer] for n in ("s0_router", "s0_router_bias")}
+        lp.update({n[3:]: share[n] for n in experts}, layer=layer)
+        got, routing = family._mixture_fn(cfg)(lp, h[None], None)
+        parts.append((got[0] - identity, held))
+        counted.append((int(routing.zero), int(jnp.sum(routing.load)),
+                        int(routing.elsewhere)))
+    # a chip's elsewhere is the other's held; the zero part is everyone's
+    (z0, h0, e0), (z1, h1, e1) = counted
+    assert z0 == z1 > 0 and (h0, h1) == (e1, e0)
+    assert z0 + h0 + e0 == 19 * 3
+    return Shares(uncut, parts, lambda total: rest + total, atol=2e-5)
+
+
+CASE = Case(
+    family=family, preset="debug-longcat-tiny", hf=HF, reference=reference,
+    page=PAGE, tolerance=1e-4, reads=_reads,
+    spec={"prefill_tokens": 24, "extend_chunks": 2, "extend_tokens": 12,
+          "decode_steps": 5, "tolerance": 1e-3, "router_tolerance": 1e-4,
+          "flip_margin_multiple": 6.0},
+    # the mixture added one sub-layer early (so that the second attention
+    # and feed-forward see it), the zero-compute experts dropped, the two
+    # LoRA scales left out: each is told apart by the logits
+    controls={name: (lambda params, name=name: CASE.control(
+        params, check_shortcut.variants(family, CASE.cfg)[name]))
+        for name in ("shortcut_early", "zero_dropped", "scales_off")},
+    control_fails_by=0.05,
+    control_spec={"extend_chunks": 0},
+    refused=tuple(({key: value}, key) for key, value in (
+        ("attention_method", "GQA"), ("zero_expert_type", "copy"),
+        ("rope_scaling", {"type": "yarn", "factor": 4}),
+        ("attention_bias", True), ("router_bias", True),
+        ("hidden_act", "gelu"), ("tie_word_embeddings", True),
+        ("q_lora_rank", None), ("n_shared_experts", 1))),
+    shares=_shares)
 
 
 # LongCat-Flash-Omni's language model as the catalog
@@ -104,17 +173,6 @@ def test_from_hf_config_reads_the_catalog_row_itself():
     assert family.kv_pool_layers(cfg) == 56
 
 
-@pytest.mark.parametrize("key, value", [
-    ("attention_method", "GQA"), ("zero_expert_type", "copy"),
-    ("rope_scaling", {"type": "yarn", "factor": 4}), ("attention_bias", True),
-    ("router_bias", True), ("hidden_act", "gelu"),
-    ("tie_word_embeddings", True), ("q_lora_rank", None),
-    ("n_shared_experts", 1)])
-def test_what_the_family_does_not_compute_is_refused_by_name(key, value):
-    with pytest.raises(NotImplementedError, match=key):
-        config_from_hf({**HF, key: value})
-
-
 def test_a_share_that_does_not_divide_the_experts_is_refused():
     with pytest.raises(ValueError, match="expert_parallel"):
         config_from_hf({**HF, "expert_parallel": {
@@ -135,41 +193,15 @@ def test_another_family_refuses_the_mechanisms_by_name():
 def test_what_the_engine_must_refuse_is_said_by_the_family():
     record = family.FAMILY
     assert not (record.int8_weights or record.int8_kv or record.lora)
-    assert family.kv_wire_cell(CFG) is None
+    assert family.kv_wire_cell(CASE.cfg) is None
     with pytest.raises(NotImplementedError, match="int8 latent page pool"):
-        family.init_kv_pages(CFG, 3, PAGE, quantized=True)
-
-
-# --- against the reference, by logits ----------------------------------------
-
-def test_prefill_extend_decode_match_the_reference_with_routing_followed(
-        params):
-    out = correctness.check(family, CFG, params, HF, SPEC, 3, PAGE, reference)
-    assert out["ok"] and out["grounds"] == [], out
-    assert out["max_rel_rms_err"] < 1e-4 and out["router_rel_rms_err"] < 1e-5
-    assert out["dropped_assignments"] == 0 and out["choice_is_own_topk"]
-    assert out["positions_compared"] == 1 + 2 + 5
-
-
-def _control(name):
-    return check_shortcut.variants(family, CFG)[name]
-
-
-@pytest.mark.parametrize("control", ["shortcut_early", "zero_dropped",
-                                     "scales_off"])
-def test_a_program_with_one_term_wrong_fails_the_comparison(control, params):
-    """The mixture added one sub-layer early (so that the second attention
-    and feed-forward see it), the zero-compute experts dropped, the two LoRA
-    scales left out: each is told apart by the logits."""
-    out = correctness.check(_control(control), CFG, params, HF, SPEC, 3, PAGE,
-                            reference)
-    assert not out["ok"] and "logits" in out["grounds"], out
-    assert out["max_rel_rms_err"] > 0.05
+        family.init_kv_pages(CASE.cfg, 3, PAGE, quantized=True)
 
 
 def test_a_choice_made_without_the_bias_is_refused(params):
-    out = correctness.check(_control("unbiased_choice"), CFG, params, HF, SPEC,
-                            3, PAGE, reference)
+    out = correctness.check(
+        check_shortcut.variants(family, CASE.cfg)["unbiased_choice"], CASE.cfg,
+        params, HF, CASE.spec, 3, PAGE, reference)
     assert "choice_is_own_topk" in out["grounds"]
     assert out["max_rel_rms_err"] < 1e-4  # the reference follows the choice
 
@@ -181,11 +213,11 @@ def test_a_burst_of_decode_steps_is_the_references_greedy_continuation(params):
     forward over the prompt and the tokens so far."""
     rng = np.random.default_rng(5)
     lens = np.asarray([20, 13], np.int32)
-    ids = rng.integers(8, CFG.vocab_size, (2, 24)).astype(np.int32)
-    ck, cv = family.init_kv_pages(CFG, 9, PAGE)
+    ids = rng.integers(8, CASE.cfg.vocab_size, (2, 24)).astype(np.int32)
+    ck, cv = family.init_kv_pages(CASE.cfg, 9, PAGE)
     tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
     logits, ck, cv, _ = family.prefill_into_pages(
-        params, CFG, jnp.asarray(ids), jnp.asarray(lens), tables, ck, cv)
+        params, CASE.cfg, jnp.asarray(ids), jnp.asarray(lens), tables, ck, cv)
     first = jnp.argmax(logits, -1).astype(jnp.int32)
 
     @jax.jit
@@ -193,7 +225,7 @@ def test_a_burst_of_decode_steps_is_the_references_greedy_continuation(params):
         def body(carry, _):
             last, seq, ck, cv = carry
             logits, ck, cv, counters = family.decode_step_paged(
-                params, CFG, last, seq, ck, cv, tables, window=64)
+                params, CASE.cfg, last, seq, ck, cv, tables, window=64)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return (nxt, seq + 1, ck, cv), (nxt, counters)
 
@@ -213,71 +245,14 @@ def test_a_burst_of_decode_steps_is_the_references_greedy_continuation(params):
 
 
 def test_a_row_that_is_not_live_is_routed_nowhere(params):
-    ck, cv = family.init_kv_pages(CFG, 9, PAGE)
+    ck, cv = family.init_kv_pages(CASE.cfg, 9, PAGE)
     tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
-    args = (params, CFG, jnp.asarray([9, 11], jnp.int32),
+    args = (params, CASE.cfg, jnp.asarray([9, 11], jnp.int32),
             jnp.asarray([3, 5], jnp.int32), ck, cv, tables)
     *_, counters = family.decode_step_paged(
         *args, window=64, live=jnp.asarray([True, False]))
     assert int(counters["zero_assignments"] + counters["expert_assignments"]
                + counters["assignments_elsewhere"]) == 1 * 3 * 2
-
-
-# --- the share ---------------------------------------------------------------
-
-def test_the_shares_add_up_to_the_uncut_layer():
-    """One layer with all 8 experts, and its cut into the shares of chip 0
-    and chip 1 (the 32-chip deployment at a small size). Each chip's layer
-    is y_rest + its held experts' part + the identity part; the held parts
-    of all chips, with the identity part and everything outside the mixture
-    counted ONCE, are the reference's uncut layer — and the program's
-    mixture of a share is the reference's of that share."""
-    whole_hf = {**HF, "n_routed_experts": 8, "expert_parallel": None}
-    whole = config_from_hf(whole_hf, jnp.float32)
-    assert whole.held_experts == (0, 8)
-    p = family.init_params(whole, jax.random.PRNGKey(11))
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(19, 64)),
-                    jnp.float32)
-    d, layer = reference.dims(whole_hf), 1
-    uncut, scores = reference.double_layer(p, layer, x, d,
-                                           reference.rule(whole_hf))
-    a = reference.attention_sublayer(
-        x, layer, *(p["s0_" + n] for n in reference._ATTN), **d)
-    _, h = reference.feed_forward(
-        a, layer, *(p["s0_" + n] for n in reference._MLP), eps=d["eps"])
-    experts = ("s0_we_gate", "s0_we_up", "s0_we_down")
-    layers, identity, counted = [], None, []
-    for chip in (0, 1):
-        hf = {**HF, "expert_parallel": {"chips": 2, "chip": chip,
-                                        "experts": 8}}
-        cfg = config_from_hf(hf, jnp.float32)
-        share = {**p, **{n: p[n][:, 4 * chip:4 * chip + 4] for n in experts}}
-        y, chip_scores = reference.double_layer(share, layer, x, d,
-                                                reference.rule(hf))
-        np.testing.assert_array_equal(chip_scores, scores)  # one router
-        layers.append(y)
-        held, identity, _ = reference.mixture_parts(
-            h, layer, p["s0_router"][layer], p["s0_router_bias"][layer],
-            *(share[n] for n in experts), None, **reference.rule(hf))
-        # the program's mixture of this share
-        lp = {n[3:]: share[n][layer] for n in ("s0_router", "s0_router_bias")}
-        lp.update({n[3:]: share[n] for n in experts}, layer=layer)
-        got, routing = family._mixture_fn(cfg)(lp, h[None], None)
-        np.testing.assert_allclose(got[0], held + identity, atol=2e-5)
-        counted.append((int(routing.zero), int(jnp.sum(routing.load)),
-                        int(routing.elsewhere)))
-    # y_0 + y_1 - (what both computed alike) = the uncut layer
-    rest = layers[0] - reference.mixture_parts(
-        h, layer, p["s0_router"][layer], p["s0_router_bias"][layer],
-        *(p[n][:, :4] for n in experts), None,
-        **reference.rule({**HF, "expert_parallel": {
-            "chips": 2, "chip": 0, "experts": 8}}))[0]
-    np.testing.assert_allclose(layers[0] + layers[1] - rest, uncut,
-                               atol=5e-5, rtol=1e-5)
-    # a chip's elsewhere is the other's held; the zero part is everyone's
-    (z0, h0, e0), (z1, h1, e1) = counted
-    assert z0 == z1 > 0 and (h0, h1) == (e1, e0)
-    assert z0 + h0 + e0 == 19 * 3
 
 
 # --- ops/moe.py: an expert that is no product --------------------------------
@@ -355,7 +330,7 @@ def test_softmax_bias_routing_by_its_rule():
 def test_the_router_bias_is_scaled_to_the_scores():
     big = config_from_hf({**PUBLISHED, "model_type": "longcat_flash"})
     assert family.router_bias_sd(big) == pytest.approx(0.1 / 768)
-    p = family.init_params(CFG, jax.random.PRNGKey(0))
+    p = family.init_params(CASE.cfg, jax.random.PRNGKey(0))
     assert p["s0_router_bias"].dtype == jnp.float32
     assert p["s0_router_bias"].shape == (2, 12)
     sd = float(jnp.std(p["s0_router_bias"]))
@@ -363,16 +338,16 @@ def test_the_router_bias_is_scaled_to_the_scores():
 
 
 def test_the_step_counters_have_the_shapes_the_family_states(params):
-    ck, cv = family.init_kv_pages(CFG, 5, PAGE)
+    ck, cv = family.init_kv_pages(CASE.cfg, 5, PAGE)
     tables = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
     *_, counters = family.prefill_into_pages(
-        params, CFG, jnp.asarray(np.arange(8, 24)[None], jnp.int32),
+        params, CASE.cfg, jnp.asarray(np.arange(8, 24)[None], jnp.int32),
         jnp.asarray([16]), tables, ck, cv)
-    shapes = family.step_counters(CFG)
+    shapes = family.step_counters(CASE.cfg)
     assert {k: v.shape for k, v in counters.items()} == shapes
     assert set(shapes) >= {"zero_assignments", "assignments_elsewhere",
                            "expert_assignments", "experts_touched"}
     assert shapes["expert_load_hist"] == (2, len(family.LOAD_BUCKETS) + 1)
     # the histogram is over the 4 HELD experts of each layer
     assert np.asarray(counters["expert_load_hist"]).sum(-1).tolist() == [4, 4]
-    assert dataclasses.replace(CFG, num_layers=3).num_moe_layers == 3
+    assert dataclasses.replace(CASE.cfg, num_layers=3).num_moe_layers == 3
