@@ -914,7 +914,7 @@ def kernel_leg() -> int:
                                         lens, interpret=False)
         check(name, "K=2,group=4", got,
               jnp.where((lens > 0)[:, None, None], want[:, 0], 0.0))
-        group = pa.decode_group(PS, kv, d, d, PPN)
+        group = pa.decode_group(PS * kv * 2 * d, PPN)
         work = pa.decode_work_list(tables, lens, page_size=PS, group=group)
         items = jnp.where(lens > 0, -(-(-(-lens // PS)) // group), 1)
         check(name, "K=2,group=4,items",
@@ -1037,6 +1037,56 @@ def kernel_leg() -> int:
         for pages, dead in ((PPN, False), (4, False), (PPN, True)):
             attempt("paged_latent_decode", f"B={b},pages={pages},dead={dead}",
                     functools.partial(latent_decode, pages, dead))
+
+    # a GROUP of a row's pages a grid step in the two kernels whose pools
+    # have no head axis (PR 58), by pa.decode_group of a page in both pools:
+    # the latent kernel at longcat-flash-omni's 64 heads (four pages an
+    # item) and the flat one at mimo-v2-5's 64 heads on 4 x 192 keys and
+    # 4 x 128 values (three), rows whose pages are no multiple of the group
+    # and a row in three not live
+    def grouped_headless(flat):
+        from llmlb_tpu.models import mimo_v2
+
+        b, heads, kv, d, dv = 32, 64, 4, 192, 128
+        p = b * PPN + 1
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, p)).reshape(b, PPN), jnp.int32)
+        lens = jnp.asarray(rng.integers(1, PPN * PS + 1, b), jnp.int32)
+        lens = jnp.where(jnp.arange(b) % 3 == 1, 0, lens)
+        if flat:
+            name, widths, want_group = "paged_flat_decode", (kv * d, kv * dv), 3
+            k_pages, v_pages = (stacked(rand(p, PS, w), 1) for w in widths)
+            q = rand(b, 1, heads, d)
+            k, v = (xla.gather_kv_pages(pool, tables, layer=1).reshape(
+                b, PPN * PS, kv, -1) for pool in (k_pages, v_pages))
+            want = mimo_v2._decode_einsum(q, k, v, lens, None)[:, 0]
+            got = pa.paged_flat_decode(q[:, 0], k_pages, v_pages, 1, tables,
+                                       lens, num_kv=kv, interpret=False)
+        else:
+            name, widths, want_group = "paged_latent_decode", (LAT, ROPE), 4
+            c_pages = stacked(rand(p, PS, LAT), 1)
+            r_pages = stacked(jnp.pad(
+                rand(p, PS, 64), ((0, 0), (0, 0), (0, ROPE - 64))), 1)
+            q_abs, q_rope = rand(b, 1, heads, LAT), rand(b, 1, heads, 64)
+            kw = dict(scale=192 ** -0.5)
+            want = xla.paged_latent_decode(q_abs, q_rope, c_pages, r_pages, 1,
+                                           tables, lens, **kw)[:, 0]
+            got = pa.paged_latent_decode(
+                q_abs[:, 0], xla._pad_last(q_rope[:, 0], ROPE), c_pages,
+                r_pages, 1, tables, lens, interpret=False, **kw)
+        case = f"H={heads},group={want_group}"
+        check(name, case, got, jnp.where((lens > 0)[:, None, None], want, 0.0))
+        group = pa.decode_group(PS * sum(widths), PPN)
+        work = pa.decode_work_list(tables, lens, page_size=PS, group=group)
+        items = jnp.where(lens > 0, -(-(-(-lens // PS)) // group), 1)
+        check(name, case + ",items",
+              jnp.asarray([[float(group), float(work.count)]]),
+              jnp.asarray([[float(want_group), float(jnp.sum(items))]]))
+
+    for flat in (False, True):
+        attempt("paged_flat_decode" if flat else "paged_latent_decode",
+                f"H=64,group={3 if flat else 4}",
+                functools.partial(grouped_headless, flat))
 
     for rows_, k_, o_, tile in ((384, 2048, 768, 32), (384, 768, 2048, 32),
                                 (6144, 2048, 768, 32)):

@@ -484,7 +484,7 @@ class Attention(NamedTuple):
     extend(q, pool_k, pool_v, layer, tables, positions, chunk_lens)
     decode(q, pool_k, pool_v, layer, tables, kv_lens, window=, work=)
         both: the stacked pool and the layer to attend over, never a slice
-    decode_work(pool_k, tables, kv_lens, window)        the decode kernel's grid
+    decode_work(pool_k, pool_v, tables, kv_lens, window) the decode kernel's grid
     """
 
     block: Callable
@@ -952,8 +952,8 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     if live is not None:
         kv_lens = jnp.where(live, kv_lens, 0)
     attention = attention or GQA_ATTENTION
-    work = attention.decode_work(_pages(cache_k), block_tables, kv_lens,
-                                 window)
+    work = attention.decode_work(_pages(cache_k), _pages(cache_v),
+                                 block_tables, kv_lens, window)
     rows = StateRows(slot_ids, start_pos=write_pos, live=live)
 
     x = _embed(cfg, params, input_ids)[:, None, :]  # [B, 1, E]

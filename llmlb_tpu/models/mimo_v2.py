@@ -551,7 +551,11 @@ def _global_attention(cfg: MimoV2Config) -> Attention:
         if _pallas_enabled():
             from llmlb_tpu.ops.pallas_attention import paged_flat_decode
 
+            if work is None:
+                work = paged_decode_work(k_pages, v_pages, tables, kv_lens,
+                                         window)
             _traced["global_decode"] = "pallas:paged_flat_decode"
+            note_decode_group("paged_flat_decode", work)
             return paged_flat_decode(
                 q[:, 0], k_pages, v_pages, layer, tables, kv_lens,
                 num_kv=kh, pages=pages, work=work)[:, None]

@@ -19,6 +19,7 @@ einsums they are checked against and the paged XLA fall-back ends in.
 
 from __future__ import annotations
 
+import math
 import os
 
 import jax
@@ -271,29 +272,29 @@ def _window_pages(block_tables, page_size: int, window: int | None) -> int:
 
 
 def paged_decode_work(
-    k_pages,  # the pool paged_attention_decode will be handed
+    k_pages,  # the two pools the step's decode calls will be handed: keys
+    v_pages,  # and values, the latent and the rope's tile, with or without
+    # a head axis
     block_tables: jnp.ndarray,  # [B, PPN] int32
     kv_lens: jnp.ndarray,  # [B] int32 — valid length per row; 0 = not live
     window: int | None = None,
 ):
-    """What a decode step builds ONCE and hands every layer's
-    paged_attention_decode as `work`: on the Pallas route the kernels' grid,
-    the work-list of the live rows' pages, a group of a row's pages an item
-    (pallas_attention.decode_work_list, decode_group); on the XLA route
-    nothing."""
+    """What a decode step builds ONCE and hands every layer's paged decode
+    call (paged_attention_decode, paged_latent_decode, a flat pool's) as
+    `work`: on the Pallas route the kernels' grid, the work-list of the live
+    rows' pages, a group of a row's pages an item
+    (pallas_attention.decode_work_list; decode_group of what a page holds in
+    both pools as they are stored); on the XLA route nothing."""
     if not _pallas_enabled():
         return None
     from llmlb_tpu.ops.pallas_attention import decode_group, decode_work_list
 
-    shape = _pool_shape(k_pages)
-    ps = shape[2]
+    shapes = _pool_shape(k_pages), _pool_shape(v_pages)
+    ps = shapes[0][2]
     pages = _window_pages(block_tables, ps, window)
-    group = 1  # the latent and the flat kernel's pools have no head axis
-    if len(shape) == 5:
-        num_kv, d = shape[3:]
-        group = decode_group(ps, num_kv, d, d, pages)
+    page_elements = sum(math.prod(shape[2:]) for shape in shapes)
     return decode_work_list(block_tables, kv_lens, page_size=ps, pages=pages,
-                            group=group)
+                            group=decode_group(page_elements, pages))
 
 
 def paged_attention_decode(
@@ -330,7 +331,8 @@ def paged_attention_decode(
     pages = _window_pages(block_tables, ps, window)
     if _pallas_enabled():
         if work is None:
-            work = paged_decode_work(k_pages, block_tables, kv_lens, window)
+            work = paged_decode_work(k_pages, v_pages, block_tables, kv_lens,
+                                     window)
         if isinstance(k_pages, dict):
             from llmlb_tpu.ops.pallas_attention import paged_flash_decode_quant
 
@@ -377,7 +379,7 @@ def paged_band_work(k_pages, band_tables: jnp.ndarray, kv_lens: jnp.ndarray,
     _, _, ps, num_kv, d = _pool_shape(k_pages)
     return decode_work_list(
         band_tables, kv_lens, page_size=ps, kv_from=kv_from,
-        group=decode_group(ps, num_kv, d, d, band_tables.shape[1]))
+        group=decode_group(ps * num_kv * 2 * d, band_tables.shape[1]))
 
 
 BAND_DECODE = "paged_band_decode"  # the call's name in a device trace
@@ -609,7 +611,11 @@ def paged_latent_decode(
     if _pallas_enabled():
         from llmlb_tpu.ops.pallas_attention import paged_latent_decode as kernel
 
+        if work is None:
+            work = paged_decode_work(c_pages, r_pages, block_tables, kv_lens,
+                                     window)
         _traced["latent_decode"] = "pallas:paged_latent_decode"
+        note_decode_group("paged_latent_decode", work)
         return kernel(q_abs[:, 0], _pad_last(q_rope[:, 0], r_pages.shape[-1]),
                       c_pages, r_pages, layer,
                       block_tables, kv_lens, scale=scale, pages=pages,

@@ -116,10 +116,10 @@ def _swept_pages(block_tables, pages: int | None) -> int:
     return ppn if pages is None else max(1, min(pages, ppn))
 
 
-# Keys and values a grid step of paged_flash_decode takes at most, in
+# What a grid step of a paged decode kernel takes of its pools at most, in
 # elements: a megabyte of bf16. A grid step costs about 0.3 us over its
-# bytes whatever they are (scripts/decode_page_cost.py, PERF.md §6 PR 54),
-# and a page of 2 KV heads is 131 KB, 0.16 us of the memory's time.
+# bytes whatever they are (scripts/decode_page_cost.py, PERF.md §6 PR 54,
+# PR 58), and a page of 2 KV heads is 131 KB, 0.16 us of the memory's time.
 _GROUP_ELEMENTS = (1 << 20) // 2
 # ... in pages: a page of the group is a block operand of the call, whose
 # index map every program that holds the kernel traces and lowers at every
@@ -129,16 +129,18 @@ _GROUP_ELEMENTS = (1 << 20) // 2
 _GROUP_MAX = 4
 
 
-def decode_group(page_size: int, num_kv: int, head_dim: int, value_dim: int,
-                 sweep: int) -> int:
-    """How many of a row's pages one grid step of paged_flash_decode takes:
-    a function of the shapes alone, the same where the work-list is built
-    and where the kernel is called. As many pages [PS, K, D + Dv] as make
-    about a megabyte of bf16 — 2 at 8 KV heads of 128, 4 at 4 and at 2 (half
-    a megabyte there), 1 at 32 — no more than the `sweep` a row has, and no
+def decode_group(page_elements: int, sweep: int) -> int:
+    """How many of a row's pages one grid step of a paged decode kernel
+    takes: a function of the shapes alone, the same where the work-list is
+    built and where the kernel is called. `page_elements` is what one page
+    holds in all the kernel's pools as they are stored — PS x K x (D + Dv)
+    of a pool with a head axis, PS x (C + R) of the latent pools (R the
+    rope's whole tile), PS x (K*D + K*Dv) of the flat ones. As many pages as
+    make about a megabyte of bf16 — 2 at 8 KV heads of 128, 4 at 4 and at 2
+    (half a megabyte there), 1 at 32; 4 latent pages of 512 + 128, 3 flat
+    ones of 4 x 192 + 4 x 128 — no more than the `sweep` a row has, and no
     more than 4."""
-    page = page_size * num_kv * (head_dim + value_dim)
-    return max(1, min(_GROUP_ELEMENTS // page, sweep, _GROUP_MAX))
+    return max(1, min(_GROUP_ELEMENTS // page_elements, sweep, _GROUP_MAX))
 
 
 def decode_work_list(
@@ -268,6 +270,41 @@ def _stacked(refs):
     return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=0)
 
 
+def _row_item(row_of_ref, page_of_ref, kv_lens_ref, *, block_k: int,
+              sweep: int, group: int, kv_from_ref=None):
+    """Item i of the work-list as a kernel body reads it: (`s`, the item's
+    first logical page; its row's length — at a group over 1 no further than
+    the `pages` a row is read to, which a group may reach past; the row's
+    first and last logical page; the row's lower bound, None without
+    `kv_from_ref`)."""
+    i = pl.program_id(0)
+    s = page_of_ref[i]
+    kv_len = kv_lens_ref[row_of_ref[i]]
+    if kv_from_ref is None:
+        first, kv_from = 0, None
+        last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+        if group > 1:
+            kv_len = jnp.minimum(kv_len, sweep * block_k)
+    else:
+        kv_from = jnp.clip(kv_from_ref[row_of_ref[i]], 0,
+                           jnp.maximum(kv_len - 1, 0))
+        first = kv_from // block_k
+        last = jnp.maximum(pl.cdiv(kv_len, block_k), 1) - 1
+    return s, kv_len, first, last, kv_from
+
+
+def _empty_softmax(m_ref, l_ref, acc_ref):
+    """The online softmax's scratch before a row's first item."""
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _holds_last_page(s, last, group: int):
+    """Whether the item of `group` pages from `s` on is its row's last."""
+    return s == last if group == 1 else s + group > last
+
+
 def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
                  m_ref, l_ref, acc_ref, pages, *,
                  block_k: int, sweep: int, num_kv: int, scale: float,
@@ -306,29 +343,18 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
     holds that cell and `s` is the item's first LOGICAL page, so the mask
     holds at both ends of the span — the oldest page's cells below the
     bound, the newest page's at or past the length."""
-    i = pl.program_id(0)
-    s = page_of_ref[i]
-    kv_len = kv_lens_ref[row_of_ref[i]]
-    if kv_from_ref is None:
-        first, kv_from = 0, None
-        last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
-        if group > 1:  # a group may reach past the `pages` a row is read to
-            kv_len = jnp.minimum(kv_len, sweep * block_k)
-    else:
-        kv_from = jnp.clip(kv_from_ref[row_of_ref[i]], 0,
-                           jnp.maximum(kv_len - 1, 0))
-        first = kv_from // block_k
-        last = jnp.maximum(pl.cdiv(kv_len, block_k), 1) - 1
+    s, kv_len, first, last, kv_from = _row_item(
+        row_of_ref, page_of_ref, kv_lens_ref, block_k=block_k, sweep=sweep,
+        group=group, kv_from_ref=kv_from_ref)
 
     @pl.when(s == first)
     def _init():
         if sink_ref is None:
-            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
+            _empty_softmax(m_ref, l_ref, acc_ref)
         else:
             m_ref[:] = sink_ref[:]
             l_ref[:] = jnp.ones_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
     @pl.when(s * block_k < kv_len)
     def _compute():
@@ -350,7 +376,7 @@ def _decode_item(row_of_ref, page_of_ref, kv_lens_ref, q_ref, o_ref,
         scores = jnp.where(keep, scores, _NEG_INF)
         _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v)
 
-    @pl.when(s == last if group == 1 else s + group > last)
+    @pl.when(_holds_last_page(s, last, group))
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -424,35 +450,38 @@ def _paged_decode_quant_kernel(
                  m_ref, l_ref, acc_ref, pages, group=group, **kw)
 
 
-def _paged_decode_call(kernel, kv_blocks, kv_operands, q, layer, block_tables,
-                       kv_lens, work, *, page_size, num_kv, pages, interpret,
-                       value_dim=None, name=None, kv_from=None, sink=None):
+def _paged_decode_call(kernel, kv_blocks, kv_operands, queries, layer,
+                       block_tables, kv_lens, work, *, page_size,
+                       page_elements, out_dim, pages, interpret, acc_dim=None,
+                       name=None, kv_from=None, sink=None, **kernel_kw):
     """The pallas_call the paged decode kernels share: `grid=(work.count,)`
-    — a run-time length — over the work-list's items; q and out blocks
-    follow the item's row, the KV blocks its pool pages: `kv_blocks` gives
-    each operand of `kv_operands` its (block shape, index map), and the call
-    hands the operand in once a page of the group, the g-th block spec
-    fetching the item's g-th pool page. The group is the work-list's own:
-    `decode_group`'s where `work` is built here. `value_dim`: the values'
-    width where it is not the keys';
-    `name`: the call's name in a device trace where it is not the calling
-    function's; `kv_from` ([B]): a sixth scalar-prefetch operand, the rows'
-    lower bounds; `sink` ([H, 1] f32): an operand behind q, whole every
-    step."""
+    — a run-time length — over the work-list's items; the blocks of
+    `queries` (each [B, H, .]) and of the output [B, H, out_dim] follow the
+    item's row, the KV blocks its pool pages: `kv_blocks` gives each operand
+    of `kv_operands` its (block shape, index map), and the call hands the
+    operand in once a page of the group, the g-th block spec fetching the
+    item's g-th pool page. The group is the work-list's own: `decode_group`'s
+    of `page_elements` where `work` is built here. `acc_dim`: the
+    accumulator's width where it is not the output's; `name`: the call's
+    name in a device trace where it is not the calling function's; `kv_from`
+    ([B]): a sixth scalar-prefetch operand, the rows' lower bounds; `sink`
+    ([H, 1] f32): an operand behind the queries, whole every step;
+    `kernel_kw`: the kernel's own static keywords beside the page size, the
+    sweep and the group."""
     if interpret is None:
         interpret = _interpret_default()
-    b, h, d = q.shape
-    dv = d if value_dim is None else value_dim
+    b, h, _ = queries[0].shape
     sweep = _swept_pages(block_tables, pages)
     if work is None:
         work = decode_work_list(
             block_tables, kv_lens, page_size=page_size, pages=pages,
-            kv_from=kv_from,
-            group=decode_group(page_size, num_kv, d, dv, sweep))
+            kv_from=kv_from, group=decode_group(page_elements, sweep))
     group = work.group
     bounds = () if kv_from is None else (kv_from.astype(jnp.int32),)
-    row_spec = pl.BlockSpec((1, h, d), _row_map, memory_space=pltpu.VMEM)
-    out_spec = pl.BlockSpec((1, h, dv), _row_map, memory_space=pltpu.VMEM)
+
+    def row_spec(width):
+        return pl.BlockSpec((1, h, width), _row_map, memory_space=pltpu.VMEM)
+
     sinks, sink_specs = (), []
     if sink is not None:
         sinks = (sink,)
@@ -465,23 +494,25 @@ def _paged_decode_call(kernel, kv_blocks, kv_operands, q, layer, block_tables,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 + len(bounds),
         grid=(work.count,),
-        in_specs=[row_spec, *sink_specs, *kv_specs],
-        out_specs=out_spec,
+        in_specs=[*(row_spec(q.shape[-1]) for q in queries), *sink_specs,
+                  *kv_specs],
+        out_specs=row_spec(out_dim),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, dv), jnp.float32),
+            pltpu.VMEM((h, out_dim if acc_dim is None else acc_dim),
+                       jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(kernel, block_k=page_size, sweep=sweep,
-                          num_kv=num_kv, scale=d**-0.5, group=group),
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        functools.partial(kernel, block_k=page_size, sweep=sweep, group=group,
+                          **kernel_kw),
+        out_shape=jax.ShapeDtypeStruct((b, h, out_dim), queries[0].dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         **({} if name is None else {"name": name}),
     )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
-      kv_lens.astype(jnp.int32), *bounds, q, *sinks,
+      kv_lens.astype(jnp.int32), *bounds, *queries, *sinks,
       *(operand for operand in kv_operands for _ in range(group)))
 
 
@@ -556,12 +587,13 @@ def paged_flash_decode(
         functools.partial(_paged_decode_kernel, sink=sink is not None,
                           bound=kv_from is not None),
         [((None, 1, ps * num_kv, width), _pool_rows_map) for width in (d, dv)],
-        (k_pages.reshape(*rows, d), v_pages.reshape(*rows, dv)), q, layer,
-        block_tables, kv_lens, work, page_size=ps, num_kv=num_kv,
-        pages=pages, interpret=interpret, value_dim=dv, name=name,
-        kv_from=kv_from,
+        (k_pages.reshape(*rows, d), v_pages.reshape(*rows, dv)), (q,), layer,
+        block_tables, kv_lens, work, page_size=ps,
+        page_elements=ps * num_kv * (d + dv), out_dim=dv, pages=pages,
+        interpret=interpret, name=name, kv_from=kv_from,
         sink=(None if sink is None
-              else sink.astype(jnp.float32).reshape(q.shape[1], 1)))
+              else sink.astype(jnp.float32).reshape(q.shape[1], 1)),
+        num_kv=num_kv, scale=d**-0.5)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
@@ -598,9 +630,10 @@ def paged_flash_decode_quant(
     return _paged_decode_call(
         _paged_decode_quant_kernel,
         [kv_block, scale_block, kv_block, scale_block],
-        (k_pages, k_scales, v_pages, v_scales), q, layer, block_tables,
-        kv_lens, work, page_size=ps, num_kv=num_kv, pages=pages,
-        interpret=interpret)
+        (k_pages, k_scales, v_pages, v_scales), (q,), layer, block_tables,
+        kv_lens, work, page_size=ps, page_elements=ps * num_kv * 2 * d,
+        out_dim=d, pages=pages, interpret=interpret, num_kv=num_kv,
+        scale=d**-0.5)
 
 
 # ---------------------------------------------------------------------------
@@ -613,65 +646,64 @@ def paged_flash_decode_quant(
 # ---------------------------------------------------------------------------
 
 
-def _latent_page_map(i, layer, row_of, page_of, pool_page_of, lens):
-    """Latent pools [L, P, PS, .]: the item's pool page, of the layer."""
-    return (layer[0], pool_page_of[i], 0, 0)
-
-
-def _latent_row_map(i, layer, row_of, page_of, pool_page_of, lens):
-    """Queries and output [B, H, .]: the item's row."""
-    return (row_of[i], 0, 0)
-
-
 def _paged_latent_decode_kernel(
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
     qc_ref,  # [1, H, C] — queries carried into the latent space
     qr_ref,  # [1, H, R] — rotated rope part of the queries
-    c_ref,  # [1, PS, C]
-    r_ref,  # [1, PS, R]
-    o_ref,  # [1, H, C]
-    m_ref,  # [H, 1] f32
-    l_ref,  # [H, 1] f32
-    acc_ref,  # [H, C] f32
-    *, block_k: int, sweep: int, scale: float,
+    *refs,
+    block_k: int, sweep: int, scale: float, group: int,
 ):
-    """One grid step: item i of the work-list is page `s` of row `row`
-    (_decode_item's contract: online softmax across a row's items, a row of
-    length 0 one item written as zeros). Every head attends over the same
-    [PS, C] latent tile: scores are two products (latent part, rope part),
-    the values are the tile itself."""
+    """One grid step: item i of the work-list is the `group` pages from `s`
+    on of row `row` (_decode_item's contract: online softmax across a row's
+    items, a row of length 0 one item written as zeros, the cells of a short
+    last group's missing pages masked by the row's length). Every head
+    attends over the same [G*PS, C] latent tiles, one under another: scores
+    are two products (latent part, rope part), the values are the tiles
+    themselves — two products, one update and one mix a step whatever the
+    group. `refs`: the group's latent blocks, G of [1, PS, C], and its rope
+    blocks, G of [1, PS, R]; the output [1, H, C]; the scratch m, l [H, 1]
+    and acc [H, C], f32."""
     del layer_ref, pool_page_of_ref
-    i = pl.program_id(0)
-    s = page_of_ref[i]
-    kv_len = kv_lens_ref[row_of_ref[i]]
-    last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+    c_refs, r_refs = refs[:group], refs[group:2 * group]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
+    s, kv_len, _, last, _ = _row_item(
+        row_of_ref, page_of_ref, kv_lens_ref, block_k=block_k, sweep=sweep,
+        group=group)
 
     @pl.when(s == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _empty_softmax(m_ref, l_ref, acc_ref)
 
     @pl.when(s * block_k < kv_len)
     def _compute():
         col = s * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), dimension=1)
-        c = c_ref[0]  # [PS, C]
+            jnp.int32, (1, group * block_k), dimension=1)
+        c = _stacked(c_refs)  # [G*PS, C]
         nt = (((1,), (1,)), ((), ()))
         scores = (
             jax.lax.dot_general(qc_ref[0], c, nt,
                                 preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(qr_ref[0], r_ref[0], nt,
+            + jax.lax.dot_general(qr_ref[0], _stacked(r_refs), nt,
                                   preferred_element_type=jnp.float32)
-        ) * scale  # [H, PS]
+        ) * scale  # [H, G*PS]
         scores = jnp.where(col < kv_len, scores, _NEG_INF)
         _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, c)
 
-    @pl.when(s == last)
+    @pl.when(_holds_last_page(s, last, group))
     def _finalize():
         l = l_ref[:]
         o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)
+
+
+def _headless_pools(*pools):
+    """`_paged_decode_call`'s blocks and page elements for pools without a
+    head axis [L, P, PS, W]: a page of each as it is stored, [PS, W]."""
+    ps = pools[0].shape[2]
+    widths = [pool.shape[-1] for pool in pools]
+    return dict(
+        kv_blocks=[((None, 1, ps, w), _pool_rows_map) for w in widths],
+        kv_operands=pools, page_size=ps, page_elements=ps * sum(widths))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
@@ -692,47 +724,16 @@ def paged_latent_decode(
     """Ragged PAGED one-token ABSORBED latent attention. Returns the mix of
     latents [B, H, C]. Grid, `work`, `layer`, `pages` and the rows that are
     not live: paged_flash_decode's contract word for word — the work-list of
-    live (row, page) pairs, the stacked pool read in place at (layer, page).
-    A page is fetched once for all H heads."""
-    if interpret is None:
-        interpret = _interpret_default()
-    b, h, c_dim = q_abs.shape
-    ps = c_pages.shape[2]
-    if work is None:
-        work = decode_work_list(block_tables, kv_lens, page_size=ps,
-                                pages=pages)
-
-    def row_spec(width):
-        return pl.BlockSpec((1, h, width), _latent_row_map,
-                            memory_space=pltpu.VMEM)
-
-    def pool_spec(width):
-        return pl.BlockSpec((None, 1, ps, width), _latent_page_map,
-                            memory_space=pltpu.VMEM)
-
-    r_dim = q_rope.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(work.count,),
-        in_specs=[row_spec(c_dim), row_spec(r_dim), pool_spec(c_dim),
-                  pool_spec(r_dim)],
-        out_specs=row_spec(c_dim),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, c_dim), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_latent_decode_kernel, block_k=ps,
-                          sweep=_swept_pages(block_tables, pages),
-                          scale=scale),
-        out_shape=jax.ShapeDtypeStruct((b, h, c_dim), q_abs.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        name="paged_latent_decode",
-    )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
-      kv_lens.astype(jnp.int32), q_abs, q_rope, c_pages, r_pages)
+    the live rows' pages, a GROUP of a row's consecutive pages an item
+    (`decode_group` of a page's PS x (C + R) elements: 4 at 512 + 128), the
+    stacked pool read in place at (layer, page). A page is fetched once for
+    all H heads. At a group of 1 the call lowers to what it always did."""
+    c_dim = q_abs.shape[-1]
+    return _paged_decode_call(
+        _paged_latent_decode_kernel, queries=(q_abs, q_rope), layer=layer,
+        block_tables=block_tables, kv_lens=kv_lens, work=work,
+        **_headless_pools(c_pages, r_pages), out_dim=c_dim, pages=pages,
+        interpret=interpret, name="paged_latent_decode", scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -750,43 +751,40 @@ def paged_latent_decode(
 def _paged_flat_decode_kernel(
     layer_ref, row_of_ref, page_of_ref, pool_page_of_ref, kv_lens_ref,
     q_ref,  # [1, H, K*D] — head r's query in its KV head's columns, else 0
-    k_ref,  # [1, PS, K*D]
-    v_ref,  # [1, PS, K*Dv]
-    o_ref,  # [1, H, Dv]
-    m_ref,  # [H, 1] f32
-    l_ref,  # [H, 1] f32
-    acc_ref,  # [H, K*Dv] f32
-    *, block_k: int, sweep: int, num_kv: int, scale: float,
+    *refs,
+    block_k: int, sweep: int, num_kv: int, scale: float, group: int,
 ):
-    """One grid step: item i of the work-list is page `s` of row `row`
-    (_decode_item's contract). A query that is zero outside its own KV
-    head's columns meets the whole row of a cell in ONE product, [H, K*D] x
-    [K*D, PS]: the scores are [H, PS], no column of another head's to mask.
-    The mix p v is [H, K*Dv]; a head keeps its own KV head's Dv columns at
-    the end."""
+    """One grid step: item i of the work-list is the `group` pages from `s`
+    on of row `row` (_decode_item's contract). A query that is zero outside
+    its own KV head's columns meets the whole row of every cell of the group
+    in ONE product, [H, K*D] x [K*D, G*PS]: the scores are [H, G*PS], no
+    column of another head's to mask. The mix p v is [H, K*Dv]; a head keeps
+    its own KV head's Dv columns at the end. `refs`: the group's key blocks,
+    G of [1, PS, K*D], and its value blocks, G of [1, PS, K*Dv]; the output
+    [1, H, Dv]; the scratch m, l [H, 1] and acc [H, K*Dv], f32."""
     del layer_ref, pool_page_of_ref
-    i = pl.program_id(0)
-    s = page_of_ref[i]
-    kv_len = kv_lens_ref[row_of_ref[i]]
-    last = jnp.clip(pl.cdiv(kv_len, block_k), 1, sweep) - 1
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
+    s, kv_len, _, last, _ = _row_item(
+        row_of_ref, page_of_ref, kv_lens_ref, block_k=block_k, sweep=sweep,
+        group=group)
 
     @pl.when(s == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _empty_softmax(m_ref, l_ref, acc_ref)
 
     @pl.when(s * block_k < kv_len)
     def _compute():
         col = s * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), dimension=1)
+            jnp.int32, (1, group * block_k), dimension=1)
         scores = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [H, PS]
+            q_ref[0], _stacked(k_refs), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, G*PS]
         scores = jnp.where(col < kv_len, scores, _NEG_INF)
-        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, v_ref[0])
+        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores,
+                       _stacked(v_refs))
 
-    @pl.when(s == last)
+    @pl.when(_holds_last_page(s, last, group))
     def _finalize():
         l = l_ref[:]
         heads, dv = o_ref.shape[1], o_ref.shape[2]
@@ -815,52 +813,24 @@ def paged_flat_decode(
 ) -> jnp.ndarray:
     """Ragged PAGED one-token GQA decode attention over a pool without a
     head axis. Returns [B, H, Dv]. Grid, `work`, `layer`, `pages` and the
-    rows that are not live: paged_flash_decode's contract word for word.
-    Scores scale by D ** -0.5; Dv must be a multiple of 128 lanes."""
-    if interpret is None:
-        interpret = _interpret_default()
+    rows that are not live: paged_flash_decode's contract word for word, a
+    GROUP of a row's consecutive pages an item (`decode_group` of a page's
+    PS x (K*D + K*Dv) elements: 3 at 4 x 192 + 4 x 128). Scores scale by
+    D ** -0.5; Dv must be a multiple of 128 lanes. At a group of 1 the call
+    lowers to what it always did."""
     b, h, d = q.shape
-    ps, kd = k_pages.shape[2:]
-    kdv = v_pages.shape[-1]
-    dv = kdv // num_kv
-    if work is None:
-        work = decode_work_list(block_tables, kv_lens, page_size=ps,
-                                pages=pages)
+    kd, kdv = k_pages.shape[-1], v_pages.shape[-1]
     # head r's query in the columns of its KV head r // G, zeros elsewhere
     own = (jnp.arange(h)[:, None] // (h // num_kv)
            == jnp.arange(num_kv)[None, :])  # [H, K]
     q_wide = jnp.where(own[None, :, :, None], q[:, :, None, :],
                        jnp.zeros((), q.dtype)).reshape(b, h, kd)
-
-    def row_spec(width):
-        return pl.BlockSpec((1, h, width), _latent_row_map,
-                            memory_space=pltpu.VMEM)
-
-    def pool_spec(width):
-        return pl.BlockSpec((None, 1, ps, width), _latent_page_map,
-                            memory_space=pltpu.VMEM)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(work.count,),
-        in_specs=[row_spec(kd), pool_spec(kd), pool_spec(kdv)],
-        out_specs=row_spec(dv),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, kdv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_flat_decode_kernel, block_k=ps,
-                          sweep=_swept_pages(block_tables, pages),
-                          num_kv=num_kv, scale=d**-0.5),
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        name="paged_flat_decode",
-    )(_layer_operand(layer), work.row_of, work.page_of, work.pool_page_of,
-      kv_lens.astype(jnp.int32), q_wide, k_pages, v_pages)
+    return _paged_decode_call(
+        _paged_flat_decode_kernel, queries=(q_wide,), layer=layer,
+        block_tables=block_tables, kv_lens=kv_lens, work=work,
+        **_headless_pools(k_pages, v_pages), out_dim=kdv // num_kv,
+        acc_dim=kdv, pages=pages, interpret=interpret,
+        name="paged_flat_decode", num_kv=num_kv, scale=d**-0.5)
 
 
 # ---------------------------------------------------------------------------

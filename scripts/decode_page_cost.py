@@ -16,7 +16,14 @@ every program's build at every start, PERF.md §6 PR 53), and at the rule's
 group one batch drawn as each cell draws its rows (`decode-saturated`: 32
 rows somewhere between a prompt of 64-128 and 512 tokens more; `chat-paced`:
 5 rows of 32 live, lognormal prompts and outputs; `reason-long-out`: 32 rows
-between a prompt and 4,096 tokens more, the last 2,048 read). Then
+between a prompt and 4,096 tokens more, the last 2,048 read). Then the two
+decode kernels over pools without a head axis (`--only headless`, ~3 min):
+`paged_latent_decode` at kanana-2-30b-a3b's 32 heads and at
+longcat-flash-omni's 64 on a latent of 512 beside the rope's tile, and
+`paged_flat_decode` at mimo-v2-5's 64 heads on 4 x 192 keys and 4 x 128
+values, at groups of 1, 2 and 4 (flat: 3; the rule's named) — a sweep of 4,
+12 and 24 full pages a row and its line, ragged rows of 1-9 pages and of
+17-33 (µs a live page with the call in it), and `one_call` as above. Then
 `paged_flash_extend` at the block family's call (32 rows x 8 queries, 4 KV
 heads x 8, a table 8 pages wide, blocks of 4), a sweep of 1, 2, 4 and 8
 pages, at q blocks of 4, 8, 16, 32, 64 and 128 queries (`--q-blocks` names
@@ -65,6 +72,17 @@ DECODE_SHAPES = {  # name: (KV heads, queries a KV head, layers, table
     "nemotron-3-nano K2xG16": (2, 16, 2, 16, False),  # the cell's 2 layers
     "trinity-mini band K4xG8": (4, 8, 12, BAND_WINDOW // PAGE + 1, True),
 }
+HEADLESS_SHAPES = {  # name: (kernel, query heads, layers, groups)
+    "kanana-2-30b-a3b latent H32": ("paged_latent_decode", 32, 8, (1, 2, 4)),
+    "longcat-flash-omni latent H64": ("paged_latent_decode", 64, 8,
+                                      (1, 2, 4)),
+    "mimo-v2-5 flat H64 on 4x192/4x128": ("paged_flat_decode", 64, 2,
+                                          (1, 2, 3)),
+}
+HEADLESS_SWEEP = (4, 12, 24)  # pages a row: whole groups at 1, 2, 3 and 4
+HEADLESS_RAGGED = {"1-9": (1, 9), "17-33": (17, 33)}  # pages a row, drawn
+HEADLESS_TABLE = 34  # a table's width in pages
+LATENT, ROPE_TILE, FLAT_KV, FLAT_K, FLAT_V = 512, 128, 4, 192, 128
 EXTEND_SHAPES = {  # name: (KV heads, queries a KV head, layers, pool pages,
     # table width, block, q blocks): the block family's call at every q
     # block; a verify chunk's 8 queries at the dense cells' heads, then the
@@ -92,8 +110,11 @@ def _draw_lens(rng, cell: str):
 
 
 def _device_us(trace_dir: str, kernel: str):
-    """(mean µs, events) of the kernel's events on the device's "XLA Ops"
-    line of the newest trace under `trace_dir`; None where there is none."""
+    """(total µs, events) of the events named for the kernel on the
+    device's "XLA Ops" line of the newest trace under `trace_dir` — the
+    kernel's own, and what its wrapper builds in front of it under its name
+    (`paged_flat_decode`'s widened queries: a second event a call); None
+    where there is none."""
     import jax
 
     found = sorted(os.path.join(r, f) for r, _d, fs in os.walk(trace_dir)
@@ -105,7 +126,7 @@ def _device_us(trace_dir: str, kernel: str):
              if (p.name or "").startswith("/device:TPU:")
              for ln in p.lines if ln.name == "XLA Ops"
              for e in ln.events if kernel in e.name]
-    return (sum(spans) / len(spans) / 1e3, len(spans)) if spans else None
+    return (sum(spans) / 1e3, len(spans)) if spans else None
 
 
 def _measure(program, args, kernel: str, reps: int) -> dict:
@@ -122,8 +143,9 @@ def _measure(program, args, kernel: str, reps: int) -> dict:
     jax.profiler.stop_trace()
     got = {"wall_us": wall / (reps * CALLS) * 1e6}
     traced = _device_us(trace_dir, kernel)
-    if traced:
-        got["device_us"], got["events"] = traced
+    if traced:  # µs a call, whatever events a call leaves under the name
+        got["device_us"], got["events"] = (traced[0] / (reps * CALLS),
+                                           traced[1])
     return got
 
 
@@ -209,7 +231,7 @@ def decode_table(shape: str, reps: int, seed: int, small: bool) -> dict:
     q, k_pages, v_pages = _operands(
         seed, (ROWS, num_kv * groups, HEAD_DIM),
         (layers, pool_pages, PAGE, num_kv, HEAD_DIM))
-    rule = pa.decode_group(PAGE, num_kv, HEAD_DIM, HEAD_DIM, width)
+    rule = pa.decode_group(PAGE * num_kv * 2 * HEAD_DIM, width)
 
     def call(group, calls):
         def program(q, k_pages, v_pages, tables, lens):
@@ -269,6 +291,81 @@ def decode_table(shape: str, reps: int, seed: int, small: bool) -> dict:
         cells[cell] = got
     return {"page_bytes": PAGE * num_kv * 2 * HEAD_DIM * 2,
             "rule_group": rule, "groups": by_group, "cells": cells}
+
+
+def headless_table(shape: str, reps: int, seed: int, small: bool) -> dict:
+    """The latent or the flat decode kernel at one cell's shape, at every
+    group of its list."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmlb_tpu.ops import pallas_attention as pa
+
+    kernel, heads, layers, groups = HEADLESS_SHAPES[shape]
+    width, sweep_pages, ragged = HEADLESS_TABLE, HEADLESS_SWEEP, HEADLESS_RAGGED
+    if small:
+        layers, width, sweep_pages = 2, 4, HEADLESS_SWEEP[:1]
+        ragged = {"1-4": (1, 4)}
+    pool_pages = ROWS * width + 1
+    rng = np.random.default_rng(seed + 2)
+    keys = jax.random.split(jax.random.PRNGKey((seed + 2) % (2 ** 31)), 4)
+    if kernel == "paged_latent_decode":
+        widths, q_widths = (LATENT, ROPE_TILE), (LATENT, ROPE_TILE)
+    else:
+        widths, q_widths = (FLAT_KV * FLAT_K, FLAT_KV * FLAT_V), (FLAT_K,)
+    pools = [jax.random.normal(key, (layers, pool_pages, PAGE, w),
+                               jnp.bfloat16)
+             for key, w in zip(keys, widths)]
+    queries = [jax.random.normal(key, (ROWS, heads, w), jnp.bfloat16)
+               for key, w in zip(keys[2:], q_widths)]
+    rule = pa.decode_group(PAGE * sum(widths), width)
+
+    def call(group, calls):
+        def program(queries, pools, tables, lens):
+            work = pa.decode_work_list(tables, lens, page_size=PAGE,
+                                       group=group)
+            q = queries[0]
+            for i in range(calls):  # each call on the last one's output
+                if kernel == "paged_latent_decode":
+                    q = pa.paged_latent_decode(
+                        q, queries[1], *pools, i % layers, tables, lens,
+                        scale=192 ** -0.5, work=work)  # the models' own
+                else:
+                    q = jnp.pad(pa.paged_flat_decode(
+                        q, *pools, i % layers, tables, lens, num_kv=FLAT_KV,
+                        work=work), ((0, 0), (0, 0), (0, FLAT_K - FLAT_V)))
+            return q
+
+        return program
+
+    def operands(pages):
+        tables = _tables(rng, pages, width, pool_pages)
+        return (queries, pools, jnp.asarray(tables, jnp.int32),
+                jnp.asarray(pages * PAGE, jnp.int32))
+
+    def run(program, pages, group):
+        got = {"live_pages": int(pages.sum()),
+               "grid_steps": int((-(-pages // group)).sum()),
+               **_measure(program, operands(pages), kernel, reps)}
+        clock = "device_us" if "device_us" in got else "wall_us"
+        got["us_a_live_page"] = got[clock] / got["live_pages"]
+        return got
+
+    by_group = {}
+    for group in groups:
+        program = jax.jit(call(group, CALLS))
+        sweep = [{"pages_a_row": p, **run(program, np.full(ROWS, p), group)}
+                 for p in sweep_pages if p <= width]
+        by_group[str(group)] = {
+            "sweep": sweep, **({} if small else {"line": _line(sweep)}),
+            "ragged": {name: run(program, rng.integers(lo, hi + 1, ROWS),
+                                 group)
+                       for name, (lo, hi) in ragged.items()},
+            "one_call": _build_seconds(
+                call(group, 1), operands(np.full(ROWS, 1)))}
+    return {"page_bytes": PAGE * sum(widths) * 2, "rule_group": rule,
+            "groups": by_group}
 
 
 def _extend_sweep(shape: str, body: str, queries: int, reps: int, seed: int,
@@ -349,7 +446,7 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=30,
                     help="runs of a program of 16 calls, a measurement")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--only", choices=("decode", "extend"))
+    ap.add_argument("--only", choices=("decode", "headless", "extend"))
     ap.add_argument("--q-blocks", help="queries a row of the extend tables' "
                     "calls, for every shape (default: each shape's own)")
     ap.add_argument("--out", default=os.path.join(
@@ -367,11 +464,15 @@ def main() -> int:
     if small:
         out["note"] = ("not a chip: the interpreter at a tenth of the size, "
                        "a rehearsal of the script and no number")
-    if args.only != "extend":
+    if args.only in (None, "decode"):
         out["paged_flash_decode"] = {
             shape: decode_table(shape, reps, args.seed, small)
             for shape in DECODE_SHAPES}
-    if args.only != "decode":
+    if args.only in (None, "headless"):
+        out["paged_headless_decode"] = {
+            shape: headless_table(shape, reps, args.seed, small)
+            for shape in HEADLESS_SHAPES}
+    if args.only in (None, "extend"):
         out["paged_flash_extend"] = {
             shape: extend_table(
                 shape, [int(n) for n in args.q_blocks.split(",")]

@@ -108,6 +108,6 @@ def test_the_group_of_pages_a_decode_step_takes_at_the_packed_shape():
     """8 heads of 64 packed are 4 rows of 128: a page is 131,072 elements
     of keys and values, so a grid step takes 4 (Trinity-Mini's global
     layers' group); unpacked the rule would read the same at 64 lanes."""
-    assert decode_group(128, 4, 128, 128, 16) == 4
-    assert decode_group(128, 8, 64, 64, 16) == 4
-    assert decode_group(128, 4, 128, 128, 2) == 2
+    assert decode_group(128 * 4 * (128 + 128), 16) == 4
+    assert decode_group(128 * 8 * (64 + 64), 16) == 4
+    assert decode_group(128 * 4 * (128 + 128), 2) == 2

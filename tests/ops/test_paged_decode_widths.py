@@ -3,9 +3,11 @@
 `paged_flash_decode` with values narrower than keys and with a sink a head
 in the softmax's denominator, over a pool of pages and over a RING a row (a
 pool of one page a row, its table the rows' slots); `paged_flat_decode`,
-GQA over a pool without a head axis; and `paged_flash_decode` at every
-width of page the cells serve taking a GROUP of a row's pages a grid step
-(PR 54), with the work-list's invariants under a group."""
+GQA over a pool without a head axis; `paged_flash_decode` at every width of
+page the cells serve taking a GROUP of a row's pages a grid step (PR 54),
+with the work-list's invariants under a group; and the two kernels over
+pools without a head axis, `paged_latent_decode` and `paged_flat_decode`,
+taking a group too (PR 58)."""
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +18,7 @@ from llmlb_tpu.ops.pallas_attention import (
     decode_work_list,
     paged_flash_decode,
     paged_flat_decode,
+    paged_latent_decode,
 )
 from tests.ops.pools import grouped_work
 
@@ -114,12 +117,15 @@ def test_a_ring_a_row_is_a_pool_of_one_page_a_row(lens):
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
 
+@pytest.mark.parametrize("group", [None, 1, 2, 3, 4],
+                         ids=lambda g: "by_shape" if g is None else f"group{g}")
 @pytest.mark.parametrize("h,kv,d,dv", [(8, 4, 24, 16), (8, 2, 16, 16),
                                        (4, 1, 24, 8)])
 @pytest.mark.parametrize("layer", [0, 1])
-def test_paged_flat_decode_matches_dense(h, kv, d, dv, layer):
+def test_paged_flat_decode_matches_dense(h, kv, d, dv, layer, group):
     """A pool without a head axis, a cell one row of its KV heads side by
-    side: the same attention as over [.., K, D] pages."""
+    side: the same attention as over [.., K, D] pages, whatever group of a
+    row's pages a grid step takes."""
     key = jax.random.PRNGKey(h + kv + d + layer)
     k_pages, v_pages = _pools(key, kv, d, dv)
     q = jax.random.normal(jax.random.fold_in(key, 1), (3, h, d), jnp.float32)
@@ -127,7 +133,8 @@ def test_paged_flat_decode_matches_dense(h, kv, d, dv, layer):
     lens = jnp.asarray([24, 0, 9], jnp.int32)
     flat = (LAYERS, PAGES, PS, -1)
     got = paged_flat_decode(q, k_pages.reshape(flat), v_pages.reshape(flat),
-                            layer, tables, lens, num_kv=kv, interpret=True)
+                            layer, tables, lens, num_kv=kv, interpret=True,
+                            work=grouped_work(group, tables, lens, PS))
     assert got.shape == (3, h, dv)
     want = _dense(q, _rows(k_pages, layer, tables),
                   _rows(v_pages, layer, tables), lens)
@@ -136,7 +143,8 @@ def test_paged_flat_decode_matches_dense(h, kv, d, dv, layer):
     # `pages` bounds the sweep as paged_flash_decode's does
     short = paged_flat_decode(q, k_pages.reshape(flat), v_pages.reshape(flat),
                               layer, tables, lens, num_kv=kv, pages=2,
-                              interpret=True)
+                              interpret=True,
+                              work=grouped_work(group, tables, lens, PS, 2))
     want = _dense(q, _rows(k_pages, layer, tables[:, :2]),
                   _rows(v_pages, layer, tables[:, :2]),
                   np.minimum(np.asarray(lens), 2 * PS))
@@ -186,6 +194,123 @@ def test_a_group_of_pages_a_grid_step_is_the_dense_softmax(case, kv, group):
     live = np.asarray(lens) > 0
     np.testing.assert_allclose(got[live], want[live], atol=2e-5)
     assert not got[~live].any()
+
+
+# --- … and of the pools without a head axis (PR 58) ---------------------------
+
+HEADLESS_GROUPS = [1, 2, 3, 4]
+# name -> the `pages` bucket over a table 7 pages wide
+HEADLESS_SWEEPS = {"whole_table": None, "bucket_of_three_pages": 3,
+                   "bucket_of_one_page": 1}
+HEADLESS_PAGES = 48  # a pool for six rows of a table's width
+
+
+def _headless_step(group, seed):
+    """Rows of 0 cells, of one cell, of exactly a group of pages, of a group
+    and one page (one cell into it), one ragged and one the table's width:
+    (tables [6, 7], lengths) over a pool of HEADLESS_PAGES pages."""
+    lens = [0, 1, group * PS, group * PS + 1, 19, WIDE_PPN * PS]
+    tables = np.random.default_rng(seed).permutation(HEADLESS_PAGES)[
+        :len(lens) * WIDE_PPN].reshape(len(lens), WIDE_PPN)
+    return jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+
+def _swept(pool, tables, lens, pages):
+    """One layer's cells [B, S, ...] of the pages a row is read to, and the
+    lengths within them."""
+    sweep = WIDE_PPN if pages is None else pages
+    got = np.asarray(pool, np.float64)[np.asarray(tables)[:, :sweep]]
+    return (got.reshape(got.shape[0], -1, *got.shape[3:]),
+            np.minimum(np.asarray(lens), sweep * PS))
+
+
+@pytest.mark.parametrize("group", HEADLESS_GROUPS)
+@pytest.mark.parametrize("heads", [32, 64])
+@pytest.mark.parametrize("sweep", sorted(HEADLESS_SWEEPS))
+def test_a_group_of_latent_pages_a_grid_step_is_the_dense_softmax(
+        sweep, heads, group):
+    """Every head over the same [G*PS, C] latent tiles, scores in two
+    products and the tiles the values, at kanana-2-30b-a3b's 32 heads and
+    longcat-flash-omni's 64, on the pool's second layer: a row attends over
+    its first min(len, pages x PS) cells exactly, whatever of its last group
+    is missing, and a row of 0 cells is zeros."""
+    pages, c_dim, r_dim, scale = HEADLESS_SWEEPS[sweep], 64, 16, 0.11
+    tables, lens = _headless_step(group, heads + group)
+    kc, kr, kq, kp = jax.random.split(jax.random.PRNGKey(heads + group), 4)
+    c_pages = jax.random.normal(kc, (LAYERS, HEADLESS_PAGES, PS, c_dim))
+    r_pages = jax.random.normal(kr, (LAYERS, HEADLESS_PAGES, PS, r_dim))
+    q_abs = jax.random.normal(kq, (len(lens), heads, c_dim), jnp.float32)
+    q_rope = jax.random.normal(kp, (len(lens), heads, r_dim), jnp.float32)
+    got = np.asarray(paged_latent_decode(
+        q_abs, q_rope, c_pages, r_pages, 1, tables, lens, scale=scale,
+        pages=pages, interpret=True,
+        work=grouped_work(group, tables, lens, PS, pages)))
+    c, seen = _swept(c_pages[1], tables, lens, pages)
+    r, _ = _swept(r_pages[1], tables, lens, pages)
+    s = (np.einsum("bhc,bsc->bhs", np.asarray(q_abs, np.float64), c)
+         + np.einsum("bhr,bsr->bhs", np.asarray(q_rope, np.float64), r)) * scale
+    s = np.where(np.arange(c.shape[1])[None, None, :] < seen[:, None, None],
+                 s, -np.inf)
+    live = np.asarray(lens) > 0
+    p = np.exp(s[live] - s[live].max(-1, keepdims=True))
+    want = np.einsum("bhs,bsc->bhc", p / p.sum(-1, keepdims=True), c[live])
+    np.testing.assert_allclose(got[live], want, atol=2e-5)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("group", HEADLESS_GROUPS)
+@pytest.mark.parametrize("h,kv,d,dv", [(64, 4, 192, 128), (8, 2, 24, 16)],
+                         ids=["mimo-64-on-4x192-4x128", "tiny"])
+@pytest.mark.parametrize("sweep", sorted(HEADLESS_SWEEPS))
+def test_a_group_of_flat_pages_a_grid_step_is_the_dense_softmax(
+        sweep, h, kv, d, dv, group):
+    """ONE product of the widened queries with a group's [G*PS, K*D] rows,
+    at mimo-v2-5's 64 heads on 4 x 192 keys and 4 x 128 values, on the
+    pool's second layer: the dense attention over a row's first min(len,
+    pages x PS) cells, and zeros for a row of 0 cells."""
+    pages = HEADLESS_SWEEPS[sweep]
+    tables, lens = _headless_step(group, h + group)
+    kk, kv_, kq = jax.random.split(jax.random.PRNGKey(h + group), 3)
+    k_pages = jax.random.normal(kk, (LAYERS, HEADLESS_PAGES, PS, kv, d))
+    v_pages = jax.random.normal(kv_, (LAYERS, HEADLESS_PAGES, PS, kv, dv))
+    q = jax.random.normal(kq, (len(lens), h, d), jnp.float32)
+    flat = (LAYERS, HEADLESS_PAGES, PS, -1)
+    got = np.asarray(paged_flat_decode(
+        q, k_pages.reshape(flat), v_pages.reshape(flat), 1, tables, lens,
+        num_kv=kv, pages=pages, interpret=True,
+        work=grouped_work(group, tables, lens, PS, pages)))
+    k, seen = _swept(k_pages[1], tables, lens, pages)
+    v, _ = _swept(v_pages[1], tables, lens, pages)
+    live = np.asarray(lens) > 0
+    want = _dense(q, k, v, seen)
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("kernel", ["latent", "flat"])
+def test_a_headless_kernel_called_alone_builds_its_shapes_own_group(kernel):
+    """Without `work` the latent and the flat kernel build their list by
+    `decode_group` of what a page holds in both pools: the same answer as
+    a list of one page an item gives, to rounding."""
+    tables, lens = _headless_step(2, 3)
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    if kernel == "latent":
+        pools = [jax.random.normal(k, (LAYERS, HEADLESS_PAGES, PS, w))
+                 for k, w in zip(keys, (64, 16))]
+        queries = [jax.random.normal(k, (len(lens), 8, w), jnp.float32)
+                   for k, w in zip(keys[2:], (64, 16))]
+        call = lambda work: paged_latent_decode(  # noqa: E731
+            *queries, *pools, 1, tables, lens, scale=0.2, interpret=True,
+            work=work)
+    else:
+        pools = [jax.random.normal(k, (LAYERS, HEADLESS_PAGES, PS, 2 * w))
+                 for k, w in zip(keys, (24, 16))]
+        q = jax.random.normal(keys[2], (len(lens), 8, 24), jnp.float32)
+        call = lambda work: paged_flat_decode(  # noqa: E731
+            q, *pools, 1, tables, lens, num_kv=2, interpret=True, work=work)
+    np.testing.assert_allclose(
+        np.asarray(call(None)),
+        np.asarray(call(grouped_work(1, tables, lens, PS))), atol=2e-5)
 
 
 def _work_list_pr52(block_tables, kv_lens, *, page_size, pages=None,

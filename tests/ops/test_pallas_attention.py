@@ -582,7 +582,9 @@ def test_the_route_record_says_which_group_a_decode_call_took(monkeypatch):
     """`attention.traced.paged_decode_group`: the pages a grid step of each
     traced paged decode call takes, by the call's name in a device trace —
     the shapes' own (2 at 8 KV heads of 128, 4 at 4 under a band's bound, 1
-    over a ring of one page a row), static a program."""
+    over a ring of one page a row; 4 over the latent pools of 512 + the
+    rope's tile, 3 over flat pools of 4 x 192 + 4 x 128), static a
+    program."""
     from llmlb_tpu.ops import attention
 
     monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
@@ -595,7 +597,7 @@ def test_the_route_record_says_which_group_a_decode_call_took(monkeypatch):
                 jax.ShapeDtypeStruct((3,), jnp.int32))
 
     def decode(*operands):  # the step's own order: the list, then the call
-        work = attention.paged_decode_work(operands[1], *operands[4:])
+        work = attention.paged_decode_work(*operands[1:3], *operands[4:])
         return attention.paged_attention_decode(*operands, work=work)
 
     def band(*operands):
@@ -612,9 +614,34 @@ def test_the_route_record_says_which_group_a_decode_call_took(monkeypatch):
                                     lens, jnp.zeros((32,), jnp.float32), {})
 
     jax.eval_shape(ring, *shapes(4, 1))
+
+    def headless(heads, q_widths, k_width, v_width):
+        """Operands of a decode call over pools without a head axis."""
+        bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+        return (*(bf16(3, 1, heads, w) for w in q_widths),
+                bf16(2, 40, 128, k_width), bf16(2, 40, 128, v_width), 0,
+                jax.ShapeDtypeStruct((3, 16), jnp.int32),
+                jax.ShapeDtypeStruct((3,), jnp.int32))
+
+    def latent(*operands):  # kanana-2-30b-a3b's and longcat-flash-omni's
+        work = attention.paged_decode_work(*operands[2:4], *operands[5:])
+        return attention.paged_latent_decode(*operands, scale=0.1, work=work)
+
+    def flat(*operands):  # mimo-v2-5's global layers
+        from llmlb_tpu.models import mimo_v2
+
+        cfg = mimo_v2.MimoV2Config(
+            vocab_size=64, hidden_size=64, intermediate_size=64,
+            num_layers=2, num_heads=64, num_kv_heads=4, head_dim=192)
+        work = attention.paged_decode_work(*operands[1:3], *operands[4:])
+        return mimo_v2._global_attention(cfg).decode(*operands, work=work)
+
+    jax.eval_shape(latent, *headless(32, (512, 64), 512, 128))
+    jax.eval_shape(flat, *headless(64, (192,), 4 * 192, 4 * 128))
     assert attention.traced_routes()["paged_decode_group"] == {
         "paged_flash_decode": 2, "paged_band_decode": 4,
-        "paged_window_decode": 1}
+        "paged_window_decode": 1, "paged_latent_decode": 4,
+        "paged_flat_decode": 3}
 
 
 def test_paged_flash_extend_under_a_scan_takes_the_layer_at_run_time():

@@ -49,8 +49,8 @@ FAMILIES = {
 
 # the pages a grid step of the decode kernels takes at these shapes (the
 # window of `_decode_jaxpr` is two pages)
-DECODE_GROUP = pallas_attention.decode_group(PAGE_SIZE, KV_HEADS, HEAD_DIM,
-                                             HEAD_DIM, 2)
+DECODE_GROUP = pallas_attention.decode_group(
+    PAGE_SIZE * KV_HEADS * 2 * HEAD_DIM, 2)
 
 
 def _equations(jaxpr, kernel_bodies=False):
@@ -208,6 +208,51 @@ def _decode_kernel_call(variant, kv_heads, groups, group=None, table=34):
     return call
 
 
+# the two decode kernels over a pool without a head axis, at their cells'
+# pages of 128: the latent kernel at kanana-2-30b-a3b's 32 heads on a latent
+# of 512 and the rope's tile, the flat one at mimo-v2-5's 64 heads on 4 x 192
+# keys and 4 x 128 values
+HEADLESS = ["latent", "flat"]
+# … and both as PR 57 (the parent of their group) traced them
+HEADLESS_PARENT_FORMS = PARENT_FORMS.with_name("paged_decode_kernel_pr57")
+
+
+def _headless_kernel_call(kind, group, table=34):
+    """The pallas_call equation of one latent or flat paged decode call,
+    traced for the interpreter, its work-list `group` pages an item (None:
+    the kernel's own list, by shape)."""
+    rows = 4
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    if kind == "latent":
+        kernel = functools.partial(pallas_attention.paged_latent_decode,
+                                   scale=192 ** -0.5)
+        operands = (bf16(rows, 32, 512), bf16(rows, 32, 128),
+                    bf16(2, 40, 128, 512), bf16(2, 40, 128, 128))
+    else:
+        kernel = functools.partial(pallas_attention.paged_flat_decode,
+                                   num_kv=4)
+        operands = (bf16(rows, 64, 192), bf16(2, 40, 128, 4 * 192),
+                    bf16(2, 40, 128, 4 * 128))
+
+    def fn(*operands):
+        work = None if group is None else pallas_attention.decode_work_list(
+            *operands[-2:], page_size=128, group=group)
+        return kernel(*operands, work=work, interpret=True)
+
+    closed = jax.make_jaxpr(fn)(*operands, ints(), ints(rows, table),
+                                ints(rows))
+    (call,) = [e for e in _equations(closed.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    return call
+
+
+def _body_census(call) -> collections.Counter:
+    """How often each primitive occurs in a kernel's traced body."""
+    return collections.Counter(
+        e.primitive.name for e in _equations(call.params["jaxpr"], True))
+
+
 def _kernel_form(call) -> str:
     """A kernel as text: its body, then each block's index map."""
     maps = [str(block.index_map_jaxpr)
@@ -221,10 +266,20 @@ def test_at_a_group_of_one_the_decode_kernel_is_the_parents(variant):
     megabytes) the call traces to the kernel and the index maps it traced to
     before there were groups, text for text — and a ring, one page a row,
     has a group of 1 whatever its heads."""
-    assert pallas_attention.decode_group(128, 32, 128, 128, 34) == 1
-    assert pallas_attention.decode_group(128, 4, 192, 128, 1) == 1
+    assert pallas_attention.decode_group(128 * 32 * (128 + 128), 34) == 1
+    assert pallas_attention.decode_group(128 * 4 * (192 + 128), 1) == 1
     got = _kernel_form(_decode_kernel_call(variant, 32, 1))
     assert got == (PARENT_FORMS / f"{variant}.txt").read_text()
+
+
+@pytest.mark.parametrize("kind", HEADLESS)
+def test_at_a_group_of_one_the_headless_decode_kernel_is_the_parents(kind):
+    """The latent and the flat kernel under a work-list of one page an item
+    (a ring's sweep, a caller's own `work`) trace to the kernel and the
+    index maps they traced to before they took groups (PR 57), text for
+    text."""
+    got = _kernel_form(_headless_kernel_call(kind, 1))
+    assert got == (HEADLESS_PARENT_FORMS / f"{kind}.txt").read_text()
 
 
 @pytest.mark.parametrize("variant", DECODE_VARIANTS)
@@ -238,10 +293,8 @@ def test_the_decode_body_does_not_grow_with_the_group(kv_heads, groups,
     product pair, one mask, one update — but for the reads of the group's
     block refs, one a block."""
     def census(group):
-        call = _decode_kernel_call(variant, kv_heads, groups, group=group)
-        return collections.Counter(
-            e.primitive.name
-            for e in _equations(call.params["jaxpr"], True))
+        return _body_census(
+            _decode_kernel_call(variant, kv_heads, groups, group=group))
 
     small, large = census(2), census(8)
     operands = 4 if variant == "quant" else 2  # values, and an int8 pool's scales
@@ -251,17 +304,67 @@ def test_the_decode_body_does_not_grow_with_the_group(kv_heads, groups,
     assert large["exp"] == small["exp"] == census(1)["exp"]
 
 
+@pytest.mark.parametrize("kind", HEADLESS)
+def test_the_headless_decode_body_does_not_grow_with_the_group(kind):
+    """The same guard for the latent and the flat kernel (PR 58): at a group
+    of 4 the body holds the equations it holds at 2 — the latent kernel its
+    two scores products and its mix, the flat one a pair — but for the reads
+    of the group's block refs, one a block of either pool."""
+    def census(group):
+        return _body_census(_headless_kernel_call(kind, group))
+
+    small, large = census(2), census(4)
+    assert large - small == collections.Counter({"get": 2 * (4 - 2)})
+    assert not small - large
+    assert large["dot_general"] == (3 if kind == "latent" else 2)
+    assert large["concatenate"] == 2
+    assert large["exp"] == small["exp"] == census(1)["exp"]
+
+
 def test_the_group_is_a_function_of_the_shapes():
     """As many pages as make a megabyte of bf16 keys and values, no more
     than the sweep and no more than 4 (a page of the group is a block
     operand every program traces: PERF.md §6, PR 54): the cells' widths."""
-    group = functools.partial(pallas_attention.decode_group, 128)
+    def group(kv, d, dv, sweep, page_size=128):  # a pool with a head axis
+        return pallas_attention.decode_group(page_size * kv * (d + dv), sweep)
+
     assert [group(kv, 128, 128, 34) for kv in (2, 4, 8, 32)] == [4, 4, 2, 1]
     assert [group(2, 128, 128, sweep) for sweep in (1, 2, 3, 17)] == [1, 2, 3, 4]
     assert group(4, 192, 128, 34) == 3  # narrower values count as they are
-    assert pallas_attention.decode_group(8, 2, 16, 16, 64) == 4  # a tiny page
+    assert group(2, 16, 16, 64, page_size=8) == 4  # a tiny page
+    # the pools without a head axis, a page as it is stored: the latent of
+    # 512 beside the rope's whole tile; 4 x 192 keys beside 4 x 128 values
+    assert pallas_attention.decode_group(128 * (512 + 128), 34) == 4
+    assert pallas_attention.decode_group(128 * (4 * 192 + 4 * 128), 34) == 3
+    assert pallas_attention.decode_group(128 * (512 + 128), 2) == 2
+    assert list(inspect.signature(pallas_attention.decode_group).parameters
+                ) == ["page_elements", "sweep"]
     source = inspect.getsource(pallas_attention.decode_group)
     assert "environ" not in source and "name" not in source
+
+
+@pytest.mark.parametrize("kind,want", [("latent", 4), ("flat", 3)])
+def test_a_decode_step_builds_the_headless_pools_group_from_both_pools(
+        kind, want, monkeypatch):
+    """`paged_decode_work`, the one list a decode step builds for all its
+    layers, counts a page in BOTH pools as they are stored: 4 pages an item
+    for kanana-2-30b-a3b's and longcat-flash-omni's latent pools, 3 for
+    mimo-v2-5's flat ones — and the kernel called without a list builds the
+    same one."""
+    from llmlb_tpu.ops import attention
+
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    call = _headless_kernel_call(kind, None)
+    pools = [v.aval for v in call.invars if len(v.aval.shape) == 4]
+    assert len(pools) == 2 * want  # each pool once a page of the group
+    tables = jax.ShapeDtypeStruct((4, 34), jnp.int32)
+    lens = jax.ShapeDtypeStruct((4,), jnp.int32)
+    work = jax.eval_shape(attention.paged_decode_work, pools[0], pools[-1],
+                          tables, lens)
+    assert work.group == want
+    assert jax.eval_shape(functools.partial(
+        attention.paged_decode_work, window=2 * 128), pools[0], pools[-1],
+        tables, lens).group == 2  # no more than the window's pages
 
 
 # --- the state-space step kernel: one turn of one loop, whatever the shape -----
@@ -277,8 +380,7 @@ def _state_step_census(heads, groups, channels=64, state=128, slots=4):
         f32((slots, groups, state)))
     (call,) = [e for e in _equations(closed.jaxpr)
                if e.primitive.name == "pallas_call"]
-    return collections.Counter(
-        e.primitive.name for e in _equations(call.params["jaxpr"], True))
+    return _body_census(call)
 
 
 @pytest.mark.parametrize("heads,groups", [(8, 1), (64, 2), (64, 8), (128, 8)],
