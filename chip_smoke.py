@@ -1225,6 +1225,29 @@ def kernel_leg() -> int:
 
     attempt("paged_flash_decode", "8x64 packed", narrow_heads)
 
+    # 64 experts of [2048, 1536] held whole (LFM2-24B-A2B, models/
+    # lfm2_moe.py): the grouped products at a shape no other family has, at
+    # a decode step's 32 rows x 4 (most experts a row or two, some none) and
+    # at a prefill's 1,024 x 4, layer 1 of a stack of two
+    for rows_, k_, o_ in ((128, 2048, 1536), (128, 1536, 2048),
+                          (4096, 2048, 1536)):
+        def whole_mixture(experts=64, tile=pallas_moe.ROW_TILE):
+            load = jnp.asarray(rng.multinomial(
+                rows_ - 8, rng.dirichlet(np.ones(experts))), jnp.int32)
+            a = rand(rows_, k_)
+            w = (rand(2, experts, k_, o_) * k_ ** -0.5).astype(bf16)
+            work = pallas_moe.group_work_list(load, rows=rows_, tile=tile)
+            got = pallas_moe.grouped_expert_matmul(a, w, 1, work, tile=tile,
+                                                   interpret=False)
+            want = jax.lax.ragged_dot(a, w[1], load,
+                                      preferred_element_type=jnp.float32)
+            valid = int(load.sum())  # rows behind the experts': unspecified
+            check("grouped_expert_matmul", f"64 experts,{rows_}x{k_}->{o_}",
+                  got[None, :valid], want[None, :valid])
+
+        attempt("grouped_expert_matmul", f"64 experts,{rows_}x{k_}->{o_}",
+                whole_mixture)
+
     n_adapters, rank = 9, 16
     for b, t in ((8, 1), (32, 1), (2, 512), (8, 5)):
         for n_in, n_out in ((2048, 2048), (2048, 256), (2048, 5632),

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from llmlb_tpu.models.afmoe import AfmoeConfig
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.granite_hybrid import GraniteHybridConfig
+from llmlb_tpu.models.lfm2_moe import Lfm2MoeConfig
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
 from llmlb_tpu.models.mimo_v2 import MimoV2Config
@@ -149,6 +150,21 @@ PRESETS: dict[str, LlamaConfig] = {
         ssm_heads=8, ssm_head_dim=16, ssm_groups=1, ssm_state=16,
         conv_kernel=4, chunk_size=16, attention_multiplier=0.0625,
         residual_multiplier=0.22,
+    ),
+    # CI-sized gated-short-convolution mixture (models/lfm2_moe.py,
+    # docs/lfm2-moe.md): conv, conv, attention, conv twice over, two rows
+    # carried a slot and conv layer, QK-normed rotary attention at two KV
+    # heads of 16 packed to a row, two dense feed-forwards, then mixtures of
+    # 8 experts, 2 a token, the head tied to the embedding table
+    "debug-lfm2-moe-tiny": Lfm2MoeConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=8, num_heads=4, num_kv_heads=2, rope_theta=1000000.0,
+        rms_eps=1e-5, dtype=jnp.float32, max_position_embeddings=512,
+        tie_word_embeddings=True,
+        layer_types=("conv", "conv", "full_attention", "conv") * 2,
+        conv_taps=3, num_dense_layers=2, num_experts=8, experts_per_token=2,
+        moe_intermediate_size=48, norm_topk_prob=True,
+        routed_scaling_factor=1.0,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

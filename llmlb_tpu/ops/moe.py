@@ -84,13 +84,14 @@ def top_k_routing(
 
 
 def _biased_choice(scores, bias, num_selected: int, scale: float,
-                   normalize: bool):
-    """The k largest of score + bias, weighed by their UNBIASED scores."""
+                   normalize: bool, eps: float = 1e-20):
+    """The k largest of score + bias, weighed by their UNBIASED scores,
+    over their sum + `eps` where normalised."""
     biased = scores + bias.astype(jnp.float32)
     _, idx = lax.top_k(biased, num_selected)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
     return picked * scale, idx, biased
 
 
@@ -101,14 +102,16 @@ def sigmoid_bias_routing(
     *,
     scale: float = 1.0,
     normalize: bool = True,
+    eps: float = 1e-20,
 ):
     """DeepSeek-V3's `noaux_tc` gate with one group: scores are sigmoids,
     the k experts are the top-k of score + bias, and the weights are the
-    UNBIASED scores of those k, normalised to sum 1 (`norm_topk_prob`) and
-    scaled (`routed_scaling_factor`). Returns (weights [S, k], indices
-    [S, k], biased scores [S, E])."""
+    UNBIASED scores of those k, normalised to sum 1 (`norm_topk_prob`: over
+    their sum + `eps`, the family's own: 1e-20 as DeepSeek-V3 has it, 1e-6
+    in models/lfm2_moe.py) and scaled (`routed_scaling_factor`). Returns
+    (weights [S, k], indices [S, k], biased scores [S, E])."""
     return _biased_choice(jax.nn.sigmoid(router_logits), bias, num_selected,
-                          scale, normalize)
+                          scale, normalize, eps)
 
 
 def softmax_bias_routing(

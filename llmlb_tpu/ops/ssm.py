@@ -24,7 +24,9 @@ it, the last `width - 1` rows of the convolution's input.
   TPU, the same arithmetic in `jax.numpy` elsewhere. Rows that are not
   `live` keep their state bit for bit (decay 1, input 0).
 - `causal_conv` is the depthwise convolution with the carried rows in
-  front, and says which rows to carry on.
+  front, and says which rows to carry on; its bias and what follows it are
+  the caller's (a gated short convolution has neither and is the mixer
+  whole: models/lfm2_moe.py).
 """
 
 from __future__ import annotations
@@ -49,21 +51,27 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def causal_conv(x, prev, w, b, lens):
+def causal_conv(x, prev, w, b, lens, *, act=jax.nn.silu):
     """Depthwise causal convolution over time. x [B, T, C]; prev [B, W-1, C]
     the rows before position 0 (zeros for a fresh sequence); w [C, W] with
-    w[:, W-1] on the current position; b [C]; lens [B]. Returns (silu(conv)
-    [B, T, C], the rows to carry on [B, W-1, C]: the W-1 rows that end at
-    position lens - 1, gathered by length so that a padded row carries its
-    true tail)."""
+    w[:, W-1] on the current position; b [C], or None for a convolution
+    without a bias; lens [B]; `act` what follows the convolution, or None
+    for nothing (a Mamba layer's has both, a gated short convolution's
+    neither: models/lfm2_moe.py). Returns (act(conv + b) [B, T, C], the rows
+    to carry on [B, W-1, C]: the W-1 rows that end at position lens - 1,
+    gathered by length so that a padded row carries its true tail)."""
     width = w.shape[-1]
     t = x.shape[1]
     padded = jnp.concatenate([prev.astype(x.dtype), x], axis=1)
     out = sum(padded[:, j:j + t].astype(F32) * w[:, j].astype(F32)
-              for j in range(width)) + b.astype(F32)
+              for j in range(width))
+    if b is not None:
+        out = out + b.astype(F32)
     at = lens[:, None] + jnp.arange(width - 1, dtype=lens.dtype)[None, :]
     carry = jnp.take_along_axis(padded, at[:, :, None], axis=1)
-    return jax.nn.silu(out).astype(x.dtype), carry
+    if act is not None:
+        out = act(out)
+    return out.astype(x.dtype), carry
 
 
 def ssd_chunked(x, dt, a, b, c, d, s0, lens, *, chunk: int = CHUNK):

@@ -33,7 +33,8 @@ PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "mimo_v2": "debug-mimo-tiny",
            "olmo_hybrid": "debug-olmo-hybrid-tiny",
            "afmoe": "debug-trinity-tiny",
-           "granite_hybrid": "debug-granite-hybrid-tiny"}
+           "granite_hybrid": "debug-granite-hybrid-tiny",
+           "lfm2_moe": "debug-lfm2-moe-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -177,7 +178,7 @@ OLD_MODEL_TYPES = {
     "sdar_moe": "sdar_moe", "nemotron_h": "nemotron_h",
     "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
     "olmo_hybrid": "olmo_hybrid", "afmoe": "afmoe",
-    "granitemoehybrid": "granite_hybrid",
+    "granitemoehybrid": "granite_hybrid", "lfm2_moe": "lfm2_moe",
 }
 OLD_MECHANISM_KEYS = {
     "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
@@ -188,9 +189,9 @@ OLD_MECHANISM_KEYS = {
     "n_shared_experts": ("deepseek_v3", "nemotron_h"),
     "first_k_dense_replace": ("deepseek_v3",),
     "num_local_experts": ("mixtral", "granite_hybrid"),
-    "num_experts": ("mixtral", "sdar_moe", "afmoe"),
+    "num_experts": ("mixtral", "sdar_moe", "afmoe", "lfm2_moe"),
     "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h",
-                              "mimo_v2", "afmoe"),
+                              "mimo_v2", "afmoe", "lfm2_moe"),
     "hybrid_override_pattern": ("nemotron_h",),
     "mamba_num_heads": ("nemotron_h",),
     "ssm_state_size": ("nemotron_h",),
@@ -204,7 +205,7 @@ OLD_MECHANISM_KEYS = {
     "swa_num_key_value_heads": ("mimo_v2",),
     # kinds of layer and the linear-attention layers' sizes: computed by one
     # family since PR 48; before it no class read them and none refused them
-    "layer_types": ("olmo_hybrid", "afmoe", "granite_hybrid"),
+    "layer_types": ("olmo_hybrid", "afmoe", "granite_hybrid", "lfm2_moe"),
     "linear_num_key_heads": ("olmo_hybrid",),
     "linear_num_value_heads": ("olmo_hybrid",),
     "linear_key_head_dim": ("olmo_hybrid",),
@@ -215,7 +216,7 @@ OLD_MECHANISM_KEYS = {
     # sigmoid-routed mixture with a shared expert: one family since PR 52
     # (`num_experts_per_tok`, which every mixture's config carries, is read
     # by it and listed by none: a listed key is refused of all the others)
-    "num_dense_layers": ("afmoe",),
+    "num_dense_layers": ("afmoe", "lfm2_moe"),
     "num_shared_experts": ("afmoe",),
     "route_norm": ("afmoe",),
     "route_scale": ("afmoe",),
@@ -232,6 +233,12 @@ OLD_MECHANISM_KEYS = {
         "attention_multiplier", "residual_multiplier", "logits_scaling",
         "position_embedding_type", "shared_intermediate_size"),
         ("granite_hybrid",)),
+    # gated short convolutions beside attention, and a sigmoid-and-bias
+    # routed mixture under its own keys: one family since PR 59
+    # (`norm_topk_prob` and `routed_scaling_factor`, which four older
+    # mixtures' configs carry, are read by it and listed by none)
+    **dict.fromkeys(("conv_L_cache", "conv_bias", "use_expert_bias"),
+                    ("lfm2_moe",)),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
 }
@@ -294,11 +301,13 @@ LINEAR_STATED = [
 
 
 @pytest.mark.parametrize("stated", LINEAR_STATED, ids=lambda d: next(iter(d)))
-# (afmoe and granite_hybrid read `layer_types` too and refuse a kind they do
-# not compute in their own class, by name: tests/engine/test_band_family.py,
-# test_granite_family.py)
+# (afmoe, granite_hybrid and lfm2_moe read `layer_types` too and refuse a
+# kind they do not compute in their own class, by name:
+# tests/engine/test_band_family.py, test_granite_family.py,
+# test_conv_moe_family.py)
 @pytest.mark.parametrize("model_type", sorted(
-    set(OLD_MODEL_TYPES) - {"olmo_hybrid", "afmoe", "granitemoehybrid"})
+    set(OLD_MODEL_TYPES) - {"olmo_hybrid", "afmoe", "granitemoehybrid",
+                            "lfm2_moe"})
     + ["a_type_nobody_registered"])
 def test_a_linear_attention_config_is_served_as_no_other_model(
         model_type, stated):
@@ -318,7 +327,8 @@ def test_a_linear_attention_config_is_served_as_no_other_model(
 
 @pytest.mark.parametrize("stated", LINEAR_STATED[2:],
                          ids=lambda d: next(iter(d)))
-@pytest.mark.parametrize("model_type", ["afmoe", "granitemoehybrid"])
+@pytest.mark.parametrize("model_type", ["afmoe", "granitemoehybrid",
+                                        "lfm2_moe"])
 def test_a_linear_key_is_refused_of_the_window_band_family_too(model_type,
                                                                stated):
     (key, value), = stated.items()

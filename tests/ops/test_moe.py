@@ -117,6 +117,32 @@ def test_sigmoid_rule_chooses_by_bias_and_weighs_without_it():
                                rtol=1e-6)
 
 
+@pytest.mark.parametrize("eps", [None, 1e-6, 0.5])
+def test_the_biased_rules_normalise_over_the_sum_and_their_own_epsilon(eps):
+    """`eps` under the chosen scores' sum is the family's own: DeepSeek-V3's
+    1e-20 where nothing is said (the four families on the rule do not
+    move), 1e-6 in models/lfm2_moe.py; 0.5 shows that it is read."""
+    from llmlb_tpu.ops.moe import softmax_bias_routing
+
+    logits = jnp.asarray([[2.0, -1.0, 0.5, 1.0]], jnp.float32)
+    bias = jnp.asarray([0.0, 0.0, 0.1, 0.0], jnp.float32)
+    kw = {} if eps is None else {"eps": eps}
+    w, idx, _ = sigmoid_bias_routing(logits, bias, 2, scale=2.0, **kw)
+    s = np.asarray(jax.nn.sigmoid(logits))[0]
+    picked = s[np.asarray(idx)[0]]
+    want = 2.0 * picked / (picked.sum() + (1e-20 if eps is None else eps))
+    np.testing.assert_allclose(np.asarray(w)[0], want, rtol=1e-6)
+    if eps == 0.5:
+        assert np.asarray(w).sum() < 2.0 * 0.8
+    # the softmax rule shares the choice and keeps the default
+    w_soft, _, _ = softmax_bias_routing(logits, bias, 2, normalize=True)
+    np.testing.assert_allclose(np.asarray(w_soft).sum(), 1.0, rtol=1e-6)
+    # said or not, 1e-20 traces the same program
+    assert str(jax.make_jaxpr(lambda l: sigmoid_bias_routing(l, bias, 2))(
+        logits)) == str(jax.make_jaxpr(lambda l: sigmoid_bias_routing(
+            l, bias, 2, eps=1e-20))(logits))
+
+
 def test_routed_int8_scales_apply_per_row_expert():
     s, m, f, e = 16, 8, 12, 4
     x, logits, wg, wu, wd, _ = _rand_moe(jax.random.PRNGKey(3), s, m, f, e)

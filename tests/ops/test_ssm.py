@@ -123,6 +123,57 @@ def test_the_convolution_carries_the_rows_that_end_at_the_length():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("lens", [[9, 5], [1, 2], [3, 9]])
+def test_a_convolution_without_bias_or_activation_is_the_bare_sum(lens):
+    """What a gated short convolution asks of `causal_conv` (models/
+    lfm2_moe.py): three taps, `b` None, `act` None — the taps' sum and
+    nothing else, the two carried rows those that end at the length, at
+    lengths under the taps too (a row of the zeros in front is then still
+    carried)."""
+    r = np.random.default_rng(4)
+    x = jnp.asarray(r.normal(size=(2, 9, 6)), jnp.float32)
+    prev = jnp.asarray(r.normal(size=(2, 2, 6)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(6, 3)), jnp.float32)
+    out, carry = ssm.causal_conv(x, prev, w, None, jnp.asarray(lens),
+                                 act=None)
+    padded = np.concatenate([np.asarray(prev), np.asarray(x)], axis=1)
+    want = sum(padded[:, j:j + 9] * np.asarray(w)[:, j] for j in range(3))
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
+    for row, n in enumerate(lens):
+        assert (np.asarray(carry[row]) == padded[row, n:n + 2]).all()
+    # each half of what is optional alone
+    biased, _ = ssm.causal_conv(x, prev, w, jnp.ones((6,)), jnp.asarray(lens),
+                                act=None)
+    np.testing.assert_allclose(np.asarray(biased), want + 1, rtol=1e-5,
+                               atol=1e-5)
+    acted, _ = ssm.causal_conv(x, prev, w, None, jnp.asarray(lens))
+    np.testing.assert_allclose(np.asarray(acted),
+                               np.asarray(jax.nn.silu(want)), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_a_mamba_callers_convolution_traces_what_it_did_before_pr_59():
+    """`causal_conv` as it stood while its bias and its silu were not
+    optional, written out: a caller that passes both positionally and says
+    nothing of `act` (models/nemotron_h.ssm_mixer, models/olmo_hybrid.py)
+    gets the same jaxpr, equation for equation."""
+    def as_it_was(x, prev, w, b, lens):
+        width = w.shape[-1]
+        t = x.shape[1]
+        padded = jnp.concatenate([prev.astype(x.dtype), x], axis=1)
+        out = sum(padded[:, j:j + t].astype(ssm.F32) * w[:, j].astype(ssm.F32)
+                  for j in range(width)) + b.astype(ssm.F32)
+        at = lens[:, None] + jnp.arange(width - 1, dtype=lens.dtype)[None, :]
+        carry = jnp.take_along_axis(padded, at[:, :, None], axis=1)
+        return jax.nn.silu(out).astype(x.dtype), carry
+
+    args = (jnp.zeros((2, 9, 6), jnp.bfloat16), jnp.zeros((2, 3, 6)),
+            jnp.zeros((6, 4), jnp.bfloat16), jnp.zeros((6,), jnp.bfloat16),
+            jnp.asarray([9, 5], jnp.int32))
+    assert str(jax.make_jaxpr(ssm.causal_conv)(*args)) == str(
+        jax.make_jaxpr(as_it_was)(*args))
+
+
 # --- the step kernel at every grouping: ONE group for all the heads
 # (Granite-4.0-H, PR 55), a group a head, and between (PR 56) ----------------
 

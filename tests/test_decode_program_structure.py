@@ -837,6 +837,49 @@ def test_a_pool_at_heads_of_64_is_held_two_heads_to_a_row_and_copied_nowhere(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def test_a_packed_pool_that_rotates_and_two_carried_rows_are_copied_nowhere(
+        one_chip, monkeypatch):
+    """LFM2-24B-A2B's widths (8 rotated KV heads of 64, gated short
+    convolutions over 2,048 channels, 64 experts of 1,536 held whole) at a
+    cut of three layers, compiled for a v5e: the page pool is `[A, P, 128,
+    4, 128]`, two heads to a 128-lane row, and the compiler neither re-lays
+    it out nor copies it; the stacked experts are read where they lie by
+    the grouped products at their new shape, `[2048, 1536]` a matrix; the
+    carried rows `[C, slots, 2, 2048]` (8 KB a slot and layer) cost no
+    temporary of any size."""
+    from llmlb_tpu.models import lfm2_moe
+
+    cfg = lfm2_moe.Lfm2MoeConfig(
+        vocab_size=65536, hidden_size=2048, intermediate_size=11776,
+        num_layers=3, num_heads=32, num_kv_heads=8, rope_theta=1e6,
+        tie_word_embeddings=True, layer_types=("conv", "full_attention",
+                                               "conv"),
+        num_dense_layers=1, num_experts=64, experts_per_token=4,
+        moe_intermediate_size=1536)
+    assert cfg.pool_pack == 2 and cfg.num_moe_layers == 2
+    compiled = _compiled_burst(
+        one_chip, monkeypatch, lfm2_moe, cfg, pages=CHIP_PAGES,
+        rows=CHIP_ROWS, window=512, pool={"num_slots": CHIP_ROWS},
+        kernels=(pallas_attention.paged_flash_decode,
+                 pallas_moe.grouped_expert_matmul))
+    hlo = compiled.as_text()
+    # one attention and two mixtures of three grouped products a decode step
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1 + 2 * 3
+    pool = rf"bf16\[1,{CHIP_PAGES},(128,4|512),128\]"
+    experts = r"bf16\[(2,)?64,(2048,1536|1536,2048)\]"
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    moves_nothing = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    bad = [(shape, op) for shape, op in results
+           if (re.match(pool, shape) and op in ("copy", "transpose"))
+           or (re.match(experts, shape) and op not in moves_nothing)]
+    assert not bad, bad
+    assert "remat_compressed" not in hlo
+    # the head is the embedding table as it lies: no transposed copy of it
+    assert not [shape for shape, op in results
+                if shape.startswith("bf16[2048,65536]")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kv_heads,groups", [(8, 4), (2, 16), (32, 1)],
                          ids=["mistral-K8xG4", "nemotron-K2xG16", "MHA-32"])
