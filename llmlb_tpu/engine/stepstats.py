@@ -72,9 +72,15 @@ names of its own (``INFLIGHT_SPANS``), so that the names above keep meaning
                        was dispatched ahead (scheduler._admit_ahead)
   dispatch_inflight  — the call of the burst behind such a prefill and its
                        activation, which the device runs before it: the
-                       block tables with the new rows, the key split, the call
+                       block tables with the new rows, the key split, the
+                       call; and the call of a burst QUEUED BEHIND the one
+                       in flight, before the wait for that one (the key
+                       split, the call): it stands in the record of the
+                       burst it is queued behind
+  fetch_inflight     — what `fetch` does, with the fetched burst's successor
+                       queued behind it and on the device by now
 
-A decode burst runs in one of three orders. Today's: ``host_sync, dispatch,
+A decode burst runs in one of four orders. Today's: ``host_sync, dispatch,
 [host_sync_inflight,] compute, fetch, emit``. Dispatched ahead (it left
 right after its predecessor's fetch): ``dispatch, emit_inflight,
 [host_sync_inflight,] compute, fetch`` and, where the next burst does not
@@ -85,6 +91,13 @@ the prefill's record is ``dispatch, activate_inflight`` behind a stretch of
 ``admit``, and the burst's ``dispatch_inflight, emit_inflight,
 [host_sync_inflight,] compute, fetch[, emit]``; the three records — the
 predecessor's, the prefill's, the burst's — end and begin at one stamp each.
+Queued behind (it left BEFORE the wait for its predecessor, whose record
+holds its ``dispatch_inflight`` and ends in ``fetch_inflight``): the record
+begins at the predecessor's fetch, ``emit_inflight, host_sync_inflight,
+compute, fetch[, emit]``, and where the next burst is queued in its turn,
+``emit_inflight, host_sync_inflight, dispatch_inflight, compute,
+fetch_inflight``: a record with no span in which the device has nothing
+from this loop.
 
 The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
 since the previous record (``since_prev.admit_s``), ``emit`` still covers
@@ -129,11 +142,11 @@ PHASES = ("plan", "draft", "host_sync", "dispatch", "compute", "fetch",
 # The closed set of span names a step is cut into (StepSpan.mark).
 SPANS = ("draft", "host_sync", "dispatch", "compute", "fetch", "emit",
          "activate", "host_sync_inflight", "emit_inflight",
-         "activate_inflight", "dispatch_inflight")
+         "activate_inflight", "dispatch_inflight", "fetch_inflight")
 # Host work with a program of this loop on the device: `compute` in the
 # legacy phases.
 INFLIGHT_SPANS = ("host_sync_inflight", "emit_inflight", "activate_inflight",
-                  "dispatch_inflight")
+                  "dispatch_inflight", "fetch_inflight")
 # Where the loop's time goes between steps, and with "step" all of it.
 GAP_BUCKETS = ("admit", "control", "record", "idle", "other")
 LOOP_BUCKETS = ("step",) + GAP_BUCKETS

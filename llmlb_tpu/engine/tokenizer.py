@@ -92,7 +92,15 @@ class IncrementalDetokenizer:
         self._emitted = 0
 
     def push(self, token_id: int) -> str:
-        self._ids.append(token_id)
+        return self.extend((token_id,))
+
+    def extend(self, token_ids: Sequence[int]) -> str:
+        """The text that `token_ids` add, at ONE decode for all of them:
+        what pushing them one by one returns, joined. A decode walks the
+        whole sequence, so a caller that needs no text between two ids
+        (service.Engine.stream takes what one fetch brought a request)
+        pays it once an event and not once a token."""
+        self._ids.extend(token_ids)
         text = self._tok.decode(self._ids)
         # Hold back a trailing replacement char: likely a split multi-byte seq.
         safe_end = len(text)

@@ -8,10 +8,15 @@ Reads the `steps` of `last_run.json` (benchmark/run.py writes the window's
 stepstats records there) and prints one JSON object a file: the decode
 records' count (a dense burst's and, since PR 51, a block family's: both
 carry the field), the share dispatched ahead (`dispatched_ahead`,
-engine/scheduler.py `_decode_bursts`) and the reasons of the others, the mean
-milliseconds a decode record spends in each span — exposed (`host_sync`,
-`dispatch`, `fetch`, `emit`, and the gap before the record by bucket) against
-in flight (`host_sync_inflight`, `emit_inflight`, `dispatch_inflight`) — and
+engine/scheduler.py `_decode_bursts`), the share QUEUED BEHIND their
+predecessor before its fetch (`queued_behind`, since PR 60) and the reasons
+of the others, the mean milliseconds a decode record spends in each span —
+exposed (`host_sync`, `dispatch`, `fetch`, `emit`, and the gap before the
+record by bucket) against in flight (`host_sync_inflight`, `emit_inflight`,
+`dispatch_inflight`, `fetch_inflight`) — and what a queued record exposes
+against one that left ahead (`queued_ms`, `ahead_ms`: a queued burst's
+record begins at its predecessor's fetch and, where its successor is queued
+in its turn, exposes nothing), and
 the same for the prefill records; then the share of the one-shot prefill
 records dispatched ahead (`_admit_ahead`: the prefill left before the burst
 in front of it was emitted) and, for those and for the others apart, the
@@ -19,7 +24,8 @@ milliseconds a prefill record exposes (its spans other than `compute` and
 the in-flight ones, and the gap before it) against those it spends in
 flight (`activate_inflight`; `compute` is the host waiting). A commit that
 has no `dispatched_ahead` on its decode records (before PR 39) reads as
-"ahead" 0 with no reasons; one that has none on its prefill records (before
+"ahead" 0 with no reasons; one that has no `queued_behind` (before PR 60)
+reads "queued" 0; one that has none on its prefill records (before
 PR 49; a chunk's record never has one) reads `prefill_ahead_share_pct` null.
 No jax, no chip: it reads a file.
 """
@@ -32,7 +38,7 @@ from collections import Counter
 
 
 INFLIGHT = ("host_sync_inflight", "emit_inflight", "activate_inflight",
-            "dispatch_inflight")
+            "dispatch_inflight", "fetch_inflight")
 
 
 def _exposed_ms(records: list[dict]) -> dict[str, float] | None:
@@ -76,6 +82,9 @@ def summarize(path: str) -> dict:
     prefill = [r for r in steps if r["kind"] == "prefill"]
     bursts = [r for r in decode if "dispatched_ahead" in r]
     ahead = [r for r in bursts if r["dispatched_ahead"]]
+    queued = [r for r in bursts if r.get("queued_behind")]
+    held = [r for r in bursts
+            if not r["dispatched_ahead"] and not r.get("queued_behind")]
     groups = [r for r in prefill if "dispatched_ahead" in r]
     groups_ahead = [r for r in groups if r["dispatched_ahead"]]
     out = {
@@ -86,8 +95,13 @@ def summarize(path: str) -> dict:
         "ahead": len(ahead),
         "ahead_share_pct": (round(100.0 * len(ahead) / len(bursts), 1)
                             if bursts else None),
-        "not_ahead": dict(Counter(r["ahead_blocked_by"] for r in bursts
-                                  if not r["dispatched_ahead"])),
+        "queued": len(queued),
+        "queued_share_pct": (round(100.0 * len(queued) / len(bursts), 1)
+                             if bursts else None),
+        "not_ahead": dict(Counter(r["ahead_blocked_by"] for r in held)),
+        "queued_ms": _exposed_ms(queued),
+        "ahead_ms": _exposed_ms(ahead),
+        "not_ahead_ms": _exposed_ms(held),
         "decode_wall_ms": round(1e3 * sum(r["wall_s"] for r in decode)
                                 / max(1, len(decode)), 3),
         "decode_mean_ms": _mean_ms(decode),
@@ -100,10 +114,11 @@ def summarize(path: str) -> dict:
         "prefill_not_ahead_ms": _exposed_ms(
             [r for r in prefill if not r.get("dispatched_ahead")]),
     }
+    if queued:
+        out["queued_mean_ms"] = _mean_ms(queued)
     if ahead:
         out["ahead_mean_ms"] = _mean_ms(ahead)
-        out["not_ahead_mean_ms"] = _mean_ms(
-            [r for r in bursts if not r["dispatched_ahead"]])
+        out["not_ahead_mean_ms"] = _mean_ms(held)
     return out
 
 

@@ -1,10 +1,11 @@
-"""A block family's burst leaves before its predecessor is emitted, and an
-arrival's prefill before that.
+"""A block family's burst leaves before its predecessor is emitted, an
+arrival's prefill before that, and with every slot held a burst leaves before
+its predecessor is even fetched.
 
-The step loop runs a decode cycle in one of three orders (docs/scheduling.md
-"The three orders of a decode cycle"; tests/engine/test_decode_overlap.py
+The step loop runs a decode cycle in one of four orders (docs/scheduling.md
+"The four orders of a decode cycle"; tests/engine/test_decode_overlap.py
 holds them for a dense burst). A family that generates by diffusion over
-blocks takes the same three, through the same loop
+blocks takes the same four, through the same loop
 (`EngineCore._decode_bursts`), with what a block burst needs that a dense one
 does not:
 
@@ -20,11 +21,12 @@ does not:
   first token pending.
 
 These tests hold what the reorder has to keep true: (a) the same tokens,
-finish reasons, usage and `commit` flight-recorder counts in all three
+finish reasons, usage and `commit` flight-recorder counts in all four
 orders; (b) an arrival seen while a burst is in flight is prefilled before
-any further burst; (c) what keeps today's order says so on the record; (d)
-the records tile the loop's time, the counters add up, and a record closed
-by `LoopClock.handover` carries the `block` counts of one closed by
+any further burst; (c) what keeps today's order, or keeps a burst from being
+queued behind the one in flight, says so on the record; (d) the records tile
+the loop's time, the counters add up, and a record closed by
+`LoopClock.handover` carries the `block` counts of one closed by
 `_record_step`.
 
 One tiny block engine's shapes a module, driven inline
@@ -189,7 +191,7 @@ def eos_of_the_first_row() -> tuple[int, int]:
 
 
 @pytest.mark.parametrize("case", list(CASES))
-def test_the_three_orders_give_the_same_streams_usage_and_commits(
+def test_every_order_gives_the_same_streams_usage_and_commits(
         case, eos_of_the_first_row):
     eos, ends_in = (eos_of_the_first_row if case.startswith("eos_")
                     else (NEVER, 0))
@@ -198,7 +200,8 @@ def test_the_three_orders_give_the_same_streams_usage_and_commits(
     today, run_today = runs["today"]
     # tokens, finish reason, the size of every content event (usage is the
     # prompt's length and the number of tokens) and the commits a burst
-    for name in ("ahead", "admission_ahead"):
+    for name in ("ahead", "admission_ahead", "queued_behind",
+                 "queued_bounded"):
         assert runs[name][0] == today, name
     for name, want in CASES[case].items():
         assert (len(today[name][0]), today[name][1]) == want, name
@@ -218,6 +221,35 @@ def test_the_three_orders_give_the_same_streams_usage_and_commits(
     _, run = runs["admission_ahead"]
     records = run.records()
     assert sum(r["dispatched_ahead"] for r in run.decode_records()) >= 3
+    assert not any(r["queued_behind"] for name in ORDERS
+                   if not name.startswith("queued_")
+                   for r in runs[name][1].decode_records())
+    _, run_queued = runs["queued_behind"]
+    queued = [r["queued_behind"] for r in run_queued.decode_records()]
+    if case == "eos_takes_the_slot":
+        # two rows on two slots: the burst behind the one that holds the EOS
+        # was on the device before that one was fetched, with the ended
+        # row's column, which went to nobody; the emit under it freed the
+        # slot, the next burst was NOT queued, and the arrival's prefill
+        # went in front of it
+        assert queued[1:ends_in + 1] == [True] * ends_in
+        assert not queued[ends_in + 1]
+        mine = run_queued.records()
+        at = [r["kind"] for r in mine].index("prefill", 1)
+        assert mine[at]["dispatched_ahead"]
+        assert list(mine[at]["request_ids"]) == ["0"]
+        assert mine[at + 1]["dispatched_ahead"]
+        assert mine[at + 2]["queued_behind"]  # both slots held again
+    elif case in ("max_tokens_inside_a_block", "eos_unemitted",
+                  "seeded_arrival"):
+        # a slot was free all along: the records are the run's above
+        assert not any(queued)
+        assert [(r["kind"], r["active_slots"], r["dispatched_ahead"])
+                for r in run_queued.records()] == [
+            (r["kind"], r["active_slots"], r["dispatched_ahead"])
+            for r in records]
+    _assert_totals_add_up(run_queued.core.metrics.summary(),
+                          run_queued.records())
     if "late" not in today:
         return
     assert "admission" in {r["ahead_blocked_by"]
@@ -288,16 +320,19 @@ def test_an_arrival_is_prefilled_before_any_further_burst():
     assert after["active_slots"] == 3  # the late row decodes at once
     assert after["t0_s"] == pytest.approx(prefill["t1_s"], abs=50e-6)
     assert records[at + 2]["dispatched_ahead"]  # and the order resumes
+    # a slot was free for the arrival all along: no burst was queued
+    assert not any(r.get("queued_behind") for r in records)
     assert len(collect_events(late, timeout=None)[0]) == 12
 
 
 # ------------------------------------------------ (c) today's order, and why
 
 
-def _blocked_run(case: str, **order):
+def _blocked_run(case: str, *, full_house: bool = False, **order):
     """One run of a case that keeps a cycle in today's order; returns the
-    outcomes and the run."""
-    kwargs: dict = {}
+    outcomes and the run. `full_house`: as many slots as rows, so that a
+    free slot is not what keeps a burst from being queued."""
+    kwargs: dict = {"num_slots": 2} if full_house else {}
     reqs = {"first": _request(8, 31, 24), "second": _seeded(8, 32, 24)}
     late = None
     if case == "pages":
@@ -308,6 +343,8 @@ def _blocked_run(case: str, **order):
         # fifth pass) and that emit frees its 4
         reqs["second"] = _seeded(8, 32, 4)
         kwargs = {"kv_pages": 11, "num_slots": 2, "slot_capacity": 64}
+    elif case == "free_slot":
+        kwargs = {"num_slots": 3}
     elif case == "first":
         # 12 tokens: no row is ever sure to outlive a burst that may
         # commit 16
@@ -384,6 +421,59 @@ def test_what_keeps_todays_order_says_so_on_the_record(case):
         "pages", "control", "first") else "admission"] >= 1
 
 
+@pytest.mark.parametrize("case", ["pages", "control", "first", "free_slot",
+                                  "none"])
+def test_what_keeps_a_block_burst_from_being_queued_says_so_on_the_record(
+        case):
+    """Every slot held (but in `free_slot`), the loop as it is: a free list
+    too short for the bound, a drain and a batch of which no row is sure to
+    outlive the burst in flight keep a burst from leaving before its
+    predecessor's fetch, and so does a free slot, behind which it leaves
+    ahead; with none of them (`none`) it is queued."""
+    full = case != "free_slot"
+    today, _ = _blocked_run(case, full_house=full, **ORDERS["today"])
+    outcome, run = _blocked_run(case, full_house=full,
+                                **ORDERS["queued_behind"])
+    assert outcome == today
+    records = run.decode_records()
+    assert records[0]["ahead_blocked_by"] == "first"
+    # one of the three fields says which order a cycle took
+    assert all((r["ahead_blocked_by"] is None)
+               == (r["dispatched_ahead"] or r["queued_behind"])
+               and not (r["dispatched_ahead"] and r["queued_behind"])
+               for r in records)
+    queued = [r["queued_behind"] for r in records]
+    blocked = [r["ahead_blocked_by"] for r in records]
+    if case == "pages":
+        # 2 and 3 held back by the free list, asked before the wait and
+        # again behind the fetch; the short row's emit left a slot free, so
+        # 4 left ahead and was not queued
+        assert blocked[:5] == ["first", "pages", "pages", None, "first"]
+        assert not any(queued) and records[3]["dispatched_ahead"]
+        assert run.core.page_pool.available() == 10  # nothing leaked
+    elif case == "control":
+        assert not any(queued) and set(blocked[1:]) == {"control"}
+    elif case == "first":
+        assert not any(queued) and set(blocked) == {"first"}
+    elif case == "free_slot":
+        assert not any(queued)
+        assert any(r["dispatched_ahead"] for r in records)
+    else:
+        # 24 tokens a row: bursts are queued while each row is sure to
+        # outlive the one in flight (it may commit 16); then none is offered
+        last = queued.index(True, 1)
+        while queued[last + 1]:
+            last += 1
+        assert queued[1] and blocked[last + 1] == "first"
+        assert not any(queued[last + 1:])
+        names = [[n for n, _a, _d in r["spans"]] for r in records]
+        assert names[0][-3:] == ["dispatch_inflight", "compute",
+                                 "fetch_inflight"]
+        assert names[last] == ["emit_inflight", "host_sync_inflight",
+                               "compute", "fetch", "emit"]
+    _assert_totals_add_up(run.core.metrics.summary(), run.records())
+
+
 # ------------------------------------------------ (d) the records tile
 
 
@@ -433,3 +523,74 @@ def test_records_tile_and_a_handover_keeps_the_block_counts():
             r[name] for r in decode)
     assert totals["decode_bursts_total"] == len(decode)
     assert totals["prefills_dispatched_ahead_total"] == 2
+
+
+def test_records_tile_with_block_bursts_queued_behind_the_one_in_flight():
+    """Two rows on two slots: every burst a row is sure to outlive is
+    queued behind the one in flight; the records tile, the totals add up,
+    and a record that ends in `fetch_inflight` carries the `block` counts
+    of the same burst in today's order."""
+    def two_rows(**order):
+        core = _core(num_slots=2)
+        run = Inline(core, **order)
+        core.pending.put(_request(8, 41, 56))
+        core.pending.put(_seeded(12, 42, 56))
+        run.run()
+        return run
+
+    run_today, run = (two_rows(**ORDERS["today"]),
+                      two_rows(**ORDERS["queued_behind"]))
+    records = run.records()
+    _assert_records_tile(records)
+    _assert_totals_add_up(run.core.metrics.summary(), records)
+    decode, decode_today = run.decode_records(), run_today.decode_records()
+    queued = [r["queued_behind"] for r in decode]
+    assert sum(queued) >= 4 and not queued[0]
+    for r, nxt in zip(decode, decode[1:]):
+        names = [n for n, _a, _d in r["spans"]]
+        assert (names[-1] == "fetch_inflight") == nxt["queued_behind"]
+        assert ("dispatch_inflight" in names) == nxt["queued_behind"]
+        if r["queued_behind"]:
+            assert names[:2] == ["emit_inflight", "host_sync_inflight"]
+    keys = BLOCK_COUNTS + ("tokens", "experts_touched", "expert_assignments")
+    assert len(decode) == len(decode_today)
+    for mine, theirs in zip(decode, decode_today):
+        assert {k: mine[k] for k in keys} == {k: theirs[k] for k in keys}
+    assert run.core._in_flight is None
+
+
+def test_a_full_house_of_block_rows_queues_runs_of_bursts():
+    """Both slots held through a dozen block bursts: never more than
+    EngineCore.QUEUED_RUN of them in a row are queued; the burst behind a
+    whole run waits for its predecessor's fetch and leaves ahead, and the
+    run begins again. The outcomes are today's and the totals add up."""
+    def full_house(**order):
+        core = _core(num_slots=2)
+        run = Inline(core, **order)
+        reqs = {"first": _request(8, 61, 112), "second": _seeded(12, 62, 112)}
+        for r in reqs.values():
+            core.pending.put(r)
+        run.run()
+        return _outcome(core, reqs), run
+
+    today, _ = full_house(**ORDERS["today"])
+    outcome, run = full_house(**ORDERS["queued_bounded"])
+    assert outcome == today
+    decode = run.decode_records()
+    n, runs, length = EngineCore.QUEUED_RUN, [], 0
+    for i, r in enumerate(decode):
+        if r["queued_behind"]:
+            length += 1
+            continue
+        if length:
+            runs.append((length, i))
+        length = 0
+    assert runs and max(length for length, _ in runs) == n
+    # (the last run ends where a row does: that cycle is today's)
+    ahead = [decode[behind] for length, behind in runs[:-1] if length == n]
+    assert len(ahead) >= 2 and all(
+        r["dispatched_ahead"] and r["ahead_blocked_by"] is None
+        for r in ahead)
+    _assert_records_tile(run.records())
+    _assert_totals_add_up(run.core.metrics.summary(), run.records())
+    assert run.core._in_flight is None

@@ -1,29 +1,40 @@
-"""A dense decode burst leaves before its predecessor is emitted, and an
-arrival's prefill before that.
+"""A dense decode burst leaves before its predecessor is emitted, an
+arrival's prefill before that, and with every slot held a burst leaves before
+its predecessor is even fetched.
 
-The step loop runs a decode cycle in one of three orders (docs/scheduling.md).
+The step loop runs a decode cycle in one of four orders (docs/scheduling.md).
 Today's: host_sync, dispatch, compute, fetch, emit, record, back through the
 loop. Ahead: right after a burst's fetch the next one is dispatched, and the
 fetched tokens are delivered, the record closed and the burst after that
 prepared while it computes. Admission ahead: where the one thing in the way
 is an arrival that can be placed without that emit, its prefill, its
 activation and the next burst (the new row among its rows) are dispatched
-back to back right after the fetch. Which one a cycle takes is decided by
-what the loop observes in its own state (`EngineCore._ahead_blocker`,
-`EngineCore._arrivals_ahead`), never by a setting. These tests hold what the
+back to back right after the fetch. Queued behind: where nothing stands in
+the prepared burst's way and no slot is free for an arrival, it is dispatched
+BEFORE the wait for the burst in flight, and the device starts it the moment
+that one ends. Which one a cycle takes is decided by what the loop observes
+in its own state (`EngineCore._ahead_blocker`, `EngineCore._arrivals_ahead`,
+`EngineCore._queues_behind`), never by a setting. These tests hold what the
 reorder has to keep true:
 
 (a) the same requests give the same streams, finish reasons and usage in all
-    three orders — rows ending by max_tokens, by EOS inside a burst with a new
+    four orders — rows ending by max_tokens, by EOS inside a burst with a new
     request taking the slot at once, and a cancel among them; an arrival, two
     arrivals, an arrival with an EOS in the un-emitted burst, one cancelled
-    before its first token, one that ends inside the burst behind its prefill;
+    before its first token, one that ends inside the burst behind its
+    prefill, and an EOS inside a burst whose successor is queued already;
 (b) a request that arrives while a burst is in flight is prefilled before
     any further burst is dispatched;
 (c) a grammar, a drafter, a drain and a free list too short each keep the
     cycle in today's order, and say so on the record; so does an arrival that
     needs an eviction, a chunked prefill, a grammar or a slot nobody has yet;
-(d) the step records still tile the loop's time, and the counters add up.
+    the same four and a free slot keep a burst from being queued;
+(d) the step records still tile the loop's time, and the counters add up;
+(e) a run of queued bursts is bounded (`EngineCore.QUEUED_RUN`, for the trace
+    reader: docs/scheduling.md): behind a whole run the next burst leaves
+    ahead, and the streams are the same. The order's own tests, (a) to (d),
+    run with the bound lifted (`ORDERS["queued_behind"]`); `queued_bounded`
+    is the loop as it is.
 
 Most engines here are driven inline (`tests.support.InlineLoop`:
 `pending.put`, then the loop's own iteration on the test's thread, with
@@ -49,6 +60,7 @@ from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.engine.stepstats import INFLIGHT_SPANS, LOOP_BUCKETS
 from llmlb_tpu.engine.tokenizer import ByteTokenizer
 from llmlb_tpu.structured import ConstraintCompiler
+from tests.support import RUN_LIFTED
 from tests.support import InlineLoop as Inline
 from tests.support import collect, collect_events
 
@@ -83,10 +95,25 @@ def _seeded(j: int, max_tokens: int, seed: int) -> Request:
 
 NEVER = CFG.vocab_size + 7  # an EOS id no row samples
 
-# InlineLoop's arguments for the three orders of a decode cycle
+# InlineLoop's arguments for the four orders of a decode cycle
 ORDERS = {"today": {"todays_order": True},
-          "ahead": {"admission_ahead": False},  # PR 39's
-          "admission_ahead": {}}
+          # PR 39's
+          "ahead": {"admission_ahead": False, "queued_behind": False},
+          "admission_ahead": {"queued_behind": False},  # PR 49's
+          # the fourth, a run of queued bursts as long as a full house
+          # lasts: EngineCore.QUEUED_RUN bounds it for the trace reader,
+          # not for the order
+          "queued_behind": {"queued_run": RUN_LIFTED},
+          "queued_bounded": {}}  # the loop as it is
+
+
+def _longest_run(flags: list[bool]) -> int:
+    """The most true flags in a row."""
+    longest = run = 0
+    for flag in flags:
+        run = run + 1 if flag else 0
+        longest = max(longest, run)
+    return longest
 
 
 def _scenario(eos: int, ends_at: int, **order):
@@ -136,9 +163,17 @@ def test_both_orders_give_the_same_streams_reasons_and_usage(
     today, run_today = _scenario(eos, ends_at, **ORDERS["today"])
     held, run_held = _scenario(eos, ends_at, **ORDERS["ahead"])
     ahead, run = _scenario(eos, ends_at, **ORDERS["admission_ahead"])
+    queued, run_queued = _scenario(eos, ends_at, **ORDERS["queued_behind"])
     # tokens, finish reason and the size of every content event (usage is
     # the prompt's length and the number of tokens)
-    assert ahead == today and held == today
+    assert ahead == today and held == today and queued == today
+    # and with the run of queued bursts bounded, as the engine bounds it
+    bounded, run_bounded = _scenario(eos, ends_at, **ORDERS["queued_bounded"])
+    assert bounded == today
+    queued_bounded = [r["queued_behind"]
+                      for r in run_bounded.decode_records()]
+    assert any(queued_bounded)
+    assert _longest_run(queued_bounded) <= EngineCore.QUEUED_RUN
     assert {name: (len(t), finish)
             for name, (t, finish, _s) in ahead.items()} == {
         "long": (30, "length"), "seeded": (22, "length"),
@@ -150,6 +185,26 @@ def test_both_orders_give_the_same_streams_reasons_and_usage(
     assert not any(r["dispatched_ahead"] for r in records_today)
     assert sum(r["dispatched_ahead"] for r in records_held) >= 4
     assert sum(r["dispatched_ahead"] for r in records) >= 6
+    assert not any(r["queued_behind"]
+                   for r in records + records_held + records_today)
+    # with the four rows' slots held, bursts 2 and 3 were queued behind their
+    # predecessors — 3 with the cancelled row's column, which went to nobody
+    # — until the emit of burst 2 saw the cancel and freed a slot: from
+    # there on a free slot kept the cycle in the order of the run above,
+    # record for record
+    records_queued = run_queued.decode_records()
+    assert [r["queued_behind"] for r in records_queued[:4]] == [
+        False, True, True, False]
+    assert [(r["dispatched_ahead"], r["ahead_blocked_by"], r["active_slots"])
+            for r in records_queued[3:]] == [
+        (r["dispatched_ahead"], r["ahead_blocked_by"], r["active_slots"])
+        for r in records[3:]]
+    assert [r["active_slots"] for r in records_queued[:3]] == [
+        r["active_slots"] for r in records[:3]]
+    assert [r["dispatched_ahead"] for r in run_queued.records("prefill")] == [
+        False, True, False]
+    _assert_totals_add_up(run_queued.core.metrics.summary(),
+                          run_queued.records())
     # ... and the rows that ended unseen by the dispatch were in a burst that
     # had left already: the cancelled one in burst 3, the one that met its
     # EOS in the burst after. Their columns went to nobody: the request that
@@ -183,7 +238,8 @@ def test_both_orders_give_the_same_streams_reasons_and_usage(
 def _arrival_case(case: str, eos: int, **order):
     """One run of a case of (a): two rows decoding, and what arrives while a
     burst is in flight. Returns every request's events and the run."""
-    core = _core(eos_id=eos, num_slots=3 if case == "eos_unemitted" else 4)
+    core = _core(eos_id=eos, num_slots={
+        "eos_unemitted": 3, "eos_with_its_successor_queued": 2}.get(case, 4))
     run = Inline(core, **order)
     reqs = {"first": _greedy(0, 40), "second": _seeded(1, 40, seed=21)}
     for r in reqs.values():
@@ -197,7 +253,10 @@ def _arrival_case(case: str, eos: int, **order):
     arrive = [lambda r=r: core.pending.put(r)
               for name, r in reqs.items() if name.startswith("late")]
     # "eos_unemitted": `first` meets its EOS in burst 2, and the arrival comes
-    # while that burst is in flight
+    # while that burst is in flight; "eos_with_its_successor_queued": the
+    # two rows hold both slots, so burst 3 is on the device before burst 2,
+    # which holds the EOS, is fetched, and the arrival comes while 3 is in
+    # flight, the slot freed by the emit under it
     run.during[2 if case == "eos_unemitted" else 3] = arrive
     if case == "cancelled_before_its_first_token":
         # placed behind burst 3's fetch; its first token comes with burst 4's
@@ -222,14 +281,16 @@ def eos_of_the_first_row() -> int:
 
 @pytest.mark.parametrize("case", [
     "an_arrival", "two_arrivals", "eos_unemitted",
-    "cancelled_before_its_first_token", "max_tokens_in_the_burst_behind"])
-def test_an_arrival_gets_the_same_stream_in_the_three_orders(
+    "cancelled_before_its_first_token", "max_tokens_in_the_burst_behind",
+    "eos_with_its_successor_queued"])
+def test_an_arrival_gets_the_same_stream_in_every_order(
         case, eos_of_the_first_row):
-    eos = eos_of_the_first_row if case == "eos_unemitted" else NEVER
+    eos = eos_of_the_first_row if case.startswith("eos_") else NEVER
     runs = {name: _arrival_case(case, eos, **order)
             for name, order in ORDERS.items()}
     today, run_today = runs["today"]
-    for name in ("ahead", "admission_ahead"):
+    for name in ("ahead", "admission_ahead", "queued_behind",
+                 "queued_bounded"):
         assert runs[name][0] == today, name
     late = today["late"]
     assert (len(late[0]), late[1]) == {
@@ -252,10 +313,45 @@ def test_an_arrival_gets_the_same_stream_in_the_three_orders(
     assert behind["kind"] == before["kind"] == "decode"
     new_rows = 2 if case == "two_arrivals" else 1
     assert prefill["active_slots"] == new_rows
-    # the burst behind the prefill holds the new rows beside the old
-    assert behind["active_slots"] == before["active_slots"] + new_rows
     assert "admission" not in {r["ahead_blocked_by"]
                                for r in run.decode_records()}
+    _, run_queued = runs["queued_behind"]
+    order = [(r["kind"], r["active_slots"], r["dispatched_ahead"],
+              r.get("queued_behind")) for r in run_queued.records()]
+    if case == "eos_with_its_successor_queued":
+        # both slots held: bursts 2 and 3 left before their predecessors'
+        # fetch, 3 with the column of the row that met its EOS in 2 — it
+        # went to nobody. The emit of 2, under 3, freed the slot, so burst 4
+        # was not queued: the arrival took the slot behind 3's fetch and its
+        # prefill went in front of burst 4, which left right behind it
+        assert today["first"][1] == "stop"
+        assert order[:7] == [
+            ("prefill", 2, False, None), ("decode", 2, False, False),
+            ("decode", 2, False, True), ("decode", 2, False, True),
+            ("prefill", 1, True, None), ("decode", 2, True, False),
+            # ... and with both slots held again the order resumes
+            ("decode", 2, False, True)]
+        assert list(run_queued.records()[4]["request_ids"]) == ["0"]
+        # before this order the slot changed hands the same way, one
+        # dispatch later each time
+        assert behind["active_slots"] == before["active_slots"] == 2
+        assert list(prefill["request_ids"]) == ["0"]
+        return
+    # while a slot was free no burst was queued, and the cycle took the
+    # order of the run above, record for record
+    same = at + 2 if case == "two_arrivals" else len(records)
+    assert order[:same] == [
+        (r["kind"], r["active_slots"], r["dispatched_ahead"],
+         r.get("queued_behind")) for r in records[:same]]
+    if case == "two_arrivals":
+        # the two took the last two slots: from the burst behind their
+        # prefill on, bursts were queued
+        assert order[same] == ("decode", 4, False, True)
+    else:
+        assert not any(r["queued_behind"]
+                       for r in run_queued.decode_records())
+    # the burst behind the prefill holds the new rows beside the old
+    assert behind["active_slots"] == before["active_slots"] + new_rows
     if case == "eos_unemitted":
         # the row that met its EOS in the un-emitted burst held slot 0 until
         # that burst's emit: the arrival placed ahead of it took slot 2, the
@@ -281,6 +377,8 @@ def test_a_started_engine_serves_the_same_usage_in_both_orders(
             core._ahead_blocker = lambda plan: "control"
         if order == "ahead":
             core._arrivals_ahead = lambda plan, k: None
+        if order != "queued_behind":
+            core._queues_behind = lambda plan: False
         core.start()
         engine = Engine("debug-tiny", core, TOK)
 
@@ -307,7 +405,14 @@ def test_a_started_engine_serves_the_same_usage_in_both_orders(
     today, totals_today = asyncio.run(serve("today"))
     held, totals_held = asyncio.run(serve("ahead"))
     ahead, totals = asyncio.run(serve("admission_ahead"))
-    assert ahead == today and held == today
+    queued, totals_queued = asyncio.run(serve("queued_behind"))
+    assert ahead == today and held == today and queued == today
+    for t in (totals_today, totals_held, totals):
+        assert t["decode_bursts_queued_behind_total"] == 0
+    assert (totals_queued["decode_bursts_queued_behind_total"]
+            + totals_queued["decode_bursts_dispatched_ahead_total"]
+            + sum(totals_queued["decode_bursts_not_ahead_total"].values())
+            == totals_queued["decode_bursts_total"])
     assert totals_today["decode_bursts_dispatched_ahead_total"] == 0
     assert totals_held["decode_bursts_dispatched_ahead_total"] > 0
     assert totals["decode_bursts_dispatched_ahead_total"] > 0
@@ -336,6 +441,9 @@ def test_an_arrival_is_prefilled_before_any_further_burst():
                               "prefill", "decode"]
     assert [r.get("dispatched_ahead") for r in records[:at]] == [
         False, False, True, True]
+    # a slot was free for the arrival all along, so no burst was on the
+    # device before its predecessor was fetched
+    assert not any(r.get("queued_behind") for r in records)
     # no burst that was not in flight when the arrival was seen runs in
     # front of its prefill: the prefill left right after burst 3's fetch,
     # before that burst was emitted, and the burst behind it at once
@@ -358,9 +466,10 @@ SCHEMA = {"type": "object", "properties": {"ok": {"type": "boolean"}},
           "required": ["ok"]}
 
 
-def _blocked_run(case: str, *, todays_order: bool):
+def _blocked_run(case: str, *, full_house: bool = False, **order):
     """One run of a case that keeps the cycle in today's order; returns the
-    streams and the run."""
+    streams and the run. `full_house`: as many slots as rows, so that a free
+    slot is not what keeps a burst from being queued."""
     kwargs, requests, drain_during = {}, [], None
     if case == "constraint":
         requests = [
@@ -392,10 +501,15 @@ def _blocked_run(case: str, *, todays_order: bool):
                 temperature=0.8, seed=11, max_tokens=9))]
         kwargs = {"slot_capacity": 32, "kv_page_size": 4, "kv_pages": 9,
                   "prefill_buckets": (16,), "num_slots": 2}
+    elif case == "free_slot":
+        requests = [_greedy(0, 20), _seeded(1, 20, seed=3)]
+        kwargs = {"num_slots": 3}
+    if full_house:
+        kwargs["num_slots"] = len(requests)
     core = _core(**kwargs)
     if case == "constraint":
         core.constraint_compiler = ConstraintCompiler(TOK, CFG.vocab_size)
-    run = Inline(core, todays_order=todays_order)
+    run = Inline(core, **order)
     for r in requests:
         core.pending.put(r)
     if drain_during:
@@ -406,8 +520,8 @@ def _blocked_run(case: str, *, todays_order: bool):
 
 @pytest.mark.parametrize("case", ["constraint", "draft", "control", "pages"])
 def test_what_needs_the_host_between_two_bursts_keeps_todays_order(case):
-    today, _ = _blocked_run(case, todays_order=True)
-    streams, run = _blocked_run(case, todays_order=False)
+    today, _ = _blocked_run(case, **ORDERS["today"])
+    streams, run = _blocked_run(case, **ORDERS["admission_ahead"])
     assert streams == today
     assert all(finish in ("length", "stop") for _t, finish in streams)
     records = run.decode_records()
@@ -431,6 +545,54 @@ def test_what_needs_the_host_between_two_bursts_keeps_todays_order(case):
     assert totals["decode_bursts_total"] == len(records)
     assert totals["decode_bursts_not_ahead_total"][case] == sum(
         r["ahead_blocked_by"] == case for r in records)
+
+
+@pytest.mark.parametrize("case", ["constraint", "draft", "control", "pages",
+                                  "free_slot"])
+def test_what_keeps_a_burst_from_being_queued_says_so_on_the_record(case):
+    """Every slot held (but in `free_slot`), the loop as it is: what needs
+    the host between two bursts keeps a burst from leaving before its
+    predecessor's fetch as it keeps it from leaving before the emit, and so
+    does a free slot, behind which the burst leaves ahead."""
+    full = case != "free_slot"
+    today, _ = _blocked_run(case, full_house=full, **ORDERS["today"])
+    streams, run = _blocked_run(case, full_house=full,
+                                **ORDERS["queued_behind"])
+    assert streams == today
+    records = run.decode_records()
+    assert len(records) >= 3 and records[0]["ahead_blocked_by"] == "first"
+    # one of the three fields says which order a cycle took
+    assert all((r["ahead_blocked_by"] is None)
+               == (r["dispatched_ahead"] or r["queued_behind"])
+               and not (r["dispatched_ahead"] and r["queued_behind"])
+               for r in records)
+    queued = [r["queued_behind"] for r in records]
+    spans = [[n for n, _a, _d in r["spans"]] for r in records]
+    if case == "pages":
+        # burst 2 queued behind 1 with both slots held; 3 held back by the
+        # free list (asked before the wait for 2, and again behind its
+        # fetch), and the short row's emit left a slot free: 4 left ahead
+        assert [r["ahead_blocked_by"] for r in records] == [
+            "first", None, "pages", None]
+        assert queued == [False, True, False, False]
+        assert records[3]["dispatched_ahead"]
+        assert run.core.page_pool.available() == 8  # nothing leaked
+    elif case == "free_slot":
+        assert not any(queued)
+        assert all(r["dispatched_ahead"] for r in records[1:])
+    else:
+        # the drain began while burst 1 was in flight; a grammar's and a
+        # drafter's rows never leave the host out
+        assert not any(queued)
+        assert {r["ahead_blocked_by"] for r in records[1:]} == {case}
+    # a burst is queued under `dispatch_inflight` in its predecessor's
+    # record, which ends in `fetch_inflight`; no other record holds either
+    for i, names in enumerate(spans):
+        behind_it = i + 1 < len(records) and queued[i + 1]
+        assert ("dispatch_inflight" in names) == behind_it
+        assert ("fetch_inflight" in names) == behind_it
+        assert ("fetch" in names) != behind_it
+    _assert_totals_add_up(run.core.metrics.summary(), run.records())
 
 
 def _held_arrival_run(case: str, **order):
@@ -571,7 +733,10 @@ def _assert_totals_add_up(after: dict, records: list[dict]) -> None:
     assert after["decode_bursts_total"] == len(decode)
     assert after["decode_bursts_dispatched_ahead_total"] == sum(
         r["dispatched_ahead"] for r in decode)
+    assert after["decode_bursts_queued_behind_total"] == sum(
+        r["queued_behind"] for r in decode)
     assert (after["decode_bursts_dispatched_ahead_total"]
+            + after["decode_bursts_queued_behind_total"]
             + sum(after["decode_bursts_not_ahead_total"].values())
             == after["decode_bursts_total"])
     for reason, n in after["decode_bursts_not_ahead_total"].items():
@@ -599,6 +764,9 @@ def test_records_tile_the_loops_time_and_the_totals_add_up():
     records = core.step_stats.snapshot(limit=512)["records"][::-1]
     decode = [r for r in records if r["kind"] == "decode"]
     assert any(r["dispatched_ahead"] for r in decode)
+    # six callers on four slots: with every slot held and nobody waiting,
+    # bursts were queued behind the one in flight
+    assert any(r["queued_behind"] for r in decode)
     _assert_records_tile(records)
     # every second of the loop thread is in one bucket
     delta = {b: after["loop_seconds_total"]["main"][b]
@@ -611,6 +779,8 @@ def test_records_tile_the_loops_time_and_the_totals_add_up():
     text = metrics.render(queue_depth=0, active_slots=0, num_slots=4)
     assert (f"llmlb_engine_decode_bursts_total {len(decode)}\n") in text
     assert 'llmlb_engine_decode_bursts_not_ahead_total{reason="first"}' in text
+    assert ("llmlb_engine_decode_bursts_queued_behind_total "
+            f"{after['decode_bursts_queued_behind_total']}\n") in text
     assert "llmlb_engine_prefills_dispatched_ahead_total " in text
     assert "llmlb_engine_prefill_dispatches_total " in text
 
@@ -663,3 +833,98 @@ def test_records_tile_with_a_prefill_between_two_bursts_that_left_ahead():
     _assert_totals_add_up(core.metrics.summary(), records)
     # the prefill histogram has every group, dispatched ahead or not
     assert core.metrics.prefill_step.n == kinds.count("prefill")
+
+
+def test_records_tile_with_bursts_queued_behind_the_one_in_flight():
+    """Queued behind: a burst's record begins at its predecessor's fetch
+    and, where its successor is queued in its turn, holds no span in which
+    the device has nothing from the loop; the predecessor's record ends in
+    `fetch_inflight` behind the `dispatch_inflight` that queued it; nothing
+    is counted twice, and nothing is in flight when the loop comes back.
+    (The run's bound lifted: five in a row.)"""
+    core = _core(num_slots=2)
+    run = Inline(core, **ORDERS["queued_behind"])
+    clock = core._clock()  # the loop's clock: made before the first reading
+    core.pending.put(_greedy(0, 33))
+    core.pending.put(_seeded(1, 21, seed=2))
+    t0, before = time.perf_counter(), dict(clock.snapshot())
+    run.run()  # asserts that nothing is in flight behind every iteration
+    t1, after = time.perf_counter(), dict(clock.snapshot())
+    records = run.records()
+    _assert_records_tile(records)
+    decode = run.decode_records()
+    # 1 first token + 8 bursts of 4: bursts 2 to 6 queued while both rows
+    # hold their slots (the short one is counted to its end in burst 5 and
+    # is no row of 6, but holds its slot until 5 is emitted, under 6); from
+    # that emit on a slot is free and the long row's bursts leave ahead
+    assert [r["queued_behind"] for r in decode] == [
+        False, True, True, True, True, True, False, False]
+    assert [r["dispatched_ahead"] for r in decode] == [
+        False, False, False, False, False, False, True, True]
+    assert [r["active_slots"] for r in decode] == [2, 2, 2, 2, 2, 1, 1, 1]
+    names = [[n for n, _a, _d in r["spans"]] for r in decode]
+    assert names[0] == ["host_sync", "dispatch", "host_sync_inflight",
+                        "dispatch_inflight", "compute", "fetch_inflight"]
+    for queued_too in names[1:5]:
+        assert queued_too == ["emit_inflight", "host_sync_inflight",
+                              "dispatch_inflight", "compute",
+                              "fetch_inflight"]
+    # the last one queued: nothing behind it on the device at its fetch
+    assert names[5] == ["emit_inflight", "host_sync_inflight", "compute",
+                        "fetch"]
+    assert names[6][:2] == ["dispatch", "emit_inflight"]
+    for r in decode[1:6]:
+        # it begins at the stamp its predecessor ends at, and the host's
+        # share of the legacy phases is what the device was not covering
+        assert sum(r["since_prev"].values()) < 50e-6
+        assert r["phases_s"]["dispatch"] == r["phases_s"]["emit"] == 0.0
+        assert r["phases_s"]["host_sync"] == 0.0
+    for r in decode[1:5]:
+        assert r["phases_s"]["fetch"] == 0.0
+        assert r["phases_s"]["compute"] == pytest.approx(r["wall_s"],
+                                                         abs=5e-6)
+    assert decode[5]["phases_s"]["fetch"] > 0.0
+    delta = {b: after[b] - before[b] for b in LOOP_BUCKETS}
+    assert sum(delta.values()) == pytest.approx(t1 - t0, rel=0.02)
+    assert delta["step"] == pytest.approx(
+        sum(r["wall_s"] for r in records), rel=0.02)
+    _assert_totals_add_up(core.metrics.summary(), records)
+    assert core._in_flight is None
+
+
+def test_a_full_house_queues_a_run_of_bursts_then_one_leaves_ahead():
+    """Both slots held through 18 bursts: QUEUED_RUN bursts in a row are
+    queued behind their predecessors, the next waits for its predecessor's
+    fetch and leaves ahead (one gap on the device, for the benchmark's trace
+    reader: EngineCore.QUEUED_RUN), and the run begins again. The streams
+    are today's, the records tile, and the counters add up."""
+    def full_house(**order):
+        core = _core(num_slots=2)
+        run = Inline(core, **order)
+        reqs = [_greedy(0, 70), _seeded(1, 70, seed=5)]
+        for r in reqs:
+            core.pending.put(r)
+        run.run()
+        return [collect_events(r, timeout=None) for r in reqs], run
+
+    today, _ = full_house(**ORDERS["today"])
+    streams, run = full_house(**ORDERS["queued_bounded"])
+    assert streams == today
+    decode = run.decode_records()
+    n = EngineCore.QUEUED_RUN
+    # 1 first token + 17 whole bursts of 4 + 1 of the last token: both rows
+    # end in burst 18, which is no burst of a run cut short
+    queued = [r["queued_behind"] for r in decode]
+    assert len(queued) == 18 and queued == [
+        i % (n + 1) != 0 for i in range(18)]
+    for i, r in enumerate(decode[1:], 1):
+        # the burst behind a whole run left ahead, blocked by nothing
+        assert r["dispatched_ahead"] == (i % (n + 1) == 0)
+        assert r["ahead_blocked_by"] is None
+    # the burst a run ends behind is fetched with nothing queued behind it
+    for i in range(n, 18 - 1, n + 1):
+        assert [name for name, _a, _d in decode[i]["spans"]][-2:] == [
+            "compute", "fetch"]
+    _assert_records_tile(run.records())
+    _assert_totals_add_up(run.core.metrics.summary(), run.records())
+    assert run.core._in_flight is None

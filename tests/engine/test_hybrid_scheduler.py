@@ -20,7 +20,7 @@ from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
 from llmlb_tpu.engine.service import Engine
 from llmlb_tpu.models import nemotron_h
 from tests.engine.test_hybrid_family import HF
-from tests.support import InlineLoop, collect_events
+from tests.support import RUN_LIFTED, InlineLoop, collect_events
 
 CFG = get_preset("debug-nemotron-h-tiny")
 PARAMS = nemotron_h.init_params(CFG, jax.random.PRNGKey(0))
@@ -143,14 +143,15 @@ def test_a_finished_rows_state_is_rewritten_by_the_next_activation():
     def serve(eos):
         core = EngineCore(CFG, PARAMS, **{**ARGS, "eos_id": eos,
                                           "num_slots": 2})
-        loop = InlineLoop(core)
+        loop = InlineLoop(core, queued_run=RUN_LIFTED)  # 2 and 3 queue
         requests = [Request(prompt_ids=p, sampling=SamplingParams(
             temperature=0.0, max_tokens=n))
             for p, n in ((ending, 30), (beside, 30), (taking, 10))]
         core.pending.put(requests[0])
         core.pending.put(requests[1])
-        # the EOS is in burst 2; burst 3 left ahead with the row in it; the
-        # arrival comes while 3 is in flight
+        # the EOS is in burst 2; burst 3 left with the row in it before 2
+        # was even fetched (both slots held: queued behind it); the arrival
+        # comes while 3 is in flight
         loop.during[3] = [lambda: core.pending.put(requests[2])]
         loop.run()
         return [collect_events(r, None) for r in requests], loop
@@ -163,7 +164,7 @@ def test_a_finished_rows_state_is_rewritten_by_the_next_activation():
         probe[at])
     assert reason == "stop" and first == probe[:at]
     records = loop.decode_records()
-    assert records[2]["dispatched_ahead"]
+    assert records[2]["queued_behind"]
     assert records[2]["active_slots"] == 2  # the ended row among them
     assert "0" in records[3]["request_ids"]  # its slot, taken at once
     assert len(second) == 30 and len(third) == 10

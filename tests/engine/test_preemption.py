@@ -182,12 +182,12 @@ def test_a_park_waits_for_the_burst_in_flight_and_resumes_identical(
     its pages to the host: both lag or are still written while a burst of
     that row is in flight. Whatever asks for a park while one is — a more
     important arrival on full slots, `request_park` — keeps the NEXT burst
-    from leaving ahead (`admission`, `control`), so the park runs after the
+    from leaving ahead or queued (`admission`, `control`), so the park runs after the
     burst's emit with nothing in flight (`_park_slot` asserts it), the
     spilled pages restore without a prefill, and the stream is the
     uninterrupted one's (docs/kv-cache.md "A burst in flight and pages
     already released")."""
-    from tests.support import InlineLoop, collect
+    from tests.support import RUN_LIFTED, InlineLoop, collect
 
     monkeypatch.setenv("LLMLB_KV_OFFLOAD_BYTES", str(1 << 26))
 
@@ -196,7 +196,7 @@ def test_a_park_waits_for_the_burst_in_flight_and_resumes_identical(
                           slot_capacity=128, prefill_buckets=(16, 32),
                           kv_page_size=16, seed=0, decode_burst=4,
                           prefix_cache=False)
-        loop = InlineLoop(core)
+        loop = InlineLoop(core, queued_run=RUN_LIFTED)  # 2 and 3 queue
         victim = Request(prompt_ids=[9, 8, 7, 6, 5], request_id="victim",
                          sampling=SamplingParams(temperature=0.8, seed=21,
                                                  max_tokens=40, priority=2))
@@ -228,11 +228,15 @@ def test_a_park_waits_for_the_burst_in_flight_and_resumes_identical(
     assert nobody == [] and streams == plain
     assert [len(t) for t, _f in streams] == [40, 40]
     # one park, of the least important row with the fewest tokens, with no
-    # burst in flight, after bursts 2 and 3 had left ahead
+    # burst in flight, after bursts 2 and 3 had left before their
+    # predecessors were fetched (both slots held: queued behind them); what
+    # asked for the park was seen before burst 3's wait, so no burst was
+    # queued behind 3
     assert len(parked_with) == 1 and parked_with[0][1] is None
     records = loop.decode_records()
-    assert [r["dispatched_ahead"] for r in records[:4]] == [
+    assert [r["queued_behind"] for r in records[:4]] == [
         False, True, True, False]
+    assert not any(r["dispatched_ahead"] for r in records[:4])
     assert records[3]["ahead_blocked_by"] == (
         "admission" if how == "priority" else "control")
     # the spill was read with nothing in flight, and came back as bytes

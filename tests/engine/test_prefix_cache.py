@@ -20,7 +20,12 @@ import pytest
 from llmlb_tpu.engine.prefix_cache import PrefixCache
 from llmlb_tpu.engine.presets import get_preset
 from llmlb_tpu.engine.scheduler import EngineCore, Request, SamplingParams
-from tests.support import InlineLoop, assert_hit_is_zero_copy, collect
+from tests.support import (
+    RUN_LIFTED,
+    InlineLoop,
+    assert_hit_is_zero_copy,
+    collect,
+)
 
 # ----------------------------------------------------------------- radix tree
 
@@ -231,7 +236,8 @@ def test_a_donors_pages_are_whole_with_a_burst_in_flight(prompt, todays_order):
         core = make_core(16, num_slots=2, slot_capacity=96,
                          prefill_buckets=(16, 32, 64), seed=0, decode_burst=4,
                          eos_id=eos, prefix_cache=cache)
-        loop = InlineLoop(core, todays_order=todays_order)
+        loop = InlineLoop(core, todays_order=todays_order,
+                          queued_run=RUN_LIFTED)  # bursts 2 and 3 queue
         donor, beside, reader = (Request(
             prompt_ids=list(ids), sampling=SamplingParams(
                 temperature=0.0, max_tokens=n))
@@ -258,8 +264,10 @@ def test_a_donors_pages_are_whole_with_a_burst_in_flight(prompt, todays_order):
     assert m.prefix_insertions_total >= 1 and m.prefix_hits_total == 1
     assert m.prefix_cached_tokens_total == 32  # the reader's 40, aligned
     if not todays_order:
+        # both slots held: burst 3 left before burst 2, the donor's last,
+        # was even fetched (queued behind it), the donor's row in it
         third = loop.decode_records()[2]
-        assert third["dispatched_ahead"] and third["active_slots"] == 2
+        assert third["queued_behind"] and third["active_slots"] == 2
 
 
 def test_divergent_tail_still_hits_shared_head(prompt, kv_page):

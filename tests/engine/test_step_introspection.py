@@ -603,7 +603,9 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
             r["since_prev"]["admit_s"], abs=2e-6)
     by_seq = {r["seq"]: r for r in records}
     for r, nxt in zip(decode, decode[1:] + [None]):
-        # the three orders of a decode cycle (docs/scheduling.md): today's,
+        # three of the four orders of a decode cycle (docs/scheduling.md;
+        # a slot is free all along here, so no burst is queued behind the
+        # one in flight: tests/engine/test_decode_overlap.py): today's,
         # the one of a burst that left before its predecessor was emitted,
         # and the one of a burst behind a prefill that did; a burst whose
         # successor left ahead has no `emit` of its own, the successor's
@@ -620,6 +622,7 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
         assert names == head + ["host_sync_inflight", "compute", "fetch"] + (
             [] if followed else ["emit"])
         assert (r["ahead_blocked_by"] is None) == r["dispatched_ahead"]
+        assert not r["queued_behind"]
         if followed:
             assert nxt["seq"] == r["seq"] + 1
             assert nxt["since_prev"]["record_s"] == 0.0

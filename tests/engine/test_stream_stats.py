@@ -196,6 +196,55 @@ async def test_a_dense_burst_is_one_event_and_one_frame_a_row(
     assert _conserved(snap) and d["events_unread_total"] == 0
 
 
+@pytest.mark.parametrize("stop", [None, ["never-in-the-text"]],
+                         ids=["no-stop", "a-stop-string"])
+async def test_an_event_is_decoded_once_not_once_a_token(burst_engine, stop,
+                                                         monkeypatch):
+    """A decode walks the whole answer: with no stop string to look for
+    between two tokens the service decodes ONCE an event (what one fetch
+    brought the row: 9, 8, 8, 8 tokens), and the text is what a decode a
+    token gives — which a stop string still gets."""
+    tokenizer = burst_engine.tokenizer
+    decodes = []
+    decode = tokenizer.decode
+    monkeypatch.setattr(tokenizer, "decode",
+                        lambda ids: decodes.append(len(ids)) or decode(ids))
+    texts, ids = [], []
+    async for delta in burst_engine.stream([5, 2, 3, 4], _sampling(33),
+                                           stop=stop):
+        texts.append(delta.text)
+        ids += delta.token_ids
+    assert len(ids) == 33 and "".join(texts) == decode(ids)
+    if stop is None:
+        # four content events and the flush at `done`
+        assert decodes == [9, 17, 25, 33, 33]
+    else:
+        assert decodes == list(range(1, 34)) + [33]
+
+
+@pytest.mark.parametrize("ids, sizes", [
+    (list(b"plain ascii"), [3, 8]),
+    # a two-byte and a three-byte character split across events: the head
+    # of a sequence is held back until its tail comes, in either way
+    (list("aé€b".encode()), [2, 1, 2, 1, 1]),
+    (list("aé€b".encode()), [7]),
+    (list("é".encode())[:1], [1]),  # never completed: flush's to emit
+])
+def test_extend_is_the_pushes_joined(ids, sizes):
+    from llmlb_tpu.engine.tokenizer import (ByteTokenizer,
+                                            IncrementalDetokenizer)
+
+    tokenizer = ByteTokenizer(512)
+    one, many = (IncrementalDetokenizer(tokenizer) for _ in range(2))
+    at = 0
+    for size in sizes:
+        event = ids[at:at + size]
+        at += size
+        assert many.extend(event) == "".join(one.push(t) for t in event)
+    assert at == len(ids)
+    assert many.flush() == one.flush()
+
+
 async def test_a_consumer_that_quits_mid_stream(engine):
     stats = engine.core.metrics.stream
     before = stats.snapshot()

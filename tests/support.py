@@ -104,22 +104,31 @@ def word_engine(decode_burst: int, **core_kwargs):
     return Engine("debug-tiny", core, tokenizer)
 
 
+# InlineLoop(queued_run=...): no bound on a run of queued bursts
+RUN_LIFTED = 1 << 30
+
+
 class InlineLoop:
     """An engine core's step loop on the test's thread, so that the steps
     and their order are the test's: `run` calls the loop's own iteration
     (`EngineCore._loop_once`) until one does no work, with `_running` set so
     that the loop's own state decides the order of a decode cycle, as in a
-    started engine (docs/scheduling.md "The three orders of a decode cycle").
+    started engine (docs/scheduling.md "The four orders of a decode cycle").
     `during[n]` is a list of calls made while the n-th burst is in
     flight — `_prepare_burst`, which every burst calls between its
     dispatch and the wait for it. `todays_order` patches the predicate to
     "not now": the parent's cycle, step for step. `admission_ahead=False`
     patches the other predicate to "no arrival can be placed ahead": a burst
     may leave before its predecessor's emit (PR 39's order), an arrival is
-    served behind that emit."""
+    served behind that emit. `queued_behind=False` patches the third to "no
+    burst leaves before its predecessor's fetch": PR 49's orders.
+    `queued_run` sets the core's `QUEUED_RUN`, the most bursts in a row that
+    may queue (None: the engine's own; the bound is there for the trace
+    reader, not for the order, so the order's tests lift it)."""
 
     def __init__(self, core, *, todays_order: bool = False,
-                 admission_ahead: bool = True):
+                 admission_ahead: bool = True, queued_behind: bool = True,
+                 queued_run: int | None = None):
         self.core = core
         self.bursts = 0
         self.during: dict[int, list] = {}
@@ -138,6 +147,10 @@ class InlineLoop:
             core._ahead_blocker = lambda plan: "control"
         if not admission_ahead:
             core._arrivals_ahead = lambda plan, k: None
+        if not queued_behind:
+            core._queues_behind = lambda plan: False
+        if queued_run is not None:
+            core.QUEUED_RUN = queued_run
 
     def run(self, iterations: int = 400) -> None:
         core = self.core
