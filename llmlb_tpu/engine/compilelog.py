@@ -23,6 +23,7 @@ with the names in the ring.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -54,6 +55,18 @@ def set_thread_class(name: str) -> None:
     """Called by a thread about itself: the step loops say "loop", the
     window prewarm says "prewarm"; a thread that says nothing is "other"."""
     _tls.cls = name
+
+
+@contextlib.contextmanager
+def thread_class(name: str):
+    """This thread's builds count under `name` inside the block (the loop's
+    one call of a program the prewarm thread built is prewarm work)."""
+    before = getattr(_tls, "cls", "other")
+    _tls.cls = name
+    try:
+        yield
+    finally:
+        _tls.cls = before
 
 
 def enter_step(seq: int) -> None:

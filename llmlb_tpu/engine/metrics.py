@@ -209,6 +209,9 @@ class EngineMetrics:
         # (scheduler._admit_ahead).
         self.prefill_dispatches_total = 0
         self.prefills_dispatched_ahead_total = 0
+        # Admissions that dispatched NO prefill: the arrival's prompt rode
+        # the first step of a decode burst (scheduler._admit_riding)
+        self.mixed_admissions_total = 0
         # Σ over decode steps of the pages their live rows hold, and of the
         # pages of slots x window: live / window is the share of a
         # window-wide sweep that was live context
@@ -450,6 +453,12 @@ class EngineMetrics:
             if ahead:
                 self.prefills_dispatched_ahead_total += 1
 
+    def record_mixed_admission(self) -> None:
+        """One arrival admitted inside a decode burst, its prompt in the
+        burst's first step: no prefill dispatch, no activation."""
+        with self._lock:
+            self.mixed_admissions_total += 1
+
     def record_decode_kv_pages(self, kv_pages_live: int,
                                kv_pages_window: int) -> None:
         """One decode step's page counts (scheduler._kv_pages)."""
@@ -671,6 +680,7 @@ class EngineMetrics:
                 "prefill_dispatches_total": self.prefill_dispatches_total,
                 "prefills_dispatched_ahead_total":
                     self.prefills_dispatched_ahead_total,
+                "mixed_admissions_total": self.mixed_admissions_total,
                 "decode_kv_pages_live_total": self.decode_kv_pages_live_total,
                 "decode_kv_pages_window_total":
                     self.decode_kv_pages_window_total,
@@ -816,6 +826,9 @@ class EngineMetrics:
                 "# TYPE llmlb_engine_prefills_dispatched_ahead_total counter",
                 "llmlb_engine_prefills_dispatched_ahead_total "
                 f"{self.prefills_dispatched_ahead_total}",
+                "# TYPE llmlb_engine_mixed_admissions_total counter",
+                "llmlb_engine_mixed_admissions_total "
+                f"{self.mixed_admissions_total}",
                 "# TYPE llmlb_engine_decode_kv_pages_live_total counter",
                 "llmlb_engine_decode_kv_pages_live_total "
                 f"{self.decode_kv_pages_live_total}",

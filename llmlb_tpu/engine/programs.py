@@ -298,6 +298,75 @@ class StepPrograms:
 
         return jax.jit(many, donate_argnums=(3, 4))
 
+    def admit_many(self, window: int) -> Callable:
+        """The decode burst whose first step carries ONE arrival's prompt
+        (a family whose record says `mixed_step`), for a context-window
+        bucket and the engine's one prompt width."""
+        k = self.decode_burst
+        return self._program(
+            "admit_many", k, window, False, ("admit_many", (k, window)),
+            lambda: self._build_admit_many(k, window))
+
+    def _build_admit_many(self, k: int, window: int) -> Callable:
+        """Jit a k-step burst that ADMITS: step 0 is the family's mixed step
+        — the rows' one token each and the arrival's whole prompt in one
+        pass over the weights (models/llama._mixed_paged_impl) — then the
+        scan of the k - 1 decode steps left, the arrival's row among them.
+        The arrival's sampling row, its length and its slot are scattered
+        into the per-slot arrays HERE (what _activate_rows does in a
+        dispatch of its own), so an admission is one call where it was
+        three. Traced as `admit_many`: `jit_many` stays the pure decode
+        burst for whoever reads a device trace by program.
+
+        `arrival` int32 [4]: (slot, prompt tokens n, top_k, seed);
+        `arrival_f` float32 [2]: (temperature, top_p); `prompt_ids` [1, T].
+        The row enters with n - 1 tokens: to the sampler and to the length
+        counter its step 0 is a decode step that took T tokens in place of
+        one — it writes up to cell n - 1, samples with the fold n - 1 (the
+        activation's) and leaves n, so all k steps of the burst are alike
+        and the fetch brings the row k tokens, the first of them the
+        request's first. Returns the state (last tokens, lengths, the four
+        sampling arrays), the caches and the burst's one array to fetch, as
+        _build_decode_many's — without step counters: a family that counts
+        on the device brings them when it brings its mixed step."""
+        module, cfg, mesh = self.module, self.cfg, self.mesh
+
+        def admit_many(params, last, lens, cache_k, cache_v, tables,
+                       temps, top_ps, top_ks, seeds, key, live,
+                       prompt_ids, arrival, arrival_f):
+            keys = jax.random.split(key, k)
+            slot, n = arrival[0], arrival[1]
+            temps = temps.at[slot].set(arrival_f[0])
+            top_ps = top_ps.at[slot].set(arrival_f[1])
+            top_ks = top_ks.at[slot].set(arrival[2])
+            seeds = seeds.at[slot].set(arrival[3])
+            lens = lens.at[slot].set(n - 1)
+            first_in = last  # pre-burst tokens: pending first emissions
+
+            logits, cache_k, cache_v = module.mixed_step_paged(
+                params, cfg, last, lens, cache_k, cache_v, tables,
+                prompt_ids, n[None], slot, mesh, window=window, live=live)
+            toks0 = sample_tokens(logits, keys[0], temps, top_ps, top_ks,
+                                  None, seeds, lens)
+
+            def body(carry, step_key):
+                last, lens, ck, cv = carry
+                logits, ck, cv = module.decode_step_paged(
+                    params, cfg, last, lens, ck, cv, tables, mesh,
+                    window=window, live=live)
+                toks = sample_tokens(logits, step_key, temps, top_ps,
+                                     top_ks, None, seeds, lens)
+                return (toks, lens + 1, ck, cv), toks
+
+            (last, lens, cache_k, cache_v), toks = jax.lax.scan(
+                body, (toks0, lens + 1, cache_k, cache_v), keys[1:])
+            toks = jnp.concatenate([first_in[None, :], toks0[None, :], toks],
+                                   axis=0)
+            return (last, lens, temps, top_ps, top_ks, seeds, cache_k,
+                    cache_v, toks)
+
+        return jax.jit(admit_many, donate_argnums=(3, 4))
+
     def verify(self, window: int, *, fused: bool,
                grammar: bool = False) -> Callable:
         """The verify step of this engine for a context-window bucket: the
@@ -513,6 +582,43 @@ class StepPrograms:
             return blk, masked, lens, left, skip, cache_k, cache_v, out
 
         return jax.jit(many, donate_argnums=(6, 7))
+
+    def prewarm_mixed(self, window: int, operands: tuple,
+                      width: int) -> bool:
+        """Lower and compile the mixed program (admit_many) of `window` off
+        the loop's thread, so that the loop's one CALL of it at an empty
+        house (EngineCore._build_mixed_program) and the first arrival to
+        ride a burst of that window find it built (no traffic's warm-up
+        forms a mixed burst: it sends into an empty house); whether it did.
+        `operands`: what a dispatch hands the program before
+        the live rows, as they stand NOW. What the loop DONATES — the pool,
+        the per-slot state — goes in as a shape with the placement the
+        loop's array has (no buffer of the loop's is touched from this
+        thread; a page pool that no program has returned yet stands as
+        fresh_kv_pool placed it, under another cache key than a returned
+        one: the caller waits for the loop's first burst). What no program
+        donates — the parameters, the block tables, the key — goes in
+        itself: the tables ride a dispatch UNPLACED (jnp.asarray), and a
+        placed shape in their stead lands under another key, which is why
+        the lowering of `prewarm`, below, builds what no dispatch finds."""
+        def placed(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=x.sharding)
+
+        params, *state, tables = operands[:6]
+        *sampling, key = operands[6:]
+        try:
+            self.admit_many(window).lower(
+                params, *jax.tree.map(placed, state), tables,
+                *map(placed, sampling), key,
+                np.zeros((self.num_slots,), np.bool_),
+                np.zeros((1, width), np.int32), np.zeros((4,), np.int32),
+                np.zeros((2,), np.float32)).compile()
+        except Exception:  # pragma: no cover - best-effort warmup
+            log.exception("window %d mixed prewarm failed (no arrival "
+                          "rides a burst of that window)", window)
+            return False
+        return True
 
     def prewarm(self, windows: tuple[int, ...], operands: tuple, *,
                 fused_decode: bool, running: Callable[[], bool]) -> None:

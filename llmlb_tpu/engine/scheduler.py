@@ -405,10 +405,29 @@ class _Burst:
     # counters behind them (_pack_step_counters)
     fetched: np.ndarray | None = None
     step_s: float = 0.0  # the cycle's wall time a token
+    # the arrival whose prompt rides this burst's first step, its row among
+    # `rows` (EngineCore._admit_riding); None: a pure decode burst
+    admitted: "_Riding | None" = None
 
     @property
     def slots(self) -> list[int]:
         return [i for i, _, _ in self.rows]
+
+
+@dataclasses.dataclass
+class _Riding:
+    """An arrival placed to RIDE the next decode burst (EngineCore.
+    _admit_riding): its prompt is the burst's first step's, so it has no
+    prefill and no activation of its own. What StepPrograms.admit_many takes
+    for it is filled at the placing, on the host: the one call carries
+    every transfer."""
+
+    slot: int
+    request: "Request"
+    tokens: int  # of its prompt
+    prompt_ids: np.ndarray  # [1, T] int32, right-padded
+    arrival: np.ndarray  # int32 [4]: slot, prompt tokens, top_k, seed
+    arrival_f: np.ndarray  # float32 [2]: temperature, top_p
 
 
 @dataclasses.dataclass
@@ -1060,6 +1079,62 @@ class EngineCore:
                     "broadcasts one plan per combined step loop)"
                 )
             self.split = SplitRuntime(self, self._disagg_prefill_slots_arg)
+        # the prompt width of this engine's mixed step; 0: no arrival rides
+        self.mixed_width = self._mixed_prompt_width()
+        # the mixed programs, by window: those the loop has dispatched a
+        # burst in, in the order it first did (the prewarm thread builds
+        # these, the latest first: _prewarm_mixed); those the thread has
+        # built (an arrival rides a burst of such a window alone: _rides);
+        # and those of them the loop has not CALLED yet — it calls each
+        # once, at an empty house (_build_mixed_program)
+        self._mixed_wanted: list[int] = []
+        self._mixed_ready: set[int] = set()
+        self._mixed_uncalled: list[int] = []
+
+    def _mixed_prompt_width(self) -> int:
+        """The ONE static prompt width T of this engine's mixed step
+        (StepPrograms.admit_many: a decode burst whose first step carries an
+        arrival's prompt), or 0 where no arrival rides a burst.
+
+        Who may ride is read off what the engine is: the family's record
+        offers the entry point (`Family.mixed_step`); the decode step is a
+        burst program (a legacy single step is three dispatches already);
+        the loop is alone (a coordinator's tick and split mode keep today's
+        order anyway); and the engine carries nothing the mixed program
+        does not serve — an ADAPTER POOL is that (its rows' deltas are
+        gathered a row, and the prompt's T tokens would need the arrival's
+        row each), and so are step counters a family computes on the device
+        (the mixed program carries none out). An int8 pool and int8 weights ride as they are: the
+        shared body writes and reads the pool through the same helpers as
+        prefill and decode (tests/engine/test_mixed_step.py holds both).
+
+        T is the largest one-shot bucket b with num_slots + b tokens under
+        the RIDGE: the tokens a pass at which the weights' products stop
+        being paid in bytes, peak FLOP/s x bytes a weight / (2 FLOPs a
+        weight and token x peak bytes/s) — 197e12 x 2 / (2 x 0.82e12) = 240
+        on a v5e at bf16 (engine/telemetry.CHIP_SPECS: the chip's published
+        peaks), so 128 at 32 and at 64 slots. Under it the pass costs what a decode step costs and a
+        shorter prompt padded into T costs what T does; one width keeps it
+        at one program a window. A chip the table does not know (a CPU) is
+        sized as the v5e."""
+        from llmlb_tpu.engine.telemetry import chip_spec_for
+
+        if (not self._record.mixed_step or self.block > 1
+                or self.programs.counter_shapes
+                or self.lora is not None or self.coordinator is not None
+                or self.split is not None
+                or not (self.decode_burst > 1 or self.fused_decode)):
+            return 0
+        devices = jax.local_devices()
+        spec = (chip_spec_for(getattr(devices[0], "device_kind", ""))
+                or chip_spec_for("v5e"))
+        flops, weight_bytes = ((spec.int8_flops, 1) if self.quant.weights else
+                               (spec.peak_flops,
+                                jnp.dtype(self.cfg.dtype).itemsize))
+        ridge = flops * weight_bytes / (2 * spec.peak_hbm_bw)
+        fits = [b for b in self.prefill_buckets
+                if self.num_slots + b <= ridge]
+        return max(fits, default=0)
 
     def _check_family_engine(self, multihost: bool, lora: bool) -> None:
         """What an engine of this family refuses to start with rather than
@@ -1182,12 +1257,14 @@ class EngineCore:
                 target=self._loop, name="engine-step-loop", daemon=True
             )
             self._thread.start()
-        if len(self._window_buckets) > 1:
+        if len(self._window_buckets) > 1 or self.mixed_width:
             # Pre-compile every window-bucket variant off-thread: the first
             # sequence to cross a bucket boundary must not stall every
-            # in-flight stream behind a multi-second XLA compile.
+            # in-flight stream behind a multi-second XLA compile — nor the
+            # first arrival that rides a burst (the mixed program, which no
+            # warm-up traffic reaches: _prewarm_mixed, _build_mixed_program).
             threading.Thread(
-                target=self._prewarm_windows, name="engine-prewarm",
+                target=self._prewarm, name="engine-prewarm",
                 daemon=True,
             ).start()
 
@@ -1207,6 +1284,82 @@ class EngineCore:
                 self.cache_k, self.cache_v, self._d_block_tables,
                 self._d_temps, self._d_top_ps, self._d_top_ks,
                 self._d_seeds, key)
+
+    def _prewarm(self) -> None:
+        """The prewarm thread: the mixed programs where this engine has
+        them — no traffic's warm-up reaches those, and an arrival rides only
+        a burst whose program stands (_rides) — else the pure decode
+        bursts' windows. Not both: the thread shares the interpreter with
+        the loop's own first builds, a traffic's warm-up reaches every
+        window it will use through the loop anyway, and the windows'
+        lowering lands under another key than a dispatch finds
+        (StepPrograms.prewarm_mixed says why; ROADMAP Speed, "Set-up")."""
+        compilelog.set_thread_class("prewarm")
+        if self.mixed_width:
+            self._prewarm_mixed()
+        elif len(self._window_buckets) > 1:
+            self._prewarm_windows()
+
+    def _prewarm_mixed(self) -> None:
+        """Lower the mixed program of every window the loop has dispatched a
+        burst in (`_mixed_wanted`), off the loop's thread
+        (StepPrograms.prewarm_mixed), the window it reached LAST first: a
+        full house decodes in the window of its longest row, so the newest
+        window is the one the next arrival will want to ride. A window no
+        burst has run in has no row for an arrival to ride with, and is
+        built the day one does; until its program stands an arrival there is
+        prefilled ahead (_rides). Nothing is wanted before the loop's first
+        burst, and by then a program has RETURNED the page pool: as
+        fresh_kv_pool placed it, it stands under another cache key than
+        every later dispatch finds it (the same bytes on the same devices),
+        and what is built for that key is built for nothing."""
+        asked: set[int] = set()
+        while self._running:
+            wanted = [w for w in self._mixed_wanted if w not in asked]
+            if not wanted:
+                time.sleep(0.05)
+                continue
+            window = wanted[-1]
+            asked.add(window)
+            if self.programs.prewarm_mixed(
+                    window, self._decode_operands(self._key),
+                    self.mixed_width):
+                self._mixed_ready.add(window)
+                self._mixed_uncalled.append(window)
+
+    def _build_mixed_program(self) -> None:
+        """CALL the mixed program (StepPrograms.admit_many) of the next
+        window the prewarm thread has built and the loop has not called yet,
+        at an EMPTY house: no row live, a prompt of one token into slot 0,
+        whose table row names the trash page alone. The thread's lowering
+        lands under a dispatch's cache key on one device; on a mesh of
+        several it may not (another thread's), and a call is what a dispatch
+        is: whatever is left to build is built here, with nobody decoding,
+        and not at the first arrival that rides. It costs a burst; what it
+        leaves in the per-slot state is what a never-used row holds anyway
+        (its length counts on, slot 0's sampling row is rewritten at its
+        next admission). The engine's key is read and not advanced: the
+        sequence of keys the requests see is the one without the call."""
+        window = self._mixed_uncalled.pop(0)
+        self._clock().switch("other")
+        # the rows freed since the last burst still name their old pages on
+        # the device, and a prefix donor's are pinned: zero them first, as
+        # host_sync does before every burst
+        self._sync_block_tables()
+        _, key = jax.random.split(self._key)
+        riding = _Riding(
+            0, None, 1, np.zeros((1, self.mixed_width), np.int32),
+            np.asarray([0, 1, 0, -1], np.int32), np.ones((2,), np.float32))
+        with compilelog.thread_class("prewarm"):  # what it builds is that
+            jax.block_until_ready(
+                self._dispatch_burst(window, key, [], False, {}, riding))
+
+    def _house_is_empty(self) -> bool:
+        """No slot holds a request and none waits: what the device's
+        per-slot state holds is nobody's."""
+        return (all(s.request is None for s in self.slots)
+                and self._held_request is None and self.pending.empty()
+                and not any(self._class_queues.values()))
 
     def _prewarm_windows(self) -> None:
         compilelog.set_thread_class("prewarm")
@@ -1572,6 +1725,9 @@ class EngineCore:
                 # buffers may already be consumed — rebuild before serving again.
                 self._reset_caches()
             if not did_work:
+                if self._mixed_uncalled and self._house_is_empty():
+                    self._build_mixed_program()
+                    continue
                 clock.switch("idle")
                 time.sleep(0.001)
 
@@ -1707,7 +1863,8 @@ class EngineCore:
         whether it left ahead of its predecessor's emit, or ahead of its
         fetch even, and why not, on the record (`dispatched_ahead`,
         `queued_behind`, `ahead_blocked_by`: one of the three says which
-        order the cycle took) and in the running totals. `ahead` is a
+        order the cycle took; `admitted` where an arrival's prompt rode its
+        first step) and in the running totals. `ahead` is a
         one-shot prefill group's: whether it left before the burst in front
         of it was emitted (`dispatched_ahead` on its record). A step that LoopClock.handover closed is observed
         inside its successor, under `emit_inflight`."""
@@ -1730,6 +1887,9 @@ class EngineCore:
                                          and not burst.queued)
             extra["queued_behind"] = burst.queued
             extra["ahead_blocked_by"] = burst.blocked_by
+            if burst.admitted is not None:
+                extra["admitted"] = {"slot": burst.admitted.slot,
+                                     "prompt_tokens": burst.admitted.tokens}
             self.metrics.record_decode_burst(burst.blocked_by,
                                              queued=burst.queued)
         if ahead is not None:
@@ -4061,9 +4221,13 @@ class EngineCore:
     def _window_for(self, active: list[int], k: int) -> int:
         """Smallest context-window bucket covering every active sequence
         plus the k tokens this dispatch will add."""
-        needed = max(int(self._seq_lens[i]) for i in active) + k + 1
+        return self._window_covering(
+            max(int(self._seq_lens[i]) for i in active) + k + 1)
+
+    def _window_covering(self, cells: int) -> int:
+        """Smallest context-window bucket of at least `cells` cells."""
         for w in self._window_buckets:
-            if w >= needed:
+            if w >= cells:
                 return w
         return self.slot_capacity
 
@@ -4224,11 +4388,14 @@ class EngineCore:
         more as can leave AHEAD: a burst whose rows need nothing from the
         host is dispatched right after its predecessor's fetch, and the
         predecessor's tokens are delivered and its record closed while it
-        computes (docs/scheduling.md "The four orders of a decode cycle").
+        computes (docs/scheduling.md "The five orders of a decode cycle").
         Where the one thing in the way is an arrival that can be placed
         without the predecessor's emit (_admit_ahead), its prefill and its
         activation leave first and the burst, its rows among the burst's,
-        right behind them. Either way the host prepares the next burst
+        right behind them — and where it is ONE arrival whose prompt fits
+        the mixed step's width (_rides), nothing leaves for it at all: it
+        is placed (_admit_riding) and the burst is the program whose first
+        step carries its prompt. Either way the host prepares the next burst
         (_prepare_burst) between a dispatch and the wait for it — and where
         nothing stands in that burst's way even then and no slot is free
         for an arrival (_queues_behind), it is dispatched BEFORE the wait,
@@ -4255,7 +4422,10 @@ class EngineCore:
         programs are dispatched in the order they would be otherwise — an
         admission takes today's order, because a free slot forbids queuing —
         so the sequence of keys is the same and tokens are identical,
-        request for request. Nothing is in flight when this returns."""
+        request for request (a riding arrival has no activation to split
+        the key for it: greedy and seeded rows draw what they drew, rows on
+        the engine's key draw from another sequence behind it). Nothing is
+        in flight when this returns."""
         clock = self._clock()
         bounds = self._burst_bounds(k)
         window = self._window_for(active, bounds.reach - 1)
@@ -4278,12 +4448,15 @@ class EngineCore:
             }
             self.metrics.record_masked_decode_step()
 
-        def leave(step, plan, key, blocked_by, queued=False) -> _Burst:
-            """Dispatch the burst of `plan`, whose record is `step`."""
+        def leave(step, plan, key, blocked_by, queued=False,
+                  riding=None) -> _Burst:
+            """Dispatch the burst of `plan`, whose record is `step`; with
+            `riding`, the arrival whose prompt is its first step's."""
             rows, window, kv_pages = plan
-            burst = _Burst(step, rows, kv_pages, blocked_by, queued)
-            burst.toks_dev = self._dispatch_burst(window, key, burst.slots,
-                                                  grammar, gram_args)
+            burst = _Burst(step, rows, kv_pages, blocked_by, queued,
+                           admitted=riding)
+            burst.toks_dev = self._dispatch_burst(
+                window, key, burst.slots, grammar, gram_args, riding)
             return burst
 
         # what no wait for a burst changes (a grammar's cursors above are
@@ -4297,6 +4470,8 @@ class EngineCore:
         run = 0
         # the prefill dispatched ahead, in front of the burst about to leave
         placed: _AheadPrefill | None = None
+        # the arrival placed to ride the burst about to leave
+        riding: _Riding | None = None
         while True:
             if queued is not None:
                 # on the device already: its record begins where its
@@ -4311,7 +4486,8 @@ class EngineCore:
                     # preparation: the sequence of keys is that of bursts
                     # and activations in the order they are dispatched
                     self._key, sk = jax.random.split(self._key)
-                burst = leave(step, plan, sk, blocked_by)
+                burst = leave(step, plan, sk, blocked_by, riding=riding)
+                riding = None
                 if prev is not None:
                     step.mark("emit_inflight")
             self._in_flight = burst
@@ -4339,6 +4515,8 @@ class EngineCore:
             step.mark("fetch" if queued is None else "fetch_inflight")
             # ONE D2H per k tokens
             burst.fetched = self._fetch_tokens(burst.toks_dev)
+            if burst.admitted is not None:
+                self._rode(burst.admitted)
             if queued is not None:
                 # the fetched burst's record ends here and the queued one's
                 # begins: no decision is left to take, it has left
@@ -4358,6 +4536,15 @@ class EngineCore:
                 # the placing, and the next burst's where the prefill's ends
                 clock.close(step, "decode")
                 burst.step_s = (step.t1 - t_cycle) / k
+                clock.switch("admit")  # the placing, whichever way it goes in
+                if self._rides(arrivals, plan, k):
+                    # no prefill and no activation: the next burst's record
+                    # begins behind the placing, the prompt in its first step
+                    riding, plan = self._admit_riding(arrivals[0], plan, k)
+                    step = clock.begin("dispatch", after=step)
+                    self._stamp_prefill(step, [riding.request])
+                    prev, t_cycle, blocked_by = burst, step.t0, None
+                    continue
                 placed, plan = self._admit_ahead(step, arrivals, plan, k)
                 step = clock.handover(placed.step, "prefill",
                                       "dispatch_inflight")
@@ -4410,12 +4597,24 @@ class EngineCore:
         return _BurstBounds(k + 1, k, k)
 
     def _dispatch_burst(self, window: int, key, slots: list[int],
-                        grammar: bool, gram_args: dict):
-        """Call the burst's program for `window` over the rows `slots`: the
+                        grammar: bool, gram_args: dict,
+                        riding: "_Riding | None" = None):
+        """Call the burst's program for `window` over the rows `slots` —
+        with `riding`, the one whose first step carries that arrival's
+        prompt and which writes its row of the per-slot state itself: the
         per-slot state and the pool it returns are the loop's from here on
         (device futures: the next dispatch takes them unread). Returns the
         array the burst's ONE fetch reads."""
         live = self._live_rows(slots)
+        if self.mixed_width and window not in self._mixed_wanted:
+            self._mixed_wanted.append(window)  # for the prewarm thread
+        if riding is not None:
+            (self._d_last_tokens, self._d_seq_lens, self._d_temps,
+             self._d_top_ps, self._d_top_ks, self._d_seeds, self.cache_k,
+             self.cache_v, toks_dev) = self.programs.admit_many(window)(
+                *self._decode_operands(key), live, riding.prompt_ids,
+                riding.arrival, riding.arrival_f)
+            return toks_dev
         if self.block > 1:
             (self._d_blk, self._d_masked, self._d_seq_lens, self._d_left,
              self._d_skip, self.cache_k, self.cache_v,
@@ -4562,6 +4761,72 @@ class EngineCore:
             return None
         return arrivals
 
+    def _rides(self, arrivals: "list[tuple[Request, int, int, bool]]",
+               plan: tuple, k: int) -> bool:
+        """Whether what _arrivals_ahead found RIDES the prepared burst
+        `plan`: it is exactly ONE arrival, its prompt fits the mixed step's
+        width (`mixed_width`, 0 where this engine has no mixed step), and
+        the mixed program of the burst's window STANDS (the prewarm thread
+        has lowered it: nothing is built between two bursts of a house that
+        decodes). Two or more at once, a longer prompt, a window whose
+        program is not there yet take the admission-ahead order; what
+        _arrivals_ahead refuses (a chunked or cached prompt, a grammar, a
+        drafter, a resume) takes today's."""
+        if len(arrivals) != 1 or not 0 < arrivals[0][1] <= self.mixed_width:
+            return False
+        # the burst's window with the arrival in it, as _plan_with will
+        # find it: the row enters with n - 1 cells
+        window = max(plan[1], self._window_covering(
+            arrivals[0][1] + self._burst_bounds(k).reach - 1))
+        return window in self._mixed_ready
+
+    def _admit_riding(self, arrival: "tuple[Request, int, int, bool]",
+                      plan: tuple, k: int) -> "tuple[_Riding, tuple]":
+        """Admission INSIDE a burst: with the burst in front fetched and not
+        emitted, its record closed, place `arrival` as _admit_ahead does —
+        the same slot, the same pages from the free list in one piece, the
+        placing under the loop's `admit` — and dispatch nothing: its prompt
+        is the first step of the prepared burst `plan`, which the caller
+        dispatches right behind with the new row in it
+        (StepPrograms.admit_many). Returns the arrival with its operands and
+        that plan.
+
+        The host's mirror of the row is what the device's is: it enters the
+        burst with n - 1 tokens, a decode row whose first step takes the
+        whole prompt in place of one token, so every one of the burst's k
+        steps brings it a token — the first of them its FIRST token — and
+        advances its length by one: nothing of the burst's arithmetic
+        (_prepare_burst, _emit_fetched, the record's tokens and pages) knows
+        a riding row from another."""
+        request, n = arrival[:2]
+        slot_id = self._place_ahead(arrival, self._free_slots()[0])
+        slot = self.slots[slot_id]
+        slot.out_tokens = []
+        slot.last_emit_at = 0.0
+        slot.first_pending = False  # it comes as the burst's first decode token
+        self._seq_lens[slot_id] = n - 1
+        self._sync_block_tables()
+        self.metrics.record_mixed_admission()
+        sampling = request.sampling
+        prompt_ids = np.zeros((1, self.mixed_width), np.int32)
+        prompt_ids[0, :n] = request.prompt_ids[:n]
+        seed = -1 if sampling.seed is None else sampling.seed & 0x7FFFFFFF
+        riding = _Riding(
+            slot_id, request, n, prompt_ids,
+            np.asarray([slot_id, n, sampling.top_k, seed], np.int32),
+            np.asarray([sampling.temperature, sampling.top_p], np.float32))
+        return riding, self._plan_with(plan, [slot_id], k)
+
+    def _rode(self, riding: _Riding) -> None:
+        """The burst that carried `riding`'s prompt is fetched: the first
+        instant the host knows the prompt filled. The request's `prefill`
+        stage ends here (`activated_at`: what an activation's issue is to a
+        prefilled group) and its `prefill_chunk` event is stamped here, as
+        _record_ahead_prefill does behind its wait; its first token is in
+        the fetch, so `first_fetch` reads the way to the emit."""
+        self._stamp_activated([riding.request], stepstats._now())
+        self._fr_prefilled([(riding.slot, riding.request, riding.tokens)])
+
     def _admit_ahead(self, step: StepSpan,
                      arrivals: "list[tuple[Request, int, int, bool]]",
                      plan: tuple, k: int) -> "tuple[_AheadPrefill, tuple]":
@@ -4577,35 +4842,47 @@ class EngineCore:
         pages, the prompt's and what the burst will write, come from the
         free list in one piece. The placing is the loop's `admit`, as in
         today's order."""
-        self._clock().switch("admit")
         free = self._free_slots()
-        group: list[tuple[int, Request, int]] = []
-        for request, n, pages, cacheable in arrivals:
-            popped = self._pop_request()
-            assert popped is request, "the queue moved under _arrivals_ahead"
-            if cacheable:
-                self.metrics.record_prefix_miss()
-            slot_id = free.pop(0)
-            self._assign_slot_pages(slot_id, (), self.page_pool.alloc(pages))
-            # Claim the slot BEFORE any dispatch, as _try_insert does
-            self.slots[slot_id].request = request
-            self.slots[slot_id].generated = 0
-            self._attach_constraint(slot_id, request)
-            group.append((slot_id, request, n))
+        group = [(self._place_ahead(arrival, free.pop(0)), arrival[0],
+                  arrival[1]) for arrival in arrivals]
         prefill = self._prefill_group(self._bucket_for(group[0][2]), group,
                                       after=step)
         self._sync_block_tables()
+        return prefill, self._plan_with(
+            plan, [slot_id for slot_id, _, _ in group], k)
+
+    def _place_ahead(self, arrival: "tuple[Request, int, int, bool]",
+                     slot_id: int) -> int:
+        """Take `arrival` (one of _arrivals_ahead's) off its queue and give
+        it the free slot `slot_id` and its pages, from the free list in one
+        piece: the slot is claimed BEFORE any dispatch, as _try_insert
+        does. Returns the slot."""
+        request, _n, pages, cacheable = arrival
+        popped = self._pop_request()
+        assert popped is request, "the queue moved under _arrivals_ahead"
+        if cacheable:
+            self.metrics.record_prefix_miss()
+        self._assign_slot_pages(slot_id, (), self.page_pool.alloc(pages))
+        self.slots[slot_id].request = request
+        self.slots[slot_id].generated = 0
+        self._attach_constraint(slot_id, request)
+        return slot_id
+
+    def _plan_with(self, plan: tuple, new: list[int], k: int) -> tuple:
+        """The prepared burst `plan` with the rows of the slots `new`, just
+        placed, in it: as their placing left them (_burst_rows: a dense row
+        behind its activation has its first token pending; a riding row and
+        a block family's have none), the window and the pages counted with
+        theirs."""
         rows, window, kv_pages = plan
-        new = [slot_id for slot_id, _, _ in group]
         bounds = self._burst_bounds(k)
         window = max(window, self._window_for(new, bounds.reach - 1))
         of_new = self._kv_pages(new, bounds.counted, window)
         kv_pages = {"kv_pages_live": (kv_pages["kv_pages_live"]
                                       + of_new["kv_pages_live"]),
                     "kv_pages_window": of_new["kv_pages_window"]}
-        # as the activation left them: a dense row's first token pending
         rows = sorted(rows + self._burst_rows(new), key=lambda row: row[0])
-        return prefill, (rows, window, kv_pages)
+        return rows, window, kv_pages
 
     def _prepare_burst(self, rows: list[tuple[int, Request, bool]], k: int):
         """With the burst of `rows` in flight and every earlier one emitted:

@@ -80,7 +80,7 @@ names of its own (``INFLIGHT_SPANS``), so that the names above keep meaning
   fetch_inflight     — what `fetch` does, with the fetched burst's successor
                        queued behind it and on the device by now
 
-A decode burst runs in one of four orders. Today's: ``host_sync, dispatch,
+A decode burst runs in one of five orders. Today's: ``host_sync, dispatch,
 [host_sync_inflight,] compute, fetch, emit``. Dispatched ahead (it left
 right after its predecessor's fetch): ``dispatch, emit_inflight,
 [host_sync_inflight,] compute, fetch`` and, where the next burst does not
@@ -97,7 +97,9 @@ begins at the predecessor's fetch, ``emit_inflight, host_sync_inflight,
 compute, fetch[, emit]``, and where the next burst is queued in its turn,
 ``emit_inflight, host_sync_inflight, dispatch_inflight, compute,
 fetch_inflight``: a record with no span in which the device has nothing
-from this loop.
+from this loop. An arrival rode the burst (its prompt was the burst's first
+step's: no prefill record): the spans of a burst that left ahead, behind a
+stretch of ``admit``, and ``admitted`` on the record.
 
 The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
 since the previous record (``since_prev.admit_s``), ``emit`` still covers

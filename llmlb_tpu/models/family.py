@@ -51,6 +51,11 @@ class Family:
     # dense feed-forward: a mixture has none)
     verifies_drafts: bool = True
     context_parallel_prefill: bool = False
+    # exports `mixed_step_paged`: a decode step with ONE arrival's prompt
+    # prefilled in the same pass over the weights (llama._mixed_paged_impl).
+    # A family sets it once its own equality test against prefill-then-decode
+    # passes; a state per slot needs its mixer called twice a layer first
+    mixed_step: bool = False
     # -- what it serves; the engine refuses the rest at start-up (`refuse`)
     int8_weights: bool = True
     int8_kv: bool = True

@@ -904,6 +904,29 @@ def verify_step_paged(
     )[:3]
 
 
+def _unrolled_layers(params, groups):
+    """The stack as decode walks it, UNROLLED: for each group, the group and
+    an iterator of (the layer's parameters — its slices by a STATIC index,
+    the group's whole stacks and the layer's own index in them beside —, the
+    layer's index in the pool it writes). Lazy, so that a layer's slices
+    are traced where its body is."""
+    at = 0
+    for group in groups:
+        stacked, whole = _group_params(params, group)
+        pool_first = at if group.pool_layer is None else group.pool_layer
+
+        def layers(group=group, stacked=stacked, whole=whole,
+                   pool_first=pool_first):
+            for in_group in range(group.count):
+                own = group.start + in_group  # static indices, unrolled
+                lp = {n: w[own] for n, w in stacked.items()}
+                lp.update(whole, layer=own)
+                yield lp, pool_first + in_group
+
+        yield group, layers()
+        at += group.count
+
+
 def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                        block_tables, *, stacked_names=None,
                        mlp_fn=_default_mlp_fn, window=None, lora_idx=None,
@@ -957,16 +980,11 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
     rows = StateRows(slot_ids, start_pos=write_pos, live=live)
 
     x = _embed(cfg, params, input_ids)[:, None, :]  # [B, 1, E]
-    aux, at, deferred = [], 0, None  # the branch a layer left (LayerGroup)
-    for group in _groups_for(cfg, stacked_names, mlp_fn, groups):
-        stacked, whole = _group_params(params, group)
-        pool_first = at if group.pool_layer is None else group.pool_layer
+    aux, deferred = [], None  # the branch a layer left (LayerGroup)
+    for group, layers in _unrolled_layers(
+            params, _groups_for(cfg, stacked_names, mlp_fn, groups)):
         group_aux = []
-        for in_group in range(group.count):
-            own = group.start + in_group  # static indices, unrolled
-            layer_idx = pool_first + in_group
-            lp = {n: w[own] for n, w in stacked.items()}
-            lp.update(whole, layer=own)
+        for lp, layer_idx in layers:
 
             def attn_fn(q, k, v, layer_idx=layer_idx):
                 nonlocal cache_k, cache_v  # write precedes attention
@@ -988,7 +1006,6 @@ def _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
                     cfg, group, lp, x, deferred, None, lora_idx)
             group_aux.append(layer_aux)
         aux.append(group_aux)
-        at += group.count
 
     logits = _unembed(cfg, params, x[:, 0])
     return logits, cache_k, cache_v, aux
@@ -1017,6 +1034,127 @@ def decode_step_paged(
     return _decode_paged_impl(params, cfg, input_ids, seq_lens, cache_k,
                               cache_v, block_tables, window=window,
                               lora_idx=lora_idx, live=live)[:3]
+
+
+def _mixed_paged_impl(params, cfg, input_ids, seq_lens, cache_k, cache_v,
+                      block_tables, prompt_ids, prompt_len, prompt_row, *,
+                      stacked_names=None, mlp_fn=_default_mlp_fn,
+                      window=None, live=None, groups=None, attention=None):
+    """Shared MIXED step: the decode rows' one token each AND one arrival's
+    whole prompt in ONE pass over the weights (the "chunked prefill" step of
+    Sarathi-Serve and vLLM at its simplest: a prompt that fits the step
+    whole). A one-prompt prefill of a hundred tokens is bound by the same
+    bytes as a decode step, every weight read once, so run alone it costs a
+    step; here its tokens ride the step the rows take anyway.
+
+    `prompt_ids` [1, T] right-padded, `prompt_len` [1], and `prompt_row`
+    (int32 scalar) the arrival's row of `block_tables` (row i is slot i, as
+    the engine's burst has it). The norms, q/k/v, `wo`, the feed-forward and
+    the head are ONE product over the B + T tokens; only the attention core
+    splits by token — the rows through `attention.decode` over the pool with
+    the step's work-list, as _decode_paged_impl; the prompt through
+    `attention.prefill` over its own fresh keys and values, which land in
+    its pages by the same scatter as the rows' cells, as _prefill_impl
+    (padding past `prompt_len` writes into the row's own later cells or the
+    trash page, and a later step overwrites it). The arrival's OWN decode
+    row has no token to decode: it is parked as a prefilling row is (it
+    writes the slot's last cell or the trash page and attends over
+    nothing, whatever `seq_lens` holds for it), and its logits are the
+    PROMPT's last position's — to the sampler the arrival is a row whose
+    step took T tokens in place of one. Unrolled like the decode body, and
+    for its reasons: static layer indices, the pool never a scan's `xs` or
+    `ys` and never sliced by layer. A group with a `mixer` keeps a state per
+    slot and would need its step for the rows and its scan for the prompt:
+    that is the family's to bring. Returns (logits [B, V], cache_k,
+    cache_v, aux) as _decode_paged_impl."""
+    b, t = input_ids.shape[0], prompt_ids.shape[1]
+    ps = kv_pool_values(cache_k).shape[2]
+    ppn = block_tables.shape[1]
+    capacity = ppn * ps
+    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    batch_idx = jnp.arange(b)
+    arrives = batch_idx == prompt_row
+    write_pos = jnp.where(arrives, capacity - 1,
+                          jnp.minimum(seq_lens, capacity - 1))
+    kv_lens = jnp.where(arrives, 0, write_pos + 1)
+    if live is not None:
+        kv_lens = jnp.where(live, kv_lens, 0)
+    prompt_pos = jnp.arange(t, dtype=jnp.int32)
+    prompt_cell = jnp.minimum(prompt_pos, capacity - 1)
+    # the B rows' cells, then the prompt's: one scatter a layer and pool
+    page = jnp.concatenate([block_tables[batch_idx, write_pos // ps],
+                            block_tables[prompt_row, prompt_cell // ps]])
+    off = jnp.concatenate([write_pos % ps, prompt_cell % ps])
+    positions = jnp.concatenate([write_pos, prompt_pos])[:, None]  # [B+T, 1]
+    token_valid = jnp.concatenate(
+        [jnp.ones((b,), jnp.bool_), prompt_pos < prompt_len[0]])[:, None]
+    attention = attention or GQA_ATTENTION
+    work = attention.decode_work(_pages(cache_k), _pages(cache_v),
+                                 block_tables, kv_lens, window)
+
+    x = _embed(cfg, params, jnp.concatenate([input_ids, prompt_ids[0]]))
+    x = x[:, None, :]  # [B + T, 1, E]: a token a row, as decode has them
+    aux, deferred = [], None
+    for group, layers in _unrolled_layers(
+            params, _groups_for(cfg, stacked_names, mlp_fn, groups)):
+        if group.mixer is not None:
+            raise NotImplementedError(
+                "a group with a state per slot has no mixed step")
+        group_aux = []
+        for lp, layer_idx in layers:
+
+            def attn_fn(q, k, v, layer_idx=layer_idx):
+                nonlocal cache_k, cache_v  # write precedes attention
+                cache_k = _write_pool(cache_k, layer_idx, page, off, k[:, 0])
+                cache_v = _write_pool(cache_v, layer_idx, page, off, v[:, 0])
+                rows = attention.decode(
+                    q[:b], _pages(cache_k), _pages(cache_v), layer_idx,
+                    block_tables, kv_lens, window=window, work=work,
+                )
+                prompt = attention.prefill(
+                    q[b:].swapaxes(0, 1), k[b:].swapaxes(0, 1),
+                    v[b:].swapaxes(0, 1), prompt_len)
+                return jnp.concatenate(
+                    [rows, prompt.swapaxes(0, 1).astype(rows.dtype)])
+
+            with _layer_scope(group):
+                if group.attends:
+                    x, _, _ = attention.block(cfg, lp, x, positions,
+                                              inv_freq, attn_fn, None)
+                x, deferred, layer_aux = _feed_forward(
+                    cfg, group, lp, x, deferred, token_valid, None)
+            group_aux.append(layer_aux)
+        aux.append(group_aux)
+
+    last = b + jnp.maximum(prompt_len[0] - 1, 0)
+    x_rows = jnp.where(arrives[:, None], x[last, 0][None, :], x[:b, 0])
+    return _unembed(cfg, params, x_rows), cache_k, cache_v, aux
+
+
+@partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
+         donate_argnames=("cache_k", "cache_v"))
+def mixed_step_paged(
+    params: Params,
+    cfg: LlamaConfig,
+    input_ids: jnp.ndarray,  # [B] int32 — previous sampled token per row
+    seq_lens: jnp.ndarray,  # [B] int32 — tokens already in the row's pages
+    cache_k: jnp.ndarray,  # [L, P, PS, K, D]
+    cache_v: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    prompt_ids: jnp.ndarray,  # [1, T] int32, right-padded — the arrival's
+    prompt_len: jnp.ndarray,  # [1] int32
+    prompt_row: jnp.ndarray,  # int32 scalar — the arrival's row of the tables
+    mesh: Mesh | None = None,  # unused; shared family signature
+    window: int | None = None,  # static context-window BUCKET, as decode's
+    live: jnp.ndarray | None = None,  # [B] bool — rows decoding; None = all
+):
+    """One decode step across all rows with ONE arrival's prompt prefilled
+    in the same pass (_mixed_paged_impl; `Family.mixed_step`). Returns
+    (logits [B, V] fp32, caches): row `prompt_row` holds the logits of the
+    prompt's last position, every other row its decode step's."""
+    return _mixed_paged_impl(params, cfg, input_ids, seq_lens, cache_k,
+                             cache_v, block_tables, prompt_ids, prompt_len,
+                             prompt_row, window=window, live=live)[:3]
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -1126,4 +1264,4 @@ FAMILY = Family(
     name="llama", config_class=LlamaConfig,
     model_types=("llama", "mistral", "qwen2"), mechanism_keys=(),
     kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
-    context_parallel_prefill=True)
+    context_parallel_prefill=True, mixed_step=True)

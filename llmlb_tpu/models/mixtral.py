@@ -28,6 +28,7 @@ from llmlb_tpu.models.llama import (
     LayerGroup,
     LlamaConfig,
     _decode_paged_impl,
+    _mixed_paged_impl,
     _prefill_extend_paged_impl,
     _prefill_impl,
 )
@@ -239,7 +240,25 @@ def decode_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
     )[:3]
 
 
+@partial(jax.jit, static_argnames=("cfg", "mesh", "window"),
+         donate_argnames=("cache_k", "cache_v"))
+def mixed_step_paged(params, cfg: MixtralConfig, input_ids, seq_lens,
+                     cache_k, cache_v, block_tables, prompt_ids, prompt_len,
+                     prompt_row, mesh: Mesh | None = None,
+                     window: int | None = None, live=None):
+    """One decode step with one arrival's prompt in the same pass. Same
+    contract as llama.mixed_step_paged: every assignment is computed at
+    every size, so a token's experts do not depend on what shares the pass,
+    and the prompt's padding is kept out of the grouped products."""
+    return _mixed_paged_impl(
+        params, cfg, input_ids, seq_lens, cache_k, cache_v, block_tables,
+        prompt_ids, prompt_len, prompt_row, groups=_groups(cfg),
+        window=window, live=live,
+    )[:3]
+
+
 FAMILY = Family(
     name="mixtral", config_class=MixtralConfig, model_types=("mixtral",),
     mechanism_keys=("num_local_experts", "num_experts"),
-    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell)
+    kv_token_layer_bytes=kv_token_layer_bytes, kv_wire_cell=kv_wire_cell,
+    mixed_step=True)

@@ -2,10 +2,10 @@
 arrival's prefill before that, and with every slot held a burst leaves before
 its predecessor is even fetched.
 
-The step loop runs a decode cycle in one of four orders (docs/scheduling.md
-"The four orders of a decode cycle"; tests/engine/test_decode_overlap.py
+The step loop runs a decode cycle in one of five orders (docs/scheduling.md
+"The five orders of a decode cycle"; tests/engine/test_decode_overlap.py
 holds them for a dense burst). A family that generates by diffusion over
-blocks takes the same four, through the same loop
+blocks takes the first four, through the same loop
 (`EngineCore._decode_bursts`), with what a block burst needs that a dense one
 does not:
 

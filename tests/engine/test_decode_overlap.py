@@ -2,7 +2,8 @@
 arrival's prefill before that, and with every slot held a burst leaves before
 its predecessor is even fetched.
 
-The step loop runs a decode cycle in one of four orders (docs/scheduling.md).
+The step loop runs a decode cycle in one of five orders (docs/scheduling.md);
+these are the first four, and tests/engine/test_mixed_admission.py the fifth's.
 Today's: host_sync, dispatch, compute, fetch, emit, record, back through the
 loop. Ahead: right after a burst's fetch the next one is dispatched, and the
 fetched tokens are delivered, the record closed and the burst after that
@@ -95,16 +96,29 @@ def _seeded(j: int, max_tokens: int, seed: int) -> Request:
 
 NEVER = CFG.vocab_size + 7  # an EOS id no row samples
 
-# InlineLoop's arguments for the four orders of a decode cycle
+# InlineLoop's arguments for four of the five orders of a decode cycle
 ORDERS = {"today": {"todays_order": True},
           # PR 39's
           "ahead": {"admission_ahead": False, "queued_behind": False},
-          "admission_ahead": {"queued_behind": False},  # PR 49's
+          # PR 49's: an arrival's prefill and activation leave ahead
+          "admission_ahead": {"queued_behind": False, "rides": False},
           # the fourth, a run of queued bursts as long as a full house
           # lasts: EngineCore.QUEUED_RUN bounds it for the trace reader,
           # not for the order
-          "queued_behind": {"queued_run": RUN_LIFTED},
-          "queued_bounded": {}}  # the loop as it is
+          "queued_behind": {"queued_run": RUN_LIFTED, "rides": False},
+          # the loop as it is: a lone arrival RIDES the next burst's first
+          # step (tests/engine/test_mixed_admission.py holds that order)
+          "queued_bounded": {}}
+
+
+def _without_frames(events: dict) -> dict:
+    """Every request's tokens and finish reason, without the sizes of its
+    content events: a row that RODE a burst (the loop as it is, PR 61) gets
+    its first token and the burst's other k - 1 in one event where a
+    prefilled row gets 1 + k (tests/engine/test_mixed_admission.py holds
+    the sizes of that order)."""
+    return {name: (tokens, finish)
+            for name, (tokens, finish, _sizes) in events.items()}
 
 
 def _longest_run(flags: list[bool]) -> int:
@@ -169,7 +183,7 @@ def test_both_orders_give_the_same_streams_reasons_and_usage(
     assert ahead == today and held == today and queued == today
     # and with the run of queued bursts bounded, as the engine bounds it
     bounded, run_bounded = _scenario(eos, ends_at, **ORDERS["queued_bounded"])
-    assert bounded == today
+    assert _without_frames(bounded) == _without_frames(today)
     queued_bounded = [r["queued_behind"]
                       for r in run_bounded.decode_records()]
     assert any(queued_bounded)
@@ -289,9 +303,10 @@ def test_an_arrival_gets_the_same_stream_in_every_order(
     runs = {name: _arrival_case(case, eos, **order)
             for name, order in ORDERS.items()}
     today, run_today = runs["today"]
-    for name in ("ahead", "admission_ahead", "queued_behind",
-                 "queued_bounded"):
+    for name in ("ahead", "admission_ahead", "queued_behind"):
         assert runs[name][0] == today, name
+    assert _without_frames(runs["queued_bounded"][0]) == _without_frames(
+        today)
     late = today["late"]
     assert (len(late[0]), late[1]) == {
         "cancelled_before_its_first_token": (0, "cancelled"),
@@ -425,7 +440,7 @@ def test_a_started_engine_serves_the_same_usage_in_both_orders(
 
 def test_an_arrival_is_prefilled_before_any_further_burst():
     core = _core()
-    run = Inline(core)
+    run = Inline(core, rides=False)  # with a prefill of its own: PR 49's
     first, second = _greedy(0, 60), _seeded(1, 60, seed=5)
     late = _greedy(2, 12)
     core.pending.put(first)
@@ -790,7 +805,7 @@ def test_records_tile_with_a_prefill_between_two_bursts_that_left_ahead():
     burst's end and begin at one stamp each, the placing between the first
     two is the prefill's `admit`, and nothing of the three is counted twice."""
     core = _core()
-    run = Inline(core)
+    run = Inline(core, rides=False)
     clock = core._clock()  # the loop's clock: made before the first reading
     core.pending.put(_greedy(0, 40))
     core.pending.put(_seeded(1, 40, seed=2))

@@ -46,11 +46,13 @@ CONTRACT = (
     "kv_pages_shardings",
     *PAGED,
     "make_context_parallel_prefill",
+    "mixed_step_paged",
 )
 # the record's field that says a family exports the name; every other name
 # of the contract is exported by all
 EXPORTED_IF = {"verify_step_paged": "verifies_drafts",
-               "make_context_parallel_prefill": "context_parallel_prefill"}
+               "make_context_parallel_prefill": "context_parallel_prefill",
+               "mixed_step_paged": "mixed_step"}
 
 
 def _params(fn) -> list[tuple[str, inspect._ParameterKind]]:

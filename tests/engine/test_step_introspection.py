@@ -603,7 +603,7 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
             r["since_prev"]["admit_s"], abs=2e-6)
     by_seq = {r["seq"]: r for r in records}
     for r, nxt in zip(decode, decode[1:] + [None]):
-        # three of the four orders of a decode cycle (docs/scheduling.md;
+        # three of the five orders of a decode cycle (docs/scheduling.md;
         # a slot is free all along here, so no burst is queued behind the
         # one in flight: tests/engine/test_decode_overlap.py): today's,
         # the one of a burst that left before its predecessor was emitted,
@@ -654,16 +654,19 @@ async def test_served_records_are_spans_in_the_order_run(served_engine):
 
 
 async def test_a_served_arrival_is_prefilled_ahead_under_inflight_spans(
-        served_engine):
+        served_engine, monkeypatch):
     """A request that comes while another decodes and a slot is free: its
     prefill leaves ahead, and the span names of the prefill and of the
     burst behind it follow the legacy-phases rule — what the host does with
-    the prefill on the device is `compute`, not `emit` or `dispatch`."""
+    the prefill on the device is `compute`, not `emit` or `dispatch`. (An
+    engine with no mixed step: with one, a lone short arrival has no
+    prefill record at all, tests/engine/test_mixed_admission.py.)"""
     from llmlb_tpu.engine.scheduler import Request, SamplingParams
     from tests.support import collect
 
     engine = served_engine
     core = engine.core
+    monkeypatch.setattr(core, "mixed_width", 0)
     start = core.step_stats.seq
     ahead_before = core.metrics.summary()["prefills_dispatched_ahead_total"]
     # the second request is submitted from the loop's own thread while the

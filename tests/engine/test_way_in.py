@@ -68,9 +68,14 @@ def _served(core, rid: str, since: int):
     for stage in ENGINE_STAGES:
         assert entry[stage] == attrs[stage]
     # the first prefill dispatch named is a prefill record of this request
+    # — or, where its prompt RODE a burst's first step, that burst's record,
+    # whose own fetch brought the token (docs/tracing.md)
     prefill = next(r for r in records if r["seq"] == attrs["prefill_seq"])
-    assert prefill["kind"] == "prefill"
-    assert prefill["seq"] < record["seq"]
+    if prefill.get("admitted"):
+        assert prefill is record and entry["chunks"] == 1
+    else:
+        assert prefill["kind"] == "prefill"
+        assert prefill["seq"] < record["seq"]
     return attrs, entry, record, records
 
 
@@ -175,12 +180,15 @@ async def test_a_cache_hit_prefills_the_suffix_alone(engine):
     assert attrs["prefill_seq"] == chunks[0]["seq"]
 
 
-async def test_an_arrival_placed_ahead(engine):
+async def test_an_arrival_placed_ahead(engine, monkeypatch):
     """A request that comes while another decodes and a slot is free: its
     prefill leaves ahead of the fetched burst's emit, its first token rides
     the burst dispatched right behind, and `prefill` ends where the host
-    learns the prefill done (with that burst in flight)."""
+    learns the prefill done (with that burst in flight). (An engine with no
+    mixed step, what a family whose record offers none reads: with one, a
+    lone short arrival rides the burst, next test.)"""
     core = engine.core
+    monkeypatch.setattr(core, "mixed_width", 0)
     since = core.step_stats.seq
     late = Request(prompt_ids=[8, 2, 3, 4, 5], request_id="ahead",
                    sampling=SamplingParams(temperature=0.0, max_tokens=8))
@@ -207,6 +215,39 @@ async def test_an_arrival_placed_ahead(engine):
     assert late.activated_at >= record["t0_s"]
     assert late.first_token_at <= _records(core, record["seq"])[0]["t1_s"]
     _served(core, "in-front", since)
+
+
+async def test_an_arrival_that_rides_a_burst(engine):
+    """The same arrival where the mixed program of the burst's window
+    stands: no prefill record, its six stamps telescope all the same, and
+    the burst that admitted it is the one whose fetch brought its token."""
+    core = engine.core
+    core._mixed_ready.update(core._window_buckets)
+    since = core.step_stats.seq
+    late = Request(prompt_ids=[8, 2, 3, 4, 5], request_id="rides",
+                   sampling=SamplingParams(temperature=0.0, max_tokens=8))
+    prepare, pending = core._prepare_burst, [late]
+
+    def prepare_and_submit(rows, k):
+        if pending:
+            core.submit(pending.pop())
+        return prepare(rows, k)
+
+    core._prepare_burst = prepare_and_submit
+    try:
+        await _complete(engine, [9, 2, 3, 4, 5], "in-front-of-it",
+                        max_tokens=56)
+        assert len(collect(late)[0]) == 8
+    finally:
+        core._prepare_burst = prepare
+    attrs, _entry, record, records = _served(core, "rides", since)
+    assert record["admitted"]["prompt_tokens"] == 5
+    assert attrs["prefill_seq"] == attrs["fetch_seq"] == record["seq"]
+    assert [r["tokens"] for r in records if r["kind"] == "prefill"] == [5]
+    # `prefill` is that burst, dispatch to fetch; `first_fetch` the emit
+    assert record["t0_s"] == pytest.approx(late.prefill_at, abs=5e-6)
+    assert record["t0_s"] < late.activated_at <= late.first_token_at
+    assert late.first_token_at <= _records(core, record["seq"])[0]["t1_s"]
 
 
 async def test_first_tokens_are_absent_where_a_fetch_brought_none(engine):

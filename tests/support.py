@@ -113,7 +113,7 @@ class InlineLoop:
     and their order are the test's: `run` calls the loop's own iteration
     (`EngineCore._loop_once`) until one does no work, with `_running` set so
     that the loop's own state decides the order of a decode cycle, as in a
-    started engine (docs/scheduling.md "The four orders of a decode cycle").
+    started engine (docs/scheduling.md "The five orders of a decode cycle").
     `during[n]` is a list of calls made while the n-th burst is in
     flight — `_prepare_burst`, which every burst calls between its
     dispatch and the wait for it. `todays_order` patches the predicate to
@@ -124,11 +124,15 @@ class InlineLoop:
     burst leaves before its predecessor's fetch": PR 49's orders.
     `queued_run` sets the core's `QUEUED_RUN`, the most bursts in a row that
     may queue (None: the engine's own; the bound is there for the trace
-    reader, not for the order, so the order's tests lift it)."""
+    reader, not for the order, so the order's tests lift it). `rides=False`
+    leaves the engine no mixed step (`mixed_width` 0, what a family whose
+    record does not offer one reads): an arrival placed ahead has its
+    prefill and its activation dispatched, PR 49's order, where it would
+    ride the next burst's first step."""
 
     def __init__(self, core, *, todays_order: bool = False,
                  admission_ahead: bool = True, queued_behind: bool = True,
-                 queued_run: int | None = None):
+                 queued_run: int | None = None, rides: bool = True):
         self.core = core
         self.bursts = 0
         self.during: dict[int, list] = {}
@@ -151,6 +155,11 @@ class InlineLoop:
             core._queues_behind = lambda plan: False
         if queued_run is not None:
             core.QUEUED_RUN = queued_run
+        if not rides:
+            core.mixed_width = 0
+        # as a started engine's prewarm thread leaves them: the mixed
+        # program of every window stands (here it is built at its first use)
+        core._mixed_ready.update(core._window_buckets)
 
     def run(self, iterations: int = 400) -> None:
         core = self.core

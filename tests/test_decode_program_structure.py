@@ -137,6 +137,70 @@ def test_paged_decode_hands_the_kernel_the_stacked_pool(name, quantized,
             kernel_operands + [q_rows]), shapes
 
 
+MIXED_WIDTH = 2 * PAGE_SIZE  # the arrival's prompt, padded: two pages
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_the_mixed_step_hands_the_kernels_the_stacked_pool(name, quantized,
+                                                           monkeypatch):
+    """The decode step that carries an arrival's prompt
+    (llama._mixed_paged_impl) passes the assertions the decode step passes:
+    no per-layer slice of the pool, the decode kernel handed the pool whole
+    with the layer's index a layer, and beside it ONE prefill kernel a layer
+    over the prompt's own fresh keys and values — which are the only values
+    of a prompt's size the program holds."""
+    family, cfg = FAMILIES[name]
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")  # read while tracing
+    family.mixed_step_paged._clear_cache()
+    params = jax.eval_shape(lambda key: family.init_params(cfg, key),
+                            jax.random.PRNGKey(0))
+    cache_k, cache_v = jax.eval_shape(
+        lambda: family.init_kv_pages(cfg, PAGES, PAGE_SIZE,
+                                     quantized=quantized))
+    rows = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    try:
+        jaxpr = jax.make_jaxpr(
+            lambda p, ids, lens, ck, cv, t, prompt, n, row:
+            family.mixed_step_paged(p, cfg, ids, lens, ck, cv, t, prompt, n,
+                                    row, window=2 * PAGE_SIZE)
+        )(params, rows, rows, cache_k, cache_v,
+          jax.ShapeDtypeStruct((ROWS, PAGES_PER_ROW), jnp.int32),
+          jax.ShapeDtypeStruct((1, MIXED_WIDTH), jnp.int32),
+          jax.ShapeDtypeStruct((1,), jnp.int32),
+          jax.ShapeDtypeStruct((), jnp.int32)).jaxpr
+    finally:
+        family.mixed_step_paged._clear_cache()  # traced with the env set
+    values = (LAYERS, PAGES, PAGE_SIZE, KV_HEADS, HEAD_DIM)
+    stored = (LAYERS, PAGES, PAGE_SIZE * KV_HEADS, HEAD_DIM)
+    layer_scales = values[1:-1]
+    never = {values[1:], stored[1:]} | (set() if quantized
+                                        else {layer_scales})
+    eqns = list(_equations(jaxpr))
+    sliced = [str(eqn) for eqn in eqns
+              if any(getattr(v.aval, "shape", None) in never
+                     for v in eqn.outvars)]
+    assert not sliced, f"a per-layer slice of the pool is back: {sliced}"
+    calls = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"
+             and not _is_expert_kernel(eqn)]
+    whole = values if quantized else stored
+    over_the_pool = [eqn for eqn in calls
+                     if whole in [v.aval.shape for v in eqn.invars]]
+    assert len(over_the_pool) == LAYERS  # the rows' decode, a layer
+    assert len(calls) == 2 * LAYERS  # and the prompt's prefill, a layer
+    for eqn in calls:
+        if not any(eqn is e for e in over_the_pool):
+            # over the prompt's own T tokens, and no cell of the pool
+            big = [v.aval.shape for v in eqn.invars if len(v.aval.shape) >= 3]
+            assert big and all(MIXED_WIDTH in shape and PAGES not in shape
+                               for shape in big), big
+    # the feed-forward once a layer over the B + T tokens: a mixture's three
+    # grouped products a layer, the decode step's count
+    experts = [eqn for eqn in eqns if eqn.primitive.name == "pallas_call"
+               and _is_expert_kernel(eqn)]
+    assert len(experts) == (3 * LAYERS if name == "mixtral" else 0)
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("name", sorted(FAMILIES))
 def test_a_decode_grid_step_is_one_pair_of_products(name, quantized,
@@ -792,6 +856,67 @@ def test_compiled_decode_burst_copies_no_part_of_the_pool(quantized, one_chip,
     layer_values = r"(bf16|s8)\[400,(128,8|1024),128\]"
     whole_pool = r"(bf16|s8)\[2,400,(128,8|1024),128\]"  # the values; an int8
     # pool's scales are re-laid-out at the loop's ends at either commit
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    assert results
+    bad = [(shape, op) for shape, op in results
+           if re.match(layer_values, shape)
+           or (op == "copy" and re.match(whole_pool, shape))]
+    assert not bad, bad
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_compiled_admitting_burst_copies_no_part_of_the_pool(quantized,
+                                                             one_chip,
+                                                             monkeypatch):
+    """The burst whose first step carries an arrival's prompt
+    (StepPrograms.admit_many: the mixed step, then the scan of the decode
+    steps left), compiled for a v5e at the benchmark cell's pool, widths and
+    prompt width of 128: what the pure decode burst above is held to — no
+    per-layer piece of the value pool, no value pool copied whole — and the
+    kernels a layer that it needs: the decode kernel in the mixed step and
+    in the scan's body, the prefill kernel in the mixed step alone."""
+    from llmlb_tpu.engine.programs import StepPrograms
+
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    for module in (pallas_attention, pallas_moe, ssm):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+    jitted = (llama.decode_step_paged, llama.mixed_step_paged,
+              pallas_attention.paged_flash_decode,
+              pallas_attention.paged_flash_decode_quant,
+              pallas_attention.flash_prefill)
+    for fn in jitted:
+        fn._clear_cache()
+    programs = StepPrograms(llama, CHIP_CFG, None, decode_burst=3,
+                            max_draft_tokens=1, num_slots=CHIP_ROWS,
+                            slot_capacity=CHIP_TABLE * CHIP_PAGE_SIZE,
+                            eos_id=2)
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda key: llama.init_params(CHIP_CFG, key), jax.random.PRNGKey(0)))
+    cache_k, cache_v = on_chip(jax.eval_shape(
+        lambda: llama.init_kv_pages(CHIP_CFG, CHIP_PAGES, CHIP_PAGE_SIZE,
+                                    quantized=quantized)))
+
+    def vector(dtype, *shape):
+        return on_chip(jax.ShapeDtypeStruct(shape or (CHIP_ROWS,), dtype))
+
+    ints, floats = vector(jnp.int32), vector(jnp.float32)
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    try:
+        with jax.default_matmul_precision("default"):
+            hlo = programs._build_admit_many(3, 512).lower(
+                params, ints, ints, cache_k, cache_v,
+                vector(jnp.int32, CHIP_ROWS, CHIP_TABLE), floats, floats,
+                ints, ints, key, vector(jnp.bool_),
+                vector(jnp.int32, 1, 128), vector(jnp.int32, 4),
+                vector(jnp.float32, 2)).compile().as_text()
+    finally:
+        for fn in jitted:
+            fn._clear_cache()
+    # a layer: decode and prefill in the mixed step, decode in the scan
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 6
+    layer_values = r"(bf16|s8)\[400,(128,8|1024),128\]"
+    whole_pool = r"(bf16|s8)\[2,400,(128,8|1024),128\]"
     results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
     assert results
     bad = [(shape, op) for shape, op in results
