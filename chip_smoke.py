@@ -1143,6 +1143,38 @@ def kernel_leg() -> int:
 
         attempt("delta_rule_step", f"slots={slots}", rule_step)
 
+    # the same kernel with a decay a KEY CHANNEL at Kimi-Linear's heads (32
+    # heads, keys and values of 128: a float32 state [128, 4096] a slot, a
+    # block a head): the decay travels with q and k down the sublanes
+    def kda_step(slots=32):
+        def f32(*shape):
+            return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+        q, k = f32(slots, 32, 128) * 128 ** -0.5, f32(slots, 32, 128)
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        v = f32(slots, 32, 128)
+        alpha = jnp.asarray(rng.uniform(0.2, 1.0, (slots, 32, 128)),
+                            jnp.float32)
+        beta = jnp.asarray(rng.uniform(0.0, 1.0, (slots, 32)), jnp.float32)
+        pool_ = f32(2, slots, 128, 32 * 128)
+        live = jnp.arange(slots) % 3 != 1
+        want_o, want = delta_rule.delta_rule_step(  # the jax.numpy route
+            pool_, 1, q, k, v, alpha, beta, live=live)
+        got, o = delta_rule.delta_rule_decode_step(
+            pool_ + 0, 1, q, jnp.where(live[:, None, None], k, 0.0), v,
+            jnp.where(live[:, None, None], alpha, 1.0),
+            jnp.where(live[:, None], beta, 0.0), interpret=False)
+        check("kda_step", "slots=32,out", o[live][None], want_o[live][None])
+        check("kda_step", "slots=32,pool", got.reshape(1, 2 * slots, -1),
+              want.reshape(1, 2 * slots, -1))
+        unmoved = np.array_equal(np.asarray(got[0]), np.asarray(pool_[0])) \
+            and np.array_equal(np.asarray(got[1][~live]),
+                               np.asarray(pool_[1][~live]))
+        check("kda_step", "slots=32,rows not live unmoved",
+              jnp.asarray([[float(unmoved)]]), jnp.asarray([[1.0]]))
+
+    attempt("kda_step", "slots=32", kda_step)
+
     # the state-space step kernel at ONE group of B and C for all 64 heads
     # (Granite-4.0-H) and at Nemotron-3-Nano's 8 groups — one body, a group's
     # rows chosen a turn of its loop — layer 1 of a pool stacked over two, a

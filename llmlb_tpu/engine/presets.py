@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from llmlb_tpu.models.afmoe import AfmoeConfig
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
 from llmlb_tpu.models.granite_hybrid import GraniteHybridConfig
+from llmlb_tpu.models.kimi_linear import KimiLinearConfig
 from llmlb_tpu.models.lfm2_moe import Lfm2MoeConfig
 from llmlb_tpu.models.llama import LlamaConfig
 from llmlb_tpu.models.longcat_flash import LongcatFlashConfig
@@ -165,6 +166,22 @@ PRESETS: dict[str, LlamaConfig] = {
         conv_taps=3, num_dense_layers=2, num_experts=8, experts_per_token=2,
         moe_intermediate_size=48, norm_topk_prob=True,
         routed_scaling_factor=1.0,
+    ),
+    # CI-sized Kimi Delta Attention mixture (models/kimi_linear.py,
+    # docs/kimi-linear.md): K | KK A | KK A, a delta-rule state whose decay
+    # is a number a key channel beside a LATENT page pool that rotates
+    # nothing, one dense feed-forward, then mixtures of which this chip holds
+    # experts 4-7 of 8 (chip 1 of 2), 2 a token, with a shared expert
+    "debug-kimi-linear-tiny": KimiLinearConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=7, num_heads=4, num_kv_heads=4, head_dim=8,
+        rms_eps=1e-5, dtype=jnp.float32, max_position_embeddings=512,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, num_experts=4, router_experts=8, first_expert=4,
+        experts_per_token=2, moe_intermediate_size=32, num_shared_experts=1,
+        first_k_dense=1, routed_scaling_factor=2.446, norm_topk_prob=True,
+        mixers=("kda", "kda", "kda", "mla", "kda", "kda", "mla"),
+        kda_heads=4, kda_head_dim=16, conv_kernel=4, chunk_size=16,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

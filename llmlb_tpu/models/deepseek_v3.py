@@ -100,6 +100,10 @@ class DeepseekV3Config(LlamaConfig):
     q_lora_rank: int | None = None
     q_lora_scale: float = 1.0
     kv_lora_scale: float = 1.0
+    # `mla_use_nope` (models/kimi_linear.py): the block rotates nothing. The
+    # shared key's numbers and the queries' last ones are projected, cached
+    # in the rope cell and scored as they are.
+    mla_nope: bool = False
 
     @property
     def num_moe_layers(self) -> int:
@@ -313,7 +317,8 @@ def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
     `kv_lora_scale`) multiplies its norm's weight, in float32 inside the
     norm: what follows the norm is linear in it, so the scaled latent — and
     for keys and values the latent the POOL keeps — is rounded once. The
-    rope key is not behind the norm and is not scaled."""
+    rope key is not behind the norm and is not scaled. Under `cfg.mla_nope`
+    neither it nor the queries' last numbers are rotated."""
     b, t, _ = x.shape
     heads, c_dim = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -334,13 +339,15 @@ def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
     q = q.reshape(b, t, heads, dn + dr)
     kv = _proj(lp, "wkv_a", h, lora_idx)  # [B, T, C + Dr]
     c = latent_norm(kv[..., :c_dim], "ln_kv", cfg.kv_lora_scale)
-    k_rope = apply_rope(kv[:, :, None, c_dim:], positions, inv_freq,
-                        cfg.rope_interleave)[:, :, 0]  # one head for all
+
+    def rotated(v):
+        return v if cfg.mla_nope else apply_rope(v, positions, inv_freq,
+                                                 cfg.rope_interleave)
+
+    k_rope = rotated(kv[:, :, None, c_dim:])[:, :, 0]  # one head for all
     k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, ROPE_CELL - dr)))
-    query = LatentQuery(
-        q[..., :dn],
-        apply_rope(q[..., dn:], positions, inv_freq, cfg.rope_interleave),
-        lp["wk_b"], lp["wv_b"])
+    query = LatentQuery(q[..., :dn], rotated(q[..., dn:]), lp["wk_b"],
+                        lp["wv_b"])
     out = attn_fn(query, c, k_rope)  # [B, T, H, Dv]
     return x + _proj(lp, "wo", out.reshape(b, t, -1), lora_idx), c, k_rope
 

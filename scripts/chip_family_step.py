@@ -9,7 +9,8 @@ device's time by operation.
 
 Prints one JSON object: seconds per decode step (host clock, the bursts
 after the first), the device's busy share of the traced bursts and its 40
-largest operations (benchmark/trace.py's reduction). For reproducing a
+largest operations (benchmark/trace.py's reduction), and what stopping,
+reading and reducing the trace cost (`trace_cost_s`). For reproducing a
 failure or reading a step's breakdown without gateway, scheduler or
 traffic; a CPU run (JAX_PLATFORMS=cpu, a preset) only shows that it runs.
 """
@@ -121,6 +122,7 @@ def main() -> int:
     jax.block_until_ready(last)
     seconds = time.monotonic() - t0
     jax.profiler.stop_trace()
+    stop_s = time.monotonic() - t0 - seconds
     found = [os.path.join(r, f) for r, _d, fs in os.walk(trace_dir)
              for f in fs if f.endswith(".xplane.pb")]
     out = {"device": devices[0].device_kind, "rows": rows,
@@ -128,8 +130,16 @@ def main() -> int:
            "counters_last_burst": jax.tree.map(
                lambda a: np.asarray(a).sum(0).tolist(), stats)}
     if found:
+        t1 = time.monotonic()
         profile = jax.profiler.ProfileData.from_file(sorted(found)[-1])
+        t2 = time.monotonic()
         red = trace_mod.reduce(profile, window_s=seconds, top=40)
+        # what the benchmark's traced window pays behind its stop (the
+        # launcher does the same three things under a client's 240 s)
+        out["trace_cost_s"] = {
+            "stop_trace": stop_s, "read": t2 - t1,
+            "reduce": time.monotonic() - t2,
+            "device_events": sum(v["count"] for v in red["ops"].values())}
         if args.timeline:
             plane = trace_mod.device_planes(profile)[0]
             events = trace_mod._events(trace_mod._line(plane, trace_mod.OPS_LINE))

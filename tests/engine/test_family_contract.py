@@ -34,7 +34,8 @@ PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "olmo_hybrid": "debug-olmo-hybrid-tiny",
            "afmoe": "debug-trinity-tiny",
            "granite_hybrid": "debug-granite-hybrid-tiny",
-           "lfm2_moe": "debug-lfm2-moe-tiny"}
+           "lfm2_moe": "debug-lfm2-moe-tiny",
+           "kimi_linear": "debug-kimi-linear-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -181,23 +182,26 @@ OLD_MODEL_TYPES = {
     "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
     "olmo_hybrid": "olmo_hybrid", "afmoe": "afmoe",
     "granitemoehybrid": "granite_hybrid", "lfm2_moe": "lfm2_moe",
+    "kimi_linear": "kimi_linear",
 }
 OLD_MECHANISM_KEYS = {
-    "kv_lora_rank": ("deepseek_v3", "longcat_flash"),
+    "kv_lora_rank": ("deepseek_v3", "longcat_flash", "kimi_linear"),
     "q_lora_rank": ("longcat_flash",),
     "zero_expert_num": ("longcat_flash",),
     "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash",
                          "mimo_v2"),
     "n_shared_experts": ("deepseek_v3", "nemotron_h"),
-    "first_k_dense_replace": ("deepseek_v3",),
+    "first_k_dense_replace": ("deepseek_v3", "kimi_linear"),
     "num_local_experts": ("mixtral", "granite_hybrid"),
-    "num_experts": ("mixtral", "sdar_moe", "afmoe", "lfm2_moe"),
+    "num_experts": ("mixtral", "sdar_moe", "afmoe", "lfm2_moe",
+                    "kimi_linear"),
     "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h",
-                              "mimo_v2", "afmoe", "lfm2_moe"),
+                              "mimo_v2", "afmoe", "lfm2_moe", "kimi_linear"),
     "hybrid_override_pattern": ("nemotron_h",),
     "mamba_num_heads": ("nemotron_h",),
     "ssm_state_size": ("nemotron_h",),
-    "expert_parallel": ("nemotron_h", "longcat_flash", "mimo_v2", "afmoe"),
+    "expert_parallel": ("nemotron_h", "longcat_flash", "mimo_v2", "afmoe",
+                        "kimi_linear"),
     # a window and a partial rotary embedding: computed by one family since
     # PR 45, refused for every other as they were for all
     "sliding_window": ("mimo_v2", "afmoe"),
@@ -219,7 +223,7 @@ OLD_MECHANISM_KEYS = {
     # (`num_experts_per_tok`, which every mixture's config carries, is read
     # by it and listed by none: a listed key is refused of all the others)
     "num_dense_layers": ("afmoe", "lfm2_moe"),
-    "num_shared_experts": ("afmoe",),
+    "num_shared_experts": ("afmoe", "kimi_linear"),
     "route_norm": ("afmoe",),
     "route_scale": ("afmoe",),
     "score_func": ("afmoe",),
@@ -241,6 +245,15 @@ OLD_MECHANISM_KEYS = {
     # mixtures' configs carry, are read by it and listed by none)
     **dict.fromkeys(("conv_L_cache", "conv_bias", "use_expert_bias"),
                     ("lfm2_moe",)),
+    # a delta rule whose decay is a number a key channel beside latent
+    # attention that rotates nothing, and a sigmoid-routed mixture under its
+    # own keys: one family since PR 62 (`routed_scaling_factor` and
+    # `topk_group`, which older mixtures' configs carry, are read by it and
+    # listed by none)
+    **dict.fromkeys(("linear_attn_config", "mla_use_nope",
+                     "num_experts_per_token", "moe_router_activation_func",
+                     "moe_renormalize", "use_grouped_topk",
+                     "num_expert_group"), ("kimi_linear",)),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
 }

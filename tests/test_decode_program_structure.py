@@ -1005,6 +1005,53 @@ def test_a_packed_pool_that_rotates_and_two_carried_rows_are_copied_nowhere(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def test_a_state_beside_a_latent_pool_is_stepped_in_place_and_copied_nowhere(
+        one_chip, monkeypatch):
+    """Kimi-Linear-48B-A3B's widths (32 KDA heads of 128 x 128 float32, a
+    decay a key channel; latent attention at 512 + 64 without rotary; 16 of
+    256 experts of 1,024 held) at a cut of three layers (K dense, K, A),
+    compiled for a v5e: the state `[K, slots, 128, 4096]` float32 goes
+    through the `kda_step` kernel aliased in and out and is never copied or
+    re-laid; the two latent pools are written by a scatter and read where
+    they lie; the stacked experts are read in place by the grouped
+    products; the step's temporaries stay small."""
+    from llmlb_tpu.models import kimi_linear
+    from llmlb_tpu.ops import delta_rule
+
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=163840, hidden_size=2304, intermediate_size=9216,
+        num_layers=3, num_heads=32, num_kv_heads=32, head_dim=64,
+        rms_eps=1e-5, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, num_experts=16,
+        router_experts=256, experts_per_token=8, moe_intermediate_size=1024,
+        num_shared_experts=1, first_k_dense=1, routed_scaling_factor=2.446,
+        mixers=("kda", "kda", "mla"), kda_heads=32, kda_head_dim=128)
+    assert [kind for _, kind, _ in kimi_linear.runs(cfg)] == [
+        "kda_dense", "kda_moe", "mla_moe"]
+    compiled = _compiled_burst(
+        one_chip, monkeypatch, kimi_linear, cfg, pages=CHIP_PAGES,
+        rows=CHIP_ROWS, window=512, pool={"num_slots": CHIP_ROWS},
+        kernels=(delta_rule.delta_rule_decode_step,
+                 pallas_attention.paged_latent_decode,
+                 pallas_moe.grouped_expert_matmul))
+    hlo = compiled.as_text()
+    # two rule steps, one latent attention, two mixtures of three products
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 + 1 + 2 * 3
+    assert hlo.count("kda_step") >= 2  # under the vector decay's own name
+    state = rf"f32\[2,{CHIP_ROWS},128,4096\]"
+    pools = rf"bf16\[1,{CHIP_PAGES},128,(512|128)\]"
+    experts = r"bf16\[(1,)?16,(2304,1024|1024,2304)\]"
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    moves_nothing = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    bad = [(shape, op) for shape, op in results
+           if (re.match(state, shape) and op in ("copy", "transpose"))
+           or (re.match(pools, shape) and op in ("copy", "transpose"))
+           or (re.match(experts, shape) and op not in moves_nothing)]
+    assert not bad, bad
+    assert "remat_compressed" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kv_heads,groups", [(8, 4), (2, 16), (32, 1)],
                          ids=["mistral-K8xG4", "nemotron-K2xG16", "MHA-32"])
