@@ -1038,6 +1038,68 @@ def kernel_leg() -> int:
             attempt("paged_latent_decode", f"B={b},pages={pages},dead={dead}",
                     functools.partial(latent_decode, pages, dead))
 
+    # learned sparse attention's three decode calls at dots3-note-prev's
+    # widths (models/dots3_note.py): the indexer's scores read in place from
+    # the index keys behind the rope's tile (64 index heads of 128), the
+    # absorbed decode over the CHOSEN cells alone (128 heads on a 512-wide
+    # latent, a selection a row), and the latent kernel over a ring as a
+    # page of 640 cells (64 heads on a 1,024-wide latent, 513 in use)
+    def sparse_decode(b=16, heads=128, dead=False):
+        p = b * PPN + 1
+        c_pages = jnp.stack([jnp.zeros((p, PS, LAT), bf16), rand(p, PS, LAT)])
+        cells = jnp.concatenate([jnp.pad(
+            rand(p, PS, 64), ((0, 0), (0, 0), (0, ROPE - 64))),
+            rand(p, PS, 128)], axis=-1)
+        r_pages = jnp.stack([jnp.zeros_like(cells), cells])
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, p)).reshape(b, PPN), jnp.int32)
+        lens = rng.integers(1, CAP + 1, b)
+        if dead:
+            lens[::3] = 0
+        lens = jnp.asarray(lens, jnp.int32)
+        q_i, w_i = rand(b, 1, 64, 128), rand(b, 1, 64).astype(jnp.float32)
+        want = xla.paged_index_scores(q_i, w_i, r_pages, 1, tables)[:, 0]
+        got = pa.index_scores_decode(q_i[:, 0], w_i[:, 0], r_pages, 1, tables,
+                                     interpret=False)
+        seen = jnp.arange(CAP)[None, :] < lens[:, None]
+        scale = np.abs(np.asarray(want)).max()
+        check("index_scores_decode", f"B={b},dead={dead}",
+              jnp.where(seen, got, 0.0)[None] / scale,
+              jnp.where(seen, want, 0.0)[None] / scale)
+        chosen = xla.topk_mask(want[:, None], seen[:, None], 256)
+        q_abs, q_rope = rand(b, 1, heads, LAT), rand(b, 1, heads, 64)
+        kw = dict(scale=192 ** -0.5)
+        want = xla.paged_latent_decode(q_abs, q_rope, c_pages, r_pages, 1,
+                                       tables, lens, selected=chosen,
+                                       **kw)[:, 0]
+        got = pa.sparse_latent_decode(
+            q_abs[:, 0], xla._pad_last(q_rope[:, 0], ROPE), c_pages, r_pages,
+            1, tables, lens, chosen[:, 0], interpret=False, **kw)
+        live = np.asarray(lens) > 0
+        check("sparse_latent_decode", f"B={b},dead={dead}",
+              got[live][None], want[live][None])
+
+    for dead in (False, True):
+        attempt("sparse_latent_decode", f"B=16,dead={dead}",
+                functools.partial(sparse_decode, dead=dead))
+
+    def window_latent_decode(b=16, heads=64, cells=640, width=1024):
+        ring_c, ring_r = rand(3, b + 1, cells, width), rand(3, b + 1, cells,
+                                                            ROPE)
+        q_abs, q_rope = rand(b, 1, heads, width), rand(b, 1, heads, ROPE)
+        slots = jnp.asarray(rng.permutation(b), jnp.int32)
+        lens = jnp.asarray(rng.integers(1, 514, b), jnp.int32)
+        seen = (jnp.arange(cells)[None, :] < lens[:, None])[:, None, :]
+        want = xla._latent_attend(q_abs, q_rope, ring_c[2, slots],
+                                  ring_r[2, slots], seen, 256 ** -0.5)[:, 0]
+        got = pa.paged_latent_decode(
+            q_abs[:, 0], q_rope[:, 0], ring_c, ring_r, 2, slots[:, None],
+            lens, scale=256 ** -0.5, interpret=False,
+            name="window_latent_decode")
+        check("window_latent_decode", f"B={b}", got[None], want[None])
+
+    attempt("window_latent_decode", "B=16", window_latent_decode)
+
     # a GROUP of a row's pages a grid step in the two kernels whose pools
     # have no head axis (PR 58), by pa.decode_group of a page in both pools:
     # the latent kernel at longcat-flash-omni's 64 heads (four pages an

@@ -9,10 +9,13 @@ name→engine alias mappings, /root/reference/llmlb/src/models/mapping.rs).
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 from llmlb_tpu.models.afmoe import AfmoeConfig
 from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
+from llmlb_tpu.models.dots3_note import Dots3NoteConfig
 from llmlb_tpu.models.granite_hybrid import GraniteHybridConfig
 from llmlb_tpu.models.kimi_linear import KimiLinearConfig
 from llmlb_tpu.models.lfm2_moe import Lfm2MoeConfig
@@ -182,6 +185,27 @@ PRESETS: dict[str, LlamaConfig] = {
         first_k_dense=1, routed_scaling_factor=2.446, norm_topk_prob=True,
         mixers=("kda", "kda", "kda", "mla", "kda", "kda", "mla"),
         kda_heads=4, kda_head_dim=16, conv_kernel=4, chunk_size=16,
+    ),
+    # docs/sparse-attention.md at a CI size: two full layers whose learned
+    # indexer picks 16 cells a query, then three sliding layers over the last
+    # 5 positions with a latent of their own in a ring a slot, a gate a head
+    # on both, one dense feed-forward, then mixtures of which this chip holds
+    # experts 4-7 of 8 (chip 1 of 2), 2 a token, with a shared expert
+    "debug-dots3-note-tiny": Dots3NoteConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96,
+        num_layers=5, num_heads=4, num_kv_heads=4, head_dim=8,
+        rope_theta=8e7, rms_eps=1e-5, dtype=jnp.float32,
+        max_position_embeddings=512,
+        kv_lora_rank=32, q_lora_rank=24, q_lora_scale=math.sqrt(64 / 24),
+        kv_lora_scale=math.sqrt(64 / 32), qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, num_experts=4, router_experts=8, first_expert=4,
+        experts_per_token=2, moe_intermediate_size=32, num_shared_experts=1,
+        first_k_dense=1, routed_scaling_factor=1.0, norm_topk_prob=True,
+        index_topk=16, index_heads=4, index_head_dim=16,
+        layer_types=("full", "full", "sliding", "sliding", "sliding"),
+        sliding_window=5, swa_heads=2, swa_q_lora_rank=24,
+        swa_kv_lora_rank=48, swa_qk_nope_head_dim=24, swa_qk_rope_head_dim=8,
+        swa_v_head_dim=16, swa_rope_theta=5e4,
     ),
     # flagship serving target (BASELINE.json config #2)
     "llama-3-8b": LlamaConfig(

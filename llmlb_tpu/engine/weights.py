@@ -55,7 +55,15 @@ def _param_builders(cfg: LlamaConfig) -> dict[str, LeafBuilder]:
         return build
 
     from llmlb_tpu.models.deepseek_v3 import DeepseekV3Config
+    from llmlb_tpu.models.dots3_note import Dots3NoteConfig
 
+    if isinstance(cfg, Dots3NoteConfig):
+        raise NotImplementedError(
+            "a dots3_note checkpoint is not loaded: no modelling file is at "
+            "hand to map its tensor names from (the indexer's, the gates', "
+            "the window layers' projections), and a guess would serve wrong "
+            "logits; a dots3_note engine runs seeded weights "
+            "(docs/sparse-attention.md)")
     if isinstance(cfg, DeepseekV3Config):
         return _deepseek_v3_param_builders(cfg, single)
     if getattr(cfg, "num_experts", 0) > 1:

@@ -13,15 +13,15 @@ FAMILIES registers the modules and everything else here is derived.
 chosen class would ignore at the cost of wrong output.
 """
 
-from llmlb_tpu.models import (afmoe, deepseek_v3, granite_hybrid, kimi_linear,
-                              lfm2_moe, llama, longcat_flash, mimo_v2,
+from llmlb_tpu.models import (afmoe, deepseek_v3, dots3_note, granite_hybrid,
+                              kimi_linear, lfm2_moe, llama, longcat_flash, mimo_v2,
                               mixtral, nemotron_h, olmo_hybrid, sdar_moe)
 
 # Adding a family is its module and its line here. The order decides nothing
 # but the order /api/health and /metrics list the families' counters in.
 FAMILIES = (llama, mixtral, deepseek_v3, sdar_moe, longcat_flash, nemotron_h,
             mimo_v2, olmo_hybrid, afmoe, granite_hybrid, lfm2_moe,
-            kimi_linear)
+            kimi_linear, dots3_note)
 
 # Every counter some family computes: an engine of any family exports them
 # all, zero where its own computes none.

@@ -68,7 +68,7 @@ from llmlb_tpu.ops.attention import (
     paged_latent_extend,
 )
 from llmlb_tpu.ops.norms import rms_norm
-from llmlb_tpu.ops.rope import apply_rope
+from llmlb_tpu.ops.rope import apply_partial_rope, apply_rope
 from llmlb_tpu.parallel.sharding import logical_to_sharding
 
 Params = dict[str, Any]
@@ -104,6 +104,14 @@ class DeepseekV3Config(LlamaConfig):
     # shared key's numbers and the queries' last ones are projected, cached
     # in the rope cell and scored as they are.
     mla_nope: bool = False
+    # models/dots3_note.py: a sigmoid GATE a head on the attention's output
+    # (`w_gate` [E, H], from the layer's normed input), and a learned INDEXER
+    # (`_index_block`) whose `index_topk` highest-scored cells are all a
+    # query attends over (0: none, the layer attends over its whole context).
+    attn_gate: bool = False
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
 
     @property
     def num_moe_layers(self) -> int:
@@ -129,7 +137,11 @@ class DeepseekV3Config(LlamaConfig):
             raise NotImplementedError(
                 f"deepseek_v3 config key(s) {bad} = "
                 f"{[hf.get(k) for k in bad]} are not supported by "
-                "models/deepseek_v3.py; refusing to serve wrong logits")
+                "models/deepseek_v3.py; refusing to serve wrong logits"
+                + (" (a low-rank query is computed by _mla_block and served "
+                   "for the model_types longcat_flash and dots3_note, whose "
+                   "checkpoints are not this family's)"
+                   if "q_lora_rank" in bad else ""))
         rope = hf.get("qk_rope_head_dim", 64)
         return cls(
             vocab_size=hf["vocab_size"],
@@ -303,6 +315,43 @@ class LatentQuery(NamedTuple):
     wv_b: jnp.ndarray  # [H, C, Dv]
 
 
+class IndexedQuery(NamedTuple):
+    """LatentQuery of a layer with a learned indexer: beside it the index
+    queries and the weights of their heads (`_index_block`)."""
+
+    nope: jnp.ndarray
+    rope: jnp.ndarray
+    wk_b: jnp.ndarray
+    wv_b: jnp.ndarray
+    index_q: jnp.ndarray  # [B, T, Hi, Di], rotated
+    index_w: jnp.ndarray  # [B, T, Hi] f32, scaled
+
+
+def _index_block(cfg: DeepseekV3Config, lp: Params, h, c_q, positions,
+                 inv_freq):
+    """The learned indexer of DeepSeek-V3.2's sparse attention, from the
+    layer's normed input `h` and the queries' scaled latent `c_q`: index
+    queries q^I = c_q W^I_q [B, T, Hi, Di], ONE index key a token k^I =
+    LayerNorm(h W^I_k) [B, T, Di] (weight and bias, eps 1e-6), both rotated
+    on their first `qk_rope_head_dim` numbers in split halves at the layer's
+    base, and the heads' weights w = h W^I_w Hi^-1/2 Di^-1/2 [B, T, Hi] in
+    float32. A cell's score is sum_j w_j ReLU(q^I_j . k^I)
+    (ops/attention.index_scores)."""
+    b, t, _ = h.shape
+    hi, di = cfg.index_heads, cfg.index_head_dim
+    q_i = _proj_heads(lp, "wi_q", c_q).reshape(b, t, hi, di)
+    k_i = _proj(lp, "wi_k", h).astype(jnp.float32)
+    k_i = k_i - jnp.mean(k_i, axis=-1, keepdims=True)
+    k_i = k_i * jax.lax.rsqrt(jnp.mean(k_i * k_i, axis=-1, keepdims=True)
+                              + 1e-6)
+    k_i = (k_i * lp["ln_ik"].astype(jnp.float32)
+           + lp["ln_ik_bias"].astype(jnp.float32)).astype(h.dtype)
+    w = _proj(lp, "wi_w", h).astype(jnp.float32) * (hi * di) ** -0.5
+    return (apply_partial_rope(q_i, positions, inv_freq),
+            apply_partial_rope(k_i[:, :, None], positions, inv_freq)[:, :, 0],
+            w)
+
+
 def _scale(cfg: DeepseekV3Config) -> float:
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
 
@@ -318,7 +367,11 @@ def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
     norm: what follows the norm is linear in it, so the scaled latent — and
     for keys and values the latent the POOL keeps — is rounded once. The
     rope key is not behind the norm and is not scaled. Under `cfg.mla_nope`
-    neither it nor the queries' last numbers are rotated."""
+    neither it nor the queries' last numbers are rotated. Under
+    `cfg.index_topk` the query carries the indexer's queries and weights
+    (IndexedQuery) and the rope cell the token's index key behind its 128
+    lanes: the third value a token leaves, in the same row. Under
+    `cfg.attn_gate` a head's output is multiplied by sigmoid(h W_g)_h."""
     b, t, _ = x.shape
     heads, c_dim = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -331,9 +384,9 @@ def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
 
     h = rms_norm(x, lp["ln_attn"], cfg.rms_eps)
     if cfg.q_lora_rank:
-        q = _proj_heads(lp, "wq_b", latent_norm(
-            _proj(lp, "wq_a", h, lora_idx), "ln_q", cfg.q_lora_scale),
-            lora_idx)
+        c_q = latent_norm(_proj(lp, "wq_a", h, lora_idx), "ln_q",
+                          cfg.q_lora_scale)
+        q = _proj_heads(lp, "wq_b", c_q, lora_idx)
     else:
         q = _proj_heads(lp, "wq", h, lora_idx)
     q = q.reshape(b, t, heads, dn + dr)
@@ -348,22 +401,35 @@ def _mla_block(cfg: DeepseekV3Config, lp: Params, x, positions, inv_freq,
     k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, ROPE_CELL - dr)))
     query = LatentQuery(q[..., :dn], rotated(q[..., dn:]), lp["wk_b"],
                         lp["wv_b"])
+    if cfg.index_topk:
+        index_q, index_k, index_w = _index_block(cfg, lp, h, c_q, positions,
+                                                 inv_freq)
+        query = IndexedQuery(*query, index_q, index_w)
+        k_rope = jnp.concatenate([k_rope, index_k], axis=-1)
     out = attn_fn(query, c, k_rope)  # [B, T, H, Dv]
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid(_proj(lp, "w_gate", h).astype(jnp.float32))
+        out = (out.astype(jnp.float32) * gate[..., None]).astype(out.dtype)
     return x + _proj(lp, "wo", out.reshape(b, t, -1), lora_idx), c, k_rope
+
+
+def absorb(q: LatentQuery):
+    """The queries carried into the latent space, q_abs = W^K_h q_nope
+    [B, T, H, C]."""
+    return jnp.einsum("bthd,hcd->bthc", q.nope, q.wk_b,
+                      preferred_element_type=jnp.float32
+                      ).astype(q.nope.dtype)
+
+
+def carry_out(mix, q: LatentQuery):
+    """A mix of latents carried back out through W^V_h [B, T, H, Dv]."""
+    return jnp.einsum("bthc,hcd->bthd", mix, q.wv_b,
+                      preferred_element_type=jnp.float32
+                      ).astype(mix.dtype)
 
 
 def _attention(cfg: DeepseekV3Config) -> Attention:
     dr = cfg.qk_rope_head_dim
-
-    def absorb(q: LatentQuery):
-        return jnp.einsum("bthd,hcd->bthc", q.nope, q.wk_b,
-                          preferred_element_type=jnp.float32
-                          ).astype(q.nope.dtype)
-
-    def carry_out(mix, q: LatentQuery):
-        return jnp.einsum("bthc,hcd->bthd", mix, q.wv_b,
-                          preferred_element_type=jnp.float32
-                          ).astype(mix.dtype)
 
     def prefill(q: LatentQuery, c, k_rope, prompt_lens):
         def up(w):  # per-head keys or values of the chunk, from its latent

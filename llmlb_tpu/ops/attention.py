@@ -576,12 +576,22 @@ def paged_latent_extend(
     q_positions: jnp.ndarray,  # [B, T] int32 — global position of each query
     *,
     scale: float,
+    selected: jnp.ndarray | None = None,  # [B, T, S] bool: topk_mask's
 ) -> jnp.ndarray:
     """Absorbed attention of a chunk of queries over row b's pages (earlier
     chunks, a cached prefix, this chunk) of one layer, causal by position:
     plain einsums over the latent gathered at (layer, table) on every
     backend (a chunk's work is the experts', not this; a paged kernel for it
-    is ROADMAP work). Returns the mix of latents [B, T, H, C]."""
+    is ROADMAP work). Returns the mix of latents [B, T, H, C]. Under
+    `selected` a query's softmax runs over the cells it names alone, a BLOCK
+    of pages at a time with an online softmax (`_latent_extend_blocked`):
+    the contexts that call for a selection are too long to hold queries x
+    heads x context scores at once."""
+    if selected is not None:
+        _traced["sparse_latent_extend"] = "xla"
+        return _latent_extend_blocked(q_abs, q_rope, c_pages, r_pages, layer,
+                                      block_tables, q_positions, selected,
+                                      scale)
     _traced["latent_extend"] = "xla"
     c = gather_kv_pages(c_pages, block_tables, layer=layer)  # [B, S, C]
     r = gather_kv_pages(r_pages, block_tables, layer=layer)
@@ -602,30 +612,196 @@ def paged_latent_decode(
     scale: float,
     window: int | None = None,
     work=None,  # paged_decode_work of the same tables, lengths and window
+    selected: jnp.ndarray | None = None,  # [B, 1, S] bool: topk_mask's
 ) -> jnp.ndarray:
     """One-token absorbed attention against one layer of the latent pool:
     same contract as paged_attention_decode (`window`, rows that are not
     live, the stacked pool addressed at (layer, page), `work`). Returns the
-    mix of latents [B, 1, H, C]."""
+    mix of latents [B, 1, H, C]. Under `selected` (over the cells of the
+    swept pages) a row's softmax runs over the cells it names alone; the
+    rope pool's row may then be wider than the rope's tile (an index key
+    behind it), and its first 128 lanes are read."""
     ps = c_pages.shape[2]
     ppn = block_tables.shape[1]
     pages = _window_pages(block_tables, ps, window)
     if _pallas_enabled():
-        from llmlb_tpu.ops.pallas_attention import paged_latent_decode as kernel
+        from llmlb_tpu.ops import pallas_attention as kernels
 
         if work is None:
             work = paged_decode_work(c_pages, r_pages, block_tables, kv_lens,
                                      window)
+        if selected is not None:
+            _traced["sparse_latent_decode"] = "pallas:" + kernels.SPARSE_DECODE
+            note_decode_group(kernels.SPARSE_DECODE, work)
+            return kernels.sparse_latent_decode(
+                q_abs[:, 0], _pad_last(q_rope[:, 0], LANES), c_pages, r_pages,
+                layer, block_tables, kv_lens, selected[:, 0], scale=scale,
+                pages=pages, work=work)[:, None]
         _traced["latent_decode"] = "pallas:paged_latent_decode"
         note_decode_group("paged_latent_decode", work)
-        return kernel(q_abs[:, 0], _pad_last(q_rope[:, 0], r_pages.shape[-1]),
-                      c_pages, r_pages, layer,
-                      block_tables, kv_lens, scale=scale, pages=pages,
-                      work=work)[:, None]
-    _traced["latent_decode"] = "xla"
+        return kernels.paged_latent_decode(
+            q_abs[:, 0], _pad_last(q_rope[:, 0], r_pages.shape[-1]),
+            c_pages, r_pages, layer,
+            block_tables, kv_lens, scale=scale, pages=pages,
+            work=work)[:, None]
     tables = block_tables[:, :pages] if pages < ppn else block_tables
     c = gather_kv_pages(c_pages, tables, layer=layer)  # [B, S, C]
     r = gather_kv_pages(r_pages, tables, layer=layer)
     cell = jnp.arange(c.shape[1], dtype=jnp.int32)
     mask = (cell[None, :] < kv_lens[:, None])[:, None, :]  # [B, 1, S]
+    if selected is None:
+        _traced["latent_decode"] = "xla"
+    else:
+        _traced["sparse_latent_decode"] = "xla"
+        mask, r = mask & selected, r[..., :LANES]
     return _latent_attend(q_abs, q_rope, c, r, mask, scale)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention (DeepSeek-V3.2's "DSA", models/dots3_note.py,
+# docs/sparse-attention.md): beside its latent and rope cell a token leaves
+# an INDEX KEY k^I [Di]; a query's indexer scores every cell it may see,
+# I[t, s] = sum_j w[t, j] ReLU(q^I[t, j] . k^I[s]) over its Hi index heads in
+# float32, and the query attends over the `k` cells of largest I alone — ties
+# to the LOWER position, all cells while it sees no more than k. The choice
+# is EXACT (no approx_max_k): the k-th largest score is found by a search
+# over the scores' bits and the ties at it are counted by position.
+# ---------------------------------------------------------------------------
+
+INDEX_KEY_BLOCK = 2048  # cells a step of index_scores holds head scores for
+EXTEND_KEY_PAGES = 8  # pages a step of _latent_extend_blocked attends over
+
+
+def index_scores(
+    q_index: jnp.ndarray,  # [B, T, Hi, Di]
+    weights: jnp.ndarray,  # [B, T, Hi] f32
+    k_index: jnp.ndarray,  # [B, S, Di]
+) -> jnp.ndarray:
+    """The indexer's scores I [B, T, S] in float32, a block of
+    INDEX_KEY_BLOCK cells at a time: the head scores [B, Hi, T, block] are
+    the largest value held."""
+    s = k_index.shape[1]
+    block = INDEX_KEY_BLOCK if s % INDEX_KEY_BLOCK == 0 else s
+    weights = weights.astype(jnp.float32)
+
+    def one(k):  # [B, block, Di]
+        head = jnp.einsum("bthd,bsd->bhts", q_index, k,
+                          preferred_element_type=jnp.float32)
+        return jnp.einsum("bhts,bth->bts", jnp.maximum(head, 0.0), weights)
+
+    if block == s:
+        return one(k_index)
+    b, _, di = k_index.shape
+    blocks = jnp.moveaxis(k_index.reshape(b, s // block, block, di), 1, 0)
+    return jnp.moveaxis(jax.lax.map(one, blocks), 0, 2).reshape(
+        b, q_index.shape[1], s)
+
+
+def paged_index_scores(
+    q_index: jnp.ndarray,  # [B, T, Hi, Di]
+    weights: jnp.ndarray,  # [B, T, Hi] f32
+    k_pages: jnp.ndarray,  # [L, P, PS, 128 + Di]: the index key's lanes last
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    window: int | None = None,
+) -> jnp.ndarray:
+    """index_scores over row b's pages of one layer of the pool that holds
+    the index keys (the upper lanes of the rope pool's row), [B, T, S] with
+    S the cells of the swept pages; every cell is scored, the caller masks
+    by position. One query a row goes through the Pallas kernel on an
+    unpartitioned TPU (the pool read in place); a chunk's queries gather
+    the keys."""
+    ps, width = k_pages.shape[2:]
+    di = q_index.shape[-1]
+    pages = _window_pages(block_tables, ps, window)
+    if _pallas_enabled() and q_index.shape[1] == 1 and width == 2 * di:
+        from llmlb_tpu.ops import pallas_attention as kernels
+
+        _traced["index_scores"] = "pallas:" + kernels.INDEX_SCORES
+        return kernels.index_scores_decode(
+            q_index[:, 0], weights[:, 0], k_pages, layer, block_tables,
+            pages=pages)[:, None]
+    _traced["index_scores" if q_index.shape[1] == 1
+            else "index_scores_chunk"] = "xla"
+    k = gather_kv_pages(k_pages, block_tables[:, :pages], layer=layer)
+    return index_scores(q_index, weights, k[..., width - di:])
+
+
+def _ordered_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 whose order is the floats' (-0.0 as +0.0)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def topk_mask(scores: jnp.ndarray, valid: jnp.ndarray, k: int) -> jnp.ndarray:
+    """[..., S] bool: the `k` entries of largest `scores` (float32, finite)
+    among those `valid` says may be chosen, ties to the LOWER index; all of
+    the valid ones where they are no more than k. Exact: the k-th largest
+    value is found a bit at a time (32 counts over the row: the largest
+    threshold that at least k entries reach), and of the entries AT it the
+    first by index fill what is left. No sort, no approx_max_k."""
+    keys = jnp.where(valid, _ordered_bits(scores.astype(jnp.float32)),
+                     jnp.uint32(0))  # a finite score's bits are above 0
+
+    def bit(i, kth):
+        trial = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        reach = jnp.sum(keys >= trial[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, trial, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(keys.shape[:-1], jnp.uint32))
+    above = keys > kth[..., None]
+    at = keys == kth[..., None]
+    left = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    first = jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= left[..., None]
+    return valid & (above | (at & first))
+
+
+def _latent_extend_blocked(q_abs, q_rope, c_pages, r_pages, layer,
+                           block_tables, q_positions, selected, scale):
+    """paged_latent_extend under a selection: EXTEND_KEY_PAGES pages of
+    every row's table a step, as many steps as the longest row's context
+    takes, softmax online in float32 (pallas_attention._online_update's
+    rule). The largest value held is one step's scores [B, H, T, block]."""
+    b, t, h, c_dim = q_abs.shape
+    ps = c_pages.shape[2]
+    ppn = block_tables.shape[1]
+    group = min(EXTEND_KEY_PAGES, ppn)
+    steps = -(-ppn // group)
+    pad = steps * group - ppn
+    tables = jnp.pad(block_tables, ((0, 0), (0, pad)))  # the trash page
+    chosen = jnp.pad(selected, ((0, 0), (0, 0), (0, pad * ps)))
+    block = group * ps
+    q_rope = _pad_last(q_rope, LANES)
+
+    def step(j, carry):
+        m, l, acc = carry
+        at = (layer, jax.lax.dynamic_slice_in_dim(tables, j * group, group, 1))
+        c = c_pages[at].reshape(b, block, c_dim)
+        r = r_pages[at][..., :LANES].reshape(b, block, LANES)
+        cell = j * block + jnp.arange(block, dtype=jnp.int32)
+        seen = (cell[None, None, :] <= q_positions[:, :, None]
+                ) & jax.lax.dynamic_slice_in_dim(chosen, j * block, block, 2)
+        scores = (jnp.einsum("bthc,bsc->bhts", q_abs, c,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bthr,bsr->bhts", q_rope, r,
+                               preferred_element_type=jnp.float32)) * scale
+        scores = jnp.where(seen[:, None], scores, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        fix = jnp.exp(m - m_new)
+        # a masked cell's weight is exp(-1e30 - m) = 0 once the row has met
+        # a cell it sees; what a row gathers before that (weights of 1 where
+        # m is still -1e30) the first real score wipes: `fix` is then 0
+        p = jnp.exp(scores - m_new)
+        l = l * fix + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * fix + jnp.einsum("bhts,bsc->bhtc", p.astype(c.dtype), c,
+                                     preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    live = jnp.minimum(-(-(jnp.max(q_positions) + 1) // block), steps)
+    _, l, acc = jax.lax.fori_loop(0, live, step, (
+        jnp.full((b, h, t, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((b, h, t, 1), jnp.float32),
+        jnp.zeros((b, h, t, c_dim), jnp.float32)))
+    out = acc / jnp.where(l == 0.0, 1.0, l)
+    return out.transpose(0, 2, 1, 3).astype(q_abs.dtype)

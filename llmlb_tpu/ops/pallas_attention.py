@@ -652,6 +652,7 @@ def _paged_latent_decode_kernel(
     qr_ref,  # [1, H, R] — rotated rope part of the queries
     *refs,
     block_k: int, sweep: int, scale: float, group: int,
+    selection: bool = False,
 ):
     """One grid step: item i of the work-list is the `group` pages from `s`
     on of row `row` (_decode_item's contract: online softmax across a row's
@@ -661,11 +662,14 @@ def _paged_latent_decode_kernel(
     are two products (latent part, rope part), the values are the tiles
     themselves — two products, one update and one mix a step whatever the
     group. `refs`: the group's latent blocks, G of [1, PS, C], and its rope
-    blocks, G of [1, PS, R]; the output [1, H, C]; the scratch m, l [H, 1]
-    and acc [H, C], f32."""
+    blocks, G of [1, PS, R]; under `selection` a third block a page, the
+    row's selection of the page's cells [1, 1, PS] f32 (1 chosen, 0 not: a
+    cell enters the softmax iff it is live AND chosen); the output
+    [1, H, C]; the scratch m, l [H, 1] and acc [H, C], f32."""
     del layer_ref, pool_page_of_ref
     c_refs, r_refs = refs[:group], refs[group:2 * group]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
+    sel_refs = refs[2 * group:3 * group] if selection else ()
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * group + len(sel_refs):]
     s, kv_len, _, last, _ = _row_item(
         row_of_ref, page_of_ref, kv_lens_ref, block_k=block_k, sweep=sweep,
         group=group)
@@ -686,7 +690,13 @@ def _paged_latent_decode_kernel(
             + jax.lax.dot_general(qr_ref[0], _stacked(r_refs), nt,
                                   preferred_element_type=jnp.float32)
         ) * scale  # [H, G*PS]
-        scores = jnp.where(col < kv_len, scores, _NEG_INF)
+        keep = col < kv_len
+        if selection:
+            chosen = [ref[0] for ref in sel_refs]  # G of [1, PS]
+            keep = jnp.logical_and(keep, (
+                chosen[0] if group == 1
+                else jnp.concatenate(chosen, axis=1)) > 0.5)
+        scores = jnp.where(keep, scores, _NEG_INF)
         _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, c)
 
     @pl.when(_holds_last_page(s, last, group))
@@ -706,7 +716,8 @@ def _headless_pools(*pools):
         kv_operands=pools, page_size=ps, page_elements=ps * sum(widths))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret",
+                                             "name"))
 def paged_latent_decode(
     q_abs: jnp.ndarray,  # [B, H, C]
     q_rope: jnp.ndarray,  # [B, H, R]
@@ -720,6 +731,7 @@ def paged_latent_decode(
     pages: int | None = None,
     work: DecodeWork | None = None,
     interpret: bool | None = None,
+    name: str = "paged_latent_decode",  # the call's name in a device trace
 ) -> jnp.ndarray:
     """Ragged PAGED one-token ABSORBED latent attention. Returns the mix of
     latents [B, H, C]. Grid, `work`, `layer`, `pages` and the rows that are
@@ -733,7 +745,143 @@ def paged_latent_decode(
         _paged_latent_decode_kernel, queries=(q_abs, q_rope), layer=layer,
         block_tables=block_tables, kv_lens=kv_lens, work=work,
         **_headless_pools(c_pages, r_pages), out_dim=c_dim, pages=pages,
-        interpret=interpret, name="paged_latent_decode", scale=scale)
+        interpret=interpret, name=name, scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention (models/dots3_note.py, docs/sparse-attention.md):
+# a token leaves an INDEX KEY beside its latent and rope cell, in the upper
+# lanes of the rope pool's row [L, P, PS, 128 + Di]; a decode step scores
+# every live cell of a row against the row's index queries
+# (`index_scores_decode`), the caller picks the top-k of them exactly
+# (ops/attention.topk_mask), and the absorbed attention runs over the chosen
+# cells alone (`sparse_latent_decode`). Both kernels read WHOLE pages — the
+# selection is a mask over the work-list's pages, not a gather of cells: at
+# 16 rows x 15k cells that is 0.6 GB a step against 0.2 GB of chosen cells,
+# and a later change may read only the pages that hold a chosen cell.
+# ---------------------------------------------------------------------------
+
+SPARSE_DECODE = "sparse_latent_decode"  # the calls' names in a device trace
+INDEX_SCORES = "index_scores_decode"
+_INDEX_GROUPS = (8, 4, 2, 1)  # pages a grid step of the score kernel takes
+
+
+def _selected_map(sweep, g, group, i, layer, row_of, page_of, pool_page_of,
+                  *lens):
+    """The selection [B, sweep, 1, PS]: the item's row, its g-th LOGICAL
+    page (a short last group's missing pages repeat the row's last: their
+    cells lie past the row's length and are masked by it)."""
+    return (row_of[i], jnp.minimum(page_of[i] + g, sweep - 1), 0, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+def sparse_latent_decode(
+    q_abs: jnp.ndarray,  # [B, H, C]
+    q_rope: jnp.ndarray,  # [B, H, 128]
+    c_pages: jnp.ndarray,  # [L, P, PS, C]
+    r_pages: jnp.ndarray,  # [L, P, PS, 128 + Di]: rope cell | index key
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    kv_lens: jnp.ndarray,  # [B] int32 — valid logical length; 0 = not live
+    selected: jnp.ndarray,  # [B, S] bool, S the swept pages' cells
+    *,
+    scale: float,
+    pages: int | None = None,
+    work: DecodeWork | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """paged_latent_decode over the cells `selected` says alone: the same
+    kernel over the same work-list of live pages with one more block a page
+    (the row's selection of its cells). Returns the mix of latents [B, H, C].
+    The rope pool's row carries the index key in its upper lanes; the block
+    read here is its first 128."""
+    b, _, c_dim = q_abs.shape
+    ps = c_pages.shape[2]
+    sweep = _swept_pages(block_tables, pages)
+    lanes = q_rope.shape[-1]
+    chosen = selected.astype(jnp.float32).reshape(b, sweep, 1, ps)
+    return _paged_decode_call(
+        _paged_latent_decode_kernel, queries=(q_abs, q_rope), layer=layer,
+        block_tables=block_tables, kv_lens=kv_lens, work=work,
+        kv_blocks=[((None, 1, ps, c_dim), _pool_rows_map),
+                   ((None, 1, ps, lanes), _pool_rows_map),
+                   ((None, 1, 1, ps), functools.partial(_selected_map, sweep))],
+        kv_operands=(c_pages, r_pages, chosen), page_size=ps,
+        page_elements=ps * (c_dim + r_pages.shape[-1]), out_dim=c_dim,
+        pages=pages, interpret=interpret, name=SPARSE_DECODE, scale=scale,
+        selection=True)
+
+
+def _index_scores_kernel(layer_ref, tables_ref, q_ref, w_ref, *refs,
+                         group: int):
+    """One grid step: `group` pages of row b's table. q [1, Hi, Di] against
+    the pages' index keys, G of [1, PS, Di]: ReLU of every head's product,
+    times the head's weight w [1, Hi, 1] f32, summed over the heads, in
+    float32. The output block is the pages' cells [1, 1, 1, G*PS]."""
+    del layer_ref, tables_ref
+    k_refs, o_ref = refs[:group], refs[group]
+    scores = jax.lax.dot_general(
+        q_ref[0], _stacked(k_refs), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)  # [Hi, G*PS]
+    o_ref[0, 0] = jnp.sum(jnp.maximum(scores, 0.0) * w_ref[0], axis=0,
+                          keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("pages", "lane_block",
+                                             "interpret"))
+def index_scores_decode(
+    q_index: jnp.ndarray,  # [B, Hi, Di]
+    weights: jnp.ndarray,  # [B, Hi] f32, the heads' weights
+    k_pages: jnp.ndarray,  # [L, P, PS, W]: the index key in lanes
+    # [lane_block * Di, (lane_block + 1) * Di) of a row
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    *,
+    pages: int | None = None,
+    lane_block: int = 1,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """The learned indexer's scores of one query a row over the first
+    `pages` pages of its table: I[b, s] = sum_j w[b, j] ReLU(q[b, j] .
+    k[s]) in float32, [B, pages * PS]. EVERY page of the sweep is scored,
+    whatever the row's length (a table's unused entries are the trash page):
+    the caller masks by position. The pool is read in place at (layer, page,
+    the key's lanes)."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, hi, di = q_index.shape
+    ps = k_pages.shape[2]
+    sweep = _swept_pages(block_tables, pages)
+    group = next(g for g in _INDEX_GROUPS if sweep % g == 0)
+    steps = sweep // group
+
+    def page_map(g, bi, ji, layer, tables):
+        return (layer[0], tables[bi, ji * group + g], 0, lane_block)
+
+    def row_map(bi, ji, layer, tables):
+        return (bi, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, steps),
+        in_specs=[
+            pl.BlockSpec((1, hi, di), row_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, hi, 1), row_map, memory_space=pltpu.VMEM),
+            *(pl.BlockSpec((None, 1, ps, di), functools.partial(page_map, g),
+                           memory_space=pltpu.VMEM) for g in range(group))],
+        out_specs=pl.BlockSpec((1, 1, 1, group * ps),
+                               lambda bi, ji, layer, tables: (bi, ji, 0, 0),
+                               memory_space=pltpu.VMEM),
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_scores_kernel, group=group),
+        out_shape=jax.ShapeDtypeStruct((b, steps, 1, group * ps),
+                                       jnp.float32),
+        grid_spec=grid_spec, interpret=interpret, name=INDEX_SCORES,
+    )(_layer_operand(layer), block_tables[:, :sweep].astype(jnp.int32),
+      q_index, weights.astype(jnp.float32)[:, :, None],
+      *(k_pages for _ in range(group)))
+    return out.reshape(b, sweep * ps)
 
 
 # ---------------------------------------------------------------------------

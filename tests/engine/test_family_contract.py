@@ -35,7 +35,8 @@ PRESETS = {"llama": "debug-tiny", "mixtral": "debug-moe-tiny",
            "afmoe": "debug-trinity-tiny",
            "granite_hybrid": "debug-granite-hybrid-tiny",
            "lfm2_moe": "debug-lfm2-moe-tiny",
-           "kimi_linear": "debug-kimi-linear-tiny"}
+           "kimi_linear": "debug-kimi-linear-tiny",
+           "dots3_note": "debug-dots3-note-tiny"}
 MODULES = {m.FAMILY.name: m for m in FAMILIES}
 
 PAGED = ("prefill_into_pages", "prefill_extend_pages", "verify_step_paged",
@@ -182,36 +183,39 @@ OLD_MODEL_TYPES = {
     "longcat_flash": "longcat_flash", "mimo_v2": "mimo_v2",
     "olmo_hybrid": "olmo_hybrid", "afmoe": "afmoe",
     "granitemoehybrid": "granite_hybrid", "lfm2_moe": "lfm2_moe",
-    "kimi_linear": "kimi_linear",
+    "kimi_linear": "kimi_linear", "dots3_note": "dots3_note",
 }
 OLD_MECHANISM_KEYS = {
-    "kv_lora_rank": ("deepseek_v3", "longcat_flash", "kimi_linear"),
-    "q_lora_rank": ("longcat_flash",),
+    "kv_lora_rank": ("deepseek_v3", "longcat_flash", "kimi_linear",
+                     "dots3_note"),
+    "q_lora_rank": ("longcat_flash", "dots3_note"),
     "zero_expert_num": ("longcat_flash",),
     "n_routed_experts": ("deepseek_v3", "nemotron_h", "longcat_flash",
-                         "mimo_v2"),
-    "n_shared_experts": ("deepseek_v3", "nemotron_h"),
-    "first_k_dense_replace": ("deepseek_v3", "kimi_linear"),
+                         "mimo_v2", "dots3_note"),
+    "n_shared_experts": ("deepseek_v3", "nemotron_h", "dots3_note"),
+    "first_k_dense_replace": ("deepseek_v3", "kimi_linear", "dots3_note"),
     "num_local_experts": ("mixtral", "granite_hybrid"),
     "num_experts": ("mixtral", "sdar_moe", "afmoe", "lfm2_moe",
                     "kimi_linear"),
     "moe_intermediate_size": ("deepseek_v3", "sdar_moe", "nemotron_h",
-                              "mimo_v2", "afmoe", "lfm2_moe", "kimi_linear"),
+                              "mimo_v2", "afmoe", "lfm2_moe", "kimi_linear",
+                              "dots3_note"),
     "hybrid_override_pattern": ("nemotron_h",),
     "mamba_num_heads": ("nemotron_h",),
     "ssm_state_size": ("nemotron_h",),
     "expert_parallel": ("nemotron_h", "longcat_flash", "mimo_v2", "afmoe",
-                        "kimi_linear"),
+                        "kimi_linear", "dots3_note"),
     # a window and a partial rotary embedding: computed by one family since
     # PR 45, refused for every other as they were for all
     "sliding_window": ("mimo_v2", "afmoe"),
     "partial_rotary_factor": ("mimo_v2",),
     "hybrid_layer_pattern": ("mimo_v2",),
     "moe_layer_freq": ("mimo_v2",),
-    "swa_num_key_value_heads": ("mimo_v2",),
+    "swa_num_key_value_heads": ("mimo_v2", "dots3_note"),
     # kinds of layer and the linear-attention layers' sizes: computed by one
     # family since PR 48; before it no class read them and none refused them
-    "layer_types": ("olmo_hybrid", "afmoe", "granite_hybrid", "lfm2_moe"),
+    "layer_types": ("olmo_hybrid", "afmoe", "granite_hybrid", "lfm2_moe",
+                    "dots3_note"),
     "linear_num_key_heads": ("olmo_hybrid",),
     "linear_num_value_heads": ("olmo_hybrid",),
     "linear_key_head_dim": ("olmo_hybrid",),
@@ -254,6 +258,15 @@ OLD_MECHANISM_KEYS = {
                      "num_experts_per_token", "moe_router_activation_func",
                      "moe_renormalize", "use_grouped_topk",
                      "num_expert_group"), ("kimi_linear",)),
+    # a learned indexer whose top-k a full layer attends over, window layers
+    # with a latent of their own, a gate a head and both latents rescaled:
+    # one family since PR 64 (`sliding_window_size` and
+    # `swa_num_attention_heads`, which mimo_v2's configs carry, are read by
+    # it and listed by none)
+    **dict.fromkeys(("index_topk", "index_n_heads", "index_head_dim",
+                     "attention_gate_type", "swa_attention_gate_type",
+                     "apply_mla_qkv_lora_rescale", "swa_kv_lora_rank",
+                     "swa_q_lora_rank"), ("dots3_note",)),
     "attn_logit_softcapping": (),
     "final_logit_softcapping": (),
 }
@@ -316,13 +329,13 @@ LINEAR_STATED = [
 
 
 @pytest.mark.parametrize("stated", LINEAR_STATED, ids=lambda d: next(iter(d)))
-# (afmoe, granite_hybrid and lfm2_moe read `layer_types` too and refuse a
-# kind they do not compute in their own class, by name:
+# (afmoe, granite_hybrid, lfm2_moe and dots3_note read `layer_types` too and
+# refuse a kind they do not compute in their own class, by name:
 # tests/engine/test_band_family.py, test_granite_family.py,
-# test_conv_moe_family.py)
+# test_conv_moe_family.py, test_sparse_family.py)
 @pytest.mark.parametrize("model_type", sorted(
     set(OLD_MODEL_TYPES) - {"olmo_hybrid", "afmoe", "granitemoehybrid",
-                            "lfm2_moe"})
+                            "lfm2_moe", "dots3_note"})
     + ["a_type_nobody_registered"])
 def test_a_linear_attention_config_is_served_as_no_other_model(
         model_type, stated):
@@ -343,7 +356,7 @@ def test_a_linear_attention_config_is_served_as_no_other_model(
 @pytest.mark.parametrize("stated", LINEAR_STATED[2:],
                          ids=lambda d: next(iter(d)))
 @pytest.mark.parametrize("model_type", ["afmoe", "granitemoehybrid",
-                                        "lfm2_moe"])
+                                        "lfm2_moe", "dots3_note"])
 def test_a_linear_key_is_refused_of_the_window_band_family_too(model_type,
                                                                stated):
     (key, value), = stated.items()

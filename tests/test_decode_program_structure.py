@@ -1081,6 +1081,61 @@ def test_a_state_beside_a_latent_pool_is_stepped_in_place_and_copied_nowhere(
     assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20
 
 
+def test_pages_with_index_keys_and_a_latent_ring_are_read_where_they_lie(
+        one_chip, monkeypatch):
+    """dots3-note-prev's widths (a full layer's 128 heads on a latent of 512
+    with 64 index heads of 128 and a top-k of 2,048; a sliding layer's 64
+    heads on a latent of 1,024 over 513 positions; 32 of 256 experts of
+    1,536 held) at a cut of three layers (F dense, F, S), compiled for a
+    v5e: Mosaic takes the score kernel, the sparse decode kernel and the
+    latent kernel over a ring of 640 cells; the two page pools — the second
+    a row of 256 lanes, the index key behind the rope's tile — and the
+    rings are written by scatters and read where they lie, never copied or
+    re-laid; the step's temporaries stay small."""
+    from llmlb_tpu.models import dots3_note
+
+    cfg = dots3_note.Dots3NoteConfig(
+        vocab_size=19008, hidden_size=5120, intermediate_size=13824,
+        num_layers=3, num_heads=128, num_kv_heads=128, head_dim=64,
+        rope_theta=8e7, rms_eps=1e-5, kv_lora_rank=512, q_lora_rank=1024,
+        q_lora_scale=5 ** 0.5, kv_lora_scale=10 ** 0.5,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_experts=32, router_experts=256, experts_per_token=8,
+        moe_intermediate_size=1536, num_shared_experts=1, first_k_dense=1,
+        routed_scaling_factor=1.0,
+        layer_types=("full", "full", "sliding"))
+    assert [kind for _, kind, _ in dots3_note.runs(cfg)] == [
+        "full_dense", "full_moe", "sliding_moe"]
+    compiled = _compiled_burst(
+        one_chip, monkeypatch, dots3_note, cfg, pages=CHIP_PAGES,
+        rows=CHIP_ROWS, window=CHIP_TABLE * CHIP_PAGE_SIZE,
+        pool={"num_slots": CHIP_ROWS},
+        kernels=(pallas_attention.index_scores_decode,
+                 pallas_attention.sparse_latent_decode,
+                 pallas_attention.paged_latent_decode,
+                 pallas_moe.grouped_expert_matmul,
+                 pallas_moe.expert_rows_in_place))
+    hlo = compiled.as_text()
+    for kernel, calls in (("index_scores_decode", 2),
+                          ("sparse_latent_decode", 2),
+                          ("window_latent_decode", 1)):
+        assert len(re.findall(rf'custom_call_target="tpu_custom_call"[^\n]*'
+                              rf'{kernel}|{kernel}[^\n]*tpu_custom_call',
+                              hlo)) >= calls, kernel
+    pools = rf"bf16\[2,{CHIP_PAGES},128,(512|256)\]"
+    rings = rf"bf16\[1,{CHIP_ROWS + 1},640,(1024|128)\]"
+    experts = r"bf16\[(1,)?32,(5120,1536|1536,5120)\]"
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    moves_nothing = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    bad = [(shape, op) for shape, op in results
+           if (re.match(pools, shape) and op in ("copy", "transpose"))
+           or (re.match(rings, shape) and op in ("copy", "transpose"))
+           or (re.match(experts, shape) and op not in moves_nothing)]
+    assert not bad, bad
+    assert "remat_compressed" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kv_heads,groups", [(8, 4), (2, 16), (32, 1)],
                          ids=["mistral-K8xG4", "nemotron-K2xG16", "MHA-32"])
