@@ -1044,15 +1044,20 @@ def kernel_leg() -> int:
     # absorbed decode over the CHOSEN cells alone (128 heads on a 512-wide
     # latent, a selection a row), and the latent kernel over a ring as a
     # page of 640 cells (64 heads on a 1,024-wide latent, 513 in use)
-    def sparse_decode(b=16, heads=128, dead=False):
+    def sparse_pools(b):
+        """Layer 1 of a latent pool and of a rope pool with the index key
+        behind the rope's tile, for b rows, and scattered tables."""
         p = b * PPN + 1
-        c_pages = jnp.stack([jnp.zeros((p, PS, LAT), bf16), rand(p, PS, LAT)])
-        cells = jnp.concatenate([jnp.pad(
+        c_pages = stacked(rand(p, PS, LAT), 1)
+        r_pages = stacked(jnp.concatenate([jnp.pad(
             rand(p, PS, 64), ((0, 0), (0, 0), (0, ROPE - 64))),
-            rand(p, PS, 128)], axis=-1)
-        r_pages = jnp.stack([jnp.zeros_like(cells), cells])
+            rand(p, PS, 128)], axis=-1), 1)
         tables = jnp.asarray(
             rng.permutation(np.arange(1, p)).reshape(b, PPN), jnp.int32)
+        return c_pages, r_pages, tables
+
+    def sparse_decode(b=16, heads=128, dead=False):
+        c_pages, r_pages, tables = sparse_pools(b)
         lens = rng.integers(1, CAP + 1, b)
         if dead:
             lens[::3] = 0
@@ -1082,6 +1087,28 @@ def kernel_leg() -> int:
     for dead in (False, True):
         attempt("sparse_latent_decode", f"B=16,dead={dead}",
                 functools.partial(sparse_decode, dead=dead))
+
+    # ... and an extend chunk's attention under the selection: 128 queries
+    # x 128 heads a row, rows of different contexts and one with padding
+    # queries, against the blocked einsums (valid queries alone count)
+    def sparse_extend(b=2, t=128, heads=128):
+        c_pages, r_pages, tables = sparse_pools(b)
+        starts = jnp.asarray([CAP - t, 300], jnp.int32)
+        positions = starts[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+        seen = jnp.arange(CAP)[None, None, :] <= positions[:, :, None]
+        chosen = xla.topk_mask(rand(b, t, CAP).astype(jnp.float32), seen, 256)
+        q_abs, q_rope = rand(b, t, heads, LAT), rand(b, t, heads, 64)
+        lens = jnp.asarray([t, t - 40], jnp.int32)
+        want = xla._latent_extend_blocked(
+            q_abs, q_rope, c_pages, r_pages, 1, tables, positions, chosen,
+            192 ** -0.5)
+        got = pa.sparse_latent_extend(
+            q_abs, xla._pad_last(q_rope, ROPE), c_pages, r_pages, 1, tables,
+            positions, lens, chosen, scale=192 ** -0.5, interpret=False)
+        check("sparse_latent_extend", f"B={b},T={t}",
+              got.reshape(b, t, -1), want.reshape(b, t, -1), valid=lens)
+
+    attempt("sparse_latent_extend", "B=2,T=128", sparse_extend)
 
     def window_latent_decode(b=16, heads=64, cells=640, width=1024):
         ring_c, ring_r = rand(3, b + 1, cells, width), rand(3, b + 1, cells,

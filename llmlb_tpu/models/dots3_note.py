@@ -486,7 +486,6 @@ def _sparse_attention(cfg: Dots3NoteConfig, selection: bool = False
             absorb(q), q.rope, c, cell[..., :ROPE_CELL], chosen, scale), q)
 
     def extend(q, c_pool, r_pool, layer, tables, positions, chunk_lens):
-        del chunk_lens  # padding queries attend like real ones; discarded
         with jax.named_scope("index_select"):
             scores = paged_index_scores(q.index_q, q.index_w, r_pool, layer,
                                         tables)
@@ -497,7 +496,7 @@ def _sparse_attention(cfg: Dots3NoteConfig, selection: bool = False
         with jax.named_scope("sparse_latent_extend"):
             return carry_out(paged_latent_extend(
                 absorb(q), q.rope, c_pool, r_pool, layer, tables, positions,
-                scale=scale, selected=chosen), q)
+                scale=scale, selected=chosen, chunk_lens=chunk_lens), q)
 
     def decode(q, c_pool, r_pool, layer, tables, kv_lens, *, window=None,
                work=None):

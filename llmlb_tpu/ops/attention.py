@@ -577,17 +577,34 @@ def paged_latent_extend(
     *,
     scale: float,
     selected: jnp.ndarray | None = None,  # [B, T, S] bool: topk_mask's
+    chunk_lens: jnp.ndarray | None = None,  # [B] int32 — valid queries
 ) -> jnp.ndarray:
     """Absorbed attention of a chunk of queries over row b's pages (earlier
-    chunks, a cached prefix, this chunk) of one layer, causal by position:
-    plain einsums over the latent gathered at (layer, table) on every
-    backend (a chunk's work is the experts', not this; a paged kernel for it
-    is ROADMAP work). Returns the mix of latents [B, T, H, C]. Under
-    `selected` a query's softmax runs over the cells it names alone, a BLOCK
-    of pages at a time with an online softmax (`_latent_extend_blocked`):
-    the contexts that call for a selection are too long to hold queries x
-    heads x context scores at once."""
+    chunks, a cached prefix, this chunk) of one layer, causal by position.
+    Returns the mix of latents [B, T, H, C]. Without a selection: plain
+    einsums over the latent gathered at (layer, table) on every backend (a
+    chunk's work is the experts', not this). Under `selected` a query's
+    softmax runs over the cells it names alone — contexts too long to hold
+    queries x heads x context scores at once: on an unpartitioned TPU ONE
+    Pallas kernel with a block's scores in VMEM
+    (`pallas_attention.sparse_latent_extend`: the pools read in place, key
+    groups past a q block's last position and q blocks past `chunk_lens`
+    skipped, so a padding query's row may come back as zeros), where its
+    shapes are whole tiles (heads a multiple of 16, queries of 8); elsewhere
+    — the CPU, a partitioned mesh, the tests' plain reference — a BLOCK of
+    pages at a time in einsums with an online softmax
+    (`_latent_extend_blocked`: padding queries attend like real ones)."""
     if selected is not None:
+        _, t, h, _ = q_abs.shape
+        if _pallas_enabled() and h % 16 == 0 and t % 8 == 0:
+            from llmlb_tpu.ops import pallas_attention as kernels
+
+            _traced["sparse_latent_extend"] = "pallas:" + kernels.SPARSE_EXTEND
+            if chunk_lens is None:
+                chunk_lens = jnp.full(q_abs.shape[:1], t, jnp.int32)
+            return kernels.sparse_latent_extend(
+                q_abs, _pad_last(q_rope, LANES), c_pages, r_pages, layer,
+                block_tables, q_positions, chunk_lens, selected, scale=scale)
         _traced["sparse_latent_extend"] = "xla"
         return _latent_extend_blocked(q_abs, q_rope, c_pages, r_pages, layer,
                                       block_tables, q_positions, selected,
@@ -759,10 +776,13 @@ def topk_mask(scores: jnp.ndarray, valid: jnp.ndarray, k: int) -> jnp.ndarray:
 
 def _latent_extend_blocked(q_abs, q_rope, c_pages, r_pages, layer,
                            block_tables, q_positions, selected, scale):
-    """paged_latent_extend under a selection: EXTEND_KEY_PAGES pages of
-    every row's table a step, as many steps as the longest row's context
-    takes, softmax online in float32 (pallas_attention._online_update's
-    rule). The largest value held is one step's scores [B, H, T, block]."""
+    """paged_latent_extend under a selection as plain einsums — the route
+    of the CPU and of a partitioned mesh, and what the Pallas kernel
+    (pallas_attention.sparse_latent_extend) is checked against:
+    EXTEND_KEY_PAGES pages of every row's table a step, as many steps as
+    the longest row's context takes, softmax online in float32
+    (pallas_attention._online_update's rule). The largest value held is one
+    step's scores [B, H, T, block], in HBM."""
     b, t, h, c_dim = q_abs.shape
     ps = c_pages.shape[2]
     ppn = block_tables.shape[1]

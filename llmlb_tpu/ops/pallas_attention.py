@@ -885,6 +885,194 @@ def index_scores_decode(
 
 
 # ---------------------------------------------------------------------------
+# A chunk's attention under a selection (an extend chunk of the full layers):
+# q [B, T, H, C | 128] against the same two pools, every head of a query
+# over ONE latent key block under ONE mask row, so the chunk's (query, head)
+# pairs are the rows of a matmul against [cells, C + 128] and the scores
+# [rows, cells] live in VMEM between the two products. In XLA
+# (ops/attention._latent_extend_blocked) the same step writes them to HBM:
+# 268 MB of float32 a block of 1,024 cells at 512 queries x 128 heads, read
+# and rewritten by the mask, the maximum, the exponential, a re-laid bf16
+# copy and the accumulator's rescale — 2 GB of traffic for 155 GFLOP.
+# ---------------------------------------------------------------------------
+
+SPARSE_EXTEND = "sparse_latent_extend"  # the call's name in a device trace
+# The q block and the pages a grid step takes at most: the measured pick
+# (scripts/extend_select_cost.py on a v5e, PERF.md §6 PR 65: 16 queries x 4
+# pages — scores [2048, 512] f32, 4 MB — ran the products at 90% of the MXU
+# at 6k and 12k; 8 x 8 within 4% of it, a page a step at 38%: the
+# accumulator's rescale a step is the cost a group amortises).
+SPARSE_EXTEND_BLOCK_Q = 16
+_SPARSE_EXTEND_GROUPS = (4, 2, 1)
+
+
+def sparse_extend_blocks(queries: int, pages: int) -> tuple[int, int]:
+    """(q block, pages a grid step) of `sparse_latent_extend` at a chunk of
+    `queries` over a table of `pages`: from the shapes alone. The group is
+    the largest of 4, 2, 1 that divides the table (no padded copy of the
+    table or the selection)."""
+    return (min(SPARSE_EXTEND_BLOCK_Q, queries),
+            next(g for g in _SPARSE_EXTEND_GROUPS if pages % g == 0))
+
+
+def _sparse_extend_kernel(
+    layer_ref, tables_ref,
+    last_ref,  # [B, NQ] — a q block's last position; -1: all padding
+    qc_ref,  # [1, BLK_Q*H, C] — row r the query r // H, head r % H
+    qr_ref,  # [1, BLK_Q*H, 128]
+    sel_ref,  # [1, BLK_Q, G*PS] f32 — 1 chosen (and seen), 0 not
+    *refs,
+    block_q: int, heads: int, block_k: int, group: int, scale: float,
+):
+    """One grid step (row b, q block qi, key group ki): the `group` pages
+    from ki * group on of row b's table, their latent tiles one under
+    another [G*PS, C] and the rope cells beside them, against the q block's
+    BLK_Q * H rows in ONE pair of products, a query's mask row spread over
+    its heads. Online softmax (m, l, acc) in VMEM scratch across a q
+    block's groups, `_online_update`'s rule; a cell enters iff the
+    selection names it (already causal). A group wholly past the q block's
+    last position, and a q block wholly of padding, compute nothing (and
+    fetch nothing new: the index maps stay on the last group that counts).
+    `refs`: G latent blocks [1, PS, C], G rope blocks [1, PS, 128], the
+    output as `qc_ref`, then m, l [BLK_Q*H, 1] and acc [BLK_Q*H, C], f32."""
+    del layer_ref, tables_ref
+    c_refs, r_refs = refs[:group], refs[group:2 * group]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
+    b, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    cells = group * block_k
+
+    @pl.when(ki == 0)
+    def _init():
+        _empty_softmax(m_ref, l_ref, acc_ref)
+
+    @pl.when(ki * cells <= last_ref[b, qi])
+    def _compute():
+        c = _stacked(c_refs)  # [G*PS, C]
+        nt = (((1,), (1,)), ((), ()))
+        scores = (
+            jax.lax.dot_general(qc_ref[0], c, nt,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(qr_ref[0], _stacked(r_refs), nt,
+                                  preferred_element_type=jnp.float32)
+        ) * scale  # [BLK_Q*H, G*PS]
+        keep = sel_ref[0] > 0.5  # [BLK_Q, G*PS]
+        scores = jnp.where(
+            keep[:, None, :], scores.reshape(block_q, heads, cells),
+            _NEG_INF).reshape(block_q * heads, cells)
+        _online_update(m_ref, l_ref, acc_ref, Ellipsis, scores, c)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        l = l_ref[:]
+        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "group",
+                                             "interpret"))
+def sparse_latent_extend(
+    q_abs: jnp.ndarray,  # [B, T, H, C]
+    q_rope: jnp.ndarray,  # [B, T, H, 128]
+    c_pages: jnp.ndarray,  # [L, P, PS, C]
+    r_pages: jnp.ndarray,  # [L, P, PS, 128 (+ Di)]: rope cell | index key
+    layer,  # int32 scalar
+    block_tables: jnp.ndarray,  # [B, PPN] int32
+    q_positions: jnp.ndarray,  # [B, T] int32 — global position of a query
+    chunk_lens: jnp.ndarray,  # [B] int32 — valid queries (rest are padding)
+    selected: jnp.ndarray,  # [B, T, PPN * PS] bool: topk_mask's, so causal
+    *,
+    scale: float,
+    block_q: int | None = None,
+    group: int | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Absorbed attention of a chunk of queries over the cells `selected`
+    names of row b's pages, one layer of the stacked pools read in place at
+    (layer, table[b, page]) through the prefetched table. Returns the mix of
+    latents [B, T, H, C]. Rows are (query, head) pairs, the chunk as it
+    lies: every head of a query meets the same key block under the same
+    mask row. Grid (row, q block, key group), the key axis innermost and as
+    long as the longest row's context takes (a run-time value); within it a
+    group past a q block's last position and a q block past `chunk_lens`
+    are skipped — such a block's rows come back as zeros. `selected` alone
+    decides what a query attends over: it must be causal, as `topk_mask`'s
+    answer is. `block_q`, `group`: `sparse_extend_blocks`' for the shapes;
+    only a measurement of the others (scripts/extend_select_cost.py, the
+    tests) names them. Same arithmetic as the plain einsums
+    (ops/attention._latent_extend_blocked): operands in the pool's dtype,
+    float32 products and softmax, the probabilities cast to the pool's
+    dtype for the value product, one division at the end."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b, t, h, c_dim = q_abs.shape
+    lanes = q_rope.shape[-1]
+    ps = c_pages.shape[2]
+    ppn = block_tables.shape[1]
+    blocks = sparse_extend_blocks(t, ppn)
+    blk_q = blocks[0] if block_q is None else min(block_q, t)
+    group = blocks[1] if group is None else group
+    assert t % blk_q == 0 and ppn % group == 0, (t, blk_q, ppn, group)
+    nq, steps, cells = t // blk_q, ppn // group, group * ps
+    starts = jnp.arange(nq, dtype=jnp.int32) * blk_q
+    last = jnp.where(
+        starts[None, :] < chunk_lens[:, None],
+        jnp.max(q_positions.reshape(b, nq, blk_q), axis=-1), -1
+    ).astype(jnp.int32)
+    live = jnp.clip(jnp.max(last) // cells + 1, 1, steps)
+
+    def q_map(bi, qi, ki, layer, tables, last):
+        return (bi, qi, 0)
+
+    def counted(bi, qi, ki, last):
+        """The key group the step fetches: ki, or the q block's last that
+        counts where ki is past it (the block then stays where it is)."""
+        return jnp.minimum(ki, jnp.maximum(last[bi, qi], 0) // cells)
+
+    def sel_map(bi, qi, ki, layer, tables, last):
+        return (bi, qi, counted(bi, qi, ki, last))
+
+    def page_map(g, bi, qi, ki, layer, tables, last):
+        return (layer[0], tables[bi, counted(bi, qi, ki, last) * group + g],
+                0, 0)
+
+    rows = blk_q * h
+
+    def q_spec(width):
+        return pl.BlockSpec((1, rows, width), q_map, memory_space=pltpu.VMEM)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, nq, live),
+        in_specs=[
+            q_spec(c_dim), q_spec(lanes),
+            pl.BlockSpec((1, blk_q, cells), sel_map, memory_space=pltpu.VMEM),
+            *(pl.BlockSpec((None, 1, ps, width),
+                           functools.partial(page_map, g),
+                           memory_space=pltpu.VMEM)
+              for width in (c_dim, lanes) for g in range(group))],
+        out_specs=q_spec(c_dim),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, c_dim), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_sparse_extend_kernel, block_q=blk_q, heads=h,
+                          block_k=ps, group=group, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, t * h, c_dim), q_abs.dtype),
+        grid_spec=grid_spec, interpret=interpret, name=SPARSE_EXTEND,
+        # the q block, its output and the accumulator are 14 MB at 16
+        # queries x 128 heads, a step's scores 4 MB more
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=48 << 20),
+    )(_layer_operand(layer), block_tables.astype(jnp.int32), last,
+      q_abs.reshape(b, t * h, c_dim), q_rope.reshape(b, t * h, lanes),
+      selected.astype(jnp.float32),
+      *(pool for pool in (c_pages, r_pages) for _ in range(group)))
+    return out.reshape(b, t, h, c_dim)
+
+
+# ---------------------------------------------------------------------------
 # Flat paged decode: GQA against a pool WITHOUT a head axis, a cell one row
 # of all its KV heads side by side — keys [L, P, PS, K*D], values [L, P, PS,
 # K*Dv]. For heads whose width is no multiple of 128 lanes on fewer than 8

@@ -13,6 +13,10 @@ largest operations (benchmark/trace.py's reduction), and what stopping,
 reading and reducing the trace cost (`trace_cost_s`). For reproducing a
 failure or reading a step's breakdown without gateway, scheduler or
 traffic; a CPU run (JAX_PLATFORMS=cpu, a preset) only shows that it runs.
+`--extend N` profiles N extend chunks of 512 tokens a row behind the context
+instead (`step_s` is then seconds a TOKEN of a chunk): where a chunk's
+device time goes, e.g. `--config benchmark/configs/dots3-note-prev-l5.json
+--rows 1 --context 6144 --extend 8` (PERF.md §5, PR 65).
 """
 
 from __future__ import annotations
@@ -39,6 +43,10 @@ def main() -> int:
     ap.add_argument("--bursts", type=int, default=6)
     ap.add_argument("--page-size", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--extend", type=int, default=0, metavar="CHUNKS",
+                    help="profile this many extend chunks of 512 tokens a "
+                         "row behind the context instead of decode bursts "
+                         "(at most eight rows)")
     ap.add_argument("--timeline", type=int, default=0,
                     help="also list this many consecutive device operations "
                          "from the middle of the trace: [label, start us, "
@@ -68,7 +76,7 @@ def main() -> int:
     params = launcher.make_params(family, cfg, args.seed, mesh)
 
     ps, rows, k = args.page_size, args.rows, args.burst
-    total = args.context + k * (args.bursts + 1)
+    total = args.context + max(k * (args.bursts + 1), 512 * (args.extend + 1))
     ppn = -(-total // ps)
     # a family with state per slot (models/nemotron_h.py): row i is slot i
     slotted = family.FAMILY.state_slot_bytes is not None
@@ -79,6 +87,7 @@ def main() -> int:
     chunk = min(args.context, 512)
     ids = jnp.asarray(rng.integers(8, cfg.vocab_size, (rows, args.context)),
                       jnp.int32)
+    slots = {}
     for lo in range(0, rows, 8):  # prefill in groups of eight, chunk by chunk
         sl = slice(lo, lo + 8)
         n = ids[sl].shape[0]
@@ -107,7 +116,22 @@ def main() -> int:
         return jax.lax.scan(body, (last, lens, cache_k, cache_v), None,
                             length=k)
 
-    step = jax.jit(burst, donate_argnums=(3, 4))
+    def chunks(params, last, lens, cache_k, cache_v, tables):
+        """One extend chunk of 512 random tokens a row behind `lens`, under
+        `burst`'s signature (its `k` is then the chunk's tokens)."""
+        more = jnp.asarray(rng.integers(8, cfg.vocab_size, (rows, 512)),
+                           jnp.int32)
+        logits, cache_k, cache_v, *stats = family.prefill_extend_pages(
+            params, cfg, more, jnp.full((rows,), 512, jnp.int32), lens,
+            tables, cache_k, cache_v, **slots)
+        return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 512,
+                cache_k, cache_v), stats
+
+    if args.extend:  # the rows of the last prefill group: all of them
+        assert rows <= 8, "--extend takes one prefill group of rows"
+        step, k, args.bursts = chunks, 512, args.extend
+    else:
+        step = jax.jit(burst, donate_argnums=(3, 4))
     last = ids[:, -1]
     lens = jnp.full((rows,), args.context, jnp.int32)
     (last, lens, cache_k, cache_v), stats = step(params, last, lens, cache_k,

@@ -3,6 +3,8 @@ docs/sparse-attention.md) on the CPU: the exact top-k with ties to the lower
 position, the indexer's scores, and the two decode kernels (interpreted)
 against the plain einsums they stand for."""
 
+import inspect
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -181,3 +183,167 @@ def test_the_ring_decode_is_the_latent_kernel_under_a_name_of_its_own():
         seen, 0.2)[:, 0]
     np.testing.assert_allclose(np.asarray(got)[[0, 2]],
                                np.asarray(want)[[0, 2]], atol=1e-5)
+
+
+# --- the extend kernel under a selection (interpreted) ------------------------
+
+
+def _chunk(rng, starts, t, *, heads=4, table=8, ps=8, density=0.5):
+    """A chunk of `t` queries a row from `starts` on over tables of `table`
+    pages of `ps` cells (a row's unused entries the trash page, 0), and a
+    selection of about `density` of the cells each query sees."""
+    b = len(starts)
+    c_pages, r_pages = _pools(rng, pages=b * table + 1, ps=ps)
+    positions = jnp.asarray(np.asarray(starts)[:, None] + np.arange(t)[None],
+                            jnp.int32)
+    used = -(-(np.asarray(starts) + t) // ps)  # pages a row's context fills
+    tables = rng.permutation(b * table).reshape(b, table) + 1
+    tables[np.arange(table)[None, :] >= used[:, None]] = 0
+    q_abs = jnp.asarray(rng.normal(size=(b, t, heads, 32)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(b, t, heads, 8)), jnp.float32)
+    cells = jnp.arange(table * ps)
+    seen = cells[None, None, :] <= positions[:, :, None]
+    chosen = seen & jnp.asarray(rng.random(seen.shape) < density)
+    chosen = chosen.at[:, :, 0].set(True)  # never an empty softmax
+    return (q_abs, q_rope, c_pages, r_pages, jnp.asarray(tables, jnp.int32),
+            positions, chosen)
+
+
+def _extend_kernel(chunk, lens=None, layer=1, **kw):
+    q_abs, q_rope, c_pages, r_pages, tables, positions, chosen = chunk
+    if lens is None:
+        lens = [q_abs.shape[1]] * q_abs.shape[0]
+    return np.asarray(kernels.sparse_latent_extend(
+        q_abs, attention._pad_last(q_rope, 128), c_pages, r_pages, layer,
+        tables, positions, jnp.asarray(lens, jnp.int32), chosen, scale=0.3,
+        interpret=True, **kw))
+
+
+def _whole_context(chunk, layer=1):
+    """The masked softmax over the gathered context, every score at once."""
+    q_abs, q_rope, c_pages, r_pages, tables, _, chosen = chunk
+    c = attention.gather_kv_pages(c_pages, tables, layer=layer)
+    r = attention.gather_kv_pages(r_pages, tables, layer=layer)[..., :128]
+    return np.asarray(attention._latent_attend(q_abs, q_rope, c, r, chosen,
+                                               0.3))
+
+
+@pytest.mark.parametrize("t", [16, 128, 512])
+@pytest.mark.parametrize("starts", [(5,), (37, 0, 18), (64, 121)],
+                         ids=["one-row", "three-rows", "a-groups-first-cell"])
+def test_the_extend_kernel_is_the_blocked_einsums_and_the_whole_context(
+        starts, t):
+    """The interpreted kernel at the chunk buckets, one row and three of
+    different contexts (ending inside a page and inside a group of pages,
+    starting on a group's first cell), against the blocked einsums it
+    replaces on the chip and against the masked softmax of the whole
+    context at once."""
+    rng = np.random.default_rng(t + len(starts))
+    heads = 4 if t < 512 else 2
+    table = -(-(max(starts) + t + 8) // 64) * 8  # whole groups of 8 pages
+    chunk = _chunk(rng, starts, t, heads=heads, table=table)
+    got = _extend_kernel(chunk)
+    blocked = np.asarray(attention._latent_extend_blocked(
+        *chunk[:4], 1, *chunk[4:], 0.3))
+    np.testing.assert_allclose(got, blocked, atol=2e-5)
+    np.testing.assert_allclose(got, _whole_context(chunk), atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,group", [
+    (8, 1), (8, 2), (16, 4), (4, 8), (16, 1), (2, 2), (8, 8), (16, 8)])
+def test_every_block_shape_of_the_extend_kernel_gives_the_same_answer(
+        block_q, group):
+    rng = np.random.default_rng(block_q * group)
+    chunk = _chunk(rng, (21, 2), 16)
+    got = _extend_kernel(chunk, block_q=block_q, group=group)
+    np.testing.assert_allclose(got, _whole_context(chunk), atol=2e-5)
+
+
+def test_a_query_that_sees_fewer_than_k_cells_attends_over_all_of_them():
+    """`topk_mask` at a k above the context: every seen cell is chosen, and
+    the kernel under that selection is the dense latent extend."""
+    rng = np.random.default_rng(11)
+    q_abs, q_rope, c_pages, r_pages, tables, positions, _ = _chunk(
+        rng, (9, 30), 16)
+    cells = jnp.arange(64)
+    seen = cells[None, None, :] <= positions[:, :, None]
+    chosen = attention.topk_mask(
+        jnp.asarray(rng.normal(size=seen.shape), jnp.float32), seen, 2048)
+    np.testing.assert_array_equal(np.asarray(chosen), np.asarray(seen))
+    got = _extend_kernel((q_abs, q_rope, c_pages, r_pages, tables, positions,
+                          chosen))
+    dense = attention.paged_latent_extend(
+        q_abs, q_rope, c_pages, r_pages[..., :128], 1, tables, positions,
+        scale=0.3)
+    np.testing.assert_allclose(got, np.asarray(dense), atol=2e-5)
+
+
+def test_a_chunks_padding_queries_are_skipped_and_the_real_ones_unmoved():
+    """Rows of 16, 11 and 3 valid queries of 16: the valid ones read as
+    they do without padding, a q block wholly of padding comes back as
+    zeros and costs no product (its last position is -1: no group counts)."""
+    rng = np.random.default_rng(12)
+    chunk = _chunk(rng, (30, 4, 17), 16)
+    lens = [16, 11, 3]
+    want = _whole_context(chunk)
+    got = _extend_kernel(chunk, lens, block_q=8, group=2)
+    for b, n in enumerate(lens):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=2e-5)
+    assert (got[2, 8:] == 0).all()  # the block of queries 8..15 of row 2
+    assert np.isfinite(got).all()
+
+
+def test_the_extend_kernel_never_reads_past_a_selection_or_a_position():
+    """Garbage in every cell outside the selection — the trash page's, the
+    pages past a row's context, the cells a query does not choose — changes
+    nothing: bit for bit."""
+    rng = np.random.default_rng(13)
+    chunk = _chunk(rng, (19, 3), 16)
+    q_abs, q_rope, c_pages, r_pages, tables, positions, chosen = chunk
+    assert (np.asarray(tables) == 0).any()  # unused entries: the trash page
+    got = _extend_kernel(chunk)
+    named = np.asarray(chosen).any(axis=1).reshape(2, 8, 8)  # by any query
+    dirty = np.asarray(c_pages).copy()
+    dirty[1, 0] = 1e4
+    for b in range(2):
+        for j in range(8):
+            if int(tables[b, j]):
+                dirty[1, int(tables[b, j])][~named[b, j]] = 1e4
+    again = _extend_kernel((q_abs, q_rope, jnp.asarray(dirty), r_pages,
+                            tables, positions, chosen))
+    np.testing.assert_array_equal(again, got)
+
+
+def test_the_extend_dispatcher_names_its_route_and_takes_whole_tiles_only(
+        monkeypatch):
+    """On the CPU the selection's extend is the blocked einsums ("xla");
+    with the kernels asked for it is the Pallas call at heads of 16 and a
+    chunk of 8, and the einsums again at shapes that are no whole tiles."""
+    rng = np.random.default_rng(14)
+    chunk = _chunk(rng, (9,), 8, heads=16)
+    q_abs, q_rope, c_pages, r_pages, tables, positions, chosen = chunk
+    args = (q_abs, q_rope, c_pages, r_pages, 1, tables, positions)
+    plain = attention.paged_latent_extend(*args, scale=0.3, selected=chosen)
+    assert attention.traced_routes()["sparse_latent_extend"] == "xla"
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    got = attention.paged_latent_extend(
+        *args, scale=0.3, selected=chosen,
+        chunk_lens=jnp.asarray([8], jnp.int32))
+    assert (attention.traced_routes()["sparse_latent_extend"]
+            == "pallas:sparse_latent_extend")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(plain), atol=2e-5)
+    odd = attention.paged_latent_extend(
+        q_abs[:, :6], q_rope[:, :6], *args[2:6], positions[:, :6], scale=0.3,
+        selected=chosen[:, :6])
+    assert attention.traced_routes()["sparse_latent_extend"] == "xla"
+    np.testing.assert_allclose(np.asarray(odd), np.asarray(plain)[:, :6],
+                               atol=2e-5)
+
+
+def test_the_extend_kernels_blocks_are_a_function_of_the_shapes():
+    assert kernels.sparse_extend_blocks(512, 136) == (16, 4)  # the cell's
+    assert kernels.sparse_extend_blocks(32, 136) == (16, 4)
+    assert kernels.sparse_extend_blocks(8, 34) == (8, 2)
+    assert kernels.sparse_extend_blocks(512, 17) == (16, 1)
+    assert list(inspect.signature(kernels.sparse_extend_blocks).parameters
+                ) == ["queries", "pages"]

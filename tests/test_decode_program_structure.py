@@ -18,6 +18,7 @@ import functools
 import inspect
 import pathlib
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -1134,6 +1135,77 @@ def test_pages_with_index_keys_and_a_latent_ring_are_read_where_they_lie(
     assert not bad, bad
     assert "remat_compressed" not in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20
+
+
+def test_a_chunk_under_a_selection_keeps_its_scores_on_the_chip(
+        one_chip, monkeypatch):
+    """dots3-note-prev's widths at the same cut (F dense, F, S), an extend
+    chunk of 512 queries over a table of 16 pages, compiled for a v5e: each
+    full layer's attention under the selection is ONE Mosaic call named
+    `sparse_latent_extend`, and nothing of heads x queries x cells in
+    float32 is left in HBM under that scope (the blocked einsums held
+    [1, 128, 512, 1024] there, 268 MB, and rewrote it five times a block:
+    PERF.md §6, PR 65). The dispatcher says which route it took: the Pallas
+    call here, "xla" on the CPU (tests/ops/test_sparse_attention.py)."""
+    from llmlb_tpu.models import dots3_note
+    from llmlb_tpu.ops import attention
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
+                           / "scripts"))
+    import program_temporaries
+
+    cfg = dots3_note.Dots3NoteConfig(
+        vocab_size=19008, hidden_size=5120, intermediate_size=13824,
+        num_layers=3, num_heads=128, num_kv_heads=128, head_dim=64,
+        rope_theta=8e7, rms_eps=1e-5, kv_lora_rank=512, q_lora_rank=1024,
+        q_lora_scale=5 ** 0.5, kv_lora_scale=10 ** 0.5,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_experts=32, router_experts=256, experts_per_token=8,
+        moe_intermediate_size=1536, num_shared_experts=1, first_k_dense=1,
+        routed_scaling_factor=1.0,
+        layer_types=("full", "full", "sliding"))
+    monkeypatch.setenv("LLMLB_TPU_ATTENTION", "pallas")
+    for module in (pallas_attention, pallas_moe):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+    fn = dots3_note.prefill_extend_pages
+    jitted = (fn, pallas_attention.sparse_latent_extend,
+              pallas_moe.grouped_expert_matmul,
+              pallas_moe.expert_rows_in_place)
+    for f in jitted:
+        f._clear_cache()
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda key: dots3_note.init_params(cfg, key), jax.random.PRNGKey(0)))
+    pools = on_chip(jax.eval_shape(
+        lambda: dots3_note.init_kv_pages(cfg, CHIP_PAGES, CHIP_PAGE_SIZE,
+                                         num_slots=1)))
+    ids = on_chip(jax.ShapeDtypeStruct((1, 512), jnp.int32))
+    row = on_chip(jax.ShapeDtypeStruct((1,), jnp.int32))
+    tables = on_chip(jax.ShapeDtypeStruct((1, CHIP_TABLE), jnp.int32))
+    attention._traced.pop("sparse_latent_extend", None)
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(params, cfg, ids, row, row, tables,
+                                *pools).compile()
+    finally:
+        for f in jitted:
+            f._clear_cache()
+    assert (attention.traced_routes()["sparse_latent_extend"]
+            == "pallas:sparse_latent_extend")
+    hlo = compiled.as_text()
+    calls = re.findall(r'^[^\n]*custom_call_target="tpu_custom_call"[^\n]*$',
+                       hlo, re.M)
+    assert sum("sparse_latent_extend/pallas_call" in c for c in calls) == 2
+    scores = 128 * 512 * CHIP_PAGE_SIZE * 4  # one page's, in float32
+    held = [r for r in program_temporaries.large_results(hlo, scores)
+            if r["shape"].startswith("f32")
+            and "sparse_latent_extend" in r["op_name"]]
+    assert not held, held
+    pool = rf"bf16\[2,{CHIP_PAGES},128,(512|256)\]"
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", hlo, re.M)
+    bad = [(shape, op) for shape, op in results
+           if re.match(pool, shape) and op in ("copy", "transpose")]
+    assert not bad, bad
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
