@@ -15,8 +15,11 @@ engine — every lifecycle edge the scheduler crosses:
                    never passed absent), with the prefill dispatches it took
                    (chunks) and the seq of the step records of its first
                    prefill (prefill_seq) and of the fetch that brought the
-                   token (fetch_seq). Once a request; the stages but accept
-                   sum to `finished`'s ttft_s (docs/tracing.md)
+                   token (fetch_seq), and where the stage `prefill` holds
+                   prefill steps of the request's own, that stage by what
+                   it waited for (prefill_cut: own, others, decode, loop).
+                   Once a request; the stages but accept sum to
+                   `finished`'s ttft_s (docs/tracing.md)
   staged           split-mode prefill complete, first token staged for a
                    decode-pool adoption (disagg, in-process)
   handoff_emitted  committed tokens wrapped into a cross-process handoff

@@ -298,7 +298,7 @@ class EngineMetrics:
         # Step-phase time breakdown (engine/stepstats.py): one histogram per
         # phase of the step loop, fed once per dispatch, plus the slow-step
         # anomaly counter. Lazily keyed so only phases that occur render.
-        from llmlb_tpu.engine.stepstats import PHASES, WAY_IN
+        from llmlb_tpu.engine.stepstats import PHASES, PREFILL_CUT, WAY_IN
 
         self.step_phase: dict[str, Histogram] = {
             p: Histogram(PHASE_BUCKETS) for p in PHASES
@@ -322,19 +322,33 @@ class EngineMetrics:
         # each first token (record_first_token).
         self.way_in_seconds_total = dict.fromkeys(WAY_IN, 0.0)
         self.way_in_requests_total = 0
+        # The stage `prefill` cut by what it waited for
+        # (stepstats.PREFILL_CUT), summed over the requests that have a cut
+        # (not a prompt that rode a burst, not a restored one), their count
+        # and their prefill dispatches.
+        self.prefill_cut_seconds_total = dict.fromkeys(PREFILL_CUT, 0.0)
+        self.prefill_cut_requests_total = 0
+        self.prefill_cut_chunks_total = 0
 
     # ------------------------------------------------------------ recorders
 
-    def record_first_token(self, ttft_s: float,
-                           stages: dict[str, float]) -> None:
+    def record_first_token(self, ttft_s: float, stages: dict[str, float],
+                           cut: dict[str, float] | None = None,
+                           chunks: int = 0) -> None:
         """A request's first token reached the host: its time to first
-        token, and its way in by stage (a stage it never passed is not
-        among `stages`)."""
+        token, its way in by stage (a stage it never passed is not among
+        `stages`) and, where it has one, the cut of its `prefill` stage
+        with the prefill dispatches it took."""
         with self._lock:
             self.ttft.observe(ttft_s)
             self.way_in_requests_total += 1
             for stage, seconds in stages.items():
                 self.way_in_seconds_total[stage] += seconds
+            if cut:
+                self.prefill_cut_requests_total += 1
+                self.prefill_cut_chunks_total += chunks
+                for part, seconds in cut.items():
+                    self.prefill_cut_seconds_total[part] += seconds
 
     def record_itl(self, seconds: float) -> None:
         with self._lock:
@@ -643,7 +657,13 @@ class EngineMetrics:
                     "requests_total": self.way_in_requests_total,
                     "seconds_total": {
                         stage: round(v, 6)
-                        for stage, v in self.way_in_seconds_total.items()}},
+                        for stage, v in self.way_in_seconds_total.items()},
+                    "prefill_cut_requests_total":
+                        self.prefill_cut_requests_total,
+                    "prefill_cut_chunks_total": self.prefill_cut_chunks_total,
+                    "prefill_cut_seconds_total": {
+                        part: round(v, 6) for part, v
+                        in self.prefill_cut_seconds_total.items()}},
                 "requests_total": self.requests_total,
                 "tokens_total": self.tokens_total,
                 "errors_total": self.errors_total,
@@ -1121,6 +1141,18 @@ class EngineMetrics:
             for stage, seconds in self.way_in_seconds_total.items():
                 lines.append(
                     f'llmlb_engine_way_in_seconds_total{{stage="{stage}"}} '
+                    f'{round(seconds, 6)}')
+            # ... and its `prefill` stage by what it waited for
+            for name, n in (("requests", self.prefill_cut_requests_total),
+                            ("chunks", self.prefill_cut_chunks_total)):
+                lines.append(
+                    f"# TYPE llmlb_engine_prefill_cut_{name}_total counter")
+                lines.append(f"llmlb_engine_prefill_cut_{name}_total {n}")
+            lines.append(
+                "# TYPE llmlb_engine_prefill_cut_seconds_total counter")
+            for part, seconds in self.prefill_cut_seconds_total.items():
+                lines.append(
+                    f'llmlb_engine_prefill_cut_seconds_total{{part="{part}"}} '
                     f'{round(seconds, 6)}')
         # where the step loops' time went, and the programs built: read
         # outside the lock (each has its own)

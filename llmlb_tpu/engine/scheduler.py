@@ -254,7 +254,9 @@ class Request:
     #                  dispatches it took, cached_tokens what a prefix hit
     #                  spared it
     #   activated_at   the host knows its prompt filled and has issued its
-    #                  activation (_stamp_activated)
+    #                  activation (_stamp_activated); prefill_cut is the
+    #                  stage `prefill` cut by what it waited for
+    #                  (stepstats.PREFILL_CUT), open from prefill_at to here
     #   first_token_at the first token reached the host (_emit)
     received_at: float | None = None
     submitted_at: float = dataclasses.field(
@@ -264,6 +266,7 @@ class Request:
     prefill_seq: int | None = None
     prefill_chunks: int = 0
     cached_tokens: int = 0
+    prefill_cut: "stepstats.PrefillCut | None" = None
     activated_at: float | None = None
     first_token_at: float | None = None
     finished_at: float | None = None
@@ -1841,7 +1844,8 @@ class EngineCore:
                       counters: "dict | None" = None,
                       block: "dict[str, int] | None" = None,
                       burst: "_Burst | None" = None,
-                      ahead: "bool | None" = None) -> None:
+                      ahead: "bool | None" = None,
+                      chunk: "dict[str, int] | None" = None) -> None:
         """Finalize the record of a CLOSED step: the admission time since
         the previous record becomes its plan phase, the record feeds the
         ring buffer + anomaly detector, and the phase durations are mirrored
@@ -1866,8 +1870,11 @@ class EngineCore:
         order the cycle took; `admitted` where an arrival's prompt rode its
         first step) and in the running totals. `ahead` is a
         one-shot prefill group's: whether it left before the burst in front
-        of it was emitted (`dispatched_ahead` on its record). A step that LoopClock.handover closed is observed
-        inside its successor, under `emit_inflight`."""
+        of it was emitted (`dispatched_ahead` on its record). `chunk` is a
+        chunked prompt's prefill step's: which chunk of its prompt it is
+        (`index`, from 0), where the chunk starts (`pos`) and the prompt's
+        tokens (`of`), on the record. A step that LoopClock.handover closed
+        is observed inside its successor, under `emit_inflight`."""
         phases = step.phases()
         request_ids: dict[str, str] | None = None
         if slots:
@@ -1894,6 +1901,8 @@ class EngineCore:
                                              queued=burst.queued)
         if ahead is not None:
             extra["dispatched_ahead"] = ahead
+        if chunk is not None:
+            extra["chunk"] = chunk
         if self._first_tokens:
             # the requests whose first token this step's fetch brought
             # (_first_token); absent on every other record
@@ -3750,12 +3759,20 @@ class EngineCore:
             )
         return info
 
-    def _prefill_counters(self, stats: list) -> dict | None:
+    def _prefill_counters(self, stats: list,
+                          step: StepSpan | None = None) -> dict | None:
         """A prefill dispatch's step counters on the host. The dispatch has
-        been waited for, and a prefill fetches nothing else: this is its
-        one small read (a burst's counters ride its token fetch instead)."""
+        been waited for, and a prefill fetches nothing else: these are its
+        reads, one blocking device-to-host copy an ARRAY of the family's
+        counters (a burst's ride its one token fetch instead). `step`: the
+        prefill's own open step, nothing of the loop in flight — the reads
+        stand under a span of their own, `counters` (stepstats.SPANS);
+        without it (a prefill recorded inside its successor) they hide
+        behind the burst in flight and have no span."""
         if not stats:
             return None
+        if step is not None:
+            step.mark("counters")
         return {name: (np.asarray(v).tolist() if np.ndim(v)
                        else int(v)) for name, v in stats[0].items()}
 
@@ -3824,7 +3841,7 @@ class EngineCore:
         self._fr_prefilled(group)
         self._activate_group(group, slot_ids, lens, logits)
         self._record_step("prefill", step, ahead=False,
-                          **self._prefill_counts(group, stats))
+                          **self._prefill_counts(group, stats, step))
         return None
 
     def _fr_prefilled(self, group: list[tuple[int, Request, int]]) -> None:
@@ -3836,12 +3853,13 @@ class EngineCore:
                                     tokens=n, cached_tokens=0)
 
     def _prefill_counts(self, group: list[tuple[int, Request, int]],
-                        stats: list) -> dict:
-        """What a one-shot group's record counts (_observe_step)."""
+                        stats: list, step: StepSpan | None = None) -> dict:
+        """What a one-shot group's record counts (_observe_step); `step`
+        as _prefill_counters takes it."""
         return {"active_slots": len(group),
                 "tokens": sum(n for _, _, n in group),
                 "slots": [s for s, _, _ in group],
-                "counters": self._prefill_counters(stats)}
+                "counters": self._prefill_counters(stats, step)}
 
     def _record_ahead_prefill(self, prefill: _AheadPrefill) -> None:
         """With the burst behind it in flight and the burst in front of it
@@ -3983,25 +4001,36 @@ class EngineCore:
         if not inflight:  # else _record_ahead_prefill, behind the wait
             self._stamp_activated([r for _, r, _ in group], stepstats._now())
 
-    @staticmethod
-    def _stamp_prefill(step: StepSpan, requests: "list[Request]") -> None:
+    def _stamp_prefill(self, step: StepSpan, requests: "list[Request]",
+                       cut: bool = True) -> None:
         """The way in: `step` is a prefill dispatch of `requests`. For one
         that has no first token yet, `place` ends where its FIRST dispatch
-        began (the step's own first stamp: no clock is read here)."""
+        began (the step's own first stamp: no clock is read here), and the
+        cut of its `prefill` stage opens there (stepstats.PrefillCut) —
+        but with `cut` false: `step` is a burst the prompt RIDES, a stage
+        with no prefill step in it."""
         for r in requests:
             if r.first_token_at is None:
                 if r.prefill_at is None:
                     r.prefill_at, r.prefill_seq = step.t0, step.seq
+                    if cut:
+                        r.prefill_cut = stepstats.PrefillCut(
+                            self._clock(), step)
+                elif r.prefill_cut is not None:
+                    r.prefill_cut.next_step(step)
                 r.prefill_chunks += 1
 
-    @staticmethod
-    def _stamp_activated(requests: "list[Request]", now: float) -> None:
+    def _stamp_activated(self, requests: "list[Request]",
+                         now: float) -> None:
         """The way in: `prefill` ends at `now`, ONE clock read for the
         group, where the host knows the prompts filled and has issued the
-        activation; `first_fetch` runs from it."""
+        activation; `first_fetch` runs from it, and the stage's cut is
+        closed (LoopClock.close_cut)."""
         for r in requests:
             if r.activated_at is None:
                 r.activated_at = now
+                if r.prefill_cut is not None:
+                    self._clock().close_cut(r.prefill_cut, now)
 
     # the per-slot device arrays an activation of a block family writes, in
     # the order of _activate_block_group's rows
@@ -4119,8 +4148,7 @@ class EngineCore:
         # spans of it and not a hole between records
         step.freeze_phases()
         self.metrics.record_prefill_step(step.mark("emit") - step.t0)
-        self._fr_emit(request, "prefill_chunk", tokens=n, cached_tokens=0,
-                      cp=True)
+        self._fr_emit(request, "prefill_chunk", tokens=n, cached_tokens=0)
         # KV beyond n is padding garbage; it lands in cells past the valid
         # length (masked by decode attention and overwritten as the sequence
         # grows into them) — same contract as the chunked path.
@@ -4176,6 +4204,9 @@ class EngineCore:
 
         self._note_prefill_dispatch()
         step = self._clock().begin("dispatch")
+        # which chunk of which prompt this record is: with `request_ids`
+        # and `seq`, the identity of the span on the device trace's clock
+        chunk = {"index": request.prefill_chunks, "pos": start, "of": n}
         self._stamp_prefill(step, [request])
         (logits, self.cache_k, self.cache_v,
          *stats) = self.programs.extend(
@@ -4199,8 +4230,8 @@ class EngineCore:
         self._record_step(
             "prefill", step,
             active_slots=1, tokens=chunk_len,
-            slots=[slot_id],
-            counters=self._prefill_counters(stats),
+            slots=[slot_id], chunk=chunk,
+            counters=self._prefill_counters(stats, step),
         )
         return True
 
@@ -4542,7 +4573,7 @@ class EngineCore:
                     # begins behind the placing, the prompt in its first step
                     riding, plan = self._admit_riding(arrivals[0], plan, k)
                     step = clock.begin("dispatch", after=step)
-                    self._stamp_prefill(step, [riding.request])
+                    self._stamp_prefill(step, [riding.request], cut=False)
                     prev, t_cycle, blocked_by = burst, step.t0, None
                     continue
                 placed, plan = self._admit_ahead(step, arrivals, plan, k)
@@ -5174,19 +5205,25 @@ class EngineCore:
         go, from the request's own stamps, to the three places that read
         them: the `first_token` flight-recorder event, the record of the
         step whose fetch brought the token (`first_tokens`, through
-        _observe_step) and the cumulative `.metrics.way_in`. Once a
+        _observe_step) and the cumulative `.metrics.way_in`; the cut of
+        the stage `prefill` goes with them (`prefill_cut`). Once a
         request; inside a capture the instant is an annotation too."""
         request.first_token_at = now
         stages = stepstats.way_in_stages(request)
-        self.metrics.record_first_token(now - request.submitted_at, stages)
+        # the stage `prefill` by what it waited for (stepstats.PREFILL_CUT):
+        # absent for a prompt that rode a burst, a restored request, and a
+        # stage two loops stamped
+        cut = request.prefill_cut and request.prefill_cut.parts
+        self.metrics.record_first_token(now - request.submitted_at, stages,
+                                        cut, request.prefill_chunks)
+        served = {"chunks": request.prefill_chunks,
+                  "prefill_seq": request.prefill_seq}
+        if cut:
+            served["prefill_cut"] = cut
         entry = {"id": gateway_rid(request.request_id), **stages,
-                 "chunks": request.prefill_chunks,
-                 "cached_tokens": request.cached_tokens,
-                 "prefill_seq": request.prefill_seq}
+                 "cached_tokens": request.cached_tokens, **served}
         self._first_tokens.append(entry)
-        self._fr_emit(request, "first_token", **stages,
-                      chunks=request.prefill_chunks,
-                      prefill_seq=request.prefill_seq,
+        self._fr_emit(request, "first_token", **stages, **served,
                       fetch_seq=self._fetch_seq)
         with first_token_annotation(entry["id"], self._fetch_seq):
             pass

@@ -53,6 +53,14 @@ Span names (``SPANS``), in the order a decode step runs them:
               program (first-token sample and the writes of the per-slot
               state), the first-token fetch where a grammar needs it, and
               the slot bookkeeping
+  counters  — a prefill's step counters read back to the host
+              (scheduler._prefill_counters: one blocking read an array,
+              the last thing of a prefill step served in today's order;
+              a burst's counters ride its token fetch and have no span).
+              The instrument's own cost on the hot path, under its own
+              name: absent where the family has no counters, and where the
+              prefill left ahead and is recorded with a burst in flight
+              (the reads hide behind the device's work there)
 
 Host work done WITH A PROGRAM OF THIS LOOP ON THE DEVICE, between a
 dispatch's return and the wait for it (scheduler._decode_bursts), has span
@@ -103,9 +111,10 @@ stretch of ``admit``, and ``admitted`` on the record.
 
 The legacy ``phases_s`` keep their meaning: ``plan`` is the admission time
 since the previous record (``since_prev.admit_s``), ``emit`` still covers
-activation, the in-flight spans count under ``compute`` — which is the
-interval from the dispatch's return to the device's completion, whatever
-the host did in it — and ``total_s`` is their sum. Records land in a bounded ring
+activation and the ``counters`` span, the in-flight spans count under
+``compute`` — which is the interval from the dispatch's return to the
+device's completion, whatever the host did in it — and ``total_s`` is
+their sum. Records land in a bounded ring
 buffer served at the engine's ``/api/steps`` plus per-phase histograms in
 ``/metrics``. A slow-step anomaly detector keeps an EMA per kind of the time
 a step took since the previous one ended (idle sleep left out), and flags
@@ -143,7 +152,7 @@ PHASES = ("plan", "draft", "host_sync", "dispatch", "compute", "fetch",
           "emit")
 # The closed set of span names a step is cut into (StepSpan.mark).
 SPANS = ("draft", "host_sync", "dispatch", "compute", "fetch", "emit",
-         "activate", "host_sync_inflight", "emit_inflight",
+         "activate", "counters", "host_sync_inflight", "emit_inflight",
          "activate_inflight", "dispatch_inflight", "fetch_inflight")
 # Host work with a program of this loop on the device: `compute` in the
 # legacy phases.
@@ -162,6 +171,19 @@ LOOP_BUCKETS = ("step",) + GAP_BUCKETS
 WAY_IN = ("accept", "inbox", "place", "prefill", "first_fetch")
 WAY_IN_STAMPS = ("received_at", "submitted_at", "taken_at", "prefill_at",
                  "activated_at", "first_token_at")
+
+
+# The stage `prefill` of a chunked prompt holds four different waits, each
+# with a cure of its own: PREFILL_CUT names them, seconds each, and they sum
+# to the stage by construction (LoopClock.close_cut).
+#   own     the prompt's own prefill steps (dispatch, compute, emit of each
+#           chunk; the last one up to `activated_at`)
+#   others  the prefill steps of OTHER prompts inside the stage (the
+#           rotation among the prefilling slots)
+#   decode  the decode and verify steps between its chunks
+#   loop    the loop between steps: every gap bucket (admit, control,
+#           record, idle, other)
+PREFILL_CUT = ("own", "others", "decode", "loop")
 
 
 def way_in_stages(request) -> dict[str, float]:
@@ -255,20 +277,47 @@ class StepSpan:
 
     def phases(self) -> dict[str, float]:
         """The legacy phase durations of a closed step: spans summed by
-        name, `activate` counted as `emit`, the in-flight spans as
-        `compute`, and the admission time since the previous record as
-        `plan`."""
+        name, `activate` and `counters` counted as `emit`, the in-flight
+        spans as `compute`, and the admission time since the previous
+        record as `plan`."""
         out = {"plan": self.since_prev["admit"]}
         spans = self.spans
         if self._legacy_spans is not None:
             spans = spans[:self._legacy_spans]
         for name, _start, dur in spans:
-            if name == "activate":
+            if name in ("activate", "counters"):
                 name = "emit"
             elif name in INFLIGHT_SPANS:
                 name = "compute"
             out[name] = out.get(name, 0.0) + dur
         return out
+
+
+class PrefillCut:
+    """A request's `prefill` stage while it is open: made where `step`,
+    just begun on `clock`, is the request's FIRST prefill dispatch (the
+    stage begins at step.t0, up to which the clock's cumulative sums stand:
+    begin switched there, so no clock is read), closed by
+    LoopClock.close_cut at `activated_at`. It holds the clock that stamps
+    it, that clock's sums where the stage began, the walls of the request's
+    own prefill steps that are closed, and its newest one. `parts` is the
+    closed cut (PREFILL_CUT, seconds), None until then and for a stage that
+    two clocks stamped."""
+
+    __slots__ = ("clock", "base", "own_s", "step", "parts")
+
+    def __init__(self, clock: "LoopClock", step: StepSpan):
+        self.clock = clock
+        self.base = clock.cut_marks()
+        self.own_s = 0.0
+        self.step = step
+        self.parts: dict[str, float] | None = None
+
+    def next_step(self, step: StepSpan) -> None:
+        """`step` is the request's next prefill step: the one before it
+        is closed by now, and its wall is the request's own."""
+        self.own_s += self.step.t1 - self.step.t0
+        self.step = step
 
 
 class LoopClock:
@@ -282,6 +331,10 @@ class LoopClock:
         self.recorder = recorder
         self.tag = tag
         self.acc = dict.fromkeys(LOOP_BUCKETS, 0.0)
+        # acc["step"] by the kind each step was closed as: between two
+        # stamps of this clock, elapsed = the three kinds' difference + the
+        # gap buckets' + the open step's part (PREFILL_CUT)
+        self.step_seconds_by_kind = dict.fromkeys(KINDS, 0.0)
         self._gap = dict.fromkeys(GAP_BUCKETS, 0.0)
         self._bucket = "other"
         self._mark = _now()
@@ -357,6 +410,7 @@ class LoopClock:
         step.gc_s = gc_total - self._gc_closed
         self._gc_closed = gc_total
         self.acc["step"] += now - self._mark
+        self.step_seconds_by_kind[kind] += now - self._mark
         self._bucket = "record"
         self._mark = now
 
@@ -386,6 +440,41 @@ class LoopClock:
         self._gap["other"] += dt
         self._bucket = "other"
         self._mark = now
+
+    def cut_marks(self) -> tuple[float, float, float]:
+        """Cumulative seconds of this loop's closed prefill steps, of its
+        closed decode and verify steps, and of its gap buckets up to the
+        last switch: what a PrefillCut is the difference of."""
+        kinds, acc = self.step_seconds_by_kind, self.acc
+        return (kinds["prefill"], kinds["decode"] + kinds["verify"],
+                sum(acc[b] for b in GAP_BUCKETS))
+
+    def close_cut(self, cut: PrefillCut, now: float) -> None:
+        """The stage ends at `now` (`activated_at`, the caller's read):
+        `cut.parts` = its seconds by PREFILL_CUT. Steps of one loop never
+        overlap and the buckets sum to the thread's lifetime, so the four
+        sum to now - the first step's t0. The running stretch is `own`
+        where the request's newest prefill step is the open one (today's
+        order: the activation is inside it), else that step is closed and
+        the stretch belongs to the burst behind it (a prefill that left
+        ahead) or, between steps, to the loop. A stage that another
+        loop's clock began (split mode's handoff) has no cut."""
+        if cut.clock is not self:
+            return
+        prefill, decode, loop = (
+            b - a for a, b in zip(cut.base, self.cut_marks()))
+        own, step, running = cut.own_s, cut.step, now - self._mark
+        if self._step is step:
+            own += running
+            prefill += running
+        else:
+            own += step.t1 - step.t0
+            if self._step is None:
+                loop += running
+            else:
+                decode += running
+        cut.parts = {"own": round(own, 6), "others": round(prefill - own, 6),
+                     "decode": round(decode, 6), "loop": round(loop, 6)}
 
     def snapshot(self) -> dict[str, float]:
         """The cumulative buckets with the running stretch added to the

@@ -19,6 +19,11 @@ and the record that carried it (the fetch). Prints one JSON object a file:
       several; a prefix hit): `n`; the median, 90th percentile and mean of
       each stage and of their sum, in ms; the client's own time to first
       token for the same requests (first frame less the due instant);
+      `prefill_cut`: the stage `prefill` by what it waited for (`own` — the
+      request's own prefill steps — `others` — other prompts' — `decode` —
+      the bursts between — `loop`: the entry's `prefill_cut`, PR 66) over
+      the class's requests that have one: `n`, and for each part its mean
+      and median in ms and `share_pct`, the part's sum over the stage's;
   join   the share of the first prefills and of the fetches that were
       dispatched ahead (`dispatched_ahead` of the two records), the decode
       records between a request's first prefill and its fetch, the `compute`
@@ -30,7 +35,8 @@ and the record that carried it (the fetch). Prints one JSON object a file:
       `sched.queue_wait_p50_s` holds.
 
 `--rows` adds one row a request. A commit before PR 50 serves no
-`first_tokens`: every class reads `n` 0. No jax, no chip: it reads a file.
+`first_tokens`: every class reads `n` 0; one before PR 66 no `prefill_cut`:
+that key reads null. No jax, no chip: it reads a file.
 """
 
 from __future__ import annotations
@@ -45,6 +51,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark import stats, way_in  # noqa: E402
 
 STAGES = ("accept",) + way_in.TTFT_STAGES
+CUT = ("own", "others", "decode", "loop")
 
 
 def _ms(values: list[float], scale: float = 1e3) -> dict[str, float] | None:
@@ -64,6 +71,22 @@ def _class(pairs: list[tuple[dict, dict]]) -> dict:
                               for _r, e in pairs
                               if all(s in e for s in way_in.TTFT_STAGES)])
     out["client_ttft"] = _ms([r["first_s"] - r["due_s"] for r, _e in pairs])
+    out["prefill_cut"] = _cut([e for _r, e in pairs if "prefill_cut" in e])
+    return out
+
+
+def _cut(entries: list[dict]) -> dict | None:
+    """The stage `prefill` of `entries` by what it waited for."""
+    if not entries:
+        return None
+    stage = sum(e["prefill"] for e in entries)
+    out: dict = {"n": len(entries)}
+    for part in CUT:
+        values = [e["prefill_cut"][part] for e in entries]
+        out[part] = {"mean": round(1e3 * sum(values) / len(values), 3),
+                     "p50": round(1e3 * stats.percentile(values, 50), 3),
+                     "share_pct": (round(100.0 * sum(values) / stage, 1)
+                                   if stage > 0 else None)}
     return out
 
 
@@ -117,6 +140,9 @@ def read(path: str, rows: bool = False) -> dict:
                 "id": r["id"], "prompt_tokens": r["prompt_tokens"],
                 **{s: round(1e3 * e[s], 3) for s in STAGES if s in e},
                 "chunks": e["chunks"], "cached_tokens": e["cached_tokens"],
+                **({"prefill_cut": {k: round(1e3 * v, 3) for k, v
+                                    in e["prefill_cut"].items()}}
+                   if "prefill_cut" in e else {}),
                 "prefill_seq": e["prefill_seq"], "fetch_seq": e["fetch_seq"],
                 "fetch_ahead": e["fetch_dispatched_ahead"],
                 "client_ttft": round(1e3 * (r["first_s"] - r["due_s"]), 3)})

@@ -988,6 +988,55 @@ async def test_instrumentation_overhead_under_one_percent(served_engine):
     )
 
 
+async def test_a_prefill_steps_counters_mark_and_cut_are_inside_the_guarantee(
+        served_engine):
+    """The same bound for what a PREFILL step records since the `counters`
+    span and the cut of the `prefill` stage (docs/tracing.md "A request's
+    way in"): five marks, the cut opened at the step's begin and closed at
+    the activation's stamp, one more float add at the close — against the
+    CPU debug engine's mean prefill step."""
+    from llmlb_tpu.engine.metrics import EngineMetrics
+
+    engine = served_engine
+    await _run_requests(engine, n=2, max_tokens=4)
+    hist = engine.core.metrics.prefill_step
+    assert hist.n > 0
+    mean_step_s = hist.total / hist.n
+
+    rec = StepRecorder()
+    clock = LoopClock(rec, "main")
+    metrics = EngineMetrics()
+    n = 2000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        clock.switch("admit")
+        clock.switch("other")
+        step = clock.begin("dispatch")
+        cut = stepstats.PrefillCut(clock, step)
+        for name in ("compute", "emit", "activate"):
+            step.mark(name)
+        clock.close_cut(cut, stepstats._now())
+        step.mark("counters")
+        clock.close(step, "prefill")
+        phases = step.phases()
+        slow = rec.observe("prefill", phases, active_slots=1, tokens=16,
+                           request_ids={"0": "a"}, span=step,
+                           extra={"chunk": {"index": 0, "pos": 0, "of": 16}})
+        metrics.record_step_phases(phases, slow=slow)
+        clock.resume(step)
+    per_step = (time.perf_counter() - t0) / n
+    record = rec.snapshot(limit=1)["records"][0]
+    assert [name for name, _at, _dur in record["spans"]] == [
+        "dispatch", "compute", "emit", "activate", "counters"]
+    assert cut.parts["own"] > 0 and cut.parts["others"] == 0
+    assert clock.step_seconds_by_kind["prefill"] == pytest.approx(
+        clock.acc["step"])
+    assert per_step < 0.01 * mean_step_s, (
+        f"instrumentation {per_step * 1e6:.1f}µs/step vs mean prefill step "
+        f"{mean_step_s * 1e3:.3f}ms — over the 1% budget"
+    )
+
+
 # -------------------------------------------------------------- /api/profile
 
 

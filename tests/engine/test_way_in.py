@@ -8,6 +8,7 @@ whose fetch brought the token, and the cumulative `.metrics.way_in`."""
 
 import asyncio
 import glob
+import itertools
 import os
 import time
 
@@ -16,9 +17,9 @@ import pytest
 from llmlb_tpu.engine import stepstats
 from llmlb_tpu.engine.flightrec import EVENTS
 from llmlb_tpu.engine.scheduler import Request, SamplingParams
-from llmlb_tpu.engine.stepstats import WAY_IN, way_in_stages
+from llmlb_tpu.engine.stepstats import PREFILL_CUT, WAY_IN, way_in_stages
 from llmlb_tpu.engine.streamstats import EventQueue, StreamStats
-from tests.support import collect
+from tests.support import InlineLoop, collect
 
 ENGINE_STAGES = ("inbox", "place", "prefill", "first_fetch")
 
@@ -358,6 +359,281 @@ def test_a_parked_and_resumed_request_keeps_the_stamps_it_has():
         assert core.metrics.summary()["way_in"]["requests_total"] == 2
     finally:
         core.stop()
+
+
+# ------------------------------- the `prefill` stage, cut by what it waited for
+
+
+class _Served:
+    """One inline engine (tests.support.InlineLoop) under a made-up clock
+    that advances a millisecond a read, so every stamp differs and every
+    sum is exact: a row that decodes (`front`, a one-shot prompt on an idle
+    engine), `arrivals` put on the inbox while its second burst is in
+    flight, all served to their ends."""
+
+    def __init__(self, arrivals, preset="debug-tiny", rides=True):
+        from llmlb_tpu.engine.presets import get_preset
+        from llmlb_tpu.engine.scheduler import EngineCore
+
+        with pytest.MonkeyPatch.context() as patch:
+            ticks = itertools.count(1)
+            patch.setattr(stepstats, "_now", lambda: next(ticks) * 0.001)
+            core = self.core = EngineCore(
+                get_preset(preset), num_slots=4, slot_capacity=256,
+                prefill_buckets=(16,), kv_page_size=16, seed=0,
+                decode_burst=4, prefix_cache=False)
+            loop = InlineLoop(core, rides=rides)
+            self.front = Request(
+                prompt_ids=[5, 6, 7], request_id="front",
+                sampling=SamplingParams(temperature=0.0, max_tokens=120))
+            self.arrivals = arrivals
+            core.pending.put(self.front)
+            loop.during[2] = [lambda: [core.pending.put(r) for r in arrivals]]
+            self.before = core.metrics.summary()["way_in"]
+            loop.run(iterations=2000)
+            self.after = core.metrics.summary()["way_in"]
+            self.records = loop.records()
+        for r in (self.front, *arrivals):
+            assert collect(r, None)[1] == "length"
+        self.entries = {e["id"]: e for r in self.records
+                        for e in r.get("first_tokens", ())}
+
+    def inside(self, request, kind):
+        """The records of `kind` that lie inside `request`'s `prefill`
+        stage (records of one loop never overlap: inside or outside)."""
+        return [r for r in self.records if r["kind"] == kind
+                and r["t0_s"] >= request.prefill_at - 1e-9
+                and r["t1_s"] <= request.activated_at + 1e-9]
+
+    def steps_of(self, request):
+        return [r for r in self.records if r["kind"] == "prefill"
+                and request.request_id in r["request_ids"].values()]
+
+
+def _long(name: str) -> Request:
+    return Request(prompt_ids=[ord(name[0])] * 40, request_id=name,
+                   sampling=SamplingParams(temperature=0.0, max_tokens=6))
+
+
+def _short(name: str) -> Request:
+    return Request(prompt_ids=[8, 2, 3, 4, 5], request_id=name,
+                   sampling=SamplingParams(temperature=0.0, max_tokens=8))
+
+
+@pytest.fixture(scope="module")
+def rotation():
+    """Three prompts of three chunks each (16 + 16 + 8), in rotation beside
+    a decoding row."""
+    return _Served([_long("a"), _long("b"), _long("c")])
+
+
+@pytest.fixture(scope="module")
+def placed_ahead():
+    """A short arrival placed AHEAD of the fetched burst's emit (an engine
+    with no mixed step)."""
+    return _Served([_short("ahead")], rides=False)
+
+
+@pytest.fixture(scope="module")
+def rode():
+    """The same arrival where its prompt rides the next burst."""
+    return _Served([_short("rides")])
+
+
+def test_the_cut_is_a_closed_set_of_four():
+    assert PREFILL_CUT == ("own", "others", "decode", "loop")
+
+
+@pytest.mark.parametrize("name", ["a", "b", "c"])
+def test_a_chunked_prompts_cut_sums_to_its_prefill_stage(rotation, name):
+    entry = rotation.entries[name]
+    cut = entry["prefill_cut"]
+    assert tuple(cut) == PREFILL_CUT and entry["chunks"] == 3
+    assert all(v >= 0 for v in cut.values())
+    assert sum(cut.values()) == pytest.approx(entry["prefill"], abs=1e-6)
+    request = next(r for r in rotation.arrivals if r.request_id == name)
+    assert request.prefill_cut.parts == cut
+    # the flight recorder's event carries the same cut
+    first = _events(rotation.core, name, "first_token")[0]["attrs"]
+    assert first["prefill_cut"] == cut and first["chunks"] == 3
+
+
+def test_others_is_the_rotation_and_decode_the_bursts_between(rotation):
+    first = min(rotation.arrivals, key=lambda r: r.prefill_at)
+    cut = rotation.entries[first.request_id]["prefill_cut"]
+    mine = rotation.steps_of(first)
+    theirs = [r for r in rotation.inside(first, "prefill") if r not in mine]
+    # the other two prompts' chunks, between this one's first and last
+    assert {i for r in theirs for i in r["request_ids"].values()} == {
+        r.request_id for r in rotation.arrivals if r is not first}
+    assert len(theirs) >= 4
+    assert cut["others"] == pytest.approx(
+        sum(r["wall_s"] for r in theirs), abs=1e-6)
+    bursts = rotation.inside(first, "decode")
+    assert len(bursts) >= 6  # one a loop iteration, behind each chunk
+    assert cut["decode"] == pytest.approx(
+        sum(r["wall_s"] for r in bursts), abs=1e-6)
+    # its own chunks, the last one up to the stamp that ends the stage
+    assert len(mine) == 3
+    assert cut["own"] == pytest.approx(
+        sum(r["wall_s"] for r in mine[:2])
+        + first.activated_at - mine[2]["t0_s"], abs=1e-6)
+    # what is left is the loop between the records
+    gaps = sum(sum(r["since_prev"].values()) for r in rotation.records
+               if first.prefill_at < r["t0_s"] <= first.activated_at)
+    assert cut["loop"] == pytest.approx(gaps, abs=1e-6) and gaps > 0
+
+
+def test_a_one_shot_prompts_cut_is_its_own_step(rotation):
+    entry = rotation.entries["front"]
+    assert entry["chunks"] == 1
+    assert entry["prefill_cut"] == {"own": entry["prefill"], "others": 0.0,
+                                    "decode": 0.0, "loop": 0.0}
+
+
+def test_a_prompt_placed_ahead_ends_its_stage_inside_the_burst_behind(
+        placed_ahead):
+    request, = placed_ahead.arrivals
+    entry = placed_ahead.entries["ahead"]
+    prefill, = placed_ahead.steps_of(request)
+    assert prefill["dispatched_ahead"] is True
+    cut = entry["prefill_cut"]
+    assert sum(cut.values()) == pytest.approx(entry["prefill"], abs=1e-6)
+    # the whole prefill record is its own; the rest of the stage is the
+    # burst behind it, in flight where the host learns the prefill done
+    assert cut["own"] == pytest.approx(prefill["wall_s"], abs=1e-6)
+    assert cut["decode"] == pytest.approx(
+        request.activated_at - prefill["t1_s"], abs=1e-6)
+    assert cut["decode"] > 0 and cut["others"] == 0 and cut["loop"] == 0
+
+
+def test_a_prompt_that_rode_a_burst_has_no_cut(rode):
+    request, = rode.arrivals
+    entry = rode.entries["rides"]
+    assert "prefill" in entry and "prefill_cut" not in entry
+    assert request.prefill_cut is None
+    assert "prefill_cut" not in _events(
+        rode.core, "rides", "first_token")[0]["attrs"]
+    # the one cut of the run is the row's in front, a one-shot prompt
+    assert rode.after["prefill_cut_requests_total"] == 1
+    assert rode.after["prefill_cut_chunks_total"] == 1
+    assert rode.after["requests_total"] == 2
+
+
+def test_a_restored_request_has_no_cut_and_moves_no_counter(rode):
+    """Its KV came as bytes: `_insert_restored` stamps `activated_at` alone,
+    so there is no `prefill` stage to cut."""
+    core = rode.core
+    restored = Request(prompt_ids=[1, 2, 3], request_id="restored",
+                       sampling=SamplingParams(max_tokens=4))
+    restored.taken_at = stepstats._now()
+    before = core.metrics.summary()["way_in"]
+    core._stamp_activated((restored,), stepstats._now())
+    core._begin_delivery(type("Step", (), {"seq": 0}))
+    core._first_token(restored, stepstats._now())
+    entry, = core._first_tokens
+    core._first_tokens = []
+    assert "prefill" not in entry and "prefill_cut" not in entry
+    after = core.metrics.summary()["way_in"]
+    assert after["requests_total"] == before["requests_total"] + 1
+    for key in ("prefill_cut_requests_total", "prefill_cut_chunks_total",
+                "prefill_cut_seconds_total"):
+        assert after[key] == before[key]
+
+
+def test_the_cumulative_cut_is_the_sum_of_the_entries(rotation):
+    before, after = rotation.before, rotation.after
+    assert before["prefill_cut_requests_total"] == 0
+    entries = rotation.entries.values()
+    assert after["prefill_cut_requests_total"] == len(entries) == 4
+    assert after["prefill_cut_chunks_total"] == 1 + 3 * 3
+    for part in PREFILL_CUT:
+        assert (after["prefill_cut_seconds_total"][part]
+                - before["prefill_cut_seconds_total"][part]
+                ) == pytest.approx(
+                    sum(e["prefill_cut"][part] for e in entries), abs=1e-6)
+    # ... and the four parts together are the stage's own counter
+    assert sum(after["prefill_cut_seconds_total"].values()) == pytest.approx(
+        after["seconds_total"]["prefill"], abs=1e-5)
+
+
+def test_the_cut_is_served_on_metrics(rotation):
+    text = rotation.core.metrics.render(queue_depth=0, active_slots=0,
+                                        num_slots=4)
+    assert "llmlb_engine_prefill_cut_requests_total 4" in text
+    assert "llmlb_engine_prefill_cut_chunks_total 10" in text
+    for part in PREFILL_CUT:
+        assert f'llmlb_engine_prefill_cut_seconds_total{{part="{part}"}} ' \
+            in text
+
+
+@pytest.mark.parametrize("name", ["a", "b", "c"])
+def test_a_chunks_record_says_which_chunk_it_is(rotation, name):
+    request = next(r for r in rotation.arrivals if r.request_id == name)
+    steps = rotation.steps_of(request)
+    assert [r["chunk"] for r in steps] == [
+        {"index": 0, "pos": 0, "of": 40}, {"index": 1, "pos": 16, "of": 40},
+        {"index": 2, "pos": 32, "of": 40}]
+    assert [r["tokens"] for r in steps] == [16, 16, 8]
+    assert all(list(r["request_ids"].values()) == [name] for r in steps)
+    # a one-shot group's record gains nothing
+    front, = rotation.steps_of(rotation.front)
+    assert "chunk" not in front
+
+
+def _span_names(record) -> list[str]:
+    return [name for name, _at, _dur in record["spans"]]
+
+
+@pytest.fixture(scope="module")
+def counted():
+    """A family whose calls return step counters (a mixture's expert load):
+    a one-shot prompt and a chunked one."""
+    return _Served([_long("chunked")], preset="debug-mla-tiny")
+
+
+@pytest.fixture(scope="module")
+def counted_ahead():
+    """The same family, a one-shot prompt placed ahead."""
+    return _Served([_short("ahead")], preset="debug-mla-tiny", rides=False)
+
+
+def test_reading_a_prefills_counters_is_a_span_of_its_own(counted):
+    front, = counted.steps_of(counted.front)
+    chunks = counted.steps_of(counted.arrivals[0])
+    assert len(chunks) == 3
+    for record in (front, *chunks):
+        names = _span_names(record)
+        # the last thing of the step, behind the activation where there is one
+        assert names.count("counters") == 1 and names[-1] == "counters"
+        assert record["experts_touched"] > 0
+        # in the legacy phases it is `emit`, as the activation is
+        host = sum(dur for name, _at, dur in record["spans"]
+                   if name in ("emit", "activate", "counters"))
+        assert record["phases_s"]["emit"] == pytest.approx(host, abs=2e-6)
+        assert record["total_s"] == pytest.approx(
+            record["wall_s"] + record["since_prev"]["admit_s"], abs=2e-6)
+    assert _span_names(chunks[0]) == ["dispatch", "compute", "emit",
+                                      "counters"]
+    assert _span_names(chunks[2]) == ["dispatch", "compute", "emit",
+                                      "activate", "counters"]
+
+
+def test_a_prefill_recorded_behind_a_burst_reads_its_counters_unmarked(
+        counted_ahead):
+    """It left ahead and is recorded inside its successor, with the device
+    busy: the reads hide there, and the record has the counters all the
+    same."""
+    ahead, = counted_ahead.steps_of(counted_ahead.arrivals[0])
+    assert ahead["dispatched_ahead"] is True
+    assert "counters" not in _span_names(ahead)
+    assert ahead["experts_touched"] > 0
+
+
+def test_a_family_without_counters_has_no_such_span(rotation):
+    prefills = [r for r in rotation.records if r["kind"] == "prefill"]
+    assert len(prefills) == 10
+    assert all("counters" not in _span_names(r) for r in prefills)
 
 
 # ------------------------------------------------- over HTTP, and in a capture
